@@ -1,0 +1,644 @@
+"""chip_smoke.py — the quickest proof that the system still starts on the chip.
+
+    python3 chip_smoke.py        # on a machine with a TPU; one process
+
+Drives the main path once through the entry points a user would call, at the
+full width of the largest dense model the repo builds (transformer LM,
+12 layers x 1024, vocab 32000): trained for a few steps by `Executor`, then
+served by `PagedKVEngine` behind `EngineServer` from the SAME scope. Then a
+ResNet-50 train step, every Pallas kernel the package selects by default on a
+TPU against its own composite, and — with four or more devices — the same LM
+under `ParallelExecutor` on a dp2 x tp2 mesh plus ring attention on sp4.
+
+Each phase prints one JSON line. The last line of stdout is
+`{"ok": true, "device": {...}}` and the exit code is 0 only when every phase
+passed. Without an accelerator (or on a device the repo has no peaks for) the
+`device` phase fails: there is no size, flag or environment variable that
+lets this script pass on a CPU. Weights are random, from fixed seeds; nothing
+is read from disk or network; no child process is started (a chip belongs to
+one process).
+
+The phase bodies are plain functions of their sizes so that
+tests/test_chip_smoke.py can run them tiny on the virtual CPU mesh.
+"""
+
+from __future__ import annotations
+
+import gc
+import json
+import os
+import re
+import sys
+import threading
+import time
+import traceback
+
+import numpy as np
+
+# whole-run deadline: the contract is exit within 1200 s, compilation included
+DEADLINE_S = 1140.0
+
+# Tolerances, as a fraction of max|reference|.
+# bf16 kernels: inputs are bf16 (8 mantissa bits, ulp 2^-8 relative); the
+# kernel rounds P to bf16 before the PV matmul and the output to bf16, the
+# f32 reference does neither — a few bf16 ulps at the output's scale.
+TOL_BF16 = 2.0 ** -6
+# f32 kernels on the VPU (decode attention): same math as the composite in a
+# different reduction order.
+TOL_F32 = 1e-5
+# f32 recurrent cells: kernel and composite both feed the MXU XLA's default
+# f32 precision (one bf16 pass), and a rounding difference in one step's
+# recurrent matmul carries through the remaining T-1 steps.
+TOL_F32_MXU = 2.0 ** -9
+
+_PHASE = ["start"]
+
+
+class SmokeFailure(Exception):
+    """A phase's check failed."""
+
+
+def _check(cond, msg):
+    if not cond:
+        raise SmokeFailure(msg)
+
+
+def _device_facts():
+    import jax
+    devs = jax.devices()
+    return {"platform": devs[0].platform, "device_kind": devs[0].device_kind,
+            "n_devices": len(devs)}
+
+
+def _emit(phase, facts):
+    line = {"phase": phase}
+    line.update(_device_facts())
+    line.update(facts)
+    print(json.dumps(line), flush=True)
+
+
+def _rel_err(got, ref):
+    got = np.asarray(got, np.float32)
+    ref = np.asarray(ref, np.float32)
+    _check(got.shape == ref.shape, f"shape {got.shape} != {ref.shape}")
+    _check(np.isfinite(got).all(), "non-finite values")
+    return float(np.max(np.abs(got - ref)) / max(np.max(np.abs(ref)), 1e-30))
+
+
+# how a Mosaic (Pallas TPU) kernel appears in optimized HLO text
+_MOSAIC_CALL = 'custom_call_target="tpu_custom_call"'
+
+
+def _n_custom_calls(hlo: str) -> int:
+    return hlo.count(_MOSAIC_CALL)
+
+
+def _free_device_memory():
+    import paddle_tpu as pt
+    pt.reset_default_programs()
+    pt.reset_global_scope()
+    gc.collect()
+
+
+def _lm_feed(batch, seq_len, vocab, seed=0):
+    rng = np.random.RandomState(seed)
+    toks = rng.randint(0, vocab, (batch, seq_len + 1)).astype("int64")
+    return {"tokens": toks[:, :-1].copy(),
+            "tokens@SEQLEN": np.full((batch,), seq_len, "int32"),
+            "targets": toks[:, 1:].copy()}
+
+
+def _build_lm_train(vocab, seq_len, d_model, d_inner, num_heads, num_layers,
+                    attn_backend=None):
+    """layers.* -> optimizer.minimize; returns the loss var. `attn_backend`
+    pins the fused_attention ops' backend (the CPU tests run the kernels
+    through the Pallas interpreter); None leaves the default selection."""
+    import paddle_tpu as pt
+    from paddle_tpu.models import transformer
+
+    _free_device_memory()
+    with pt.core.unique_name.guard():
+        loss, _ = transformer.transformer_lm(
+            vocab=vocab, max_len=seq_len, d_model=d_model, d_inner=d_inner,
+            num_heads=num_heads, num_layers=num_layers, dropout=0.0)
+        pt.optimizer.AdamOptimizer(learning_rate=1e-4).minimize(loss)
+    if attn_backend is not None:
+        for op in pt.default_main_program().global_block().ops:
+            if op.type == "fused_attention":
+                op.attrs["backend"] = attn_backend
+    return loss
+
+
+def _run_steps(run, steps, decreasing=True):
+    """run() -> loss; first call timed as compile, the rest as run."""
+    t0 = time.time()
+    losses = [float(run())]
+    compile_s = time.time() - t0
+    t0 = time.time()
+    losses += [float(run()) for _ in range(steps - 1)]
+    run_s = time.time() - t0
+    _check(all(np.isfinite(losses)), f"non-finite loss: {losses}")
+    _check(not decreasing or all(b < a for a, b in zip(losses, losses[1:])),
+           f"loss not decreasing on a fixed batch: {losses}")
+    return losses, compile_s, run_s
+
+
+# ---------------------------------------------------------------- phases
+
+
+def phase_device():
+    """A TPU of a kind the repo has peaks for — or the run ends here."""
+    import jax
+    from paddle_tpu.framework.costs import device_peaks
+
+    facts = _device_facts()
+    _check(facts["platform"] == "tpu",
+           f"no chip: JAX found platform {facts['platform']!r} "
+           f"({facts['device_kind']}); chip_smoke.py needs a TPU and never "
+           f"runs smaller on a CPU")
+    peaks = device_peaks(facts["device_kind"])
+    _check(peaks is not None,
+           f"device_kind {facts['device_kind']!r} is not in the repo's "
+           f"peaks table (paddle_tpu/framework/costs.py DEVICE_PEAKS)")
+    from importlib import metadata
+    return {"compile_s": 0.0, "run_s": 0.0,
+            "peak_bf16_tflops": peaks["peak_flops"] / 1e12,
+            "hbm_gbps": peaks["hbm_bps"] / 1e9,
+            "jax": jax.__version__, "libtpu": metadata.version("libtpu")}
+
+
+def phase_train_lm(vocab=32000, seq_len=1024, d_model=1024, d_inner=4096,
+                   num_heads=16, num_layers=12, batch=8, steps=4,
+                   flash_calls_per_layer=3):
+    """Train the LM for a few steps on one fixed batch through Executor.run.
+    Leaves the trained weights in the global scope for `phase_serve_lm`."""
+    import paddle_tpu as pt
+
+    loss = _build_lm_train(vocab, seq_len, d_model, d_inner, num_heads,
+                           num_layers)
+    exe = pt.Executor()
+    exe.run(pt.default_startup_program())
+    feed = _lm_feed(batch, seq_len, vocab)
+    losses, compile_s, run_s = _run_steps(
+        lambda: exe.run(feed=feed, fetch_list=[loss])[0], steps)
+    t0 = time.time()
+    n_calls = _n_custom_calls(exe.compiled_hlo(feed=feed, fetch_list=[loss]))
+    hlo_s = time.time() - t0
+    # flash forward + the dq and dk/dv backward kernels, per layer: the step
+    # ran the Mosaic kernels, not the composite
+    _check(n_calls == flash_calls_per_layer * num_layers,
+           f"{n_calls} tpu_custom_calls in the compiled train step, expected "
+           f"{flash_calls_per_layer} x {num_layers} layers")
+    return {"compile_s": round(compile_s, 2), "run_s": round(run_s, 2),
+            "hlo_s": round(hlo_s, 2), "steps": steps,
+            "losses": [round(x, 4) for x in losses],
+            "tpu_custom_calls": n_calls, "batch_tokens": batch * seq_len}
+
+
+def phase_serve_lm(scope, vocab=32000, max_len=1024, d_model=1024,
+                   d_inner=4096, num_heads=16, num_layers=12, n_slots=16,
+                   n_requests=8, min_prompt=16, max_prompt=64, max_new=32,
+                   deadline_s=420.0, expect_decode_path="composite",
+                   expect_custom_calls=0):
+    """Serve the weights `scope` holds (shared by name with the train graph)
+    through PagedKVEngine behind EngineServer, an EngineClient in a thread of
+    this process. `expect_*` is what the tick's attention compiles to at
+    this shape TODAY (16 heads x 64 over a 1024-token span is past the fused
+    decode kernel's VMEM gate, fusion/decode_attention.py `_pallas_fits`):
+    a gate change that silently turns the kernel on or off here fails."""
+    from paddle_tpu.serving import EngineClient, EngineServer, PagedKVEngine
+
+    trained = {n: scope.get(n) for n in ("tok_emb", "lm_head.w_0")
+               if scope.has_var(n)}
+    _check(len(trained) == 2, "the scope holds no trained LM weights")
+    t0 = time.time()
+    eng = PagedKVEngine(n_slots=n_slots, vocab=vocab, max_len=max_len,
+                        d_model=d_model, d_inner=d_inner,
+                        num_heads=num_heads, num_layers=num_layers,
+                        scope=scope)
+    _check(all(scope.get(n) is v for n, v in trained.items()),
+           "the engine re-initialized weights the scope already held")
+
+    rng = np.random.RandomState(1)
+    prompts = [rng.randint(0, vocab, (int(n),)).tolist()
+               for n in rng.randint(min_prompt, max_prompt + 1, n_requests)]
+    result = {}
+
+    def client(address):
+        try:
+            with EngineClient(*address) as c:
+                c.generate([1], max_new=1)        # compiles the tick
+                t1 = time.time()
+                tags = [c.send_gen(p, max_new=max_new) for p in prompts]
+                done = dict((tag, toks) for tag, toks, _ in
+                            (c.recv_done() for _ in prompts))
+                result["tokens"] = [done[t] for t in tags]
+                hits = eng.pager.stats()["prefix_hits"]
+                # the first prompt again, after its first answer completed
+                result["repeat"] = c.generate(prompts[0], max_new=max_new)
+                result["prefix_hits"] = (eng.pager.stats()["prefix_hits"]
+                                         - hits)
+                result["run_s"] = time.time() - t1
+        except Exception as e:     # reported by the phase, which then fails
+            result["error"] = f"{type(e).__name__}: {e}"
+
+    with EngineServer(eng, host="127.0.0.1") as srv:
+        th = threading.Thread(target=client, args=(srv.address,),
+                              daemon=True)
+        th.start()
+        th.join(deadline_s)
+        health = srv.health()
+    _check("run_s" in result,
+           f"client: {result.get('error', 'no answer')} after "
+           f"{time.time() - t0:.0f} s of a {deadline_s:.0f} s deadline "
+           f"(server health: status={health['status']}, "
+           f"error={health['error']})")
+    compile_s = time.time() - t0 - result["run_s"]
+    _check(all(len(t) == max_new for t in result["tokens"]),
+           f"token counts {[len(t) for t in result['tokens']]} != {max_new}")
+    _check(result["repeat"] == result["tokens"][0],
+           "the repeated prompt did not decode token-identically")
+    _check(result["prefix_hits"] >= 1,
+           "the repeated prompt did not hit the prefix cache")
+
+    n_calls = _n_custom_calls(eng.tick_hlo())
+    path = "pallas" if n_calls else "composite"
+    _check((path, n_calls) == (expect_decode_path, expect_custom_calls),
+           f"decode attention compiled to {path} with {n_calls} "
+           f"tpu_custom_calls; this shape is expected to take "
+           f"{expect_decode_path} with {expect_custom_calls}")
+    stats = eng.stats()
+    return {"compile_s": round(compile_s, 2),
+            "run_s": round(result["run_s"], 2),
+            "requests": n_requests + 1, "max_new": max_new,
+            "prompt_lens": [len(p) for p in prompts],
+            "ticks": stats["ticks"], "tokens_out": stats["tokens_out"],
+            "prefix_hits": result["prefix_hits"],
+            "decode_attention": path, "tpu_custom_calls": n_calls,
+            "block_size": eng.block_size, "n_blocks": eng.n_blocks}
+
+
+def phase_train_resnet50(batch=256, steps=2, depth=50, image=224):
+    """bench.py's training graph: ResNet-50 NHWC bf16, uint8 staging
+    declared (and fed), Momentum."""
+    import paddle_tpu as pt
+    from paddle_tpu import models
+
+    _free_device_memory()
+    with pt.core.unique_name.guard():
+        img = pt.layers.data(name="img", shape=[image, image, 3],
+                             staging_dtype="uint8")
+        loss, _, _ = models.resnet.resnet_imagenet(
+            img=img, depth=depth, is_test=False, data_format="NHWC",
+            use_bf16=True)
+        pt.optimizer.MomentumOptimizer(learning_rate=3e-3,
+                                       momentum=0.9).minimize(loss)
+    exe = pt.Executor()
+    exe.run(pt.default_startup_program())
+    scope = pt.global_scope()
+    pname = pt.default_main_program().global_block().all_parameters()[0].name
+    before = np.asarray(scope.get(pname)).copy()
+    rng = np.random.RandomState(2)
+    feed = {"img": rng.randint(0, 256, (batch, image, image, 3), "uint8"),
+            "label": rng.randint(0, 1000, (batch, 1)).astype("int64")}
+    losses, compile_s, run_s = _run_steps(
+        lambda: exe.run(feed=feed, fetch_list=[loss])[0], steps,
+        decreasing=False)
+    after = np.asarray(scope.get(pname))
+    _check(np.isfinite(after).all() and not np.array_equal(before, after),
+           f"parameter {pname} did not change")
+    return {"compile_s": round(compile_s, 2), "run_s": round(run_s, 2),
+            "steps": steps, "batch": batch,
+            "losses": [round(x, 4) for x in losses], "param_changed": pname}
+
+
+def _timed_first(f, *args):
+    import jax
+    t0 = time.time()
+    out = jax.block_until_ready(f(*args))
+    return out, time.time() - t0
+
+
+def _check_flash(shape, with_segments, backend, timing):
+    """Flash fwd + bwd (one jit) against the composite evaluated in f32 at
+    the highest matmul precision, head by head so a long sequence's [T, T]
+    scores never need more than one head of HBM."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import pallas_kernels as pk
+
+    B, H, T, D = shape
+    rng = np.random.RandomState(3)
+    q, k, v, do = (jnp.asarray(rng.randn(*shape), jnp.bfloat16)
+                   for _ in range(4))
+    ids = None
+    if with_segments:      # four packed segments per row, ids 1..4
+        cuts = np.sort(rng.randint(1, T, (B, 3)), axis=1)
+        ids = jnp.asarray(1 + (np.arange(T)[None, :, None]
+                               >= cuts[:, None, :]).sum(-1), jnp.int32)
+
+    def fwd_bwd(be, q, k, v, do):
+        out, vjp = jax.vjp(lambda q, k, v: pk.flash_attention(
+            q, k, v, causal=True, backend=be, segment_ids=ids), q, k, v)
+        return (out,) + vjp(do.astype(out.dtype))
+
+    got, c = _timed_first(jax.jit(lambda *a: fwd_bwd(backend, *a)),
+                          q, k, v, do)
+    timing["compile_s"] += c
+    t0 = time.time()
+
+    @jax.jit
+    def ref_head(q, k, v, do):
+        return fwd_bwd("xla", *(a.astype(jnp.float32) for a in (q, k, v, do)))
+    errs = [0.0] * 4
+    with jax.default_matmul_precision("highest"):
+        for h in range(H):
+            sl = slice(h, h + 1)
+            ref = ref_head(q[:, sl], k[:, sl], v[:, sl], do[:, sl])
+            for i in range(4):
+                errs[i] = max(errs[i], _rel_err(got[i][:, sl], ref[i]))
+    timing["run_s"] += time.time() - t0
+    _check(max(errs) <= TOL_BF16,
+           f"flash {shape} segments={with_segments}: out/dq/dk/dv errors "
+           f"{errs} exceed {TOL_BF16}")
+    return max(errs)
+
+
+def _check_decode(num_heads, d_head, span, rows, backend, timing):
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.fusion import decode_attention as da
+
+    _check(da._pallas_fits(num_heads, span, d_head)
+           and not da._pallas_fits(num_heads, span + 128, d_head),
+           f"T={span} is not the largest span the decode kernel's gate "
+           f"admits at {num_heads} heads x {d_head}")
+    rng = np.random.RandomState(4)
+    q = jnp.asarray(rng.randn(rows, 1, num_heads, 1, d_head), jnp.float32)
+    k, v = (jnp.asarray(rng.randn(rows, 1, num_heads, span, d_head),
+                        jnp.float32) for _ in range(2))
+    lens = rng.randint(1, span + 1, (rows,))
+    bias = jnp.asarray(np.where(np.arange(span)[None] < lens[:, None],
+                                0.0, -1e9).reshape(rows, 1, 1, 1, span),
+                       jnp.float32)
+    scale = d_head ** -0.5
+
+    def run(be):
+        return jax.jit(lambda *a: da.fused_decode_attention(
+            *a, scale=scale, backend=be))
+    got, c = _timed_first(run(backend), q, k, v, bias)
+    timing["compile_s"] += c
+    t0 = time.time()
+    with jax.default_matmul_precision("highest"):
+        ref = run("xla")(q, k, v, bias)
+    err = _rel_err(got, ref)
+    timing["run_s"] += time.time() - t0
+    _check(err <= TOL_F32, f"decode attention T={span}: error {err}")
+    return err
+
+
+def _check_recurrent(kind, batch, steps, hidden, backend, timing):
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.fusion import fused_gru_sequence, fused_lstm_sequence
+
+    gates = 4 if kind == "lstm" else 3
+    rng = np.random.RandomState(5)
+    x = jnp.asarray(rng.randn(batch, steps, gates * hidden) * 0.5,
+                    jnp.float32)
+    w = jnp.asarray(rng.randn(hidden, gates * hidden) * hidden ** -0.5,
+                    jnp.float32)
+    states = [jnp.asarray(rng.randn(batch, hidden) * 0.1, jnp.float32)
+              for _ in range(2 if kind == "lstm" else 1)]
+    seqlen = jnp.asarray(rng.randint(steps // 2, steps + 1, (batch,)),
+                         jnp.int32)
+
+    def fwd_grad(be, x, w, *states):
+        def loss(x, w):
+            if kind == "lstm":
+                hs, cs = fused_lstm_sequence(x, *states, w, seqlen,
+                                             backend=be)
+                return jnp.sum(hs * hs) + jnp.sum(cs), hs
+            hs = fused_gru_sequence(x, *states, w, seqlen, backend=be)
+            return jnp.sum(hs * hs), hs
+        (_, hs), (dx, dw) = jax.value_and_grad(loss, argnums=(0, 1),
+                                               has_aux=True)(x, w)
+        return hs, dx, dw
+
+    got, c = _timed_first(jax.jit(lambda *a: fwd_grad(backend, *a)),
+                          x, w, *states)
+    timing["compile_s"] += c
+    t0 = time.time()
+    ref = jax.jit(lambda *a: fwd_grad("xla", *a))(x, w, *states)
+    errs = [_rel_err(g, r) for g, r in zip(got, ref)]
+    timing["run_s"] += time.time() - t0
+    _check(max(errs) <= TOL_F32_MXU,
+           f"fused {kind} B{batch} T{steps} H{hidden}: hs/dx/dw errors "
+           f"{errs} exceed {TOL_F32_MXU}")
+    return max(errs)
+
+
+def phase_kernels(backend="pallas",
+                  flash_shapes=((8, 16, 1024, 64), (1, 8, 8192, 128)),
+                  decode=(16, 64, 640, 16), recurrent=(64, 64, 256)):
+    """Every Pallas kernel the package selects by default on a TPU, called
+    directly, compiled by Mosaic, run, and compared with its own composite.
+    decode = (heads, d_head, span, rows); recurrent = (batch, steps, hidden).
+    """
+    timing = {"compile_s": 0.0, "run_s": 0.0}
+    errs = {}
+    for shape in flash_shapes:
+        for seg in (False, True):
+            name = "flash_" + "x".join(map(str, shape)) + ("_seg" * seg)
+            errs[name] = _check_flash(shape, seg, backend, timing)
+    errs["decode_T%d" % decode[2]] = _check_decode(*decode, backend, timing)
+    for kind in ("lstm", "gru"):
+        errs["fused_" + kind] = _check_recurrent(kind, *recurrent, backend,
+                                                 timing)
+    return {"compile_s": round(timing["compile_s"], 2),
+            "run_s": round(timing["run_s"], 2), "backend": backend,
+            "max_rel_err": {k: float("%.2e" % v) for k, v in errs.items()}}
+
+
+def _multichip_ring(devices, ring_shape):
+    """Ring attention over sp=4, causal, fwd + grad (backend auto), against
+    flash attention on one chip. ring_shape = [B, T, H, D]."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops.pallas_kernels import flash_attention
+    from paddle_tpu.parallel import DeviceMesh
+    from paddle_tpu.parallel.ring_attention import ring_attention_sharded
+
+    t0 = time.time()
+    rng = np.random.RandomState(6)
+    q, k, v, do = (jnp.asarray(rng.randn(*ring_shape), jnp.bfloat16)
+                   for _ in range(4))
+    sp_mesh = DeviceMesh(devices, {"sp": 4})
+
+    def fwd_bwd(attend, q, k, v, do):
+        out, vjp = jax.vjp(attend, q, k, v)
+        return (out,) + vjp(do)
+
+    def ring(q, k, v):
+        return ring_attention_sharded(sp_mesh, q, k, v, causal=True)
+
+    def flash(q, k, v):        # [B, T, H, D] -> the kernel's [B, H, T, D]
+        def t(a):
+            return jnp.transpose(a, (0, 2, 1, 3))
+        return t(flash_attention(t(q), t(k), t(v), causal=True))
+
+    got = jax.block_until_ready(
+        jax.jit(lambda *a: fwd_bwd(ring, *a))(q, k, v, do))
+    ref = jax.jit(lambda *a: fwd_bwd(flash, *a))(q, k, v, do)
+    errs = [_rel_err(g, r) for g, r in zip(got, ref)]
+    # both sides are bf16 kernels; the ring merges four partial softmaxes
+    _check(max(errs) <= TOL_BF16,
+           f"ring attention vs flash: out/dq/dk/dv errors {errs}")
+    return {"mesh": {"sp": 4}, "shape": list(ring_shape),
+            "seconds": round(time.time() - t0, 2),
+            "max_rel_err": float("%.2e" % max(errs))}
+
+
+def _shapes(text):
+    """[(dims...), ...] of every typed array shape in an HLO fragment."""
+    return [tuple(int(d) for d in m.split(","))
+            for m in re.findall(r"\b[a-z]+[0-9]*\[([0-9,]+)\]", text)]
+
+
+def phase_multichip(ref_first_loss=None, vocab=32000, seq_len=1024,
+                    d_model=1024, d_inner=4096, num_heads=16, num_layers=12,
+                    batch=8, steps=3, flash_calls_per_layer=3,
+                    attn_backend=None, ring_shape=(1, 8192, 8, 128),
+                    loss_tol=5e-3, min_bytes_in_use=1 << 20):
+    """The train_lm model under ParallelExecutor on a dp2 x tp2 mesh
+    (annotate_tp + ZeRO-1 Reduce), then ring attention on sp4 against
+    single-chip flash. One process drives the four chips."""
+    import jax
+    import paddle_tpu as pt
+    from paddle_tpu.parallel import (BuildStrategy, DeviceMesh,
+                                     ParallelExecutor, ReduceStrategy,
+                                     annotate_tp)
+
+    devices = jax.devices()[:4]
+    loss = _build_lm_train(vocab, seq_len, d_model, d_inner, num_heads,
+                           num_layers, attn_backend)
+    annotate_tp()
+    pt.Executor().run(pt.default_startup_program())
+    mesh = DeviceMesh(devices, {"dp": 2, "tp": 2})
+    pe = ParallelExecutor(
+        loss_name=loss.name, mesh=mesh,
+        build_strategy=BuildStrategy(reduce_strategy=ReduceStrategy.Reduce))
+    feed = _lm_feed(batch, seq_len, vocab)
+    losses, compile_s, run_s = _run_steps(
+        lambda: pe.run(fetch_list=[loss], feed=feed)[0], steps)
+    if ref_first_loss is not None:
+        # same seeds, same batch: only the reduction order differs
+        _check(abs(losses[0] - ref_first_loss)
+               <= loss_tol * abs(ref_first_loss),
+               f"first-step loss {losses[0]} on the mesh vs "
+               f"{ref_first_loss} on one chip")
+
+    # every device holds shards of the state, and real memory
+    scope = pt.global_scope()
+    holders = set()
+    for name in scope.local_var_names():
+        val = scope.get(name)
+        if hasattr(val, "addressable_shards"):
+            holders |= {s.device for s in val.addressable_shards}
+    _check(set(devices) <= holders,
+           f"devices without a shard: {set(devices) - holders}")
+    in_use = []
+    for d in devices:
+        stats = d.memory_stats()
+        if stats is not None:          # the CPU backend reports none
+            in_use.append(int(stats["bytes_in_use"]))
+            _check(in_use[-1] >= min_bytes_in_use,
+                   f"{d} holds {in_use[-1]} bytes")
+
+    # the flash kernels run per shard: [B/dp * H/tp, T, D] operands, and no
+    # all-gather rebuilds a full-size q/k/v in front of them
+    n_calls = 0
+    if flash_calls_per_layer:
+        hlo = pe.compiled_hlo(feed=feed, fetch_list=[loss])
+        calls = [ln for ln in hlo.splitlines() if _MOSAIC_CALL in ln]
+        n_calls = len(calls)
+        _check(n_calls == flash_calls_per_layer * num_layers,
+               f"{n_calls} tpu_custom_calls in the sharded step")
+        rows = (batch // 2) * (num_heads // 2)
+        d_head = d_model // num_heads
+        for ln in calls:
+            lead = {s[0] for s in _shapes(ln) if len(s) == 3}
+            _check(lead == {rows},
+                   f"flash call with leading dims {lead}, expected per-shard "
+                   f"{rows}: {ln[:200]}")
+        for ln in hlo.splitlines():
+            if " all-gather(" in ln:
+                result = _shapes(ln.split(" all-gather(")[0])
+                _check(not any(s[-2:] == (seq_len, d_head) for s in result),
+                       f"q/k/v all-gather: {ln[:200]}")
+    facts = {"compile_s": round(compile_s, 2), "run_s": round(run_s, 2),
+             "mesh": {"dp": 2, "tp": 2}, "steps": steps,
+             "losses": [round(x, 4) for x in losses],
+             "ref_first_loss": ref_first_loss,
+             "tpu_custom_calls": n_calls, "bytes_in_use": in_use}
+    _free_device_memory()
+    facts["ring"] = _multichip_ring(devices, ring_shape)
+    return facts
+
+
+# ------------------------------------------------------------------ driver
+
+
+def _expire():
+    print(f"chip_smoke: deadline of {DEADLINE_S:.0f} s expired in phase "
+          f"{_PHASE[0]}", file=sys.stderr, flush=True)
+    os._exit(4)
+
+
+def _run():
+    import jax
+    import paddle_tpu as pt
+
+    def phase(name, fn, *args, **kw):
+        _PHASE[0] = name
+        facts = fn(*args, **kw)
+        _emit(name, facts)
+        return facts
+
+    phase("device", phase_device)
+    train = phase("train_lm", phase_train_lm)
+    phase("serve_lm", phase_serve_lm, pt.global_scope())
+    phase("train_resnet50", phase_train_resnet50)
+    _free_device_memory()
+    phase("kernels", phase_kernels)
+    if len(jax.devices()) >= 4:
+        phase("multichip", phase_multichip, train["losses"][0])
+    else:
+        _PHASE[0] = "multichip"
+        _emit("multichip", {"compile_s": 0.0, "run_s": 0.0,
+                            "skipped": f"{len(jax.devices())} device"})
+    d = _device_facts()
+    return {"platform": d["platform"], "kind": d["device_kind"],
+            "count": d["n_devices"]}
+
+
+def main():
+    watchdog = threading.Timer(DEADLINE_S, _expire)
+    watchdog.daemon = True
+    watchdog.start()
+    t0 = time.time()
+    try:
+        device = _run()
+    except Exception:
+        traceback.print_exc()
+        print(f"chip_smoke: FAILED in phase {_PHASE[0]} after "
+              f"{time.time() - t0:.0f} s", file=sys.stderr, flush=True)
+        return 1
+    print(f"chip_smoke: all phases passed in {time.time() - t0:.0f} s",
+          file=sys.stderr, flush=True)
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

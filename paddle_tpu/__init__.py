@@ -7,7 +7,11 @@ metrics, io — implemented TPU-first: programs trace to jax functions compiled
 by XLA; parallelism is SPMD over a jax.sharding.Mesh with compiled collectives.
 """
 
-from . import clip, initializer, layers, optimizer, regularizer  # noqa: F401
+from .core import compile_cache as _compile_cache
+
+_compile_cache.configure()
+
+from . import clip, initializer, layers, optimizer, regularizer  # noqa: F401,E402
 from .core import (CPUPlace, Place, TPUPlace, default_place,  # noqa: F401
                    device_count, devices, is_compiled_with_tpu)
 from .core import flags  # noqa: F401

@@ -104,7 +104,6 @@ define_bool("benchmark", False,
 define_int("vlog", 0, "Verbose logging level (≙ glog VLOG).")
 define_bool("use_bf16_matmul", True,
             "Prefer bfloat16 MXU matmul precision where layers opt in.")
-define_string("jit_cache", "", "Persistent XLA compilation cache directory.")
 define_bool("conv1x1_mixed_vjp", False,
             "Lower the backward of 1x1 stride-1 NHWC convs with a "
             "mixed-emitter custom_vjp (dgrad as one dot_general, wgrad "

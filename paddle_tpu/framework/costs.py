@@ -49,6 +49,21 @@ V5E_ICI_BPS = 45e9
 # RELATIVE ranking figure, not a wall-clock forecast.
 V5E_PCIE_BPS = 32e9
 
+# peaks per chip, keyed by jax `Device.device_kind` (source: Google Cloud
+# documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM). A kind absent
+# here HAS no peaks: utilization sensors record nothing for it and
+# measurement paths fail — there is no default chip.
+DEVICE_PEAKS = {
+    "TPU v5 lite": {"peak_flops": V5E_PEAK_TFLOPS, "hbm_bps": V5E_HBM_BPS},
+    "TPU v5e": {"peak_flops": V5E_PEAK_TFLOPS, "hbm_bps": V5E_HBM_BPS},
+}
+
+
+def device_peaks(device_kind: str) -> Optional[Dict[str, float]]:
+    """{peak_flops, hbm_bps} of one chip of `device_kind`, or None when
+    the table has no entry for it (the CPU included)."""
+    return DEVICE_PEAKS.get(device_kind)
+
 # dtype byte widths for parsing XLA shape strings — the ONE copy shared by
 # the probes (probe_caps) and the comm-structure tests. Covers every XLA
 # scalar type that can appear in a typed shape (ADVICE r5 #4); an

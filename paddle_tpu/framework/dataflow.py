@@ -655,7 +655,7 @@ def _check_tp_partials(program, diags):
                     f"replicated value"))
 
 
-def _check_replica_divergence(program, env, diags):
+def _replica_divergence_check(program, env, diags):
     """Parameter updates must consume replica-consistent values: every
     optimizer input carrying a divergence taint — other than the
     sanctioned ZeRO-1 dp shards on a sharded-update op and tp-local
@@ -968,6 +968,6 @@ def dataflow_checks(program: Program) -> List[Diagnostic]:
     _check_collective_axes(program, diags)
     _check_pp_stage_order(program, diags)
     _check_divergent_control(program, env, diags)
-    _check_replica_divergence(program, env, diags)
+    _replica_divergence_check(program, env, diags)
     _check_buffer_reuse(program, diags)
     return diags

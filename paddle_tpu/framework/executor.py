@@ -72,25 +72,6 @@ class _CompiledStep:
         self.fetch_names = fetch_names
 
 
-_jit_cache_configured = []
-
-
-def _configure_jit_cache():
-    """Wire the PTPU_JIT_CACHE flag into jax's persistent compilation
-    cache (once): compiled XLA executables survive process restarts, which
-    on TPU turns 20-40s first compiles into millisecond cache loads."""
-    if _jit_cache_configured:
-        return
-    _jit_cache_configured.append(True)
-    path = flags.get_flag("jit_cache")
-    if not path:
-        return
-    import os
-    os.makedirs(path, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", path)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-
-
 class PreparedStep:
     """Bound (program, feed-signature, fetch, scope) handle with the
     per-call dispatch overhead stripped: no fetch validation, no feed
@@ -232,7 +213,6 @@ class Executor:
     """≙ fluid.Executor (reference python/paddle/fluid/executor.py:256)."""
 
     def __init__(self, place: Optional[Place] = None):
-        _configure_jit_cache()
         self.place = place or default_place()
         self._cache: Dict[Any, _CompiledStep] = {}
         self._persistable_cache: Dict[Any, list] = {}
@@ -277,6 +257,7 @@ class Executor:
         # program and the compile-cache key (original program version) are
         # untouched — the rewrite is deterministic per version.
         from .passes import apply_fusion_passes
+        mesh = self._lowering_mesh(program)
         program = apply_fusion_passes(
             program, protected=set(fetch_names) | set(state_out_names))
         block = program.global_block()
@@ -287,7 +268,7 @@ class Executor:
         def step(feed_vals, ro_vals, rw_vals, seed):
             # fetch_names ride along so live-out-narrowed vjp regions
             # (transpiler.memory_optimize) never drop a fetch target
-            ctx = LowerCtx(rng_key=jax.random.PRNGKey(seed),
+            ctx = LowerCtx(rng_key=jax.random.PRNGKey(seed), mesh=mesh,
                            extras={"program": program,
                                    "fetch_names": tuple(fetch_names)})
             env: Dict[str, Any] = {}
@@ -323,6 +304,13 @@ class Executor:
             return fetches, new_state
 
         return step
+
+    def _lowering_mesh(self, program: Program):
+        """Hook: the DeviceMesh an SPMD-partitioned step is compiled over,
+        handed to lowerings as `LowerCtx.mesh` — a lowering that emits an
+        opaque kernel call needs it to run the kernel per shard. None here
+        (one device); ParallelExecutor supplies its mesh."""
+        return None
 
     def _prepare_program(self, program: Program, scope: Scope) -> Program:
         """Hook: executor-level program rewrite before state analysis and
@@ -540,8 +528,8 @@ class Executor:
             if _prof.profiler_enabled():
                 jax.block_until_ready(fetches)
         if flags.get_flag("check_nan_inf") and jax.default_backend() != "cpu":
-            # TPU fallback for the in-graph nan guard (which needs host
-            # callbacks and so no-ops off-CPU, lowering.py _nan_guard):
+            # accelerator counterpart of the in-graph nan guard (whose host
+            # callbacks stay off the chip's hot path, lowering.py _nan_guard):
             # sweep every fetch and updated state for non-finite values
             # BEFORE the scope write-back, so the last-good parameters stay
             # checkpointable when the step diverges. Coarser than the per-op
@@ -576,9 +564,8 @@ class Executor:
 
         ≙ the reference's py_reader-driven executor loop (reference
         layers/io.py:474 + executor hot loop), where the device consumes a
-        queue without a Python round-trip per step. On a remote/tunneled
-        device this amortizes every per-call cost; on any device it lets
-        XLA overlap adjacent steps' host interaction.
+        queue without a Python round-trip per step: one dispatch covers K
+        steps, so every per-call host cost is paid once per window.
 
         All feeds must share one signature. Returns a list over
         fetch_list of arrays STACKED over steps (e.g. the per-step loss
@@ -741,24 +728,40 @@ class Executor:
             compiled.aot_cache = aot
         return aot
 
+    def _compiled_for(self, program, feed, fetch_list, scope):
+        """(compiled step, feed, scope) for the analysis entry points below:
+        defaults resolved, compiled on a cache miss."""
+        program = program or default_main_program()
+        feed = dict(feed or {})
+        scope = scope or global_scope()
+        fetch_names = [f.name if isinstance(f, Variable) else f
+                       for f in (fetch_list or [])]
+        return (self._lookup_or_compile(program, feed, fetch_names, scope),
+                feed, scope)
+
     def cost_analysis(self, program=None, feed=None, fetch_list=None,
                       scope=None):
         """XLA cost analysis (flops, bytes accessed) of the compiled step for
         the given (program, feed, fetch) — the evidence the reference
         publishes next to its benchmark tables (reference
         benchmark/README.md:33). Compiles if not already cached."""
-        program = program or default_main_program()
-        feed = dict(feed or {})
-        scope = scope or global_scope()
-        fetch_names = [f.name if isinstance(f, Variable) else f
-                       for f in (fetch_list or [])]
-        compiled = self._lookup_or_compile(program, feed, fetch_names, scope)
+        compiled, feed, scope = self._compiled_for(program, feed, fetch_list,
+                                                   scope)
         ca = getattr(compiled, "cost_analysis_cache", None)
         if ca is None:
             ca = self._aot_compiled(compiled, feed, scope).cost_analysis()
-            ca = ca[0] if isinstance(ca, (list, tuple)) else ca
             compiled.cost_analysis_cache = ca
         return ca
+
+    def compiled_hlo(self, program=None, feed=None, fetch_list=None,
+                     scope=None) -> str:
+        """Optimized (post-partitioning) HLO text of the compiled step for
+        the given (program, feed, fetch): what the device actually runs —
+        which kernels stayed custom calls, which collectives the
+        partitioner inserted. Compiles (AOT, memoized) if needed."""
+        compiled, feed, scope = self._compiled_for(program, feed, fetch_list,
+                                                   scope)
+        return self._aot_compiled(compiled, feed, scope).as_text()
 
     def memory_analysis(self, program=None, feed=None, fetch_list=None,
                         scope=None):
@@ -768,12 +771,8 @@ class Executor:
         documented HLO liveness-walk fallback when the backend reports a
         zero temp figure). Compiles (AOT, memoized) if needed; updates
         the `executor_temp_bytes` watermark with what it measured."""
-        program = program or default_main_program()
-        feed = dict(feed or {})
-        scope = scope or global_scope()
-        fetch_names = [f.name if isinstance(f, Variable) else f
-                       for f in (fetch_list or [])]
-        compiled = self._lookup_or_compile(program, feed, fetch_names, scope)
+        compiled, feed, scope = self._compiled_for(program, feed, fetch_list,
+                                                   scope)
         from ..observability import memory as _memory
         stats = _memory.executable_memory(
             self._aot_compiled(compiled, feed, scope))

@@ -74,6 +74,7 @@ def _decode_step_kernel(q_ref, k_ref, v_ref, b_ref, o_ref, *, scale):
 
 def _decode_pallas(q4, k4, v4, bias3, scale, interpret):
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     r, nh, _, dh = q4.shape
     t = k4.shape[2]
@@ -104,14 +105,26 @@ def _decode_pallas(q4, k4, v4, bias3, scale, interpret):
         ],
         out_specs=pl.BlockSpec((1, nhp, 1, dh), lambda i: (i, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((r, nhp, 1, dh), q4.dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_vmem_limit_bytes(nhp, tp, dh)),
         interpret=interpret,
     )(qf, kf, vf, bf)
     return out[:, :nh]
 
 
-# per-grid-step VMEM budget for the kernel's K/V/bias blocks: stay well
-# under the ~16 MB/core VMEM so the compiler has room for double buffering
+# shape gate: logical bytes of one grid step's K/V/bias blocks
 _VMEM_BUDGET_BYTES = 6 << 20
+
+
+def _vmem_limit_bytes(nhp, tp, dh):
+    """Scoped-VMEM limit the kernel asks Mosaic for. A [nhp, tp, dh] f32
+    block is lane-padded to 128 in VMEM; the K and V blocks are each
+    double-buffered and the two broadcast-multiply products are block-sized
+    temporaries — six padded blocks, plus room for the [nhp, tp] score
+    tiles. At the gate's largest shapes that is ~40 MiB of a v5e core's
+    128 MiB; the compiler's 16 MiB default refuses anything past
+    nh=16, T=512, dh=64."""
+    return 6 * nhp * tp * max(dh, 128) * 4 + (4 << 20)
 
 
 def _pallas_fits(nh, t, dh):

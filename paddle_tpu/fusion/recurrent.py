@@ -7,7 +7,10 @@ step further: the kernel's grid iterates (batch-block, time) with the
 hidden/cell state held in VMEM scratch across the sequential time steps
 (TPU grid semantics, same mechanism as the flash kernel's online-softmax
 accumulators), so the ENTIRE sequence is a single kernel launch — no
-per-tick dispatch at all. The [B, T, 4H] input projections are computed
+per-tick dispatch at all. The kernel sees the sequence TIME-MAJOR
+([T, B, G*H], one (1, bb, G*H) block per step): Mosaic tiles a block's
+last two dims, and (bb, G*H) is (8k, 128m)-legal where the batch-major
+(bb, 1, G*H) block is not. The [B, T, 4H] input projections are computed
 once outside (one big MXU matmul, exactly as `dynamic_lstm` already does);
 what the kernel fuses is everything the unfused `lax.scan` body dispatched
 per tick: the [H, 4H] recurrent matmul, four activations, the state update
@@ -33,9 +36,11 @@ import jax.numpy as jnp
 
 from ..framework.registry import register_op
 
-# batch rows per grid step; VMEM must hold x-block [bb, 4H] + w [H, 4H] +
-# state scratch, so cap it (512 rows x 2048 gate lanes f32 = 4 MB)
+# batch rows per grid step (the VMEM gate below bounds bb x gate lanes)
 _MAX_BATCH_BLOCK = 512
+# the kernel's blocks must fit the compiler's default scoped-VMEM limit
+# (16 MiB on a v5e) with room for the gate temporaries
+_VMEM_BUDGET_BYTES = 12 << 20
 
 
 def _auto_backend():
@@ -43,11 +48,34 @@ def _auto_backend():
     return _ab()
 
 
+def _batch_block(b):
+    return min(_round_up(b, 8), _MAX_BATCH_BLOCK)
+
+
+def _vmem_bytes(b, hidden, n_gates):
+    """f32 bytes the whole-sequence kernel holds in VMEM per grid step:
+    every input/output block twice (Pallas double-buffers them, the
+    grid-invariant recurrent weight included) plus the state scratch."""
+    bb = _batch_block(b)
+    gh = n_gates * hidden
+    n_states = 2 if n_gates == 4 else 1
+    blocks = (bb * gh            # x step
+              + bb * 128         # seqlen, lane-broadcast
+              + n_states * bb * hidden   # h0[, c0]
+              + hidden * gh      # recurrent weight
+              + n_states * bb * hidden   # hs[, cs] step
+              + bb * gh)         # gate stash step
+    return 4 * (2 * blocks + n_states * bb * hidden)
+
+
 def _pallas_ok(x, w, hidden):
-    """The Mosaic path needs lane-sliceable gate columns (128 | H) and f32
-    compute; anything else takes the XLA composite (identical math)."""
+    """The Mosaic path needs lane-sliceable gate columns (128 | H), f32
+    compute, and blocks that fit scoped VMEM; anything else takes the XLA
+    composite (identical math)."""
     return (hidden % 128 == 0 and x.dtype == jnp.float32
-            and w.dtype == jnp.float32)
+            and w.dtype == jnp.float32
+            and _vmem_bytes(x.shape[0], hidden, x.shape[-1] // hidden)
+            <= _VMEM_BUDGET_BYTES)
 
 
 def _resolve_backend(backend, x, w, hidden):
@@ -55,8 +83,9 @@ def _resolve_backend(backend, x, w, hidden):
     if backend in ("pallas", "pallas_interpret") and not _pallas_ok(
             x, w, hidden):
         from ..core import flags
-        flags.vlog(1, "fused recurrent cell: shape (H=%d, dtype=%s) not "
-                   "tile-aligned; using XLA composite", hidden, x.dtype)
+        flags.vlog(1, "fused recurrent cell: shape (B=%d, H=%d, dtype=%s) "
+                   "not tile-aligned or over the VMEM budget; using XLA "
+                   "composite", x.shape[0], hidden, x.dtype)
         return "xla"
     return backend
 
@@ -77,6 +106,14 @@ def _pad_rows(a, rows):
 # ---------------------------------------------------------------------------
 
 
+def _step_valid(sl_ref, shape, t, t_total, reverse):
+    """[bb, H] mask of rows still inside their sequence at grid step t.
+    The int32 lengths are lane-broadcast BEFORE the compare so the select
+    sees a full-width mask (no [bb, 1] bool broadcast for Mosaic)."""
+    tpos = (t_total - 1 - t) if reverse else t
+    return jnp.broadcast_to(sl_ref[:, :1], shape) > tpos
+
+
 def _lstm_seq_kernel(x_ref, sl_ref, h0_ref, c0_ref, w_ref, hs_ref, cs_ref,
                      g_ref, h_scr, c_scr, *, hidden, t_total, reverse):
     from jax.experimental import pallas as pl
@@ -90,7 +127,7 @@ def _lstm_seq_kernel(x_ref, sl_ref, h0_ref, c0_ref, w_ref, hs_ref, cs_ref,
 
     h_prev = h_scr[:]
     c_prev = c_scr[:]
-    xt = x_ref[:, 0, :].astype(jnp.float32)                  # [bb, 4H]
+    xt = x_ref[0].astype(jnp.float32)                        # [bb, 4H]
     gates = xt + jax.lax.dot_general(
         h_prev, w_ref[:].astype(jnp.float32), (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
@@ -100,19 +137,18 @@ def _lstm_seq_kernel(x_ref, sl_ref, h0_ref, c0_ref, w_ref, hs_ref, cs_ref,
     o = jax.nn.sigmoid(gates[:, 3 * hidden:])
     c_new = f * c_prev + i * g
     h_new = o * jnp.tanh(c_new)
-    tpos = (t_total - 1 - t) if reverse else t
-    valid = sl_ref[:, :1] > tpos                             # [bb, 1]
+    valid = _step_valid(sl_ref, h_prev.shape, t, t_total, reverse)
     h_new = jnp.where(valid, h_new, h_prev)
     c_new = jnp.where(valid, c_new, c_prev)
     h_scr[:] = h_new
     c_scr[:] = c_new
-    hs_ref[:, 0, :] = h_new.astype(hs_ref.dtype)
-    cs_ref[:, 0, :] = c_new.astype(cs_ref.dtype)
+    hs_ref[0] = h_new.astype(hs_ref.dtype)
+    cs_ref[0] = c_new.astype(cs_ref.dtype)
     if g_ref is not None:
-        g_ref[:, 0, :hidden] = i
-        g_ref[:, 0, hidden:2 * hidden] = f
-        g_ref[:, 0, 2 * hidden:3 * hidden] = g
-        g_ref[:, 0, 3 * hidden:] = o
+        g_ref[0, :, :hidden] = i
+        g_ref[0, :, hidden:2 * hidden] = f
+        g_ref[0, :, 2 * hidden:3 * hidden] = g
+        g_ref[0, :, 3 * hidden:] = o
 
 
 def _gru_seq_kernel(x_ref, sl_ref, h0_ref, w_ref, hs_ref, g_ref, h_scr, *,
@@ -126,7 +162,7 @@ def _gru_seq_kernel(x_ref, sl_ref, h0_ref, w_ref, hs_ref, g_ref, h_scr, *,
         h_scr[:] = h0_ref[:].astype(jnp.float32)
 
     h_prev = h_scr[:]
-    xt = x_ref[:, 0, :].astype(jnp.float32)                  # [bb, 3H]
+    xt = x_ref[0].astype(jnp.float32)                        # [bb, 3H]
     w = w_ref[:].astype(jnp.float32)
     rz = jax.nn.sigmoid(xt[:, :2 * hidden] + jax.lax.dot_general(
         h_prev, w[:, :2 * hidden], (((1,), (0,)), ((), ())),
@@ -137,15 +173,14 @@ def _gru_seq_kernel(x_ref, sl_ref, h0_ref, w_ref, hs_ref, g_ref, h_scr, *,
         r * h_prev, w[:, 2 * hidden:], (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32))
     h_new = z * h_prev + (1 - z) * c
-    tpos = (t_total - 1 - t) if reverse else t
-    valid = sl_ref[:, :1] > tpos
+    valid = _step_valid(sl_ref, h_prev.shape, t, t_total, reverse)
     h_new = jnp.where(valid, h_new, h_prev)
     h_scr[:] = h_new
-    hs_ref[:, 0, :] = h_new.astype(hs_ref.dtype)
+    hs_ref[0] = h_new.astype(hs_ref.dtype)
     if g_ref is not None:
-        g_ref[:, 0, :hidden] = r
-        g_ref[:, 0, hidden:2 * hidden] = z
-        g_ref[:, 0, 2 * hidden:] = c
+        g_ref[0, :, :hidden] = r
+        g_ref[0, :, hidden:2 * hidden] = z
+        g_ref[0, :, 2 * hidden:] = c
 
 
 def _pallas_seq(kind, x, states0, w, seqlen, reverse, interpret, with_stash):
@@ -157,11 +192,11 @@ def _pallas_seq(kind, x, states0, w, seqlen, reverse, interpret, with_stash):
     b, t, gh = x.shape
     n_gates = 4 if kind == "lstm" else 3
     hidden = gh // n_gates
-    bb = min(_round_up(b, 8), _MAX_BATCH_BLOCK)
+    bb = _batch_block(b)
     bp = _round_up(b, bb)
     nb = bp // bb
 
-    xf = _pad_rows(x, bp)
+    xf = jnp.swapaxes(_pad_rows(x, bp), 0, 1)                # [T, bp, G*H]
     # seqlen rides broadcast over 128 lanes (a [B] vector output/input is
     # not Mosaic-tileable; same layout trick as the flash kernel's lse)
     slf = jnp.broadcast_to(
@@ -169,22 +204,22 @@ def _pallas_seq(kind, x, states0, w, seqlen, reverse, interpret, with_stash):
     states = [_pad_rows(s, bp) for s in states0]
 
     grid = (nb, t)
-    x_spec = pl.BlockSpec((bb, 1, gh), lambda bi, ti: (bi, ti, 0))
+    x_spec = pl.BlockSpec((1, bb, gh), lambda bi, ti: (ti, bi, 0))
     sl_spec = pl.BlockSpec((bb, 128), lambda bi, ti: (bi, 0))
     s_spec = pl.BlockSpec((bb, hidden), lambda bi, ti: (bi, 0))
     w_spec = pl.BlockSpec(w.shape, lambda bi, ti: (0, 0))
-    seq_spec = pl.BlockSpec((bb, 1, hidden), lambda bi, ti: (bi, ti, 0))
-    g_spec = pl.BlockSpec((bb, 1, gh), lambda bi, ti: (bi, ti, 0))
+    seq_spec = pl.BlockSpec((1, bb, hidden), lambda bi, ti: (ti, bi, 0))
+    g_spec = pl.BlockSpec((1, bb, gh), lambda bi, ti: (ti, bi, 0))
 
     in_specs = [x_spec, sl_spec] + [s_spec] * len(states) + [w_spec]
     inputs = [xf, slf] + states + [w]
     n_state_outs = 2 if kind == "lstm" else 1
     out_specs = [seq_spec] * n_state_outs
-    out_shape = [jax.ShapeDtypeStruct((bp, t, hidden), x.dtype)
+    out_shape = [jax.ShapeDtypeStruct((t, bp, hidden), x.dtype)
                  for _ in range(n_state_outs)]
     if with_stash:
         out_specs.append(g_spec)
-        out_shape.append(jax.ShapeDtypeStruct((bp, t, gh), jnp.float32))
+        out_shape.append(jax.ShapeDtypeStruct((t, bp, gh), jnp.float32))
 
     kern = (_lstm_seq_kernel if kind == "lstm" else _gru_seq_kernel)
     kern = functools.partial(kern, hidden=hidden, t_total=t, reverse=reverse)
@@ -204,7 +239,7 @@ def _pallas_seq(kind, x, states0, w, seqlen, reverse, interpret, with_stash):
         body, grid=grid, in_specs=in_specs, out_specs=out_specs,
         out_shape=out_shape, scratch_shapes=scratch,
         interpret=interpret)(*inputs)
-    return tuple(r[:b] for r in res)
+    return tuple(jnp.swapaxes(r, 0, 1)[:b] for r in res)
 
 
 # ---------------------------------------------------------------------------

@@ -42,7 +42,7 @@ artifact is `BENCH_MEM_r17.json` (tools/bench_mem.py).
 from __future__ import annotations
 
 import threading
-from typing import Any, Dict, Sequence
+from typing import Any, Dict, Optional, Sequence
 
 import numpy as np
 
@@ -116,17 +116,29 @@ def update_watermark(channel: str, value: float):
         _tracing.record_counter("memory/" + channel, v)
 
 
-def note_mfu(flops: float, step_s: float):
+def note_mfu(flops: float, step_s: float,
+             peak_flops: Optional[float] = None):
     """One measured step: predicted flops over wall seconds -> the
     `ptpu_mfu` gauge (+ a `memory/mfu` counter sample when tracing).
     Callers measure step_s across a dispatch window; under donated-state
-    backpressure successive dispatches track true step time."""
+    backpressure successive dispatches track true step time.
+
+    The peak is the computing device's own (`costs.device_peaks` by
+    `device_kind`) unless passed explicitly. On a device the table lacks —
+    the CPU included — NO sample is recorded: a fraction of some other
+    chip's peak is not a utilization."""
     from ..framework import costs as _costs
+    if peak_flops is None:
+        import jax
+        peaks = _costs.device_peaks(jax.devices()[0].device_kind)
+        if peaks is None:
+            return
+        peak_flops = peaks["peak_flops"]
     memory_metrics()
     with _lock:
         _mfu["flops"] = float(flops)
         _mfu["step_s"] = float(step_s)
-        _mfu["value"] = _costs.mfu(flops, step_s)
+        _mfu["value"] = _costs.mfu(flops, step_s, peak_flops)
     from . import tracing as _tracing
     _tracing.record_counter("memory/mfu", _mfu["value"])
 

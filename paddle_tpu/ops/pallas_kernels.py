@@ -611,6 +611,44 @@ def _fused_attention_bwd(scale, causal, backend, block_q, block_k, res, g):
 _fused_attention.defvjp(_fused_attention_fwd, _fused_attention_bwd)
 
 
+def _attention_over_mesh(mesh, q, k, v, segment_ids, scale, causal, backend):
+    """The flash kernels inside an SPMD-partitioned step (ParallelExecutor).
+
+    The partitioner cannot see into a Mosaic custom call: left bare, the
+    kernel runs replicated at the FULL batch on every chip behind an
+    all-gather of q/k/v. Attention is independent across batch rows and
+    heads, so the call is mapped over the mesh instead: batch over the data
+    axis, heads over the model axis (each only where it divides), sequence
+    and head_dim whole — every chip runs the kernel on its own
+    [B/dp, H/tp, T, D] shard and no collective is needed."""
+    from jax.sharding import PartitionSpec as P
+
+    from ..parallel.mesh import DATA_AXIS, MODEL_AXIS
+
+    def axis_for(name, n):
+        size = mesh.axis_size(name)
+        return name if size > 1 and n % size == 0 else None
+
+    b_ax = axis_for(DATA_AXIS, q.shape[0])
+    h_ax = axis_for(MODEL_AXIS, q.shape[1])
+    if b_ax is None and h_ax is None:
+        return _fused_attention(q, k, v, segment_ids, scale, causal, backend)
+    qkv = P(b_ax, h_ax, None, None)
+    args, specs = [q, k, v], [qkv, qkv, qkv]
+    if segment_ids is not None:
+        args += list(segment_ids)
+        specs += [P(b_ax, None), P(b_ax, None)]
+
+    def per_shard(q, k, v, *seg):
+        return _fused_attention(q, k, v, seg or None, scale, causal, backend)
+
+    # same exemption as ring attention: the pallas INTERPRETER's discharge
+    # path trips the varying-axes check; the compiled kernel keeps it
+    return jax.shard_map(per_shard, mesh=mesh.jax_mesh, in_specs=tuple(specs),
+                         out_specs=qkv,
+                         check_vma=backend != "pallas_interpret")(*args)
+
+
 def _register():
     from ..framework.registry import register_op
 
@@ -629,8 +667,13 @@ def _register():
             q_ids = ins["QSeg"][0]
             kv_ids = ins["KVSeg"][0] if ins.get("KVSeg") else q_ids
             seg = (q_ids, kv_ids)
-        out = _fused_attention(q, k, v, seg, scale,
-                               attrs.get("causal", False), backend)
+        causal = attrs.get("causal", False)
+        mesh = getattr(ctx, "mesh", None)
+        if backend != "xla" and mesh is not None:
+            out = _attention_over_mesh(mesh, q, k, v, seg, scale, causal,
+                                       backend)
+        else:
+            out = _fused_attention(q, k, v, seg, scale, causal, backend)
         return {"Out": [out]}
 
 

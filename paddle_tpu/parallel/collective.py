@@ -18,7 +18,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..core.enforce import InvalidArgumentError, enforce
-from .mesh import DeviceMesh, shard_map
+from .mesh import DeviceMesh
 
 
 def axis_size(axis_name: str) -> int:
@@ -93,7 +93,7 @@ def axis_index(axis_name: str):
 
 
 def sharded(mesh: DeviceMesh, in_specs, out_specs,
-            check_rep: bool = False) -> Callable:
+            check_vma: bool = False) -> Callable:
     """Decorator: run fn as per-shard SPMD code over `mesh` (shard_map).
 
     This is the escape hatch from the "annotate & let XLA partition" world
@@ -102,8 +102,8 @@ def sharded(mesh: DeviceMesh, in_specs, out_specs,
     from graph building into hand-written op handles.
     """
     def deco(fn):
-        smapped = shard_map(fn, mesh=mesh.jax_mesh, in_specs=in_specs,
-                            out_specs=out_specs, check_rep=check_rep)
+        smapped = jax.shard_map(fn, mesh=mesh.jax_mesh, in_specs=in_specs,
+                                out_specs=out_specs, check_vma=check_vma)
         return functools.wraps(fn)(smapped)
     return deco
 

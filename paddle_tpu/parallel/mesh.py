@@ -25,27 +25,6 @@ PIPELINE_AXIS = "pp"
 SEQUENCE_AXIS = "sp"
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True,
-              check_rep=None):
-    """`jax.shard_map` across jax versions: new jax exports it top-level
-    with a `check_vma` flag; jax < 0.5 has it under `jax.experimental`
-    with the flag spelled `check_rep`. One adapter so every caller in
-    paddle_tpu/parallel works on both."""
-    check = check_vma if check_rep is None else check_rep
-    try:
-        from jax import shard_map as _sm
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as _sm
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=check)
-    try:
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_vma=check)
-    except TypeError:
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=check)
-
-
 class DeviceMesh:
     """Named logical mesh over physical devices.
 

@@ -38,7 +38,7 @@ from . import grad_comm as _grad_comm
 from . import pipeline as _pipeline
 from . import tensor_parallel as _tensor_parallel
 from .mesh import (DATA_AXIS, MODEL_AXIS, PIPELINE_AXIS, SEQUENCE_AXIS,
-                   DeviceMesh, get_default_mesh, shard_map as _shard_map)
+                   DeviceMesh, get_default_mesh)
 from .strategy import (BuildStrategy, ExecutionStrategy,
                        GradientScaleStrategy, ReduceStrategy)
 
@@ -566,6 +566,14 @@ class ParallelExecutor(Executor):
             sp.attrs["moved"] = moved
         marks[id(scope)] = cfg_key
 
+    def _lowering_mesh(self, program: Program):
+        # the manual modes already run the step per shard (shard_map
+        # below): a lowering must not map its kernel over the mesh again
+        if (getattr(program, "_dp_comm_applied", False)
+                or getattr(program, "_pp_applied", False)):
+            return None
+        return self.mesh
+
     def _build_step_fn(self, program, feed_names, fetch_names, ro, rw,
                        state_out_names):
         """Manual modes: run the whole step as per-shard SPMD code —
@@ -692,11 +700,11 @@ class ParallelExecutor(Executor):
         pp_spec = PartitionSpec(PIPELINE_AXIS) if has_pp else PartitionSpec()
         tp_spec = (PartitionSpec(MODEL_AXIS)
                    if MODEL_AXIS in self.mesh.axes else PartitionSpec())
-        mapped = _shard_map(shard_step, mesh=self.mesh.jax_mesh,
-                            in_specs=(dp_spec, pp_spec, tp_spec, feed_specs,
-                                      ro_specs, rw_specs, PartitionSpec()),
-                            out_specs=(fetch_specs, state_specs),
-                            check_vma=False)
+        mapped = jax.shard_map(
+            shard_step, mesh=self.mesh.jax_mesh,
+            in_specs=(dp_spec, pp_spec, tp_spec, feed_specs, ro_specs,
+                      rw_specs, PartitionSpec()),
+            out_specs=(fetch_specs, state_specs), check_vma=False)
         dp = self._dp
         ppn = self.mesh.axis_size(PIPELINE_AXIS)
         tpn = self.mesh.axis_size(MODEL_AXIS)

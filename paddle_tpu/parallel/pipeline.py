@@ -40,7 +40,7 @@ from jax.tree_util import tree_map
 
 from ..core.enforce import InvalidArgumentError, enforce
 from .collective import ring_perm
-from .mesh import PIPELINE_AXIS, DeviceMesh, shard_map
+from .mesh import PIPELINE_AXIS, DeviceMesh
 
 
 def _pipeline_body(stage_fn: Callable, axis_name: str):
@@ -60,11 +60,9 @@ def _pipeline_body(stage_fn: Callable, axis_name: str):
         y = jnp.zeros(x.shape, x.dtype)               # outputs (last stage)
         # the scan carry is device-varying (each stage holds different
         # activations) — mark the initial zeros as such for shard_map's
-        # varying-axis type system (jax < 0.6 has no pvary and no vma
-        # tracking either, so nothing needs marking there)
-        if hasattr(jax.lax, "pvary"):
-            state = jax.lax.pvary(state, (axis_name,))
-            y = jax.lax.pvary(y, (axis_name,))
+        # varying-axis type system
+        state = jax.lax.pcast(state, (axis_name,), to="varying")
+        y = jax.lax.pcast(y, (axis_name,), to="varying")
 
         def tick(carry, t):
             state, y = carry
@@ -129,9 +127,8 @@ def pipeline_apply(mesh: DeviceMesh, stage_fn: Callable, stacked_params, x,
     param_specs = tree_map(
         lambda p: P(*([axis_name] + [None] * (p.ndim - 1))), stacked_params)
     body = _pipeline_body(stage_fn, axis_name)
-    f = shard_map(body, mesh=mesh.jax_mesh,
-                  in_specs=(param_specs, P()), out_specs=P(),
-                  )
+    f = jax.shard_map(body, mesh=mesh.jax_mesh,
+                      in_specs=(param_specs, P()), out_specs=P())
     ym = f(stacked_params, xm)
     return ym.reshape((b,) + ym.shape[2:])
 

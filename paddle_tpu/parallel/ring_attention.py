@@ -35,8 +35,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from .mesh import (DATA_AXIS, SEQUENCE_AXIS, DeviceMesh,  # noqa: F401
-                   shard_map)
+from .mesh import DATA_AXIS, SEQUENCE_AXIS, DeviceMesh
 
 _NEG_INF = -1e30
 
@@ -140,16 +139,15 @@ def _block_bwd(q, k, v, do, lse, delta, scale, causal, q_ids, kv_ids,
 
 
 def _as_varying_as(x, *refs):
-    """Mark a freshly-created constant as device-varying over every mesh
-    axis any of `refs` varies over — lax.switch requires all branches to
+    """Mark `x` as device-varying over every mesh axis any of `refs` varies
+    over (and `x` does not yet) — lax.switch requires all branches to
     produce identical vma types under shard_map, and the dead branch's
-    zeros would otherwise come out replicated."""
+    fresh zeros would otherwise come out replicated."""
     axes = set()
     for r in refs:
         axes |= set(getattr(r.aval, "vma", ()) or ())
-    if not axes or not hasattr(jax.lax, "pcast"):
-        # jax < 0.7 has no vma tracking (avals carry no .vma, so `axes` is
-        # empty there anyway) — nothing to mark
+    axes -= set(getattr(jax.typeof(x), "vma", ()) or ())
+    if not axes:
         return x
     return jax.lax.pcast(x, tuple(sorted(axes)), to="varying")
 
@@ -401,18 +399,18 @@ def ring_attention_sharded(mesh: DeviceMesh, q, k, v, *, causal=False,
             return ring_attention(q, k, v, causal=causal, scale=scale,
                                   backend=backend, block_q=block_q,
                                   block_k=block_k)
-        f = shard_map(body, mesh=mesh.jax_mesh,
-                      in_specs=(in_spec, in_spec, in_spec),
-                      out_specs=in_spec, check_vma=check_vma)
+        f = jax.shard_map(body, mesh=mesh.jax_mesh,
+                          in_specs=(in_spec, in_spec, in_spec),
+                          out_specs=in_spec, check_vma=check_vma)
         return f(q, k, v)
 
     def body(q, k, v, seg):
         return ring_attention(q, k, v, causal=causal, scale=scale,
                               segment_ids=seg, backend=backend,
                               block_q=block_q, block_k=block_k)
-    f = shard_map(body, mesh=mesh.jax_mesh,
-                  in_specs=(in_spec, in_spec, in_spec, seg_spec),
-                  out_specs=in_spec, check_vma=check_vma)
+    f = jax.shard_map(body, mesh=mesh.jax_mesh,
+                      in_specs=(in_spec, in_spec, in_spec, seg_spec),
+                      out_specs=in_spec, check_vma=check_vma)
     return f(q, k, v, segment_ids)
 
 
@@ -447,10 +445,13 @@ def ring_attention_live_blocks(mesh: DeviceMesh, q, k, v, *, causal=False,
         out, live = ring_attention(
             xs[0], xs[1], xs[2], causal=causal, scale=scale,
             segment_ids=seg, backend=backend, with_stats=True)
-        return out, jax.lax.psum(live, shard_axes)
+        # without segment ids the count does not depend on the data shard,
+        # so it is not varying over dp — and psum refuses an axis its
+        # operand does not vary over
+        return out, jax.lax.psum(_as_varying_as(live, *xs), shard_axes)
 
-    f = shard_map(body, mesh=mesh.jax_mesh, in_specs=tuple(specs),
-                  out_specs=(in_spec, mesh.pspec()),
-                  check_vma=backend != "pallas_interpret")
+    f = jax.shard_map(body, mesh=mesh.jax_mesh, in_specs=tuple(specs),
+                      out_specs=(in_spec, mesh.pspec()),
+                      check_vma=backend != "pallas_interpret")
     out, live = f(*args)
     return out, int(jnp.max(live))

@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from .mesh import MODEL_AXIS, DeviceMesh, shard_map
+from .mesh import MODEL_AXIS, DeviceMesh
 
 
 def sharded_embedding_lookup(mesh: DeviceMesh, table, ids,
@@ -41,7 +41,7 @@ def sharded_embedding_lookup(mesh: DeviceMesh, table, ids,
         vals = jnp.where(in_range[..., None], vals, 0.0)
         return jax.lax.psum(vals, axis_name)
 
-    f = shard_map(body, mesh=mesh.jax_mesh,
+    f = jax.shard_map(body, mesh=mesh.jax_mesh,
                   in_specs=(P(axis_name, None), P()),
                   out_specs=P())
     return f(table, ids)

@@ -116,7 +116,7 @@ class GenRequest:
                  "submitted_pc", "admitted_at", "admitted_pc",
                  "first_token_pc", "done_pc", "sent_at", "sent_pc",
                  "defer_transport", "table", "shared_len",
-                 "spec_draft_s", "spec_verify_s")
+                 "spec_draft_s", "spec_verify_s", "error")
 
     def __init__(self, rid, prompt, max_new, eos_id=None, on_done=None,
                  request_id: Optional[str] = None,
@@ -159,6 +159,9 @@ class GenRequest:
         #: — or immediately if the frame cannot be delivered); False =
         #: no wire, transport/e2e close at completion
         self.defer_transport = bool(defer_transport)
+        #: the exception that killed the engine under this request
+        #: (`ContinuousBatchingEngine.fail_all`); None on a normal finish
+        self.error: Optional[BaseException] = None
         self._event = threading.Event()
 
     @property
@@ -205,6 +208,9 @@ class GenRequest:
     def wait(self, timeout: Optional[float] = None) -> List[int]:
         if not self._event.wait(timeout):
             raise TimeoutError(f"request {self.rid} not done in {timeout}s")
+        if self.error is not None:
+            raise RuntimeError(
+                f"engine failed under request {self.rid}") from self.error
         return self.tokens
 
     def _complete(self):
@@ -264,6 +270,9 @@ class ContinuousBatchingEngine:
         self._pending: "deque[GenRequest]" = deque()
         self._lock = threading.Lock()
         self._rid = 0
+        #: the exception a tick raised (`fail_all`); a failed engine
+        #: refuses new work — its donated cache state is gone
+        self.failed: Optional[BaseException] = None
 
         self._program, self._startup = Program(), Program()
         with program_guard(self._program, self._startup), \
@@ -516,6 +525,10 @@ class ContinuousBatchingEngine:
                 exc=InvalidArgumentError)
         self._enforce_request_fits(prompt, max_new)
         with self._lock:
+            if self.failed is not None:
+                raise RuntimeError(
+                    f"engine failed: {type(self.failed).__name__}: "
+                    f"{self.failed}")
             self._rid += 1
             req = GenRequest(self._rid, prompt, max_new,
                              self.eos_id if eos_id == "engine" else eos_id,
@@ -755,7 +768,6 @@ class ContinuousBatchingEngine:
         self._m_dispatch.observe(td - t0)
         if _tracing.enabled():
             # the host-dispatch share of the tick as a named phase
-            # (PROBE_GAP_r07's `host_dispatch`, now first-class)
             _tracing.record_span("dispatch", "engine/dispatch", t0, td,
                                  active=len(active))
         self._m_tick_latency.observe(time.perf_counter() - t0)
@@ -811,6 +823,23 @@ class ContinuousBatchingEngine:
         self._m_req_phase["transport"].observe(req.sent_pc - req.done_pc)
         self._m_req_e2e.observe(req.e2e_s())
 
+    def fail_all(self, exc: BaseException) -> List[GenRequest]:
+        """A tick raised `exc` (compile failure, device memory exhausted
+        at the first tick): the step's donated state is gone and the
+        engine cannot continue. Every active and pending request
+        completes NOW carrying `exc` — `wait()` raises, `on_done`
+        callbacks see `req.error` — and later submits are refused, so no
+        caller is left waiting on an engine that will never tick again."""
+        with self._lock:
+            self.failed = exc
+            reqs = list(self._active.values()) + list(self._pending)
+            self._active.clear()
+            self._pending.clear()
+        for req in reqs:
+            req.error = exc
+            req._complete()
+        return reqs
+
     def run_until_idle(self, max_ticks: Optional[int] = None
                        ) -> List[GenRequest]:
         """Tick until every pending/active request completed (or
@@ -826,6 +855,15 @@ class ContinuousBatchingEngine:
             ticks += 1
             if max_ticks is not None and ticks >= max_ticks:
                 return done
+
+    def tick_hlo(self) -> str:
+        """Optimized HLO text of the compiled decode tick
+        (`Executor.compiled_hlo`): shows which attention path the tick
+        took at this engine's shape — a `tpu_custom_call` per layer when
+        the fused decode kernel is in, none when the shape gate sent it
+        to the composite."""
+        return self._exe.compiled_hlo(self._program, dict(self._feeds),
+                                      self._tick_fetches(), self.scope)
 
     def occupancy(self) -> float:
         """Fraction of slot-ticks that carried an active request —
@@ -990,6 +1028,9 @@ class EngineServer:
         self._writers: List = []
         self._lock = threading.Lock()
         self._prev_sigterm = None
+        #: "Type: message" of the exception that killed the engine thread
+        #: (`_engine_failed`); /healthz reports it under status "failed"
+        self.error: Optional[str] = None
         # Prometheus exposition + health: a small HTTP listener serving
         # GET /metrics and GET /healthz. A SEPARATE socket from the
         # generation RPC (that one speaks the serving.py frame protocol;
@@ -1028,8 +1069,10 @@ class EngineServer:
         from ..parallel import elastic as _elastic
         restarts = os.environ.get("PTPU_SUPERVISOR_RESTARTS")
         return {
-            "status": ("draining" if self._draining.is_set()
+            "status": ("failed" if self.error is not None
+                       else "draining" if self._draining.is_set()
                        else "serving"),
+            "error": self.error,
             "engine": self.engine.stats(),
             "checkpoints": {
                 "pending_async": _elastic.pending_async_count()},
@@ -1074,12 +1117,8 @@ class EngineServer:
         # window where a request is admitted into a stopping engine
         with self._lock:
             self._draining.set()
-        try:
-            # closing the listener unblocks accept(); in-flight conns
-            # stay open so completions can still go out
-            self._sock.close()
-        except OSError:
-            pass
+        # in-flight conns stay open so completions can still go out
+        self._close_listener()
         deadline = None if timeout is None else time.time() + timeout
         drained = True
         while self.engine.n_active or self.engine.n_pending:
@@ -1148,10 +1187,7 @@ class EngineServer:
             if getattr(self, "_http_started", False):
                 self._http.shutdown()
             self._http.server_close()
-        try:
-            self._sock.close()
-        except OSError:
-            pass
+        self._close_listener()
         import socket as _socket
         with self._lock:
             conns = list(self._conns)
@@ -1170,6 +1206,10 @@ class EngineServer:
         for t in self._threads:
             t.join(timeout=10)
 
+    def _close_listener(self):
+        from .transport import _close_listener
+        _close_listener(self._sock)
+
     def __enter__(self):
         return self.start()
 
@@ -1178,12 +1218,32 @@ class EngineServer:
 
     # -- engine thread ----------------------------------------------------
     def _engine_loop(self):
-        while not self._stop.is_set():
-            if self.engine.n_active or self.engine.n_pending:
-                self.engine.step()
-            else:
-                self._wake.wait(timeout=0.05)
-                self._wake.clear()
+        try:
+            while not self._stop.is_set():
+                if self.engine.n_active or self.engine.n_pending:
+                    self.engine.step()
+                else:
+                    self._wake.wait(timeout=0.05)
+                    self._wake.clear()
+        except Exception as e:
+            # thread boundary: a tick that raises must reach every client
+            # as an error frame — a daemon thread dying silently leaves
+            # them blocked in recv forever
+            self._engine_failed(e)
+
+    def _engine_failed(self, exc: Exception):
+        import traceback
+        from ..core import flags
+        self.error = f"{type(exc).__name__}: {exc}"
+        flags.vlog(0, "engine thread failed, server stopping: %s\n%s",
+                   self.error, traceback.format_exc())
+        # same locked flip as drain(): a reader either sees the flag (and
+        # rejects with the error) or finished its submit before fail_all
+        with self._lock:
+            self._draining.set()
+        self.engine.fail_all(exc)
+        self._stop.set()
+        self._close_listener()
 
     # -- I/O threads ------------------------------------------------------
     def _accept_loop(self):
@@ -1215,6 +1275,11 @@ class EngineServer:
             self._writers.append(writer)
 
         def on_done(req, tag):
+            if req.error is not None:
+                writer.offer(_encode_msg({
+                    "error": f"engine failed: {type(req.error).__name__}: "
+                             f"{req.error}", "tag": tag}))
+                return
             ph = req.phases() or {}
             frame = _encode_msg({"done": {
                 "tag": tag, "tokens": req.tokens,
@@ -1247,7 +1312,9 @@ class EngineServer:
                 # drain()'s locked flag flip): a submit can never slip in
                 # after drain decided the engine is idle
                 with self._lock:
-                    if self._draining.is_set():
+                    if self.error is not None:
+                        err = f"engine failed: {self.error}"
+                    elif self._draining.is_set():
                         # graceful drain: in-flight work completes, but
                         # nothing new is admitted — the client gets an
                         # explicit rejection, never a silent drop

@@ -14,9 +14,7 @@ shared-weights predictor per serving thread). The TPU translation:
   run concurrently — XLA executions release the GIL, so concurrent
   requests genuinely overlap on device.
 
-Transport (v2 — the round-5 serving link sat at 0.54–0.71 of what the
-prefetcher sustained on the same link; the per-request turnaround below is
-what closed it, BENCH_SERVE_r07.json):
+Transport (v2 — built to cut the per-request turnaround):
 
 - ZERO-COPY VECTORED FRAMING: a frame (length prefix + header + tensor
   payloads) goes out as ONE sendmsg syscall over memoryviews of the numpy
@@ -303,6 +301,18 @@ def _recv_msg(sock: socket.socket, pool: Optional[_RecvBufferPool] = None,
     return header, buffers, buf
 
 
+def _close_listener(sock):
+    """shutdown() BEFORE close(): closing a listening fd does not wake a
+    thread parked in accept() on Linux — it then sits out its whole join
+    timeout in the server's shutdown — while shutting the socket down makes
+    accept() return at once. Shared by PredictorServer and EngineServer."""
+    for op in (lambda: sock.shutdown(socket.SHUT_RDWR), sock.close):
+        try:
+            op()
+        except OSError:
+            pass
+
+
 class PredictorServer:
     """Serve a Predictor (or ExportedPredictor) over TCP.
 
@@ -334,10 +344,7 @@ class PredictorServer:
 
     def shutdown(self):
         self._stop.set()
-        try:
-            self._sock.close()
-        except OSError:
-            pass
+        _close_listener(self._sock)
         # close live connections so threads blocked in recv() exit NOW
         # instead of eating the join timeout each
         with self._lock:

@@ -476,6 +476,13 @@ class Supervisor:
 
         Supervisor([sys.executable, "train.py"], max_restarts=20).run()
 
+    One process per chip: a chip belongs to one process, and a parent that
+    has touched JAX holds it — a child that needs it then fails or hangs.
+    Importing `paddle_tpu` (this module included) initializes no JAX
+    backend (tests/test_chip_smoke.py), so a supervising process CAN
+    start children that own the chips, provided the supervising script
+    itself stays off JAX: no computation, no `jax.devices()`.
+
     Hardening knobs:
 
     - the restart budget is a HARD cap: when it runs out, run() logs a
@@ -496,9 +503,9 @@ class Supervisor:
     env; any rank dying kills the rest of the gang (SIGTERM, then wait)
     and the whole world restarts together — the restart granularity the
     chief-commits barrier assumes (a half-restarted world would dead-ack
-    the barrier). Structure-pinned for hardware; in this container the
-    gang members cannot form a jax process world (jaxlib 0.4.x), so
-    multi-rank children run the simulated ProcessWorld internally.
+    the barrier). The tests' multi-rank children run the simulated
+    ProcessWorld internally; a gang of real chip-owning ranks has not run
+    on hardware.
 
     `dossier_dir` arms the flight recorder across restarts
     (observability/flight_recorder.py): children inherit

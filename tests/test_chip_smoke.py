@@ -1,0 +1,135 @@
+"""chip_smoke.py's phase bodies at a tiny size on the virtual CPU mesh.
+
+The script itself only passes on a TPU (its `device` phase refuses anything
+else); what can be checked here is that every phase body runs end to end and
+that its checks hold at a small size — which is also how the script is
+debugged before any chip time is spent.
+"""
+
+import os
+import subprocess
+import sys
+
+import pytest
+
+import paddle_tpu as pt
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+import chip_smoke  # noqa: E402
+
+_LM = dict(vocab=128, d_model=64, d_inner=128, num_heads=4, num_layers=2)
+
+
+def test_script_refuses_to_run_without_a_chip():
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    p = subprocess.run([sys.executable, os.path.join(REPO, "chip_smoke.py")],
+                       env=env, capture_output=True, text=True, timeout=300)
+    assert p.returncode != 0
+    assert "no chip" in p.stderr and "phase device" in p.stderr
+    assert '"ok"' not in p.stdout and p.stdout.strip() == ""
+
+
+def test_device_phase_names_the_missing_chip():
+    with pytest.raises(chip_smoke.SmokeFailure, match="no chip.*cpu"):
+        chip_smoke.phase_device()
+
+
+def test_train_then_serve_from_the_same_scope():
+    train = chip_smoke.phase_train_lm(seq_len=32, batch=4, steps=3,
+                                      flash_calls_per_layer=0, **_LM)
+    assert train["losses"][-1] < train["losses"][0]
+    serve = chip_smoke.phase_serve_lm(
+        pt.global_scope(), max_len=32, n_slots=4, n_requests=8,
+        min_prompt=9, max_prompt=12, max_new=4, deadline_s=240.0, **_LM)
+    assert serve["requests"] == 9 and serve["prefix_hits"] >= 1
+    assert serve["decode_attention"] == "composite"
+
+
+def test_train_resnet_phase():
+    out = chip_smoke.phase_train_resnet50(batch=2, steps=2, depth=18,
+                                          image=32)
+    assert len(out["losses"]) == 2
+
+
+def test_kernels_phase_through_the_interpreter():
+    out = chip_smoke.phase_kernels(
+        backend="pallas_interpret", flash_shapes=((1, 2, 256, 64),),
+        decode=(4, 64, 1408, 2), recurrent=(8, 4, 128))
+    assert set(out["max_rel_err"]) == {
+        "flash_1x2x256x64", "flash_1x2x256x64_seg", "decode_T1408",
+        "fused_lstm", "fused_gru"}
+
+
+def test_multichip_phase_on_four_virtual_devices():
+    ref = chip_smoke.phase_train_lm(seq_len=128, batch=4, steps=2,
+                                    flash_calls_per_layer=0, **_LM)
+    out = chip_smoke.phase_multichip(
+        ref["losses"][0], seq_len=128, batch=4, steps=2,
+        flash_calls_per_layer=0, attn_backend="pallas_interpret",
+        ring_shape=(1, 64, 2, 8), **_LM)
+    assert out["mesh"] == {"dp": 2, "tp": 2}
+    assert out["ring"]["max_rel_err"] <= chip_smoke.TOL_BF16
+
+
+# -- the process and cache contract the smoke rests on ----------------------
+# A chip belongs to one process: a parent that has touched JAX holds it and a
+# child that needs it fails or hangs. So importing the package must
+# initialize no backend (a supervisor can then start children that own the
+# chip), and the persistent compile cache must sit where every process of a
+# checkout finds it again (core/compile_cache.py).
+
+
+def _spawn(code, cwd, **env_changes):
+    env = dict(os.environ)
+    for k, v in env_changes.items():
+        if v is None:
+            env.pop(k, None)
+        else:
+            env[k] = v
+    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
+    return subprocess.Popen([sys.executable, "-c", code], cwd=str(cwd),
+                            env=env, stdout=subprocess.PIPE,
+                            stderr=subprocess.PIPE, text=True)
+
+
+def _finish(proc):
+    out, err = proc.communicate(timeout=120)
+    assert proc.returncode == 0, err[-2000:]
+    return out.strip().splitlines()[-1]
+
+
+def test_import_initializes_no_backend(tmp_path):
+    # jax refuses to resize the CPU platform once ANY backend exists, so a
+    # successful resize after the imports proves none was initialized
+    code = (
+        "import jax\n"
+        "import paddle_tpu, paddle_tpu.serving, paddle_tpu.trainer\n"
+        "jax.config.update('jax_num_cpu_devices', 3)\n"
+        "print(len(jax.devices()))\n")
+    assert _finish(_spawn(code, tmp_path, JAX_PLATFORMS="cpu")) == "3"
+
+
+def test_compile_cache_placed_from_outside(tmp_path):
+    code = ("import jax, paddle_tpu\n"
+            "print(jax.config.jax_compilation_cache_dir)\n")
+    a, b = tmp_path / "a", tmp_path / "b"
+    a.mkdir()
+    b.mkdir()
+    given = str(tmp_path / "given_cache")
+    # no backend is initialized by these imports, so leaving the platform
+    # unpinned is safe on a machine without a chip
+    procs = [
+        _spawn(code, a, JAX_COMPILATION_CACHE_DIR=None, JAX_PLATFORMS=None),
+        _spawn(code, b, JAX_COMPILATION_CACHE_DIR=None, JAX_PLATFORMS=None),
+        _spawn(code, a, JAX_COMPILATION_CACHE_DIR=given, JAX_PLATFORMS=None),
+        _spawn(code, a, JAX_COMPILATION_CACHE_DIR=None, JAX_PLATFORMS="cpu"),
+    ]
+    from_a, from_b, from_env, cpu_pinned = [_finish(p) for p in procs]
+    # unset: the fixed in-checkout path, whatever the working directory
+    assert from_a == from_b == os.path.join(REPO, ".jax_cache")
+    # set from outside: JAX honours it and the package sets nothing
+    assert from_env == given
+    # the CPU-pinned test tier keeps no cache
+    assert cpu_pinned == "None"
+    assert not os.path.exists(given)    # configuring creates nothing

@@ -18,7 +18,7 @@ from jax.sharding import PartitionSpec as P
 import paddle_tpu as pt
 from paddle_tpu.core.enforce import InvalidArgumentError
 from paddle_tpu.parallel import collective as C
-from paddle_tpu.parallel.mesh import DeviceMesh, shard_map
+from paddle_tpu.parallel.mesh import DeviceMesh
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "tools"))
@@ -93,7 +93,7 @@ class TestReduceScatterBoundary:
 
     def test_divisible_ok(self):
         mesh = self._mesh()
-        f = shard_map(lambda x: C.reduce_scatter(x, "dp"),
+        f = jax.shard_map(lambda x: C.reduce_scatter(x, "dp"),
                       mesh=mesh.jax_mesh, in_specs=(P(),),
                       out_specs=P("dp"), check_vma=False)
         out = jax.jit(f)(jnp.ones((16, 4), jnp.float32))
@@ -103,7 +103,7 @@ class TestReduceScatterBoundary:
 
     def test_non_divisible_raises_clear_error(self):
         mesh = self._mesh()
-        f = shard_map(lambda x: C.reduce_scatter(x, "dp"),
+        f = jax.shard_map(lambda x: C.reduce_scatter(x, "dp"),
                       mesh=mesh.jax_mesh, in_specs=(P(),),
                       out_specs=P("dp"), check_vma=False)
         with pytest.raises(InvalidArgumentError, match="not divisible"):
@@ -111,7 +111,7 @@ class TestReduceScatterBoundary:
 
     def test_bad_dim_raises(self):
         mesh = self._mesh()
-        f = shard_map(lambda x: C.reduce_scatter(x, "dp", scatter_dim=3),
+        f = jax.shard_map(lambda x: C.reduce_scatter(x, "dp", scatter_dim=3),
                       mesh=mesh.jax_mesh, in_specs=(P(),),
                       out_specs=P("dp"), check_vma=False)
         with pytest.raises(InvalidArgumentError, match="out of range"):

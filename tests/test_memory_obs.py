@@ -145,7 +145,8 @@ class TestWatermarks:
 
     def test_gauges_live_in_default_registry(self):
         obs_memory.update_watermark("host_staging_bytes", 7)
-        obs_memory.note_mfu(1e12, 0.1)   # 1e13 flops/s over 197e12 peak
+        # 1e13 flops/s over an explicit 197e12 peak
+        obs_memory.note_mfu(1e12, 0.1, peak_flops=costs.V5E_PEAK_TFLOPS)
         text = obs_metrics.default_registry().expose()
         assert "ptpu_memory_host_staging_bytes 7" in text
         assert ('ptpu_memory_watermark_bytes'
@@ -154,6 +155,18 @@ class TestWatermarks:
                     if ln.startswith("ptpu_mfu ")][0]
         assert abs(float(mfu_line.split()[-1])
                    - 1e12 / 0.1 / costs.V5E_PEAK_TFLOPS) < 1e-12
+
+    def test_mfu_records_nothing_without_device_peaks(self):
+        import jax
+        assert costs.device_peaks(jax.devices()[0].device_kind) is None
+        assert costs.device_peaks("TPU v5 lite")["peak_flops"] \
+            == costs.V5E_PEAK_TFLOPS
+        obs_memory.reset_watermarks()
+        m = tracing.mark()
+        obs_memory.note_mfu(1e12, 0.1)       # CPU: no peak, no sample
+        assert obs_memory.watermark_board()["mfu"]["value"] == 0.0
+        assert not [s for s in tracing.spans_since(m)
+                    if s.name == "memory/mfu"]
 
     def test_counter_samples_render_as_chrome_counter_events(self,
                                                              tmp_path):
@@ -424,8 +437,9 @@ class TestOverheadBudgetWithMemoryChannel:
     def test_budget_holds_with_memory_channel(self, rng):
         step_s, spans_per_step, counters_per_step = \
             self._step_time_and_spans(rng)
-        # the executor's per-run sampling IS live (device_state + mfu)
-        assert counters_per_step >= 2, counters_per_step
+        # the executor's per-run sampling IS live (device_state; ptpu_mfu
+        # records nothing on a device without peaks, the CPU included)
+        assert counters_per_step >= 1, counters_per_step
         span_cost = tracing.span_overhead_s()
         ctr_cost = _counter_overhead_s()
         frac_on = (span_cost * spans_per_step

@@ -313,33 +313,25 @@ def test_get_places_lists_devices():
     assert get_places(device_count=2) == places[:2]
 
 
-def test_jit_cache_flag_wires_persistent_cache(tmp_path, rng):
-    """PTPU_JIT_CACHE -> jax persistent compilation cache (compiled
-    executables survive restarts; the 20-40s TPU first-compiles become
-    cache loads)."""
-    import glob
+def test_compile_cache_rule_in_process(monkeypatch):
+    """core/compile_cache.py replaced the PTPU_JIT_CACHE flag with one
+    rule: JAX_COMPILATION_CACHE_DIR set -> the package sets nothing; unset
+    -> the fixed in-checkout directory, except in a CPU-pinned process like
+    this one (tests/test_chip_smoke.py checks the TPU-side half from
+    fresh interpreters)."""
+    import os
     import jax
-    from paddle_tpu.core import flags
-    from paddle_tpu.framework import executor as ex
+    from paddle_tpu.core import compile_cache, flags
 
-    prev = flags.get_flag("jit_cache")
-    prev_cfg = jax.config.jax_compilation_cache_dir
-    cache = str(tmp_path / "xla_cache")
-    from paddle_tpu import layers
-    try:
-        flags.set_flag("jit_cache", cache)
-        ex._jit_cache_configured.clear()
-        x = layers.data("jcx", shape=[32])
-        loss = layers.mean(layers.fc(x, size=32))
-        exe = pt.Executor()
-        exe.run(pt.default_startup_program())
-        exe.run(feed={"jcx": np.zeros((4, 32), "float32")},
-                fetch_list=[loss])
-        assert jax.config.jax_compilation_cache_dir == cache
-    finally:
-        flags.set_flag("jit_cache", prev)
-        jax.config.update("jax_compilation_cache_dir", prev_cfg)
-        ex._jit_cache_configured.clear()
+    assert "jit_cache" not in flags.all_flags()
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert compile_cache.CACHE_DIR == os.path.join(repo, ".jax_cache")
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/somewhere/else")
+    compile_cache.configure()
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    compile_cache.configure()
+    assert jax.config.jax_compilation_cache_dir == before
 
 
 class TestNanGuard:

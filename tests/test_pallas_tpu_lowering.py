@@ -1,0 +1,176 @@
+"""Pallas -> Mosaic lowering for the TPU platform, checked without a chip.
+
+`jax.jit(f).trace(*args).lower(lowering_platforms=("tpu",))` runs the whole
+Pallas TPU lowering (block-shape legality, layout rules) from a CPU-only
+process; only Mosaic's own compile needs the device. Interpret mode checks
+none of this: the batch-major blocks of the r06 recurrent kernel passed every
+interpret-mode test and were refused here ("last two dimensions of your block
+shape are divisible by 8 and 128"). Every kernel chip_smoke.py's `kernels`
+phase runs is lowered at the smoke's shapes, plus the program-level steps
+whose kernel selection depends on shape gates.
+"""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import paddle_tpu as pt
+from paddle_tpu.fusion import (fused_decode_attention, fused_gru_sequence,
+                               fused_lstm_sequence)
+from paddle_tpu.ops import pallas_kernels
+from paddle_tpu.ops.pallas_kernels import flash_attention
+
+S = jax.ShapeDtypeStruct
+F32, BF16, I32 = jnp.float32, jnp.bfloat16, jnp.int32
+
+
+def _tpu_text(f, *args):
+    return jax.jit(f).trace(*args).lower(lowering_platforms=("tpu",)).as_text()
+
+
+def _n_calls(text):
+    return text.count("tpu_custom_call")
+
+
+@pytest.mark.parametrize("shape", [(8, 16, 1024, 64), (1, 8, 8192, 128)])
+@pytest.mark.parametrize("segments", [False, True])
+def test_flash_fwd_bwd_lowers(shape, segments):
+    B, _, T, _ = shape
+
+    def fwd_bwd(q, k, v, do, ids):
+        out, vjp = jax.vjp(lambda q, k, v: flash_attention(
+            q, k, v, causal=True, backend="pallas",
+            segment_ids=ids if segments else None), q, k, v)
+        return (out,) + vjp(do)
+
+    text = _tpu_text(fwd_bwd, *[S(shape, BF16)] * 4, S((B, T), I32))
+    assert _n_calls(text) == 3          # forward, dq, dk/dv
+
+
+def test_decode_attention_lowers_at_the_gate_edge():
+    rows, nh, dh, span = 16, 16, 64, 640
+    text = _tpu_text(
+        lambda q, k, v, b: fused_decode_attention(
+            q, k, v, b, scale=dh ** -0.5, backend="pallas"),
+        S((rows, 1, nh, 1, dh), F32), S((rows, 1, nh, span, dh), F32),
+        S((rows, 1, nh, span, dh), F32), S((rows, 1, 1, 1, span), F32))
+    assert _n_calls(text) == 1
+    # past the gate the same call is the composite: the choice is by shape
+    text = _tpu_text(
+        lambda q, k, v, b: fused_decode_attention(
+            q, k, v, b, scale=dh ** -0.5, backend="pallas"),
+        S((rows, 1, nh, 1, dh), F32), S((rows, 1, nh, 1024, dh), F32),
+        S((rows, 1, nh, 1024, dh), F32), S((rows, 1, 1, 1, 1024), F32))
+    assert _n_calls(text) == 0
+
+
+@pytest.mark.parametrize("kind", ["lstm", "gru"])
+def test_fused_recurrent_fwd_grad_lowers(kind):
+    B, T, H = 64, 64, 256
+    gates = 4 if kind == "lstm" else 3
+
+    def fwd_grad(x, w, sl, *states):
+        def loss(x, w):
+            if kind == "lstm":
+                hs, cs = fused_lstm_sequence(x, *states, w, sl,
+                                             backend="pallas")
+                return jnp.sum(hs) + jnp.sum(cs)
+            return jnp.sum(fused_gru_sequence(x, *states, w, sl,
+                                              backend="pallas"))
+        return jax.grad(loss, argnums=(0, 1))(x, w)
+
+    states = [S((B, H), F32)] * (2 if kind == "lstm" else 1)
+    text = _tpu_text(fwd_grad, S((B, T, gates * H), F32),
+                     S((H, gates * H), F32), S((B,), I32), *states)
+    assert _n_calls(text) == 1     # the forward recurrence; bwd is a scan
+
+
+# -- program-level steps, with the backend selection a TPU would make -------
+
+
+@pytest.fixture
+def as_on_tpu(monkeypatch):
+    """Kernel selection as on a TPU (`_auto_backend` reads the default
+    backend, which is the CPU here); every shape gate stays live."""
+    monkeypatch.setattr(pallas_kernels, "_auto_backend", lambda: "pallas")
+
+
+def _step_tpu_text(compiled, feed, scope):
+    args = (tuple(jnp.asarray(feed[n]) for n in compiled.feed_names),
+            tuple(scope.get(n) for n in compiled.ro_names),
+            tuple(scope.get(n) for n in compiled.rw_names), np.uint32(0))
+    return compiled.fn.trace(*args).lower(
+        lowering_platforms=("tpu",)).as_text()
+
+
+def test_stacked_lstm_train_step_lowers_with_default_flags(as_on_tpu):
+    """bs64 / T64 / H256: the model with chip history that the batch-major
+    kernel made un-lowerable for a TPU under default flags."""
+    from paddle_tpu.models import stacked_lstm
+    b, t = 64, 64
+    loss, _, _ = stacked_lstm.stacked_lstm_net(
+        dict_dim=10000, emb_dim=256, hid_dim=256, max_len=t)
+    pt.optimizer.AdamOptimizer(learning_rate=5e-4).minimize(loss)
+    exe = pt.Executor()
+    exe.run(pt.default_startup_program())
+    feed = {"words": np.zeros((b, t), "int64"),
+            "words@SEQLEN": np.full((b,), t, "int32"),
+            "label": np.zeros((b, 1), "int64")}
+    compiled = exe._compile(pt.default_main_program(), pt.global_scope(),
+                            list(feed), [loss.name])
+    text = _step_tpu_text(compiled, feed, pt.global_scope())
+    n_lstm = sum(op.type == "dynamic_lstm"
+                 for op in pt.default_main_program().global_block().ops)
+    assert n_lstm >= 2 and _n_calls(text) == n_lstm
+
+
+@pytest.mark.parametrize("max_len,calls_per_layer", [(256, 1), (1024, 0)])
+def test_paged_tick_attention_path_by_span(as_on_tpu, max_len,
+                                           calls_per_layer):
+    """16 heads x 64: a 256-token span takes the fused decode kernel, one
+    custom call per layer; a 1024-token span is past its VMEM gate and
+    takes the composite (what chip_smoke.py's serve_lm phase asserts)."""
+    from paddle_tpu.serving import PagedKVEngine
+    n_layers = 2
+    eng = PagedKVEngine(n_slots=2, vocab=64, max_len=max_len, d_model=1024,
+                        d_inner=64, num_heads=16, num_layers=n_layers)
+    text = _step_tpu_text(eng._step._compiled, eng._feeds, eng.scope)
+    assert _n_calls(text) == calls_per_layer * n_layers
+
+
+def test_sharded_train_step_runs_flash_per_shard(as_on_tpu):
+    """Under ParallelExecutor's SPMD mode the flash calls sit inside a
+    shard_map: their operands are the per-shard [B/dp * H/tp, T, D], not
+    the full batch behind an all-gather."""
+    from paddle_tpu import models
+    from paddle_tpu.parallel import (BuildStrategy, DeviceMesh,
+                                     ParallelExecutor, ReduceStrategy,
+                                     annotate_tp)
+    b, t, nh, dh, n_layers = 8, 128, 4, 16, 2
+    loss, _ = models.transformer.transformer_lm(
+        vocab=128, max_len=t, d_model=nh * dh, d_inner=128, num_heads=nh,
+        num_layers=n_layers, dropout=0.0)
+    pt.optimizer.AdamOptimizer(learning_rate=1e-3).minimize(loss)
+    annotate_tp()
+    pt.Executor().run(pt.default_startup_program())
+    mesh = DeviceMesh(jax.devices()[:4], {"dp": 2, "tp": 2})
+    pe = ParallelExecutor(
+        loss_name=loss.name, mesh=mesh,
+        build_strategy=BuildStrategy(reduce_strategy=ReduceStrategy.Reduce))
+    feed = {"tokens": np.zeros((b, t), "int64"),
+            "tokens@SEQLEN": np.full((b,), t, "int32"),
+            "targets": np.zeros((b, t), "int64")}
+    pe._feed_shapes = {n: np.shape(v) for n, v in feed.items()}
+    compiled = pe._compile(pt.default_main_program(), pt.global_scope(),
+                           list(feed), [loss.name])
+    text = _step_tpu_text(compiled, feed, pt.global_scope())
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == 3 * n_layers
+    per_shard = f"tensor<{(b // 2) * (nh // 2)}x{t}x{dh}xbf16>"
+    full = f"tensor<{b * nh}x{t}x{dh}xbf16>"
+    for ln in calls:
+        assert per_shard in ln and full not in ln, ln[:300]
+    assert re.search(r"sdy\.manual_computation|shard_map", text)

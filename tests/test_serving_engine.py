@@ -284,6 +284,50 @@ class TestEngineServer:
                 assert len(c.generate([3], max_new=2)) == 2
 
 
+    def test_shutdown_is_prompt_and_leaves_no_thread(self, shared_eng):
+        """Closing a listening socket does not wake accept() on Linux: the
+        accept thread used to sit out its whole 10 s join timeout on every
+        shutdown (and stay alive after it)."""
+        import time
+        srv = EngineServer(shared_eng).start()
+        with EngineClient(*srv.address) as c:
+            assert len(c.generate([5], max_new=2)) == 2
+        t0 = time.time()
+        srv.shutdown()
+        assert time.time() - t0 < 5.0
+        assert not any(t.is_alive() for t in srv._threads)
+
+    def test_raising_tick_surfaces_at_client(self, monkeypatch):
+        """A tick that raises (on a first chip run: a compile failure,
+        device memory exhausted) used to kill the daemon engine thread and
+        leave every client blocked in recv. It must fail the in-flight
+        requests, show in /healthz, and refuse later ones."""
+        eng = ContinuousBatchingEngine(n_slots=2, **_ENG_DIMS)
+
+        def boom():
+            raise MemoryError("RESOURCE_EXHAUSTED: out of device memory")
+        monkeypatch.setattr(eng, "step", boom)
+        with EngineServer(eng) as srv:
+            host, port = srv.address
+            with EngineClient(host, port) as c:
+                c._sock.settimeout(30)     # a hang fails, never blocks CI
+                c.send_gen([1, 2], max_new=4)
+                c.send_gen([3], max_new=2)
+                for _ in range(2):         # both in-flight requests fail
+                    with pytest.raises(RuntimeError,
+                                       match="engine failed.*MemoryError"):
+                        c.recv_done()
+                health = srv.health()
+                assert health["status"] == "failed"
+                assert "RESOURCE_EXHAUSTED" in health["error"]
+                c.send_gen([4], max_new=1)  # refused, with the cause
+                with pytest.raises(RuntimeError, match="engine failed"):
+                    c.recv_done()
+        assert eng.n_active == 0 and eng.n_pending == 0
+        with pytest.raises(RuntimeError, match="engine failed"):
+            eng.submit([1], 1)
+
+
 class TestPreparedStep:
     def test_batch_row_mask_injected_per_call(self, rng):
         """A prepared program declaring the reserved batch-row mask must

@@ -231,10 +231,9 @@ def main():
     print(json.dumps({
         "bench": "data-parallel gradient path A/B (ISSUE r8)",
         "mesh": f"{DP} virtual CPU devices, single process "
-                f"(jaxlib < 0.5: no multi-process CPU backend on this "
-                f"container — tools/benchmark.py --update_method multiproc "
-                f"carries the same reduce_mode/byte fields for hosts that "
-                f"can form a real N-process world)",
+                f"(tools/benchmark.py --update_method multiproc carries "
+                f"the same reduce_mode/byte fields over a real N-process "
+                f"world)",
         "rows": rows,
         "convergence": conv,
         "reading": {

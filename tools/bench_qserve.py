@@ -13,10 +13,10 @@ One artifact, five measurements (the r21 perf round's evidence):
      error (the quantization noise that flips near-tie argmaxes).
   c. dispatch A/B — the prepared tick's per-tick dict path
      (`PreparedStep.run`) vs the donated bound path
-     (`PreparedStep.run_bound`) at PROBE_GAP_r07's
+     (`PreparedStep.run_bound`) at tools/probe_gap.py's
      serve_tick_lm2l_64d_8slots config, plus per-tick Python allocation
      bytes (tracemalloc) for both paths and the live engine's `dispatch`
-     span share — compared against r07's 19.1% dispatch-saved baseline.
+     span share.
   d. KV headroom — the HBM bytes freed by weight quantization converted
      into extra BlockPool blocks at a FIXED total budget; admitted
      concurrency under backlog measured on the saturated arrival trace
@@ -52,16 +52,11 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 sys.path.insert(0, os.path.join(REPO, "tools"))
 
-# PROBE_GAP_r07's serve-tick config (serve_tick_lm2l_64d_8slots): the
-# dispatch baseline was measured here, so the A/B re-measures here
+# tools/probe_gap.py's serve-tick config (serve_tick_lm2l_64d_8slots)
 _DIMS = dict(vocab=1000, d_model=64, d_inner=128, num_heads=4,
              num_layers=2)
 _MAX_LEN = 64
 _SLOTS = 8
-# PROBE_GAP_r07.json vs_executor_run at that config: prepared 1.088 ms,
-# run 1.345 ms -> 19.1% of the per-tick wall was per-call dispatch
-_R07 = dict(prepared_tick_ms=1.088, run_tick_ms=1.345,
-            dispatch_saved_pct=19.1)
 # BENCH_GEN_r05.json committed rows (the open bs16 regression: vs_r04
 # recorded bs16_greedy 10877 -> 10360, bs16_beam4 5951 -> 5169)
 _R05 = dict(bs16_greedy_tokens_per_sec=10360.5,
@@ -272,7 +267,6 @@ def bench_dispatch(smoke=False):
                                                           alloc_iters), 1),
         "alloc_bytes_per_tick_bound": round(_alloc_per_tick(bound,
                                                             alloc_iters), 1),
-        "baseline_r07": _R07,
     }
 
     # live engine: the `dispatch` span (tick start -> run_bound return)
@@ -307,7 +301,7 @@ def bench_dispatch(smoke=False):
             "honest win on this mesh is run_tick_ms -> bound_tick_ms "
             "(per-tick argument marshalling removed) and the per-tick "
             "Python allocation floor. On TPU the same span measures true "
-            "async-dispatch cost against r07's 19.1% baseline."),
+            "async-dispatch cost."),
     })
     return row
 

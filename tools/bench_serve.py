@@ -21,7 +21,7 @@ Two claims, both CPU-mesh-measurable (the ISSUE r7 acceptance bar):
    exactly the per-request protocol turnaround the round-5 artifact
    couldn't attribute (VERDICT r5 weak #3). Target >= 0.85.
 
-    JAX_PLATFORMS=cpu python tools/bench_serve.py | tee BENCH_SERVE_r07.json
+    JAX_PLATFORMS=cpu python tools/bench_serve.py
 """
 
 from __future__ import annotations
@@ -318,12 +318,6 @@ def bench_transport(n_runs=3):
     floor_ms = _PAYLOAD / (float(np.mean(floors)) * 1e6) * 1e3
     raw_ms = _PAYLOAD / (float(np.mean(raws)) * 1e6) * 1e3
     served_ms = _PAYLOAD / (float(np.mean(served)) * 1e6) * 1e3
-    # on a real serving link (the dev tunnel sustains ~24 MB/s, bench.py),
-    # the measured per-request CPU cost is amortized over the wire time of
-    # the same payload — the predicted utilization there
-    tunnel_wire_ms = _PAYLOAD / 24e6 * 1e3
-    pred_tunnel_util = tunnel_wire_ms / (tunnel_wire_ms
-                                         + (served_ms - raw_ms))
     out = {"exp": "transport_link_utilization",
            "payload_bytes_per_request": _PAYLOAD,
            "pipeline_depth": 8,
@@ -344,8 +338,6 @@ def bench_transport(n_runs=3):
                                   "served": round(served_ms, 2)},
                "protocol_turnaround_ms": round(floor_ms - raw_ms, 2),
                "stack_overhead_ms": round(served_ms - floor_ms, 2),
-               "predicted_tunnel_link_utilization":
-                   round(pred_tunnel_util, 3),
                "note": "On this 2-core loopback the 'link' runs at memcpy "
                        "speed, so every per-request CPU cost is charged "
                        "against it: the zero-stack floor experiment shows "
@@ -353,12 +345,7 @@ def bench_transport(n_runs=3):
                        "~half the firehose; the serving stack's own "
                        "addition is the smaller stack_overhead_ms "
                        "(reader/worker/writer handoffs that buy "
-                       "compute/I-O overlap). On the actual serving link "
-                       "(dev tunnel, ~24 MB/s measured in bench.py) the "
-                       "same absolute per-request cost amortizes over "
-                       "~175 ms of wire time per payload -> predicted "
-                       "utilization above, vs the 0.54-0.71 the r05 "
-                       "transport measured on that link.",
+                       "compute/I-O overlap).",
            }}
     print(json.dumps(out), flush=True)
     return out
@@ -381,8 +368,7 @@ def main():
                 tx["served_link_utilization"] >= 0.85),
             # the acceptance's alternative branch: the sub-0.85 residual
             # is decomposed with numbers in residual_attribution (protocol
-            # turnaround dominates; predicted utilization on the real
-            # tunnel link is committed there)
+            # turnaround dominates)
             "residual_attributed_to_protocol_turnaround": bool(
                 tx["served_link_utilization"] < 0.85
                 and "residual_attribution" in tx),

@@ -53,9 +53,7 @@ def measure_step(build: Callable, make_feed: Callable[[], Dict],
     if hlo_path:
         with open(hlo_path, "w") as f:
             f.write(ex.as_text())
-    ca = ex.cost_analysis()
-    ca = ca[0] if isinstance(ca, (list, tuple)) else ca
-    ca = ca or {}
+    ca = ex.cost_analysis() or {}
     bytes_acc = float(ca.get("bytes accessed", 0.0))
     flops = float(ca.get("flops", 0.0))
 

@@ -493,16 +493,16 @@ class Executor:
             return_numpy: bool = True):
         """≙ Executor.run (reference executor.py:374-473). Missing fetch vars
         raise; feed arrays are validated against declared var dtypes."""
-        program = program or default_main_program()
-        feed = self._synthesize_batch_mask(program, dict(feed or {}))
-        fetch_list = list(fetch_list or [])
-        scope = scope or global_scope()
-        fetch_names = [f.name if isinstance(f, Variable) else f
-                       for f in fetch_list]
-
         from .. import profiler as _prof
         from ..observability import tracing as _tracing
-        compiled = self._lookup_or_compile(program, feed, fetch_names, scope)
+        program = program or default_main_program()
+        scope = scope or global_scope()
+        with _tracing.span("step", "executor/lookup"):
+            feed = self._synthesize_batch_mask(program, dict(feed or {}))
+            fetch_names = [f.name if isinstance(f, Variable) else f
+                           for f in (fetch_list or [])]
+            compiled = self._lookup_or_compile(program, feed, fetch_names,
+                                               scope)
 
         with _tracing.span("feed_fetch", "executor/feed",
                            n_feeds=len(compiled.feed_names)):
@@ -536,22 +536,26 @@ class Executor:
             # guard — it names WHICH var went bad but not which op; rerun
             # under JAX_PLATFORMS=cpu to localize. ≙ reference
             # CheckTensorNANOrInf (framework/operator.cc:726-736).
-            self._sweep_nonfinite(
-                list(zip(compiled.fetch_names, fetches)) +
-                list(zip(compiled.state_out_names, new_state)),
-                "rerun under JAX_PLATFORMS=cpu with PTPU_CHECK_NAN_INF=1 "
-                "to localize the op")
+            with _tracing.span("step", "executor/post"):
+                self._sweep_nonfinite(
+                    list(zip(compiled.fetch_names, fetches)) +
+                    list(zip(compiled.state_out_names, new_state)),
+                    "rerun under JAX_PLATFORMS=cpu with "
+                    "PTPU_CHECK_NAN_INF=1 to localize the op")
         with _tracing.span("feed_fetch", "executor/state_writeback",
                            n_state=len(compiled.state_out_names)):
             for name, val in zip(compiled.state_out_names, new_state):
                 scope.set_var(name, val)
-        self._note_run_memory(compiled, time.time() - t0)
-        if flags.get_flag("benchmark"):
-            jax.block_until_ready(fetches)
-            print(f"[benchmark] program run took {time.time() - t0:.4f}s")
-        if return_numpy:
+        with _tracing.span("step", "executor/post"):
+            self._note_run_memory(compiled, time.time() - t0)
+            if flags.get_flag("benchmark"):
+                jax.block_until_ready(fetches)
+                print(f"[benchmark] program run took "
+                      f"{time.time() - t0:.4f}s")
+        if not return_numpy:
+            return list(fetches)
+        with _tracing.span("feed_fetch", "executor/fetch"):
             return [as_numpy(f) for f in fetches]
-        return list(fetches)
 
     def run_steps(self,
                   feed_list: Sequence[Dict[str, Any]],

@@ -24,6 +24,7 @@ per-tick, not per-op, so contention is nil; correctness over cleverness.
 
 from __future__ import annotations
 
+import bisect
 import threading
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -145,7 +146,7 @@ DEFAULT_BUCKETS = (1e-4, 2.5e-4, 5e-4, 1e-3, 2.5e-3, 5e-3, 1e-2, 2.5e-2,
 
 class Histogram(_Metric):
     """Cumulative-bucket histogram (tick/step latency). `observe(v)` is
-    O(#buckets); `quantile(q)` estimates from the bucket counts with
+    O(log #buckets); `quantile(q)` estimates from the bucket counts with
     linear interpolation inside the winning bucket (the standard
     histogram_quantile() estimate, computed host-side)."""
 
@@ -167,10 +168,8 @@ class Histogram(_Metric):
         with self._lock:
             self._sum += v
             self._count += 1
-            for i, b in enumerate(self.buckets):
-                if v <= b:
-                    self._counts[i] += 1
-                    break
+            # the first edge >= v; the last edge is +Inf
+            self._counts[bisect.bisect_left(self.buckets, v)] += 1
 
     @property
     def count(self) -> int:

@@ -33,11 +33,11 @@ import time
 from typing import Any, Callable, Dict, List, Optional
 
 from ..core import flags
-from ..core.enforce import InvalidArgumentError, enforce
+from ..core.enforce import (InvalidArgumentError, OutOfRangeError,
+                            enforce)
 
 SPAN_KINDS = frozenset({
     "compile",     # executor trace+XLA-compile of a program
-    "trace",       # program -> jaxpr tracing sub-phases (region runners)
     "step",        # one executor.run / run_steps dispatch
     "tick",        # one serving-engine decode tick
     "collective",  # host-side collective setup (placement, reconcile)
@@ -68,10 +68,10 @@ class Span:
     `trace_ring` of these."""
 
     __slots__ = ("kind", "name", "start", "end", "thread_id", "parent",
-                 "depth", "attrs", "seq")
+                 "depth", "attrs", "seq", "id", "parent_id")
 
     def __init__(self, kind, name, start, end, thread_id, parent, depth,
-                 attrs, seq):
+                 attrs, seq, id=-1, parent_id=-1):
         self.kind = kind
         self.name = name
         self.start = start
@@ -81,6 +81,8 @@ class Span:
         self.depth = depth
         self.attrs = attrs
         self.seq = seq
+        self.id = id               # drawn at ENTER (seq is drawn at exit)
+        self.parent_id = parent_id  # enclosing live span's id, -1 at top
 
     @property
     def duration_ms(self) -> float:
@@ -90,6 +92,7 @@ class Span:
         return {"kind": self.kind, "name": self.name,
                 "duration_ms": round(self.duration_ms, 6),
                 "parent": self.parent, "depth": self.depth,
+                "id": self.id, "parent_id": self.parent_id,
                 "thread_id": self.thread_id, "attrs": self.attrs}
 
 
@@ -99,10 +102,13 @@ class Span:
 _ring: List[Optional[Span]] = []
 _ring_cap = 0
 _seq = itertools.count()
+_ids = itertools.count()    # span ids, drawn at enter; retroactive spans
+#                             and counter samples draw one when recorded
 _resize_lock = threading.Lock()
 
-# per-thread nesting stack: (name, depth) — plus the thread's tag dict
-# (scoped_tags), merged into every span the thread records
+# per-thread nesting stack: the thread's live `span` scopes, outermost
+# first — plus the thread's tag dict (scoped_tags), merged into every span
+# the thread records
 _tls = threading.local()
 
 
@@ -157,23 +163,25 @@ annotation_factory: Optional[Callable[[str], Any]] = None
 
 
 def _ensure_ring():
-    global _ring, _ring_cap
-    raw = flags.get_flag("trace_ring")
+    global _ring, _ring_cap, _ring_raw
+    raw = _RING_FLAG.value
+    if raw is _ring_raw:      # unchanged since it was last validated
+        return _ring
     try:
         cap = int(raw)
     except (TypeError, ValueError):
         raise InvalidArgumentError(
             f"PTPU_TRACE_RING (flag trace_ring) must be a positive "
             f"integer span-ring capacity, got {raw!r}") from None
-    if cap < 1:   # no eager f-string on the record hot path
+    if cap < 1:
         raise InvalidArgumentError(
             f"PTPU_TRACE_RING (flag trace_ring) must be >= 1 (the span "
             f"ring needs at least one slot), got {cap}")
-    if cap != _ring_cap:
-        with _resize_lock:
-            if cap != _ring_cap:
-                _ring = [None] * cap
-                _ring_cap = cap
+    with _resize_lock:
+        if cap != _ring_cap:
+            _ring = [None] * cap
+            _ring_cap = cap
+        _ring_raw = raw
     return _ring
 
 
@@ -181,6 +189,8 @@ def _ensure_ring():
 # .value in place) — holding it dodges a registry lookup per span on the
 # hot path
 _TRACE_FLAG = flags._REGISTRY["trace"]
+_RING_FLAG = flags._REGISTRY["trace_ring"]
+_ring_raw = object()    # the trace_ring value the ring was last sized for
 
 
 def enabled() -> bool:
@@ -220,16 +230,20 @@ def _record(span: Span):
 class span:
     """RAII span scope. Usage:
 
-        with span("pass", "tp_shard_pass", tp=2):
+        with span("pass", "tp_shard_pass", tp=2) as sp:
             ...
+            sp.attrs["moved"] = n      # counts taken where the work happens
 
     Attributes must be JSON-serializable scalars/strings (op_loc output,
-    config ints) — they land in the Chrome trace `args` and the ledger.
-    When disabled, enter/exit touch one module global and return.
+    config ints) — they land in the Chrome trace `args` and the ledger;
+    they are read at EXIT, so a scope may add what it counted. A live span
+    draws its `id` at enter and knows its parent's: `self_time_ms` rebuilds
+    the tree from the pairs. When disabled, enter/exit touch one module
+    global and return.
     """
 
-    __slots__ = ("kind", "name", "attrs", "_start", "_parent", "_depth",
-                 "_annotation", "_live")
+    __slots__ = ("kind", "name", "attrs", "id", "_start", "_parent",
+                 "_stack", "_annotation")
 
     def __init__(self, kind: str, name: Optional[str] = None, **attrs):
         if kind not in SPAN_KINDS:   # no eager f-string on the hot path
@@ -239,20 +253,20 @@ class span:
         self.kind = kind
         self.name = name or kind
         self.attrs = attrs
-        self._start = None
-        self._annotation = None
-        self._live = False
+        self._stack = None           # the thread's stack while live
 
     def __enter__(self):
         if not (_TRACE_FLAG.value or _force_count):
             return self
-        stack = getattr(_tls, "stack", None)
-        if stack is None:
+        try:
+            stack = _tls.stack
+        except AttributeError:
             stack = _tls.stack = []
-        self._parent = stack[-1][0] if stack else ""
-        self._depth = len(stack)
-        stack.append((self.name, self._depth))
-        self._live = True
+        self._parent = stack[-1] if stack else None
+        self.id = next(_ids)
+        stack.append(self)
+        self._stack = stack
+        self._annotation = None
         if annotation_factory is not None:
             try:
                 self._annotation = annotation_factory(self.name)
@@ -263,21 +277,29 @@ class span:
         return self
 
     def __exit__(self, *exc):
-        if not self._live:
+        stack = self._stack
+        if stack is None:
             return False
         end = time.perf_counter()
         if self._annotation is not None:
             self._annotation.__exit__(*exc)
             self._annotation = None
-        stack = getattr(_tls, "stack", None)
-        if stack and stack[-1][0] == self.name:
+        depth = len(stack) - 1
+        if stack[-1] is self:
             stack.pop()
+        else:                        # scopes closed out of order
+            depth = stack.index(self)
+            stack.remove(self)
+        self._stack = None
+        parent = self._parent
+        self._parent = None
         tags = getattr(_tls, "tags", None)
-        attrs = {**tags, **self.attrs} if tags else self.attrs
         _record(Span(self.kind, self.name, self._start, end,
-                     threading.get_ident(), self._parent, self._depth,
-                     attrs, next(_seq)))
-        self._live = False
+                     threading.get_ident(),
+                     parent.name if parent is not None else "", depth,
+                     {**tags, **self.attrs} if tags else self.attrs,
+                     next(_seq), self.id,
+                     parent.id if parent is not None else -1))
         return False
 
 
@@ -299,7 +321,7 @@ def record_span(kind: str, name: str, start: float, end: float,
     if tags:
         attrs = {**tags, **attrs}
     s = Span(kind, name, float(start), float(end),
-             threading.get_ident(), "", 0, attrs, next(_seq))
+             threading.get_ident(), "", 0, attrs, next(_seq), next(_ids))
     _record(s)
     return s
 
@@ -320,7 +342,7 @@ def record_counter(name: str, value: float, **attrs) -> Optional[Span]:
     attrs = ({**tags, "value": float(value), **attrs} if tags
              else {"value": float(value), **attrs})
     s = Span("memory", name, now, now, threading.get_ident(), "", 0,
-             attrs, next(_seq))
+             attrs, next(_seq), next(_ids))
     _record(s)
     return s
 
@@ -344,27 +366,70 @@ def spans(since: Optional[int] = None) -> List[Span]:
 
 
 def spans_since(mark_value: int) -> List[Span]:
-    return spans(since=mark_value)
+    """The WHOLE window since a mark(), or an error: when the ring has
+    wrapped past the mark the window's head is overwritten, and a median
+    over what is left reads like one over the window. `spans(since=...)`
+    is the lenient read (the profiler's report, which says so itself)."""
+    out = spans()
+    lost = out[-1].seq - len(_ring) - mark_value if out else 0
+    if lost > 0:
+        raise OutOfRangeError(
+            f"the span ring (PTPU_TRACE_RING={len(_ring)}) wrapped: the "
+            f"oldest {lost} records since mark {mark_value} are "
+            f"overwritten; raise PTPU_TRACE_RING or read shorter windows")
+    return [s for s in out if s.seq >= mark_value]
+
+
+def _self_ms(span_list: List[Span]) -> List[float]:
+    """For each span of the list, in its order: its duration minus the
+    union of its DIRECT children's intervals, in ms. Children are found by
+    `parent_id` and clipped to the parent; retroactive spans and counter
+    samples have no parent and are nobody's child."""
+    kids: Dict[int, list] = {}
+    for s in span_list:
+        if s.parent_id >= 0:
+            kids.setdefault(s.parent_id, []).append((s.start, s.end))
+    out = []
+    for s in span_list:
+        covered, reach = 0.0, s.start
+        for a, b in sorted(kids.get(s.id, ())):
+            a, b = max(a, reach), min(b, s.end)
+            if b > a:
+                covered += b - a
+                reach = b
+        out.append((s.end - s.start - covered) * 1e3)
+    return out
+
+
+def self_time_ms(span_list: List[Span], name: str) -> List[float]:
+    """For each span called `name`, in the list's order: the time it spent
+    under NO direct child — what a scope did itself, or what nobody named.
+    The one place the subtraction is written; metric readers call it."""
+    return [own for s, own in zip(span_list, _self_ms(span_list))
+            if s.name == name]
 
 
 def aggregate(span_list: Optional[List[Span]] = None,
               by: str = "name") -> Dict[str, Dict]:
-    """Per-span summary table: {key: {calls, total_ms, max_ms, min_ms,
-    avg_ms, kind}} — the profiler report and the benchmark span_ms rows
-    both read this. `by` is 'name' or 'kind'."""
+    """Per-span summary table: {key: {calls, total_ms, self_ms, max_ms,
+    min_ms, avg_ms, kind}} — the profiler report and the benchmark span_ms
+    rows both read this. `by` is 'name' or 'kind'; `self_ms` is the total
+    of `self_time_ms` over the row's spans."""
     enforce(by in ("name", "kind"), f"aggregate by {by!r}?",
             exc=InvalidArgumentError)
+    span_list = spans() if span_list is None else span_list
     rows: Dict[str, Dict] = {}
-    for s in (spans() if span_list is None else span_list):
+    for s, own in zip(span_list, _self_ms(span_list)):
         key = s.name if by == "name" else s.kind
         r = rows.get(key)
         d = s.duration_ms
         if r is None:
             rows[key] = {"kind": s.kind, "calls": 1, "total_ms": d,
-                         "max_ms": d, "min_ms": d}
+                         "self_ms": own, "max_ms": d, "min_ms": d}
         else:
             r["calls"] += 1
             r["total_ms"] += d
+            r["self_ms"] += own
             r["max_ms"] = max(r["max_ms"], d)
             r["min_ms"] = min(r["min_ms"], d)
     for r in rows.values():
@@ -393,7 +458,8 @@ def chrome_trace_events(span_list: Optional[List[Span]] = None,
             "name": s.name, "cat": s.kind, "ph": "X",
             "ts": s.start * 1e6, "dur": (s.end - s.start) * 1e6,
             "pid": pid, "tid": s.thread_id,
-            "args": {**s.attrs, "parent": s.parent, "depth": s.depth},
+            "args": {**s.attrs, "parent": s.parent, "depth": s.depth,
+                     "id": s.id, "parent_id": s.parent_id},
         })
     return evs
 

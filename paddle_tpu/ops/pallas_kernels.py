@@ -310,19 +310,22 @@ def _flash_attention_pallas(q, k, v, scale, causal, block_q, block_k,
         lse_ref = outs[1] if with_lse else None
         _k(ins[0], ins[1], ins[2], qs_ref, ks_ref, outs[0], lse_ref,
            *scratch)
-    res = pl.pallas_call(
-        body,
-        grid=(B * H, nq, nk),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=[
-            pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, D), jnp.float32),
-        ],
-        interpret=interpret,
-    )(*inputs)
+    # the scope is the kernel's stable name in a device trace: XLA names the
+    # custom call after it (`jvp_flash_fwd_...`), whatever wraps the call
+    with jax.named_scope("flash_fwd"):
+        res = pl.pallas_call(
+            body,
+            grid=(B * H, nq, nk),
+            in_specs=in_specs,
+            out_specs=out_specs,
+            out_shape=out_shape,
+            scratch_shapes=[
+                pltpu.VMEM((bq, 1), jnp.float32),
+                pltpu.VMEM((bq, 1), jnp.float32),
+                pltpu.VMEM((bq, D), jnp.float32),
+            ],
+            interpret=interpret,
+        )(*inputs)
     out = res[0][:, :T].reshape(B, H, T, D)
     if with_lse:
         return out, res[1][:, :T, 0].reshape(B, H, T)
@@ -501,15 +504,16 @@ def _flash_attention_bwd_pallas(q, k, v, o, lse, do, scale, causal,
             q_ids, kv_ids, H, Tp, Tkp, bq, bk)
         dq_inputs += list(seg_inputs)
         dq_specs += list(seg_specs)
-    dq = pl.pallas_call(
-        _splice_seg(dq_kernel, 6),
-        grid=(B * H, nq, nk),
-        in_specs=dq_specs,
-        out_specs=q_spec,
-        out_shape=_out_struct((B * H, Tp, D), q.dtype, q, k, v, do),
-        scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
-        interpret=interpret,
-    )(*dq_inputs)
+    with jax.named_scope("flash_bwd_dq"):
+        dq = pl.pallas_call(
+            _splice_seg(dq_kernel, 6),
+            grid=(B * H, nq, nk),
+            in_specs=dq_specs,
+            out_specs=q_spec,
+            out_shape=_out_struct((B * H, Tp, D), q.dtype, q, k, v, do),
+            scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
+            interpret=interpret,
+        )(*dq_inputs)
 
     # dk/dv: k blocks are the outer (revisited) dim, q blocks stream inner
     qi_spec = pl.BlockSpec((1, bq, D), lambda b, j, i: (b, i, 0))
@@ -528,17 +532,18 @@ def _flash_attention_bwd_pallas(q, k, v, o, lse, do, scale, causal,
             pl.BlockSpec((1, bq, 128), lambda b, j, i, H=H: (b // H, i, 0)),
             pl.BlockSpec((1, 8, bk), lambda b, j, i, H=H: (b // H, 0, j)),
         ]
-    dk, dv = pl.pallas_call(
-        _splice_seg(dkv_kernel, 6),
-        grid=(B * H, nk, nq),
-        in_specs=dkv_specs,
-        out_specs=[kj_spec, kj_spec],
-        out_shape=[_out_struct((B * H, Tkp, D), k.dtype, q, k, v, do),
-                   _out_struct((B * H, Tkp, D), v.dtype, q, k, v, do)],
-        scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
-                        pltpu.VMEM((bk, D), jnp.float32)],
-        interpret=interpret,
-    )(*dkv_inputs)
+    with jax.named_scope("flash_bwd_dkv"):
+        dk, dv = pl.pallas_call(
+            _splice_seg(dkv_kernel, 6),
+            grid=(B * H, nk, nq),
+            in_specs=dkv_specs,
+            out_specs=[kj_spec, kj_spec],
+            out_shape=[_out_struct((B * H, Tkp, D), k.dtype, q, k, v, do),
+                       _out_struct((B * H, Tkp, D), v.dtype, q, k, v, do)],
+            scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
+                            pltpu.VMEM((bk, D), jnp.float32)],
+            interpret=interpret,
+        )(*dkv_inputs)
 
     return (dq[:, :T].reshape(B, H, T, D),
             dk[:, :Tk].reshape(B, H, Tk, D),

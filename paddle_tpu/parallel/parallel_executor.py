@@ -967,57 +967,65 @@ class ParallelExecutor(Executor):
             return_numpy: bool = True):
         """≙ ParallelExecutor.run (reference parallel_executor.py:168).
         Argument order follows the reference (fetch_list first)."""
+        from ..observability import tracing as _tracing
         program = program or self.main_program or default_main_program()
         scope = scope or self.scope
-        # provisional feed shapes BEFORE the rewrite: the memory planner's
-        # nominal batch reads them (padded shapes re-stash below)
-        if feed:
+        with _tracing.span("collective", "parallel/prepare"):
+            # provisional feed shapes BEFORE the rewrite: the memory
+            # planner's nominal batch reads them (padded shapes re-stash
+            # below)
+            if feed:
+                self._feed_shapes = {n: np.shape(v) for n, v in feed.items()}
+            # see run_steps: placement below must read the REWRITTEN program
+            program = self._prepare_program(program, scope)
+            # ZeRO-offload: the accumulator shards live on the host between
+            # steps — h2d them back BEFORE placement/dispatch, d2h them out
+            # after the fetches return (the d2h overlaps whatever the host
+            # does next; costs.predict's `offload` section prices whether
+            # the round-trip hides behind the step)
+            host_opt = self._host_optimizer_state(program, scope)
+            if host_opt is not None:
+                host_opt.restore()
+            feed, real_b, padded_b = self._pad_for_dp(program,
+                                                      dict(feed or {}))
+            padded = real_b is not None and padded_b != real_b
+            # synthesize the batch-row mask BEFORE multi-process placement:
+            # the base Executor would otherwise inject a host numpy array
+            # after the _place loop, which jit cannot auto-place onto a
+            # non-addressable global sharding
+            feed = self._synthesize_batch_mask(program, feed)
+            # stash shapes so _compile can build feed shardings without
+            # re-plumbing the Executor.run signature.
             self._feed_shapes = {n: np.shape(v) for n, v in feed.items()}
-        # see run_steps: placement below must read the REWRITTEN program
-        program = self._prepare_program(program, scope)
-        # ZeRO-offload: the accumulator shards live on the host between
-        # steps — h2d them back BEFORE placement/dispatch, d2h them out
-        # after the fetches return (the d2h overlaps whatever the host
-        # does next; costs.predict's `offload` section prices whether
-        # the round-trip hides behind the step)
-        host_opt = self._host_optimizer_state(program, scope)
-        if host_opt is not None:
-            host_opt.restore()
-        feed, real_b, padded_b = self._pad_for_dp(program, dict(feed or {}))
-        # synthesize the batch-row mask BEFORE multi-process placement: the
-        # base Executor would otherwise inject a host numpy array after the
-        # _place loop, which jit cannot auto-place onto a non-addressable
-        # global sharding
-        feed = self._synthesize_batch_mask(program, feed)
-        # stash shapes so _compile can build feed shardings without
-        # re-plumbing the Executor.run signature.
-        self._feed_shapes = {n: np.shape(v) for n, v in feed.items()}
-        if self._spans_processes():
-            self._globalize_state(program, scope)
-            # feeds carry the GLOBAL batch (identical on every process —
-            # the reference's nccl2-mode trainers likewise each construct
-            # their portion deterministically); device_put materializes
-            # each process's addressable shards of the dp split. Values
-            # that are ALREADY global jax arrays (e.g. built with
-            # make_array_from_process_local_data for per-process-distinct
-            # data) pass through untouched.
-            def _place(n, v):
-                sh = getattr(v, "sharding", None)
-                if sh is not None and not sh.is_fully_addressable:
-                    return v
-                return jax.device_put(
-                    np.asarray(v),
-                    self._feed_sharding(program, n, np.shape(v)))
-            feed = {n: _place(n, v) for n, v in feed.items()}
+            if self._spans_processes():
+                self._globalize_state(program, scope)
+                # feeds carry the GLOBAL batch (identical on every process —
+                # the reference's nccl2-mode trainers likewise each
+                # construct their portion deterministically); device_put
+                # materializes each process's addressable shards of the dp
+                # split. Values that are ALREADY global jax arrays (e.g.
+                # built with make_array_from_process_local_data for
+                # per-process-distinct data) pass through untouched.
+                def _place(n, v):
+                    sh = getattr(v, "sharding", None)
+                    if sh is not None and not sh.is_fully_addressable:
+                        return v
+                    return jax.device_put(
+                        np.asarray(v),
+                        self._feed_sharding(program, n, np.shape(v)))
+                feed = {n: _place(n, v) for n, v in feed.items()}
         fetches = super().run(program=program, feed=feed,
                               fetch_list=fetch_list, scope=scope,
                               return_numpy=return_numpy)
-        if host_opt is not None:
-            host_opt.offload()
-        if real_b is not None and padded_b != real_b:
-            fetches = self._slice_padded_fetches(
-                fetches, self._batch_led_fetches(program, fetch_list),
-                real_b)
+        if host_opt is None and not padded:
+            return fetches
+        with _tracing.span("collective", "parallel/finish"):
+            if host_opt is not None:
+                host_opt.offload()
+            if padded:
+                fetches = self._slice_padded_fetches(
+                    fetches, self._batch_led_fetches(program, fetch_list),
+                    real_b)
         return fetches
 
     def cost_report(self, program: Optional[Program] = None,

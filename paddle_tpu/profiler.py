@@ -142,7 +142,7 @@ def stop_profiler(sorted_key: Optional[str] = None,
 
 
 def _window_spans():
-    spans = _tracing.spans_since(_window_mark)
+    spans = _tracing.spans(since=_window_mark)
     # the recorder is a bounded ring (PTPU_TRACE_RING, default 65536);
     # a window longer than that has lost its oldest events — say so
     # instead of printing a silently-truncated report (the pre-r12

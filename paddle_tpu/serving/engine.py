@@ -90,6 +90,16 @@ class SlotAllocator:
         return len(self._used)
 
 
+#: Edges of `ptpu_engine_tick_latency_seconds`. A decode tick of a model
+#: worth serving takes 10-100 ms, and the p50/p95/p99 gauges interpolate
+#: inside the winning bucket: so that decade has an edge every ~10%
+#: (10 ms x 10^(k/24)), the decades around it the usual 1-2.5-5.
+TICK_LATENCY_BUCKETS = (
+    1e-4, 2.5e-4, 5e-4, 1e-3, 2.5e-3, 5e-3,
+    *(round(1e-2 * 10 ** (k / 24), 5) for k in range(25)),
+    0.25, 0.5, 1.0, 2.5)
+
+
 class GenRequest:
     """One generation request moving through the engine.
 
@@ -439,8 +449,7 @@ class ContinuousBatchingEngine:
         self._m_tick_latency = r.histogram(
             "ptpu_engine_tick_latency_seconds",
             "Wall latency of one decode tick.",
-            buckets=(1e-4, 2.5e-4, 5e-4, 1e-3, 2.5e-3, 5e-3, 1e-2,
-                     2.5e-2, 5e-2, 0.1, 0.25, 0.5, 1.0, 2.5))
+            buckets=TICK_LATENCY_BUCKETS)
         self._m_dispatch = r.histogram(
             "ptpu_engine_dispatch_seconds",
             "Host-side dispatch share of one decode tick: feed fill + "
@@ -619,10 +628,16 @@ class ContinuousBatchingEngine:
         through the pager. Returns the number of blocks rolled back."""
         return 0
 
+    def _admit_pool_attrs(self) -> Dict[str, int]:
+        """What `engine/admit` says of the resource admission waits on,
+        read after the admissions: the paged engine's pool level. The
+        slot engine's only resource is the slot."""
+        return {}
+
     def _admit(self):
         admitted = []
         with _tracing.span("admission", "engine/admit",
-                           pending=len(self._pending)), self._lock:
+                           pending=len(self._pending)) as sp, self._lock:
             if self.policy == "static" and (self._active
                                             or not self._pending):
                 return
@@ -642,6 +657,16 @@ class ContinuousBatchingEngine:
                 req.admitted_pc = time.perf_counter()
                 self._active[slot] = req
                 admitted.append(req)
+            if _tracing.enabled():
+                # counted here, where admission happens: the prompt tokens
+                # taken on, and how many of them the prefix cache already
+                # held (trace provenance only: not summed when tracing is
+                # off)
+                sp.attrs.update(
+                    admitted=len(admitted),
+                    prompt_tokens=sum(len(r.prompt) for r in admitted),
+                    shared_tokens=sum(r.shared_len for r in admitted),
+                    **self._admit_pool_attrs())
         for req in admitted:
             # the queue-wait phase becomes a first-class span the moment
             # it ends (slot assignment) — retroactive, exact boundaries
@@ -705,30 +730,32 @@ class ContinuousBatchingEngine:
                 self._spec_capable(r, self.spec.cfg.gamma + 1)
                 for r in active.values()):
             finished = self.spec.round(active)
-            self._m_ticks.inc()
-            self.n_ticks += 1
-            self.last_tick_at = time.time()
-            self._stamp_kv_watermarks(active)
-            self.busy_slot_ticks += len(active)
-            self.total_slot_ticks += self.n_slots
+            with _tracing.span("tick", "engine/commit"):
+                self._m_ticks.inc()
+                self.n_ticks += 1
+                self.last_tick_at = time.time()
+                self._stamp_kv_watermarks(active)
+                self.busy_slot_ticks += len(active)
+                self.total_slot_ticks += self.n_slots
         else:
             finished = self._plain_tick(active)
         if finished:
-            # complete (firing on_done -> writer.offer) BEFORE dropping
-            # the request from _active: a drain poll reading
-            # n_active==0 must imply every completion frame is already
-            # in its writer queue, or the drain could close the writer
-            # ahead of the final frame and silently drop it
-            for req in finished:
-                req._complete()
-            with self._lock:
+            with _tracing.span("request", "engine/finish"):
+                # complete (firing on_done -> writer.offer) BEFORE dropping
+                # the request from _active: a drain poll reading
+                # n_active==0 must imply every completion frame is already
+                # in its writer queue, or the drain could close the writer
+                # ahead of the final frame and silently drop it
                 for req in finished:
-                    del self._active[req.slot]
-                    self._slots.free(req.slot)
-                    self._release_request(req)
-            self._m_completed.inc(len(finished))
-            for req in finished:
-                self._finalize_request(req)
+                    req._complete()
+                with self._lock:
+                    for req in finished:
+                        del self._active[req.slot]
+                        self._slots.free(req.slot)
+                        self._release_request(req)
+                self._m_completed.inc(len(finished))
+                for req in finished:
+                    self._finalize_request(req)
         return finished
 
     def _pre_tick(self, active: Dict[int, "GenRequest"]
@@ -738,53 +765,64 @@ class ContinuousBatchingEngine:
         `host_tier=`) resumes/suspends requests here — swapping KV
         blocks against the host tier between ticks — and returns the
         RESIDENT subset that actually ticks. Default: everything
-        admitted is resident."""
+        admitted is resident. An override that does work opens
+        `engine/pre_tick` around it."""
         return active
 
     def _plain_tick(self, active: Dict[int, "GenRequest"]
                     ) -> List[GenRequest]:
+        span = _tracing.span
         t0 = time.perf_counter()
-        active = self._pre_tick(active)
-        # the rid list is trace provenance only — don't build it per
-        # tick when tracing is off (the decode loop is the hot path)
-        span_attrs = {"active": len(active)}
-        if _tracing.enabled():
-            span_attrs["request_ids"] = [r.request_id
-                                         for r in active.values()]
-        with _tracing.span("tick", "engine/tick", **span_attrs):
-            self._fill_tick_feeds(active)
-            self._note_tick_writes(active)
-            if self._target_state_owner != "main":
-                # a speculative verify forward ran since the last plain
-                # tick and owns the donated target-cache buffers —
-                # re-point the bound step at the live arrays
-                self._step.refresh_state()
-                self._target_state_owner = "main"
-            fetches = self._step.run_bound()   # zero-dispatch bound tick
-            self.target_forwards += 1
-            td = time.perf_counter()           # async dispatch returned
-            ids = np.asarray(fetches[0])   # realization barrier: the next
-            #                                tick's feed depends on it
-        self._m_dispatch.observe(td - t0)
-        if _tracing.enabled():
-            # the host-dispatch share of the tick as a named phase
-            _tracing.record_span("dispatch", "engine/dispatch", t0, td,
-                                 active=len(active))
-        self._m_tick_latency.observe(time.perf_counter() - t0)
-        self._m_ticks.inc()
-        self.n_ticks += 1
-        self.last_tick_at = time.time()
-        # re-stamp the kv watermarks so the live `current` reflects the
-        # ENGINE that is actually ticking: reserved from the pinned
-        # construction-time census, used from the positions live
-        # requests occupy this tick (O(active))
-        self._stamp_kv_watermarks(active)
-        self.busy_slot_ticks += len(active)
-        self.total_slot_ticks += self.n_slots
-        finished = []
-        for slot, req in active.items():
-            if self._advance_slot(req, int(ids[slot, 0])):
-                finished.append(req)
+        with span("tick", "engine/tick") as tick:
+            with span("dispatch", "engine/dispatch") as dispatch:
+                active = self._pre_tick(active)
+                with span("dispatch", "engine/fill_feeds"):
+                    self._fill_tick_feeds(active)
+                    self._note_tick_writes(active)
+                with span("dispatch", "engine/launch"):
+                    if self._target_state_owner != "main":
+                        # a speculative verify forward ran since the last
+                        # plain tick and owns the donated target-cache
+                        # buffers — re-point the bound step at the live
+                        # arrays
+                        self._step.refresh_state()
+                        self._target_state_owner = "main"
+                    fetches = self._step.run_bound()   # zero-dispatch tick
+                    self.target_forwards += 1
+                dispatch.attrs["active"] = len(active)
+                td = time.perf_counter()       # async dispatch returned
+            if _tracing.enabled():
+                # counted here, in the scheduler, while the device runs: a
+                # slot PREFILLS on this tick when the position it fed is a
+                # prompt token whose output is dropped; the others decode.
+                # Trace provenance only, like the rid list: neither is
+                # built when tracing is off (the decode loop is the hot
+                # path)
+                tick.attrs.update(
+                    active=len(active),
+                    prefill=sum(1 for r in active.values()
+                                if r.fed < len(r.prompt) - 1),
+                    request_ids=[r.request_id for r in active.values()])
+            with span("tick", "engine/wait"):
+                ids = np.asarray(fetches[0])   # realization barrier: the
+                #                    next tick's feed depends on it
+        with span("tick", "engine/commit"):
+            self._m_dispatch.observe(td - t0)
+            self._m_tick_latency.observe(time.perf_counter() - t0)
+            self._m_ticks.inc()
+            self.n_ticks += 1
+            self.last_tick_at = time.time()
+            # re-stamp the kv watermarks so the live `current` reflects the
+            # ENGINE that is actually ticking: reserved from the pinned
+            # construction-time census, used from the positions live
+            # requests occupy this tick (O(active))
+            self._stamp_kv_watermarks(active)
+            self.busy_slot_ticks += len(active)
+            self.total_slot_ticks += self.n_slots
+            finished = []
+            for slot, req in active.items():
+                if self._advance_slot(req, int(ids[slot, 0])):
+                    finished.append(req)
         return finished
 
     def _finalize_request(self, req: GenRequest):

@@ -94,6 +94,7 @@ from ..core.enforce import InvalidArgumentError, enforce
 from ..framework import offload as _offload
 from ..framework.offload import HostTierConfig
 from ..observability import memory as _obs_memory
+from ..observability import tracing as _tracing
 from .engine import ContinuousBatchingEngine, GenRequest, _ENGINE_SEQ
 
 
@@ -842,6 +843,10 @@ class PagedKVEngine(ContinuousBatchingEngine):
         self._ht_queue.append(req)
         return True
 
+    def _admit_pool_attrs(self) -> Dict[str, int]:
+        return {"pool_used": self.pager.pool.n_used,
+                "pool_blocks": self.n_blocks}
+
     def _release_request(self, req: GenRequest):
         if req.table is not None:
             self.pager.release(req.table)
@@ -886,6 +891,11 @@ class PagedKVEngine(ContinuousBatchingEngine):
         their slots but do not tick."""
         if self.host_tier is None:
             return active
+        with _tracing.span("dispatch", "engine/pre_tick"):
+            return self._swap_schedule(active)
+
+    def _swap_schedule(self, active: Dict[int, GenRequest]
+                       ) -> Dict[int, GenRequest]:
         tick = self.n_ticks
         while self._ht_queue and self._try_resume(self._ht_queue[0]):
             self._ht_queue.pop(0)

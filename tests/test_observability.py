@@ -56,6 +56,12 @@ class TestTracing:
         # record order: inner completes before outer
         assert by_name["inner"].seq < by_name["outer"].seq
 
+    def test_trace_kind_is_gone(self):
+        # nothing opened it (ISSUE 24); a closed set names only what is used
+        assert "trace" not in tracing.SPAN_KINDS
+        with pytest.raises(Exception, match="unknown span kind"):
+            tracing.span("trace", "x")
+
     def test_unknown_kind_rejected(self):
         with pytest.raises(Exception, match="unknown span kind"):
             tracing.span("not_a_kind", "x")
@@ -364,6 +370,443 @@ class TestOverheadBudget:
         frac_off = off_cost * spans_per_step / step_s
         assert frac_off <= 0.005, (frac_off, off_cost, spans_per_step,
                                    step_s)
+
+
+# ---------------------------------------------------------------------------
+# host-phase spans: every millisecond of a step and a tick under a live span
+# ---------------------------------------------------------------------------
+
+
+class _Annotation:
+    """Stands in for jax.profiler.TraceAnnotation as `annotation_factory`:
+    logs its open and its close into the list `_Annotation.log`."""
+
+    __slots__ = ("name",)
+    log = []
+
+    def __init__(self, name):
+        self.name = name
+
+    def __enter__(self):
+        self.log.append(("open", self.name))
+
+    def __exit__(self, *exc):
+        self.log.append(("close", self.name))
+
+
+def _recorded(fn):
+    """Run fn() under a caller's `user` span with annotations logged;
+    returns (the spans recorded, the annotation events in order)."""
+    _Annotation.log = events = []
+    m = tracing.mark()
+    tracing.annotation_factory = _Annotation
+    try:
+        with tracing.span("user", "caller"):
+            fn()
+    finally:
+        tracing.annotation_factory = None
+    return tracing.spans_since(m), events
+
+
+def _fresh_programs():
+    pt.reset_default_programs()
+    pt.reset_global_scope()
+    tracing.clear()
+
+
+EXECUTOR_SPANS = ("executor/lookup", "executor/feed", "executor/run",
+                  "executor/state_writeback", "executor/post",
+                  "executor/fetch")
+PARALLEL_SPANS = ("parallel/prepare",) + EXECUTOR_SPANS + ("parallel/finish",)
+TICK_SPANS = ("engine/admit", "engine/tick", "engine/dispatch",
+              "engine/fill_feeds", "engine/launch", "engine/wait",
+              "engine/commit", "engine/finish")
+
+
+@pytest.fixture(scope="module")
+def executor_step():
+    """One warm Executor.run with a fetch, of a step of >= 5 ms, under a
+    caller's span."""
+    from paddle_tpu.core import unique_name
+    _fresh_programs()
+    with unique_name.guard():
+        x = layers.data(name="x", shape=[1024], dtype="float32")
+        h = x
+        for _ in range(4):
+            h = layers.fc(h, size=1024, act="relu")
+        loss = layers.mean(h)
+        pt.optimizer.SGDOptimizer(0.01).minimize(loss)
+        exe = pt.Executor()
+        exe.run(pt.default_startup_program())
+        feed = {"x": np.ones((512, 1024), "float32")}
+        exe.run(feed=feed, fetch_list=[loss])          # compile
+        exe.run(feed=feed, fetch_list=[loss])
+        return _recorded(lambda: exe.run(feed=feed, fetch_list=[loss]))
+
+
+@pytest.fixture(scope="module")
+def parallel_step():
+    """One warm ParallelExecutor.run of a 5-row batch over 8 devices: the
+    batch is padded, so `parallel/finish` has rows to strip."""
+    from paddle_tpu.core import unique_name
+    from paddle_tpu.parallel import ParallelExecutor
+    _fresh_programs()
+    with unique_name.guard():
+        img = layers.data(name="img", shape=[16], dtype="float32")
+        mask = layers.reshape(layers.batch_row_mask(), shape=[-1, 1])
+        logits = layers.fc(img, size=10)
+        loss = (layers.reduce_sum(layers.reduce_sum(logits, dim=1,
+                                                    keep_dim=True) * mask)
+                / layers.reduce_sum(mask))
+        pt.Executor().run(pt.default_startup_program())
+        pe = ParallelExecutor(loss_name=loss.name)
+        feed = {"img": np.ones((5, 16), "float32")}
+        pe.run(fetch_list=[logits], feed=feed)         # compile
+        out = []
+        spans, events = _recorded(lambda: out.extend(
+            pe.run(fetch_list=[logits], feed=feed)))
+        assert np.asarray(out[0]).shape == (5, 10)
+        return spans, events
+
+
+@pytest.fixture(scope="module")
+def engine_life():
+    """A small PagedKVEngine serving three requests to the end, the second
+    and third sharing the first's two full prompt blocks; one caller span
+    and one annotation log around every engine.step()."""
+    from paddle_tpu.core import unique_name
+    from paddle_tpu.serving.kv_pager import PagedKVEngine
+    _fresh_programs()
+    with unique_name.guard():
+        eng = PagedKVEngine(n_slots=2, max_len=24, block_size=4,
+                            n_blocks=24, vocab=50, d_model=32, d_inner=64,
+                            num_heads=4, num_layers=2)
+        head = [7, 8, 9, 10, 11, 12, 13, 14]
+        reqs = [eng.submit(head + [3, 4], max_new=3)]
+        steps = []
+        for _ in range(8):       # the first fills both blocks of `head`
+            steps.append(_recorded(eng.step))
+        reqs.append(eng.submit(head + [5], max_new=2))
+        reqs.append(eng.submit(head + [6, 7, 8], max_new=4))
+        while eng.n_active or eng.n_pending:
+            steps.append(_recorded(eng.step))
+        assert all(r.done and r.error is None for r in reqs)
+        return reqs, steps
+
+
+class TestHostPhaseSpans:
+    """ISSUE 24: between the entry of Executor.run / ParallelExecutor.run /
+    engine.step() and its return the host's time is under a live span of
+    the program, and the counts are attrs of the span where the work
+    happens."""
+
+    @staticmethod
+    def _one(spans, name):
+        got = [s for s in spans if s.name == name]
+        assert len(got) == 1, (name, [s.name for s in spans])
+        return got[0]
+
+    @pytest.mark.parametrize("name", EXECUTOR_SPANS)
+    def test_executor_span_nests_under_the_caller(self, executor_step, name):
+        spans, _ = executor_step
+        caller = self._one(spans, "caller")
+        s = self._one(spans, name)
+        assert s.parent_id == caller.id and s.parent == "caller"
+        assert s.depth == 1 and s.id > caller.id
+        assert caller.start <= s.start <= s.end <= caller.end
+
+    def test_executor_counts_and_caller_self_time(self, executor_step):
+        spans, _ = executor_step
+        caller = self._one(spans, "caller")
+        assert caller.duration_ms >= 5.0, "the step is too short to judge"
+        own, = tracing.self_time_ms(spans, "caller")
+        assert 0.0 <= own < 0.1 * caller.duration_ms, (own,
+                                                       caller.duration_ms)
+        # only what a metric or an operator reads is counted: the feed's
+        # `n_feeds` was there, the new spans carry no attrs
+        assert self._one(spans, "executor/feed").attrs == {"n_feeds": 1}
+        for name in ("executor/lookup", "executor/post", "executor/fetch"):
+            assert self._one(spans, name).attrs == {}
+        rows = tracing.aggregate(spans)
+        assert rows["caller"]["self_ms"] == pytest.approx(own)
+        assert rows["executor/run"]["self_ms"] == pytest.approx(
+            rows["executor/run"]["total_ms"])
+
+    def test_lookup_miss_holds_the_compile(self):
+        x = layers.data(name="x", shape=[4], dtype="float32")
+        loss = layers.mean(layers.fc(x, size=2))
+        exe = pt.Executor()
+        exe.run(pt.default_startup_program())
+        m = tracing.mark()
+        exe.run(feed={"x": np.ones((2, 4), "float32")}, fetch_list=[loss],
+                return_numpy=False)
+        spans = tracing.spans_since(m)
+        lookup = self._one(spans, "executor/lookup")
+        assert self._one(spans,
+                         "executor/trace_and_compile").parent_id == lookup.id
+        # no numpy asked for: no fetch span, nothing waited for
+        assert not [s for s in spans if s.name == "executor/fetch"]
+
+    @pytest.mark.parametrize("name", PARALLEL_SPANS)
+    def test_parallel_span_nests_under_the_caller(self, parallel_step, name):
+        spans, _ = parallel_step
+        s = self._one(spans, name)
+        assert s.parent_id == self._one(spans, "caller").id
+        if name == "parallel/prepare":
+            assert s.end <= self._one(spans, "executor/lookup").start
+        if name == "parallel/finish":
+            assert s.start >= self._one(spans, "executor/fetch").end
+
+    @pytest.mark.parametrize("name", TICK_SPANS)
+    def test_engine_span_is_live_and_nested(self, engine_life, name):
+        parent_of = {"engine/admit": "caller", "engine/tick": "caller",
+                     "engine/commit": "caller", "engine/finish": "caller",
+                     "engine/dispatch": "engine/tick",
+                     "engine/wait": "engine/tick",
+                     "engine/fill_feeds": "engine/dispatch",
+                     "engine/launch": "engine/dispatch"}
+        _, steps = engine_life
+        seen = 0
+        for spans, _ in steps:
+            by_id = {s.id: s for s in spans}
+            for s in (s for s in spans if s.name == name):
+                seen += 1
+                assert by_id[s.parent_id].name == parent_of[name]
+        # every step admits, ticks and commits; only three finish
+        assert seen == (3 if name == "engine/finish" else len(steps))
+
+    def test_engine_step_leaves_the_caller_no_time_of_its_own(self,
+                                                              engine_life):
+        _, steps = engine_life
+        shares = []
+        for spans, _ in steps:
+            caller = self._one(spans, "caller")
+            own, = tracing.self_time_ms(spans, "caller")
+            shares.append(own / caller.duration_ms)
+            tick = self._one(spans, "engine/tick")
+            d = self._one(spans, "engine/dispatch")
+            kids = [s for s in spans if s.parent_id == d.id]
+            assert [s.name for s in kids] == ["engine/fill_feeds",
+                                              "engine/launch"]
+            assert tick.start <= d.start and d.end <= \
+                self._one(spans, "engine/wait").start
+        assert float(np.median(shares)) < 0.1, shares
+
+    def test_tick_counts_prefill_where_it_happens(self, engine_life):
+        reqs, steps = engine_life
+        prefill = {r.request_id: 0 for r in reqs}
+        for spans, _ in steps:
+            tick = self._one(spans, "engine/tick").attrs
+            assert 0 <= tick["prefill"] <= tick["active"] \
+                == len(tick["request_ids"])
+            assert self._one(spans,
+                             "engine/dispatch").attrs["active"] == \
+                tick["active"]
+        # a request's prefill ticks, counted from the ticks' own attrs: it
+        # rides `prefill` of a tick while the tick's count says so
+        for r in reqs:
+            fed = r.shared_len
+            for spans, _ in steps:
+                tick = self._one(spans, "engine/tick")
+                if r.request_id not in tick.attrs["request_ids"]:
+                    continue
+                if fed < len(r.prompt) - 1:
+                    prefill[r.request_id] += 1
+                fed += 1
+            assert prefill[r.request_id] == \
+                len(r.prompt) - 1 - r.shared_len
+        assert sum(self._one(spans, "engine/tick").attrs["prefill"]
+                   for spans, _ in steps) == sum(prefill.values())
+        assert [r.shared_len for r in reqs] == [0, 8, 8]
+
+    def test_admit_counts_what_it_admitted(self, engine_life):
+        reqs, steps = engine_life
+        admits = [self._one(spans, "engine/admit").attrs
+                  for spans, _ in steps]
+        assert sum(a["admitted"] for a in admits) == len(reqs)
+        assert sum(a["prompt_tokens"] for a in admits) == \
+            sum(len(r.prompt) for r in reqs)
+        assert sum(a["shared_tokens"] for a in admits) == \
+            sum(r.shared_len for r in reqs) == 16
+        # the step that admitted the second request found the prefix cached
+        second = next(a for a in admits[8:] if a["admitted"])
+        assert second["shared_tokens"] >= 8
+        assert all(0 <= a["pool_used"] <= a["pool_blocks"] == 24
+                   for a in admits)
+
+    def test_slot_engine_admit_has_no_pool(self):
+        from paddle_tpu.serving_engine import ContinuousBatchingEngine
+        eng = ContinuousBatchingEngine(n_slots=2, vocab=50, max_len=8,
+                                       d_model=16, d_inner=32, num_heads=2,
+                                       num_layers=1)
+        eng.submit([1, 2], max_new=2)
+        m = tracing.mark()
+        eng.step()
+        attrs = self._one(tracing.spans_since(m), "engine/admit").attrs
+        assert attrs == {"pending": 1, "admitted": 1, "prompt_tokens": 2,
+                         "shared_tokens": 0}
+        # the base hook does nothing: no span is opened for it
+        assert not [s for s in tracing.spans_since(m)
+                    if s.name == "engine/pre_tick"]
+
+    @pytest.mark.parametrize("who,names", [
+        ("executor_step", EXECUTOR_SPANS),
+        ("parallel_step", PARALLEL_SPANS),
+        ("engine_life", TICK_SPANS)])
+    def test_every_span_is_one_annotation_in_order(self, request, who,
+                                                   names):
+        got = request.getfixturevalue(who)
+        runs = got[1] if who == "engine_life" else [got]
+        for spans, events in runs:
+            # the log replays as a stack: each span opened and closed one
+            # annotation, properly nested, in the order the spans ran
+            stack, closed = [], []
+            for what, name in events:
+                if what == "open":
+                    stack.append(name)
+                else:
+                    assert stack.pop() == name
+                    closed.append(name)
+            assert not stack
+            live = [s.name for s in sorted(spans, key=lambda s: s.end)
+                    if s.kind != "memory"
+                    and not s.name.startswith("request/")]
+            assert closed == live
+            assert set(closed) - {"caller", "executor/trace_and_compile"} \
+                <= set(names) | {"engine/pre_tick"}
+            assert set(names) - {"engine/finish"} <= set(closed)
+
+    def test_self_time_of_a_hand_built_tree(self):
+        def sp(name, start, end, id, parent_id=-1):
+            return tracing.Span("user", name, start, end, 0, "", 0, {}, id,
+                                id, parent_id)
+        spans = [
+            sp("outer", 0.0, 10.0, 1),
+            sp("a", 1.0, 3.0, 2, 1),          # sequential
+            sp("b", 3.0, 4.0, 3, 1),
+            sp("c", 5.0, 8.0, 4, 1),          # overlaps d
+            sp("d", 7.0, 9.0, 5, 1),
+            sp("grandchild", 1.5, 2.5, 6, 2),  # not outer's direct child
+            sp("late", 9.5, 12.0, 7, 1),      # clipped to its parent
+            sp("outer", 20.0, 21.0, 8),       # no children: all its own
+            sp("retro", 0.0, 10.0, 9),        # retroactive: nobody's child
+        ]
+        assert tracing.self_time_ms(spans, "outer") == pytest.approx(
+            [(10 - 2 - 1 - 4 - 0.5) * 1e3, 1e3])
+        assert tracing.self_time_ms(spans, "a") == pytest.approx([1e3])
+        assert tracing.self_time_ms(spans, "nothing") == []
+        rows = tracing.aggregate(spans)
+        assert rows["outer"]["self_ms"] == pytest.approx(3.5e3)
+        assert rows["outer"]["total_ms"] == pytest.approx(11e3)
+
+    def test_ids_in_dict_and_chrome_export(self):
+        with tracing.span("user", "outer") as outer:
+            with tracing.span("user", "inner"):
+                pass
+        tracing.record_span("request", "retro", 0.0, 1.0)
+        by_name = {s.name: s for s in tracing.spans()}
+        assert by_name["outer"].id == outer.id
+        assert by_name["outer"].parent_id == -1
+        assert by_name["inner"].parent_id == outer.id
+        assert by_name["retro"].parent_id == -1
+        assert len({s.id for s in by_name.values()}) == 3
+        d = by_name["inner"].to_dict()
+        assert (d["id"], d["parent_id"]) == (by_name["inner"].id, outer.id)
+        ev = {e["name"]: e for e in tracing.chrome_trace_events()}
+        assert ev["inner"]["args"]["parent_id"] == outer.id
+        assert ev["inner"]["args"]["id"] == by_name["inner"].id
+
+    @pytest.mark.parametrize("n_spans,wrapped", [(8, False), (9, True),
+                                                 (20, True)])
+    def test_spans_since_refuses_a_wrapped_window(self, n_spans, wrapped):
+        from paddle_tpu.core.enforce import OutOfRangeError
+        old = flags.get_flag("trace_ring")
+        flags.set_flag("trace_ring", 8)
+        tracing.clear()
+        try:
+            m = tracing.mark()
+            for i in range(n_spans):
+                with tracing.span("user", f"s{i}"):
+                    pass
+            if wrapped:
+                # the head of the window is overwritten: no partial list
+                # for a median to be taken of
+                with pytest.raises(OutOfRangeError, match="PTPU_TRACE_RING"):
+                    tracing.spans_since(m)
+                assert len(tracing.spans(since=m)) == 8    # the lenient read
+            else:
+                assert [s.name for s in tracing.spans_since(m)] == \
+                    [f"s{i}" for i in range(n_spans)]
+            # a window that starts now is whole again
+            m = tracing.mark()
+            with tracing.span("user", "after"):
+                pass
+            assert [s.name for s in tracing.spans_since(m)] == ["after"]
+        finally:
+            flags.set_flag("trace_ring", old)
+            tracing.clear()
+
+    def test_nothing_is_counted_with_tracing_off(self, monkeypatch):
+        from paddle_tpu.core import unique_name
+        from paddle_tpu.serving.kv_pager import PagedKVEngine
+        _fresh_programs()
+        with unique_name.guard():
+            eng = PagedKVEngine(n_slots=2, max_len=16, block_size=4,
+                                n_blocks=8, vocab=50, d_model=16, d_inner=32,
+                                num_heads=2, num_layers=1)
+
+        def counted(*a):
+            raise AssertionError("counted for a span nobody records")
+        monkeypatch.setattr(eng, "_admit_pool_attrs", counted)
+        req = eng.submit([1, 2, 3], max_new=2)
+        old = flags.get_flag("trace")
+        flags.set_flag("trace", False)
+        try:
+            m = tracing.mark()
+            eng.run_until_idle(max_ticks=50)
+            assert tracing.spans_since(m) == []
+        finally:
+            flags.set_flag("trace", old)
+        assert req.done and req.error is None and len(req.tokens) == 2
+        # on again, the same hook is what `engine/admit` reads the pool from
+        eng.submit([1, 2, 3], max_new=1)
+        with pytest.raises(AssertionError, match="nobody records"):
+            eng.step()
+
+    def test_speculative_round_counts_its_tick_under_commit(self):
+        from paddle_tpu.framework.scope import Scope
+        from paddle_tpu.serving import ContinuousBatchingEngine, SpecConfig
+        eng = ContinuousBatchingEngine(
+            n_slots=2, scope=Scope(), vocab=80, max_len=32, d_model=32,
+            d_inner=64, num_heads=4, num_layers=2,
+            speculative=SpecConfig(gamma=2, draft="int8"))
+        eng.submit([3, 4, 5], max_new=6)
+        ticks, rounds = eng.n_ticks, eng.spec.rounds
+        with tracing.span("user", "caller") as caller:
+            eng.step()
+        assert eng.spec.rounds == rounds + 1, "not a speculative round"
+        assert eng.n_ticks == ticks + 1 and eng.busy_slot_ticks == 1
+        spans = tracing.spans()
+        kinds = [s.kind for s in spans]
+        assert "speculate" in kinds and "verify" in kinds
+        commit = self._one(spans, "engine/commit")
+        assert commit.parent_id == caller.id
+        assert not [s for s in spans if s.name == "engine/tick"]
+
+    def test_tick_latency_buckets_resolve_a_35ms_tick(self):
+        from paddle_tpu.serving.engine import TICK_LATENCY_BUCKETS as edges
+        assert list(edges) == sorted(set(edges))
+        assert edges[0] == 1e-4 and edges[-1] == 2.5     # the outer ones
+        inner = [e for e in edges if 1e-2 <= e <= 0.1]
+        assert inner[0] == 1e-2 and inner[-1] == 0.1
+        steps = [b / a for a, b in zip(inner, inner[1:])]
+        assert max(steps) < 1.11 and min(steps) > 1.09
+        h = obs_metrics.MetricsRegistry().histogram("ptpu_t", buckets=edges)
+        for _ in range(100):
+            h.observe(0.0337)
+        # interpolated inside a bucket 10% wide, not one of 25 ms
+        assert abs(h.quantile(0.5) - 0.0337) < 0.002
+        assert abs(h.quantile(0.99) - 0.0337) < 0.002
 
 
 # ---------------------------------------------------------------------------

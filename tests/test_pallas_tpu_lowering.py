@@ -50,6 +50,24 @@ def test_flash_fwd_bwd_lowers(shape, segments):
     assert _n_calls(text) == 3          # forward, dq, dk/dv
 
 
+@pytest.mark.parametrize("scope", ["flash_fwd", "flash_bwd_dq",
+                                   "flash_bwd_dkv"])
+def test_flash_kernels_carry_their_names(scope):
+    """Each flash kernel's pallas_call sits in a named scope of its own: XLA
+    names the custom call after it, which is how a device trace tells the
+    three apart (PERF.md, `device_ops`)."""
+    def fwd_bwd(q, k, v, do):
+        out, vjp = jax.vjp(lambda q, k, v: flash_attention(
+            q, k, v, causal=True, backend="pallas"), q, k, v)
+        return (out,) + vjp(do)
+
+    lowered = jax.jit(fwd_bwd).trace(*[S((2, 4, 256, 64), BF16)] * 4).lower(
+        lowering_platforms=("tpu",))
+    names = re.findall(r'"jit\(fwd_bwd\)/([^"]*)/pallas_call"',
+                       lowered.as_text(debug_info=True))
+    assert sum(scope + ")" in n for n in set(names)) == 1, names
+
+
 def test_decode_attention_lowers_at_the_gate_edge():
     rows, nh, dh, span = 16, 16, 64, 640
     text = _tpu_text(

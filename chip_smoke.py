@@ -198,14 +198,12 @@ def phase_train_lm(vocab=32000, seq_len=1024, d_model=1024, d_inner=4096,
 def phase_serve_lm(scope, vocab=32000, max_len=1024, d_model=1024,
                    d_inner=4096, num_heads=16, num_layers=12, n_slots=16,
                    n_requests=8, min_prompt=16, max_prompt=64, max_new=32,
-                   deadline_s=420.0, expect_decode_path="composite",
-                   expect_custom_calls=0):
+                   deadline_s=420.0, expect_lowering="kernel"):
     """Serve the weights `scope` holds (shared by name with the train graph)
     through PagedKVEngine behind EngineServer, an EngineClient in a thread of
-    this process. `expect_*` is what the tick's attention compiles to at
-    this shape TODAY (16 heads x 64 over a 1024-token span is past the fused
-    decode kernel's VMEM gate, fusion/decode_attention.py `_pallas_fits`):
-    a gate change that silently turns the kernel on or off here fails."""
+    this process. `expect_lowering` is what the tick's cache read must
+    compile to: on a chip the paged kernel, one Mosaic call a layer
+    (fusion/paged_attention.py); "composite" is for a run off the chip."""
     from paddle_tpu.serving import EngineClient, EngineServer, PagedKVEngine
 
     trained = {n: scope.get(n) for n in ("tok_emb", "lm_head.w_0")
@@ -261,20 +259,22 @@ def phase_serve_lm(scope, vocab=32000, max_len=1024, d_model=1024,
     _check(result["prefix_hits"] >= 1,
            "the repeated prompt did not hit the prefix cache")
 
-    n_calls = _n_custom_calls(eng.tick_hlo())
-    path = "pallas" if n_calls else "composite"
-    _check((path, n_calls) == (expect_decode_path, expect_custom_calls),
-           f"decode attention compiled to {path} with {n_calls} "
-           f"tpu_custom_calls; this shape is expected to take "
-           f"{expect_decode_path} with {expect_custom_calls}")
     stats = eng.stats()
+    lowering = stats["paged_attention_lowering"]
+    n_calls = _n_custom_calls(eng.tick_hlo())
+    want_calls = num_layers if expect_lowering == "kernel" else 0
+    _check((lowering, n_calls) == (expect_lowering, want_calls),
+           f"the engine reports its cache read as {lowering!r} and the "
+           f"compiled tick holds {n_calls} tpu_custom_calls; expected "
+           f"{expect_lowering!r} with {want_calls}")
     return {"compile_s": round(compile_s, 2),
             "run_s": round(result["run_s"], 2),
             "requests": n_requests + 1, "max_new": max_new,
             "prompt_lens": [len(p) for p in prompts],
             "ticks": stats["ticks"], "tokens_out": stats["tokens_out"],
             "prefix_hits": result["prefix_hits"],
-            "decode_attention": path, "tpu_custom_calls": n_calls,
+            "paged_attention_lowering": lowering,
+            "tpu_custom_calls": n_calls,
             "block_size": eng.block_size, "n_blocks": eng.n_blocks}
 
 
@@ -397,6 +397,52 @@ def _check_decode(num_heads, d_head, span, rows, backend, timing):
     return err
 
 
+def _check_paged(n_slots, n_blocks, block_size, num_heads, d_head,
+                 blocks_per_req, backend, timing):
+    """The paged decode-attention kernel against its composite: ragged
+    positions (an idle slot on the null block, a block's last row, the
+    whole span among them), a permuted table."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.fusion import (paged_attention_lowering,
+                                   paged_decode_attention)
+    from paddle_tpu.ops.tensor_ops import pool_block_shape
+
+    rng = np.random.RandomState(7)
+    span = blocks_per_req * block_size
+    # the pools as the tick declares them (lane-dense at these widths)
+    shape = (n_blocks,) + pool_block_shape(num_heads, block_size, d_head)
+    _check(paged_attention_lowering("float32", shape[-1], 1, d_head, False,
+                                    backend=backend) == "kernel",
+           f"pools {shape} do not take the paged kernel")
+    k_pool, v_pool = (jnp.asarray(rng.randn(*shape), jnp.float32)
+                      for _ in range(2))
+    q = jnp.asarray(rng.randn(n_slots, 1, num_heads * d_head), jnp.float32)
+    pos = rng.randint(0, span, (n_slots,))
+    pos[:3] = 0, block_size - 1, span - 1
+    btab = np.zeros((n_slots, blocks_per_req), np.int32)
+    ids = iter(np.resize(rng.permutation(np.arange(1, n_blocks)),
+                         n_slots * blocks_per_req))
+    for s in range(1, n_slots):              # slot 0 idle: the null block
+        for j in range(pos[s] // block_size + 1):
+            btab[s, j] = next(ids)
+    args = (q, k_pool, v_pool, jnp.asarray(btab),
+            jnp.asarray(pos, jnp.float32))
+
+    def run(be):
+        return jax.jit(lambda *a: paged_decode_attention(
+            *a, num_heads, scale=d_head ** -0.5, backend=be))
+    got, c = _timed_first(run(backend), *args)
+    timing["compile_s"] += c
+    t0 = time.time()
+    with jax.default_matmul_precision("highest"):
+        ref = run("xla")(*args)
+    err = _rel_err(got, ref)
+    timing["run_s"] += time.time() - t0
+    _check(err <= TOL_F32, f"paged decode attention: error {err}")
+    return err, float(np.max(np.abs(np.asarray(got) - np.asarray(ref))))
+
+
 def _check_recurrent(kind, batch, steps, hidden, backend, timing):
     import jax
     import jax.numpy as jnp
@@ -440,11 +486,13 @@ def _check_recurrent(kind, batch, steps, hidden, backend, timing):
 
 def phase_kernels(backend="pallas",
                   flash_shapes=((8, 16, 1024, 64), (1, 8, 8192, 128)),
-                  decode=(16, 64, 640, 16), recurrent=(64, 64, 256)):
+                  decode=(16, 64, 640, 16), recurrent=(64, 64, 256),
+                  paged=(16, 1024, 16, 16, 64, 64)):
     """Every Pallas kernel the package selects by default on a TPU, called
     directly, compiled by Mosaic, run, and compared with its own composite.
-    decode = (heads, d_head, span, rows); recurrent = (batch, steps, hidden).
-    """
+    decode = (heads, d_head, span, rows); recurrent = (batch, steps, hidden);
+    paged = (slots, pool blocks, block size, heads, d_head, blocks a
+    request): the serving benchmark's tick."""
     timing = {"compile_s": 0.0, "run_s": 0.0}
     errs = {}
     for shape in flash_shapes:
@@ -452,12 +500,14 @@ def phase_kernels(backend="pallas",
             name = "flash_" + "x".join(map(str, shape)) + ("_seg" * seg)
             errs[name] = _check_flash(shape, seg, backend, timing)
     errs["decode_T%d" % decode[2]] = _check_decode(*decode, backend, timing)
+    errs["paged_decode"], paged_abs = _check_paged(*paged, backend, timing)
     for kind in ("lstm", "gru"):
         errs["fused_" + kind] = _check_recurrent(kind, *recurrent, backend,
                                                  timing)
     return {"compile_s": round(timing["compile_s"], 2),
             "run_s": round(timing["run_s"], 2), "backend": backend,
-            "max_rel_err": {k: float("%.2e" % v) for k, v in errs.items()}}
+            "max_rel_err": {k: float("%.2e" % v) for k, v in errs.items()},
+            "paged_decode_max_abs_diff": float("%.2e" % paged_abs)}
 
 
 def _multichip_ring(devices, ring_shape):

@@ -205,10 +205,12 @@ define_bool("trace", True,
             "Kill switch: PTPU_TRACE=0 makes every span a no-op (span "
             "enter/exit cost drops below the 0.5%%-of-step budget asserted "
             "in tests/test_observability.py).")
-define_int("trace_ring", 65536,
+define_int("trace_ring", 262144,
            "Capacity of the span ring buffer (observability/tracing.py). "
            "Oldest spans are overwritten; the buffer is preallocated so "
-           "recording never allocates on the hot path.")
+           "recording never allocates on the hot path. A served tick "
+           "records about eight spans: the default holds a minute of a "
+           "2 ms tick (a full ring keeps ~60 MB of span records alive).")
 # (num_iteration_per_drop_scope lives on ExecutionStrategy for API parity;
 # the functional executor has no per-iteration kid scopes to drop)
 define_int("sparse_dense_apply_max_bytes", 1 << 30,

@@ -192,7 +192,8 @@ def _ensure_builtin_ops():
                        optimizer_ops, random_ops, sequence_ops, metric_ops,
                        control_ops, loss_ops, sequence_label_ops,
                        beam_search_ops, detection_ops, pallas_kernels)
-    from ..fusion import decode_attention, recurrent  # noqa: F401
+    from ..fusion import (decode_attention, paged_attention,  # noqa: F401
+                          recurrent)
 
 
 @dataclass

@@ -20,6 +20,12 @@ bound:
   round-trips of the [.., 1, T] score/weight tensors — in one kernel.
   The cache WRITE side stays on the existing `cache_write`
   dynamic-update-slice op.
+- `paged_decode_attention`: the PAGED ticks' cache read. The pool is read
+  through the block table, live blocks only, by a Pallas kernel with the
+  table scalar-prefetched (TPU, float32 pools, one query position); the
+  composite that gathers the dense table view serves everything else
+  (paged_attention.py). Built into the paged tick graphs directly, not
+  by a pass.
 
 Users normally never call these: the graph passes in
 `framework/passes.py` (`fuse_recurrent_cell_pass`,
@@ -37,5 +43,7 @@ interpreter so the CPU suite pins the same tiling logic the TPU runs.
 from .decode_attention import (dequantize_kv_time_blocks,  # noqa: F401
                                fused_decode_attention,
                                quantize_kv_time_blocks)
+from .paged_attention import (paged_attention_lowering,  # noqa: F401
+                              paged_decode_attention)
 from .recurrent import (fused_gru_sequence,  # noqa: F401
                         fused_lstm_sequence)

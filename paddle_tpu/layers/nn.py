@@ -1003,6 +1003,30 @@ def paged_cache_write_quant(pool, scales, new, block_ids, offsets,
     return out, scales_out
 
 
+def paged_decode_attention(q, k_pool, v_pool, block_table, pos, num_heads,
+                           scale=1.0, k_scale=None, v_scale=None,
+                           name=None):
+    """Attention of each tick slot's query rows over its PAGED cache, read
+    through the block table (fusion/paged_attention.py). `q` is [S, G, H]
+    (G = 1 for the decode tick, γ+1 for a verify window), `k_pool`/`v_pool`
+    the written pools [n_blocks, nh, block_size, dh] (with `k_scale`/
+    `v_scale` [n_blocks, nh, block_size, 1] when they are int8),
+    `block_table` [S, NLB], `pos` the position of each slot's first query
+    row (S elements; row g attends cache positions 0..pos+g). Returns
+    [S, G, H]."""
+    helper = LayerHelper("paged_decode_attention", name=name)
+    out = helper.create_tmp_variable(dtype=dtype_name(q.dtype),
+                                     shape=q.shape, stop_gradient=True)
+    inputs = {"Q": [q], "KPool": [k_pool], "VPool": [v_pool],
+              "BlockTable": [block_table], "Pos": [pos]}
+    if k_scale is not None:
+        inputs["KScale"], inputs["VScale"] = [k_scale], [v_scale]
+    helper.append_op(type="paged_decode_attention", inputs=inputs,
+                     outputs={"Out": [out]},
+                     attrs={"num_heads": num_heads, "scale": float(scale)})
+    return out
+
+
 def lrn(input, n=5, k=2.0, alpha=1e-4, beta=0.75, name=None):
     helper = LayerHelper("lrn", name=name)
     out = helper.create_tmp_variable(dtype=dtype_name(input.dtype),

@@ -730,25 +730,29 @@ def transformer_lm_paged_decode_tick(n_slots, n_blocks, block_size,
 
     The slot tick owns a full [S,1,nh,max_len,dh] row per slot; here the
     KV state is one device-resident POOL per layer per k/v —
-    [n_blocks, nh, block_size, dh] persistable variables — and each slot
+    [n_blocks, nh, block_size, dh] persistable variables (declared
+    lane-dense, [n_blocks, nh, block_size*dh/128, 128], where a head's
+    rows pack 128 lanes: `_paged_pool_vars`) — and each slot
     sees the cache through its BLOCK TABLE (`tick_btab` [S, NLB] int64,
     NLB = blocks_per_req): logical block j of slot s lives in physical
-    block tick_btab[s, j]. The read path is gather(pool, btab) →
-    transpose → reshape, reconstructing the exact [S,1,nh,T,dh] view the
-    slot tick attends over (T = NLB*block_size), so the downstream
-    q·K/softmax/·V chain is IDENTICAL and fuse_decode_attention_pass
-    matches it unchanged. The write path is `paged_cache_write`: slot
-    s's new k/v row lands at pool[tick_wblock[s], :, tick_woff[s], :] —
-    block-granular, one XLA scatter.
+    block tick_btab[s, j]. The write path is `paged_cache_write`: slot
+    s's new k/v row lands at pool[tick_wblock[s], :, tick_woff[s], :],
+    in place, rows only. The read path is ONE `paged_decode_attention`
+    op a layer over the written pools: on a TPU a Pallas kernel that
+    DMAs each slot's LIVE blocks straight from the pool through the
+    table; elsewhere (and for int8 pools) the composite that gathers the
+    [S,nh,T,dh] view the slot tick attends over (T = NLB*block_size) and
+    runs the slot tick's q·K/softmax/·V math on it
+    (fusion/paged_attention.py). Nothing of pool shape is computed.
 
     Physical block 0 is the pool's reserved NULL block: idle slots are
     steered to write there (tok/pos zeroed, btab all-zero) so one
     fixed-shape compiled tick serves any live/idle mix; a live block
-    table never maps block 0, and the positional mask hides every view
-    position beyond a slot's own `tick_pos`, so null-block garbage is
-    never attended. Prefix sharing needs no graph support at all: a
-    shared prefix simply means two rows of `tick_btab` carry the SAME
-    physical block id — the gather reads the same bytes twice.
+    table never maps block 0, and the read attends no position beyond a
+    slot's own `tick_pos`, so null-block garbage is never attended.
+    Prefix sharing needs no graph support at all: a shared prefix simply
+    means two rows of `tick_btab` carry the SAME physical block id — the
+    read fetches the same bytes twice.
 
     Weights are shared BY NAME with transformer_lm (tok_emb, l{i}_attn_*,
     l{i}_ln*, l{i}_ffn_*, lm_head) — same contract as the slot tick;
@@ -766,11 +770,12 @@ def transformer_lm_paged_decode_tick(n_slots, n_blocks, block_size,
     kv_quant=True stores the pools as int8 payloads plus per-row f32
     scale pools ([NB, nh, BS, 1], names `{cache_prefix}_{k,v}{i}_sc`):
     writes quantize on the way in (`paged_cache_write_quant`, symmetric
-    amax/127 over each dh row) and the read gathers payload+scales and
-    dequantizes with one cast+multiply that XLA fuses into the cache
-    read — so the resident pool bytes drop ~4x and the pager hands the
-    freed bytes back as extra admitted blocks (the r21 quantized-KV
-    kernel path wired into the engine pool storage itself)."""
+    amax/127 over each dh row) and the read (the composite lowering)
+    gathers payload+scales and dequantizes with one cast+multiply that
+    XLA fuses into the cache read — so the resident pool bytes drop ~4x
+    and the pager hands the freed bytes back as extra admitted blocks
+    (the r21 quantized-KV kernel path wired into the engine pool storage
+    itself)."""
     S, NB, BS, NLB = n_slots, n_blocks, block_size, blocks_per_req
     T = NLB * BS                      # the per-request logical span
     d_head = d_model // num_heads
@@ -790,10 +795,8 @@ def transformer_lm_paged_decode_tick(n_slots, n_blocks, block_size,
                                           d_head, num_layers, kv_quant)
 
     pe_table = positional_encoding_table(T, d_model).astype("float32")
-    arange = np.arange(T, dtype="float32").reshape(1, 1, T)
     x = _gen_embed_step(tok, pos, "tok_emb", vocab, d_model, pe_table,
                         dropout)
-    bias = _step_mask_bias(pos, arange)       # per-slot: pos broadcasts
     H = d_model
     for i in range(num_layers):
         q = layers.fc(x, size=H, num_flatten_dims=2, bias_attr=False,
@@ -802,18 +805,9 @@ def transformer_lm_paged_decode_tick(n_slots, n_blocks, block_size,
                        use_bf16=True, name=f"l{i}_attn_k")
         vn = layers.fc(x, size=H, num_flatten_dims=2, bias_attr=False,
                        use_bf16=True, name=f"l{i}_attn_v")
-        views = []
-        for sname, new in (("k", kn), ("v", vn)):
-            # write this tick's row into each slot's current block (the
-            # pool var round-trips through donated state, as in the
-            # slot tick), THEN read the table view from the written pool
-            # so the new row is attendable within the same tick
-            new3 = layers.reshape(new, shape=[0, num_heads, d_head])
-            views.append(_paged_pool_view(
-                pools, scale_pools, f"{sname}{i}", new3, wblock, woff,
-                btab, num_heads, T, d_head))
-        ctx = _attend_cached(q, views[0], views[1], bias, 1, num_heads,
-                             d_head, attn_dropout)
+        ctx = _paged_attention(pools, scale_pools, i, q, kn, vn, S, wblock,
+                               woff, btab, pos, num_heads, d_head,
+                               attn_dropout)
         attn = layers.fc(ctx, size=H, num_flatten_dims=2, bias_attr=False,
                          use_bf16=True, name=f"l{i}_attn_o")
         x = _add_norm(attn, x, dropout, True, name=f"l{i}_ln1")
@@ -833,15 +827,19 @@ def transformer_lm_paged_decode_tick(n_slots, n_blocks, block_size,
 
 def _paged_pool_vars(cache_prefix, n_blocks, num_heads, block_size, d_head,
                      num_layers, kv_quant):
-    """Per-layer k/v pool variables for the paged ticks. kv_quant=False:
-    f32 pools, empty scale dict. kv_quant=True: int8 payload pools plus
-    f32 per-row scale pools (`{cache_prefix}_{s}{i}_sc`)."""
+    """Per-layer k/v pool variables for the paged ticks, [n_blocks] +
+    `pool_block_shape` (a block's [nh, block_size, d_head], declared
+    lane-dense where its rows pack 128 lanes: axis 0 is the physical block
+    either way). kv_quant=False: f32 pools, empty scale dict.
+    kv_quant=True: int8 payload pools plus f32 per-row scale pools
+    (`{cache_prefix}_{s}{i}_sc`, [n_blocks, nh, block_size, 1])."""
+    from ..ops.tensor_ops import pool_block_shape
+    block = list(pool_block_shape(num_heads, block_size, d_head))
     pools, scale_pools = {}, {}
     for i in range(num_layers):
         for s in ("k", "v"):
             pools[f"{s}{i}"] = _slot_cache_var(
-                f"{cache_prefix}_{s}{i}",
-                [n_blocks, num_heads, block_size, d_head],
+                f"{cache_prefix}_{s}{i}", [n_blocks] + block,
                 dtype="int8" if kv_quant else "float32")
             if kv_quant:
                 scale_pools[f"{s}{i}"] = _slot_cache_var(
@@ -850,29 +848,36 @@ def _paged_pool_vars(cache_prefix, n_blocks, num_heads, block_size, d_head,
     return pools, scale_pools
 
 
-def _paged_pool_view(pools, scale_pools, key, new3, wblock, woff, btab,
-                     num_heads, T, d_head):
-    """Write `new3` rows into pool `key` then reconstruct the slot-tick
-    cache view [S,1,nh,T,dh] through the block table — dequantizing
-    against the gathered scale view when the pool is int8 (scale_pools
-    non-empty). Shared by the paged decode tick (one row per slot) and
-    the paged verify tick (G rows per slot: wblock/woff [S,G], new3
-    [S*G,nh,dh] — `paged_cache_write` flattens the targets)."""
-    pool = pools[key]
-    if scale_pools:
-        spool = scale_pools[key]
-        written, wscales = layers.paged_cache_write_quant(
-            pool, spool, new3, wblock, woff, out=pool, scales_out=spool)
-        g = layers.cast(layers.gather(written, btab), "float32")
-        gs = layers.gather(wscales, btab)        # [S,NLB,nh,BS,1]
-        g = layers.elementwise_mul(g, gs)        # [S,NLB,nh,BS,dh] f32
-    else:
-        written = layers.paged_cache_write(pool, new3, wblock, woff,
-                                           out=pool)
-        g = layers.gather(written, btab)         # [S,NLB,nh,BS,dh]
-    g = layers.transpose(g, perm=[0, 2, 1, 3, 4])
-    g = layers.reshape(g, shape=[0, num_heads, T, d_head])
-    return layers.unsqueeze(g, axes=[1])         # [S,1,nh,T,dh]
+def _paged_attention(pools, scale_pools, layer, q, kn, vn, n_rows, wblock,
+                     woff, btab, pos, num_heads, d_head, dropout=0.0):
+    """One layer's paged cache write + read: the new K/V rows (`kn`/`vn`
+    [S,G,H]; `wblock`/`woff` give each row's physical target, flattened to
+    `n_rows` = S*G) go into the layer's pools in place — the pool vars
+    round-trip through donated state, as in the slot tick — and THEN
+    `paged_decode_attention` reads the WRITTEN pools through the block
+    table, so the new rows are attended within the same tick. int8 pools
+    (scale_pools non-empty) quantize on the way in and hand the read
+    their scale pools. Shared by the paged decode tick (G = 1) and the
+    paged verify tick. Returns the context [S,G,H], scaled by (1-p) when
+    the train graph had attention dropout (as `_attend_cached`)."""
+    written = {}
+    for sname, new in (("k", kn), ("v", vn)):
+        pool = pools[f"{sname}{layer}"]
+        new3 = layers.reshape(new, shape=[n_rows, num_heads, d_head])
+        if scale_pools:
+            spool = scale_pools[f"{sname}{layer}"]
+            written[sname] = layers.paged_cache_write_quant(
+                pool, spool, new3, wblock, woff, out=pool, scales_out=spool)
+        else:
+            written[sname] = (layers.paged_cache_write(
+                pool, new3, wblock, woff, out=pool), None)
+    ctx = layers.paged_decode_attention(
+        q, written["k"][0], written["v"][0], btab, pos, num_heads,
+        scale=float(d_head) ** -0.5, k_scale=written["k"][1],
+        v_scale=written["v"][1])
+    if dropout:
+        ctx = layers.scale(ctx, scale=1.0 - dropout)
+    return ctx
 
 
 def transformer_lm_paged_spec_verify_tick(n_slots, gamma, n_blocks,
@@ -887,8 +892,9 @@ def transformer_lm_paged_spec_verify_tick(n_slots, gamma, n_blocks,
     slot scores G = γ+1 positions in one forward; the G new KV rows
     scatter into the slot's CURRENT blocks (`spec_wblock`/`spec_woff`
     [S,G]: per-position physical targets the engine derives from the
-    block table at fed..fed+γ), then the table view is gathered back and
-    attended with the per-position causal bias. Verify positions occupy
+    block table at fed..fed+γ), then `paged_decode_attention` reads the
+    written pools through the table, row g attending positions
+    0..pos+g (the composite lowering: G > 1). Verify positions occupy
     the slot-tick layout the way beam forks do: rows of rejected
     positions stay in place, masked, until the pager's rollback detaches
     their fully-rejected blocks (`KVPager.rollback`) and later writes
@@ -923,11 +929,9 @@ def transformer_lm_paged_spec_verify_tick(n_slots, gamma, n_blocks,
                                           d_head, num_layers, kv_quant)
 
     pe_table = positional_encoding_table(T, d_model).astype("float32")
-    arange = np.arange(T, dtype="float32").reshape(1, 1, T)
     posg = _spec_window_positions(pos, G)             # [S,G,1]
     x = _gen_embed_step(tok, posg, f"{param_prefix}tok_emb", vocab, d_model,
                         pe_table, dropout)
-    bias = _spec_mask_bias(posg, arange)              # [S,1,1,G,T]
     for i in range(num_layers):
         prefix = f"{param_prefix}l{i}_attn"
         q = layers.fc(x, size=H, num_flatten_dims=2, bias_attr=False,
@@ -936,14 +940,9 @@ def transformer_lm_paged_spec_verify_tick(n_slots, gamma, n_blocks,
                        use_bf16=True, name=f"{prefix}_k")
         vn = layers.fc(x, size=H, num_flatten_dims=2, bias_attr=False,
                        use_bf16=True, name=f"{prefix}_v")
-        views = []
-        for sname, new in (("k", kn), ("v", vn)):
-            new3 = layers.reshape(new, shape=[S * G, num_heads, d_head])
-            views.append(_paged_pool_view(
-                pools, scale_pools, f"{sname}{i}", new3, wblock, woff,
-                btab, num_heads, T, d_head))
-        ctx = _attend_cached_multi(q, views[0], views[1], bias, G,
-                                   num_heads, d_head, attn_dropout)
+        ctx = _paged_attention(pools, scale_pools, i, q, kn, vn, S * G,
+                               wblock, woff, btab, pos, num_heads, d_head,
+                               attn_dropout)
         attn = layers.fc(ctx, size=H, num_flatten_dims=2, bias_attr=False,
                          use_bf16=True, name=f"{prefix}_o")
         x = _add_norm(attn, x, dropout, True, name=f"{param_prefix}l{i}_ln1")

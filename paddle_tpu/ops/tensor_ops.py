@@ -218,15 +218,18 @@ def _paged_cache_write(ctx, ins, attrs):
     """Block-granular KV write for the paged cache (serving/kv_pager.py):
     scatter one new token row per slot into a device-resident block POOL
     instead of a per-slot cache row. `Cache` is the pool
-    [n_blocks, nh, block_size, dh]; `New` is [S, nh, dh] (one row per
-    tick slot); `BlockIds`/`Offsets` are [S] — slot s lands at
-    pool[BlockIds[s], :, Offsets[s], :]. Inactive slots are steered at
+    [n_blocks, nh, block_size, dh] (or its lane-dense declaration
+    [n_blocks, nh, block_size*dh/128, 128]: `pool_block_shape`); `New` is
+    [S, nh, dh] (one row per tick slot); `BlockIds`/`Offsets` are [S] —
+    slot s lands at pool[BlockIds[s], :, Offsets[s], :] of the
+    [.., block_size, dh] view. Inactive slots are steered at
     the reserved null block 0 (never mapped by a live block table), so
     one fixed-shape compiled tick serves any mix of live/idle slots —
     the same trick the slot tick plays with its zeroed feeds. Duplicate
     (block, offset) targets are only ever the null block, where any
-    write order is acceptable. Lowers to one XLA scatter; inside the
-    executor's donated-state path the pool updates in place."""
+    write order is acceptable. Lowers to one `dynamic_update_slice` per
+    row (`_write_pool_rows`): inside the executor's donated-state path the
+    pool updates in place and nothing of pool shape is computed."""
     pool = ins["Cache"][0]
     new = ins["New"][0].astype(pool.dtype)
     blocks = ins["BlockIds"][0].reshape(-1).astype(jnp.int32)
@@ -239,7 +242,43 @@ def _paged_cache_write(ctx, ins, attrs):
         raise ValueError(
             f"paged_cache_write: BlockIds {blocks.shape} and Offsets "
             f"{offs.shape} must agree")
-    return {"Out": [pool.at[blocks, :, offs, :].set(new)]}
+    return {"Out": [_write_pool_rows(pool, new, blocks, offs)]}
+
+
+POOL_LANES = 128
+
+
+def pool_block_shape(num_heads, block_size, d_head):
+    """The shape of one block of a paged K or V pool, `[nh, R, L]`:
+    lane-dense `[nh, block_size * d_head / 128, 128]` when a head's rows
+    pack whole 128-lane rows, `[nh, block_size, d_head]` if not (tiny test
+    widths). Row-major, the two hold the same values in the same order;
+    why a pool is declared lane-dense: fusion/paged_attention.py."""
+    if POOL_LANES % d_head == 0 and (block_size * d_head) % POOL_LANES == 0:
+        return (num_heads, block_size * d_head // POOL_LANES, POOL_LANES)
+    return (num_heads, block_size, d_head)
+
+
+# jitted: every layer's K and V write is the same function of the same
+# shapes, traced and lowered once a tick program, not 24 times
+@jax.jit
+def _write_pool_rows(pool, rows, blocks, offs):
+    """Row i (`rows[i]`, [nh, w]) becomes the w values of in-block position
+    `offs[i]` of block `blocks[i]` in every head's `[R, L]` plane of `pool`
+    [NB, nh, R, L] (either `pool_block_shape`; w = dh, or 1 for a scale
+    pool): one in-place `dynamic_update_slice` of [1, nh, 1, w] a row, in
+    order (a later duplicate target wins: only the null block has any). A
+    scatter says the same; XLA on a TPU made it a pass over the whole pool
+    (64 MB a pool at the benchmark's widths, to write 16 rows of 4 KB),
+    and a row update is what the donated buffer takes in place for sure."""
+    width, lanes = rows.shape[-1], pool.shape[-1]
+    zero = jnp.int32(0)
+    for i in range(rows.shape[0]):
+        lin = offs[i] * width
+        pool = jax.lax.dynamic_update_slice(
+            pool, rows[i][None, :, None, :],
+            (blocks[i], zero, lin // lanes, lin % lanes))
+    return pool
 
 
 @register_op("paged_cache_write_quant", stop_gradient=True)
@@ -249,8 +288,8 @@ def _paged_cache_write_quant(ctx, ins, attrs):
     and each incoming f32 row is quantized symmetrically over its dh
     vector on the way in — amax/127 scale per (slot, head) row, zero rows
     pinned to scale 1.0 so dequantization is exact for them. The payload
-    scatter and the scale scatter are the same one-XLA-scatter shape as
-    the f32 write; the engine-side win is the pool's RESIDENT bytes
+    write and the scale write are the same in-place row writes as the
+    f32 write; the engine-side win is the pool's RESIDENT bytes
     (f32 -> int8 + one scale per dh row), which the pager hands back as
     extra admitted blocks. Same null-block steering contract as
     `paged_cache_write`."""
@@ -270,8 +309,8 @@ def _paged_cache_write_quant(ctx, ins, attrs):
     amax = jnp.max(jnp.abs(new), axis=-1, keepdims=True)
     sc = jnp.where(amax > 0, amax / 127.0, 1.0).astype(jnp.float32)
     q = jnp.clip(jnp.round(new / sc), -127, 127).astype(jnp.int8)
-    return {"Out": [pool.at[blocks, :, offs, :].set(q)],
-            "ScalesOut": [scales.at[blocks, :, offs, :].set(sc)]}
+    return {"Out": [_write_pool_rows(pool, q, blocks, offs)],
+            "ScalesOut": [_write_pool_rows(scales, sc, blocks, offs)]}
 
 
 @register_op("one_hot", stop_gradient=True)

@@ -327,6 +327,9 @@ class ContinuousBatchingEngine:
                 self.params_bytes_quantized = self._param_bytes()
                 self.quant_freed_bytes = (self.params_bytes_f32
                                           - self.params_bytes_quantized)
+        #: counts `_fill_tick_feeds` takes where it walks the feeds anyway;
+        #: they ride the `engine/tick` span (the paged engine: `kv_blocks`)
+        self._tick_attrs: Dict[str, int] = {}
         self._feeds = self._init_tick_feeds()
         self._tok = self._feeds["tick_tok"]
         self._pos = self._feeds["tick_pos"]
@@ -799,7 +802,7 @@ class ContinuousBatchingEngine:
                 # built when tracing is off (the decode loop is the hot
                 # path)
                 tick.attrs.update(
-                    active=len(active),
+                    self._tick_attrs, active=len(active),
                     prefill=sum(1 for r in active.values()
                                 if r.fed < len(r.prompt) - 1),
                     request_ids=[r.request_id for r in active.values()])

@@ -37,9 +37,9 @@ category. This module replaces the per-slot rows with PAGES:
   LRU eviction of cached prefixes), prefill SKIPS shared positions
   (compute is deterministic — the shared blocks hold byte-identical
   K/V, which is why decode is token-identical to the slot engine), and
-  the compiled tick is `transformer_lm_paged_decode_tick` (gather by
-  block table; the fused r06 decode-attention kernel matches the
-  gathered view unchanged).
+  the compiled tick is `transformer_lm_paged_decode_tick` (rows written
+  in place, the pool read through the block table by
+  `paged_decode_attention`: fusion/paged_attention.py).
 - `paged_beam_search` — beam decode over the paged engine: hypotheses
   share their common prefix physically (block refcounts), forks CoW the
   divergence block, and the per-tick top-k log-probs from the compiled
@@ -651,9 +651,10 @@ class PagedKVEngine(ContinuousBatchingEngine):
     named hooks — the scheduler itself is untouched, which is what
     makes the decode-identity guarantee auditable):
 
-    - the compiled tick is `transformer_lm_paged_decode_tick` (gather
-      by block table + `paged_cache_write`; same attention chain, same
-      fused decode kernel);
+    - the compiled tick is `transformer_lm_paged_decode_tick` (in-place
+      `paged_cache_write` + `paged_decode_attention` reading the pool
+      through the block table; `stats()["paged_attention_lowering"]` says
+      which lowering the read took);
     - admission acquires a BlockTable from the `KVPager` (head-of-line
       wait under pool pressure — `_admit_request` returning False);
       prefix hits start the request at `fed = shared_len`, skipping the
@@ -757,7 +758,17 @@ class PagedKVEngine(ContinuousBatchingEngine):
     def _build_tick_program(self, n_slots, vocab, max_len, d_model,
                             d_inner, num_heads, num_layers, dropout,
                             packed, cache_prefix):
+        from ..fusion.paged_attention import paged_attention_lowering
+        from ..ops.tensor_ops import pool_block_shape
         from ..models import transformer
+        # which lowering the tick's cache read takes, decided here by the
+        # rule the op itself applies when the tick compiles; on a TPU it
+        # raises rather than serve float32 pools from the composite
+        dh = d_model // num_heads
+        self.paged_attention_lowering = paged_attention_lowering(
+            "int8" if self.kv_quant else "float32",
+            pool_block_shape(num_heads, self.block_size, dh)[-1], 1, dh,
+            self.kv_quant)
         outs = transformer.transformer_lm_paged_decode_tick(
             n_slots=n_slots, n_blocks=self.n_blocks,
             block_size=self.block_size,
@@ -794,12 +805,15 @@ class PagedKVEngine(ContinuousBatchingEngine):
         wblock[:] = 0
         woff[:] = 0
         bs = self.block_size
+        kv_blocks = 0
         for slot, req in active.items():
             blocks = req.table.blocks
             btab[slot, :len(blocks)] = blocks
             lb, off = divmod(req.fed, bs)
             wblock[slot] = blocks[lb]
             woff[slot] = off
+            kv_blocks += lb + 1      # the blocks this slot's read spans
+        self._tick_attrs["kv_blocks"] = kv_blocks
 
     def _note_tick_writes(self, active: Dict[int, GenRequest]):
         # shadow-state sanitizer: every position this tick writes must
@@ -1191,6 +1205,11 @@ class PagedKVEngine(ContinuousBatchingEngine):
                 "Block-table entries rolled back to fresh blocks after "
                 "speculative verify rejected their whole span.",
                 fn=lambda: pager.rolled_back_blocks)
+        r.gauge("ptpu_engine_paged_attention_kernel",
+                "1 when the compiled tick reads the KV pool through the "
+                "block table with the Pallas kernel, 0 when it takes the "
+                "composite that gathers the dense table view.",
+                fn=lambda: int(self.paged_attention_lowering == "kernel"))
         r.gauge("ptpu_engine_kv_quant_freed_bytes",
                 "Bytes the int8 KV block pools save vs f32 pools at the "
                 "same block count (0 with kv_quant off).",
@@ -1228,6 +1247,7 @@ class PagedKVEngine(ContinuousBatchingEngine):
     def stats(self) -> Dict:
         s = super().stats()
         s["pager"] = self.pager.stats()
+        s["paged_attention_lowering"] = self.paged_attention_lowering
         s["kv_quant"] = {"enabled": self.kv_quant,
                          "freed_bytes": self.kv_quant_freed_bytes}
         if self.host_tier is not None:

@@ -41,9 +41,11 @@ def test_train_then_serve_from_the_same_scope():
     assert train["losses"][-1] < train["losses"][0]
     serve = chip_smoke.phase_serve_lm(
         pt.global_scope(), max_len=32, n_slots=4, n_requests=8,
-        min_prompt=9, max_prompt=12, max_new=4, deadline_s=240.0, **_LM)
+        min_prompt=9, max_prompt=12, max_new=4, deadline_s=240.0,
+        expect_lowering="composite", **_LM)
     assert serve["requests"] == 9 and serve["prefix_hits"] >= 1
-    assert serve["decode_attention"] == "composite"
+    assert serve["paged_attention_lowering"] == "composite"
+    assert serve["tpu_custom_calls"] == 0
 
 
 def test_train_resnet_phase():
@@ -55,10 +57,12 @@ def test_train_resnet_phase():
 def test_kernels_phase_through_the_interpreter():
     out = chip_smoke.phase_kernels(
         backend="pallas_interpret", flash_shapes=((1, 2, 256, 64),),
-        decode=(4, 64, 1408, 2), recurrent=(8, 4, 128))
+        decode=(4, 64, 1408, 2), recurrent=(8, 4, 128),
+        paged=(5, 40, 8, 4, 16, 6))     # 8 rows of 16: one 128-lane row
     assert set(out["max_rel_err"]) == {
         "flash_1x2x256x64", "flash_1x2x256x64_seg", "decode_T1408",
-        "fused_lstm", "fused_gru"}
+        "paged_decode", "fused_lstm", "fused_gru"}
+    assert out["paged_decode_max_abs_diff"] <= 1e-5
 
 
 def test_multichip_phase_on_four_virtual_devices():

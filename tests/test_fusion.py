@@ -231,6 +231,65 @@ def _lstm_losses_and_params(fuse, rng):
 
 
 @pytest.mark.quick
+class TestPagedDecodeAttentionComposite:
+    """`paged_decode_attention`'s composite lowering IS the slot tick's
+    fused decode attention over the gathered table view (the kernel against
+    the composite: tests/test_kv_pager.py)."""
+
+    NB, NH, BS, DH, NLB = 11, 2, 4, 8, 3
+
+    def _pools(self, rng):
+        shape = (self.NB, self.NH, self.BS, self.DH)
+        return (rng.randn(*shape).astype("float32"),
+                rng.randn(*shape).astype("float32"))
+
+    def _dense(self, pool, btab):
+        return np.stack([np.concatenate([pool[b] for b in row], axis=1)
+                         for row in btab])[:, None]       # [S,1,nh,T,dh]
+
+    @pytest.mark.parametrize("g", [1, 3])
+    def test_equals_fused_decode_attention_on_the_table_view(self, rng, g):
+        """G query rows a slot: row i attends 0..pos+i, the verify
+        window's causal mask."""
+        from paddle_tpu.fusion import (fused_decode_attention,
+                                       paged_decode_attention)
+        k_pool, v_pool = self._pools(rng)
+        btab = np.array([[4, 9, 2], [7, 1, 0]], "int64")
+        pos = np.array([5, 2], "int64")
+        S, T = 2, self.NLB * self.BS
+        q = rng.randn(S, g, self.NH * self.DH).astype("float32")
+        got = paged_decode_attention(
+            q, k_pool, v_pool, btab, pos.astype("float32"), self.NH,
+            scale=0.25, backend="xla")
+        valid = (np.arange(T)[None, None]
+                 <= (pos[:, None] + np.arange(g)[None])[:, :, None])
+        bias = np.where(valid, 0.0, -1e9).astype("float32")[:, None, None]
+        q5 = q.reshape(S, g, self.NH, self.DH).transpose(0, 2, 1, 3)[:, None]
+        ref = fused_decode_attention(
+            q5, self._dense(k_pool, btab), self._dense(v_pool, btab), bias,
+            scale=0.25, backend="xla")                    # [S,1,nh,G,dh]
+        ref = np.asarray(ref)[:, 0].transpose(0, 2, 1, 3).reshape(q.shape)
+        np.testing.assert_allclose(got, ref, rtol=1e-6, atol=1e-6)
+
+    def test_int8_pools_dequantize_against_their_scale_pools(self, rng):
+        from paddle_tpu.fusion import paged_decode_attention
+        k_pool, v_pool = self._pools(rng)
+
+        def quant(pool):
+            sc = np.abs(pool).max(-1, keepdims=True) / 127.0
+            return np.round(pool / sc).astype("int8"), sc.astype("float32")
+        (kq, ks), (vq, vs) = quant(k_pool), quant(v_pool)
+        btab = np.array([[4, 9, 2], [7, 1, 0]], "int64")
+        pos = np.array([9, 6], "float32")
+        q = rng.randn(2, 1, self.NH * self.DH).astype("float32")
+        got = paged_decode_attention(q, kq, vq, btab, pos, self.NH,
+                                     scale=0.25, k_scale=ks, v_scale=vs)
+        ref = paged_decode_attention(
+            q, kq.astype("float32") * ks, vq.astype("float32") * vs, btab,
+            pos, self.NH, scale=0.25, backend="xla")
+        np.testing.assert_allclose(got, ref, rtol=1e-6, atol=1e-6)
+
+
 def test_fuse_recurrent_cell_pass_preserves_stacked_lstm_training(rng):
     """stacked_lstm_net + Adam, 3 steps: losses AND updated parameters are
     identical with the fuse pass on vs off — forward and gradient of the

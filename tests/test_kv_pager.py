@@ -22,7 +22,11 @@ Covers the paging contract end to end:
   span named;
 - census/watermark reconciliation: kv_cache category == pool bytes,
   used watermark == used blocks x per-block bytes;
-- the paged tick compiles through the r06 fused decode path.
+- the paged tick's cache read is ONE `paged_decode_attention` op a layer:
+  the Pallas kernel (interpret mode here) against the composite on ragged
+  positions, idle slots, shared and permuted tables and garbage beyond
+  the position; the in-place row write against a numpy reference; and the
+  lowered tick computes nothing of pool shape.
 """
 
 import numpy as np
@@ -128,6 +132,29 @@ class TestRadixPrefixIndex:
         pool.check()
 
 
+def _run_write(pool, new, blocks, offs):
+    n, nh, dh = new.shape
+    with pt.program_guard(pt.Program(), pt.Program()):
+        c = layers.data(name="pc", shape=list(pool.shape),
+                        dtype="float32", append_batch_size=False)
+        nv = layers.data(name="pn", shape=[n, nh, dh], dtype="float32",
+                         append_batch_size=False)
+        b = layers.data(name="pb", shape=[n], dtype="int64",
+                        append_batch_size=False)
+        o = layers.data(name="po", shape=[n], dtype="int64",
+                        append_batch_size=False)
+        out = layers.paged_cache_write(c, nv, b, o)
+        return pt.Executor().run(
+            feed={"pc": pool, "pn": new, "pb": blocks, "po": offs},
+            fetch_list=[out])[0]
+
+
+def _run_write_declared(pool, new, blocks, offs):
+    """The write into the pool as the tick declares it, read back as
+    [NB, nh, BS, dh]."""
+    return _run_write(_declared(pool), new, blocks, offs).reshape(pool.shape)
+
+
 class TestPagedCacheWriteOp:
     def test_parity_vs_numpy(self, rng):
         NB, nh, bs, dh = 6, 2, 4, 3
@@ -135,22 +162,215 @@ class TestPagedCacheWriteOp:
         new = rng.randn(2, nh, dh).astype("float32")
         blocks = np.array([2, 5], "int64")
         offs = np.array([1, 3], "int64")
-        c = layers.data(name="pc", shape=[NB, nh, bs, dh],
-                        dtype="float32", append_batch_size=False)
-        n = layers.data(name="pn", shape=[2, nh, dh], dtype="float32",
-                        append_batch_size=False)
-        b = layers.data(name="pb", shape=[2], dtype="int64",
-                        append_batch_size=False)
-        o = layers.data(name="po", shape=[2], dtype="int64",
-                        append_batch_size=False)
-        out = layers.paged_cache_write(c, n, b, o)
-        got = pt.Executor().run(
-            feed={"pc": pool, "pn": new, "pb": blocks, "po": offs},
-            fetch_list=[out])[0]
+        got = _run_write(pool, new, blocks, offs)
         ref = pool.copy()
         for i in range(2):
             ref[blocks[i], :, offs[i], :] = new[i]
         np.testing.assert_allclose(got, ref, rtol=1e-6)
+
+    @pytest.mark.parametrize("blocks,offs", [
+        ([3, 1, 4], [0, 3, 2]),            # distinct blocks
+        ([2, 2, 2], [0, 1, 3]),            # one block, three rows
+        ([0, 5, 0, 0], [0, 2, 0, 0]),      # idle slots: duplicate null rows
+        ([5, 0, 1, 0], [3, 1, 0, 1]),      # null rows between live ones
+    ], ids=["distinct", "same_block", "null_dups", "null_mixed"])
+    @pytest.mark.parametrize("dh", [8, 64], ids=["plain", "lane_dense"])
+    def test_in_place_rows_vs_numpy(self, rng, blocks, offs, dh):
+        """Rows only: every element outside the targeted rows is the
+        pool's own, bit for bit; a live target holds its row; duplicate
+        targets (only ever the null block) hold ONE of the rows sent.
+        dh 64 packs two positions to a 128-lane row: the pool is declared
+        [NB, nh, 2, 128] and the row lands at a lane offset."""
+        NB, nh, bs = 6, 2, 4
+        pool = rng.randn(NB, nh, bs, dh).astype("float32")
+        new = rng.randn(len(blocks), nh, dh).astype("float32")
+        assert (_declared(pool).shape[-1] == 128) == (dh == 64)
+        got = _run_write_declared(pool, new, np.array(blocks, "int64"),
+                                  np.array(offs, "int64"))
+        touched = np.zeros((NB, bs), bool)
+        for i, (b, o) in enumerate(zip(blocks, offs)):
+            touched[b, o] = True
+            sent = [new[j] for j in range(len(blocks))
+                    if (blocks[j], offs[j]) == (b, o)]
+            assert any(np.array_equal(got[b, :, o, :], r) for r in sent)
+            if len(sent) == 1:
+                np.testing.assert_array_equal(got[b, :, o, :], new[i])
+        keep = ~touched[:, None, :, None] & np.ones_like(pool, bool)
+        np.testing.assert_array_equal(got[keep], pool[keep])
+
+
+# -- the read: kernel (Pallas interpret mode) against the composite ---------
+
+_NBK, _NH, _BS, _DH, _NLB = 23, 4, 8, 16, 5     # span T = 40
+
+
+def _attn_case(rng, name):
+    """(q, k_pool, v_pool, btab, pos) for one named case. Tables map only
+    what the positions need unless the case says otherwise; unmapped
+    entries stay 0, the null block."""
+    S = 4
+    k_pool = rng.randn(_NBK, _NH, _BS, _DH).astype("float32")
+    v_pool = rng.randn(_NBK, _NH, _BS, _DH).astype("float32")
+    q = rng.randn(S, 1, _NH * _DH).astype("float32")
+    T = _NLB * _BS
+    pos = {"ragged": [0, _BS - 1, _BS, 2 * _BS + 3],
+           "full_span": [T - 1, T - 1, _BS + 1, T - _BS],
+           "idle_slots": [0, 2 * _BS + 5, 0, 0],
+           "shared_prefix": [3 * _BS + 2, 3 * _BS + 6, 2 * _BS, 5],
+           "permuted": [T - 1, 3 * _BS, _BS + 4, 2 * _BS - 1],
+           "garbage": [0, _BS - 2, 2 * _BS + 1, 3 * _BS]}[name]
+    pos = np.array(pos, "int64")
+    ids = list(rng.permutation(np.arange(1, _NBK)))
+    if name != "permuted":
+        ids = sorted(ids)
+    btab = np.zeros((S, _NLB), "int64")
+    for s in range(S):
+        if name == "idle_slots" and pos[s] == 0 and s != 1:
+            continue                       # btab row all null, pos 0
+        for j in range(pos[s] // _BS + 1):
+            btab[s, j] = ids.pop()
+    if name == "shared_prefix":
+        btab[1, :3] = btab[0, :3]          # two slots, same physical blocks
+        btab[2, :2] = btab[0, :2]
+    return q, k_pool, v_pool, btab, pos
+
+
+def _declared(pool):
+    """A [NB, nh, BS, dh] pool as the tick declares it (lane-dense where a
+    head's rows pack 128 lanes: the same values in the same order)."""
+    from paddle_tpu.ops.tensor_ops import pool_block_shape
+    nb, nh, bs, dh = pool.shape
+    return pool.reshape((nb,) + pool_block_shape(nh, bs, dh))
+
+
+def _attn(backend, q, k_pool, v_pool, btab, pos, declared=True):
+    from paddle_tpu.fusion import paged_decode_attention
+    if declared:
+        k_pool, v_pool = _declared(k_pool), _declared(v_pool)
+    return np.asarray(paged_decode_attention(
+        q, k_pool, v_pool, btab, pos.astype("float32").reshape(-1, 1, 1),
+        k_pool.shape[1], scale=(q.shape[-1] // k_pool.shape[1]) ** -0.5,
+        backend=backend))
+
+
+class TestPagedDecodeAttentionKernel:
+    @pytest.mark.parametrize("case", ["ragged", "full_span", "idle_slots",
+                                      "shared_prefix", "permuted"])
+    def test_kernel_matches_composite(self, rng, case):
+        args = _attn_case(rng, case)
+        ref = _attn("xla", *args)
+        got = _attn("pallas_interpret", *args)
+        np.testing.assert_allclose(got, ref, rtol=1e-5, atol=1e-5)
+
+    @pytest.mark.parametrize("backend", ["xla", "pallas_interpret"])
+    def test_garbage_beyond_the_position_is_never_attended(self, rng,
+                                                           backend):
+        """Rows past `pos` in the last live block, every unmapped block
+        and the null block may hold anything: the output is the same bit
+        for bit."""
+        q, k_pool, v_pool, btab, pos = _attn_case(rng, "garbage")
+        clean = _attn(backend, q, k_pool, v_pool, btab, pos)
+        k2, v2 = k_pool.copy(), v_pool.copy()
+        live = set()
+        for s in range(len(pos)):
+            last = pos[s] // _BS
+            live.update(int(b) for b in btab[s, :last + 1])
+            for pool in (k2, v2):
+                pool[btab[s, last], :, pos[s] % _BS + 1:, :] = 1e4
+        for b in range(_NBK):
+            if b not in live:
+                k2[b], v2[b] = -3e4, 7e4
+        np.testing.assert_array_equal(
+            _attn(backend, q, k2, v2, btab, pos), clean)
+
+    @pytest.mark.parametrize("nh,bs,dh", [(2, 16, 64), (4, 8, 32),
+                                          (2, 8, 128)])
+    def test_kernel_at_other_packings(self, rng, nh, bs, dh):
+        """128 // dh positions to a 128-lane row: two (the benchmark's 16
+        rows of 64), four, one."""
+        S, NB, NLB = 3, 9, 3
+        k_pool = rng.randn(NB, nh, bs, dh).astype("float32")
+        v_pool = rng.randn(NB, nh, bs, dh).astype("float32")
+        q = rng.randn(S, 1, nh * dh).astype("float32")
+        btab = np.array([[3, 7, 1], [5, 0, 0], [8, 2, 0]], "int64")
+        pos = np.array([3 * bs - 1, bs - 3, bs + 1], "int64")
+        assert _declared(k_pool).shape[-1] == 128
+        ref = _attn("xla", q, k_pool, v_pool, btab, pos, declared=False)
+        np.testing.assert_allclose(
+            _attn("xla", q, k_pool, v_pool, btab, pos), ref,
+            rtol=1e-6, atol=1e-6)
+        np.testing.assert_allclose(
+            _attn("pallas_interpret", q, k_pool, v_pool, btab, pos), ref,
+            rtol=1e-5, atol=1e-5)
+
+    def test_matches_dense_attention(self, rng):
+        """The composite against attention written out over each slot's
+        own contiguous K/V: the table indirection and the position mask
+        mean what they say."""
+        q, k_pool, v_pool, btab, pos = _attn_case(rng, "permuted")
+        got = _attn("xla", q, k_pool, v_pool, btab, pos)
+        for s in range(len(pos)):
+            n = pos[s] + 1
+            k = np.concatenate([k_pool[b] for b in btab[s]], axis=1)[:, :n]
+            v = np.concatenate([v_pool[b] for b in btab[s]], axis=1)[:, :n]
+            qh = q[s, 0].reshape(_NH, _DH)
+            sc = np.einsum("hd,htd->ht", qh, k) * _DH ** -0.5
+            w = np.exp(sc - sc.max(-1, keepdims=True))
+            w /= w.sum(-1, keepdims=True)
+            ref = np.einsum("ht,htd->hd", w, v).reshape(-1)
+            np.testing.assert_allclose(got[s, 0], ref, rtol=2e-5, atol=2e-5)
+
+
+class TestPagedAttentionLowering:
+    @pytest.mark.parametrize("backend,dtype,lanes,g,dh,quant,platform,want", [
+        ("pallas", "float32", 128, 1, 64, False, "tpu", "kernel"),
+        ("pallas", "float32", 128, 1, 128, False, "tpu", "kernel"),
+        ("pallas_interpret", "float32", 128, 1, 16, False, "cpu", "kernel"),
+        ("xla", "float32", 128, 1, 64, False, "cpu", "composite"),
+        (None, "float32", 128, 1, 64, False, "cpu", "composite"),  # a CPU
+        ("pallas", "float32", 128, 3, 64, False, "tpu", "composite"),  # G>1
+        ("pallas", "int8", 128, 1, 64, True, "tpu", "composite"),  # kv_quant
+        ("pallas", "float32", 8, 1, 8, False, "tpu", "composite"),   # tiny
+        ("pallas", "float32", 256, 1, 256, False, "tpu", "composite"),
+    ])
+    def test_chosen_from_what_the_op_sees(self, backend, dtype, lanes, g, dh,
+                                          quant, platform, want):
+        from paddle_tpu.fusion import paged_attention_lowering
+        assert paged_attention_lowering(dtype, lanes, g, dh, quant,
+                                        backend=backend,
+                                        platform=platform) == want
+
+    def test_composite_on_a_tpu_is_an_error_not_a_fallback(self):
+        """The backend a program gets here is "xla" (a CPU): on a TPU that
+        selection, for a shape the kernel serves, raises. A caller that
+        ASKS for the composite (the smoke's reference) gets it."""
+        from paddle_tpu.fusion import paged_attention_lowering
+        with pytest.raises(RuntimeError, match="not a fallback"):
+            paged_attention_lowering("float32", 128, 1, 64, False,
+                                     platform="tpu")
+        assert paged_attention_lowering(
+            "float32", 128, 1, 64, False, backend="xla",
+            platform="tpu") == "composite"
+
+    def test_engine_reports_its_lowering(self, engines):
+        _, paged, _ = engines
+        assert paged.stats()["paged_attention_lowering"] == "composite"
+        text = paged.metrics_registry.expose()
+        assert "ptpu_engine_paged_attention_kernel 0" in text
+
+    def test_tick_span_counts_the_blocks_it_reads(self, engines):
+        from paddle_tpu.observability import tracing
+        _, _, unshared = engines                       # no prefix hits
+        mark = tracing.mark()
+        unshared.submit([7, 8, 9, 1, 2], max_new=3)    # fed 0..6: bs = 4
+        unshared.submit([4, 5], max_new=1)             # fed 0..1
+        unshared.run_until_idle()
+        counts = [s.attrs["kv_blocks"] for s in tracing.spans_since(mark)
+                  if s.name == "engine/tick"]
+        # a slot at position f spans f // 4 + 1 blocks: two ticks with
+        # both slots in their first block, two more of the long one
+        # alone there, then three in its second
+        assert counts == [2, 2, 1, 1, 2, 2, 2]
 
 
 class TestDecodeIdentity:
@@ -291,18 +511,89 @@ class TestCensusReconciliation:
                 <= board["kv_cache_bytes"]["current"])
 
 
+def _pool_shaped_results(text, pool_type):
+    """(op, line) of every instruction in the body of a lowered module
+    whose RESULT has the pool's type (`%x = op ... : ... -> type`, or
+    `... : type` for an op whose operands share the result's type)."""
+    out = []
+    for ln in text.splitlines():
+        ln = ln.strip()
+        if not ln.startswith("%") or " = " not in ln:
+            continue
+        tail = ln.rsplit("->", 1)[-1] if "->" in ln else ln.rsplit(":", 1)[-1]
+        if pool_type in tail:
+            out.append((ln.split(" = ", 1)[1].split()[0].split("(")[0], ln))
+    return out
+
+
 class TestFusedDecodeStructure:
-    def test_paged_tick_fuses_attention(self):
-        from paddle_tpu.framework.passes import FuseDecodeAttentionPass
+    DIMS = dict(n_slots=3, n_blocks=7, block_size=4, blocks_per_req=2,
+                vocab=50, d_model=32, d_inner=64, num_heads=4, num_layers=2)
+
+    @pytest.mark.parametrize("kv_quant", [False, True])
+    def test_paged_tick_fuses_attention(self, kv_quant):
+        """One `paged_decode_attention` a layer reading the WRITTEN pools,
+        and no op that rebuilds the table view in the graph: no gather,
+        no transpose, no softmax chain for the decode pass to find."""
+        from paddle_tpu.framework.passes import apply_fusion_passes
         from paddle_tpu.models.transformer import \
             transformer_lm_paged_decode_tick
         main, startup = pt.Program(), pt.Program()
         with pt.program_guard(main, startup):
-            transformer_lm_paged_decode_tick(
-                n_slots=2, n_blocks=5, block_size=4, blocks_per_req=2,
-                vocab=50, d_model=32, d_inner=64, num_heads=4,
-                num_layers=2, cache_prefix="tstpgd")
-        FuseDecodeAttentionPass().apply(main)
-        fused = [op for op in main.blocks[0].ops
-                 if op.type == "fused_decode_attention"]
-        assert len(fused) == 2               # one per layer
+            next_ids, cache_names = transformer_lm_paged_decode_tick(
+                cache_prefix="tstpgd", kv_quant=kv_quant, **self.DIMS)
+        main = apply_fusion_passes(main, protected={next_ids.name})
+        ops = main.global_block().ops
+        types = [op.type for op in ops]
+        reads = [op for op in ops if op.type == "paged_decode_attention"]
+        assert len(reads) == self.DIMS["num_layers"]
+        for banned in ("gather", "transpose", "softmax",
+                       "fused_decode_attention"):
+            assert types.count(banned) == 0, banned
+        write = "paged_cache_write_quant" if kv_quant \
+            else "paged_cache_write"
+        assert types.count(write) == 2 * self.DIMS["num_layers"]
+        for op in reads:
+            # the read follows the write: its pools are the write's output
+            assert op.inputs["KPool"][0] in cache_names
+            assert op.inputs["VPool"][0] in cache_names
+            assert ("KScale" in op.inputs) == kv_quant
+
+    def test_lowered_tick_computes_nothing_of_pool_shape(self, monkeypatch):
+        """The tick as lowered for a TPU with the kernel in: the only
+        instructions whose result has a pool's shape are the in-place row
+        updates. (The gather → transpose → reshape view this replaces was
+        three passes over a whole pool per layer per tick; this is the
+        test that would have caught them.)"""
+        import jax.numpy as jnp
+        from paddle_tpu.ops import pallas_kernels
+        monkeypatch.setattr(pallas_kernels, "_auto_backend",
+                            lambda: "pallas")
+        # dh 16, 8 rows a block: one 128-lane row a head, lane-dense
+        d = dict(self.DIMS, d_model=64, num_heads=4, block_size=8)
+        eng = PagedKVEngine(
+            n_slots=d["n_slots"], vocab=d["vocab"],
+            max_len=d["block_size"] * d["blocks_per_req"],
+            d_model=d["d_model"], d_inner=d["d_inner"],
+            num_heads=d["num_heads"], num_layers=d["num_layers"],
+            block_size=d["block_size"], n_blocks=d["n_blocks"])
+        assert eng.stats()["paged_attention_lowering"] == "kernel"
+        c = eng._step._compiled
+        args = (tuple(jnp.asarray(eng._feeds[n]) for n in c.feed_names),
+                tuple(eng.scope.get(n) for n in c.ro_names),
+                tuple(eng.scope.get(n) for n in c.rw_names), np.uint32(0))
+        text = c.fn.trace(*args).lower(lowering_platforms=("tpu",)).as_text()
+        # the read and the write are one jitted function each, lowered once
+        # and called by every layer
+        assert text.count("tpu_custom_call") == 1
+        assert text.count("call @_paged_pallas(") == d["num_layers"]
+        pool = "tensor<%dx%dx1x128xf32>" % (d["n_blocks"], d["num_heads"])
+        assert pool in text
+        found = _pool_shaped_results(text, pool)
+        updates = [ln for op, ln in found
+                   if op == "stablehlo.dynamic_update_slice"]
+        writes = [ln for op, ln in found
+                  if op == "call" and "@_write_pool_rows(" in ln]
+        assert len(updates) + len(writes) == len(found), found
+        assert len(updates) == d["n_slots"]         # a row a slot, in place
+        assert len(writes) == 2 * d["num_layers"]   # K and V, per layer

@@ -1672,6 +1672,11 @@ COVERED_ELSEWHERE = {
     # table + pool program context — op parity + engine identity live in
     # the pager suite
     "paged_cache_write": "tests/test_kv_pager.py",
+    # the paged ticks' cache read through the block table: the Pallas
+    # kernel against the composite, and the composite against dense
+    # attention and the slot tick's fused op, live in the pager and
+    # fusion suites
+    "paged_decode_attention": "tests/test_kv_pager.py",
     # weight-only quantized serving (r21): payload+scale op pairs emitted
     # by quantize_params_pass — rewrite structure, dequant error bounds,
     # and decode parity live in the quant-serving suite

@@ -145,18 +145,37 @@ def test_stacked_lstm_train_step_lowers_with_default_flags(as_on_tpu):
     assert n_lstm >= 2 and _n_calls(text) == n_lstm
 
 
-@pytest.mark.parametrize("max_len,calls_per_layer", [(256, 1), (1024, 0)])
-def test_paged_tick_attention_path_by_span(as_on_tpu, max_len,
-                                           calls_per_layer):
-    """16 heads x 64: a 256-token span takes the fused decode kernel, one
-    custom call per layer; a 1024-token span is past its VMEM gate and
-    takes the composite (what chip_smoke.py's serve_lm phase asserts)."""
+@pytest.mark.parametrize("max_len", [256, 1024])
+def test_paged_tick_reads_the_pool_through_the_paged_kernel(as_on_tpu,
+                                                            max_len):
+    """16 heads x 64, 16-token blocks: the tick's cache read is the paged
+    decode kernel, one custom call per layer, at a 256-token span and at
+    the benchmark's 1024 alike (the slot kernel's VMEM gate, which sent the
+    1024 span to the composite, plays no part: a step holds a few blocks,
+    never the span). chip_smoke.py's serve_lm phase asserts the same."""
     from paddle_tpu.serving import PagedKVEngine
     n_layers = 2
     eng = PagedKVEngine(n_slots=2, vocab=64, max_len=max_len, d_model=1024,
-                        d_inner=64, num_heads=16, num_layers=n_layers)
+                        d_inner=64, num_heads=16, num_layers=n_layers,
+                        block_size=16)
+    assert eng.stats()["paged_attention_lowering"] == "kernel"
     text = _step_tpu_text(eng._step._compiled, eng._feeds, eng.scope)
-    assert _n_calls(text) == calls_per_layer * n_layers
+    # the read is one jitted function: lowered to Mosaic once, called by
+    # every layer (the compiled tick inlines it: a custom call a layer)
+    assert _n_calls(text) == 1
+    assert len(re.findall(r"call @_paged_pallas\b", text)) == n_layers
+
+
+def test_paged_decode_kernel_lowers_at_the_benchmark_shapes():
+    from paddle_tpu.fusion import paged_decode_attention
+    slots, blocks, heads, dh, per_req = 16, 1024, 16, 64, 64
+    text = _tpu_text(
+        lambda q, k, v, t, p: paged_decode_attention(
+            q, k, v, t, p, heads, scale=dh ** -0.5, backend="pallas"),
+        S((slots, 1, heads * dh), F32), S((blocks, heads, 8, 128), F32),
+        S((blocks, heads, 8, 128), F32), S((slots, per_req), I32),
+        S((slots, 1, 1), F32))
+    assert _n_calls(text) == 1
 
 
 def test_sharded_train_step_runs_flash_per_shard(as_on_tpu):

@@ -93,6 +93,20 @@ def _n_custom_calls(hlo: str) -> int:
     return hlo.count(_MOSAIC_CALL)
 
 
+def _count_compiles():
+    """The running list of XLA compilations (a load of a cached executable
+    counts), one entry each; the listener is registered on first use."""
+    if not hasattr(_count_compiles, "log"):
+        import jax.monitoring
+        log = _count_compiles.log = []
+
+        def on(event, duration, **kw):
+            if event == "/jax/core/compile/backend_compile_duration":
+                log.append(duration)
+        jax.monitoring.register_event_duration_secs_listener(on)
+    return _count_compiles.log
+
+
 def _free_device_memory():
     import paddle_tpu as pt
     pt.reset_default_programs()
@@ -216,6 +230,11 @@ def phase_serve_lm(scope, vocab=32000, max_len=1024, d_model=1024,
                         scope=scope)
     _check(all(scope.get(n) is v for n, v in trained.items()),
            "the engine re-initialized weights the scope already held")
+    _check(eng.stats()["prefill"] == "chunked",
+           f"the engine consumes prompts {eng.stats()['prefill']!r}, not "
+           f"in chunks through the mixed tick's lanes")
+    compiles = _count_compiles()
+    built = len(compiles)
 
     rng = np.random.RandomState(1)
     prompts = [rng.randint(0, vocab, (int(n),)).tolist()
@@ -259,14 +278,25 @@ def phase_serve_lm(scope, vocab=32000, max_len=1024, d_model=1024,
     _check(result["prefix_hits"] >= 1,
            "the repeated prompt did not hit the prefix cache")
 
+    # serving compiled the engine's two tick programs (the mixed tick on the
+    # first prompt, the decode tick on the first tick without one) and
+    # nothing else: every later shape of traffic runs one of the two
+    n_programs = len(compiles) - built
+    _check(n_programs == 2,
+           f"serving compiled {n_programs} programs; the engine has two "
+           f"(the decode tick and the mixed tick)")
     stats = eng.stats()
     lowering = stats["paged_attention_lowering"]
     n_calls = _n_custom_calls(eng.tick_hlo())
+    n_mixed = _n_custom_calls(eng.mixed_tick_hlo())
     want_calls = num_layers if expect_lowering == "kernel" else 0
-    _check((lowering, n_calls) == (expect_lowering, want_calls),
-           f"the engine reports its cache read as {lowering!r} and the "
-           f"compiled tick holds {n_calls} tpu_custom_calls; expected "
-           f"{expect_lowering!r} with {want_calls}")
+    # the mixed tick: the decode rows' kernel and the lanes' chunk kernel
+    _check((lowering, n_calls, n_mixed)
+           == (expect_lowering, want_calls, 2 * want_calls),
+           f"the engine reports its cache read as {lowering!r}, the "
+           f"compiled tick holds {n_calls} tpu_custom_calls and the mixed "
+           f"tick {n_mixed}; expected {expect_lowering!r} with "
+           f"{want_calls} and {2 * want_calls}")
     return {"compile_s": round(compile_s, 2),
             "run_s": round(result["run_s"], 2),
             "requests": n_requests + 1, "max_new": max_new,
@@ -274,7 +304,8 @@ def phase_serve_lm(scope, vocab=32000, max_len=1024, d_model=1024,
             "ticks": stats["ticks"], "tokens_out": stats["tokens_out"],
             "prefix_hits": result["prefix_hits"],
             "paged_attention_lowering": lowering,
-            "tpu_custom_calls": n_calls,
+            "prefill": stats["prefill"], "programs_compiled": n_programs,
+            "tpu_custom_calls": n_calls, "mixed_tpu_custom_calls": n_mixed,
             "block_size": eng.block_size, "n_blocks": eng.n_blocks}
 
 
@@ -443,6 +474,61 @@ def _check_paged(n_slots, n_blocks, block_size, num_heads, d_head,
     return err, float(np.max(np.abs(np.asarray(got) - np.asarray(ref))))
 
 
+def _check_paged_chunk(n_lanes, chunk, n_blocks, block_size, num_heads,
+                       d_head, blocks_per_req, backend, timing):
+    """The chunk-attention kernel (a prefill lane's read of the mixed tick)
+    against its composite, through a permuted table: a whole chunk behind a
+    four-block prefix beside a short last chunk, then a few rows from
+    position 0 beside an idle lane. Only a lane's real rows are compared;
+    everything returned must be finite."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.fusion import (paged_attention_lowering,
+                                   paged_decode_attention)
+    from paddle_tpu.ops.tensor_ops import pool_block_shape
+
+    rng = np.random.RandomState(11)
+    shape = (n_blocks,) + pool_block_shape(num_heads, block_size, d_head)
+    _check(paged_attention_lowering("float32", shape[-1], chunk, d_head,
+                                    False, backend=backend) == "kernel",
+           f"pools {shape} with {chunk} query rows do not take the chunk "
+           f"kernel")
+    k_pool, v_pool = (jnp.asarray(rng.randn(*shape), jnp.float32)
+                      for _ in range(2))
+    perm = rng.permutation(np.arange(1, n_blocks))
+
+    def run(be):
+        return jax.jit(lambda q, b, p, r: paged_decode_attention(
+            q, k_pool, v_pool, b, p, num_heads, scale=d_head ** -0.5,
+            backend=be, rows=r))
+    worst = 0.0
+    for pos, rows in (((4 * block_size, 2 * chunk), (chunk, chunk // 3 + 1)),
+                      ((0, 0), (5, 0))):
+        pos, rows = pos[:n_lanes], rows[:n_lanes]
+        btab = np.zeros((n_lanes, blocks_per_req), np.int32)
+        for lane in range(n_lanes):
+            n = -(-(pos[lane] + rows[lane]) // block_size) if rows[lane] else 0
+            btab[lane, :n] = perm[lane * blocks_per_req:][:n]
+        q = jnp.asarray(rng.randn(n_lanes, chunk, num_heads * d_head),
+                        jnp.float32)
+        args = (q, jnp.asarray(btab), jnp.asarray(pos, jnp.int32),
+                jnp.asarray(rows, jnp.int32))
+        got, c = _timed_first(run(backend), *args)
+        timing["compile_s"] += c
+        t0 = time.time()
+        with jax.default_matmul_precision("highest"):
+            ref = run("xla")(*args)
+        timing["run_s"] += time.time() - t0
+        _check(bool(jnp.isfinite(got).all()), "chunk attention: not finite")
+        for lane in range(n_lanes):
+            if rows[lane]:
+                worst = max(worst, _rel_err(got[lane, :rows[lane]],
+                                            ref[lane, :rows[lane]]))
+    # bf16 operands on the MXU against float32 at the highest precision
+    _check(worst <= TOL_BF16, f"paged chunk attention: error {worst}")
+    return worst
+
+
 def _check_recurrent(kind, batch, steps, hidden, backend, timing):
     import jax
     import jax.numpy as jnp
@@ -487,12 +573,13 @@ def _check_recurrent(kind, batch, steps, hidden, backend, timing):
 def phase_kernels(backend="pallas",
                   flash_shapes=((8, 16, 1024, 64), (1, 8, 8192, 128)),
                   decode=(16, 64, 640, 16), recurrent=(64, 64, 256),
-                  paged=(16, 1024, 16, 16, 64, 64)):
+                  paged=(16, 1024, 16, 16, 64, 64), chunk=(2, 128)):
     """Every Pallas kernel the package selects by default on a TPU, called
     directly, compiled by Mosaic, run, and compared with its own composite.
     decode = (heads, d_head, span, rows); recurrent = (batch, steps, hidden);
     paged = (slots, pool blocks, block size, heads, d_head, blocks a
-    request): the serving benchmark's tick."""
+    request): the serving benchmark's tick; chunk = (lanes, tokens a
+    lane) of its mixed tick, over the same pools."""
     timing = {"compile_s": 0.0, "run_s": 0.0}
     errs = {}
     for shape in flash_shapes:
@@ -501,6 +588,8 @@ def phase_kernels(backend="pallas",
             errs[name] = _check_flash(shape, seg, backend, timing)
     errs["decode_T%d" % decode[2]] = _check_decode(*decode, backend, timing)
     errs["paged_decode"], paged_abs = _check_paged(*paged, backend, timing)
+    errs["paged_chunk"] = _check_paged_chunk(*chunk, *paged[1:], backend,
+                                             timing)
     for kind in ("lstm", "gru"):
         errs["fused_" + kind] = _check_recurrent(kind, *recurrent, backend,
                                                  timing)

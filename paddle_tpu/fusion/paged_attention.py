@@ -20,8 +20,16 @@ algorithm:
   clamped to one index computes the same, and spends 0.1 ms a call on the
   1024 dead steps of an idle tick where this spends 0.008: PERF.md,
   PR 25.)
+- the chunk kernel (the same pools, G query rows a slot with G a multiple
+  of 8: a prefill lane of the mixed tick feeds a chunk of its prompt): one
+  grid step a lane; the lane's live blocks, up to position pos + rows - 1,
+  are DMA'd 128 key rows a step, double-buffered, and scored on the MXU a
+  head at a time (bf16 operands, float32 accumulation, float32 online
+  softmax); row g attends positions 0..pos+g, so the shared prefix's
+  blocks, earlier chunks and the chunk itself are one causal read. A lane
+  with no rows fetches nothing and returns zeros.
 - the composite (everything else: a CPU, int8 pools with their scale pools,
-  a verify window of G > 1 positions): gather the table view and run
+  a verify window of a few positions): gather the table view and run
   `decode_attention._decode_xla`, the math the slot tick runs.
 
 Which one a shape takes is `paged_attention_lowering`; on a TPU the composite
@@ -55,6 +63,7 @@ _MASKED = -1e9     # the additive bias `_mask_to_bias` gives a hidden key
 _M_INIT = -1e30    # running max before the first block (finite: no inf-inf)
 
 KERNEL, COMPOSITE = "kernel", "composite"
+_CHUNK_ROWS = 8    # the chunk kernel's query rows come in whole sublane tiles
 
 
 def paged_attention_lowering(pool_dtype, pool_lanes, n_query, d_head,
@@ -72,15 +81,15 @@ def paged_attention_lowering(pool_dtype, pool_lanes, n_query, d_head,
     backend = backend or _auto_backend()
     platform = platform or jax.default_backend()
     served = (jnp.dtype(pool_dtype) == jnp.float32 and not quantized
-              and n_query == 1 and pool_lanes == _LANES
-              and _LANES % d_head == 0)
+              and (n_query == 1 or n_query % _CHUNK_ROWS == 0)
+              and pool_lanes == _LANES and _LANES % d_head == 0)
     if served and backend != "xla":
         return KERNEL
     if served and platform == "tpu" and not asked:
         raise RuntimeError(
-            "paged_decode_attention: float32 pools with one query position "
-            f"(d_head {d_head}) take the Pallas kernel on a TPU, but the "
-            "backend selected is 'xla' (PTPU_DISABLE_PALLAS?); the "
+            f"paged_decode_attention: float32 pools with {n_query} query "
+            f"position(s) (d_head {d_head}) take a Pallas kernel on a TPU, "
+            "but the backend selected is 'xla' (PTPU_DISABLE_PALLAS?); the "
             "composite rebuilds the whole pool per layer per tick and is "
             "not a fallback here")
     return COMPOSITE
@@ -240,9 +249,162 @@ def _paged_pallas(q4, k_pool, v_pool, btab, pos, scale, interpret):
     return out.reshape(n_slots, nh, per_row, dh).sum(axis=2, keepdims=True)
 
 
+def _chunk_kernel(btab_ref, pos_ref, rows_ref, q_ref, k_hbm, v_hbm, o_ref,
+                  kbuf, vbuf, sem, m_ref, l_ref, acc_ref, *, n_logical,
+                  block_size, d_head, group, mxu_dtype):
+    """One grid step = one lane: C query rows at positions pos..pos+C-1, of
+    which the first `rows` are real. The lane's live blocks (those holding
+    a position up to pos + rows - 1) come `group` at a time: each DMA'd
+    whole from the pool into its rows of one of two VMEM buffers, the next
+    group in flight while this one is scored. A block stays [nh, R, 128]
+    as in `_paged_kernel` (row ρ of a block holds per_row positions, dh
+    lanes each); the keys of lane segment g are scored by the query tiled
+    over the segments and zeroed outside segment g, so no lane is ever
+    sliced: scores [C, group*R] a segment, a head at a time on the MXU.
+    The context accumulates per lane, [nh, C, 128], segment g holding the
+    share of the keys of segment g; the caller adds the segments. Every
+    block of a live group is fetched (a dead one is the null block or an
+    unwritten block of the request: finite, and masked by position): p = 0
+    times a stale VMEM row could be NaN."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    lane = pl.program_id(0)
+    per_row = _LANES // d_head
+    n_rows = block_size // per_row
+    nh, c = q_ref.shape[1], q_ref.shape[2]
+    key_rows = group * n_rows
+    pos = pos_ref[lane]
+    rows = rows_ref[lane]
+    n_live = jnp.where(rows > 0,
+                       jax.lax.div(pos + rows - 1, block_size) + 1, 0)
+    n_steps = jax.lax.div(n_live + group - 1, group)
+
+    def fetch(step, buf, wait):
+        """Start (or wait for) the DMAs of the `group` blocks of `step` into
+        buffer `buf`: a loop, not `group` unrolled descriptors, so that the
+        kernel's text stays short (it is lowered at every set-up)."""
+        def one(g, carry):
+            j = jnp.minimum(step * group + g, n_logical - 1)
+            blk = btab_ref[lane * n_logical + j]
+            dst = pl.ds(pl.multiple_of(g * n_rows, n_rows), n_rows)
+            for hbm, vmem, kv in ((k_hbm, kbuf, 0), (v_hbm, vbuf, 1)):
+                cp = pltpu.make_async_copy(
+                    hbm.at[blk], vmem.at[buf, :, dst, :], sem.at[kv, buf, g])
+                cp.wait() if wait else cp.start()
+            return carry
+        jax.lax.fori_loop(0, group, one, 0)
+
+    m_ref[...] = jnp.full(m_ref.shape, _M_INIT, jnp.float32)
+    l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+    acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    @pl.when(n_steps > 0)
+    def _():
+        fetch(0, 0, wait=False)
+
+    seg = jax.lax.broadcasted_iota(jnp.int32, (1, _LANES), 1) // d_head
+    q_pos = pos + jax.lax.broadcasted_iota(jnp.int32, (c, key_rows), 0)
+    key_row = jax.lax.broadcasted_iota(jnp.int32, (c, key_rows), 1)
+
+    def step_body(step, carry):
+        buf = jax.lax.rem(step, 2)
+
+        @pl.when(step + 1 < n_steps)
+        def _():
+            fetch(step + 1, 1 - buf, wait=False)
+
+        fetch(step, buf, wait=True)
+        key0 = step * (group * block_size)
+
+        def head_body(h, carry):
+            q = q_ref[0, h]                                    # [C, 128]
+            k = kbuf[buf, h].astype(mxu_dtype)                 # [keys/per_row, 128]
+            v = vbuf[buf, h].astype(mxu_dtype)
+            scores = []
+            for g in range(per_row):
+                qg = q if per_row == 1 else jnp.where(seg == g, q, 0.0)
+                sg = jax.lax.dot_general(
+                    qg.astype(mxu_dtype), k, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)        # [C, key_rows]
+                scores.append(jnp.where(
+                    key0 + key_row * per_row + g <= q_pos, sg, _MASKED))
+            m_prev = m_ref[h]                                  # [C, 1]
+            m_new = m_prev
+            for sg in scores:
+                m_new = jnp.maximum(m_new, jnp.max(sg, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            l_new = alpha * l_ref[h]
+            ctx = None
+            for g, sg in enumerate(scores):
+                p = jnp.exp(sg - m_new)
+                l_new = l_new + jnp.sum(p, axis=1, keepdims=True)
+                og = jax.lax.dot_general(
+                    p.astype(mxu_dtype), v, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)        # [C, 128]
+                if per_row > 1:
+                    og = jnp.where(seg == g, og, 0.0)
+                ctx = og if ctx is None else ctx + og
+            acc_ref[h] = alpha * acc_ref[h] + ctx
+            m_ref[h] = m_new
+            l_ref[h] = l_new
+            return carry
+
+        return jax.lax.fori_loop(0, nh, head_body, carry)
+
+    jax.lax.fori_loop(0, n_steps, step_body, 0)
+    l = l_ref[...]
+    o_ref[0] = (acc_ref[...] / jnp.where(l > 0.0, l, 1.0)).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def _chunk_pallas(q4, k_pool, v_pool, btab, pos, rows, scale, interpret):
+    """q4 [L, nh, C, dh] float32, pools [NB, nh, R, 128] → [L, nh, C, dh].
+    On the chip the MXU takes bf16 operands (what XLA's default precision
+    gives the composite's float32 matmuls there); interpreted, the
+    operands stay float32 and the composite is matched to rounding."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    n_lanes, nh, c, dh = q4.shape
+    n_rows = k_pool.shape[2]
+    per_row = _LANES // dh
+    n_logical = btab.shape[1]
+    group = max(1, min(_LANES // n_rows, n_logical))
+    qspec = pl.BlockSpec((1, nh, c, _LANES), lambda i, *_: (i, 0, 0, 0))
+    with jax.named_scope("paged_chunk_attention"):
+        out = pl.pallas_call(
+            functools.partial(
+                _chunk_kernel, n_logical=n_logical,
+                block_size=n_rows * per_row, d_head=dh, group=group,
+                mxu_dtype=jnp.float32 if interpret else jnp.bfloat16),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=3,
+                grid=(n_lanes,),
+                in_specs=[qspec, pl.BlockSpec(memory_space=pl.ANY),
+                          pl.BlockSpec(memory_space=pl.ANY)],
+                out_specs=qspec,
+                scratch_shapes=[
+                    pltpu.VMEM((2, nh, group * n_rows, _LANES), k_pool.dtype),
+                    pltpu.VMEM((2, nh, group * n_rows, _LANES), v_pool.dtype),
+                    pltpu.SemaphoreType.DMA((2, 2, group)),
+                    pltpu.VMEM((nh, c, 1), jnp.float32),
+                    pltpu.VMEM((nh, c, 1), jnp.float32),
+                    pltpu.VMEM((nh, c, _LANES), jnp.float32)]),
+            out_shape=jax.ShapeDtypeStruct((n_lanes, nh, c, _LANES),
+                                           q4.dtype),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",),
+                vmem_limit_bytes=32 * 1024 * 1024),
+            interpret=interpret,
+        )(btab.reshape(-1), pos, rows,
+          jnp.tile(q4 * scale, (1, 1, 1, per_row)), k_pool, v_pool)
+    return out.reshape(n_lanes, nh, c, per_row, dh).sum(axis=3)
+
+
 def paged_decode_attention(q, k_pool, v_pool, btab, pos, num_heads,
                            scale=1.0, backend=None, k_scale=None,
-                           v_scale=None):
+                           v_scale=None, rows=None):
     """Attention of each slot's G query positions over its paged cache.
 
     q [S, G, nh*dh]; k_pool / v_pool [NB, nh, R, L], either
@@ -253,7 +415,10 @@ def paged_decode_attention(q, k_pool, v_pool, btab, pos, num_heads,
     0..pos+g: the rows written earlier in the same forward included).
     Every logical block up to position pos + G - 1 must be mapped; blocks
     beyond it and the rows beyond the position are never attended,
-    whatever they hold. Returns [S, G, nh*dh]."""
+    whatever they hold. `rows` [S] (optional; G when absent) is how many of
+    a slot's G rows are real: the others, and every row of a slot with
+    none, return values nobody reads (finite), and only the blocks up to
+    position pos + rows - 1 need be mapped. Returns [S, G, nh*dh]."""
     s, g, h = q.shape
     dh = h // num_heads
     btab = btab.astype(jnp.int32)
@@ -262,9 +427,15 @@ def paged_decode_attention(q, k_pool, v_pool, btab, pos, num_heads,
         k_pool.dtype, k_pool.shape[-1], g, dh, k_scale is not None,
         backend=backend)
     q4 = q.reshape(s, g, num_heads, dh).transpose(0, 2, 1, 3)
-    if lowering == KERNEL:
+    interpret = backend == "pallas_interpret"
+    if lowering == KERNEL and g == 1:
         out = _paged_pallas(q4, k_pool, v_pool, btab, pos, float(scale),
-                            interpret=(backend == "pallas_interpret"))
+                            interpret=interpret)
+    elif lowering == KERNEL:
+        rows = (jnp.full((s,), g, jnp.int32) if rows is None
+                else rows.reshape(-1).astype(jnp.int32))
+        out = _chunk_pallas(q4, k_pool, v_pool, btab, pos, rows,
+                            float(scale), interpret=interpret)
     else:
         out = _paged_composite(q4, k_pool, v_pool, btab, pos, float(scale),
                                k_scale, v_scale)
@@ -279,5 +450,6 @@ def _paged_decode_attention_op(ctx, ins, attrs):
         ins["Q"][0], ins["KPool"][0], ins["VPool"][0],
         ins["BlockTable"][0], ins["Pos"][0], attrs["num_heads"],
         scale=attrs.get("scale", 1.0), backend=attrs.get("backend"),
-        k_scale=ks[0] if ks else None, v_scale=vs[0] if vs else None)
+        k_scale=ks[0] if ks else None, v_scale=vs[0] if vs else None,
+        rows=ins["Rows"][0] if ins.get("Rows") else None)
     return {"Out": [out]}

@@ -954,23 +954,28 @@ def cache_write(cache, new, pos, axis, batch_axis=None, out=None, name=None):
     return out
 
 
-def paged_cache_write(pool, new, block_ids, offsets, out=None, name=None):
+def paged_cache_write(pool, new, block_ids, offsets, out=None, name=None,
+                      chunk=None, chunk_block_ids=None):
     """Scatter one new KV row per tick slot into the paged block pool —
     the block-granular counterpart of `cache_write` (serving/kv_pager.py).
     `pool` is [n_blocks, nh, block_size, dh]; `new` is [S, nh, dh];
     `block_ids`/`offsets` give each slot's physical target
     (pool[block_ids[s], :, offsets[s], :]). Pass the pool variable as
     `out` to round-trip the persistable pool through the executor's
-    donated-state path, same as `cache_write(out=...)`."""
+    donated-state path, same as `cache_write(out=...)`. The mixed tick's
+    prefill lanes add `chunk` [L, C, H] (C block-aligned token rows a
+    lane) and `chunk_block_ids` [L * C/block_size]: whole blocks, written
+    by the same op so that a pool keeps one writer."""
     helper = LayerHelper("paged_cache_write", name=name)
     if out is None:
         out = helper.create_tmp_variable(dtype=dtype_name(pool.dtype),
                                          shape=pool.shape,
                                          stop_gradient=True)
-    helper.append_op(type="paged_cache_write",
-                     inputs={"Cache": [pool], "New": [new],
-                             "BlockIds": [block_ids],
-                             "Offsets": [offsets]},
+    inputs = {"Cache": [pool], "New": [new], "BlockIds": [block_ids],
+              "Offsets": [offsets]}
+    if chunk is not None:
+        inputs["Chunk"], inputs["ChunkBlockIds"] = [chunk], [chunk_block_ids]
+    helper.append_op(type="paged_cache_write", inputs=inputs,
                      outputs={"Out": [out]})
     return out
 
@@ -1005,15 +1010,18 @@ def paged_cache_write_quant(pool, scales, new, block_ids, offsets,
 
 def paged_decode_attention(q, k_pool, v_pool, block_table, pos, num_heads,
                            scale=1.0, k_scale=None, v_scale=None,
-                           name=None):
+                           name=None, n_rows=None):
     """Attention of each tick slot's query rows over its PAGED cache, read
     through the block table (fusion/paged_attention.py). `q` is [S, G, H]
-    (G = 1 for the decode tick, γ+1 for a verify window), `k_pool`/`v_pool`
+    (G = 1 for the decode tick, γ+1 for a verify window, the chunk length
+    for a prefill lane), `k_pool`/`v_pool`
     the written pools [n_blocks, nh, block_size, dh] (with `k_scale`/
     `v_scale` [n_blocks, nh, block_size, 1] when they are int8),
     `block_table` [S, NLB], `pos` the position of each slot's first query
-    row (S elements; row g attends cache positions 0..pos+g). Returns
-    [S, G, H]."""
+    row (S elements; row g attends cache positions 0..pos+g). `n_rows`
+    (S elements, optional) says how many of a slot's G rows are real: the
+    rest, and a slot with none, return values nobody reads, and the blocks
+    only they would attend are not fetched. Returns [S, G, H]."""
     helper = LayerHelper("paged_decode_attention", name=name)
     out = helper.create_tmp_variable(dtype=dtype_name(q.dtype),
                                      shape=q.shape, stop_gradient=True)
@@ -1021,6 +1029,8 @@ def paged_decode_attention(q, k_pool, v_pool, block_table, pos, num_heads,
               "BlockTable": [block_table], "Pos": [pos]}
     if k_scale is not None:
         inputs["KScale"], inputs["VScale"] = [k_scale], [v_scale]
+    if n_rows is not None:
+        inputs["Rows"] = [n_rows]
     helper.append_op(type="paged_decode_attention", inputs=inputs,
                      outputs={"Out": [out]},
                      attrs={"num_heads": num_heads, "scale": float(scale)})

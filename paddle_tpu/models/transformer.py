@@ -825,6 +825,120 @@ def transformer_lm_paged_decode_tick(n_slots, n_blocks, block_size,
     return next_ids, cache_names
 
 
+def transformer_lm_paged_mixed_tick(n_slots, n_lanes, chunk, n_blocks,
+                                    block_size, blocks_per_req, vocab=32000,
+                                    d_model=512, d_inner=2048, num_heads=8,
+                                    num_layers=6, dropout=0.0, packed=False,
+                                    cache_prefix="pgd"):
+    """ONE tick of decode rows AND prefill lanes over the paged KV pools:
+    `transformer_lm_paged_decode_tick`'s S decode rows (same feeds, same
+    pools and weights by name) plus L = `n_lanes` lanes that each feed up to
+    C = `chunk` consecutive prompt tokens of ONE request, starting on a
+    block boundary (`chunk` is a whole number of blocks). The S + L*C rows
+    go through every weight ONCE (the matmuls see one [S + L*C, H] batch);
+    only the attention differs by row kind:
+
+    - a decode row writes its K/V row and reads its cache as in the decode
+      tick (`paged_decode_attention`, one query position);
+    - a lane's chunk lands as whole blocks (`paged_cache_write(chunk=...)`,
+      one update a block, `lane_wblocks` naming each block's physical home
+      and 0, the null block, for the blocks a short chunk leaves unused)
+      and its C rows then attend causally over the request's table
+      (`lane_btab`): the shared prefix's blocks, earlier chunks and the
+      chunk itself — `paged_decode_attention` with C query rows, row c at
+      position `lane_pos` + c, of which the first `lane_rows` are real.
+
+    The vocabulary head runs on S + L rows: the decode rows and each lane's
+    LAST real row (`lane_last`, its index among the L*C lane rows), whose
+    argmax is the request's first sampled token when the chunk ends its
+    prompt. A lane with `lane_rows` 0 is idle (everything zero: it writes
+    the null block and fetches nothing); the rows of a short chunk beyond
+    `lane_rows` write rows beyond the request's position, which nothing
+    attends before a later write replaces them.
+
+    Inputs beyond the decode tick's: `lane_tok` [L,C] int64, `lane_pos`
+    [L,1,1] float32, `lane_btab` [L,NLB] int64, `lane_wblocks` [L*C/BS]
+    int64, `lane_rows` [L] int64, `lane_last` [L] int64.
+
+    Returns (next_ids [S+L,1] int64: the decode rows' then the lanes',
+    cache_names). It declares the decode tick's persistable variables and
+    no other, so it needs no startup run where that tick's state exists."""
+    S, L, C, NB, BS, NLB = (n_slots, n_lanes, chunk, n_blocks, block_size,
+                            blocks_per_req)
+    assert C % BS == 0, "a chunk is a whole number of blocks"
+    T, H, N = NLB * BS, d_model, n_slots + n_lanes * chunk
+    d_head = d_model // num_heads
+
+    def data(name, shape, dtype="int64"):
+        return layers.data(name=name, shape=shape, dtype=dtype,
+                           append_batch_size=False)
+
+    tok, pos = data("tick_tok", [S, 1]), data("tick_pos", [S, 1, 1],
+                                              "float32")
+    btab, wblock, woff = (data("tick_btab", [S, NLB]),
+                          data("tick_wblock", [S]), data("tick_woff", [S]))
+    ltok, lpos = data("lane_tok", [L, C]), data("lane_pos", [L, 1, 1],
+                                                "float32")
+    lbtab, lwblocks = (data("lane_btab", [L, NLB]),
+                       data("lane_wblocks", [L * C // BS]))
+    lrows, llast = data("lane_rows", [L]), data("lane_last", [L])
+    attn_dropout = 0.0 if packed else dropout
+
+    pools, _ = _paged_pool_vars(cache_prefix, NB, num_heads, BS, d_head,
+                                num_layers, False)
+    pe_table = positional_encoding_table(T, d_model).astype("float32")
+    lposc = _spec_window_positions(lpos, C)               # [L,C,1]
+    x = _gen_embed_step(
+        layers.concat([tok, layers.reshape(ltok, shape=[L * C, 1])], axis=0),
+        layers.concat([pos, layers.reshape(lposc, shape=[L * C, 1, 1])],
+                      axis=0),
+        "tok_emb", vocab, d_model, pe_table, dropout)     # [N,1,H]
+
+    def rows_of(t):
+        """[N,1,H] → (decode rows [S,1,H], lane rows [L,C,H])."""
+        return (layers.slice(t, axes=[0], starts=[0], ends=[S]),
+                layers.reshape(layers.slice(t, axes=[0], starts=[S],
+                                            ends=[N]), shape=[L, C, H]))
+
+    for i in range(num_layers):
+        q, kn, vn = (layers.fc(x, size=H, num_flatten_dims=2,
+                               bias_attr=False, use_bf16=True,
+                               name=f"l{i}_attn_{n}") for n in "qkv")
+        (qd, ql), (kd, kl), (vd, vl) = rows_of(q), rows_of(kn), rows_of(vn)
+        written = {}
+        for sname, rows, lanes in (("k", kd, kl), ("v", vd, vl)):
+            pool = pools[f"{sname}{i}"]
+            written[sname] = layers.paged_cache_write(
+                pool, layers.reshape(rows, shape=[S, num_heads, d_head]),
+                wblock, woff, out=pool, chunk=lanes,
+                chunk_block_ids=lwblocks)
+        scale = float(d_head) ** -0.5
+        ctx_d = layers.paged_decode_attention(
+            qd, written["k"], written["v"], btab, pos, num_heads,
+            scale=scale)
+        ctx_l = layers.paged_decode_attention(
+            ql, written["k"], written["v"], lbtab, lpos, num_heads,
+            scale=scale, n_rows=lrows)
+        ctx = layers.concat(
+            [ctx_d, layers.reshape(ctx_l, shape=[L * C, 1, H])], axis=0)
+        if attn_dropout:
+            ctx = layers.scale(ctx, scale=1.0 - attn_dropout)
+        attn = layers.fc(ctx, size=H, num_flatten_dims=2, bias_attr=False,
+                         use_bf16=True, name=f"l{i}_attn_o")
+        x = _add_norm(attn, x, dropout, True, name=f"l{i}_ln1")
+        f = ffn(x, d_model, d_inner, dropout, True, name=f"l{i}_ffn")
+        x = _add_norm(f, x, dropout, True, name=f"l{i}_ln2")
+    xd, xl = rows_of(x)
+    heads = layers.concat(
+        [xd, layers.reshape(
+            layers.gather(layers.reshape(xl, shape=[L * C, H]), llast),
+            shape=[L, 1, H])], axis=0)                    # [S+L,1,H]
+    logits = layers.fc(heads, size=vocab, num_flatten_dims=2, use_bf16=True,
+                       name="lm_head")
+    next_ids = layers.argmax(logits, axis=2)              # [S+L,1] int64
+    return next_ids, [v.name for v in pools.values()]
+
+
 def _paged_pool_vars(cache_prefix, n_blocks, num_heads, block_size, d_head,
                      num_layers, kv_quant):
     """Per-layer k/v pool variables for the paged ticks, [n_blocks] +

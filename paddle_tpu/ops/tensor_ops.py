@@ -8,6 +8,8 @@ label_smooth,lookup_table}_op.cc (SURVEY §2.2 tensor-manip family).
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -229,8 +231,21 @@ def _paged_cache_write(ctx, ins, attrs):
     (block, offset) targets are only ever the null block, where any
     write order is acceptable. Lowers to one `dynamic_update_slice` per
     row (`_write_pool_rows`): inside the executor's donated-state path the
-    pool updates in place and nothing of pool shape is computed."""
+    pool updates in place and nothing of pool shape is computed.
+
+    The mixed tick's prefill lanes ride the same op (a pool keeps ONE
+    writer a layer): optional `Chunk` [L, C, nh*dh] holds C consecutive
+    token rows a lane, starting on a block boundary, and `ChunkBlockIds`
+    [L * C/block_size] the physical block of each whole block of them
+    (0, the null block, for the blocks a short chunk leaves unused). Each
+    lands as ONE whole-block update (`_write_pool_blocks`), not
+    block_size row writes."""
     pool = ins["Cache"][0]
+    if ins.get("Chunk"):
+        pool = _write_pool_blocks(
+            pool, ins["Chunk"][0].astype(pool.dtype),
+            ins["ChunkBlockIds"][0].reshape(-1).astype(jnp.int32),
+            new_heads=ins["New"][0].shape[-2])
     new = ins["New"][0].astype(pool.dtype)
     blocks = ins["BlockIds"][0].reshape(-1).astype(jnp.int32)
     offs = ins["Offsets"][0].reshape(-1).astype(jnp.int32)
@@ -278,6 +293,28 @@ def _write_pool_rows(pool, rows, blocks, offs):
         pool = jax.lax.dynamic_update_slice(
             pool, rows[i][None, :, None, :],
             (blocks[i], zero, lin // lanes, lin % lanes))
+    return pool
+
+
+@functools.partial(jax.jit, static_argnames=("new_heads",))
+def _write_pool_blocks(pool, chunk, block_ids, new_heads):
+    """`chunk` [L, C, nh*dh]: lane l's C consecutive token rows, the first
+    on a block boundary. Its whole blocks ([nh, block_size, dh], laid out
+    as the pool declares a block) go to `pool[block_ids[i]]`, i over the
+    L * C/block_size blocks in order: one in-place `dynamic_update_slice`
+    of a whole contiguous block each (the row writes' lowering, a block at
+    a time). A later duplicate target wins: only the null block has any."""
+    block_shape = pool.shape[1:]
+    n = block_ids.shape[0]
+    d_head = chunk.shape[-1] // new_heads
+    blocks = chunk.reshape(n, -1, new_heads, d_head).transpose(0, 2, 1, 3)
+    blocks = blocks.reshape((n,) + block_shape)
+    zero = jnp.int32(0)
+    for i in range(n):      # lax slices: a traced jnp index costs a millisecond
+        pool = jax.lax.dynamic_update_slice(
+            pool, jax.lax.slice_in_dim(blocks, i, i + 1),
+            (jax.lax.index_in_dim(block_ids, i, keepdims=False),
+             zero, zero, zero))
     return pool
 
 

@@ -20,11 +20,15 @@ The pieces:
   rows 0..P-1 before they are ever exposed (asserted in
   tests/test_serving_engine.py).
 - `ContinuousBatchingEngine` — request queue + scheduler + tick loop.
-  Prefill is teacher-forced through the same tick program (the fed token
-  is the next prompt token until the prompt is consumed, then the slot's
-  previously sampled token), so one executable serves every mixture of
-  request phases. Dispatch rides `Executor.prepare` — the per-call
-  validation/signature-hash overhead is off the tick path.
+  Prefill is teacher-forced through the same tick program, one prompt
+  token a tick (the fed token is the next prompt token until the prompt
+  is consumed, then the slot's previously sampled token), so one
+  executable serves every mixture of request phases: the identity oracle
+  of the paged engine, whose prompts go many tokens a launch through the
+  prefill lanes of a second, mixed tick program (`PagedKVEngine`,
+  serving/kv_pager.py; `stats()["prefill"]` says which). Dispatch rides
+  `Executor.prepare` — the per-call validation/signature-hash overhead
+  is off the tick path.
 - `EngineServer`/`EngineClient` — generation RPC over the serving.py v2
   transport (vectored frames, batched writes): the engine thread ticks
   while reader/writer threads move bytes, so decode and socket I/O
@@ -241,6 +245,10 @@ class ContinuousBatchingEngine:
     fresh engine also runs standalone (random weights — tests, benches).
     """
 
+    #: how a prompt is consumed (`stats()["prefill"]`): one token a tick
+    #: here; the paged engine says "chunked" when it builds its mixed tick
+    prefill = "one_token"
+
     def __init__(self, n_slots: int = 8, vocab: int = 32000,
                  max_len: int = 64, d_model: int = 512, d_inner: int = 2048,
                  num_heads: int = 8, num_layers: int = 6,
@@ -253,7 +261,7 @@ class ContinuousBatchingEngine:
         from ..core import unique_name
         from ..framework.executor import Executor
         from ..framework.program import Program, program_guard
-        from ..framework.scope import Scope, global_scope
+        from ..framework.scope import global_scope
 
         enforce(policy in ("continuous", "static"),
                 f"unknown scheduling policy {policy!r}",
@@ -292,7 +300,7 @@ class ContinuousBatchingEngine:
                 num_layers, dropout, packed, cache_prefix)
         self.scope = scope or global_scope()
         self._exe = Executor()
-        self._init_missing_vars(Scope)
+        self._init_missing_vars(self._startup)
         # speculative decoding (serving/speculative.py): the draft model
         # COPIES the target's f32 weights under the reserved `draft_`
         # prefix, so it must be built BEFORE the target quantize pass
@@ -511,16 +519,38 @@ class ContinuousBatchingEngine:
                 total += int(np.prod(v.shape)) * np.dtype(v.dtype).itemsize
         return total
 
-    def _init_missing_vars(self, Scope):
-        """Run the startup program into a throwaway scope and copy ONLY
-        the vars the serving scope lacks: trained weights already present
-        (shared by name) must not be re-randomized; caches and any
-        untrained parameters get their init."""
-        tmp = Scope()
-        self._exe.run(self._startup, scope=tmp)
-        for name in tmp.local_var_names():
-            if not self.scope.has_var(name):
-                self.scope.set_var(name, tmp.get(name))
+    def _init_missing_vars(self, startup, aliases=None) -> List[str]:
+        """Give the serving scope the variables of `startup` it lacks, and
+        touch no other: only the startup ops that produce a missing
+        variable run (`Program.prune`), so trained weights already present
+        (shared by name) are neither re-randomized nor initialized a
+        second time into a scope that is thrown away; caches and any
+        untrained parameters get their init. A variable the weight
+        quantization pass erased (its payload lives on as `@qparam`)
+        counts as present. `aliases` maps a missing name to a resident
+        one it shares a buffer with instead of being initialized (the
+        speculative draft's weights). Returns the names it initialized."""
+        from ..framework.scope import Scope
+        scope = self.scope
+        produced = [n for op in startup.global_block().ops
+                    for n in op.output_names()]
+        aliases = aliases or {}
+        missing = []
+        for name in dict.fromkeys(produced):
+            if scope.has_var(name) or scope.has_var(name + "@qparam"):
+                continue
+            if name in aliases:
+                scope.set_var(name, scope.get(aliases[name]))
+            else:
+                missing.append(name)
+        if missing:
+            pruned = startup.prune(missing)
+            pruned.random_seed = startup.random_seed
+            tmp = Scope()
+            self._exe.run(pruned, scope=tmp)
+            for name in missing:
+                scope.set_var(name, tmp.get(name))
+        return missing
 
     # -- request intake ---------------------------------------------------
     def submit(self, prompt: Sequence[int], max_new: int,
@@ -702,6 +732,12 @@ class ContinuousBatchingEngine:
         if k < len(req.prompt) - 1:
             req.next_tok = req.prompt[k + 1]     # still prefilling
             return False
+        return self._emit_token(req, out_id)
+
+    def _emit_token(self, req: GenRequest, out_id: int) -> bool:
+        """`out_id` is the token sampled after `req`'s last consumed
+        position: stamp the first one, record it, make it the next fed
+        token. Returns True when the request just finished."""
         t = int(out_id)                          # sampled next token
         if req.first_token_at is None:
             req.first_token_at = time.time()
@@ -783,14 +819,7 @@ class ContinuousBatchingEngine:
                     self._fill_tick_feeds(active)
                     self._note_tick_writes(active)
                 with span("dispatch", "engine/launch"):
-                    if self._target_state_owner != "main":
-                        # a speculative verify forward ran since the last
-                        # plain tick and owns the donated target-cache
-                        # buffers — re-point the bound step at the live
-                        # arrays
-                        self._step.refresh_state()
-                        self._target_state_owner = "main"
-                    fetches = self._step.run_bound()   # zero-dispatch tick
+                    fetches = self._launch_tick()
                     self.target_forwards += 1
                 dispatch.attrs["active"] = len(active)
                 td = time.perf_counter()       # async dispatch returned
@@ -803,9 +832,11 @@ class ContinuousBatchingEngine:
                 # path)
                 tick.attrs.update(
                     self._tick_attrs, active=len(active),
-                    prefill=sum(1 for r in active.values()
-                                if r.fed < len(r.prompt) - 1),
                     request_ids=[r.request_id for r in active.values()])
+                if "prefill" not in tick.attrs:   # else: the lanes filled
+                    tick.attrs["prefill"] = sum(
+                        1 for r in active.values()
+                        if r.fed < len(r.prompt) - 1)
             with span("tick", "engine/wait"):
                 ids = np.asarray(fetches[0])   # realization barrier: the
                 #                    next tick's feed depends on it
@@ -822,11 +853,32 @@ class ContinuousBatchingEngine:
             self._stamp_kv_watermarks(active)
             self.busy_slot_ticks += len(active)
             self.total_slot_ticks += self.n_slots
-            finished = []
-            for slot, req in active.items():
-                if self._advance_slot(req, int(ids[slot, 0])):
-                    finished.append(req)
+            finished = self._commit_tick(active, ids)
         return finished
+
+    def _launch_tick(self):
+        """Launch the tick `_fill_tick_feeds` just filled; returns its
+        fetches (device arrays: nothing waits here)."""
+        return self._run_bound_step(self._step, "main")
+
+    def _run_bound_step(self, step, owner: str):
+        """Launch a bound step over the target's donated caches. Bound
+        steps that share them (the plain tick, the paged engine's mixed
+        tick, the speculative verify forward) each hold the buffers their
+        own last call returned: whichever runs after another re-points
+        itself at the live arrays first (`PreparedStep.refresh_state`, a
+        dict probe a cache); a run of one step never refreshes."""
+        if self._target_state_owner != owner:
+            step.refresh_state()
+            self._target_state_owner = owner
+        return step.run_bound()                # zero-dispatch tick
+
+    def _commit_tick(self, active: Dict[int, "GenRequest"],
+                     ids: np.ndarray) -> List[GenRequest]:
+        """Advance every slot that ticked with its row of `ids`; returns
+        the requests that finished."""
+        return [req for slot, req in active.items()
+                if self._advance_slot(req, int(ids[slot, 0]))]
 
     def _finalize_request(self, req: GenRequest):
         """Completion-side telemetry: the prefill/decode phase spans and
@@ -927,6 +979,9 @@ class ContinuousBatchingEngine:
                                 if self.last_tick_at is not None
                                 else None),
             "uptime_s": now - self._started_at,
+            # how a prompt is consumed: "one_token" a tick through the
+            # decode rows, or "chunked" through the paged engine's lanes
+            "prefill": self.prefill,
             "target_forwards": self.target_forwards,
             "tokens_per_target_forward": (
                 self.tokens_out / max(self.target_forwards, 1)),

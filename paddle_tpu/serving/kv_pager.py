@@ -642,6 +642,13 @@ class KVPager:
         }
 
 
+def prefill_chunk_tokens(block_size: int, blocks_per_req: int) -> int:
+    """The tokens one prefill lane of the mixed tick feeds: whole blocks,
+    128 tokens (a lane fills the MXU's rows) where the block table spans
+    four such chunks, a quarter of the span (at least a block) below."""
+    return block_size * max(1, min(128 // block_size, blocks_per_req // 4))
+
+
 class PagedKVEngine(ContinuousBatchingEngine):
     """Continuous batching over the paged KV cache: the slot engine's
     scheduler and tick loop, with the per-slot [max_len] KV rows
@@ -725,6 +732,23 @@ class PagedKVEngine(ContinuousBatchingEngine):
             self.kv_quant_freed_bytes = \
                 (int(n_blocks) - 1) * (per_blk_f32 - per_blk_i8)
         self.n_blocks = int(n_blocks)
+        # how a prompt is consumed, from what the engine is built with: in
+        # block-aligned chunks through the prefill lanes of a second,
+        # mixed tick program, unless a feature that walks one position a
+        # tick is on — a speculative round's rollback, the host tier's
+        # resume, beam search's own prefill over the top-k tick, and the
+        # int8 pools, whose write quantizes row by row
+        # (`paged_cache_write_quant` has no whole-block form; no cell
+        # runs them). One shape: `n_lanes` lanes of `chunk_tokens` tokens
+        self.n_lanes = 2
+        self.chunk_tokens = prefill_chunk_tokens(self.block_size,
+                                                 self.blocks_per_req)
+        spec_on = speculative is not None and speculative is not False
+        if not (spec_on or host_tier is not None or self.kv_quant
+                or self.topk_k):
+            self.prefill = "chunked"
+        self._mixed_step = None
+        self._lanes: Sequence[Tuple[GenRequest, int]] = ()
         enforce(self.n_blocks >= self.blocks_per_req + 1,
                 f"pool of {self.n_blocks} blocks cannot hold one "
                 f"full-span request ({self.blocks_per_req} blocks + the "
@@ -753,8 +777,59 @@ class PagedKVEngine(ContinuousBatchingEngine):
             eos_id=eos_id, scope=scope, policy=policy,
             cache_prefix=cache_prefix, quant=quant,
             speculative=speculative)
+        if self.prefill == "chunked":
+            self._build_mixed_step()
 
-    # -- tick program -----------------------------------------------------
+    # -- tick programs ----------------------------------------------------
+    def _build_mixed_step(self):
+        """The second compiled program: the decode tick's rows plus the
+        prefill lanes (`transformer_lm_paged_mixed_tick`), bound to the
+        decode tick's own feed arrays and the lanes'. It shares every
+        weight and pool with the decode tick by name and declares nothing
+        else, so there is no startup to run for it (and a weight-quantized
+        engine rewrites it onto the payloads the decode tick already
+        holds, as the verify tick does)."""
+        from ..core import unique_name
+        from ..framework.program import Program, program_guard
+        from ..models import transformer
+        d = self._builder_dims
+        self._mixed_program, startup = Program(), Program()
+        with program_guard(self._mixed_program, startup), \
+                unique_name.guard():
+            self._mixed_ids, _ = transformer.transformer_lm_paged_mixed_tick(
+                n_slots=self.n_slots, n_lanes=self.n_lanes,
+                chunk=self.chunk_tokens, n_blocks=self.n_blocks,
+                block_size=self.block_size,
+                blocks_per_req=self.blocks_per_req, vocab=d["vocab"],
+                d_model=d["d_model"], d_inner=d["d_inner"],
+                num_heads=d["num_heads"], num_layers=d["num_layers"],
+                dropout=d["dropout"], packed=d["packed"],
+                cache_prefix=self._cache_prefix)
+        self._init_missing_vars(startup)        # nothing, by construction
+        if self.quant is not None:
+            from ..framework.passes import get_pass
+            get_pass("quantize_params_pass",
+                     bits=8 if self.quant == "int8" else 4)(
+                self._mixed_program, self.scope)
+        L, C = self.n_lanes, self.chunk_tokens
+        self._lane_feeds = {
+            "lane_tok": np.zeros((L, C), np.int64),
+            "lane_pos": np.zeros((L, 1, 1), np.float32),
+            "lane_btab": np.zeros((L, self.blocks_per_req), np.int64),
+            "lane_wblocks": np.zeros((L * C // self.block_size,), np.int64),
+            "lane_rows": np.zeros((L,), np.int64),
+            "lane_last": np.zeros((L,), np.int64)}
+        self._mixed_feeds = {**self._feeds, **self._lane_feeds}
+        self._mixed_step = self._exe.prepare(
+            self._mixed_program, dict(self._mixed_feeds), [self._mixed_ids],
+            self.scope).bind(self._mixed_feeds)
+
+    def mixed_tick_hlo(self) -> str:
+        """`tick_hlo()` of the mixed tick (chunked engines only)."""
+        return self._exe.compiled_hlo(
+            self._mixed_program, dict(self._mixed_feeds), [self._mixed_ids],
+            self.scope)
+
     def _build_tick_program(self, n_slots, vocab, max_len, d_model,
                             d_inner, num_heads, num_layers, dropout,
                             packed, cache_prefix):
@@ -796,17 +871,30 @@ class PagedKVEngine(ContinuousBatchingEngine):
             return [self._next_ids, self._topk_logp, self._topk_ids]
         return [self._next_ids]
 
+    def _prefilling(self, req: GenRequest) -> bool:
+        """Does `req` still have prompt tokens for a lane to consume? (A
+        one-token engine feeds them through the decode rows: never.)"""
+        return self._mixed_step is not None and req.fed < len(req.prompt)
+
     def _fill_tick_feeds(self, active: Dict[int, GenRequest]):
-        super()._fill_tick_feeds(active)        # tok/pos rows
+        tok, pos = self._tok, self._pos
         btab = self._feeds["tick_btab"]
         wblock = self._feeds["tick_wblock"]
         woff = self._feeds["tick_woff"]
+        tok[:] = 0
+        pos[:] = 0.0
         btab[:] = 0                              # idle slots → null block
         wblock[:] = 0
         woff[:] = 0
         bs = self.block_size
         kv_blocks = 0
+        prefilling = []
         for slot, req in active.items():
+            if self._prefilling(req):
+                prefilling.append(req)   # a lane feeds it: no decode row
+                continue
+            tok[slot, 0] = req.next_tok
+            pos[slot, 0, 0] = float(req.fed)
             blocks = req.table.blocks
             btab[slot, :len(blocks)] = blocks
             lb, off = divmod(req.fed, bs)
@@ -814,6 +902,82 @@ class PagedKVEngine(ContinuousBatchingEngine):
             woff[slot] = off
             kv_blocks += lb + 1      # the blocks this slot's read spans
         self._tick_attrs["kv_blocks"] = kv_blocks
+        if self._mixed_step is not None:
+            self._fill_lanes(prefilling)
+
+    def _fill_lanes(self, prefilling: List[GenRequest]):
+        """Give the tick's lanes to the slots in prefill, in admission
+        order (`prefilling` comes in `_active`'s order, which is it), and
+        fill the lanes' feeds: each lane its request's next `chunk_tokens`
+        prompt tokens (fewer on the last chunk), from a block boundary. A
+        slot in prefill beyond the lanes waits this tick out. Counts what
+        the lanes take (`prefill`, `prefill_tokens`; `kv_blocks` adds the
+        blocks their reads span)."""
+        attrs = self._tick_attrs
+        lanes = []
+        tokens = 0
+        if prefilling:
+            lf = self._lane_feeds
+            for a in lf.values():
+                a[:] = 0                         # idle lane → null block
+            bs, C = self.block_size, self.chunk_tokens
+            cb = C // bs
+            for lane, req in enumerate(prefilling[:self.n_lanes]):
+                k0, blocks = req.fed, req.table.blocks
+                n = min(C, len(req.prompt) - k0)
+                b0, nb = k0 // bs, -(-n // bs)
+                lf["lane_tok"][lane, :n] = req.prompt[k0:k0 + n]
+                lf["lane_pos"][lane, 0, 0] = float(k0)
+                lf["lane_btab"][lane, :len(blocks)] = blocks
+                lf["lane_wblocks"][lane * cb:lane * cb + nb] = \
+                    blocks[b0:b0 + nb]
+                lf["lane_rows"][lane] = n
+                lf["lane_last"][lane] = lane * C + n - 1
+                lanes.append((req, n))
+                tokens += n
+                attrs["kv_blocks"] += b0 + nb
+        self._lanes = lanes
+        attrs["prefill"] = len(lanes)
+        attrs["prefill_tokens"] = tokens
+
+    def _launch_tick(self):
+        # a tick with a slot in prefill is the mixed program (the decode
+        # rows ride in it); any other is the decode tick, unchanged
+        if self._lanes:
+            return self._run_bound_step(self._mixed_step, "mixed")
+        return super()._launch_tick()
+
+    def _commit_tick(self, active: Dict[int, GenRequest],
+                     ids: np.ndarray) -> List[GenRequest]:
+        lanes = self._lanes
+        if not lanes:
+            return super()._commit_tick(active, ids)
+        # the decode rows first (a slot in prefill sent none: judged
+        # before any lane advances), then each lane's chunk with the
+        # lane's row of ids, which follow the S decode rows
+        finished = [req for slot, req in active.items()
+                    if not self._prefilling(req)
+                    and self._advance_slot(req, int(ids[slot, 0]))]
+        for lane, (req, n) in enumerate(lanes):
+            if self._advance_chunk(req, n, int(ids[self.n_slots + lane, 0])):
+                finished.append(req)
+        return finished
+
+    def _advance_chunk(self, req: GenRequest, n: int, out_id: int) -> bool:
+        """A lane consumed `req`'s next `n` prompt tokens: advance it,
+        offer every block the chunk completed to the prefix cache, and —
+        when that was the prompt's end — take `out_id` (the last row's
+        argmax) as the first sampled token, in this tick. Returns True
+        when the request just finished (`max_new` = 1, eos)."""
+        bs = self.block_size
+        k0 = req.fed
+        req.fed = k0 + n
+        for lb in range(k0 // bs, req.fed // bs):
+            self.pager.note_block_filled(req.table, lb, req.prompt)
+        if req.fed < len(req.prompt):
+            req.next_tok = req.prompt[req.fed]
+            return False
+        return self._emit_token(req, out_id)
 
     def _note_tick_writes(self, active: Dict[int, GenRequest]):
         # shadow-state sanitizer: every position this tick writes must
@@ -822,7 +986,11 @@ class PagedKVEngine(ContinuousBatchingEngine):
         san = self.pager.sanitizer
         if san is not None:
             for req in active.values():
-                san.note_write(req.table, req.fed)
+                if not self._prefilling(req):
+                    san.note_write(req.table, req.fed)
+            for req, n in self._lanes:
+                for p in range(req.fed, req.fed + n):
+                    san.note_write(req.table, p)
 
     # -- scheduler hooks --------------------------------------------------
     def _admit_request(self, req: GenRequest) -> bool:

@@ -180,7 +180,6 @@ class SpeculativeDecoder:
         from ..core import unique_name
         from ..framework.passes import get_pass
         from ..framework.program import Program, program_guard
-        from ..framework.scope import Scope
         from ..models import transformer
 
         eng = self.engine
@@ -203,16 +202,11 @@ class SpeculativeDecoder:
         # copy is BY REFERENCE — with an f32 draft over an f32 target
         # the two names share one device buffer until either side's
         # quantize pass erases its f32 name.
-        tmp = Scope()
-        eng._exe.run(self._draft_startup, scope=tmp)
-        for name in tmp.local_var_names():
-            if eng.scope.has_var(name):
-                continue
-            src = name[len(DRAFT_PREFIX):]
-            if name.startswith(DRAFT_PREFIX) and eng.scope.has_var(src):
-                eng.scope.set_var(name, eng.scope.get(src))
-            else:
-                eng.scope.set_var(name, tmp.get(name))
+        cut = len(DRAFT_PREFIX)
+        eng._init_missing_vars(self._draft_startup, aliases={
+            n: n[cut:] for op in self._draft_startup.global_block().ops
+            for n in op.output_names()
+            if n.startswith(DRAFT_PREFIX) and eng.scope.has_var(n[cut:])})
         if self.cfg.draft in ("int8", "int4") \
                 and _flags.get_flag("quant_params"):
             get_pass("quantize_params_pass",
@@ -227,7 +221,6 @@ class SpeculativeDecoder:
         from ..core import unique_name
         from ..framework.passes import get_pass
         from ..framework.program import Program, program_guard
-        from ..framework.scope import Scope
 
         eng = self.engine
         g = self.cfg.gamma + 1
@@ -237,22 +230,14 @@ class SpeculativeDecoder:
             (self._verify_ids, self._verify_logp,
              self.verify_cache_names) = eng._build_verify_tick(
                 self.cfg.gamma)
-        # target caches/weights are already resident; copy only what the
-        # verify startup would mint beyond them (none today — belt and
-        # braces against future builder state)
-        tmp = Scope()
-        eng._exe.run(self._verify_startup, scope=tmp)
-        for name in tmp.local_var_names():
-            if eng.scope.has_var(name):
-                continue
-            if eng.scope.has_var(name + "@qparam"):
-                # the f32 name was ERASED by the target quantize pass and
-                # its payload lives on as @qparam/@qscale — reinstalling
-                # the startup's fresh random init here would make the
-                # verify quantize pass below re-quantize garbage OVER the
-                # resident payloads (they're shared with the main tick)
-                continue
-            eng.scope.set_var(name, tmp.get(name))
+        # target caches/weights are already resident; initialize only what
+        # the verify startup would mint beyond them (none today — belt and
+        # braces against future builder state). A name the target quantize
+        # pass ERASED counts as resident (its payload lives on as
+        # @qparam/@qscale): a fresh random init under it would make the
+        # verify quantize pass below re-quantize garbage OVER the resident
+        # payloads, which the main tick shares
+        eng._init_missing_vars(self._verify_startup)
         if eng.quant is not None:
             get_pass("quantize_params_pass",
                      bits=8 if eng.quant == "int8" else 4)(

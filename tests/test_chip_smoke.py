@@ -45,7 +45,8 @@ def test_train_then_serve_from_the_same_scope():
         expect_lowering="composite", **_LM)
     assert serve["requests"] == 9 and serve["prefix_hits"] >= 1
     assert serve["paged_attention_lowering"] == "composite"
-    assert serve["tpu_custom_calls"] == 0
+    assert serve["tpu_custom_calls"] == serve["mixed_tpu_custom_calls"] == 0
+    assert serve["prefill"] == "chunked" and serve["programs_compiled"] == 2
 
 
 def test_train_resnet_phase():
@@ -58,10 +59,12 @@ def test_kernels_phase_through_the_interpreter():
     out = chip_smoke.phase_kernels(
         backend="pallas_interpret", flash_shapes=((1, 2, 256, 64),),
         decode=(4, 64, 1408, 2), recurrent=(8, 4, 128),
-        paged=(5, 40, 8, 4, 16, 6))     # 8 rows of 16: one 128-lane row
+        paged=(5, 40, 8, 4, 16, 6),     # 8 rows of 16: one 128-lane row
+        chunk=(2, 16))
     assert set(out["max_rel_err"]) == {
         "flash_1x2x256x64", "flash_1x2x256x64_seg", "decode_T1408",
-        "paged_decode", "fused_lstm", "fused_gru"}
+        "paged_decode", "paged_chunk", "fused_lstm", "fused_gru"}
+    assert out["max_rel_err"]["paged_chunk"] <= 1e-5
     assert out["paged_decode_max_abs_diff"] <= 1e-5
 
 

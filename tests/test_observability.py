@@ -469,29 +469,45 @@ def parallel_step():
         return spans, events
 
 
-@pytest.fixture(scope="module")
-def engine_life():
+def _engine_life(first_steps, **engine_kw):
     """A small PagedKVEngine serving three requests to the end, the second
-    and third sharing the first's two full prompt blocks; one caller span
-    and one annotation log around every engine.step()."""
+    and third sharing the first's two full prompt blocks (they arrive after
+    `first_steps` ticks of the first, which has filled both by then and is
+    still decoding); one caller span and one annotation log around every
+    engine.step()."""
     from paddle_tpu.core import unique_name
     from paddle_tpu.serving.kv_pager import PagedKVEngine
     _fresh_programs()
     with unique_name.guard():
         eng = PagedKVEngine(n_slots=2, max_len=24, block_size=4,
                             n_blocks=24, vocab=50, d_model=32, d_inner=64,
-                            num_heads=4, num_layers=2)
+                            num_heads=4, num_layers=2, **engine_kw)
         head = [7, 8, 9, 10, 11, 12, 13, 14]
         reqs = [eng.submit(head + [3, 4], max_new=3)]
         steps = []
-        for _ in range(8):       # the first fills both blocks of `head`
+        for _ in range(first_steps):
             steps.append(_recorded(eng.step))
+        assert eng.n_active == 1
         reqs.append(eng.submit(head + [5], max_new=2))
         reqs.append(eng.submit(head + [6, 7, 8], max_new=4))
         while eng.n_active or eng.n_pending:
             steps.append(_recorded(eng.step))
         assert all(r.done and r.error is None for r in reqs)
         return reqs, steps
+
+
+@pytest.fixture(scope="module")
+def engine_life():
+    """The engine as it is served: prompts go through the mixed tick's
+    lanes, four tokens a chunk here (three chunks fill the first prompt)."""
+    return _engine_life(3)
+
+
+@pytest.fixture(scope="module")
+def one_token_life():
+    """The same requests through an engine that feeds one prompt token a
+    tick (a top-k tick keeps the decode rows' prefill)."""
+    return _engine_life(8, topk_k=1)
 
 
 class TestHostPhaseSpans:
@@ -572,8 +588,10 @@ class TestHostPhaseSpans:
             for s in (s for s in spans if s.name == name):
                 seen += 1
                 assert by_id[s.parent_id].name == parent_of[name]
-        # every step admits, ticks and commits; only three finish
-        assert seen == (3 if name == "engine/finish" else len(steps))
+        # every step admits, ticks and commits; requests finish in two of
+        # them (the first two together, on the tick the second's two
+        # tokens and the first's three are out; then the third)
+        assert seen == (2 if name == "engine/finish" else len(steps))
 
     def test_engine_step_leaves_the_caller_no_time_of_its_own(self,
                                                               engine_life):
@@ -592,8 +610,27 @@ class TestHostPhaseSpans:
                 self._one(spans, "engine/wait").start
         assert float(np.median(shares)) < 0.1, shares
 
-    def test_tick_counts_prefill_where_it_happens(self, engine_life):
+    def test_tick_counts_the_lanes_it_fills(self, engine_life):
+        """A chunked engine's `prefill` is the lanes a tick filled and
+        `prefill_tokens` what they consumed: over a request's life
+        ceil(unshared prompt / chunk) lanes and its unshared prompt."""
         reqs, steps = engine_life
+        ticks = [self._one(spans, "engine/tick").attrs for spans, _ in steps]
+        for tick in ticks:
+            assert 0 <= tick["prefill"] <= tick["active"] \
+                == len(tick["request_ids"])
+            assert (tick["prefill_tokens"] > 0) == (tick["prefill"] > 0)
+        assert [r.shared_len for r in reqs] == [0, 8, 8]
+        unshared = [len(r.prompt) - r.shared_len for r in reqs]
+        assert unshared == [10, 1, 3]
+        assert sum(t["prefill_tokens"] for t in ticks) == sum(unshared)
+        assert sum(t["prefill"] for t in ticks) == \
+            sum(-(-n // 4) for n in unshared)
+        # the third request waits for a slot, not for a lane
+        assert [t["prefill_tokens"] for t in ticks[:6]] == [4, 4, 2, 1, 0, 3]
+
+    def test_tick_counts_prefill_where_it_happens(self, one_token_life):
+        reqs, steps = one_token_life
         prefill = {r.request_id: 0 for r in reqs}
         for spans, _ in steps:
             tick = self._one(spans, "engine/tick").attrs
@@ -629,7 +666,7 @@ class TestHostPhaseSpans:
         assert sum(a["shared_tokens"] for a in admits) == \
             sum(r.shared_len for r in reqs) == 16
         # the step that admitted the second request found the prefix cached
-        second = next(a for a in admits[8:] if a["admitted"])
+        second = next(a for a in admits[1:] if a["admitted"])
         assert second["shared_tokens"] >= 8
         assert all(0 <= a["pool_used"] <= a["pool_blocks"] == 24
                    for a in admits)

@@ -178,6 +178,41 @@ def test_paged_decode_kernel_lowers_at_the_benchmark_shapes():
     assert _n_calls(text) == 1
 
 
+def test_paged_chunk_kernel_lowers_at_the_benchmark_shapes():
+    """Two prefill lanes of 128 rows over the serving cell's pools."""
+    from paddle_tpu.fusion import paged_decode_attention
+    lanes, chunk, blocks, heads, dh, per_req = 2, 128, 1024, 16, 64, 64
+    text = _tpu_text(
+        lambda q, k, v, t, p, r: paged_decode_attention(
+            q, k, v, t, p, heads, scale=dh ** -0.5, backend="pallas",
+            rows=r),
+        S((lanes, chunk, heads * dh), F32), S((blocks, heads, 8, 128), F32),
+        S((blocks, heads, 8, 128), F32), S((lanes, per_req), I32),
+        S((lanes, 1, 1), F32), S((lanes,), I32))
+    assert _n_calls(text) == 1
+
+
+def test_mixed_tick_reads_the_pool_through_both_kernels(as_on_tpu):
+    """The mixed tick at the benchmark's widths and span: a layer's decode
+    rows take the decode kernel and its lanes the chunk kernel (128 rows a
+    lane), each lowered to Mosaic once; 16 + 2 * 128 rows share the
+    matmuls; the decode tick beside it holds what it held."""
+    from paddle_tpu.serving import PagedKVEngine
+    n_layers = 2
+    eng = PagedKVEngine(n_slots=16, vocab=64, max_len=1024, d_model=1024,
+                        d_inner=64, num_heads=16, num_layers=n_layers,
+                        block_size=16)
+    assert (eng.n_lanes, eng.chunk_tokens) == (2, 128)
+    text = _step_tpu_text(eng._mixed_step._compiled, eng._mixed_feeds,
+                          eng.scope)
+    assert _n_calls(text) == 2
+    assert len(re.findall(r"call @_paged_pallas\b", text)) == n_layers
+    assert len(re.findall(r"call @_chunk_pallas\b", text)) == n_layers
+    assert "tensor<272x1x1024xf32>" in text
+    decode = _step_tpu_text(eng._step._compiled, eng._feeds, eng.scope)
+    assert _n_calls(decode) == 1 and "_chunk_pallas" not in decode
+
+
 def test_sharded_train_step_runs_flash_per_shard(as_on_tpu):
     """Under ParallelExecutor's SPMD mode the flash calls sit inside a
     shard_map: their operands are the per-shard [B/dp * H/tp, T, D], not

@@ -86,6 +86,17 @@ def _paged_decode_tick():
     return None
 
 
+def _paged_mixed_tick():
+    """The paged engine's second compiled step: the decode rows plus two
+    prefill lanes of two blocks, ONE paged_cache_write a pool a layer for
+    rows and whole blocks alike."""
+    models.transformer.transformer_lm_paged_mixed_tick(
+        n_slots=2, n_lanes=2, chunk=8, n_blocks=9, block_size=4,
+        blocks_per_req=4, vocab=100, d_model=32, d_inner=64, num_heads=4,
+        num_layers=2)
+    return None
+
+
 def _quant_decode_tick():
     """The weight-only quantized engine's compiled step: the decode tick
     rewritten in place by quantize_params_pass (startup runs first so the
@@ -157,6 +168,7 @@ MODEL_BUILDERS = {
     "transformer_lm_tp": _tp_transformer,
     "transformer_lm_decode_tick": _decode_tick,
     "transformer_lm_paged_decode_tick": _paged_decode_tick,
+    "transformer_lm_paged_mixed_tick": _paged_mixed_tick,
     "transformer_lm_quant_decode_tick": _quant_decode_tick,
     "transformer_lm_draft_tick": _draft_tick,
     "transformer_lm_spec_verify_tick": _spec_verify_tick,

@@ -77,6 +77,16 @@ def _builders():
             num_layers=2)
         return None
 
+    def paged_mixed_tick():
+        # the paged engine's second compiled step: the decode rows plus
+        # two prefill lanes of a chunk each (rows and whole blocks through
+        # one paged_cache_write a pool)
+        models.transformer.transformer_lm_paged_mixed_tick(
+            n_slots=4, n_lanes=2, chunk=8, n_blocks=17, block_size=8,
+            blocks_per_req=4, vocab=1000, d_model=64, d_inner=128,
+            num_heads=4, num_layers=2)
+        return None
+
     def quant_decode_tick():
         # the weight-only quantized engine's compiled step: the decode
         # tick rewritten in place by quantize_params_pass (startup runs
@@ -151,6 +161,7 @@ def _builders():
         "transformer_lm_decode_tick": decode_tick,
         "transformer_lm_quant_decode_tick": quant_decode_tick,
         "transformer_lm_paged_decode_tick": paged_decode_tick,
+        "transformer_lm_paged_mixed_tick": paged_mixed_tick,
         "transformer_lm_draft_tick": draft_tick,
         "transformer_lm_spec_verify_tick": spec_verify_tick,
         "transformer_lm_paged_spec_verify_tick": paged_spec_verify_tick,

@@ -275,9 +275,10 @@ class ContinuousBatchingEngine:
         self.n_slots = n_slots
         self.max_len = max_len
         self.eos_id = eos_id
-        #: the model dims + cache namespace, kept for the auxiliary
-        #: program builders (speculative draft/verify ticks must match
-        #: the main tick's architecture and share its cache names)
+        #: the model dims + cache namespace, which every program builder
+        #: hook reads (`**self._builder_dims`): the speculative
+        #: draft/verify ticks and the mixed tick must match the main
+        #: tick's architecture and share its cache names
         self._cache_prefix = cache_prefix
         self._builder_dims = dict(
             vocab=vocab, d_model=d_model, d_inner=d_inner,
@@ -295,9 +296,7 @@ class ContinuousBatchingEngine:
         self._program, self._startup = Program(), Program()
         with program_guard(self._program, self._startup), \
                 unique_name.guard():
-            self._build_tick_program(
-                n_slots, vocab, max_len, d_model, d_inner, num_heads,
-                num_layers, dropout, packed, cache_prefix)
+            self._build_tick_program()
         self.scope = scope or global_scope()
         self._exe = Executor()
         self._init_missing_vars(self._startup)
@@ -338,7 +337,7 @@ class ContinuousBatchingEngine:
         #: counts `_fill_tick_feeds` takes where it walks the feeds anyway;
         #: they ride the `engine/tick` span (the paged engine: `kv_blocks`)
         self._tick_attrs: Dict[str, int] = {}
-        self._feeds = self._init_tick_feeds()
+        self._feeds = _feed_arrays(self._program)
         self._tok = self._feeds["tick_tok"]
         self._pos = self._feeds["tick_pos"]
         self._step = self._exe.prepare(
@@ -390,22 +389,15 @@ class ContinuousBatchingEngine:
             self.spec.finalize()
 
     # -- tick-program construction (overridden by PagedKVEngine) ----------
-    def _build_tick_program(self, n_slots, vocab, max_len, d_model,
-                            d_inner, num_heads, num_layers, dropout,
-                            packed, cache_prefix):
+    def _build_tick_program(self):
         """Build the compiled tick into the current default programs; must
         set `self._next_ids` (the [S,1] int64 fetch) and
         `self.cache_names` (the persistable KV state var names)."""
+        from ..models import transformer
         self._next_ids, self.cache_names = \
-            _decode_tick_builder(n_slots, vocab, max_len, d_model,
-                                 d_inner, num_heads, num_layers,
-                                 dropout, packed, cache_prefix)
-
-    def _init_tick_feeds(self) -> Dict[str, np.ndarray]:
-        """The per-tick feed arrays, reused across ticks (filled in place
-        by `_fill_tick_feeds` — the decode loop allocates nothing)."""
-        return {"tick_tok": np.zeros((self.n_slots, 1), np.int64),
-                "tick_pos": np.zeros((self.n_slots, 1, 1), np.float32)}
+            transformer.transformer_lm_decode_tick(
+                n_slots=self.n_slots, max_len=self.max_len,
+                cache_prefix=self._cache_prefix, **self._builder_dims)
 
     def _tick_fetches(self):
         return [self._next_ids]
@@ -625,18 +617,9 @@ class ContinuousBatchingEngine:
         TARGET's caches and weights, shared by name) into the current
         default programs; returns (ids, logp, cache_names)."""
         from ..models import transformer
-        d = self._builder_dims
         return transformer.transformer_lm_spec_verify_tick(
-            n_slots=self.n_slots, gamma=gamma, vocab=d["vocab"],
-            max_len=self.max_len, d_model=d["d_model"],
-            d_inner=d["d_inner"], num_heads=d["num_heads"],
-            num_layers=d["num_layers"], dropout=d["dropout"],
-            packed=d["packed"], cache_prefix=self._cache_prefix)
-
-    def _init_verify_feeds(self, g: int) -> Dict[str, np.ndarray]:
-        """The verify forward's reusable feed arrays (g = γ+1)."""
-        return {"spec_tok": np.zeros((self.n_slots, g), np.int64),
-                "spec_pos": np.zeros((self.n_slots, 1, 1), np.float32)}
+            n_slots=self.n_slots, gamma=gamma, max_len=self.max_len,
+            cache_prefix=self._cache_prefix, **self._builder_dims)
 
     def _fill_verify_row(self, feeds, slot: int, req: GenRequest,
                          g: int):
@@ -990,14 +973,18 @@ class ContinuousBatchingEngine:
         }
 
 
-def _decode_tick_builder(n_slots, vocab, max_len, d_model, d_inner,
-                         num_heads, num_layers, dropout, packed,
-                         cache_prefix):
-    from ..models import transformer
-    return transformer.transformer_lm_decode_tick(
-        n_slots=n_slots, vocab=vocab, max_len=max_len, d_model=d_model,
-        d_inner=d_inner, num_heads=num_heads, num_layers=num_layers,
-        dropout=dropout, packed=packed, cache_prefix=cache_prefix)
+def _feed_arrays(program, share=()) -> Dict[str, np.ndarray]:
+    """The feed arrays of a tick program, zeroed, made from the feeds its
+    builder declared (`layers.data`), in declaration order: the builder is
+    the one place that says a feed's name, shape and dtype. An engine
+    makes them once, binds its prepared step to them and fills them in
+    place every tick (the decode loop allocates nothing). A name in
+    `share` takes that array instead of a new one: a second program over
+    the same feeds runs on what the first one's fill wrote."""
+    share = dict(share)
+    return {v.name: (share[v.name] if v.name in share
+                     else np.zeros(v.shape, np.dtype(v.dtype)))
+            for v in program.global_block().vars.values() if v.is_data}
 
 
 # ---------------------------------------------------------------------------

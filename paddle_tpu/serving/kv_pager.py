@@ -95,7 +95,8 @@ from ..framework import offload as _offload
 from ..framework.offload import HostTierConfig
 from ..observability import memory as _obs_memory
 from ..observability import tracing as _tracing
-from .engine import ContinuousBatchingEngine, GenRequest, _ENGINE_SEQ
+from .engine import (ContinuousBatchingEngine, GenRequest, _ENGINE_SEQ,
+                     _feed_arrays)
 
 
 class BlockPool:
@@ -792,7 +793,6 @@ class PagedKVEngine(ContinuousBatchingEngine):
         from ..core import unique_name
         from ..framework.program import Program, program_guard
         from ..models import transformer
-        d = self._builder_dims
         self._mixed_program, startup = Program(), Program()
         with program_guard(self._mixed_program, startup), \
                 unique_name.guard():
@@ -800,26 +800,18 @@ class PagedKVEngine(ContinuousBatchingEngine):
                 n_slots=self.n_slots, n_lanes=self.n_lanes,
                 chunk=self.chunk_tokens, n_blocks=self.n_blocks,
                 block_size=self.block_size,
-                blocks_per_req=self.blocks_per_req, vocab=d["vocab"],
-                d_model=d["d_model"], d_inner=d["d_inner"],
-                num_heads=d["num_heads"], num_layers=d["num_layers"],
-                dropout=d["dropout"], packed=d["packed"],
-                cache_prefix=self._cache_prefix)
+                blocks_per_req=self.blocks_per_req,
+                cache_prefix=self._cache_prefix, **self._builder_dims)
         self._init_missing_vars(startup)        # nothing, by construction
         if self.quant is not None:
             from ..framework.passes import get_pass
             get_pass("quantize_params_pass",
                      bits=8 if self.quant == "int8" else 4)(
                 self._mixed_program, self.scope)
-        L, C = self.n_lanes, self.chunk_tokens
-        self._lane_feeds = {
-            "lane_tok": np.zeros((L, C), np.int64),
-            "lane_pos": np.zeros((L, 1, 1), np.float32),
-            "lane_btab": np.zeros((L, self.blocks_per_req), np.int64),
-            "lane_wblocks": np.zeros((L * C // self.block_size,), np.int64),
-            "lane_rows": np.zeros((L,), np.int64),
-            "lane_last": np.zeros((L,), np.int64)}
-        self._mixed_feeds = {**self._feeds, **self._lane_feeds}
+        self._mixed_feeds = _feed_arrays(self._mixed_program,
+                                        share=self._feeds)
+        self._lane_feeds = {n: a for n, a in self._mixed_feeds.items()
+                            if n not in self._feeds}
         self._mixed_step = self._exe.prepare(
             self._mixed_program, dict(self._mixed_feeds), [self._mixed_ids],
             self.scope).bind(self._mixed_feeds)
@@ -830,41 +822,30 @@ class PagedKVEngine(ContinuousBatchingEngine):
             self._mixed_program, dict(self._mixed_feeds), [self._mixed_ids],
             self.scope)
 
-    def _build_tick_program(self, n_slots, vocab, max_len, d_model,
-                            d_inner, num_heads, num_layers, dropout,
-                            packed, cache_prefix):
+    def _build_tick_program(self):
         from ..fusion.paged_attention import paged_attention_lowering
         from ..ops.tensor_ops import pool_block_shape
         from ..models import transformer
         # which lowering the tick's cache read takes, decided here by the
         # rule the op itself applies when the tick compiles; on a TPU it
         # raises rather than serve float32 pools from the composite
-        dh = d_model // num_heads
+        d = self._builder_dims
+        dh = d["d_model"] // d["num_heads"]
         self.paged_attention_lowering = paged_attention_lowering(
             "int8" if self.kv_quant else "float32",
-            pool_block_shape(num_heads, self.block_size, dh)[-1], 1, dh,
+            pool_block_shape(d["num_heads"], self.block_size, dh)[-1], 1, dh,
             self.kv_quant)
         outs = transformer.transformer_lm_paged_decode_tick(
-            n_slots=n_slots, n_blocks=self.n_blocks,
+            n_slots=self.n_slots, n_blocks=self.n_blocks,
             block_size=self.block_size,
-            blocks_per_req=self.blocks_per_req, vocab=vocab,
-            d_model=d_model, d_inner=d_inner, num_heads=num_heads,
-            num_layers=num_layers, dropout=dropout, packed=packed,
-            cache_prefix=cache_prefix, topk_k=self.topk_k,
-            kv_quant=self.kv_quant)
+            blocks_per_req=self.blocks_per_req,
+            cache_prefix=self._cache_prefix, topk_k=self.topk_k,
+            kv_quant=self.kv_quant, **d)
         if self.topk_k:
             (self._next_ids, self.cache_names,
              self._topk_logp, self._topk_ids) = outs
         else:
             self._next_ids, self.cache_names = outs
-
-    def _init_tick_feeds(self) -> Dict[str, np.ndarray]:
-        f = super()._init_tick_feeds()
-        f["tick_btab"] = np.zeros((self.n_slots, self.blocks_per_req),
-                                  np.int64)
-        f["tick_wblock"] = np.zeros((self.n_slots,), np.int64)
-        f["tick_woff"] = np.zeros((self.n_slots,), np.int64)
-        return f
 
     def _tick_fetches(self):
         if self.topk_k:
@@ -1277,23 +1258,12 @@ class PagedKVEngine(ContinuousBatchingEngine):
     # -- speculative-decoding hooks (serving/speculative.py) --------------
     def _build_verify_tick(self, gamma):
         from ..models import transformer
-        d = self._builder_dims
         return transformer.transformer_lm_paged_spec_verify_tick(
             self.n_slots, gamma, n_blocks=self.n_blocks,
             block_size=self.block_size,
-            blocks_per_req=self.blocks_per_req, vocab=d["vocab"],
-            d_model=d["d_model"], d_inner=d["d_inner"],
-            num_heads=d["num_heads"], num_layers=d["num_layers"],
-            dropout=d["dropout"], packed=d["packed"],
-            cache_prefix=self._cache_prefix, kv_quant=self.kv_quant)
-
-    def _init_verify_feeds(self, g):
-        f = super()._init_verify_feeds(g)
-        f["spec_btab"] = np.zeros((self.n_slots, self.blocks_per_req),
-                                  np.int64)
-        f["spec_wblock"] = np.zeros((self.n_slots, g), np.int64)
-        f["spec_woff"] = np.zeros((self.n_slots, g), np.int64)
-        return f
+            blocks_per_req=self.blocks_per_req,
+            cache_prefix=self._cache_prefix, kv_quant=self.kv_quant,
+            **self._builder_dims)
 
     def _fill_verify_row(self, feeds, slot, req, g):
         super()._fill_verify_row(feeds, slot, req, g)

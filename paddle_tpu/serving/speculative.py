@@ -68,6 +68,7 @@ import numpy as np
 
 from ..core.enforce import InvalidArgumentError, enforce
 from ..observability import tracing as _tracing
+from .engine import _feed_arrays
 
 #: reserved name prefix for draft-model state: the census classifier
 #: (framework/costs.state_category) maps `draft_*` weights — including
@@ -183,18 +184,14 @@ class SpeculativeDecoder:
         from ..models import transformer
 
         eng = self.engine
-        d = eng._builder_dims
+        dims = {**eng._builder_dims, "num_layers": self.draft_layers}
         self._draft_program, self._draft_startup = Program(), Program()
         with program_guard(self._draft_program, self._draft_startup), \
                 unique_name.guard():
             outs = transformer.transformer_lm_decode_tick(
-                n_slots=eng.n_slots, vocab=d["vocab"],
-                max_len=eng.max_len, d_model=d["d_model"],
-                d_inner=d["d_inner"], num_heads=d["num_heads"],
-                num_layers=self.draft_layers, dropout=d["dropout"],
-                packed=d["packed"],
+                n_slots=eng.n_slots, max_len=eng.max_len,
                 cache_prefix=eng._cache_prefix + "dr",
-                param_prefix=DRAFT_PREFIX, emit_logp=True)
+                param_prefix=DRAFT_PREFIX, emit_logp=True, **dims)
         self._draft_ids, self.draft_cache_names, self._draft_logp = outs
         # weight copy: draft_<w> <- <w> for every draft parameter whose
         # target twin is resident (trained or engine-initialized); the
@@ -242,10 +239,8 @@ class SpeculativeDecoder:
             get_pass("quantize_params_pass",
                      bits=8 if eng.quant == "int8" else 4)(
                 self._verify_program, eng.scope)
-        self._draft_feeds = {
-            "tick_tok": np.zeros((eng.n_slots, 1), np.int64),
-            "tick_pos": np.zeros((eng.n_slots, 1, 1), np.float32)}
-        self._verify_feeds = eng._init_verify_feeds(g)
+        self._draft_feeds = _feed_arrays(self._draft_program)
+        self._verify_feeds = _feed_arrays(self._verify_program)
         self._draft_step = eng._exe.prepare(
             self._draft_program, dict(self._draft_feeds),
             [self._draft_ids, self._draft_logp],

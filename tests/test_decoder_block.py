@@ -1,0 +1,214 @@
+"""The model is described in one place (ISSUE 31).
+
+- Every graph that runs decoder layers through a KV cache or the flash path
+  — the seven LM graphs and `transformer_generate` — builds each layer
+  through `models.transformer._decoder_block`, the one caller of
+  `_add_norm` outside the NMT training layers.
+- An engine's feed arrays are made from what the tick builder declared
+  (`serving.engine._feed_arrays`), so a feed exists on both sides or on
+  neither.
+"""
+
+import ast
+import inspect
+
+import numpy as np
+import pytest
+
+import paddle_tpu as pt
+from paddle_tpu import layers, serving
+from paddle_tpu.core import unique_name
+from paddle_tpu.framework.program import Program, program_guard
+from paddle_tpu.models import transformer as T
+from paddle_tpu.serving.engine import _feed_arrays
+from paddle_tpu.serving.kv_pager import prefill_chunk_tokens
+
+DIMS = dict(vocab=61, d_model=32, d_inner=64, num_heads=4, num_layers=2)
+NMT = dict(src_vocab=50, tgt_vocab=60, d_model=32, d_inner=64, num_heads=4,
+           num_layers=2)
+
+#: graph -> (builder, LayerNorms a layer)
+GRAPHS = {
+    "transformer_lm": (lambda: T.transformer_lm(max_len=16, **DIMS), 2),
+    "transformer_lm_generate": (
+        lambda: T.transformer_lm_generate(max_gen=8, beam_size=2, **DIMS), 2),
+    "decode_tick": (
+        lambda: T.transformer_lm_decode_tick(4, max_len=24, **DIMS), 2),
+    "spec_verify_tick": (
+        lambda: T.transformer_lm_spec_verify_tick(4, 3, max_len=24, **DIMS),
+        2),
+    "paged_decode_tick": (
+        lambda: T.transformer_lm_paged_decode_tick(4, 20, 4, 6, **DIMS), 2),
+    "paged_mixed_tick": (
+        lambda: T.transformer_lm_paged_mixed_tick(4, 2, 8, 20, 4, 6, **DIMS),
+        2),
+    "paged_spec_verify_tick": (
+        lambda: T.transformer_lm_paged_spec_verify_tick(4, 3, 20, 4, 6,
+                                                        **DIMS), 2),
+    "transformer_generate": (
+        lambda: T.transformer_generate(max_src_len=10, max_gen=8, beam_size=2,
+                                       **NMT), 3),
+}
+
+
+def _op_types(build):
+    main, startup = Program(), Program()
+    with program_guard(main, startup), unique_name.guard():
+        build()
+    return [op.type for b in main.blocks for op in b.ops]
+
+
+@pytest.mark.parametrize("graph", sorted(GRAPHS))
+def test_every_graph_builds_its_layers_through_the_one_block(graph,
+                                                             monkeypatch):
+    """Swap the norm inside `_decoder_block` (and nowhere else) for one
+    that leaves a mark: every layer of every graph carries the mark."""
+    build, norms = GRAPHS[graph]
+    before = _op_types(build)
+
+    block, add_norm = T._decoder_block, T._add_norm
+
+    def marked_norm(*a, **kw):
+        return layers.scale(add_norm(*a, **kw), scale=1.0, bias=0.0)
+
+    def marked_block(*a, **kw):
+        with monkeypatch.context() as m:
+            m.setattr(T, "_add_norm", marked_norm)
+            return block(*a, **kw)
+
+    monkeypatch.setattr(T, "_decoder_block", marked_block)
+    after = _op_types(build)
+    n_layers = DIMS["num_layers"]
+    assert after.count("scale") - before.count("scale") == norms * n_layers
+    assert after.count("layer_norm") == before.count("layer_norm")
+    assert len(after) == len(before) + norms * n_layers
+
+
+def test_the_block_is_the_one_place():
+    """`_add_norm` is called from the block and the two NMT training
+    layers only, and no builder spells a projection's name of its own."""
+    tree = ast.parse(inspect.getsource(T))
+    callers = set()
+    for fn in ast.walk(tree):
+        if not isinstance(fn, ast.FunctionDef):
+            continue
+        for node in ast.walk(fn):
+            if (isinstance(node, ast.Call)
+                    and getattr(node.func, "id", None) == "_add_norm"):
+                callers.add(fn.name)
+    assert callers == {"_decoder_block", "encoder_layer", "decoder_layer"}
+    for fn in tree.body:
+        if isinstance(fn, ast.FunctionDef) and fn.name.startswith(
+                "transformer_lm"):
+            consts = [n.value for n in ast.walk(fn)
+                      if isinstance(n, ast.Constant)
+                      and isinstance(n.value, str)
+                      and fn.body and n is not getattr(fn.body[0], "value",
+                                                       None)]
+            assert not [c for c in consts if "_attn" in c or "_ln" in c
+                        or "_ffn" in c], fn.name
+
+
+# -- the feed format is declared once ---------------------------------------
+
+S, BS, NLB, G, L = 3, 4, 6, 3, 2      # slots, block, blocks a request, γ+1
+C = prefill_chunk_tokens(BS, NLB)      # a lane's tokens
+I64, F32 = np.dtype("int64"), np.dtype("float32")
+
+#: the format as the engines spelled it by hand before: name -> (shape, dtype)
+TICK = {"tick_tok": ((S, 1), I64), "tick_pos": ((S, 1, 1), F32)}
+PAGED_TICK = {**TICK, "tick_btab": ((S, NLB), I64),
+              "tick_wblock": ((S,), I64), "tick_woff": ((S,), I64)}
+LANES = {"lane_tok": ((L, C), I64), "lane_pos": ((L, 1, 1), F32),
+         "lane_btab": ((L, NLB), I64), "lane_wblocks": ((L * C // BS,), I64),
+         "lane_rows": ((L,), I64), "lane_last": ((L,), I64)}
+VERIFY = {"spec_tok": ((S, G), I64), "spec_pos": ((S, 1, 1), F32)}
+PAGED_VERIFY = {**VERIFY, "spec_btab": ((S, NLB), I64),
+                "spec_wblock": ((S, G), I64), "spec_woff": ((S, G), I64)}
+
+
+def _slot_engine(**kw):
+    return serving.ContinuousBatchingEngine(
+        n_slots=S, max_len=BS * NLB, scope=pt.Scope(), **DIMS, **kw)
+
+
+def _paged_engine(**kw):
+    return serving.PagedKVEngine(
+        n_slots=S, max_len=BS * NLB, block_size=BS, scope=pt.Scope(), **DIMS,
+        **kw)
+
+
+def _spec():
+    return serving.SpecConfig(gamma=G - 1)
+
+
+PROGRAMS = {
+    # kind -> (engine, program of, its bound feeds, fetches, expected format)
+    "slot": (_slot_engine, lambda e: (
+        e._program, e._feeds, e._tick_fetches(), TICK)),
+    "paged_decode": (_paged_engine, lambda e: (
+        e._program, e._feeds, e._tick_fetches(), PAGED_TICK)),
+    "paged_mixed": (_paged_engine, lambda e: (
+        e._mixed_program, e._mixed_feeds, [e._mixed_ids],
+        {**PAGED_TICK, **LANES})),
+    "draft": (lambda: _slot_engine(speculative=_spec()), lambda e: (
+        e.spec._draft_program, e.spec._draft_feeds,
+        [e.spec._draft_ids, e.spec._draft_logp], TICK)),
+    "verify": (lambda: _slot_engine(speculative=_spec()), lambda e: (
+        e.spec._verify_program, e.spec._verify_feeds,
+        [e.spec._verify_ids, e.spec._verify_logp], VERIFY)),
+    "paged_verify": (lambda: _paged_engine(speculative=_spec()), lambda e: (
+        e.spec._verify_program, e.spec._verify_feeds,
+        [e.spec._verify_ids, e.spec._verify_logp], PAGED_VERIFY)),
+}
+
+
+@pytest.mark.parametrize("kind", sorted(PROGRAMS))
+def test_feed_arrays_are_what_the_program_takes(kind):
+    make, pick = PROGRAMS[kind]
+    eng = make()
+    program, bound, fetches, expected = pick(eng)
+    feeds = _feed_arrays(program)
+    # the helper against the format the engines used to restate by hand
+    assert {n: (a.shape, a.dtype) for n, a in feeds.items()} == expected
+    assert list(feeds) == list(expected)            # declaration order
+    assert not any(a.any() for a in feeds.values())
+    # ... against the builder's declarations
+    declared = {v.name: (tuple(v.shape), np.dtype(v.dtype))
+                for v in program.global_block().vars.values() if v.is_data}
+    assert declared == expected
+    # ... and against what the engine bound its step to
+    assert list(bound) == list(feeds)
+    assert all(bound[n].shape == a.shape and bound[n].dtype == a.dtype
+               for n, a in feeds.items())
+    # the prepared step takes exactly these, and runs on them
+    step = eng._exe.prepare(program, dict(feeds), fetches, eng.scope)
+    assert list(step._compiled.feed_names) == list(feeds)
+    out = step.run(feeds, return_numpy=True)
+    assert len(out) == len(fetches)
+
+
+def test_mixed_tick_runs_on_the_decode_ticks_arrays():
+    eng = _paged_engine()
+    assert eng.prefill == "chunked"
+    for name, arr in eng._feeds.items():
+        assert eng._mixed_feeds[name] is arr
+    assert list(eng._lane_feeds) == list(LANES)
+    assert all(eng._mixed_feeds[n] is a for n, a in eng._lane_feeds.items())
+
+
+def test_a_feed_on_one_side_only_cannot_happen():
+    """A feed the builder adds reaches the engine's arrays with no engine
+    edit: the helper reads the program, not a second list."""
+    main, startup = Program(), Program()
+    with program_guard(main, startup), unique_name.guard():
+        T.transformer_lm_decode_tick(S, max_len=24, **DIMS)
+        layers.data(name="tick_extra", shape=[S, 2], dtype="int32",
+                    append_batch_size=False)
+    feeds = _feed_arrays(main)
+    assert list(feeds) == ["tick_tok", "tick_pos", "tick_extra"]
+    assert feeds["tick_extra"].shape == (S, 2)
+    assert feeds["tick_extra"].dtype == np.dtype("int32")
+    shared = _feed_arrays(main, share={"tick_tok": feeds["tick_tok"]})
+    assert shared["tick_tok"] is feeds["tick_tok"]
+    assert shared["tick_pos"] is not feeds["tick_pos"]

@@ -297,8 +297,15 @@ def phase_serve_lm(scope, vocab=32000, max_len=1024, d_model=1024,
            f"compiled tick holds {n_calls} tpu_custom_calls and the mixed "
            f"tick {n_mixed}; expected {expect_lowering!r} with "
            f"{want_calls} and {2 * want_calls}")
+    # a launch of either tick hands the device ONE host array: the feeds
+    # and the seed, packed (PreparedStep.bind)
+    host_args = {n: d["host_args"] for n, d in stats["dispatch"].items()}
+    _check(host_args == {"main": 1, "mixed": 1},
+           f"a launch hands over {host_args} host arrays; expected one for "
+           f"the decode tick and one for the mixed tick")
     return {"compile_s": round(compile_s, 2),
             "run_s": round(result["run_s"], 2),
+            "host_args": host_args,
             "requests": n_requests + 1, "max_new": max_new,
             "prompt_lens": [len(p) for p in prompts],
             "ticks": stats["ticks"], "tokens_out": stats["tokens_out"],

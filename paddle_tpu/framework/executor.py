@@ -16,6 +16,7 @@ state are donated to XLA so parameter updates are in-place on device.
 
 from __future__ import annotations
 
+import math
 import operator
 import time
 from typing import Any, Dict, List, Optional, Sequence, Union
@@ -70,6 +71,62 @@ class _CompiledStep:
         self.rw_names = rw_names
         self.feed_names = feed_names
         self.fetch_names = fetch_names
+        self._packed_fns = {}
+
+    def packed_fn(self, spans):
+        """The step as a PreparedStep launches it: `fn(pack, rest_vals,
+        ro_vals, rw_vals)`, where `pack` is ONE int32 array holding the
+        seed in slot 0 and, at `spans` = ((feed index, offset, shape,
+        dtype), ...), the feeds that crossed from the host inside it.
+        They are cut out of it here, by static slices (a bitcast where the
+        feed is not int32), before the program's ops run; `rest_vals` are
+        the other feeds in feed order. One jitted function a layout, kept
+        on the compiled step so a second prepare() of it compiles
+        nothing."""
+        fn = self._packed_fns.get(spans)
+        if fn is not None:
+            return fn
+        inner, n_feeds = self.pure_step, len(self.feed_names)
+        kwargs = dict(self.jit_kwargs, donate_argnums=(3,))
+        feed_sh = None
+        if "in_shardings" in kwargs:
+            # a mesh executor's placements: the pack is replicated like
+            # the seed it carries, a feed cut out of it is constrained to
+            # the sharding it would have arrived with
+            feed_sh, ro_sh, rw_sh, repl = kwargs["in_shardings"]
+            packed = {i for i, *_ in spans}
+            kwargs["in_shardings"] = (
+                repl, tuple(sh for i, sh in enumerate(feed_sh)
+                            if i not in packed), ro_sh, rw_sh)
+
+        def step(pack, rest_vals, ro_vals, rw_vals):
+            rest = iter(rest_vals)
+            cut = {}
+            for i, off, shape, dtype in spans:
+                x = pack[off:off + math.prod(shape)]
+                if x.dtype != dtype:
+                    x = jax.lax.bitcast_convert_type(x, dtype)
+                x = x.reshape(shape)
+                if feed_sh is not None:
+                    x = jax.lax.with_sharding_constraint(x, feed_sh[i])
+                cut[i] = x
+            feed_vals = tuple(cut[i] if i in cut else next(rest)
+                              for i in range(n_feeds))
+            seed = jax.lax.bitcast_convert_type(pack[0], jnp.uint32)
+            return inner(feed_vals, ro_vals, rw_vals, seed)
+
+        fn = self._packed_fns[spans] = jax.jit(step, **kwargs)
+        return fn
+
+
+def _packed_dtype(v):
+    """The dtype `v` has in a PreparedStep's pack, or None where it stays
+    an argument of its own: packed is a HOST value whose items are 4 bytes
+    on the device (an int64 feed is int32 there, x64 off)."""
+    if isinstance(v, jax.Array):
+        return None
+    dtype = np.dtype(jax.dtypes.canonicalize_dtype(np.asarray(v).dtype))
+    return dtype if dtype.itemsize == 4 else None
 
 
 class PreparedStep:
@@ -82,6 +139,16 @@ class PreparedStep:
     loop is the serving engine's decode tick, where the Python dispatch
     path IS the measured overhang (tools/probe_gap.py `host_dispatch`).
 
+    A launch hands the compiled function ONE host array: every feed that
+    comes from the host with 4-byte items on the device (int32, float32,
+    uint32; an int64 feed is int32 there) has a span of one int32 buffer,
+    the seed has its slot 0, and the function cuts them apart on the
+    device (`_CompiledStep.packed_fn`). A transfer costs the host some
+    0.1 ms however small it is, so a tick of eleven feeds paid for twelve.
+    What decides is an argument's item size and that it is a host array,
+    nothing else: a bool, int8 or float16 feed and a feed that is a
+    `jax.Array` already stay arguments of their own.
+
     State contract matches Executor.run: read-write persistable state is
     donated to XLA and written back to the scope after each call; the
     RNG seed follows the same (program.random_seed, run counter) stream,
@@ -89,39 +156,76 @@ class PreparedStep:
     (the reserved @batch_row_mask) are re-injected per call."""
 
     __slots__ = ("_compiled", "_scope", "_owner", "_random_seed",
-                 "_injected", "_b_feed_vals", "_b_ro_vals", "_b_rw_vals",
-                 "_b_rw_pick", "_b_state_names", "_b_scope_vars",
-                 "_b_seed_base")
+                 "_injected", "_fn", "_spans", "_buf", "_views",
+                 "_rest_names", "_aot", "host_args", "_b_rest_vals",
+                 "_b_ro_vals", "_b_rw_vals", "_b_rw_pick", "_b_state_names",
+                 "_b_scope_vars", "_b_seed_base")
 
-    def __init__(self, compiled, scope, owner, random_seed, injected):
+    def __init__(self, compiled, scope, owner, random_seed, injected,
+                 example):
         self._compiled = compiled
         self._scope = scope
         self._owner = owner
         self._random_seed = random_seed
         self._injected = injected      # name -> constant value (batch mask)
         self._b_rw_vals = None         # set by bind(): zero-dispatch state
+        self._aot = None
+        # the layout, from the example feed prepare() compiled for: slot 0
+        # is the seed's, then every packable feed in feed order
+        vals = {n: injected[n] if n in injected else example[n]
+                for n in compiled.feed_names}
+        spans, names, end = [], [], 1
+        for i, (n, v) in enumerate(vals.items()):
+            dtype = _packed_dtype(v)
+            if dtype is not None:
+                spans.append((i, end, tuple(np.shape(v)), dtype))
+                names.append(n)
+                end += math.prod(np.shape(v))
+        self._spans = tuple(spans)
+        self._fn = compiled.packed_fn(self._spans)
+        self._buf = buf = np.zeros(end, np.int32)
+        # one view a packed feed, of its shape and device dtype (a float32
+        # feed's is the float32 view of its span)
+        self._views = {
+            n: buf[off:off + math.prod(shape)].view(dtype).reshape(shape)
+            for n, (_, off, shape, dtype) in zip(names, spans)}
+        self._rest_names = tuple(n for n in vals if n not in self._views)
+        self._b_rest_vals = tuple(vals[n] for n in self._rest_names)
+        #: host arrays a bound launch hands over: the pack, and each host
+        #: feed it cannot hold (counted at bind(), which captures those)
+        self.host_args = None
 
     @property
     def fetch_names(self):
         return list(self._compiled.fetch_names)
 
+    def _pack(self, feed):
+        """Copy the packable feeds of `feed` into the pack (nothing to do
+        for one that IS the pack's view: a bound caller fills in place)."""
+        for n, view in self._views.items():
+            v = feed[n]
+            if v is not view:
+                view[...] = v
+
     def run(self, feed, return_numpy=False):
         """feed: dict with EXACTLY the prepared names/shapes/dtypes (not
         re-validated — a drifted signature recompiles via jit's own shape
-        check or fails inside XLA). Returns the fetch list (jax arrays
-        unless return_numpy)."""
+        check or fails inside XLA). Packs them on the way in: the launch
+        is run_bound()'s. Returns the fetch list (jax arrays unless
+        return_numpy)."""
         compiled = self._compiled
         scope = self._scope
         injected = self._injected
-        feed_vals = tuple(
+        self._pack(feed)
+        rest_vals = tuple(
             jnp.asarray(feed[n] if n in feed else injected[n])
-            for n in compiled.feed_names)
+            for n in self._rest_names)
         ro_vals = tuple(scope.get(n) for n in compiled.ro_names)
         rw_vals = tuple(scope.get(n) for n in compiled.rw_names)
         self._owner._run_counter += 1
-        seed = np.uint32((self._random_seed * 1000003
-                          + self._owner._run_counter) % (2 ** 31))
-        fetches, new_state = compiled.fn(feed_vals, ro_vals, rw_vals, seed)
+        self._buf[0] = (self._random_seed * 1000003
+                        + self._owner._run_counter) % (2 ** 31)
+        fetches, new_state = self._fn(self._buf, rest_vals, ro_vals, rw_vals)
         for name, val in zip(compiled.state_out_names, new_state):
             scope.set_var(name, val)
         if self._b_rw_vals is not None:
@@ -134,25 +238,50 @@ class PreparedStep:
             return [as_numpy(f) for f in fetches]
         return list(fetches)
 
-    def bind(self, feed):
-        """One-time setup of the zero-dispatch tick: capture the caller's
-        feed buffers (the serving engine mutates them in place between
-        ticks), pin the read-only state straight out of the scope, and
-        precompute everything run() recomputes per call — the argument
-        tuples, the rw<-new_state selection, and the seed stream base.
-        After bind(), run_bound() is the hot path: no dict probes, no
-        per-name scope lookups, no tuple-comprehension rebuilds.
+    def bind(self, feed, share=None):
+        """One-time setup of the zero-dispatch tick: lay the caller's
+        feeds out in the pack, pin the read-only state straight out of the
+        scope, and precompute everything run() recomputes per call — the
+        argument tuples, the rw<-new_state selection, and the seed stream
+        base. After bind(), run_bound() is the hot path: no dict probes,
+        no per-name scope lookups, no tuple-comprehension rebuilds, one
+        host array.
 
-        Contract: `feed` must hold the EXACT arrays fed forever after
-        (mutate them in place; rebinding is required if they are
-        replaced), and read-only persistables are pinned at bind time —
-        swap weights in the scope -> bind() again."""
+        bind() OWNS the buffer a launch transfers: it copies the values
+        `feed` holds into it and REPLACES each packed entry of `feed` with
+        the view of its span, so the caller goes on filling `feed[name]`
+        in place (the serving engine does, between ticks) and writes the
+        pack by doing so. Fill it only once the fetches of the launch
+        before were read: a launch may read the buffer until its transfer
+        is done. A feed the pack cannot hold stays the caller's own array,
+        mutated in place as before.
+
+        `share`: a bound step whose feeds START with this step's (same
+        names, shapes and dtypes, in order). This step then takes the
+        leading span of that step's buffer, and its views, instead of a
+        buffer of its own: the caller fills one set of arrays, this step
+        transfers the prefix and `share` the whole.
+
+        Contract: read-only persistables are pinned at bind time — swap
+        weights in the scope -> bind() again."""
         compiled = self._compiled
         injected = self._injected
         scope = self._scope
-        self._b_feed_vals = tuple(
-            feed[n] if n in feed else injected[n]
-            for n in compiled.feed_names)
+        if share is not None:
+            n = len(self._spans)
+            enforce(self._spans == share._spans[:n]
+                    and list(self._views) == list(share._views)[:n],
+                    "bind(share=): the feeds of the step shared with must "
+                    "start with this step's, shape for shape",
+                    exc=InvalidArgumentError)
+            self._buf = share._buf[:len(self._buf)]
+            self._views = {n: share._views[n] for n in self._views}
+        self._pack(feed)
+        feed.update(self._views)
+        self._b_rest_vals = tuple(
+            feed[n] if n in feed else injected[n] for n in self._rest_names)
+        self.host_args = 1 + sum(not isinstance(v, jax.Array)
+                                 for v in self._b_rest_vals)
         self._b_ro_vals = tuple(scope.get(n) for n in compiled.ro_names)
         self._b_rw_vals = tuple(scope.get(n) for n in compiled.rw_names)
         self._b_state_names = tuple(compiled.state_out_names)
@@ -192,21 +321,39 @@ class PreparedStep:
     def run_bound(self):
         """The zero-dispatch steady-state tick over the buffers captured by
         bind(): donated rw state threads call-to-call through a precomputed
-        selector, feeds are the caller's in-place-mutated arrays, and the
-        scope write-back is a raw dict store per state var. Returns the
-        fetch tuple (jax arrays)."""
-        compiled = self._compiled
+        selector, the feeds are the pack the caller filled through its
+        views, the seed goes into the pack's slot 0, and the scope
+        write-back is a raw dict store per state var. Returns the fetch
+        tuple (jax arrays)."""
         owner = self._owner
         owner._run_counter += 1
-        seed = np.uint32((self._b_seed_base + owner._run_counter)
-                         % 2147483648)
-        fetches, new_state = compiled.fn(self._b_feed_vals, self._b_ro_vals,
-                                         self._b_rw_vals, seed)
+        buf = self._buf
+        buf[0] = (self._b_seed_base + owner._run_counter) % 2147483648
+        fetches, new_state = self._fn(buf, self._b_rest_vals,
+                                      self._b_ro_vals, self._b_rw_vals)
         self._b_rw_vals = self._b_rw_pick(new_state)
         sv = self._b_scope_vars
         for name, val in zip(self._b_state_names, new_state):
             sv[name] = val
         return fetches
+
+    def lower(self, **kw):
+        """The launch function lowered for the arguments a launch passes
+        (`jax.stages.Traced.lower`'s keywords, e.g. `lowering_platforms`):
+        the pack cut apart ahead of the program's ops."""
+        compiled, scope = self._compiled, self._scope
+        return self._fn.trace(
+            self._buf, self._b_rest_vals,
+            tuple(scope.get(n) for n in compiled.ro_names),
+            tuple(scope.get(n) for n in compiled.rw_names)).lower(**kw)
+
+    def compiled_hlo(self) -> str:
+        """Optimized HLO text of the step as this handle launches it: what
+        the device actually runs. Compiles ahead of time once, memoized:
+        the AOT path bypasses the jit executable cache."""
+        if self._aot is None:
+            self._aot = self.lower().compile()
+        return self._aot.as_text()
 
 
 class Executor:
@@ -385,6 +532,9 @@ class Executor:
         fn = jax.jit(step, **jit_kwargs)
         compiled = _CompiledStep(fn, ro, rw, feed_names, fetch_names)
         compiled.state_out_names = state_out_names
+        # what a PreparedStep builds its one-host-array launch from
+        # (_CompiledStep.packed_fn)
+        compiled.pure_step, compiled.jit_kwargs = step, jit_kwargs
         self._stash_flops_estimate(compiled, program)
         return compiled
 
@@ -711,7 +861,7 @@ class Executor:
         injected = {n: jnp.asarray(v) for n, v in feed.items()
                     if n not in user_names}
         return PreparedStep(compiled, scope, self, program.random_seed,
-                            injected)
+                            injected, feed)
 
     def _aot_compiled(self, compiled: _CompiledStep, feed, scope):
         """The AOT `lower().compile()` twin of a cached step, memoized on

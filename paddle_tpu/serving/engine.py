@@ -338,15 +338,20 @@ class ContinuousBatchingEngine:
         #: they ride the `engine/tick` span (the paged engine: `kv_blocks`)
         self._tick_attrs: Dict[str, int] = {}
         self._feeds = _feed_arrays(self._program)
-        self._tok = self._feeds["tick_tok"]
-        self._pos = self._feeds["tick_pos"]
+        # zero-dispatch steady state: the prepared step is BOUND — argument
+        # tuples are built once here, never per tick — and bind() lays the
+        # feeds out in the one host buffer a launch transfers: from here
+        # on `_feeds` holds the views of it that the fills write in place
+        # (PreparedStep.bind)
         self._step = self._exe.prepare(
             self._program, dict(self._feeds), self._tick_fetches(),
-            self.scope)
-        # zero-dispatch steady state: the prepared step is BOUND to the
-        # engine's in-place-mutated feed arrays — argument tuples are
-        # built once here, never per tick (PreparedStep.bind)
-        self._step.bind(self._feeds)
+            self.scope).bind(self._feeds)
+        self._tok = self._feeds["tick_tok"]
+        self._pos = self._feeds["tick_pos"]
+        #: the bound steps by the name `_run_bound_step` knows them under
+        #: (`stats()["dispatch"]`; `_target_state_owner` names the one
+        #: that ran last)
+        self._bound_steps = {"main": self._step}
         # which bound step's held rw tuple points at the LIVE target
         # caches: "main" (the plain tick) or "verify" (the speculative
         # verify forward). The two share the donated cache buffers, so
@@ -801,9 +806,11 @@ class ContinuousBatchingEngine:
                 with span("dispatch", "engine/fill_feeds"):
                     self._fill_tick_feeds(active)
                     self._note_tick_writes(active)
-                with span("dispatch", "engine/launch"):
+                with span("dispatch", "engine/launch") as launch:
                     fetches = self._launch_tick()
                     self.target_forwards += 1
+                    launch.attrs["host_args"] = self._bound_steps[
+                        self._target_state_owner].host_args
                 dispatch.attrs["active"] = len(active)
                 td = time.perf_counter()       # async dispatch returned
             if _tracing.enabled():
@@ -934,12 +941,11 @@ class ContinuousBatchingEngine:
 
     def tick_hlo(self) -> str:
         """Optimized HLO text of the compiled decode tick
-        (`Executor.compiled_hlo`): shows which attention path the tick
+        (`PreparedStep.compiled_hlo`): shows which attention path the tick
         took at this engine's shape — a `tpu_custom_call` per layer when
         the fused decode kernel is in, none when the shape gate sent it
         to the composite."""
-        return self._exe.compiled_hlo(self._program, dict(self._feeds),
-                                      self._tick_fetches(), self.scope)
+        return self._step.compiled_hlo()
 
     def occupancy(self) -> float:
         """Fraction of slot-ticks that carried an active request —
@@ -970,6 +976,9 @@ class ContinuousBatchingEngine:
                 self.tokens_out / max(self.target_forwards, 1)),
             "speculative": (self.spec.stats()
                             if self.spec is not None else None),
+            # per bound step, the host arrays one launch hands over
+            "dispatch": {name: {"host_args": step.host_args}
+                         for name, step in self._bound_steps.items()},
         }
 
 
@@ -977,10 +986,13 @@ def _feed_arrays(program, share=()) -> Dict[str, np.ndarray]:
     """The feed arrays of a tick program, zeroed, made from the feeds its
     builder declared (`layers.data`), in declaration order: the builder is
     the one place that says a feed's name, shape and dtype. An engine
-    makes them once, binds its prepared step to them and fills them in
-    place every tick (the decode loop allocates nothing). A name in
-    `share` takes that array instead of a new one: a second program over
-    the same feeds runs on what the first one's fill wrote."""
+    makes them once and binds its prepared step to them; `bind` swaps each
+    for the view of its span of the one buffer a launch transfers
+    (declared shape, the dtype it has on the device), and the engine
+    fills those in place every tick (the decode loop allocates nothing).
+    A name in `share` takes that array instead of a new one: a second
+    program over the same feeds runs on what the first one's fill
+    wrote."""
     share = dict(share)
     return {v.name: (share[v.name] if v.name in share
                      else np.zeros(v.shape, np.dtype(v.dtype)))

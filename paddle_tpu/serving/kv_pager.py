@@ -810,17 +810,23 @@ class PagedKVEngine(ContinuousBatchingEngine):
                 self._mixed_program, self.scope)
         self._mixed_feeds = _feed_arrays(self._mixed_program,
                                         share=self._feeds)
-        self._lane_feeds = {n: a for n, a in self._mixed_feeds.items()
-                            if n not in self._feeds}
         self._mixed_step = self._exe.prepare(
             self._mixed_program, dict(self._mixed_feeds), [self._mixed_ids],
             self.scope).bind(self._mixed_feeds)
+        # ONE host buffer for both ticks: the decode tick's feeds lead the
+        # mixed tick's, so the decode step moves onto that leading span
+        # (and its views): a fill writes them once, the decode tick
+        # transfers the prefix and the mixed tick the whole
+        self._step.bind(self._feeds, share=self._mixed_step)
+        self._tok = self._feeds["tick_tok"]
+        self._pos = self._feeds["tick_pos"]
+        self._lane_feeds = {n: a for n, a in self._mixed_feeds.items()
+                            if n not in self._feeds}
+        self._bound_steps["mixed"] = self._mixed_step
 
     def mixed_tick_hlo(self) -> str:
         """`tick_hlo()` of the mixed tick (chunked engines only)."""
-        return self._exe.compiled_hlo(
-            self._mixed_program, dict(self._mixed_feeds), [self._mixed_ids],
-            self.scope)
+        return self._mixed_step.compiled_hlo()
 
     def _build_tick_program(self):
         from ..fusion.paged_attention import paged_attention_lowering

@@ -249,6 +249,8 @@ class SpeculativeDecoder:
             self._verify_program, dict(self._verify_feeds),
             [self._verify_ids, self._verify_logp],
             eng.scope).bind(self._verify_feeds)
+        eng._bound_steps.update(draft=self._draft_step,
+                                verify=self._verify_step)
         self._rng = np.random.RandomState(self.cfg.seed)
         self._windows = np.zeros((eng.n_slots, g), np.int64)
         self._from_draft = np.zeros((eng.n_slots, g), bool)

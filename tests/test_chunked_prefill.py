@@ -505,10 +505,9 @@ class TestWholeBlockWrite:
 
 
 def _lowered(step):
-    """The StableHLO text a bound step lowers to (no source locations)."""
-    args = (step._b_feed_vals, step._b_ro_vals, step._b_rw_vals,
-            np.uint32(0))
-    return step._compiled.fn.lower(*args).as_text()
+    """The StableHLO text a bound step's launch lowers to (no source
+    locations): the one packed host array cut apart, then the program."""
+    return step.lower().as_text()
 
 
 class TestDecodeTickUnchanged:

@@ -6,12 +6,14 @@
   `_add_norm` outside the NMT training layers.
 - An engine's feed arrays are made from what the tick builder declared
   (`serving.engine._feed_arrays`), so a feed exists on both sides or on
-  neither.
+  neither; binding the step swaps them for views of the one host buffer a
+  launch transfers (ISSUE 33).
 """
 
 import ast
 import inspect
 
+import jax
 import numpy as np
 import pytest
 
@@ -177,15 +179,34 @@ def test_feed_arrays_are_what_the_program_takes(kind):
     declared = {v.name: (tuple(v.shape), np.dtype(v.dtype))
                 for v in program.global_block().vars.values() if v.is_data}
     assert declared == expected
-    # ... and against what the engine bound its step to
+    # ... and against what the engine fills: bind() swapped every array
+    # for a view of the step's one buffer, of the declared shape and the
+    # dtype the feed has on the device (int64 is int32 there)
     assert list(bound) == list(feeds)
-    assert all(bound[n].shape == a.shape and bound[n].dtype == a.dtype
+    assert all(bound[n].shape == a.shape
+               and bound[n].dtype == jax.dtypes.canonicalize_dtype(a.dtype)
                for n, a in feeds.items())
     # the prepared step takes exactly these, and runs on them
     step = eng._exe.prepare(program, dict(feeds), fetches, eng.scope)
     assert list(step._compiled.feed_names) == list(feeds)
     out = step.run(feeds, return_numpy=True)
     assert len(out) == len(fetches)
+    # bound, its views alias ONE buffer: the seed's slot, then a span a
+    # feed in declaration order, nothing between them and nothing beside
+    assert step.bind(feeds) is step and step.host_args == 1
+    buf = step._buf
+    assert buf.dtype == np.int32 and buf.ndim == 1
+    at = buf.ctypes.data + buf.itemsize
+    for n, (shape, _) in expected.items():
+        view = feeds[n]
+        assert view.shape == shape and view.flags.c_contiguous
+        assert not view.flags.owndata and view.ctypes.data == at, n
+        at += view.nbytes
+    assert at == buf.ctypes.data + buf.nbytes
+    # a fill through a view is a write to the pack the launch transfers
+    first = next(iter(feeds))
+    feeds[first].flat[0] = 7
+    assert buf[1] == 7
 
 
 def test_mixed_tick_runs_on_the_decode_ticks_arrays():
@@ -195,6 +216,16 @@ def test_mixed_tick_runs_on_the_decode_ticks_arrays():
         assert eng._mixed_feeds[name] is arr
     assert list(eng._lane_feeds) == list(LANES)
     assert all(eng._mixed_feeds[n] is a for n, a in eng._lane_feeds.items())
+    # one buffer: the decode tick transfers its leading span, the mixed
+    # tick the whole, and the fills write the arrays the engine kept
+    whole, lead = eng._mixed_step._buf, eng._step._buf
+    assert lead.ctypes.data == whole.ctypes.data and lead.size < whole.size
+    assert lead.size == 1 + sum(a.size for a in eng._feeds.values())
+    assert whole.size == 1 + sum(a.size for a in eng._mixed_feeds.values())
+    assert eng._tok is eng._feeds["tick_tok"]
+    assert eng._pos is eng._feeds["tick_pos"]
+    assert eng.stats()["dispatch"] == {"main": {"host_args": 1},
+                                       "mixed": {"host_args": 1}}
 
 
 def test_a_feed_on_one_side_only_cannot_happen():

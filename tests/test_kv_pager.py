@@ -565,7 +565,6 @@ class TestFusedDecodeStructure:
         updates. (The gather → transpose → reshape view this replaces was
         three passes over a whole pool per layer per tick; this is the
         test that would have caught them.)"""
-        import jax.numpy as jnp
         from paddle_tpu.ops import pallas_kernels
         monkeypatch.setattr(pallas_kernels, "_auto_backend",
                             lambda: "pallas")
@@ -578,11 +577,8 @@ class TestFusedDecodeStructure:
             num_heads=d["num_heads"], num_layers=d["num_layers"],
             block_size=d["block_size"], n_blocks=d["n_blocks"])
         assert eng.stats()["paged_attention_lowering"] == "kernel"
-        c = eng._step._compiled
-        args = (tuple(jnp.asarray(eng._feeds[n]) for n in c.feed_names),
-                tuple(eng.scope.get(n) for n in c.ro_names),
-                tuple(eng.scope.get(n) for n in c.rw_names), np.uint32(0))
-        text = c.fn.trace(*args).lower(lowering_platforms=("tpu",)).as_text()
+        # the tick as the engine launches it
+        text = eng._step.lower(lowering_platforms=("tpu",)).as_text()
         # the read and the write are one jitted function each, lowered once
         # and called by every layer
         assert text.count("tpu_custom_call") == 1

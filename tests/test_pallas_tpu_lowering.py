@@ -124,6 +124,12 @@ def _step_tpu_text(compiled, feed, scope):
         lowering_platforms=("tpu",)).as_text()
 
 
+def _tick_tpu_text(step):
+    """A bound step as the engine launches it: the packed host array cut
+    apart (slices, a bitcast for the float spans), then the program."""
+    return step.lower(lowering_platforms=("tpu",)).as_text()
+
+
 def test_stacked_lstm_train_step_lowers_with_default_flags(as_on_tpu):
     """bs64 / T64 / H256: the model with chip history that the batch-major
     kernel made un-lowerable for a TPU under default flags."""
@@ -159,7 +165,7 @@ def test_paged_tick_reads_the_pool_through_the_paged_kernel(as_on_tpu,
                         d_inner=64, num_heads=16, num_layers=n_layers,
                         block_size=16)
     assert eng.stats()["paged_attention_lowering"] == "kernel"
-    text = _step_tpu_text(eng._step._compiled, eng._feeds, eng.scope)
+    text = _tick_tpu_text(eng._step)
     # the read is one jitted function: lowered to Mosaic once, called by
     # every layer (the compiled tick inlines it: a custom call a layer)
     assert _n_calls(text) == 1
@@ -203,13 +209,15 @@ def test_mixed_tick_reads_the_pool_through_both_kernels(as_on_tpu):
                         d_inner=64, num_heads=16, num_layers=n_layers,
                         block_size=16)
     assert (eng.n_lanes, eng.chunk_tokens) == (2, 128)
-    text = _step_tpu_text(eng._mixed_step._compiled, eng._mixed_feeds,
-                          eng.scope)
+    text = _tick_tpu_text(eng._mixed_step)
     assert _n_calls(text) == 2
     assert len(re.findall(r"call @_paged_pallas\b", text)) == n_layers
     assert len(re.findall(r"call @_chunk_pallas\b", text)) == n_layers
     assert "tensor<272x1x1024xf32>" in text
-    decode = _step_tpu_text(eng._step._compiled, eng._feeds, eng.scope)
+    decode = _tick_tpu_text(eng._step)
+    # either launch takes ONE small host argument: the seed and the feeds
+    assert "tensor<%dxi32>" % eng._mixed_step._buf.size in text
+    assert "tensor<%dxi32>" % eng._step._buf.size in decode
     assert _n_calls(decode) == 1 and "_chunk_pallas" not in decode
 
 

@@ -17,6 +17,8 @@ import importlib.util
 import json
 import os
 import shutil
+import sys
+import time
 
 import numpy as np
 
@@ -73,9 +75,15 @@ class Cell:
 
 def device_facts(chips: int) -> dict:
     """The accelerator as JAX reports it, or exit: a cell never runs smaller
-    on a CPU, and never on fewer chips than it asks for."""
+    on a CPU, and never on fewer chips than it asks for. This is the
+    process's first touch of the device: `jax.devices()` starts the TPU
+    runtime, and one small array put on every chip of the cell brings up its
+    transfer path, so that `start_run` can time the runtime's start apart
+    from what the program sets up."""
     import jax
     devs = jax.devices()
+    jax.block_until_ready([jax.device_put(np.zeros(8, np.float32), d)
+                           for d in devs[:chips]])
     kind = devs[0].device_kind
     peaks = load_json("peaks.json")
     if devs[0].platform != "tpu":
@@ -166,9 +174,11 @@ class Run:
     def __init__(self, cell: Cell, seed: int, seconds: float, device: dict):
         self.cell, self.seed, self.seconds, self.device = \
             cell, seed, seconds, device
-        self.setup_s = None
-        self.setup_parts = {}     # import / build / init / compile_or_load / warm
-        self.steps = []           # (start, end, tokens) on perf_counter
+        self.t0 = None            # the process's start, on perf_counter
+        self.setup_s = None       # process start to the window, less runtime_start
+        self.setup_parts = {}     # import / runtime_start / build / init /
+                                  # compile_or_load / warm, in seconds
+        self.steps = []           # (dispatched, loss on the host, tokens)
         self.batches = []         # the ring (training)
         self.requests = []        # dicts with due/submitted/.../done (serving)
         self.counters = {}        # program counters read after the window
@@ -178,10 +188,32 @@ class Run:
         self.attempted = 0
         self.failed = 0
         self.correct = False
+        self.checks = {}          # what `correct` compared: name -> (value, limit)
+        self.window_clock = {}    # wall_s and thread_cpu_s of the measured loop
         self.notes = {}
+
+    def open_window(self, t_open: float):
+        """The window opens at `t_open`: set-up is what came before it, less
+        the TPU runtime's start."""
+        self.setup_s = t_open - self.t0 - self.setup_parts["runtime_start"]
 
     def span_ms(self, name: str):
         return [s.duration_ms for s in self.spans if s.name == name]
+
+
+def start_run(cell: Cell, args, t0: float) -> Run:
+    """What every loop does first: the imports' seconds since the process's
+    start `t0`, then the first touch of the device, timed apart as
+    `runtime_start`: the TPU runtime's start reads 6 to 22 s on one machine
+    and one tree (PERF.md, section 6, PR 29 and PR 31) and no statement of
+    the program shortens it, so `setup_s` leaves it out (metrics/setup_s.py)."""
+    parts = {"import": time.perf_counter() - t0}    # jax, paddle_tpu, manifest
+    t = time.perf_counter()
+    device = device_facts(cell.chips)
+    parts["runtime_start"] = time.perf_counter() - t
+    run = Run(cell, args.seed, args.seconds, device)
+    run.t0, run.setup_parts = t0, parts
+    return run
 
 
 def read_metrics(run: Run, group: str) -> dict:
@@ -205,7 +237,8 @@ def emit(run: Run, traced: bool):
             "device": f"{dev['platform']} {dev['kind']} x{dev['count']}",
             "compilations_in_window": run.compiles_in_window,
             "counted": run.attempted, "failed": run.failed,
-            "setup_s": run.setup_s, "setup_parts": run.setup_parts,
+            "setup_s": run.setup_s,
+            "setup_parts": {k: round(v, 3) for k, v in run.setup_parts.items()},
             "notes": run.notes,
             "memory_stats": {k: v for k, v in
                              (dev["devices"][0].memory_stats() or {}).items()
@@ -226,4 +259,12 @@ def emit(run: Run, traced: bool):
         line["breakdown"] = {
             "device_ops": xplane.top(run.trace.op_seconds()),
             "idle_gaps": xplane.top(run.trace.idle_gaps_by_host_span())}
+    # each number `correct` compared beside its limit: last in the line, and
+    # the last lines of standard error
+    checks = dict(run.checks, compilations_in_window=(run.compiles_in_window, 0),
+                  failed=(run.failed, 0))
+    line["checks"] = {k: {"value": v, "limit": lim}
+                      for k, (v, lim) in checks.items()}
     print(json.dumps(line), flush=True)
+    for k, (v, lim) in checks.items():
+        print(f"check {k}: {v} (limit {lim})", file=sys.stderr, flush=True)

@@ -9,6 +9,14 @@ not finished by then, or ended in error, is `failed` and misses every
 percentile. Times are taken from when a request was DUE, so a stall that
 delays submission is charged to the requests it delays.
 
+Around that loop (window and drain) the wall clock and the main thread's own
+CPU time are taken: what is left of the wall after the thread's CPU time and
+its `engine/wait` spans is time the machine's host took from the thread
+(metrics/window_stolen_ms.py). The three numbers are in every run's `notes`,
+beside the latencies they explain; no run is dropped or reweighted by them.
+Set-up is counted from the process's start to the window's opening less
+`runtime_start`, the first touch of the device (metrics/setup_s.py).
+
 With --trace 1 a second, short open loop of the same mix follows the drain:
 TRACE_LEAD_S seconds to fill the slots, then the cell's `trace_seconds` under
 the profiler. Starting and stopping a trace stalls the host for seconds;
@@ -18,6 +26,7 @@ inside the window that would be charged to the requests in flight.
 from __future__ import annotations
 
 import gc
+import resource
 import time
 
 import numpy as np
@@ -25,6 +34,31 @@ import numpy as np
 from .. import harness, traffic
 
 TRACE_LEAD_S = 4.0
+# spans an engine step records (the benchmark's wrapper, admit, tick, dispatch
+# and its three, wait, commit, finish) with room: a window recorded 82,000 at
+# 6,088 ticks
+SPANS_PER_TICK = 16
+LANE_TOKENS = 128       # a prompt takes at most a tick a chunk of this many
+
+
+def _thread_cpu_s():
+    """User and system seconds of the calling thread."""
+    u = resource.getrusage(resource.RUSAGE_THREAD)
+    return u.ru_utime + u.ru_stime
+
+
+def _size_span_ring(requests):
+    """The program's span ring has to hold the window and the drain
+    (`spans_since` raises where it wrapped). Upper bound of the ticks: every
+    request alone in the engine, a tick a token and a tick a prompt chunk.
+    Raised through the program's flag before the engine is built, never
+    lowered: the chat cell at 3.2 req/s stayed inside the default."""
+    from paddle_tpu.core import flags
+    ticks = sum(r["max_new"] + len(r["prompt"]) // LANE_TOKENS + 2
+                for r in requests)
+    want = 1 << (SPANS_PER_TICK * ticks).bit_length()
+    if want > int(flags.get_flag("trace_ring")):
+        flags.set_flag("trace_ring", want)
 
 
 class _Loop:
@@ -72,14 +106,14 @@ def run(cell, args, t0):
     import jax
     from paddle_tpu.observability import tracing
 
+    out = harness.start_run(cell, args, t0)
+    parts, device = out.setup_parts, out.device
     t = time.perf_counter()
-    parts = {"import": t - t0}      # interpreter, jax, paddle_tpu, manifest
-    device = harness.device_facts(cell.chips)
     compiles = harness.CompileCounter()
-    out = harness.Run(cell, args.seed, args.seconds, device)
     mix, cfg, adapter = cell.traffic, cell.config, cell.adapter
     load = traffic.open_loop_requests(mix, args.seed, args.seconds,
                                       cfg["vocab"])
+    _size_span_ring(load["requests"])
     parts["build"] = time.perf_counter() - t
 
     t = time.perf_counter()
@@ -111,16 +145,18 @@ def run(cell, args, t0):
     mark = tracing.mark()
     compiled_before = compiles.n
     at_open = _slot_ticks(engine)
+    cpu_open = _thread_cpu_s()
     t_open = loop.start()
-    out.setup_s = t_open - t0
+    out.open_window(t_open)
     loop.run_until(args.seconds, until_idle=False)
     at_close = _slot_ticks(engine)
     loop.run_until(args.seconds + mix["drain_deadline_s"], until_idle=True)
     t_end = time.perf_counter()
+    out.window_clock = {"wall_s": t_end - t_open,
+                        "thread_cpu_s": _thread_cpu_s() - cpu_open}
     at_end = _slot_ticks(engine)
     out.compiles_in_window = compiles.n - compiled_before
     out.spans = tracing.spans_since(mark)
-    out.setup_parts = {k: round(v, 3) for k, v in parts.items()}
 
     for k, spec in enumerate(load["requests"]):
         rec = {"due": t_open + spec["due"], "prompt_len": len(spec["prompt"]),
@@ -145,6 +181,14 @@ def run(cell, args, t0):
     quarters = [t_open + q * args.seconds for q in (0.25, 0.5, 0.75, 1.0)]
     out.notes = {"requests": out.attempted,
                  "drain_s": max(t_end - t_open - args.seconds, 0.0),
+                 # the machine beside the program (metrics/window_stolen_ms.py):
+                 # wall, the main thread's own CPU time and its waits for the
+                 # device over window and drain; what is left was taken away
+                 "window_wall_s": out.window_clock["wall_s"],
+                 "window_thread_cpu_s": out.window_clock["thread_cpu_s"],
+                 "window_engine_wait_s": sum(out.span_ms("engine/wait")) / 1e3,
+                 "window_stolen_ms": harness.load_module(
+                     "metrics", "window_stolen_ms").read(out),
                  # requests due and not yet done at each quarter of the window:
                  # a backlog that grows from quarter to quarter is past the knee
                  "in_system_at_quarters": [
@@ -201,4 +245,5 @@ def _check(cell, scope, handles, load, out):
     out.notes.update(check_requests=min(len(done), cell.spec["check_requests"]),
                      check_worst_logit_gap=worst,
                      check_tol=cell.spec["logit_gap_tol"])
+    out.checks["worst_logit_gap"] = (worst, cell.spec["logit_gap_tol"])
     return bool(done) and worst <= cell.spec["logit_gap_tol"]

@@ -1,17 +1,26 @@
 """Training cells: a new seeded batch every step through Executor.run (or
-ParallelExecutor.run on a mesh), the loss fetched to the host every step, as
-the program's users call it.
+ParallelExecutor.run on a mesh), every step's loss read on the host, the
+cell's `steps_ahead` steps late (30: 3.3 to 4.5 seconds of steps).
 
-The window holds whole steps only. It ends at the first step boundary at or
-after --seconds, and the rate is counted over the steps' own elapsed time,
-from the first step's start to the last one's loss on the host. With
+Steps are dispatched ahead of the one whose loss is waited for
+(`return_numpy=False` leaves the loss on the device), so the chip stays fed
+while the machine's host stands still for a tenth of a second or for some
+seconds: with the loss fetched at every step such a stall was lost step time,
+and one run in a few read 2 to 4% low (PERF.md, section 6, PR 35). The
+window holds whole steps only. Once --seconds are up nothing more is sent,
+every step sent is waited for, and the clock is read after that wait: the
+rate counts all of that work over all of that time, from the first step's
+dispatch to the last one's loss on the host. With
 --trace 1 the same steps go on under the profiler for the cell's
 `trace_seconds` AFTER the window, so the spans and counters of the window are
-those of an undisturbed run.
+those of an undisturbed run. Set-up is counted from the process's start to
+the window's opening less `runtime_start`, the first touch of the device
+(metrics/setup_s.py).
 """
 
 from __future__ import annotations
 
+import collections
 import gc
 import time
 
@@ -22,16 +31,42 @@ from .. import harness, traffic
 WARM_STEPS = 3      # the first compiles or loads; two more settle the allocator
 
 
+class Pipeline:
+    """Steps in flight, oldest first. `send` dispatches one and, once `ahead`
+    are in flight, waits for the oldest one's loss; `drain` waits for all."""
+
+    def __init__(self, launch, ahead, tracing):
+        self.launch, self.ahead, self.tracing = launch, ahead, tracing
+        self.flying = collections.deque()   # (dispatched at, loss, tokens)
+        self.steps, self.losses = [], []    # (dispatched, loss here, tokens)
+
+    def send(self, batch):
+        with self.tracing.span("user", "benchmark/step"):
+            self.flying.append((time.perf_counter(),
+                                self.launch(batch["feed"]), batch["tokens"]))
+            if len(self.flying) > self.ahead:
+                self.land()
+
+    def land(self):
+        start, value, tokens = self.flying.popleft()
+        with self.tracing.span("user", "benchmark/loss_wait"):
+            self.losses.append(float(np.asarray(value)))
+        self.steps.append((start, time.perf_counter(), tokens))
+
+    def drain(self):
+        while self.flying:
+            self.land()
+
+
 def run(cell, args, t0):
     import jax
     import paddle_tpu as pt
     from paddle_tpu.observability import tracing
 
+    out = harness.start_run(cell, args, t0)
+    parts, device = out.setup_parts, out.device
     t = time.perf_counter()
-    parts = {"import": t - t0}      # interpreter, jax, paddle_tpu, manifest
-    device = harness.device_facts(cell.chips)
     compiles = harness.CompileCounter()
-    out = harness.Run(cell, args.seed, args.seconds, device)
     mix, cfg, adapter = cell.traffic, cell.config, cell.adapter
     with pt.core.unique_name.guard():
         loss = adapter.build_train(cfg, mix)
@@ -53,11 +88,13 @@ def run(cell, args, t0):
         mesh = DeviceMesh(device["devices"], dict(cell.spec["mesh"]))
         exe = ParallelExecutor(loss_name=loss.name, mesh=mesh)
 
-        def step(feed):
-            return exe.run(fetch_list=[loss], feed=feed)[0]
+        def step(feed, return_numpy=True):
+            return exe.run(fetch_list=[loss], feed=feed,
+                           return_numpy=return_numpy)[0]
     elif cell.spec["executor"] == "Executor":
-        def step(feed):
-            return exe.run(feed=feed, fetch_list=[loss])[0]
+        def step(feed, return_numpy=True):
+            return exe.run(feed=feed, fetch_list=[loss],
+                           return_numpy=return_numpy)[0]
     else:
         raise SystemExit(f"unknown executor {cell.spec['executor']!r}")
     jax.block_until_ready(pt.global_scope().get(adapter.param_names(cfg)[0]))
@@ -71,39 +108,39 @@ def run(cell, args, t0):
     losses += [float(step(ring[i % len(ring)]["feed"]))
                for i in range(1, WARM_STEPS)]
     parts["warm"] = time.perf_counter() - t
+    ahead = cell.spec["steps_ahead"]
+
+    def launch(feed):
+        return step(feed, return_numpy=False)
 
     gc.collect()
     gc.freeze()
     mark = tracing.mark()
     compiled_before = compiles.n
-    steps = out.steps
+    window = Pipeline(launch, ahead, tracing)
     i = WARM_STEPS
     t_open = time.perf_counter()
-    out.setup_s = t_open - t0
-    while True:
-        batch = ring[i % len(ring)]
-        with tracing.span("user", "benchmark/step"):
-            ts = time.perf_counter()
-            value = step(batch["feed"])
-            te = time.perf_counter()
-        steps.append((ts, te, batch["tokens"]))
-        losses.append(float(value))
+    out.open_window(t_open)
+    while time.perf_counter() - t_open < args.seconds:
+        window.send(ring[i % len(ring)])
         i += 1
-        if te - t_open >= args.seconds:
-            break
+    window.drain()
+    out.steps, steps = window.steps, window.steps
+    losses += window.losses
     out.compiles_in_window = compiles.n - compiled_before
     out.spans = tracing.spans_since(mark)
     if args.trace:
         # the traced phase: more of the same steps, after the window
+        traced = Pipeline(launch, ahead, tracing)
         with harness.Profiler() as profiler:
             t_trace = time.perf_counter()
             while time.perf_counter() - t_trace < cell.spec["trace_seconds"]:
-                with tracing.span("user", "benchmark/step"):
-                    losses.append(float(step(ring[i % len(ring)]["feed"])))
+                traced.send(ring[i % len(ring)])
                 i += 1
+            traced.drain()
+        losses += traced.losses
         out.trace = profiler.result()
     gc.unfreeze()
-    out.setup_parts = {k: round(v, 3) for k, v in parts.items()}
     out.attempted = len(steps)
     out.failed = sum(not np.isfinite(x) for x in losses)
 
@@ -115,12 +152,19 @@ def run(cell, args, t0):
     ref = adapter.reference_loss(cfg, params, check)
     got = float(step(check["feed"]))
     tol = cell.spec["loss_rel_tol"]
+    gaps = [1e3 * (b[1] - a[1]) for a, b in zip(steps, steps[1:])]
     out.notes = {"loss_first": losses[0], "loss_last": losses[-1],
                  "check_loss": got, "check_reference": ref,
                  "check_rel_err": abs(got - ref) / abs(ref), "check_tol": tol,
-                 "steps": len(steps),
-                 "step_ms_p50": harness.quantile(
-                     [1e3 * (e - s) for s, e, _ in steps], 0.5)}
+                 "steps": len(steps), "steps_ahead": ahead,
+                 "window_s": steps[-1][1] - steps[0][0],
+                 # from one step's loss on the host to the next one's: the
+                 # median, and the longest with the step it came before (a
+                 # run that reads low shows here where it stood still)
+                 "step_ms_p50": harness.quantile(gaps, 0.5),
+                 "step_gap_max_ms": max(gaps),
+                 "step_gap_max_at": gaps.index(max(gaps)) + 1}
+    out.checks["loss_rel_err"] = (abs(got - ref) / abs(ref), tol)
     out.correct = (out.failed == 0
                    and abs(got - ref) <= tol * abs(ref))
     return out

@@ -1,8 +1,8 @@
 """Model FLOP/s utilisation: the operations forward and backward need for the
 tokens counted (from shapes, benchmark/counts.py; recomputation not counted),
-per second the steps themselves took (the profiler's start and stop, which a
-traced run has between two steps, are not step time), over chips x the table's
-bf16 peak."""
+per second of the window, from its first step's dispatch to its last one's
+loss on the host (the steps overlap: the loop keeps some in flight), over
+chips x the table's bf16 peak."""
 
 UNIT = "%"
 SOURCE = "host_clock"
@@ -19,6 +19,6 @@ def read(run):
     per_step = sum(cell.adapter.train_flops(cell.config, cell.traffic, b)
                    for b in run.batches) / len(run.batches)
     flops = per_step * len(run.steps)
-    elapsed = sum(end - start for start, end, _ in run.steps)
+    elapsed = run.steps[-1][1] - run.steps[0][0]
     peak = run.device["peaks"]["bf16_flops_per_s"] * run.device["count"]
     return 100.0 * flops / elapsed / peak

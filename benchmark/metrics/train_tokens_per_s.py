@@ -1,5 +1,6 @@
-"""Tokens of the whole steps completed, over the time from the first of those
-steps' start to the last one's loss on the host. The language model counts
+"""Tokens of all the steps the window sent, over the time from the first one's
+dispatch to the last one's loss on the host (the loop keeps steps in flight
+and waits for every one it sent: loops/train.py). The language model counts
 every position, the translation model its non-padding target tokens."""
 
 UNIT = "tokens/s"

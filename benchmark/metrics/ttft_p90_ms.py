@@ -1,6 +1,6 @@
 """90th percentile of the time from when a request was DUE to its first sampled
-token on the host. With some 130 requests a dozen lie beyond it; a 95th would
-have half as many."""
+token on the host. The mix's rate decides how many requests lie beyond it: a
+tenth of round(rate_per_s x --seconds); PERF.md section 2 has the count."""
 
 from ..harness import quantile
 

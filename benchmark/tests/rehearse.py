@@ -51,14 +51,17 @@ MIXES = [
 ]
 CELLS = [
     ({"name": "tiny_train", "loop": "train", "executor": "Executor",
-      "trace_seconds": 1, "loss_rel_tol": 0.002}, "tiny-lm", "tiny_stream", 1,
+      "trace_seconds": 1, "steps_ahead": 8, "loss_rel_tol": 0.002},
+     "tiny-lm", "tiny_stream", 1,
      "lm-big_train_1chip"),
     ({"name": "tiny_nmt_train", "loop": "train", "executor": "Executor",
-      "trace_seconds": 1, "loss_rel_tol": 0.002}, "tiny-nmt", "tiny_pairs", 1,
+      "trace_seconds": 1, "steps_ahead": 8, "loss_rel_tol": 0.002},
+     "tiny-nmt", "tiny_pairs", 1,
      "nmt-big_train_1chip"),
     ({"name": "tiny_train_dp4", "loop": "train",
       "executor": "ParallelExecutor", "mesh": {"dp": 4}, "trace_seconds": 1,
-      "loss_rel_tol": 0.002}, "tiny-lm", "tiny_stream", 4, "lm-big_train_dp4"),
+      "steps_ahead": 8, "loss_rel_tol": 0.002},
+     "tiny-lm", "tiny_stream", 4, "lm-big_train_dp4"),
     ({"name": "tiny_serve", "loop": "serve",
       "engine": {"class": "PagedKVEngine", "n_slots": 4, "block_size": 4,
                  "n_blocks": 64, "max_len": 64},

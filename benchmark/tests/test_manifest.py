@@ -80,6 +80,10 @@ def test_metrics(bench):
         assert set(m) - {"workloads"} == {"name", "unit", "better", "bound",
                                           "source"}
         assert m["source"] in {"host_clock", "device_trace"}
+        # PR 35's issue asked to widen the upper limit to what the serving
+        # sets ask; the contract admits no bound over 0.1 and refuses the
+        # file, so a metric whose sets ask for more keeps 0.1 and PERF.md
+        # says what that bound cannot tell (section 2)
         assert 0.01 <= m["bound"] <= 0.1
     assert "workloads" not in e2e["setup_s"]
     layers = set()
@@ -104,6 +108,43 @@ def test_metrics(bench):
     perf = open(os.path.join(ROOT, "PERF.md")).read()
     for layer in layers:
         assert f"| {layer} |" in perf, layer
+
+
+def test_retired_metrics_are_gone_entry_and_reader(bench):
+    # prefill_tick_share read prompt tokens per busy slot-tick since PR 29
+    # (128.78 "%"): a percent over 105 invites the driver's refusal
+    names = {m["name"] for m in bench["end_to_end"] + bench["per_layer"]}
+    for retired in ("prefill_tick_share",):
+        assert retired not in names
+        assert not os.path.exists(os.path.join(HERE, "metrics", retired + ".py"))
+    stolen = next(m for m in bench["per_layer"]
+                  if m["name"] == "window_stolen_ms")
+    assert stolen == {"name": "window_stolen_ms", "unit": "ms",
+                      "better": "lower", "source": "host_clock",
+                      "layer": "load generator", "moves": "ttft_p90_ms",
+                      "workloads": ["lm-big_serve_chat"]}
+
+
+def test_setup_s_leaves_out_the_runtimes_start(monkeypatch):
+    import time
+    import types
+    from benchmark import harness
+
+    def slow_device(chips):
+        time.sleep(0.05)
+        return {"count": chips}
+    monkeypatch.setattr(harness, "device_facts", slow_device)
+    cell = types.SimpleNamespace(chips=4)
+    args = types.SimpleNamespace(seed=7, seconds=1.0)
+    t0 = time.perf_counter() - 3.0          # three seconds of imports
+    run = harness.start_run(cell, args, t0)
+    assert run.device == {"count": 4} and run.seed == 7
+    assert set(run.setup_parts) == {"import", "runtime_start"}
+    assert run.setup_parts["import"] == pytest.approx(3.0, abs=0.05)
+    assert 0.05 <= run.setup_parts["runtime_start"] < 0.5
+    run.setup_parts.update(runtime_start=12.5, build=0.5)
+    run.open_window(t_open=t0 + 30.0)
+    assert run.setup_s == pytest.approx(17.5)
 
 
 def test_every_file_under_paths_is_named_from_a_names_letters():

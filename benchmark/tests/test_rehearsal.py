@@ -15,7 +15,7 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 REPO = os.path.dirname(os.path.dirname(HERE))
 
 
-def rehearse(tmp, workload, trace, devices=1, seconds=1.5, seed=2 ** 31 + 11):
+def rehearse(tmp, workload, trace, devices=1, seconds=0.8, seed=2 ** 31 + 11):
     cmd = [sys.executable, os.path.join(HERE, "rehearse.py"), str(tmp),
            "--devices", str(devices), "--", "--workload", workload,
            "--seed", str(seed), "--seconds", str(seconds),
@@ -40,6 +40,19 @@ def check_schema(line, traced, chips=1):
         assert set(m) == {"value", "unit"} and isinstance(m["value"], float)
     if not traced:
         assert "setup_s" in line["metrics"]
+    # what `correct` compared, each number beside its limit, comes last
+    assert list(line)[-1] == "checks"
+    assert {"compilations_in_window", "failed"} < set(line["checks"])
+    assert all(set(c) == {"value", "limit"} for c in line["checks"].values())
+
+
+def check_setup(line, info):
+    """setup_s is the parts of set-up less the runtime's start."""
+    parts = info["setup_parts"]
+    assert set(parts) == {"import", "runtime_start", "build", "init",
+                          "compile_or_load", "warm"}
+    rest = sum(v for k, v in parts.items() if k != "runtime_start")
+    assert line["metrics"]["setup_s"]["value"] == pytest.approx(rest, abs=0.5)
 
 
 @pytest.mark.parametrize("cell,devices", [("tiny_train", 1),
@@ -53,6 +66,14 @@ def test_training_cells(tmp_path, cell, devices):
     assert info["notes"]["check_rel_err"] <= info["notes"]["check_tol"]
     assert info["compilations_in_window"] == 0
     assert info["notes"]["steps"] == line["attempted"]
+    # steps are dispatched ahead, every one sent is waited for, and the
+    # window is read from the first dispatch to the last loss on the host
+    assert info["notes"]["steps_ahead"] == 8
+    assert info["notes"]["window_s"] >= 0.8
+    tokens_per_s = line["metrics"]["train_tokens_per_s"]["value"]
+    assert tokens_per_s > 0
+    assert "loss_rel_err" in line["checks"]
+    check_setup(line, info)
 
 
 def test_training_cell_traced(tmp_path):
@@ -74,10 +95,19 @@ def test_serving_cell_and_the_throwaway_metric(tmp_path):
                                     "tpot_p50_ms", "setup_s"}
     assert line["attempted"] == 12 and info["notes"]["check_requests"] == 3
     assert info["notes"]["check_worst_logit_gap"] <= info["notes"]["check_tol"]
+    assert line["checks"]["worst_logit_gap"]["limit"] == info["notes"]["check_tol"]
+    check_setup(line, info)
+    # the machine beside the program, in every untraced run's notes
+    n = info["notes"]
+    assert n["window_stolen_ms"] == pytest.approx(
+        1e3 * (n["window_wall_s"] - n["window_thread_cpu_s"]
+               - n["window_engine_wait_s"]))
+    assert n["window_wall_s"] >= 2 and n["window_thread_cpu_s"] > 0
     traced, _, p = rehearse(tmp_path, "tiny_serve", 1, seconds=2)
     check_schema(traced, True)
     assert {"admit_ms_p50", "prefix_hit_rate", "tick_ms_p50",
-            "slot_occupancy"} <= set(traced["metrics"])
+            "slot_occupancy", "window_stolen_ms"} <= set(traced["metrics"])
+    assert "prefill_tick_share" not in traced["metrics"]
     assert 0 < traced["metrics"]["prefix_hit_rate"]["value"] < 100
     # every committed file of the benchmark is byte for byte in the copy:
     # the throw-away cell, mix, configuration and metric only ADDED files

@@ -101,6 +101,34 @@ def test_reader_gives_none_without_its_spans(metric):
         assert read(metric, alone) is None
 
 
+def test_step_host_takes_out_the_loop_s_wait_for_an_earlier_loss():
+    # the training loop keeps steps in flight and waits for an earlier one's
+    # loss under its own span: the same subtraction, and a turn that only
+    # filled the pipeline (no wait under it) is not counted
+    piped = [span("benchmark/loss_wait" if s.name == "executor/fetch"
+                  else s.name, 1e3 * s.start, 1e3 * s.end, s.id, s.parent_id)
+             for s in TRAIN] + [span("benchmark/step", 220, 226, 21)]
+    assert read("step_host_ms_p50", piped) == \
+        pytest.approx(read("step_host_ms_p50", TRAIN), abs=1e-9)
+
+
+def test_window_stolen_ms_is_wall_less_thread_time_less_waits():
+    # SERVE's two engine/wait spans: 32.6 + 31.9 = 64.5 ms. A loop of 80 ms
+    # whose thread ran 15.5 ms of them lost nothing; of 100 ms, 20 ms
+    run = run_of(SERVE)
+    run.window_clock = {"wall_s": 0.080, "thread_cpu_s": 0.0155}
+    read = harness.load_module("metrics", "window_stolen_ms").read
+    assert read(run) == pytest.approx(0.0, abs=1e-9)
+    run.window_clock = {"wall_s": 0.100, "thread_cpu_s": 0.0155}
+    assert read(run) == pytest.approx(20.0, abs=1e-9)
+    # a loop that took no clock (training), or a program without the span
+    run.window_clock = {}
+    assert read(run) is None
+    other = run_of([s for s in SERVE if s.name != "engine/wait"])
+    other.window_clock = {"wall_s": 0.100, "thread_cpu_s": 0.0155}
+    assert read(other) is None
+
+
 class ParentSpan:
     """A span as the program recorded it before it had ids (PR 23): the
     driver lays this benchmark over that program too."""
@@ -133,7 +161,7 @@ def test_manifest_and_readers_agree_on_the_new_metrics():
     import os
     bench = json.load(open(os.path.join(harness.ROOT, "BENCHMARK.json")))
     entries = {m["name"]: m for m in bench["per_layer"]}
-    for metric, _, _ in BY_HAND:
+    for metric in [m for m, _, _ in BY_HAND] + ["window_stolen_ms"]:
         mod, entry = harness.load_module("metrics", metric), entries[metric]
         assert (mod.UNIT, mod.SOURCE, mod.LAYER, mod.MOVES) == (
             entry["unit"], entry["source"], entry["layer"], entry["moves"])

@@ -55,6 +55,19 @@ def test_count_follows_seconds_and_all_are_due_inside(seconds):
     assert dues == sorted(dues) and 0 <= dues[0] and dues[-1] < seconds
 
 
+def test_the_cell_sends_what_the_files_rate_says():
+    # the rate is a number in the file, set by a sweep (rate_note), and the
+    # count of a 45 s window follows from it alone
+    assert isinstance(MIX["rate_per_s"], (int, float)) and MIX["rate_per_s"] > 0
+    n = traffic.request_count(MIX, 45)
+    assert n == round(MIX["rate_per_s"] * 45)
+    load = traffic.open_loop_requests(MIX, 3500000077, 45, VOCAB)
+    assert len(load["requests"]) == n
+    # the 90th percentile has a tenth of them beyond it: some hundreds
+    assert n // 10 >= 100
+    assert "PERF.md" in MIX["rate_note"]
+
+
 def test_lengths_are_the_clipped_lognormal_quantiles():
     spec = MIX["user_tokens"]
     got = traffic.lengths(spec, 135)

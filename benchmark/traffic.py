@@ -3,9 +3,11 @@
 A mix fixes the WORK: how many requests or rows, and the multiset of their
 lengths; the seed never changes it. For served traffic the mix also fixes the
 SCHEDULE, from its own `schedule_seed`: which request arrives when. A tail
-over random arrivals moves with the queue (measured: 12-22% between seeds at
-four fifths of the knee), so every run replays one drawn arrival trace, as a
-recorded trace would be replayed. The run's seed fills the requests with
+over random arrivals moves with the queue (measured on PR 23's engine, at
+four fifths of its knee: 12-22% between seeds), so every run replays one drawn
+arrival trace, as a recorded trace would be replayed. The rate is the file's
+`rate_per_s`, a number a sweep set once (benchmark/sweep.py; `rate_note`
+says which), and the count of requests follows from it and --seconds alone. The run's seed fills the requests with
 token ids (and the model with weights). For training rows the seed also deals
 the fixed multiset of lengths into batches. So two runs with different seeds
 do the same work at the same times, and a metric does not move with which

@@ -47,3 +47,6 @@ from .paged_attention import (paged_attention_lowering,  # noqa: F401
                               paged_decode_attention)
 from .recurrent import (fused_gru_sequence,  # noqa: F401
                         fused_lstm_sequence)
+from .latent_attention import (latent_attention_lowering,  # noqa: F401
+                               latent_paged_attention)
+from . import moe  # noqa: F401  (registers moe_route / moe_experts)

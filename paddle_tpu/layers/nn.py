@@ -29,7 +29,7 @@ def _prod(xs):
 
 # ---------------------------------------------------------------- fc
 def fc(input, size, num_flatten_dims=1, param_attr=None, bias_attr=None,
-       act=None, is_test=False, name=None, use_bf16=False):
+       act=None, is_test=False, name=None, use_bf16=False, out_dtype=None):
     """Fully connected layer (≙ reference layers/nn.py:114).
 
     use_bf16 routes the matmul through bfloat16 on the MXU with fp32
@@ -44,12 +44,14 @@ def fc(input, size, num_flatten_dims=1, param_attr=None, bias_attr=None,
         w = helper.create_parameter(pattr, shape=[in_dim, size],
                                     dtype=dtype_name(inp.dtype))
         out_shape = list(inp.shape[:num_flatten_dims]) + [size]
-        tmp = helper.create_tmp_variable(dtype=dtype_name(inp.dtype),
-                                         shape=out_shape)
+        tmp = helper.create_tmp_variable(
+            dtype=out_dtype or dtype_name(inp.dtype), shape=out_shape)
+        attrs = {"x_num_col_dims": num_flatten_dims, "y_num_col_dims": 1,
+                 "use_bf16": use_bf16}
+        if out_dtype:       # e.g. float32 logits over bfloat16 activations
+            attrs["out_dtype"] = out_dtype
         helper.append_op(type="mul", inputs={"X": [inp], "Y": [w]},
-                         outputs={"Out": [tmp]},
-                         attrs={"x_num_col_dims": num_flatten_dims,
-                                "y_num_col_dims": 1, "use_bf16": use_bf16})
+                         outputs={"Out": [tmp]}, attrs=attrs)
         mul_results.append(tmp)
     if len(mul_results) == 1:
         pre_bias = mul_results[0]
@@ -1034,6 +1036,108 @@ def paged_decode_attention(q, k_pool, v_pool, block_table, pos, num_heads,
     helper.append_op(type="paged_decode_attention", inputs=inputs,
                      outputs={"Out": [out]},
                      attrs={"num_heads": num_heads, "scale": float(scale)})
+    return out
+
+
+def rms_norm(input, epsilon=1e-6, param_attr=None, name=None):
+    """RMSNorm over the last axis with a learned scale (no bias, no mean):
+    statistics in float32, the result in the input's dtype."""
+    helper = LayerHelper("rms_norm", name=name)
+    dtype = dtype_name(input.dtype)
+    scale = helper.create_parameter(
+        param_attr, shape=[input.shape[-1]], dtype=dtype,
+        default_initializer=ConstantInitializer(1.0))
+    y = helper.create_tmp_variable(dtype=dtype, shape=input.shape)
+    helper.append_op(type="rms_norm", inputs={"X": [input], "Scale": [scale]},
+                     outputs={"Y": [y]}, attrs={"epsilon": float(epsilon)})
+    return y
+
+
+def rotary(x, pos, table, name=None):
+    """Rotary positions: rotate each head's values of `x` [N, .., heads*dim]
+    by the angles of row `pos[n]` of `table` [T, dim] (cos | sin)."""
+    helper = LayerHelper("rotary", name=name)
+    out = helper.create_tmp_variable(dtype=dtype_name(x.dtype), shape=x.shape,
+                                     stop_gradient=True)
+    helper.append_op(type="rotary",
+                     inputs={"X": [x], "Pos": [pos], "Table": [table]},
+                     outputs={"Out": [out]})
+    return out
+
+
+def latent_head_proj(x, w, mode, num_heads, k_dim, v_dim, name=None):
+    """One half of latent attention's `kv_b_proj` `w` [c, nh*(k_dim+v_dim)],
+    a head at a time: mode "absorb_q" takes q_nope [.., nh*k_dim] to the
+    latent [.., nh*c]; "expand_v" takes a latent context [.., nh*c] to
+    values [.., nh*v_dim] (fusion/latent_attention.py)."""
+    helper = LayerHelper("latent_head_proj", name=name)
+    width = num_heads * (w.shape[0] if mode == "absorb_q" else v_dim)
+    out = helper.create_tmp_variable(dtype=dtype_name(x.dtype),
+                                     shape=list(x.shape[:-1]) + [width],
+                                     stop_gradient=True)
+    helper.append_op(type="latent_head_proj", inputs={"X": [x], "W": [w]},
+                     outputs={"Out": [out]},
+                     attrs={"mode": mode, "num_heads": num_heads,
+                            "k_dim": k_dim, "v_dim": v_dim})
+    return out
+
+
+def latent_paged_attention(q, pool, block_table, pos, num_heads, v_width,
+                           scale, n_rows=None, name=None):
+    """Attention of padded per-head query rows `q` [S, G, nh*W] over the
+    latent rows of `pool` [n_blocks, 1, block_size, W], read through the
+    block table; returns per head the weighted sum of the rows' first
+    `v_width` values, [S, G, nh*v_width] (fusion/latent_attention.py)."""
+    helper = LayerHelper("latent_paged_attention", name=name)
+    out = helper.create_tmp_variable(
+        dtype=dtype_name(q.dtype),
+        shape=list(q.shape[:-1]) + [num_heads * v_width], stop_gradient=True)
+    inputs = {"Q": [q], "Pool": [pool], "BlockTable": [block_table],
+              "Pos": [pos]}
+    if n_rows is not None:
+        inputs["Rows"] = [n_rows]
+    helper.append_op(type="latent_paged_attention", inputs=inputs,
+                     outputs={"Out": [out]},
+                     attrs={"num_heads": num_heads, "v_width": v_width,
+                            "scale": float(scale)})
+    return out
+
+
+def moe_route(x, w_router, held, top_k, scaling, norm_topk_prob=True,
+              live=None, name=None):
+    """Sigmoid top-k routing over every column of `w_router`; returns the
+    dense weights of the `held` experts [n_held, N, 1] (float32) and the
+    rows each got [n_held] (int32) (fusion/moe.py). `live` [N]: rows that
+    are 0 there select nothing."""
+    helper = LayerHelper("moe_route", name=name)
+    n = _prod(x.shape[:-1])
+    weights = helper.create_tmp_variable(dtype="float32",
+                                         shape=[len(held), n, 1],
+                                         stop_gradient=True)
+    rows = helper.create_tmp_variable(dtype="int32", shape=[len(held)],
+                                      stop_gradient=True)
+    inputs = {"X": [x], "W": [w_router]}
+    if live is not None:
+        inputs["Live"] = [live]
+    helper.append_op(type="moe_route", inputs=inputs,
+                     outputs={"Weights": [weights], "Rows": [rows]},
+                     attrs={"held": [int(e) for e in held],
+                            "top_k": int(top_k), "scaling": float(scaling),
+                            "norm_topk_prob": bool(norm_topk_prob)})
+    return weights, rows
+
+
+def moe_experts(x, weights, rows, gate, up, down, name=None):
+    """The held experts' part of a routed layer: sum over them of
+    `weights[e] * down_e(silu(gate_e x) * up_e x)`, skipping every expert
+    whose `rows[e]` is 0 (fusion/moe.py)."""
+    helper = LayerHelper("moe_experts", name=name)
+    out = helper.create_tmp_variable(dtype=dtype_name(x.dtype), shape=x.shape,
+                                     stop_gradient=True)
+    helper.append_op(type="moe_experts",
+                     inputs={"X": [x], "Weights": [weights], "Rows": [rows],
+                             "Gate": [gate], "Up": [up], "Down": [down]},
+                     outputs={"Out": [out]})
     return out
 
 

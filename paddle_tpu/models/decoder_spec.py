@@ -1,0 +1,229 @@
+"""The description a decoder graph is built from.
+
+`models.transformer._decoder_block` builds one layer from a `DecoderSpec`:
+norm kind, residual order, position scheme, attention kind and feed-forward
+kind. The six dims every builder used to take (`vocab`, `d_model`,
+`d_inner`, `num_heads`, `num_layers`, `dropout`) are `DecoderSpec.classic`:
+post-LayerNorm, sinusoidal positions at the embedding, full heads, a ReLU
+pair, float32 parameters. `DecoderSpec.latent_moe` is the other point that
+is built. A spec comes from one of the two constructors; the fields are
+what `_decoder_block` reads, not a product to pick from: any other
+combination raises where a graph would have to build it.
+`serving.PagedKVEngine(model=spec)` takes either; everything else in the
+package takes the classic one.
+
+Kinds (each a string, checked by name; nothing is guessed):
+
+  norm        "layer_norm" | "rms_norm"
+  residual    "post" (x = norm(x + f(x))) | "pre" (x = x + f(norm(x)))
+  positions   "sinusoid" (added at the embedding) | "rotary" (inside attention)
+  attention   "full" (q/k/v heads over K and V pools) | "latent" (`LatentSpec`)
+  ffn         "relu" | "gated_silu"; layers from `moe.first_dense` on are
+              routed experts + shared expert (`MoESpec`)
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import Optional, Tuple
+
+LANES = 128
+
+
+def yarn_mscale(factor: float, a: float) -> float:
+    """m(f, a) = 0.1 a ln f + 1 (1 where nothing is stretched)."""
+    return 1.0 if factor <= 1.0 else 0.1 * a * math.log(factor) + 1.0
+
+
+@dataclasses.dataclass(frozen=True)
+class RopeSpec:
+    """Rotary positions over `dim` values, YaRN-stretched when `factor` > 1
+    (per-frequency blend of theta_i and theta_i / factor, a linear ramp
+    between the correction dims of `beta_fast` and `beta_slow` over
+    `original_max` positions)."""
+    dim: int
+    theta: float = 10000.0
+    factor: float = 1.0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+    original_max: int = 4096
+
+    @property
+    def table_scale(self) -> float:
+        """What cos and sin are multiplied by."""
+        return (yarn_mscale(self.factor, self.mscale)
+                / yarn_mscale(self.factor, self.mscale_all_dim))
+
+    @property
+    def softmax_mscale(self) -> float:
+        """m(factor, mscale_all_dim): its SQUARE multiplies the softmax scale."""
+        return yarn_mscale(self.factor, self.mscale_all_dim)
+
+
+@dataclasses.dataclass(frozen=True)
+class LatentSpec:
+    """Latent attention (MLA): queries through a rank-`q_lora_rank`
+    bottleneck; keys and values from ONE cached row a token,
+    `kv_lora_rank` normalised values + `rope.dim` rotated ones, shared by all
+    heads."""
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    v_head_dim: int
+    rope: RopeSpec
+
+    @property
+    def row_values(self) -> int:
+        return self.kv_lora_rank + self.rope.dim
+
+    @property
+    def row_lanes(self) -> int:
+        """The stored width of a cache row: `row_values` padded with zeros
+        to whole 128-lane rows (576 -> 640). A pool whose minor dimension
+        is not a multiple of 128 is stored block-minor by XLA on a TPU
+        (fusion/paged_attention.py, "The pool's shape"), so the row is
+        padded, and a query row is padded alike: one matmul scores both
+        parts and the zeros add nothing."""
+        return -(-self.row_values // LANES) * LANES
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.rope.dim
+
+    @property
+    def softmax_scale(self) -> float:
+        return self.qk_head_dim ** -0.5 * self.rope.softmax_mscale ** 2
+
+
+TOPK_METHODS = ("none",)
+SCORING = ("sigmoid",)
+
+
+@dataclasses.dataclass(frozen=True)
+class MoESpec:
+    """Routed experts beside shared ones. The router scores ALL `n_routed`
+    experts and picks `top_k`; `held` names the experts whose weights this
+    program has (one chip's share of an expert-parallel deployment), and
+    only their part of the sum is computed: what the others would add is
+    left out, and no code stands in for the chips that hold them."""
+    n_routed: int
+    top_k: int
+    d_expert: int
+    held: Tuple[int, ...]
+    n_shared: int = 1
+    first_dense: int = 1
+    scaling: float = 1.0
+    norm_topk_prob: bool = True
+    scoring: str = "sigmoid"
+    topk_method: str = "none"
+
+    def __post_init__(self):
+        if self.topk_method not in TOPK_METHODS:
+            raise NotImplementedError(
+                f"topk_method {self.topk_method!r}: the router implements "
+                f"{TOPK_METHODS} (plain top-k over every expert, no group "
+                "limit, no correction bias)")
+        if self.scoring not in SCORING:
+            raise NotImplementedError(
+                f"scoring_func {self.scoring!r}: the router implements "
+                f"{SCORING}")
+        if not self.held or sorted(set(self.held)) != list(self.held) or \
+                not 0 <= self.held[0] <= self.held[-1] < self.n_routed:
+            raise ValueError(f"held experts {self.held!r} must be distinct, "
+                             f"ascending, inside 0..{self.n_routed - 1}")
+
+
+@dataclasses.dataclass(frozen=True)
+class DecoderSpec:
+    vocab: int
+    d_model: int
+    d_inner: int
+    num_heads: int
+    num_layers: int
+    dropout: float = 0.0
+    packed: bool = False
+    norm: str = "layer_norm"
+    norm_eps: float = 1e-5
+    residual: str = "post"
+    positions: str = "sinusoid"
+    attention: str = "full"
+    ffn: str = "relu"
+    dtype: str = "float32"          # parameters, activations and the cache
+    latent: Optional[LatentSpec] = None
+    moe: Optional[MoESpec] = None
+
+    def __post_init__(self):
+        for field, kinds in (("norm", ("layer_norm", "rms_norm")),
+                             ("residual", ("post", "pre")),
+                             ("positions", ("sinusoid", "rotary")),
+                             ("attention", ("full", "latent")),
+                             ("ffn", ("relu", "gated_silu")),
+                             ("dtype", ("float32", "bfloat16"))):
+            if getattr(self, field) not in kinds:
+                raise ValueError(f"DecoderSpec.{field} = "
+                                 f"{getattr(self, field)!r}: one of {kinds}")
+        if (self.attention == "latent") != (self.latent is not None):
+            raise ValueError("attention='latent' comes with a LatentSpec, "
+                             "and only it")
+        if self.attention == "latent" and self.positions != "rotary":
+            raise ValueError("latent attention rotates part of its row: "
+                             "positions='rotary'")
+        if self.attention == "full" and self.positions == "rotary":
+            raise NotImplementedError(
+                "rotary positions with full heads: no graph builds it yet")
+
+    @classmethod
+    def classic(cls, vocab=32000, d_model=512, d_inner=2048, num_heads=8,
+                num_layers=6, dropout=0.0, packed=False):
+        """'Attention Is All You Need' section 3, as every graph here built
+        it before a block had kinds."""
+        return cls(vocab, d_model, d_inner, num_heads, num_layers, dropout,
+                   packed)
+
+    @classmethod
+    def latent_moe(cls, vocab, d_model, d_inner, num_heads, num_layers,
+                   latent: LatentSpec, moe: Optional[MoESpec] = None,
+                   norm_eps=1e-6, dtype="bfloat16"):
+        """The DeepSeek-V3 family's block: pre-norm RMSNorm residuals,
+        rotary positions inside latent attention, a gated SiLU pair, routed
+        experts from `moe.first_dense` on. With `classic`, the two points of
+        the kinds' product that a graph here builds."""
+        return cls(vocab, d_model, d_inner, num_heads, num_layers,
+                   norm="rms_norm", norm_eps=norm_eps, residual="pre",
+                   positions="rotary", attention="latent", ffn="gated_silu",
+                   dtype=dtype, latent=latent, moe=moe)
+
+    @property
+    def is_classic(self) -> bool:
+        return self == DecoderSpec.classic(**self.dims())
+
+    def dims(self) -> dict:
+        """The six dims (and `packed`) the classic builders take."""
+        return dict(vocab=self.vocab, d_model=self.d_model,
+                    d_inner=self.d_inner, num_heads=self.num_heads,
+                    num_layers=self.num_layers, dropout=self.dropout,
+                    packed=self.packed)
+
+    def ffn_kind(self, layer: int) -> str:
+        if self.moe is not None and layer >= self.moe.first_dense:
+            return "moe"
+        return self.ffn
+
+    @property
+    def moe_layers(self) -> Tuple[int, ...]:
+        return tuple(i for i in range(self.num_layers)
+                     if self.ffn_kind(i) == "moe")
+
+    # -- bytes ----------------------------------------------------------------
+    @property
+    def itemsize(self) -> int:
+        return 2 if self.dtype == "bfloat16" else 4
+
+    def cache_row_bytes(self) -> int:
+        """Bytes ONE position holds in the cache, over all layers, as stored."""
+        if self.attention == "latent":
+            return self.num_layers * self.latent.row_lanes * self.itemsize
+        return self.num_layers * 2 * self.d_model * self.itemsize

@@ -25,7 +25,16 @@ rows go and how the cache is read back:
   each slot at its own position, a window of G rows a forward;
 - `_PagedCache`: persistable block pools (float32, or int8 + scales) read
   through a block table, G rows a slot;
-- `_PagedLaneCache`: the same pools under decode rows plus prefill lanes.
+- `_PagedLaneCache`: the same pools under decode rows plus prefill lanes;
+- `_LatentPagedCache`: ONE pool a layer of latent rows (latent attention:
+  what is cached is the row every head shares), under decode rows and,
+  when the tick has them, prefill lanes.
+
+What a block is made of is a `DecoderSpec` (models/decoder_spec.py): norm
+kind, residual order, position scheme, attention kind, feed-forward kind.
+The classic spec (post-LayerNorm, sinusoid, full heads, ReLU pair) is what
+every graph above builds, op for op as before a block had kinds; the paged
+ticks take any spec (`model=`).
 
 A tick builder declares its feeds (`layers.data`; the serving engines make
 their feed arrays from these declarations, `serving.engine._feed_arrays`),
@@ -41,6 +50,7 @@ import numpy as np
 from .. import layers
 from ..initializer import NormalInitializer
 from ..param_attr import ParamAttr
+from .decoder_spec import DecoderSpec
 
 
 def positional_encoding_table(max_len, d_model):
@@ -174,40 +184,218 @@ def decoder_layer(x, enc_out, d_model, num_heads, d_inner, dropout, is_test,
     return _add_norm(f, x, dropout, is_test, name=name + "_ln3")
 
 
+def rotary_table(rope, max_len):
+    """[max_len, rope.dim] float32: row t holds cos(t * f_i) in its first
+    half and sin(t * f_i) in its second, both times `rope.table_scale`, the
+    angles in float64 on the host (at 17k positions a float32 product and a
+    device cosine of it would lose three digits). YaRN as the family's code
+    has it: f_i blends theta^(-2i/dim) and the same over `factor` by a
+    linear ramp between the correction dims of beta_fast and beta_slow."""
+    dim, half = rope.dim, rope.dim // 2
+    freq = rope.theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+    if rope.factor > 1.0:
+        def correction_dim(rotations):
+            return (dim * np.log(rope.original_max / (rotations * 2 * np.pi))
+                    / (2 * np.log(rope.theta)))
+        low = max(int(np.floor(correction_dim(rope.beta_fast))), 0)
+        high = min(int(np.ceil(correction_dim(rope.beta_slow))), dim - 1)
+        ramp = np.clip((np.arange(half, dtype=np.float64) - low)
+                       / max(high - low, 1e-3), 0.0, 1.0)
+        freq = freq / rope.factor * ramp + freq * (1.0 - ramp)
+    angle = np.arange(max_len, dtype=np.float64)[:, None] * freq[None, :]
+    table = np.concatenate([np.cos(angle), np.sin(angle)], axis=1)
+    return (table * rope.table_scale).astype("float32")
+
+
+class _TickRows:
+    """What a block of a non-classic spec needs to know of the rows it is
+    fed, beyond their values: each row's position (rotary positions enter
+    attention, not the embedding), which rows are real (a dead row — an
+    idle slot, the tail of a short chunk — selects no expert), and where
+    the routed layers leave their counts (`expert_rows`: per layer, the
+    rows each held expert got)."""
+
+    def __init__(self, spec, positions, live, max_len):
+        self.positions, self.live = positions, live
+        self.expert_rows = []
+        self.table = None
+        if spec.positions == "rotary":
+            self.table = layers.assign(rotary_table(spec.latent.rope, max_len))
+
+    def with_counts(self, next_ids):
+        """`next_ids` [R,1] int64 followed by the routed layers' counts, one
+        value a (layer, held expert): ONE fetch, so the counts come back in
+        the copy that brings the ids."""
+        if not self.expert_rows:
+            return next_ids
+        counts = layers.cast(layers.concat(self.expert_rows, axis=0), "int64")
+        return layers.concat(
+            [next_ids, layers.reshape(counts, shape=[-1, 1])], axis=0)
+
+
+def _param(name, shape, dtype):
+    """A named parameter no layer function makes (an expert stack, the two
+    halves of `kv_b`): in the main program, and the startup program with
+    the default initializer."""
+    from ..layer_helper import LayerHelper
+    return LayerHelper("param").create_parameter(ParamAttr(name=name),
+                                                 shape=shape, dtype=dtype)
+
+
+def _pre_norm(x, spec, name):
+    if spec.norm == "rms_norm":
+        return layers.rms_norm(x, epsilon=spec.norm_eps,
+                               param_attr=ParamAttr(name=name + ".scale"))
+    return layers.layer_norm(x, begin_norm_axis=2, epsilon=spec.norm_eps,
+                             param_attr=ParamAttr(name=name + ".scale"),
+                             bias_attr=ParamAttr(name=name + ".bias"))
+
+
+def _gated_ffn(x, d_model, d_inner, name):
+    """down(silu(gate x) * up x), no biases."""
+    gate, up = (_proj(x, d_inner, f"{name}_{n}") for n in ("gate", "up"))
+    return _proj(layers.elementwise_mul(layers.silu(gate), up), d_model,
+                 name + "_down")
+
+
+def _moe_ffn(x, spec, name, rows):
+    """The routed layer: the held experts' part of the top-k sum (routing
+    over every expert; `fusion/moe.py`) plus the shared expert."""
+    moe, d = spec.moe, spec.d_model
+    router = _param(name + "_router.w_0", [d, moe.n_routed], spec.dtype)
+    stack = {n: _param(f"{name}_experts_{n}",
+                       [len(moe.held), moe.d_expert, d] if n == "down"
+                       else [len(moe.held), d, moe.d_expert], spec.dtype)
+             for n in ("gate", "up", "down")}
+    weights, n_rows = layers.moe_route(
+        x, router, moe.held, moe.top_k, moe.scaling, moe.norm_topk_prob,
+        live=rows.live)
+    rows.expert_rows.append(n_rows)
+    routed = layers.moe_experts(x, weights, n_rows, stack["gate"],
+                                stack["up"], stack["down"])
+    shared = _gated_ffn(x, d, moe.d_expert * moe.n_shared, name + "_shared")
+    return layers.elementwise_add(routed, shared)
+
+
+def _latent_attention(x, spec, name, attend, rows):
+    """Latent attention (MLA) around `attend(q_rows, cache_row)`: queries
+    through the `q_a` bottleneck and its norm; ONE row a token from `kv_a`
+    (normalised `c_kv`, rotated `k_pe`); the key half of `kv_b` absorbed
+    into the query, so that the cache is read as it is stored, and the
+    value half applied to what the read returns (fusion/latent_attention.py).
+    A query row and a cache row are padded alike to `row_lanes`."""
+    lat, nh = spec.latent, spec.num_heads
+    n = x.shape[0]
+    dn, dr, c = lat.qk_nope_head_dim, lat.rope.dim, lat.kv_lora_rank
+    pad = lat.row_lanes - lat.row_values
+    c_q = layers.rms_norm(_proj(x, lat.q_lora_rank, name + "_qa"),
+                          epsilon=spec.norm_eps,
+                          param_attr=ParamAttr(name=name + "_qa_norm.scale"))
+    q = layers.reshape(_proj(c_q, nh * (dn + dr), name + "_qb"),
+                       shape=[n, nh, dn + dr])
+    q_nope = layers.reshape(
+        layers.slice(q, axes=[2], starts=[0], ends=[dn]), shape=[n, nh * dn])
+    q_pe = layers.rotary(
+        layers.reshape(layers.slice(q, axes=[2], starts=[dn], ends=[dn + dr]),
+                       shape=[n, nh * dr]), rows.positions, rows.table)
+    kv = layers.reshape(_proj(x, c + dr, name + "_kva"), shape=[n, c + dr])
+    c_kv = layers.rms_norm(
+        layers.slice(kv, axes=[1], starts=[0], ends=[c]),
+        epsilon=spec.norm_eps,
+        param_attr=ParamAttr(name=name + "_kva_norm.scale"))
+    k_pe = layers.rotary(layers.slice(kv, axes=[1], starts=[c], ends=[c + dr]),
+                         rows.positions, rows.table)
+    kv_b = _param(name + "_kvb.w_0", [c, nh * (dn + lat.v_head_dim)],
+                  spec.dtype)
+    q_lat = layers.latent_head_proj(q_nope, kv_b, "absorb_q", nh, dn,
+                                    lat.v_head_dim)
+    q_parts = [layers.reshape(q_lat, shape=[n, nh, c]),
+               layers.reshape(q_pe, shape=[n, nh, dr])]
+    row_parts = [c_kv, k_pe]
+    if pad:
+        q_parts.append(layers.fill_constant([n, nh, pad], spec.dtype, 0.0))
+        row_parts.append(layers.fill_constant([n, pad], spec.dtype, 0.0))
+    ctx = attend(
+        layers.reshape(layers.concat(q_parts, axis=2),
+                       shape=[n, 1, nh * lat.row_lanes]),
+        layers.reshape(layers.concat(row_parts, axis=1),
+                       shape=[n, 1, lat.row_lanes]))          # [n,1,nh*c]
+    out = layers.latent_head_proj(ctx, kv_b, "expand_v", nh, dn,
+                                  lat.v_head_dim)
+    return _proj(out, spec.d_model, name + "_o")
+
+
 def _decoder_block(x, i, attend, d_model, d_inner, dropout, is_test=True,
-                   prefix="l", attn="attn", cross=None):
-    """Decoder layer `i` of every graph but the NMT training graph:
-    self-attention (`_attention` around `attend(i, q, k_new, v_new)`, which
-    is all a graph chooses: see the module docstring), `cross(i, x)` when
-    given, the FFN — each followed by residual add + LayerNorm, named
-    `{prefix}{i}_ln{1,2[,3]}`. The parameter names are the contract: a
-    graph built from this block runs the weights any other trained."""
+                   prefix="l", attn="attn", cross=None, spec=None,
+                   rows=None):
+    """Decoder layer `i` of every graph but the NMT training graph, built
+    from what `spec` (a `DecoderSpec`; None is the classic one) says a block
+    is made of:
+
+    - attention: full heads (`_attention` around `attend(i, q, k_new,
+      v_new)`, which is all a graph chooses: see the module docstring) or
+      latent (`_latent_attention` around `attend(i, q_rows, cache_row)`);
+    - `cross(i, x)` when given;
+    - the feed-forward: the ReLU pair, the gated SiLU pair, or from
+      `spec.moe.first_dense` on routed experts beside the shared one;
+    - the residual order: post (`_add_norm`: add, then LayerNorm, named
+      `{prefix}{i}_ln{1,2[,3]}`) or pre (`x + f(norm(x))`, same names).
+
+    `rows` (`_TickRows`) carries what the rotary and routed kinds need of
+    the tick. The parameter names are the contract: a graph built from this
+    block runs the weights any other trained."""
     name = f"{prefix}{i}"
-    sublayers = [lambda x: _attention(x, x, x, d_model, f"{name}_{attn}",
-                                      functools.partial(attend, i))]
+    spec = spec or DecoderSpec.classic(d_model=d_model, d_inner=d_inner,
+                                       dropout=dropout)
+    if spec.attention == "latent":
+        sublayers = [lambda x: _latent_attention(
+            x, spec, f"{name}_{attn}", functools.partial(attend, i), rows)]
+    else:
+        sublayers = [lambda x: _attention(x, x, x, d_model, f"{name}_{attn}",
+                                          functools.partial(attend, i))]
     if cross is not None:
         sublayers.append(functools.partial(cross, i))
-    sublayers.append(lambda x: ffn(x, d_model, d_inner, dropout, is_test,
-                                   name=f"{name}_ffn"))
+    kind = spec.ffn_kind(i)
+    if kind == "moe":
+        sublayers.append(lambda x: _moe_ffn(x, spec, f"{name}_moe", rows))
+    elif kind == "gated_silu":
+        sublayers.append(lambda x: _gated_ffn(x, d_model, d_inner,
+                                              f"{name}_ffn"))
+    else:
+        sublayers.append(lambda x: ffn(x, d_model, d_inner, dropout, is_test,
+                                       name=f"{name}_ffn"))
+    if spec.residual == "pre":
+        for n, sublayer in enumerate(sublayers, 1):
+            x = layers.elementwise_add(
+                x, sublayer(_pre_norm(x, spec, f"{name}_ln{n}")))
+        return x
+    if spec.norm != "layer_norm":
+        raise NotImplementedError(
+            f"a post-norm block with norm {spec.norm!r}: no graph builds it")
     for n, sublayer in enumerate(sublayers, 1):
         x = _add_norm(sublayer(x), x, dropout, is_test, name=f"{name}_ln{n}")
     return x
 
 
 def _lm_decoder(x, attend, num_layers, d_model, d_inner, dropout,
-                is_test=True, param_prefix=""):
-    """The LM's stack of `_decoder_block`s, weights `{param_prefix}l{i}_*`."""
+                is_test=True, param_prefix="", spec=None, rows=None):
+    """The LM's stack of `_decoder_block`s, weights `{param_prefix}l{i}_*`;
+    a pre-norm stack ends in its final norm (`{param_prefix}final_norm`)."""
     for i in range(num_layers):
         x = _decoder_block(x, i, attend, d_model, d_inner, dropout, is_test,
-                           prefix=f"{param_prefix}l")
+                           prefix=f"{param_prefix}l", spec=spec, rows=rows)
+    if spec is not None and spec.residual == "pre":
+        x = _pre_norm(x, spec, f"{param_prefix}final_norm")
     return x
 
 
-def _lm_head(x, vocab, name="lm_head", ids=True, logp=False):
+def _lm_head(x, vocab, name="lm_head", ids=True, logp=False, bias=True,
+             out_dtype=None):
     """The vocabulary head: (logits, their argmax if `ids`, their
     log-softmax if `logp`)."""
     logits = layers.fc(x, size=vocab, num_flatten_dims=2, use_bf16=True,
-                       name=name)
+                       name=name, bias_attr=None if bias else False,
+                       out_dtype=out_dtype)
     return (logits, layers.argmax(logits, axis=2) if ids else None,
             layers.log_softmax(logits) if logp else None)
 
@@ -546,6 +734,25 @@ def _feed(name, shape, dtype="int64"):
                        append_batch_size=False)
 
 
+def _decode_feeds(S, NLB):
+    """The paged decode tick's feeds, declared here and nowhere else (the
+    mixed ticks lead with them, in this order: a bound step lays its feeds
+    out in declaration order and the decode step shares the leading span of
+    the mixed step's buffer) -> (tok, pos, btab, wblock, woff)."""
+    return (_feed("tick_tok", [S, 1]), _feed("tick_pos", [S, 1, 1], "float32"),
+            _feed("tick_btab", [S, NLB]), _feed("tick_wblock", [S]),
+            _feed("tick_woff", [S]))
+
+
+def _lane_feeds(L, C, NLB, block_size):
+    """The prefill lanes' feeds, after `_decode_feeds` -> (ltok, lpos, lbtab,
+    lwblocks, lrows, llast)."""
+    return (_feed("lane_tok", [L, C]), _feed("lane_pos", [L, 1, 1], "float32"),
+            _feed("lane_btab", [L, NLB]),
+            _feed("lane_wblocks", [L * C // block_size]),
+            _feed("lane_rows", [L]), _feed("lane_last", [L]))
+
+
 def _slot_cache_var(name, shape, dtype="float32"):
     """Persistable zero-initialized cache variable (main + startup blocks,
     the optimizer-accumulator idiom): the serving engine's KV caches live
@@ -788,7 +995,7 @@ def transformer_lm_paged_decode_tick(n_slots, n_blocks, block_size,
                                      d_model=512, d_inner=2048, num_heads=8,
                                      num_layers=6, dropout=0.0, packed=False,
                                      cache_prefix="pgd", topk_k=0,
-                                     kv_quant=False):
+                                     kv_quant=False, model=None):
     """ONE decode tick over a PAGED KV cache (`_PagedCache`) — the
     block-table variant of `transformer_lm_decode_tick`, whose slots own a
     full [1,nh,max_len,dh] row each; here a request's span is T =
@@ -807,14 +1014,17 @@ def transformer_lm_paged_decode_tick(n_slots, n_blocks, block_size,
     ranks hypotheses with. kv_quant=True stores the pools as int8, so the
     resident pool bytes drop ~4x and the pager hands the freed bytes back
     as extra admitted blocks."""
+    if model is not None and not model.is_classic:
+        # `model` (a DecoderSpec) describes the block; the dims above are
+        # the classic spec's and are not read
+        return _kinds_paged_tick(model, n_slots, n_blocks, block_size,
+                                 blocks_per_req, cache_prefix)
     S, NLB = n_slots, blocks_per_req
-    tok = _feed("tick_tok", [S, 1])
-    pos = _feed("tick_pos", [S, 1, 1], "float32")
+    tok, pos, btab, wblock, woff = _decode_feeds(S, NLB)
     cache = _PagedCache(
         cache_prefix, n_blocks, block_size, num_heads, d_model // num_heads,
-        num_layers, _feed("tick_btab", [S, NLB]), pos,
-        _feed("tick_wblock", [S]), _feed("tick_woff", [S]),
-        0.0 if packed else dropout, kv_quant)
+        num_layers, btab, pos, wblock, woff, 0.0 if packed else dropout,
+        kv_quant)
     x = _gen_embed_step(
         tok, pos, "tok_emb", vocab, d_model,
         positional_encoding_table(NLB * block_size, d_model), dropout)
@@ -823,6 +1033,15 @@ def transformer_lm_paged_decode_tick(n_slots, n_blocks, block_size,
     if topk_k:
         return (next_ids, cache.names, *layers.topk(logp, k=topk_k))
     return next_ids, cache.names
+
+
+def _split_rows(t, S, L, C):
+    """A mixed tick's [S + L*C, 1, H] → (decode rows [S,1,H], lane rows
+    [L,C,H])."""
+    return (layers.slice(t, axes=[0], starts=[0], ends=[S]),
+            layers.reshape(
+                layers.slice(t, axes=[0], starts=[S], ends=[S + L * C]),
+                shape=[L, C, t.shape[-1]]))
 
 
 class _PagedLaneCache(_PagedCache):
@@ -856,11 +1075,7 @@ class _PagedLaneCache(_PagedCache):
 
     def rows_of(self, t):
         """[S + L*C, 1, H] → (decode rows [S,1,H], lane rows [L,C,H])."""
-        S, L, C = self.S, self.L, self.C
-        return (layers.slice(t, axes=[0], starts=[0], ends=[S]),
-                layers.reshape(
-                    layers.slice(t, axes=[0], starts=[S], ends=[S + L * C]),
-                    shape=[L, C, t.shape[-1]]))
+        return _split_rows(t, self.S, self.L, self.C)
 
     def attend(self, i, q, kn, vn):
         (qd, ql), (kd, kl), (vd, vl) = (self.rows_of(q), self.rows_of(kn),
@@ -881,11 +1096,134 @@ class _PagedLaneCache(_PagedCache):
         return _infer_scale(ctx, self.dropout)
 
 
+class _LatentPagedCache:
+    """The paged cache of latent attention: per layer ONE pool
+    `{cache_prefix}_c{i}` [n_blocks, 1, block_size, row_lanes] in the
+    spec's dtype, a row a position: the normalised `c_kv`, the rotated
+    `k_pe`, zeros up to `LatentSpec.row_lanes` (the pool is declared as it
+    is stored: a minor dimension of whole 128-lane rows; the one-head form
+    of `_PagedCache`'s pool, so `paged_cache_write` writes it, rows and
+    whole blocks, as it writes a K pool). Block tables, the null block and
+    prefix sharing are `_PagedCache`'s; with `lanes` (the mixed tick's
+    n_slots, n_lanes, chunk, lbtab, lpos, lwblocks, lrows) the rows split
+    as in `_PagedLaneCache`. `attend(i, q_rows, row)` writes the new rows
+    and reads the written pool on the latent itself
+    (`layers.latent_paged_attention`)."""
+
+    def __init__(self, cache_prefix, n_blocks, block_size, spec, btab, pos,
+                 wblock, woff, lanes=None):
+        lat = spec.latent
+        self.num_heads, self.lat = spec.num_heads, lat
+        self.btab, self.pos, self.wblock, self.woff = btab, pos, wblock, woff
+        self.lanes = lanes
+        self.pools = [_slot_cache_var(
+            f"{cache_prefix}_c{i}", [n_blocks, 1, block_size, lat.row_lanes],
+            dtype=spec.dtype) for i in range(spec.num_layers)]
+        self.names = [v.name for v in self.pools]
+
+    def _read(self, q, pool, btab, pos, n_rows=None):
+        return layers.latent_paged_attention(
+            q, pool, btab, pos, self.num_heads, self.lat.kv_lora_rank,
+            self.lat.softmax_scale, n_rows=n_rows)
+
+    def attend(self, i, q, row):
+        pool, w = self.pools[i], self.lat.row_lanes
+        if self.lanes is None:
+            pool = layers.paged_cache_write(
+                pool, layers.reshape(row, shape=[-1, 1, w]), self.wblock,
+                self.woff, out=pool)
+            return self._read(q, pool, self.btab, self.pos)
+        ln = self.lanes
+        split = functools.partial(_split_rows, S=ln["n_slots"],
+                                  L=ln["n_lanes"], C=ln["chunk"])
+        (qd, ql), (rd, rl) = split(q), split(row)
+        pool = layers.paged_cache_write(
+            pool, layers.reshape(rd, shape=[-1, 1, w]), self.wblock,
+            self.woff, out=pool, chunk=rl, chunk_block_ids=ln["lwblocks"])
+        ctx_d = self._read(qd, pool, self.btab, self.pos)
+        ctx_l = self._read(ql, pool, ln["lbtab"], ln["lpos"], ln["lrows"])
+        return layers.concat(
+            [ctx_d, layers.reshape(ctx_l, shape=[-1, 1, ctx_d.shape[-1]])],
+            axis=0)
+
+
+def _embed_rows(tok, spec, name="tok_emb"):
+    """[N,1] ids -> [N,1,H] in the spec's dtype, unscaled, no positions
+    (the rotary kinds put them inside attention)."""
+    return layers.embedding(layers.unsqueeze(tok, axes=[2]),
+                            size=[spec.vocab, spec.d_model],
+                            param_attr=ParamAttr(name=name),
+                            dtype=spec.dtype)
+
+
+def _live_rows(wblock, lrows=None, chunk=0):
+    """1.0 for the rows of a tick that are real: a decode row that writes a
+    block of its own (an idle slot writes the null block, 0), a lane row
+    below its lane's `lane_rows`."""
+    zero = layers.fill_constant([1], "int64", 0)
+    live = layers.cast(layers.greater_than(wblock, zero), "float32")
+    if lrows is None:
+        return live
+    L = lrows.shape[0]
+    in_chunk = layers.less_than(
+        layers.assign(np.tile(np.arange(chunk, dtype="int64"), (L, 1))),
+        layers.reshape(lrows, shape=[L, 1]))
+    return layers.concat(
+        [live, layers.reshape(layers.cast(in_chunk, "float32"),
+                              shape=[L * chunk])], axis=0)
+
+
+def _kinds_paged_tick(model, n_slots, n_blocks, block_size, blocks_per_req,
+                      cache_prefix, lanes=None):
+    """The paged decode tick (`lanes` None) or mixed tick of a non-classic
+    `DecoderSpec`: the classic builders' feeds (`_decode_feeds`,
+    `_lane_feeds`); rows embedded without positions; `_LatentPagedCache`;
+    the blocks through `_lm_decoder`; a float32 head without bias. Returns
+    (next_ids followed by the routed layers' counts, cache names)."""
+    if model.attention != "latent":
+        raise NotImplementedError(
+            "the paged ticks build the classic spec or latent attention; "
+            f"attention {model.attention!r} with norm {model.norm!r}, "
+            f"residual {model.residual!r}, ffn {model.ffn!r} has no cache "
+            "seam yet")
+    S, NLB = n_slots, blocks_per_req
+    tok, pos, btab, wblock, woff = _decode_feeds(S, NLB)
+    toks, positions, lane_feeds, lrows = tok, pos, None, None
+    if lanes is not None:
+        L, C = lanes
+        ltok, lpos, lbtab, lwblocks, lrows, llast = _lane_feeds(
+            L, C, NLB, block_size)
+        lane_feeds = dict(n_slots=S, n_lanes=L, chunk=C, lbtab=lbtab,
+                          lpos=lpos, lwblocks=lwblocks, lrows=lrows)
+        toks = layers.concat([tok, layers.reshape(ltok, shape=[L * C, 1])],
+                             axis=0)
+        positions = layers.concat(
+            [pos, layers.reshape(_window_positions(lpos, C),
+                                 shape=[L * C, 1, 1])], axis=0)
+    cache = _LatentPagedCache(cache_prefix, n_blocks, block_size, model, btab,
+                              pos, wblock, woff, lane_feeds)
+    rows = _TickRows(model, positions,
+                     _live_rows(wblock, lrows, lanes[1] if lanes else 0),
+                     NLB * block_size)
+    x = _lm_decoder(_embed_rows(toks, model), cache.attend, model.num_layers,
+                    model.d_model, model.d_inner, 0.0, spec=model, rows=rows)
+    if lanes is not None:
+        xd, xl = _split_rows(x, S, L, C)
+        x = layers.concat(
+            [xd, layers.reshape(
+                layers.gather(layers.reshape(xl, shape=[L * C,
+                                                        model.d_model]),
+                              llast), shape=[L, 1, model.d_model])], axis=0)
+    _, next_ids, _ = _lm_head(x, model.vocab, bias=False,
+                              out_dtype="float32")
+    return rows.with_counts(next_ids), cache.names
+
+
 def transformer_lm_paged_mixed_tick(n_slots, n_lanes, chunk, n_blocks,
                                     block_size, blocks_per_req, vocab=32000,
                                     d_model=512, d_inner=2048, num_heads=8,
                                     num_layers=6, dropout=0.0, packed=False,
-                                    cache_prefix="pgd"):
+                                    cache_prefix="pgd", model=None):
     """ONE tick of decode rows AND prefill lanes over the paged KV pools
     (`_PagedLaneCache`): `transformer_lm_paged_decode_tick`'s S decode rows
     (same feeds, same pools and weights by name) plus L = `n_lanes` lanes
@@ -906,15 +1244,11 @@ def transformer_lm_paged_mixed_tick(n_slots, n_lanes, chunk, n_blocks,
     no other, so it needs no startup run where that tick's state exists."""
     S, L, C, BS, NLB = n_slots, n_lanes, chunk, block_size, blocks_per_req
     assert C % BS == 0, "a chunk is a whole number of blocks"
-    tok = _feed("tick_tok", [S, 1])
-    pos = _feed("tick_pos", [S, 1, 1], "float32")
-    btab, wblock, woff = (_feed("tick_btab", [S, NLB]),
-                          _feed("tick_wblock", [S]), _feed("tick_woff", [S]))
-    ltok = _feed("lane_tok", [L, C])
-    lpos = _feed("lane_pos", [L, 1, 1], "float32")
-    lbtab, lwblocks = (_feed("lane_btab", [L, NLB]),
-                       _feed("lane_wblocks", [L * C // BS]))
-    lrows, llast = _feed("lane_rows", [L]), _feed("lane_last", [L])
+    if model is not None and not model.is_classic:
+        return _kinds_paged_tick(model, S, n_blocks, BS, NLB, cache_prefix,
+                                 lanes=(L, C))
+    tok, pos, btab, wblock, woff = _decode_feeds(S, NLB)
+    ltok, lpos, lbtab, lwblocks, lrows, llast = _lane_feeds(L, C, NLB, BS)
     cache = _PagedLaneCache(
         cache_prefix, n_blocks, BS, num_heads, d_model // num_heads,
         num_layers, btab, pos, wblock, woff, 0.0 if packed else dropout,

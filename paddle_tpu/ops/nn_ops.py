@@ -44,6 +44,8 @@ def _matmul_out_dtype(in_dtype, attrs):
     per-op bf16<->fp32 convert pairs, which profiling showed cost ~30% of
     a ResNet-50 train step. Norm statistics and the loss still compute in
     fp32 (see _batch_norm/_softmax_with_cross_entropy)."""
+    if attrs.get("out_dtype"):      # asked for by name (a float32 head
+        return jnp.dtype(attrs["out_dtype"])    # over bfloat16 activations)
     if _bf16_active(attrs):
         return jnp.bfloat16
     return in_dtype
@@ -418,6 +420,35 @@ def _layer_norm(ctx, ins, attrs):
         y = y + jnp.reshape(ins["Bias"][0], norm_shape)
     return {"Y": [y], "Mean": [jnp.reshape(mean, mean.shape[:begin])],
             "Variance": [jnp.reshape(var, var.shape[:begin])]}
+
+
+@register_op("rms_norm")
+def _rms_norm(ctx, ins, attrs):
+    """x / sqrt(mean(x^2) + eps) * scale over the last axis; the statistics
+    in float32 whatever x is, the result in x's dtype."""
+    x = ins["X"][0]
+    xf = x.astype(jnp.float32)
+    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True)
+                           + attrs.get("epsilon", 1e-6))
+    return {"Y": [(y * ins["Scale"][0].astype(jnp.float32)).astype(x.dtype)]}
+
+
+@register_op("rotary", stop_gradient=True)
+def _rotary(ctx, ins, attrs):
+    """Rotate X [N, .., heads*dim] (each head's `dim` values paired (i,
+    i + dim/2): rotate-half) by the angles of row `Pos[n]` of `Table`
+    [T, dim]: cos in its first half, sin in its second, already scaled
+    (`models.transformer.rotary_table`). Float32 inside, X's dtype out."""
+    x, table = ins["X"][0], ins["Table"][0]
+    dim = table.shape[-1]
+    half = dim // 2
+    pos = ins["Pos"][0].reshape(-1).astype(jnp.int32)
+    row = table[pos].astype(jnp.float32)                       # [N, dim]
+    xf = x.reshape(pos.shape[0], -1, dim).astype(jnp.float32)  # [N, h, dim]
+    cos, sin = row[:, None, :half], row[:, None, half:]
+    x1, x2 = xf[..., :half], xf[..., half:]
+    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+    return {"Out": [out.reshape(x.shape).astype(x.dtype)]}
 
 
 @register_op("softmax")

@@ -830,6 +830,7 @@ class ContinuousBatchingEngine:
             with span("tick", "engine/wait"):
                 ids = np.asarray(fetches[0])   # realization barrier: the
                 #                    next tick's feed depends on it
+            self._note_tick_counts(tick, ids)
         with span("tick", "engine/commit"):
             self._m_dispatch.observe(td - t0)
             self._m_tick_latency.observe(time.perf_counter() - t0)
@@ -845,6 +846,11 @@ class ContinuousBatchingEngine:
             self.total_slot_ticks += self.n_slots
             finished = self._commit_tick(active, ids)
         return finished
+
+    def _note_tick_counts(self, tick, ids: np.ndarray):
+        """What the tick brought back behind its ids, onto the still open
+        `engine/tick` span and the engine's counters. Nothing here; the
+        paged engine's routed layers count their rows."""
 
     def _launch_tick(self):
         """Launch the tick `_fill_tick_feeds` just filled; returns its

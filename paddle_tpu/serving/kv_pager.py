@@ -678,6 +678,17 @@ class PagedKVEngine(ContinuousBatchingEngine):
 
     `topk_k` > 0 additionally fetches each tick's top-k log-probs —
     `paged_beam_search`'s scoring surface (greedy serving leaves it 0).
+
+    `model=` (a `models.decoder_spec.DecoderSpec`) describes the block the
+    ticks are built from, in place of the six dims (which are the classic
+    spec: `DecoderSpec.classic`). A latent-attention spec gets the latent
+    paged cache (one pool a layer, a padded row a position:
+    `_LatentPagedCache`), a spec with `moe` the routed expert layer over
+    the experts it holds; both ticks then bring back, behind the ids and in
+    the same copy, the rows every held expert got (`engine/tick`'s
+    `experts_touched` and `routed_rows`, `stats()["expert_rows"]`). With
+    such a model `speculative=`, `host_tier=`, `kv_quant=`, `quant=` and
+    `topk_k` are refused by name: none of them is built for it.
     """
 
     def __init__(self, n_slots: int = 4, vocab: int = 32000,
@@ -691,7 +702,40 @@ class PagedKVEngine(ContinuousBatchingEngine):
                  prefix_sharing: bool = True, topk_k: int = 0,
                  quant: Optional[str] = None, kv_quant: bool = False,
                  speculative=None,
-                 host_tier: Optional[HostTierConfig] = None):
+                 host_tier: Optional[HostTierConfig] = None,
+                 model=None):
+        from ..models.decoder_spec import DecoderSpec
+        if model is None:
+            model = DecoderSpec.classic(vocab, d_model, d_inner, num_heads,
+                                        num_layers, dropout, packed)
+        else:
+            enforce(isinstance(model, DecoderSpec),
+                    f"model must be a DecoderSpec, got "
+                    f"{type(model).__name__}", exc=InvalidArgumentError)
+            vocab, d_model, d_inner, num_heads, num_layers, dropout, packed \
+                = (model.vocab, model.d_model, model.d_inner,
+                   model.num_heads, model.num_layers, model.dropout,
+                   model.packed)
+        #: what the ticks' blocks are made of (`DecoderSpec`)
+        self.model = model
+        if not model.is_classic:
+            spec_on = speculative is not None and speculative is not False
+            for option, on in (("speculative", spec_on),
+                               ("host_tier", host_tier is not None),
+                               ("kv_quant", bool(kv_quant)),
+                               ("quant", quant is not None),
+                               ("topk_k", bool(topk_k))):
+                enforce(not on,
+                        f"{option}= is not built for a model with "
+                        f"attention {model.attention!r}"
+                        + (" and routed experts" if model.moe else "")
+                        + ": it walks the classic K/V pools and float32 "
+                        "weights; serve this model without it",
+                        exc=InvalidArgumentError)
+        #: (layer, held expert) -> rows routed to it since construction
+        self.expert_rows = np.zeros(
+            (len(model.moe_layers), len(model.moe.held) if model.moe else 0),
+            np.int64)
         enforce(host_tier is None or speculative is None,
                 "host_tier does not compose with speculative decoding "
                 "yet: a speculative round's rollback remaps blocks the "
@@ -718,6 +762,8 @@ class PagedKVEngine(ContinuousBatchingEngine):
         # scaled up by bytes_f32/bytes_int8 (an explicit n_blocks is
         # honored as-is — the caller owns the budget then).
         dh = d_model // num_heads
+        #: bytes one block holds over all layers, by the model's cache kind
+        self.block_bytes = model.cache_row_bytes() * self.block_size
         per_blk_f32 = 2 * num_layers * num_heads * self.block_size * dh * 4
         per_blk_i8 = 2 * num_layers * num_heads * self.block_size * (dh + 4)
         self.kv_quant_freed_bytes = 0
@@ -796,12 +842,14 @@ class PagedKVEngine(ContinuousBatchingEngine):
         self._mixed_program, startup = Program(), Program()
         with program_guard(self._mixed_program, startup), \
                 unique_name.guard():
-            self._mixed_ids, _ = transformer.transformer_lm_paged_mixed_tick(
+            outs = transformer.transformer_lm_paged_mixed_tick(
                 n_slots=self.n_slots, n_lanes=self.n_lanes,
                 chunk=self.chunk_tokens, n_blocks=self.n_blocks,
                 block_size=self.block_size,
                 blocks_per_req=self.blocks_per_req,
-                cache_prefix=self._cache_prefix, **self._builder_dims)
+                cache_prefix=self._cache_prefix, model=self.model,
+                **self._builder_dims)
+            self._mixed_ids = outs[0]
         self._init_missing_vars(startup)        # nothing, by construction
         if self.quant is not None:
             from ..framework.passes import get_pass
@@ -811,8 +859,8 @@ class PagedKVEngine(ContinuousBatchingEngine):
         self._mixed_feeds = _feed_arrays(self._mixed_program,
                                         share=self._feeds)
         self._mixed_step = self._exe.prepare(
-            self._mixed_program, dict(self._mixed_feeds), [self._mixed_ids],
-            self.scope).bind(self._mixed_feeds)
+            self._mixed_program, dict(self._mixed_feeds),
+            self._mixed_fetches(), self.scope).bind(self._mixed_feeds)
         # ONE host buffer for both ticks: the decode tick's feeds lead the
         # mixed tick's, so the decode step moves onto that leading span
         # (and its views): a fill writes them once, the decode tick
@@ -837,16 +885,22 @@ class PagedKVEngine(ContinuousBatchingEngine):
         # raises rather than serve float32 pools from the composite
         d = self._builder_dims
         dh = d["d_model"] // d["num_heads"]
-        self.paged_attention_lowering = paged_attention_lowering(
-            "int8" if self.kv_quant else "float32",
-            pool_block_shape(d["num_heads"], self.block_size, dh)[-1], 1, dh,
-            self.kv_quant)
+        if self.model.attention == "latent":
+            from ..fusion.latent_attention import latent_attention_lowering
+            lat = self.model.latent
+            self.paged_attention_lowering = latent_attention_lowering(
+                lat.row_lanes, lat.kv_lora_rank, d["num_heads"], 1)
+        else:
+            self.paged_attention_lowering = paged_attention_lowering(
+                "int8" if self.kv_quant else "float32",
+                pool_block_shape(d["num_heads"], self.block_size, dh)[-1], 1,
+                dh, self.kv_quant)
         outs = transformer.transformer_lm_paged_decode_tick(
             n_slots=self.n_slots, n_blocks=self.n_blocks,
             block_size=self.block_size,
             blocks_per_req=self.blocks_per_req,
             cache_prefix=self._cache_prefix, topk_k=self.topk_k,
-            kv_quant=self.kv_quant, **d)
+            kv_quant=self.kv_quant, model=self.model, **d)
         if self.topk_k:
             (self._next_ids, self.cache_names,
              self._topk_logp, self._topk_ids) = outs
@@ -857,6 +911,10 @@ class PagedKVEngine(ContinuousBatchingEngine):
         if self.topk_k:
             return [self._next_ids, self._topk_logp, self._topk_ids]
         return [self._next_ids]
+
+    def _mixed_fetches(self):
+        """`_tick_fetches` of the mixed tick."""
+        return [self._mixed_ids]
 
     def _prefilling(self, req: GenRequest) -> bool:
         """Does `req` still have prompt tokens for a lane to consume? (A
@@ -874,7 +932,7 @@ class PagedKVEngine(ContinuousBatchingEngine):
         wblock[:] = 0
         woff[:] = 0
         bs = self.block_size
-        kv_blocks = 0
+        kv_blocks = kv_rows = 0
         prefilling = []
         for slot, req in active.items():
             if self._prefilling(req):
@@ -888,7 +946,9 @@ class PagedKVEngine(ContinuousBatchingEngine):
             wblock[slot] = blocks[lb]
             woff[slot] = off
             kv_blocks += lb + 1      # the blocks this slot's read spans
+            kv_rows += req.fed + 1   # ... and the positions it attends
         self._tick_attrs["kv_blocks"] = kv_blocks
+        self._tick_attrs["decode_rows"] = kv_rows
         if self._mixed_step is not None:
             self._fill_lanes(prefilling)
 
@@ -930,9 +990,19 @@ class PagedKVEngine(ContinuousBatchingEngine):
     def _launch_tick(self):
         # a tick with a slot in prefill is the mixed program (the decode
         # rows ride in it); any other is the decode tick, unchanged
-        if self._lanes:
-            return self._run_bound_step(self._mixed_step, "mixed")
-        return super()._launch_tick()
+        return (self._run_bound_step(self._mixed_step, "mixed")
+                if self._lanes else super()._launch_tick())
+
+    def _note_tick_counts(self, tick, ids: np.ndarray):
+        # a routed model's ticks end in one count a (layer, held expert):
+        # the rows the tick routed to it (`_TickRows.with_counts`)
+        total = self.expert_rows
+        if total.size:
+            counts = ids[-total.size:, 0].reshape(total.shape)
+            total += counts
+            tick.attrs["experts_touched"] = int(np.count_nonzero(counts))
+            tick.attrs["routed_rows"] = int(counts.sum())
+            tick.attrs["expert_rows"] = counts.ravel().tolist()
 
     def _commit_tick(self, active: Dict[int, GenRequest],
                      ids: np.ndarray) -> List[GenRequest]:
@@ -1394,6 +1464,17 @@ class PagedKVEngine(ContinuousBatchingEngine):
         s["paged_attention_lowering"] = self.paged_attention_lowering
         s["kv_quant"] = {"enabled": self.kv_quant,
                          "freed_bytes": self.kv_quant_freed_bytes}
+        s["block_bytes"] = self.block_bytes
+        if self.model.attention == "latent":
+            lat = self.model.latent
+            s["latent_row"] = {"values": lat.row_values,
+                               "stored": lat.row_lanes}
+        if self.model.moe is not None:
+            # per routed layer, the rows each held expert got since
+            # construction (in `held`'s order)
+            s["expert_rows"] = {"layers": list(self.model.moe_layers),
+                                "held": list(self.model.moe.held),
+                                "rows": self.expert_rows.tolist()}
         if self.host_tier is not None:
             # measured wire bytes (actual buffer sizes the stream moved)
             # next to the per-block figure the prediction side uses —

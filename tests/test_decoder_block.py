@@ -243,3 +243,120 @@ def test_a_feed_on_one_side_only_cannot_happen():
     shared = _feed_arrays(main, share={"tick_tok": feeds["tick_tok"]})
     assert shared["tick_tok"] is feeds["tick_tok"]
     assert shared["tick_pos"] is not feeds["tick_pos"]
+
+
+# -- a block has kinds (ISSUE 36) --------------------------------------------
+
+from paddle_tpu.models.decoder_spec import (DecoderSpec, LatentSpec, MoESpec,
+                                            RopeSpec)
+
+KINDS = DecoderSpec.latent_moe(
+    vocab=61, d_model=32, d_inner=64, num_heads=8, num_layers=3,
+    latent=LatentSpec(q_lora_rank=24, kv_lora_rank=128, qk_nope_head_dim=8,
+                      v_head_dim=8, rope=RopeSpec(dim=16, factor=4.0,
+                                                  original_max=8)),
+    moe=MoESpec(n_routed=16, top_k=4, d_expert=256, held=(4, 5, 6, 7),
+                scaling=2.5))
+
+PAGED_BUILDERS = {
+    "paged_decode_tick": lambda **kw: T.transformer_lm_paged_decode_tick(
+        4, 20, 4, 6, **kw),
+    "paged_mixed_tick": lambda **kw: T.transformer_lm_paged_mixed_tick(
+        4, 2, 8, 20, 4, 6, **kw),
+}
+
+
+def _program(build):
+    main, startup = Program(), Program()
+    with program_guard(main, startup), unique_name.guard():
+        build()
+    return main
+
+
+@pytest.mark.parametrize("graph", sorted(PAGED_BUILDERS))
+def test_the_classic_spec_builds_the_program_the_six_dims_built(graph):
+    """`model=DecoderSpec.classic(...)` and the six dims are one program,
+    op for op, attr for attr, name for name: the cells that serve the
+    classic block run what they ran before a block had kinds."""
+    build = PAGED_BUILDERS[graph]
+    a = _program(lambda: build(**DIMS))
+    b = _program(lambda: build(model=DecoderSpec.classic(**DIMS), **DIMS))
+
+    def text(program):
+        return [(op.type, sorted(op.input_names()), sorted(op.output_names()),
+                 sorted((k, repr(v)) for k, v in op.attrs.items()))
+                for blk in program.blocks for op in blk.ops]
+    assert text(a) == text(b)
+    assert sorted(a.global_block().vars) == sorted(b.global_block().vars)
+    assert DecoderSpec.classic(**DIMS).is_classic and not KINDS.is_classic
+
+
+@pytest.mark.parametrize("graph", sorted(PAGED_BUILDERS))
+def test_a_block_of_other_kinds_goes_through_the_one_block(graph,
+                                                           monkeypatch):
+    """The latent, routed, pre-norm tick builds every layer through
+    `_decoder_block` (its two norms a layer carry the mark; the final norm,
+    outside the block, does not), declares the classic tick's feeds in the
+    classic order, and names its parameters from the block's prefix."""
+    build = lambda: PAGED_BUILDERS[graph](model=KINDS)
+    before = _op_types(build)
+    block, pre_norm = T._decoder_block, T._pre_norm
+
+    def marked_block(*a, **kw):
+        with monkeypatch.context() as m:
+            m.setattr(T, "_pre_norm", lambda *a, **kw: layers.scale(
+                pre_norm(*a, **kw), scale=1.0, bias=0.0))
+            return block(*a, **kw)
+
+    monkeypatch.setattr(T, "_decoder_block", marked_block)
+    after = _op_types(build)
+    assert after.count("scale") - before.count("scale") == 2 * KINDS.num_layers
+    monkeypatch.undo()
+    n = KINDS.num_layers
+    # rms_norm: two a block, two inside latent attention, the final one
+    assert before.count("rms_norm") == 4 * n + 1 and "layer_norm" not in before
+    assert before.count("latent_head_proj") == 2 * n
+    assert before.count("paged_cache_write") == n       # one pool a layer
+    reads = 2 if graph == "paged_mixed_tick" else 1
+    assert before.count("latent_paged_attention") == reads * n
+    assert before.count("moe_route") == before.count("moe_experts") == n - 1
+    assert "paged_decode_attention" not in before
+    program = _program(build)
+    feeds = _feed_arrays(program)
+    s, bs, nlb = 4, 4, 6
+    want = {"tick_tok": (s, 1), "tick_pos": (s, 1, 1), "tick_btab": (s, nlb),
+            "tick_wblock": (s,), "tick_woff": (s,)}
+    if graph == "paged_mixed_tick":
+        want.update({"lane_tok": (2, 8), "lane_pos": (2, 1, 1),
+                     "lane_btab": (2, nlb), "lane_wblocks": (4,),
+                     "lane_rows": (2,), "lane_last": (2,)})
+    assert {k: v.shape for k, v in feeds.items()} == want
+    assert list(feeds) == list(want)
+    params = {v.name: (tuple(v.shape), str(v.dtype))
+              for v in program.global_block().vars.values()
+              if v.persistable and not v.name.startswith("pgd")}
+    assert params["l0_ffn_gate.w_0"] == ((32, 64), "bfloat16")
+    assert params["l1_moe_experts_down"] == ((4, 256, 32), "bfloat16")
+    assert params["l2_attn_kvb.w_0"] == ((128, 8 * 16), "bfloat16")
+    assert params["l1_moe_router.w_0"] == ((32, 16), "bfloat16")
+    assert "l0_moe_router.w_0" not in params and "l1_ffn_gate.w_0" not in params
+    assert {"final_norm.scale", "lm_head.w_0", "tok_emb", "l2_ln2.scale",
+            "l0_attn_qa_norm.scale"} <= set(params)
+    pools = [v for v in program.global_block().vars.values()
+             if v.name.startswith("pgd_c")]
+    assert len(pools) == n and all(tuple(v.shape) == (20, 1, 4, 256)
+                                   for v in pools)
+
+
+def test_kinds_are_checked_by_name():
+    with pytest.raises(ValueError, match="norm"):
+        DecoderSpec.classic(**DIMS).__class__(**{
+            **DecoderSpec.classic(**DIMS).__dict__, "norm": "batch_norm"})
+    with pytest.raises(ValueError, match="LatentSpec"):
+        DecoderSpec(61, 32, 64, 4, 2, attention="latent", positions="rotary")
+    with pytest.raises(NotImplementedError, match="rotary positions"):
+        DecoderSpec(61, 32, 64, 4, 2, positions="rotary")
+    odd = DecoderSpec(61, 32, 64, 4, 2, norm="rms_norm")
+    with pytest.raises(NotImplementedError, match="no cache seam"):
+        _program(lambda: T.transformer_lm_paged_decode_tick(4, 20, 4, 6,
+                                                           model=odd))

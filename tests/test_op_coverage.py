@@ -1672,6 +1672,12 @@ COVERED_ELSEWHERE = {
     # table + pool program context — op parity + engine identity live in
     # the pager suite
     "paged_cache_write": "tests/test_kv_pager.py",
+    "latent_paged_attention": "tests/test_latent_attention.py",
+    "latent_head_proj": "tests/test_latent_attention.py",
+    "rotary": "tests/test_latent_attention.py",
+    "rms_norm": "tests/test_latent_moe_engine.py",
+    "moe_route": "tests/test_routed_experts.py",
+    "moe_experts": "tests/test_routed_experts.py",
     # the paged ticks' cache read through the block table: the Pallas
     # kernel against the composite, and the composite against dense
     # attention and the slot tick's fused op, live in the pager and

@@ -254,3 +254,32 @@ def test_sharded_train_step_runs_flash_per_shard(as_on_tpu):
     for ln in calls:
         assert per_shard in ln and full not in ln, ln[:300]
     assert re.search(r"sdy\.manual_computation|shard_map", text)
+
+
+@pytest.mark.parametrize("slots, positions", [(32, 1), (2, 128)])
+def test_latent_attention_kernel_lowers_at_the_benchmark_shapes(slots,
+                                                                positions):
+    """The latent read of axk1-ep16_serve_docqa: 32 decode rows, and two
+    prefill lanes of 128 positions, 64 heads over rows of 640 lanes in
+    blocks of 64, a table of 272 blocks."""
+    from paddle_tpu.fusion import latent_paged_attention
+    text = _tpu_text(
+        lambda q, pool, t, p, r: latent_paged_attention(
+            q, pool, t, p, 64, 512, 0.13, rows=r, backend="pallas"),
+        S((slots, positions, 64 * 640), BF16), S((2048, 1, 64, 640), BF16),
+        S((slots, 272), I32), S((slots, 1, 1), F32), S((slots,), I32))
+    assert _n_calls(text) == 1
+
+
+@pytest.mark.parametrize("rows", [32, 288])
+def test_expert_product_kernel_lowers_at_the_benchmark_shapes(rows):
+    """The grouped expert product of the decode tick (32 rows) and the mixed
+    tick (32 + 2 * 128), twelve held experts of 7168 x 2048."""
+    from paddle_tpu.fusion import moe
+    text = _tpu_text(
+        lambda x, w, n, g, u, d: moe.experts(x, w, n, g, u, d,
+                                             backend="pallas"),
+        S((rows, 7168), BF16), S((12, rows, 1), F32), S((12,), I32),
+        S((12, 7168, 2048), BF16), S((12, 7168, 2048), BF16),
+        S((12, 2048, 7168), BF16))
+    assert _n_calls(text) == 1

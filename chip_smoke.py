@@ -183,7 +183,7 @@ def phase_device():
 
 def phase_train_lm(vocab=32000, seq_len=1024, d_model=1024, d_inner=4096,
                    num_heads=16, num_layers=12, batch=8, steps=4,
-                   flash_calls_per_layer=3):
+                   flash_calls_per_layer=2):
     """Train the LM for a few steps on one fixed batch through Executor.run.
     Leaves the trained weights in the global scope for `phase_serve_lm`."""
     import paddle_tpu as pt
@@ -198,8 +198,8 @@ def phase_train_lm(vocab=32000, seq_len=1024, d_model=1024, d_inner=4096,
     t0 = time.time()
     n_calls = _n_custom_calls(exe.compiled_hlo(feed=feed, fetch_list=[loss]))
     hlo_s = time.time() - t0
-    # flash forward + the dq and dk/dv backward kernels, per layer: the step
-    # ran the Mosaic kernels, not the composite
+    # flash forward + the one-pass backward kernel, per layer: the step ran
+    # the Mosaic kernels, not the composite
     _check(n_calls == flash_calls_per_layer * num_layers,
            f"{n_calls} tpu_custom_calls in the compiled train step, expected "
            f"{flash_calls_per_layer} x {num_layers} layers")
@@ -357,7 +357,7 @@ def _timed_first(f, *args):
     return out, time.time() - t0
 
 
-def _check_flash(shape, with_segments, backend, timing):
+def _check_flash(shape, causal, with_segments, backend, timing):
     """Flash fwd + bwd (one jit) against the composite evaluated in f32 at
     the highest matmul precision, head by head so a long sequence's [T, T]
     scores never need more than one head of HBM."""
@@ -377,7 +377,7 @@ def _check_flash(shape, with_segments, backend, timing):
 
     def fwd_bwd(be, q, k, v, do):
         out, vjp = jax.vjp(lambda q, k, v: pk.flash_attention(
-            q, k, v, causal=True, backend=be, segment_ids=ids), q, k, v)
+            q, k, v, causal=causal, backend=be, segment_ids=ids), q, k, v)
         return (out,) + vjp(do.astype(out.dtype))
 
     got, c = _timed_first(jax.jit(lambda *a: fwd_bwd(backend, *a)),
@@ -397,8 +397,8 @@ def _check_flash(shape, with_segments, backend, timing):
                 errs[i] = max(errs[i], _rel_err(got[i][:, sl], ref[i]))
     timing["run_s"] += time.time() - t0
     _check(max(errs) <= TOL_BF16,
-           f"flash {shape} segments={with_segments}: out/dq/dk/dv errors "
-           f"{errs} exceed {TOL_BF16}")
+           f"flash {shape} causal={causal} segments={with_segments}: "
+           f"out/dq/dk/dv errors {errs} exceed {TOL_BF16}")
     return max(errs)
 
 
@@ -578,21 +578,34 @@ def _check_recurrent(kind, batch, steps, hidden, backend, timing):
 
 
 def phase_kernels(backend="pallas",
-                  flash_shapes=((8, 16, 1024, 64), (1, 8, 8192, 128)),
+                  flash_shapes=(((8, 16, 1024, 64), True),
+                                ((64, 16, 128, 64), True),
+                                ((64, 16, 128, 64), False),
+                                ((1, 8, 8192, 128), True)),
                   decode=(16, 64, 640, 16), recurrent=(64, 64, 256),
                   paged=(16, 1024, 16, 16, 64, 64), chunk=(2, 128)):
     """Every Pallas kernel the package selects by default on a TPU, called
     directly, compiled by Mosaic, run, and compared with its own composite.
+    flash_shapes = ([B, H, T, D], causal): the training cells' shapes (the
+    LM's, which is also its dp4 shard's; the NMT decoder's; its encoder's and
+    cross attention's) and a head that streams, each with and without
+    segment ids, under the plan its shape gives (`flash_plans`);
     decode = (heads, d_head, span, rows); recurrent = (batch, steps, hidden);
     paged = (slots, pool blocks, block size, heads, d_head, blocks a
     request): the serving benchmark's tick; chunk = (lanes, tokens a
     lane) of its mixed tick, over the same pools."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops.pallas_kernels import _plan_for
     timing = {"compile_s": 0.0, "run_s": 0.0}
-    errs = {}
-    for shape in flash_shapes:
+    errs, plans = {}, {}
+    for shape, causal in flash_shapes:
+        qkv = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
         for seg in (False, True):
-            name = "flash_" + "x".join(map(str, shape)) + ("_seg" * seg)
-            errs[name] = _check_flash(shape, seg, backend, timing)
+            name = ("flash_" + "x".join(map(str, shape))
+                    + ("" if causal else "_full") + ("_seg" * seg))
+            errs[name] = _check_flash(shape, causal, seg, backend, timing)
+            plans[name] = _plan_for(qkv, qkv, seg).scopes()
     errs["decode_T%d" % decode[2]] = _check_decode(*decode, backend, timing)
     errs["paged_decode"], paged_abs = _check_paged(*paged, backend, timing)
     errs["paged_chunk"] = _check_paged_chunk(*chunk, *paged[1:], backend,
@@ -603,6 +616,7 @@ def phase_kernels(backend="pallas",
     return {"compile_s": round(timing["compile_s"], 2),
             "run_s": round(timing["run_s"], 2), "backend": backend,
             "max_rel_err": {k: float("%.2e" % v) for k, v in errs.items()},
+            "flash_plans": plans,
             "paged_decode_max_abs_diff": float("%.2e" % paged_abs)}
 
 
@@ -653,7 +667,7 @@ def _shapes(text):
 
 def phase_multichip(ref_first_loss=None, vocab=32000, seq_len=1024,
                     d_model=1024, d_inner=4096, num_heads=16, num_layers=12,
-                    batch=8, steps=3, flash_calls_per_layer=3,
+                    batch=8, steps=3, flash_calls_per_layer=2,
                     attn_backend=None, ring_shape=(1, 8192, 8, 128),
                     loss_tol=5e-3, min_bytes_in_use=1 << 20):
     """The train_lm model under ParallelExecutor on a dp2 x tp2 mesh

@@ -16,7 +16,9 @@ the kernel itself in pallas interpret mode to pin the tiling logic).
 
 from __future__ import annotations
 
+import dataclasses
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -80,133 +82,257 @@ def _attention_reference(q, k, v, scale, causal, segment_ids=None):
     return jnp.einsum("bhqk,bhkd->bhqd", p, v)
 
 
-def _segment_mask(qseg_ref, kvseg_ref, block_k):
-    """[bq, bk] equality mask from the staged segment-id blocks.
+_LANES = 128
 
-    Layout (mirrors jax's own TPU flash kernel): q ids ride broadcast over
-    128 lanes as a [bq, 128] block, kv ids ride broadcast over 8 sublanes
-    as an [8, bk] block — Mosaic-legal tilings for what are logically 1-D
-    vectors."""
-    if block_k <= 128:
-        q_ids = qseg_ref[0][:, :block_k]           # [bq, bk] (lane slice)
+
+def _clamp_block(block, t):
+    """Block size actually used for length t when the caller NAMES one: the
+    requested block, clamped to t rounded UP to a 128 multiple. Keeps every
+    block shape Mosaic-legal (128 | bq, bk) for ANY sequence length — the
+    sequence is padded up to the block multiple and the padding
+    masked/sliced — and guarantees the segment-id tiling precondition
+    (128 | bk) by construction."""
+    return min(block, -(-t // _LANES) * _LANES)
+
+
+def _fit_block(t, target):
+    """Block size chosen for length t: the largest 128 multiple up to
+    `target` that divides t rounded up to 128, so the sequence is padded to
+    the lane width and no further."""
+    pieces = -(-t // _LANES)
+    return _LANES * max(d for d in range(1, target // _LANES + 1)
+                        if pieces % d == 0)
+
+
+def _pad_to(x, axis, target):
+    pad = [(0, 0)] * x.ndim
+    pad[axis] = (0, target - x.shape[axis])
+    return jnp.pad(x, pad) if target != x.shape[axis] else x
+
+
+# ---------------------------------------------------------------------------
+# the plan: how one flash call tiles the shape it is given
+# ---------------------------------------------------------------------------
+
+# VMEM a resident plan may fill with what grows with the key length, of the
+# 16 MiB Mosaic grants a kernel by default (the score tiles and the
+# query-side blocks take the rest): K and V and, in the backward, dK and dV,
+# each double-buffered by the pipeline, plus the backward's two float32
+# accumulators
+_VMEM_BUDGET = 8 << 20
+# score entries of one tile over all the heads of a step (1 MiB of float32):
+# what a v5e overlaps best at the benchmark's shapes — 4 heads of 256 x 256
+# at T = 1024, 16 heads of 128 x 128 at T = 128 (PERF.md section 6, PR 38)
+_TILE_SCORES = 1 << 18
+
+
+@dataclasses.dataclass(frozen=True)
+class FlashPlan:
+    """Tiling of one flash call. `resident`: a grid step holds the whole K
+    and V of `rows` heads in VMEM and loops over their key blocks inside the
+    kernel, stopping at the causal diagonal; otherwise key blocks stream
+    through the grid, one head a step. Either way a tile is `block_k` x
+    `block_q` scores a head, and a step's heads are one batch of it."""
+    resident: bool
+    block_q: int
+    block_k: int
+    rows: int
+
+    def scope(self, kernel):
+        """The kernel's name in a device trace: XLA names the custom call
+        after its `jax.named_scope`, so `device_ops` spells the plan."""
+        tiles = f"q{self.block_q}_k{self.block_k}"
+        if self.resident:
+            return f"flash_{kernel}_resident_{tiles}_rows{self.rows}"
+        return f"flash_{kernel}_streamed_{tiles}"
+
+    def scopes(self):
+        """Every kernel a forward and backward under this plan runs: a
+        resident head's backward is one pass, a streamed one's two."""
+        backward = ("bwd",) if self.resident else ("bwd_dq", "bwd_dkv")
+        return [self.scope(k) for k in ("fwd",) + backward]
+
+
+def _flash_plan(T, Tk, D, itemsize, heads, block_q=None, block_k=None):
+    """The plan for q [.., T, D] against k, v [.., Tk, D]: a pure function
+    of the shape, for the forward and the backward alike. `heads`: how many
+    heads may share a grid step (B*H; H when segment ids are given, whose
+    row a step's heads must share). An explicit block size is honoured
+    (clamped to the padded length).
+
+    A head whose keys fit the VMEM budget is resident, in tiles of 256 (the
+    diagonal stop then skips 3/8 of a causal square at T = 1024), as many
+    heads a step as fill `_TILE_SCORES` and divide `heads`. A longer head
+    streams 1024-wide blocks through the grid."""
+    head_bytes = -(-Tk // _LANES) * _LANES * D * (8 * itemsize + 8)
+    resident = head_bytes <= _VMEM_BUDGET
+    side = 256 if resident else 1024
+    bq = _clamp_block(block_q, T) if block_q else _fit_block(T, side)
+    bk = _clamp_block(block_k, Tk) if block_k else _fit_block(Tk, side)
+    if not resident:
+        return FlashPlan(False, bq, bk, 1)
+    most = max(1, min(_VMEM_BUDGET // head_bytes, _TILE_SCORES // (bq * bk)))
+    rows = max(r for r in range(1, most + 1) if heads % r == 0)
+    return FlashPlan(True, bq, bk, rows)
+
+
+def _plan_for(q, k, segments, block_q=None, block_k=None):
+    """The plan of a call on q [B, H, T, D] and k [B, H, Tk, D] (arrays or
+    shapes with a dtype), with segment ids or without."""
+    B, H, T, D = q.shape
+    return _flash_plan(T, k.shape[2], D, jnp.dtype(q.dtype).itemsize,
+                       H if segments else B * H, block_q, block_k)
+
+
+# ---------------------------------------------------------------------------
+# pieces the forward and backward kernels share
+# ---------------------------------------------------------------------------
+
+def _segment_mask(major_ids, minor_ids):
+    """[n, m] equality mask of a score tile from its staged segment ids.
+
+    Layout (mirrors jax's own TPU flash kernel): the ids of the tile's ROWS
+    (keys) ride broadcast over 128 lanes as [n, 128], the ids of its COLUMNS
+    (queries) ride broadcast over 8 sublanes as [8, m] — Mosaic-legal
+    tilings for what are logically 1-D vectors."""
+    m = minor_ids.shape[1]
+    if m <= _LANES:
+        rows = major_ids[:, :m]                    # lane slice
     else:
-        repeats, rem = divmod(block_k, 128)
+        repeats, rem = divmod(m, _LANES)
         if rem:
-            raise NotImplementedError("block_k must be a multiple of 128 "
+            raise NotImplementedError("blocks must be multiples of 128 "
                                       "when segment ids are used")
-        q_ids = jnp.tile(qseg_ref[0], (1, repeats))  # [bq, bk]
-    kv_ids = kvseg_ref[0][:1]                      # [1, bk]
-    return q_ids == kv_ids
+        rows = jnp.tile(major_ids, (1, repeats))
+    return rows == minor_ids[:1]
 
 
-def _block_alive(q_blk_idx, k_blk_idx, block_q, block_k, causal,
-                 causal_offset, qseg_ref, kvseg_ref):
-    """Cheap scalar predicate: can ANY (query, key) pair in this
-    (q-block, k-block) tile be unmasked? False → the whole tile's matmuls,
-    exp and accumulator updates are skipped (pl.when), which at T=32768
-    causal halves the issued FLOPs and on packed batches skips most
-    cross-segment tiles. Two safe over-approximations compose:
-
-    - causal: alive iff the LAST query row of the block can see the FIRST
-      key column (bottom-right alignment).
-    - segments: alive iff the blocks' id RANGES overlap — exact as a
-      "no-pair-can-match" test for any id assignment (ranges disjoint ⇒ no
-      equality), merely conservative when ranges overlap without an exact
-      match; the per-element mask still zeroes those.
-    Returns None when nothing can be skipped (no causal, no segments)."""
-    alive = None
-    if causal:
-        alive = ((q_blk_idx + 1) * block_q - 1 + causal_offset
-                 >= k_blk_idx * block_k)
-    if qseg_ref is not None:
-        q_ids = qseg_ref[0]
-        kv_ids = kvseg_ref[0]
-        seg_alive = ((jnp.max(q_ids) >= jnp.min(kv_ids))
-                     & (jnp.min(q_ids) <= jnp.max(kv_ids)))
-        alive = seg_alive if alive is None else alive & seg_alive
-    return alive
+def _ranges_overlap(a_ids, b_ids):
+    """Can ANY id of block a equal one of block b? Exact as a "no pair can
+    match" test for any id assignment (ranges disjoint => no equality),
+    merely conservative when ranges overlap without an exact match; the
+    per-element mask still zeroes those."""
+    return ((jnp.max(a_ids) >= jnp.min(b_ids))
+            & (jnp.min(a_ids) <= jnp.max(b_ids)))
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, qseg_ref, kvseg_ref, o_ref, lse_ref,
-                  m_ref, l_ref, acc_ref, *, scale, causal, block_q, block_k,
-                  num_k_blocks, causal_offset, true_tk):
-    """One (batch·head, q-block, k-block) grid step of flash attention.
+@dataclasses.dataclass(frozen=True)
+class _Visible:
+    """What decides which keys a query sees, beside segment ids: fixed for a
+    call. `offset` = Tk - T aligns the causal diagonal bottom-right (query i
+    sees keys up to i + offset: matches _attention_reference for Tq != Tk);
+    keys from `true_tk` on are padding."""
+    causal: bool
+    offset: int
+    true_tk: int
+    num_k_blocks: int
 
-    Grid iterates the k dimension innermost; m/l/acc scratch persists
-    across those sequential iterations (TPU grid semantics), implementing
-    the online softmax. Fully-masked tiles are skipped (_block_alive).
-    """
+
+class _KeyBlocks:
+    """The key blocks q-block `qi` has to see, inside a kernel. Blocks
+    [0, n_full) are visible whole to every query of the block: no mask is
+    paid. Blocks [n_full, n_live) hold some masked pair (the causal
+    diagonal; padded keys; any block under segment ids) and pay the
+    per-element mask. Blocks from n_live on hold no live pair and are not
+    visited: at T = 32768 causal that halves the issued FLOPs."""
+
+    def __init__(self, plan, see, qi, key_axis, major_ids_ref, minor_ids_ref):
+        self.plan, self.see, self.qi, self.key_axis = plan, see, qi, key_axis
+        self.major_ids_ref, self.minor_ids_ref = major_ids_ref, minor_ids_ref
+        self.segments = major_ids_ref is not None
+        bq, bk = plan.block_q, plan.block_k
+        # whether the call can meet a masked tile at all (else none is traced)
+        self.any_masked = (see.causal or self.segments
+                           or see.true_tk % bk != 0)
+        self.n_live = jnp.int32(see.num_k_blocks)
+        self.n_full = jnp.int32(0 if self.segments else see.true_tk // bk)
+        if see.causal:
+            first = qi * bq + see.offset + 1    # keys the FIRST query sees
+            last = first + bq - 1               # keys the LAST query sees
+            self.n_full = jnp.minimum(self.n_full,
+                                      jnp.maximum(first, 0) // bk)
+            self.n_live = jnp.minimum(
+                self.n_live, (jnp.maximum(last, 0) + bk - 1) // bk)
+
+    def _ids(self, j):
+        return (self.major_ids_ref[0, _key_rows(self.plan, j), :],
+                self.minor_ids_ref[0, 0])
+
+    def mask(self, j):
+        """[1, bk, bq]: which entries of masked tile j are alive."""
+        bq, bk = self.plan.block_q, self.plan.block_k
+        q0, k0 = self.qi * bq, j * bk
+        ids = self._ids(j) if self.segments else None
+        k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, (bk, bq), 0)
+        ok = k_pos < self.see.true_tk
+        if self.see.causal:
+            q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, (bk, bq), 1)
+            ok &= q_pos + self.see.offset >= k_pos
+        if ids is not None:
+            ok &= _segment_mask(*ids)
+        return ok[None]
+
+    def visit(self, tile):
+        """Run `tile(j, masked)` over the blocks to see. A resident plan
+        loops inside the kernel; a streamed one is at key block
+        program_id(key_axis) of the grid and runs it or not. Under segment
+        ids a block whose id range misses the queries' is skipped too."""
+        from jax.experimental import pallas as pl
+
+        def masked(j):
+            if self.segments:
+                pl.when(_ranges_overlap(*self._ids(j)))(
+                    lambda: tile(j, True))
+            else:
+                tile(j, True)
+
+        if self.plan.resident:
+            jax.lax.fori_loop(0, self.n_full,
+                              lambda j, c: tile(j, False) or c, 0)
+            if self.any_masked:
+                jax.lax.fori_loop(self.n_full, self.n_live,
+                                  lambda j, c: masked(j) or c, 0)
+        else:
+            j = pl.program_id(self.key_axis)
+            pl.when(j < self.n_full)(lambda: tile(j, False))
+            if self.any_masked:
+                pl.when((j >= self.n_full) & (j < self.n_live))(
+                    lambda: masked(j))
+
+
+def _key_rows(plan, j):
+    """Where key block j lies in a step's key-side blocks: a slice of the
+    resident heads, or all of the block the grid streamed in."""
     from jax.experimental import pallas as pl
+    if not plan.resident:
+        return slice(None)
+    return pl.ds(pl.multiple_of(j * plan.block_k, plan.block_k), plan.block_k)
 
-    j = pl.program_id(2)
-    qi = pl.program_id(1)
 
-    @pl.when(j == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+def _row_stat(ref, qi, block_q):
+    """A q-block's per-row residual (lse, delta) as [rows, 1, block_q]. The
+    array holds a head's T values as [T / 128, 128], so 1,024 floats are one
+    (8, 128) tile in HBM and not 128 copies a row."""
+    from jax.experimental import pallas as pl
+    pieces = block_q // ref.shape[-1]
+    return jnp.concatenate(
+        [ref[:, pl.ds(qi * pieces + c, 1), :] for c in range(pieces)], axis=2)
 
-    def _compute():
-        q = q_ref[0]                               # [bq, D]
-        k = k_ref[0]                               # [bk, D]
-        v = v_ref[0]                               # [bk, D]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # [bq, bk]
 
-        k_pos = j * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        # padded key columns (from rounding Tk up to the block size) are
-        # dead
-        s = jnp.where(k_pos < true_tk, s, _NEG_INF)
-        if qseg_ref is not None:
-            s = jnp.where(_segment_mask(qseg_ref, kvseg_ref, block_k), s,
-                          _NEG_INF)
-        if causal:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            # bottom-right alignment: matches _attention_reference for
-            # Tq != Tk
-            s = jnp.where(q_pos + causal_offset >= k_pos, s, _NEG_INF)
+def _store_row_stat(ref, qi, row):
+    """Write a q-block's [rows, 1, block_q] into the `_row_stat` layout."""
+    from jax.experimental import pallas as pl
+    lanes = ref.shape[-1]
+    pieces = row.shape[2] // lanes
+    for c in range(pieces):
+        ref[:, pl.ds(qi * pieces + c, 1), :] = (
+            row[:, :, c * lanes:(c + 1) * lanes])
 
-        m_prev = m_ref[:]                          # [bq, 1]
-        l_prev = l_ref[:]
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)                     # [bq, bk]
-        # a fully-masked row has m == s == NEG_INF, making exp(s - m) == 1
-        # for every DEAD entry — zero them so such rows output 0, not
-        # mean(v)
-        p = jnp.where(s > _NEG_INF / 2, p, 0.0)
-        l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[:] = m_new
-        l_ref[:] = l_new
 
-    alive = _block_alive(qi, j, block_q, block_k, causal, causal_offset,
-                         qseg_ref, kvseg_ref)
-    if alive is None:
-        _compute()
-    else:
-        pl.when(alive)(_compute)
-
-    @pl.when(j == num_k_blocks - 1)
-    def _finalize():
-        o_ref[0] = (acc_ref[:] /
-                    jnp.maximum(l_ref[:], 1e-30)).astype(o_ref.dtype)
-        if lse_ref is not None:
-            # logsumexp per query row — the backward kernels' residual.
-            # Stored broadcast over 128 lanes: Mosaic requires the last two
-            # block dims to be (8k, 128m)-tileable, so a [bq] vector output
-            # is illegal on real TPU (same layout as jax's own tpu
-            # flash_attention lse).
-            lse = m_ref[:] + jnp.log(jnp.maximum(l_ref[:], 1e-30))  # [bq,1]
-            lse_ref[0] = jnp.broadcast_to(lse, lse_ref[0].shape)
-
+# dot_general dimension numbers over [rows, ., .] operands, heads batched
+_NT = (((2,), (2,)), ((0,), (0,)))      # a @ b^T
+_NN = (((2,), (1,)), ((0,), (0,)))      # a @ b
+_TN = (((1,), (1,)), ((0,), (0,)))      # a^T @ b
 
 
 def _out_struct(shape, dtype, *refs):
@@ -221,114 +347,223 @@ def _out_struct(shape, dtype, *refs):
     return jax.ShapeDtypeStruct(shape, dtype)
 
 
-def _clamp_block(block, t):
-    """Block size actually used for length t: the requested block, clamped
-    to t rounded UP to a 128 multiple. Keeps every block shape
-    Mosaic-legal (128 | bq, bk) for ANY sequence length — the sequence is
-    padded up to the block multiple and the padding masked/sliced — and
-    guarantees the segment-id tiling precondition (128 | bk) by
-    construction."""
-    return min(block, -(-t // 128) * 128)
+def _named_call(kernel, scope, grid, ins, outs, scratch, interpret):
+    """pallas_call with refs handed to `kernel` by NAME (pallas passes them
+    positionally: inputs, outputs, scratch): an optional ref the call does
+    not stage — segment ids, lse — is simply absent, and the kernel's
+    default None stands. ins: {name: (array, spec)}; outs: {name: (struct,
+    spec)}; scratch: {name: VMEM shape}. Returns {name: array}. The scope is
+    the kernel's stable name in a device trace, whatever wraps the call."""
+    from jax.experimental import pallas as pl
+    names = [*ins, *outs, *scratch]
+
+    def body(*refs):
+        kernel(**dict(zip(names, refs)))
+
+    with jax.named_scope(scope):
+        res = pl.pallas_call(
+            body, grid=grid,
+            in_specs=[spec for _, spec in ins.values()],
+            out_specs=[spec for _, spec in outs.values()],
+            out_shape=[struct for struct, _ in outs.values()],
+            scratch_shapes=list(scratch.values()),
+            interpret=interpret,
+        )(*[x for x, _ in ins.values()])
+    return dict(zip(outs, res))
 
 
-def _pad_to(x, axis, target):
-    pad = [(0, 0)] * x.ndim
-    pad[axis] = (0, target - x.shape[axis])
-    return jnp.pad(x, pad) if target != x.shape[axis] else x
+class _Tiling:
+    """The padded operands and BlockSpecs of one flash call under a plan, by
+    name. The grid runs (head group, q block) for a resident plan and
+    (head group, q block, key block) for a streamed one — key blocks BEFORE
+    q blocks with `keys_outer`, the streamed dK / dV pass, which accumulates
+    over the q blocks."""
+
+    def __init__(self, plan, q, k, H, q_ids, kv_ids, keys_outer=False):
+        from jax.experimental import pallas as pl
+        self.plan = plan
+        BH, T, D = q.shape
+        bq, bk, rows = plan.block_q, plan.block_k, plan.rows
+        self.Tp, self.Tkp = -(-T // bq) * bq, -(-k.shape[1] // bk) * bk
+        self.nq, self.nk = self.Tp // bq, self.Tkp // bk
+        self.lanes = math.gcd(bq, _LANES)
+        self.q_ids, self.kv_ids = q_ids, kv_ids
+        if plan.resident:
+            self.grid = (BH // rows, self.nq)
+        elif keys_outer:
+            self.grid = (BH, self.nk, self.nq)
+        else:
+            self.grid = (BH, self.nq, self.nk)
+        # the keys a step holds: the head's, or one streamed block
+        keys = self.Tkp if plan.resident else bk
+
+        def spec(block, index):
+            def index_map(g, a, b=0):
+                return index(g, *((b, a) if keys_outer else (a, b)))
+            return pl.BlockSpec(block, index_map)
+
+        def batch(g):        # the row of segment ids a step's heads share
+            return g * rows // H
+
+        self.q_spec = spec((rows, bq, D), lambda g, i, j: (g, i, 0))
+        self.k_spec = spec((rows, keys, D), lambda g, i, j: (g, j, 0))
+        self.stat_spec = spec((rows, self.Tp // self.lanes, self.lanes),
+                              lambda g, i, j: (g, 0, 0))
+        # segment ids of a tile's rows (keys) / columns (queries): see
+        # _segment_mask
+        self.major_ids_spec = spec((1, keys, _LANES),
+                                   lambda g, i, j: (batch(g), j, 0))
+        self.minor_ids_spec = spec((1, 1, 8, bq),
+                                   lambda g, i, j: (batch(g), i, 0, 0))
+
+    def heads(self, x, length):
+        """[B, H, t, D] -> [B*H, length, D], zero-padded."""
+        return _pad_to(x.reshape(-1, *x.shape[2:]), 1, length)
+
+    def stat(self, x):
+        """[B, H, T] per-row residual -> the `_row_stat` layout."""
+        x = _pad_to(x.reshape(-1, x.shape[-1]), 1, self.Tp)
+        return x.reshape(x.shape[0], self.Tp // self.lanes, self.lanes)
+
+    def segment_inputs(self):
+        """{name: (ids, spec)} of the staged segment ids, or {}. Padding
+        carries id 0, which is harmless: padded keys are killed by the
+        true_tk guard, padded queries are sliced off (forward) or carry
+        do = 0 (backward) regardless of id."""
+        if self.q_ids is None:
+            return {}
+        bq = self.plan.block_q
+        B = self.q_ids.shape[0]
+        keys = _pad_to(self.kv_ids, 1, self.Tkp)
+        keys = jnp.broadcast_to(keys[:, :, None], (B, self.Tkp, _LANES))
+        queries = _pad_to(self.q_ids, 1, self.Tp).reshape(B, self.nq, 1, bq)
+        queries = jnp.broadcast_to(queries, (B, self.nq, 8, bq))
+        return {"major_ids_ref": (keys, self.major_ids_spec),
+                "minor_ids_ref": (queries, self.minor_ids_spec)}
 
 
-def _stage_segment_ids(q_ids, kv_ids, H, Tp, Tkp, bq, bk):
-    """Broadcast + pad segment ids into their Mosaic-legal layouts and
-    build (inputs, specs) for a grid whose leading dim is B*H. Padding
-    rows/columns carry id 0, which is harmless: padded key columns are
-    killed by the true_tk position guard and padded query rows are sliced
-    off (fwd) / killed by the true_tq guard (bwd) regardless of id."""
+# ---------------------------------------------------------------------------
+# flash forward
+# ---------------------------------------------------------------------------
+
+def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_ref=None, l_ref=None,
+                      acc_ref=None, lse_ref=None, major_ids_ref=None,
+                      minor_ids_ref=None, *, plan, see, single, scale):
+    """One grid step of flash attention: the q-block program_id(1) of
+    `plan.rows` heads against the key blocks it can see, by an online softmax
+    whose m / l / acc live in VMEM scratch (across the streamed plan's
+    sequential key steps: TPU grid semantics). The heads of a step are one
+    batch of every product and every elementwise pass: independent chains
+    the scheduler overlaps, where one short head alone waits out each
+    product's latency. A tile is laid [keys, queries], as the backward's: the
+    per-query m and l are then [1, bq] rows, eight to a vreg row and not one,
+    the accumulator [D, bq] fills its lanes at D = 64, and lse leaves in the
+    layout the backward reads; the output is transposed once a q-block.
+    Scores, softmax and accumulators are float32; p is cast to v's type for
+    the second product; a row with no visible key gives 0."""
     from jax.experimental import pallas as pl
 
-    B = q_ids.shape[0]
-    qseg = jnp.broadcast_to(
-        _pad_to(q_ids, 1, Tp)[:, :, None], (B, Tp, 128))
-    kvseg = jnp.broadcast_to(
-        _pad_to(kv_ids, 1, Tkp)[:, None, :], (B, 8, Tkp))
-    qseg_spec = pl.BlockSpec((1, bq, 128), lambda b, i, j, H=H: (b // H, i, 0))
-    kvseg_spec = pl.BlockSpec((1, 8, bk), lambda b, i, j, H=H: (b // H, 0, j))
-    return (qseg, kvseg), (qseg_spec, kvseg_spec)
+    qi = pl.program_id(1)
+    blocks = _KeyBlocks(plan, see, qi, 2, major_ids_ref, minor_ids_ref)
+
+    def init():
+        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[:] = jnp.zeros_like(l_ref)
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+
+    def visit(j, masked):
+        keys = _key_rows(plan, j)
+        v = v_ref[:, keys, :]                      # [rows, bk, D]
+        s = jax.lax.dot_general(
+            k_ref[:, keys, :], q_ref[:], _NT,
+            preferred_element_type=jnp.float32) * scale  # [rows, bk, bq]
+        if masked:
+            ok = blocks.mask(j)
+            s = jnp.where(ok, s, _NEG_INF)
+        m_new = jnp.max(s, axis=1, keepdims=True)  # [rows, 1, bq]
+        if not single:
+            m_prev = m_ref[:]
+            m_new = jnp.maximum(m_prev, m_new)
+            alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)                     # [rows, bk, bq]
+        if masked:
+            # a fully-masked row has m == s == NEG_INF, making
+            # exp(s - m) == 1 for every DEAD entry — zero them so such rows
+            # output 0, not mean(v)
+            p = jnp.where(ok, p, 0.0)
+        l = jnp.sum(p, axis=1, keepdims=True)
+        acc = jax.lax.dot_general(v, p.astype(v.dtype), _TN,
+                                  preferred_element_type=jnp.float32)
+        if single:
+            finalize(m_new, l, acc)
+        else:
+            l_ref[:] = l_ref[:] * alpha + l
+            acc_ref[:] = acc_ref[:] * alpha + acc  # [rows, D, bq]
+            m_ref[:] = m_new
+
+    def finalize(m, l, acc):
+        l = jnp.maximum(l, 1e-30)
+        o_ref[:] = jnp.swapaxes(acc / l, 1, 2).astype(o_ref.dtype)
+        if lse_ref is not None:
+            # logsumexp per query row — the backward kernels' residual
+            _store_row_stat(lse_ref, qi, m + jnp.log(l))
+
+    def finalize_state():
+        finalize(m_ref[:], l_ref[:], acc_ref[:])
+
+    if single:
+        visit(0, blocks.any_masked)
+    elif plan.resident:
+        init()
+        blocks.visit(visit)
+        finalize_state()
+    else:
+        j = pl.program_id(2)
+        pl.when(j == 0)(init)
+        blocks.visit(visit)
+        pl.when(j == see.num_k_blocks - 1)(finalize_state)
 
 
-def _flash_attention_pallas(q, k, v, scale, causal, block_q, block_k,
-                            interpret, with_lse=False, segment_ids=None):
-    from jax.experimental import pallas as pl
+def _flash_attention_pallas(q, k, v, scale, causal, block_q=None,
+                            block_k=None, interpret=False, with_lse=False,
+                            segment_ids=None):
     from jax.experimental.pallas import tpu as pltpu
 
     q_ids, kv_ids = _normalize_segment_ids(segment_ids, q, k)
     B, H, T, D = q.shape
     Tk = k.shape[2]
-    bq = _clamp_block(block_q, T)
-    bk = _clamp_block(block_k, Tk)
-    # round sequence lengths up to block multiples: padded queries are
+    plan = _plan_for(q, k, q_ids is not None, block_q, block_k)
+    t = _Tiling(plan, q.reshape(B * H, T, D), k.reshape(B * H, Tk, D), H,
+                q_ids, kv_ids)
+    bq = plan.block_q
+    # sequence lengths are rounded up to block multiples: padded queries are
     # sliced off, padded keys are masked dead inside the kernel
-    Tp = -(-T // bq) * bq
-    Tkp = -(-Tk // bk) * bk
-    qf = _pad_to(q.reshape(B * H, T, D), 1, Tp)
-    kf = _pad_to(k.reshape(B * H, Tk, D), 1, Tkp)
-    vf = _pad_to(v.reshape(B * H, Tk, D), 1, Tkp)
-    nq, nk = Tp // bq, Tkp // bk
-
-    inputs = [qf, kf, vf]
-    in_specs = [
-        pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
-        pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, 0)),
-        pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, 0)),
-    ]
-    has_seg = q_ids is not None
-    if has_seg:
-        seg_inputs, seg_specs = _stage_segment_ids(
-            q_ids, kv_ids, H, Tp, Tkp, bq, bk)
-        inputs += list(seg_inputs)
-        in_specs += list(seg_specs)
-
+    ins = {"q_ref": (t.heads(q, t.Tp), t.q_spec),
+           "k_ref": (t.heads(k, t.Tkp), t.k_spec),
+           "v_ref": (t.heads(v, t.Tkp), t.k_spec),
+           **t.segment_inputs()}
+    outs = {"o_ref": (_out_struct((B * H, t.Tp, D), q.dtype, q, k, v),
+                      t.q_spec)}
+    if with_lse:
+        outs["lse_ref"] = (
+            _out_struct((B * H, t.Tp // t.lanes, t.lanes), jnp.float32,
+                        q, k, v), t.stat_spec)
+    # a resident head that is ONE key block wide needs no running softmax:
+    # no state is kept, rescaled or revisited (q-blocks no query of which
+    # sees a key, causal with T > Tk, keep the general path: it writes 0)
+    single = plan.resident and t.nk == 1 and (not causal or Tk >= T)
     kernel = functools.partial(
-        _flash_kernel, scale=scale, causal=causal, block_q=bq, block_k=bk,
-        num_k_blocks=nk, causal_offset=Tk - T, true_tk=Tk)
-    out_specs = [pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0))]
-    out_shape = [_out_struct((B * H, Tp, D), q.dtype, q, k, v)]
+        _flash_fwd_kernel, plan=plan, see=_Visible(causal, Tk - T, Tk, t.nk),
+        single=single, scale=scale)
+    state = {} if single else {
+        "m_ref": pltpu.VMEM((plan.rows, 1, bq), jnp.float32),
+        "l_ref": pltpu.VMEM((plan.rows, 1, bq), jnp.float32),
+        "acc_ref": pltpu.VMEM((plan.rows, D, bq), jnp.float32)}
+    res = _named_call(kernel, plan.scope("fwd"), t.grid, ins, outs, state,
+                      interpret)
+    out = res["o_ref"][:, :T].reshape(B, H, T, D)
     if with_lse:
-        out_specs.append(
-            pl.BlockSpec((1, bq, 128), lambda b, i, j: (b, i, 0)))
-        out_shape.append(
-            _out_struct((B * H, Tp, 128), jnp.float32, q, k, v))
-    # adapt the kernel's (fixed) signature to the optional refs actually
-    # staged: segment refs when packed, lse only on the training path.
-    # pallas passes refs positionally (inputs, outputs, scratch), so one
-    # generic splicer covers every combination.
-    n_in, n_out = len(in_specs), len(out_specs)
-
-    def body(*refs, _k=kernel):
-        ins, outs = refs[:n_in], refs[n_in:n_in + n_out]
-        scratch = refs[n_in + n_out:]
-        qs_ref, ks_ref = (ins[3], ins[4]) if has_seg else (None, None)
-        lse_ref = outs[1] if with_lse else None
-        _k(ins[0], ins[1], ins[2], qs_ref, ks_ref, outs[0], lse_ref,
-           *scratch)
-    # the scope is the kernel's stable name in a device trace: XLA names the
-    # custom call after it (`jvp_flash_fwd_...`), whatever wraps the call
-    with jax.named_scope("flash_fwd"):
-        res = pl.pallas_call(
-            body,
-            grid=(B * H, nq, nk),
-            in_specs=in_specs,
-            out_specs=out_specs,
-            out_shape=out_shape,
-            scratch_shapes=[
-                pltpu.VMEM((bq, 1), jnp.float32),
-                pltpu.VMEM((bq, 1), jnp.float32),
-                pltpu.VMEM((bq, D), jnp.float32),
-            ],
-            interpret=interpret,
-        )(*inputs)
-    out = res[0][:, :T].reshape(B, H, T, D)
-    if with_lse:
-        return out, res[1][:, :T, 0].reshape(B, H, T)
+        return out, res["lse_ref"].reshape(B * H, t.Tp)[:, :T].reshape(B, H, T)
     return out
 
 
@@ -337,221 +572,164 @@ def _flash_attention_pallas(q, k, v, scale, causal, block_q, block_k,
 # lse) in VMEM — no [T, T] materialization in HBM on the backward either
 # ---------------------------------------------------------------------------
 
-def _bwd_masks(qi, j, block_q, block_k, causal, causal_offset,
-               true_tq, true_tk, qseg_ref=None, kvseg_ref=None):
-    q_pos = qi * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)
-    k_pos = j * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
-    valid = (q_pos < true_tq) & (k_pos < true_tk)
-    if causal:
-        valid &= q_pos + causal_offset >= k_pos
-    if qseg_ref is not None:
-        valid &= _segment_mask(qseg_ref, kvseg_ref, block_k)
-    return valid
+def _bwd_tile(q, k, v, do, lse, delta, ok, scale):
+    """One [rows, keys, queries] tile of the backward: (dq [rows, bq, D],
+    dk, dv [rows, bk, D]) partial sums in float32, dq and dk still to be
+    multiplied by `scale` (once, where they are written, and not an entry of
+    ds at a time). The tile is laid keys-major so that the per-query
+    residuals lse / delta broadcast as [rows, 1, bq] rows and four of the
+    five products need no transposed operand (dq's does)."""
+    f32 = jnp.float32
+    s = jax.lax.dot_general(k, q, _NT, preferred_element_type=f32) * scale
+    p = jnp.exp(s - lse)                           # [rows, bk, bq]
+    if ok is not None:
+        p = jnp.where(ok, p, 0.0)
+    dv = jax.lax.dot_general(p.astype(do.dtype), do, _NN,
+                             preferred_element_type=f32)
+    dp = jax.lax.dot_general(v, do, _NT, preferred_element_type=f32)
+    ds = p * (dp - delta)                          # [rows, bk, bq]
+    dk = jax.lax.dot_general(ds.astype(q.dtype), q, _NN,
+                             preferred_element_type=f32)
+    dq = jax.lax.dot_general(ds.astype(k.dtype), k, _TN,
+                             preferred_element_type=f32)
+    return dq, dk, dv
 
 
-def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                         qseg_ref, kvseg_ref, dq_ref, acc_ref, *, scale,
-                         causal, block_q, block_k, num_k_blocks,
-                         causal_offset, true_tq, true_tk):
+def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                      major_ids_ref=None, minor_ids_ref=None, dq_ref=None,
+                      dk_ref=None, dv_ref=None, dq_acc=None, dk_acc=None,
+                      dv_acc=None, *, plan, see, keys_outer, single, scale,
+                      num_q_blocks):
+    """One grid step of the flash backward.
+
+    Resident plan: dq, dk and dv come from ONE pass that recomputes s and p
+    once a tile — the q-block program_id(1) of `plan.rows` heads loops over
+    the key blocks it can see, dq accumulating over that loop and dk / dv in
+    VMEM over the q-blocks of the head. Streamed plan (a head's keys do not
+    fit): the same tile in two passes, because one of dq and dk / dv has to
+    accumulate over a grid axis the other is blocked along — a dq pass
+    (grid g, i, j; dk_ref None) and a dk / dv pass (grid g, j, i; dq_ref
+    None)."""
     from jax.experimental import pallas as pl
 
-    j = pl.program_id(2)
-    qi = pl.program_id(1)
+    q_axis, key_axis = (2, 1) if keys_outer else (1, 2)
+    qi = pl.program_id(q_axis)
+    blocks = _KeyBlocks(plan, see, qi, key_axis, major_ids_ref,
+                        minor_ids_ref)
 
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+    def tile(j, masked):
+        keys = _key_rows(plan, j)
+        ok = blocks.mask(j) if masked else None
+        return _bwd_tile(
+            q_ref[:], k_ref[:, keys, :], v_ref[:, keys, :], do_ref[:],
+            _row_stat(lse_ref, qi, plan.block_q),
+            _row_stat(delta_ref, qi, plan.block_q), ok, scale)
 
-    def _compute():
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
-        lse = lse_ref[0][:, :1]                    # [bq, 1] (128-lane bcast)
-        delta = delta_ref[0][:, :1]                # [bq, 1]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        valid = _bwd_masks(qi, j, block_q, block_k, causal, causal_offset,
-                           true_tq, true_tk, qseg_ref, kvseg_ref)
-        p = jnp.where(valid, jnp.exp(s - lse), 0.0)  # [bq, bk]
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * scale
-        acc_ref[:] += jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    def visit(j, masked):
+        keys = _key_rows(plan, j)
+        dq, dk, dv = tile(j, masked)
+        if dq_ref is not None:
+            dq_acc[:] += dq
+        if dk_ref is not None:
+            dk_acc[:, keys, :] += dk
+            dv_acc[:, keys, :] += dv
 
-    alive = _block_alive(qi, j, block_q, block_k, causal, causal_offset,
-                         qseg_ref, kvseg_ref)
-    if alive is None:
-        _compute()
-    else:
-        pl.when(alive)(_compute)
+    def zero_dq():
+        dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    @pl.when(j == num_k_blocks - 1)
-    def _finalize():
-        dq_ref[0] = acc_ref[:].astype(dq_ref.dtype)
+    def write(dq=None, dk=None, dv=None):
+        if dq is not None:
+            dq_ref[:] = (dq * scale).astype(dq_ref.dtype)
+        if dk is not None:
+            dk_ref[:] = (dk * scale).astype(dk_ref.dtype)
+            dv_ref[:] = dv.astype(dv_ref.dtype)
 
-
-def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                          qseg_ref, kvseg_ref, dk_ref, dv_ref, dk_acc,
-                          dv_acc, *, scale, causal, block_q, block_k,
-                          num_q_blocks, causal_offset, true_tq, true_tk):
-    from jax.experimental import pallas as pl
-
-    i = pl.program_id(2)      # inner: q blocks
-    ki = pl.program_id(1)
-
-    @pl.when(i == 0)
-    def _init():
+    def zero_dkv():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    def _compute():
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
-        lse = lse_ref[0][:, :1]                    # [bq, 1] (128-lane bcast)
-        delta = delta_ref[0][:, :1]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        valid = _bwd_masks(i, ki, block_q, block_k, causal, causal_offset,
-                           true_tq, true_tk, qseg_ref, kvseg_ref)
-        p = jnp.where(valid, jnp.exp(s - lse), 0.0)  # [bq, bk]
-        dv_acc[:] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)    # [bk, D]
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * scale              # [bq, bk]
-        dk_acc[:] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)    # [bk, D]
+    def write_dkv():
+        write(dk=dk_acc[:], dv=dv_acc[:])
 
-    alive = _block_alive(i, ki, block_q, block_k, causal, causal_offset,
-                         qseg_ref, kvseg_ref)
-    if alive is None:
-        _compute()
+    if single:       # one tile is the whole head: nothing to accumulate
+        write(*tile(0, blocks.any_masked))
+    elif plan.resident:
+        pl.when(qi == 0)(zero_dkv)
+        zero_dq()
+        blocks.visit(visit)
+        write(dq=dq_acc[:])
+        pl.when(qi == num_q_blocks - 1)(write_dkv)
+    elif dq_ref is not None:
+        j = pl.program_id(key_axis)
+        pl.when(j == 0)(zero_dq)
+        blocks.visit(visit)
+        pl.when(j == see.num_k_blocks - 1)(lambda: write(dq=dq_acc[:]))
     else:
-        pl.when(alive)(_compute)
-
-    @pl.when(i == num_q_blocks - 1)
-    def _finalize():
-        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+        pl.when(qi == 0)(zero_dkv)
+        blocks.visit(visit)
+        pl.when(qi == num_q_blocks - 1)(write_dkv)
 
 
 def _flash_attention_bwd_pallas(q, k, v, o, lse, do, scale, causal,
-                                block_q, block_k, interpret,
+                                block_q=None, block_k=None, interpret=False,
                                 segment_ids=None, delta=None):
-    from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     q_ids, kv_ids = _normalize_segment_ids(segment_ids, q, k)
     B, H, T, D = q.shape
     Tk = k.shape[2]
-    bq = _clamp_block(block_q, T)
-    bk = _clamp_block(block_k, Tk)
-    Tp = -(-T // bq) * bq
-    Tkp = -(-Tk // bk) * bk
-    nq, nk = Tp // bq, Tkp // bk
-
+    plan = _plan_for(q, k, q_ids is not None, block_q, block_k)
     if delta is None:
         # delta_i = sum_d do*o — recomputed here on the single-device path;
         # ring attention passes the global delta in (o may then be None)
         delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                         axis=-1)                   # [B, H, T]
-    qf = _pad_to(q.reshape(B * H, T, D), 1, Tp)
-    kf = _pad_to(k.reshape(B * H, Tk, D), 1, Tkp)
-    vf = _pad_to(v.reshape(B * H, Tk, D), 1, Tkp)
-    dof = _pad_to(do.reshape(B * H, T, D), 1, Tp)
-    # per-row residuals ride broadcast over 128 lanes (Mosaic tiling; see
-    # the forward lse layout note)
-    lsef = jnp.broadcast_to(
-        _pad_to(lse.reshape(B * H, T), 1, Tp)[..., None],
-        (B * H, Tp, 128))
-    deltaf = jnp.broadcast_to(
-        _pad_to(delta.reshape(B * H, T), 1, Tp)[..., None],
-        (B * H, Tp, 128))
+    f32 = jnp.float32
+    bq, bk, rows = plan.block_q, plan.block_k, plan.rows
 
-    common = dict(scale=scale, causal=causal, block_q=bq, block_k=bk,
-                  causal_offset=Tk - T, true_tq=T, true_tk=Tk)
-    has_seg = q_ids is not None
-    q_spec = pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0))
-    r_spec = pl.BlockSpec((1, bq, 128), lambda b, i, j: (b, i, 0))
-    k_spec = pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, 0))
+    def call(scope, want_dq, want_dkv):
+        keys_outer = not want_dq
+        t = _Tiling(plan, q.reshape(B * H, T, D), k.reshape(B * H, Tk, D),
+                    H, q_ids, kv_ids, keys_outer)
+        # padded queries carry do = 0 and delta = 0 (and a finite lse), so
+        # they add nothing to dk and dv whatever they see
+        ins = {"q_ref": (t.heads(q, t.Tp), t.q_spec),
+               "k_ref": (t.heads(k, t.Tkp), t.k_spec),
+               "v_ref": (t.heads(v, t.Tkp), t.k_spec),
+               "do_ref": (t.heads(do, t.Tp), t.q_spec),
+               "lse_ref": (t.stat(lse), t.stat_spec),
+               "delta_ref": (t.stat(delta), t.stat_spec),
+               **t.segment_inputs()}
+        outs, scratch = {}, {}
+        keys = t.Tkp if plan.resident else bk
+        single = plan.resident and t.nq == 1 and t.nk == 1
+        if want_dq:
+            outs["dq_ref"] = (_out_struct((B * H, t.Tp, D), q.dtype,
+                                          q, k, v, do), t.q_spec)
+            scratch["dq_acc"] = pltpu.VMEM((rows, bq, D), f32)
+        if want_dkv:
+            for name, x in (("dk", k), ("dv", v)):
+                outs[name + "_ref"] = (_out_struct(
+                    (B * H, t.Tkp, D), x.dtype, q, k, v, do), t.k_spec)
+                scratch[name + "_acc"] = pltpu.VMEM((rows, keys, D), f32)
+        kernel = functools.partial(
+            _flash_bwd_kernel, plan=plan,
+            see=_Visible(causal, Tk - T, Tk, t.nk), keys_outer=keys_outer,
+            single=single, scale=scale, num_q_blocks=t.nq)
+        return _named_call(kernel, plan.scope(scope), t.grid, ins, outs,
+                           {} if single else scratch, interpret)
 
-    def _splice_seg(kernel, n_in):
-        """Generic adapter: insert (None, None) for the segment refs when
-        no segment inputs are staged (pallas passes refs positionally:
-        inputs, outputs, scratch)."""
-        if has_seg:
-            return kernel
-
-        def body(*refs, _k=kernel):
-            return _k(*refs[:n_in], None, None, *refs[n_in:])
-        return body
-
-    dq_inputs = [qf, kf, vf, dof, lsef, deltaf]
-    dq_specs = [q_spec, k_spec, k_spec, q_spec, r_spec, r_spec]
-    dq_kernel = functools.partial(_flash_bwd_dq_kernel, num_k_blocks=nk,
-                                  **common)
-    if has_seg:
-        seg_inputs, seg_specs = _stage_segment_ids(
-            q_ids, kv_ids, H, Tp, Tkp, bq, bk)
-        dq_inputs += list(seg_inputs)
-        dq_specs += list(seg_specs)
-    with jax.named_scope("flash_bwd_dq"):
-        dq = pl.pallas_call(
-            _splice_seg(dq_kernel, 6),
-            grid=(B * H, nq, nk),
-            in_specs=dq_specs,
-            out_specs=q_spec,
-            out_shape=_out_struct((B * H, Tp, D), q.dtype, q, k, v, do),
-            scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
-            interpret=interpret,
-        )(*dq_inputs)
-
-    # dk/dv: k blocks are the outer (revisited) dim, q blocks stream inner
-    qi_spec = pl.BlockSpec((1, bq, D), lambda b, j, i: (b, i, 0))
-    ri_spec = pl.BlockSpec((1, bq, 128), lambda b, j, i: (b, i, 0))
-    kj_spec = pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, 0))
-    dkv_inputs = [qf, kf, vf, dof, lsef, deltaf]
-    dkv_specs = [qi_spec, kj_spec, kj_spec, qi_spec, ri_spec, ri_spec]
-    dkv_kernel = functools.partial(_flash_bwd_dkv_kernel, num_q_blocks=nq,
-                                   **common)
-    if has_seg:
-        # grid order here is (b, k-block j, q-block i): swap the index-map
-        # arguments accordingly
-        qsegf, kvsegf = seg_inputs
-        dkv_inputs += [qsegf, kvsegf]
-        dkv_specs += [
-            pl.BlockSpec((1, bq, 128), lambda b, j, i, H=H: (b // H, i, 0)),
-            pl.BlockSpec((1, 8, bk), lambda b, j, i, H=H: (b // H, 0, j)),
-        ]
-    with jax.named_scope("flash_bwd_dkv"):
-        dk, dv = pl.pallas_call(
-            _splice_seg(dkv_kernel, 6),
-            grid=(B * H, nk, nq),
-            in_specs=dkv_specs,
-            out_specs=[kj_spec, kj_spec],
-            out_shape=[_out_struct((B * H, Tkp, D), k.dtype, q, k, v, do),
-                       _out_struct((B * H, Tkp, D), v.dtype, q, k, v, do)],
-            scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
-                            pltpu.VMEM((bk, D), jnp.float32)],
-            interpret=interpret,
-        )(*dkv_inputs)
-
-    return (dq[:, :T].reshape(B, H, T, D),
-            dk[:, :Tk].reshape(B, H, Tk, D),
-            dv[:, :Tk].reshape(B, H, Tk, D))
+    if plan.resident:
+        res = call("bwd", True, True)
+    else:
+        res = {**call("bwd_dq", True, False), **call("bwd_dkv", False, True)}
+    return (res["dq_ref"][:, :T].reshape(B, H, T, D),
+            res["dk_ref"][:, :Tk].reshape(B, H, Tk, D),
+            res["dv_ref"][:, :Tk].reshape(B, H, Tk, D))
 
 
-def flash_attention(q, k, v, scale=None, causal=False, block_q=512,
-                    block_k=1024, backend=None, segment_ids=None):
+def flash_attention(q, k, v, scale=None, causal=False, block_q=None,
+                    block_k=None, backend=None, segment_ids=None):
     """Fused multi-head attention. q/k/v: [B, H, T, D].
 
     backend: None = auto (pallas on TPU, XLA composite elsewhere);
@@ -562,6 +740,9 @@ def flash_attention(q, k, v, scale=None, causal=False, block_q=512,
     a [B, T] int array (self-attention) or a (q_ids, kv_ids) pair; a query
     attends a key iff their ids are equal, matching
     parallel.ring_attention's semantics. Composes with `causal`.
+
+    block_q / block_k: None = the kernels take their tiling from the shape
+    (`_flash_plan`); a value names the tile's sides.
     """
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
@@ -577,7 +758,7 @@ def flash_attention(q, k, v, scale=None, causal=False, block_q=512,
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
 def _fused_attention(q, k, v, segment_ids, scale, causal, backend,
-                     block_q=512, block_k=1024):
+                     block_q=None, block_k=None):
     if backend == "xla":
         return _attention_reference(q, k, v, scale, causal, segment_ids)
     return _flash_attention_pallas(q, k, v, scale, causal, block_q, block_k,
@@ -586,7 +767,7 @@ def _fused_attention(q, k, v, segment_ids, scale, causal, backend,
 
 
 def _fused_attention_fwd(q, k, v, segment_ids, scale, causal, backend,
-                         block_q=512, block_k=1024):
+                         block_q=None, block_k=None):
     if backend == "xla":
         out = _attention_reference(q, k, v, scale, causal, segment_ids)
         return out, (q, k, v, segment_ids, None, None)

@@ -57,13 +57,21 @@ def test_train_resnet_phase():
 
 def test_kernels_phase_through_the_interpreter():
     out = chip_smoke.phase_kernels(
-        backend="pallas_interpret", flash_shapes=((1, 2, 256, 64),),
+        backend="pallas_interpret",
+        flash_shapes=(((1, 2, 256, 64), True), ((2, 2, 128, 64), False)),
         decode=(4, 64, 1408, 2), recurrent=(8, 4, 128),
         paged=(5, 40, 8, 4, 16, 6),     # 8 rows of 16: one 128-lane row
         chunk=(2, 16))
     assert set(out["max_rel_err"]) == {
-        "flash_1x2x256x64", "flash_1x2x256x64_seg", "decode_T1408",
+        "flash_1x2x256x64", "flash_1x2x256x64_seg", "flash_2x2x128x64_full",
+        "flash_2x2x128x64_full_seg", "decode_T1408",
         "paged_decode", "paged_chunk", "fused_lstm", "fused_gru"}
+    assert out["flash_plans"]["flash_1x2x256x64"] == [
+        "flash_fwd_resident_q256_k256_rows2",
+        "flash_bwd_resident_q256_k256_rows2"]
+    assert out["flash_plans"]["flash_2x2x128x64_full_seg"] == [
+        "flash_fwd_resident_q128_k128_rows2",
+        "flash_bwd_resident_q128_k128_rows2"]
     assert out["max_rel_err"]["paged_chunk"] <= 1e-5
     assert out["paged_decode_max_abs_diff"] <= 1e-5
 

@@ -349,3 +349,208 @@ class TestSegmentIds:
         ref = flash_attention(qv, qv, qv, causal=True, backend="xla",
                               segment_ids=segv)
         np.testing.assert_allclose(got, np.asarray(ref), atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# the plan: every tiling `_flash_plan` can return, held to the composite
+# ---------------------------------------------------------------------------
+
+def _case(name, B=2, H=2, T=64, Tk=None, D=16, causal=False, seg=None,
+          bq=None, bk=None, budget=None, tile_scores=None, resident=True,
+          rows=None):
+    return pytest.param(dict(B=B, H=H, T=T, Tk=Tk or T, D=D, causal=causal,
+                             seg=seg, bq=bq, bk=bk, budget=budget,
+                             tile_scores=tile_scores, resident=resident,
+                             rows=rows), id=name)
+
+
+# `budget=0` leaves no head resident: the streamed plan at a size the
+# interpreter can run. `rows`: what the plan has to choose (None: not pinned).
+_PLAN_CASES = [
+    # resident: the key loop inside the kernel
+    _case("resident-rows4-causal-blocks", causal=True, bq=16, bk=16, rows=4),
+    _case("resident-rows4-full-blocks", bq=32, bk=16, rows=4),
+    _case("resident-rows1", B=1, H=1, causal=True, bq=16, bk=32, rows=1),
+    _case("resident-rows3-of-6", B=1, H=6, causal=True, bq=16, bk=16,
+          tile_scores=4 * 16 * 16, rows=3),
+    _case("resident-single-tile-rows8", B=2, H=4, causal=True, rows=8),
+    _case("resident-single-tile-full", B=1, H=3, T=48, rows=3),
+    _case("resident-default-T300", B=1, H=2, T=300, causal=True, rows=2),
+    _case("resident-default-T200-Tk456", B=1, H=2, T=200, Tk=456, rows=2),
+    _case("resident-decode-Tq1", T=1, Tk=64, causal=True, bq=8, bk=16),
+    _case("resident-prefill-Tq16-Tk64", T=16, Tk=64, causal=True, bq=8,
+          bk=16),
+    _case("resident-no-visible-key-rows", T=64, Tk=16, causal=True, bq=16,
+          bk=16),
+    _case("resident-no-visible-key-single", T=8, Tk=4, D=4, causal=True),
+    _case("resident-uneven-T48", T=48, causal=True, bq=32, bk=32),
+    _case("resident-segments", seg="self", bq=32, bk=32, rows=2),
+    _case("resident-segments-causal", seg="self", causal=True, bq=16, bk=16,
+          rows=2),
+    _case("resident-segments-pair", T=16, Tk=32, seg="pair", bq=8, bk=16),
+    _case("resident-segments-no-matching-key", T=16, Tk=32, seg="orphan",
+          bq=8, bk=16),
+    _case("resident-segments-default-T300", B=1, T=300, seg="self",
+          causal=True, rows=2),
+    # streamed: key blocks through the grid, the backward in two passes
+    _case("streamed-causal", causal=True, bq=16, bk=16, budget=0,
+          resident=False, rows=1),
+    _case("streamed-full", bq=32, bk=16, budget=0, resident=False),
+    _case("streamed-Tq16-Tk64", T=16, Tk=64, causal=True, bq=8, bk=16,
+          budget=0, resident=False),
+    _case("streamed-no-visible-key-rows", T=64, Tk=16, causal=True, bq=16,
+          bk=16, budget=0, resident=False),
+    _case("streamed-uneven-T48", T=48, causal=True, bq=32, bk=32, budget=0,
+          resident=False),
+    _case("streamed-segments-causal", seg="self", causal=True, bq=16, bk=16,
+          budget=0, resident=False),
+    _case("streamed-segments-pair", T=16, Tk=32, seg="pair", bq=8, bk=16,
+          budget=0, resident=False),
+    _case("streamed-default-T300", B=1, H=2, T=300, causal=True, budget=0,
+          resident=False),
+]
+
+
+class TestEveryPlan:
+    """Forward, lse, dq, dk and dv of every plan the function can return
+    (resident and streamed, one head a step and several, a head count the
+    step's heads do not divide, the single-tile path) against
+    `_attention_reference`."""
+
+    @staticmethod
+    def _segments(rng, kind, B, T, Tk):
+        if kind is None:
+            return None
+        if kind == "self":
+            ids = TestSegmentIds._ragged_pack(rng, B, T)
+            return ids, ids
+        q_ids = np.repeat(np.arange(2, dtype=np.int32)[None], T // 2, axis=1)
+        kv_ids = np.repeat(np.arange(2, dtype=np.int32)[None], Tk // 2,
+                           axis=1)
+        q_ids = np.repeat(q_ids, B, axis=0)
+        kv_ids = np.repeat(kv_ids, B, axis=0)
+        if kind == "orphan":        # the last queries match no key at all
+            q_ids[:, -3:] = 7
+        return q_ids, kv_ids
+
+    @pytest.mark.parametrize("c", _PLAN_CASES)
+    def test_matches_reference(self, rng, monkeypatch, c):
+        from paddle_tpu.ops import pallas_kernels as pk
+        if c["budget"] is not None:
+            monkeypatch.setattr(pk, "_VMEM_BUDGET", c["budget"])
+        if c["tile_scores"] is not None:
+            monkeypatch.setattr(pk, "_TILE_SCORES", c["tile_scores"])
+        B, H, T, Tk, D = (c[n] for n in ("B", "H", "T", "Tk", "D"))
+        seg = self._segments(rng, c["seg"], B, T, Tk)
+        plan = pk._flash_plan(T, Tk, D, 4, H if seg else B * H, c["bq"],
+                              c["bk"])
+        assert plan.resident == c["resident"], plan
+        if c["rows"] is not None:
+            assert plan.rows == c["rows"], plan
+        assert (H if seg else B * H) % plan.rows == 0
+
+        q = jnp.asarray((rng.randn(B, H, T, D) * 0.5).astype("float32"))
+        k = jnp.asarray((rng.randn(B, H, Tk, D) * 0.5).astype("float32"))
+        v = jnp.asarray(rng.randn(B, H, Tk, D).astype("float32"))
+        g = jnp.asarray(rng.randn(B, H, T, D).astype("float32"))
+        scale = 1.0 / np.sqrt(D)
+        causal = c["causal"]
+
+        def grads(backend):
+            def fn(q_, k_, v_):
+                return jnp.vdot(pk._fused_attention(
+                    q_, k_, v_, seg, scale, causal, backend, c["bq"],
+                    c["bk"]), g)
+            return jax.grad(fn, argnums=(0, 1, 2))(q, k, v)
+
+        out, lse = pk._flash_attention_pallas(
+            q, k, v, scale, causal, c["bq"], c["bk"], interpret=True,
+            with_lse=True, segment_ids=seg)
+        ref = pk._attention_reference(q, k, v, scale, causal, seg)
+        np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
+
+        # lse against logsumexp of the live scores, where a row has any
+        s = np.einsum("bhqd,bhkd->bhqk", q, k) * scale
+        live = np.ones((B, 1, T, Tk), bool)
+        if causal:
+            live &= np.tril(np.ones((T, Tk), bool), Tk - T)[None, None]
+        if seg:
+            live &= (seg[0][:, :, None] == seg[1][:, None, :])[:, None]
+        s = np.where(live, s, -np.inf)
+        any_key = np.broadcast_to(live.any(-1), (B, H, T))
+        with np.errstate(invalid="ignore", divide="ignore"):
+            ref_lse = np.log(np.sum(np.exp(s - s.max(-1, keepdims=True)),
+                                    -1)) + s.max(-1)
+        np.testing.assert_allclose(np.asarray(lse)[any_key],
+                                   ref_lse[any_key], atol=2e-5, rtol=2e-5)
+        # a row with no visible key: zero output, and a residual the ring
+        # merge weighs as nothing
+        assert (np.asarray(out)[~any_key] == 0).all()
+        assert (np.asarray(lse)[~any_key] < -1e29).all()
+
+        for a, b in zip(grads("xla"), grads("pallas_interpret")):
+            np.testing.assert_allclose(a, b, atol=3e-5, rtol=3e-5)
+
+
+class TestFlashPlan:
+    """The plan is a pure function of the shape."""
+
+    def test_the_benchmark_cells(self):
+        from paddle_tpu.ops.pallas_kernels import FlashPlan, _flash_plan
+        # lm-big_train_1chip, and lm-big_train_dp4's shard: [8 * 16, 1024, 64]
+        lm = _flash_plan(1024, 1024, 64, 2, 8 * 16)
+        assert lm == FlashPlan(True, 256, 256, 4)
+        assert lm.scope("fwd") == "flash_fwd_resident_q256_k256_rows4"
+        assert lm.scope("bwd") == "flash_bwd_resident_q256_k256_rows4"
+        # nmt-big_train_1chip: [64 * 16, 128, 64], three attentions a layer
+        nmt = _flash_plan(128, 128, 64, 2, 64 * 16)
+        assert nmt == FlashPlan(True, 128, 128, 16)
+        assert nmt.scope("bwd") == "flash_bwd_resident_q128_k128_rows16"
+
+    @pytest.mark.parametrize("T, D", [(8192, 128), (32768, 64),
+                                      (32768, 128), (4096, 128)])
+    def test_a_head_over_the_vmem_budget_streams(self, T, D):
+        from paddle_tpu.ops.pallas_kernels import FlashPlan, _flash_plan
+        plan = _flash_plan(T, T, D, 2, 8)
+        assert plan == FlashPlan(False, 1024, 1024, 1)
+        assert plan.scope("bwd_dq") == "flash_bwd_dq_streamed_q1024_k1024"
+        assert plan.scope("bwd_dkv") == "flash_bwd_dkv_streamed_q1024_k1024"
+
+    @pytest.mark.parametrize("T, D, itemsize, heads, rows", [
+        (4096, 64, 2, 16, 1),      # fits, alone
+        (2048, 64, 2, 16, 2),      # the VMEM budget bounds the heads a step
+        (1024, 64, 4, 128, 2),     # float32 operands take twice the room
+        (1024, 128, 2, 64, 2),
+        (512, 64, 2, 64, 4),
+        (128, 64, 2, 6, 6),        # fewer heads than a step would take
+        (128, 64, 2, 1000, 10),    # rows divides the heads it is given
+        (128, 64, 2, 7, 7),
+        (1024, 64, 2, 7, 1),
+    ])
+    def test_resident_rows(self, T, D, itemsize, heads, rows):
+        from paddle_tpu.ops import pallas_kernels as pk
+        plan = pk._flash_plan(T, T, D, itemsize, heads)
+        assert plan.resident and plan.rows == rows, plan
+        assert heads % plan.rows == 0
+        assert plan.rows * plan.block_q * plan.block_k <= pk._TILE_SCORES
+
+    @pytest.mark.parametrize("T, block", [(1024, 256), (128, 128), (64, 128),
+                                          (1, 128), (200, 256), (300, 128),
+                                          (384, 128), (640, 128), (768, 256),
+                                          (1536, 256)])
+    def test_blocks_pad_to_the_lane_width_only(self, T, block):
+        from paddle_tpu.ops.pallas_kernels import _flash_plan
+        plan = _flash_plan(T, T, 64, 2, 16)
+        assert plan.block_q == plan.block_k == block
+        assert -(-T // block) * block == -(-T // 128) * 128
+
+    def test_explicit_blocks_are_honoured(self):
+        from paddle_tpu.ops.pallas_kernels import _flash_plan
+        plan = _flash_plan(1024, 2048, 64, 2, 16, block_q=512, block_k=1024)
+        assert (plan.block_q, plan.block_k) == (512, 1024)
+        assert plan.resident and plan.rows == 1
+        plan = _flash_plan(64, 64, 16, 4, 4, block_q=32, block_k=16)
+        assert (plan.block_q, plan.block_k, plan.rows) == (32, 16, 4)
+        # clamped to the padded length, as before
+        assert _flash_plan(200, 300, 64, 2, 4, 512, 1024).block_q == 256
+        assert _flash_plan(200, 300, 64, 2, 4, 512, 1024).block_k == 384

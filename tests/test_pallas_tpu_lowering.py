@@ -35,33 +35,75 @@ def _n_calls(text):
     return text.count("tpu_custom_call")
 
 
-@pytest.mark.parametrize("shape", [(8, 16, 1024, 64), (1, 8, 8192, 128)])
-@pytest.mark.parametrize("segments", [False, True])
-def test_flash_fwd_bwd_lowers(shape, segments):
-    B, _, T, _ = shape
-
+def _flash_fwd_bwd(causal=True, segments=False):
     def fwd_bwd(q, k, v, do, ids):
         out, vjp = jax.vjp(lambda q, k, v: flash_attention(
-            q, k, v, causal=True, backend="pallas",
+            q, k, v, causal=causal, backend="pallas",
             segment_ids=ids if segments else None), q, k, v)
         return (out,) + vjp(do)
-
-    text = _tpu_text(fwd_bwd, *[S(shape, BF16)] * 4, S((B, T), I32))
-    assert _n_calls(text) == 3          # forward, dq, dk/dv
+    return fwd_bwd
 
 
-@pytest.mark.parametrize("scope", ["flash_fwd", "flash_bwd_dq",
-                                   "flash_bwd_dkv"])
-def test_flash_kernels_carry_their_names(scope):
-    """Each flash kernel's pallas_call sits in a named scope of its own: XLA
-    names the custom call after it, which is how a device trace tells the
-    three apart (PERF.md, `device_ops`)."""
-    def fwd_bwd(q, k, v, do):
-        out, vjp = jax.vjp(lambda q, k, v: flash_attention(
-            q, k, v, causal=True, backend="pallas"), q, k, v)
-        return (out,) + vjp(do)
+def _flash_args(shape):
+    return [S(shape, BF16)] * 4 + [S((shape[0], shape[2]), I32)]
 
-    lowered = jax.jit(fwd_bwd).trace(*[S((2, 4, 256, 64), BF16)] * 4).lower(
+
+# [B, H, T, D], causal, kernels: the cells' shapes take the resident plan (a
+# forward and ONE backward), a head over the VMEM budget streams (dq and
+# dk / dv in two passes)
+_FLASH_SHAPES = [
+    pytest.param((8, 16, 1024, 64), True, 2, id="lm-big_train_1chip"),
+    pytest.param((32 // 4, 16, 1024, 64), True, 2, id="lm-big_train_dp4-shard"),
+    pytest.param((64, 16, 128, 64), True, 2, id="nmt-big-decoder"),
+    pytest.param((64, 16, 128, 64), False, 2, id="nmt-big-encoder-cross"),
+    pytest.param((1, 8, 8192, 128), True, 3, id="long-context-streams"),
+]
+
+
+@pytest.mark.parametrize("shape, causal, kernels", _FLASH_SHAPES)
+@pytest.mark.parametrize("segments", [False, True])
+def test_flash_fwd_bwd_lowers(shape, causal, kernels, segments):
+    text = _tpu_text(_flash_fwd_bwd(causal, segments), *_flash_args(shape))
+    assert _n_calls(text) == kernels
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """A described (not attached) v5e chip: the TPU compiler runs Mosaic's
+    own compile for it, which is what refuses a kernel for its VMEM."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("shape, causal, kernels", _FLASH_SHAPES)
+def test_flash_fwd_bwd_compiles_for_v5e(one_chip, shape, causal, kernels):
+    """Lowering checks block shapes; only Mosaic's compile checks that a
+    plan's blocks, scratch and tiles fit the 16 MiB of VMEM a kernel gets."""
+    args = [S(a.shape, a.dtype, sharding=one_chip)
+            for a in _flash_args(shape)]
+    compiled = jax.jit(_flash_fwd_bwd(causal)).lower(*args).compile()
+    assert compiled.as_text().count("tpu_custom_call") == kernels
+
+
+@pytest.mark.parametrize("shape, scope", [
+    ((2, 4, 256, 64), "flash_fwd_resident_q256_k256_rows4"),
+    ((2, 4, 256, 64), "flash_bwd_resident_q256_k256_rows4"),
+    ((1, 2, 8192, 128), "flash_fwd_streamed_q1024_k1024"),
+    ((1, 2, 8192, 128), "flash_bwd_dq_streamed_q1024_k1024"),
+    ((1, 2, 8192, 128), "flash_bwd_dkv_streamed_q1024_k1024"),
+])
+def test_flash_kernels_carry_their_names(shape, scope):
+    """Each flash kernel's pallas_call sits in a named scope that spells its
+    plan: XLA names the custom call after it, which is how a device trace
+    tells the kernels apart and says which plan a cell ran (PERF.md,
+    `device_ops`)."""
+    lowered = jax.jit(_flash_fwd_bwd()).trace(*_flash_args(shape)).lower(
         lowering_platforms=("tpu",))
     names = re.findall(r'"jit\(fwd_bwd\)/([^"]*)/pallas_call"',
                        lowered.as_text(debug_info=True))
@@ -248,7 +290,7 @@ def test_sharded_train_step_runs_flash_per_shard(as_on_tpu):
                            list(feed), [loss.name])
     text = _step_tpu_text(compiled, feed, pt.global_scope())
     calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
-    assert len(calls) == 3 * n_layers
+    assert len(calls) == 2 * n_layers       # a forward and ONE backward
     per_shard = f"tensor<{(b // 2) * (nh // 2)}x{t}x{dh}xbf16>"
     full = f"tensor<{b * nh}x{t}x{dh}xbf16>"
     for ln in calls:

@@ -316,6 +316,97 @@ def phase_serve_lm(scope, vocab=32000, max_len=1024, d_model=1024,
             "block_size": eng.block_size, "n_blocks": eng.n_blocks}
 
 
+def phase_serve_hybrid(vocab=65536, d_model=2048, d_inner=7168, num_heads=32,
+                       num_kv_heads=8, d_expert=1792, n_experts=32, top_k=4,
+                       kinds=("conv", "conv", "attention", "conv"),
+                       n_slots=16, block_size=64, n_blocks=128, max_len=1024,
+                       preamble=256, turns=(40, 150), max_new=24,
+                       expect_lowering="kernel"):
+    """A model with a kind a layer through the same PagedKVEngine: gated
+    short convolutions with a per-request state beside grouped-query rotary
+    attention over bfloat16 pools of the key/value heads, routed experts all
+    held under a bias-corrected top-k, the embedding as the head, at the
+    published widths of benchmark/configs/lfm2-8b-a1b.json and four layers.
+    Requests that start from a shared preamble (K/V blocks AND the conv
+    state's snapshot from the prefix cache) must emit the tokens an engine
+    without prefix sharing emits, which prefills the preamble itself."""
+    from paddle_tpu.models.decoder_spec import DecoderSpec, MoESpec, RopeSpec
+    from paddle_tpu.serving import PagedKVEngine
+    import paddle_tpu as pt
+
+    spec = DecoderSpec.conv_gqa_moe(
+        vocab, d_model, d_inner, num_heads, num_kv_heads, kinds,
+        RopeSpec(dim=d_model // num_heads, theta=1e6),
+        MoESpec(n_routed=n_experts, top_k=top_k, d_expert=d_expert,
+                held=tuple(range(n_experts)), n_shared=0, first_dense=1,
+                topk_method="bias", norm_eps=1e-6))
+    t0 = time.time()
+    scope = pt.Scope()
+    engines = [PagedKVEngine(n_slots=n_slots, max_len=max_len,
+                             block_size=block_size, n_blocks=n_blocks,
+                             scope=scope, model=spec, prefix_sharing=share)
+               for share in (True, False)]
+    rng = np.random.RandomState(3)
+    head = rng.randint(0, vocab, (preamble,)).tolist()
+    prompts = [head + rng.randint(0, vocab, (n,)).tolist() for n in turns]
+    tokens = []
+    for eng in engines:
+        first = eng.submit(prompts[0], max_new)
+        eng.run_until_idle()            # the preamble's blocks are cached
+        rest = [eng.submit(p, max_new) for p in prompts[1:]]
+        eng.run_until_idle()
+        _check(all(r.done and r.error is None for r in [first] + rest),
+               "a request of the hybrid engine did not finish")
+        tokens.append([r.tokens for r in [first] + rest])
+    run_s = time.time() - t0
+    shared, alone = engines
+    st = shared.stats()
+    _check(tokens[0] == tokens[1],
+           "a request that resumed from the prefix cache (K/V blocks and conv "
+           "state snapshot) emitted other tokens than its self-prefilled twin")
+    conv = st["conv_state"]
+    _check(conv["restores"] == len(turns) - 1
+           and alone.stats()["conv_state"]["restores"] == 0,
+           f"conv state restores {conv}: every prefix hit resumes from a "
+           f"block's snapshot")
+    n_attn = list(kinds).count("attention")
+    n_moe = len(kinds) - 1
+    want = (n_attn + n_moe) if expect_lowering == "kernel" else 0
+    n_calls = _n_custom_calls(shared.tick_hlo())
+    n_mixed = _n_custom_calls(shared.mixed_tick_hlo())
+    _check((st["paged_attention_lowering"], n_calls, n_mixed)
+           == (expect_lowering, want, want + (n_attn if want else 0)),
+           f"the hybrid engine reports its cache read as "
+           f"{st['paged_attention_lowering']!r}, its ticks hold {n_calls} "
+           f"and {n_mixed} tpu_custom_calls; expected {expect_lowering!r} "
+           f"with {want} (a read an attention layer, a product a routed "
+           f"layer) and {n_attn} more for the lanes")
+    # the comparison above has to refuse a restore that is broken: with the
+    # snapshots zeroed, a request that starts two tokens after the shared
+    # preamble reads a zero state where its twin reads the preamble's
+    import jax.numpy as jnp
+    name = shared._cache_prefix + "_conv_block"
+    scope.set_var(name, jnp.zeros_like(scope.get(name)))
+    probe = head + rng.randint(0, vocab, (2,)).tolist()
+    pair = [eng.submit(probe, max_new) for eng in engines]
+    for eng in engines:
+        eng.run_until_idle()
+    _check(pair[0].shared_len == preamble and pair[1].shared_len == 0
+           and pair[0].tokens != pair[1].tokens,
+           "a request that resumed from a ZEROED conv state snapshot emitted "
+           "its self-prefilled twin's tokens: the twins' comparison does not "
+           "see the state")
+    return {"compile_s": 0.0, "run_s": round(run_s, 2),
+            "tokens_out": sum(len(t) for t in tokens[0]),
+            "zeroed_state_differs_at": next(
+                i for i, (a, b) in enumerate(zip(*(r.tokens for r in pair)))
+                if a != b),
+            "conv_state": conv, "block_bytes": st["block_bytes"],
+            "experts_touched": int(np.count_nonzero(shared.expert_rows)),
+            "paged_attention_lowering": st["paged_attention_lowering"],
+            "tpu_custom_calls": n_calls, "mixed_tpu_custom_calls": n_mixed}
+
+
 def phase_train_resnet50(batch=256, steps=2, depth=50, image=224):
     """bench.py's training graph: ResNet-50 NHWC bf16, uint8 staging
     declared (and fed), Momentum."""
@@ -769,6 +860,8 @@ def _run():
     train = phase("train_lm", phase_train_lm)
     phase("serve_lm", phase_serve_lm, pt.global_scope())
     phase("train_resnet50", phase_train_resnet50)
+    _free_device_memory()
+    phase("serve_hybrid", phase_serve_hybrid)
     _free_device_memory()
     phase("kernels", phase_kernels)
     if len(jax.devices()) >= 4:

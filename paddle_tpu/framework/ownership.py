@@ -81,6 +81,11 @@ DIAGNOSTICS: Dict[str, str] = {
         "spilled content was committed/consumed before its transfer "
         "ticket arrived — offload-use-before-arrival at the block "
         "granularity (a resume would scatter stale or torn rows)",
+    "kv-state-snapshot-missing":
+        "a block the prefix index offers (or a request was just admitted "
+        "onto) holds no state snapshot — a model with per-request state "
+        "beside its per-token rows (short convolutions) would resume the "
+        "shared span from a state nobody computed",
     "serving-cache-write-alias":
         "a tick-program cache write breaks the donated in-place "
         "contract: the pool var is written more than once per tick, or "
@@ -104,6 +109,7 @@ MUTATIONS: Dict[str, str] = {
     "write-shared-block": "kv-write-shared-block",
     "prefetch-after-use": "kv-prefetch-after-use",
     "rollback-double-free": "kv-double-free",
+    "skipped-snapshot": "kv-state-snapshot-missing",
 }
 
 
@@ -168,7 +174,11 @@ class TableState:
 class AbstractState:
     """The declarative pager state: per-block refcounts + free list
     (device tier), the single-family radix chain, per-table records and
-    the host-tier ledger. Primitive transitions (`alloc_at`, `share`,
+    the host-tier ledger, and a second resource kind that lives with a
+    block: `snap[b]`, whether block b holds the STATE SNAPSHOT a model with
+    per-request state (conv layers) resumes a shared span from — written by
+    the tick that fills a prompt block, void once the block is handed out
+    again. Primitive transitions (`alloc_at`, `share`,
     `release`, `note_write`) carry the per-op preconditions; composed
     protocol transitions (`admit` .. `reload`) mirror `KVPager` method
     for method; `check_invariants` proves the whole-state identities.
@@ -184,6 +194,8 @@ class AbstractState:
         self.block_size = int(block_size)
         self.host_blocks = int(host_blocks)
         self.ref = [0] * self.n_blocks        # ref[0] stays 0 (null)
+        self.snap = [False] * self.n_blocks   # block holds a state snapshot
+        self.track_state = True               # False: the model has none
         self.free = set(range(1, self.n_blocks))
         self.index_chain: List[int] = []      # checker's radix reduction
         self.tables: Dict[int, TableState] = {}
@@ -202,6 +214,7 @@ class AbstractState:
                 block=b)
         self.free.discard(b)
         self.ref[b] = 1
+        self.snap[b] = False                 # whatever it held is void
 
     def share(self, block: int, op: str = "share"):
         b = int(block)
@@ -299,6 +312,11 @@ class AbstractState:
                     self.release(held, op)
                 return False
             blocks.append(b)
+        if self.track_state and chain and not self.snap[chain[-1]]:
+            raise OwnershipViolation(
+                "kv-state-snapshot-missing", op,
+                f"the shared span ends in block {chain[-1]}, which holds "
+                f"no state snapshot to resume from", block=chain[-1])
         rec = TableState(blocks, len(chain), len(chain) * bs, prompt_len)
         if mutation == "write-shared-block" and rec.n_shared:
             # seeded off-by-one: the write frontier replays the LAST
@@ -323,6 +341,8 @@ class AbstractState:
         j = pos // bs                        # block just filled
         if j < rec.n_shared or (j + 1) * bs > rec.prompt_len:
             return                           # not a sharable prompt block
+        if mutation != "skipped-snapshot":
+            self.snap[rec.blocks[j]] = True  # the filling tick wrote it
         if j == len(self.index_chain):       # ancestor chain intact,
             self.index_chain.append(rec.blocks[j])   # node is new
             self.share(rec.blocks[j], op + "/register")
@@ -514,6 +534,13 @@ class AbstractState:
                     f"block {b} has {h} holder(s) but refcount "
                     f"{self.ref[b]} — a table maps a block it no "
                     f"longer holds", block=b)
+        if self.track_state:
+            for b in pins:
+                if not self.snap[b]:
+                    raise OwnershipViolation(
+                        "kv-state-snapshot-missing", op,
+                        f"block {b} is offered by the prefix index "
+                        f"without a state snapshot", block=b)
         if not (0 <= self.host_used <= self.host_blocks):
             raise OwnershipViolation(
                 "kv-host-accounting", op,
@@ -544,6 +571,8 @@ class AbstractState:
         st.block_size = self.block_size
         st.host_blocks = self.host_blocks
         st.ref = list(self.ref)
+        st.snap = list(self.snap)
+        st.track_state = self.track_state
         st.free = set(self.free)
         st.index_chain = list(self.index_chain)
         st.tables = {tid: rec.clone() for tid, rec in self.tables.items()}
@@ -551,7 +580,9 @@ class AbstractState:
         return st
 
     def snapshot(self) -> tuple:
-        return (tuple(self.ref), tuple(self.index_chain), self.host_used,
+        return (tuple(self.ref),
+                tuple(s and r > 0 for s, r in zip(self.snap, self.ref)),
+                tuple(self.index_chain), self.host_used,
                 tuple(sorted((tid, rec.key())
                              for tid, rec in self.tables.items())))
 
@@ -660,7 +691,7 @@ class ModelChecker:
             st.admit(op[1], self.prompt_len, self.need_len,
                      mutation=m if m == "write-shared-block" else None)
         elif kind == "write":
-            st.write(op[1])
+            st.write(op[1], mutation=m if m == "skipped-snapshot" else None)
         elif kind == "release":
             st.release_table(
                 op[1], mutation=m if m == "leaked-release" else None)

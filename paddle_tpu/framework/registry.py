@@ -193,7 +193,8 @@ def _ensure_builtin_ops():
                        control_ops, loss_ops, sequence_label_ops,
                        beam_search_ops, detection_ops, pallas_kernels)
     from ..fusion import (decode_attention, paged_attention,  # noqa: F401
-                          recurrent, latent_attention, moe)
+                          recurrent, latent_attention, moe,
+                          short_conv)
 
 
 @dataclass

@@ -2,7 +2,10 @@
 
 `moe_route`: sigmoid scores over ALL `n_routed` experts (float32), the
 `top_k` largest, weights `score / sum(selected scores) * scaling`; the sum
-runs over every selected expert, held here or not. What leaves the op is the
+runs over every selected expert, held here or not. With a `bias` (one value
+an expert) the selection is the top-k of score + bias and the weights stay
+the UNBIASED scores of the selected; `norm_eps` joins the sum the weights
+are divided by. What leaves the op is the
 weights' HELD part, dense: `[n_held, N, 1]`, zero where a row did not select
 the expert (or the row is dead: an idle slot, the tail of a short chunk),
 and `rows[e]`, how many rows expert e got.
@@ -38,13 +41,21 @@ KERNEL, COMPOSITE = "kernel", "composite"
 _TILE = 256            # columns of the expert width a step takes
 
 
-def route(x, w_router, held, top_k, scaling, norm_topk_prob=True, live=None):
-    """x [N, D], w_router [D, E] -> (weights [n_held, N, 1] float32,
-    rows [n_held] int32)."""
+def route(x, w_router, held, top_k, scaling, norm_topk_prob=True, live=None,
+          bias=None, norm_eps=0.0):
+    """x [N, D], w_router [D, E], bias [E] or None -> (weights
+    [n_held, N, 1] float32, rows [n_held] int32)."""
     logits = jnp.dot(x, w_router, preferred_element_type=jnp.float32)
     scores = jax.nn.sigmoid(logits.astype(jnp.float32))
-    top, idx = jax.lax.top_k(scores, top_k)                   # [N, k]
-    w = top / jnp.sum(top, axis=-1, keepdims=True) if norm_topk_prob else top
+    if bias is None:
+        top, idx = jax.lax.top_k(scores, top_k)               # [N, k]
+    else:
+        _, idx = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+        top = jnp.take_along_axis(scores, idx, axis=-1)
+    total = jnp.sum(top, axis=-1, keepdims=True)
+    if norm_eps:
+        total = total + norm_eps
+    w = top / total if norm_topk_prob else top
     w = w * scaling
     held = jnp.asarray(held, jnp.int32)
     hit = idx[None, :, :] == held[:, None, None]              # [h, N, k]
@@ -63,7 +74,9 @@ def _moe_route_op(ctx, ins, attrs):
     w, rows = route(x.reshape(-1, x.shape[-1]), ins["W"][0], attrs["held"],
                     attrs["top_k"], attrs["scaling"],
                     attrs.get("norm_topk_prob", True),
-                    ins["Live"][0] if ins.get("Live") else None)
+                    ins["Live"][0] if ins.get("Live") else None,
+                    ins["Bias"][0] if ins.get("Bias") else None,
+                    attrs.get("norm_eps", 0.0))
     return {"Weights": [w], "Rows": [rows]}
 
 

@@ -28,9 +28,17 @@ algorithm:
   softmax); row g attends positions 0..pos+g, so the shared prefix's
   blocks, earlier chunks and the chunk itself are one causal read. A lane
   with no rows fetches nothing and returns zeros.
+- grouped queries (fewer key/value heads than query heads: the pool's head
+  axis is the key/value heads', `grp` = query heads / key/value heads), and
+  bfloat16 pools: the chunk kernel again, the `grp` query heads of a
+  key/value head as ROWS of its products. A lane's C positions are C * grp
+  rows a key/value head (row r at position pos + r // grp); a decode row is
+  grp rows at one position, padded to a sublane tile. One grid step a slot
+  or lane; a block's K and V are fetched once for the whole group.
 - the composite (everything else: a CPU, int8 pools with their scale pools,
-  a verify window of a few positions): gather the table view and run
-  `decode_attention._decode_xla`, the math the slot tick runs.
+  a verify window of a few positions): gather the table view (a key/value
+  head repeated over its group) and run `decode_attention._decode_xla`, the
+  math the slot tick runs.
 
 Which one a shape takes is `paged_attention_lowering`; on a TPU the composite
 is never a fallback for a shape the kernel serves (it raises).
@@ -80,14 +88,16 @@ def paged_attention_lowering(pool_dtype, pool_lanes, n_query, d_head,
     asked = backend is not None
     backend = backend or _auto_backend()
     platform = platform or jax.default_backend()
-    served = (jnp.dtype(pool_dtype) == jnp.float32 and not quantized
+    served = (jnp.dtype(pool_dtype) in (jnp.float32, jnp.bfloat16)
+              and not quantized
               and (n_query == 1 or n_query % _CHUNK_ROWS == 0)
               and pool_lanes == _LANES and _LANES % d_head == 0)
     if served and backend != "xla":
         return KERNEL
     if served and platform == "tpu" and not asked:
         raise RuntimeError(
-            f"paged_decode_attention: float32 pools with {n_query} query "
+            f"paged_decode_attention: {jnp.dtype(pool_dtype).name} pools "
+            f"with {n_query} query "
             f"position(s) (d_head {d_head}) take a Pallas kernel on a TPU, "
             "but the backend selected is 'xla' (PTPU_DISABLE_PALLAS?); the "
             "composite rebuilds the whole pool per layer per tick and is "
@@ -114,6 +124,9 @@ def _paged_composite(q4, k_pool, v_pool, btab, pos, scale, k_scale, v_scale):
     dh = q4.shape[-1]
     k4 = _table_view(k_pool, btab, dh, k_scale)
     v4 = _table_view(v_pool, btab, dh, v_scale)
+    grp = q4.shape[1] // k4.shape[1]
+    if grp > 1:         # query head i reads key/value head i // grp
+        k4, v4 = (jnp.repeat(t, grp, axis=1) for t in (k4, v4))
     g, t = q4.shape[2], k4.shape[2]
     posg = pos[:, None].astype(jnp.float32) + jnp.arange(g, dtype=jnp.float32)
     valid = jnp.arange(t, dtype=jnp.float32) < posg[:, :, None] + 1.0
@@ -251,7 +264,7 @@ def _paged_pallas(q4, k_pool, v_pool, btab, pos, scale, interpret):
 
 def _chunk_kernel(btab_ref, pos_ref, rows_ref, q_ref, k_hbm, v_hbm, o_ref,
                   kbuf, vbuf, sem, m_ref, l_ref, acc_ref, *, n_logical,
-                  block_size, d_head, group, mxu_dtype):
+                  block_size, d_head, group, mxu_dtype, rows_per_pos=1):
     """One grid step = one lane: C query rows at positions pos..pos+C-1, of
     which the first `rows` are real. The lane's live blocks (those holding
     a position up to pos + rows - 1) come `group` at a time: each DMA'd
@@ -265,7 +278,10 @@ def _chunk_kernel(btab_ref, pos_ref, rows_ref, q_ref, k_hbm, v_hbm, o_ref,
     share of the keys of segment g; the caller adds the segments. Every
     block of a live group is fetched (a dead one is the null block or an
     unwritten block of the request: finite, and masked by position): p = 0
-    times a stale VMEM row could be NaN."""
+    times a stale VMEM row could be NaN. With `rows_per_pos` > 1 (grouped
+    queries: the pool's heads are the key/value heads) `rows_per_pos`
+    consecutive query rows share a position: row r sits at pos + r //
+    rows_per_pos, and `rows` still counts positions."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -304,7 +320,8 @@ def _chunk_kernel(btab_ref, pos_ref, rows_ref, q_ref, k_hbm, v_hbm, o_ref,
         fetch(0, 0, wait=False)
 
     seg = jax.lax.broadcasted_iota(jnp.int32, (1, _LANES), 1) // d_head
-    q_pos = pos + jax.lax.broadcasted_iota(jnp.int32, (c, key_rows), 0)
+    q_row = jax.lax.broadcasted_iota(jnp.int32, (c, key_rows), 0)
+    q_pos = pos + (q_row if rows_per_pos == 1 else q_row // rows_per_pos)
     key_row = jax.lax.broadcasted_iota(jnp.int32, (c, key_rows), 1)
 
     def step_body(step, carry):
@@ -357,12 +374,16 @@ def _chunk_kernel(btab_ref, pos_ref, rows_ref, q_ref, k_hbm, v_hbm, o_ref,
     o_ref[0] = (acc_ref[...] / jnp.where(l > 0.0, l, 1.0)).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
-def _chunk_pallas(q4, k_pool, v_pool, btab, pos, rows, scale, interpret):
+@functools.partial(jax.jit,
+                   static_argnames=("scale", "interpret", "rows_per_pos"))
+def _chunk_pallas(q4, k_pool, v_pool, btab, pos, rows, scale, interpret,
+                  rows_per_pos=1):
     """q4 [L, nh, C, dh] float32, pools [NB, nh, R, 128] → [L, nh, C, dh].
     On the chip the MXU takes bf16 operands (what XLA's default precision
     gives the composite's float32 matmuls there); interpreted, the
-    operands stay float32 and the composite is matched to rounding."""
+    operands stay float32 and the composite is matched to rounding. With
+    `rows_per_pos` (grouped queries) the C rows are `rows_per_pos` a
+    position and nh is the key/value heads'."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -372,12 +393,14 @@ def _chunk_pallas(q4, k_pool, v_pool, btab, pos, rows, scale, interpret):
     n_logical = btab.shape[1]
     group = max(1, min(_LANES // n_rows, n_logical))
     qspec = pl.BlockSpec((1, nh, c, _LANES), lambda i, *_: (i, 0, 0, 0))
-    with jax.named_scope("paged_chunk_attention"):
+    with jax.named_scope("paged_chunk_attention" if rows_per_pos == 1
+                         else "paged_gqa_attention"):
         out = pl.pallas_call(
             functools.partial(
                 _chunk_kernel, n_logical=n_logical,
                 block_size=n_rows * per_row, d_head=dh, group=group,
-                mxu_dtype=jnp.float32 if interpret else jnp.bfloat16),
+                mxu_dtype=jnp.float32 if interpret else jnp.bfloat16,
+                rows_per_pos=rows_per_pos),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=3,
                 grid=(n_lanes,),
@@ -407,9 +430,11 @@ def paged_decode_attention(q, k_pool, v_pool, btab, pos, num_heads,
                            v_scale=None, rows=None):
     """Attention of each slot's G query positions over its paged cache.
 
-    q [S, G, nh*dh]; k_pool / v_pool [NB, nh, R, L], either
-    `pool_block_shape` (float32, or int8 with `k_scale` / `v_scale`
-    [NB, nh, BS, 1]); btab [S, NLB] physical block of each logical block;
+    q [S, G, nh*dh]; k_pool / v_pool [NB, nkv, R, L], either
+    `pool_block_shape` (float32 or bfloat16, or int8 with `k_scale` /
+    `v_scale` [NB, nh, BS, 1]; nkv the key/value heads, nh or a divisor of
+    it: query head i reads key/value head i // (nh / nkv)); btab [S, NLB]
+    physical block of each logical block;
     pos [S] (any shape of S elements) the position of each slot's FIRST
     query row, which attends cache positions 0..pos (row g attends
     0..pos+g: the rows written earlier in the same forward included).
@@ -426,8 +451,13 @@ def paged_decode_attention(q, k_pool, v_pool, btab, pos, num_heads,
     lowering = paged_attention_lowering(
         k_pool.dtype, k_pool.shape[-1], g, dh, k_scale is not None,
         backend=backend)
-    q4 = q.reshape(s, g, num_heads, dh).transpose(0, 2, 1, 3)
     interpret = backend == "pallas_interpret"
+    nkv = k_pool.shape[1]
+    if lowering == KERNEL and (nkv != num_heads
+                               or k_pool.dtype != jnp.float32):
+        return _grouped_kernel(q, k_pool, v_pool, btab, pos, rows, num_heads,
+                               float(scale), interpret)
+    q4 = q.reshape(s, g, num_heads, dh).transpose(0, 2, 1, 3)
     if lowering == KERNEL and g == 1:
         out = _paged_pallas(q4, k_pool, v_pool, btab, pos, float(scale),
                             interpret=interpret)
@@ -440,6 +470,27 @@ def paged_decode_attention(q, k_pool, v_pool, btab, pos, num_heads,
         out = _paged_composite(q4, k_pool, v_pool, btab, pos, float(scale),
                                k_scale, v_scale)
     return out.transpose(0, 2, 1, 3).reshape(s, g, h)
+
+
+def _grouped_kernel(q, k_pool, v_pool, btab, pos, rows, num_heads, scale,
+                    interpret):
+    """The chunk kernel under grouped queries (and bfloat16 pools): the
+    `grp` query heads of a key/value head, position-major, as the rows of
+    one product. A decode row's grp rows are padded to a sublane tile."""
+    s, g, h = q.shape
+    nkv = k_pool.shape[1]
+    grp, dh = num_heads // nkv, h // num_heads
+    per_pos = grp if g > 1 else -(-grp // _CHUNK_ROWS) * _CHUNK_ROWS
+    q5 = q.reshape(s, g, nkv, grp, dh).astype(jnp.float32)
+    if per_pos != grp:
+        q5 = jnp.pad(q5, ((0, 0),) * 3 + ((0, per_pos - grp), (0, 0)))
+    q4 = q5.transpose(0, 2, 1, 3, 4).reshape(s, nkv, g * per_pos, dh)
+    rows = (jnp.full((s,), g, jnp.int32) if rows is None
+            else rows.reshape(-1).astype(jnp.int32))
+    out = _chunk_pallas(q4, k_pool, v_pool, btab, pos, rows, scale,
+                        interpret=interpret, rows_per_pos=per_pos)
+    out = out.reshape(s, nkv, g, per_pos, dh)[:, :, :, :grp]
+    return out.transpose(0, 2, 1, 3, 4).reshape(s, g, h).astype(q.dtype)
 
 
 @register_op("paged_decode_attention", stop_gradient=True)

@@ -864,7 +864,7 @@ def auc(input, label, curve="ROC", num_thresholds=200, topk=1):
 
 # ---------------------------------------------------------------- misc
 def matmul(x, y, transpose_x=False, transpose_y=False, alpha=1.0, name=None,
-           use_bf16=False):
+           use_bf16=False, out_dtype=None):
     helper = LayerHelper("matmul", name=name)
     xs, ys = list(x.shape), list(y.shape)
     if transpose_x:
@@ -873,13 +873,14 @@ def matmul(x, y, transpose_x=False, transpose_y=False, alpha=1.0, name=None,
         ys[-1], ys[-2] = ys[-2], ys[-1]
     batch = xs[:-2] if len(xs) >= len(ys) else ys[:-2]
     out_shape = batch + [xs[-2] if len(xs) > 1 else 1, ys[-1]]
-    out = helper.create_tmp_variable(dtype=dtype_name(x.dtype),
+    out = helper.create_tmp_variable(dtype=out_dtype or dtype_name(x.dtype),
                                      shape=out_shape)
+    attrs = {"transpose_X": transpose_x, "transpose_Y": transpose_y,
+             "alpha": alpha, "use_bf16": use_bf16}
+    if out_dtype:           # as `fc`: float32 logits over bfloat16 rows
+        attrs["out_dtype"] = out_dtype
     helper.append_op(type="matmul", inputs={"X": [x], "Y": [y]},
-                     outputs={"Out": [out]},
-                     attrs={"transpose_X": transpose_x,
-                            "transpose_Y": transpose_y, "alpha": alpha,
-                            "use_bf16": use_bf16})
+                     outputs={"Out": [out]}, attrs=attrs)
     return out
 
 
@@ -1104,11 +1105,13 @@ def latent_paged_attention(q, pool, block_table, pos, num_heads, v_width,
 
 
 def moe_route(x, w_router, held, top_k, scaling, norm_topk_prob=True,
-              live=None, name=None):
+              live=None, name=None, bias=None, norm_eps=0.0):
     """Sigmoid top-k routing over every column of `w_router`; returns the
     dense weights of the `held` experts [n_held, N, 1] (float32) and the
     rows each got [n_held] (int32) (fusion/moe.py). `live` [N]: rows that
-    are 0 there select nothing."""
+    are 0 there select nothing. `bias` [E]: the selection is the top-k of
+    score + bias, the weights the unbiased scores; `norm_eps` joins the sum
+    they are divided by."""
     helper = LayerHelper("moe_route", name=name)
     n = _prod(x.shape[:-1])
     weights = helper.create_tmp_variable(dtype="float32",
@@ -1119,11 +1122,16 @@ def moe_route(x, w_router, held, top_k, scaling, norm_topk_prob=True,
     inputs = {"X": [x], "W": [w_router]}
     if live is not None:
         inputs["Live"] = [live]
+    attrs = {"held": [int(e) for e in held], "top_k": int(top_k),
+             "scaling": float(scaling),
+             "norm_topk_prob": bool(norm_topk_prob)}
+    if bias is not None:
+        inputs["Bias"] = [bias]
+    if norm_eps:
+        attrs["norm_eps"] = float(norm_eps)
     helper.append_op(type="moe_route", inputs=inputs,
                      outputs={"Weights": [weights], "Rows": [rows]},
-                     attrs={"held": [int(e) for e in held],
-                            "top_k": int(top_k), "scaling": float(scaling),
-                            "norm_topk_prob": bool(norm_topk_prob)})
+                     attrs=attrs)
     return weights, rows
 
 
@@ -1139,6 +1147,62 @@ def moe_experts(x, weights, rows, gate, up, down, name=None):
                              "Gate": [gate], "Up": [up], "Down": [down]},
                      outputs={"Out": [out]})
     return out
+
+
+def short_conv(u, taps, slot_state, layer, n_slots, lanes=None, name=None):
+    """The causal part of a gated short convolution over a tick's rows `u`
+    [S + L*C, 1, D] (S decode rows, then the lanes'), `taps` [D, K], from
+    the state in `slot_state` and, with `lanes` (dict: block_state, lbtab,
+    lpos, lrows, chunk, block_size), the block snapshots a chunk starts
+    from (fusion/short_conv.py). Returns (c, the decode rows' new state,
+    and with lanes the chunks' block snapshots and last states)."""
+    helper = LayerHelper("short_conv", name=name)
+    dtype, d = dtype_name(u.dtype), u.shape[-1]
+    r = taps.shape[1] - 1
+    tmp = lambda shape: helper.create_tmp_variable(  # noqa: E731
+        dtype=dtype, shape=shape, stop_gradient=True)
+    out, new_d = tmp(u.shape), tmp([n_slots, r, d])
+    inputs = {"U": [u], "Taps": [taps], "SlotState": [slot_state]}
+    outputs = {"Out": [out], "DecodeState": [new_d]}
+    attrs = {"layer": int(layer), "n_slots": int(n_slots)}
+    snaps = last = None
+    if lanes is not None:
+        n_lanes = lanes["lbtab"].shape[0]
+        per_lane = lanes["chunk"] // lanes["block_size"]
+        snaps, last = tmp([n_lanes * per_lane, r, d]), tmp([n_lanes, r, d])
+        inputs.update(BlockState=[lanes["block_state"]],
+                      LaneBlockTable=[lanes["lbtab"]],
+                      LanePos=[lanes["lpos"]], LaneRows=[lanes["lrows"]])
+        outputs.update(LaneSnaps=[snaps], LaneState=[last])
+        attrs.update(chunk=int(lanes["chunk"]),
+                     block_size=int(lanes["block_size"]))
+    helper.append_op(type="short_conv", inputs=inputs, outputs=outputs,
+                     attrs=attrs)
+    return out, new_d, snaps, last
+
+
+def conv_state_commit(slot_state, new_states, live, lanes=None, name=None):
+    """Write a tick's conv states (`new_states`: a list a conv layer of
+    `short_conv`'s decode states) into `slot_state`, and with `lanes`
+    (dict: block_state, snaps, last (a list a layer each), lwblocks, lrows,
+    lslot, block_size) the lanes' into `block_state` and their slots, in
+    place (fusion/short_conv.py)."""
+    helper = LayerHelper("conv_state_commit", name=name)
+    inputs = {"SlotState": [slot_state], "DecodeState": list(new_states),
+              "Live": [live]}
+    outputs = {"SlotStateOut": [slot_state]}
+    attrs = {}
+    if lanes is not None:
+        inputs.update(BlockState=[lanes["block_state"]],
+                      LaneSnaps=list(lanes["snaps"]),
+                      LaneState=list(lanes["last"]),
+                      LaneWriteBlocks=[lanes["lwblocks"]],
+                      LaneRows=[lanes["lrows"]], LaneSlot=[lanes["lslot"]])
+        outputs["BlockStateOut"] = [lanes["block_state"]]
+        attrs["block_size"] = int(lanes["block_size"])
+    helper.append_op(type="conv_state_commit", inputs=inputs,
+                     outputs=outputs, attrs=attrs)
+    return slot_state
 
 
 def lrn(input, n=5, k=2.0, alpha=1e-4, beta=0.75, name=None):

@@ -6,20 +6,28 @@ kind. The six dims every builder used to take (`vocab`, `d_model`,
 `d_inner`, `num_heads`, `num_layers`, `dropout`) are `DecoderSpec.classic`:
 post-LayerNorm, sinusoidal positions at the embedding, full heads, a ReLU
 pair, float32 parameters. `DecoderSpec.latent_moe` is the other point that
-is built. A spec comes from one of the two constructors; the fields are
-what `_decoder_block` reads, not a product to pick from: any other
-combination raises where a graph would have to build it.
-`serving.PagedKVEngine(model=spec)` takes either; everything else in the
-package takes the classic one.
+is built, `DecoderSpec.conv_gqa_moe` the third (a kind PER LAYER: gated
+short convolutions with a per-request state beside grouped-query rotary
+attention). A spec comes from one of the constructors; the fields are what
+`_decoder_block` reads, not a product to pick from: any other combination
+raises where a graph would have to build it.
+`serving.PagedKVEngine(model=spec)` takes any of them; everything else in
+the package takes the classic one.
 
 Kinds (each a string, checked by name; nothing is guessed):
 
   norm        "layer_norm" | "rms_norm"
   residual    "post" (x = norm(x + f(x))) | "pre" (x = x + f(norm(x)))
   positions   "sinusoid" (added at the embedding) | "rotary" (inside attention)
-  attention   "full" (q/k/v heads over K and V pools) | "latent" (`LatentSpec`)
+  attention   "full" (q/k/v heads over K and V pools; `num_kv_heads` fewer
+              key/value heads than query heads, `qk_norm` an RMSNorm a head
+              on q and k) | "latent" (`LatentSpec`)
+  layer_kinds a kind a layer, "attention" | "conv" (`ConvSpec`: a gated
+              short convolution whose state is the last rows of its input);
+              None: attention everywhere
   ffn         "relu" | "gated_silu"; layers from `moe.first_dense` on are
               routed experts + shared expert (`MoESpec`)
+  tied_head   the vocabulary head is the embedding, transposed
 """
 
 from __future__ import annotations
@@ -98,7 +106,20 @@ class LatentSpec:
         return self.qk_head_dim ** -0.5 * self.rope.softmax_mscale ** 2
 
 
-TOPK_METHODS = ("none",)
+@dataclasses.dataclass(frozen=True)
+class ConvSpec:
+    """The gated short convolution: `[B, C, z] = x W_in`; `u = B * z`; a
+    depthwise causal convolution of `taps` taps over u, no bias; the output
+    `(C * conv) W_out`. What a request carries from token to token is the
+    last `taps - 1` rows of u (zero before position 0)."""
+    taps: int = 3
+
+    @property
+    def state_rows(self) -> int:
+        return self.taps - 1
+
+
+TOPK_METHODS = ("none", "bias")
 SCORING = ("sigmoid",)
 
 
@@ -108,7 +129,10 @@ class MoESpec:
     experts and picks `top_k`; `held` names the experts whose weights this
     program has (one chip's share of an expert-parallel deployment), and
     only their part of the sum is computed: what the others would add is
-    left out, and no code stands in for the chips that hold them."""
+    left out, and no code stands in for the chips that hold them.
+    `topk_method` "bias": the selection is the top-k of score + a learned
+    per-expert bias, the weights are the UNBIASED scores of the selected;
+    `norm_eps` is added to the sum the weights are divided by."""
     n_routed: int
     top_k: int
     d_expert: int
@@ -119,13 +143,14 @@ class MoESpec:
     norm_topk_prob: bool = True
     scoring: str = "sigmoid"
     topk_method: str = "none"
+    norm_eps: float = 0.0
 
     def __post_init__(self):
         if self.topk_method not in TOPK_METHODS:
             raise NotImplementedError(
                 f"topk_method {self.topk_method!r}: the router implements "
-                f"{TOPK_METHODS} (plain top-k over every expert, no group "
-                "limit, no correction bias)")
+                f"{TOPK_METHODS} (top-k over every expert, plain or of "
+                "score + bias; no group limit)")
         if self.scoring not in SCORING:
             raise NotImplementedError(
                 f"scoring_func {self.scoring!r}: the router implements "
@@ -154,6 +179,12 @@ class DecoderSpec:
     dtype: str = "float32"          # parameters, activations and the cache
     latent: Optional[LatentSpec] = None
     moe: Optional[MoESpec] = None
+    num_kv_heads: Optional[int] = None      # None: as many as query heads
+    qk_norm: bool = False
+    rope: Optional[RopeSpec] = None         # full heads' rotary positions
+    layer_kinds: Optional[Tuple[str, ...]] = None
+    conv: Optional[ConvSpec] = None
+    tied_head: bool = False
 
     def __post_init__(self):
         for field, kinds in (("norm", ("layer_norm", "rms_norm")),
@@ -171,9 +202,22 @@ class DecoderSpec:
         if self.attention == "latent" and self.positions != "rotary":
             raise ValueError("latent attention rotates part of its row: "
                              "positions='rotary'")
-        if self.attention == "full" and self.positions == "rotary":
-            raise NotImplementedError(
-                "rotary positions with full heads: no graph builds it yet")
+        if self.attention == "full" and \
+                (self.positions == "rotary") != (self.rope is not None):
+            raise ValueError("rotary positions with full heads come with a "
+                             "RopeSpec (`rope`), and only they")
+        if self.num_heads % self.kv_heads:
+            raise ValueError(f"{self.num_heads} query heads over "
+                             f"{self.kv_heads} key/value heads")
+        kinds = self.layer_kinds
+        if kinds is not None and (
+                len(kinds) != self.num_layers
+                or set(kinds) - {"attention", "conv"}):
+            raise ValueError(f"layer_kinds {kinds!r}: one of 'attention' | "
+                             f"'conv' for each of {self.num_layers} layers")
+        if (self.conv is not None) != bool(self.conv_layers):
+            raise ValueError("a 'conv' layer comes with a ConvSpec, and "
+                             "only it")
 
     @classmethod
     def classic(cls, vocab=32000, d_model=512, d_inner=2048, num_heads=8,
@@ -196,6 +240,24 @@ class DecoderSpec:
                    positions="rotary", attention="latent", ffn="gated_silu",
                    dtype=dtype, latent=latent, moe=moe)
 
+    @classmethod
+    def conv_gqa_moe(cls, vocab, d_model, d_inner, num_heads, num_kv_heads,
+                     layer_kinds, rope: RopeSpec,
+                     moe: Optional[MoESpec] = None, conv_taps=3,
+                     norm_eps=1e-5, dtype="bfloat16"):
+        """The LFM2 family's block: pre-norm RMSNorm residuals; a layer's
+        operator by `layer_kinds`, a gated short convolution or
+        grouped-query attention with an RMSNorm a head on q and k and
+        rotary positions over the whole head; a gated SiLU pair, routed
+        experts (no shared one) from `moe.first_dense` on; a final norm and
+        the embedding as the head."""
+        return cls(vocab, d_model, d_inner, num_heads, len(layer_kinds),
+                   norm="rms_norm", norm_eps=norm_eps, residual="pre",
+                   positions="rotary", ffn="gated_silu", dtype=dtype, moe=moe,
+                   num_kv_heads=num_kv_heads, qk_norm=True, rope=rope,
+                   layer_kinds=tuple(layer_kinds), conv=ConvSpec(conv_taps),
+                   tied_head=True)
+
     @property
     def is_classic(self) -> bool:
         return self == DecoderSpec.classic(**self.dims())
@@ -217,13 +279,45 @@ class DecoderSpec:
         return tuple(i for i in range(self.num_layers)
                      if self.ffn_kind(i) == "moe")
 
+    @property
+    def kv_heads(self) -> int:
+        return self.num_kv_heads or self.num_heads
+
+    @property
+    def d_head(self) -> int:
+        return self.d_model // self.num_heads
+
+    def layer_kind(self, layer: int) -> str:
+        return self.layer_kinds[layer] if self.layer_kinds else "attention"
+
+    @property
+    def attention_layers(self) -> Tuple[int, ...]:
+        return tuple(i for i in range(self.num_layers)
+                     if self.layer_kind(i) == "attention")
+
+    @property
+    def conv_layers(self) -> Tuple[int, ...]:
+        return tuple(i for i in range(self.num_layers)
+                     if self.layer_kind(i) == "conv")
+
     # -- bytes ----------------------------------------------------------------
     @property
     def itemsize(self) -> int:
         return 2 if self.dtype == "bfloat16" else 4
 
     def cache_row_bytes(self) -> int:
-        """Bytes ONE position holds in the cache, over all layers, as stored."""
+        """Bytes ONE position holds in the cache, as stored: over the
+        attention layers only, K and V of the key/value heads."""
         if self.attention == "latent":
             return self.num_layers * self.latent.row_lanes * self.itemsize
-        return self.num_layers * 2 * self.d_model * self.itemsize
+        return (len(self.attention_layers) * 2 * self.kv_heads * self.d_head
+                * self.itemsize)
+
+    def state_bytes(self) -> int:
+        """Bytes of ONE copy of a request's per-layer state beside its
+        per-token rows (a slot's, or a pool block's snapshot): the conv
+        layers' last rows; 0 where every layer is attention."""
+        if self.conv is None:
+            return 0
+        return (len(self.conv_layers) * self.conv.state_rows * self.d_model
+                * self.itemsize)

@@ -30,8 +30,13 @@ rows go and how the cache is read back:
   what is cached is the row every head shares), under decode rows and,
   when the tick has them, prefill lanes.
 
+A layer whose operator is a gated short convolution (`_short_conv`) keeps no
+row a position but a STATE a request (`_ConvState`: a slot's last rows, and a
+snapshot beside every pool block for the prefix cache to hand over with it).
+
 What a block is made of is a `DecoderSpec` (models/decoder_spec.py): norm
-kind, residual order, position scheme, attention kind, feed-forward kind.
+kind, residual order, position scheme, attention kind, feed-forward kind, and
+where layers differ a kind a layer.
 The classic spec (post-LayerNorm, sinusoid, full heads, ReLU pair) is what
 every graph above builds, op for op as before a block had kinds; the paged
 ticks take any spec (`model=`).
@@ -213,14 +218,16 @@ class _TickRows:
     attention, not the embedding), which rows are real (a dead row — an
     idle slot, the tail of a short chunk — selects no expert), and where
     the routed layers leave their counts (`expert_rows`: per layer, the
-    rows each held expert got)."""
+    rows each held expert got); `conv`, where the spec has conv layers, is
+    their state (`_ConvState`)."""
 
-    def __init__(self, spec, positions, live, max_len):
-        self.positions, self.live = positions, live
+    def __init__(self, spec, positions, live, max_len, conv=None):
+        self.positions, self.live, self.conv = positions, live, conv
         self.expert_rows = []
         self.table = None
         if spec.positions == "rotary":
-            self.table = layers.assign(rotary_table(spec.latent.rope, max_len))
+            rope = spec.latent.rope if spec.latent else spec.rope
+            self.table = layers.assign(rotary_table(rope, max_len))
 
     def with_counts(self, next_ids):
         """`next_ids` [R,1] int64 followed by the routed layers' counts, one
@@ -260,19 +267,25 @@ def _gated_ffn(x, d_model, d_inner, name):
 
 def _moe_ffn(x, spec, name, rows):
     """The routed layer: the held experts' part of the top-k sum (routing
-    over every expert; `fusion/moe.py`) plus the shared expert."""
+    over every expert; `fusion/moe.py`) plus the shared expert, where the
+    spec has one."""
     moe, d = spec.moe, spec.d_model
     router = _param(name + "_router.w_0", [d, moe.n_routed], spec.dtype)
     stack = {n: _param(f"{name}_experts_{n}",
                        [len(moe.held), moe.d_expert, d] if n == "down"
                        else [len(moe.held), d, moe.d_expert], spec.dtype)
              for n in ("gate", "up", "down")}
+    bias = None
+    if moe.topk_method == "bias":
+        bias = _param(name + "_router_bias", [moe.n_routed], "float32")
     weights, n_rows = layers.moe_route(
         x, router, moe.held, moe.top_k, moe.scaling, moe.norm_topk_prob,
-        live=rows.live)
+        live=rows.live, bias=bias, norm_eps=moe.norm_eps)
     rows.expert_rows.append(n_rows)
     routed = layers.moe_experts(x, weights, n_rows, stack["gate"],
                                 stack["up"], stack["down"])
+    if not moe.n_shared:
+        return routed
     shared = _gated_ffn(x, d, moe.d_expert * moe.n_shared, name + "_shared")
     return layers.elementwise_add(routed, shared)
 
@@ -325,6 +338,42 @@ def _latent_attention(x, spec, name, attend, rows):
     return _proj(out, spec.d_model, name + "_o")
 
 
+def _grouped_attention(x, spec, name, attend, rows):
+    """Attention with `spec.kv_heads` key/value heads under `num_heads`
+    query heads (query head i reads key/value head i // group), an RMSNorm
+    a head on q and k where the spec asks (`qk_norm`), rotary positions
+    over the whole head, around `attend(q, k_new, v_new)`."""
+    n, nh, nkv, dh = x.shape[0], spec.num_heads, spec.kv_heads, spec.d_head
+
+    def heads(t, count, which):
+        if spec.qk_norm:
+            t = layers.rms_norm(
+                layers.reshape(t, shape=[n, count, dh]),
+                epsilon=spec.norm_eps,
+                param_attr=ParamAttr(name=f"{name}_{which}_norm.scale"))
+        t = layers.rotary(layers.reshape(t, shape=[n, count * dh]),
+                          rows.positions, rows.table)
+        return layers.reshape(t, shape=[n, 1, count * dh])
+
+    q = heads(_proj(x, nh * dh, name + "_q"), nh, "q")
+    k = heads(_proj(x, nkv * dh, name + "_k"), nkv, "k")
+    v = _proj(x, nkv * dh, name + "_v")
+    return _proj(attend(q, k, v), spec.d_model, name + "_o")
+
+
+def _short_conv(x, spec, name, rows):
+    """The gated short convolution: `[B, C, z] = x W_in`, `u = B * z`, the
+    causal depthwise convolution of u from the request's state
+    (`rows.conv`, fusion/short_conv.py), `(C * conv) W_out`; no bias."""
+    d = spec.d_model
+    bcz = _proj(x, 3 * d, name + "_in")
+    b, c, z = (layers.slice(bcz, axes=[2], starts=[j * d], ends=[(j + 1) * d])
+               for j in range(3))
+    taps = _param(name + "_taps", [d, spec.conv.taps], spec.dtype)
+    conv = rows.conv.layer(layers.elementwise_mul(b, z), taps)
+    return _proj(layers.elementwise_mul(c, conv), d, name + "_out")
+
+
 def _decoder_block(x, i, attend, d_model, d_inner, dropout, is_test=True,
                    prefix="l", attn="attn", cross=None, spec=None,
                    rows=None):
@@ -332,9 +381,12 @@ def _decoder_block(x, i, attend, d_model, d_inner, dropout, is_test=True,
     from what `spec` (a `DecoderSpec`; None is the classic one) says a block
     is made of:
 
-    - attention: full heads (`_attention` around `attend(i, q, k_new,
-      v_new)`, which is all a graph chooses: see the module docstring) or
-      latent (`_latent_attention` around `attend(i, q_rows, cache_row)`);
+    - the operator, by `spec.layer_kind(i)`: attention with full heads
+      (`_attention` around `attend(i, q, k_new, v_new)`, which is all a
+      graph chooses: see the module docstring; `_grouped_attention` where
+      the heads are rotated, normalised or grouped), latent attention
+      (`_latent_attention` around `attend(i, q_rows, cache_row)`), or the
+      gated short convolution (`_short_conv`, its state in `rows.conv`);
     - `cross(i, x)` when given;
     - the feed-forward: the ReLU pair, the gated SiLU pair, or from
       `spec.moe.first_dense` on routed experts beside the shared one;
@@ -347,8 +399,14 @@ def _decoder_block(x, i, attend, d_model, d_inner, dropout, is_test=True,
     name = f"{prefix}{i}"
     spec = spec or DecoderSpec.classic(d_model=d_model, d_inner=d_inner,
                                        dropout=dropout)
-    if spec.attention == "latent":
+    if spec.layer_kind(i) == "conv":
+        sublayers = [lambda x: _short_conv(x, spec, f"{name}_conv", rows)]
+    elif spec.attention == "latent":
         sublayers = [lambda x: _latent_attention(
+            x, spec, f"{name}_{attn}", functools.partial(attend, i), rows)]
+    elif spec.rope is not None or spec.qk_norm \
+            or spec.kv_heads != spec.num_heads:
+        sublayers = [lambda x: _grouped_attention(
             x, spec, f"{name}_{attn}", functools.partial(attend, i), rows)]
     else:
         sublayers = [lambda x: _attention(x, x, x, d_model, f"{name}_{attn}",
@@ -949,17 +1007,23 @@ class _PagedCache:
 
     def __init__(self, cache_prefix, n_blocks, block_size, num_heads, d_head,
                  num_layers, btab, pos, wblock, woff, dropout,
-                 kv_quant=False):
+                 kv_quant=False, kv_heads=None, dtype="float32",
+                 cached_layers=None):
+        """`kv_heads` (None: `num_heads`) key/value heads a pool holds;
+        `dtype` the pools'; `cached_layers` the layers that have pools
+        (None: all `num_layers`)."""
         from ..ops.tensor_ops import pool_block_shape
         self.num_heads, self.d_head, self.dropout = num_heads, d_head, dropout
+        self.kv_heads = kv_heads or num_heads
         self.btab, self.pos, self.wblock, self.woff = btab, pos, wblock, woff
-        block = list(pool_block_shape(num_heads, block_size, d_head))
+        block = list(pool_block_shape(self.kv_heads, block_size, d_head))
         self.pools, self.scale_pools = {}, {}
-        for i in range(num_layers):
+        for i in (range(num_layers) if cached_layers is None
+                  else cached_layers):
             for s in "kv":
                 self.pools[f"{s}{i}"] = _slot_cache_var(
                     f"{cache_prefix}_{s}{i}", [n_blocks] + block,
-                    dtype="int8" if kv_quant else "float32")
+                    dtype="int8" if kv_quant else dtype)
                 if kv_quant:
                     self.scale_pools[f"{s}{i}"] = _slot_cache_var(
                         f"{cache_prefix}_{s}{i}_sc",
@@ -971,7 +1035,7 @@ class _PagedCache:
         """Scatter rows `new` into pool `key` → (pool, scale pool | None)."""
         pool = self.pools[key]
         new3 = layers.reshape(new, shape=[int(np.prod(self.wblock.shape)),
-                                          self.num_heads, self.d_head])
+                                          self.kv_heads, self.d_head])
         if self.scale_pools:
             spool = self.scale_pools[key]
             return layers.paged_cache_write_quant(
@@ -1067,8 +1131,8 @@ class _PagedLaneCache(_PagedCache):
     attends before a later write replaces them."""
 
     def __init__(self, *paged, n_slots, n_lanes, chunk, lbtab, lpos,
-                 lwblocks, lrows):
-        super().__init__(*paged)
+                 lwblocks, lrows, **kinds):
+        super().__init__(*paged, **kinds)
         self.S, self.L, self.C = n_slots, n_lanes, chunk
         self.lbtab, self.lpos = lbtab, lpos
         self.lwblocks, self.lrows = lwblocks, lrows
@@ -1147,6 +1211,45 @@ class _LatentPagedCache:
             axis=0)
 
 
+class _ConvState:
+    """The conv layers' state beside the paged cache (fusion/short_conv.py
+    has the scheme): `{cache_prefix}_conv_slot` [n_slots, n_conv, K-1, D],
+    a slot's last rows, and `{cache_prefix}_conv_block` [n_blocks, n_conv,
+    K-1, D], the state after each pool block's last position, both
+    persistable, in the spec's dtype, zero at start-up. `layer(u, taps)` is
+    one conv layer over the tick's rows; `commit()`, after the last layer,
+    writes what the layers left into the two arrays, in place. `lanes` as
+    `_LatentPagedCache` takes them, plus `lslot` (each lane's slot) and
+    `block_size`."""
+
+    def __init__(self, cache_prefix, spec, n_slots, n_blocks, wblock,
+                 lanes=None):
+        shape = [len(spec.conv_layers), spec.conv.state_rows, spec.d_model]
+        self.slot = _slot_cache_var(f"{cache_prefix}_conv_slot",
+                                    [n_slots] + shape, dtype=spec.dtype)
+        self.block = _slot_cache_var(f"{cache_prefix}_conv_block",
+                                     [n_blocks] + shape, dtype=spec.dtype)
+        self.n_slots, self.wblock, self.lanes = n_slots, wblock, lanes
+        self.decode, self.snaps, self.last = [], [], []
+
+    def layer(self, u, taps):
+        ln = self.lanes
+        out, new_d, snaps, last = layers.short_conv(
+            u, taps, self.slot, len(self.decode), self.n_slots,
+            lanes=ln and dict(ln, block_state=self.block))
+        self.decode.append(new_d)
+        self.snaps.append(snaps)
+        self.last.append(last)
+        return out
+
+    def commit(self):
+        ln = self.lanes
+        layers.conv_state_commit(
+            self.slot, self.decode, self.wblock,
+            lanes=ln and dict(ln, block_state=self.block, snaps=self.snaps,
+                              last=self.last))
+
+
 def _embed_rows(tok, spec, name="tok_emb"):
     """[N,1] ids -> [N,1,H] in the spec's dtype, unscaled, no positions
     (the rotary kinds put them inside attention)."""
@@ -1177,15 +1280,20 @@ def _kinds_paged_tick(model, n_slots, n_blocks, block_size, blocks_per_req,
                       cache_prefix, lanes=None):
     """The paged decode tick (`lanes` None) or mixed tick of a non-classic
     `DecoderSpec`: the classic builders' feeds (`_decode_feeds`,
-    `_lane_feeds`); rows embedded without positions; `_LatentPagedCache`;
-    the blocks through `_lm_decoder`; a float32 head without bias. Returns
-    (next_ids followed by the routed layers' counts, cache names)."""
-    if model.attention != "latent":
+    `_lane_feeds`, and `lane_slot` where a lane leaves a state in its
+    slot); rows embedded without positions; `_LatentPagedCache`, or
+    `_PagedCache` / `_PagedLaneCache` over the attention layers' key/value
+    heads beside `_ConvState`; the blocks through `_lm_decoder`; a float32
+    head without bias, the embedding itself where the spec ties it.
+    Returns (next_ids followed by the routed layers' counts, the K/V
+    pools' names)."""
+    if model.residual != "pre" or model.positions != "rotary":
         raise NotImplementedError(
-            "the paged ticks build the classic spec or latent attention; "
-            f"attention {model.attention!r} with norm {model.norm!r}, "
-            f"residual {model.residual!r}, ffn {model.ffn!r} has no cache "
-            "seam yet")
+            "the paged ticks build the classic spec, latent attention or "
+            "rotary grouped attention beside short convolutions, each in a "
+            f"pre-norm block; norm {model.norm!r}, residual "
+            f"{model.residual!r}, positions {model.positions!r} has no "
+            "cache seam yet")
     S, NLB = n_slots, blocks_per_req
     tok, pos, btab, wblock, woff = _decode_feeds(S, NLB)
     toks, positions, lane_feeds, lrows = tok, pos, None, None
@@ -1200,13 +1308,29 @@ def _kinds_paged_tick(model, n_slots, n_blocks, block_size, blocks_per_req,
         positions = layers.concat(
             [pos, layers.reshape(_window_positions(lpos, C),
                                  shape=[L * C, 1, 1])], axis=0)
-    cache = _LatentPagedCache(cache_prefix, n_blocks, block_size, model, btab,
-                              pos, wblock, woff, lane_feeds)
+    conv = None
+    if model.attention == "latent":
+        cache = _LatentPagedCache(cache_prefix, n_blocks, block_size, model,
+                                  btab, pos, wblock, woff, lane_feeds)
+    else:
+        paged = (cache_prefix, n_blocks, block_size, model.num_heads,
+                 model.d_head, model.num_layers, btab, pos, wblock, woff, 0.0)
+        kinds = dict(kv_heads=model.kv_heads, dtype=model.dtype,
+                     cached_layers=model.attention_layers)
+        cache = (_PagedCache(*paged, **kinds) if lanes is None
+                 else _PagedLaneCache(*paged, **lane_feeds, **kinds))
+        if model.conv is not None:
+            conv = _ConvState(
+                cache_prefix, model, S, n_blocks, wblock,
+                lanes and dict(lane_feeds, block_size=block_size,
+                               lslot=_feed("lane_slot", [lanes[0]])))
     rows = _TickRows(model, positions,
                      _live_rows(wblock, lrows, lanes[1] if lanes else 0),
-                     NLB * block_size)
+                     NLB * block_size, conv)
     x = _lm_decoder(_embed_rows(toks, model), cache.attend, model.num_layers,
                     model.d_model, model.d_inner, 0.0, spec=model, rows=rows)
+    if conv is not None:
+        conv.commit()
     if lanes is not None:
         xd, xl = _split_rows(x, S, L, C)
         x = layers.concat(
@@ -1214,8 +1338,15 @@ def _kinds_paged_tick(model, n_slots, n_blocks, block_size, blocks_per_req,
                 layers.gather(layers.reshape(xl, shape=[L * C,
                                                         model.d_model]),
                               llast), shape=[L, 1, model.d_model])], axis=0)
-    _, next_ids, _ = _lm_head(x, model.vocab, bias=False,
-                              out_dtype="float32")
+    if model.tied_head:
+        from ..framework.program import default_main_program
+        table = default_main_program().global_block().vars["tok_emb"]
+        next_ids = layers.argmax(
+            layers.matmul(x, table, transpose_y=True, out_dtype="float32"),
+            axis=2)
+    else:
+        _, next_ids, _ = _lm_head(x, model.vocab, bias=False,
+                                  out_dtype="float32")
     return rows.with_counts(next_ids), cache.names
 
 

@@ -332,9 +332,20 @@ class KVPager:
 
     def __init__(self, n_blocks: int, block_size: int,
                  prefix_sharing: bool = True,
-                 host_tier: Optional[HostTierConfig] = None):
+                 host_tier: Optional[HostTierConfig] = None,
+                 block_state: bool = False):
         self.block_size = int(block_size)
         self.prefix_sharing = bool(prefix_sharing)
+        #: the model keeps a per-request STATE beside its per-token rows
+        #: (conv layers): every block then carries a snapshot of the state
+        #: after its last position, written by the tick that fills it.
+        #: `_snap[b]` says block b's is valid; it is void once the block
+        #: is handed out again, and a prefix hit is served only up to a
+        #: block that has one (the request resumes from it).
+        self.block_state = bool(block_state)
+        self._snap = np.zeros(n_blocks, bool)
+        self.state_restores = 0         # admissions resumed from a snapshot
+        self.state_snapshots = 0        # block snapshots written
         self.pool = BlockPool(n_blocks, block_size)
         self.index = RadixPrefixIndex(block_size)
         self.host_tier = host_tier
@@ -380,6 +391,14 @@ class KVPager:
             shared_nodes = self.index.match(prompt)
         max_shared = (len(prompt) - 1) // self.block_size
         shared_nodes = shared_nodes[:min(max_shared, n_logical)]
+        if self.block_state and shared_nodes:
+            # the request resumes from the snapshot of the span's last block:
+            # every indexed block has one (`note_block_filled` offers none
+            # without, and a block handed out again left the index first)
+            enforce(self._snap[shared_nodes[-1].block],
+                    f"block {shared_nodes[-1].block} is in the prefix index "
+                    f"without a state snapshot")
+            self.state_restores += 1
         # pin the matched blocks FIRST: eviction under pressure below
         # may drop their index nodes, but a pinned block cannot free
         blocks = []
@@ -406,6 +425,7 @@ class KVPager:
         while True:
             b = self.pool.alloc()
             if b is not None:
+                self._snap[b] = False    # its last holder's state is void
                 return b
             if not self.index.evict_one(self.pool):
                 return None
@@ -413,17 +433,25 @@ class KVPager:
 
     # -- lifecycle --------------------------------------------------------
     def note_block_filled(self, table: BlockTable, logical_block: int,
-                          prompt: Sequence[int]):
+                          prompt: Sequence[int], snapshot: bool = False):
         """Block `logical_block` of the request just received its last
         row. If it is a FULL prompt block (generated tokens are not
         shareable prefix — they differ per request even for equal
         prompts under different max_new/eos) and not itself served from
         the index, offer it to the prefix cache NOW: a request arriving
-        mid-prefill of its twin already shares the finished span."""
+        mid-prefill of its twin already shares the finished span.
+        `snapshot`: the tick that filled it also wrote its state snapshot
+        (a prefill lane of a model with conv layers; a block a DECODE row
+        completes has none, `_note_position_written`)."""
+        if snapshot and logical_block >= table.n_shared:
+            self._snap[table.blocks[logical_block]] = True
+            self.state_snapshots += 1
         if not self.prefix_sharing or logical_block < table.n_shared:
             return
         if (logical_block + 1) * self.block_size > len(prompt):
             return
+        if self.block_state and not self._snap[table.blocks[logical_block]]:
+            return          # nothing to resume from: not offered
         self.index.register(prompt, logical_block,
                             table.blocks[logical_block], self.pool)
 
@@ -626,6 +654,10 @@ class KVPager:
             "evictions": self.evictions,
             "cow_copies": self.cow_copies,
             "rolled_back_blocks": self.rolled_back_blocks,
+            "block_state": None if not self.block_state else {
+                "restores": self.state_restores,
+                "snapshots": self.state_snapshots,
+                "blocks_with_snapshot": int(self._snap.sum())},
             "host_tier": None if self.host_tier is None else {
                 "host_blocks": self.host_tier.host_blocks,
                 "host_blocks_used": self.host_blocks_used,
@@ -686,9 +718,14 @@ class PagedKVEngine(ContinuousBatchingEngine):
     `_LatentPagedCache`), a spec with `moe` the routed expert layer over
     the experts it holds; both ticks then bring back, behind the ids and in
     the same copy, the rows every held expert got (`engine/tick`'s
-    `experts_touched` and `routed_rows`, `stats()["expert_rows"]`). With
-    such a model `speculative=`, `host_tier=`, `kv_quant=`, `quant=` and
-    `topk_k` are refused by name: none of them is built for it.
+    `experts_touched` and `routed_rows`, `stats()["expert_rows"]`). A spec
+    with conv layers gets a second kind of state beside the K/V pools (of
+    the attention layers' key/value heads only): a slot's conv state and a
+    snapshot of it beside every pool block, which a prefix hit resumes
+    from (`models.transformer._ConvState`; `stats()["conv_state"]`,
+    `engine/admit`'s `state_restored`, `engine/tick`'s `state_snapshots`).
+    With any such model `speculative=`, `host_tier=`, `kv_quant=`, `quant=`
+    and `topk_k` are refused by name: none of them is built for it.
     """
 
     def __init__(self, n_slots: int = 4, vocab: int = 32000,
@@ -728,9 +765,18 @@ class PagedKVEngine(ContinuousBatchingEngine):
                 enforce(not on,
                         f"{option}= is not built for a model with "
                         f"attention {model.attention!r}"
+                        + (f" over {model.kv_heads} key/value heads"
+                           if model.kv_heads != model.num_heads else "")
+                        + (" beside short-convolution layers"
+                           if model.conv else "")
                         + (" and routed experts" if model.moe else "")
                         + ": it walks the classic K/V pools and float32 "
-                        "weights; serve this model without it",
+                        "weights"
+                        + (", not the conv state (a slot's rows and the "
+                           "blocks' snapshots), which it would neither "
+                           "roll back, spill, fork nor quantize"
+                           if model.conv else "")
+                        + "; serve this model without it",
                         exc=InvalidArgumentError)
         #: (layer, held expert) -> rows routed to it since construction
         self.expert_rows = np.zeros(
@@ -763,7 +809,11 @@ class PagedKVEngine(ContinuousBatchingEngine):
         # honored as-is — the caller owns the budget then).
         dh = d_model // num_heads
         #: bytes one block holds over all layers, by the model's cache kind
+        #: (K and V of the key/value heads, attention layers only) ...
         self.block_bytes = model.cache_row_bytes() * self.block_size
+        #: ... and of ONE copy of the conv layers' state: a slot holds
+        #: one, and every pool block a snapshot (0 without conv layers)
+        self.state_bytes = model.state_bytes()
         per_blk_f32 = 2 * num_layers * num_heads * self.block_size * dh * 4
         per_blk_i8 = 2 * num_layers * num_heads * self.block_size * (dh + 4)
         self.kv_quant_freed_bytes = 0
@@ -801,7 +851,11 @@ class PagedKVEngine(ContinuousBatchingEngine):
                 f"full-span request ({self.blocks_per_req} blocks + the "
                 f"null block)", exc=InvalidArgumentError)
         self.pager = KVPager(self.n_blocks, self.block_size,
-                             prefix_sharing, host_tier=host_tier)
+                             prefix_sharing, host_tier=host_tier,
+                             block_state=bool(self.state_bytes))
+        # the pager's `state_restores` at the last `engine/admit` span,
+        # which carries what was added since
+        self._state_seen = 0
         # two-tier scheduler state: per-rid host residency records and
         # the FIFO of suspended requests (admission order — no
         # starvation, same discipline as the head-of-line device wait)
@@ -892,9 +946,9 @@ class PagedKVEngine(ContinuousBatchingEngine):
                 lat.row_lanes, lat.kv_lora_rank, d["num_heads"], 1)
         else:
             self.paged_attention_lowering = paged_attention_lowering(
-                "int8" if self.kv_quant else "float32",
-                pool_block_shape(d["num_heads"], self.block_size, dh)[-1], 1,
-                dh, self.kv_quant)
+                "int8" if self.kv_quant else self.model.dtype,
+                pool_block_shape(self.model.kv_heads, self.block_size,
+                                 dh)[-1], 1, dh, self.kv_quant)
         outs = transformer.transformer_lm_paged_decode_tick(
             n_slots=self.n_slots, n_blocks=self.n_blocks,
             block_size=self.block_size,
@@ -962,7 +1016,7 @@ class PagedKVEngine(ContinuousBatchingEngine):
         blocks their reads span)."""
         attrs = self._tick_attrs
         lanes = []
-        tokens = 0
+        tokens = snapshots = 0
         if prefilling:
             lf = self._lane_feeds
             for a in lf.values():
@@ -980,12 +1034,19 @@ class PagedKVEngine(ContinuousBatchingEngine):
                     blocks[b0:b0 + nb]
                 lf["lane_rows"][lane] = n
                 lf["lane_last"][lane] = lane * C + n - 1
+                if self.state_bytes:
+                    # the lane leaves its last state in the request's slot,
+                    # and a snapshot beside every block it fills
+                    lf["lane_slot"][lane] = req.slot
+                    snapshots += (k0 + n) // bs - b0
                 lanes.append((req, n))
                 tokens += n
                 attrs["kv_blocks"] += b0 + nb
         self._lanes = lanes
         attrs["prefill"] = len(lanes)
         attrs["prefill_tokens"] = tokens
+        if self.state_bytes:
+            attrs["state_snapshots"] = snapshots
 
     def _launch_tick(self):
         # a tick with a slot in prefill is the mixed program (the decode
@@ -1030,7 +1091,8 @@ class PagedKVEngine(ContinuousBatchingEngine):
         k0 = req.fed
         req.fed = k0 + n
         for lb in range(k0 // bs, req.fed // bs):
-            self.pager.note_block_filled(req.table, lb, req.prompt)
+            self.pager.note_block_filled(req.table, lb, req.prompt,
+                                         snapshot=self.pager.block_state)
         if req.fed < len(req.prompt):
             req.next_tok = req.prompt[req.fed]
             return False
@@ -1059,7 +1121,10 @@ class PagedKVEngine(ContinuousBatchingEngine):
             if table.shared_len:
                 # the shared span's K/V is already resident and
                 # byte-exact (deterministic compute) — skip its
-                # prefill ticks
+                # prefill ticks. A model with conv layers resumes from
+                # the state snapshot of the span's last block: its first
+                # chunk starts there (`fusion/short_conv.py`), and the
+                # pager served the span only up to a block that has one
                 req.fed = table.shared_len
                 req.next_tok = req.prompt[table.shared_len]
             if self.host_tier is not None:
@@ -1083,8 +1148,13 @@ class PagedKVEngine(ContinuousBatchingEngine):
         return True
 
     def _admit_pool_attrs(self) -> Dict[str, int]:
-        return {"pool_used": self.pager.pool.n_used,
-                "pool_blocks": self.n_blocks}
+        attrs = {"pool_used": self.pager.pool.n_used,
+                 "pool_blocks": self.n_blocks}
+        if self.state_bytes:
+            now = self.pager.state_restores
+            attrs["state_restored"] = now - self._state_seen
+            self._state_seen = now
+        return attrs
 
     def _release_request(self, req: GenRequest):
         if req.table is not None:
@@ -1465,6 +1535,13 @@ class PagedKVEngine(ContinuousBatchingEngine):
         s["kv_quant"] = {"enabled": self.kv_quant,
                          "freed_bytes": self.kv_quant_freed_bytes}
         s["block_bytes"] = self.block_bytes
+        if self.state_bytes:
+            # the second kind of state: a copy a slot, a snapshot a block
+            s["conv_state"] = dict(
+                self.pager.stats()["block_state"],
+                bytes_per_copy=self.state_bytes,
+                slot_bytes=self.state_bytes * self.n_slots,
+                snapshot_bytes=self.state_bytes * self.n_blocks)
         if self.model.attention == "latent":
             lat = self.model.latent
             s["latent_row"] = {"values": lat.row_values,

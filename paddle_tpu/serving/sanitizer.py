@@ -96,6 +96,7 @@ class KVSanitizer:
         self.model = AbstractState(
             pager.pool.n_blocks, pager.pool.block_size,
             pager.host_tier.host_blocks if pager.host_tier else 0)
+        self.model.track_state = pager.block_state
         self._detached_host = 0   # spill blocks released-but-unrefunded
         self._ctx: List[str] = []  # pager op naming the inner pool ops
         self.ops_mirrored = 0
@@ -388,6 +389,9 @@ class KVSanitizer:
                 "kv-block-leak", op,
                 f"radix index holds {n_pins} pinned blocks but "
                 f"n_cached says {self.pager.index.n_cached}")
+        if self.model.track_state:
+            # the second resource kind: which blocks hold a state snapshot
+            self.model.snap = self.pager._snap.tolist()
         with self._shadowed():
             self.model.check_invariants(op=op, pins=pins,
                                         detached_host=self._detached_host)

@@ -49,6 +49,21 @@ def test_train_then_serve_from_the_same_scope():
     assert serve["prefill"] == "chunked" and serve["programs_compiled"] == 2
 
 
+def test_hybrid_serving_phase():
+    """The smoke's hybrid engine (conv layers with a state, grouped rotary
+    attention, all-held experts under a biased top-k, a tied head) at a tiny
+    size: prefix-hit requests equal their self-prefilled twins."""
+    out = chip_smoke.phase_serve_hybrid(
+        vocab=97, d_model=64, d_inner=96, num_heads=8, num_kv_heads=2,
+        d_expert=256, n_experts=8, top_k=2, n_slots=4, block_size=8,
+        n_blocks=40, max_len=64, preamble=24, turns=(5, 11, 3), max_new=6,
+        expect_lowering="composite")
+    assert out["conv_state"]["restores"] == 2
+    assert out["conv_state"]["snapshots"] >= 3 and out["tokens_out"] == 18
+    assert out["zeroed_state_differs_at"] < 6   # the planted fault is refused
+    assert out["block_bytes"] == 1 * 2 * 2 * 8 * 2 * 8    # one attention layer
+
+
 def test_train_resnet_phase():
     out = chip_smoke.phase_train_resnet50(batch=2, steps=2, depth=18,
                                           image=32)

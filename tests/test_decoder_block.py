@@ -354,8 +354,13 @@ def test_kinds_are_checked_by_name():
             **DecoderSpec.classic(**DIMS).__dict__, "norm": "batch_norm"})
     with pytest.raises(ValueError, match="LatentSpec"):
         DecoderSpec(61, 32, 64, 4, 2, attention="latent", positions="rotary")
-    with pytest.raises(NotImplementedError, match="rotary positions"):
+    # rotary positions with full heads are built since PR 39, with a RopeSpec
+    with pytest.raises(ValueError, match="RopeSpec"):
         DecoderSpec(61, 32, 64, 4, 2, positions="rotary")
+    with pytest.raises(ValueError, match="key/value heads"):
+        DecoderSpec(61, 32, 64, 4, 2, num_kv_heads=3)
+    with pytest.raises(ValueError, match="ConvSpec"):
+        DecoderSpec(61, 32, 64, 4, 2, layer_kinds=("conv", "attention"))
     odd = DecoderSpec(61, 32, 64, 4, 2, norm="rms_norm")
     with pytest.raises(NotImplementedError, match="no cache seam"):
         _program(lambda: T.transformer_lm_paged_decode_tick(4, 20, 4, 6,

@@ -1677,6 +1677,8 @@ COVERED_ELSEWHERE = {
     "rotary": "tests/test_latent_attention.py",
     "rms_norm": "tests/test_latent_moe_engine.py",
     "moe_route": "tests/test_routed_experts.py",
+    "short_conv": "tests/test_short_conv.py",
+    "conv_state_commit": "tests/test_short_conv.py",
     "moe_experts": "tests/test_routed_experts.py",
     # the paged ticks' cache read through the block table: the Pallas
     # kernel against the composite, and the composite against dense

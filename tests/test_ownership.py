@@ -133,15 +133,15 @@ class TestModelChecker:
         # deterministic BFS over a deterministic op set: the exact
         # coverage IS the spec — a protocol change must update it here
         # and in docs/static_analysis.md §5 together
-        assert (res.states_explored, res.transitions) == (233, 676)
+        assert (res.states_explored, res.transitions) == (238, 686)
 
     def test_state_space_closes_exhaustively(self):
         # past depth 33 no new states exist at this scope: raising the
         # bound far beyond it proves TOTAL coverage, not a sample
         res = ModelChecker(depth=64).run()
         assert res.ok, res.violations
-        assert res.states_explored == 4886
-        assert res.transitions == 28843
+        assert res.states_explored == 5262
+        assert res.transitions == 31021
 
     @pytest.mark.parametrize("mutation", sorted(MUTATIONS))
     def test_mutation_caught_by_name(self, mutation):

@@ -325,3 +325,65 @@ def test_expert_product_kernel_lowers_at_the_benchmark_shapes(rows):
         S((12, 7168, 2048), BF16), S((12, 7168, 2048), BF16),
         S((12, 2048, 7168), BF16))
     assert _n_calls(text) == 1
+
+
+# -- grouped queries over bfloat16 pools, and all 32 experts held -----------
+
+_GQA = dict(nh=32, nkv=8, dh=64, bs=64, n_blocks=2048, table=48)
+
+
+def _gqa_read(slots, positions):
+    from paddle_tpu.fusion import paged_decode_attention
+    g = _GQA
+    pool = S((g["n_blocks"], g["nkv"], g["bs"] * g["dh"] // 128, 128), BF16)
+    return (lambda q, k, v, t, p, r: paged_decode_attention(
+                q, k, v, t, p, g["nh"], scale=g["dh"] ** -0.5, rows=r,
+                backend="pallas"),
+            [S((slots, positions, g["nh"] * g["dh"]), BF16), pool, pool,
+             S((slots, g["table"]), I32), S((slots, 1, 1), F32),
+             S((slots,), I32)])
+
+
+@pytest.mark.parametrize("slots, positions", [(64, 1), (2, 128)])
+def test_grouped_paged_kernel_compiles_for_v5e(one_chip, slots, positions):
+    """The paged read of lfm2-8b-a1b_serve_assistant at its published widths:
+    64 decode rows, and two prefill lanes of 128 positions, 32 query heads
+    over 8 key/value heads of 64 in bfloat16 blocks of 64, a table of 48
+    blocks: ONE Mosaic call, which the TPU compiler takes for a v5e."""
+    f, args = _gqa_read(slots, positions)
+    assert _n_calls(_tpu_text(f, *args)) == 1
+    args = [S(a.shape, a.dtype, sharding=one_chip) for a in args]
+    compiled = jax.jit(f).lower(*args).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+
+
+@pytest.mark.parametrize("rows", [64, 320])
+def test_all_held_expert_product_compiles_for_v5e(one_chip, rows):
+    """The grouped expert product of that cell's decode tick (64 rows) and
+    mixed tick (64 + 2 * 128): all 32 experts of 2048 x 1792 held."""
+    from paddle_tpu.fusion import moe
+    args = [S((rows, 2048), BF16), S((32, rows, 1), F32), S((32,), I32),
+            S((32, 2048, 1792), BF16), S((32, 2048, 1792), BF16),
+            S((32, 1792, 2048), BF16)]
+    f = lambda x, w, n, g, u, d: moe.experts(x, w, n, g, u, d,   # noqa: E731
+                                             backend="pallas")
+    assert _n_calls(_tpu_text(f, *args)) == 1
+    args = [S(a.shape, a.dtype, sharding=one_chip) for a in args]
+    assert jax.jit(f).lower(*args).compile().as_text().count(
+        "tpu_custom_call") == 1
+
+
+def test_equal_heads_float32_pools_keep_their_kernels():
+    """The classic float32 pools with as many key/value heads as query heads
+    still take `_paged_kernel` (one position) and the chunk kernel under its
+    old name: the grouped path is taken by the pool's heads and dtype."""
+    from paddle_tpu.fusion import paged_decode_attention
+    pool = S((1024, 16, 8, 128), F32)
+    for positions, scope in ((1, "paged_decode_attention"),
+                             (128, "paged_chunk_attention")):
+        text = _tpu_text(
+            lambda q, k, v, t, p: paged_decode_attention(
+                q, k, v, t, p, 16, scale=0.125, backend="pallas"),
+            S((2, positions, 1024), F32), pool, pool, S((2, 18), I32),
+            S((2, 1, 1), F32))
+        assert _n_calls(text) == 1 and "paged_gqa_attention" not in text

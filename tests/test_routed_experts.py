@@ -159,3 +159,66 @@ def test_route_near_swaps_one_pair_the_scores_do_not_tell_apart():
     assert len(ref.route_near(x, w_r, cfg, (7,), 0.0, n_rows=3)[2]) == 0
     # rows past `n_rows` (padding) start nothing
     assert len(ref.route_near(x, w_r, cfg, (7,), 0.05, n_rows=0)[2]) == 0
+
+
+# -- a bias-corrected selection, no shared expert (ISSUE 39) -----------------
+
+BIAS = jnp.asarray(np.random.default_rng(5).normal(size=E) * 0.05, jnp.float32)
+
+
+def test_the_bias_chooses_and_the_unbiased_score_weighs():
+    k = 4
+    held = tuple(range(E))
+    w, rows = moe.route(X, W_R, held, k, 1.0, bias=BIAS, norm_eps=1e-6)
+    w = np.asarray(w)[:, :, 0].T                       # [N, E]
+    sigma = 1.0 / (1.0 + np.exp(-(np.asarray(X) @ np.asarray(W_R))))
+    moved = 0
+    for n in range(N):
+        top = np.argsort(-(sigma[n] + np.asarray(BIAS)))[:k]
+        plain = np.argsort(-sigma[n])[:k]
+        moved += set(top) != set(plain)
+        want = np.zeros(E)
+        want[top] = sigma[n, top] / (sigma[n, top].sum() + 1e-6)
+        np.testing.assert_allclose(w[n], want, atol=1e-6)
+    # the bias moves the selection of a measurable share of rows, and where
+    # it does the weights are still the scores': nothing of it is summed in
+    assert 0 < moved < N
+    assert np.asarray(rows).sum() == N * k
+    # without it: the plain router, to the bit, whatever norm_eps is named
+    a = moe.route(X, W_R, held, k, 1.0)
+    b = moe.route(X, W_R, held, k, 1.0, bias=None, norm_eps=0.0)
+    np.testing.assert_array_equal(np.asarray(a[0]), np.asarray(b[0]))
+
+
+def test_four_shares_of_eight_and_no_shared_expert_add_up_to_the_uncut_layer():
+    """Four chips that hold eight of the 32 experts each: their parts of the
+    routed sum under the biased top-4 are the reference's layer with every
+    expert held and nothing shared."""
+    from lfm2_tiny import ref as lfm2_ref
+    p = {"m_router.w_0": W_R, "m_router_bias": BIAS, "m_experts_gate": GATE,
+         "m_experts_up": UP, "m_experts_down": DOWN}
+    cfg = dict(num_experts_per_tok=4, norm_topk_prob=True,
+               routed_scaling_factor=1, use_expert_bias=True)
+    with jax.default_matmul_precision("highest"):
+        ids, w, *_ = lfm2_ref.route_near(X, p, "m", cfg, 0.0, 0)
+        want = lfm2_ref.experts(p, "m", X, ids, w, E, jnp.zeros((N, D)))
+        total = jnp.zeros((N, D))
+        for share in range(4):
+            held = tuple(range(share * 8, share * 8 + 8))
+            hw, hrows = moe.route(X, W_R, held, 4, 1.0, bias=BIAS,
+                                  norm_eps=1e-6)
+            sl = slice(held[0], held[-1] + 1)
+            total = total + moe.experts(X, hw, hrows, GATE[sl], UP[sl],
+                                        DOWN[sl], backend="xla")
+    np.testing.assert_allclose(np.asarray(total), np.asarray(want),
+                               atol=2e-5)
+
+
+def test_a_spec_without_a_shared_expert_and_with_the_bias():
+    spec = MoESpec(n_routed=32, top_k=4, d_expert=64, held=tuple(range(32)),
+                   n_shared=0, first_dense=2, topk_method="bias",
+                   norm_eps=1e-6)
+    assert spec.n_shared == 0 and spec.topk_method == "bias"
+    with pytest.raises(NotImplementedError, match="group_limited_greedy"):
+        MoESpec(n_routed=32, top_k=4, d_expert=64, held=(0,),
+                topk_method="group_limited_greedy")

@@ -321,7 +321,7 @@ def phase_serve_hybrid(vocab=65536, d_model=2048, d_inner=7168, num_heads=32,
                        kinds=("conv", "conv", "attention", "conv"),
                        n_slots=16, block_size=64, n_blocks=128, max_len=1024,
                        preamble=256, turns=(40, 150), max_new=24,
-                       expect_lowering="kernel"):
+                       expect_lowering="kernel", decode_read=None):
     """A model with a kind a layer through the same PagedKVEngine: gated
     short convolutions with a per-request state beside grouped-query rotary
     attention over bfloat16 pools of the key/value heads, routed experts all
@@ -329,7 +329,11 @@ def phase_serve_hybrid(vocab=65536, d_model=2048, d_inner=7168, num_heads=32,
     published widths of benchmark/configs/lfm2-8b-a1b.json and four layers.
     Requests that start from a shared preamble (K/V blocks AND the conv
     state's snapshot from the prefix cache) must emit the tokens an engine
-    without prefix sharing emits, which prefills the preamble itself."""
+    without prefix sharing emits, which prefills the preamble itself.
+    Beside it the tick's grouped decode read alone, at the widths of
+    benchmark/cells/lfm2-8b-a1b_serve_assistant.json (64 slots, a quarter of
+    them live, a table of 48 blocks; `decode_read` overrides
+    `_check_grouped_decode`'s arguments), against the composite."""
     from paddle_tpu.models.decoder_spec import DecoderSpec, MoESpec, RopeSpec
     from paddle_tpu.serving import PagedKVEngine
     import paddle_tpu as pt
@@ -396,8 +400,14 @@ def phase_serve_hybrid(vocab=65536, d_model=2048, d_inner=7168, num_heads=32,
            "a request that resumed from a ZEROED conv state snapshot emitted "
            "its self-prefilled twin's tokens: the twins' comparison does not "
            "see the state")
+    read_err = _check_grouped_decode(**{
+        "n_slots": 64, "n_blocks": 1024, "block_size": block_size,
+        "num_heads": num_heads, "num_kv_heads": num_kv_heads,
+        "d_head": d_model // num_heads, "blocks_per_req": 48,
+        **(decode_read or {})})
     return {"compile_s": 0.0, "run_s": round(run_s, 2),
             "tokens_out": sum(len(t) for t in tokens[0]),
+            "decode_read_max_rel_err": float("%.2e" % read_err),
             "zeroed_state_differs_at": next(
                 i for i, (a, b) in enumerate(zip(*(r.tokens for r in pair)))
                 if a != b),
@@ -570,6 +580,53 @@ def _check_paged(n_slots, n_blocks, block_size, num_heads, d_head,
     timing["run_s"] += time.time() - t0
     _check(err <= TOL_F32, f"paged decode attention: error {err}")
     return err, float(np.max(np.abs(np.asarray(got) - np.asarray(ref))))
+
+
+def _check_grouped_decode(n_slots, n_blocks, block_size, num_heads,
+                          num_kv_heads, d_head, blocks_per_req, backend=None):
+    """The grouped decode read (one position a slot over bfloat16 pools of
+    the key/value heads: fusion/paged_attention.py `_decode_kernel`) against
+    its composite: every fourth slot live and the others idle on the null
+    block, ragged positions (a block's first and last row and the whole
+    span among them), a permuted table."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.fusion import (paged_attention_lowering,
+                                   paged_decode_attention)
+    from paddle_tpu.ops.tensor_ops import pool_block_shape
+
+    rng = np.random.RandomState(13)
+    span = blocks_per_req * block_size
+    shape = (n_blocks,) + pool_block_shape(num_kv_heads, block_size, d_head)
+    _check(paged_attention_lowering("bfloat16", shape[-1], 1, d_head, False,
+                                    backend=backend) == "kernel",
+           f"bfloat16 pools {shape} do not take the grouped decode kernel")
+    k_pool, v_pool = (jnp.asarray(rng.randn(*shape), jnp.bfloat16)
+                      for _ in range(2))
+    q = jnp.asarray(rng.randn(n_slots, 1, num_heads * d_head), jnp.float32)
+    live = np.arange(n_slots) % 4 == 1
+    pos = np.where(live, rng.randint(0, span // 2, (n_slots,)), 0)
+    edges = np.flatnonzero(live)[:3]
+    pos[edges] = (block_size, block_size - 1, span - 1)[:len(edges)]
+    ids = iter(np.resize(rng.permutation(np.arange(1, n_blocks)),
+                         n_slots * blocks_per_req))
+    btab = np.zeros((n_slots, blocks_per_req), np.int32)
+    for s in np.flatnonzero(live):
+        for j in range(pos[s] // block_size + 1):
+            btab[s, j] = next(ids)
+    args = (q, k_pool, v_pool, jnp.asarray(btab), jnp.asarray(pos, jnp.int32))
+
+    def run(be):
+        return jax.jit(lambda *a: paged_decode_attention(
+            *a, num_heads, scale=d_head ** -0.5, backend=be))
+    got = run(backend)(*args)
+    with jax.default_matmul_precision("highest"):
+        ref = run("xla")(*args)
+    _check(bool(jnp.isfinite(got).all()), "grouped decode read: not finite")
+    err = _rel_err(got[live], ref[live])
+    # bf16 operands on the MXU against float32 at the highest precision
+    _check(err <= TOL_BF16, f"grouped decode read: error {err}")
+    return err
 
 
 def _check_paged_chunk(n_lanes, chunk, n_blocks, block_size, num_heads,

@@ -5,36 +5,60 @@ keeps its K/V in one pool per layer and a slot sees its cache through a row
 of the block table. Until this op the read rebuilt the slot tick's dense
 `[S, nh, T, dh]` view (gather → transpose → reshape) every layer of every
 tick: a pass over as many bytes as the whole pool, live or not.
-`paged_decode_attention` is the read as ONE op with two lowerings of one
-algorithm:
+`paged_decode_attention` is the read as ONE op: three kernels and a
+composite of one algorithm, chosen by what the op can see (the number of
+query positions, the pool's dtype, the head counts) and nothing else:
 
-- the kernel (a TPU, float32 pools whose block is lane-dense, one query
-  position): a Pallas kernel with the block table and the positions
-  scalar-prefetched. One grid step a slot; the slot's LIVE blocks
-  (`pos // block_size + 1` of them) are DMA'd straight from the pool in
-  HBM, double-buffered, the next block (or the next slot's first) in
-  flight while this one is scored; blocks past the position cost nothing;
-  online softmax in float32 across the blocks; the tail of the last block
-  is masked by position. Nothing of pool shape is read or written. (A
-  BlockSpec pipeline over (slot, logical block) with the dead blocks
-  clamped to one index computes the same, and spends 0.1 ms a call on the
-  1024 dead steps of an idle tick where this spends 0.008: PERF.md,
-  PR 25.)
-- the chunk kernel (the same pools, G query rows a slot with G a multiple
-  of 8: a prefill lane of the mixed tick feeds a chunk of its prompt): one
-  grid step a lane; the lane's live blocks, up to position pos + rows - 1,
-  are DMA'd 128 key rows a step, double-buffered, and scored on the MXU a
-  head at a time (bf16 operands, float32 accumulation, float32 online
-  softmax); row g attends positions 0..pos+g, so the shared prefix's
-  blocks, earlier chunks and the chunk itself are one causal read. A lane
-  with no rows fetches nothing and returns zeros.
-- grouped queries (fewer key/value heads than query heads: the pool's head
-  axis is the key/value heads', `grp` = query heads / key/value heads), and
-  bfloat16 pools: the chunk kernel again, the `grp` query heads of a
-  key/value head as ROWS of its products. A lane's C positions are C * grp
-  rows a key/value head (row r at position pos + r // grp); a decode row is
-  grp rows at one position, padded to a sublane tile. One grid step a slot
-  or lane; a block's K and V are fetched once for the whole group.
+| query positions a slot | pools                          | lowering         |
+| ---------------------- | ------------------------------ | ---------------- |
+| 1                      | float32, as many heads as q    | `_paged_kernel`  |
+| 1                      | grouped heads, or bfloat16     | `_decode_kernel` |
+| a multiple of 8        | float32 or bfloat16, any group | `_chunk_kernel`  |
+| anything else; int8 pools; a CPU                        | the composite    |
+
+- the decode kernel of equal heads (a TPU, float32 pools whose block is
+  lane-dense, one query position): a Pallas kernel with the block table
+  and the positions scalar-prefetched. One grid step a slot; the slot's
+  LIVE blocks (`pos // block_size + 1` of them) are DMA'd straight from the
+  pool in HBM, double-buffered, the next block (or the next slot's first)
+  in flight while this one is scored on the VPU in float32; blocks past
+  the position cost nothing; online softmax in float32 across the blocks;
+  the tail of the last block is masked by position. Nothing of pool shape
+  is read or written. (A BlockSpec pipeline over (slot, logical block)
+  with the dead blocks clamped to one index computes the same, and spends
+  0.1 ms a call on the 1024 dead steps of an idle tick where this spends
+  0.008: PERF.md, PR 25.)
+- the decode kernel of grouped queries (fewer key/value heads than query
+  heads: the pool's head axis is the key/value heads', `grp` = query heads
+  / key/value heads) and of bfloat16 pools, one query position: one grid
+  step a slot, the slot's live blocks `_DECODE_KEY_ROWS` pool rows a step,
+  double-buffered, ALL key/value heads of a step in one batched product on
+  the MXU (bf16 operands, float32 accumulation, float32 online softmax).
+  **The block-diagonal query layout**: a pool row holds per_row = 128 // dh
+  positions, one a lane segment; the `grp` query heads of a key/value head
+  are padded to `rp` rows (a sublane tile) and laid `[per_row * rp, 128]`,
+  rows g*rp .. g*rp+rp-1 carrying the queries on the lanes of segment g
+  and zeros elsewhere, so ONE product scores every position of every row
+  (no lane is sliced, no tile goes through the MXU twice) and ONE product
+  weighs V; the result `[nkv, rp, 128]` holds segment g's share of the
+  context on segment g's lanes and the caller adds the segments.
+  **The cross-slot prefetch**: during a slot's last step the first step of
+  the next LIVE slot is in flight (a parity scratch says which buffer it
+  lands in); an idle slot (no real row, or position 0 on the null block,
+  where the pager points an idle slot) fetches nothing, returns zeros and
+  costs a grid step. (Before PR 40 these rows took the chunk kernel, eight
+  rows a head in a loop over the heads: a tenth of the read's roofline.)
+- the chunk kernel (G query rows a slot with G a multiple of 8: a prefill
+  lane of the mixed tick feeds a chunk of its prompt): one grid step a
+  lane; the lane's live blocks, up to position pos + rows - 1, are DMA'd
+  128 key rows a step, double-buffered, and scored on the MXU a head at a
+  time (bf16 operands, float32 accumulation, float32 online softmax); row
+  g attends positions 0..pos+g, so the shared prefix's blocks, earlier
+  chunks and the chunk itself are one causal read. A lane with no rows
+  fetches nothing and returns zeros. Under grouped queries the `grp` query
+  heads of a key/value head are ROWS of its products: a lane's C positions
+  are C * grp rows a key/value head (row r at position pos + r // grp); a
+  block's K and V are fetched once for the whole group.
 - the composite (everything else: a CPU, int8 pools with their scale pools,
   a verify window of a few positions): gather the table view (a key/value
   head repeated over its group) and run `decode_attention._decode_xla`, the
@@ -64,6 +88,7 @@ import jax
 import jax.numpy as jnp
 
 from ..framework.registry import register_op
+from ..ops.pallas_kernels import _NN, _NT    # [heads, ., .] a @ b, a @ b^T
 from ..ops.tensor_ops import POOL_LANES as _LANES
 from .decode_attention import _auto_backend, _decode_xla
 
@@ -72,6 +97,7 @@ _M_INIT = -1e30    # running max before the first block (finite: no inf-inf)
 
 KERNEL, COMPOSITE = "kernel", "composite"
 _CHUNK_ROWS = 8    # the chunk kernel's query rows come in whole sublane tiles
+_DECODE_KEY_ROWS = 256   # pool rows the grouped decode kernel scores a step
 
 
 def paged_attention_lowering(pool_dtype, pool_lanes, n_query, d_head,
@@ -425,6 +451,189 @@ def _chunk_pallas(q4, k_pool, v_pool, btab, pos, rows, scale, interpret,
     return out.reshape(n_lanes, nh, c, per_row, dh).sum(axis=3)
 
 
+def _decode_kernel(btab_ref, pos_ref, rows_ref, q_ref, k_hbm, v_hbm, o_ref,
+                   kbuf, vbuf, sem, parity_ref, *, n_slots, n_logical,
+                   block_size, d_head, group, mxu_dtype):
+    """One grid step = one slot's ONE query position under grouped queries
+    (or bfloat16 pools). The slot's live blocks come `group` at a time into
+    one of two VMEM buffers, live blocks only, the next group in flight
+    while this one is scored; during a slot's last group the first group of
+    the next LIVE slot is in flight (`parity_ref` says which buffer it lands
+    in). A slot is idle when it has no real row, or sits at position 0 on the
+    null block (physical block 0: where the pager points an idle slot); it
+    fetches nothing, returns zeros and costs a grid step.
+
+    A group is [nkv, group*R, 128] as fetched: row ρ holds per_row
+    positions, dh lanes each. The queries are [nkv, per_row*rp, 128],
+    block-diagonal: rows g*rp .. g*rp+rp-1 carry the rp query rows of a
+    key/value head on the lanes of segment g and zeros elsewhere, so ONE
+    product batched over the key/value heads scores every position of every
+    row (query row g*rp+r against key row ρ is position ρ*per_row+g), one
+    softmax update runs on the [nkv, per_row*rp, group*R] scores, and one
+    product weighs V: rows g*rp.. of the context are right on the lanes of
+    segment g (the other lanes are dropped at the end). m, l and the context
+    are loop values; the segments' running maxima differ and are reconciled
+    once a slot. The result is [nkv, rp, 128], segment g holding the share
+    of the positions of segment g: the caller adds the segments. The buffers
+    are zeroed at the first step, so the rows past a slot's last live block
+    hold zeros or an earlier group's rows: finite, and masked by position."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    s = pl.program_id(0)
+    per_row = _LANES // d_head
+    n_rows = block_size // per_row
+    nkv, q_rows = q_ref.shape[1], q_ref.shape[2]
+    rp = q_rows // per_row
+    key_rows = group * n_rows
+
+    def live_blocks(slot):
+        pos = pos_ref[slot]
+        idle = (rows_ref[slot] <= 0) | (
+            (pos == 0) & (btab_ref[slot * n_logical] == 0))
+        return jnp.where(idle, 0, jax.lax.div(pos, block_size) + 1)
+
+    def first_live(slot):
+        """The first live slot at or after `slot`; n_slots when none is."""
+        return jax.lax.while_loop(
+            lambda t: (t < n_slots)
+            & (live_blocks(jnp.minimum(t, n_slots - 1)) == 0),
+            lambda t: t + 1, slot)
+
+    def fetch(slot, step, buf, wait):
+        """Start (or wait for) the DMAs of the live blocks of group `step`
+        of `slot` into buffer `buf`."""
+        def one(g, carry):
+            blk = btab_ref[slot * n_logical + step * group + g]
+            dst = pl.ds(pl.multiple_of(g * n_rows, n_rows), n_rows)
+            for hbm, vmem, kv in ((k_hbm, kbuf, 0), (v_hbm, vbuf, 1)):
+                cp = pltpu.make_async_copy(
+                    hbm.at[blk], vmem.at[buf, :, dst, :], sem.at[kv, buf, g])
+                cp.wait() if wait else cp.start()
+            return carry
+        jax.lax.fori_loop(
+            0, jnp.minimum(group, live_blocks(slot) - step * group), one, 0)
+
+    @pl.when(s == 0)
+    def _():
+        kbuf[...] = jnp.zeros(kbuf.shape, kbuf.dtype)
+        vbuf[...] = jnp.zeros(vbuf.shape, vbuf.dtype)
+        parity_ref[0] = 0
+        first = first_live(0)
+
+        @pl.when(first < n_slots)
+        def _():
+            fetch(first, 0, 0, wait=False)
+
+    n_live = live_blocks(s)
+
+    @pl.when(n_live == 0)
+    def _():
+        o_ref[0] = jnp.zeros(o_ref.shape[1:], o_ref.dtype)
+
+    @pl.when(n_live > 0)
+    def _():
+        base = parity_ref[0]      # the buffer this slot's first group is in
+        pos = pos_ref[s]
+        n_steps = jax.lax.div(n_live + group - 1, group)
+        nxt_slot = first_live(s + 1)
+        q = q_ref[0]                                 # [nkv, q_rows, 128]
+        # query row i scores the positions of segment i // rp of a key row
+        key_off = (jax.lax.broadcasted_iota(
+            jnp.int32, (1, q_rows, key_rows), 2) * per_row
+            + jax.lax.broadcasted_iota(
+                jnp.int32, (1, q_rows, key_rows), 1) // rp)
+
+        def body(j, carry):
+            m, l, acc = carry
+            buf = jax.lax.rem(base + j, 2)
+            last = j + 1 >= n_steps
+            to_slot = jnp.where(last, nxt_slot, s)
+
+            @pl.when(to_slot < n_slots)  # the next group, or the next slot's
+            def _():
+                fetch(to_slot, jnp.where(last, 0, j + 1), 1 - buf, wait=False)
+
+            fetch(s, j, buf, wait=True)
+            sc = jax.lax.dot_general(
+                q, kbuf[buf].astype(mxu_dtype), _NT,
+                preferred_element_type=jnp.float32)  # [nkv, q_rows, keys]
+            sc = jnp.where(key_off <= pos - j * (group * block_size), sc,
+                           _MASKED)
+            m_new = jnp.maximum(m, jnp.max(sc, axis=2, keepdims=True))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(sc - m_new)
+            l = alpha * l + jnp.sum(p, axis=2, keepdims=True)
+            acc = alpha * acc + jax.lax.dot_general(
+                p.astype(mxu_dtype), vbuf[buf].astype(mxu_dtype), _NN,
+                preferred_element_type=jnp.float32)  # [nkv, q_rows, 128]
+            return m_new, l, acc
+
+        m, l, acc = jax.lax.fori_loop(
+            0, n_steps, body,
+            (jnp.full((nkv, q_rows, 1), _M_INIT, jnp.float32),
+             jnp.zeros((nkv, q_rows, 1), jnp.float32),
+             jnp.zeros((nkv, q_rows, _LANES), jnp.float32)))
+        parity_ref[0] = jax.lax.rem(base + n_steps, 2)
+        # a segment with no visible position has m = _MASKED: weight 0
+        segs = [slice(g * rp, (g + 1) * rp) for g in range(per_row)]
+        m_all = functools.reduce(jnp.maximum, (m[:, sl] for sl in segs))
+        w = [jnp.exp(m[:, sl] - m_all) for sl in segs]
+        l_all = sum(l[:, sl] * wg for sl, wg in zip(segs, w))
+        seg = jax.lax.broadcasted_iota(jnp.int32, (1, 1, _LANES), 2) // d_head
+        ctx = acc[:, segs[-1]] * w[-1]
+        for g in range(per_row - 1):
+            ctx = jnp.where(seg == g, acc[:, segs[g]] * w[g], ctx)
+        o_ref[0] = (ctx / l_all).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("d_head", "interpret"))
+def _decode_pallas(q2, k_pool, v_pool, btab, pos, rows, d_head, interpret):
+    """q2 [S, nkv, per_row*rp, 128] float32, scaled and block-diagonal
+    (`_grouped_decode`), pools [NB, nkv, R, 128] → [S, nkv, rp, 128]
+    float32, the context's share a lane segment. The operands of both
+    products are bf16 on the chip and float32 interpreted, as the chunk
+    kernel's."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    n_slots, nkv, q_rows, _ = q2.shape
+    n_rows = k_pool.shape[2]
+    per_row = _LANES // d_head
+    n_logical = btab.shape[1]
+    group = max(1, min(_DECODE_KEY_ROWS // n_rows, n_logical))
+    mxu_dtype = jnp.float32 if interpret else jnp.bfloat16
+    with jax.named_scope("paged_gqa_attention"):
+        return pl.pallas_call(
+            functools.partial(
+                _decode_kernel, n_slots=n_slots, n_logical=n_logical,
+                block_size=n_rows * per_row, d_head=d_head, group=group,
+                mxu_dtype=mxu_dtype),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=3,
+                grid=(n_slots,),
+                in_specs=[pl.BlockSpec((1, nkv, q_rows, _LANES),
+                                       lambda i, *_: (i, 0, 0, 0)),
+                          pl.BlockSpec(memory_space=pl.ANY),
+                          pl.BlockSpec(memory_space=pl.ANY)],
+                out_specs=pl.BlockSpec((1, nkv, q_rows // per_row, _LANES),
+                                       lambda i, *_: (i, 0, 0, 0)),
+                scratch_shapes=[
+                    pltpu.VMEM((2, nkv, group * n_rows, _LANES), k_pool.dtype),
+                    pltpu.VMEM((2, nkv, group * n_rows, _LANES), v_pool.dtype),
+                    pltpu.SemaphoreType.DMA((2, 2, group)),
+                    pltpu.SMEM((1,), jnp.int32)]),
+            out_shape=jax.ShapeDtypeStruct(
+                (n_slots, nkv, q_rows // per_row, _LANES), jnp.float32),
+            # slots run in order: a slot's first group is fetched while the
+            # live slot before it scores its last
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",),
+                vmem_limit_bytes=32 * 1024 * 1024),
+            interpret=interpret,
+        )(btab.reshape(-1), pos, rows, q2.astype(mxu_dtype), k_pool, v_pool)
+
+
 def paged_decode_attention(q, k_pool, v_pool, btab, pos, num_heads,
                            scale=1.0, backend=None, k_scale=None,
                            v_scale=None, rows=None):
@@ -443,7 +652,10 @@ def paged_decode_attention(q, k_pool, v_pool, btab, pos, num_heads,
     whatever they hold. `rows` [S] (optional; G when absent) is how many of
     a slot's G rows are real: the others, and every row of a slot with
     none, return values nobody reads (finite), and only the blocks up to
-    position pos + rows - 1 need be mapped. Returns [S, G, nh*dh]."""
+    position pos + rows - 1 need be mapped. Physical block 0 is the pager's
+    null block, never a request's: a slot at position 0 whose first logical
+    block is block 0 is an idle slot, and the grouped decode kernel returns
+    it zeros without fetching. Returns [S, G, nh*dh]."""
     s, g, h = q.shape
     dh = h // num_heads
     btab = btab.astype(jnp.int32)
@@ -455,42 +667,68 @@ def paged_decode_attention(q, k_pool, v_pool, btab, pos, num_heads,
     nkv = k_pool.shape[1]
     if lowering == KERNEL and (nkv != num_heads
                                or k_pool.dtype != jnp.float32):
-        return _grouped_kernel(q, k_pool, v_pool, btab, pos, rows, num_heads,
-                               float(scale), interpret)
+        grouped = _grouped_decode if g == 1 else _grouped_lanes
+        return grouped(q, k_pool, v_pool, btab, pos, rows, num_heads,
+                       float(scale), interpret)
     q4 = q.reshape(s, g, num_heads, dh).transpose(0, 2, 1, 3)
     if lowering == KERNEL and g == 1:
         out = _paged_pallas(q4, k_pool, v_pool, btab, pos, float(scale),
                             interpret=interpret)
     elif lowering == KERNEL:
-        rows = (jnp.full((s,), g, jnp.int32) if rows is None
-                else rows.reshape(-1).astype(jnp.int32))
-        out = _chunk_pallas(q4, k_pool, v_pool, btab, pos, rows,
-                            float(scale), interpret=interpret)
+        out = _chunk_pallas(q4, k_pool, v_pool, btab, pos,
+                            _real_rows(rows, s, g), float(scale),
+                            interpret=interpret)
     else:
         out = _paged_composite(q4, k_pool, v_pool, btab, pos, float(scale),
                                k_scale, v_scale)
     return out.transpose(0, 2, 1, 3).reshape(s, g, h)
 
 
-def _grouped_kernel(q, k_pool, v_pool, btab, pos, rows, num_heads, scale,
-                    interpret):
+def _real_rows(rows, s, g):
+    """`rows` as the kernels take it: [S] int32, all `g` real when absent."""
+    return (jnp.full((s,), g, jnp.int32) if rows is None
+            else rows.reshape(-1).astype(jnp.int32))
+
+
+def _grouped_lanes(q, k_pool, v_pool, btab, pos, rows, num_heads, scale,
+                   interpret):
     """The chunk kernel under grouped queries (and bfloat16 pools): the
     `grp` query heads of a key/value head, position-major, as the rows of
-    one product. A decode row's grp rows are padded to a sublane tile."""
+    one product."""
     s, g, h = q.shape
     nkv = k_pool.shape[1]
     grp, dh = num_heads // nkv, h // num_heads
-    per_pos = grp if g > 1 else -(-grp // _CHUNK_ROWS) * _CHUNK_ROWS
-    q5 = q.reshape(s, g, nkv, grp, dh).astype(jnp.float32)
-    if per_pos != grp:
-        q5 = jnp.pad(q5, ((0, 0),) * 3 + ((0, per_pos - grp), (0, 0)))
-    q4 = q5.transpose(0, 2, 1, 3, 4).reshape(s, nkv, g * per_pos, dh)
-    rows = (jnp.full((s,), g, jnp.int32) if rows is None
-            else rows.reshape(-1).astype(jnp.int32))
-    out = _chunk_pallas(q4, k_pool, v_pool, btab, pos, rows, scale,
-                        interpret=interpret, rows_per_pos=per_pos)
-    out = out.reshape(s, nkv, g, per_pos, dh)[:, :, :, :grp]
+    q4 = (q.reshape(s, g, nkv, grp, dh).astype(jnp.float32)
+          .transpose(0, 2, 1, 3, 4).reshape(s, nkv, g * grp, dh))
+    out = _chunk_pallas(q4, k_pool, v_pool, btab, pos, _real_rows(rows, s, g),
+                        scale, interpret=interpret, rows_per_pos=grp)
+    out = out.reshape(s, nkv, g, grp, dh)
     return out.transpose(0, 2, 1, 3, 4).reshape(s, g, h).astype(q.dtype)
+
+
+def _grouped_decode(q, k_pool, v_pool, btab, pos, rows, num_heads, scale,
+                    interpret):
+    """The decode kernel of grouped queries (and bfloat16 pools): a slot's
+    one position, the `grp` query heads of a key/value head padded to a
+    sublane tile and laid block-diagonally over the lane segments
+    (`_decode_kernel`); the kernel's result holds each segment's share of
+    the context on that segment's lanes, added here."""
+    s, _, h = q.shape
+    nkv = k_pool.shape[1]
+    grp, dh = num_heads // nkv, h // num_heads
+    per_row = _LANES // dh
+    rp = -(-grp // _CHUNK_ROWS) * _CHUNK_ROWS
+    q4 = q.reshape(s, nkv, grp, dh).astype(jnp.float32) * scale
+    q4 = jnp.pad(q4, ((0, 0), (0, 0), (0, rp - grp), (0, 0)))
+    # rows g*rp .. g*rp+rp-1 hold the queries on the lanes of segment g and
+    # zeros elsewhere: one product scores every position of a pool row
+    q2 = (q4[:, :, None, :, None, :]
+          * jnp.eye(per_row, dtype=q4.dtype)[:, None, :, None])
+    q2 = q2.reshape(s, nkv, per_row * rp, _LANES)
+    out = _decode_pallas(q2, k_pool, v_pool, btab, pos, _real_rows(rows, s, 1),
+                         d_head=dh, interpret=interpret)   # [S, nkv, rp, 128]
+    out = out.reshape(s, nkv, rp, per_row, dh).sum(axis=3)[:, :, :grp]
+    return out.reshape(s, 1, h).astype(q.dtype)
 
 
 @register_op("paged_decode_attention", stop_gradient=True)

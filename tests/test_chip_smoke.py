@@ -57,7 +57,11 @@ def test_hybrid_serving_phase():
         vocab=97, d_model=64, d_inner=96, num_heads=8, num_kv_heads=2,
         d_expert=256, n_experts=8, top_k=2, n_slots=4, block_size=8,
         n_blocks=40, max_len=64, preamble=24, turns=(5, 11, 3), max_new=6,
-        expect_lowering="composite")
+        expect_lowering="composite",
+        # the decode read alone, interpreted: heads of 64 in blocks of 64
+        decode_read=dict(n_slots=8, n_blocks=24, block_size=64, d_head=64,
+                         blocks_per_req=10, backend="pallas_interpret"))
+    assert 0 < out["decode_read_max_rel_err"] < 1e-5
     assert out["conv_state"]["restores"] == 2
     assert out["conv_state"]["snapshots"] >= 3 and out["tokens_out"] == 18
     assert out["zeroed_state_differs_at"] < 6   # the planted fault is refused

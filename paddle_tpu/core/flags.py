@@ -209,7 +209,8 @@ define_int("trace_ring", 262144,
            "Capacity of the span ring buffer (observability/tracing.py). "
            "Oldest spans are overwritten; the buffer is preallocated so "
            "recording never allocates on the hot path. A served tick "
-           "records about eight spans: the default holds a minute of a "
+           "records seven or eight live spans, and a benchmark window about "
+           "fourteen records a tick: the default holds half a minute of a "
            "2 ms tick (a full ring keeps ~60 MB of span records alive).")
 # (num_iteration_per_drop_scope lives on ExecutionStrategy for API parity;
 # the functional executor has no per-iteration kid scopes to drop)

@@ -130,7 +130,8 @@ class GenRequest:
                  "submitted_pc", "admitted_at", "admitted_pc",
                  "first_token_pc", "done_pc", "sent_at", "sent_pc",
                  "defer_transport", "table", "shared_len",
-                 "spec_draft_s", "spec_verify_s", "error")
+                 "spec_draft_s", "spec_verify_s", "error",
+                 "admitted_tick", "ticks_to_first", "lane_wait_ticks")
 
     def __init__(self, rid, prompt, max_new, eos_id=None, on_done=None,
                  request_id: Optional[str] = None,
@@ -156,6 +157,15 @@ class GenRequest:
         self.sent_at: Optional[float] = None
         self.sent_pc: Optional[float] = None
         self.on_done = on_done
+        #: the way to the first token, counted where it is scheduled: the
+        #: engine's tick count at admission; then the engine ticks from
+        #: the one that admitted the request to the one that emitted its
+        #: first token, inclusive (1: the tick it was admitted before);
+        #: and the ticks of those in which it was in prefill and got no
+        #: lane (chunked paged engine; 0 anywhere else)
+        self.admitted_tick = 0
+        self.ticks_to_first = 0
+        self.lane_wait_ticks = 0
         #: paged-KV engine state: the request's BlockTable, and how many
         #: leading prompt positions were satisfied from the prefix cache
         #: (prefill starts at `shared_len` instead of 0). None/0 on the
@@ -244,6 +254,13 @@ class ContinuousBatchingEngine:
     parameters are initialized by this engine's own startup program, so a
     fresh engine also runs standalone (random weights — tests, benches).
     """
+
+    #: one tick in this many realizes its ids in two parts, under
+    #: `engine/device_wait` and `engine/copy_back`: enough for a window's
+    #: medians (800 of 13,000 ticks), too few to move one (two parts cost
+    #: the thread a second sleep and wake-up, 0.08 ms a tick on a TPU host:
+    #: PERF.md section 6, PR 41)
+    WAIT_SPLIT_EVERY = 16
 
     #: how a prompt is consumed (`stats()["prefill"]`): one token a tick
     #: here; the paged engine says "chunked" when it builds its mixed tick
@@ -676,6 +693,7 @@ class ContinuousBatchingEngine:
                 req.slot = slot
                 req.admitted_at = time.time()
                 req.admitted_pc = time.perf_counter()
+                req.admitted_tick = self.n_ticks
                 self._active[slot] = req
                 admitted.append(req)
             if _tracing.enabled():
@@ -730,6 +748,9 @@ class ContinuousBatchingEngine:
         if req.first_token_at is None:
             req.first_token_at = time.time()
             req.first_token_pc = time.perf_counter()
+            # the tick that emits it is counted already (a speculative
+            # round is counted after it emits: `step` adds its one)
+            req.ticks_to_first = self.n_ticks - req.admitted_tick
         req.tokens.append(t)
         self.tokens_out += 1
         self._m_tokens.inc()
@@ -756,10 +777,15 @@ class ContinuousBatchingEngine:
         if self.spec is not None and all(
                 self._spec_capable(r, self.spec.cfg.gamma + 1)
                 for r in active.values()):
+            no_token = [r for r in active.values()
+                        if r.first_token_pc is None]
             finished = self.spec.round(active)
             with _tracing.span("tick", "engine/commit"):
                 self._m_ticks.inc()
                 self.n_ticks += 1
+                for r in no_token:     # the round itself, counted just now
+                    if r.first_token_pc is not None:
+                        r.ticks_to_first += 1
                 self.last_tick_at = time.time()
                 self._stamp_kv_watermarks(active)
                 self.busy_slot_ticks += len(active)
@@ -801,7 +827,7 @@ class ContinuousBatchingEngine:
         span = _tracing.span
         t0 = time.perf_counter()
         with span("tick", "engine/tick") as tick:
-            with span("dispatch", "engine/dispatch") as dispatch:
+            with span("dispatch", "engine/dispatch"):
                 active = self._pre_tick(active)
                 with span("dispatch", "engine/fill_feeds"):
                     self._fill_tick_feeds(active)
@@ -811,7 +837,6 @@ class ContinuousBatchingEngine:
                     self.target_forwards += 1
                     launch.attrs["host_args"] = self._bound_steps[
                         self._target_state_owner].host_args
-                dispatch.attrs["active"] = len(active)
                 td = time.perf_counter()       # async dispatch returned
             if _tracing.enabled():
                 # counted here, in the scheduler, while the device runs: a
@@ -828,8 +853,22 @@ class ContinuousBatchingEngine:
                         1 for r in active.values()
                         if r.fed < len(r.prompt) - 1)
             with span("tick", "engine/wait"):
-                ids = np.asarray(fetches[0])   # realization barrier: the
-                #                    next tick's feed depends on it
+                # realization barrier: the next tick's feed depends on it.
+                # ONE `np.asarray`; on a sampled tick the same barrier in
+                # its two parts: the copy back enqueued first thing, as
+                # `np.asarray` alone does (0.05-0.1 ms of the host's own
+                # work on a TPU, the device busy through it), then until
+                # the thread knows the step's last op is done; and the
+                # rest of the ids' way back after it
+                if (self.n_ticks % self.WAIT_SPLIT_EVERY == 0
+                        and _tracing.enabled()):
+                    with span("tick", "engine/device_wait"):
+                        fetches[0].copy_to_host_async()
+                        fetches[0].block_until_ready()
+                    with span("tick", "engine/copy_back"):
+                        ids = np.asarray(fetches[0])
+                else:
+                    ids = np.asarray(fetches[0])
             self._note_tick_counts(tick, ids)
         with span("tick", "engine/commit"):
             self._m_dispatch.observe(td - t0)
@@ -888,7 +927,9 @@ class ContinuousBatchingEngine:
         _tracing.record_span("request", "request/prefill",
                              req.admitted_pc, first,
                              request_id=req.request_id, slot=req.slot,
-                             prompt_len=len(req.prompt))
+                             prompt_len=len(req.prompt),
+                             ticks=req.ticks_to_first,
+                             lane_wait_ticks=req.lane_wait_ticks)
         _tracing.record_span("request", "request/decode", first,
                              req.done_pc, request_id=req.request_id,
                              slot=req.slot, new_tokens=len(req.tokens))

@@ -1011,12 +1011,16 @@ class PagedKVEngine(ContinuousBatchingEngine):
         order (`prefilling` comes in `_active`'s order, which is it), and
         fill the lanes' feeds: each lane its request's next `chunk_tokens`
         prompt tokens (fewer on the last chunk), from a block boundary. A
-        slot in prefill beyond the lanes waits this tick out. Counts what
-        the lanes take (`prefill`, `prefill_tokens`; `kv_blocks` adds the
-        blocks their reads span)."""
+        slot in prefill beyond the lanes waits this tick out, and the wait
+        is counted on it (`lane_wait_ticks`) and on the tick
+        (`lane_waiting`). Counts what the lanes take (`prefill`,
+        `prefill_tokens`; `kv_blocks` adds the blocks their reads span)."""
         attrs = self._tick_attrs
         lanes = []
         tokens = snapshots = 0
+        for i in range(self.n_lanes, len(prefilling)):
+            prefilling[i].lane_wait_ticks += 1
+        attrs["lane_waiting"] = max(len(prefilling) - self.n_lanes, 0)
         if prefilling:
             lf = self._lane_feeds
             for a in lf.values():
@@ -1051,8 +1055,10 @@ class PagedKVEngine(ContinuousBatchingEngine):
     def _launch_tick(self):
         # a tick with a slot in prefill is the mixed program (the decode
         # rows ride in it); any other is the decode tick, unchanged
+        mixed = bool(self._lanes)
+        self._tick_attrs["mixed"] = int(mixed)
         return (self._run_bound_step(self._mixed_step, "mixed")
-                if self._lanes else super()._launch_tick())
+                if mixed else super()._launch_tick())
 
     def _note_tick_counts(self, tick, ids: np.ndarray):
         # a routed model's ticks end in one count a (layer, held expert):

@@ -629,6 +629,61 @@ def _check_grouped_decode(n_slots, n_blocks, block_size, num_heads,
     return err
 
 
+def _check_latent_decode(n_slots, n_live, n_blocks, block_size, num_heads,
+                         row_lanes, v_width, blocks_per_req, backend, timing):
+    """The latent decode read (one position a slot, every head on the slot's
+    latent rows in a bfloat16 pool: fusion/latent_attention.py
+    `_latent_decode_kernel`) against its composite: `n_live` slots spread
+    over the grid, each near the end of its table (the first one on its last
+    row), the others idle on the null block; a permuted table. Returns the
+    error and, on a TPU, the milliseconds of one call."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.fusion import (latent_attention_lowering,
+                                   latent_paged_attention)
+
+    rng = np.random.RandomState(17)
+    span = blocks_per_req * block_size
+    _check(latent_attention_lowering(row_lanes, v_width, num_heads, 1,
+                                     backend) == "kernel",
+           f"rows of {row_lanes} lanes do not take the latent kernel")
+    pool = jnp.asarray(rng.randn(n_blocks, 1, block_size, row_lanes) * 0.5,
+                       jnp.bfloat16)
+    q = jnp.asarray(rng.randn(n_slots, 1, num_heads * row_lanes),
+                    jnp.bfloat16)
+    live = np.linspace(1, n_slots - 1, n_live).astype(int)
+    pos = np.zeros((n_slots,), np.int32)
+    pos[live] = rng.randint(span - 2 * block_size, span, (n_live,))
+    pos[live[0]] = span - 1
+    btab = np.zeros((n_slots, blocks_per_req), np.int32)
+    for s in live:
+        btab[s] = rng.randint(1, n_blocks, blocks_per_req)
+    btab, pos = jnp.asarray(btab), jnp.asarray(pos)
+
+    def run(be):
+        return jax.jit(lambda q, t, p: latent_paged_attention(
+            q, pool, t, p, num_heads, v_width, row_lanes ** -0.5, backend=be))
+    read = run(backend)
+    got, c = _timed_first(read, q, btab, pos)
+    timing["compile_s"] += c
+    t0 = time.time()
+    with jax.default_matmul_precision("highest"):
+        ref = run("xla")(q[live], btab[live], pos[live])
+    _check(bool(jnp.isfinite(got).all()), "latent decode read: not finite")
+    err = _rel_err(got[live], ref)
+    call_ms = None
+    if jax.default_backend() == "tpu":
+        t1 = time.perf_counter()
+        for _ in range(20):
+            out = read(q, btab, pos)
+        out.block_until_ready()
+        call_ms = (time.perf_counter() - t1) / 20 * 1e3
+    timing["run_s"] += time.time() - t0
+    # bf16 operands on the MXU against float32 at the highest precision
+    _check(err <= TOL_BF16, f"latent decode read: error {err}")
+    return err, call_ms
+
+
 def _check_paged_chunk(n_lanes, chunk, n_blocks, block_size, num_heads,
                        d_head, blocks_per_req, backend, timing):
     """The chunk-attention kernel (a prefill lane's read of the mixed tick)
@@ -731,7 +786,8 @@ def phase_kernels(backend="pallas",
                                 ((64, 16, 128, 64), False),
                                 ((1, 8, 8192, 128), True)),
                   decode=(16, 64, 640, 16), recurrent=(64, 64, 256),
-                  paged=(16, 1024, 16, 16, 64, 64), chunk=(2, 128)):
+                  paged=(16, 1024, 16, 16, 64, 64), chunk=(2, 128),
+                  latent=(32, 5, 2048, 64, 64, 640, 512, 272)):
     """Every Pallas kernel the package selects by default on a TPU, called
     directly, compiled by Mosaic, run, and compared with its own composite.
     flash_shapes = ([B, H, T, D], causal): the training cells' shapes (the
@@ -741,7 +797,10 @@ def phase_kernels(backend="pallas",
     decode = (heads, d_head, span, rows); recurrent = (batch, steps, hidden);
     paged = (slots, pool blocks, block size, heads, d_head, blocks a
     request): the serving benchmark's tick; chunk = (lanes, tokens a
-    lane) of its mixed tick, over the same pools."""
+    lane) of its mixed tick, over the same pools; latent = (slots, live
+    slots, pool blocks, block size, heads, row lanes, value lanes, blocks a
+    request): the document cell's latent decode read at its published
+    widths, five slots live at 17k positions."""
     import jax
     import jax.numpy as jnp
     from paddle_tpu.ops.pallas_kernels import _plan_for
@@ -758,14 +817,19 @@ def phase_kernels(backend="pallas",
     errs["paged_decode"], paged_abs = _check_paged(*paged, backend, timing)
     errs["paged_chunk"] = _check_paged_chunk(*chunk, *paged[1:], backend,
                                              timing)
+    errs["latent_decode"], latent_ms = _check_latent_decode(*latent, backend,
+                                                            timing)
     for kind in ("lstm", "gru"):
         errs["fused_" + kind] = _check_recurrent(kind, *recurrent, backend,
                                                  timing)
-    return {"compile_s": round(timing["compile_s"], 2),
-            "run_s": round(timing["run_s"], 2), "backend": backend,
-            "max_rel_err": {k: float("%.2e" % v) for k, v in errs.items()},
-            "flash_plans": plans,
-            "paged_decode_max_abs_diff": float("%.2e" % paged_abs)}
+    out = {"compile_s": round(timing["compile_s"], 2),
+           "run_s": round(timing["run_s"], 2), "backend": backend,
+           "max_rel_err": {k: float("%.2e" % v) for k, v in errs.items()},
+           "flash_plans": plans,
+           "paged_decode_max_abs_diff": float("%.2e" % paged_abs)}
+    if latent_ms is not None:       # a TPU's: a call with its dispatch
+        out["latent_decode_call_ms"] = round(latent_ms, 4)
+    return out
 
 
 def _multichip_ring(devices, ring_shape):

@@ -80,11 +80,13 @@ def test_kernels_phase_through_the_interpreter():
         flash_shapes=(((1, 2, 256, 64), True), ((2, 2, 128, 64), False)),
         decode=(4, 64, 1408, 2), recurrent=(8, 4, 128),
         paged=(5, 40, 8, 4, 16, 6),     # 8 rows of 16: one 128-lane row
-        chunk=(2, 16))
+        chunk=(2, 16),
+        latent=(4, 2, 24, 16, 8, 256, 128, 6))
     assert set(out["max_rel_err"]) == {
         "flash_1x2x256x64", "flash_1x2x256x64_seg", "flash_2x2x128x64_full",
         "flash_2x2x128x64_full_seg", "decode_T1408",
-        "paged_decode", "paged_chunk", "fused_lstm", "fused_gru"}
+        "paged_decode", "paged_chunk", "latent_decode", "fused_lstm",
+        "fused_gru"}
     assert out["flash_plans"]["flash_1x2x256x64"] == [
         "flash_fwd_resident_q256_k256_rows2",
         "flash_bwd_resident_q256_k256_rows2"]
@@ -92,6 +94,9 @@ def test_kernels_phase_through_the_interpreter():
         "flash_fwd_resident_q128_k128_rows2",
         "flash_bwd_resident_q128_k128_rows2"]
     assert out["max_rel_err"]["paged_chunk"] <= 1e-5
+    # bfloat16 rows in the pool, float32 products interpreted
+    assert out["max_rel_err"]["latent_decode"] <= 2.0 ** -6
+    assert "latent_decode_call_ms" not in out        # a TPU's number
     assert out["paged_decode_max_abs_diff"] <= 1e-5
 
 

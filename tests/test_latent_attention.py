@@ -1,16 +1,18 @@
 """Latent attention on the latent itself (ISSUE 36): the absorbed read over
 the padded latent rows equals attention with K and V expanded through
-`kv_b_proj`; the kernel (interpreted) equals the composite; YaRN's table
-equals the reference's angles past the original length; the pool is written
-and read through a block table; a prefix hit and a miss give the same
-logits."""
+`kv_b_proj`; the kernels (interpreted: the lanes' body and the decode body,
+ISSUE 42) equal the composite; YaRN's table equals the reference's angles
+past the original length; the pool is written and read through a block
+table; a prefix hit and a miss give the same logits."""
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 import axk1_tiny as T
 from axk1_tiny import axk1, ref  # noqa: F401
+from test_grouped_paged_attention import _pallas_calls
 from paddle_tpu.fusion import latent_attention as la
 from paddle_tpu.models import transformer
 from paddle_tpu.models.decoder_spec import LatentSpec, RopeSpec
@@ -23,10 +25,10 @@ W = LAT.row_lanes                      # 192 values padded to 256
 BS, NLB, NB = 16, 6, 24
 
 
-def _pool_and_table(rng, n_slots):
-    rows = rng.normal(size=(NB, 1, BS, W)).astype(np.float32)
+def _pool_and_table(rng, n_slots, bs=BS, nlb=NLB, nb=NB):
+    rows = rng.normal(size=(nb, 1, bs, W)).astype(np.float32)
     rows[..., LAT.row_values:] = 0.0
-    btab = np.stack([rng.permutation(np.arange(1, NB))[:NLB]
+    btab = np.stack([rng.permutation(np.arange(1, nb))[:nlb]
                      for _ in range(n_slots)])
     return jnp.asarray(rows), jnp.asarray(btab)
 
@@ -50,15 +52,18 @@ def test_the_row_is_padded_to_whole_lanes_and_says_so():
                                                rel=1e-4)
 
 
-@pytest.mark.parametrize("g, pos, rows", [
-    (1, [5, 40, 0], None), (16, [16, 32, 0], [16, 9, 0])])
-def test_absorbed_read_equals_expanded_attention(g, pos, rows):
+@pytest.mark.parametrize("g, pos, rows, table", [
+    (1, [5, 40, 0], None, {}), (16, [16, 32, 0], [16, 9, 0], {}),
+    # the decode body over two whole groups of 1024 keys and a partly dead
+    # one, over one group ending on its boundary, and at position 0
+    (1, [2300, 1023, 0], None, dict(bs=64, nlb=40, nb=48))])
+def test_absorbed_read_equals_expanded_attention(g, pos, rows, table):
     """score = q~ . c_kv + q_pe . k_pe with q~_h = W_k_h q_nope_h, and the
     value half applied after the read, against K and V expanded a head at a
     time and plain softmax attention."""
     rng = np.random.default_rng(3)
     s = len(pos)
-    pool, btab = _pool_and_table(rng, s)
+    pool, btab = _pool_and_table(rng, s, **table)
     kv_b = rng.normal(size=(C, NH, DN + DV)).astype(np.float32) * C ** -0.5
     q_nope = rng.normal(size=(s, g, NH, DN)).astype(np.float32)
     q_pe = rng.normal(size=(s, g, NH, DR)).astype(np.float32)
@@ -72,7 +77,7 @@ def test_absorbed_read_equals_expanded_attention(g, pos, rows):
             rows=rows_a, backend=backend)
         ctx = np.asarray(ctx).reshape(s, g, NH, C)
         outs[backend] = np.einsum("sghc,chd->sghd", ctx, kv_b[..., DN:])
-    view = np.asarray(pool)[np.asarray(btab)].reshape(s, NLB * BS, W)
+    view = np.asarray(pool)[np.asarray(btab)].reshape(s, -1, W)
     for i in range(s):
         n = g if rows is None else rows[i]
         for j in range(n):
@@ -90,9 +95,95 @@ def test_absorbed_read_equals_expanded_attention(g, pos, rows):
     assert all(np.isfinite(o).all() for o in outs.values())
 
 
+# the decode body's edges, in units of its group (1024 keys = 16 blocks of 64)
+# and its chunk (512 keys). A slot: (position, real rows, on the null block).
+_LIVE1, _LIVE2, _LIVE3 = (700, 1, False), (1500, 1, False), (2300, 1, False)
+_IDLE, _NULL = (900, 0, False), (0, 1, True)
+_DECODE_CASES = {
+    "one_group_one_chunk": [(5, 1, False), (511, 1, False), (63, 1, False),
+                            (64, 1, False)],
+    "one_group_two_chunks": [(512, 1, False), _LIVE1, (1000, 1, False),
+                             (513, 1, False)],
+    "last_group_partly_dead": [(1024, 1, False), _LIVE2, _LIVE3,
+                               (2559, 1, False)],
+    "ends_on_a_group_boundary": [(1023, 1, False), (2047, 1, False),
+                                 (1535, 1, False), (2048, 1, False)],
+    "position_0": [(0, 1, False), (0, 1, False), (1, 1, False), _LIVE1],
+    "idle_between_live": [_LIVE2, _IDLE, _NULL, _LIVE1],
+    "only_live_slot_last": [_IDLE, _NULL, _IDLE, _LIVE3],
+    "only_live_slot_first": [_LIVE3, _NULL, _IDLE, _NULL],
+    "all_idle": [_IDLE, _NULL, _NULL, _IDLE],
+    # one, two and three groups before a slot boundary: the parity of the
+    # buffer the next live slot's first group lands in
+    "odd_groups_then_a_slot": [_LIVE1, _LIVE2, _LIVE3, _LIVE1],
+    "even_groups_then_a_slot": [_LIVE2, _LIVE2, _LIVE1, _LIVE3],
+    "three_groups_idle_then_a_slot": [_LIVE3, _IDLE, _LIVE3, _LIVE2],
+}
+
+
+@pytest.mark.parametrize("case", sorted(_DECODE_CASES))
+def test_decode_read_matches_the_composite(case):
+    """One position a slot through `_latent_decode_kernel` (interpreted,
+    float32 pool) against the composite: live slots' rows equal, idle slots
+    (no real row, or position 0 on the null block) zeros, all finite."""
+    slots = _DECODE_CASES[case]
+    rng = np.random.default_rng(sum(map(ord, case)))
+    pool, btab = _pool_and_table(rng, len(slots), bs=64, nlb=40, nb=64)
+    pos = jnp.asarray([p for p, _, _ in slots])
+    rows = jnp.asarray([r for _, r, _ in slots])
+    null = np.asarray([n for _, _, n in slots])
+    btab = jnp.where(null[:, None], 0, btab)
+    q = jnp.asarray(rng.normal(size=(len(slots), 1, NH * W)), jnp.float32)
+    got, want = (np.asarray(la.latent_paged_attention(
+        q, pool, btab, pos, NH, C, 0.2, rows=rows, backend=backend))
+        for backend in ("pallas_interpret", "xla"))
+    live = np.asarray([r > 0 and not n for _, r, n in slots])
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got[live], want[live], atol=2e-5)
+    assert not got[~live].any()
+
+
+def test_decode_read_over_a_table_shorter_than_a_group():
+    """Six blocks of 16: the group is the whole table and the chunk divides
+    it; positions in the first and the last block, and one idle slot."""
+    rng = np.random.default_rng(8)
+    pool, btab = _pool_and_table(rng, 3)
+    q = jnp.asarray(rng.normal(size=(3, 1, NH * W)), jnp.float32)
+    pos, rows = jnp.asarray([95, 3, 50]), jnp.asarray([1, 1, 0])
+    got, want = (np.asarray(la.latent_paged_attention(
+        q, pool, btab, pos, NH, C, 0.2, rows=rows, backend=backend))
+        for backend in ("pallas_interpret", "xla"))
+    np.testing.assert_allclose(got[:2], want[:2], atol=2e-5)
+    assert not got[2].any()
+
+
+@pytest.mark.parametrize("n_query, kernel, grid", [
+    (1, "_latent_decode_kernel", (6,)), (128, "_latent_kernel", (6, 16))])
+def test_the_shape_selects_the_body(n_query, kernel, grid):
+    """One position a slot takes the decode body, whole tiles of positions
+    the lanes' body; both are ONE call in the scope the benchmark looks
+    for, the decode call's result `[S, num_heads, v_width]`."""
+    S = jax.ShapeDtypeStruct
+    jaxpr = jax.make_jaxpr(lambda q, pool, t, p: la.latent_paged_attention(
+        q, pool, t, p, 64, 512, 0.13, backend="pallas"))(
+        S((6, n_query, 64 * 640), jnp.bfloat16),
+        S((32, 1, 64, 640), jnp.bfloat16), S((6, 8), jnp.int32),
+        S((6,), jnp.int32))
+    (call,) = _pallas_calls(jaxpr.jaxpr)
+    out = call.outvars[0].aval
+    assert call.params["jaxpr"].debug_info.func_name == kernel
+    assert call.params["grid_mapping"].grid == grid
+    assert str(call.source_info.name_stack).split("/")[-1] == \
+        "latent_paged_attention"
+    assert (out.dtype, out.shape) == (jnp.bfloat16, (6, 64 * n_query, 512))
+
+
 def test_lowering_is_chosen_by_shape_and_backend():
     assert la.latent_attention_lowering(640, 512, 64, 1, "pallas") == la.KERNEL
     assert la.latent_attention_lowering(640, 512, 64, 128, "pallas") == la.KERNEL
+    # which body of the kernel serves which: test_the_shape_selects_the_body
+    assert la.latent_attention_body(1) is la._latent_decode_pallas
+    assert la.latent_attention_body(128) is la._latent_pallas
     assert la.latent_attention_lowering(576, 512, 64, 1, "xla") == la.COMPOSITE
     # on a CPU a shape no kernel serves takes the composite
     assert la.latent_attention_lowering(576, 512, 64, 1) == la.COMPOSITE

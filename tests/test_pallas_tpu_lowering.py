@@ -298,19 +298,48 @@ def test_sharded_train_step_runs_flash_per_shard(as_on_tpu):
     assert re.search(r"sdy\.manual_computation|shard_map", text)
 
 
-@pytest.mark.parametrize("slots, positions", [(32, 1), (2, 128)])
-def test_latent_attention_kernel_lowers_at_the_benchmark_shapes(slots,
-                                                                positions):
-    """The latent read of axk1-ep16_serve_docqa: 32 decode rows, and two
-    prefill lanes of 128 positions, 64 heads over rows of 640 lanes in
-    blocks of 64, a table of 272 blocks."""
+def _latent_read(slots, positions):
     from paddle_tpu.fusion import latent_paged_attention
-    text = _tpu_text(
-        lambda q, pool, t, p, r: latent_paged_attention(
-            q, pool, t, p, 64, 512, 0.13, rows=r, backend="pallas"),
-        S((slots, positions, 64 * 640), BF16), S((2048, 1, 64, 640), BF16),
-        S((slots, 272), I32), S((slots, 1, 1), F32), S((slots,), I32))
-    assert _n_calls(text) == 1
+    return (lambda q, pool, t, p, r: latent_paged_attention(
+                q, pool, t, p, 64, 512, 0.13, rows=r, backend="pallas"),
+            [S((slots, positions, 64 * 640), BF16),
+             S((2048, 1, 64, 640), BF16), S((slots, 272), I32),
+             S((slots, 1, 1), F32), S((slots,), I32)])
+
+
+@pytest.mark.parametrize("slots, positions, body, result", [
+    (32, 1, "_latent_decode_kernel", "tensor<32x64x512xbf16>"),
+    (2, 128, "_latent_kernel", "tensor<2x8192x512xbf16>")])
+def test_latent_attention_kernel_lowers_at_the_benchmark_shapes(
+        slots, positions, body, result):
+    """The latent read of axk1-ep16_serve_docqa: 32 decode rows through the
+    decode body, and two prefill lanes of 128 positions through the lanes'
+    body, 64 heads over rows of 640 lanes in blocks of 64, a table of 272
+    blocks. Each is ONE Mosaic call in the scope `latent_paged_attention`
+    whose result is `bf16[slots, rows, 512]`: what benchmark/kernel_ops.py
+    finds the decode read by."""
+    f, args = _latent_read(slots, positions)
+    text = jax.jit(f).trace(*args).lower(
+        lowering_platforms=("tpu",)).as_text(debug_info=True)
+    (call,) = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert f"-> {result}" in call
+    (loc,) = re.findall(r"loc\((#loc\d+)\)", call)
+    scope = re.search(re.escape(loc) + r' = loc\("([^"]+)"', text).group(1)
+    assert scope.split("/")[-2:] == ["latent_paged_attention", "pallas_call"]
+    assert f'kernel_name = "{body}"' in call
+
+
+@pytest.mark.parametrize("slots, positions", [(32, 1), (2, 128)])
+def test_latent_attention_kernel_compiles_for_v5e(one_chip, slots, positions):
+    """Both bodies at those widths through the TPU compiler for a v5e: the
+    decode body's two 1024-row buffers and its written-out copies, the
+    lanes' body as it was."""
+    f, args = _latent_read(slots, positions)
+    args = [S(a.shape, a.dtype, sharding=one_chip) for a in args]
+    compiled = jax.jit(f).lower(*args).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    if positions == 1:
+        assert "bf16[32,64,512]" in compiled.as_text()
 
 
 @pytest.mark.parametrize("rows", [32, 288])

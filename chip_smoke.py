@@ -417,6 +417,114 @@ def phase_serve_hybrid(vocab=65536, d_model=2048, d_inner=7168, num_heads=32,
             "tpu_custom_calls": n_calls, "mixed_tpu_custom_calls": n_mixed}
 
 
+def phase_serve_ssm(vocab=32768, d_model=4096, num_heads=32, num_kv_heads=2,
+                    d_head=128, ssm=(128, 64, 8, 128), latent=1024,
+                    d_expert=2688, d_shared=5376, n_routed=512, n_held=16,
+                    top_k=22, kinds=("ssm", "moe", "ssm", "attention"),
+                    n_slots=16, block_size=64, n_blocks=128, n_snapshots=4,
+                    max_len=1024, preamble=256, turns=(40, 150), max_new=24,
+                    expect_lowering="kernel"):
+    """A model of one sublayer a layer through the same PagedKVEngine:
+    Mamba-2 mixers whose float32 state a decode row updates in place and a
+    prefix hit restores from the snapshot POOL, attention over two
+    key/value heads without positions, latent two-matrix experts of which a
+    share is held, at the published widths of
+    benchmark/configs/nemotron3-super-ep4.json, four layers and 16 held
+    experts. Requests that start from a shared preamble (K/V blocks from the
+    prefix cache and the state after them from the pool) must emit the tokens
+    an engine without prefix sharing emits, which prefills the preamble
+    itself; with the pool's entries swapped they must not."""
+    from paddle_tpu.models.decoder_spec import DecoderSpec, MoESpec, SsmSpec
+    from paddle_tpu.serving import PagedKVEngine
+    import paddle_tpu as pt
+
+    spec = DecoderSpec.ssm_gqa_moe(
+        vocab, d_model, num_heads, num_kv_heads, d_head, kinds, SsmSpec(*ssm),
+        MoESpec(n_routed=n_routed, top_k=top_k, d_expert=d_expert,
+                held=tuple(range(n_held)), n_shared=1, first_dense=0,
+                scaling=5.0, topk_method="bias", norm_eps=1e-20,
+                activation="relu2", latent=latent, d_shared=d_shared))
+    t0 = time.time()
+    scope = pt.Scope()
+    engines = [PagedKVEngine(n_slots=n_slots, max_len=max_len,
+                             block_size=block_size, n_blocks=n_blocks,
+                             n_snapshots=n_snapshots, scope=scope, model=spec,
+                             prefix_sharing=share)
+               for share in (True, False)]
+    # the startup program leaves A_log, dt_bias and D as it leaves a matrix;
+    # a state that neither explodes nor forgets at once needs A < 0 of order
+    # one and a dt well under one
+    import jax.numpy as jnp
+    for name in list(scope.local_var_names()):
+        if name.endswith("_a_log"):
+            scope.set_var(name, jnp.zeros_like(scope.get(name)))
+        elif name.endswith("_dt_bias"):
+            scope.set_var(name, jnp.full_like(scope.get(name), -3.0))
+    rng = np.random.RandomState(3)
+    head = rng.randint(0, vocab, (preamble,)).tolist()
+    other = rng.randint(0, vocab, (preamble,)).tolist()
+    prompts = [head + rng.randint(0, vocab, (n,)).tolist() for n in turns]
+    tokens = []
+    for eng in engines:
+        warm = [eng.submit(p, 2) for p in (head, other)]
+        eng.run_until_idle()        # both preambles' blocks and snapshots
+        rest = [eng.submit(p, max_new) for p in prompts]
+        eng.run_until_idle()
+        _check(all(r.done and r.error is None for r in warm + rest),
+               "a request of the state-space engine did not finish")
+        tokens.append([r.tokens for r in rest])
+    run_s = time.time() - t0
+    shared, alone = engines
+    st = shared.stats()
+    _check(tokens[0] == tokens[1],
+           "a request that resumed from the prefix cache (K/V blocks and the "
+           "state-space snapshot) emitted other tokens than its "
+           "self-prefilled twin")
+    pool = st["ssm_state"]
+    _check(pool["restores"] == len(turns)
+           and alone.stats()["ssm_state"]["restores"] == 0,
+           f"state-space restores {pool}: every prefix hit resumes from an "
+           f"entry of the snapshot pool")
+    n_calls = _n_custom_calls(shared.tick_hlo())
+    n_mixed = _n_custom_calls(shared.mixed_tick_hlo())
+    per = {k: list(kinds).count(k) for k in ("ssm", "moe", "attention")}
+    want = sum(per.values()) if expect_lowering == "kernel" else 0
+    _check((st["paged_attention_lowering"], n_calls, n_mixed)
+           == (expect_lowering, want,
+               want + (per["attention"] if want else 0)),
+           f"the state-space engine reports its cache read as "
+           f"{st['paged_attention_lowering']!r}, its ticks hold {n_calls} "
+           f"and {n_mixed} tpu_custom_calls; expected {expect_lowering!r} "
+           f"with {want} (a state update a mixer, a product a routed layer, "
+           f"a read an attention layer) and one more a lanes' read")
+    # the comparison above has to refuse a restore that is broken: with the
+    # two preambles' snapshots swapped, a request that starts two tokens
+    # after `head` reads the state after `other`
+    for j in range(per["ssm"]):
+        for part in ("h", "conv"):
+            name = f"{shared._cache_prefix}_ssm_snap_{part}{j}"
+            snap = scope.get(name)
+            scope.set_var(name, snap.at[0].set(snap[1]).at[1].set(snap[0]))
+    probe = head + rng.randint(0, vocab, (2,)).tolist()
+    pair = [eng.submit(probe, max_new) for eng in engines]
+    for eng in engines:
+        eng.run_until_idle()
+    _check(pair[0].shared_len == preamble and pair[1].shared_len == 0
+           and pair[0].tokens != pair[1].tokens,
+           "a request that resumed from ANOTHER prompt's snapshot emitted its "
+           "self-prefilled twin's tokens: the twins' comparison does not see "
+           "the state")
+    return {"compile_s": 0.0, "run_s": round(run_s, 2),
+            "tokens_out": sum(len(t) for t in tokens[0]),
+            "swapped_state_differs_at": next(
+                i for i, (a, b) in enumerate(zip(*(r.tokens for r in pair)))
+                if a != b),
+            "ssm_state": pool, "block_bytes": st["block_bytes"],
+            "experts_touched": int(np.count_nonzero(shared.expert_rows)),
+            "paged_attention_lowering": st["paged_attention_lowering"],
+            "tpu_custom_calls": n_calls, "mixed_tpu_custom_calls": n_mixed}
+
+
 def phase_train_resnet50(batch=256, steps=2, depth=50, image=224):
     """bench.py's training graph: ResNet-50 NHWC bf16, uint8 staging
     declared (and fed), Momentum."""
@@ -983,6 +1091,7 @@ def _run():
     phase("train_resnet50", phase_train_resnet50)
     _free_device_memory()
     phase("serve_hybrid", phase_serve_hybrid)
+    phase("serve_ssm", phase_serve_ssm)
     _free_device_memory()
     phase("kernels", phase_kernels)
     if len(jax.devices()) >= 4:

@@ -33,13 +33,13 @@ interleavings across distinct prefixes add blocks but no new
 transition structure.
 """
 
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 from ..core.enforce import InvalidArgumentError
 
 __all__ = [
     "DIAGNOSTICS", "MUTATIONS", "OwnershipViolation", "TableState",
-    "AbstractState", "ModelChecker", "CheckResult",
+    "AbstractState", "ModelChecker", "CheckResult", "check_span_snapshot",
 ]
 
 # ---------------------------------------------------------------------------
@@ -86,6 +86,13 @@ DIAGNOSTICS: Dict[str, str] = {
         "onto) holds no state snapshot — a model with per-request state "
         "beside its per-token rows (short convolutions) would resume the "
         "shared span from a state nobody computed",
+    "kv-span-past-snapshot":
+        "a request was admitted onto a shared span that does not END at the "
+        "block its state snapshot is the state after — a model whose "
+        "per-request state is kept in a snapshot POOL (state-space layers: "
+        "far fewer snapshots than blocks) may share K/V blocks only up to "
+        "the deepest one that holds a snapshot; past it the state-space "
+        "layers would resume from a state of other positions",
     "serving-cache-write-alias":
         "a tick-program cache write breaks the donated in-place "
         "contract: the pool var is written more than once per tick, or "
@@ -111,6 +118,19 @@ MUTATIONS: Dict[str, str] = {
     "rollback-double-free": "kv-double-free",
     "skipped-snapshot": "kv-state-snapshot-missing",
 }
+
+
+def check_span_snapshot(shared_blocks: Sequence[int],
+                        snapshot_block: Optional[int], op: str):
+    """The snapshot pool's rule (`serving/kv_pager.py` `try_admit` calls it
+    on every span it hands out with a snapshot): the span's last block is
+    the one the snapshot is the state after."""
+    if shared_blocks and shared_blocks[-1] != snapshot_block:
+        raise OwnershipViolation(
+            "kv-span-past-snapshot", op,
+            f"the shared span ends in block {shared_blocks[-1]}, its "
+            f"snapshot is the state after block {snapshot_block}",
+            block=shared_blocks[-1])
 
 
 class OwnershipViolation(InvalidArgumentError):

@@ -25,6 +25,13 @@ experts, a grouped matrix product with two lowerings:
   in the resident output block.
 - the composite (a CPU, or asked for): the same sum in `jax.numpy`, over
   every held expert.
+
+With `gate` None an expert is `down_e(relu(up_e x)^2)`: two matrices, no gate
+(the latent experts, whose x is a latent row between projections the layer
+shares: `models/transformer.py _moe_ffn`). Its kernel takes the tile of the
+expert width from the shape, up to a whole expert a step (`relu2_tile`): 128
+held experts of width 2,688 at a tile of 256 would be 1,408 steps of ~0.35 us
+against the ~1.1 ms their weights stream in.
 """
 
 from __future__ import annotations
@@ -80,10 +87,26 @@ def _moe_route_op(ctx, ins, attrs):
     return {"Weights": [w], "Rows": [rows]}
 
 
-def experts_lowering(n_rows, d_model, d_expert, backend=None):
+#: VMEM the two-matrix kernel's weight tiles may take, double-buffered
+_RELU2_VMEM = 48 * 1024 * 1024
+
+
+def relu2_tile(d_model, d_expert, itemsize):
+    """The columns of the expert width a step of the two-matrix kernel
+    takes: the largest divisor of the width in whole 128-lane rows whose two
+    tiles, double-buffered, fit `_RELU2_VMEM`; 0 where the width has none."""
+    for n in range(1, d_expert // 128 + 1):
+        tile, rest = divmod(d_expert, n)
+        if not rest and tile % 128 == 0 and \
+                4 * d_model * tile * itemsize <= _RELU2_VMEM:
+            return tile
+    return 0
+
+
+def experts_lowering(n_rows, d_model, d_expert, backend=None, tile=_TILE):
     backend = backend or _auto_backend()
     served = (n_rows % 16 == 0 and d_model % 128 == 0
-              and d_expert % _TILE == 0)
+              and tile and d_expert % tile == 0)
     if served and backend != "xla":
         return KERNEL
     if jax.default_backend() == "tpu" and backend != "xla":
@@ -173,14 +196,97 @@ def _experts_pallas(x, w, touched, gate, up, down, interpret):
         )(eblk, fhold, touched, x.astype(gate.dtype), w, gate, up, down)
 
 
+def _relu2_composite(x, w, up, down):
+    u = jnp.einsum("nd,edf->enf", x.astype(up.dtype), up,
+                   preferred_element_type=jnp.float32)
+    h = (jnp.square(jax.nn.relu(u)) * w).astype(down.dtype)
+    return jnp.einsum("enf,efd->nd", h, down,
+                      preferred_element_type=jnp.float32)
+
+
+def _relu2_kernel(eblk_ref, fhold_ref, touched_ref, x_ref, w_ref, u_ref,
+                  d_ref, o_ref):
+    from jax.experimental import pallas as pl
+
+    e, f = pl.program_id(0), pl.program_id(1)
+
+    @pl.when((e == 0) & (f == 0))
+    def _():
+        o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
+
+    @pl.when(touched_ref[e] > 0)
+    def _():
+        x = x_ref[...]
+        u = jnp.maximum(
+            jnp.dot(x, u_ref[0], preferred_element_type=jnp.float32), 0.0)
+        o_ref[...] += jnp.dot((u * u * w_ref[0]).astype(x.dtype), d_ref[0],
+                              preferred_element_type=jnp.float32)
+
+
+@functools.partial(jax.jit, static_argnames=("tile", "interpret"))
+def _relu2_pallas(x, w, touched, up, down, tile, interpret):
+    """`_experts_pallas` for two matrices an expert, `tile` columns a step."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    n, d = x.shape
+    n_held, _, width = up.shape
+    nf = width // tile
+    touched = touched.astype(jnp.int32)
+    ids = jnp.arange(n_held, dtype=jnp.int32)
+    before = jax.lax.cummax(jnp.where(touched > 0, ids, -1))
+    first = jnp.argmax(touched > 0).astype(jnp.int32)
+    eblk = jnp.where(before >= 0, before, first)
+    fhold = jnp.where(before >= 0, nf - 1, 0).astype(jnp.int32)
+
+    def held(e, f, eblk_ref, fhold_ref, touched_ref):
+        return eblk_ref[e], jnp.where(touched_ref[e] > 0, f, fhold_ref[e])
+
+    def up_map(e, f, *refs):
+        blk, col = held(e, f, *refs)
+        return blk, 0, col
+
+    def down_map(e, f, *refs):
+        blk, col = held(e, f, *refs)
+        return blk, col, 0
+
+    with jax.named_scope("latent_experts"):
+        return pl.pallas_call(
+            _relu2_kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=3,
+                grid=(n_held, nf),
+                in_specs=[
+                    pl.BlockSpec((n, d), lambda e, f, *_: (0, 0)),
+                    pl.BlockSpec((1, n, 1), lambda e, f, *_: (e, 0, 0)),
+                    pl.BlockSpec((1, d, tile), up_map),
+                    pl.BlockSpec((1, tile, d), down_map)],
+                out_specs=pl.BlockSpec((n, d), lambda e, f, *_: (0, 0))),
+            out_shape=jax.ShapeDtypeStruct((n, d), jnp.float32),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary", "arbitrary"),
+                vmem_limit_bytes=100 * 1024 * 1024),
+            interpret=interpret,
+        )(eblk, fhold, touched, x.astype(up.dtype), w, up, down)
+
+
 def experts(x, w, rows, gate, up, down, backend=None):
     """x [N, D]; w [n_held, N, 1] float32 (`route`); rows [n_held]; gate,
-    up [n_held, D, F]; down [n_held, F, D] -> [N, D] float32."""
+    up [n_held, D, F]; down [n_held, F, D] -> [N, D] float32. `gate` None:
+    the two-matrix form, `down_e(relu(up_e x)^2)`."""
+    interpret = backend == "pallas_interpret"
+    if gate is None:
+        tile = relu2_tile(x.shape[1], up.shape[-1], up.dtype.itemsize)
+        if experts_lowering(x.shape[0], x.shape[1], up.shape[-1], backend,
+                            tile) == KERNEL:
+            return _relu2_pallas(x, w, rows, up, down, tile=tile,
+                                 interpret=interpret)
+        return _relu2_composite(x, w, up, down)
     lowering = experts_lowering(x.shape[0], x.shape[1], gate.shape[-1],
                                 backend)
     if lowering == KERNEL:
         return _experts_pallas(x, w, rows, gate, up, down,
-                               interpret=backend == "pallas_interpret")
+                               interpret=interpret)
     return _experts_composite(x, w, gate, up, down)
 
 
@@ -188,6 +294,7 @@ def experts(x, w, rows, gate, up, down, backend=None):
 def _moe_experts_op(ctx, ins, attrs):
     x = ins["X"][0]
     out = experts(x.reshape(-1, x.shape[-1]), ins["Weights"][0],
-                  ins["Rows"][0], ins["Gate"][0], ins["Up"][0],
+                  ins["Rows"][0],
+                  ins["Gate"][0] if ins.get("Gate") else None, ins["Up"][0],
                   ins["Down"][0], backend=attrs.get("backend"))
     return {"Out": [out.reshape(x.shape).astype(x.dtype)]}

@@ -1138,13 +1138,16 @@ def moe_route(x, w_router, held, top_k, scaling, norm_topk_prob=True,
 def moe_experts(x, weights, rows, gate, up, down, name=None):
     """The held experts' part of a routed layer: sum over them of
     `weights[e] * down_e(silu(gate_e x) * up_e x)`, skipping every expert
-    whose `rows[e]` is 0 (fusion/moe.py)."""
+    whose `rows[e]` is 0 (fusion/moe.py). `gate` None: an expert is
+    `down_e(relu(up_e x)^2)`."""
     helper = LayerHelper("moe_experts", name=name)
     out = helper.create_tmp_variable(dtype=dtype_name(x.dtype), shape=x.shape,
                                      stop_gradient=True)
-    helper.append_op(type="moe_experts",
-                     inputs={"X": [x], "Weights": [weights], "Rows": [rows],
-                             "Gate": [gate], "Up": [up], "Down": [down]},
+    inputs = {"X": [x], "Weights": [weights], "Rows": [rows], "Up": [up],
+              "Down": [down]}
+    if gate is not None:
+        inputs["Gate"] = [gate]
+    helper.append_op(type="moe_experts", inputs=inputs,
                      outputs={"Out": [out]})
     return out
 
@@ -1203,6 +1206,57 @@ def conv_state_commit(slot_state, new_states, live, lanes=None, name=None):
     helper.append_op(type="conv_state_commit", inputs=inputs,
                      outputs=outputs, attrs=attrs)
     return slot_state
+
+
+def ssm_scan(xbc, dt, params, state, live, ssm, lanes=None, name=None):
+    """One state-space layer's convolution and scan over a tick's rows
+    (fusion/ssm.py): `xbc` [N, 1, conv_dim] and `dt` [N, 1, heads] from the
+    input projection, `params` (dict: taps, conv_bias, a_log, dt_bias, d),
+    `state` (dict: slot_h, slot_conv, and with `lanes` snap_h, snap_conv), all
+    updated in place, `lanes` (dict: lpos, lrows, lslot, snap_src, snap_dst,
+    snap_rows, chunk), `ssm` the `SsmSpec`. Returns y [N, 1, d_inner]."""
+    helper = LayerHelper("ssm_scan", name=name)
+    out = helper.create_tmp_variable(
+        dtype=dtype_name(xbc.dtype),
+        shape=list(xbc.shape[:-1]) + [ssm.d_inner], stop_gradient=True)
+    inputs = {"XBC": [xbc], "Dt": [dt], "Taps": [params["taps"]],
+              "ConvBias": [params["conv_bias"]], "ALog": [params["a_log"]],
+              "DtBias": [params["dt_bias"]], "D": [params["d"]],
+              "SlotH": [state["slot_h"]], "SlotConv": [state["slot_conv"]],
+              "Live": [live]}
+    outputs = {"Out": [out], "SlotHOut": [state["slot_h"]],
+               "SlotConvOut": [state["slot_conv"]]}
+    attrs = {"heads": ssm.heads, "head_dim": ssm.head_dim,
+             "groups": ssm.groups, "state": ssm.state}
+    if lanes is not None:
+        inputs.update(SnapH=[state["snap_h"]], SnapConv=[state["snap_conv"]],
+                      LanePos=[lanes["lpos"]], LaneRows=[lanes["lrows"]],
+                      LaneSlot=[lanes["lslot"]], SnapSrc=[lanes["snap_src"]],
+                      SnapDst=[lanes["snap_dst"]],
+                      SnapRows=[lanes["snap_rows"]])
+        outputs.update(SnapHOut=[state["snap_h"]],
+                       SnapConvOut=[state["snap_conv"]])
+        attrs["chunk"] = int(lanes["chunk"])
+    helper.append_op(type="ssm_scan", inputs=inputs, outputs=outputs,
+                     attrs=attrs)
+    return out
+
+
+def gated_rms_norm(x, z, groups, epsilon=1e-5, param_attr=None, name=None):
+    """RMSNorm over each of `groups` groups of the last dimension of
+    `x * silu(z)` (the gate first), with a learned scale a value
+    (fusion/ssm.py)."""
+    helper = LayerHelper("gated_rms_norm", name=name)
+    scale = helper.create_parameter(
+        param_attr, shape=[x.shape[-1]], dtype=dtype_name(x.dtype),
+        default_initializer=ConstantInitializer(1.0))
+    out = helper.create_tmp_variable(dtype=dtype_name(x.dtype), shape=x.shape,
+                                     stop_gradient=True)
+    helper.append_op(type="gated_rms_norm",
+                     inputs={"X": [x], "Z": [z], "Scale": [scale]},
+                     outputs={"Out": [out]},
+                     attrs={"groups": int(groups), "epsilon": float(epsilon)})
+    return out
 
 
 def lrn(input, n=5, k=2.0, alpha=1e-4, beta=0.75, name=None):

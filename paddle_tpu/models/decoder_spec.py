@@ -8,7 +8,9 @@ post-LayerNorm, sinusoidal positions at the embedding, full heads, a ReLU
 pair, float32 parameters. `DecoderSpec.latent_moe` is the other point that
 is built, `DecoderSpec.conv_gqa_moe` the third (a kind PER LAYER: gated
 short convolutions with a per-request state beside grouped-query rotary
-attention). A spec comes from one of the constructors; the fields are what
+attention), `DecoderSpec.ssm_gqa_moe` the fourth (ONE sublayer a layer: a
+Mamba-2 state-space mixer, grouped-query attention without positions, or
+latent routed experts). A spec comes from one of the constructors; the fields are what
 `_decoder_block` reads, not a product to pick from: any other combination
 raises where a graph would have to build it.
 `serving.PagedKVEngine(model=spec)` takes any of them; everything else in
@@ -19,12 +21,15 @@ Kinds (each a string, checked by name; nothing is guessed):
   norm        "layer_norm" | "rms_norm"
   residual    "post" (x = norm(x + f(x))) | "pre" (x = x + f(norm(x)))
   positions   "sinusoid" (added at the embedding) | "rotary" (inside attention)
+              | "none" (the state-space layers carry the order)
   attention   "full" (q/k/v heads over K and V pools; `num_kv_heads` fewer
               key/value heads than query heads, `qk_norm` an RMSNorm a head
               on q and k) | "latent" (`LatentSpec`)
   layer_kinds a kind a layer, "attention" | "conv" (`ConvSpec`: a gated
               short convolution whose state is the last rows of its input);
-              None: attention everywhere
+              None: attention everywhere. With `one_sublayer` a layer is its
+              kind ALONE under one pre-norm residual, out of "ssm"
+              (`SsmSpec`) | "attention" | "moe"
   ffn         "relu" | "gated_silu"; layers from `moe.first_dense` on are
               routed experts + shared expert (`MoESpec`)
   tied_head   the vocabulary head is the embedding, transposed
@@ -119,7 +124,49 @@ class ConvSpec:
         return self.taps - 1
 
 
+@dataclasses.dataclass(frozen=True)
+class SsmSpec:
+    """The Mamba-2 mixer (fusion/ssm.py has the equations): `heads` heads of
+    `head_dim` values, `groups` groups sharing B and C of `state` values, a
+    causal depthwise convolution of `taps` taps (bias, SiLU) over x, B and C
+    (the chunked form's chunk is the engine's prefill chunk). What a request
+    carries from
+    token to token is `h` [heads, head_dim, state] in float32 and the last
+    `taps - 1` rows of the convolution's input."""
+    heads: int
+    head_dim: int
+    groups: int
+    state: int
+    taps: int = 4
+
+    def __post_init__(self):
+        if self.heads % self.groups:
+            raise ValueError(f"{self.heads} heads over {self.groups} groups")
+
+    @property
+    def d_inner(self) -> int:
+        return self.heads * self.head_dim
+
+    @property
+    def conv_dim(self) -> int:
+        """Channels the convolution runs over: x, then B and C of a group."""
+        return self.d_inner + 2 * self.groups * self.state
+
+    @property
+    def in_dim(self) -> int:
+        """Columns of the input projection: z, xBC, dt."""
+        return self.d_inner + self.conv_dim + self.heads
+
+    @property
+    def state_rows(self) -> int:
+        return self.taps - 1
+
+    def h_bytes(self) -> int:
+        return self.heads * self.head_dim * self.state * 4
+
+
 TOPK_METHODS = ("none", "bias")
+ACTIVATIONS = ("gated_silu", "relu2")
 SCORING = ("sigmoid",)
 
 
@@ -132,7 +179,11 @@ class MoESpec:
     left out, and no code stands in for the chips that hold them.
     `topk_method` "bias": the selection is the top-k of score + a learned
     per-expert bias, the weights are the UNBIASED scores of the selected;
-    `norm_eps` is added to the sum the weights are divided by."""
+    `norm_eps` is added to the sum the weights are divided by.
+    `activation` "relu2": an expert is `W2 relu(W1 z)^2`, two matrices and no
+    gate; `latent` > 0: the routed experts run on a row of that width between
+    a down- and an up-projection all of them share; `d_shared`: the shared
+    expert's own width (None: `d_expert * n_shared`)."""
     n_routed: int
     top_k: int
     d_expert: int
@@ -144,8 +195,19 @@ class MoESpec:
     scoring: str = "sigmoid"
     topk_method: str = "none"
     norm_eps: float = 0.0
+    activation: str = "gated_silu"
+    latent: int = 0
+    d_shared: Optional[int] = None
+
+    @property
+    def shared_width(self) -> int:
+        return (self.d_expert * self.n_shared if self.d_shared is None
+                else self.d_shared)
 
     def __post_init__(self):
+        if self.activation not in ACTIVATIONS:
+            raise NotImplementedError(
+                f"expert activation {self.activation!r}: one of {ACTIVATIONS}")
         if self.topk_method not in TOPK_METHODS:
             raise NotImplementedError(
                 f"topk_method {self.topk_method!r}: the router implements "
@@ -185,11 +247,14 @@ class DecoderSpec:
     layer_kinds: Optional[Tuple[str, ...]] = None
     conv: Optional[ConvSpec] = None
     tied_head: bool = False
+    one_sublayer: bool = False              # a layer is its kind alone
+    head_dim: Optional[int] = None          # None: d_model // num_heads
+    ssm: Optional[SsmSpec] = None
 
     def __post_init__(self):
         for field, kinds in (("norm", ("layer_norm", "rms_norm")),
                              ("residual", ("post", "pre")),
-                             ("positions", ("sinusoid", "rotary")),
+                             ("positions", ("sinusoid", "rotary", "none")),
                              ("attention", ("full", "latent")),
                              ("ffn", ("relu", "gated_silu")),
                              ("dtype", ("float32", "bfloat16"))):
@@ -210,11 +275,19 @@ class DecoderSpec:
             raise ValueError(f"{self.num_heads} query heads over "
                              f"{self.kv_heads} key/value heads")
         kinds = self.layer_kinds
-        if kinds is not None and (
-                len(kinds) != self.num_layers
-                or set(kinds) - {"attention", "conv"}):
-            raise ValueError(f"layer_kinds {kinds!r}: one of 'attention' | "
-                             f"'conv' for each of {self.num_layers} layers")
+        known = ({"ssm", "attention", "moe"} if self.one_sublayer
+                 else {"attention", "conv"})
+        if (kinds is None and self.one_sublayer) or (kinds is not None and (
+                len(kinds) != self.num_layers or set(kinds) - known)):
+            raise ValueError(f"layer_kinds {kinds!r}: one of "
+                             f"{sorted(known)} for each of "
+                             f"{self.num_layers} layers")
+        if (self.ssm is not None) != bool(self.ssm_layers):
+            raise ValueError("an 'ssm' layer comes with an SsmSpec, and "
+                             "only it")
+        if self.one_sublayer and (self.moe is None) != (not self.moe_layers):
+            raise ValueError("a 'moe' layer comes with a MoESpec, and only "
+                             "it")
         if (self.conv is not None) != bool(self.conv_layers):
             raise ValueError("a 'conv' layer comes with a ConvSpec, and "
                              "only it")
@@ -258,6 +331,22 @@ class DecoderSpec:
                    layer_kinds=tuple(layer_kinds), conv=ConvSpec(conv_taps),
                    tied_head=True)
 
+    @classmethod
+    def ssm_gqa_moe(cls, vocab, d_model, num_heads, num_kv_heads, d_head,
+                    layer_kinds, ssm: SsmSpec, moe: Optional[MoESpec] = None,
+                    norm_eps=1e-5, dtype="bfloat16"):
+        """The Nemotron-H family's block: ONE sublayer a layer under one
+        pre-norm RMSNorm residual, by `layer_kinds` a Mamba-2 mixer ("ssm"),
+        grouped-query attention with no positions, no bias and no QK-norm
+        ("attention", heads of `d_head` whatever `d_model`), or routed
+        experts beside a shared one ("moe"); a final norm and an untied
+        head."""
+        return cls(vocab, d_model, 0, num_heads, len(layer_kinds),
+                   norm="rms_norm", norm_eps=norm_eps, residual="pre",
+                   positions="none", dtype=dtype, moe=moe,
+                   num_kv_heads=num_kv_heads, head_dim=d_head,
+                   layer_kinds=tuple(layer_kinds), one_sublayer=True, ssm=ssm)
+
     @property
     def is_classic(self) -> bool:
         return self == DecoderSpec.classic(**self.dims())
@@ -270,6 +359,8 @@ class DecoderSpec:
                     packed=self.packed)
 
     def ffn_kind(self, layer: int) -> str:
+        if self.one_sublayer:
+            return "moe" if self.layer_kinds[layer] == "moe" else "none"
         if self.moe is not None and layer >= self.moe.first_dense:
             return "moe"
         return self.ffn
@@ -285,7 +376,7 @@ class DecoderSpec:
 
     @property
     def d_head(self) -> int:
-        return self.d_model // self.num_heads
+        return self.head_dim or self.d_model // self.num_heads
 
     def layer_kind(self, layer: int) -> str:
         return self.layer_kinds[layer] if self.layer_kinds else "attention"
@@ -299,6 +390,11 @@ class DecoderSpec:
     def conv_layers(self) -> Tuple[int, ...]:
         return tuple(i for i in range(self.num_layers)
                      if self.layer_kind(i) == "conv")
+
+    @property
+    def ssm_layers(self) -> Tuple[int, ...]:
+        return tuple(i for i in range(self.num_layers)
+                     if self.layer_kind(i) == "ssm")
 
     # -- bytes ----------------------------------------------------------------
     @property
@@ -315,8 +411,13 @@ class DecoderSpec:
 
     def state_bytes(self) -> int:
         """Bytes of ONE copy of a request's per-layer state beside its
-        per-token rows (a slot's, or a pool block's snapshot): the conv
-        layers' last rows; 0 where every layer is attention."""
+        per-token rows (a slot's, or a snapshot): the conv layers' last
+        rows, or the state-space layers' `h` (float32) and last conv rows;
+        0 where every layer is attention."""
+        if self.ssm is not None:
+            return len(self.ssm_layers) * (
+                self.ssm.h_bytes()
+                + self.ssm.state_rows * self.ssm.conv_dim * self.itemsize)
         if self.conv is None:
             return 0
         return (len(self.conv_layers) * self.conv.state_rows * self.d_model

@@ -219,10 +219,11 @@ class _TickRows:
     idle slot, the tail of a short chunk — selects no expert), and where
     the routed layers leave their counts (`expert_rows`: per layer, the
     rows each held expert got); `conv`, where the spec has conv layers, is
-    their state (`_ConvState`)."""
+    their state (`_ConvState`), `ssm` the state-space layers' (`_SsmState`)."""
 
-    def __init__(self, spec, positions, live, max_len, conv=None):
+    def __init__(self, spec, positions, live, max_len, conv=None, ssm=None):
         self.positions, self.live, self.conv = positions, live, conv
+        self.ssm = ssm
         self.expert_rows = []
         self.table = None
         if spec.positions == "rotary":
@@ -270,11 +271,15 @@ def _moe_ffn(x, spec, name, rows):
     over every expert; `fusion/moe.py`) plus the shared expert, where the
     spec has one."""
     moe, d = spec.moe, spec.d_model
+    gated = moe.activation == "gated_silu"
+    # the routed experts' row: x, or a latent between two projections that
+    # every expert shares (the router and the shared expert read x itself)
+    dz = moe.latent or d
     router = _param(name + "_router.w_0", [d, moe.n_routed], spec.dtype)
     stack = {n: _param(f"{name}_experts_{n}",
-                       [len(moe.held), moe.d_expert, d] if n == "down"
-                       else [len(moe.held), d, moe.d_expert], spec.dtype)
-             for n in ("gate", "up", "down")}
+                       [len(moe.held), moe.d_expert, dz] if n == "down"
+                       else [len(moe.held), dz, moe.d_expert], spec.dtype)
+             for n in (("gate", "up", "down") if gated else ("up", "down"))}
     bias = None
     if moe.topk_method == "bias":
         bias = _param(name + "_router_bias", [moe.n_routed], "float32")
@@ -282,11 +287,19 @@ def _moe_ffn(x, spec, name, rows):
         x, router, moe.held, moe.top_k, moe.scaling, moe.norm_topk_prob,
         live=rows.live, bias=bias, norm_eps=moe.norm_eps)
     rows.expert_rows.append(n_rows)
-    routed = layers.moe_experts(x, weights, n_rows, stack["gate"],
+    z = _proj(x, dz, name + "_latent_down") if moe.latent else x
+    routed = layers.moe_experts(z, weights, n_rows, stack.get("gate"),
                                 stack["up"], stack["down"])
+    if moe.latent:
+        routed = _proj(routed, d, name + "_latent_up")
     if not moe.n_shared:
         return routed
-    shared = _gated_ffn(x, d, moe.d_expert * moe.n_shared, name + "_shared")
+    if gated:
+        shared = _gated_ffn(x, d, moe.shared_width, name + "_shared")
+    else:
+        shared = _proj(layers.square(layers.relu(
+            _proj(x, moe.shared_width, name + "_shared_up"))), d,
+            name + "_shared_down")
     return layers.elementwise_add(routed, shared)
 
 
@@ -342,7 +355,8 @@ def _grouped_attention(x, spec, name, attend, rows):
     """Attention with `spec.kv_heads` key/value heads under `num_heads`
     query heads (query head i reads key/value head i // group), an RMSNorm
     a head on q and k where the spec asks (`qk_norm`), rotary positions
-    over the whole head, around `attend(q, k_new, v_new)`."""
+    over the whole head where it has them, around `attend(q, k_new,
+    v_new)`."""
     n, nh, nkv, dh = x.shape[0], spec.num_heads, spec.kv_heads, spec.d_head
 
     def heads(t, count, which):
@@ -351,8 +365,9 @@ def _grouped_attention(x, spec, name, attend, rows):
                 layers.reshape(t, shape=[n, count, dh]),
                 epsilon=spec.norm_eps,
                 param_attr=ParamAttr(name=f"{name}_{which}_norm.scale"))
-        t = layers.rotary(layers.reshape(t, shape=[n, count * dh]),
-                          rows.positions, rows.table)
+        if rows.table is not None:
+            t = layers.rotary(layers.reshape(t, shape=[n, count * dh]),
+                              rows.positions, rows.table)
         return layers.reshape(t, shape=[n, 1, count * dh])
 
     q = heads(_proj(x, nh * dh, name + "_q"), nh, "q")
@@ -374,6 +389,28 @@ def _short_conv(x, spec, name, rows):
     return _proj(layers.elementwise_mul(c, conv), d, name + "_out")
 
 
+def _ssm_mixer(x, spec, name, rows):
+    """The Mamba-2 mixer: `[z, xBC, dt] = x W_in`; the convolution and the
+    scan from the request's state (`rows.ssm`, fusion/ssm.py); the gated
+    group RMSNorm; `W_out`; no bias on either projection."""
+    ssm = spec.ssm
+    zxd = _proj(x, ssm.in_dim, name + "_in")
+    z, xbc, dt = (layers.slice(zxd, axes=[2], starts=[a], ends=[b])
+                  for a, b in ((0, ssm.d_inner),
+                               (ssm.d_inner, ssm.d_inner + ssm.conv_dim),
+                               (ssm.d_inner + ssm.conv_dim, ssm.in_dim)))
+    params = dict(
+        taps=_param(name + "_taps", [ssm.conv_dim, ssm.taps], spec.dtype),
+        conv_bias=_param(name + "_conv_bias", [ssm.conv_dim], spec.dtype),
+        a_log=_param(name + "_a_log", [ssm.heads], "float32"),
+        dt_bias=_param(name + "_dt_bias", [ssm.heads], "float32"),
+        d=_param(name + "_d", [ssm.heads], "float32"))
+    y = rows.ssm.layer(xbc, dt, params, rows.live)
+    y = layers.gated_rms_norm(y, z, ssm.groups, epsilon=spec.norm_eps,
+                              param_attr=ParamAttr(name=name + "_norm.scale"))
+    return _proj(y, spec.d_model, name + "_out")
+
+
 def _decoder_block(x, i, attend, d_model, d_inner, dropout, is_test=True,
                    prefix="l", attn="attn", cross=None, spec=None,
                    rows=None):
@@ -391,7 +428,10 @@ def _decoder_block(x, i, attend, d_model, d_inner, dropout, is_test=True,
     - the feed-forward: the ReLU pair, the gated SiLU pair, or from
       `spec.moe.first_dense` on routed experts beside the shared one;
     - the residual order: post (`_add_norm`: add, then LayerNorm, named
-      `{prefix}{i}_ln{1,2[,3]}`) or pre (`x + f(norm(x))`, same names).
+      `{prefix}{i}_ln{1,2[,3]}`) or pre (`x + f(norm(x))`, same names);
+    - with `spec.one_sublayer` ONE of the Mamba-2 mixer (`_ssm_mixer`, its
+      state in `rows.ssm`), grouped attention or the routed experts, alone
+      under one pre-norm residual.
 
     `rows` (`_TickRows`) carries what the rotary and routed kinds need of
     the tick. The parameter names are the contract: a graph built from this
@@ -399,6 +439,17 @@ def _decoder_block(x, i, attend, d_model, d_inner, dropout, is_test=True,
     name = f"{prefix}{i}"
     spec = spec or DecoderSpec.classic(d_model=d_model, d_inner=d_inner,
                                        dropout=dropout)
+    if spec.one_sublayer:
+        # a layer is its kind ALONE, under one pre-norm residual
+        kind = spec.layer_kind(i)
+        sublayer = {
+            "ssm": lambda x: _ssm_mixer(x, spec, f"{name}_ssm", rows),
+            "attention": lambda x: _grouped_attention(
+                x, spec, f"{name}_{attn}", functools.partial(attend, i),
+                rows),
+            "moe": lambda x: _moe_ffn(x, spec, f"{name}_moe", rows)}[kind]
+        return layers.elementwise_add(
+            x, sublayer(_pre_norm(x, spec, f"{name}_ln1")))
     if spec.layer_kind(i) == "conv":
         sublayers = [lambda x: _short_conv(x, spec, f"{name}_conv", rows)]
     elif spec.attention == "latent":
@@ -1059,7 +1110,8 @@ def transformer_lm_paged_decode_tick(n_slots, n_blocks, block_size,
                                      d_model=512, d_inner=2048, num_heads=8,
                                      num_layers=6, dropout=0.0, packed=False,
                                      cache_prefix="pgd", topk_k=0,
-                                     kv_quant=False, model=None):
+                                     kv_quant=False, model=None,
+                                     n_snapshots=0):
     """ONE decode tick over a PAGED KV cache (`_PagedCache`) — the
     block-table variant of `transformer_lm_decode_tick`, whose slots own a
     full [1,nh,max_len,dh] row each; here a request's span is T =
@@ -1082,7 +1134,8 @@ def transformer_lm_paged_decode_tick(n_slots, n_blocks, block_size,
         # `model` (a DecoderSpec) describes the block; the dims above are
         # the classic spec's and are not read
         return _kinds_paged_tick(model, n_slots, n_blocks, block_size,
-                                 blocks_per_req, cache_prefix)
+                                 blocks_per_req, cache_prefix,
+                                 n_snapshots=n_snapshots)
     S, NLB = n_slots, blocks_per_req
     tok, pos, btab, wblock, woff = _decode_feeds(S, NLB)
     cache = _PagedCache(
@@ -1250,6 +1303,43 @@ class _ConvState:
                               last=self.last))
 
 
+class _SsmState:
+    """The state-space layers' state beside the paged cache (fusion/ssm.py
+    has the scheme): per layer j `{cache_prefix}_ssm_h{j}` [n_slots, H, P, N]
+    float32 and `{cache_prefix}_ssm_conv{j}` [n_slots, K-1, conv_dim] in the
+    spec's dtype, a slot's state, and the snapshot POOL
+    `{cache_prefix}_ssm_snap_h{j}` / `_ssm_snap_conv{j}` [n_snapshots, ..],
+    all persistable, zero at start-up, each written in place by its layer's
+    one `ssm_scan`. `lanes`: the mixed tick's lpos and lrows plus the
+    lanes' own feeds (`lane_slot`, `lane_snap_src`, `lane_snap_dst`,
+    `lane_snap_rows`: serving/kv_pager.py fills them)."""
+
+    def __init__(self, cache_prefix, spec, n_slots, n_snapshots, lanes=None):
+        ssm = spec.ssm
+        h = [ssm.heads, ssm.head_dim, ssm.state]
+        conv = [ssm.state_rows, ssm.conv_dim]
+        self.ssm, self.lanes = ssm, lanes
+        self.states = [dict(
+            slot_h=_slot_cache_var(f"{cache_prefix}_ssm_h{j}", [n_slots] + h),
+            slot_conv=_slot_cache_var(f"{cache_prefix}_ssm_conv{j}",
+                                      [n_slots] + conv, dtype=spec.dtype),
+            snap_h=_slot_cache_var(f"{cache_prefix}_ssm_snap_h{j}",
+                                   [n_snapshots] + h),
+            snap_conv=_slot_cache_var(f"{cache_prefix}_ssm_snap_conv{j}",
+                                      [n_snapshots] + conv, dtype=spec.dtype))
+            for j in range(len(spec.ssm_layers))]
+        self.n_slots, self.built, self.live = n_slots, 0, None
+
+    def layer(self, xbc, dt, params, live):
+        state = self.states[self.built]
+        self.built += 1
+        if self.live is None:           # the decode rows' part, cut once
+            self.live = layers.slice(live, axes=[0], starts=[0],
+                                     ends=[self.n_slots])
+        return layers.ssm_scan(xbc, dt, params, state, self.live, self.ssm,
+                               lanes=self.lanes)
+
+
 def _embed_rows(tok, spec, name="tok_emb"):
     """[N,1] ids -> [N,1,H] in the spec's dtype, unscaled, no positions
     (the rotary kinds put them inside attention)."""
@@ -1277,17 +1367,18 @@ def _live_rows(wblock, lrows=None, chunk=0):
 
 
 def _kinds_paged_tick(model, n_slots, n_blocks, block_size, blocks_per_req,
-                      cache_prefix, lanes=None):
+                      cache_prefix, lanes=None, n_snapshots=0):
     """The paged decode tick (`lanes` None) or mixed tick of a non-classic
     `DecoderSpec`: the classic builders' feeds (`_decode_feeds`,
     `_lane_feeds`, and `lane_slot` where a lane leaves a state in its
     slot); rows embedded without positions; `_LatentPagedCache`, or
     `_PagedCache` / `_PagedLaneCache` over the attention layers' key/value
-    heads beside `_ConvState`; the blocks through `_lm_decoder`; a float32
-    head without bias, the embedding itself where the spec ties it.
+    heads beside `_ConvState` or `_SsmState` (`n_snapshots` entries in its
+    snapshot pool); the blocks through `_lm_decoder`; a float32 head without
+    bias, the embedding itself where the spec ties it.
     Returns (next_ids followed by the routed layers' counts, the K/V
     pools' names)."""
-    if model.residual != "pre" or model.positions != "rotary":
+    if model.residual != "pre" or model.positions == "sinusoid":
         raise NotImplementedError(
             "the paged ticks build the classic spec, latent attention or "
             "rotary grouped attention beside short convolutions, each in a "
@@ -1308,7 +1399,7 @@ def _kinds_paged_tick(model, n_slots, n_blocks, block_size, blocks_per_req,
         positions = layers.concat(
             [pos, layers.reshape(_window_positions(lpos, C),
                                  shape=[L * C, 1, 1])], axis=0)
-    conv = None
+    conv = ssm = None
     if model.attention == "latent":
         cache = _LatentPagedCache(cache_prefix, n_blocks, block_size, model,
                                   btab, pos, wblock, woff, lane_feeds)
@@ -1324,9 +1415,17 @@ def _kinds_paged_tick(model, n_slots, n_blocks, block_size, blocks_per_req,
                 cache_prefix, model, S, n_blocks, wblock,
                 lanes and dict(lane_feeds, block_size=block_size,
                                lslot=_feed("lane_slot", [lanes[0]])))
+        if model.ssm is not None:
+            ssm = _SsmState(
+                cache_prefix, model, S, n_snapshots,
+                lanes and dict(
+                    lpos=lpos, lrows=lrows, chunk=lanes[1],
+                    lslot=_feed("lane_slot", [lanes[0]]),
+                    **{n: _feed("lane_" + n, [lanes[0]])
+                       for n in ("snap_src", "snap_dst", "snap_rows")}))
     rows = _TickRows(model, positions,
                      _live_rows(wblock, lrows, lanes[1] if lanes else 0),
-                     NLB * block_size, conv)
+                     NLB * block_size, conv, ssm)
     x = _lm_decoder(_embed_rows(toks, model), cache.attend, model.num_layers,
                     model.d_model, model.d_inner, 0.0, spec=model, rows=rows)
     if conv is not None:
@@ -1354,7 +1453,8 @@ def transformer_lm_paged_mixed_tick(n_slots, n_lanes, chunk, n_blocks,
                                     block_size, blocks_per_req, vocab=32000,
                                     d_model=512, d_inner=2048, num_heads=8,
                                     num_layers=6, dropout=0.0, packed=False,
-                                    cache_prefix="pgd", model=None):
+                                    cache_prefix="pgd", model=None,
+                                    n_snapshots=0):
     """ONE tick of decode rows AND prefill lanes over the paged KV pools
     (`_PagedLaneCache`): `transformer_lm_paged_decode_tick`'s S decode rows
     (same feeds, same pools and weights by name) plus L = `n_lanes` lanes
@@ -1377,7 +1477,7 @@ def transformer_lm_paged_mixed_tick(n_slots, n_lanes, chunk, n_blocks,
     assert C % BS == 0, "a chunk is a whole number of blocks"
     if model is not None and not model.is_classic:
         return _kinds_paged_tick(model, S, n_blocks, BS, NLB, cache_prefix,
-                                 lanes=(L, C))
+                                 lanes=(L, C), n_snapshots=n_snapshots)
     tok, pos, btab, wblock, woff = _decode_feeds(S, NLB)
     ltok, lpos, lbtab, lwblocks, lrows, llast = _lane_feeds(L, C, NLB, BS)
     cache = _PagedLaneCache(

@@ -93,6 +93,7 @@ import numpy as np
 from ..core.enforce import InvalidArgumentError, enforce
 from ..framework import offload as _offload
 from ..framework.offload import HostTierConfig
+from ..framework.ownership import check_span_snapshot
 from ..observability import memory as _obs_memory
 from ..observability import tracing as _tracing
 from .engine import (ContinuousBatchingEngine, GenRequest, _ENGINE_SEQ,
@@ -186,13 +187,19 @@ class BlockTable:
     the leading `n_shared` entries came from the prefix index (read-only
     to this request — writes start at `shared_len`)."""
 
-    __slots__ = ("blocks", "n_shared", "shared_len")
+    __slots__ = ("blocks", "n_shared", "shared_len", "snapshot",
+                 "snapshot_write")
 
     def __init__(self, blocks: List[int], n_shared: int = 0,
                  shared_len: int = 0):
         self.blocks = list(blocks)
         self.n_shared = int(n_shared)
         self.shared_len = int(shared_len)
+        #: the snapshot-pool entry the request's first chunk starts from
+        #: (pinned from admission until that chunk ran), and (entry, logical
+        #: block) of the snapshot a lane of the tick in flight is writing
+        self.snapshot: Optional[int] = None
+        self.snapshot_write: Optional[Tuple[int, int]] = None
 
     def __len__(self):
         return len(self.blocks)
@@ -203,7 +210,7 @@ class BlockTable:
 
 
 class _RadixNode:
-    __slots__ = ("key", "block", "children", "parent", "last_used")
+    __slots__ = ("key", "block", "children", "parent", "last_used", "snap")
 
     def __init__(self, key, block, parent):
         self.key = key              # tuple of block_size token ids
@@ -211,6 +218,7 @@ class _RadixNode:
         self.children: Dict[tuple, "_RadixNode"] = {}
         self.parent = parent
         self.last_used = 0
+        self.snap: Optional[int] = None   # its entry of the snapshot pool
 
 
 class RadixPrefixIndex:
@@ -228,6 +236,9 @@ class RadixPrefixIndex:
         self.root = _RadixNode((), None, None)
         self._clock = 0
         self.n_cached = 0
+        #: called with every node that leaves the tree (the pager voids the
+        #: node's state snapshot)
+        self.on_evict: Optional[Callable[[_RadixNode], None]] = None
 
     def _tick(self) -> int:
         self._clock += 1
@@ -293,7 +304,20 @@ class RadixPrefixIndex:
         del victim.parent.children[victim.key]
         pool.release(victim.block)
         self.n_cached -= 1
+        if self.on_evict is not None:
+            self.on_evict(victim)
         return True
+
+    def node_of(self, prompt: Sequence[int],
+                logical_block: int) -> Optional[_RadixNode]:
+        """The node of block `logical_block` of `prompt`'s chain, if the
+        whole chain is cached (no LRU clock moves)."""
+        node = self.root
+        for key in self._keys(prompt, logical_block + 1):
+            node = node.children.get(key)
+            if node is None:
+                return None
+        return node
 
     def evict_all(self, pool: BlockPool) -> int:
         n = 0
@@ -333,7 +357,7 @@ class KVPager:
     def __init__(self, n_blocks: int, block_size: int,
                  prefix_sharing: bool = True,
                  host_tier: Optional[HostTierConfig] = None,
-                 block_state: bool = False):
+                 block_state: bool = False, n_snapshots: int = 0):
         self.block_size = int(block_size)
         self.prefix_sharing = bool(prefix_sharing)
         #: the model keeps a per-request STATE beside its per-token rows
@@ -348,6 +372,23 @@ class KVPager:
         self.state_snapshots = 0        # block snapshots written
         self.pool = BlockPool(n_blocks, block_size)
         self.index = RadixPrefixIndex(block_size)
+        #: ... or, where a copy of the state is megabytes (state-space
+        #: layers), a POOL of `n_snapshots` snapshots, far fewer than
+        #: blocks: an index node may hold an entry (`_RadixNode.snap`),
+        #: written by the lane whose chunk crosses the end of a prompt's
+        #: last whole block. A prefix hit is truncated to the deepest node
+        #: that holds one (the state-space layers would recompute the
+        #: positions beyond it anyway); an entry is taken least recently
+        #: used among the unpinned, and void once its node leaves the index.
+        self.n_snapshots = int(n_snapshots)
+        self._snap_node: List[Optional[_RadixNode]] = [None] * self.n_snapshots
+        self._snap_used = [0] * self.n_snapshots     # LRU clock an entry
+        self._snap_pins = [0] * self.n_snapshots     # admitted, not yet read
+        self._snap_clock = 0
+        self.snapshot_evictions = 0     # valid entries taken for another
+        self.hits_truncated = 0         # hits cut to a shallower snapshot
+        if self.n_snapshots:
+            self.index.on_evict = self._node_evicted
         self.host_tier = host_tier
         self.host_blocks_used = 0
         # -- counters (ptpu_engine_* gauges read these) --
@@ -399,6 +440,21 @@ class KVPager:
                     f"block {shared_nodes[-1].block} is in the prefix index "
                     f"without a state snapshot")
             self.state_restores += 1
+        entry = None
+        if self.n_snapshots and shared_nodes:
+            # the span ends at the deepest node that holds a snapshot
+            matched = len(shared_nodes)
+            while shared_nodes and shared_nodes[-1].snap is None:
+                shared_nodes.pop()
+            self.hits_truncated += len(shared_nodes) < matched
+            if shared_nodes:
+                entry = shared_nodes[-1].snap
+                # kin to `kv-state-snapshot-missing`: a span is never handed
+                # out past its snapshot
+                holder = self._snap_node[entry]
+                check_span_snapshot([n.block for n in shared_nodes],
+                                    holder and holder.block, "try_admit")
+                self._snap_pins[entry] += 1
         # pin the matched blocks FIRST: eviction under pressure below
         # may drop their index nodes, but a pinned block cannot free
         blocks = []
@@ -411,6 +467,8 @@ class KVPager:
             if b is None:                    # rollback, stay pending
                 for held in blocks:
                     self.pool.release(held)
+                if entry is not None:
+                    self._snap_pins[entry] -= 1
                 return None
             blocks.append(b)
         n_shared = len(shared_nodes)
@@ -419,7 +477,76 @@ class KVPager:
         if n_shared:
             self.prefix_hits += 1
             self.shared_blocks_total += n_shared
-        return BlockTable(blocks, n_shared, n_shared * self.block_size)
+        table = BlockTable(blocks, n_shared, n_shared * self.block_size)
+        if entry is not None:
+            table.snapshot = entry
+            if self._snap_node[entry] is not None:   # else: evicted just now
+                self._touch_snapshot(entry)
+            self.state_restores += 1
+        return table
+
+    # -- the snapshot pool ------------------------------------------------
+    @property
+    def snapshots_valid(self) -> int:
+        """Entries that hold an index node's snapshot."""
+        return sum(n is not None for n in self._snap_node)
+
+    def _touch_snapshot(self, entry: int):
+        self._snap_clock += 1
+        self._snap_used[entry] = self._snap_clock
+
+    def _node_evicted(self, node: _RadixNode):
+        """`node` left the index: its snapshot is void (an admitted request
+        that has yet to read it keeps its pin, and the entry with it)."""
+        if node.snap is not None:
+            self._snap_node[node.snap] = None
+            self._snap_used[node.snap] = 0
+            node.snap = None
+
+    def snapshot_read(self, table: BlockTable):
+        """The table's first chunk ran (or never will): unpin the entry it
+        was admitted onto."""
+        if table.snapshot is not None:
+            self._snap_pins[table.snapshot] -= 1
+            table.snapshot = None
+
+    def take_snapshot_entry(self, table: BlockTable,
+                            logical_block: int) -> Optional[int]:
+        """An entry for the state after block `logical_block` of `table`'s
+        prompt, which a lane of the tick being filled will write: the least
+        recently used unpinned one, its old holder's snapshot void from
+        now. Pinned until `snapshot_written`. None when every entry is
+        pinned (the lane writes none)."""
+        free = [e for e in range(self.n_snapshots) if not self._snap_pins[e]]
+        if not free:
+            return None
+        entry = min(free, key=self._snap_used.__getitem__)
+        node = self._snap_node[entry]
+        if node is not None:
+            node.snap = None
+            self._snap_node[entry] = None
+            self.snapshot_evictions += 1
+        self._snap_pins[entry] += 1
+        table.snapshot_write = (entry, logical_block)
+        return entry
+
+    def snapshot_written(self, table: BlockTable, prompt: Sequence[int]):
+        """The tick that wrote `table.snapshot_write` is done: hand the
+        entry to the index node of that block (registered by
+        `note_block_filled` just before, or an earlier request's), unless
+        the chain is broken or the node already holds one."""
+        entry, logical_block = table.snapshot_write
+        table.snapshot_write = None
+        self._snap_pins[entry] -= 1
+        self.state_snapshots += 1
+        node = self.index.node_of(prompt, logical_block) \
+            if self.prefix_sharing else None
+        if node is None or node.snap is not None:
+            self._snap_used[entry] = 0          # free again, first to go
+            return
+        node.snap = entry
+        self._snap_node[entry] = node
+        self._touch_snapshot(entry)
 
     def _alloc_or_evict(self) -> Optional[int]:
         while True:
@@ -500,6 +627,10 @@ class KVPager:
             if b:
                 self.pool.release(b)
         table.blocks = []
+        self.snapshot_read(table)
+        if table.snapshot_write is not None:     # its tick never committed
+            self._snap_pins[table.snapshot_write[0]] -= 1
+            table.snapshot_write = None
 
     def rollback(self, table: BlockTable, keep_len: int,
                  written_len: int) -> int:
@@ -658,6 +789,14 @@ class KVPager:
                 "restores": self.state_restores,
                 "snapshots": self.state_snapshots,
                 "blocks_with_snapshot": int(self._snap.sum())},
+            "snapshot_pool": None if not self.n_snapshots else {
+                "entries": self.n_snapshots,
+                "valid": self.snapshots_valid,
+                "pinned": sum(p > 0 for p in self._snap_pins),
+                "written": self.state_snapshots,
+                "restores": self.state_restores,
+                "evictions": self.snapshot_evictions,
+                "hits_truncated": self.hits_truncated},
             "host_tier": None if self.host_tier is None else {
                 "host_blocks": self.host_tier.host_blocks,
                 "host_blocks_used": self.host_blocks_used,
@@ -740,7 +879,7 @@ class PagedKVEngine(ContinuousBatchingEngine):
                  quant: Optional[str] = None, kv_quant: bool = False,
                  speculative=None,
                  host_tier: Optional[HostTierConfig] = None,
-                 model=None):
+                 model=None, n_snapshots: int = 0):
         from ..models.decoder_spec import DecoderSpec
         if model is None:
             model = DecoderSpec.classic(vocab, d_model, d_inner, num_heads,
@@ -769,6 +908,7 @@ class PagedKVEngine(ContinuousBatchingEngine):
                            if model.kv_heads != model.num_heads else "")
                         + (" beside short-convolution layers"
                            if model.conv else "")
+                        + (" beside state-space layers" if model.ssm else "")
                         + (" and routed experts" if model.moe else "")
                         + ": it walks the classic K/V pools and float32 "
                         "weights"
@@ -776,6 +916,10 @@ class PagedKVEngine(ContinuousBatchingEngine):
                            "blocks' snapshots), which it would neither "
                            "roll back, spill, fork nor quantize"
                            if model.conv else "")
+                        + (", not the state-space state (a slot's h and conv "
+                           "rows, the snapshot pool), which it would "
+                           "neither roll back, spill, fork nor quantize"
+                           if model.ssm else "")
                         + "; serve this model without it",
                         exc=InvalidArgumentError)
         #: (layer, held expert) -> rows routed to it since construction
@@ -814,6 +958,13 @@ class PagedKVEngine(ContinuousBatchingEngine):
         #: ... and of ONE copy of the conv layers' state: a slot holds
         #: one, and every pool block a snapshot (0 without conv layers)
         self.state_bytes = model.state_bytes()
+        #: entries of the snapshot pool (state-space layers only: their
+        #: state is too large for a snapshot a block)
+        self.n_snapshots = int(n_snapshots) if model.ssm is not None else 0
+        enforce(model.ssm is None or self.n_snapshots >= 1,
+                "a model with state-space layers needs n_snapshots >= 1: "
+                "without a snapshot every request prefills its whole prompt",
+                exc=InvalidArgumentError)
         per_blk_f32 = 2 * num_layers * num_heads * self.block_size * dh * 4
         per_blk_i8 = 2 * num_layers * num_heads * self.block_size * (dh + 4)
         self.kv_quant_freed_bytes = 0
@@ -852,10 +1003,13 @@ class PagedKVEngine(ContinuousBatchingEngine):
                 f"null block)", exc=InvalidArgumentError)
         self.pager = KVPager(self.n_blocks, self.block_size,
                              prefix_sharing, host_tier=host_tier,
-                             block_state=bool(self.state_bytes))
-        # the pager's `state_restores` at the last `engine/admit` span,
-        # which carries what was added since
+                             block_state=bool(self.state_bytes)
+                             and not self.n_snapshots,
+                             n_snapshots=self.n_snapshots)
+        # the pager's `state_restores` (and `snapshot_evictions`) at the
+        # last `engine/admit` span, which carries what was added since
         self._state_seen = 0
+        self._evictions_seen = 0
         # two-tier scheduler state: per-rid host residency records and
         # the FIFO of suspended requests (admission order — no
         # starvation, same discipline as the head-of-line device wait)
@@ -902,7 +1056,7 @@ class PagedKVEngine(ContinuousBatchingEngine):
                 block_size=self.block_size,
                 blocks_per_req=self.blocks_per_req,
                 cache_prefix=self._cache_prefix, model=self.model,
-                **self._builder_dims)
+                n_snapshots=self.n_snapshots, **self._builder_dims)
             self._mixed_ids = outs[0]
         self._init_missing_vars(startup)        # nothing, by construction
         if self.quant is not None:
@@ -954,7 +1108,8 @@ class PagedKVEngine(ContinuousBatchingEngine):
             block_size=self.block_size,
             blocks_per_req=self.blocks_per_req,
             cache_prefix=self._cache_prefix, topk_k=self.topk_k,
-            kv_quant=self.kv_quant, model=self.model, **d)
+            kv_quant=self.kv_quant, model=self.model,
+            n_snapshots=self.n_snapshots, **d)
         if self.topk_k:
             (self._next_ids, self.cache_names,
              self._topk_logp, self._topk_ids) = outs
@@ -1025,6 +1180,9 @@ class PagedKVEngine(ContinuousBatchingEngine):
             lf = self._lane_feeds
             for a in lf.values():
                 a[:] = 0                         # idle lane → null block
+            if self.n_snapshots:
+                lf["lane_snap_src"][:] = -1      # ... and no snapshot
+                lf["lane_snap_dst"][:] = -1
             bs, C = self.block_size, self.chunk_tokens
             cb = C // bs
             for lane, req in enumerate(prefilling[:self.n_lanes]):
@@ -1038,7 +1196,10 @@ class PagedKVEngine(ContinuousBatchingEngine):
                     blocks[b0:b0 + nb]
                 lf["lane_rows"][lane] = n
                 lf["lane_last"][lane] = lane * C + n - 1
-                if self.state_bytes:
+                if self.n_snapshots:
+                    lf["lane_slot"][lane] = req.slot
+                    snapshots += self._fill_lane_snapshots(lane, req, k0, n)
+                elif self.state_bytes:
                     # the lane leaves its last state in the request's slot,
                     # and a snapshot beside every block it fills
                     lf["lane_slot"][lane] = req.slot
@@ -1051,6 +1212,27 @@ class PagedKVEngine(ContinuousBatchingEngine):
         attrs["prefill_tokens"] = tokens
         if self.state_bytes:
             attrs["state_snapshots"] = snapshots
+
+    def _fill_lane_snapshots(self, lane: int, req: GenRequest, k0: int,
+                             n: int) -> int:
+        """The snapshot pool's part of a lane's feeds: the entry the chunk
+        starts from (the request's first chunk after a prefix hit; -1: its
+        slot's own state), and the entry it writes, where the chunk crosses
+        the end of the prompt's last whole block (-1: none). Returns the
+        snapshots the lane writes."""
+        lf, table = self._lane_feeds, req.table
+        if table.snapshot is not None and k0 == table.shared_len:
+            lf["lane_snap_src"][lane] = table.snapshot
+        end = len(req.prompt) // self.block_size * self.block_size
+        if not k0 < end <= k0 + n:
+            return 0
+        entry = self.pager.take_snapshot_entry(
+            table, end // self.block_size - 1)
+        if entry is None:
+            return 0
+        lf["lane_snap_dst"][lane] = entry
+        lf["lane_snap_rows"][lane] = end - k0
+        return 1
 
     def _launch_tick(self):
         # a tick with a slot in prefill is the mixed program (the decode
@@ -1099,6 +1281,10 @@ class PagedKVEngine(ContinuousBatchingEngine):
         for lb in range(k0 // bs, req.fed // bs):
             self.pager.note_block_filled(req.table, lb, req.prompt,
                                          snapshot=self.pager.block_state)
+        if self.n_snapshots:
+            self.pager.snapshot_read(req.table)   # the chunk started there
+            if req.table.snapshot_write is not None:
+                self.pager.snapshot_written(req.table, req.prompt)
         if req.fed < len(req.prompt):
             req.next_tok = req.prompt[req.fed]
             return False
@@ -1160,6 +1346,11 @@ class PagedKVEngine(ContinuousBatchingEngine):
             now = self.pager.state_restores
             attrs["state_restored"] = now - self._state_seen
             self._state_seen = now
+        if self.n_snapshots:
+            attrs["snapshots_used"] = self.pager.snapshots_valid
+            now = self.pager.snapshot_evictions
+            attrs["snapshot_evictions"] = now - self._evictions_seen
+            self._evictions_seen = now
         return attrs
 
     def _release_request(self, req: GenRequest):
@@ -1541,7 +1732,15 @@ class PagedKVEngine(ContinuousBatchingEngine):
         s["kv_quant"] = {"enabled": self.kv_quant,
                          "freed_bytes": self.kv_quant_freed_bytes}
         s["block_bytes"] = self.block_bytes
-        if self.state_bytes:
+        if self.n_snapshots:
+            # the second kind of state, too large for a snapshot a block: a
+            # copy a slot, and the pool's entries
+            s["ssm_state"] = dict(
+                s["pager"]["snapshot_pool"],
+                bytes_per_copy=self.state_bytes,
+                slot_bytes=self.state_bytes * self.n_slots,
+                pool_bytes=self.state_bytes * self.n_snapshots)
+        elif self.state_bytes:
             # the second kind of state: a copy a slot, a snapshot a block
             s["conv_state"] = dict(
                 self.pager.stats()["block_state"],

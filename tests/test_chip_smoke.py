@@ -68,6 +68,23 @@ def test_hybrid_serving_phase():
     assert out["block_bytes"] == 1 * 2 * 2 * 8 * 2 * 8    # one attention layer
 
 
+def test_serve_ssm_phase():
+    """The smoke's state-space engine (Mamba-2 mixers with a float32 state,
+    two key/value heads, latent two-matrix experts of which a share is held)
+    at a tiny size: prefix-hit requests equal their self-prefilled twins, and
+    do not once the pool's entries are swapped."""
+    out = chip_smoke.phase_serve_ssm(
+        vocab=97, d_model=64, num_heads=8, num_kv_heads=2, d_head=8,
+        ssm=(16, 8, 4, 16), latent=32, d_expert=48, d_shared=96, n_routed=16,
+        n_held=4, top_k=6, n_slots=4, block_size=8, n_blocks=40,
+        n_snapshots=4, max_len=64, preamble=24, turns=(5, 11), max_new=6,
+        expect_lowering="composite")
+    assert out["ssm_state"]["restores"] == 2 and out["tokens_out"] == 12
+    assert out["ssm_state"]["written"] >= 2
+    assert out["swapped_state_differs_at"] < 6  # the planted fault is refused
+    assert out["block_bytes"] == 1 * 2 * 2 * 8 * 2 * 8    # one attention layer
+
+
 def test_train_resnet_phase():
     out = chip_smoke.phase_train_resnet50(batch=2, steps=2, depth=18,
                                           image=32)

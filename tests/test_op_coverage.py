@@ -1679,6 +1679,8 @@ COVERED_ELSEWHERE = {
     "moe_route": "tests/test_routed_experts.py",
     "short_conv": "tests/test_short_conv.py",
     "conv_state_commit": "tests/test_short_conv.py",
+    "ssm_scan": "tests/test_ssm.py",
+    "gated_rms_norm": "tests/test_ssm.py",
     "moe_experts": "tests/test_routed_experts.py",
     # the paged ticks' cache read through the block table: the Pallas
     # kernel against the composite, and the composite against dense

@@ -503,3 +503,36 @@ class TestKillSwitch:
         flags.set_flag("kv_sanitize", False)
         without = build()
         assert with_san == without
+
+
+class TestSnapshotPoolRule:
+    """The snapshot pool's rule: a shared span ends at the block its
+    snapshot is the state after (`kv-span-past-snapshot`)."""
+
+    def test_a_span_that_ends_at_its_snapshot_passes(self):
+        from paddle_tpu.framework.ownership import check_span_snapshot
+        check_span_snapshot([3, 4, 9], 9, "admit")
+        check_span_snapshot([], None, "admit")
+
+    def test_a_span_past_its_snapshot_is_named(self):
+        from paddle_tpu.framework.ownership import check_span_snapshot
+        with pytest.raises(OwnershipViolation) as e:
+            check_span_snapshot([3, 4, 9], 4, "admit")
+        assert e.value.code == "kv-span-past-snapshot" and e.value.block == 9
+        assert "kv-span-past-snapshot" in DIAGNOSTICS
+
+    def test_the_pager_refuses_a_snapshot_that_moved_under_its_node(self):
+        from paddle_tpu.serving.kv_pager import KVPager
+        pager = KVPager(12, 4, n_snapshots=2)
+        prompt = list(range(9))
+        table = pager.try_admit(prompt, 12)
+        for lb in range(2):
+            pager.note_block_filled(table, lb, prompt)
+        assert pager.take_snapshot_entry(table, 1) == 0
+        pager.snapshot_written(table, prompt)
+        hit = pager.try_admit(prompt, 12)
+        assert hit.shared_len == 8 and hit.snapshot == 0
+        # the planted bug: the entry is said to be another block's state
+        pager._snap_node[0] = pager.index.node_of(prompt, 0)
+        with pytest.raises(OwnershipViolation, match="kv-span-past-snapshot"):
+            pager.try_admit(prompt, 12)

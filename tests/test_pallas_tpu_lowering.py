@@ -416,3 +416,64 @@ def test_equal_heads_float32_pools_keep_their_kernels():
             S((2, positions, 1024), F32), pool, pool, S((2, 18), I32),
             S((2, 1, 1), F32))
         assert _n_calls(text) == 1 and "paged_gqa_attention" not in text
+
+
+# -- the state-space decode update, latent experts, two key/value heads ------
+
+def _ssm_update(slots=64):
+    from paddle_tpu.fusion import ssm
+    h, p, g, n = 128, 64, 8, 128
+    args = [S((slots, h, p, n), F32), S((slots,), F32), S((slots, h, p), BF16),
+            S((slots, g, n), BF16), S((slots, g, n), BF16), S((slots, h), F32),
+            S((slots, h), F32)]
+    return (lambda st, live, x, b, c, dt, dec: ssm.ssm_decode_update(
+        st, live, x, b, c, dt, dec, backend="pallas")), args
+
+
+def test_ssm_decode_update_compiles_for_v5e_in_place(one_chip):
+    """The decode update at the published widths (64 slots of 128 heads x 64
+    x 128 float32, 4 MB a slot: a whole slot a grid step, 17 MB of VMEM
+    double-buffered) through the TPU compiler for a v5e, the state aliased
+    onto its input."""
+    f, args = _ssm_update()
+    text = _tpu_text(f, *args)
+    assert _n_calls(text) == 1 and "ssm_decode_update" in jax.jit(f).trace(
+        *args).lower(lowering_platforms=("tpu",)).as_text(debug_info=True)
+    assert "output_operand_aliases" in text or "operand_index" in text
+    args = [S(a.shape, a.dtype, sharding=one_chip) for a in args]
+    compiled = jax.jit(f, donate_argnums=0).lower(*args).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    assert "f32[64,128,1,64]" in compiled.as_text()
+
+
+@pytest.mark.parametrize("rows", [64, 320])
+def test_latent_expert_product_compiles_for_v5e(one_chip, rows):
+    """128 held experts of 1024 x 2688 and 2688 x 1024, a whole expert a grid
+    step (2,688 is no multiple of the gated kernel's tile of 256), under the
+    decode tick's 64 rows and the mixed tick's 64 + 2 * 128."""
+    from paddle_tpu.fusion import moe
+    args = [S((rows, 1024), BF16), S((128, rows, 1), F32), S((128,), I32),
+            S((128, 1024, 2688), BF16), S((128, 2688, 1024), BF16)]
+    f = lambda x, w, n, u, d: moe.experts(x, w, n, None, u, d,  # noqa: E731
+                                          backend="pallas")
+    assert _n_calls(_tpu_text(f, *args)) == 1
+    args = [S(a.shape, a.dtype, sharding=one_chip) for a in args]
+    text = jax.jit(f).lower(*args).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert f"f32[{rows},1024]" in text
+
+
+@pytest.mark.parametrize("slots, positions", [(64, 1), (2, 128)])
+def test_two_kv_head_read_compiles_for_v5e(one_chip, slots, positions):
+    """32 query heads over 2 key/value heads of 128: a head is a whole
+    128-lane pool row and a group of 16 is two sublane tiles of query rows
+    (the assistant cell's heads are halves of a row in groups of 4)."""
+    from paddle_tpu.fusion import paged_decode_attention
+    pool = S((2048, 2, 64, 128), BF16)
+    args = [S((slots, positions, 4096), BF16), pool, pool,
+            S((slots, 24), I32), S((slots, 1, 1), F32), S((slots,), I32)]
+    f = lambda q, k, v, t, p, r: paged_decode_attention(  # noqa: E731
+        q, k, v, t, p, 32, scale=128 ** -0.5, backend="pallas", rows=r)
+    args = [S(a.shape, a.dtype, sharding=one_chip) for a in args]
+    assert jax.jit(f).lower(*args).compile().as_text().count(
+        "tpu_custom_call") == 1

@@ -222,3 +222,119 @@ def test_a_spec_without_a_shared_expert_and_with_the_bias():
     with pytest.raises(NotImplementedError, match="group_limited_greedy"):
         MoESpec(n_routed=32, top_k=4, d_expert=64, held=(0,),
                 topk_method="group_limited_greedy")
+
+
+# -- latent experts of two matrices (relu^2, no gate) ------------------------
+
+Z, FL, FS = 128, 384, 256        # latent width, expert width (3 x 128), shared
+L_DN = jnp.asarray(RNG.normal(size=(D, Z)) * D ** -0.5, jnp.float32)
+L_UP = jnp.asarray(RNG.normal(size=(Z, D)) * Z ** -0.5, jnp.float32)
+UP2 = jnp.asarray(RNG.normal(size=(E, Z, FL)) * Z ** -0.5, jnp.float32)
+DOWN2 = jnp.asarray(RNG.normal(size=(E, FL, Z)) * FL ** -0.5, jnp.float32)
+S_UP = jnp.asarray(RNG.normal(size=(D, FS)) * D ** -0.5, jnp.float32)
+S_DOWN = jnp.asarray(RNG.normal(size=(FS, D)) * FS ** -0.5, jnp.float32)
+BIAS2 = jnp.asarray(RNG.normal(size=(E,)) * 0.05, jnp.float32)
+
+
+def test_four_latent_shares_and_one_shared_expert_add_up_to_the_uncut_layer():
+    """Four expert ranks that hold eight of the 32 latent experts each: their
+    parts of the routed sum (biased top-6, scaling 5) go through the shared
+    up-projection, which is linear and has no bias, so the parts add up; with
+    the shared expert counted once they are the reference's layer with every
+    expert held (benchmark/models/nemotron_h_reference.py)."""
+    from nemotron_h_tiny import ref as nemo_ref
+    p = {"m_router.w_0": W_R, "m_router_bias": BIAS2,
+         "m_latent_down.w_0": L_DN, "m_latent_up.w_0": L_UP,
+         "m_experts_up": UP2, "m_experts_down": DOWN2,
+         "m_shared_up.w_0": S_UP, "m_shared_down.w_0": S_DOWN}
+    cfg = dict(num_experts_per_tok=6, n_routed_experts=E,
+               routed_scaling_factor=5)
+    with jax.default_matmul_precision("highest"):
+        want = np.asarray(nemo_ref.moe(X, p, "m", cfg))
+        z = X @ L_DN
+        total = jnp.square(jax.nn.relu(X @ S_UP)) @ S_DOWN
+        touched = 0
+        for share in range(4):
+            held = tuple(range(share * 8, share * 8 + 8))
+            w, rows = moe.route(X, W_R, held, 6, 5.0, bias=BIAS2,
+                                norm_eps=1e-20)
+            sl = slice(held[0], held[-1] + 1)
+            part = moe.experts(z, w, rows, None, UP2[sl], DOWN2[sl],
+                               backend="xla")
+            total = total + part @ L_UP
+            touched += int(np.asarray(rows).sum())
+            # one rank alone is the reference given that rank's experts
+            if share == 0:
+                alone = nemo_ref.moe(X, dict(p), "m",
+                                     dict(cfg, n_routed_experts=8))
+                shared = jnp.square(jax.nn.relu(X @ S_UP)) @ S_DOWN
+                np.testing.assert_allclose(
+                    np.asarray(part @ L_UP + shared), np.asarray(alone),
+                    atol=5e-5)
+    assert touched == N * 6
+    np.testing.assert_allclose(np.asarray(total), want, atol=1e-4)
+
+
+@pytest.mark.parametrize("held", [(0, 1, 2, 3), (5, 11, 17, 29)])
+def test_two_matrix_kernel_equals_composite_and_skips_the_unselected(held):
+    live = jnp.asarray((np.arange(N) < 6).astype(np.float32))
+    w, rows = moe.route(X, W_R, held, 2, 5.0, live=live, bias=BIAS2,
+                        norm_eps=1e-20)
+    assert (np.asarray(rows) == 0).any() and (np.asarray(rows) > 0).any()
+    idx = jnp.asarray(held)
+    z = X @ L_DN
+    a = np.asarray(moe.experts(z, w, rows, None, UP2[idx], DOWN2[idx],
+                               backend="xla"))
+    b = np.asarray(moe.experts(z, w, rows, None, UP2[idx], DOWN2[idx],
+                               backend="pallas_interpret"))
+    np.testing.assert_allclose(a, b, atol=2e-5)
+    assert not a[6:].any() and np.abs(a[:6]).max() > 0.01
+    dead = np.asarray(rows) == 0
+    poison = jnp.where(jnp.asarray(dead)[:, None, None], jnp.nan, UP2[idx])
+    c = np.asarray(moe.experts(z, w, rows, None, poison, DOWN2[idx],
+                               backend="pallas_interpret"))
+    np.testing.assert_allclose(c, b, atol=1e-6)
+
+
+def test_the_two_matrix_kernels_tile_comes_from_the_shape():
+    # a whole expert a step where two tiles of it, double-buffered, fit
+    assert moe.relu2_tile(1024, 2688, 2) == 2688
+    assert moe.relu2_tile(128, 384, 4) == 384
+    # a wider row: the largest divisor in whole 128-lane rows that fits
+    assert moe.relu2_tile(8192, 2688, 2) == 384
+    # a width that is no multiple of 128 has none: the composite
+    assert moe.relu2_tile(32, 48, 4) == 0
+    assert moe.experts_lowering(64, 1024, 2688, "pallas",
+                                moe.relu2_tile(1024, 2688, 2)) == moe.KERNEL
+    assert moe.experts_lowering(64, 32, 48, "pallas", 0) == moe.COMPOSITE
+    # 2,688 is no multiple of the gated kernel's 256
+    assert moe.experts_lowering(64, 1024, 2688, "pallas") == moe.COMPOSITE
+
+
+def test_the_gated_product_keeps_its_kernel_and_its_name():
+    S = jax.ShapeDtypeStruct
+    args = [S((32, 128), jnp.bfloat16), S((4, 32, 1), jnp.float32),
+            S((4,), jnp.int32), S((4, 128, 256), jnp.bfloat16),
+            S((4, 128, 256), jnp.bfloat16), S((4, 256, 128), jnp.bfloat16)]
+    gated = jax.jit(lambda x, w, n, g, u, d: moe.experts(
+        x, w, n, g, u, d, backend="pallas")).trace(*args).lower(
+        lowering_platforms=("tpu",)).as_text(debug_info=True)
+    assert "moe_experts" in gated and "latent_experts" not in gated
+    two = jax.jit(lambda x, w, n, u, d: moe.experts(
+        x, w, n, None, u, d, backend="pallas")).trace(
+        *args[:3], *args[4:]).lower(
+        lowering_platforms=("tpu",)).as_text(debug_info=True)
+    assert "latent_experts" in two and two.count("tpu_custom_call") == 1
+
+
+def test_a_latent_spec_names_its_activation_and_widths():
+    spec = MoESpec(n_routed=512, top_k=22, d_expert=2688,
+                   held=tuple(range(128)), n_shared=1, first_dense=0,
+                   scaling=5.0, topk_method="bias", norm_eps=1e-20,
+                   activation="relu2", latent=1024, d_shared=5376)
+    assert spec.shared_width == 5376 and spec.latent == 1024
+    assert MoESpec(n_routed=8, top_k=2, d_expert=64, held=(0,),
+                   n_shared=2).shared_width == 128
+    with pytest.raises(NotImplementedError, match="swiglu"):
+        MoESpec(n_routed=8, top_k=2, d_expert=64, held=(0,),
+                activation="swiglu")

@@ -298,14 +298,31 @@ def phase_serve_lm(scope, vocab=32000, max_len=1024, d_model=1024,
            f"tick {n_mixed}; expected {expect_lowering!r} with "
            f"{want_calls} and {2 * want_calls}")
     # a launch of either tick hands the device ONE host array: the feeds
-    # and the seed, packed (PreparedStep.bind)
-    host_args = {n: d["host_args"] for n, d in stats["dispatch"].items()}
+    # and the seed, packed (PreparedStep.bind), `tick_from_last` among them
+    dispatch = dict(stats["dispatch"])
+    late_reads = dispatch.pop("late_reads")
+    host_args = {n: d["host_args"] for n, d in dispatch.items()}
     _check(host_args == {"main": 1, "mixed": 1},
            f"a launch hands over {host_args} host arrays; expected one for "
            f"the decode tick and one for the mixed tick")
+    # most ticks left their ids on the device, where the next tick's decode
+    # rows took them, and the host read them a launch late (engine.py
+    # `_plain_tick`): the same requests through the same engine, every tick
+    # read at once and the prompts prefilled anew, emit the same tokens
+    _check(0 < late_reads < stats["ticks"],
+           f"{late_reads} of {stats['ticks']} ticks were read a launch late")
+    eng.pager.index.evict_all(eng.pager.pool)
+    eng._late_ok = False
+    eager = [eng.submit(p, max_new) for p in prompts]
+    eng.run_until_idle()
+    _check([r.tokens for r in eager] == result["tokens"],
+           "the ticks read a launch late did not emit the eager order's "
+           "tokens")
+    _check(eng.stats()["dispatch"]["late_reads"] == late_reads,
+           "an engine made to read every tick at once read one late")
     return {"compile_s": round(compile_s, 2),
             "run_s": round(result["run_s"], 2),
-            "host_args": host_args,
+            "host_args": host_args, "late_reads": late_reads,
             "requests": n_requests + 1, "max_new": max_new,
             "prompt_lens": [len(p) for p in prompts],
             "ticks": stats["ticks"], "tokens_out": stats["tokens_out"],

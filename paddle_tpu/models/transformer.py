@@ -847,10 +847,10 @@ def _decode_feeds(S, NLB):
     """The paged decode tick's feeds, declared here and nowhere else (the
     mixed ticks lead with them, in this order: a bound step lays its feeds
     out in declaration order and the decode step shares the leading span of
-    the mixed step's buffer) -> (tok, pos, btab, wblock, woff)."""
+    the mixed step's buffer) -> (tok, pos, btab, wblock, woff, from_last)."""
     return (_feed("tick_tok", [S, 1]), _feed("tick_pos", [S, 1, 1], "float32"),
             _feed("tick_btab", [S, NLB]), _feed("tick_wblock", [S]),
-            _feed("tick_woff", [S]))
+            _feed("tick_woff", [S]), _feed("tick_from_last", [S, 1]))
 
 
 def _lane_feeds(L, C, NLB, block_size):
@@ -881,6 +881,41 @@ def _slot_cache_var(name, shape, dtype="float32"):
     sb.append_op("fill_constant", outputs={"Out": [sv.name]},
                  attrs={"shape": list(shape), "value": 0.0, "dtype": dtype})
     return var
+
+
+class _LastIds:
+    """The ids a tick's `n_slots` decode rows sampled, kept on the device for
+    the next tick: `{cache_prefix}_last_ids` [S,1] int64, persistable, one
+    name and one shape in every tick program of an engine, so it rides their
+    donated read-write state like the caches. `feed(tok, from_last)` is the
+    token each decode row consumes: the one the last tick sampled for its
+    slot where `from_last` [S,1] is set (the host has not read that tick's
+    ids yet: serving/engine.py reads them a launch late), else the host's
+    `tok`. `keep(next_ids)` leaves this tick's first S ids for the next; the
+    fetch the host reads stays an output of its own."""
+
+    def __init__(self, cache_prefix, n_slots):
+        self.S = n_slots
+        self.var = _slot_cache_var(f"{cache_prefix}_last_ids", [n_slots, 1],
+                                   dtype="int64")
+
+    def feed(self, tok, from_last):
+        from ..layer_helper import LayerHelper
+        helper = LayerHelper("where")
+        out = helper.create_tmp_variable(dtype="int64", shape=[self.S, 1],
+                                         stop_gradient=True)
+        late = layers.greater_than(from_last,
+                                   layers.fill_constant([1], "int64", 0))
+        helper.append_op(type="where",
+                         inputs={"Condition": [late], "X": [self.var],
+                                 "Y": [tok]}, outputs={"Out": [out]})
+        return out
+
+    def keep(self, next_ids):
+        if next_ids.shape[0] != self.S:
+            next_ids = layers.slice(next_ids, axes=[0], starts=[0],
+                                    ends=[self.S])
+        layers.assign(next_ids, output=self.var)
 
 
 class _SlotCache:
@@ -978,15 +1013,19 @@ def transformer_lm_decode_tick(n_slots, vocab=32000, max_len=64,
     S, T = n_slots, max_len
     tok = _feed("tick_tok", [S, 1])
     pos = _feed("tick_pos", [S, 1, 1], "float32")
+    from_last = _feed("tick_from_last", [S, 1])
     cache = _SlotCache(cache_prefix, S, T, num_heads, d_model // num_heads,
                        num_layers, 0.0 if packed else dropout)
-    x = _gen_embed_step(tok, pos, f"{param_prefix}tok_emb", vocab, d_model,
+    last = _LastIds(cache_prefix, S)
+    x = _gen_embed_step(last.feed(tok, from_last), pos,
+                        f"{param_prefix}tok_emb", vocab, d_model,
                         positional_encoding_table(T, d_model), dropout)
     cache.open_window(pos)
     x = _lm_decoder(x, cache.attend, num_layers, d_model, d_inner, dropout,
                     param_prefix=param_prefix)
     _, next_ids, logp = _lm_head(x, vocab, f"{param_prefix}lm_head",
                                  logp=emit_logp)
+    last.keep(next_ids)
     if emit_logp:
         return next_ids, cache.names, logp
     return next_ids, cache.names
@@ -1137,16 +1176,18 @@ def transformer_lm_paged_decode_tick(n_slots, n_blocks, block_size,
                                  blocks_per_req, cache_prefix,
                                  n_snapshots=n_snapshots)
     S, NLB = n_slots, blocks_per_req
-    tok, pos, btab, wblock, woff = _decode_feeds(S, NLB)
+    tok, pos, btab, wblock, woff, from_last = _decode_feeds(S, NLB)
     cache = _PagedCache(
         cache_prefix, n_blocks, block_size, num_heads, d_model // num_heads,
         num_layers, btab, pos, wblock, woff, 0.0 if packed else dropout,
         kv_quant)
+    last = _LastIds(cache_prefix, S)
     x = _gen_embed_step(
-        tok, pos, "tok_emb", vocab, d_model,
+        last.feed(tok, from_last), pos, "tok_emb", vocab, d_model,
         positional_encoding_table(NLB * block_size, d_model), dropout)
     x = _lm_decoder(x, cache.attend, num_layers, d_model, d_inner, dropout)
     _, next_ids, logp = _lm_head(x, vocab, logp=bool(topk_k))
+    last.keep(next_ids)
     if topk_k:
         return (next_ids, cache.names, *layers.topk(logp, k=topk_k))
     return next_ids, cache.names
@@ -1386,7 +1427,9 @@ def _kinds_paged_tick(model, n_slots, n_blocks, block_size, blocks_per_req,
             f"{model.residual!r}, positions {model.positions!r} has no "
             "cache seam yet")
     S, NLB = n_slots, blocks_per_req
-    tok, pos, btab, wblock, woff = _decode_feeds(S, NLB)
+    tok, pos, btab, wblock, woff, from_last = _decode_feeds(S, NLB)
+    last = _LastIds(cache_prefix, S)
+    tok = last.feed(tok, from_last)
     toks, positions, lane_feeds, lrows = tok, pos, None, None
     if lanes is not None:
         L, C = lanes
@@ -1446,6 +1489,7 @@ def _kinds_paged_tick(model, n_slots, n_blocks, block_size, blocks_per_req,
     else:
         _, next_ids, _ = _lm_head(x, model.vocab, bias=False,
                                   out_dtype="float32")
+    last.keep(next_ids)
     return rows.with_counts(next_ids), cache.names
 
 
@@ -1478,8 +1522,10 @@ def transformer_lm_paged_mixed_tick(n_slots, n_lanes, chunk, n_blocks,
     if model is not None and not model.is_classic:
         return _kinds_paged_tick(model, S, n_blocks, BS, NLB, cache_prefix,
                                  lanes=(L, C), n_snapshots=n_snapshots)
-    tok, pos, btab, wblock, woff = _decode_feeds(S, NLB)
+    tok, pos, btab, wblock, woff, from_last = _decode_feeds(S, NLB)
     ltok, lpos, lbtab, lwblocks, lrows, llast = _lane_feeds(L, C, NLB, BS)
+    last = _LastIds(cache_prefix, S)
+    tok = last.feed(tok, from_last)
     cache = _PagedLaneCache(
         cache_prefix, n_blocks, BS, num_heads, d_model // num_heads,
         num_layers, btab, pos, wblock, woff, 0.0 if packed else dropout,
@@ -1499,6 +1545,7 @@ def transformer_lm_paged_mixed_tick(n_slots, n_lanes, chunk, n_blocks,
             layers.gather(layers.reshape(xl, shape=[L * C, d_model]), llast),
             shape=[L, 1, d_model])], axis=0)              # [S+L,1,H]
     _, next_ids, _ = _lm_head(heads, vocab)               # [S+L,1] int64
+    last.keep(next_ids)
     return next_ids, cache.names
 
 

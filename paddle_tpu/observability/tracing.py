@@ -294,11 +294,14 @@ class span:
         parent = self._parent
         self._parent = None
         tags = getattr(_tls, "tags", None)
+        if tags:
+            self.attrs = {**tags, **self.attrs}
+        # the record holds the scope's own dict: a count that comes in after
+        # the exit (a tick's ids read a launch late) still lands on it
         _record(Span(self.kind, self.name, self._start, end,
                      threading.get_ident(),
                      parent.name if parent is not None else "", depth,
-                     {**tags, **self.attrs} if tags else self.attrs,
-                     next(_seq), self.id,
+                     self.attrs, next(_seq), self.id,
                      parent.id if parent is not None else -1))
         return False
 

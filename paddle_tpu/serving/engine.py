@@ -131,7 +131,8 @@ class GenRequest:
                  "first_token_pc", "done_pc", "sent_at", "sent_pc",
                  "defer_transport", "table", "shared_len",
                  "spec_draft_s", "spec_verify_s", "error",
-                 "admitted_tick", "ticks_to_first", "lane_wait_ticks")
+                 "admitted_tick", "ticks_to_first", "lane_wait_ticks",
+                 "closed")
 
     def __init__(self, rid, prompt, max_new, eos_id=None, on_done=None,
                  request_id: Optional[str] = None,
@@ -145,7 +146,14 @@ class GenRequest:
         self.tokens: List[int] = []
         self.slot: Optional[int] = None
         self.fed = 0                       # positions consumed so far
-        self.next_tok = self.prompt[0]     # token the next tick feeds
+        #: token the next tick feeds; None: the one the last tick sampled,
+        #: still on the device (that tick's ids are read a launch late)
+        self.next_tok: Optional[int] = self.prompt[0]
+        #: takes no further row: it ended by count on a tick whose ids are
+        #: not read yet, or its `eos` was read while its next row was in
+        #: flight. It keeps its slot (and stays in the engine's `_active`)
+        #: until its completion is delivered and that row is dropped
+        self.closed = False
         self.submitted_at = time.time()
         self.submitted_pc = time.perf_counter()
         self.admitted_at: Optional[float] = None
@@ -255,11 +263,12 @@ class ContinuousBatchingEngine:
     fresh engine also runs standalone (random weights — tests, benches).
     """
 
-    #: one tick in this many realizes its ids in two parts, under
-    #: `engine/device_wait` and `engine/copy_back`: enough for a window's
-    #: medians (800 of 13,000 ticks), too few to move one (two parts cost
-    #: the thread a second sleep and wake-up, 0.08 ms a tick on a TPU host:
-    #: PERF.md section 6, PR 41)
+    #: one EAGER tick in this many realizes its ids in two parts, under
+    #: `engine/device_wait` and `engine/copy_back` (two parts cost the thread
+    #: a second sleep and wake-up in front of a first token, 0.08 ms on a TPU
+    #: host: PERF.md section 6, PR 41). A tick read late has the two parts by
+    #: construction, the second beside the next tick, so the medians have
+    #: every one of those
     WAIT_SPLIT_EVERY = 16
 
     #: how a prompt is consumed (`stats()["prefill"]`): one token a tick
@@ -365,6 +374,7 @@ class ContinuousBatchingEngine:
             self.scope).bind(self._feeds)
         self._tok = self._feeds["tick_tok"]
         self._pos = self._feeds["tick_pos"]
+        self._from_last = self._feeds["tick_from_last"]
         #: the bound steps by the name `_run_bound_step` knows them under
         #: (`stats()["dispatch"]`; `_target_state_owner` names the one
         #: that ran last)
@@ -375,6 +385,12 @@ class ContinuousBatchingEngine:
         # whichever runs after the other refreshes first
         # (PreparedStep.refresh_state); pure steady states never refresh.
         self._target_state_owner = "main"
+        #: the tick whose ids are still on the device: (its `engine/tick`
+        #: span, the fetch, the (request, row) pairs whose row is a sampled
+        #: token). `_plain_tick` reads and commits it after the next launch
+        self._uncommitted = None
+        #: ticks whose ids were read a launch late (`stats()["dispatch"]`)
+        self.late_reads = 0
         # census counters (tools/bench_serve.py occupancy evidence)
         self.n_ticks = 0
         self.busy_slot_ticks = 0
@@ -409,6 +425,10 @@ class ContinuousBatchingEngine:
             # tick — same resident payloads), binds both spec steps,
             # registers the spec gauges
             self.spec.finalize()
+        #: may a tick's ids be read a launch late (`_plain_tick`)? Not in an
+        #: engine built with something that reads or moves a tick's results
+        #: between ticks
+        self._late_ok = not self._commits_every_tick()
 
     # -- tick-program construction (overridden by PagedKVEngine) ----------
     def _build_tick_program(self):
@@ -425,11 +445,15 @@ class ContinuousBatchingEngine:
         return [self._next_ids]
 
     def _fill_tick_feeds(self, active: Dict[int, "GenRequest"]):
-        tok, pos = self._tok, self._pos
+        tok, pos, from_last = self._tok, self._pos, self._from_last
         tok[:] = 0
         pos[:] = 0.0
+        from_last[:] = 0
         for slot, req in active.items():
-            tok[slot, 0] = req.next_tok
+            if req.next_tok is None:     # the last tick left it on the device
+                from_last[slot, 0] = 1
+            else:
+                tok[slot, 0] = req.next_tok
             pos[slot, 0, 0] = float(req.fed)
 
     def _stamp_kv_watermarks(self, active: Dict[int, "GenRequest"]):
@@ -726,19 +750,32 @@ class ContinuousBatchingEngine:
         with self._lock:
             return len(self._pending)
 
-    def _advance_slot(self, req: GenRequest, out_id: int) -> bool:
-        """Advance `req` one position with the model's output `out_id`
-        for that position — the per-slot commit shared by the plain tick
-        and every speculative verify position (identical phase stamps and
-        finish semantics by construction). Returns True when the request
-        just finished (max_new / eos / out of room)."""
+    def _commits_every_tick(self) -> bool:
+        """Was the engine built with something that needs a tick's ids (or
+        its blocks) before the next launch? A speculative round reads its
+        ids inside the round; the paged engine adds its own. Such an engine
+        commits every tick before `step()` returns."""
+        return self.spec is not None
+
+    def _advance_position(self, req: GenRequest) -> bool:
+        """The half of a slot's commit that needs no ids: `req` consumed
+        one position. Returns True when that position's output is a
+        sampled token (`_emit_token`'s), False while the prompt goes on."""
         k = req.fed                    # the position just consumed
         req.fed += 1
         self._note_position_written(req, k)
         if k < len(req.prompt) - 1:
             req.next_tok = req.prompt[k + 1]     # still prefilling
             return False
-        return self._emit_token(req, out_id)
+        return True
+
+    def _advance_slot(self, req: GenRequest, out_id: int) -> bool:
+        """Advance `req` one position with the model's output `out_id`
+        for that position — the per-slot commit shared by the plain tick
+        and every speculative verify position (identical phase stamps and
+        finish semantics by construction). Returns True when the request
+        just finished (max_new / eos / out of room)."""
+        return self._advance_position(req) and self._emit_token(req, out_id)
 
     def _emit_token(self, req: GenRequest, out_id: int) -> bool:
         """`out_id` is the token sampled after `req`'s last consumed
@@ -756,60 +793,76 @@ class ContinuousBatchingEngine:
         self._m_tokens.inc()
         req.next_tok = t
         hit_eos = (req.eos_id is not None and t == req.eos_id)
-        out_of_room = req.fed >= self.max_len
-        return len(req.tokens) >= req.max_new or hit_eos or out_of_room
+        return self._ends_by_count(req, 0) or hit_eos
+
+    def _ends_by_count(self, req: GenRequest, unread: int = 1) -> bool:
+        """Does `req` end with the position it consumed last, whatever the
+        token: `max_new` reached or out of room? `unread`: the sampled
+        tokens of it that are not in `req.tokens` yet."""
+        return (len(req.tokens) + unread >= req.max_new
+                or req.fed >= self.max_len)
 
     def step(self) -> List[GenRequest]:
-        """One decode step: admit, run, collect. Returns the requests
-        that COMPLETED on this step. A no-op (returns []) when nothing is
+        """One decode step: admit, run, collect. Returns the requests whose
+        completion this step DELIVERED. A no-op (returns []) when nothing is
         active or pending. Without speculation (or when any active
         request is too close to its length cap to take a full window)
         this is one plain tick, recorded as a "tick" span and observed
         into the tick-latency histogram; with `speculative=` it is one
         speculative round (γ+1 draft ticks + one verify forward —
         `speculate`/`verify` spans) advancing every slot up to γ+1
-        positions."""
+        positions.
+
+        A plain tick whose ids nobody waits for is read a launch LATE
+        (`_plain_tick`): its tokens, and the completions among them, come
+        out of the next `step()`. The step that leaves the engine idle
+        always delivers its own."""
         self._admit()
         with self._lock:
-            active = dict(self._active)
+            # a closed request holds its slot for its completion alone
+            active = {s: r for s, r in self._active.items() if not r.closed}
         if not active:
             return []
-        if self.spec is not None and all(
+        if self.spec is None or not all(
                 self._spec_capable(r, self.spec.cfg.gamma + 1)
                 for r in active.values()):
-            no_token = [r for r in active.values()
-                        if r.first_token_pc is None]
-            finished = self.spec.round(active)
-            with _tracing.span("tick", "engine/commit"):
-                self._m_ticks.inc()
-                self.n_ticks += 1
-                for r in no_token:     # the round itself, counted just now
-                    if r.first_token_pc is not None:
-                        r.ticks_to_first += 1
-                self.last_tick_at = time.time()
-                self._stamp_kv_watermarks(active)
-                self.busy_slot_ticks += len(active)
-                self.total_slot_ticks += self.n_slots
-        else:
-            finished = self._plain_tick(active)
-        if finished:
-            with _tracing.span("request", "engine/finish"):
-                # complete (firing on_done -> writer.offer) BEFORE dropping
-                # the request from _active: a drain poll reading
-                # n_active==0 must imply every completion frame is already
-                # in its writer queue, or the drain could close the writer
-                # ahead of the final frame and silently drop it
-                for req in finished:
-                    req._complete()
-                with self._lock:
-                    for req in finished:
-                        del self._active[req.slot]
-                        self._slots.free(req.slot)
-                        self._release_request(req)
-                self._m_completed.inc(len(finished))
-                for req in finished:
-                    self._finalize_request(req)
+            return self._plain_tick(active)
+        no_token = [r for r in active.values() if r.first_token_pc is None]
+        finished = self.spec.round(active)
+        with _tracing.span("tick", "engine/commit"):
+            self._m_ticks.inc()
+            self.n_ticks += 1
+            for r in no_token:     # the round itself, counted just now
+                if r.first_token_pc is not None:
+                    r.ticks_to_first += 1
+            self.last_tick_at = time.time()
+            self._stamp_kv_watermarks(active)
+            self.busy_slot_ticks += len(active)
+            self.total_slot_ticks += self.n_slots
+        self._finish(finished)
         return finished
+
+    def _finish(self, finished: List[GenRequest]):
+        """Deliver the completions of `finished` and give up their slots
+        (and what `_release_request` holds for them)."""
+        if not finished:
+            return
+        with _tracing.span("request", "engine/finish"):
+            # complete (firing on_done -> writer.offer) BEFORE dropping
+            # the request from _active: a drain poll reading
+            # n_active==0 must imply every completion frame is already
+            # in its writer queue, or the drain could close the writer
+            # ahead of the final frame and silently drop it
+            for req in finished:
+                req._complete()
+            with self._lock:
+                for req in finished:
+                    del self._active[req.slot]
+                    self._slots.free(req.slot)
+                    self._release_request(req)
+            self._m_completed.inc(len(finished))
+            for req in finished:
+                self._finalize_request(req)
 
     def _pre_tick(self, active: Dict[int, "GenRequest"]
                   ) -> Dict[int, "GenRequest"]:
@@ -824,6 +877,18 @@ class ContinuousBatchingEngine:
 
     def _plain_tick(self, active: Dict[int, "GenRequest"]
                     ) -> List[GenRequest]:
+        """Fill, launch, commit, wait: one tick. What the host does with a
+        tick's results is in two halves. The POSITIONS (`_advance_positions`:
+        `fed`, the blocks filled, who ends by count) need no ids and are
+        applied right after the launch, beside the device. The IDS
+        (`_commit_ids`: tokens, the first token's stamp, `eos`, completions)
+        are read before this returns on an EAGER tick, and on a LATE one
+        (`_reads_late`) left on the device, where the next tick's decode
+        rows take them (`_LastIds`, models/transformer.py), and read and
+        committed after that next launch, again beside the device. Either
+        way this returns only once the device is done with the tick it
+        launched, so whoever polls arrivals between steps sees them when a
+        tick ends. Returns the requests whose completion it delivered."""
         span = _tracing.span
         t0 = time.perf_counter()
         with span("tick", "engine/tick") as tick:
@@ -852,15 +917,41 @@ class ContinuousBatchingEngine:
                     tick.attrs["prefill"] = sum(
                         1 for r in active.values()
                         if r.fed < len(r.prompt) - 1)
+            # the tick before, if its ids were left on the device: it is
+            # done (the step that launched it waited for it), so this is the
+            # ids' way back and no wait, and the device runs beside it
+            before, self._uncommitted = self._uncommitted, None
+            if before is not None:
+                tick_before, fetch_before, emits_before = before
+                with span("tick", "engine/copy_back"):
+                    ids = np.asarray(fetch_before)
+            with span("tick", "engine/commit"):
+                delivered: List[GenRequest] = []
+                if before is not None:
+                    self._note_tick_counts(tick_before, ids)
+                    delivered = self._commit_ids(emits_before, ids)
+                    for req in delivered:
+                        # whose `eos` came out just now has a row in the tick
+                        # in flight: `_advance_positions` drops it
+                        req.closed = True
+                self._stamp_kv_watermarks(active)
+                emits = self._advance_positions(active)
+                late = self._reads_late(active, emits)
+            self._finish(delivered)
+            tick.attrs["late"] = int(late)
             with span("tick", "engine/wait"):
-                # realization barrier: the next tick's feed depends on it.
-                # ONE `np.asarray`; on a sampled tick the same barrier in
-                # its two parts: the copy back enqueued first thing, as
-                # `np.asarray` alone does (0.05-0.1 ms of the host's own
-                # work on a TPU, the device busy through it), then until
-                # the thread knows the step's last op is done; and the
-                # rest of the ids' way back after it
-                if (self.n_ticks % self.WAIT_SPLIT_EVERY == 0
+                # the barrier a caller's arrivals are polled behind: until
+                # the thread knows the step's last op is done. A late tick
+                # stops there; an eager one needs its ids now: ONE
+                # `np.asarray`, and on a sampled tick the same in its two
+                # parts (the copy back enqueued first thing, as `np.asarray`
+                # alone does, 0.05-0.1 ms of the host's own work on a TPU,
+                # the device busy through it; the rest of the ids' way back
+                # after the wait)
+                if late:
+                    with span("tick", "engine/device_wait"):
+                        fetches[0].block_until_ready()
+                elif (self.n_ticks % self.WAIT_SPLIT_EVERY == 0
                         and _tracing.enabled()):
                     with span("tick", "engine/device_wait"):
                         fetches[0].copy_to_host_async()
@@ -869,27 +960,31 @@ class ContinuousBatchingEngine:
                         ids = np.asarray(fetches[0])
                 else:
                     ids = np.asarray(fetches[0])
-            self._note_tick_counts(tick, ids)
+            if not late:
+                self._note_tick_counts(tick, ids)
         with span("tick", "engine/commit"):
             self._m_dispatch.observe(td - t0)
             self._m_tick_latency.observe(time.perf_counter() - t0)
             self._m_ticks.inc()
             self.n_ticks += 1
             self.last_tick_at = time.time()
-            # re-stamp the kv watermarks so the live `current` reflects the
-            # ENGINE that is actually ticking: reserved from the pinned
-            # construction-time census, used from the positions live
-            # requests occupy this tick (O(active))
-            self._stamp_kv_watermarks(active)
             self.busy_slot_ticks += len(active)
             self.total_slot_ticks += self.n_slots
-            finished = self._commit_tick(active, ids)
-        return finished
+            if late:
+                self.late_reads += 1
+                self._uncommitted = (tick, fetches[0], emits)
+                finished = []
+            else:
+                finished = self._commit_ids(emits, ids)
+        self._finish(finished)
+        return delivered + finished
 
     def _note_tick_counts(self, tick, ids: np.ndarray):
-        """What the tick brought back behind its ids, onto the still open
-        `engine/tick` span and the engine's counters. Nothing here; the
-        paged engine's routed layers count their rows."""
+        """What the tick brought back behind its ids, onto ITS `engine/tick`
+        span `tick` (still open on an eager tick, closed a launch ago on a
+        late one: a span's attrs are its record's) and the engine's
+        counters. Nothing here; the paged engine's routed layers count
+        their rows."""
 
     def _launch_tick(self):
         """Launch the tick `_fill_tick_feeds` just filled; returns its
@@ -908,12 +1003,42 @@ class ContinuousBatchingEngine:
             self._target_state_owner = owner
         return step.run_bound()                # zero-dispatch tick
 
-    def _commit_tick(self, active: Dict[int, "GenRequest"],
-                     ids: np.ndarray) -> List[GenRequest]:
-        """Advance every slot that ticked with its row of `ids`; returns
-        the requests that finished."""
-        return [req for slot, req in active.items()
-                if self._advance_slot(req, int(ids[slot, 0]))]
+    def _advance_positions(self, active: Dict[int, "GenRequest"]
+                           ) -> List[tuple]:
+        """The positional half of the commit of the tick just launched:
+        every request that ticked consumed its position. Returns the
+        (request, row of the tick's ids) pairs whose row is a sampled
+        token. A closed request's row is dropped: its `eos` was read after
+        the row was launched."""
+        return [(req, slot) for slot, req in active.items()
+                if not req.closed and self._advance_position(req)]
+
+    def _reads_late(self, active: Dict[int, "GenRequest"],
+                    emits: List[tuple]) -> bool:
+        """Are the ids of the tick just launched left on the device, to be
+        read after the NEXT launch? Exactly when nobody waits for them and
+        a next tick is certain: none of `emits` is a request's first token,
+        and some request of `active` goes on after this tick (the last tick
+        before the engine idles is read at once). Then the requests of
+        `emits` that go on take their next token from the device, and those
+        that end by count are closed until their tokens are delivered."""
+        if not self._late_ok or not all(req.tokens for req, _ in emits):
+            return False
+        ending = [req for req, _ in emits if self._ends_by_count(req)]
+        if len(ending) == sum(not r.closed for r in active.values()):
+            return False
+        for req, _ in emits:
+            req.next_tok = None
+        for req in ending:
+            req.closed = True
+        return True
+
+    def _commit_ids(self, emits: List[tuple], ids: np.ndarray
+                    ) -> List[GenRequest]:
+        """The half of a tick's commit that needs its ids: every request
+        of `emits` takes its row's token; returns those that finished."""
+        return [req for req, row in emits
+                if self._emit_token(req, int(ids[row, 0]))]
 
     def _finalize_request(self, req: GenRequest):
         """Completion-side telemetry: the prefill/decode phase spans and
@@ -962,6 +1087,9 @@ class ContinuousBatchingEngine:
         caller is left waiting on an engine that will never tick again."""
         with self._lock:
             self.failed = exc
+            # the requests of a tick whose ids were never read are among
+            # the active: a request leaves `_active` when it is delivered
+            self._uncommitted = None
             reqs = list(self._active.values()) + list(self._pending)
             self._active.clear()
             self._pending.clear()
@@ -1023,9 +1151,11 @@ class ContinuousBatchingEngine:
                 self.tokens_out / max(self.target_forwards, 1)),
             "speculative": (self.spec.stats()
                             if self.spec is not None else None),
-            # per bound step, the host arrays one launch hands over
-            "dispatch": {name: {"host_args": step.host_args}
-                         for name, step in self._bound_steps.items()},
+            # per bound step, the host arrays one launch hands over; and
+            # the ticks whose ids were read a launch late
+            "dispatch": {**{name: {"host_args": step.host_args}
+                            for name, step in self._bound_steps.items()},
+                         "late_reads": self.late_reads},
         }
 
 

@@ -1076,6 +1076,7 @@ class PagedKVEngine(ContinuousBatchingEngine):
         self._step.bind(self._feeds, share=self._mixed_step)
         self._tok = self._feeds["tick_tok"]
         self._pos = self._feeds["tick_pos"]
+        self._from_last = self._feeds["tick_from_last"]
         self._lane_feeds = {n: a for n, a in self._mixed_feeds.items()
                             if n not in self._feeds}
         self._bound_steps["mixed"] = self._mixed_step
@@ -1131,12 +1132,13 @@ class PagedKVEngine(ContinuousBatchingEngine):
         return self._mixed_step is not None and req.fed < len(req.prompt)
 
     def _fill_tick_feeds(self, active: Dict[int, GenRequest]):
-        tok, pos = self._tok, self._pos
+        tok, pos, from_last = self._tok, self._pos, self._from_last
         btab = self._feeds["tick_btab"]
         wblock = self._feeds["tick_wblock"]
         woff = self._feeds["tick_woff"]
         tok[:] = 0
         pos[:] = 0.0
+        from_last[:] = 0
         btab[:] = 0                              # idle slots → null block
         wblock[:] = 0
         woff[:] = 0
@@ -1147,7 +1149,10 @@ class PagedKVEngine(ContinuousBatchingEngine):
             if self._prefilling(req):
                 prefilling.append(req)   # a lane feeds it: no decode row
                 continue
-            tok[slot, 0] = req.next_tok
+            if req.next_tok is None:     # the last tick left it on the device
+                from_last[slot, 0] = 1
+            else:
+                tok[slot, 0] = req.next_tok
             pos[slot, 0, 0] = float(req.fed)
             blocks = req.table.blocks
             btab[slot, :len(blocks)] = blocks
@@ -1253,28 +1258,33 @@ class PagedKVEngine(ContinuousBatchingEngine):
             tick.attrs["routed_rows"] = int(counts.sum())
             tick.attrs["expert_rows"] = counts.ravel().tolist()
 
-    def _commit_tick(self, active: Dict[int, GenRequest],
-                     ids: np.ndarray) -> List[GenRequest]:
+    def _commits_every_tick(self) -> bool:
+        # the host tier's `_pre_tick` moves blocks between ticks, and
+        # `paged_beam_search` reads a top-k tick's fetches at once
+        return (super()._commits_every_tick() or self.host_tier is not None
+                or bool(self.topk_k))
+
+    def _advance_positions(self, active: Dict[int, GenRequest]
+                           ) -> List[tuple]:
         lanes = self._lanes
         if not lanes:
-            return super()._commit_tick(active, ids)
+            return super()._advance_positions(active)
         # the decode rows first (a slot in prefill sent none: judged
-        # before any lane advances), then each lane's chunk with the
-        # lane's row of ids, which follow the S decode rows
-        finished = [req for slot, req in active.items()
-                    if not self._prefilling(req)
-                    and self._advance_slot(req, int(ids[slot, 0]))]
-        for lane, (req, n) in enumerate(lanes):
-            if self._advance_chunk(req, n, int(ids[self.n_slots + lane, 0])):
-                finished.append(req)
-        return finished
+        # before any lane advances), then each lane's chunk; the lanes'
+        # rows of ids follow the S decode rows
+        emits = [(req, slot) for slot, req in active.items()
+                 if not req.closed and not self._prefilling(req)
+                 and self._advance_position(req)]
+        emits += [(req, self.n_slots + lane)
+                  for lane, (req, n) in enumerate(lanes)
+                  if self._advance_chunk(req, n)]
+        return emits
 
-    def _advance_chunk(self, req: GenRequest, n: int, out_id: int) -> bool:
-        """A lane consumed `req`'s next `n` prompt tokens: advance it,
-        offer every block the chunk completed to the prefix cache, and —
-        when that was the prompt's end — take `out_id` (the last row's
-        argmax) as the first sampled token, in this tick. Returns True
-        when the request just finished (`max_new` = 1, eos)."""
+    def _advance_chunk(self, req: GenRequest, n: int) -> bool:
+        """A lane consumed `req`'s next `n` prompt tokens: advance it and
+        offer every block the chunk completed to the prefix cache. Returns
+        True when that was the prompt's end: the lane's row of ids (the last
+        row's argmax) is then the first sampled token, of this tick."""
         bs = self.block_size
         k0 = req.fed
         req.fed = k0 + n
@@ -1288,7 +1298,7 @@ class PagedKVEngine(ContinuousBatchingEngine):
         if req.fed < len(req.prompt):
             req.next_tok = req.prompt[req.fed]
             return False
-        return self._emit_token(req, out_id)
+        return True
 
     def _note_tick_writes(self, active: Dict[int, GenRequest]):
         # shadow-state sanitizer: every position this tick writes must

@@ -81,11 +81,17 @@ def scored_engine(**kw):
     """A PagedKVEngine whose two ticks also fetch the head's float32 logits
     (`last_logits` [rows, 1, vocab]: the decode rows, then in a mixed tick
     the lanes' last rows): a test's view into the programs the engine runs,
-    where the ids alone say too little. The engine has no such option."""
+    where the ids alone say too little. The engine has no such option.
+    `emitted_logits` reads a tick's logits for the token that tick emitted,
+    so this engine commits every tick at once, as one that fetches top-k
+    does (tests/test_late_read.py has the late order against it)."""
     from paddle_tpu import serving
 
     class Scored(serving.PagedKVEngine):
         last_logits = None
+
+        def _commits_every_tick(self):
+            return True
 
         def _tick_fetches(self):
             return super()._tick_fetches() + [_head_logits(self._program)]

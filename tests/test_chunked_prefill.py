@@ -523,7 +523,7 @@ class TestDecodeTickUnchanged:
         assert chunked._mixed_step is not None
         assert one_token._mixed_step is None
         names = ["tick_tok", "tick_pos", "tick_btab", "tick_wblock",
-                 "tick_woff"]
+                 "tick_woff", "tick_from_last"]
         assert list(chunked._feeds) == list(one_token._feeds) == names
         assert chunked._step._compiled.feed_names == \
             one_token._step._compiled.feed_names
@@ -534,7 +534,7 @@ class TestDecodeTickUnchanged:
         assert _lowered(chunked._step) == _lowered(one_token._step)
         # the mixed tick is another program over the same state
         mixed = chunked._mixed_step._compiled
-        assert mixed.feed_names[:5] == names
+        assert mixed.feed_names[:6] == names
         assert set(mixed.rw_names) == set(chunked._step._compiled.rw_names)
         assert chunked._mixed_program is not chunked._program
 
@@ -582,12 +582,15 @@ class TestInitMissingVars:
         ran = self._spy(monkeypatch)
         eng = PagedKVEngine(n_slots=2, max_len=32, block_size=_BS,
                             scope=scope, **_DIMS)
-        # one startup run, of the pools and the absent parameter alone: no
-        # op of it writes a variable the scope held
+        # one startup run, of the pools (and the ids the decode rows leave
+        # on the device for the next tick) and the absent parameter alone:
+        # no op of it writes a variable the scope held
         assert len(ran) == 1
         outs = {n for op in ran[0].global_block().ops
                 for n in op.output_names()}
-        assert outs == set(eng.cache_names) | {"lm_head.w_1"}
+        last_ids = f"{eng._cache_prefix}_last_ids"
+        assert outs == set(eng.cache_names) | {"lm_head.w_1", last_ids}
+        assert np.asarray(scope.get(last_ids)).shape == (2, 1)
         assert not outs & set(present)
         for n, v in present.items():
             assert scope.get(n) is v                   # the same buffers
@@ -623,6 +626,7 @@ class TestInitMissingVars:
         assert scope.get("draft_tok_emb") is scope.get("tok_emb")
         for program in ran:
             for op in program.global_block().ops:
-                assert all("_k" in n or "_v" in n for n in op.output_names())
+                assert all("_k" in n or "_v" in n or n.endswith("_last_ids")
+                           for n in op.output_names())
         assert len(ran) == 2           # the target's caches, the draft's
         assert eng.spec is not None

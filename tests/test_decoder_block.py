@@ -118,9 +118,14 @@ C = prefill_chunk_tokens(BS, NLB)      # a lane's tokens
 I64, F32 = np.dtype("int64"), np.dtype("float32")
 
 #: the format as the engines spelled it by hand before: name -> (shape, dtype)
-TICK = {"tick_tok": ((S, 1), I64), "tick_pos": ((S, 1, 1), F32)}
-PAGED_TICK = {**TICK, "tick_btab": ((S, NLB), I64),
-              "tick_wblock": ((S,), I64), "tick_woff": ((S,), I64)}
+_TOK_POS = {"tick_tok": ((S, 1), I64), "tick_pos": ((S, 1, 1), F32)}
+#: ... and `tick_from_last` since a decode row can take its token from the
+#: device (the ids of the tick before, read a launch late)
+_FROM_LAST = {"tick_from_last": ((S, 1), I64)}
+TICK = {**_TOK_POS, **_FROM_LAST}
+PAGED_TICK = {**_TOK_POS, "tick_btab": ((S, NLB), I64),
+              "tick_wblock": ((S,), I64), "tick_woff": ((S,), I64),
+              **_FROM_LAST}
 LANES = {"lane_tok": ((L, C), I64), "lane_pos": ((L, 1, 1), F32),
          "lane_btab": ((L, NLB), I64), "lane_wblocks": ((L * C // BS,), I64),
          "lane_rows": ((L,), I64), "lane_last": ((L,), I64)}
@@ -225,7 +230,8 @@ def test_mixed_tick_runs_on_the_decode_ticks_arrays():
     assert eng._tok is eng._feeds["tick_tok"]
     assert eng._pos is eng._feeds["tick_pos"]
     assert eng.stats()["dispatch"] == {"main": {"host_args": 1},
-                                       "mixed": {"host_args": 1}}
+                                       "mixed": {"host_args": 1},
+                                       "late_reads": 0}
 
 
 def test_a_feed_on_one_side_only_cannot_happen():
@@ -237,7 +243,8 @@ def test_a_feed_on_one_side_only_cannot_happen():
         layers.data(name="tick_extra", shape=[S, 2], dtype="int32",
                     append_batch_size=False)
     feeds = _feed_arrays(main)
-    assert list(feeds) == ["tick_tok", "tick_pos", "tick_extra"]
+    assert list(feeds) == ["tick_tok", "tick_pos", "tick_from_last",
+                           "tick_extra"]
     assert feeds["tick_extra"].shape == (S, 2)
     assert feeds["tick_extra"].dtype == np.dtype("int32")
     shared = _feed_arrays(main, share={"tick_tok": feeds["tick_tok"]})
@@ -325,7 +332,7 @@ def test_a_block_of_other_kinds_goes_through_the_one_block(graph,
     feeds = _feed_arrays(program)
     s, bs, nlb = 4, 4, 6
     want = {"tick_tok": (s, 1), "tick_pos": (s, 1, 1), "tick_btab": (s, nlb),
-            "tick_wblock": (s,), "tick_woff": (s,)}
+            "tick_wblock": (s,), "tick_woff": (s,), "tick_from_last": (s, 1)}
     if graph == "paged_mixed_tick":
         want.update({"lane_tok": (2, 8), "lane_pos": (2, 1, 1),
                      "lane_btab": (2, nlb), "lane_wblocks": (4,),

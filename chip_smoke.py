@@ -542,6 +542,34 @@ def phase_serve_ssm(vocab=32768, d_model=4096, num_heads=32, num_kv_heads=2,
             "tpu_custom_calls": n_calls, "mixed_tpu_custom_calls": n_mixed}
 
 
+def phase_window_read(n_slots=32, n_blocks=512, block_size=64, num_heads=64,
+                      num_kv_heads=8, d_head=128, blocks_per_req=16,
+                      window=128, n_lanes=2, chunk=128, backend=None):
+    """The sliding-window read of k-exaone-ep8_serve_long_sessions at its
+    published widths (64 query heads over 8 key/value heads of 128, bfloat16
+    pools in blocks of 64, of a table's blocks a row maps the two or three
+    its window lies in): the decode rows' kernel and the lanes' chunk kernel,
+    each against the composite, and the same two reads with no window as the
+    full layer takes them. The table is 16 blocks wide, not the cell's 272:
+    the composite gathers the whole table for every query head in float32,
+    18 GB at 272 (the cell's own runs read through the kernels at 272, and
+    `tests/test_pallas_tpu_lowering.py` compiles them there)."""
+    shape = dict(n_blocks=n_blocks, block_size=block_size,
+                 num_heads=num_heads, num_kv_heads=num_kv_heads,
+                 d_head=d_head, blocks_per_req=blocks_per_req,
+                 backend=backend)
+    t0 = time.time()
+    errs = {
+        "window_decode": _check_grouped_decode(n_slots, window=window,
+                                               **shape),
+        "window_chunk": _check_grouped_decode(n_lanes, window=window,
+                                              rows=chunk, **shape),
+        "full_decode": _check_grouped_decode(n_slots, **shape),
+        "full_chunk": _check_grouped_decode(n_lanes, rows=chunk, **shape)}
+    return {"compile_s": 0.0, "run_s": round(time.time() - t0, 2),
+            "max_rel_err": {k: float("%.2e" % v) for k, v in errs.items()}}
+
+
 def phase_train_resnet50(batch=256, steps=2, depth=50, image=224):
     """bench.py's training graph: ResNet-50 NHWC bf16, uint8 staging
     declared (and fed), Momentum."""
@@ -708,12 +736,17 @@ def _check_paged(n_slots, n_blocks, block_size, num_heads, d_head,
 
 
 def _check_grouped_decode(n_slots, n_blocks, block_size, num_heads,
-                          num_kv_heads, d_head, blocks_per_req, backend=None):
+                          num_kv_heads, d_head, blocks_per_req, backend=None,
+                          window=0, rows=1):
     """The grouped decode read (one position a slot over bfloat16 pools of
     the key/value heads: fusion/paged_attention.py `_decode_kernel`) against
     its composite: every fourth slot live and the others idle on the null
     block, ragged positions (a block's first and last row and the whole
-    span among them), a permuted table."""
+    span among them), a permuted table. With `window` the read is a
+    sliding-window layer's: the table maps only the blocks a position of the
+    window lies in (the pager has released the others), and the positions
+    lie to either side of the window's edge; with `rows` > 1 it is a lane's
+    chunk of that many positions (`_chunk_kernel`), every slot live."""
     import jax
     import jax.numpy as jnp
     from paddle_tpu.fusion import (paged_attention_lowering,
@@ -728,22 +761,29 @@ def _check_grouped_decode(n_slots, n_blocks, block_size, num_heads,
            f"bfloat16 pools {shape} do not take the grouped decode kernel")
     k_pool, v_pool = (jnp.asarray(rng.randn(*shape), jnp.bfloat16)
                       for _ in range(2))
-    q = jnp.asarray(rng.randn(n_slots, 1, num_heads * d_head), jnp.float32)
-    live = np.arange(n_slots) % 4 == 1
+    q = jnp.asarray(rng.randn(n_slots, rows, num_heads * d_head),
+                    jnp.float32)
+    live = np.arange(n_slots) % 4 == 1 if rows == 1 else \
+        np.ones(n_slots, bool)
     pos = np.where(live, rng.randint(0, span // 2, (n_slots,)), 0)
-    edges = np.flatnonzero(live)[:3]
-    pos[edges] = (block_size, block_size - 1, span - 1)[:len(edges)]
+    if rows > 1:
+        pos = pos // block_size * block_size    # a chunk starts a block
+    edges = np.flatnonzero(live)[:5]
+    pos[edges] = ((block_size, block_size - 1, span - rows,
+                   max(window - 1, 0), window) if rows == 1
+                  else (0, span - rows))[:len(edges)]
     ids = iter(np.resize(rng.permutation(np.arange(1, n_blocks)),
                          n_slots * blocks_per_req))
     btab = np.zeros((n_slots, blocks_per_req), np.int32)
     for s in np.flatnonzero(live):
-        for j in range(pos[s] // block_size + 1):
+        first = max(pos[s] - (window - 1), 0) // block_size if window else 0
+        for j in range(first, (pos[s] + rows - 1) // block_size + 1):
             btab[s, j] = next(ids)
     args = (q, k_pool, v_pool, jnp.asarray(btab), jnp.asarray(pos, jnp.int32))
 
     def run(be):
         return jax.jit(lambda *a: paged_decode_attention(
-            *a, num_heads, scale=d_head ** -0.5, backend=be))
+            *a, num_heads, scale=d_head ** -0.5, backend=be, window=window))
     got = run(backend)(*args)
     with jax.default_matmul_precision("highest"):
         ref = run("xla")(*args)
@@ -1110,6 +1150,7 @@ def _run():
     phase("serve_hybrid", phase_serve_hybrid)
     phase("serve_ssm", phase_serve_ssm)
     _free_device_memory()
+    phase("window_read", phase_window_read)
     phase("kernels", phase_kernels)
     if len(jax.devices()) >= 4:
         phase("multichip", phase_multichip, train["losses"][0])

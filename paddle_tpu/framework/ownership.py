@@ -40,6 +40,7 @@ from ..core.enforce import InvalidArgumentError
 __all__ = [
     "DIAGNOSTICS", "MUTATIONS", "OwnershipViolation", "TableState",
     "AbstractState", "ModelChecker", "CheckResult", "check_span_snapshot",
+    "check_window_read",
 ]
 
 # ---------------------------------------------------------------------------
@@ -93,6 +94,13 @@ DIAGNOSTICS: Dict[str, str] = {
         "far fewer snapshots than blocks) may share K/V blocks only up to "
         "the deepest one that holds a snapshot; past it the state-space "
         "layers would resume from a state of other positions",
+    "kv-window-read-after-release":
+        "a read through a request's WINDOW table spans a logical block that "
+        "is not mapped — a model with sliding-window layers keeps a block of "
+        "the window pool only while a position of it can still be attended; "
+        "a read that starts below the released span (a window miscounted by "
+        "one, a prefix hit handed out past its window tail) would attend "
+        "the window pool's null block, or another request's rows",
     "serving-cache-write-alias":
         "a tick-program cache write breaks the donated in-place "
         "contract: the pool var is written more than once per tick, or "
@@ -131,6 +139,22 @@ def check_span_snapshot(shared_blocks: Sequence[int],
             f"the shared span ends in block {shared_blocks[-1]}, its "
             f"snapshot is the state after block {snapshot_block}",
             block=shared_blocks[-1])
+
+
+def check_window_read(window_blocks: Sequence[int], first: int, last: int,
+                      op: str):
+    """The window pool's rule (`serving/kv_pager.py` calls it where a tick's
+    feeds are filled and where a prefix hit is handed out; kin to
+    `kv-span-past-snapshot`): every logical block `first..last` a read
+    through the window table spans is mapped. A released (or never shared)
+    block reads 0, the window pool's null block."""
+    for j in range(first, last + 1):
+        if not window_blocks[j]:
+            raise OwnershipViolation(
+                "kv-window-read-after-release", op,
+                f"the window read spans logical blocks {first}..{last}, "
+                f"and block {j} is not mapped (released behind the window, "
+                f"or never part of the shared span's tail)", block=j)
 
 
 class OwnershipViolation(InvalidArgumentError):

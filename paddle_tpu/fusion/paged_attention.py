@@ -98,6 +98,9 @@ _M_INIT = -1e30    # running max before the first block (finite: no inf-inf)
 KERNEL, COMPOSITE = "kernel", "composite"
 _CHUNK_ROWS = 8    # the chunk kernel's query rows come in whole sublane tiles
 _DECODE_KEY_ROWS = 256   # pool rows the grouped decode kernel scores a step
+#: the scope (and so the device trace's name) of a read bounded by a window:
+#: a window layer's read and a full layer's are told apart by it
+_WINDOW_SCOPE = "paged_window_attention"
 
 
 def paged_attention_lowering(pool_dtype, pool_lanes, n_query, d_head,
@@ -144,9 +147,11 @@ def _table_view(pool, btab, d_head, scales=None):
     return g.transpose(0, 2, 1, 3, 4).reshape(s, nh, -1, d_head)
 
 
-def _paged_composite(q4, k_pool, v_pool, btab, pos, scale, k_scale, v_scale):
+def _paged_composite(q4, k_pool, v_pool, btab, pos, scale, k_scale, v_scale,
+                     window=0):
     """q4 [S, nh, G, dh]; query row g of slot s sits at position pos[s] + g
-    and attends the cache positions t <= pos[s] + g."""
+    and attends the cache positions t <= pos[s] + g (with `window`, the last
+    `window` of them: pos[s] + g - window < t)."""
     dh = q4.shape[-1]
     k4 = _table_view(k_pool, btab, dh, k_scale)
     v4 = _table_view(v_pool, btab, dh, v_scale)
@@ -155,7 +160,10 @@ def _paged_composite(q4, k_pool, v_pool, btab, pos, scale, k_scale, v_scale):
         k4, v4 = (jnp.repeat(t, grp, axis=1) for t in (k4, v4))
     g, t = q4.shape[2], k4.shape[2]
     posg = pos[:, None].astype(jnp.float32) + jnp.arange(g, dtype=jnp.float32)
-    valid = jnp.arange(t, dtype=jnp.float32) < posg[:, :, None] + 1.0
+    keys = jnp.arange(t, dtype=jnp.float32)
+    valid = keys < posg[:, :, None] + 1.0
+    if window:
+        valid &= keys > posg[:, :, None] - float(window)
     bias4 = jnp.where(valid, 0.0, _MASKED).astype(jnp.float32)[:, None]
     return _decode_xla(q4, k4.astype(q4.dtype), v4.astype(q4.dtype), bias4,
                        scale)
@@ -290,7 +298,8 @@ def _paged_pallas(q4, k_pool, v_pool, btab, pos, scale, interpret):
 
 def _chunk_kernel(btab_ref, pos_ref, rows_ref, q_ref, k_hbm, v_hbm, o_ref,
                   kbuf, vbuf, sem, m_ref, l_ref, acc_ref, *, n_logical,
-                  block_size, d_head, group, mxu_dtype, rows_per_pos=1):
+                  block_size, d_head, group, mxu_dtype, rows_per_pos=1,
+                  window=0):
     """One grid step = one lane: C query rows at positions pos..pos+C-1, of
     which the first `rows` are real. The lane's live blocks (those holding
     a position up to pos + rows - 1) come `group` at a time: each DMA'd
@@ -307,7 +316,10 @@ def _chunk_kernel(btab_ref, pos_ref, rows_ref, q_ref, k_hbm, v_hbm, o_ref,
     times a stale VMEM row could be NaN. With `rows_per_pos` > 1 (grouped
     queries: the pool's heads are the key/value heads) `rows_per_pos`
     consecutive query rows share a position: row r sits at pos + r //
-    rows_per_pos, and `rows` still counts positions."""
+    rows_per_pos, and `rows` still counts positions. With `window` a row
+    attends the last `window` positions up to its own: the walk starts at the
+    block that holds position pos - window + 1 (`first`), nothing below it is
+    fetched, and that block's head is masked row by row."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -320,6 +332,10 @@ def _chunk_kernel(btab_ref, pos_ref, rows_ref, q_ref, k_hbm, v_hbm, o_ref,
     rows = rows_ref[lane]
     n_live = jnp.where(rows > 0,
                        jax.lax.div(pos + rows - 1, block_size) + 1, 0)
+    first = 0
+    if window:
+        first = jax.lax.div(jnp.maximum(pos - (window - 1), 0), block_size)
+        n_live = jnp.maximum(n_live - first, 0)
     n_steps = jax.lax.div(n_live + group - 1, group)
 
     def fetch(step, buf, wait):
@@ -327,7 +343,7 @@ def _chunk_kernel(btab_ref, pos_ref, rows_ref, q_ref, k_hbm, v_hbm, o_ref,
         buffer `buf`: a loop, not `group` unrolled descriptors, so that the
         kernel's text stays short (it is lowered at every set-up)."""
         def one(g, carry):
-            j = jnp.minimum(step * group + g, n_logical - 1)
+            j = jnp.minimum(first + step * group + g, n_logical - 1)
             blk = btab_ref[lane * n_logical + j]
             dst = pl.ds(pl.multiple_of(g * n_rows, n_rows), n_rows)
             for hbm, vmem, kv in ((k_hbm, kbuf, 0), (v_hbm, vbuf, 1)):
@@ -358,7 +374,8 @@ def _chunk_kernel(btab_ref, pos_ref, rows_ref, q_ref, k_hbm, v_hbm, o_ref,
             fetch(step + 1, 1 - buf, wait=False)
 
         fetch(step, buf, wait=True)
-        key0 = step * (group * block_size)
+        key0 = (first * block_size if window else 0) \
+            + step * (group * block_size)
 
         def head_body(h, carry):
             q = q_ref[0, h]                                    # [C, 128]
@@ -370,8 +387,11 @@ def _chunk_kernel(btab_ref, pos_ref, rows_ref, q_ref, k_hbm, v_hbm, o_ref,
                 sg = jax.lax.dot_general(
                     qg.astype(mxu_dtype), k, (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32)        # [C, key_rows]
-                scores.append(jnp.where(
-                    key0 + key_row * per_row + g <= q_pos, sg, _MASKED))
+                key_pos = key0 + key_row * per_row + g
+                seen = key_pos <= q_pos
+                if window:
+                    seen &= key_pos > q_pos - window
+                scores.append(jnp.where(seen, sg, _MASKED))
             m_prev = m_ref[h]                                  # [C, 1]
             m_new = m_prev
             for sg in scores:
@@ -400,10 +420,10 @@ def _chunk_kernel(btab_ref, pos_ref, rows_ref, q_ref, k_hbm, v_hbm, o_ref,
     o_ref[0] = (acc_ref[...] / jnp.where(l > 0.0, l, 1.0)).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("scale", "interpret", "rows_per_pos"))
+@functools.partial(jax.jit, static_argnames=("scale", "interpret",
+                                             "rows_per_pos", "window"))
 def _chunk_pallas(q4, k_pool, v_pool, btab, pos, rows, scale, interpret,
-                  rows_per_pos=1):
+                  rows_per_pos=1, window=0):
     """q4 [L, nh, C, dh] float32, pools [NB, nh, R, 128] → [L, nh, C, dh].
     On the chip the MXU takes bf16 operands (what XLA's default precision
     gives the composite's float32 matmuls there); interpreted, the
@@ -419,14 +439,15 @@ def _chunk_pallas(q4, k_pool, v_pool, btab, pos, rows, scale, interpret,
     n_logical = btab.shape[1]
     group = max(1, min(_LANES // n_rows, n_logical))
     qspec = pl.BlockSpec((1, nh, c, _LANES), lambda i, *_: (i, 0, 0, 0))
-    with jax.named_scope("paged_chunk_attention" if rows_per_pos == 1
+    with jax.named_scope(_WINDOW_SCOPE if window
+                         else "paged_chunk_attention" if rows_per_pos == 1
                          else "paged_gqa_attention"):
         out = pl.pallas_call(
             functools.partial(
                 _chunk_kernel, n_logical=n_logical,
                 block_size=n_rows * per_row, d_head=dh, group=group,
                 mxu_dtype=jnp.float32 if interpret else jnp.bfloat16,
-                rows_per_pos=rows_per_pos),
+                rows_per_pos=rows_per_pos, window=window),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=3,
                 grid=(n_lanes,),
@@ -453,7 +474,7 @@ def _chunk_pallas(q4, k_pool, v_pool, btab, pos, rows, scale, interpret,
 
 def _decode_kernel(btab_ref, pos_ref, rows_ref, q_ref, k_hbm, v_hbm, o_ref,
                    kbuf, vbuf, sem, parity_ref, *, n_slots, n_logical,
-                   block_size, d_head, group, mxu_dtype):
+                   block_size, d_head, group, mxu_dtype, window=0):
     """One grid step = one slot's ONE query position under grouped queries
     (or bfloat16 pools). The slot's live blocks come `group` at a time into
     one of two VMEM buffers, live blocks only, the next group in flight
@@ -476,7 +497,12 @@ def _decode_kernel(btab_ref, pos_ref, rows_ref, q_ref, k_hbm, v_hbm, o_ref,
     once a slot. The result is [nkv, rp, 128], segment g holding the share
     of the positions of segment g: the caller adds the segments. The buffers
     are zeroed at the first step, so the rows past a slot's last live block
-    hold zeros or an earlier group's rows: finite, and masked by position."""
+    hold zeros or an earlier group's rows: finite, and masked by position.
+
+    With `window` the slot attends its last `window` positions: its live
+    blocks start at the one that holds position pos - window + 1
+    (`first_block`; the table's entries below it may be unmapped and are
+    never looked at), and that block's head is masked."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -487,11 +513,18 @@ def _decode_kernel(btab_ref, pos_ref, rows_ref, q_ref, k_hbm, v_hbm, o_ref,
     rp = q_rows // per_row
     key_rows = group * n_rows
 
+    def first_block(slot):
+        if not window:
+            return 0
+        return jax.lax.div(jnp.maximum(pos_ref[slot] - (window - 1), 0),
+                           block_size)
+
     def live_blocks(slot):
         pos = pos_ref[slot]
         idle = (rows_ref[slot] <= 0) | (
             (pos == 0) & (btab_ref[slot * n_logical] == 0))
-        return jnp.where(idle, 0, jax.lax.div(pos, block_size) + 1)
+        return jnp.where(idle, 0, jax.lax.div(pos, block_size) + 1
+                         - first_block(slot))
 
     def first_live(slot):
         """The first live slot at or after `slot`; n_slots when none is."""
@@ -504,7 +537,8 @@ def _decode_kernel(btab_ref, pos_ref, rows_ref, q_ref, k_hbm, v_hbm, o_ref,
         """Start (or wait for) the DMAs of the live blocks of group `step`
         of `slot` into buffer `buf`."""
         def one(g, carry):
-            blk = btab_ref[slot * n_logical + step * group + g]
+            blk = btab_ref[slot * n_logical + first_block(slot)
+                           + step * group + g]
             dst = pl.ds(pl.multiple_of(g * n_rows, n_rows), n_rows)
             for hbm, vmem, kv in ((k_hbm, kbuf, 0), (v_hbm, vbuf, 1)):
                 cp = pltpu.make_async_copy(
@@ -534,7 +568,8 @@ def _decode_kernel(btab_ref, pos_ref, rows_ref, q_ref, k_hbm, v_hbm, o_ref,
     @pl.when(n_live > 0)
     def _():
         base = parity_ref[0]      # the buffer this slot's first group is in
-        pos = pos_ref[s]
+        # the slot's position, counted from its first live block
+        pos = pos_ref[s] - first_block(s) * block_size
         n_steps = jax.lax.div(n_live + group - 1, group)
         nxt_slot = first_live(s + 1)
         q = q_ref[0]                                 # [nkv, q_rows, 128]
@@ -558,8 +593,10 @@ def _decode_kernel(btab_ref, pos_ref, rows_ref, q_ref, k_hbm, v_hbm, o_ref,
             sc = jax.lax.dot_general(
                 q, kbuf[buf].astype(mxu_dtype), _NT,
                 preferred_element_type=jnp.float32)  # [nkv, q_rows, keys]
-            sc = jnp.where(key_off <= pos - j * (group * block_size), sc,
-                           _MASKED)
+            seen = key_off <= pos - j * (group * block_size)
+            if window:
+                seen &= key_off > pos - j * (group * block_size) - window
+            sc = jnp.where(seen, sc, _MASKED)
             m_new = jnp.maximum(m, jnp.max(sc, axis=2, keepdims=True))
             alpha = jnp.exp(m - m_new)
             p = jnp.exp(sc - m_new)
@@ -587,8 +624,10 @@ def _decode_kernel(btab_ref, pos_ref, rows_ref, q_ref, k_hbm, v_hbm, o_ref,
         o_ref[0] = (ctx / l_all).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("d_head", "interpret"))
-def _decode_pallas(q2, k_pool, v_pool, btab, pos, rows, d_head, interpret):
+@functools.partial(jax.jit,
+                   static_argnames=("d_head", "interpret", "window"))
+def _decode_pallas(q2, k_pool, v_pool, btab, pos, rows, d_head, interpret,
+                   window=0):
     """q2 [S, nkv, per_row*rp, 128] float32, scaled and block-diagonal
     (`_grouped_decode`), pools [NB, nkv, R, 128] → [S, nkv, rp, 128]
     float32, the context's share a lane segment. The operands of both
@@ -603,12 +642,14 @@ def _decode_pallas(q2, k_pool, v_pool, btab, pos, rows, d_head, interpret):
     n_logical = btab.shape[1]
     group = max(1, min(_DECODE_KEY_ROWS // n_rows, n_logical))
     mxu_dtype = jnp.float32 if interpret else jnp.bfloat16
-    with jax.named_scope("paged_gqa_attention"):
+    if window:      # never more blocks a step than a window spans
+        group = min(group, -(-(window - 1) // (n_rows * per_row)) + 1)
+    with jax.named_scope(_WINDOW_SCOPE if window else "paged_gqa_attention"):
         return pl.pallas_call(
             functools.partial(
                 _decode_kernel, n_slots=n_slots, n_logical=n_logical,
                 block_size=n_rows * per_row, d_head=d_head, group=group,
-                mxu_dtype=mxu_dtype),
+                mxu_dtype=mxu_dtype, window=window),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=3,
                 grid=(n_slots,),
@@ -636,7 +677,7 @@ def _decode_pallas(q2, k_pool, v_pool, btab, pos, rows, d_head, interpret):
 
 def paged_decode_attention(q, k_pool, v_pool, btab, pos, num_heads,
                            scale=1.0, backend=None, k_scale=None,
-                           v_scale=None, rows=None):
+                           v_scale=None, rows=None, window=0):
     """Attention of each slot's G query positions over its paged cache.
 
     q [S, G, nh*dh]; k_pool / v_pool [NB, nkv, R, L], either
@@ -655,7 +696,14 @@ def paged_decode_attention(q, k_pool, v_pool, btab, pos, num_heads,
     position pos + rows - 1 need be mapped. Physical block 0 is the pager's
     null block, never a request's: a slot at position 0 whose first logical
     block is block 0 is an idle slot, and the grouped decode kernel returns
-    it zeros without fetching. Returns [S, G, nh*dh]."""
+    it zeros without fetching. `window` > 0 (a sliding-window layer): row g
+    attends positions pos + g - window + 1 .. pos + g only; the walk starts
+    at the block that holds the first of them, and the table's entries below
+    that block are never read (the pager has released them:
+    serving/kv_pager.py). The two grouped kernels and the composite take it;
+    with 0 every call lowers as it did without the argument.
+    Returns [S, G, nh*dh]."""
+    window = int(window)
     s, g, h = q.shape
     dh = h // num_heads
     btab = btab.astype(jnp.int32)
@@ -665,11 +713,11 @@ def paged_decode_attention(q, k_pool, v_pool, btab, pos, num_heads,
         backend=backend)
     interpret = backend == "pallas_interpret"
     nkv = k_pool.shape[1]
-    if lowering == KERNEL and (nkv != num_heads
+    if lowering == KERNEL and (nkv != num_heads or window
                                or k_pool.dtype != jnp.float32):
         grouped = _grouped_decode if g == 1 else _grouped_lanes
         return grouped(q, k_pool, v_pool, btab, pos, rows, num_heads,
-                       float(scale), interpret)
+                       float(scale), interpret, window)
     q4 = q.reshape(s, g, num_heads, dh).transpose(0, 2, 1, 3)
     if lowering == KERNEL and g == 1:
         out = _paged_pallas(q4, k_pool, v_pool, btab, pos, float(scale),
@@ -680,7 +728,7 @@ def paged_decode_attention(q, k_pool, v_pool, btab, pos, num_heads,
                             interpret=interpret)
     else:
         out = _paged_composite(q4, k_pool, v_pool, btab, pos, float(scale),
-                               k_scale, v_scale)
+                               k_scale, v_scale, window)
     return out.transpose(0, 2, 1, 3).reshape(s, g, h)
 
 
@@ -691,7 +739,7 @@ def _real_rows(rows, s, g):
 
 
 def _grouped_lanes(q, k_pool, v_pool, btab, pos, rows, num_heads, scale,
-                   interpret):
+                   interpret, window=0):
     """The chunk kernel under grouped queries (and bfloat16 pools): the
     `grp` query heads of a key/value head, position-major, as the rows of
     one product."""
@@ -701,13 +749,14 @@ def _grouped_lanes(q, k_pool, v_pool, btab, pos, rows, num_heads, scale,
     q4 = (q.reshape(s, g, nkv, grp, dh).astype(jnp.float32)
           .transpose(0, 2, 1, 3, 4).reshape(s, nkv, g * grp, dh))
     out = _chunk_pallas(q4, k_pool, v_pool, btab, pos, _real_rows(rows, s, g),
-                        scale, interpret=interpret, rows_per_pos=grp)
+                        scale, interpret=interpret, rows_per_pos=grp,
+                        window=window)
     out = out.reshape(s, nkv, g, grp, dh)
     return out.transpose(0, 2, 1, 3, 4).reshape(s, g, h).astype(q.dtype)
 
 
 def _grouped_decode(q, k_pool, v_pool, btab, pos, rows, num_heads, scale,
-                    interpret):
+                    interpret, window=0):
     """The decode kernel of grouped queries (and bfloat16 pools): a slot's
     one position, the `grp` query heads of a key/value head padded to a
     sublane tile and laid block-diagonally over the lane segments
@@ -726,7 +775,8 @@ def _grouped_decode(q, k_pool, v_pool, btab, pos, rows, num_heads, scale,
           * jnp.eye(per_row, dtype=q4.dtype)[:, None, :, None])
     q2 = q2.reshape(s, nkv, per_row * rp, _LANES)
     out = _decode_pallas(q2, k_pool, v_pool, btab, pos, _real_rows(rows, s, 1),
-                         d_head=dh, interpret=interpret)   # [S, nkv, rp, 128]
+                         d_head=dh, interpret=interpret,
+                         window=window)                    # [S, nkv, rp, 128]
     out = out.reshape(s, nkv, rp, per_row, dh).sum(axis=3)[:, :, :grp]
     return out.reshape(s, 1, h).astype(q.dtype)
 
@@ -740,5 +790,6 @@ def _paged_decode_attention_op(ctx, ins, attrs):
         ins["BlockTable"][0], ins["Pos"][0], attrs["num_heads"],
         scale=attrs.get("scale", 1.0), backend=attrs.get("backend"),
         k_scale=ks[0] if ks else None, v_scale=vs[0] if vs else None,
-        rows=ins["Rows"][0] if ins.get("Rows") else None)
+        rows=ins["Rows"][0] if ins.get("Rows") else None,
+        window=attrs.get("window", 0))
     return {"Out": [out]}

@@ -1013,7 +1013,7 @@ def paged_cache_write_quant(pool, scales, new, block_ids, offsets,
 
 def paged_decode_attention(q, k_pool, v_pool, block_table, pos, num_heads,
                            scale=1.0, k_scale=None, v_scale=None,
-                           name=None, n_rows=None):
+                           name=None, n_rows=None, window=0):
     """Attention of each tick slot's query rows over its PAGED cache, read
     through the block table (fusion/paged_attention.py). `q` is [S, G, H]
     (G = 1 for the decode tick, γ+1 for a verify window, the chunk length
@@ -1024,7 +1024,9 @@ def paged_decode_attention(q, k_pool, v_pool, block_table, pos, num_heads,
     row (S elements; row g attends cache positions 0..pos+g). `n_rows`
     (S elements, optional) says how many of a slot's G rows are real: the
     rest, and a slot with none, return values nobody reads, and the blocks
-    only they would attend are not fetched. Returns [S, G, H]."""
+    only they would attend are not fetched. `window` > 0: a row attends its
+    last `window` positions only (a sliding-window layer, read through its
+    own table). Returns [S, G, H]."""
     helper = LayerHelper("paged_decode_attention", name=name)
     out = helper.create_tmp_variable(dtype=dtype_name(q.dtype),
                                      shape=q.shape, stop_gradient=True)
@@ -1034,9 +1036,11 @@ def paged_decode_attention(q, k_pool, v_pool, block_table, pos, num_heads,
         inputs["KScale"], inputs["VScale"] = [k_scale], [v_scale]
     if n_rows is not None:
         inputs["Rows"] = [n_rows]
+    attrs = {"num_heads": num_heads, "scale": float(scale)}
+    if window:
+        attrs["window"] = int(window)
     helper.append_op(type="paged_decode_attention", inputs=inputs,
-                     outputs={"Out": [out]},
-                     attrs={"num_heads": num_heads, "scale": float(scale)})
+                     outputs={"Out": [out]}, attrs=attrs)
     return out
 
 

@@ -10,7 +10,9 @@ is built, `DecoderSpec.conv_gqa_moe` the third (a kind PER LAYER: gated
 short convolutions with a per-request state beside grouped-query rotary
 attention), `DecoderSpec.ssm_gqa_moe` the fourth (ONE sublayer a layer: a
 Mamba-2 state-space mixer, grouped-query attention without positions, or
-latent routed experts). A spec comes from one of the constructors; the fields are what
+latent routed experts), `DecoderSpec.window_gqa_moe` the fifth (a kind of
+ATTENTION a layer: a sliding window with rotary positions, or every position
+without any). A spec comes from one of the constructors; the fields are what
 `_decoder_block` reads, not a product to pick from: any other combination
 raises where a graph would have to build it.
 `serving.PagedKVEngine(model=spec)` takes any of them; everything else in
@@ -25,6 +27,10 @@ Kinds (each a string, checked by name; nothing is guessed):
   attention   "full" (q/k/v heads over K and V pools; `num_kv_heads` fewer
               key/value heads than query heads, `qk_norm` an RMSNorm a head
               on q and k) | "latent" (`LatentSpec`)
+  attention_kinds  a kind an (attention) layer, "window" (a query sees the
+              last `window` positions, itself among them; rotated where the
+              spec has a `rope`) | "full" (every position; NOT rotated);
+              None: full everywhere, rotated where the spec has a `rope`
   layer_kinds a kind a layer, "attention" | "conv" (`ConvSpec`: a gated
               short convolution whose state is the last rows of its input);
               None: attention everywhere. With `one_sublayer` a layer is its
@@ -250,6 +256,8 @@ class DecoderSpec:
     one_sublayer: bool = False              # a layer is its kind alone
     head_dim: Optional[int] = None          # None: d_model // num_heads
     ssm: Optional[SsmSpec] = None
+    attention_kinds: Optional[Tuple[str, ...]] = None   # "window" | "full"
+    window: int = 0                         # positions a window layer sees
 
     def __post_init__(self):
         for field, kinds in (("norm", ("layer_norm", "rms_norm")),
@@ -290,6 +298,17 @@ class DecoderSpec:
                              "it")
         if (self.conv is not None) != bool(self.conv_layers):
             raise ValueError("a 'conv' layer comes with a ConvSpec, and "
+                             "only it")
+        akinds = self.attention_kinds
+        if akinds is not None and (
+                len(akinds) != self.num_layers
+                or set(akinds) - {"window", "full"}
+                or self.attention != "full" or self.layer_kinds is not None):
+            raise ValueError(f"attention_kinds {akinds!r}: 'window' or "
+                             f"'full' for each of {self.num_layers} layers "
+                             "of full-head attention")
+        if (self.window > 0) != bool(self.window_layers):
+            raise ValueError("a 'window' layer comes with `window` > 0, and "
                              "only it")
 
     @classmethod
@@ -347,6 +366,25 @@ class DecoderSpec:
                    num_kv_heads=num_kv_heads, head_dim=d_head,
                    layer_kinds=tuple(layer_kinds), one_sublayer=True, ssm=ssm)
 
+    @classmethod
+    def window_gqa_moe(cls, vocab, d_model, d_inner, num_heads, num_kv_heads,
+                       d_head, attention_kinds, window, rope: RopeSpec,
+                       moe: Optional[MoESpec] = None, norm_eps=1e-5,
+                       dtype="bfloat16"):
+        """The EXAONE-4 / K-EXAONE family's block: pre-norm RMSNorm
+        residuals; grouped-query attention with heads of `d_head` (whatever
+        `d_model`) and an RMSNorm a head on q and k in EVERY layer; by
+        `attention_kinds` a layer sees the last `window` positions, q and k
+        rotated over the whole head, or every position, NOT rotated; a gated
+        SiLU pair, routed experts beside a shared one from
+        `moe.first_dense` on; a final norm and an untied head."""
+        return cls(vocab, d_model, d_inner, num_heads, len(attention_kinds),
+                   norm="rms_norm", norm_eps=norm_eps, residual="pre",
+                   positions="rotary", ffn="gated_silu", dtype=dtype, moe=moe,
+                   num_kv_heads=num_kv_heads, qk_norm=True, rope=rope,
+                   head_dim=d_head, attention_kinds=tuple(attention_kinds),
+                   window=int(window))
+
     @property
     def is_classic(self) -> bool:
         return self == DecoderSpec.classic(**self.dims())
@@ -386,6 +424,28 @@ class DecoderSpec:
         return tuple(i for i in range(self.num_layers)
                      if self.layer_kind(i) == "attention")
 
+    def attention_kind(self, layer: int) -> str:
+        return self.attention_kinds[layer] if self.attention_kinds else "full"
+
+    @property
+    def window_layers(self) -> Tuple[int, ...]:
+        """The layers whose K/V live in the window pool."""
+        return tuple(i for i in self.attention_layers
+                     if self.attention_kind(i) == "window")
+
+    @property
+    def full_layers(self) -> Tuple[int, ...]:
+        """The attention layers that see every position."""
+        return tuple(i for i in self.attention_layers
+                     if self.attention_kind(i) == "full")
+
+    def rotates(self, layer: int) -> bool:
+        """Are q and k of attention layer `layer` rotated? Where the spec
+        gives a kind of attention a layer, the window layers alone."""
+        return self.rope is not None and (
+            self.attention_kinds is None
+            or self.attention_kind(layer) == "window")
+
     @property
     def conv_layers(self) -> Tuple[int, ...]:
         return tuple(i for i in range(self.num_layers)
@@ -403,10 +463,18 @@ class DecoderSpec:
 
     def cache_row_bytes(self) -> int:
         """Bytes ONE position holds in the cache, as stored: over the
-        attention layers only, K and V of the key/value heads."""
+        attention layers that see every position, K and V of the key/value
+        heads (a window layer's rows are the window pool's:
+        `window_row_bytes`)."""
         if self.attention == "latent":
             return self.num_layers * self.latent.row_lanes * self.itemsize
-        return (len(self.attention_layers) * 2 * self.kv_heads * self.d_head
+        return (len(self.full_layers) * 2 * self.kv_heads * self.d_head
+                * self.itemsize)
+
+    def window_row_bytes(self) -> int:
+        """Bytes ONE position holds in the window pool, over the window
+        layers (0 without any)."""
+        return (len(self.window_layers) * 2 * self.kv_heads * self.d_head
                 * self.itemsize)
 
     def state_bytes(self) -> int:

@@ -351,12 +351,12 @@ def _latent_attention(x, spec, name, attend, rows):
     return _proj(out, spec.d_model, name + "_o")
 
 
-def _grouped_attention(x, spec, name, attend, rows):
+def _grouped_attention(x, spec, name, attend, rows, rotate=True):
     """Attention with `spec.kv_heads` key/value heads under `num_heads`
     query heads (query head i reads key/value head i // group), an RMSNorm
     a head on q and k where the spec asks (`qk_norm`), rotary positions
-    over the whole head where it has them, around `attend(q, k_new,
-    v_new)`."""
+    over the whole head where it has them and the layer is one that
+    `rotate`s (`DecoderSpec.rotates`), around `attend(q, k_new, v_new)`."""
     n, nh, nkv, dh = x.shape[0], spec.num_heads, spec.kv_heads, spec.d_head
 
     def heads(t, count, which):
@@ -365,7 +365,7 @@ def _grouped_attention(x, spec, name, attend, rows):
                 layers.reshape(t, shape=[n, count, dh]),
                 epsilon=spec.norm_eps,
                 param_attr=ParamAttr(name=f"{name}_{which}_norm.scale"))
-        if rows.table is not None:
+        if rows.table is not None and rotate:
             t = layers.rotary(layers.reshape(t, shape=[n, count * dh]),
                               rows.positions, rows.table)
         return layers.reshape(t, shape=[n, 1, count * dh])
@@ -458,7 +458,8 @@ def _decoder_block(x, i, attend, d_model, d_inner, dropout, is_test=True,
     elif spec.rope is not None or spec.qk_norm \
             or spec.kv_heads != spec.num_heads:
         sublayers = [lambda x: _grouped_attention(
-            x, spec, f"{name}_{attn}", functools.partial(attend, i), rows)]
+            x, spec, f"{name}_{attn}", functools.partial(attend, i), rows,
+            rotate=spec.rotates(i))]
     else:
         sublayers = [lambda x: _attention(x, x, x, d_model, f"{name}_{attn}",
                                           functools.partial(attend, i))]
@@ -862,6 +863,21 @@ def _lane_feeds(L, C, NLB, block_size):
             _feed("lane_rows", [L]), _feed("lane_last", [L]))
 
 
+def _window_decode_feeds(S, NLB):
+    """The decode rows' feeds of the WINDOW pool (a spec with window layers;
+    declared right behind `_decode_feeds`, ahead of every lane feed) ->
+    (wbtab, wwblock): a slot's second table, mapped only where a position
+    of the block is still inside the window, and the window-pool block its
+    row is written to."""
+    return _feed("tick_wbtab", [S, NLB]), _feed("tick_wwblock", [S])
+
+
+def _window_lane_feeds(L, C, NLB, block_size):
+    """The lanes' feeds of the window pool -> (lwbtab, lwwblocks)."""
+    return (_feed("lane_wbtab", [L, NLB]),
+            _feed("lane_wwblocks", [L * C // block_size]))
+
+
 def _slot_cache_var(name, shape, dtype="float32"):
     """Persistable zero-initialized cache variable (main + startup blocks,
     the optimizer-accumulator idiom): the serving engine's KV caches live
@@ -1093,26 +1109,35 @@ class _PagedCache:
     fixed-shape compiled tick serves any live/idle mix; a live block table
     never maps block 0, and the read attends no position beyond a slot's
     own, so null-block garbage is never attended. Prefix sharing needs no
-    graph support: two rows of `btab` carry the SAME physical block id."""
+    graph support: two rows of `btab` carry the SAME physical block id.
+
+    `window` (a spec with sliding-window layers): those layers' pools are a
+    SECOND pool of `n_blocks` blocks with its own null block, written and
+    read through a second table and write block (`btab`, `wblock`; with
+    lanes `lbtab`, `lwblocks`), the read bounded to the last `size`
+    positions; the offsets and positions are the first pool's."""
 
     def __init__(self, cache_prefix, n_blocks, block_size, num_heads, d_head,
                  num_layers, btab, pos, wblock, woff, dropout,
                  kv_quant=False, kv_heads=None, dtype="float32",
-                 cached_layers=None):
+                 cached_layers=None, window=None):
         """`kv_heads` (None: `num_heads`) key/value heads a pool holds;
         `dtype` the pools'; `cached_layers` the layers that have pools
-        (None: all `num_layers`)."""
+        (None: all `num_layers`); `window` dict(layers, n_blocks, size,
+        btab, wblock[, lbtab, lwblocks])."""
         from ..ops.tensor_ops import pool_block_shape
         self.num_heads, self.d_head, self.dropout = num_heads, d_head, dropout
         self.kv_heads = kv_heads or num_heads
         self.btab, self.pos, self.wblock, self.woff = btab, pos, wblock, woff
+        self.window = window
         block = list(pool_block_shape(self.kv_heads, block_size, d_head))
         self.pools, self.scale_pools = {}, {}
         for i in (range(num_layers) if cached_layers is None
                   else cached_layers):
+            nb = window["n_blocks"] if self._windowed(i) else n_blocks
             for s in "kv":
                 self.pools[f"{s}{i}"] = _slot_cache_var(
-                    f"{cache_prefix}_{s}{i}", [n_blocks] + block,
+                    f"{cache_prefix}_{s}{i}", [nb] + block,
                     dtype="int8" if kv_quant else dtype)
                 if kv_quant:
                     self.scale_pools[f"{s}{i}"] = _slot_cache_var(
@@ -1121,26 +1146,37 @@ class _PagedCache:
         self.names = [v.name for v in (*self.pools.values(),
                                        *self.scale_pools.values())]
 
-    def _write(self, key, new, **lanes):
+    def _windowed(self, i):
+        return self.window is not None and i in self.window["layers"]
+
+    def _tables(self, i):
+        """(block table, write blocks, read kinds) of layer `i`'s pools."""
+        if self._windowed(i):
+            w = self.window
+            return w["btab"], w["wblock"], {"window": w["size"]}
+        return self.btab, self.wblock, {}
+
+    def _write(self, key, new, wblock, **lanes):
         """Scatter rows `new` into pool `key` → (pool, scale pool | None)."""
         pool = self.pools[key]
-        new3 = layers.reshape(new, shape=[int(np.prod(self.wblock.shape)),
+        new3 = layers.reshape(new, shape=[int(np.prod(wblock.shape)),
                                           self.kv_heads, self.d_head])
         if self.scale_pools:
             spool = self.scale_pools[key]
             return layers.paged_cache_write_quant(
-                pool, spool, new3, self.wblock, self.woff, out=pool,
+                pool, spool, new3, wblock, self.woff, out=pool,
                 scales_out=spool)
-        return layers.paged_cache_write(pool, new3, self.wblock, self.woff,
+        return layers.paged_cache_write(pool, new3, wblock, self.woff,
                                         out=pool, **lanes), None
 
     def attend(self, i, q, kn, vn):
-        (k, k_scale), (v, v_scale) = (self._write(f"k{i}", kn),
-                                      self._write(f"v{i}", vn))
+        btab, wblock, kinds = self._tables(i)
+        (k, k_scale), (v, v_scale) = (self._write(f"k{i}", kn, wblock),
+                                      self._write(f"v{i}", vn, wblock))
         ctx = layers.paged_decode_attention(
-            q, k, v, self.btab, self.pos, self.num_heads,
+            q, k, v, btab, self.pos, self.num_heads,
             scale=float(self.d_head) ** -0.5, k_scale=k_scale,
-            v_scale=v_scale)
+            v_scale=v_scale, **kinds)
         return _infer_scale(ctx, self.dropout)
 
 
@@ -1150,7 +1186,7 @@ def transformer_lm_paged_decode_tick(n_slots, n_blocks, block_size,
                                      num_layers=6, dropout=0.0, packed=False,
                                      cache_prefix="pgd", topk_k=0,
                                      kv_quant=False, model=None,
-                                     n_snapshots=0):
+                                     n_snapshots=0, n_window_blocks=0):
     """ONE decode tick over a PAGED KV cache (`_PagedCache`) — the
     block-table variant of `transformer_lm_decode_tick`, whose slots own a
     full [1,nh,max_len,dh] row each; here a request's span is T =
@@ -1174,7 +1210,8 @@ def transformer_lm_paged_decode_tick(n_slots, n_blocks, block_size,
         # the classic spec's and are not read
         return _kinds_paged_tick(model, n_slots, n_blocks, block_size,
                                  blocks_per_req, cache_prefix,
-                                 n_snapshots=n_snapshots)
+                                 n_snapshots=n_snapshots,
+                                 n_window_blocks=n_window_blocks)
     S, NLB = n_slots, blocks_per_req
     tok, pos, btab, wblock, woff, from_last = _decode_feeds(S, NLB)
     cache = _PagedCache(
@@ -1238,16 +1275,20 @@ class _PagedLaneCache(_PagedCache):
     def attend(self, i, q, kn, vn):
         (qd, ql), (kd, kl), (vd, vl) = (self.rows_of(q), self.rows_of(kn),
                                         self.rows_of(vn))
-        k, v = (self._write(key, rows, chunk=lanes,
-                            chunk_block_ids=self.lwblocks)[0]
+        btab, wblock, kinds = self._tables(i)
+        lbtab, lwblocks = ((self.window["lbtab"], self.window["lwblocks"])
+                           if self._windowed(i)
+                           else (self.lbtab, self.lwblocks))
+        k, v = (self._write(key, rows, wblock, chunk=lanes,
+                            chunk_block_ids=lwblocks)[0]
                 for key, rows, lanes in ((f"k{i}", kd, kl),
                                          (f"v{i}", vd, vl)))
         scale = float(self.d_head) ** -0.5
         ctx_d = layers.paged_decode_attention(
-            qd, k, v, self.btab, self.pos, self.num_heads, scale=scale)
+            qd, k, v, btab, self.pos, self.num_heads, scale=scale, **kinds)
         ctx_l = layers.paged_decode_attention(
-            ql, k, v, self.lbtab, self.lpos, self.num_heads, scale=scale,
-            n_rows=self.lrows)
+            ql, k, v, lbtab, self.lpos, self.num_heads, scale=scale,
+            n_rows=self.lrows, **kinds)
         ctx = layers.concat(
             [ctx_d, layers.reshape(ctx_l, shape=[self.L * self.C, 1,
                                                  q.shape[-1]])], axis=0)
@@ -1408,7 +1449,8 @@ def _live_rows(wblock, lrows=None, chunk=0):
 
 
 def _kinds_paged_tick(model, n_slots, n_blocks, block_size, blocks_per_req,
-                      cache_prefix, lanes=None, n_snapshots=0):
+                      cache_prefix, lanes=None, n_snapshots=0,
+                      n_window_blocks=0):
     """The paged decode tick (`lanes` None) or mixed tick of a non-classic
     `DecoderSpec`: the classic builders' feeds (`_decode_feeds`,
     `_lane_feeds`, and `lane_slot` where a lane leaves a state in its
@@ -1428,6 +1470,11 @@ def _kinds_paged_tick(model, n_slots, n_blocks, block_size, blocks_per_req,
             "cache seam yet")
     S, NLB = n_slots, blocks_per_req
     tok, pos, btab, wblock, woff, from_last = _decode_feeds(S, NLB)
+    window = None
+    if model.window_layers:
+        wbtab, wwblock = _window_decode_feeds(S, NLB)
+        window = dict(layers=model.window_layers, n_blocks=n_window_blocks,
+                      size=model.window, btab=wbtab, wblock=wwblock)
     last = _LastIds(cache_prefix, S)
     tok = last.feed(tok, from_last)
     toks, positions, lane_feeds, lrows = tok, pos, None, None
@@ -1435,6 +1482,9 @@ def _kinds_paged_tick(model, n_slots, n_blocks, block_size, blocks_per_req,
         L, C = lanes
         ltok, lpos, lbtab, lwblocks, lrows, llast = _lane_feeds(
             L, C, NLB, block_size)
+        if window is not None:
+            window["lbtab"], window["lwblocks"] = _window_lane_feeds(
+                L, C, NLB, block_size)
         lane_feeds = dict(n_slots=S, n_lanes=L, chunk=C, lbtab=lbtab,
                           lpos=lpos, lwblocks=lwblocks, lrows=lrows)
         toks = layers.concat([tok, layers.reshape(ltok, shape=[L * C, 1])],
@@ -1450,7 +1500,7 @@ def _kinds_paged_tick(model, n_slots, n_blocks, block_size, blocks_per_req,
         paged = (cache_prefix, n_blocks, block_size, model.num_heads,
                  model.d_head, model.num_layers, btab, pos, wblock, woff, 0.0)
         kinds = dict(kv_heads=model.kv_heads, dtype=model.dtype,
-                     cached_layers=model.attention_layers)
+                     cached_layers=model.attention_layers, window=window)
         cache = (_PagedCache(*paged, **kinds) if lanes is None
                  else _PagedLaneCache(*paged, **lane_feeds, **kinds))
         if model.conv is not None:
@@ -1498,7 +1548,7 @@ def transformer_lm_paged_mixed_tick(n_slots, n_lanes, chunk, n_blocks,
                                     d_model=512, d_inner=2048, num_heads=8,
                                     num_layers=6, dropout=0.0, packed=False,
                                     cache_prefix="pgd", model=None,
-                                    n_snapshots=0):
+                                    n_snapshots=0, n_window_blocks=0):
     """ONE tick of decode rows AND prefill lanes over the paged KV pools
     (`_PagedLaneCache`): `transformer_lm_paged_decode_tick`'s S decode rows
     (same feeds, same pools and weights by name) plus L = `n_lanes` lanes
@@ -1521,7 +1571,8 @@ def transformer_lm_paged_mixed_tick(n_slots, n_lanes, chunk, n_blocks,
     assert C % BS == 0, "a chunk is a whole number of blocks"
     if model is not None and not model.is_classic:
         return _kinds_paged_tick(model, S, n_blocks, BS, NLB, cache_prefix,
-                                 lanes=(L, C), n_snapshots=n_snapshots)
+                                 lanes=(L, C), n_snapshots=n_snapshots,
+                                 n_window_blocks=n_window_blocks)
     tok, pos, btab, wblock, woff, from_last = _decode_feeds(S, NLB)
     ltok, lpos, lbtab, lwblocks, lrows, llast = _lane_feeds(L, C, NLB, BS)
     last = _LastIds(cache_prefix, S)

@@ -74,6 +74,16 @@ tests/test_offload.py and BENCH_OFFLOAD_r23.json). The two-pool
 accounting identity extends exactly: used_dev + used_host + free_dev +
 free_host == (n_blocks - 1) + host_blocks (`KVPager.check_two_tier`).
 
+Two kinds of attention layer (ISSUE 45): a model with sliding-window
+layers keeps those layers' K/V in a SECOND pool behind a second table a
+request (`BlockTable.window_blocks`): a block is mapped when a tick first
+writes it (`KVPager.map_window`) and released once every position of it
+has slid out of the window (`KVPager.slide_window`), so a request holds a
+bounded number of window blocks whatever its length; an index node keeps
+its window block only as part of a registered span's window TAIL, and a
+prefix hit is cut where the tail is gone (docs/serving.md, "the window
+pool"). Both pools' accounting is exact (`KVPager.check_window`).
+
 Ownership verification (ISSUE r24): every mutation this module makes
 is modeled declaratively in `framework/ownership.py` — the
 depth-bounded model checker proves the protocol's invariants over all
@@ -93,7 +103,7 @@ import numpy as np
 from ..core.enforce import InvalidArgumentError, enforce
 from ..framework import offload as _offload
 from ..framework.offload import HostTierConfig
-from ..framework.ownership import check_span_snapshot
+from ..framework.ownership import check_span_snapshot, check_window_read
 from ..observability import memory as _obs_memory
 from ..observability import tracing as _tracing
 from .engine import (ContinuousBatchingEngine, GenRequest, _ENGINE_SEQ,
@@ -188,7 +198,7 @@ class BlockTable:
     to this request — writes start at `shared_len`)."""
 
     __slots__ = ("blocks", "n_shared", "shared_len", "snapshot",
-                 "snapshot_write")
+                 "snapshot_write", "window_blocks", "window_lo")
 
     def __init__(self, blocks: List[int], n_shared: int = 0,
                  shared_len: int = 0):
@@ -200,6 +210,19 @@ class BlockTable:
         #: block) of the snapshot a lane of the tick in flight is writing
         self.snapshot: Optional[int] = None
         self.snapshot_write: Optional[Tuple[int, int]] = None
+        #: the SECOND table, of a model with sliding-window layers (None
+        #: without): logical block j -> its block of the window pool, 0
+        #: where it is not mapped (released behind the window, never
+        #: written yet, or below a shared span's tail); `window_lo` is the
+        #: first logical block that may still be mapped
+        self.window_blocks: Optional[List[int]] = None
+        self.window_lo = 0
+
+    @property
+    def window_held(self) -> int:
+        """Window-pool blocks this table maps right now."""
+        wb = self.window_blocks
+        return 0 if wb is None else sum(1 for b in wb[self.window_lo:] if b)
 
     def __len__(self):
         return len(self.blocks)
@@ -210,7 +233,8 @@ class BlockTable:
 
 
 class _RadixNode:
-    __slots__ = ("key", "block", "children", "parent", "last_used", "snap")
+    __slots__ = ("key", "block", "children", "parent", "last_used", "snap",
+                 "wblock")
 
     def __init__(self, key, block, parent):
         self.key = key              # tuple of block_size token ids
@@ -219,6 +243,7 @@ class _RadixNode:
         self.parent = parent
         self.last_used = 0
         self.snap: Optional[int] = None   # its entry of the snapshot pool
+        self.wblock: Optional[int] = None  # its block of the window pool
 
 
 class RadixPrefixIndex:
@@ -262,14 +287,17 @@ class RadixPrefixIndex:
         return out
 
     def register(self, prompt: Sequence[int], logical_block: int,
-                 phys: int, pool: BlockPool) -> bool:
+                 phys: int, pool: BlockPool,
+                 note: Optional[Callable[[_RadixNode], None]] = None) -> bool:
         """Offer block `logical_block` of `prompt` (physically `phys`,
         just fully written) to the cache. No-ops when the content chain
         already exists (a concurrent request filled the same prefix
         first — the existing copy stays canonical) or when an ancestor
         chain node is missing (evicted mid-flight — registering would
         orphan the new node's match path). On success the index takes
-        its OWN ref on `phys`, so the block outlives its request."""
+        its OWN ref on `phys`, so the block outlives its request. `note` is
+        called with the block's node, new or cached before (the pager hangs
+        the node's window-pool block on it)."""
         node = self.root
         keys = self._keys(prompt, logical_block + 1)
         for j, key in enumerate(keys):
@@ -282,9 +310,13 @@ class RadixPrefixIndex:
                 pool.share(phys)            # the index's retention ref
                 self.n_cached += 1
                 child.last_used = self._tick()
+                if note is not None:
+                    note(child)
                 return True
             child.last_used = self._tick()
             node = child
+        if note is not None and keys:
+            note(node)
         return False                        # full chain already cached
 
     def evict_one(self, pool: BlockPool) -> bool:
@@ -357,7 +389,8 @@ class KVPager:
     def __init__(self, n_blocks: int, block_size: int,
                  prefix_sharing: bool = True,
                  host_tier: Optional[HostTierConfig] = None,
-                 block_state: bool = False, n_snapshots: int = 0):
+                 block_state: bool = False, n_snapshots: int = 0,
+                 window: int = 0, n_window_blocks: int = 0):
         self.block_size = int(block_size)
         self.prefix_sharing = bool(prefix_sharing)
         #: the model keeps a per-request STATE beside its per-token rows
@@ -387,7 +420,31 @@ class KVPager:
         self._snap_clock = 0
         self.snapshot_evictions = 0     # valid entries taken for another
         self.hits_truncated = 0         # hits cut to a shallower snapshot
-        if self.n_snapshots:
+        #: ... or, where some layers attend a sliding WINDOW of `window`
+        #: positions, a second pool for those layers' K/V and a second table
+        #: a request (`BlockTable.window_blocks`): a block is mapped from
+        #: the tick that first writes it (`map_window`) until every
+        #: position of it has slid out of the window (`slide_window`), so a
+        #: request holds a bounded number whatever its length. An index
+        #: node holds its window block only as one of the last
+        #: `tail_nodes` blocks of a prompt as it was registered (the
+        #: span's window TAIL); a prefix hit is cut to the deepest node
+        #: whose tail is resident, and a tail is evicted with its node, or
+        #: alone (least recently used) when the window pool runs dry.
+        self.window = int(window)
+        self.wpool = BlockPool(n_window_blocks, block_size) \
+            if self.window else None
+        self.tail_nodes = -(-self.window // self.block_size) + 1 \
+            if self.window else 0
+        self._tails: Dict[int, _RadixNode] = {}   # id(node) -> node w/ wblock
+        self.window_blocks_released = 0  # slid out of a request's window
+        self.window_tail_lookups = 0     # admissions that matched a span
+        self.window_tail_hits = 0        # ... and kept the whole of it
+        self.window_tail_evictions = 0   # tails dropped, node kept
+        #: window blocks a request held, counted at each of its commits
+        #: (index = blocks, value = commits)
+        self.window_blocks_held = np.zeros(64, np.int64)
+        if self.n_snapshots or self.window:
             self.index.on_evict = self._node_evicted
         self.host_tier = host_tier
         self.host_blocks_used = 0
@@ -455,18 +512,35 @@ class KVPager:
                 check_span_snapshot([n.block for n in shared_nodes],
                                     holder and holder.block, "try_admit")
                 self._snap_pins[entry] += 1
+        wtail: List[int] = []       # the span's window tail, by block
+        if self.window and shared_nodes:
+            # the span ends at the deepest node whose window tail is resident
+            # (`hits_truncated`, as for a missing snapshot): the request's
+            # first chunk reads the last window - 1 positions of the span
+            matched = len(shared_nodes)
+            self.window_tail_lookups += 1
+            while shared_nodes and not all(
+                    n.wblock for n in self._tail_of(shared_nodes)):
+                shared_nodes.pop()
+            self.hits_truncated += len(shared_nodes) < matched
+            self.window_tail_hits += len(shared_nodes) == matched
+            wtail = [n.wblock for n in self._tail_of(shared_nodes)]
         # pin the matched blocks FIRST: eviction under pressure below
         # may drop their index nodes, but a pinned block cannot free
         blocks = []
         for node in shared_nodes:
             self.pool.share(node.block)
             blocks.append(node.block)
+        for held in wtail:
+            self.wpool.share(held)
         need_new = n_logical - len(shared_nodes)
         for _ in range(need_new):
             b = self._alloc_or_evict()
             if b is None:                    # rollback, stay pending
                 for held in blocks:
                     self.pool.release(held)
+                for held in wtail:
+                    self.wpool.release(held)
                 if entry is not None:
                     self._snap_pins[entry] -= 1
                 return None
@@ -478,6 +552,16 @@ class KVPager:
             self.prefix_hits += 1
             self.shared_blocks_total += n_shared
         table = BlockTable(blocks, n_shared, n_shared * self.block_size)
+        if self.window:
+            table.window_blocks = [0] * n_logical
+            table.window_lo = n_shared - len(wtail)
+            table.window_blocks[table.window_lo:n_shared] = wtail
+            if n_shared:
+                # the rule a span is handed out under: its first chunk's
+                # window read finds every block it spans
+                check_window_read(table.window_blocks,
+                                  self.window_first(table.shared_len),
+                                  n_shared - 1, "try_admit")
         if entry is not None:
             table.snapshot = entry
             if self._snap_node[entry] is not None:   # else: evicted just now
@@ -502,6 +586,8 @@ class KVPager:
             self._snap_node[node.snap] = None
             self._snap_used[node.snap] = 0
             node.snap = None
+        if node.wblock is not None:     # its window tail goes with it
+            self._drop_tail(node)
 
     def snapshot_read(self, table: BlockTable):
         """The table's first chunk ran (or never will): unpin the entry it
@@ -548,6 +634,86 @@ class KVPager:
         self._snap_node[entry] = node
         self._touch_snapshot(entry)
 
+    # -- the window pool --------------------------------------------------
+    def window_first(self, pos: int) -> int:
+        """The first logical block a window read at position `pos` spans."""
+        return max(pos - (self.window - 1), 0) // self.block_size
+
+    def _tail_of(self, nodes: List[_RadixNode]) -> List[_RadixNode]:
+        """The nodes of a span of `len(nodes)` blocks whose window blocks
+        the position after the span still attends."""
+        return nodes[self.window_first(len(nodes) * self.block_size):]
+
+    def window_bound(self, chunk: int) -> int:
+        """The most window blocks one request holds at a time, whatever its
+        length, with `chunk` prompt tokens a tick."""
+        return -(-(self.window + int(chunk)) // self.block_size) + 1
+
+    def _node_holds_tail(self, node: _RadixNode, wblock: int):
+        """The index takes its own ref on `wblock` for `node`."""
+        self.wpool.share(wblock)
+        node.wblock = wblock
+        self._tails[id(node)] = node
+
+    def _drop_tail(self, node: _RadixNode):
+        self.wpool.release(node.wblock)
+        node.wblock = None
+        del self._tails[id(node)]
+
+    def _alloc_window(self) -> int:
+        """A block of the window pool; under pressure the least recently
+        used tail goes, its node stays (deeper hits are cut, never wrong).
+        The engine sizes the pool so that every slot's bound fits beside
+        nothing else: this cannot come up dry."""
+        while True:
+            b = self.wpool.alloc()
+            if b is not None:
+                return b
+            enforce(self._tails,
+                    f"window pool exhausted: {self.wpool.n_blocks - 1} blocks "
+                    f"all held by live requests (the engine sizes it to "
+                    f"n_slots x the per-request bound)",
+                    exc=InvalidArgumentError)
+            self._drop_tail(min(self._tails.values(),
+                                key=lambda n: n.last_used))
+            self.window_tail_evictions += 1
+
+    def map_window(self, table: BlockTable, pos: int, n: int = 1):
+        """The tick being filled writes positions pos..pos+n-1 of `table`'s
+        request into the window layers: map their logical blocks (a block
+        already mapped stays), then hold the read to the window's rule."""
+        wb = table.window_blocks
+        last = (pos + n - 1) // self.block_size
+        for j in range(pos // self.block_size, last + 1):
+            if not wb[j]:
+                wb[j] = self._alloc_window()
+        check_window_read(wb, self.window_first(pos), last, "map_window")
+
+    def slide_window(self, table: BlockTable, fed: int):
+        """Positions below `fed` are committed: every window block wholly
+        below position fed - 1 - (window - 1) goes back to the pool (the
+        index keeps a tail's block alive), and what the request still holds
+        is counted."""
+        wb = table.window_blocks
+        if wb is None:
+            return
+        keep = max(fed - self.window, 0) // self.block_size
+        for j in range(table.window_lo, min(keep, len(wb))):
+            if wb[j]:
+                self.wpool.release(wb[j])
+                wb[j] = 0
+                self.window_blocks_released += 1
+        table.window_lo = max(table.window_lo, min(keep, len(wb)))
+        held = table.window_held
+        self.window_blocks_held[min(held, len(self.window_blocks_held) - 1)] \
+            += 1
+
+    def check_window(self):
+        """The window pool's accounting identity (`BlockPool.check`: used +
+        free == n - 1, free iff refcount 0); nothing without a window."""
+        if self.wpool is not None:
+            self.wpool.check()
+
     def _alloc_or_evict(self) -> Optional[int]:
         while True:
             b = self.pool.alloc()
@@ -579,8 +745,19 @@ class KVPager:
             return
         if self.block_state and not self._snap[table.blocks[logical_block]]:
             return          # nothing to resume from: not offered
+        note = None
+        if self.window and logical_block >= \
+                len(prompt) // self.block_size - self.tail_nodes:
+            # one of the span's last blocks as it is registered: its node
+            # holds the window block too (a node cached before that lost
+            # its tail gets this request's, the same bytes)
+            wblock = table.window_blocks[logical_block]
+
+            def note(node):
+                if node.wblock is None and wblock:
+                    self._node_holds_tail(node, wblock)
         self.index.register(prompt, logical_block,
-                            table.blocks[logical_block], self.pool)
+                            table.blocks[logical_block], self.pool, note)
 
     def fork(self, table: BlockTable, written_len: int,
              copy_block: Callable[[int, int], None]) -> BlockTable:
@@ -590,6 +767,10 @@ class KVPager:
         moves its device bytes), and takes fresh private blocks for the
         not-yet-written remainder. Raises when the pool cannot cover
         the fork even after eviction."""
+        enforce(not self.window,
+                "fork walks the block table alone: a model with window "
+                "layers keeps a second table it would neither share nor copy",
+                exc=InvalidArgumentError)
         n_full, rem = divmod(int(written_len), self.block_size)
         blocks: List[int] = []
         try:
@@ -627,6 +808,11 @@ class KVPager:
             if b:
                 self.pool.release(b)
         table.blocks = []
+        if table.window_blocks is not None:
+            for b in table.window_blocks[table.window_lo:]:
+                if b:
+                    self.wpool.release(b)
+            table.window_blocks = None
         self.snapshot_read(table)
         if table.snapshot_write is not None:     # its tick never committed
             self._snap_pins[table.snapshot_write[0]] -= 1
@@ -648,6 +834,10 @@ class KVPager:
         holds at least the block just freed). Both halves are enforced:
         a refcounted rollback block or a failed realloc is an invariant
         breach, not a condition to handle."""
+        enforce(not self.window,
+                "rollback remaps the block table alone: a model with window "
+                "layers keeps a second table whose released blocks it could "
+                "not bring back", exc=InvalidArgumentError)
         bs = self.block_size
         first = -(-int(keep_len) // bs)          # first fully-rejected block
         last = (int(written_len) - 1) // bs      # last written block
@@ -685,6 +875,9 @@ class KVPager:
         enforce(self.host_tier is not None,
                 "evict_table_to_host without a host tier",
                 exc=InvalidArgumentError)
+        enforce(not self.window,
+                "the host tier spills the block table alone, not the window "
+                "table", exc=InvalidArgumentError)
         bs = self.block_size
         n_content = -(-int(written_len) // bs)   # blocks with live rows
         spilled = [j for j in range(table.n_shared,
@@ -797,6 +990,19 @@ class KVPager:
                 "restores": self.state_restores,
                 "evictions": self.snapshot_evictions,
                 "hits_truncated": self.hits_truncated},
+            "window": None if not self.window else {
+                "positions": self.window,
+                "n_blocks": self.wpool.n_blocks,
+                "blocks_used": self.wpool.n_used,
+                "blocks_free": self.wpool.n_free,
+                "tail_nodes": self.tail_nodes,
+                "tails_cached": len(self._tails),
+                "blocks_released": self.window_blocks_released,
+                "tail_lookups": self.window_tail_lookups,
+                "tail_hits": self.window_tail_hits,
+                "tail_evictions": self.window_tail_evictions,
+                "hits_truncated": self.hits_truncated,
+                "blocks_held": self.window_blocks_held.tolist()},
             "host_tier": None if self.host_tier is None else {
                 "host_blocks": self.host_tier.host_blocks,
                 "host_blocks_used": self.host_blocks_used,
@@ -863,6 +1069,12 @@ class PagedKVEngine(ContinuousBatchingEngine):
     snapshot of it beside every pool block, which a prefix hit resumes
     from (`models.transformer._ConvState`; `stats()["conv_state"]`,
     `engine/admit`'s `state_restored`, `engine/tick`'s `state_snapshots`).
+    A spec with sliding-window layers (`DecoderSpec.window_gqa_moe`) gets a
+    SECOND pool of `n_window_blocks` blocks for those layers and a second
+    table a request, whose blocks go back to the pool as they slide out of
+    the window (`stats()["window_pool"]`; `engine/tick`'s `kv_blocks` then
+    counts both pools' reads, `window_blocks` and `window_rows` the window
+    part).
     With any such model `speculative=`, `host_tier=`, `kv_quant=`, `quant=`
     and `topk_k` are refused by name: none of them is built for it.
     """
@@ -879,7 +1091,8 @@ class PagedKVEngine(ContinuousBatchingEngine):
                  quant: Optional[str] = None, kv_quant: bool = False,
                  speculative=None,
                  host_tier: Optional[HostTierConfig] = None,
-                 model=None, n_snapshots: int = 0):
+                 model=None, n_snapshots: int = 0,
+                 n_window_blocks: int = 0):
         from ..models.decoder_spec import DecoderSpec
         if model is None:
             model = DecoderSpec.classic(vocab, d_model, d_inner, num_heads,
@@ -909,6 +1122,8 @@ class PagedKVEngine(ContinuousBatchingEngine):
                         + (" beside short-convolution layers"
                            if model.conv else "")
                         + (" beside state-space layers" if model.ssm else "")
+                        + (" beside sliding-window layers"
+                           if model.window else "")
                         + (" and routed experts" if model.moe else "")
                         + ": it walks the classic K/V pools and float32 "
                         "weights"
@@ -920,6 +1135,11 @@ class PagedKVEngine(ContinuousBatchingEngine):
                            "rows, the snapshot pool), which it would "
                            "neither roll back, spill, fork nor quantize"
                            if model.ssm else "")
+                        + (", not the window table (a request's second block "
+                           "table over the window pool, whose blocks are "
+                           "released behind the window), which it would "
+                           "neither roll back, spill, fork nor quantize"
+                           if model.window else "")
                         + "; serve this model without it",
                         exc=InvalidArgumentError)
         #: (layer, held expert) -> rows routed to it since construction
@@ -958,6 +1178,9 @@ class PagedKVEngine(ContinuousBatchingEngine):
         #: ... and of ONE copy of the conv layers' state: a slot holds
         #: one, and every pool block a snapshot (0 without conv layers)
         self.state_bytes = model.state_bytes()
+        #: ... and in the window pool (0 without sliding-window layers)
+        self.window_block_bytes = model.window_row_bytes() * self.block_size
+        self.n_window_blocks = int(n_window_blocks) if model.window else 0
         #: entries of the snapshot pool (state-space layers only: their
         #: state is too large for a snapshot a block)
         self.n_snapshots = int(n_snapshots) if model.ssm is not None else 0
@@ -1005,7 +1228,20 @@ class PagedKVEngine(ContinuousBatchingEngine):
                              prefix_sharing, host_tier=host_tier,
                              block_state=bool(self.state_bytes)
                              and not self.n_snapshots,
-                             n_snapshots=self.n_snapshots)
+                             n_snapshots=self.n_snapshots,
+                             window=model.window,
+                             n_window_blocks=self.n_window_blocks)
+        if model.window:
+            # every slot's bound beside nothing else: a tick's fill can
+            # always map what it writes once the index gave its tails up
+            bound = self.pager.window_bound(self.chunk_tokens)
+            enforce(self.n_window_blocks >= n_slots * bound + 1,
+                    f"n_window_blocks = {self.n_window_blocks}: a model "
+                    f"with sliding-window layers needs n_slots ({n_slots}) x "
+                    f"{bound} blocks (a request's bound at a window of "
+                    f"{model.window}, chunks of {self.chunk_tokens} and "
+                    f"blocks of {self.block_size}) + the null block",
+                    exc=InvalidArgumentError)
         # the pager's `state_restores` (and `snapshot_evictions`) at the
         # last `engine/admit` span, which carries what was added since
         self._state_seen = 0
@@ -1056,7 +1292,8 @@ class PagedKVEngine(ContinuousBatchingEngine):
                 block_size=self.block_size,
                 blocks_per_req=self.blocks_per_req,
                 cache_prefix=self._cache_prefix, model=self.model,
-                n_snapshots=self.n_snapshots, **self._builder_dims)
+                n_snapshots=self.n_snapshots,
+                n_window_blocks=self.n_window_blocks, **self._builder_dims)
             self._mixed_ids = outs[0]
         self._init_missing_vars(startup)        # nothing, by construction
         if self.quant is not None:
@@ -1110,7 +1347,8 @@ class PagedKVEngine(ContinuousBatchingEngine):
             blocks_per_req=self.blocks_per_req,
             cache_prefix=self._cache_prefix, topk_k=self.topk_k,
             kv_quant=self.kv_quant, model=self.model,
-            n_snapshots=self.n_snapshots, **d)
+            n_snapshots=self.n_snapshots,
+            n_window_blocks=self.n_window_blocks, **d)
         if self.topk_k:
             (self._next_ids, self.cache_names,
              self._topk_logp, self._topk_ids) = outs
@@ -1144,6 +1382,13 @@ class PagedKVEngine(ContinuousBatchingEngine):
         woff[:] = 0
         bs = self.block_size
         kv_blocks = kv_rows = 0
+        window = self.model.window
+        if window:
+            wbtab = self._feeds["tick_wbtab"]
+            wwblock = self._feeds["tick_wwblock"]
+            wbtab[:] = 0
+            wwblock[:] = 0
+            win_blocks = win_rows = 0
         prefilling = []
         for slot, req in active.items():
             if self._prefilling(req):
@@ -1161,8 +1406,22 @@ class PagedKVEngine(ContinuousBatchingEngine):
             woff[slot] = off
             kv_blocks += lb + 1      # the blocks this slot's read spans
             kv_rows += req.fed + 1   # ... and the positions it attends
+            if window:
+                # the row's block of the window pool, mapped now; the read
+                # starts at the block of position fed - (window - 1)
+                self.pager.map_window(req.table, req.fed)
+                wb, lo = req.table.window_blocks, req.table.window_lo
+                wbtab[slot, lo:lb + 1] = wb[lo:lb + 1]
+                wwblock[slot] = wb[lb]
+                win_blocks += lb + 1 - self.pager.window_first(req.fed)
+                win_rows += min(req.fed + 1, window)
         self._tick_attrs["kv_blocks"] = kv_blocks
         self._tick_attrs["decode_rows"] = kv_rows
+        if window:
+            # `kv_blocks`: the blocks the reads span in BOTH pools
+            self._tick_attrs["kv_blocks"] += win_blocks
+            self._tick_attrs["window_blocks"] = win_blocks
+            self._tick_attrs["window_rows"] = win_rows
         if self._mixed_step is not None:
             self._fill_lanes(prefilling)
 
@@ -1190,10 +1449,20 @@ class PagedKVEngine(ContinuousBatchingEngine):
                 lf["lane_snap_dst"][:] = -1
             bs, C = self.block_size, self.chunk_tokens
             cb = C // bs
+            window = self.model.window
             for lane, req in enumerate(prefilling[:self.n_lanes]):
                 k0, blocks = req.fed, req.table.blocks
                 n = min(C, len(req.prompt) - k0)
                 b0, nb = k0 // bs, -(-n // bs)
+                if window:
+                    self.pager.map_window(req.table, k0, n)
+                    wb, lo = req.table.window_blocks, req.table.window_lo
+                    lf["lane_wbtab"][lane, lo:b0 + nb] = wb[lo:b0 + nb]
+                    lf["lane_wwblocks"][lane * cb:lane * cb + nb] = \
+                        wb[b0:b0 + nb]
+                    span = b0 + nb - self.pager.window_first(k0)
+                    attrs["kv_blocks"] += span
+                    attrs["window_blocks"] += span
                 lf["lane_tok"][lane, :n] = req.prompt[k0:k0 + n]
                 lf["lane_pos"][lane, 0, 0] = float(k0)
                 lf["lane_btab"][lane, :len(blocks)] = blocks
@@ -1295,6 +1564,7 @@ class PagedKVEngine(ContinuousBatchingEngine):
             self.pager.snapshot_read(req.table)   # the chunk started there
             if req.table.snapshot_write is not None:
                 self.pager.snapshot_written(req.table, req.prompt)
+        self.pager.slide_window(req.table, req.fed)
         if req.fed < len(req.prompt):
             req.next_tok = req.prompt[req.fed]
             return False
@@ -1352,6 +1622,10 @@ class PagedKVEngine(ContinuousBatchingEngine):
     def _admit_pool_attrs(self) -> Dict[str, int]:
         attrs = {"pool_used": self.pager.pool.n_used,
                  "pool_blocks": self.n_blocks}
+        if self.model.window:
+            attrs["window_pool_used"] = self.pager.wpool.n_used
+            attrs["window_tail_hits"] = self.pager.window_tail_hits
+            attrs["hits_truncated"] = self.pager.hits_truncated
         if self.state_bytes:
             now = self.pager.state_restores
             attrs["state_restored"] = now - self._state_seen
@@ -1384,6 +1658,7 @@ class PagedKVEngine(ContinuousBatchingEngine):
             self.pager.note_block_filled(req.table,
                                          pos // self.block_size,
                                          req.prompt)
+        self.pager.slide_window(req.table, pos + 1)
 
     # -- two-tier scheduler (host_tier=) ----------------------------------
     @staticmethod
@@ -1658,11 +1933,17 @@ class PagedKVEngine(ContinuousBatchingEngine):
         # blocks actually allocated right now — live paging state, the
         # split the slot engine can only fake (its rows are always
         # reserved whole)
-        per_block = self._kv_bytes_static / max(self.n_blocks, 1)
         _obs_memory.update_watermark("kv_cache_bytes",
                                      self._kv_bytes_static)
-        _obs_memory.update_watermark("kv_cache_used_bytes",
-                                     self.pager.pool.n_used * per_block)
+        if self.model.window:
+            # two pools under the one pair: reserved is both, used each
+            # pool's allocated blocks at its own block's bytes
+            used = (self.pager.pool.n_used * self.block_bytes
+                    + self.pager.wpool.n_used * self.window_block_bytes)
+        else:
+            used = self.pager.pool.n_used * (
+                self._kv_bytes_static / max(self.n_blocks, 1))
+        _obs_memory.update_watermark("kv_cache_used_bytes", used)
 
     def _init_metrics(self):
         super()._init_metrics()
@@ -1742,6 +2023,12 @@ class PagedKVEngine(ContinuousBatchingEngine):
         s["kv_quant"] = {"enabled": self.kv_quant,
                          "freed_bytes": self.kv_quant_freed_bytes}
         s["block_bytes"] = self.block_bytes
+        if self.model.window:
+            # the second pool, of the sliding-window layers
+            s["window_pool"] = dict(
+                s["pager"]["window"], block_bytes=self.window_block_bytes,
+                pool_bytes=self.window_block_bytes * self.n_window_blocks,
+                request_bound=self.pager.window_bound(self.chunk_tokens))
         if self.n_snapshots:
             # the second kind of state, too large for a snapshot a block: a
             # copy a slot, and the pool's entries

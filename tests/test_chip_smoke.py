@@ -49,6 +49,19 @@ def test_train_then_serve_from_the_same_scope():
     assert serve["prefill"] == "chunked" and serve["programs_compiled"] == 2
 
 
+def test_window_read_phase():
+    """The smoke's window read (the decode kernel and the chunk kernel
+    bounded by a sliding window, and unbounded, against the composite),
+    interpreted at a small table: heads of 128 in blocks of 64, a window of
+    128 as the cell has them."""
+    out = chip_smoke.phase_window_read(
+        n_slots=8, n_blocks=40, blocks_per_req=6, num_heads=16,
+        num_kv_heads=2, chunk=64, backend="pallas_interpret")
+    assert set(out["max_rel_err"]) == {"window_decode", "window_chunk",
+                                       "full_decode", "full_chunk"}
+    assert all(0 < e < 1e-5 for e in out["max_rel_err"].values())
+
+
 def test_hybrid_serving_phase():
     """The smoke's hybrid engine (conv layers with a state, grouped rotary
     attention, all-held experts under a biased top-k, a tied head) at a tiny

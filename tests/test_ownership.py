@@ -536,3 +536,69 @@ class TestSnapshotPoolRule:
         pager._snap_node[0] = pager.index.node_of(prompt, 0)
         with pytest.raises(OwnershipViolation, match="kv-span-past-snapshot"):
             pager.try_admit(prompt, 12)
+
+
+class TestWindowReadRule:
+    """The window pool's rule: a read through a request's window table spans
+    mapped blocks only (`kv-window-read-after-release`, kin to
+    `kv-span-past-snapshot`)."""
+
+    def test_a_read_inside_the_mapped_span_passes(self):
+        from paddle_tpu.framework.ownership import check_window_read
+        check_window_read([0, 0, 7, 9, 4, 0], 2, 4, "map_window")
+        check_window_read([0, 0], 0, -1, "try_admit")       # spans nothing
+
+    def test_a_read_that_spans_a_released_block_is_named(self):
+        from paddle_tpu.framework.ownership import check_window_read
+        with pytest.raises(OwnershipViolation) as e:
+            check_window_read([0, 0, 7, 9, 4, 0], 1, 4, "map_window")
+        assert e.value.code == "kv-window-read-after-release"
+        assert e.value.block == 1 and e.value.op == "map_window"
+        assert "kv-window-read-after-release" in DIAGNOSTICS
+
+    def test_the_pager_refuses_a_window_that_starts_below_what_it_released(
+            self):
+        from paddle_tpu.serving.kv_pager import KVPager
+        pager = KVPager(40, 4, window=8, n_window_blocks=12)
+        prompt = list(range(30))
+        table = pager.try_admit(prompt, 40)
+        for pos in range(0, 24, 8):                 # three chunks of eight
+            pager.map_window(table, pos, 8)
+            pager.slide_window(table, pos + 8)
+        assert table.window_lo == 4 and table.window_held == 2
+        assert pager.window_blocks_released == 4
+        pager.map_window(table, 24)                 # the next position: fine
+        # the planted bug: a window counted one block too wide
+        pager.window = 13
+        with pytest.raises(OwnershipViolation,
+                           match="kv-window-read-after-release"):
+            pager.map_window(table, 24)
+        pager.window = 8
+        pager.release(table)
+        pager.check_window()
+        assert pager.wpool.n_used == 0
+
+    def test_a_span_is_handed_out_only_with_its_whole_tail(self):
+        from paddle_tpu.serving.kv_pager import KVPager
+        pager = KVPager(40, 4, window=8, n_window_blocks=12)
+        prompt = list(range(24))
+        table = pager.try_admit(prompt, 28)
+        for pos in range(0, 24, 8):
+            pager.map_window(table, pos, 8)
+            for lb in (pos // 4, pos // 4 + 1):
+                pager.note_block_filled(table, lb, prompt)
+            pager.slide_window(table, pos + 8)
+        pager.release(table)
+        # blocks 3, 4, 5 of the six are the span's tail
+        assert [pager.index.node_of(prompt, j).wblock is not None
+                for j in range(6)] == [False] * 3 + [True] * 3
+        hit = pager.try_admit(prompt + [1, 2], 32)
+        assert hit.shared_len == 24 and hit.window_lo == 4
+        assert hit.window_held == 2 and pager.window_tail_hits == 1
+        # the planted bug: the tail said to be there with a block of it gone
+        node = pager.index.node_of(prompt, 4)
+        pager.wpool.release(node.wblock)
+        kept, node.wblock = node.wblock, 0
+        assert pager.try_admit(prompt + [3], 32).shared_len < 24
+        node.wblock = kept
+        pager.wpool.share  # (the pool is the test's to leave unbalanced)

@@ -418,6 +418,56 @@ def test_equal_heads_float32_pools_keep_their_kernels():
         assert _n_calls(text) == 1 and "paged_gqa_attention" not in text
 
 
+# -- a sliding window beside full layers: heads of 128, two pools ------------
+
+_WIN = dict(nh=64, nkv=8, dh=128, bs=64, n_blocks=512, table=272, window=128)
+
+
+def _window_read(slots, positions, window):
+    from paddle_tpu.fusion import paged_decode_attention
+    g = _WIN
+    pool = S((g["n_blocks"], g["nkv"], g["bs"] * g["dh"] // 128, 128), BF16)
+    return (lambda q, k, v, t, p, r: paged_decode_attention(
+                q, k, v, t, p, g["nh"], scale=g["dh"] ** -0.5, rows=r,
+                backend="pallas", window=window),
+            [S((slots, positions, g["nh"] * g["dh"]), BF16), pool, pool,
+             S((slots, g["table"]), I32), S((slots, 1, 1), F32),
+             S((slots,), I32)])
+
+
+@pytest.mark.parametrize("slots, positions", [(32, 1), (2, 128)])
+@pytest.mark.parametrize("window", [128, 0])
+def test_window_paged_kernel_compiles_for_v5e(one_chip, slots, positions,
+                                              window):
+    """The paged reads of k-exaone-ep8_serve_long_sessions at its published
+    widths: 32 decode rows, and two prefill lanes of 128 positions, 64 query
+    heads over 8 key/value heads of 128 in bfloat16 blocks of 64, a table of
+    272 blocks; bounded by the window of 128 (the four sliding layers) and
+    unbounded (the full layer): ONE Mosaic call each, which the TPU compiler
+    takes for a v5e, and the two carry different names in the trace."""
+    f, args = _window_read(slots, positions, window)
+    lowered = jax.jit(f).trace(*args).lower(lowering_platforms=("tpu",))
+    assert _n_calls(lowered.as_text()) == 1
+    named = lowered.as_text(debug_info=True)
+    assert ("paged_window_attention" in named) == bool(window)
+    assert ("paged_gqa_attention" in named) == (not window)
+    args = [S(a.shape, a.dtype, sharding=one_chip) for a in args]
+    compiled = jax.jit(f).lower(*args).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+
+
+def test_a_windowless_read_lowers_to_the_text_it_had():
+    """`window` 0 is no argument: the lowered text of the grouped read is the
+    same with and without it."""
+    f0, args = _window_read(32, 1, 0)
+    from paddle_tpu.fusion import paged_decode_attention
+    g = _WIN
+    plain = lambda q, k, v, t, p, r: paged_decode_attention(   # noqa: E731
+        q, k, v, t, p, g["nh"], scale=g["dh"] ** -0.5, rows=r,
+        backend="pallas")
+    assert _tpu_text(f0, *args) == _tpu_text(plain, *args)
+
+
 # -- the state-space decode update, latent experts, two key/value heads ------
 
 def _ssm_update(slots=64):

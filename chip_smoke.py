@@ -611,10 +611,13 @@ def _timed_first(f, *args):
     return out, time.time() - t0
 
 
-def _check_flash(shape, causal, with_segments, backend, timing):
+def _check_flash(shape, causal, with_segments, backend, timing,
+                 token_major=False):
     """Flash fwd + bwd (one jit) against the composite evaluated in f32 at
     the highest matmul precision, head by head so a long sequence's [T, T]
-    scores never need more than one head of HBM."""
+    scores never need more than one head of HBM. `token_major`: the same
+    numbers handed over as [B, T, H*D], the layout a training step's
+    projections leave, through `_attend`."""
     import jax
     import jax.numpy as jnp
     from paddle_tpu.ops import pallas_kernels as pk
@@ -634,8 +637,19 @@ def _check_flash(shape, causal, with_segments, backend, timing):
             q, k, v, causal=causal, backend=be, segment_ids=ids), q, k, v)
         return (out,) + vjp(do.astype(out.dtype))
 
-    got, c = _timed_first(jax.jit(lambda *a: fwd_bwd(backend, *a)),
-                          q, k, v, do)
+    def fwd_bwd_token_major(q, k, v, do):
+        def rows(x):        # [B, H, T, D] -> [B, T, H*D]
+            return jnp.swapaxes(x, 1, 2).reshape(B, T, H * D)
+        out, vjp = jax.vjp(lambda q, k, v: pk._attend(
+            q, k, v, None if ids is None else (ids, ids), D ** -0.5, causal,
+            backend, H),
+            rows(q), rows(k), rows(v))
+        return tuple(jnp.swapaxes(x.reshape(B, T, H, D), 1, 2)
+                     for x in (out,) + vjp(rows(do)))
+
+    got, c = _timed_first(
+        jax.jit(fwd_bwd_token_major if token_major
+                else lambda *a: fwd_bwd(backend, *a)), q, k, v, do)
     timing["compile_s"] += c
     t0 = time.time()
 
@@ -958,7 +972,9 @@ def phase_kernels(backend="pallas",
     flash_shapes = ([B, H, T, D], causal): the training cells' shapes (the
     LM's, which is also its dp4 shard's; the NMT decoder's; its encoder's and
     cross attention's) and a head that streams, each with and without
-    segment ids, under the plan its shape gives (`flash_plans`);
+    segment ids, head-major as `flash_attention` takes them and token-major
+    (`_tm`) as a training step hands them over, under the plan each shape
+    gives (`flash_plans`);
     decode = (heads, d_head, span, rows); recurrent = (batch, steps, hidden);
     paged = (slots, pool blocks, block size, heads, d_head, blocks a
     request): the serving benchmark's tick; chunk = (lanes, tokens a
@@ -972,12 +988,18 @@ def phase_kernels(backend="pallas",
     timing = {"compile_s": 0.0, "run_s": 0.0}
     errs, plans = {}, {}
     for shape, causal in flash_shapes:
+        B, H, T, D = shape
         qkv = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+        rows = jax.ShapeDtypeStruct((B, T, H * D), jnp.bfloat16)
         for seg in (False, True):
             name = ("flash_" + "x".join(map(str, shape))
                     + ("" if causal else "_full") + ("_seg" * seg))
             errs[name] = _check_flash(shape, causal, seg, backend, timing)
             plans[name] = _plan_for(qkv, qkv, seg).scopes()
+            errs[name + "_tm"] = _check_flash(shape, causal, seg, backend,
+                                              timing, token_major=True)
+            plans[name + "_tm"] = _plan_for(rows, rows, seg,
+                                            num_heads=H).scopes()
     errs["decode_T%d" % decode[2]] = _check_decode(*decode, backend, timing)
     errs["paged_decode"], paged_abs = _check_paged(*paged, backend, timing)
     errs["paged_chunk"] = _check_paged_chunk(*chunk, *paged[1:], backend,
@@ -1092,8 +1114,10 @@ def phase_multichip(ref_first_loss=None, vocab=32000, seq_len=1024,
             _check(in_use[-1] >= min_bytes_in_use,
                    f"{d} holds {in_use[-1]} bytes")
 
-    # the flash kernels run per shard: [B/dp * H/tp, T, D] operands, and no
-    # all-gather rebuilds a full-size q/k/v in front of them
+    # the flash kernels run per shard: [B/dp, T, H/tp * D] operands (head-major
+    # [B/dp * H/tp, T, D], as the residuals' rows, where the shard's heads
+    # fill no whole lane tile), and no all-gather rebuilds a full-size q/k/v
+    # in front of them
     n_calls = 0
     if flash_calls_per_layer:
         hlo = pe.compiled_hlo(feed=feed, fetch_list=[loss])
@@ -1105,13 +1129,15 @@ def phase_multichip(ref_first_loss=None, vocab=32000, seq_len=1024,
         d_head = d_model // num_heads
         for ln in calls:
             lead = {s[0] for s in _shapes(ln) if len(s) == 3}
-            _check(lead == {rows},
+            _check(rows in lead and lead <= {rows, batch // 2},
                    f"flash call with leading dims {lead}, expected per-shard "
-                   f"{rows}: {ln[:200]}")
+                   f"{rows} or {batch // 2}: {ln[:200]}")
         for ln in hlo.splitlines():
             if " all-gather(" in ln:
                 result = _shapes(ln.split(" all-gather(")[0])
-                _check(not any(s[-2:] == (seq_len, d_head) for s in result),
+                _check(not any(s[-2:] in ((seq_len, d_head),
+                                          (seq_len, d_model))
+                               for s in result),
                        f"q/k/v all-gather: {ln[:200]}")
     facts = {"compile_s": round(compile_s, 2), "run_s": round(run_s, 2),
              "mesh": {"dp": 2, "tp": 2}, "steps": steps,

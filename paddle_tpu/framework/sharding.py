@@ -792,7 +792,12 @@ def _shard_fused_attention(sctx, in_specs, attrs):
         if s is not None and tuple(s) != tuple(qs):
             sctx.conflict(f"fused_attention {name} sharding {list(s)} "
                           f"!= Q sharding {list(qs)}")
-    if len(qs) >= 2 and any(s is not None for s in qs[-2:]):
+    if attrs.get("num_heads"):
+        # token-major [B, T, H*D]: the last dim sharded is the heads sharded
+        if len(qs) >= 2 and qs[1] is not None:
+            sctx.conflict("fused_attention sequence dim may not be "
+                          "tp-sharded (shard the heads)")
+    elif len(qs) >= 2 and any(s is not None for s in qs[-2:]):
         sctx.conflict("fused_attention sequence/head-depth dims may not "
                       "be tp-sharded (shard the head COUNT dim)")
     return {"Out": [qs]}
@@ -1096,6 +1101,20 @@ class TpShardPass(Pass):
                     shape[d] //= tp
             op.attrs = dict(op.attrs)
             op.attrs["shape"] = shape
+        # fused_attention on token-major [B, T, H*D@tp] operands: a shard
+        # holds H / tp whole heads
+        for op in block.ops:
+            if op.type != "fused_attention":
+                continue
+            heads = op.attrs.get("num_heads")
+            spec = sharded.get(op.outputs["Out"][0])
+            if not heads or not spec or spec[-1] is None:
+                continue
+            enforce(heads % tp == 0,
+                    f"fused_attention num_heads ({heads}) not divisible by "
+                    f"tp={tp}", exc=InvalidArgumentError)
+            op.attrs = dict(op.attrs)
+            op.attrs["num_heads"] = heads // tp
 
         # --- rebuild the op list with the insertions ---------------------
         new_ops: List[Operator] = []

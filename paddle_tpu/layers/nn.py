@@ -1549,10 +1549,15 @@ def log_softmax(x, axis=-1, name=None):
 
 
 def fused_attention(q, k, v, scale=None, causal=False, segment_ids=None,
-                    kv_segment_ids=None, name=None):
+                    kv_segment_ids=None, num_heads=None, name=None):
     """Fused scaled-dot-product attention over [B, H, T, D] tensors —
     flash kernel (Pallas) on TPU, XLA composite elsewhere
     (≙ nets.py scaled_dot_product_attention, kernelized).
+
+    num_heads: q [B, T, H*D] and k, v [B, Tk, H*D] come as the projections
+    leave them, H heads side by side in the last axis, and so does the
+    context: the kernels then read and write that layout where the shape
+    allows (docs/fusion.md), with no head-major copy around the call.
 
     segment_ids ([B, T] int var) enables packed-batch masking — multiple
     sequences share one row and attend only within their own segment (the
@@ -1570,10 +1575,13 @@ def fused_attention(q, k, v, scale=None, causal=False, segment_ids=None,
         inputs["QSeg"] = [segment_ids]
         inputs["KVSeg"] = [kv_segment_ids if kv_segment_ids is not None
                            else segment_ids]
+    attrs = {"scale": scale, "causal": causal}
+    if num_heads:
+        attrs["num_heads"] = num_heads
     helper.append_op(type="fused_attention",
                      inputs=inputs,
                      outputs={"Out": [out]},
-                     attrs={"scale": scale, "causal": causal})
+                     attrs=attrs)
     return out
 
 

@@ -18,7 +18,8 @@ the graphs share trained weights BY NAME). `_lm_head` builds the vocabulary
 head. What differs between the graphs is `attend` alone — where the new K/V
 rows go and how the cache is read back:
 
-- training: no cache; `_flash_attend` (split heads, `fused_attention`, merge);
+- training: no cache; `_flash_attend` (`fused_attention` on q, k, v as the
+  projections leave them, [B, T, H*D]);
 - `_ScanCache`: the generate graphs' scan carry, every sequence at one
   position;
 - `_SlotCache`: one persistable row of `max_len` positions per serving slot,
@@ -85,19 +86,13 @@ def _attention(q_in, k_in, v_in, d_model, name, attend):
 
 def _flash_attend(q, k, v, num_heads, dropout=0.0, is_test=False,
                   causal=False, segment_ids=None):
-    """Attention of projected q [B,Tq,D] over k/v [B,Tk,D] with an explicit
-    head split; head dim stays last for lane alignment. Returns [B,Tq,D]."""
+    """Attention of projected q [B,Tq,D] over k/v [B,Tk,D], `num_heads`
+    heads side by side in D. Returns [B,Tq,D]. The fused op takes and gives
+    that layout; only the path that needs the attention weights themselves
+    splits the heads out."""
     b, t_q, d_model = q.shape
     t_k = k.shape[1]
     d_head = d_model // num_heads
-
-    def split_heads(x, t):
-        x = layers.reshape(x, shape=[b, t, num_heads, d_head])
-        return layers.transpose(x, perm=[0, 2, 1, 3])
-
-    q = split_heads(q, t_q)
-    k = split_heads(k, t_k)
-    v = split_heads(v, t_k)
     if segment_ids is not None and dropout and not is_test:
         raise NotImplementedError(
             "packed batches (segment_ids) require the fused attention "
@@ -109,22 +104,26 @@ def _flash_attend(q, k, v, num_heads, dropout=0.0, is_test=False,
         ctx = layers.fused_attention(q, k, v,
                                      scale=float(d_head) ** -0.5,
                                      causal=causal,
-                                     segment_ids=segment_ids)
-        if is_test:
-            ctx = _infer_scale(ctx, dropout)
-    else:
-        # attention-weight dropout needs the explicit weights tensor
-        q = layers.scale(q, scale=float(d_head) ** -0.5)
-        scores = layers.matmul(q, k, transpose_y=True, use_bf16=True)
-        if causal:
-            mask_np = np.triu(np.full((t_q, t_k), -1e9, dtype="float32"),
-                              k=1)
-            mask = layers.assign(mask_np.reshape(1, 1, t_q, t_k))
-            scores = layers.elementwise_add(scores, mask)
-        weights = layers.softmax(scores)
-        weights = layers.dropout(weights, dropout_prob=dropout,
-                                 is_test=is_test)
-        ctx = layers.matmul(weights, v, use_bf16=True)
+                                     segment_ids=segment_ids,
+                                     num_heads=num_heads)
+        return _infer_scale(ctx, dropout) if is_test else ctx
+
+    def split_heads(x, t):
+        x = layers.reshape(x, shape=[b, t, num_heads, d_head])
+        return layers.transpose(x, perm=[0, 2, 1, 3])
+
+    # attention-weight dropout needs the explicit weights tensor
+    q = layers.scale(split_heads(q, t_q), scale=float(d_head) ** -0.5)
+    k = split_heads(k, t_k)
+    v = split_heads(v, t_k)
+    scores = layers.matmul(q, k, transpose_y=True, use_bf16=True)
+    if causal:
+        mask_np = np.triu(np.full((t_q, t_k), -1e9, dtype="float32"), k=1)
+        mask = layers.assign(mask_np.reshape(1, 1, t_q, t_k))
+        scores = layers.elementwise_add(scores, mask)
+    weights = layers.softmax(scores)
+    weights = layers.dropout(weights, dropout_prob=dropout, is_test=is_test)
+    ctx = layers.matmul(weights, v, use_bf16=True)
     ctx = layers.transpose(ctx, perm=[0, 2, 1, 3])
     return layers.reshape(ctx, shape=[b, t_q, d_model])
 

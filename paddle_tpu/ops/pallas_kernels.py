@@ -18,6 +18,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import inspect
 import math
 
 import jax
@@ -33,12 +34,23 @@ def _auto_backend():
     return "pallas" if jax.default_backend() == "tpu" else "xla"
 
 
-def _normalize_segment_ids(segment_ids, q, k):
+def _dims(q, k, num_heads=None):
+    """(B, H, T, Tk, D) of a call on q [B, H, T, D] and k [B, H, Tk, D] or,
+    with `num_heads`, on token-major q [B, T, H * D] and k [B, Tk, H * D]."""
+    if num_heads:
+        B, T, HD = q.shape
+        return B, num_heads, T, k.shape[1], HD // num_heads
+    B, H, T, D = q.shape
+    return B, H, T, k.shape[2], D
+
+
+def _normalize_segment_ids(segment_ids, q, k, num_heads=None):
     """Accept a single [B, Tq] array (self-attention; Tq must equal Tk) or
     a (q_ids [B, Tq], kv_ids [B, Tk]) pair. Returns (q_ids, kv_ids) int32
     or (None, None). Same semantics as parallel.ring_attention: a query
     attends a key iff their ids are equal — the static-shape translation
-    of the reference's LoD ragged batches (SURVEY §5 long-context row)."""
+    of the reference's LoD ragged batches (SURVEY §5 long-context row).
+    q, k: [B, H, T, D], or with `num_heads` [B, T, H * D]."""
     if segment_ids is None:
         return None, None
     if isinstance(segment_ids, (tuple, list)):
@@ -47,8 +59,7 @@ def _normalize_segment_ids(segment_ids, q, k):
         q_ids = kv_ids = segment_ids
     q_ids = jnp.asarray(q_ids, jnp.int32)
     kv_ids = jnp.asarray(kv_ids, jnp.int32)
-    B, _, Tq, _ = q.shape
-    Tk = k.shape[2]
+    B, _, Tq, Tk, _ = _dims(q, k, num_heads)
     if q_ids.shape != (B, Tq) or kv_ids.shape != (B, Tk):
         raise ValueError(
             f"segment_ids shapes {q_ids.shape}/{kv_ids.shape} do not match "
@@ -132,18 +143,24 @@ class FlashPlan:
     and V of `rows` heads in VMEM and loops over their key blocks inside the
     kernel, stopping at the causal diagonal; otherwise key blocks stream
     through the grid, one head a step. Either way a tile is `block_k` x
-    `block_q` scores a head, and a step's heads are one batch of it."""
+    `block_q` scores a head, and a step's heads are one batch of it.
+    `token_major`: the operands are [B, T, H * D] as the projections leave
+    them and a step's heads are `rows * D` lanes of one batch row, a whole
+    number of 128-lane tiles; otherwise [B * H, T, D], a head a row."""
     resident: bool
     block_q: int
     block_k: int
     rows: int
+    token_major: bool = False
 
     def scope(self, kernel):
         """The kernel's name in a device trace: XLA names the custom call
-        after its `jax.named_scope`, so `device_ops` spells the plan."""
+        after its `jax.named_scope`, so `device_ops` spells the plan, `_tm`
+        last where the operands are token-major."""
         tiles = f"q{self.block_q}_k{self.block_k}"
         if self.resident:
-            return f"flash_{kernel}_resident_{tiles}_rows{self.rows}"
+            return (f"flash_{kernel}_resident_{tiles}_rows{self.rows}"
+                    + "_tm" * self.token_major)
         return f"flash_{kernel}_streamed_{tiles}"
 
     def scopes(self):
@@ -153,17 +170,24 @@ class FlashPlan:
         return [self.scope(k) for k in ("fwd",) + backward]
 
 
-def _flash_plan(T, Tk, D, itemsize, heads, block_q=None, block_k=None):
+def _flash_plan(T, Tk, D, itemsize, heads, block_q=None, block_k=None,
+                num_heads=None):
     """The plan for q [.., T, D] against k, v [.., Tk, D]: a pure function
     of the shape, for the forward and the backward alike. `heads`: how many
     heads may share a grid step (B*H; H when segment ids are given, whose
     row a step's heads must share). An explicit block size is honoured
-    (clamped to the padded length).
+    (clamped to the padded length). `num_heads`: the H of an operand that
+    comes token-major, [B, T, H * D]; None for [B, H, T, D].
 
     A head whose keys fit the VMEM budget is resident, in tiles of 256 (the
     diagonal stop then skips 3/8 of a causal square at T = 1024), as many
     heads a step as fill `_TILE_SCORES` and divide `heads`. A longer head
-    streams 1024-wide blocks through the grid."""
+    streams 1024-wide blocks through the grid.
+
+    A token-major operand keeps its layout where the plan is resident and
+    some such number of heads divides H and fills whole 128-lane tiles
+    (`rows` even at D = 64): the largest that does is taken. Every other
+    shape gets the head-major plan, and its caller transposes."""
     head_bytes = -(-Tk // _LANES) * _LANES * D * (8 * itemsize + 8)
     resident = head_bytes <= _VMEM_BUDGET
     side = 256 if resident else 1024
@@ -172,16 +196,23 @@ def _flash_plan(T, Tk, D, itemsize, heads, block_q=None, block_k=None):
     if not resident:
         return FlashPlan(False, bq, bk, 1)
     most = max(1, min(_VMEM_BUDGET // head_bytes, _TILE_SCORES // (bq * bk)))
+    whole_tiles = [r for r in range(1, most + 1)
+                   if num_heads and num_heads % r == 0
+                   and r * D % _LANES == 0]
+    if whole_tiles:
+        return FlashPlan(True, bq, bk, max(whole_tiles), token_major=True)
     rows = max(r for r in range(1, most + 1) if heads % r == 0)
     return FlashPlan(True, bq, bk, rows)
 
 
-def _plan_for(q, k, segments, block_q=None, block_k=None):
-    """The plan of a call on q [B, H, T, D] and k [B, H, Tk, D] (arrays or
-    shapes with a dtype), with segment ids or without."""
-    B, H, T, D = q.shape
-    return _flash_plan(T, k.shape[2], D, jnp.dtype(q.dtype).itemsize,
-                       H if segments else B * H, block_q, block_k)
+def _plan_for(q, k, segments, block_q=None, block_k=None, num_heads=None):
+    """The plan of a call on q [B, H, T, D] and k [B, H, Tk, D] or, with
+    `num_heads`, on q [B, T, H * D] and k [B, Tk, H * D] (arrays or shapes
+    with a dtype), with segment ids or without."""
+    B, H, T, Tk, D = _dims(q, k, num_heads)
+    return _flash_plan(T, Tk, D, jnp.dtype(q.dtype).itemsize,
+                       H if segments else B * H, block_q, block_k,
+                       num_heads=num_heads)
 
 
 # ---------------------------------------------------------------------------
@@ -377,23 +408,28 @@ class _Tiling:
     name. The grid runs (head group, q block) for a resident plan and
     (head group, q block, key block) for a streamed one — key blocks BEFORE
     q blocks with `keys_outer`, the streamed dK / dV pass, which accumulates
-    over the q blocks."""
+    over the q blocks. A head group is `plan.rows` heads: rows of the
+    head-major [B * H, T, D], or `rows * D` lanes of one batch row of the
+    token-major [B, T, H * D]. The per-row residuals lie [B * H, T / 128,
+    128] in either form, and so do the head groups' numbers."""
 
-    def __init__(self, plan, q, k, H, q_ids, kv_ids, keys_outer=False):
+    def __init__(self, plan, q, k, num_heads, q_ids, kv_ids,
+                 keys_outer=False):
         from jax.experimental import pallas as pl
         self.plan = plan
-        BH, T, D = q.shape
+        B, H, T, Tk, D = _dims(q, k, num_heads)
+        self.B, self.H, self.T, self.Tk, self.D = B, H, T, Tk, D
         bq, bk, rows = plan.block_q, plan.block_k, plan.rows
-        self.Tp, self.Tkp = -(-T // bq) * bq, -(-k.shape[1] // bk) * bk
+        self.Tp, self.Tkp = -(-T // bq) * bq, -(-Tk // bk) * bk
         self.nq, self.nk = self.Tp // bq, self.Tkp // bk
         self.lanes = math.gcd(bq, _LANES)
         self.q_ids, self.kv_ids = q_ids, kv_ids
         if plan.resident:
-            self.grid = (BH // rows, self.nq)
+            self.grid = (B * H // rows, self.nq)
         elif keys_outer:
-            self.grid = (BH, self.nk, self.nq)
+            self.grid = (B * H, self.nk, self.nq)
         else:
-            self.grid = (BH, self.nq, self.nk)
+            self.grid = (B * H, self.nq, self.nk)
         # the keys a step holds: the head's, or one streamed block
         keys = self.Tkp if plan.resident else bk
 
@@ -402,11 +438,18 @@ class _Tiling:
                 return index(g, *((b, a) if keys_outer else (a, b)))
             return pl.BlockSpec(block, index_map)
 
-        def batch(g):        # the row of segment ids a step's heads share
+        def batch(g):        # the batch row a step's heads lie in
             return g * rows // H
 
-        self.q_spec = spec((rows, bq, D), lambda g, i, j: (g, i, 0))
-        self.k_spec = spec((rows, keys, D), lambda g, i, j: (g, j, 0))
+        if plan.token_major:
+            groups = H // rows
+            self.q_spec = spec((1, bq, rows * D),
+                               lambda g, i, j: (g // groups, i, g % groups))
+            self.k_spec = spec((1, keys, rows * D),
+                               lambda g, i, j: (g // groups, j, g % groups))
+        else:
+            self.q_spec = spec((rows, bq, D), lambda g, i, j: (g, i, 0))
+            self.k_spec = spec((rows, keys, D), lambda g, i, j: (g, j, 0))
         self.stat_spec = spec((rows, self.Tp // self.lanes, self.lanes),
                               lambda g, i, j: (g, 0, 0))
         # segment ids of a tile's rows (keys) / columns (queries): see
@@ -416,9 +459,26 @@ class _Tiling:
         self.minor_ids_spec = spec((1, 1, 8, bq),
                                    lambda g, i, j: (batch(g), i, 0, 0))
 
-    def heads(self, x, length):
-        """[B, H, t, D] -> [B*H, length, D], zero-padded."""
-        return _pad_to(x.reshape(-1, *x.shape[2:]), 1, length)
+    def operand(self, x, length):
+        """A caller's q, k, v or do as the kernel takes it, its sequence
+        zero-padded to `length`: [B, H, t, D] -> [B * H, length, D]; a
+        token-major [B, t, H * D] stays as it lies."""
+        if not self.plan.token_major:
+            x = x.reshape(-1, *x.shape[2:])
+        return _pad_to(x, 1, length)
+
+    def shape(self, length):
+        """The shape `operand` gives at that length."""
+        if self.plan.token_major:
+            return (self.B, length, self.H * self.D)
+        return (self.B * self.H, length, self.D)
+
+    def result(self, y, t):
+        """A kernel's output as the caller's layout has it, `t` long."""
+        y = y[:, :t]
+        if self.plan.token_major:
+            return y
+        return y.reshape(self.B, self.H, t, self.D)
 
     def stat(self, x):
         """[B, H, T] per-row residual -> the `_row_stat` layout."""
@@ -440,6 +500,69 @@ class _Tiling:
         queries = jnp.broadcast_to(queries, (B, self.nq, 8, bq))
         return {"major_ids_ref": (keys, self.major_ids_spec),
                 "minor_ids_ref": (queries, self.minor_ids_spec)}
+
+
+def _heads_of(plan, ref, rows=slice(None)):
+    """[plan.rows, n, D]: rows `rows` of every head of a step's block. A
+    head-major block is that already; in a token-major one, [1, n, rows * D],
+    a head is D lanes at a static offset."""
+    if not plan.token_major:
+        return ref[:, rows, :]
+    D = ref.shape[2] // plan.rows
+    return jnp.stack([ref[0, rows, r * D:(r + 1) * D]
+                      for r in range(plan.rows)])
+
+
+def _held_heads(plan, ref):
+    """The heads of a q-side block for every tile of a grid step, as a
+    function to call where they are used. Token-major the lane slices are
+    taken ONCE a step and held over the key loop; head-major every use reads
+    the ref, which is the block as it lies: held, a step of the LM's shape is
+    4% slower and a streamed one 5% (PERF.md section 6, PR 46-47)."""
+    if not plan.token_major:
+        return lambda: ref[:]
+    held = _heads_of(plan, ref)
+    return lambda: held
+
+
+def _put_heads_transposed(plan, ref, x):
+    """Write [plan.rows, D, n], a head's rows its D, as the block of `ref`:
+    head-major a transpose a head; token-major the heads' rows are one
+    matrix [rows * D, n] and its transpose is the block."""
+    if not plan.token_major:
+        ref[:] = jnp.swapaxes(x, 1, 2).astype(ref.dtype)
+    else:
+        rows, D, n = x.shape
+        ref[0] = x.reshape(rows * D, n).T.astype(ref.dtype)
+
+
+# ---------------------------------------------------------------------------
+# one trace a step: equal calls share one jaxpr and one lowered function
+# ---------------------------------------------------------------------------
+
+def _traced_once(fn):
+    """`fn(*arrays, **what_is_not_an_array)` behind ONE jitted callable, every
+    keyword static. A step calls a kernel once a layer with the same shapes
+    and the same keywords: jit's cache then hands every call after the first
+    the first one's jaxpr, and the step's module holds the kernel's body once
+    and calls it, where each call site used to trace the body and lower it to
+    Mosaic again (24 times in a 12-layer step; PERF.md section 6, PR 46-47).
+    It holds under `jax.vjp`, `jax.checkpoint` and `jax.shard_map`: the
+    operands' varying axes are part of the cache's key."""
+    names = tuple(p.name for p in inspect.signature(fn).parameters.values()
+                  if p.kind is p.KEYWORD_ONLY)
+    return jax.jit(fn, static_argnames=names)
+
+
+def _count(name, plan, backward=False):
+    """A set-up counter (a compiled step records nothing), once a kernel of
+    a call: `flash/call`, a call of a flash kernel at a call site, and
+    `flash/body_traced`, a trace of its body, `scope` the kernel's name under
+    `plan`; the second over the first is the share of the calls that paid a
+    trace."""
+    from ..observability import tracing
+    for scope in plan.scopes()[1:] if backward else plan.scopes()[:1]:
+        tracing.record_counter(name, 1, scope=scope)
 
 
 # ---------------------------------------------------------------------------
@@ -471,11 +594,13 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_ref=None, l_ref=None,
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
+    q = _held_heads(plan, q_ref)                   # [rows, bq, D]
+
     def visit(j, masked):
         keys = _key_rows(plan, j)
-        v = v_ref[:, keys, :]                      # [rows, bk, D]
+        v = _heads_of(plan, v_ref, keys)           # [rows, bk, D]
         s = jax.lax.dot_general(
-            k_ref[:, keys, :], q_ref[:], _NT,
+            _heads_of(plan, k_ref, keys), q(), _NT,
             preferred_element_type=jnp.float32) * scale  # [rows, bk, bq]
         if masked:
             ok = blocks.mask(j)
@@ -503,7 +628,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_ref=None, l_ref=None,
 
     def finalize(m, l, acc):
         l = jnp.maximum(l, 1e-30)
-        o_ref[:] = jnp.swapaxes(acc / l, 1, 2).astype(o_ref.dtype)
+        _put_heads_transposed(plan, o_ref, acc / l)
         if lse_ref is not None:
             # logsumexp per query row — the backward kernels' residual
             _store_row_stat(lse_ref, qi, m + jnp.log(l))
@@ -526,23 +651,37 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_ref=None, l_ref=None,
 
 def _flash_attention_pallas(q, k, v, scale, causal, block_q=None,
                             block_k=None, interpret=False, with_lse=False,
-                            segment_ids=None):
+                            segment_ids=None, num_heads=None):
+    """The flash forward on q [B, H, T, D] and k, v [B, H, Tk, D]: the
+    context, and with `with_lse` the rows' logsumexp [B, H, T] beside it.
+    With `num_heads` the operands and the context are token-major, [B, T,
+    H * D], for a shape whose plan is (`_flash_plan`)."""
+    plan = _plan_for(q, k, segment_ids is not None, block_q, block_k,
+                     num_heads)
+    _count("flash/call", plan)
+    return _flash_fwd(q, k, v, segment_ids, scale=float(scale),
+                      causal=bool(causal), plan=plan,
+                      interpret=bool(interpret), with_lse=bool(with_lse),
+                      num_heads=num_heads)
+
+
+@_traced_once
+def _flash_fwd(q, k, v, segment_ids, *, scale, causal, plan, interpret,
+               with_lse, num_heads):
     from jax.experimental.pallas import tpu as pltpu
 
-    q_ids, kv_ids = _normalize_segment_ids(segment_ids, q, k)
-    B, H, T, D = q.shape
-    Tk = k.shape[2]
-    plan = _plan_for(q, k, q_ids is not None, block_q, block_k)
-    t = _Tiling(plan, q.reshape(B * H, T, D), k.reshape(B * H, Tk, D), H,
-                q_ids, kv_ids)
+    _count("flash/body_traced", plan)
+    q_ids, kv_ids = _normalize_segment_ids(segment_ids, q, k, num_heads)
+    t = _Tiling(plan, q, k, num_heads, q_ids, kv_ids)
+    B, H, T, Tk, D = t.B, t.H, t.T, t.Tk, t.D
     bq = plan.block_q
     # sequence lengths are rounded up to block multiples: padded queries are
     # sliced off, padded keys are masked dead inside the kernel
-    ins = {"q_ref": (t.heads(q, t.Tp), t.q_spec),
-           "k_ref": (t.heads(k, t.Tkp), t.k_spec),
-           "v_ref": (t.heads(v, t.Tkp), t.k_spec),
+    ins = {"q_ref": (t.operand(q, t.Tp), t.q_spec),
+           "k_ref": (t.operand(k, t.Tkp), t.k_spec),
+           "v_ref": (t.operand(v, t.Tkp), t.k_spec),
            **t.segment_inputs()}
-    outs = {"o_ref": (_out_struct((B * H, t.Tp, D), q.dtype, q, k, v),
+    outs = {"o_ref": (_out_struct(t.shape(t.Tp), q.dtype, q, k, v),
                       t.q_spec)}
     if with_lse:
         outs["lse_ref"] = (
@@ -561,7 +700,7 @@ def _flash_attention_pallas(q, k, v, scale, causal, block_q=None,
         "acc_ref": pltpu.VMEM((plan.rows, D, bq), jnp.float32)}
     res = _named_call(kernel, plan.scope("fwd"), t.grid, ins, outs, state,
                       interpret)
-    out = res["o_ref"][:, :T].reshape(B, H, T, D)
+    out = t.result(res["o_ref"], T)
     if with_lse:
         return out, res["lse_ref"].reshape(B * H, t.Tp)[:, :T].reshape(B, H, T)
     return out
@@ -572,25 +711,30 @@ def _flash_attention_pallas(q, k, v, scale, causal, block_q=None,
 # lse) in VMEM — no [T, T] materialization in HBM on the backward either
 # ---------------------------------------------------------------------------
 
+_TT = (((1,), (2,)), ((0,), (0,)))      # a^T @ b^T
+
+
 def _bwd_tile(q, k, v, do, lse, delta, ok, scale):
-    """One [rows, keys, queries] tile of the backward: (dq [rows, bq, D],
-    dk, dv [rows, bk, D]) partial sums in float32, dq and dk still to be
+    """One [rows, keys, queries] tile of the backward: (dq [rows, D, bq],
+    dk, dv [rows, D, bk]) partial sums in float32, dq and dk still to be
     multiplied by `scale` (once, where they are written, and not an entry of
     ds at a time). The tile is laid keys-major so that the per-query
-    residuals lse / delta broadcast as [rows, 1, bq] rows and four of the
-    five products need no transposed operand (dq's does)."""
+    residuals lse / delta broadcast as [rows, 1, bq] rows; the sums come with
+    D before the positions, so that the small operand of each of their
+    products is the transposed one, an accumulator fills its lanes at D = 64
+    and a block is written by one transpose."""
     f32 = jnp.float32
     s = jax.lax.dot_general(k, q, _NT, preferred_element_type=f32) * scale
     p = jnp.exp(s - lse)                           # [rows, bk, bq]
     if ok is not None:
         p = jnp.where(ok, p, 0.0)
-    dv = jax.lax.dot_general(p.astype(do.dtype), do, _NN,
-                             preferred_element_type=f32)
     dp = jax.lax.dot_general(v, do, _NT, preferred_element_type=f32)
     ds = p * (dp - delta)                          # [rows, bk, bq]
-    dk = jax.lax.dot_general(ds.astype(q.dtype), q, _NN,
+    dv = jax.lax.dot_general(do, p.astype(do.dtype), _TT,
                              preferred_element_type=f32)
-    dq = jax.lax.dot_general(ds.astype(k.dtype), k, _TN,
+    dk = jax.lax.dot_general(q, ds.astype(q.dtype), _TT,
+                             preferred_element_type=f32)
+    dq = jax.lax.dot_general(k, ds.astype(k.dtype), _TN,
                              preferred_element_type=f32)
     return dq, dk, dv
 
@@ -609,19 +753,23 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     fit): the same tile in two passes, because one of dq and dk / dv has to
     accumulate over a grid axis the other is blocked along — a dq pass
     (grid g, i, j; dk_ref None) and a dk / dv pass (grid g, j, i; dq_ref
-    None)."""
+    None). Either way the three sums and their accumulators are [rows, D,
+    positions], and a block is written by one transpose (`_bwd_tile`,
+    `_put_heads_transposed`)."""
     from jax.experimental import pallas as pl
 
     q_axis, key_axis = (2, 1) if keys_outer else (1, 2)
     qi = pl.program_id(q_axis)
     blocks = _KeyBlocks(plan, see, qi, key_axis, major_ids_ref,
                         minor_ids_ref)
+    q, do = _held_heads(plan, q_ref), _held_heads(plan, do_ref)
 
     def tile(j, masked):
         keys = _key_rows(plan, j)
         ok = blocks.mask(j) if masked else None
         return _bwd_tile(
-            q_ref[:], k_ref[:, keys, :], v_ref[:, keys, :], do_ref[:],
+            q(), _heads_of(plan, k_ref, keys), _heads_of(plan, v_ref, keys),
+            do(),
             _row_stat(lse_ref, qi, plan.block_q),
             _row_stat(delta_ref, qi, plan.block_q), ok, scale)
 
@@ -631,18 +779,18 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         if dq_ref is not None:
             dq_acc[:] += dq
         if dk_ref is not None:
-            dk_acc[:, keys, :] += dk
-            dv_acc[:, keys, :] += dv
+            dk_acc[:, :, keys] += dk
+            dv_acc[:, :, keys] += dv
 
     def zero_dq():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
     def write(dq=None, dk=None, dv=None):
         if dq is not None:
-            dq_ref[:] = (dq * scale).astype(dq_ref.dtype)
+            _put_heads_transposed(plan, dq_ref, dq * scale)
         if dk is not None:
-            dk_ref[:] = (dk * scale).astype(dk_ref.dtype)
-            dv_ref[:] = dv.astype(dv_ref.dtype)
+            _put_heads_transposed(plan, dk_ref, dk * scale)
+            _put_heads_transposed(plan, dv_ref, dv)
 
     def zero_dkv():
         dk_acc[:] = jnp.zeros_like(dk_acc)
@@ -672,31 +820,49 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _flash_attention_bwd_pallas(q, k, v, o, lse, do, scale, causal,
                                 block_q=None, block_k=None, interpret=False,
-                                segment_ids=None, delta=None):
+                                segment_ids=None, delta=None, num_heads=None):
+    """The flash backward: (dq, dk, dv) from the forward's operands, its
+    context `o` and logsumexp, and the context's cotangent `do`, in the
+    operands' layout (`num_heads`: as the forward's). Ring attention passes
+    the global `delta` in (`o` may then be None)."""
+    plan = _plan_for(q, k, segment_ids is not None, block_q, block_k,
+                     num_heads)
+    _count("flash/call", plan, backward=True)
+    return _flash_bwd(q, k, v, o, lse, do, segment_ids, delta,
+                      scale=float(scale), causal=bool(causal), plan=plan,
+                      interpret=bool(interpret), num_heads=num_heads)
+
+
+@_traced_once
+def _flash_bwd(q, k, v, o, lse, do, segment_ids, delta, *, scale, causal,
+               plan, interpret, num_heads):
     from jax.experimental.pallas import tpu as pltpu
 
-    q_ids, kv_ids = _normalize_segment_ids(segment_ids, q, k)
-    B, H, T, D = q.shape
-    Tk = k.shape[2]
-    plan = _plan_for(q, k, q_ids is not None, block_q, block_k)
+    _count("flash/body_traced", plan, backward=True)
+    q_ids, kv_ids = _normalize_segment_ids(segment_ids, q, k, num_heads)
     if delta is None:
         # delta_i = sum_d do*o — recomputed here on the single-device path;
         # ring attention passes the global delta in (o may then be None)
-        delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                        axis=-1)                   # [B, H, T]
+        delta = do.astype(jnp.float32) * o.astype(jnp.float32)
+        if plan.token_major:                       # [B, T, H * D]
+            delta = jnp.swapaxes(jnp.sum(
+                delta.reshape(*delta.shape[:2], num_heads, -1), axis=-1),
+                1, 2)
+        else:
+            delta = jnp.sum(delta, axis=-1)        # [B, H, T]
     f32 = jnp.float32
     bq, bk, rows = plan.block_q, plan.block_k, plan.rows
 
     def call(scope, want_dq, want_dkv):
         keys_outer = not want_dq
-        t = _Tiling(plan, q.reshape(B * H, T, D), k.reshape(B * H, Tk, D),
-                    H, q_ids, kv_ids, keys_outer)
+        t = _Tiling(plan, q, k, num_heads, q_ids, kv_ids, keys_outer)
+        T, Tk, D = t.T, t.Tk, t.D
         # padded queries carry do = 0 and delta = 0 (and a finite lse), so
         # they add nothing to dk and dv whatever they see
-        ins = {"q_ref": (t.heads(q, t.Tp), t.q_spec),
-               "k_ref": (t.heads(k, t.Tkp), t.k_spec),
-               "v_ref": (t.heads(v, t.Tkp), t.k_spec),
-               "do_ref": (t.heads(do, t.Tp), t.q_spec),
+        ins = {"q_ref": (t.operand(q, t.Tp), t.q_spec),
+               "k_ref": (t.operand(k, t.Tkp), t.k_spec),
+               "v_ref": (t.operand(v, t.Tkp), t.k_spec),
+               "do_ref": (t.operand(do, t.Tp), t.q_spec),
                "lse_ref": (t.stat(lse), t.stat_spec),
                "delta_ref": (t.stat(delta), t.stat_spec),
                **t.segment_inputs()}
@@ -704,28 +870,28 @@ def _flash_attention_bwd_pallas(q, k, v, o, lse, do, scale, causal,
         keys = t.Tkp if plan.resident else bk
         single = plan.resident and t.nq == 1 and t.nk == 1
         if want_dq:
-            outs["dq_ref"] = (_out_struct((B * H, t.Tp, D), q.dtype,
+            outs["dq_ref"] = (_out_struct(t.shape(t.Tp), q.dtype,
                                           q, k, v, do), t.q_spec)
-            scratch["dq_acc"] = pltpu.VMEM((rows, bq, D), f32)
+            scratch["dq_acc"] = pltpu.VMEM((rows, D, bq), f32)
         if want_dkv:
             for name, x in (("dk", k), ("dv", v)):
                 outs[name + "_ref"] = (_out_struct(
-                    (B * H, t.Tkp, D), x.dtype, q, k, v, do), t.k_spec)
-                scratch[name + "_acc"] = pltpu.VMEM((rows, keys, D), f32)
+                    t.shape(t.Tkp), x.dtype, q, k, v, do), t.k_spec)
+                scratch[name + "_acc"] = pltpu.VMEM((rows, D, keys), f32)
         kernel = functools.partial(
             _flash_bwd_kernel, plan=plan,
             see=_Visible(causal, Tk - T, Tk, t.nk), keys_outer=keys_outer,
             single=single, scale=scale, num_q_blocks=t.nq)
-        return _named_call(kernel, plan.scope(scope), t.grid, ins, outs,
-                           {} if single else scratch, interpret)
+        res = _named_call(kernel, plan.scope(scope), t.grid, ins, outs,
+                          {} if single else scratch, interpret)
+        return {name: t.result(x, T if name == "dq_ref" else Tk)
+                for name, x in res.items()}
 
     if plan.resident:
         res = call("bwd", True, True)
     else:
         res = {**call("bwd_dq", True, False), **call("bwd_dkv", False, True)}
-    return (res["dq_ref"][:, :T].reshape(B, H, T, D),
-            res["dk_ref"][:, :Tk].reshape(B, H, Tk, D),
-            res["dv_ref"][:, :Tk].reshape(B, H, Tk, D))
+    return res["dq_ref"], res["dk_ref"], res["dv_ref"]
 
 
 def flash_attention(q, k, v, scale=None, causal=False, block_q=None,
@@ -756,29 +922,34 @@ def flash_attention(q, k, v, scale=None, causal=False, block_q=None,
 # differentiable wrapper + op registration
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
 def _fused_attention(q, k, v, segment_ids, scale, causal, backend,
-                     block_q=None, block_k=None):
+                     block_q=None, block_k=None, num_heads=None):
+    """Differentiable attention on [B, H, T, D] operands or, with
+    `num_heads`, on token-major [B, T, H * D] ones whose plan is token-major
+    (`_attend` sees to that)."""
     if backend == "xla":
         return _attention_reference(q, k, v, scale, causal, segment_ids)
     return _flash_attention_pallas(q, k, v, scale, causal, block_q, block_k,
                                    interpret=(backend == "pallas_interpret"),
-                                   segment_ids=segment_ids)
+                                   segment_ids=segment_ids,
+                                   num_heads=num_heads)
 
 
 def _fused_attention_fwd(q, k, v, segment_ids, scale, causal, backend,
-                         block_q=None, block_k=None):
+                         block_q=None, block_k=None, num_heads=None):
     if backend == "xla":
         out = _attention_reference(q, k, v, scale, causal, segment_ids)
         return out, (q, k, v, segment_ids, None, None)
     out, lse = _flash_attention_pallas(
         q, k, v, scale, causal, block_q, block_k,
         interpret=(backend == "pallas_interpret"), with_lse=True,
-        segment_ids=segment_ids)
+        segment_ids=segment_ids, num_heads=num_heads)
     return out, (q, k, v, segment_ids, out, lse)
 
 
-def _fused_attention_bwd(scale, causal, backend, block_q, block_k, res, g):
+def _fused_attention_bwd(scale, causal, backend, block_q, block_k, num_heads,
+                         res, g):
     q, k, v, segment_ids, o, lse = res
     if backend == "xla":
         _, vjp = jax.vjp(
@@ -791,13 +962,36 @@ def _fused_attention_bwd(scale, causal, backend, block_q, block_k, res, g):
     return _flash_attention_bwd_pallas(
         q, k, v, o, lse, g, scale, causal, block_q, block_k,
         interpret=(backend == "pallas_interpret"),
-        segment_ids=segment_ids) + (None,)
+        segment_ids=segment_ids, num_heads=num_heads) + (None,)
 
 
 _fused_attention.defvjp(_fused_attention_fwd, _fused_attention_bwd)
 
 
-def _attention_over_mesh(mesh, q, k, v, segment_ids, scale, causal, backend):
+def _attend(q, k, v, segment_ids, scale, causal, backend, num_heads=None):
+    """The op on either layout. With `num_heads`: attention of q [B, T,
+    H * D] over k, v [B, Tk, H * D], the layout the projections leave, and
+    the context [B, T, H * D]: the kernels take a shape whose plan is
+    token-major as it lies; every other shape, and the composite, goes
+    head-major between two transposes, as every call did before PR 47.
+    Without: q, k, v [B, H, T, D], a rank-4 caller's."""
+    if not num_heads:
+        return _fused_attention(q, k, v, segment_ids, scale, causal, backend)
+    if backend != "xla" and _plan_for(q, k, segment_ids is not None,
+                                      num_heads=num_heads).token_major:
+        return _fused_attention(q, k, v, segment_ids, scale, causal, backend,
+                                None, None, num_heads)
+
+    def heads(x):
+        return jnp.swapaxes(x.reshape(*x.shape[:2], num_heads, -1), 1, 2)
+
+    out = _fused_attention(heads(q), heads(k), heads(v), segment_ids, scale,
+                           causal, backend)
+    return jnp.swapaxes(out, 1, 2).reshape(q.shape)
+
+
+def _attention_over_mesh(mesh, q, k, v, segment_ids, scale, causal, backend,
+                         num_heads=None):
     """The flash kernels inside an SPMD-partitioned step (ParallelExecutor).
 
     The partitioner cannot see into a Mosaic custom call: left bare, the
@@ -806,7 +1000,8 @@ def _attention_over_mesh(mesh, q, k, v, segment_ids, scale, causal, backend):
     heads, so the call is mapped over the mesh instead: batch over the data
     axis, heads over the model axis (each only where it divides), sequence
     and head_dim whole — every chip runs the kernel on its own
-    [B/dp, H/tp, T, D] shard and no collective is needed."""
+    [B/dp, H/tp, T, D] shard (token-major, `num_heads` given: [B/dp, T,
+    H/tp * D]) and no collective is needed."""
     from jax.sharding import PartitionSpec as P
 
     from ..parallel.mesh import DATA_AXIS, MODEL_AXIS
@@ -816,17 +1011,23 @@ def _attention_over_mesh(mesh, q, k, v, segment_ids, scale, causal, backend):
         return name if size > 1 and n % size == 0 else None
 
     b_ax = axis_for(DATA_AXIS, q.shape[0])
-    h_ax = axis_for(MODEL_AXIS, q.shape[1])
+    h_ax = axis_for(MODEL_AXIS, num_heads or q.shape[1])
+    if num_heads and h_ax:
+        num_heads //= mesh.axis_size(h_ax)
+
+    def attend(q, k, v, seg):
+        return _attend(q, k, v, seg, scale, causal, backend, num_heads)
+
     if b_ax is None and h_ax is None:
-        return _fused_attention(q, k, v, segment_ids, scale, causal, backend)
-    qkv = P(b_ax, h_ax, None, None)
+        return attend(q, k, v, segment_ids)
+    qkv = P(b_ax, None, h_ax) if num_heads else P(b_ax, h_ax, None, None)
     args, specs = [q, k, v], [qkv, qkv, qkv]
     if segment_ids is not None:
         args += list(segment_ids)
         specs += [P(b_ax, None), P(b_ax, None)]
 
     def per_shard(q, k, v, *seg):
-        return _fused_attention(q, k, v, seg or None, scale, causal, backend)
+        return attend(q, k, v, seg or None)
 
     # same exemption as ring attention: the pallas INTERPRETER's discharge
     # path trips the varying-axes check; the compiled kernel keeps it
@@ -844,9 +1045,12 @@ def _register():
         nets.py:332 scaled_dot_product_attention upgraded to a flash
         kernel). Lowering picks the backend per device — the TPU-native
         translation of the reference's (place, dtype, ...) kernel
-        dispatch (op_registry.h:214)."""
+        dispatch (op_registry.h:214). Q, K, V: [B, H, T, D], or with the
+        attr `num_heads` token-major, [B, T, H * D]; Out is as Q."""
         q, k, v = ins["Q"][0], ins["K"][0], ins["V"][0]
-        scale = attrs.get("scale") or 1.0 / (q.shape[-1] ** 0.5)
+        num_heads = attrs.get("num_heads")
+        d_head = q.shape[-1] // (num_heads or 1)
+        scale = attrs.get("scale") or 1.0 / (d_head ** 0.5)
         backend = attrs.get("backend") or _auto_backend()
         seg = None
         if ins.get("QSeg"):
@@ -857,9 +1061,9 @@ def _register():
         mesh = getattr(ctx, "mesh", None)
         if backend != "xla" and mesh is not None:
             out = _attention_over_mesh(mesh, q, k, v, seg, scale, causal,
-                                       backend)
+                                       backend, num_heads)
         else:
-            out = _fused_attention(q, k, v, seg, scale, causal, backend)
+            out = _attend(q, k, v, seg, scale, causal, backend, num_heads)
         return {"Out": [out]}
 
 

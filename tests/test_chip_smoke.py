@@ -112,17 +112,24 @@ def test_kernels_phase_through_the_interpreter():
         paged=(5, 40, 8, 4, 16, 6),     # 8 rows of 16: one 128-lane row
         chunk=(2, 16),
         latent=(4, 2, 24, 16, 8, 256, 128, 6))
-    assert set(out["max_rel_err"]) == {
-        "flash_1x2x256x64", "flash_1x2x256x64_seg", "flash_2x2x128x64_full",
-        "flash_2x2x128x64_full_seg", "decode_T1408",
-        "paged_decode", "paged_chunk", "latent_decode", "fused_lstm",
-        "fused_gru"}
+    flash = {"flash_1x2x256x64", "flash_1x2x256x64_seg",
+             "flash_2x2x128x64_full", "flash_2x2x128x64_full_seg"}
+    assert set(out["max_rel_err"]) == flash | {f + "_tm" for f in flash} | {
+        "decode_T1408", "paged_decode", "paged_chunk", "latent_decode",
+        "fused_lstm", "fused_gru"}
     assert out["flash_plans"]["flash_1x2x256x64"] == [
         "flash_fwd_resident_q256_k256_rows2",
         "flash_bwd_resident_q256_k256_rows2"]
     assert out["flash_plans"]["flash_2x2x128x64_full_seg"] == [
         "flash_fwd_resident_q128_k128_rows2",
         "flash_bwd_resident_q128_k128_rows2"]
+    # the same numbers as [B, T, H*D]: two heads of 64 are one lane tile
+    assert out["flash_plans"]["flash_1x2x256x64_tm"] == [
+        "flash_fwd_resident_q256_k256_rows2_tm",
+        "flash_bwd_resident_q256_k256_rows2_tm"]
+    assert out["flash_plans"]["flash_2x2x128x64_full_seg_tm"] == [
+        "flash_fwd_resident_q128_k128_rows2_tm",
+        "flash_bwd_resident_q128_k128_rows2_tm"]
     assert out["max_rel_err"]["paged_chunk"] <= 1e-5
     # bfloat16 rows in the pool, float32 products interpreted
     assert out["max_rel_err"]["latent_decode"] <= 2.0 ** -6

@@ -411,11 +411,25 @@ _PLAN_CASES = [
 ]
 
 
+@pytest.fixture
+def traced_bodies():
+    """The names of the flash kernels whose bodies are traced from here on.
+    The two jitted callables first forget what earlier tests traced, so a
+    case whose shapes an earlier case ran traces its own plan's kernels."""
+    from paddle_tpu.observability import tracing
+    from paddle_tpu.ops import pallas_kernels as pk
+    pk._flash_fwd.clear_cache()
+    pk._flash_bwd.clear_cache()
+    mark = tracing.mark()
+    return lambda: {s.attrs["scope"] for s in tracing.spans_since(mark)
+                    if s.name == "flash/body_traced"}
+
+
 class TestEveryPlan:
     """Forward, lse, dq, dk and dv of every plan the function can return
     (resident and streamed, one head a step and several, a head count the
     step's heads do not divide, the single-tile path) against
-    `_attention_reference`."""
+    `_attention_reference`, and the kernels that ran are the plan's."""
 
     @staticmethod
     def _segments(rng, kind, B, T, Tk):
@@ -434,7 +448,7 @@ class TestEveryPlan:
         return q_ids, kv_ids
 
     @pytest.mark.parametrize("c", _PLAN_CASES)
-    def test_matches_reference(self, rng, monkeypatch, c):
+    def test_matches_reference(self, rng, monkeypatch, traced_bodies, c):
         from paddle_tpu.ops import pallas_kernels as pk
         if c["budget"] is not None:
             monkeypatch.setattr(pk, "_VMEM_BUDGET", c["budget"])
@@ -490,6 +504,103 @@ class TestEveryPlan:
 
         for a, b in zip(grads("xla"), grads("pallas_interpret")):
             np.testing.assert_allclose(a, b, atol=3e-5, rtol=3e-5)
+        # what ran is this plan's forward and backward: a streamed case is
+        # not handed the jaxpr of its resident twin of the same shapes
+        assert traced_bodies() == set(plan.scopes())
+        assert all(("streamed" in s) != c["resident"] for s in plan.scopes())
+
+
+def _tm_case(name, B=1, H=4, T=256, Tk=None, D=64, causal=False, seg=None,
+             budget=None, rows=None, token_major=True):
+    return pytest.param(dict(B=B, H=H, T=T, Tk=Tk or T, D=D, causal=causal,
+                             seg=seg, budget=budget, rows=rows,
+                             token_major=token_major), id=name)
+
+
+# `rows`: the heads a step must take; `token_major=False`: a shape the rule
+# sends back to the head-major kernels between two transposes
+_TOKEN_MAJOR_CASES = [
+    _tm_case("lm-rows4-causal-four-tiles", T=512, causal=True, rows=4),
+    _tm_case("lm-rows4-full-four-tiles", T=512, rows=4),
+    _tm_case("nmt-rows16-single-tile", H=16, T=128, rows=16),
+    _tm_case("nmt-rows16-single-tile-causal", B=2, H=16, T=128, causal=True,
+             rows=16),
+    _tm_case("cross-Tk-longer", H=4, T=128, Tk=384, rows=4),
+    _tm_case("cross-Tk-shorter-causal", H=2, T=256, Tk=128, causal=True),
+    _tm_case("padded-T200-Tk300", B=2, T=200, Tk=300, rows=4),
+    _tm_case("padded-T200-causal", T=200, causal=True, rows=4),
+    _tm_case("D128-rows2", H=2, T=256, D=128, causal=True, rows=2),
+    _tm_case("D128-one-head", H=1, T=128, D=128, rows=1),
+    _tm_case("D32-rows4", H=4, T=128, D=32, rows=4),
+    _tm_case("rows-rounded-to-a-pair-of-6", H=6, T=512, causal=True, rows=2),
+    _tm_case("segments", B=2, T=256, seg="self", rows=4),
+    _tm_case("segments-causal-four-tiles", T=512, seg="self", causal=True,
+             rows=4),
+    _tm_case("segments-pair-cross", B=2, T=128, Tk=256, seg="pair", rows=4),
+    # the shapes the rule leaves out
+    _tm_case("odd-lanes-3-heads-of-64", H=3, T=128, causal=True,
+             token_major=False),
+    _tm_case("odd-lanes-one-head-of-64", H=1, T=128, token_major=False),
+    _tm_case("odd-lanes-D16", H=4, T=128, D=16, token_major=False),
+    _tm_case("streamed", H=2, T=256, causal=True, budget=0,
+             token_major=False),
+]
+
+
+class TestTokenMajor:
+    """`fused_attention` on q, k, v as the projections leave them, [B, T,
+    H*D]: the context and all three gradients against the head-major
+    kernels on the same numbers, both through the interpreter. The two forms
+    do the same arithmetic in the same order, so they agree to rounding of
+    the last place, whichever form the rule picks."""
+
+    @pytest.mark.parametrize("c", _TOKEN_MAJOR_CASES)
+    def test_matches_head_major(self, rng, monkeypatch, traced_bodies, c):
+        from paddle_tpu.ops import pallas_kernels as pk
+        if c["budget"] is not None:
+            monkeypatch.setattr(pk, "_VMEM_BUDGET", c["budget"])
+        B, H, T, Tk, D = (c[n] for n in ("B", "H", "T", "Tk", "D"))
+        seg = TestEveryPlan._segments(rng, c["seg"], B, T, Tk)
+        q = jnp.asarray((rng.randn(B, T, H * D) * 0.5).astype("float32"))
+        k = jnp.asarray((rng.randn(B, Tk, H * D) * 0.5).astype("float32"))
+        v = jnp.asarray(rng.randn(B, Tk, H * D).astype("float32"))
+        g = jnp.asarray(rng.randn(B, T, H * D).astype("float32"))
+        scale, causal = 1.0 / np.sqrt(D), c["causal"]
+        plan = pk._plan_for(q, k, seg is not None, num_heads=H)
+        assert plan.token_major == c["token_major"], plan
+        if c["rows"] is not None:
+            assert plan.rows == c["rows"], plan
+        if plan.token_major:
+            assert H % plan.rows == 0 and plan.rows * D % 128 == 0
+            assert plan.scope("fwd").endswith(f"rows{plan.rows}_tm")
+
+        def heads(x):
+            return jnp.swapaxes(x.reshape(B, -1, H, D), 1, 2)
+
+        def token_major(q_, k_, v_):
+            return pk._attend(q_, k_, v_, seg, scale, causal,
+                              "pallas_interpret", H)
+
+        def head_major(q_, k_, v_):
+            out = pk._fused_attention(heads(q_), heads(k_), heads(v_), seg,
+                                      scale, causal, "pallas_interpret")
+            return jnp.swapaxes(out, 1, 2).reshape(B, T, H * D)
+
+        def both(fn):
+            out, vjp = jax.vjp(fn, q, k, v)
+            return (out,) + vjp(g)
+
+        for got, want in zip(both(token_major), both(head_major)):
+            np.testing.assert_allclose(got, want, atol=2e-6, rtol=2e-6)
+        # both forms ran: the rule's own kernels and the rank-4 caller's
+        # (one and the same where the rule sends the shape back)
+        head_major_plan = pk._plan_for(heads(q), heads(k), seg is not None)
+        assert traced_bodies() == {*plan.scopes(), *head_major_plan.scopes()}
+        assert head_major_plan.resident == (c["budget"] is None)
+        # and the composite, so that the pair cannot be wrong together
+        ref = pk._attend(q, k, v, seg, scale, causal, "xla", H)
+        np.testing.assert_allclose(token_major(q, k, v), ref, atol=2e-5,
+                                   rtol=2e-5)
 
 
 class TestFlashPlan:
@@ -506,6 +617,33 @@ class TestFlashPlan:
         nmt = _flash_plan(128, 128, 64, 2, 64 * 16)
         assert nmt == FlashPlan(True, 128, 128, 16)
         assert nmt.scope("bwd") == "flash_bwd_resident_q128_k128_rows16"
+
+    @pytest.mark.parametrize("T, D, H, plan", [
+        # the plans docs/fusion.md lists, asked for token-major [B, T, H*D]
+        (1024, 64, 16, "flash_fwd_resident_q256_k256_rows4_tm"),
+        (128, 64, 16, "flash_fwd_resident_q128_k128_rows16_tm"),
+        (1024, 128, 16, "flash_fwd_resident_q256_k256_rows2_tm"),
+        (2048, 64, 16, "flash_fwd_resident_q256_k256_rows2_tm"),
+        (1024, 64, 6, "flash_fwd_resident_q256_k256_rows2_tm"),
+        # no number of heads that fits fills whole 128-lane tiles
+        (4096, 64, 16, "flash_fwd_resident_q256_k256_rows1"),
+        (1024, 64, 7, "flash_fwd_resident_q256_k256_rows1"),
+        (128, 64, 3, "flash_fwd_resident_q128_k128_rows3"),
+        (128, 16, 4, "flash_fwd_resident_q128_k128_rows4"),
+        # a head over the budget streams, head-major
+        (8192, 128, 8, "flash_fwd_streamed_q1024_k1024"),
+        (32768, 64, 16, "flash_fwd_streamed_q1024_k1024"),
+    ])
+    def test_token_major_where_heads_fill_whole_lane_tiles(self, T, D, H,
+                                                           plan):
+        from paddle_tpu.ops.pallas_kernels import _flash_plan
+        got = _flash_plan(T, T, D, 2, H, num_heads=H)
+        assert got.scope("fwd") == plan
+        assert got.token_major == plan.endswith("_tm")
+        if got.token_major:
+            assert H % got.rows == 0 and got.rows * D % 128 == 0
+        # a rank-4 caller's plan does not know the field
+        assert not _flash_plan(T, T, D, 2, H).token_major
 
     @pytest.mark.parametrize("T, D", [(8192, 128), (32768, 64),
                                       (32768, 128), (4096, 128)])

@@ -48,6 +48,19 @@ def _flash_args(shape):
     return [S(shape, BF16)] * 4 + [S((shape[0], shape[2]), I32)]
 
 
+def _flash_fwd_bwd_token_major(shape, causal=True, segments=False):
+    """The same call on q, k, v as a training step's projections leave them,
+    [B, T, H*D]: the op's own path (`_attend`)."""
+    B, H, T, D = shape
+
+    def fwd_bwd(q, k, v, do, ids):
+        out, vjp = jax.vjp(lambda q, k, v: pallas_kernels._attend(
+            q, k, v, (ids, ids) if segments else None, D ** -0.5, causal,
+            "pallas", H), q, k, v)
+        return (out,) + vjp(do)
+    return fwd_bwd, [S((B, T, H * D), BF16)] * 4 + [S((B, T), I32)]
+
+
 # [B, H, T, D], causal, kernels: the cells' shapes take the resident plan (a
 # forward and ONE backward), a head over the VMEM budget streams (dq and
 # dk / dv in two passes)
@@ -65,6 +78,20 @@ _FLASH_SHAPES = [
 def test_flash_fwd_bwd_lowers(shape, causal, kernels, segments):
     text = _tpu_text(_flash_fwd_bwd(causal, segments), *_flash_args(shape))
     assert _n_calls(text) == kernels
+
+
+@pytest.mark.parametrize("shape, causal, kernels", _FLASH_SHAPES)
+@pytest.mark.parametrize("segments", [False, True])
+def test_flash_fwd_bwd_lowers_token_major(shape, causal, kernels, segments):
+    """The cells' shapes as the training steps hand them over; the head that
+    streams goes back to the head-major kernels between two transposes."""
+    f, args = _flash_fwd_bwd_token_major(shape, causal, segments)
+    text = jax.jit(f).trace(*args).lower(
+        lowering_platforms=("tpu",)).as_text(debug_info=True)
+    assert _n_calls(text) == kernels
+    names = set(re.findall(r'loc\("(flash_[^"]*)/pallas_call"', text))
+    assert len(names) == kernels
+    assert all(n.endswith("_tm") == (kernels == 2) for n in names), names
 
 
 @pytest.fixture(scope="module")
@@ -91,6 +118,30 @@ def test_flash_fwd_bwd_compiles_for_v5e(one_chip, shape, causal, kernels):
     assert compiled.as_text().count("tpu_custom_call") == kernels
 
 
+# [B, H, T, D] handed over as [B, T, H*D]: the cells' shapes, then what else
+# the rule sends token-major (two heads of 128, a pair of six heads, four
+# heads of 32, keys of another length, a length that pads)
+_TOKEN_MAJOR_SHAPES = [p for p in _FLASH_SHAPES[:4]] + [
+    pytest.param((4, 16, 1024, 128), True, 2, id="D128-rows2"),
+    pytest.param((4, 6, 1024, 64), True, 2, id="pair-of-6-heads"),
+    pytest.param((4, 8, 512, 32), False, 2, id="D32-rows8"),
+    pytest.param((4, 16, 2048, 64), True, 2, id="T2048-rows2"),
+    pytest.param((8, 16, 200, 64), True, 2, id="T200-padded"),
+]
+
+
+@pytest.mark.parametrize("shape, causal, kernels", _TOKEN_MAJOR_SHAPES)
+def test_flash_token_major_compiles_for_v5e(one_chip, shape, causal, kernels):
+    """Mosaic's own compile of the token-major kernels: the heads' lane
+    slices, the q-side heads held over the key loop and the transposes that
+    write the results must fit VMEM beside the blocks."""
+    f, args = _flash_fwd_bwd_token_major(shape, causal)
+    args = [S(a.shape, a.dtype, sharding=one_chip) for a in args]
+    text = jax.jit(f).lower(*args).compile().as_text()
+    assert text.count("tpu_custom_call") == kernels
+    assert text.count("_tm") >= kernels
+
+
 @pytest.mark.parametrize("shape, scope", [
     ((2, 4, 256, 64), "flash_fwd_resident_q256_k256_rows4"),
     ((2, 4, 256, 64), "flash_bwd_resident_q256_k256_rows4"),
@@ -105,9 +156,11 @@ def test_flash_kernels_carry_their_names(shape, scope):
     `device_ops`)."""
     lowered = jax.jit(_flash_fwd_bwd()).trace(*_flash_args(shape)).lower(
         lowering_platforms=("tpu",))
-    names = re.findall(r'"jit\(fwd_bwd\)/([^"]*)/pallas_call"',
+    # the call lies in the kernel's own jitted function (traced once a
+    # step), so its location starts at the scope
+    names = re.findall(r'loc\("([^"]*)/pallas_call"',
                        lowered.as_text(debug_info=True))
-    assert sum(scope + ")" in n for n in set(names)) == 1, names
+    assert sum(n == scope for n in set(names)) == 1, names
 
 
 def test_decode_attention_lowers_at_the_gate_edge():
@@ -290,12 +343,101 @@ def test_sharded_train_step_runs_flash_per_shard(as_on_tpu):
                            list(feed), [loss.name])
     text = _step_tpu_text(compiled, feed, pt.global_scope())
     calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
-    assert len(calls) == 2 * n_layers       # a forward and ONE backward
+    # a forward and ONE backward, each lowered once for all the layers; two
+    # heads of 16 fill no 128-lane tile, so the shards run head-major
+    assert len(calls) == 2
     per_shard = f"tensor<{(b // 2) * (nh // 2)}x{t}x{dh}xbf16>"
     full = f"tensor<{b * nh}x{t}x{dh}xbf16>"
     for ln in calls:
         assert per_shard in ln and full not in ln, ln[:300]
     assert re.search(r"sdy\.manual_computation|shard_map", text)
+
+
+# -- a step holds each flash body once, and no head-major copy ---------------
+
+
+def _flash_counters(mark):
+    from paddle_tpu.observability import tracing
+    spans = [s.name for s in tracing.spans_since(mark)]
+    return spans.count("flash/body_traced"), spans.count("flash/call")
+
+
+def _lm_step(n_layers, b=2, t=256, nh=4, dh=64):
+    loss, _ = pt.models.transformer.transformer_lm(
+        vocab=128, max_len=t, d_model=nh * dh, d_inner=128, num_heads=nh,
+        num_layers=n_layers, dropout=0.0)
+    feed = {"tokens": np.zeros((b, t), "int64"),
+            "tokens@SEQLEN": np.full((b,), t, "int32"),
+            "targets": np.zeros((b, t), "int64")}
+    return loss, feed
+
+
+def _nmt_step(n_layers, b=2, t=128, nh=4, dh=64):
+    loss, _ = pt.models.transformer.transformer(
+        src_vocab=64, tgt_vocab=64, max_len=t, d_model=nh * dh, d_inner=128,
+        num_heads=nh, num_layers=n_layers, dropout=0.0)
+    feed = {"src": np.zeros((b, t), "int64"),
+            "src@SEQLEN": np.full((b,), t, "int32"),
+            "tgt": np.zeros((b, t), "int64"),
+            "tgt@SEQLEN": np.full((b,), t, "int32"),
+            "lbl": np.zeros((b, t), "int64")}
+    return loss, feed
+
+
+_HEAD_MAJOR_COPY = re.compile(r"stablehlo\.transpose[^\n]*dims = \[0, 2, 1, 3\]")
+
+
+@pytest.mark.parametrize("build, n_layers, calls, bodies, scopes", [
+    # a causal forward and its backward, whatever the depth
+    pytest.param(_lm_step, 2, 4, 2, {"rows4_tm"}, id="lm-2-layers"),
+    pytest.param(_lm_step, 3, 6, 2, {"rows4_tm"}, id="lm-3-layers"),
+    # encoder and cross attention share the full bodies (Tk = T), the
+    # decoder's self attention has the causal ones
+    pytest.param(_nmt_step, 2, 12, 4, {"rows4_tm"}, id="nmt-2+2-layers"),
+])
+@pytest.mark.parametrize("mesh_of_four", [False, True],
+                         ids=["one-chip", "dp4"])
+def test_a_step_lowers_each_flash_body_once(as_on_tpu, build, n_layers,
+                                            calls, bodies, scopes,
+                                            mesh_of_four):
+    """A training step traces and lowers a flash body once, not once a
+    layer: the module holds `bodies` Mosaic calls for `calls` call sites and
+    the counters say the same; the kernels take the projections' [B, T, H*D]
+    (`_tm`), so no head-major copy stands between a projection and a call.
+    `dp4`: the same inside the shard_map of a data-parallel mesh of four."""
+    from paddle_tpu.observability import tracing
+    from paddle_tpu.parallel import DeviceMesh, ParallelExecutor
+    loss, feed = build(n_layers)
+    pt.optimizer.AdamOptimizer(learning_rate=1e-3).minimize(loss)
+    exe = pt.Executor()
+    exe.run(pt.default_startup_program())
+    if mesh_of_four:
+        feed = {n: np.concatenate([v] * 4) for n, v in feed.items()}
+        exe = ParallelExecutor(
+            loss_name=loss.name,
+            mesh=DeviceMesh(jax.devices()[:4], {"dp": 4}))
+        exe._feed_shapes = {n: np.shape(v) for n, v in feed.items()}
+    compiled = exe._compile(pt.default_main_program(), pt.global_scope(),
+                            list(feed), [loss.name])
+    jax.clear_caches()      # a body an earlier test traced is not traced again
+    mark = tracing.mark()
+    args = (tuple(jnp.asarray(feed[n]) for n in compiled.feed_names),
+            tuple(pt.global_scope().get(n) for n in compiled.ro_names),
+            tuple(pt.global_scope().get(n) for n in compiled.rw_names),
+            np.uint32(0))
+    text = compiled.fn.trace(*args).lower(
+        lowering_platforms=("tpu",)).as_text(debug_info=True)
+    assert _n_calls(text) == bodies
+    assert _flash_counters(mark) == (bodies, calls)
+    names = set(re.findall(r'loc\("(flash_[^"]*)/pallas_call"', text))
+    assert {n.split("_", 5)[-1] for n in names} == scopes, names
+    assert not _HEAD_MAJOR_COPY.search(text)
+    if mesh_of_four:
+        assert re.search(r"sdy\.manual_computation|shard_map", text)
+        b = feed[compiled.feed_names[0]].shape[0] // 4
+        for ln in text.splitlines():
+            if "tpu_custom_call" in ln:
+                assert f"tensor<{b}x" in ln, ln[:300]
 
 
 def _latent_read(slots, positions):

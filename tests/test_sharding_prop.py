@@ -50,12 +50,13 @@ def _col_row_mlp(d_in=8, d_h=8, col=True, row=True, nclass=4):
     return loss
 
 
-def _tp_transformer(vocab=64, d_model=32, heads=4):
+def _tp_transformer(vocab=64, d_model=32, heads=4, dropout=None):
     from paddle_tpu.models import transformer
     from paddle_tpu.parallel import annotate_tp
+    kw = {} if dropout is None else {"dropout": dropout}
     loss, _ = transformer.transformer_lm(
         vocab=vocab, max_len=8, d_model=d_model, d_inner=2 * d_model,
-        num_heads=heads, num_layers=2, mean_loss=True)
+        num_heads=heads, num_layers=2, mean_loss=True, **kw)
     pt.optimizer.Adam(learning_rate=1e-3).minimize(loss)
     return loss, annotate_tp()
 
@@ -183,9 +184,14 @@ class TestPropagation:
         res = propagate_sharding(pt.default_main_program(), tp_size=2)
         assert not res.errors, [str(d) for d in res.errors]
         sharded = res.sharded_vars()
-        # head-sharded attention rides through the reshape/transpose pair
-        assert any(s and len(s) == 4 and s[1] == "tp"
-                   for s in sharded.values()), "no head-sharded 4d value"
+        # head-sharded attention: q, k, v and the context stay [B, T, H*D]
+        # through the fused op, their heads (the last dim) over tp
+        block = pt.default_main_program().global_block()
+        fused = [op for op in block.ops if op.type == "fused_attention"]
+        assert fused
+        for op in fused:
+            for name in (op.inputs["Q"][0], op.outputs["Out"][0]):
+                assert tuple(sharded[name]) == (None, None, "tp"), name
 
 
 # ---------------------------------------------------------------------------
@@ -334,8 +340,9 @@ class TestTpShardPass:
 
     def test_reshape_attrs_localized(self):
         """Head-split reshape targets divide by tp (the [B,T,D@tp] ->
-        [B,T,nh/tp,dh] case)."""
-        _tp_transformer(d_model=32, heads=4)
+        [B,T,nh/tp,dh] case), on the path that needs the attention
+        weights (dropout on them); the fused op's `num_heads` likewise."""
+        _tp_transformer(d_model=32, heads=4, dropout=0.1)
         out = get_pass("tp_shard_pass", tp=2)(pt.default_main_program())
         head_splits = [op for op in out.global_block().ops
                        if op.type == "reshape"
@@ -343,6 +350,18 @@ class TestTpShardPass:
         assert head_splits
         for op in head_splits:
             assert op.attrs["shape"][2] == 2  # 4 heads / tp2
+
+    def test_fused_attention_heads_localized(self):
+        """The fused op on [B,T,H*D@tp] sees H/tp whole heads a shard."""
+        _tp_transformer(d_model=32, heads=4)
+        prog = pt.default_main_program()
+        out = get_pass("tp_shard_pass", tp=2)(prog)
+        fused = [op for op in out.global_block().ops
+                 if op.type == "fused_attention"]
+        assert fused and all(op.attrs["num_heads"] == 2 for op in fused)
+        assert all(op.attrs["num_heads"] == 4
+                   for op in prog.global_block().ops
+                   if op.type == "fused_attention")
 
     def test_analytic_wire_bytes(self):
         _col_row_mlp()

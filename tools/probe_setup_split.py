@@ -1,0 +1,187 @@
+"""Probe: where a training cell's set-up goes, up to its first step.
+
+    python3 tools/probe_setup_split.py --workload lm-big_train_1chip [--attention xla]
+    python3 tools/probe_setup_split.py --kernels 12 --shape 8,16,1024,64 --causal 1
+
+The first form builds the cell's program as `benchmark/loops/train.py` does,
+runs the startup program and ONE step, and prints one JSON line: the loop's
+own `build`, `init` and `compile_or_load`, and inside the last the seconds JAX
+reports for the step's trace (`jaxpr_trace`), its lowering to a module
+(`jaxpr_to_mlir`), the backend's compile (`backend_compile`: 0 in a run that
+loads the executable from the compile cache) and the cache's retrieval, with
+the `flash/call` and `flash/body_traced` counters of that trace.
+`--attention xla` pins every `fused_attention` op to the composite, as
+`chip_smoke.py` does: the difference between the two runs is the flash calls'
+share. The second form times `jax.jit(jax.grad(...)).trace()` and `.lower()`
+of N flash calls alone, head-major as `flash_attention` takes them.
+
+A run that finds the cache cold fills it: run twice and read the second.
+Run from the root of the tree to be probed (PR 47, Step 0)."""
+
+import argparse
+import collections
+import json
+import os
+import sys
+import time
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+EVENTS = {"/jax/core/compile/jaxpr_trace_duration": "jaxpr_trace",
+          "/jax/core/compile/jaxpr_to_mlir_module_duration": "jaxpr_to_mlir",
+          "/jax/core/compile/backend_compile_duration": "backend_compile",
+          "/jax/compilation_cache/cache_retrieval_time_sec": "cache_retrieval",
+          "/jax/compilation_cache/compile_time_saved_sec": "cache_saved"}
+
+
+class Durations:
+    def __init__(self):
+        import jax.monitoring
+        self.total = collections.defaultdict(float)
+        self.count = collections.Counter()
+        self.longest = collections.defaultdict(float)
+        jax.monitoring.register_event_duration_secs_listener(self._on)
+
+    def _on(self, event, duration, **kw):
+        name = EVENTS.get(event)
+        if name:
+            self.total[name] += duration
+            self.count[name] += 1
+            self.longest[name] = max(self.longest[name], duration)
+
+    def since(self, before):
+        return {k: round(v - before.get(k, 0.0), 3)
+                for k, v in self.total.items()}
+
+
+def flash_counters(tracing, mark):
+    names = collections.Counter(
+        s.name for s in tracing.spans_since(mark)
+        if s.name.startswith("flash/"))
+    return dict(names)
+
+
+def probe_cell(args):
+    t0 = time.perf_counter()
+    import jax
+    import paddle_tpu as pt
+    from benchmark import harness, traffic
+    from paddle_tpu.observability import tracing
+
+    durations = Durations()
+    cell = harness.Cell(args.workload)
+    parts = {"import": time.perf_counter() - t0}
+    t = time.perf_counter()
+    device = harness.device_facts(cell.chips)
+    parts["runtime_start"] = time.perf_counter() - t
+
+    t = time.perf_counter()
+    mix, cfg, adapter = cell.traffic, cell.config, cell.adapter
+    with pt.core.unique_name.guard():
+        loss = adapter.build_train(cfg, mix)
+        pt.optimizer.AdamOptimizer(
+            learning_rate=mix["optimizer"]["learning_rate"]).minimize(loss)
+    n_attention = 0
+    for op in pt.default_main_program().global_block().ops:
+        if op.type == "fused_attention":
+            n_attention += 1
+            if args.attention:
+                op.attrs["backend"] = args.attention
+    batches = traffic.train_batches(mix, args.seed, cell.chips,
+                                    adapter.vocabs(cfg))
+    parts["build"] = time.perf_counter() - t
+
+    t = time.perf_counter()
+    pt.default_startup_program().random_seed = args.seed % (2 ** 31 - 1) + 1
+    exe = pt.Executor()
+    exe.run(pt.default_startup_program())
+    if cell.spec["executor"] == "ParallelExecutor":
+        from paddle_tpu.parallel import DeviceMesh, ParallelExecutor
+        mesh = DeviceMesh(device["devices"], dict(cell.spec["mesh"]))
+        exe = ParallelExecutor(loss_name=loss.name, mesh=mesh)
+
+        def step(feed):
+            return exe.run(fetch_list=[loss], feed=feed)[0]
+    else:
+        def step(feed):
+            return exe.run(feed=feed, fetch_list=[loss])[0]
+    jax.block_until_ready(pt.global_scope().get(adapter.param_names(cfg)[0]))
+    parts["init"] = time.perf_counter() - t
+
+    before, mark = dict(durations.total), tracing.mark()
+    t = time.perf_counter()
+    first = float(step(batches[0]["feed"]))
+    parts["compile_or_load"] = time.perf_counter() - t
+    t = time.perf_counter()
+    second = float(step(batches[1 % len(batches)]["feed"]))
+    parts["second_step"] = time.perf_counter() - t
+    print(json.dumps({
+        "workload": args.workload, "attention": args.attention or "default",
+        "fused_attention_ops": n_attention,
+        "parts": {k: round(v, 3) for k, v in parts.items()},
+        "in_compile_or_load": durations.since(before),
+        "whole_process": durations.since({}),
+        # traces nest (a jitted callable inside the step is an event of its
+        # own inside the step's): the longest is the step's whole trace
+        "longest_event": {k: round(v, 3)
+                          for k, v in durations.longest.items()},
+        "events": dict(durations.count),
+        "flash": flash_counters(tracing, mark),
+        "loss": [first, second],
+        "device": f"{device['platform']} {device['kind']} x{device['count']}",
+    }), flush=True)
+
+
+def probe_kernels(args):
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops import pallas_kernels as pk
+
+    shape = tuple(int(x) for x in args.shape.split(","))
+    scale = shape[-1] ** -0.5
+
+    def loss(q, k, v):
+        x = q
+        for _ in range(args.kernels):
+            x = pk.flash_attention(x, k, v, scale=scale,
+                                   causal=bool(args.causal),
+                                   backend="pallas")
+        return jnp.sum(x.astype(jnp.float32))
+
+    s = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    out = {"kernels": args.kernels, "shape": list(shape),
+           "causal": bool(args.causal), "rounds": []}
+    for _ in range(2):      # the second round has every import behind it
+        jax.clear_caches()
+        t = time.perf_counter()
+        traced = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(s, s, s)
+        t1 = time.perf_counter()
+        lowered = traced.lower(lowering_platforms=("tpu",))
+        t2 = time.perf_counter()
+        text = lowered.as_text()
+        out["rounds"].append({"trace_s": round(t1 - t, 3),
+                              "lower_s": round(t2 - t1, 3),
+                              "custom_calls": text.count("tpu_custom_call"),
+                              "module_mb": round(len(text) / 1e6, 3)})
+    print(json.dumps(out), flush=True)
+
+
+def main():
+    ap = argparse.ArgumentParser(description=__doc__)
+    ap.add_argument("--workload")
+    ap.add_argument("--attention", default=None)
+    ap.add_argument("--seed", type=int, default=3000000001)
+    ap.add_argument("--kernels", type=int, default=0)
+    ap.add_argument("--shape", default="8,16,1024,64")
+    ap.add_argument("--causal", type=int, default=1)
+    args = ap.parse_args()
+    if args.kernels:
+        probe_kernels(args)
+    else:
+        probe_cell(args)
+
+
+if __name__ == "__main__":
+    main()

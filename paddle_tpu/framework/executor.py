@@ -159,7 +159,7 @@ class PreparedStep:
                  "_injected", "_fn", "_spans", "_buf", "_views",
                  "_rest_names", "_aot", "host_args", "_b_rest_vals",
                  "_b_ro_vals", "_b_rw_vals", "_b_rw_pick", "_b_state_names",
-                 "_b_scope_vars", "_b_seed_base")
+                 "_b_scope_vars", "_b_seed_base", "_b_staged")
 
     def __init__(self, compiled, scope, owner, random_seed, injected,
                  example):
@@ -251,10 +251,13 @@ class PreparedStep:
         `feed` holds into it and REPLACES each packed entry of `feed` with
         the view of its span, so the caller goes on filling `feed[name]`
         in place (the serving engine does, between ticks) and writes the
-        pack by doing so. Fill it only once the fetches of the launch
-        before were read: a launch may read the buffer until its transfer
-        is done. A feed the pack cannot hold stays the caller's own array,
-        mutated in place as before.
+        pack by doing so. A launch transfers one of TWO copies of it, used
+        in turn (`run_bound`), so the pack may be filled again as soon as
+        the launch returns, with that launch and the one before it still
+        on the device; a third launch waits until the first was read. A
+        feed the pack cannot hold stays the caller's own array, mutated in
+        place as before, and a launch may read it until its transfer is
+        done.
 
         `share`: a bound step whose feeds START with this step's (same
         names, shapes and dtypes, in order). This step then takes the
@@ -278,6 +281,7 @@ class PreparedStep:
             self._views = {n: share._views[n] for n in self._views}
         self._pack(feed)
         feed.update(self._views)
+        self._b_staged = (np.empty_like(self._buf), np.empty_like(self._buf))
         self._b_rest_vals = tuple(
             feed[n] if n in feed else injected[n] for n in self._rest_names)
         self.host_args = 1 + sum(not isinstance(v, jax.Array)
@@ -329,7 +333,14 @@ class PreparedStep:
         owner._run_counter += 1
         buf = self._buf
         buf[0] = (self._b_seed_base + owner._run_counter) % 2147483648
-        fetches, new_state = self._fn(buf, self._b_rest_vals,
+        # the launch transfers a copy, the two copies used in turn: the
+        # caller may fill the pack again while this launch and the one
+        # before it are still on the device (a backend may read the host
+        # array it was handed until the program that takes it has run)
+        staged, other = self._b_staged
+        self._b_staged = (other, staged)
+        np.copyto(staged, buf)
+        fetches, new_state = self._fn(staged, self._b_rest_vals,
                                       self._b_ro_vals, self._b_rw_vals)
         self._b_rw_vals = self._b_rw_pick(new_state)
         sv = self._b_scope_vars

@@ -253,6 +253,163 @@ class GenRequest:
         self._event.set()
 
 
+class _Running:
+    """Running mean of a reading, and of its distance from that mean. A
+    reading over twice the mean counts as twice the mean (a compile inside
+    a launch, a stall of the host: not what the next one will take); one
+    under a quarter of it replaces it (the first reading was such a one)."""
+
+    __slots__ = ("mean", "dev")
+
+    def __init__(self, mean: Optional[float] = None):
+        self.mean = mean
+        self.dev = 0.0
+
+    def add(self, x: float):
+        mean = self.mean
+        if mean is None or x < mean / 4:
+            self.mean = x
+        else:
+            x = min(x, 2 * mean)
+            self.dev += (abs(x - mean) - self.dev) / 8
+            self.mean = mean + (x - mean) / 8
+
+
+class _Launched:
+    """A tick on the device whose ids the host has not read: its
+    `engine/tick` span, the fetch, the (request, row) pairs whose row is a
+    sampled token, the program that runs it, when its launch returned and
+    when the device was done with it (None: not seen yet)."""
+
+    __slots__ = ("tick", "fetch", "emits", "program", "launched_at",
+                 "done_at")
+
+    def __init__(self, tick, fetch, program, launched_at):
+        self.tick, self.fetch, self.program = tick, fetch, program
+        self.launched_at = launched_at
+        self.emits: List[tuple] = []
+        self.done_at: Optional[float] = None
+
+
+class _TickPacer:
+    """When to give the thread back while a tick is still on the device:
+    one launch's own time before the device is done with it, so that the
+    next tick is queued just as the device comes free and whoever polls
+    arrivals between two steps still gets them into the very next tick.
+    Everything is estimated from what the engine sees, on `clock`:
+
+    - `copy_back`: the running mean of the ids' way back (`np.asarray` of
+      a tick that is done). The host sees a tick done that much after the
+      device was: `done` takes it off;
+    - `device_s[program]`: the device times last seen for a tick program,
+      each from the later of (the tick before done, this tick's launch
+      returned) to this tick done; the hold goes by the LEAST of the last
+      three (one seen across a stall of the host is too long, and a hold
+      that is too long leaves the chip idle and is then seen as long again;
+      one too short only queues the next tick early);
+    - the lead a launch needs (`lead_s`): the running mean of the host's
+      own stretch from `step()`'s entry to the launch's return, plus that
+      of the caller's gap between two steps (return to next entry), plus
+      four times their running deviations (a launch that comes late leaves
+      the chip idle for as long; one that comes early costs an arrival a
+      tick only if it falls into that stretch);
+    - what a sleep costs on this host: the shortest sleep it gives
+      (`nap_floor_s`, taken once, here) and the running mean of what the
+      hold's own sleeps overran (`oversleep`)."""
+
+    __slots__ = ("clock", "sleep", "device_s", "own", "gap", "copy_back",
+                 "nap_floor_s", "oversleep", "entered_at", "left_at",
+                 "free_at")
+
+    def __init__(self, clock=time.perf_counter, sleep=time.sleep):
+        self.clock, self.sleep = clock, sleep
+        self.device_s: Dict[str, deque] = {}
+        self.free_at = float("-inf")    # the device was never seen busy
+        self.own, self.gap, self.copy_back = _Running(), _Running(), _Running()
+        naps = []
+        for _ in range(3):
+            t = clock()
+            sleep(1e-9)
+            naps.append(clock() - t)
+        self.nap_floor_s = min(naps)
+        self.oversleep = _Running(self.nap_floor_s)
+        self.entered_at = self.left_at = None
+
+    def entered(self):
+        """`step()` was entered (after `left`: the caller's gap ends)."""
+        self.entered_at = now = self.clock()
+        if self.left_at is not None:
+            self.gap.add(now - self.left_at)
+            self.left_at = None
+
+    def launched(self, tick, fetch, program: str) -> _Launched:
+        """The launch of `tick` returned just now."""
+        now = self.clock()
+        self.own.add(now - self.entered_at)
+        return _Launched(tick, fetch, program, now)
+
+    def left(self):
+        """`step()` returns with a tick on the device."""
+        self.left_at = self.clock()
+
+    def lead_s(self) -> float:
+        own, gap = self.own, self.gap
+        return own.mean + (gap.mean or 0.0) + 4 * (own.dev + gap.dev)
+
+    def _started(self, run: _Launched) -> float:
+        """When the device took `run` up: when its launch returned, or when
+        the tick before it was done if that came later."""
+        return max(run.launched_at, self.free_at)
+
+    def done(self, run: _Launched, seen_at: float):
+        """`run` was seen done at `seen_at` (a wait on it returned, or ONE
+        `np.asarray` of it): when the device was done with it and free for
+        the next, and its program's device time."""
+        run.done_at = seen_at - (self.copy_back.mean or 0.0)
+        self.device_s.setdefault(run.program, deque(maxlen=3)).append(
+            run.done_at - self._started(run))
+        self.free_at = run.done_at
+
+    def hold_until(self, run: _Launched) -> Optional[float]:
+        """The instant `run` (the newest launch, the tick before it done) is
+        one lead from its end; None while its program's device time was
+        never seen."""
+        seen = self.device_s.get(run.program)
+        if seen is None:
+            return None
+        return self._started(run) + min(seen) - self.lead_s()
+
+    def until(self, fetch, deadline: float) -> bool:
+        """Give the core away until `deadline`, or hold it to the moment
+        `fetch` is found ready if that comes first (True). ONE sleep, cut
+        short by twice what such sleeps overran, where that leaves a sleep
+        this host can give; then a spin: a sleep that overruns leaves the
+        chip idle, a spin costs the core for that stretch alone."""
+        clock = self.clock
+        while not fetch.is_ready():
+            now = clock()
+            if now >= deadline:
+                return False
+            nap = deadline - now - 2 * self.oversleep.mean
+            if nap >= self.nap_floor_s:
+                self.sleep(nap)
+                self.oversleep.add(clock() - now - nap)
+        return True
+
+    def wait_for(self, run: _Launched):
+        """Until `run` is done on the device (at once if it was seen so)."""
+        if run.done_at is None:
+            run.fetch.block_until_ready()
+            self.done(run, self.clock())
+
+    def read(self, fetch) -> np.ndarray:
+        """The ids of a tick that is done, timed: their way back."""
+        t = self.clock()
+        ids = np.asarray(fetch)
+        self.copy_back.add(self.clock() - t)
+        return ids
+
+
 class ContinuousBatchingEngine:
     """Slot-scheduled decode loop: one compiled tick, S independent
     sequences in flight, admission the tick a slot frees.
@@ -267,8 +424,8 @@ class ContinuousBatchingEngine:
     #: `engine/device_wait` and `engine/copy_back` (two parts cost the thread
     #: a second sleep and wake-up in front of a first token, 0.08 ms on a TPU
     #: host: PERF.md section 6, PR 41). A tick read late has the two parts by
-    #: construction, the second beside the next tick, so the medians have
-    #: every one of those
+    #: construction, both after the next launch, so the medians have every
+    #: one of those
     WAIT_SPLIT_EVERY = 16
 
     #: how a prompt is consumed (`stats()["prefill"]`): one token a tick
@@ -385,12 +542,16 @@ class ContinuousBatchingEngine:
         # whichever runs after the other refreshes first
         # (PreparedStep.refresh_state); pure steady states never refresh.
         self._target_state_owner = "main"
-        #: the tick whose ids are still on the device: (its `engine/tick`
-        #: span, the fetch, the (request, row) pairs whose row is a sampled
-        #: token). `_plain_tick` reads and commits it after the next launch
-        self._uncommitted = None
-        #: ticks whose ids were read a launch late (`stats()["dispatch"]`)
+        #: the tick whose ids are still on the device, and which may still
+        #: be running there (`_Launched`). `_plain_tick` waits for it, reads
+        #: and commits it after the next launch
+        self._uncommitted: Optional[_Launched] = None
+        #: when a late tick's `step()` returns (`_plain_tick`, the hold)
+        self._pacer = _TickPacer()
+        #: ticks whose ids were read a launch late, and ticks launched while
+        #: the tick before was still on the device (`stats()["dispatch"]`)
         self.late_reads = 0
+        self.run_ahead = 0
         # census counters (tools/bench_serve.py occupancy evidence)
         self.n_ticks = 0
         self.busy_slot_ticks = 0
@@ -815,8 +976,10 @@ class ContinuousBatchingEngine:
 
         A plain tick whose ids nobody waits for is read a launch LATE
         (`_plain_tick`): its tokens, and the completions among them, come
-        out of the next `step()`. The step that leaves the engine idle
-        always delivers its own."""
+        out of the next `step()`, and this one returns while the tick is
+        still on the device, one launch's own time before its end. The
+        step that leaves the engine idle always delivers its own."""
+        self._pacer.entered()
         self._admit()
         with self._lock:
             # a closed request holds its slot for its completion alone
@@ -877,19 +1040,28 @@ class ContinuousBatchingEngine:
 
     def _plain_tick(self, active: Dict[int, "GenRequest"]
                     ) -> List[GenRequest]:
-        """Fill, launch, commit, wait: one tick. What the host does with a
-        tick's results is in two halves. The POSITIONS (`_advance_positions`:
-        `fed`, the blocks filled, who ends by count) need no ids and are
-        applied right after the launch, beside the device. The IDS
-        (`_commit_ids`: tokens, the first token's stamp, `eos`, completions)
-        are read before this returns on an EAGER tick, and on a LATE one
-        (`_reads_late`) left on the device, where the next tick's decode
-        rows take them (`_LastIds`, models/transformer.py), and read and
-        committed after that next launch, again beside the device. Either
-        way this returns only once the device is done with the tick it
-        launched, so whoever polls arrivals between steps sees them when a
-        tick ends. Returns the requests whose completion it delivered."""
+        """Fill, launch, read the tick before, commit, hold: one tick. What
+        the host does with a tick's results is in two halves. The POSITIONS
+        (`_advance_positions`: `fed`, the blocks filled, who ends by count)
+        need no ids and are applied after the launch, beside the device. The
+        IDS (`_commit_ids`: tokens, the first token's stamp, `eos`,
+        completions) are read before this returns on an EAGER tick, and on a
+        LATE one (`_reads_late`) left on the device, where the next tick's
+        decode rows take them (`_LastIds`, models/transformer.py), and read
+        and committed after that next launch.
+
+        A late tick is not waited for either: this returns while it runs,
+        one launch's own time before the device is done with it
+        (`_TickPacer`: the hold), so the next tick is launched BEHIND it, the
+        device goes from one into the next, and the wait for its end
+        (`engine/device_wait`) comes after that next launch. Whoever polls
+        arrivals between steps still gets them into the very next tick, but
+        for the last stretch of a tick, one launch long. When this returns at
+        most one launched tick is unread, and the tick before it is read:
+        never is a tick launched behind one that has not started. Returns
+        the requests whose completion it delivered."""
         span = _tracing.span
+        pacer = self._pacer
         t0 = time.perf_counter()
         with span("tick", "engine/tick") as tick:
             with span("dispatch", "engine/dispatch"):
@@ -902,7 +1074,14 @@ class ContinuousBatchingEngine:
                     self.target_forwards += 1
                     launch.attrs["host_args"] = self._bound_steps[
                         self._target_state_owner].host_args
-                td = time.perf_counter()       # async dispatch returned
+                # async dispatch returned
+                run = pacer.launched(tick, fetches[0],
+                                     self._target_state_owner)
+            # the tick before, if its ids were left on the device: was it
+            # still running when this one was queued behind it?
+            before, self._uncommitted = self._uncommitted, None
+            ahead = before is not None and not before.fetch.is_ready()
+            self.run_ahead += ahead
             if _tracing.enabled():
                 # counted here, in the scheduler, while the device runs: a
                 # slot PREFILLS on this tick when the position it fed is a
@@ -917,53 +1096,68 @@ class ContinuousBatchingEngine:
                     tick.attrs["prefill"] = sum(
                         1 for r in active.values()
                         if r.fed < len(r.prompt) - 1)
-            # the tick before, if its ids were left on the device: it is
-            # done (the step that launched it waited for it), so this is the
-            # ids' way back and no wait, and the device runs beside it
-            before, self._uncommitted = self._uncommitted, None
             if before is not None:
-                tick_before, fetch_before, emits_before = before
-                with span("tick", "engine/copy_back"):
-                    ids = np.asarray(fetch_before)
+                # its end, with this tick queued behind it: the device goes
+                # from one into the other, whenever the thread wakes up; then
+                # the ids' way back, beside the device
+                with span("tick", "engine/wait"):
+                    with span("tick", "engine/device_wait"):
+                        pacer.wait_for(before)
+                    with span("tick", "engine/copy_back"):
+                        ids = pacer.read(before.fetch)
             with span("tick", "engine/commit"):
                 delivered: List[GenRequest] = []
                 if before is not None:
-                    self._note_tick_counts(tick_before, ids)
-                    delivered = self._commit_ids(emits_before, ids)
+                    self._note_tick_counts(before.tick, ids)
+                    delivered = self._commit_ids(before.emits, ids)
                     for req in delivered:
                         # whose `eos` came out just now has a row in the tick
                         # in flight: `_advance_positions` drops it
                         req.closed = True
                 self._stamp_kv_watermarks(active)
-                emits = self._advance_positions(active)
-                late = self._reads_late(active, emits)
+                run.emits = self._advance_positions(active)
+                late = self._reads_late(active, run.emits)
             self._finish(delivered)
             tick.attrs["late"] = int(late)
+            tick.attrs["ahead"] = int(ahead)
             with span("tick", "engine/wait"):
-                # the barrier a caller's arrivals are polled behind: until
-                # the thread knows the step's last op is done. A late tick
-                # stops there; an eager one needs its ids now: ONE
-                # `np.asarray`, and on a sampled tick the same in its two
-                # parts (the copy back enqueued first thing, as `np.asarray`
-                # alone does, 0.05-0.1 ms of the host's own work on a TPU,
-                # the device busy through it; the rest of the ids' way back
-                # after the wait)
+                # every stretch in which the thread waits for the device is
+                # in here. A late tick is HELD until it is one launch from
+                # its end (at once where it is found done; to its end, today
+                # as before, while its program's device time was never
+                # seen). An eager one needs its ids now: ONE `np.asarray`,
+                # and on a sampled tick the same in its two parts (the copy
+                # back enqueued first thing, as `np.asarray` alone does,
+                # 0.05-0.1 ms of the host's own work on a TPU, the device
+                # busy through it; the rest of the ids' way back after the
+                # wait)
                 if late:
-                    with span("tick", "engine/device_wait"):
-                        fetches[0].block_until_ready()
+                    target = pacer.hold_until(run)
+                    if target is None:
+                        with span("tick", "engine/device_wait"):
+                            pacer.wait_for(run)
+                    else:
+                        with span("tick", "engine/hold") as hold:
+                            early = pacer.until(run.fetch, target)
+                            if early:
+                                pacer.done(run, pacer.clock())
+                            hold.attrs["early"] = int(early)
+                    pacer.left()        # the lead runs from here
                 elif (self.n_ticks % self.WAIT_SPLIT_EVERY == 0
                         and _tracing.enabled()):
                     with span("tick", "engine/device_wait"):
                         fetches[0].copy_to_host_async()
                         fetches[0].block_until_ready()
+                        pacer.done(run, pacer.clock())
                     with span("tick", "engine/copy_back"):
-                        ids = np.asarray(fetches[0])
+                        ids = pacer.read(fetches[0])
                 else:
                     ids = np.asarray(fetches[0])
+                    pacer.done(run, pacer.clock())
             if not late:
                 self._note_tick_counts(tick, ids)
         with span("tick", "engine/commit"):
-            self._m_dispatch.observe(td - t0)
+            self._m_dispatch.observe(run.launched_at - t0)
             self._m_tick_latency.observe(time.perf_counter() - t0)
             self._m_ticks.inc()
             self.n_ticks += 1
@@ -972,10 +1166,10 @@ class ContinuousBatchingEngine:
             self.total_slot_ticks += self.n_slots
             if late:
                 self.late_reads += 1
-                self._uncommitted = (tick, fetches[0], emits)
+                self._uncommitted = run
                 finished = []
             else:
-                finished = self._commit_ids(emits, ids)
+                finished = self._commit_ids(run.emits, ids)
         self._finish(finished)
         return delivered + finished
 
@@ -1151,11 +1345,13 @@ class ContinuousBatchingEngine:
                 self.tokens_out / max(self.target_forwards, 1)),
             "speculative": (self.spec.stats()
                             if self.spec is not None else None),
-            # per bound step, the host arrays one launch hands over; and
-            # the ticks whose ids were read a launch late
+            # per bound step, the host arrays one launch hands over; the
+            # ticks whose ids were read a launch late; and those launched
+            # while the tick before was still on the device
             "dispatch": {**{name: {"host_args": step.host_args}
                             for name, step in self._bound_steps.items()},
-                         "late_reads": self.late_reads},
+                         "late_reads": self.late_reads,
+                         "run_ahead": self.run_ahead},
         }
 
 

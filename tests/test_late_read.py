@@ -1,6 +1,9 @@
 """A tick's ids read a launch late (ISSUE 44, serving/engine.py `_plain_tick`):
 the tick's decode rows take their token from the ids the tick before left on
-the device, and the host reads and commits those after the next launch.
+the device, and the host reads and commits those after the next launch. Since
+ISSUE 48 such a tick is not waited for either: `step()` returns while it is on
+the device, one launch's own time before its end (`_TickPacer`), and the next
+tick is launched behind it.
 
 Every claim is held against the EAGER order: the same engine, same weights,
 made to commit every tick before `step()` returns (`_late_ok` off, which the
@@ -349,3 +352,373 @@ def test_an_engine_that_needs_its_ids_between_ticks_reads_none_late(built):
     assert eng.late_reads == 0 == eng.stats()["dispatch"]["late_reads"]
     assert all(t.attrs["late"] == 0 for t in _ticks(steps))
     assert not eng._feeds["tick_from_last"].any()
+
+
+# -- the next tick launched while this one is on the device (ISSUE 48) -------
+
+
+class _Fetch:
+    """A tick's fetch for the pacer: ready from `at` on, on `clock`."""
+
+    def __init__(self, clock, at):
+        self.clock, self.at, self.blocked = clock, at, 0
+
+    def is_ready(self):
+        return self.clock.now >= self.at
+
+    def block_until_ready(self):
+        self.blocked += 1
+        self.clock.now = max(self.clock.now, self.at)
+
+
+class _Clock:
+    """An injected clock: `sleep` moves it on by what was asked and
+    `overrun`; every look at it costs `look`."""
+
+    def __init__(self, overrun=0.0, look=0.0):
+        self.now, self.overrun, self.look, self.naps = 100.0, overrun, look, []
+
+    def __call__(self):
+        self.now += self.look
+        return self.now
+
+    def sleep(self, s):
+        self.naps.append(s)
+        self.now += s + self.overrun
+
+
+def _pacer(**kw):
+    from paddle_tpu.serving.engine import _TickPacer
+    clock = _Clock(**kw)
+    pacer = _TickPacer(clock=clock, sleep=clock.sleep)
+    clock.naps.clear()                  # the three that took the floor
+    return pacer, clock
+
+
+def _tick(pacer, clock, program, enter, dispatch, device, free=None):
+    """One step on the injected clock: entered at `enter`, the launch returns
+    `dispatch` later, the device takes the tick up then (or at `free`, when
+    the tick before ends) and is done `device` later -> the launch."""
+    clock.now = enter
+    pacer.entered()
+    clock.now = enter + dispatch
+    run = pacer.launched(None, None, program)
+    start = run.launched_at if free is None else max(free, run.launched_at)
+    run.fetch = _Fetch(clock, start + device)
+    return run
+
+
+def test_the_hold_ends_one_lead_before_the_device_is_done():
+    """target = (the later of the tick before done and the launch's return)
+    + the program's device time - (own stretch + caller's gap)."""
+    pacer, clock = _pacer()
+    # two ticks waited out: device 8, dispatch 1, the caller 0.25 between
+    a = _tick(pacer, clock, "main", 0.0, 1.0, 8.0)
+    assert pacer.hold_until(a) is None           # never seen: waited out
+    pacer.wait_for(a)
+    assert a.fetch.blocked == 1 and clock.now == 9.0
+    assert list(pacer.device_s["main"]) == [8.0] and pacer.free_at == 9.0
+    pacer.left()
+    b = _tick(pacer, clock, "main", 9.25, 1.0, 8.0)
+    assert pacer.own.mean == 1.0 and pacer.gap.mean == 0.25
+    assert pacer.lead_s() == 1.25
+    # launched on an idle device: from the launch's return
+    assert pacer.hold_until(b) == 10.25 + 8.0 - 1.25
+    assert pacer.until(b.fetch, pacer.hold_until(b)) is False
+    assert clock.now == 17.0 and clock.naps[0] == pytest.approx(6.75)
+    assert sum(clock.naps) == pytest.approx(6.75)
+    pacer.left()
+    # the next launch returns as the device comes free (17 + 0.25 + 1.0),
+    # behind a tick that is still running: from that tick's end
+    c = _tick(pacer, clock, "main", 17.25, 1.0, 8.0, free=18.25)
+    pacer.wait_for(b)
+    assert pacer.free_at == 18.25 and list(pacer.device_s["main"]) == [8.0] * 2
+    assert pacer.hold_until(c) == 18.25 + 8.0 - 1.25
+    # a wait on a tick already seen done is no wait
+    blocked = b.fetch.blocked
+    pacer.wait_for(b)
+    assert b.fetch.blocked == blocked
+
+
+def test_the_hold_ends_at_once_on_a_tick_found_done():
+    pacer, clock = _pacer(look=0.001)
+    a = _tick(pacer, clock, "main", 0.0, 1.0, 8.0)
+    pacer.wait_for(a)
+    pacer.left()
+    # the tick takes 2 where 8 were seen last: found ready before the target
+    b = _tick(pacer, clock, "main", 9.5, 1.0, 2.0)
+    clock.now = 13.0
+    target = pacer.hold_until(b)
+    assert target > 13.0
+    assert pacer.until(b.fetch, target) is True
+    assert clock.naps == [] and clock.now < 13.01
+    # ... and past its target a tick is not looked at for long either
+    c = _tick(pacer, clock, "main", 14.0, 1.0, 50.0)
+    clock.now = 40.0
+    assert pacer.until(c.fetch, 30.0) is False and clock.naps == []
+
+
+def test_decode_and_mixed_ticks_are_kept_apart():
+    pacer, clock = _pacer()
+    t = 0.0
+    for program, device in (("main", 1.5), ("mixed", 3.8), ("main", 1.5)):
+        run = _tick(pacer, clock, program, t, 0.5, device)
+        pacer.wait_for(run)
+        pacer.left()
+        t = clock.now + 0.1
+    assert sorted(pacer.device_s) == ["main", "mixed"]
+    assert list(pacer.device_s["main"]) == pytest.approx([1.5, 1.5])
+    assert list(pacer.device_s["mixed"]) == pytest.approx([3.8])
+    lead = pacer.lead_s()
+    for program, device in (("mixed", 3.8), ("main", 1.5)):
+        run = _tick(pacer, clock, program, t, 0.5, device)
+        assert pacer.hold_until(run) == pytest.approx(t + 0.5 + device - lead)
+        pacer.wait_for(run)
+        t = clock.now + 0.1
+
+
+def test_one_tick_seen_across_a_stall_does_not_lengthen_the_hold():
+    """The hold goes by the least of the last three device times seen: a
+    tick seen done late (the host stood) would hold the next one past its
+    end, the chip idle, and be seen long again."""
+    pacer, clock = _pacer()
+    t = 0.0
+    for device in (2.0, 2.0, 30.0):             # the third across a stall
+        run = _tick(pacer, clock, "main", t, 0.5, device)
+        pacer.wait_for(run)
+        pacer.left()
+        t = clock.now + 0.1
+    run = _tick(pacer, clock, "main", t, 0.5, 2.0)
+    assert pacer.hold_until(run) == pytest.approx(t + 0.5 + 2.0
+                                                  - pacer.lead_s())
+    # ... and a tick that really got longer is believed after three
+    for device in (5.0, 5.0, 5.0, 5.0):
+        pacer.wait_for(run)
+        pacer.left()
+        t = clock.now + 0.1
+        run = _tick(pacer, clock, "main", t, 0.5, device)
+    assert pacer.hold_until(run) == pytest.approx(t + 0.5 + 5.0
+                                                  - pacer.lead_s())
+
+
+def test_a_compile_inside_a_launch_does_not_stay_in_the_lead():
+    from paddle_tpu.serving.engine import _Running
+    r = _Running()
+    r.add(5000.0)                       # the first launch compiled
+    r.add(1.0)
+    assert r.mean == 1.0                # far under what was believed
+    for _ in range(8):
+        r.add(1.0)
+    r.add(4000.0)                       # the second program compiles
+    assert r.mean == pytest.approx(1.125) and r.dev == pytest.approx(0.125)
+    r.add(0.9)
+    assert 1.0 < r.mean < 1.125         # a reading nearby moves it an eighth
+
+
+def test_seen_done_is_taken_back_by_the_ids_way_back():
+    """The host sees a tick done later than the device was: by the copy back
+    where ONE `np.asarray` read it, timed on the ticks where wait and copy
+    back are apart."""
+    pacer, clock = _pacer()
+    for _ in range(3):
+        pacer.copy_back.add(0.4)
+    a = _tick(pacer, clock, "mixed", 0.0, 1.0, 4.0)
+    pacer.done(a, 5.4)                  # the `np.asarray` returned at 5.4
+    assert a.done_at == pytest.approx(5.0) == pacer.free_at
+    assert list(pacer.device_s["mixed"]) == pytest.approx([4.0])
+
+
+def test_a_sleep_is_cut_by_twice_what_sleeps_overran():
+    """The hold sleeps once, cut short by twice what its sleeps overran, and
+    spins from there: no sleep ends past the target, and a stretch shorter
+    than the host's shortest sleep and that cut is all spin."""
+    pacer, clock = _pacer(overrun=0.3, look=0.01)
+    floor = pacer.nap_floor_s
+    assert 0.3 < floor < 0.35 and pacer.oversleep.mean == floor
+    a = _tick(pacer, clock, "main", 0.0, 1.0, 500.0)
+    clock.now = 10.0
+    assert pacer.until(a.fetch, 20.0) is False
+    assert clock.naps == [pytest.approx(10 - 0.01 - 2 * floor)]
+    assert 20.0 <= clock.now < 20.02
+    assert pacer.oversleep.mean == pytest.approx(0.31, abs=0.02)
+    # what is left is under the cut and the floor: no sleep at all
+    clock.now, clock.naps = 50.0, []
+    assert pacer.until(a.fetch, 50.9) is False
+    assert clock.naps == [] and 50.9 <= clock.now < 50.92
+    # a sleep across a stall of the host teaches little
+    clock.now, clock.naps, clock.overrun = 60.0, [], 100.0
+    assert pacer.until(a.fetch, 70.0) is False
+    assert len(clock.naps) == 1 and pacer.oversleep.mean < 0.4
+
+
+@pytest.mark.parametrize("kind", ["classic", "slot", "classic_one_token"])
+def test_one_tick_at_most_is_unread_and_none_on_an_idle_engine(kind):
+    """When `step()` returns at most ONE launched tick is unread, the tick
+    before it is read (never is a tick launched behind one that has not
+    started), and an arrival submitted between two steps rides the very next
+    launch."""
+    eng = KINDS[kind]()()
+    load = _load(eng._builder_dims["vocab"])
+    launches, read_at_launch = [], []
+    launch = eng._launch_tick
+
+    def counted():
+        # at a launch: the tick before may be on the device, the one before
+        # THAT was read and committed by the step that launched it
+        read_at_launch.append(
+            (len(launches), None if eng._uncommitted is None
+             else eng._uncommitted.fetch))
+        fetches = launch()
+        launches.append(fetches[0])
+        return fetches
+    eng._launch_tick = counted
+    reqs, k, seen_unread = [], 0, 0
+    while len(reqs) < len(load) or eng.n_active or eng.n_pending:
+        for at, prompt, max_new in load[len(reqs):]:
+            if at > k:
+                break
+            reqs.append(eng.submit(prompt, max_new))
+        n_before = len(launches)
+        eng.step()
+        k += 1
+        assert len(launches) == n_before + 1
+        # six slots for six requests: whoever arrived is in THIS launch
+        assert eng.n_pending == 0 and all(r.slot is not None or r.done
+                                          for r in reqs)
+        run = eng._uncommitted
+        if run is not None:
+            seen_unread += 1
+            assert run.fetch is launches[-1]     # the newest, and no other
+            assert eng.n_active > 0
+        else:
+            assert all(f.is_ready() for f in launches)
+        # every tick but the newest was read before `step()` returned
+        assert all(f.is_ready() for f in launches[:-1])
+    assert seen_unread > 0 and eng._uncommitted is None and eng.n_active == 0
+    for n, fetch in read_at_launch:
+        if fetch is not None:
+            assert fetch is launches[n - 1]      # only the tick before
+    assert all(r.done and len(r.tokens) == n for r, (_, _, n)
+               in zip(reqs, load))
+    assert eng.run_until_idle() == []
+
+
+def test_an_arrival_between_two_steps_rides_the_next_launch():
+    eng = KINDS["classic"]()()
+    load = _load(50)
+    first = eng.submit(load[0][1], 12)
+    while eng._uncommitted is None:
+        eng.step()
+    assert not first.done                        # a tick is on the device
+    late = eng.submit(load[1][1], 4)
+    ticks = eng.n_ticks
+    mark = tracing.mark()
+    eng.step()
+    tick, = [s for s in tracing.spans_since(mark) if s.name == "engine/tick"]
+    assert late.request_id in tick.attrs["request_ids"]
+    assert late.admitted_tick == ticks and late.slot is not None
+    eng.run_until_idle()
+    assert late.done and first.done
+
+
+def test_the_counter_is_the_spans_and_a_tick_runs_ahead_only_behind_a_late_one(
+        pair):
+    _, _, _, (late, _, steps), (eager, _, esteps) = pair
+    ticks = _ticks(steps)
+    ahead = [t.attrs["ahead"] for t in ticks]
+    lates = [t.attrs["late"] for t in ticks]
+    assert late.stats()["dispatch"]["run_ahead"] >= sum(ahead)
+    assert all(a <= before for a, before in zip(ahead, [0] + lates))
+    assert eager.stats()["dispatch"]["run_ahead"] == 0
+    assert [t.attrs["ahead"] for t in _ticks(esteps)] == [0] * len(esteps)
+    # every wait for the device is inside `engine/wait`, and so is the hold
+    for spans, _ in steps:
+        waits = [s for s in spans if s.name == "engine/wait"]
+        for s in spans:
+            if s.name in ("engine/device_wait", "engine/hold",
+                          "engine/copy_back"):
+                assert any(w.start <= s.start and s.end <= w.end
+                           for w in waits)
+    holds = [s for spans, _ in steps for s in spans
+             if s.name == "engine/hold"]
+    assert holds and all(s.attrs["early"] in (0, 1) for s in holds)
+
+
+def test_a_tick_that_is_still_running_is_waited_for_after_the_next_launch():
+    """Run-ahead proper, on an injected clock (the device made slow: a tick
+    is done 50 ms after the later of its launch and the tick before's end,
+    a launch costs the host 2-3 ms): the hold ends while
+    the tick runs, the next launch is queued behind it (`ahead` 1), its wait
+    comes after that launch, tokens as in the eager order."""
+    make = KINDS["classic"]()
+    eng, ref = make(), make()
+    ref._late_ok = False
+    load = _load(50)[:3]
+    want = [ref.submit(p, n) for _, p, n in load]
+    ref.run_until_idle()
+    clock = _Clock(look=1e-4)
+    eng._pacer = type(eng._pacer)(clock=clock, sleep=clock.sleep)
+    free, order = [clock.now], []
+
+    class Slow(_Fetch):
+        def __init__(self, fetch):
+            free[0] = max(free[0], clock.now) + 0.05
+            super().__init__(clock, free[0])
+            self.fetch = fetch
+
+        def block_until_ready(self):
+            order.append(("waited", self))
+            super().block_until_ready()
+
+        def copy_to_host_async(self):
+            pass
+
+        def __array__(self, *a, **kw):
+            super().block_until_ready()
+            return np.asarray(self.fetch)
+
+    launch = eng._launch_tick
+
+    def slow_launch():
+        # the launch costs the host 2 or 3 ms in turn: a lead, and a
+        # deviation for the lead's margin
+        clock.now += 0.002 + 0.001 * (eng.target_forwards % 2)
+        fetches = [Slow(f) for f in launch()]
+        order.append(("launched", fetches[0]))
+        return fetches
+    eng._launch_tick = slow_launch
+    reqs = [eng.submit(p, n) for _, p, n in load]
+    mark = tracing.mark()
+    eng.run_until_idle()
+    assert [r.tokens for r in reqs] == [r.tokens for r in want]
+    spans = tracing.spans_since(mark)
+    ticks = [s for s in spans if s.name == "engine/tick"]
+    ahead = [t.attrs["ahead"] for t in ticks]
+    lates = [t.attrs["late"] for t in ticks]
+    assert eng.run_ahead == sum(ahead) == \
+        eng.stats()["dispatch"]["run_ahead"]
+    # a tick runs ahead only behind a held one, which it found running; a
+    # tick waited out (the first late tick of a program) is found done
+    holds = [s for s in spans if s.name == "engine/hold"]
+    assert 2 < sum(ahead) <= len(holds) <= sum(lates) == eng.late_reads
+    assert not any(s.attrs["early"] for s in holds)
+    # the tick a launch was queued behind is waited for after that launch
+    launched = [f for what, f in order if what == "launched"]
+    for k in range(1, len(launched)):
+        if ahead[k]:
+            assert order.index(("launched", launched[k])) \
+                < order.index(("waited", launched[k - 1]))
+
+
+@pytest.mark.parametrize("kind", ["classic", "slot"])
+def test_fail_all_with_a_tick_on_the_device(kind):
+    eng = KINDS[kind]()()
+    reqs = [eng.submit(p, n) for _, p, n in _load(50)[:2]]
+    while eng._uncommitted is None:
+        eng.step()
+    boom = RuntimeError("device lost")
+    assert sorted(eng.fail_all(boom), key=lambda r: r.rid) == reqs
+    assert eng._uncommitted is None and eng.n_active == 0 == eng.n_pending
+    assert all(r.done and r.error is boom for r in reqs)
+    with pytest.raises(RuntimeError):
+        eng.submit([1, 2], 2)

@@ -573,7 +573,7 @@ def phase_window_read(n_slots=32, n_blocks=512, block_size=64, num_heads=64,
 
 
 def phase_train_resnet50(batch=256, steps=2, depth=50, image=224):
-    """bench.py's training graph: ResNet-50 NHWC bf16, uint8 staging
+    """The image training graph: ResNet-50 NHWC bf16, uint8 staging
     declared (and fed), Momentum."""
     import paddle_tpu as pt
     from paddle_tpu import models

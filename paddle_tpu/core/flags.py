@@ -104,15 +104,6 @@ define_bool("benchmark", False,
 define_int("vlog", 0, "Verbose logging level (≙ glog VLOG).")
 define_bool("use_bf16_matmul", True,
             "Prefer bfloat16 MXU matmul precision where layers opt in.")
-define_bool("conv1x1_mixed_vjp", False,
-            "Lower the backward of 1x1 stride-1 NHWC convs with a "
-            "mixed-emitter custom_vjp (dgrad as one dot_general, wgrad "
-            "on the conv emitter). Wins 1.52x on the ISOLATED fwd+bwd "
-            "unit but LOSES 1.43x inside the full flagship step (+30 GB "
-            "traffic: the [BHW,C] reshapes force layout copies of every "
-            "1x1 activation and break BN-backward fusion) - default OFF; "
-            "kept as the committed falsification probe "
-            "(PROBE_DGRAD_r05.json, tools/ab_conv1x1.py).")
 define_bool("disable_pallas", False,
             "Force XLA-composite lowerings for ops that default to Pallas "
             "kernels on TPU (escape hatch: PTPU_DISABLE_PALLAS=1).")

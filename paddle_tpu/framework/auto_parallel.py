@@ -35,7 +35,7 @@ restore reproduces bit-identically across retries. An optional
 measured refinement (`measure_fn`/`measure_k`) re-ranks the top of the
 predicted frontier by real step time — the TVM move for meshes whose
 constants differ from the v5e model (the CPU bench mesh above all);
-`tools/bench_plan.py` uses it, the executor path stays model-only.
+no caller in the tree passes one; the executor path stays model-only.
 """
 
 from __future__ import annotations
@@ -195,8 +195,8 @@ class SearchSpace:
     memory_plan: Tuple[bool, ...] = (False, True)
     # '' only by default: the HBM budget this container's planner prices
     # against is the v5e constant, and offloading optimizer state is a
-    # capacity lever the operator pulls (bench_plan / lint --strategy
-    # pass offload_modes=("", "optimizer") to search it); the annealer
+    # capacity lever the operator pulls (a caller passes
+    # offload_modes=("", "optimizer") to search it); the annealer
     # reaches it in one move once it is in the space.
     offload_modes: Tuple[str, ...] = ("",)
     max_pp: int = 8
@@ -209,8 +209,8 @@ def numerics_preserving_space(strategy_base=None) -> SearchSpace:
     to the user's own setting. int8/bf16 gradient compression changes
     the training math (r08 committed the convergence deltas: int8+EF
     max |Δloss| ~0.03), so the planner never flips it on implicitly —
-    it remains a searched knob on the tooling surfaces (bench_plan,
-    lint --strategy) where the operator asked for the full space."""
+    it remains a searched knob for a caller that builds its own
+    SearchSpace, where the operator asked for the full space."""
     quant = getattr(strategy_base, "quant_comm", "") or ""
     # offload is numerics-preserving but stays PINNED to the user's own
     # setting here too: it is a capacity/latency trade the operator

@@ -1,12 +1,12 @@
 """Analytic cost models as a first-class framework API.
 
 Five generations of probes each carried a private copy of some slice of
-this: the flop/byte roofline (tools/probe_common, r03+), the collective
+this: the flop/byte roofline (r03+), the collective
 wire-byte ring model (r08), the pipeline bubble model (r09), the static
 peak-live-bytes estimator (r10), and the tp collective model (r11). This
-module is now the ONE home: `tools/probe_common` re-exports from here (so
-the r08/r09/r11 exact-census test assertions flow through this API
-unchanged), `framework/passes.py` balances pipeline stages with it, and
+module is now the ONE home: the r08/r09/r11 exact-census test
+assertions read the census and the wire bytes from here,
+`framework/passes.py` balances pipeline stages with it, and
 `predict(program, ...)` joins every model into a single CostReport — the
 queryable substrate the auto-parallel planner (ROADMAP item 2) searches
 over and `observability/ledger.py` reconciles against measured traces.
@@ -65,7 +65,7 @@ def device_peaks(device_kind: str) -> Optional[Dict[str, float]]:
     return DEVICE_PEAKS.get(device_kind)
 
 # dtype byte widths for parsing XLA shape strings — the ONE copy shared by
-# the probes (probe_caps) and the comm-structure tests. Covers every XLA
+# the census and the comm-structure tests. Covers every XLA
 # scalar type that can appear in a typed shape (ADVICE r5 #4); an
 # unrecognized typed-shape token RAISES instead of silently counting 0
 # bytes (which would let byte-balance assertions pass/fail misleadingly
@@ -308,7 +308,7 @@ def census_wire_bytes(census: Dict[str, list], n_devices: int,
 # ---------------------------------------------------------------------------
 # analytic per-op cost model — the balancing signal for the pipeline
 # partitioner (framework/passes.py pipeline_partition_pass) and the
-# per-stage compute model of tools/probe_bubble.py. Costs are RELATIVE
+# per-stage compute model of predict()'s bubble term. Costs are RELATIVE
 # (batch dims unknown until feed time use `nominal_batch`).
 # ---------------------------------------------------------------------------
 
@@ -673,8 +673,8 @@ def speculative_expectation(gamma: int, acceptance,
         "gamma": g,
         "acceptance": a,
         "expected_tokens_per_round": expected,
-        # one target forward (the verify) per round: the amortization
-        # headline tools/bench_spec.py measures
+        # one target forward (the verify) per round: what speculation
+        # amortizes
         "tokens_per_target_forward": expected,
         "draft_ticks_per_round": g + 1,
         "draft_cost_ratio": float(draft_cost_ratio),

@@ -137,7 +137,7 @@ class PreparedStep:
     Prepare/RunPreparedContext split (executor.cc:294,321), whose whole
     point is hoisting per-run setup out of a hot serve loop; here the hot
     loop is the serving engine's decode tick, where the Python dispatch
-    path IS the measured overhang (tools/probe_gap.py `host_dispatch`).
+    path IS the measured overhang (PERF.md, `engine/dispatch`).
 
     A launch hands the compiled function ONE host array: every feed that
     comes from the host with 4-byte items on the device (int32, float32,
@@ -502,7 +502,7 @@ class Executor:
         `ptpu_mfu` gauge — predicted PER-DEVICE model flops (whole-step
         flops over the device count) over the dispatch-window wall time.
         Under donated-state backpressure successive dispatches track
-        true step time; tools/benchmark.py rows carry the
+        true step time; the benchmark's training loop reads the
         blocked-measured figure. O(1) per run."""
         from ..observability import memory as _memory
         sb = getattr(compiled, "census_state_bytes", None)

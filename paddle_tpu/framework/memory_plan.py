@@ -45,9 +45,9 @@ sanitizer, so every apply is proven race- and invariant-free):
    stage's recompute pays for its stash at the budget.
 
 `plan_report()` emits the whole decision record: the slot table, the
-predicted peak before/after, and the per-stage remat decisions —
-`tools/bench_mem.py --plan` commits the MEASURED census deltas next to it
-(BENCH_MEMPLAN_r18.json). Kill switch: PTPU_MEMORY_PLAN=0 (in the
+predicted peak before/after, and the per-stage remat decisions;
+`observability/ledger.py` `check_plan_reduction` holds the MEASURED census
+deltas against it. Kill switch: PTPU_MEMORY_PLAN=0 (in the
 executor's compile cache key). docs/static_analysis.md carries the
 scheduling rule, the coloring invariant, and the search's acceptance
 contract.
@@ -88,8 +88,8 @@ _REMAT_CANDIDATES: Tuple[Tuple[int, Optional[str]], ...] = (
 #: may fold any recompute that would cost wall-clock back into the
 #: forward, so the plan is a liveness HINT more than a recompute
 #: mandate — measured returns decay past a handful of segments (the
-#: boundary overhead and partial CSE eat them; BENCH_MEMPLAN_r18.json
-#: carries the curve), so the shallow cuts are the honest candidate set
+#: boundary overhead and partial CSE eat them: a CPU reading, r18,
+#: never repeated on a chip), so the shallow cuts are the honest candidate set
 _REMAT_CANDIDATES_CSEABLE: Tuple[Tuple[int, Optional[str]], ...] = (
     (2, None), (3, None), (4, None),
 )

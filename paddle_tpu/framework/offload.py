@@ -15,7 +15,7 @@ module is the shared substrate; three consumers ride it:
   host_tier=HostTierConfig(...))` spills cold requests' private blocks
   to the host pool and prefetches them back ahead of scheduled reads,
   so admitted concurrency at a fixed device pool-byte budget exceeds
-  the r20/r21 device-only ceiling (BENCH_OFFLOAD_r23.json).
+  the r20/r21 device-only ceiling (tests/test_offload.py).
 - **host-resident optimizer state** (`HostOptimizerState`, wired into
   `ParallelExecutor.run` behind `BuildStrategy.offload_optimizer_state`):
   ZeRO-1 accumulator shards live on host between steps and round-trip
@@ -33,8 +33,8 @@ Three deliberate disciplines, inherited from earlier rounds:
   the `host_*_bytes` watermark channels. The census cannot
   double-count what a single ledger emits.
 - exact wire census (r08/r11): `TransferStream` counts the actual
-  bytes each job moves; BENCH_OFFLOAD_r23.json asserts predicted
-  d2h/h2d bytes == these counters EXACTLY, per cell.
+  bytes each job moves; tests/test_offload.py asserts predicted
+  d2h/h2d bytes == these counters EXACTLY.
 - named-diagnostic lint (r13): `check_schedule` turns a transfer
   scheduled after its read into the error-severity
   `offload-use-before-arrival` diagnostic (`tools/lint_program.py
@@ -317,7 +317,7 @@ class TransferStream:
     three offload consumers submit to. Each job runs under an
     `offload` span (kind added to tracing.SPAN_KINDS this round) and
     lands on the exact byte census (`counters()`), which
-    BENCH_OFFLOAD_r23.json diffs against the predicted wire bytes.
+    tests/test_offload.py holds to the predicted wire bytes.
 
     The job callable runs ON THE STREAM THREAD: d2h jobs materialize
     jax arrays (`np.asarray` blocks there, overlapping the compute
@@ -510,8 +510,8 @@ class HostOptimizerState:
 
     The round-trip is bitwise (numpy staging preserves exact bytes),
     so offload-on training is loss-identical to offload-off — asserted
-    by tests/test_offload.py and the BENCH_OFFLOAD_r23.json optimizer
-    cell.
+    by tests/test_offload.py.
+
 
     CPU-mesh caveat: jit consumes every argument at dispatch, so the
     full shard is device-resident DURING the step; the streamed

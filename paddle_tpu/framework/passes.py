@@ -522,7 +522,7 @@ class QuantizeParamsPass(Pass):
 # splitting: the transpiler that cuts a program into per-device sections and
 # runs them as a microbatched pipeline). The pass cuts the single
 # vjp_region's forward segment into K contiguous stages balanced by the
-# analytic flop/byte cost model (tools/probe_common.op_cost_flops_bytes),
+# analytic flop/byte cost model (framework/costs.py op_cost_flops_bytes),
 # validates every boundary is a narrow activation cut, splices explicit
 # `pp_send`/`pp_recv` ops at the cuts (the census-able collectives — same
 # discipline as dp_grad_comm), and replaces the vjp_region with a
@@ -533,8 +533,8 @@ class QuantizeParamsPass(Pass):
 
 def _pipeline_cost_fns():
     """(op_cost_flops_bytes, op_time_cost) from framework/costs.py — the
-    ONE analytic cost model, shared with the probes (tools/probe_common
-    re-exports it) and the predict() ledger API."""
+    ONE analytic cost model, shared with the predict() ledger API (a
+    function so that costs.py is imported at the first partition)."""
     from .costs import op_cost_flops_bytes, op_time_cost
     return op_cost_flops_bytes, op_time_cost
 

@@ -1151,7 +1151,7 @@ class TpShardPass(Pass):
 
 # ---------------------------------------------------------------------------
 # analytic wire model (ring accounting, shared discipline with
-# grad_comm.analytic_wire_bytes / probe_common.collective_wire_bytes)
+# grad_comm.analytic_wire_bytes / costs.collective_wire_bytes)
 # ---------------------------------------------------------------------------
 
 
@@ -1169,8 +1169,8 @@ def tp_analytic_wire_bytes(program: Program, tp: int,
                            nominal_batch: int = 8) -> Optional[Dict]:
     """Per-device interconnect bytes per TRAIN step of the tp collectives a
     tp_shard_pass-rewritten program executes — the analytic side the HLO
-    census is asserted against (tests/test_ztp_exec.py, tools/benchmark.py
-    --tp rows). Ring accounting (probe_common.collective_wire_bytes):
+    census is asserted against (tests/test_ztp_exec.py). Ring accounting
+    (framework.costs.collective_wire_bytes):
 
       tp_allreduce (fwd psum):        2 n (tp-1)/tp
       tp_ident (BWD psum of its

@@ -1,8 +1,8 @@
 """Cost ledger: predicted-vs-measured reconciliation in one artifact.
 
 Every evidence round so far published its analytic-vs-census comparison
-through a bespoke script (bench_dp wire bytes, probe_bubble slot fits,
-bench_tp ring sums). The ledger is the common form: one row per
+through a script of its own (dp wire bytes, pipeline slot fits,
+tp ring sums). The ledger is the common form: one row per
 (model, strategy) run joining
 
   predicted:  a `framework.costs.predict()` CostReport
@@ -12,7 +12,7 @@ bench_tp ring sums). The ledger is the common form: one row per
   checks:     named predicted-vs-measured comparisons, each with the
               tolerance it was held to and whether it passed.
 
-`write()` emits the BENCH_OBS artifact; `check_*` helpers implement the
+`write()` emits the record as JSON; `check_*` helpers implement the
 two standing reconciliation disciplines — EXACT byte balance for
 collectives (r08/r11) and banded agreement for bubbles (r09).
 """
@@ -235,7 +235,7 @@ class LedgerRow:
     def check_plan_reduction(self, unplanned, *, min_reduction: float = 0.0,
                              time_band: float = 0.02) -> Dict:
         """Reconcile a memory-PLANNED cell against its unplanned twin
-        (the r18 acceptance shape — bench_mem --plan): `unplanned` is the
+        (the r18 acceptance shape): `unplanned` is the
         twin's row-shaped dict {"memory": census, "step_ms": float}.
 
         1. `plan_state_feeds_invariant`: the plan may only move TRANSIENT

@@ -35,8 +35,8 @@ This module is the measured side — the sensor layer ROADMAP items 1
   PAPERS.md).
 
 The ledger's accounting identity over all of this lives in
-`observability/ledger.py` (`check_memory_identity`); the committed
-artifact is `BENCH_MEM_r17.json` (tools/bench_mem.py).
+`observability/ledger.py` (`check_memory_identity`), held per builder
+by tests/test_memory_obs.py.
 """
 
 from __future__ import annotations

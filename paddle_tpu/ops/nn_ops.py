@@ -114,46 +114,6 @@ def _conv_dimension_numbers(data_format, ndim):
     return ("NCDHW", "OIDHW", "NCDHW")
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
-def _conv1x1_mixed(x, w, dn):
-    """1x1 stride-1 NHWC conv with a mixed-emitter backward: dgrad runs
-    as ONE dot_general (a 1x1 conv IS a matmul; the matmul emitter beats
-    the conv emitter 1.33x on it and skips its 64->128 lane padding),
-    wgrad stays on the conv emitter (which wins the huge-K skinny GEMM).
-    Measured 1.52x on the ISOLATED fwd+bwd unit of the flagship's
-    worst-traffic conv shape — but 1.43x SLOWER inside the full train
-    step (+30 GB cost-model traffic): the [BHW,C] reshapes materialize
-    layout copies of every 1x1 activation and the custom_vjp boundary
-    breaks the BN-backward fusions the conv path enjoys. Default OFF
-    (flag conv1x1_mixed_vjp); kept as the committed falsification probe
-    for PROF_r04's irreducibility claim (tools/probe_dgrad.py --exp mixed_1x1,
-    tools/ab_conv1x1.py, PROBE_DGRAD_r05.json)."""
-    return jax.lax.conv_general_dilated(
-        x, w, window_strides=(1, 1), padding=[(0, 0), (0, 0)],
-        dimension_numbers=dn)
-
-
-def _conv1x1_mixed_fwd(x, w, dn):
-    return _conv1x1_mixed(x, w, dn), (x, w)
-
-
-def _conv1x1_mixed_bwd(dn, res, dy):
-    x, w = res
-    ci, co = w.shape[2], w.shape[3]            # HWIO
-    dx = jax.lax.dot_general(
-        dy.reshape(-1, co), w.reshape(ci, co), (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32).astype(dy.dtype)
-    dx = dx.reshape(x.shape)
-    _, wgrad = jax.vjp(
-        lambda w_: jax.lax.conv_general_dilated(
-            x, w_, window_strides=(1, 1), padding=[(0, 0), (0, 0)],
-            dimension_numbers=dn), w)
-    return dx, wgrad(dy)[0]
-
-
-_conv1x1_mixed.defvjp(_conv1x1_mixed_fwd, _conv1x1_mixed_bwd)
-
-
 @register_op("conv2d")
 def _conv2d(ctx, ins, attrs):
     """≙ conv_op.cc / conv_cudnn_op.cu.cc. Filter layout is OIHW as in the
@@ -175,17 +135,10 @@ def _conv2d(ctx, ins, attrs):
     # see a f32 cotangent against bf16 operands, which lax.conv rejects. The
     # MXU accumulates bf16 convs in fp32 internally regardless; the explicit
     # astype below restores the program dtype.
-    from ..core import flags as _flags
-    if (nd == 2 and data_format == "NHWC" and groups == 1
-            and tuple(w.shape[:2]) == (1, 1) and strides == (1, 1)
-            and all(p == 0 for p in pads) and dilations == (1, 1)
-            and _flags.get_flag("conv1x1_mixed_vjp")):
-        out = _conv1x1_mixed(x, w, dn)
-    else:
-        out = jax.lax.conv_general_dilated(
-            x, w, window_strides=strides, padding=padding,
-            rhs_dilation=dilations, dimension_numbers=dn,
-            feature_group_count=groups)
+    out = jax.lax.conv_general_dilated(
+        x, w, window_strides=strides, padding=padding,
+        rhs_dilation=dilations, dimension_numbers=dn,
+        feature_group_count=groups)
     return {"Output": [out.astype(
         _matmul_out_dtype(ins["Input"][0].dtype, attrs))]}
 

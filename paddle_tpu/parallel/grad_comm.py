@@ -442,7 +442,7 @@ def analytic_wire_bytes(program: Program, dp: int) -> Optional[Dict]:
     byte balance the HLO census is asserted against
     (tests/test_zero_comm.py). Returns None for non-rewritten programs
     (SPMD mode: use spmd_allreduce_wire_bytes). Ring accounting throughout
-    (see probe_common.collective_wire_bytes)."""
+    (see framework.costs.collective_wire_bytes)."""
     if not getattr(program, "_dp_comm_applied", False):
         return None
     block0 = program.global_block()

@@ -23,7 +23,7 @@ Two layers live here:
    across microbatches; 1F1B's whole point is the bounded stash
    (≤ num_stages in-flight microbatches vs GPipe's num_microbatches).
    The schedule is a host-side table (`build_schedule`), so the measured
-   bubble census (`schedule_census`, tools/probe_bubble.py) reads the SAME
+   bubble census (`schedule_census`) reads the SAME
    tables the device executes.
 """
 
@@ -245,7 +245,7 @@ class PipelineSchedule:
         """Per-stage peak stashed-microbatch count (activation liveness):
         for stage k, the max number of microbatches whose forward input is
         held for a pending backward. This is DERIVED from the executed
-        tables, not assumed — tools/probe_bubble.py and the tests read it."""
+        tables, not assumed — the tests read it."""
         M, K = self.num_microbatches, self.num_stages
         return [self._peak_live(k, "act") for k in range(K)]
 

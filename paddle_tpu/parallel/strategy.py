@@ -107,7 +107,7 @@ class BuildStrategy:
     # Coalesce small gradients into flat transfer buckets of at most this
     # many bytes before the collective (≙ the reference's fuse_all_reduce
     # capability, build_strategy.h fuse_all_reduce_ops_). 0 disables
-    # bucketing (one collective per gradient — the probe_overlap A/B side).
+    # bucketing (one collective per gradient).
     comm_bucket_bytes: int = 4 << 20
     # --- program-level pipeline parallelism (framework/passes.py
     # pipeline_partition_pass + parallel/pipeline.py schedule engine,

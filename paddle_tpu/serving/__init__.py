@@ -17,7 +17,7 @@ package (ISSUE r20 tentpole):
   prefix-sharing radix index with copy-on-write at the divergence
   block, and `PagedKVEngine` — the engine that decodes through it
   (token-identical to the slot engine, at a fraction of the KV bytes
-  per request; `BENCH_SERVE_KV_r20.json`).
+  per request; tests/test_kv_pager.py).
 - `sanitizer`  — the shadow-state sanitizer over the paged KV stack
   (r24): with the `kv_sanitize` flag on (`PTPU_KV_SANITIZE=1`), every
   `KVPager` mirrors its block-lifetime mutations against the abstract
@@ -27,7 +27,7 @@ package (ISSUE r20 tentpole):
   (`SpecConfig`, `SpeculativeDecoder`): a quantized draft twin proposes
   γ tokens, one γ+1-wide target forward verifies, rejected paged blocks
   roll back through the pager (greedy mode token-identical to plain
-  decode; `BENCH_SPEC_r22.json`).
+  decode; tests/test_speculative.py).
 """
 
 from __future__ import annotations

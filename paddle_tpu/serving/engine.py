@@ -35,7 +35,7 @@ The pieces:
   overlap; completions landing on the same tick go out as one vectored
   send.
 
-Scheduling policies (the A/B in tools/bench_serve.py):
+Scheduling policies:
 
 - "continuous": admit whenever a slot is free — the engine's point.
 - "static": admit only when ALL slots are free (form a batch, run it to
@@ -119,8 +119,8 @@ class GenRequest:
                                                  wire; 0 without a server)
 
     The four phases partition [submitted, sent] exactly — their sum IS
-    the end-to-end latency (BENCH_REQTRACE's 5% acceptance bar is float
-    noise headroom, not slack in the definition). `request_id` threads
+    the end-to-end latency (a tolerance on that sum is float noise
+    headroom, not slack in the definition). `request_id` threads
     from EngineClient through admission, every tick's span attrs, and
     the completion frame."""
 
@@ -498,7 +498,7 @@ class ContinuousBatchingEngine:
         # (payload, scales) pairs BEFORE the step is prepared. The freed
         # f32 bytes (quant_freed_bytes) are KV headroom: at a fixed HBM
         # budget they buy extra BlockPool blocks on the paged engine
-        # (tools/bench_qserve.py measures the admitted-concurrency win).
+        # (tests/test_quant_serving.py holds the freed bytes to the count).
         # Kill switch PTPU_QUANT_PARAMS=0 serves f32 regardless of `quant`.
         enforce(quant in (None, "int8", "int4"),
                 f"quant must be None, 'int8' or 'int4', got {quant!r}",
@@ -552,21 +552,21 @@ class ContinuousBatchingEngine:
         #: the tick before was still on the device (`stats()["dispatch"]`)
         self.late_reads = 0
         self.run_ahead = 0
-        # census counters (tools/bench_serve.py occupancy evidence)
+        # census counters (`stats()`: occupancy)
         self.n_ticks = 0
         self.busy_slot_ticks = 0
         self.total_slot_ticks = 0
         self.tokens_out = 0
         #: TARGET-model forwards executed (plain ticks + verify
         #: forwards): the denominator of tokens-per-target-forward — the
-        #: speculative amortization headline (tools/bench_spec.py)
+        #: speculative amortization headline (`stats()["speculative"]`)
         self.target_forwards = 0
         self._started_at = time.time()
         #: wall time of the last executed decode tick (None before the
         #: first) — /healthz reports its age as the liveness signal
         self.last_tick_at: Optional[float] = None
         #: completed requests, newest last (bounded) — the per-request
-        #: latency decomposition record tools/bench_reqtrace.py reads
+        #: latency decomposition record (`GenRequest`'s four phases)
         self.completed_log: "deque[GenRequest]" = deque(maxlen=512)
         self._init_metrics()
         # the slot KV caches are persistable fixed-shape state: their
@@ -674,7 +674,7 @@ class ContinuousBatchingEngine:
                         self._m_tick_latency.quantile(q) or 0.0))
         # per-request latency decomposition: one labeled histogram
         # family, phase=queue_wait|prefill|decode|transport, plus the
-        # end-to-end series the phases must sum to (BENCH_REQTRACE)
+        # end-to-end series the phases must sum to
         req_buckets = (1e-4, 2.5e-4, 5e-4, 1e-3, 2.5e-3, 5e-3, 1e-2,
                        2.5e-2, 5e-2, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0,
                        10.0, 30.0)

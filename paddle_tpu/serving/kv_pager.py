@@ -45,7 +45,7 @@ category. This module replaces the per-slot rows with PAGES:
   divergence block, and the per-tick top-k log-probs from the compiled
   tick drive host-side hypothesis selection.
 
-Capacity math (the BENCH_SERVE_KV_r20 claim): at fixed pool bytes a
+Capacity math: at fixed pool bytes a
 request pins ceil(L/block_size) blocks instead of max_len tokens, so
 short/long-tail mixes admit ~max_len/L× more concurrency, and shared
 prefixes reduce the marginal request to its PRIVATE blocks only.
@@ -70,7 +70,7 @@ blocks are pinned on device — they are the highest-fanout bytes.
 Per-slot decode is independent and deterministic, so suspend/resume
 changes WHICH slots tick, never what any slot computes: two-tier
 decode is token-identical to device-only decode (asserted by
-tests/test_offload.py and BENCH_OFFLOAD_r23.json). The two-pool
+tests/test_offload.py). The two-pool
 accounting identity extends exactly: used_dev + used_host + free_dev +
 free_host == (n_blocks - 1) + host_blocks (`KVPager.check_two_tier`).
 
@@ -1610,7 +1610,7 @@ class PagedKVEngine(ContinuousBatchingEngine):
         # bytes on either tier (it has never ticked); it starts decoding
         # when a resident finishes or the rotation quantum frees blocks.
         # This is exactly where admitted concurrency beats the
-        # device-only ceiling (BENCH_OFFLOAD_r23.json).
+        # device-only ceiling.
         req.table = None
         self._ht_state[req.rid] = {"state": "waiting",
                                    "spill": None, "bufs": None,
@@ -2057,7 +2057,7 @@ class PagedKVEngine(ContinuousBatchingEngine):
         if self.host_tier is not None:
             # measured wire bytes (actual buffer sizes the stream moved)
             # next to the per-block figure the prediction side uses —
-            # BENCH_OFFLOAD_r23.json asserts they reconcile EXACTLY
+            # tests/test_offload.py asserts they reconcile EXACTLY
             s["offload"] = {
                 "d2h_bytes": self.ht_d2h_bytes,
                 "h2d_bytes": self.ht_h2d_bytes,

@@ -339,7 +339,7 @@ class KVSanitizer:
         This is the sanitizer's hottest path — once per active request
         per tick — so the `_shadowed` contextmanager and the defensive
         list copy are inlined away (the only sanitizer code where that
-        trade is worth it; see BENCH_KV_SANITIZE_r24.json)."""
+        trade is worth it)."""
         self.ops_mirrored += 1
         rec = self.model.tables.get(id(table))
         if rec is None:

@@ -3,33 +3,11 @@ benchmark/configs/axk1-ep16.json (latent attention with a padded row, YaRN
 past its original length, a leading dense layer, routed experts of which a
 share is held, a shared expert), none of its widths."""
 
-import importlib
-import importlib.util
-import os
-import sys
-
 import numpy as np
 
-
-def _benchmark_models():
-    """The repo's `benchmark.models.axk1` and its reference, loaded under a
-    name of their own: `tools/benchmark.py` is a MODULE called `benchmark`
-    that other tests put first on the path, and whichever is imported
-    first in a worker wins the name."""
-    root = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "benchmark")
-    alias = "ptpu_benchmark"
-    if alias not in sys.modules:
-        spec = importlib.util.spec_from_file_location(
-            alias, os.path.join(root, "__init__.py"),
-            submodule_search_locations=[root])
-        sys.modules[alias] = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(sys.modules[alias])
-    return (importlib.import_module(alias + ".models.axk1"),
-            importlib.import_module(alias + ".models.axk1_reference"))
-
-
-axk1, ref = _benchmark_models()
+import tiny_engines
+from benchmark.models import axk1, axk1_reference as ref  # noqa: F401
+from tiny_engines import emitted_logits, scored_engine  # noqa: F401
 
 CFG = dict(
     model="axk1", hidden_size=64, intermediate_size=96,
@@ -54,10 +32,7 @@ def cfg(**over):
 
 
 def engine(config, seed=7, **spec):
-    scope = axk1.build_weights(config, seed)
-    eng = axk1.build_engine(config, dict(ENGINE, **spec), scope)
-    params = {n: scope.get(n) for n in axk1.param_names(config)}
-    return eng, params
+    return tiny_engines.engine(axk1, ENGINE, config, seed, **spec)
 
 
 def gaps(config, params, req, pad_to=64):
@@ -69,59 +44,6 @@ def gaps(config, params, req, pad_to=64):
     ref = ref[len(req.prompt) - 1:]
     toks = req.tokens
     return (ref.max(-1) - ref[np.arange(len(toks)), toks]) / ref.std(-1)
-
-
-def _head_logits(program):
-    """The variable a tick program's head takes its argmax of."""
-    op = next(o for o in program.global_block().ops if o.type == "arg_max")
-    return program.global_block().var(op.inputs["X"][0])
-
-
-def scored_engine(**kw):
-    """A PagedKVEngine whose two ticks also fetch the head's float32 logits
-    (`last_logits` [rows, 1, vocab]: the decode rows, then in a mixed tick
-    the lanes' last rows): a test's view into the programs the engine runs,
-    where the ids alone say too little. The engine has no such option.
-    `emitted_logits` reads a tick's logits for the token that tick emitted,
-    so this engine commits every tick at once, as one that fetches top-k
-    does (tests/test_late_read.py has the late order against it)."""
-    from paddle_tpu import serving
-
-    class Scored(serving.PagedKVEngine):
-        last_logits = None
-
-        def _commits_every_tick(self):
-            return True
-
-        def _tick_fetches(self):
-            return super()._tick_fetches() + [_head_logits(self._program)]
-
-        def _mixed_fetches(self):
-            return super()._mixed_fetches() + [
-                _head_logits(self._mixed_program)]
-
-        def _launch_tick(self):
-            fetches = super()._launch_tick()
-            self.last_logits = fetches[1]
-            return fetches
-
-    return Scored(**kw)
-
-
-def emitted_logits(eng, prompt, max_new):
-    """Run one request alone through `eng` (a `scored_engine`) and
-    return (request, the float32 logits the program made for each token it
-    emitted [max_new, vocab]): the lane's last row when a chunk ended the
-    prompt, the request's decode row after."""
-    req = eng.submit(prompt, max_new)
-    rows = []
-    while not req.done:
-        before = len(req.tokens)
-        eng.step()
-        if len(req.tokens) > before:
-            row = eng.n_slots if eng._lanes else req.slot
-            rows.append(np.asarray(eng.last_logits)[row, 0])
-    return req, np.stack(rows)
 
 
 def logit_error(config, params, req, got, pad_to=64):

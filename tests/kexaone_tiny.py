@@ -6,17 +6,12 @@ experts of which a rank's share is held, a shared expert, an untied head),
 none of its widths: a window of 8 positions, blocks of 4, 16 experts of which
 a rank holds 2."""
 
-import importlib
-
 import numpy as np
 
-import axk1_tiny
-
-axk1_tiny._benchmark_models()            # registers the `ptpu_benchmark` alias
-kex = importlib.import_module("ptpu_benchmark.models.kexaone")
-ref = importlib.import_module("ptpu_benchmark.models.kexaone_reference")
-scored_engine, emitted_logits = axk1_tiny.scored_engine, \
-    axk1_tiny.emitted_logits
+import tiny_engines
+from benchmark.models import kexaone as kex  # noqa: F401
+from benchmark.models import kexaone_reference as ref  # noqa: F401
+from tiny_engines import emitted_logits, scored_engine  # noqa: F401
 
 LAYERS = ["sliding_attention", "sliding_attention", "sliding_attention",
           "full_attention", "sliding_attention"]
@@ -43,18 +38,7 @@ def cfg(**over):
 
 
 def engine(config, seed=7, scored=False, **spec):
-    scope = kex.build_weights(config, seed)
-    spec = dict(ENGINE, **spec)
-    if scored:
-        eng = scored_engine(
-            n_slots=spec["n_slots"], max_len=spec["max_len"],
-            block_size=spec["block_size"], n_blocks=spec["n_blocks"],
-            n_window_blocks=spec["n_window_blocks"], scope=scope,
-            model=kex.spec_of(config))
-    else:
-        eng = kex.build_engine(config, spec, scope)
-    params = {n: scope.get(n) for n in kex.param_names(config)}
-    return eng, params
+    return tiny_engines.engine(kex, ENGINE, config, seed, scored, **spec)
 
 
 def reference(config, params, req, pad_to=64):

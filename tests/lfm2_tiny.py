@@ -4,17 +4,11 @@ grouped-query attention with QK-norm and rotary positions, a leading dense
 layer, routed experts all held under a bias-corrected top-k, no shared expert,
 a tied head), none of its widths."""
 
-import importlib
-
 import numpy as np
 
-import axk1_tiny
-
-axk1_tiny._benchmark_models()            # registers the `ptpu_benchmark` alias
-lfm2 = importlib.import_module("ptpu_benchmark.models.lfm2")
-ref = importlib.import_module("ptpu_benchmark.models.lfm2_reference")
-scored_engine, emitted_logits = axk1_tiny.scored_engine, \
-    axk1_tiny.emitted_logits
+import tiny_engines
+from benchmark.models import lfm2, lfm2_reference as ref  # noqa: F401
+from tiny_engines import emitted_logits, scored_engine  # noqa: F401
 
 CFG = dict(
     model="lfm2", hidden_size=64, intermediate_size=96,
@@ -42,17 +36,7 @@ def cfg(**over):
 
 
 def engine(config, seed=7, scored=False, **spec):
-    scope = lfm2.build_weights(config, seed)
-    spec = dict(ENGINE, **spec)
-    if scored:
-        eng = scored_engine(
-            n_slots=spec["n_slots"], max_len=spec["max_len"],
-            block_size=spec["block_size"], n_blocks=spec["n_blocks"],
-            scope=scope, model=lfm2.spec_of(config))
-    else:
-        eng = lfm2.build_engine(config, spec, scope)
-    params = {n: scope.get(n) for n in lfm2.param_names(config)}
-    return eng, params
+    return tiny_engines.engine(lfm2, ENGINE, config, seed, scored, **spec)
 
 
 def reference(config, params, req, pad_to=64):

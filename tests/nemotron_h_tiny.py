@@ -6,17 +6,12 @@ key/value heads without positions; latent routed experts of which a share is
 held, two matrices an expert of a width that is no multiple of 256, a shared
 expert of its own width; an untied head), none of its widths."""
 
-import importlib
-
 import numpy as np
 
-import axk1_tiny
-
-axk1_tiny._benchmark_models()            # registers the `ptpu_benchmark` alias
-nemo = importlib.import_module("ptpu_benchmark.models.nemotron_h")
-ref = importlib.import_module("ptpu_benchmark.models.nemotron_h_reference")
-scored_engine, emitted_logits = axk1_tiny.scored_engine, \
-    axk1_tiny.emitted_logits
+import tiny_engines
+from benchmark.models import nemotron_h as nemo  # noqa: F401
+from benchmark.models import nemotron_h_reference as ref  # noqa: F401
+from tiny_engines import emitted_logits, scored_engine  # noqa: F401
 
 CFG = dict(
     model="nemotron_h", hidden_size=64, num_attention_heads=8,
@@ -41,18 +36,7 @@ def cfg(**over):
 
 
 def engine(config, seed=7, scored=False, **spec):
-    scope = nemo.build_weights(config, seed)
-    spec = dict(ENGINE, **spec)
-    if scored:
-        eng = scored_engine(
-            n_slots=spec["n_slots"], max_len=spec["max_len"],
-            block_size=spec["block_size"], n_blocks=spec["n_blocks"],
-            n_snapshots=spec["n_snapshots"], scope=scope,
-            model=nemo.spec_of(config))
-    else:
-        eng = nemo.build_engine(config, spec, scope)
-    params = {n: scope.get(n) for n in nemo.param_names(config)}
-    return eng, params
+    return tiny_engines.engine(nemo, ENGINE, config, seed, scored, **spec)
 
 
 def reference(config, params, req, pad_to=64):

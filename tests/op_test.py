@@ -62,12 +62,11 @@ def _numeric_grad(f: Callable[[np.ndarray], float], x: np.ndarray,
     return g
 
 
-def check_grad(op_type: str, inputs: Dict[str, Any],
-               grad_slots: Sequence[str], out_slot: str = "Out",
-               attrs=None, eps=1e-3, atol=5e-3, rtol=5e-3, seed=0,
-               reduce_fn=None):
-    """Compare jax.grad of the lowering against numeric finite differences
-    (≙ check_grad_with_place). grad_slots name the input slots to check."""
+def _grad_case(op_type: str, inputs: Dict[str, Any], slot: str, idx: int,
+               out_slot: str = "Out", attrs=None, seed=0, reduce_fn=None):
+    """-> (f, x0): the lowering's reduced output as a jax function of
+    `inputs[slot][idx]` alone (float inputs fed as float32), and that
+    input's value in float64."""
     opdef = lookup_op(op_type)
     attrs = dict(attrs or {})
     base = {k: [np.asarray(x, dtype=np.float64 if
@@ -76,31 +75,46 @@ def check_grad(op_type: str, inputs: Dict[str, Any],
                 (v if isinstance(v, list) else [v])]
             for k, v in inputs.items()}
     if reduce_fn is None:
-        reduce_fn = lambda o: jnp.sum(o)  # noqa: E731
+        reduce_fn = jnp.sum
 
+    def f(x):
+        ins = {k: [jnp.asarray(np.asarray(v, dtype=np.float32)
+                               if np.issubdtype(
+                                   np.asarray(v).dtype, np.floating)
+                               else v) for v in vs]
+               for k, vs in base.items()}
+        ins[slot] = list(ins[slot])
+        ins[slot][idx] = x
+        ctx = LowerCtx(rng_key=jax.random.PRNGKey(seed))
+        out = opdef.lower(ctx, ins, attrs)[out_slot][0]
+        return reduce_fn(out)
+
+    return f, base[slot][idx]
+
+
+def _on_host(f):
+    """`f` as `_numeric_grad` calls it: float64 host array in, float out."""
+    return lambda x: float(f(jnp.asarray(x.astype(np.float32))))
+
+
+def check_grad(op_type: str, inputs: Dict[str, Any],
+               grad_slots: Sequence[str], out_slot: str = "Out",
+               attrs=None, eps=1e-3, atol=5e-3, rtol=5e-3, seed=0,
+               reduce_fn=None):
+    """Compare jax.grad of the lowering against numeric finite differences
+    (≙ check_grad_with_place). grad_slots name the input slots to check."""
     for slot in grad_slots:
-        for idx in range(len(base[slot])):
-
-            def f_jax(x):
-                ins = {k: [jnp.asarray(np.asarray(v, dtype=np.float32)
-                                       if np.issubdtype(
-                                           np.asarray(v).dtype, np.floating)
-                                       else v) for v in vs]
-                       for k, vs in base.items()}
-                ins[slot] = list(ins[slot])
-                ins[slot][idx] = x
-                ctx = LowerCtx(rng_key=jax.random.PRNGKey(seed))
-                out = opdef.lower(ctx, ins, attrs)[out_slot][0]
-                return reduce_fn(out)
-
-            x0 = jnp.asarray(np.asarray(base[slot][idx], dtype=np.float32))
-            analytic = np.asarray(jax.grad(f_jax)(x0), dtype=np.float64)
-
-            def f_np(x):
-                return float(f_jax(jnp.asarray(x.astype(np.float32))))
-
-            numeric = _numeric_grad(
-                f_np, np.asarray(base[slot][idx], dtype=np.float64), eps)
+        n = len(inputs[slot]) if isinstance(inputs[slot], list) else 1
+        for idx in range(n):
+            f, x0 = _grad_case(op_type, inputs, slot, idx, out_slot, attrs,
+                               seed, reduce_fn)
+            analytic = np.asarray(
+                jax.grad(f)(jnp.asarray(x0.astype(np.float32))),
+                dtype=np.float64)
+            # ONE executable for the 2*N evaluations: un-jitted, a lowering
+            # with a Python loop (the recurrent ops, the CRF, CTC)
+            # dispatches every primitive of it twice an input element
+            numeric = _numeric_grad(_on_host(jax.jit(f)), x0.copy(), eps)
             np.testing.assert_allclose(
                 analytic, numeric, atol=atol, rtol=rtol,
                 err_msg=f"{op_type} grad wrt {slot}[{idx}] mismatch")

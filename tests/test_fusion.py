@@ -444,24 +444,6 @@ def test_kill_switch_disables_rewrite():
     assert apply_fusion_passes(prog) is prog   # untouched, not even cloned
 
 
-@pytest.mark.slow
-def test_bench_fusion_ab_harness_end_to_end():
-    """The A/B bench harness itself (tools/bench_fusion.py) runs both
-    sides and reports a sane record — slow-marked (excluded from tier-1)
-    because it compiles 6 programs."""
-    import os
-    import sys
-    sys.path.insert(0, os.path.join(os.path.dirname(
-        os.path.dirname(os.path.abspath(__file__))), "tools"))
-    from bench_fusion import _decode_small, ab, measure_stacked_lstm
-    r = ab("lstm_smoke", measure_stacked_lstm, batch=2, seq=4, hid=16,
-           iters=1)
-    assert r["unfused_ms"] > 0 and r["fused_ms"] > 0
-    r = ab("decode_smoke", _decode_small, batch=2, gen_len=3, beam=2,
-           iters=1)
-    assert r["unfused_ms"] > 0 and r["fused_ms"] > 0
-
-
 # ---------------------------------------------------------------------------
 # satellite: cache_write uniform-Pos contract (ADVICE r5 #3)
 # ---------------------------------------------------------------------------

@@ -6,9 +6,6 @@ wire-byte accounting model. The executor-level pipeline suite (HLO census,
 loss parity, error-feedback state) lives in tests/test_zero_comm.py.
 """
 
-import os
-import sys
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -17,12 +14,10 @@ from jax.sharding import PartitionSpec as P
 
 import paddle_tpu as pt
 from paddle_tpu.core.enforce import InvalidArgumentError
+from paddle_tpu.framework.costs import (collective_census,
+                                        collective_wire_bytes)
 from paddle_tpu.parallel import collective as C
 from paddle_tpu.parallel.mesh import DeviceMesh
-
-sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "tools"))
-from probe_common import collective_census, collective_wire_bytes  # noqa: E402
 
 
 def _np_quantize_blocks(flat, block):

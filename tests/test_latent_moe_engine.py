@@ -9,6 +9,7 @@ import numpy as np
 import pytest
 
 import axk1_tiny as T
+import tiny_engines
 from axk1_tiny import axk1
 from paddle_tpu import serving
 from paddle_tpu.core import flags
@@ -26,7 +27,7 @@ def exact():
     old = flags.get_flag("use_bf16_matmul")
     flags.set_flag("use_bf16_matmul", False)
     cfg = T.cfg(**F32)
-    scope = axk1.build_weights(cfg, 7)
+    scope = tiny_engines.weights(axk1, cfg, 7)
     eng = T.scored_engine(
         n_slots=4, max_len=64, block_size=8, n_blocks=40, scope=scope,
         model=axk1.spec_of(cfg))

@@ -17,6 +17,7 @@ import numpy as np
 import pytest
 
 import lfm2_tiny as T
+import tiny_engines
 from lfm2_tiny import lfm2, ref
 from paddle_tpu import serving
 from paddle_tpu.core import flags
@@ -101,7 +102,7 @@ def test_the_tolerance_catches_a_fault_planted_in_the_program(
     """The program built without the RMSNorm on q and k, or with a head of
     its own in place of the embedding."""
     cfg = exact_matmuls
-    scope = lfm2.build_weights(cfg, 7)
+    scope = tiny_engines.weights(lfm2, cfg, 7)
     spec = dataclasses.replace(lfm2.spec_of(cfg), **{fault: False})
     eng = T.scored_engine(n_slots=4, max_len=64, block_size=8, n_blocks=40,
                           scope=scope, model=spec)
@@ -152,6 +153,16 @@ def _committed(kind, name):
         return json.load(f)
 
 
+def _cell_cfg():
+    """The tiny configuration at twelve layers with what the committed one
+    gives the cell's comparison. ONE dict for every test that serves it: the
+    weights are kept per configuration (tests/tiny_engines.py), and a key
+    more makes another."""
+    config = _committed("configs", "lfm2-8b-a1b")
+    return T.cfg(**{k: config[k] for k in (
+        "router_tie_margin", "check_rows_held", "check_echo")}, **T.DEEP)
+
+
 @pytest.mark.parametrize("seed", [4, 7])
 def test_bfloat16_engine_passes_the_cells_comparison_and_the_control_fails(
         seed):
@@ -160,9 +171,7 @@ def test_bfloat16_engine_passes_the_cells_comparison_and_the_control_fails(
     limit and the configuration's own `router_tie_margin`; the reference
     computed one precision below is refused by the same limit."""
     tol = _committed("cells", "lfm2-8b-a1b_serve_assistant")["logit_gap_tol"]
-    config = _committed("configs", "lfm2-8b-a1b")
-    cfg = T.cfg(**{k: config[k] for k in (
-        "router_tie_margin", "check_rows_held", "check_echo")}, **T.DEEP)
+    cfg = _cell_cfg()
     eng, params = T.engine(cfg, seed)
     rng = np.random.default_rng(seed)
     head = rng.integers(0, 97, 24).tolist()
@@ -214,10 +223,9 @@ def test_the_witness_at_the_stated_precision_reads_like_the_program():
     (`at_stated_precision`), teacher-forced on the program's tokens: its
     choices lie as far from the float32 rows as the program's do, under the
     cell's limit, and the control does not."""
-    config = _committed("configs", "lfm2-8b-a1b")
     tol = _committed("cells", "lfm2-8b-a1b_serve_assistant")["logit_gap_tol"]
-    held, echo = config["check_rows_held"], config["check_echo"]
-    cfg = T.cfg(router_tie_margin=config["router_tie_margin"], **T.DEEP)
+    cfg = _cell_cfg()        # `envelope_logits` reads neither check key
+    held, echo = cfg["check_rows_held"], cfg["check_echo"]
     eng, params = T.engine(cfg, 4)
     rng = np.random.default_rng(4)
     head = rng.integers(0, 97, 24).tolist()

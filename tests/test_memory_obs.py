@@ -7,9 +7,7 @@
 - costs.memory_categories per-device predictions vs hand-computed bytes;
 - the LEDGER ACCOUNTING IDENTITY (check_memory_identity) on a builder
   sweep across parallel configs — per-category bytes EXACT, the category
-  walk re-deriving XLA's argument figure, unattributed residual bounded
-  (the full r17 cell matrix incl. pp/tp/ef is committed by
-  tools/bench_mem.py as BENCH_MEM_r17.json);
+  walk re-deriving XLA's argument figure, unattributed residual bounded;
 - one mutation test per identity discipline: an inflated predicted
   category is caught BY NAME in the residual buckets;
 - the tracing overhead budget (<= 3% on / <= 0.5% off) re-asserted with
@@ -301,10 +299,8 @@ def _run_cell(rng, mode, batch=16):
 
 
 class TestMemoryLedgerIdentity:
-    """The per-builder identity sweep. The full r17 matrix — incl.
-    dp2xpp2, tp2, and the quantized+error-feedback cell — is committed
-    by tools/bench_mem.py (BENCH_MEM_r17.json); this sweep keeps the
-    tier-1 cells cheap."""
+    """The per-builder identity sweep, at cells cheap enough for
+    tier-1 (dp2xpp2, tp2 and quantized+error-feedback are not swept)."""
 
     @pytest.mark.parametrize("mode", ["plain", "dp2", "pp2"])
     def test_identity_holds_mnist(self, rng, mode):

@@ -2,8 +2,7 @@
 
 - span nesting / kind typing / attribution + the PTPU_TRACE kill switch
   and the tracing overhead budget (<= 3% of step time enabled, <= 0.5%
-  disabled — the ISSUE 7 acceptance bar, also committed in
-  BENCH_OBS_r12.json);
+  disabled — the ISSUE 7 acceptance bar);
 - metrics registry semantics (counter/gauge/histogram) + a Prometheus
   text-format golden + the EngineServer /metrics endpoint smoked through
   EngineClient traffic;
@@ -311,8 +310,7 @@ class TestOverheadBudget:
     """ISSUE 7 acceptance: tracing overhead <= 3% of step time with
     PTPU_TRACE=1 and <= 0.5% with it off. Overhead = measured per-span
     enter/exit cost x spans recorded per step, against the measured step
-    time of the mnist mlp — the same arithmetic BENCH_OBS_r12.json
-    commits (a direct wall-clock A/B on a 2-core CI box is noise-bound;
+    time of the mnist mlp (a direct wall-clock A/B on a 2-core CI box is noise-bound;
     the per-span microbench is stable)."""
 
     def _step_time_and_spans(self, rng):
@@ -1582,18 +1580,6 @@ def _compiled_hlo(exe, feed):
 
 
 class TestCosts:
-    def test_probe_common_reexports_framework_costs(self):
-        """The r08/r09/r11 census tests import collective_census &co from
-        tools/probe_common — those names must BE the framework.costs
-        objects now (one model, rewired imports)."""
-        import probe_common
-        from paddle_tpu.framework import costs
-        assert probe_common.collective_census is costs.collective_census
-        assert probe_common.census_wire_bytes is costs.census_wire_bytes
-        assert probe_common.hlo_shape_bytes is costs.hlo_shape_bytes
-        assert probe_common.op_cost_flops_bytes is costs.op_cost_flops_bytes
-        assert probe_common.HLO_ITEM_BYTES is costs.HLO_ITEM_BYTES
-
     def test_program_flops_bytes_sums_ops(self):
         from paddle_tpu.framework import costs
         x = layers.data("x", shape=[64])
@@ -1681,7 +1667,7 @@ class TestCosts:
         assert not row.check_bubble_fraction(0.5, band=0.02)["ok"]
 
     def test_ledger_wire_bytes_exact_dp2xpp2(self, rng):
-        """The BENCH_OBS dp2 x pp2 discipline in-suite: once-per-step
+        """The dp2 x pp2 discipline: once-per-step
         wire bytes (dp reduce-scatter/all-gather + the region's pp grad
         psum) == census exactly, and the boundary permutes reconcile
         structurally (exactly 2 at the predicted buffer bytes)."""

@@ -362,46 +362,3 @@ class TestUnitCellsAndMisc:
         np.testing.assert_allclose(out[:, 3:6],
                                    x[:, :, :4, :4].max(axis=(2, 3)),
                                    rtol=1e-6)
-
-
-class TestConv1x1MixedVjp:
-    """The mixed-emitter 1x1 conv backward (dgrad as dot_general, wgrad on
-    the conv emitter — ops/nn_ops.py _conv1x1_mixed, PROBE_DGRAD_r05) must
-    be numerically invisible: training with the flag on and off produces
-    identical trajectories."""
-
-    def _train(self, flag, rng):
-        import paddle_tpu as pt
-        from paddle_tpu import layers
-        from paddle_tpu.core import flags as _flags
-
-        pt.reset_default_programs()
-        pt.reset_global_scope()
-        old = _flags.get_flag("conv1x1_mixed_vjp")
-        _flags._REGISTRY["conv1x1_mixed_vjp"].value = flag
-        try:
-            with pt.core.unique_name.guard():
-                img = layers.data("img", shape=[8, 8, 16])
-                y = layers.conv2d(img, num_filters=32, filter_size=1,
-                                  data_format="NHWC", name="cm1")
-                y = layers.conv2d(y, num_filters=16, filter_size=3,
-                                  padding=1, data_format="NHWC", name="cm2")
-                loss = layers.reduce_mean(layers.square(y))
-                pt.optimizer.MomentumOptimizer(
-                    learning_rate=0.1, momentum=0.9).minimize(loss)
-            exe = pt.Executor()
-            exe.run(pt.default_startup_program())
-            feed = {"img": rng.rand(4, 8, 8, 16).astype("float32")}
-            losses = [float(exe.run(feed=feed, fetch_list=[loss])[0])
-                      for _ in range(4)]
-            w = np.asarray(pt.global_scope().get("cm1.w_0")).copy()
-            return losses, w
-        finally:
-            _flags._REGISTRY["conv1x1_mixed_vjp"].value = old
-
-    def test_training_trajectory_identical(self):
-        l1, w1 = self._train(True, np.random.RandomState(0))
-        l2, w2 = self._train(False, np.random.RandomState(0))
-        np.testing.assert_allclose(l1, l2, rtol=1e-6)
-        np.testing.assert_allclose(w1, w2, rtol=1e-5, atol=1e-7)
-        assert l1[-1] < l1[0]  # and it actually trains

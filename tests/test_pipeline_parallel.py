@@ -11,9 +11,6 @@ and the schedule census read from the SAME tick tables the device executes
 stashed-activation count strictly below GPipe's at M >= 2*stages.
 """
 
-import os
-import sys
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -23,16 +20,13 @@ import paddle_tpu as pt
 from paddle_tpu import layers
 from paddle_tpu.core import flags
 from paddle_tpu.core.enforce import InvalidArgumentError
+from paddle_tpu.framework.costs import collective_census
 from paddle_tpu.framework.passes import get_pass
 from paddle_tpu.parallel import ParallelExecutor
 from paddle_tpu.parallel.mesh import DeviceMesh
 from paddle_tpu.parallel.pipeline import (build_schedule, pipeline_apply,
                                           schedule_census)
 from paddle_tpu.parallel.strategy import BuildStrategy, ReduceStrategy
-
-sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "tools"))
-from probe_common import collective_census  # noqa: E402
 
 
 def _build_mlp(depth=4):

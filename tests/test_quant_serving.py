@@ -227,8 +227,7 @@ class TestGreedyDecodeParity:
         UNTRAINED random model; the stated bound: every sequence matches
         f32 on its FIRST greedy token. Beyond the first divergence the
         trajectories condition on different tokens and are legitimately
-        incomparable token-wise — the bench artifact (BENCH_QSERVE)
-        quantifies the rest as max first-tick logit error."""
+        incomparable token-wise."""
         f32, _, q4, _ = quant_engines
         ref = _gen(f32, _PROMPTS)
         got = _gen(q4, _PROMPTS)

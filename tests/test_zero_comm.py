@@ -12,9 +12,7 @@ quantization only engages on the dp axis.
 whole suite; the fast unit half lives in tests/test_grad_comm.py.)
 """
 
-import os
 import re
-import sys
 
 import jax
 import jax.numpy as jnp
@@ -25,14 +23,11 @@ import paddle_tpu as pt
 from paddle_tpu import layers
 from paddle_tpu.core import flags
 from paddle_tpu.core.enforce import InvalidArgumentError
+from paddle_tpu.framework.costs import (census_wire_bytes, collective_census,
+                                        collective_wire_bytes)
 from paddle_tpu.parallel import ParallelExecutor
 from paddle_tpu.parallel.mesh import DeviceMesh
 from paddle_tpu.parallel.strategy import BuildStrategy, ReduceStrategy
-
-sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "tools"))
-from probe_common import (census_wire_bytes, collective_census,  # noqa: E402
-                          collective_wire_bytes)
 
 DP = 8
 # fc(64->128) + fc(128->10): w1/b1/w2 ride the sharded path (dim0 % 8 == 0),

@@ -7,9 +7,6 @@ after the whole suite — the same discipline as tests/test_zero_comm.py;
 the fast unit half lives in tests/test_pipeline_parallel.py.)
 """
 
-import os
-import sys
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -18,13 +15,10 @@ import pytest
 import paddle_tpu as pt
 from paddle_tpu import layers
 from paddle_tpu.core import flags
+from paddle_tpu.framework.costs import collective_census
 from paddle_tpu.parallel import ParallelExecutor
 from paddle_tpu.parallel.mesh import DeviceMesh
 from paddle_tpu.parallel.strategy import BuildStrategy, ReduceStrategy
-
-sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "tools"))
-from probe_common import collective_census  # noqa: E402
 
 from test_pipeline_parallel import (_baseline, _build_conv,  # noqa: E402
                                     _build_mlp, _compiled_hlo, _conv_feed,

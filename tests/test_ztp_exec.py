@@ -9,9 +9,6 @@ the same discipline as test_zero_comm.py / test_zpipeline_exec.py; the
 fast propagation/pass/gate unit half lives in tests/test_sharding_prop.py.)
 """
 
-import os
-import sys
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -20,14 +17,11 @@ import pytest
 import paddle_tpu as pt
 from paddle_tpu.core import flags
 from paddle_tpu.core.enforce import InvalidArgumentError
+from paddle_tpu.framework.costs import collective_census
 from paddle_tpu.framework.sharding import tp_analytic_wire_bytes
 from paddle_tpu.parallel import ParallelExecutor, annotate_tp
 from paddle_tpu.parallel.mesh import DeviceMesh
 from paddle_tpu.parallel.strategy import BuildStrategy, ReduceStrategy
-
-sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "tools"))
-from probe_common import collective_census  # noqa: E402
 
 VOCAB, T, D, HEADS, LAYERS = 64, 8, 32, 4, 2
 

@@ -1,11 +1,11 @@
 #!/bin/bash
 # CI driver (≙ reference paddle/scripts/paddle_build.sh: build + test +
-# API check + benchmark smoke). Runs on the virtual 8-device CPU mesh.
+# API check + smokes). Runs on the virtual 8-device CPU mesh.
 #
 #   tools/run_ci.sh          full tier (suite measured at ~40 min on this
 #                            2-core box single-process — budget an hour)
 #   tools/run_ci.sh quick    smoke tier (~5 min): build + API check +
-#                            `-m quick`-marked tests + bench smoke
+#                            `-m quick`-marked tests + the smokes below
 set -e
 cd "$(dirname "$0")/.."
 TIER="${1:-full}"
@@ -105,15 +105,11 @@ if [ "$TIER" = "quick" ]; then
         python -m pytest tests/ -q -x -m quick
 else
     echo "== full test pyramid (~29 min on 2 cores with -n 2; measured) =="
-    # tier-1 selection: everything but the slow-marked A/B bench smokes
+    # tier-1 selection: everything but the slow-marked tests
     PTPU_VERIFY_PASSES=1 \
     XLA_FLAGS=--xla_force_host_platform_device_count=8 JAX_PLATFORMS=cpu \
         python -m pytest tests/ -q -n 2 --dist load -m 'not slow'
 fi
-
-echo "== benchmark smoke =="
-JAX_PLATFORMS=cpu python tools/benchmark.py --model mnist --batch_size 8 \
-    --iters 3 --warmup 1
 
 echo "== dp-comm smoke (reduce-scatter + quantized collectives) =="
 # the explicit gradient pipeline end to end on the 8-virtual-device mesh:
@@ -130,9 +126,7 @@ import paddle_tpu as pt
 from paddle_tpu import layers
 from paddle_tpu.parallel import ParallelExecutor
 from paddle_tpu.parallel.strategy import BuildStrategy, ReduceStrategy
-import sys, os
-sys.path.insert(0, "tools")
-from probe_common import collective_census
+from paddle_tpu.framework.costs import collective_census
 
 for quant in ("", "int8"):
     pt.reset_default_programs(); pt.reset_global_scope()
@@ -232,9 +226,7 @@ from paddle_tpu import layers
 from paddle_tpu.parallel import ParallelExecutor
 from paddle_tpu.parallel.mesh import DeviceMesh
 from paddle_tpu.parallel.strategy import BuildStrategy
-import sys
-sys.path.insert(0, "tools")
-from probe_common import collective_census
+from paddle_tpu.framework.costs import collective_census
 
 def build():
     x = layers.data("x", shape=[32])
@@ -378,8 +370,7 @@ echo "== memory-observability smoke (census + ledger identity + MFU) =="
 # costs.predict's per-device categories under the accounting identity
 # (state/feed categories EXACT, unattributed residual <= 10% of the
 # measured peak), stamp the ptpu_memory_* watermarks + ptpu_mfu, and
-# emit memory COUNTER events into the Chrome trace export. Then the
-# BENCH_MEM artifact generator must run clean on the same cell.
+# emit memory COUNTER events into the Chrome trace export.
 XLA_FLAGS=--xla_force_host_platform_device_count=8 JAX_PLATFORMS=cpu \
 python - <<'PY'
 import json, numpy as np, jax
@@ -429,17 +420,7 @@ counters = {e["name"] for e in evs if e.get("ph") == "C"}
 assert any(n.startswith("memory/") for n in counters), counters
 print("memory-observability smoke OK:", json.dumps(rec["buckets"]))
 PY
-rm -f /tmp/ptpu_mem_trace_ci.json /tmp/bench_mem_ci.json
-XLA_FLAGS=--xla_force_host_platform_device_count=8 JAX_PLATFORMS=cpu \
-    python tools/bench_mem.py --out /tmp/bench_mem_ci.json --iters 2 \
-    --cells mnist:dp2 --skip_live
-python - <<'PY'
-import json
-doc = json.load(open("/tmp/bench_mem_ci.json"))
-assert doc["ok"] and len(doc["rows"]) == 1, doc["ok"]
-print("bench_mem smoke OK")
-PY
-rm -f /tmp/bench_mem_ci.json
+rm -f /tmp/ptpu_mem_trace_ci.json
 
 echo "== memory-plan smoke (planner + detectors + measured reduction) =="
 # the r18 static memory planner end to end (docs/static_analysis.md):
@@ -450,8 +431,7 @@ echo "== memory-plan smoke (planner + detectors + measured reduction) =="
 #     SEARCH (nothing to free on the mlp) and its census must not
 #     regress;
 # (2) the activation-heavy transformer cell: the searched remat plan's
-#     memory_census peak must land STRICTLY below the unplanned twin
-#     (the measured matrix with step-time bands is BENCH_MEMPLAN_r18.json).
+#     memory_census peak must land STRICTLY below the unplanned twin.
 XLA_FLAGS=--xla_force_host_platform_device_count=8 JAX_PLATFORMS=cpu \
     python - <<'PY'
 import numpy as np, jax
@@ -734,13 +714,6 @@ assert board["kv_cache_used_bytes"]["current"] == pool.n_used * per_block
 print("paged-serving smoke OK")
 PY
 
-echo "== bench_serve_kv smoke (slot-vs-paged capacity harness) =="
-# the r20 load harness end to end in --smoke shape: asserts decode
-# identity, pool reconciliation, and at least one capacity bar inside
-# main() (BENCH_SERVE_KV_r20.json is the committed full-shape run)
-JAX_PLATFORMS=cpu python tools/bench_serve_kv.py --smoke > /dev/null
-echo "bench_serve_kv smoke OK"
-
 echo "== quantized-serving smoke (r21: weight-only int8 + zero-dispatch tick) =="
 # quantize an mnist-scale LM tick in place: census ledger identity must
 # be EXACT (predicted params_quantized == measured, byte for byte),
@@ -814,8 +787,7 @@ echo "== speculative-decoding smoke (r22: draft propose + one-forward verify) ==
 # TOKEN-IDENTICAL to the target-only twin on shared weights (the accept
 # rule is structural), the acceptance gauge must be live on the engine
 # registry, and the block pool must reconcile with per-round checks on
-# (rollbacks included). The full harness is tools/bench_spec.py
-# (BENCH_SPEC_r22.json is the committed full-shape run).
+# (rollbacks included).
 JAX_PLATFORMS=cpu PTPU_SPEC_POOL_CHECK=1 python - <<'PY'
 import numpy as np
 import paddle_tpu as pt
@@ -853,13 +825,6 @@ print(f"speculative smoke OK (acceptance={s['acceptance_rate']:.3f}, "
       f"vs 1.0 plain)")
 PY
 
-echo "== bench_spec smoke (speculative amortization harness) =="
-# the r22 harness end to end in --smoke shape: asserts greedy identity,
-# the ≥1.5x tokens-per-target-forward bar at saturation, per-round pool
-# reconciliation, and the params_draft ledger identity inside main()
-JAX_PLATFORMS=cpu python tools/bench_spec.py --smoke > /dev/null
-echo "bench_spec smoke OK"
-
 echo "== two-tier host-offload smoke (r23: spill + prefetch + exact census) =="
 # a paged engine at a deliberately tight device pool with the host tier
 # on: decode must be TOKEN-IDENTICAL to an unconstrained-pool twin,
@@ -867,8 +832,7 @@ echo "== two-tier host-offload smoke (r23: spill + prefetch + exact census) =="
 # EXACTLY (eviction/reload counters x per-block bytes == the transfer
 # stream's measured bytes), and the two-pool accounting identity must
 # hold. The offload schedule lint must pass on the shipped prefetch
-# policy. Full harness: tools/bench_offload.py (BENCH_OFFLOAD_r23.json
-# is the committed full-shape run).
+# policy.
 JAX_PLATFORMS=cpu python - <<'PY'
 import numpy as np
 import paddle_tpu as pt
@@ -911,15 +875,6 @@ JAX_PLATFORMS=cpu python tools/lint_program.py --model mnist --offload > /dev/nu
 JAX_PLATFORMS=cpu python tools/lint_program.py \
     --model transformer_lm_paged_decode_tick --offload > /dev/null
 echo "lint --offload OK"
-
-echo "== bench_offload smoke (two-tier capacity harness) =="
-# the r23 harness end to end in --smoke shape: asserts token identity,
-# the exact per-cell wire census, the ≥1.5x admitted-concurrency bar at
-# the anchor pool, optimizer-offload loss identity, and the planner's
-# refuse/accept verdicts on the stash roofline inside main()
-JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
-    python tools/bench_offload.py --smoke > /dev/null
-echo "bench_offload smoke OK"
 
 echo "== serving ownership verifier (r24: model check + seeded mutation + lint contract) =="
 # the block-lifetime model checker must exhaustively clear the shipped

@@ -26,6 +26,18 @@ experts, a grouped matrix product with two lowerings:
 - the composite (a CPU, or asked for): the same sum in `jax.numpy`, over
   every held expert.
 
+`moe_train` (PR 50) is the layer as a TRAINING graph runs it, differentiable
+in the rows, the router and the stacks: softmax scores (`train_route`), a
+balance term (`balance_term`), and the held experts' part of the sum as a
+grouped product over the (row, expert) pairs sorted by expert
+(`train_experts`: megablox's Mosaic kernels on a TPU, `jax.lax.ragged_dot`
+elsewhere; over a pair buffer with room for an even routing's held pairs
+and a quarter more, or by `lax.cond` over the buffer of all the pairs, so no
+row is ever dropped and no count changes a shape), with counters the
+step keeps on the device. docs/fusion.md has the section. The two ops above
+stay what the serving ticks use: their kernel is the decode shape, and they
+carry no gradient.
+
 With `gate` None an expert is `down_e(relu(up_e x)^2)`: two matrices, no gate
 (the latent experts, whose x is a latent row between projections the layer
 shares: `models/transformer.py _moe_ffn`). Its kernel takes the tile of the
@@ -288,6 +300,294 @@ def experts(x, w, rows, gate, up, down, backend=None):
         return _experts_pallas(x, w, rows, gate, up, down,
                                interpret=interpret)
     return _experts_composite(x, w, gate, up, down)
+
+
+# ---------------------------------------------------------------------------
+# the routed layer as a TRAINING step runs it: a differentiable route, and a
+# grouped product over the (row, expert) pairs sorted by expert
+# ---------------------------------------------------------------------------
+
+# rows of a tile of the grouped product, and what the pair buffer is padded to
+_PAIR_TILE = 512
+# (tm, tk, tn) of megablox's kernels, by the product: the forward's two
+# (x @ [gate | up], hidden @ down), their transposes for the rows' gradients
+# and the two weight gradients (tgmm: tk tiles the ROWS). Timed on a v5e at
+# [16,384 live of 65,536, 2304] x [16, 2304, 2 x 896] (PERF.md section 6,
+# PR 50)
+_TILINGS = {"in": (512, 1152, 896), "out": (512, 896, 1152),
+            "in_t": (512, 896, 1152), "out_t": (512, 1152, 896),
+            "in_w": (512, 1152, 896), "out_w": (512, 896, 1152)}
+
+
+def train_route(x, w_router, top_k, norm_topk_prob=True, scaling=1.0):
+    """x [N, D], w_router [D, E] -> (p [N, E] softmax over ALL experts,
+    idx [N, k] the top-k, w [N, k] their weights `p / sum of the selected`
+    times `scaling`), float32 at full precision. The gradient reaches
+    `w_router` through w and through p (the balance term); the indices
+    carry none."""
+    logits = jnp.dot(x.astype(jnp.float32), w_router.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST,
+                     preferred_element_type=jnp.float32)
+    p = jax.nn.softmax(logits, axis=-1)
+    top, idx = jax.lax.top_k(p, top_k)
+    if norm_topk_prob:
+        top = top / jnp.sum(top, axis=-1, keepdims=True)
+    return p, idx, top * scaling
+
+
+def balance_term(p, idx):
+    """E * sum_e f_e P_e over ALL E experts: f_e the share of the N x k
+    assignments that chose e (no gradient), P_e the mean of p_e over the
+    rows. 1 under an even spread. Returns (term, assignments an expert
+    [E] float32)."""
+    n_routed = p.shape[-1]
+    chosen = jnp.sum(jax.nn.one_hot(idx, n_routed, dtype=jnp.float32),
+                     axis=(0, 1))
+    share = jax.lax.stop_gradient(chosen / idx.size)
+    return n_routed * jnp.sum(share * jnp.mean(p, axis=0)), chosen
+
+
+def _megablox():
+    """megablox's kernel module: the package exports a FUNCTION under the
+    module's own name (`gmm`, with one tiling for all of its derivatives), so
+    a plain import of the module gets the function."""
+    import importlib
+    return importlib.import_module(
+        "jax.experimental.pallas.ops.tpu.megablox.gmm")
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _grouped(lhs, rhs, sizes, role, transpose_rhs, interpret):
+    """lhs[rows of group e] @ rhs[e] for the groups laid one after another
+    from row 0 (`sizes` rows each; the kernel leaves the rows past their sum
+    as they lie in memory: they come back 0), by megablox's kernel under
+    `_TILINGS[role]`; lhs's dtype out, float32 sums."""
+    with jax.named_scope("moe_train_" + role):
+        out = _megablox().gmm(lhs, rhs, sizes, lhs.dtype, _TILINGS[role],
+                              transpose_rhs=transpose_rhs,
+                              interpret=interpret)
+    live = jnp.arange(lhs.shape[0])[:, None] < jnp.sum(sizes)
+    return jnp.where(live, out, jnp.zeros((), out.dtype))
+
+
+def _grouped_fwd(lhs, rhs, sizes, role, transpose_rhs, interpret):
+    return (_grouped(lhs, rhs, sizes, role, transpose_rhs, interpret),
+            (lhs, rhs, sizes))
+
+
+def _grouped_bwd(role, transpose_rhs, interpret, res, g):
+    lhs, rhs, sizes = res
+    assert not transpose_rhs
+    d_lhs = _grouped(g, rhs, sizes, role + "_t", True, interpret)
+    with jax.named_scope("moe_train_" + role + "_w"):
+        d_rhs = _megablox().tgmm(lhs.swapaxes(0, 1), g, sizes, rhs.dtype,
+                                 _TILINGS[role + "_w"], interpret=interpret)
+    return d_lhs, d_rhs, None
+
+
+_grouped.defvjp(_grouped_fwd, _grouped_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _expand(x, perm, inv, k):
+    """x [N, D] -> the rows of the sorted pairs `perm` holds, [m, D]: pair
+    `perm[i]` is row `perm[i] // k`. Its transpose is `_combine` (a gather
+    too: the derivative XLA would write is a scatter-add over the rows)."""
+    return x[jnp.minimum(perm // k, x.shape[0] - 1)]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _combine(y, perm, inv, k):
+    """The sorted pairs' rows y [m, D] -> [N, D] float32: a row's k pairs
+    summed (`inv[p]`: where pair p lies in the sorted order; a pair past the
+    m rows y has adds nothing)."""
+    m = y.shape[0]
+    rows = jnp.where((inv < m)[:, None], y[jnp.minimum(inv, m - 1)],
+                     jnp.zeros((), y.dtype)).astype(jnp.float32)
+    return jnp.sum(rows.reshape(-1, k, y.shape[-1]), axis=1)
+
+
+def _expand_fwd(x, perm, inv, k):
+    return _expand(x, perm, inv, k), (perm, inv)
+
+
+def _expand_bwd(k, res, g):
+    perm, inv = res
+    return _combine(g, perm, inv, k).astype(g.dtype), None, None
+
+
+def _combine_fwd(y, perm, inv, k):
+    # an empty array carries y's dtype: a dtype is no residual JAX takes
+    return _combine(y, perm, inv, k), (perm, inv, jnp.zeros((0,), y.dtype))
+
+
+def _combine_bwd(k, res, g):
+    perm, inv, like = res
+    return _expand(g.astype(like.dtype), perm, inv, k), None, None
+
+
+_expand.defvjp(_expand_fwd, _expand_bwd)
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def sort_pairs(idx, held, n_routed):
+    """The N x k (row, expert) pairs ordered by held expert, those that
+    landed on an expert held elsewhere last: (perm [M] sorted -> pair, inv
+    [N * k] pair -> sorted, sizes [n_held] pairs a held expert). M is N x k
+    padded to whole tiles of `_PAIR_TILE`."""
+    n_held = len(held)
+    slot = jnp.full((n_routed,), n_held, jnp.int32).at[
+        jnp.asarray(held, jnp.int32)].set(jnp.arange(n_held, dtype=jnp.int32))
+    key = slot[idx.reshape(-1)]
+    pad = -key.shape[0] % _PAIR_TILE
+    key = jnp.concatenate([key, jnp.full((pad,), n_held, jnp.int32)])
+    perm = jnp.argsort(key, stable=True).astype(jnp.int32)
+    inv = jnp.zeros_like(perm).at[perm].set(
+        jnp.arange(perm.shape[0], dtype=jnp.int32))[:idx.size]
+    sizes = jnp.sum(jax.nn.one_hot(key, n_held + 1, dtype=jnp.int32),
+                    axis=0)[:n_held]
+    return perm, inv, sizes
+
+
+def _pair_rows(n_pairs, n_held, n_routed):
+    """The rows of the two pair buffers, whole tiles of `_PAIR_TILE`: the
+    held pairs an even routing makes and a quarter more (seeded routers are
+    not even: PERF.md section 6, PR 50), and ALL the pairs, which no routing
+    overflows. Both are in the program: nothing is dropped and no count
+    changes a shape."""
+    even = n_pairs * n_held // n_routed
+    first, whole = (-(-n // _PAIR_TILE) * _PAIR_TILE
+                    for n in (even + even // 4, n_pairs))
+    return (first, whole) if first < whole else (whole,)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9, 10, 11))
+def _held_sum(x, w, gate, up, down, perm, inv, sizes, k, rows, backend,
+              compute_dtype):
+    """`train_experts` on sorted pairs, over the first of the buffers `rows`
+    that holds the held pairs. Its backward keeps none of the buffer's rows:
+    it gathers and multiplies them again from x (one more [gate | up]
+    product a layer, for the gathered rows, the hidden rows and their
+    products' outputs of every layer not held from the forward to the
+    backward)."""
+    return _by_buffer(sizes, rows, lambda m: _pair_sum(
+        x, w, gate, up, down, perm, inv, sizes, k, m, backend,
+        compute_dtype))
+
+
+def _by_buffer(sizes, rows, run):
+    if len(rows) == 1:
+        return run(rows[0])
+    return jax.lax.cond(jnp.sum(sizes) > rows[0],
+                        functools.partial(run, rows[1]),
+                        functools.partial(run, rows[0]))
+
+
+def _pair_sum(x, w, gate, up, down, perm, inv, sizes, k, m, backend,
+              compute_dtype):
+    """The layer over the first m sorted pairs (every held one is among
+    them): their rows gathered, [gate | up] as ONE grouped product, the
+    weighted hidden rows through down, a row's pairs summed back. The
+    products are megablox's kernels, or under backend "xla" (off a TPU)
+    `jax.lax.ragged_dot`: XLA's own lowering of it reached 14% of the chip's
+    peak at the training cell's shape where the kernels reach 60% (PERF.md
+    section 6, PR 50)."""
+    perm = perm[:m]
+    xs = _expand(x.astype(compute_dtype), perm, inv, k)
+    ws = _expand(w.reshape(-1, 1), perm, inv, 1)
+    width = gate.shape[-1]
+    wide = jnp.concatenate([gate, up], axis=-1).astype(compute_dtype)
+    narrow = down.astype(compute_dtype)
+    if backend == "xla":
+        def grouped(a, b, role):
+            return jax.lax.ragged_dot(
+                a, b, sizes, preferred_element_type=jnp.float32
+            ).astype(compute_dtype)
+    else:
+        def grouped(a, b, role):
+            return _grouped(a, b, sizes, role, False,
+                            backend == "pallas_interpret")
+    # the rows past the held pairs are 0 out of either product
+    gu = grouped(xs, wide, "in").astype(jnp.float32)
+    hidden = jax.nn.silu(gu[:, :width]) * gu[:, width:] * ws
+    y = grouped(hidden.astype(compute_dtype), narrow, "out")
+    return _combine(y, perm, inv, k)
+
+
+def _held_sum_fwd(x, w, gate, up, down, perm, inv, sizes, k, rows, backend,
+                  compute_dtype):
+    out = _held_sum(x, w, gate, up, down, perm, inv, sizes, k, rows, backend,
+                    compute_dtype)
+    return out, (x, w, gate, up, down, perm, inv, sizes)
+
+
+def _held_sum_bwd(k, rows, backend, compute_dtype, res, g):
+    x, w, gate, up, down, perm, inv, sizes = res
+
+    def back(m):
+        _, vjp = jax.vjp(
+            lambda *a: _pair_sum(*a, perm, inv, sizes, k, m, backend,
+                                 compute_dtype), x, w, gate, up, down)
+        return vjp(g)
+
+    return _by_buffer(sizes, rows, back) + (None, None, None)
+
+
+_held_sum.defvjp(_held_sum_fwd, _held_sum_bwd)
+
+
+def train_experts(x, idx, w, held, n_routed, gate, up, down, backend=None,
+                  compute_dtype=jnp.bfloat16):
+    """sum over a row's selected experts that are HELD of
+    w * down_e(silu(gate_e x) * up_e x): x [N, D], idx, w [N, k]
+    (`train_route`), stacks [n_held, D, F] / [n_held, F, D] -> ([N, D]
+    float32, sizes [n_held] int32). The pairs sorted by held expert, the
+    held ones' rows gathered into a buffer, grouped products over it, a
+    row's pairs summed back (`_pair_sum`); differentiable in x, w and the
+    stacks. The buffer is the first of `_pair_rows`' two that holds the held
+    pairs: room for an even routing's and a quarter more, else all N x k
+    pairs, so no routing drops a row. Static shapes: the routing changes
+    `sizes` and which buffer a step takes, not the program."""
+    perm, inv, sizes = sort_pairs(idx, held, n_routed)
+    rows = _pair_rows(idx.size, len(held), n_routed)
+    out = _held_sum(x, w, gate, up, down, perm, inv, sizes, idx.shape[1],
+                    rows, backend or _auto_backend(),
+                    jnp.dtype(compute_dtype))
+    return out, sizes
+
+
+@register_op("moe_train")
+def _moe_train_op(ctx, ins, attrs):
+    """The routed layer of a training step: X [.., D], the router, the held
+    experts' stacks -> Out (the held selected experts' weighted sum), Aux
+    (the balance term of the layer, a scalar), and the counters the step
+    keeps on the device, this step's counts added to what the scope holds:
+    RowsTotal [n_held] (pairs a held expert got), PairsTotal [3] (routed,
+    held, dropped: the last is 0, there is no capacity), AuxLast [1]."""
+    x = ins["X"][0]
+    rows = x.reshape(-1, x.shape[-1])
+    held, n_routed = attrs["held"], attrs["n_routed"]
+    p, idx, w = train_route(
+        rows, ins["W"][0], attrs["top_k"], attrs.get("norm_topk_prob", True),
+        attrs.get("scaling", 1.0))
+    aux, _ = balance_term(p, idx)
+    from ..core import flags
+    out, sizes = train_experts(
+        rows, idx, w, held, n_routed, ins["Gate"][0], ins["Up"][0],
+        ins["Down"][0], backend=attrs.get("backend"),
+        # bfloat16 operands like every `use_bf16` matmul of the graph, and
+        # like them the stored dtype under the global kill-switch
+        compute_dtype=(jnp.bfloat16 if flags.get_flag("use_bf16_matmul")
+                       else ins["Gate"][0].dtype))
+    # dropped: the pairs that chose a held expert less those given a row
+    mine = jnp.sum(jnp.isin(idx, jnp.asarray(held, jnp.int32)),
+                   dtype=jnp.int32)
+    pairs = jnp.stack([jnp.int32(idx.size), jnp.sum(sizes),
+                       mine - jnp.sum(sizes)])
+    return {"Out": [out.reshape(x.shape).astype(x.dtype)], "Aux": [aux],
+            "RowsTotalOut": [ins["RowsTotal"][0] + sizes],
+            "PairsTotalOut": [ins["PairsTotal"][0] + pairs],
+            "AuxLastOut": [jax.lax.stop_gradient(aux).reshape(1)]}
 
 
 @register_op("moe_experts", stop_gradient=True)

@@ -1058,12 +1058,14 @@ def rms_norm(input, epsilon=1e-6, param_attr=None, name=None):
     return y
 
 
-def rotary(x, pos, table, name=None):
+def rotary(x, pos, table, name=None, stop_gradient=True):
     """Rotary positions: rotate each head's values of `x` [N, .., heads*dim]
-    by the angles of row `pos[n]` of `table` [T, dim] (cos | sin)."""
+    by the angles of row `pos[n]` of `table` [T, dim] (cos | sin). A
+    training graph passes `stop_gradient=False`: the rotation is linear in
+    x and its gradient flows."""
     helper = LayerHelper("rotary", name=name)
     out = helper.create_tmp_variable(dtype=dtype_name(x.dtype), shape=x.shape,
-                                     stop_gradient=True)
+                                     stop_gradient=stop_gradient)
     helper.append_op(type="rotary",
                      inputs={"X": [x], "Pos": [pos], "Table": [table]},
                      outputs={"Out": [out]})
@@ -1137,6 +1139,42 @@ def moe_route(x, w_router, held, top_k, scaling, norm_topk_prob=True,
                      outputs={"Weights": [weights], "Rows": [rows]},
                      attrs=attrs)
     return weights, rows
+
+
+def moe_train(x, w_router, held, top_k, gate, up, down, counters,
+              scaling=1.0, norm_topk_prob=True, name=None):
+    """The routed layer of a TRAINING graph, differentiable in x, the
+    router and the three stacks (fusion/moe.py `moe_train`): softmax scores
+    over every column of `w_router`, the top-k, weights normalised over the
+    selected, the `held` experts' part of the sum by a grouped product over
+    the (row, expert) pairs sorted by expert. Returns (out as x, aux: the
+    layer's balance term, a scalar). `counters`: a name prefix; the step
+    keeps, as persistable variables it adds to in the graph,
+    `<prefix>.rows` [n_held] (pairs each held expert got), `<prefix>.pairs`
+    [3] (routed, held, dropped) and `<prefix>.aux` [1] (the balance term's
+    last value)."""
+    helper = LayerHelper("moe_train", name=name)
+    out = helper.create_tmp_variable(dtype=dtype_name(x.dtype), shape=x.shape)
+    aux = helper.create_tmp_variable(dtype="float32", shape=[])
+    inputs = {"X": [x], "W": [w_router], "Gate": [gate], "Up": [up],
+              "Down": [down]}
+    outputs = {"Out": [out], "Aux": [aux]}
+    for slot, suffix, shape, dtype in (
+            ("RowsTotal", "rows", [len(held)], "int32"),
+            ("PairsTotal", "pairs", [3], "int32"),
+            ("AuxLast", "aux", [1], "float32")):
+        var = helper.create_parameter(
+            ParamAttr(name=f"{counters}.{suffix}", trainable=False),
+            shape=shape, dtype=dtype,
+            default_initializer=ConstantInitializer(0.0))
+        var.stop_gradient = True
+        inputs[slot], outputs[slot + "Out"] = [var], [var]
+    helper.append_op(type="moe_train", inputs=inputs, outputs=outputs,
+                     attrs={"held": [int(e) for e in held],
+                            "n_routed": int(w_router.shape[-1]),
+                            "top_k": int(top_k), "scaling": float(scaling),
+                            "norm_topk_prob": bool(norm_topk_prob)})
+    return out, aux
 
 
 def moe_experts(x, weights, rows, gate, up, down, name=None):
@@ -1549,7 +1587,8 @@ def log_softmax(x, axis=-1, name=None):
 
 
 def fused_attention(q, k, v, scale=None, causal=False, segment_ids=None,
-                    kv_segment_ids=None, num_heads=None, name=None):
+                    kv_segment_ids=None, num_heads=None, name=None,
+                    window=0):
     """Fused scaled-dot-product attention over [B, H, T, D] tensors —
     flash kernel (Pallas) on TPU, XLA composite elsewhere
     (≙ nets.py scaled_dot_product_attention, kernelized).
@@ -1562,7 +1601,11 @@ def fused_attention(q, k, v, scale=None, causal=False, segment_ids=None,
     segment_ids ([B, T] int var) enables packed-batch masking — multiple
     sequences share one row and attend only within their own segment (the
     static-shape LoD translation, SURVEY §5); kv_segment_ids defaults to
-    segment_ids (self-attention). Composes with `causal`."""
+    segment_ids (self-attention). Composes with `causal`.
+
+    k and v may have fewer heads than q (grouped heads: query head i reads
+    key/value head i // group); `window` > 0 (causal only): a query sees
+    its last `window` keys, itself among them."""
     helper = LayerHelper("fused_attention", name=name)
     out = helper.create_tmp_variable(dtype=dtype_name(q.dtype),
                                      shape=list(q.shape))
@@ -1578,6 +1621,8 @@ def fused_attention(q, k, v, scale=None, causal=False, segment_ids=None,
     attrs = {"scale": scale, "causal": causal}
     if num_heads:
         attrs["num_heads"] = num_heads
+    if window:
+        attrs["window"] = int(window)
     helper.append_op(type="fused_attention",
                      inputs=inputs,
                      outputs={"Out": [out]},

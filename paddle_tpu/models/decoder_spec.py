@@ -11,12 +11,15 @@ short convolutions with a per-request state beside grouped-query rotary
 attention), `DecoderSpec.ssm_gqa_moe` the fourth (ONE sublayer a layer: a
 Mamba-2 state-space mixer, grouped-query attention without positions, or
 latent routed experts), `DecoderSpec.window_gqa_moe` the fifth (a kind of
-ATTENTION a layer: a sliding window with rotary positions, or every position
-without any). A spec comes from one of the constructors; the fields are what
-`_decoder_block` reads, not a product to pick from: any other combination
-raises where a graph would have to build it.
-`serving.PagedKVEngine(model=spec)` takes any of them; everything else in
-the package takes the classic one.
+ATTENTION a layer: a sliding window or every position, each kind with its
+own rotary positions or none). A spec comes from one of the constructors;
+the fields are what `_decoder_block` reads, not a product to pick from: any
+other combination raises where a graph would have to build it.
+`serving.PagedKVEngine(model=spec)` takes any of them;
+`models.transformer.transformer_lm(model=spec)` TRAINS the fifth (pre-norm
+RMSNorm, grouped heads, a window and a rotation a kind of layer through the
+flash kernels, softmax-routed experts with a gradient and a balance term);
+everything else in the package takes the classic one.
 
 Kinds (each a string, checked by name; nothing is guessed):
 
@@ -28,8 +31,9 @@ Kinds (each a string, checked by name; nothing is guessed):
               key/value heads than query heads, `qk_norm` an RMSNorm a head
               on q and k) | "latent" (`LatentSpec`)
   attention_kinds  a kind an (attention) layer, "window" (a query sees the
-              last `window` positions, itself among them; rotated where the
-              spec has a `rope`) | "full" (every position; NOT rotated);
+              last `window` positions, itself among them; rotated by `rope`
+              where the spec has one) | "full" (every position; rotated by
+              `rope_full` where the spec has one, else NOT rotated);
               None: full everywhere, rotated where the spec has a `rope`
   layer_kinds a kind a layer, "attention" | "conv" (`ConvSpec`: a gated
               short convolution whose state is the last rows of its input);
@@ -37,7 +41,9 @@ Kinds (each a string, checked by name; nothing is guessed):
               kind ALONE under one pre-norm residual, out of "ssm"
               (`SsmSpec`) | "attention" | "moe"
   ffn         "relu" | "gated_silu"; layers from `moe.first_dense` on are
-              routed experts + shared expert (`MoESpec`)
+              routed experts + shared expert (`MoESpec`: `scoring`
+              "sigmoid" is what the serving ticks route by, "softmax" with
+              a balance term `aux_coef` what a training graph does)
   tied_head   the vocabulary head is the embedding, transposed
 """
 
@@ -173,7 +179,7 @@ class SsmSpec:
 
 TOPK_METHODS = ("none", "bias")
 ACTIVATIONS = ("gated_silu", "relu2")
-SCORING = ("sigmoid",)
+SCORING = ("sigmoid", "softmax")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -189,7 +195,12 @@ class MoESpec:
     `activation` "relu2": an expert is `W2 relu(W1 z)^2`, two matrices and no
     gate; `latent` > 0: the routed experts run on a row of that width between
     a down- and an up-projection all of them share; `d_shared`: the shared
-    expert's own width (None: `d_expert * n_shared`)."""
+    expert's own width (None: `d_expert * n_shared`). `scoring` "softmax":
+    the scores are a softmax over all `n_routed` experts before the top-k
+    (a training graph; the serving ticks route by "sigmoid" alone);
+    `aux_coef`: what a training loss adds of each routed layer's balance
+    term `n_routed * sum_e f_e P_e` (f_e the share of the assignments that
+    chose e, P_e the mean score of e, over ALL experts)."""
     n_routed: int
     top_k: int
     d_expert: int
@@ -204,6 +215,7 @@ class MoESpec:
     activation: str = "gated_silu"
     latent: int = 0
     d_shared: Optional[int] = None
+    aux_coef: float = 0.0
 
     @property
     def shared_width(self) -> int:
@@ -258,6 +270,7 @@ class DecoderSpec:
     ssm: Optional[SsmSpec] = None
     attention_kinds: Optional[Tuple[str, ...]] = None   # "window" | "full"
     window: int = 0                         # positions a window layer sees
+    rope_full: Optional[RopeSpec] = None    # the "full" kind's own rotation
 
     def __post_init__(self):
         for field, kinds in (("norm", ("layer_norm", "rms_norm")),
@@ -310,6 +323,12 @@ class DecoderSpec:
         if (self.window > 0) != bool(self.window_layers):
             raise ValueError("a 'window' layer comes with `window` > 0, and "
                              "only it")
+        if self.rope_full is not None and (
+                akinds is None or self.rope is None
+                or self.rope_full.dim != self.rope.dim):
+            raise ValueError("`rope_full` rotates the 'full' kind of "
+                             "`attention_kinds` beside a `rope` of the same "
+                             "width for the 'window' kind")
 
     @classmethod
     def classic(cls, vocab=32000, d_model=512, d_inner=2048, num_heads=8,
@@ -370,20 +389,24 @@ class DecoderSpec:
     def window_gqa_moe(cls, vocab, d_model, d_inner, num_heads, num_kv_heads,
                        d_head, attention_kinds, window, rope: RopeSpec,
                        moe: Optional[MoESpec] = None, norm_eps=1e-5,
-                       dtype="bfloat16"):
-        """The EXAONE-4 / K-EXAONE family's block: pre-norm RMSNorm
+                       dtype="bfloat16", rope_full: Optional[RopeSpec] = None,
+                       qk_norm=True):
+        """The sliding-window families' block (EXAONE-4 / K-EXAONE; with
+        `rope_full` and without `qk_norm`, Mellum 2): pre-norm RMSNorm
         residuals; grouped-query attention with heads of `d_head` (whatever
-        `d_model`) and an RMSNorm a head on q and k in EVERY layer; by
-        `attention_kinds` a layer sees the last `window` positions, q and k
-        rotated over the whole head, or every position, NOT rotated; a gated
-        SiLU pair, routed experts beside a shared one from
-        `moe.first_dense` on; a final norm and an untied head."""
+        `d_model`), an RMSNorm a head on q and k in EVERY layer where
+        `qk_norm`; by `attention_kinds` a layer sees the last `window`
+        positions, q and k rotated by `rope` over the whole head, or every
+        position, rotated by `rope_full` (None: NOT rotated); a gated SiLU
+        pair, routed experts beside the shared ones (`moe.n_shared` may be
+        0) from `moe.first_dense` on; a final norm and an untied head."""
         return cls(vocab, d_model, d_inner, num_heads, len(attention_kinds),
                    norm="rms_norm", norm_eps=norm_eps, residual="pre",
                    positions="rotary", ffn="gated_silu", dtype=dtype, moe=moe,
-                   num_kv_heads=num_kv_heads, qk_norm=True, rope=rope,
-                   head_dim=d_head, attention_kinds=tuple(attention_kinds),
-                   window=int(window))
+                   num_kv_heads=num_kv_heads, qk_norm=bool(qk_norm),
+                   rope=rope, head_dim=d_head,
+                   attention_kinds=tuple(attention_kinds),
+                   window=int(window), rope_full=rope_full)
 
     @property
     def is_classic(self) -> bool:
@@ -439,12 +462,18 @@ class DecoderSpec:
         return tuple(i for i in self.attention_layers
                      if self.attention_kind(i) == "full")
 
+    def rope_of(self, layer: int) -> Optional[RopeSpec]:
+        """What rotates q and k of attention layer `layer`, or None. Where
+        the spec gives a kind of attention a layer: `rope` the window
+        layers, `rope_full` (None in the K-EXAONE family) the full ones."""
+        if self.attention_kinds is None \
+                or self.attention_kind(layer) == "window":
+            return self.rope
+        return self.rope_full
+
     def rotates(self, layer: int) -> bool:
-        """Are q and k of attention layer `layer` rotated? Where the spec
-        gives a kind of attention a layer, the window layers alone."""
-        return self.rope is not None and (
-            self.attention_kinds is None
-            or self.attention_kind(layer) == "window")
+        """Are q and k of attention layer `layer` rotated?"""
+        return self.rope_of(layer) is not None
 
     @property
     def conv_layers(self) -> Tuple[int, ...]:

@@ -218,16 +218,30 @@ class _TickRows:
     idle slot, the tail of a short chunk — selects no expert), and where
     the routed layers leave their counts (`expert_rows`: per layer, the
     rows each held expert got); `conv`, where the spec has conv layers, is
-    their state (`_ConvState`), `ssm` the state-space layers' (`_SsmState`)."""
+    their state (`_ConvState`), `ssm` the state-space layers' (`_SsmState`).
+    `training`: the rows are a training graph's, [B, T] under one row of
+    positions: the rotation carries a gradient, the routed layers are
+    `layers.moe_train` and leave their balance terms in `aux`."""
 
-    def __init__(self, spec, positions, live, max_len, conv=None, ssm=None):
+    def __init__(self, spec, positions, live, max_len, conv=None, ssm=None,
+                 training=False):
         self.positions, self.live, self.conv = positions, live, conv
-        self.ssm = ssm
-        self.expert_rows = []
-        self.table = None
+        self.ssm, self.training = ssm, training
+        self.expert_rows, self.aux = [], []
+        self.table, self.tables = None, {}
         if spec.positions == "rotary":
             rope = spec.latent.rope if spec.latent else spec.rope
             self.table = layers.assign(rotary_table(rope, max_len))
+            self.tables[rope] = self.table
+            if spec.rope_full is not None and spec.rope_full not in self.tables:
+                self.tables[spec.rope_full] = layers.assign(
+                    rotary_table(spec.rope_full, max_len))
+
+    def table_of(self, spec, layer):
+        """The rotary table of attention layer `layer` (a kind of layer may
+        have its own: `DecoderSpec.rope_of`), None where it is not rotated."""
+        rope = spec.rope_of(layer)
+        return None if rope is None else self.tables[rope]
 
     def with_counts(self, next_ids):
         """`next_ids` [R,1] int64 followed by the routed layers' counts, one
@@ -240,13 +254,14 @@ class _TickRows:
             [next_ids, layers.reshape(counts, shape=[-1, 1])], axis=0)
 
 
-def _param(name, shape, dtype):
+def _param(name, shape, dtype, initializer=None):
     """A named parameter no layer function makes (an expert stack, the two
     halves of `kv_b`): in the main program, and the startup program with
-    the default initializer."""
+    the default initializer, or the one given."""
     from ..layer_helper import LayerHelper
-    return LayerHelper("param").create_parameter(ParamAttr(name=name),
-                                                 shape=shape, dtype=dtype)
+    return LayerHelper("param").create_parameter(
+        ParamAttr(name=name, initializer=initializer), shape=shape,
+        dtype=dtype)
 
 
 def _pre_norm(x, spec, name):
@@ -271,6 +286,12 @@ def _moe_ffn(x, spec, name, rows):
     spec has one."""
     moe, d = spec.moe, spec.d_model
     gated = moe.activation == "gated_silu"
+    if rows.training:
+        return _moe_train_ffn(x, spec, name, rows)
+    if moe.scoring != "sigmoid":
+        raise NotImplementedError(
+            f"scoring {moe.scoring!r}: a serving tick routes by sigmoid "
+            "scores (fusion/moe.py `route`); softmax is the training graph's")
     # the routed experts' row: x, or a latent between two projections that
     # every expert shares (the router and the shared expert read x itself)
     dz = moe.latent or d
@@ -300,6 +321,33 @@ def _moe_ffn(x, spec, name, rows):
             _proj(x, moe.shared_width, name + "_shared_up"))), d,
             name + "_shared_down")
     return layers.elementwise_add(routed, shared)
+
+
+def _moe_train_ffn(x, spec, name, rows):
+    """The routed layer of a training graph (`layers.moe_train`: softmax
+    scores, a gradient to the router and the stacks, the balance term left
+    in `rows.aux`, counters `{name}.rows/.pairs/.aux` kept in the scope),
+    under the parameter names the serving ticks read; the stacks start at
+    N(0, 1 / fan-in)."""
+    moe, d = spec.moe, spec.d_model
+    if (moe.scoring, moe.activation, moe.topk_method) != \
+            ("softmax", "gated_silu", "none") or moe.latent or moe.n_shared:
+        raise NotImplementedError(
+            "a training graph builds softmax-scored gated-SiLU experts "
+            f"without a shared one, a latent or a selection bias: {moe}")
+    router = _param(name + "_router.w_0", [d, moe.n_routed], "float32")
+    stack = {n: _param(f"{name}_experts_{n}",
+                       [len(moe.held), moe.d_expert, d] if n == "down"
+                       else [len(moe.held), d, moe.d_expert], "float32",
+                       NormalInitializer(0., float(
+                           moe.d_expert if n == "down" else d) ** -0.5))
+             for n in ("gate", "up", "down")}
+    out, aux = layers.moe_train(
+        x, router, moe.held, moe.top_k, stack["gate"], stack["up"],
+        stack["down"], counters=name, scaling=moe.scaling,
+        norm_topk_prob=moe.norm_topk_prob)
+    rows.aux.append(aux)
+    return out
 
 
 def _latent_attention(x, spec, name, attend, rows):
@@ -350,12 +398,13 @@ def _latent_attention(x, spec, name, attend, rows):
     return _proj(out, spec.d_model, name + "_o")
 
 
-def _grouped_attention(x, spec, name, attend, rows, rotate=True):
+def _grouped_attention(x, spec, name, attend, rows, table=None):
     """Attention with `spec.kv_heads` key/value heads under `num_heads`
     query heads (query head i reads key/value head i // group), an RMSNorm
     a head on q and k where the spec asks (`qk_norm`), rotary positions
-    over the whole head where it has them and the layer is one that
-    `rotate`s (`DecoderSpec.rotates`), around `attend(q, k_new, v_new)`."""
+    over the whole head by `table` (the layer's: `_TickRows.table_of`; None:
+    not rotated), around `attend(q, k_new, v_new)`. x is a tick's rows
+    [n, 1, d] or a training graph's [B, T, d]."""
     n, nh, nkv, dh = x.shape[0], spec.num_heads, spec.kv_heads, spec.d_head
 
     def heads(t, count, which):
@@ -364,10 +413,11 @@ def _grouped_attention(x, spec, name, attend, rows, rotate=True):
                 layers.reshape(t, shape=[n, count, dh]),
                 epsilon=spec.norm_eps,
                 param_attr=ParamAttr(name=f"{name}_{which}_norm.scale"))
-        if rows.table is not None and rotate:
+        if table is not None:
             t = layers.rotary(layers.reshape(t, shape=[n, count * dh]),
-                              rows.positions, rows.table)
-        return layers.reshape(t, shape=[n, 1, count * dh])
+                              rows.positions, table,
+                              stop_gradient=not rows.training)
+        return layers.reshape(t, shape=[n, x.shape[1], count * dh])
 
     q = heads(_proj(x, nh * dh, name + "_q"), nh, "q")
     k = heads(_proj(x, nkv * dh, name + "_k"), nkv, "k")
@@ -445,7 +495,7 @@ def _decoder_block(x, i, attend, d_model, d_inner, dropout, is_test=True,
             "ssm": lambda x: _ssm_mixer(x, spec, f"{name}_ssm", rows),
             "attention": lambda x: _grouped_attention(
                 x, spec, f"{name}_{attn}", functools.partial(attend, i),
-                rows),
+                rows, rows.table),
             "moe": lambda x: _moe_ffn(x, spec, f"{name}_moe", rows)}[kind]
         return layers.elementwise_add(
             x, sublayer(_pre_norm(x, spec, f"{name}_ln1")))
@@ -458,7 +508,7 @@ def _decoder_block(x, i, attend, d_model, d_inner, dropout, is_test=True,
             or spec.kv_heads != spec.num_heads:
         sublayers = [lambda x: _grouped_attention(
             x, spec, f"{name}_{attn}", functools.partial(attend, i), rows,
-            rotate=spec.rotates(i))]
+            rows.table_of(spec, i))]
     else:
         sublayers = [lambda x: _attention(x, x, x, d_model, f"{name}_{attn}",
                                           functools.partial(attend, i))]
@@ -1643,12 +1693,59 @@ def transformer_lm_paged_spec_verify_tick(n_slots, gamma, n_blocks,
     return ids, logp, cache.names
 
 
+def _spec_lm(tokens, label, spec, max_len):
+    """The training graph of a decoder whose block is a `DecoderSpec` of the
+    window / grouped-query / routed-experts kind (`window_gqa_moe`): the
+    embedding as it lies (no scale, no table: the positions are rotary,
+    inside attention), `_decoder_block` a layer with the flash kernels as
+    `attend` (grouped heads; the layer's window), the final norm, an untied
+    head without a bias, and as the loss the mean cross-entropy plus
+    `moe.aux_coef` times the routed layers' balance terms. Parameters are
+    float32 under the names the serving ticks read; matmul operands
+    bfloat16 (`use_bf16`)."""
+    if spec.attention != "full" or spec.layer_kinds is not None \
+            or spec.residual != "pre" or spec.positions != "rotary" \
+            or spec.tied_head or spec.dropout or spec.packed:
+        raise NotImplementedError(
+            "transformer_lm(model=spec) trains the pre-norm block of "
+            "rotary full-head attention (DecoderSpec.window_gqa_moe); "
+            f"not {spec}")
+    x = layers.embedding(
+        input=tokens, size=[spec.vocab, spec.d_model],
+        param_attr=ParamAttr(name="tok_emb",
+                             initializer=NormalInitializer(0., 1.)))
+    rows = _TickRows(spec, layers.assign(np.arange(max_len, dtype="int32")),
+                     None, max_len, training=True)
+    scale = float(spec.d_head) ** -0.5
+
+    def attend(i, q, k, v):
+        return layers.fused_attention(
+            q, k, v, scale=scale, causal=True, num_heads=spec.num_heads,
+            window=spec.window if spec.attention_kind(i) == "window" else 0)
+
+    x = _lm_decoder(x, attend, spec.num_layers, spec.d_model, spec.d_inner,
+                    0.0, is_test=False, spec=spec, rows=rows)
+    logits, _, _ = _lm_head(x, spec.vocab, ids=False, bias=False,
+                            out_dtype="float32")
+    loss = layers.mean(layers.softmax_with_cross_entropy(
+        logits, layers.unsqueeze(label, axes=[2])))
+    if rows.aux and spec.moe.aux_coef:
+        loss = layers.elementwise_add(loss, layers.scale(
+            layers.sums(rows.aux), scale=float(spec.moe.aux_coef)))
+    return loss, logits
+
+
 def transformer_lm(tokens=None, label=None, vocab=32000, max_len=128,
                    d_model=512, d_inner=2048, num_heads=8, num_layers=6,
                    dropout=0.0, is_test=False, packed=False,
-                   mean_loss=False):
+                   mean_loss=False, model=None):
     """Decoder-only causal LM — the flagship config used by
     __graft_entry__ (simplest shape that exercises dp/tp/sp sharding).
+
+    model: a `DecoderSpec` in place of the six dims; a classic one builds
+    what the dims build, op for op; `DecoderSpec.window_gqa_moe` builds the
+    pre-norm block it describes (`_spec_lm`; every row is a full context:
+    no padding, not packed).
 
     packed=True: each batch row holds MULTIPLE sequences back to back,
     described by a `segments` int32 input ([B, max_len]; 0 = padding,
@@ -1657,11 +1754,16 @@ def transformer_lm(tokens=None, label=None, vocab=32000, max_len=128,
     non-pad tokens. This is the throughput idiom for ragged corpora: no
     compute wasted on padding (≙ the reference's LoD batches whose whole
     point is padding-free ragged training, lod_tensor.h:58)."""
+    if model is not None and model.is_classic:
+        (vocab, d_model, d_inner, num_heads, num_layers, dropout,
+         packed), model = model.dims().values(), None
     if tokens is None:
         tokens = layers.data(name="tokens", shape=[max_len], dtype="int64",
                              lod_level=0 if packed else 1)
     if label is None:
         label = layers.data(name="targets", shape=[max_len], dtype="int64")
+    if model is not None:
+        return _spec_lm(tokens, label, model, max_len)
     segments = positions = None
     if packed:
         segments = layers.data(name="segments", shape=[max_len],

@@ -331,3 +331,41 @@ def get_or_create(registry: MetricsRegistry, kind: str, name: str,
     if m is not None:
         return m
     return getattr(registry, kind)(name, help, labels=labels, **kw)
+
+
+def train_expert_gauges(scope, counters: Sequence[str],
+                        registry: Optional[MetricsRegistry] = None):
+    """Gauges over the counters a TRAINING step keeps on the device for its
+    routed layers (`layers.moe_train(counters=<prefix>)`: `<prefix>.rows`,
+    `.pairs`, `.aux` in `scope`), one series a layer under the label
+    `layer=<prefix>`, read from the scope when scraped and not before (no
+    fetch rides the step):
+
+      ptpu_train_experts_touched   held experts that got a row (the serving
+                                   ticks' `experts_touched`)
+      ptpu_train_routed_rows       (row, expert) pairs that landed on held
+                                   experts (the serving ticks' `routed_rows`)
+      ptpu_train_routed_pairs      pairs the router made, held here or not
+      ptpu_train_dropped_rows      held pairs that got no row: 0, there is
+                                   no capacity
+      ptpu_train_balance_term      the layer's balance term at the last step
+
+    all but the last summed since the startup program ran. Returns the
+    gauges by (name, prefix)."""
+    import numpy as np
+    registry = registry or default_registry()
+    reads = {
+        "experts_touched": lambda p: np.count_nonzero(scope.get(p + ".rows")),
+        "routed_rows": lambda p: np.asarray(scope.get(p + ".pairs"))[1],
+        "routed_pairs": lambda p: np.asarray(scope.get(p + ".pairs"))[0],
+        "dropped_rows": lambda p: np.asarray(scope.get(p + ".pairs"))[2],
+        "balance_term": lambda p: np.asarray(scope.get(p + ".aux"))[0]}
+    out = {}
+    for prefix in counters:
+        for name, read in reads.items():
+            out[name, prefix] = get_or_create(
+                registry, "gauge", "ptpu_train_" + name,
+                "routed-experts counter of a training step",
+                labels={"layer": prefix},
+                fn=lambda read=read, prefix=prefix: float(read(prefix)))
+    return out

@@ -391,11 +391,16 @@ def _rotary(ctx, ins, attrs):
     """Rotate X [N, .., heads*dim] (each head's `dim` values paired (i,
     i + dim/2): rotate-half) by the angles of row `Pos[n]` of `Table`
     [T, dim]: cos in its first half, sin in its second, already scaled
-    (`models.transformer.rotary_table`). Float32 inside, X's dtype out."""
+    (`models.transformer.rotary_table`). Float32 inside, X's dtype out. A
+    `Pos` shorter than X's rows repeats over them (a training graph's one
+    row of positions under [B, T] rows)."""
     x, table = ins["X"][0], ins["Table"][0]
     dim = table.shape[-1]
     half = dim // 2
     pos = ins["Pos"][0].reshape(-1).astype(jnp.int32)
+    n_rows = x.size // x.shape[-1]
+    if pos.shape[0] < n_rows:
+        pos = jnp.tile(pos, n_rows // pos.shape[0])
     row = table[pos].astype(jnp.float32)                       # [N, dim]
     xf = x.reshape(pos.shape[0], -1, dim).astype(jnp.float32)  # [N, h, dim]
     cos, sin = row[:, None, :half], row[:, None, half:]

@@ -67,8 +67,11 @@ def _normalize_segment_ids(segment_ids, q, k, num_heads=None):
     return q_ids, kv_ids
 
 
-def _attention_reference(q, k, v, scale, causal, segment_ids=None):
-    """Naive composite (the XLA fallback path). q/k/v: [B, H, T, D].
+def _attention_reference(q, k, v, scale, causal, segment_ids=None, window=0):
+    """Naive composite (the XLA fallback path). q: [B, H, T, D]; k, v: [B,
+    KV, Tk, D], KV a divisor of H (query head i reads key/value head
+    i // (H / KV)). `window` > 0: a query sees the last `window` keys the
+    causal mask leaves it, itself among them.
     Causal masking is bottom-right aligned (query i sees keys up to
     i + Tk - Tq — the incremental-decode convention). A query row with NO
     visible keys (causal T > Tk head rows, or a segment id matching no
@@ -76,11 +79,16 @@ def _attention_reference(q, k, v, scale, causal, segment_ids=None):
     softmax's uniform-weights artifact, so every backend computes
     identical values and gradients."""
     q_ids, kv_ids = _normalize_segment_ids(segment_ids, q, k)
+    group = q.shape[1] // k.shape[1]
+    if group > 1:
+        k, v = (jnp.repeat(x, group, axis=1) for x in (k, v))
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
     tq, tk = s.shape[-2], s.shape[-1]
     mask = jnp.ones((1, tq, tk), bool)
     if causal:
         mask &= jnp.tril(jnp.ones((tq, tk), bool), tk - tq)[None]
+    if window:
+        mask &= ~jnp.tril(jnp.ones((tq, tk), bool), tk - tq - window)[None]
     if q_ids is not None:
         mask &= q_ids[:, :, None] == kv_ids[:, None, :]      # [B, tq, tk]
     if causal or q_ids is not None:
@@ -146,22 +154,31 @@ class FlashPlan:
     `block_q` scores a head, and a step's heads are one batch of it.
     `token_major`: the operands are [B, T, H * D] as the projections leave
     them and a step's heads are `rows * D` lanes of one batch row, a whole
-    number of 128-lane tiles; otherwise [B * H, T, D], a head a row."""
+    number of 128-lane tiles; otherwise [B * H, T, D], a head a row.
+    `group` > 1: that many query heads read one key/value head (k and v come
+    [B * H / group, Tk, D]); `window` > 0: a query sees its last `window`
+    keys. Both are streamed plans: the key axis of the grid is then as long
+    as the blocks ONE q-block can see, and starts at its first live block."""
     resident: bool
     block_q: int
     block_k: int
     rows: int
     token_major: bool = False
+    group: int = 1
+    window: int = 0
 
     def scope(self, kernel):
         """The kernel's name in a device trace: XLA names the custom call
         after its `jax.named_scope`, so `device_ops` spells the plan, `_tm`
-        last where the operands are token-major."""
+        last where the operands are token-major, `_g<group>` where the heads
+        are grouped and `_w<window>` where the keys are windowed."""
         tiles = f"q{self.block_q}_k{self.block_k}"
         if self.resident:
             return (f"flash_{kernel}_resident_{tiles}_rows{self.rows}"
                     + "_tm" * self.token_major)
-        return f"flash_{kernel}_streamed_{tiles}"
+        return (f"flash_{kernel}_streamed_{tiles}"
+                + f"_g{self.group}" * (self.group > 1)
+                + f"_w{self.window}" * (self.window > 0))
 
     def scopes(self):
         """Every kernel a forward and backward under this plan runs: a
@@ -170,8 +187,13 @@ class FlashPlan:
         return [self.scope(k) for k in ("fwd",) + backward]
 
 
+# the side of a tile of a windowed call: a q-block of 512 under a window of
+# 1,024 visits three key blocks of 512 for two blocks' worth of live pairs
+_WINDOW_SIDE = 512
+
+
 def _flash_plan(T, Tk, D, itemsize, heads, block_q=None, block_k=None,
-                num_heads=None):
+                num_heads=None, group=1, window=0):
     """The plan for q [.., T, D] against k, v [.., Tk, D]: a pure function
     of the shape, for the forward and the backward alike. `heads`: how many
     heads may share a grid step (B*H; H when segment ids are given, whose
@@ -187,14 +209,19 @@ def _flash_plan(T, Tk, D, itemsize, heads, block_q=None, block_k=None,
     A token-major operand keeps its layout where the plan is resident and
     some such number of heads divides H and fills whole 128-lane tiles
     (`rows` even at D = 64): the largest that does is taken. Every other
-    shape gets the head-major plan, and its caller transposes."""
+    shape gets the head-major plan, and its caller transposes.
+
+    Grouped heads (`group` query heads a key/value head) and a `window` take
+    the streamed plan at any length: its grid walks the live key blocks of a
+    q-block alone, and in the dK / dV pass the q-blocks of the group's heads
+    that see a key block, summing over them in VMEM."""
     head_bytes = -(-Tk // _LANES) * _LANES * D * (8 * itemsize + 8)
-    resident = head_bytes <= _VMEM_BUDGET
-    side = 256 if resident else 1024
+    resident = head_bytes <= _VMEM_BUDGET and group == 1 and not window
+    side = 256 if resident else _WINDOW_SIDE if window else 1024
     bq = _clamp_block(block_q, T) if block_q else _fit_block(T, side)
     bk = _clamp_block(block_k, Tk) if block_k else _fit_block(Tk, side)
     if not resident:
-        return FlashPlan(False, bq, bk, 1)
+        return FlashPlan(False, bq, bk, 1, group=group, window=window)
     most = max(1, min(_VMEM_BUDGET // head_bytes, _TILE_SCORES // (bq * bk)))
     whole_tiles = [r for r in range(1, most + 1)
                    if num_heads and num_heads % r == 0
@@ -205,14 +232,19 @@ def _flash_plan(T, Tk, D, itemsize, heads, block_q=None, block_k=None,
     return FlashPlan(True, bq, bk, rows)
 
 
-def _plan_for(q, k, segments, block_q=None, block_k=None, num_heads=None):
-    """The plan of a call on q [B, H, T, D] and k [B, H, Tk, D] or, with
-    `num_heads`, on q [B, T, H * D] and k [B, Tk, H * D] (arrays or shapes
+def _plan_for(q, k, segments, block_q=None, block_k=None, num_heads=None,
+              window=0):
+    """The plan of a call on q [B, H, T, D] and k [B, KV, Tk, D] or, with
+    `num_heads`, on q [B, T, H * D] and k [B, Tk, KV * D] (arrays or shapes
     with a dtype), with segment ids or without."""
     B, H, T, Tk, D = _dims(q, k, num_heads)
+    kv_heads = k.shape[2] // D if num_heads else k.shape[1]
+    if H % kv_heads:
+        raise ValueError(f"{H} query heads over {kv_heads} key/value heads")
     return _flash_plan(T, Tk, D, jnp.dtype(q.dtype).itemsize,
                        H if segments else B * H, block_q, block_k,
-                       num_heads=num_heads)
+                       num_heads=num_heads, group=H // kv_heads,
+                       window=int(window))
 
 
 # ---------------------------------------------------------------------------
@@ -252,23 +284,97 @@ class _Visible:
     """What decides which keys a query sees, beside segment ids: fixed for a
     call. `offset` = Tk - T aligns the causal diagonal bottom-right (query i
     sees keys up to i + offset: matches _attention_reference for Tq != Tk);
-    keys from `true_tk` on are padding."""
+    keys from `true_tk` on are padding. `window` > 0: query i sees keys
+    above i + offset - window. `k_steps` / `q_steps`: how long the key axis
+    (forward, dQ pass) and the q axis (dK / dV pass) of a streamed grid are:
+    all the blocks, or with a window those one block of the other side can
+    see (`_first_key_block`, `_first_q_block` say where they start)."""
     causal: bool
     offset: int
     true_tk: int
     num_k_blocks: int
+    num_q_blocks: int = 0
+    window: int = 0
+    k_steps: int = 0
+    q_steps: int = 0
+
+
+def _floor_div0(x, d):
+    """max(x, 0) // d for a Python int or a traced one."""
+    return (max(x, 0) if isinstance(x, int) else jnp.maximum(x, 0)) // d
+
+
+def _first_key_block(plan, see, qi):
+    """The first key block some query of q-block `qi` sees."""
+    if not see.window:
+        return 0
+    return _floor_div0(qi * plan.block_q + see.offset - see.window + 1,
+                       plan.block_k)
+
+
+def _last_key_block(plan, see, qi):
+    """The last key block some query of q-block `qi` sees (causal)."""
+    last = see.num_k_blocks - 1
+    if not see.causal:
+        return last
+    seen = _floor_div0((qi + 1) * plan.block_q - 1 + see.offset, plan.block_k)
+    return min(seen, last) if isinstance(seen, int) else \
+        jnp.minimum(seen, last)
+
+
+def _first_q_block(plan, see, j):
+    """The first q-block some query of which sees key block `j` (causal)."""
+    if not see.causal:
+        return 0
+    return _floor_div0(j * plan.block_k - see.offset, plan.block_q)
+
+
+def _last_q_block(plan, see, j):
+    """The last q-block some query of which sees key block `j`."""
+    last = see.num_q_blocks - 1
+    if not see.window:
+        return last
+    seen = _floor_div0((j + 1) * plan.block_k - 1 - see.offset
+                       + see.window - 1, plan.block_q)
+    return min(seen, last)
+
+
+def _step_q_block(plan, see, j, r):
+    """The q-block step `r` of the dK / dV pass's q axis is at, under key
+    block `j`: the axis runs over the `q_steps` q-blocks of each head of the
+    group in turn, from the first that sees the key block where a window
+    bounds them."""
+    first = _first_q_block(plan, see, j) if see.window else 0
+    return first + r % see.q_steps
+
+
+def _visible(plan, causal, T, Tk, nq, nk, window=0):
+    """The `_Visible` of a call, with the streamed grid's axis lengths."""
+    see = _Visible(causal, Tk - T, Tk, nk, nq, window)
+    if not window:
+        return dataclasses.replace(see, k_steps=nk, q_steps=nq)
+    k_steps = max(_last_key_block(plan, see, i)
+                  - _first_key_block(plan, see, i) + 1 for i in range(nq))
+    q_steps = max(_last_q_block(plan, see, j)
+                  - _first_q_block(plan, see, j) + 1 for j in range(nk))
+    return dataclasses.replace(see, k_steps=max(k_steps, 1),
+                               q_steps=max(q_steps, 1))
 
 
 class _KeyBlocks:
     """The key blocks q-block `qi` has to see, inside a kernel. Blocks
-    [0, n_full) are visible whole to every query of the block: no mask is
-    paid. Blocks [n_full, n_live) hold some masked pair (the causal
-    diagonal; padded keys; any block under segment ids) and pay the
-    per-element mask. Blocks from n_live on hold no live pair and are not
-    visited: at T = 32768 causal that halves the issued FLOPs."""
+    [n_first, n_live) hold a live pair; of them [n_inside, n_full) are
+    visible whole to every query of the block: no mask is paid. The others
+    hold some masked pair (the causal diagonal; the window's far edge; padded
+    keys; any block under segment ids) and pay the per-element mask. Blocks
+    outside [n_first, n_live) hold no live pair and are not visited: at
+    T = 32768 causal that halves the issued FLOPs, and under a window of
+    1,024 at T = 8,192 it leaves 3 key blocks of 512 a q-block of the 16.
+    Without a window n_first and n_inside are 0. `j`: the key block a
+    streamed step is at (a resident plan loops over them)."""
 
-    def __init__(self, plan, see, qi, key_axis, major_ids_ref, minor_ids_ref):
-        self.plan, self.see, self.qi, self.key_axis = plan, see, qi, key_axis
+    def __init__(self, plan, see, qi, j, major_ids_ref, minor_ids_ref):
+        self.plan, self.see, self.qi, self.j = plan, see, qi, j
         self.major_ids_ref, self.minor_ids_ref = major_ids_ref, minor_ids_ref
         self.segments = major_ids_ref is not None
         bq, bk = plan.block_q, plan.block_k
@@ -277,6 +383,7 @@ class _KeyBlocks:
                            or see.true_tk % bk != 0)
         self.n_live = jnp.int32(see.num_k_blocks)
         self.n_full = jnp.int32(0 if self.segments else see.true_tk // bk)
+        self.n_first = self.n_inside = jnp.int32(0)
         if see.causal:
             first = qi * bq + see.offset + 1    # keys the FIRST query sees
             last = first + bq - 1               # keys the LAST query sees
@@ -284,6 +391,15 @@ class _KeyBlocks:
                                       jnp.maximum(first, 0) // bk)
             self.n_live = jnp.minimum(
                 self.n_live, (jnp.maximum(last, 0) + bk - 1) // bk)
+        if see.window:
+            low = qi * bq + see.offset - see.window + 1  # first query's first
+            self.n_first = jnp.maximum(low, 0) // bk
+            self.n_inside = (jnp.maximum(low + bq - 1, 0) + bk - 1) // bk
+            # a q-block past the last one (the dK / dV pass's q axis starts
+            # at a key block's first q-block and may run over) sees nothing
+            alive = qi < see.num_q_blocks
+            self.n_live = jnp.where(alive, self.n_live, 0)
+            self.n_full = jnp.where(alive, self.n_full, 0)
 
     def _ids(self, j):
         return (self.major_ids_ref[0, _key_rows(self.plan, j), :],
@@ -299,15 +415,17 @@ class _KeyBlocks:
         if self.see.causal:
             q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, (bk, bq), 1)
             ok &= q_pos + self.see.offset >= k_pos
+            if self.see.window:
+                ok &= q_pos + self.see.offset - self.see.window < k_pos
         if ids is not None:
             ok &= _segment_mask(*ids)
         return ok[None]
 
     def visit(self, tile):
         """Run `tile(j, masked)` over the blocks to see. A resident plan
-        loops inside the kernel; a streamed one is at key block
-        program_id(key_axis) of the grid and runs it or not. Under segment
-        ids a block whose id range misses the queries' is skipped too."""
+        loops inside the kernel; a streamed one is at key block `j` and runs
+        it or not. Under segment ids a block whose id range misses the
+        queries' is skipped too."""
         from jax.experimental import pallas as pl
 
         def masked(j):
@@ -324,11 +442,12 @@ class _KeyBlocks:
                 jax.lax.fori_loop(self.n_full, self.n_live,
                                   lambda j, c: masked(j) or c, 0)
         else:
-            j = pl.program_id(self.key_axis)
-            pl.when(j < self.n_full)(lambda: tile(j, False))
+            j = self.j
+            whole = (j >= self.n_inside) & (j < self.n_full)
+            pl.when(whole)(lambda: tile(j, False))
             if self.any_masked:
-                pl.when((j >= self.n_full) & (j < self.n_live))(
-                    lambda: masked(j))
+                pl.when((j >= self.n_first) & (j < self.n_live)
+                        & jnp.logical_not(whole))(lambda: masked(j))
 
 
 def _key_rows(plan, j):
@@ -413,51 +532,67 @@ class _Tiling:
     token-major [B, T, H * D]. The per-row residuals lie [B * H, T / 128,
     128] in either form, and so do the head groups' numbers."""
 
-    def __init__(self, plan, q, k, num_heads, q_ids, kv_ids,
+    def __init__(self, plan, q, k, num_heads, q_ids, kv_ids, causal,
                  keys_outer=False):
         from jax.experimental import pallas as pl
         self.plan = plan
         B, H, T, Tk, D = _dims(q, k, num_heads)
         self.B, self.H, self.T, self.Tk, self.D = B, H, T, Tk, D
+        self.KV = H // plan.group
         bq, bk, rows = plan.block_q, plan.block_k, plan.rows
         self.Tp, self.Tkp = -(-T // bq) * bq, -(-Tk // bk) * bk
         self.nq, self.nk = self.Tp // bq, self.Tkp // bk
         self.lanes = math.gcd(bq, _LANES)
         self.q_ids, self.kv_ids = q_ids, kv_ids
+        self.see = see = _visible(plan, causal, T, Tk, self.nq, self.nk,
+                                  plan.window)
         if plan.resident:
             self.grid = (B * H // rows, self.nq)
         elif keys_outer:
-            self.grid = (B * H, self.nk, self.nq)
+            self.grid = (B * self.KV, self.nk, plan.group * see.q_steps)
         else:
-            self.grid = (B * H, self.nq, self.nk)
+            self.grid = (B * H, self.nq, see.k_steps)
         # the keys a step holds: the head's, or one streamed block
         keys = self.Tkp if plan.resident else bk
 
-        def spec(block, index):
-            def index_map(g, a, b=0):
-                return index(g, *((b, a) if keys_outer else (a, b)))
-            return pl.BlockSpec(block, index_map)
+        def where(g, a, b=0):
+            """(query head, key/value head, q-block, key block) a grid step
+            stages. A streamed step that sees nothing (`_KeyBlocks`) stages
+            the nearest block that some step does: the pipeline fetches a
+            block again only when its index changes."""
+            if plan.resident:
+                return g, g, a, 0
+            if keys_outer:
+                i = _step_q_block(plan, see, a, b)
+                i = jnp.clip(i, _first_q_block(plan, see, a), self.nq - 1)
+                return g * plan.group + b // see.q_steps, g, i, a
+            j = _first_key_block(plan, see, a) + b
+            j = jnp.minimum(j, _last_key_block(plan, see, a))
+            return g, g // plan.group, a, j
 
-        def batch(g):        # the batch row a step's heads lie in
-            return g * rows // H
+        def spec(block, index):
+            return pl.BlockSpec(block, lambda *ids: index(*where(*ids)))
+
+        def batch(h):        # the batch row a step's query heads lie in
+            return h * rows // H
 
         if plan.token_major:
             groups = H // rows
             self.q_spec = spec((1, bq, rows * D),
-                               lambda g, i, j: (g // groups, i, g % groups))
+                               lambda h, _, i, j: (h // groups, i, h % groups))
             self.k_spec = spec((1, keys, rows * D),
-                               lambda g, i, j: (g // groups, j, g % groups))
+                               lambda h, _, i, j: (h // groups, j, h % groups))
         else:
-            self.q_spec = spec((rows, bq, D), lambda g, i, j: (g, i, 0))
-            self.k_spec = spec((rows, keys, D), lambda g, i, j: (g, j, 0))
+            self.q_spec = spec((rows, bq, D), lambda h, _, i, j: (h, i, 0))
+            self.k_spec = spec((rows, keys, D), lambda _, h, i, j: (h, j, 0))
         self.stat_spec = spec((rows, self.Tp // self.lanes, self.lanes),
-                              lambda g, i, j: (g, 0, 0))
+                              lambda h, _, i, j: (h, 0, 0))
         # segment ids of a tile's rows (keys) / columns (queries): see
         # _segment_mask
         self.major_ids_spec = spec((1, keys, _LANES),
-                                   lambda g, i, j: (batch(g), j, 0))
+                                   lambda h, _, i, j: (batch(h), j, 0))
         self.minor_ids_spec = spec((1, 1, 8, bq),
-                                   lambda g, i, j: (batch(g), i, 0, 0))
+                                   lambda h, _, i, j: (batch(h), i, 0, 0))
 
     def operand(self, x, length):
         """A caller's q, k, v or do as the kernel takes it, its sequence
@@ -467,18 +602,19 @@ class _Tiling:
             x = x.reshape(-1, *x.shape[2:])
         return _pad_to(x, 1, length)
 
-    def shape(self, length):
-        """The shape `operand` gives at that length."""
+    def shape(self, length, heads=None):
+        """The shape `operand` gives at that length (`heads`: of a
+        key/value-side operand whose heads are fewer)."""
         if self.plan.token_major:
             return (self.B, length, self.H * self.D)
-        return (self.B * self.H, length, self.D)
+        return (self.B * (heads or self.H), length, self.D)
 
-    def result(self, y, t):
+    def result(self, y, t, heads=None):
         """A kernel's output as the caller's layout has it, `t` long."""
         y = y[:, :t]
         if self.plan.token_major:
             return y
-        return y.reshape(self.B, self.H, t, self.D)
+        return y.reshape(self.B, heads or self.H, t, self.D)
 
     def stat(self, x):
         """[B, H, T] per-row residual -> the `_row_stat` layout."""
@@ -587,7 +723,11 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_ref=None, l_ref=None,
     from jax.experimental import pallas as pl
 
     qi = pl.program_id(1)
-    blocks = _KeyBlocks(plan, see, qi, 2, major_ids_ref, minor_ids_ref)
+    step = None if plan.resident else pl.program_id(2)
+    blocks = _KeyBlocks(
+        plan, see, qi,
+        None if plan.resident else _first_key_block(plan, see, qi) + step,
+        major_ids_ref, minor_ids_ref)
 
     def init():
         m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
@@ -643,21 +783,28 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_ref=None, l_ref=None,
         blocks.visit(visit)
         finalize_state()
     else:
-        j = pl.program_id(2)
-        pl.when(j == 0)(init)
+        pl.when(step == 0)(init)
         blocks.visit(visit)
-        pl.when(j == see.num_k_blocks - 1)(finalize_state)
+        pl.when(step == see.k_steps - 1)(finalize_state)
+
+
+def _checked_window(window, causal):
+    if window and not causal:
+        raise ValueError("a window bounds the keys a CAUSAL query sees: "
+                         "window > 0 comes with causal=True")
+    return int(window or 0)
 
 
 def _flash_attention_pallas(q, k, v, scale, causal, block_q=None,
                             block_k=None, interpret=False, with_lse=False,
-                            segment_ids=None, num_heads=None):
-    """The flash forward on q [B, H, T, D] and k, v [B, H, Tk, D]: the
+                            segment_ids=None, num_heads=None, window=0):
+    """The flash forward on q [B, H, T, D] and k, v [B, KV, Tk, D]: the
     context, and with `with_lse` the rows' logsumexp [B, H, T] beside it.
     With `num_heads` the operands and the context are token-major, [B, T,
-    H * D], for a shape whose plan is (`_flash_plan`)."""
+    H * D], for a shape whose plan is (`_flash_plan`). `window`: a query
+    sees its last `window` keys (the plan carries it, and the head group)."""
     plan = _plan_for(q, k, segment_ids is not None, block_q, block_k,
-                     num_heads)
+                     num_heads, _checked_window(window, causal))
     _count("flash/call", plan)
     return _flash_fwd(q, k, v, segment_ids, scale=float(scale),
                       causal=bool(causal), plan=plan,
@@ -672,7 +819,7 @@ def _flash_fwd(q, k, v, segment_ids, *, scale, causal, plan, interpret,
 
     _count("flash/body_traced", plan)
     q_ids, kv_ids = _normalize_segment_ids(segment_ids, q, k, num_heads)
-    t = _Tiling(plan, q, k, num_heads, q_ids, kv_ids)
+    t = _Tiling(plan, q, k, num_heads, q_ids, kv_ids, causal)
     B, H, T, Tk, D = t.B, t.H, t.T, t.Tk, t.D
     bq = plan.block_q
     # sequence lengths are rounded up to block multiples: padded queries are
@@ -692,8 +839,7 @@ def _flash_fwd(q, k, v, segment_ids, *, scale, causal, plan, interpret,
     # sees a key, causal with T > Tk, keep the general path: it writes 0)
     single = plan.resident and t.nk == 1 and (not causal or Tk >= T)
     kernel = functools.partial(
-        _flash_fwd_kernel, plan=plan, see=_Visible(causal, Tk - T, Tk, t.nk),
-        single=single, scale=scale)
+        _flash_fwd_kernel, plan=plan, see=t.see, single=single, scale=scale)
     state = {} if single else {
         "m_ref": pltpu.VMEM((plan.rows, 1, bq), jnp.float32),
         "l_ref": pltpu.VMEM((plan.rows, 1, bq), jnp.float32),
@@ -742,8 +888,7 @@ def _bwd_tile(q, k, v, do, lse, delta, ok, scale):
 def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                       major_ids_ref=None, minor_ids_ref=None, dq_ref=None,
                       dk_ref=None, dv_ref=None, dq_acc=None, dk_acc=None,
-                      dv_acc=None, *, plan, see, keys_outer, single, scale,
-                      num_q_blocks):
+                      dv_acc=None, *, plan, see, keys_outer, single, scale):
     """One grid step of the flash backward.
 
     Resident plan: dq, dk and dv come from ONE pass that recomputes s and p
@@ -758,10 +903,15 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     `_put_heads_transposed`)."""
     from jax.experimental import pallas as pl
 
-    q_axis, key_axis = (2, 1) if keys_outer else (1, 2)
-    qi = pl.program_id(q_axis)
-    blocks = _KeyBlocks(plan, see, qi, key_axis, major_ids_ref,
-                        minor_ids_ref)
+    if plan.resident:
+        qi, j, step = pl.program_id(1), None, None
+    elif keys_outer:
+        j, step = pl.program_id(1), pl.program_id(2)
+        qi = _step_q_block(plan, see, j, step)
+    else:
+        qi, step = pl.program_id(1), pl.program_id(2)
+        j = _first_key_block(plan, see, qi) + step
+    blocks = _KeyBlocks(plan, see, qi, j, major_ids_ref, minor_ids_ref)
     q, do = _held_heads(plan, q_ref), _held_heads(plan, do_ref)
 
     def tile(j, masked):
@@ -806,27 +956,28 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         zero_dq()
         blocks.visit(visit)
         write(dq=dq_acc[:])
-        pl.when(qi == num_q_blocks - 1)(write_dkv)
+        pl.when(qi == see.num_q_blocks - 1)(write_dkv)
     elif dq_ref is not None:
-        j = pl.program_id(key_axis)
-        pl.when(j == 0)(zero_dq)
+        pl.when(step == 0)(zero_dq)
         blocks.visit(visit)
-        pl.when(j == see.num_k_blocks - 1)(lambda: write(dq=dq_acc[:]))
+        pl.when(step == see.k_steps - 1)(lambda: write(dq=dq_acc[:]))
     else:
-        pl.when(qi == 0)(zero_dkv)
+        # over the q-blocks of every query head of the key/value head's group
+        pl.when(step == 0)(zero_dkv)
         blocks.visit(visit)
-        pl.when(qi == num_q_blocks - 1)(write_dkv)
+        pl.when(step == plan.group * see.q_steps - 1)(write_dkv)
 
 
 def _flash_attention_bwd_pallas(q, k, v, o, lse, do, scale, causal,
                                 block_q=None, block_k=None, interpret=False,
-                                segment_ids=None, delta=None, num_heads=None):
+                                segment_ids=None, delta=None, num_heads=None,
+                                window=0):
     """The flash backward: (dq, dk, dv) from the forward's operands, its
     context `o` and logsumexp, and the context's cotangent `do`, in the
     operands' layout (`num_heads`: as the forward's). Ring attention passes
     the global `delta` in (`o` may then be None)."""
     plan = _plan_for(q, k, segment_ids is not None, block_q, block_k,
-                     num_heads)
+                     num_heads, _checked_window(window, causal))
     _count("flash/call", plan, backward=True)
     return _flash_bwd(q, k, v, o, lse, do, segment_ids, delta,
                       scale=float(scale), causal=bool(causal), plan=plan,
@@ -855,7 +1006,7 @@ def _flash_bwd(q, k, v, o, lse, do, segment_ids, delta, *, scale, causal,
 
     def call(scope, want_dq, want_dkv):
         keys_outer = not want_dq
-        t = _Tiling(plan, q, k, num_heads, q_ids, kv_ids, keys_outer)
+        t = _Tiling(plan, q, k, num_heads, q_ids, kv_ids, causal, keys_outer)
         T, Tk, D = t.T, t.Tk, t.D
         # padded queries carry do = 0 and delta = 0 (and a finite lse), so
         # they add nothing to dk and dv whatever they see
@@ -876,15 +1027,15 @@ def _flash_bwd(q, k, v, o, lse, do, segment_ids, delta, *, scale, causal,
         if want_dkv:
             for name, x in (("dk", k), ("dv", v)):
                 outs[name + "_ref"] = (_out_struct(
-                    t.shape(t.Tkp), x.dtype, q, k, v, do), t.k_spec)
+                    t.shape(t.Tkp, t.KV), x.dtype, q, k, v, do), t.k_spec)
                 scratch[name + "_acc"] = pltpu.VMEM((rows, D, keys), f32)
         kernel = functools.partial(
-            _flash_bwd_kernel, plan=plan,
-            see=_Visible(causal, Tk - T, Tk, t.nk), keys_outer=keys_outer,
-            single=single, scale=scale, num_q_blocks=t.nq)
+            _flash_bwd_kernel, plan=plan, see=t.see, keys_outer=keys_outer,
+            single=single, scale=scale)
         res = _named_call(kernel, plan.scope(scope), t.grid, ins, outs,
                           {} if single else scratch, interpret)
-        return {name: t.result(x, T if name == "dq_ref" else Tk)
+        return {name: (t.result(x, T) if name == "dq_ref"
+                       else t.result(x, Tk, t.KV))
                 for name, x in res.items()}
 
     if plan.resident:
@@ -895,8 +1046,11 @@ def _flash_bwd(q, k, v, o, lse, do, segment_ids, delta, *, scale, causal,
 
 
 def flash_attention(q, k, v, scale=None, causal=False, block_q=None,
-                    block_k=None, backend=None, segment_ids=None):
-    """Fused multi-head attention. q/k/v: [B, H, T, D].
+                    block_k=None, backend=None, segment_ids=None, window=0):
+    """Fused multi-head attention. q: [B, H, T, D]; k, v: [B, KV, Tk, D],
+    KV a divisor of H (grouped heads: query head i reads key/value head
+    i // (H / KV)). `window` > 0 (causal only): a query sees its last
+    `window` keys, itself among them.
 
     backend: None = auto (pallas on TPU, XLA composite elsewhere);
     "pallas_interpret" forces the kernel through the pallas interpreter
@@ -915,46 +1069,51 @@ def flash_attention(q, k, v, scale=None, causal=False, block_q=None,
     if backend is None:
         backend = _auto_backend()
     return _fused_attention(q, k, v, segment_ids, scale, causal, backend,
-                            block_q, block_k)
+                            block_q, block_k, None,
+                            _checked_window(window, causal))
 
 
 # ---------------------------------------------------------------------------
 # differentiable wrapper + op registration
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10))
 def _fused_attention(q, k, v, segment_ids, scale, causal, backend,
-                     block_q=None, block_k=None, num_heads=None):
-    """Differentiable attention on [B, H, T, D] operands or, with
-    `num_heads`, on token-major [B, T, H * D] ones whose plan is token-major
-    (`_attend` sees to that)."""
+                     block_q=None, block_k=None, num_heads=None, window=0):
+    """Differentiable attention on [B, H, T, D] operands (k, v may have
+    fewer heads: grouped) or, with `num_heads`, on token-major [B, T, H * D]
+    ones whose plan is token-major (`_attend` sees to that)."""
     if backend == "xla":
-        return _attention_reference(q, k, v, scale, causal, segment_ids)
+        return _attention_reference(q, k, v, scale, causal, segment_ids,
+                                    window)
     return _flash_attention_pallas(q, k, v, scale, causal, block_q, block_k,
                                    interpret=(backend == "pallas_interpret"),
                                    segment_ids=segment_ids,
-                                   num_heads=num_heads)
+                                   num_heads=num_heads, window=window)
 
 
 def _fused_attention_fwd(q, k, v, segment_ids, scale, causal, backend,
-                         block_q=None, block_k=None, num_heads=None):
+                         block_q=None, block_k=None, num_heads=None,
+                         window=0):
     if backend == "xla":
-        out = _attention_reference(q, k, v, scale, causal, segment_ids)
+        out = _attention_reference(q, k, v, scale, causal, segment_ids,
+                                   window)
         return out, (q, k, v, segment_ids, None, None)
     out, lse = _flash_attention_pallas(
         q, k, v, scale, causal, block_q, block_k,
         interpret=(backend == "pallas_interpret"), with_lse=True,
-        segment_ids=segment_ids, num_heads=num_heads)
+        segment_ids=segment_ids, num_heads=num_heads, window=window)
     return out, (q, k, v, segment_ids, out, lse)
 
 
 def _fused_attention_bwd(scale, causal, backend, block_q, block_k, num_heads,
-                         res, g):
+                         window, res, g):
     q, k, v, segment_ids, o, lse = res
     if backend == "xla":
         _, vjp = jax.vjp(
             lambda q_, k_, v_: _attention_reference(q_, k_, v_, scale,
-                                                    causal, segment_ids),
+                                                    causal, segment_ids,
+                                                    window),
             q, k, v)
         return vjp(g) + (None,)
     # flash backward: recompute P tiles from (q, k, lse) in VMEM — the
@@ -962,36 +1121,43 @@ def _fused_attention_bwd(scale, causal, backend, block_q, block_k, num_heads,
     return _flash_attention_bwd_pallas(
         q, k, v, o, lse, g, scale, causal, block_q, block_k,
         interpret=(backend == "pallas_interpret"),
-        segment_ids=segment_ids, num_heads=num_heads) + (None,)
+        segment_ids=segment_ids, num_heads=num_heads,
+        window=window) + (None,)
 
 
 _fused_attention.defvjp(_fused_attention_fwd, _fused_attention_bwd)
 
 
-def _attend(q, k, v, segment_ids, scale, causal, backend, num_heads=None):
+def _attend(q, k, v, segment_ids, scale, causal, backend, num_heads=None,
+            window=0):
     """The op on either layout. With `num_heads`: attention of q [B, T,
-    H * D] over k, v [B, Tk, H * D], the layout the projections leave, and
+    H * D] over k, v [B, Tk, KV * D], the layout the projections leave, and
     the context [B, T, H * D]: the kernels take a shape whose plan is
-    token-major as it lies; every other shape, and the composite, goes
-    head-major between two transposes, as every call did before PR 47.
-    Without: q, k, v [B, H, T, D], a rank-4 caller's."""
+    token-major as it lies; every other shape (grouped heads and a window
+    among them), and the composite, goes head-major between two transposes,
+    as every call did before PR 47. Without: q [B, H, T, D] and k, v
+    [B, KV, Tk, D], a rank-4 caller's."""
+    window = _checked_window(window, causal)
     if not num_heads:
-        return _fused_attention(q, k, v, segment_ids, scale, causal, backend)
+        return _fused_attention(q, k, v, segment_ids, scale, causal, backend,
+                                None, None, None, window)
     if backend != "xla" and _plan_for(q, k, segment_ids is not None,
-                                      num_heads=num_heads).token_major:
+                                      num_heads=num_heads,
+                                      window=window).token_major:
         return _fused_attention(q, k, v, segment_ids, scale, causal, backend,
                                 None, None, num_heads)
+    d_head = q.shape[-1] // num_heads
 
     def heads(x):
-        return jnp.swapaxes(x.reshape(*x.shape[:2], num_heads, -1), 1, 2)
+        return jnp.swapaxes(x.reshape(*x.shape[:2], -1, d_head), 1, 2)
 
     out = _fused_attention(heads(q), heads(k), heads(v), segment_ids, scale,
-                           causal, backend)
+                           causal, backend, None, None, None, window)
     return jnp.swapaxes(out, 1, 2).reshape(q.shape)
 
 
 def _attention_over_mesh(mesh, q, k, v, segment_ids, scale, causal, backend,
-                         num_heads=None):
+                         num_heads=None, window=0):
     """The flash kernels inside an SPMD-partitioned step (ParallelExecutor).
 
     The partitioner cannot see into a Mosaic custom call: left bare, the
@@ -1011,12 +1177,16 @@ def _attention_over_mesh(mesh, q, k, v, segment_ids, scale, causal, backend,
         return name if size > 1 and n % size == 0 else None
 
     b_ax = axis_for(DATA_AXIS, q.shape[0])
-    h_ax = axis_for(MODEL_AXIS, num_heads or q.shape[1])
+    # the heads split where the key/value heads (the fewer) divide
+    kv_heads = (k.shape[2] * num_heads // q.shape[2] if num_heads
+                else k.shape[1])
+    h_ax = axis_for(MODEL_AXIS, kv_heads)
     if num_heads and h_ax:
         num_heads //= mesh.axis_size(h_ax)
 
     def attend(q, k, v, seg):
-        return _attend(q, k, v, seg, scale, causal, backend, num_heads)
+        return _attend(q, k, v, seg, scale, causal, backend, num_heads,
+                       window)
 
     if b_ax is None and h_ax is None:
         return attend(q, k, v, segment_ids)
@@ -1046,7 +1216,9 @@ def _register():
         kernel). Lowering picks the backend per device — the TPU-native
         translation of the reference's (place, dtype, ...) kernel
         dispatch (op_registry.h:214). Q, K, V: [B, H, T, D], or with the
-        attr `num_heads` token-major, [B, T, H * D]; Out is as Q."""
+        attr `num_heads` token-major, [B, T, H * D]; Out is as Q. K and V
+        may have fewer heads than Q (grouped heads); the attr `window`
+        bounds the keys a causal query sees."""
         q, k, v = ins["Q"][0], ins["K"][0], ins["V"][0]
         num_heads = attrs.get("num_heads")
         d_head = q.shape[-1] // (num_heads or 1)
@@ -1058,12 +1230,14 @@ def _register():
             kv_ids = ins["KVSeg"][0] if ins.get("KVSeg") else q_ids
             seg = (q_ids, kv_ids)
         causal = attrs.get("causal", False)
+        window = attrs.get("window", 0)
         mesh = getattr(ctx, "mesh", None)
         if backend != "xla" and mesh is not None:
             out = _attention_over_mesh(mesh, q, k, v, seg, scale, causal,
-                                       backend, num_heads)
+                                       backend, num_heads, window)
         else:
-            out = _attend(q, k, v, seg, scale, causal, backend, num_heads)
+            out = _attend(q, k, v, seg, scale, causal, backend, num_heads,
+                          window)
         return {"Out": [out]}
 
 

@@ -1682,6 +1682,9 @@ COVERED_ELSEWHERE = {
     "ssm_scan": "tests/test_ssm.py",
     "gated_rms_norm": "tests/test_ssm.py",
     "moe_experts": "tests/test_routed_experts.py",
+    # the routed layer of a training graph: values and every gradient
+    # against the plain reference, the ranks' shares, no dropped row
+    "moe_train": "tests/test_mellum_train.py",
     # the paged ticks' cache read through the block table: the Pallas
     # kernel against the composite, and the composite against dense
     # attention and the slot tick's fused op, live in the pager and

@@ -669,3 +669,56 @@ def test_two_kv_head_read_compiles_for_v5e(one_chip, slots, positions):
     args = [S(a.shape, a.dtype, sharding=one_chip) for a in args]
     assert jax.jit(f).lower(*args).compile().as_text().count(
         "tpu_custom_call") == 1
+
+
+# -- the training cell of grouped heads, a window and routed experts ----------
+
+@pytest.mark.parametrize("window, scope", [
+    (1024, "streamed_q512_k512_g8_w1024"), (0, "streamed_q1024_k1024_g8")])
+def test_grouped_window_flash_compiles_for_v5e(one_chip, window, scope):
+    """The flash forward and backward at the 8k training cell's shape: 32
+    query heads of 128 over 4 key/value heads, token-major operands as the
+    projections leave them (the op transposes: the plan is streamed), under
+    the window of 1,024 and without: three Mosaic kernels each, named by
+    their plan."""
+    B, H, KV, T, D = 1, 32, 4, 8192, 128
+
+    def fwd_bwd(q, k, v, do):
+        out, vjp = jax.vjp(
+            lambda q, k, v: pallas_kernels._attend(
+                q, k, v, None, D ** -0.5, True, "pallas", H, window), q, k, v)
+        return (out,) + vjp(do)
+
+    q = S((B, T, H * D), jnp.bfloat16, sharding=one_chip)
+    k = S((B, T, KV * D), jnp.bfloat16, sharding=one_chip)
+    text = jax.jit(fwd_bwd).lower(q, k, k, q).compile().as_text()
+    assert text.count("tpu_custom_call") == 3
+    for kernel in ("fwd", "bwd_dq", "bwd_dkv"):
+        assert f"flash_{kernel}_{scope}" in text
+
+
+def test_training_expert_product_compiles_for_v5e(one_chip):
+    """The routed layer's training product at the 8k training cell's shape
+    (8,192 rows of 2,304, top-8 of 64, 16 held experts of width 896),
+    forward and backward: megablox's kernels under fusion/moe.py's tilings
+    fit VMEM, for both pair buffers a step chooses from (20,480 rows under
+    an even routing, or all 65,536 pairs), both in the program."""
+    from paddle_tpu.fusion import moe
+    N, D, F, E, H, K = 8192, 2304, 896, 64, 16, 8
+    assert moe._pair_rows(N * K, H, E) == (20480, 65536)
+
+    def fwd_bwd(x, idx, w, gate, up, down):
+        def layer(x, w, gate, up, down):
+            return moe.train_experts(x, idx, w, tuple(range(H)), E, gate, up,
+                                     down, backend="pallas")[0]
+        out, vjp = jax.vjp(layer, x, w, gate, up, down)
+        return (out,) + vjp(out)
+
+    args = [S((N, D), jnp.float32), S((N, K), jnp.int32),
+            S((N, K), jnp.float32), S((H, D, F), jnp.float32),
+            S((H, D, F), jnp.float32), S((H, F, D), jnp.float32)]
+    args = [S(a.shape, a.dtype, sharding=one_chip) for a in args]
+    text = jax.jit(fwd_bwd).lower(*args).compile().as_text()
+    # forward 2 products, backward 1 again + 2 transposed + 2 weight
+    # gradients, in each of the two buffers' branches
+    assert text.count("tpu_custom_call") == 2 * (2 + 5)

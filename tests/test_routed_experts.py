@@ -113,8 +113,12 @@ def test_an_unknown_topk_method_raises_by_name():
                 topk_method="group_limited_greedy")
     with pytest.raises(NotImplementedError, match="noaux_tc"):
         ref.route(X, W_R, dict(CFG, topk_method="noaux_tc"))
-    with pytest.raises(NotImplementedError, match="softmax"):
-        MoESpec(n_routed=E, top_k=K, d_expert=F, held=(0,), scoring="softmax")
+    with pytest.raises(NotImplementedError, match="tanh"):
+        MoESpec(n_routed=E, top_k=K, d_expert=F, held=(0,), scoring="tanh")
+    # softmax scores are a training graph's (PR 50); a serving tick refuses
+    # them by name (tests/test_mellum_train.py)
+    assert MoESpec(n_routed=E, top_k=K, d_expert=F, held=(0,),
+                   scoring="softmax").scoring == "softmax"
     with pytest.raises(ValueError, match="held"):
         MoESpec(n_routed=E, top_k=K, d_expert=F, held=(3, 1))
     assert MoESpec(n_routed=E, top_k=K, d_expert=F, held=(1, 3)).held == (1, 3)

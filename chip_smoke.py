@@ -810,6 +810,57 @@ def _check_grouped_decode(n_slots, n_blocks, block_size, num_heads,
     return err
 
 
+def _check_experts(gated, n_held, d_model, d_expert, rows, backend, timing):
+    """The serving expert product (fusion/moe.py `experts`: the packed walk
+    over the touched experts, bfloat16 stacks) against its composite over
+    every held expert, with a scattered touched set (every third expert and
+    the last), with every expert touched and with none. Returns the largest
+    error."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.fusion import moe
+
+    key = jax.random.PRNGKey(5)
+    shapes = ([(n_held, d_model, d_expert)] * (2 if gated else 1)
+              + [(n_held, d_expert, d_model)])
+    stacks = [(jax.random.normal(jax.random.fold_in(key, i), s, jnp.float32)
+               * s[1] ** -0.5).astype(jnp.bfloat16)
+              for i, s in enumerate(shapes)]
+    x = jax.random.normal(jax.random.fold_in(key, 7), (rows, d_model),
+                          jnp.bfloat16)
+    rng = np.random.RandomState(5)
+    _check(moe.experts_lowering(rows, d_model, d_expert, backend,
+                                moe.experts_tile(rows, d_model, d_expert, 2,
+                                                 len(stacks))) == moe.KERNEL,
+           f"no kernel serves {rows} rows x [{d_model}, {d_expert}]")
+
+    def run(be):
+        return jax.jit(lambda x, w, n, *m: moe.experts(
+            x, w, n, *(m if gated else (None,) + m), backend=be))
+    kernel, composite = run(backend), run("xla")
+    worst = 0.0
+    scattered = np.arange(n_held) % 3 == 0
+    scattered[-1] = True
+    # ... and with none (an idle engine's tick): zeros out of one step
+    for on in (scattered, np.ones(n_held, bool), np.zeros(n_held, bool)):
+        picked = on[:, None, None] & (rng.rand(n_held, rows, 1) < 0.3)
+        picked[on, 0] = True                 # a touched expert has a row
+        w = jnp.asarray(picked * rng.uniform(0.05, 0.5, picked.shape),
+                        jnp.float32)
+        counts = jnp.asarray(picked.sum(axis=(1, 2)), jnp.int32)
+        args = (x, w, counts, *stacks)
+        got, c = _timed_first(kernel, *args)
+        timing["compile_s"] += c
+        t0 = time.time()
+        err = _rel_err(got, composite(*args))
+        timing["run_s"] += time.time() - t0
+        _check(err <= TOL_BF16,
+               f"expert product ({'gated' if gated else 'two-matrix'}, "
+               f"{int(on.sum())} of {n_held} touched): error {err}")
+        worst = max(worst, err)
+    return worst
+
+
 def _check_latent_decode(n_slots, n_live, n_blocks, block_size, num_heads,
                          row_lanes, v_width, blocks_per_req, backend, timing):
     """The latent decode read (one position a slot, every head on the slot's
@@ -968,7 +1019,9 @@ def phase_kernels(backend="pallas",
                                 ((1, 8, 8192, 128), True)),
                   decode=(16, 64, 640, 16), recurrent=(64, 64, 256),
                   paged=(16, 1024, 16, 16, 64, 64), chunk=(2, 128),
-                  latent=(32, 5, 2048, 64, 64, 640, 512, 272)):
+                  latent=(32, 5, 2048, 64, 64, 640, 512, 272),
+                  experts=((True, 32, 2048, 1792, 64),
+                           (False, 128, 1024, 2688, 64))):
     """Every Pallas kernel the package selects by default on a TPU, called
     directly, compiled by Mosaic, run, and compared with its own composite.
     flash_shapes = ([B, H, T, D], causal): the training cells' shapes (the
@@ -983,7 +1036,10 @@ def phase_kernels(backend="pallas",
     lane) of its mixed tick, over the same pools; latent = (slots, live
     slots, pool blocks, block size, heads, row lanes, value lanes, blocks a
     request): the document cell's latent decode read at its published
-    widths, five slots live at 17k positions."""
+    widths, five slots live at 17k positions; experts = (gated, held experts,
+    d_model, d_expert, rows) a case: the assistant cell's gated product and
+    the bursts cell's two-matrix one at their decode shapes, each with a
+    scattered touched set, with every expert touched and with none."""
     import jax
     import jax.numpy as jnp
     from paddle_tpu.ops.pallas_kernels import _plan_for
@@ -1008,6 +1064,9 @@ def phase_kernels(backend="pallas",
                                              timing)
     errs["latent_decode"], latent_ms = _check_latent_decode(*latent, backend,
                                                             timing)
+    for case in experts:
+        errs["experts_gated" if case[0] else "experts_two_matrix"] = \
+            _check_experts(*case, backend, timing)
     for kind in ("lstm", "gru"):
         errs["fused_" + kind] = _check_recurrent(kind, *recurrent, backend,
                                                  timing)

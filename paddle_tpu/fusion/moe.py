@@ -13,16 +13,27 @@ and `rows[e]`, how many rows expert e got.
 `moe_experts`: `sum_e w[e] * down_e(silu(gate_e x) * up_e x)` over the held
 experts, a grouped matrix product with two lowerings:
 
-- the kernel (a TPU): grid (held expert, tile of the expert width). The
-  weights stay in HBM behind BlockSpecs whose index maps read a
-  scalar-prefetched table: an expert no row selected maps every step to
-  the block the step before it used, so NONE of its weights are fetched,
-  and its steps compute nothing. A touched expert streams its three
-  matrices once, a tile at a time, and all N rows ride each tile (N is a
-  tick's rows: tens to a few hundred, so the product is bound by the
-  weights it streams, and a row that did not select the expert costs
-  MXU time that is idle anyway; its weight is zero). Accumulates in float32
-  in the resident output block.
+- the kernel (a TPU): a walk over the TOUCHED experts only (PR 51). From
+  the rows every held expert got the jitted wrapper builds, on the device,
+  the list of touched expert ids, ascending and packed to the front, and
+  their count (`packed_walk`); the grid is (touched expert, tile of the
+  expert width), its expert axis ending with the count (a dynamic grid
+  bound: a call with 5 of 16 experts touched runs 5 experts' steps). The
+  weights stay in HBM behind BlockSpecs whose index maps read the
+  scalar-prefetched list, so step e streams the e-th touched expert's
+  tiles and its `[1, N, 1]` routing weights, and Pallas' one-step lookahead
+  always finds the next touched tile: the DMA queue does not drain between
+  a call's first and last tile (the grid over the experts in their stored
+  order, before it, lost a step's compute at every touched -> untouched
+  edge). An expert no row selected is never fetched; a call with none
+  touched runs one step that fetches one tile and computes nothing, and
+  returns zeros. The `+=` into the resident float32 output block runs over
+  the experts in ascending order, as the composite's sum does. All N rows
+  ride each tile (N is a tick's rows: tens to a few hundred, so the product
+  is bound by the weights it streams, and a row that did not select the
+  expert costs MXU time that is idle anyway; its weight is zero). The
+  interpreter takes no dynamic grid bound: there the expert axis runs over
+  all held experts and the steps past the count hold the last touched tile.
 - the composite (a CPU, or asked for): the same sum in `jax.numpy`, over
   every held expert.
 
@@ -40,10 +51,11 @@ carry no gradient.
 
 With `gate` None an expert is `down_e(relu(up_e x)^2)`: two matrices, no gate
 (the latent experts, whose x is a latent row between projections the layer
-shares: `models/transformer.py _moe_ffn`). Its kernel takes the tile of the
-expert width from the shape, up to a whole expert a step (`relu2_tile`): 128
-held experts of width 2,688 at a tile of 256 would be 1,408 steps of ~0.35 us
-against the ~1.1 ms their weights stream in.
+shares: `models/transformer.py _moe_ffn`). Both forms share the walk
+(`_walk_pallas`, `_walk_step`) and the tile's rule (`experts_tile`): the
+columns of the expert width a step takes come from the shape the op sees
+(rows, d_model, d_expert, item size, matrices an expert), timed alone on a
+v5e at the four serving cells' shapes: docs/fusion.md has the table.
 """
 
 from __future__ import annotations
@@ -57,7 +69,6 @@ from ..framework.registry import register_op
 from .decode_attention import _auto_backend
 
 KERNEL, COMPOSITE = "kernel", "composite"
-_TILE = 256            # columns of the expert width a step takes
 
 
 def route(x, w_router, held, top_k, scaling, norm_topk_prob=True, live=None,
@@ -99,24 +110,50 @@ def _moe_route_op(ctx, ins, attrs):
     return {"Weights": [w], "Rows": [rows]}
 
 
-#: VMEM the two-matrix kernel's weight tiles may take, double-buffered
-_RELU2_VMEM = 48 * 1024 * 1024
+#: columns a tile may have, and bytes of weights a step may fetch (its
+#: `matrices` tiles together; the pipeline holds the next step's beside
+#: them) under a decode tick's rows and under a mixed tick's. Timed alone on
+#: a v5e AND in the four serving cells (PERF.md section 6, PR 51): alone a
+#: decode step wants 11-22 MB and a mixed tick's, bound by the MXU, 9-11; in
+#: a cell a call's first fetch costs more than alone, and the narrower tile
+#: won wherever the two disagreed but at [6144, 2048]
+_TILE_COLUMNS = 512
+_STEP_BYTES = 20 * 1024 * 1024
+_MIXED_STEP_BYTES = 12 * 1024 * 1024
+#: VMEM a call may take (`vmem_limit_bytes`)
+_VMEM_LIMIT = 100 * 1024 * 1024
 
 
-def relu2_tile(d_model, d_expert, itemsize):
-    """The columns of the expert width a step of the two-matrix kernel
-    takes: the largest divisor of the width in whole 128-lane rows whose two
-    tiles, double-buffered, fit `_RELU2_VMEM`; 0 where the width has none."""
+def experts_tile(n_rows, d_model, d_expert, itemsize, matrices=3):
+    """The columns of the expert width a step takes, from the shape the op
+    sees: the largest divisor of the width in whole 128-lane rows, of at
+    most `_TILE_COLUMNS` columns, whose `matrices` tiles are at most
+    `_STEP_BYTES` together (`_MIXED_STEP_BYTES` under a mixed tick's rows:
+    more than one pass of the MXU's 128); 0 where the width has none."""
+    budget = _STEP_BYTES if n_rows <= 128 else _MIXED_STEP_BYTES
     for n in range(1, d_expert // 128 + 1):
         tile, rest = divmod(d_expert, n)
-        if not rest and tile % 128 == 0 and \
-                4 * d_model * tile * itemsize <= _RELU2_VMEM:
+        if not rest and tile % 128 == 0 and tile <= _TILE_COLUMNS and \
+                matrices * d_model * tile * itemsize <= budget:
             return tile
     return 0
 
 
-def experts_lowering(n_rows, d_model, d_expert, backend=None, tile=_TILE):
+def experts_vmem_bytes(n_rows, d_model, tile, itemsize, matrices=3):
+    """What a call holds in VMEM at most: the weight tiles and the rows
+    double-buffered, the resident float32 output (counted twice, as the
+    pipeline may hold it), a routing-weights column padded to whole (8, 128)
+    tiles, and three [n_rows, tile] float32 intermediates."""
+    weights = 2 * matrices * d_model * tile * itemsize
+    rows = 2 * n_rows * d_model * (itemsize + 4)
+    column = 2 * -(-n_rows // 8) * 8 * 128 * 4
+    return weights + rows + column + 3 * n_rows * tile * 4
+
+
+def experts_lowering(n_rows, d_model, d_expert, backend=None, tile=None):
     backend = backend or _auto_backend()
+    if tile is None:
+        tile = experts_tile(n_rows, d_model, d_expert, 2)
     served = (n_rows % 16 == 0 and d_model % 128 == 0
               and tile and d_expert % tile == 0)
     if served and backend != "xla":
@@ -138,76 +175,6 @@ def _experts_composite(x, w, gate, up, down):
                       preferred_element_type=jnp.float32)
 
 
-def _experts_kernel(eblk_ref, fhold_ref, touched_ref, x_ref, w_ref, g_ref,
-                    u_ref, d_ref, o_ref):
-    from jax.experimental import pallas as pl
-
-    e, f = pl.program_id(0), pl.program_id(1)
-
-    @pl.when((e == 0) & (f == 0))
-    def _():
-        o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
-
-    @pl.when(touched_ref[e] > 0)
-    def _():
-        x = x_ref[...]
-        g = jnp.dot(x, g_ref[0], preferred_element_type=jnp.float32)
-        u = jnp.dot(x, u_ref[0], preferred_element_type=jnp.float32)
-        h = g * jax.nn.sigmoid(g) * u * w_ref[0]
-        o_ref[...] += jnp.dot(h.astype(x.dtype), d_ref[0],
-                              preferred_element_type=jnp.float32)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _experts_pallas(x, w, touched, gate, up, down, interpret):
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    n, d = x.shape
-    n_held, _, width = gate.shape
-    nf = width // _TILE
-    touched = touched.astype(jnp.int32)
-    # an untouched expert holds the block its neighbour uses: the last tile
-    # of the touched expert before it, or tile 0 of the first touched one
-    ids = jnp.arange(n_held, dtype=jnp.int32)
-    before = jax.lax.cummax(jnp.where(touched > 0, ids, -1))
-    first = jnp.argmax(touched > 0).astype(jnp.int32)
-    eblk = jnp.where(before >= 0, before, first)
-    fhold = jnp.where(before >= 0, nf - 1, 0).astype(jnp.int32)
-
-    def tile(e, f, eblk_ref, fhold_ref, touched_ref):
-        on = touched_ref[e] > 0
-        return eblk_ref[e], jnp.where(on, f, fhold_ref[e])
-
-    def in_map(e, f, *refs):
-        blk, col = tile(e, f, *refs)
-        return blk, 0, col
-
-    def down_map(e, f, *refs):
-        blk, col = tile(e, f, *refs)
-        return blk, col, 0
-
-    with jax.named_scope("moe_experts"):
-        return pl.pallas_call(
-            _experts_kernel,
-            grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=3,
-                grid=(n_held, nf),
-                in_specs=[
-                    pl.BlockSpec((n, d), lambda e, f, *_: (0, 0)),
-                    pl.BlockSpec((1, n, 1), lambda e, f, *_: (e, 0, 0)),
-                    pl.BlockSpec((1, d, _TILE), in_map),
-                    pl.BlockSpec((1, d, _TILE), in_map),
-                    pl.BlockSpec((1, _TILE, d), down_map)],
-                out_specs=pl.BlockSpec((n, d), lambda e, f, *_: (0, 0))),
-            out_shape=jax.ShapeDtypeStruct((n, d), jnp.float32),
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("arbitrary", "arbitrary"),
-                vmem_limit_bytes=100 * 1024 * 1024),
-            interpret=interpret,
-        )(eblk, fhold, touched, x.astype(gate.dtype), w, gate, up, down)
-
-
 def _relu2_composite(x, w, up, down):
     u = jnp.einsum("nd,edf->enf", x.astype(up.dtype), up,
                    preferred_element_type=jnp.float32)
@@ -216,8 +183,47 @@ def _relu2_composite(x, w, up, down):
                       preferred_element_type=jnp.float32)
 
 
-def _relu2_kernel(eblk_ref, fhold_ref, touched_ref, x_ref, w_ref, u_ref,
-                  d_ref, o_ref):
+def packed_walk(touched):
+    """The walk's tables from `touched` [n_held] (rows an expert got, or any
+    value that is positive where it got one): (order [n_held] int32, the
+    touched experts' ids ascending and packed to the front, every entry past
+    them the LAST touched id (0 where none is touched); count [1] int32)."""
+    on = touched > 0
+    ids = jnp.arange(on.shape[0], dtype=jnp.int32)
+    count = jnp.sum(on, dtype=jnp.int32)
+    # where each touched expert lands, and the table's inverse, by compares
+    # over [n_held, n_held]: one small fusion where a sort or a scatter
+    # would be an operation of its own before every call
+    pos = jnp.sum(on[None, :] & (ids[None, :] <= ids[:, None]), axis=1) - 1
+    order = jnp.sum(jnp.where(on[None, :] & (pos[None, :] == ids[:, None]),
+                              ids[None, :], 0), axis=1)
+    last = jnp.max(jnp.where(on, ids, 0))
+    return (jnp.where(ids < count, order, last).astype(jnp.int32),
+            count.reshape(1))
+
+
+def _experts_kernel(order_ref, count_ref, x_ref, w_ref, g_ref, u_ref, d_ref,
+                    o_ref):
+    def hidden(x):
+        g = jnp.dot(x, g_ref[0], preferred_element_type=jnp.float32)
+        u = jnp.dot(x, u_ref[0], preferred_element_type=jnp.float32)
+        return g * jax.nn.sigmoid(g) * u
+
+    _walk_step(count_ref, x_ref, w_ref, d_ref, o_ref, hidden)
+
+
+def _relu2_kernel(order_ref, count_ref, x_ref, w_ref, u_ref, d_ref, o_ref):
+    def hidden(x):
+        u = jnp.maximum(
+            jnp.dot(x, u_ref[0], preferred_element_type=jnp.float32), 0.0)
+        return u * u
+
+    _walk_step(count_ref, x_ref, w_ref, d_ref, o_ref, hidden)
+
+
+def _walk_step(count_ref, x_ref, w_ref, d_ref, o_ref, hidden):
+    """One step of the walk: step e of the expert axis is the e-th TOUCHED
+    expert's; a step past the count computes nothing."""
     from jax.experimental import pallas as pl
 
     e, f = pl.program_id(0), pl.program_id(1)
@@ -226,79 +232,74 @@ def _relu2_kernel(eblk_ref, fhold_ref, touched_ref, x_ref, w_ref, u_ref,
     def _():
         o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
 
-    @pl.when(touched_ref[e] > 0)
+    @pl.when(e < count_ref[0])
     def _():
         x = x_ref[...]
-        u = jnp.maximum(
-            jnp.dot(x, u_ref[0], preferred_element_type=jnp.float32), 0.0)
-        o_ref[...] += jnp.dot((u * u * w_ref[0]).astype(x.dtype), d_ref[0],
-                              preferred_element_type=jnp.float32)
+        o_ref[...] += jnp.dot((hidden(x) * w_ref[0]).astype(x.dtype),
+                              d_ref[0], preferred_element_type=jnp.float32)
 
 
 @functools.partial(jax.jit, static_argnames=("tile", "interpret"))
-def _relu2_pallas(x, w, touched, up, down, tile, interpret):
-    """`_experts_pallas` for two matrices an expert, `tile` columns a step."""
+def _walk_pallas(x, w, touched, stacks, tile, interpret):
+    """The grouped product over the touched experts only: `stacks` is (gate,
+    up, down) or (up, down), `tile` columns of the expert width a step."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    *ins, down = stacks
     n, d = x.shape
-    n_held, _, width = up.shape
+    n_held, _, width = ins[0].shape
     nf = width // tile
-    touched = touched.astype(jnp.int32)
-    ids = jnp.arange(n_held, dtype=jnp.int32)
-    before = jax.lax.cummax(jnp.where(touched > 0, ids, -1))
-    first = jnp.argmax(touched > 0).astype(jnp.int32)
-    eblk = jnp.where(before >= 0, before, first)
-    fhold = jnp.where(before >= 0, nf - 1, 0).astype(jnp.int32)
+    order, count = packed_walk(touched)
 
-    def held(e, f, eblk_ref, fhold_ref, touched_ref):
-        return eblk_ref[e], jnp.where(touched_ref[e] > 0, f, fhold_ref[e])
+    # a step past the count holds the last touched expert's last tile: it
+    # fetches nothing
+    def col(e, f, count_ref):
+        return jnp.where(e < count_ref[0], f, nf - 1)
 
-    def up_map(e, f, *refs):
-        blk, col = held(e, f, *refs)
-        return blk, 0, col
+    def in_map(e, f, order_ref, count_ref):
+        return order_ref[e], 0, col(e, f, count_ref)
 
-    def down_map(e, f, *refs):
-        blk, col = held(e, f, *refs)
-        return blk, col, 0
+    def down_map(e, f, order_ref, count_ref):
+        return order_ref[e], col(e, f, count_ref), 0
 
-    with jax.named_scope("latent_experts"):
+    gated = len(ins) == 2
+    # the walk ends with the count (the interpreter wants a static grid)
+    steps = n_held if interpret else jnp.maximum(count[0], 1)
+    with jax.named_scope("moe_experts" if gated else "latent_experts"):
         return pl.pallas_call(
-            _relu2_kernel,
+            _experts_kernel if gated else _relu2_kernel,
             grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=3,
-                grid=(n_held, nf),
+                num_scalar_prefetch=2,
+                grid=(steps, nf),
                 in_specs=[
                     pl.BlockSpec((n, d), lambda e, f, *_: (0, 0)),
-                    pl.BlockSpec((1, n, 1), lambda e, f, *_: (e, 0, 0)),
-                    pl.BlockSpec((1, d, tile), up_map),
+                    pl.BlockSpec((1, n, 1),
+                                 lambda e, f, order_ref, _: (order_ref[e], 0,
+                                                             0)),
+                    *[pl.BlockSpec((1, d, tile), in_map) for _ in ins],
                     pl.BlockSpec((1, tile, d), down_map)],
                 out_specs=pl.BlockSpec((n, d), lambda e, f, *_: (0, 0))),
             out_shape=jax.ShapeDtypeStruct((n, d), jnp.float32),
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary", "arbitrary"),
-                vmem_limit_bytes=100 * 1024 * 1024),
+                vmem_limit_bytes=_VMEM_LIMIT),
             interpret=interpret,
-        )(eblk, fhold, touched, x.astype(up.dtype), w, up, down)
+        )(order, count, x.astype(down.dtype), w, *ins, down)
 
 
 def experts(x, w, rows, gate, up, down, backend=None):
     """x [N, D]; w [n_held, N, 1] float32 (`route`); rows [n_held]; gate,
     up [n_held, D, F]; down [n_held, F, D] -> [N, D] float32. `gate` None:
     the two-matrix form, `down_e(relu(up_e x)^2)`."""
-    interpret = backend == "pallas_interpret"
+    stacks = (up, down) if gate is None else (gate, up, down)
+    tile = experts_tile(*x.shape, up.shape[-1], up.dtype.itemsize,
+                        len(stacks))
+    if experts_lowering(*x.shape, up.shape[-1], backend, tile) == KERNEL:
+        return _walk_pallas(x, w, rows, stacks, tile=tile,
+                            interpret=backend == "pallas_interpret")
     if gate is None:
-        tile = relu2_tile(x.shape[1], up.shape[-1], up.dtype.itemsize)
-        if experts_lowering(x.shape[0], x.shape[1], up.shape[-1], backend,
-                            tile) == KERNEL:
-            return _relu2_pallas(x, w, rows, up, down, tile=tile,
-                                 interpret=interpret)
         return _relu2_composite(x, w, up, down)
-    lowering = experts_lowering(x.shape[0], x.shape[1], gate.shape[-1],
-                                backend)
-    if lowering == KERNEL:
-        return _experts_pallas(x, w, rows, gate, up, down,
-                               interpret=interpret)
     return _experts_composite(x, w, gate, up, down)
 
 

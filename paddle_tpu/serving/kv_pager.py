@@ -1524,6 +1524,13 @@ class PagedKVEngine(ContinuousBatchingEngine):
             counts = ids[-total.size:, 0].reshape(total.shape)
             total += counts
             tick.attrs["experts_touched"] = int(np.count_nonzero(counts))
+            # maximal runs of touched experts in their stored order, over
+            # the routed layers: a run's first expert has no touched one
+            # before it in its layer
+            on = counts > 0
+            tick.attrs["expert_runs"] = int(
+                np.count_nonzero(on[:, 0])
+                + np.count_nonzero(on[:, 1:] & ~on[:, :-1]))
             tick.attrs["routed_rows"] = int(counts.sum())
             tick.attrs["expert_rows"] = counts.ravel().tolist()
 

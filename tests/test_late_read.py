@@ -189,7 +189,7 @@ def test_counts_stay_on_the_tick_that_made_them(pair):
     taken at the fill (the five router and roofline readers join them)."""
     kind, _, _, (late, _, steps), (eager, _, esteps) = pair
     keys = ("active", "prefill", "mixed", "kv_blocks", "decode_rows",
-            "experts_touched", "routed_rows", "expert_rows")
+            "experts_touched", "expert_runs", "routed_rows", "expert_rows")
     got, want = ([{k: t.attrs[k] for k in keys if k in t.attrs}
                   for t in _ticks(s)] for s in (steps, esteps))
     assert got == want

@@ -130,6 +130,29 @@ def test_bfloat16_engine_passes_the_cells_comparison_and_the_control_fails(
         assert st["block_bytes"] == 3 * 8 * 256 * 2
 
 
+@pytest.mark.parametrize("rows, touched, runs", [
+    # (layer, held expert) -> rows; a run is a maximal stretch of touched
+    # experts in one layer's stored order, and does not span two layers
+    ([[0, 0, 0, 0, 0, 0], [0, 0, 0, 0, 0, 0]], 0, 0),
+    ([[3, 1, 2, 9, 1, 1], [1, 1, 1, 1, 1, 1]], 12, 2),
+    ([[0, 0, 0, 0, 0, 5], [7, 0, 0, 0, 0, 0]], 2, 2),
+    ([[1, 0, 1, 0, 1, 0], [0, 1, 0, 1, 0, 1]], 6, 6),
+    ([[0, 2, 2, 2, 0, 0], [4, 4, 0, 0, 1, 1]], 7, 3),
+])
+def test_a_ticks_counts_give_the_touched_experts_and_their_runs(
+        rows, touched, runs):
+    from types import SimpleNamespace
+    eng = SimpleNamespace(expert_rows=np.ones((2, 6), np.int64))
+    tick = SimpleNamespace(attrs={})
+    # the tick's fetch: the ids' rows, then one count a (layer, held expert)
+    ids = np.concatenate([np.arange(5), np.ravel(rows)])[:, None]
+    serving.PagedKVEngine._note_tick_counts(eng, tick, ids)
+    assert tick.attrs["experts_touched"] == touched
+    assert tick.attrs["expert_runs"] == runs
+    assert tick.attrs["routed_rows"] == np.sum(rows)
+    assert (eng.expert_rows == 1 + np.asarray(rows)).all()
+
+
 def test_a_tick_counts_the_rows_its_experts_got():
     cfg = T.cfg()
     eng, _ = T.engine(cfg, 7)
@@ -143,6 +166,11 @@ def test_a_tick_counts_the_rows_its_experts_got():
     assert [s.attrs["routed_rows"] for s in ticks] == rows.sum(1).tolist()
     assert [s.attrs["experts_touched"] for s in ticks] == \
         (rows > 0).sum(1).tolist()
+    # the runs of touched experts a layer, by hand from the same counts
+    on = rows.reshape(len(ticks), 2, 4) > 0
+    assert [s.attrs["expert_runs"] for s in ticks] == [
+        sum(len("".join("x" if t else " " for t in layer).split())
+            for layer in tick) for tick in on]
     # a decode tick has one live row: it selects 4 of 16 experts a layer, so
     # at most 4 of the held ones; dead rows select nothing
     decode = [s for s in ticks if not s.attrs["prefill"]]

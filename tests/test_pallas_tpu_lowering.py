@@ -485,17 +485,29 @@ def test_latent_attention_kernel_compiles_for_v5e(one_chip, slots, positions):
 
 
 @pytest.mark.parametrize("rows", [32, 288])
-def test_expert_product_kernel_lowers_at_the_benchmark_shapes(rows):
+@pytest.mark.parametrize("held, width", [(12, 7168), (16, 6144)],
+                         ids=["document", "long_sessions"])
+def test_expert_product_kernel_lowers_at_the_benchmark_shapes(one_chip, held,
+                                                              width, rows):
     """The grouped expert product of the decode tick (32 rows) and the mixed
-    tick (32 + 2 * 128), twelve held experts of 7168 x 2048."""
+    tick (32 + 2 * 128): twelve held experts of 7168 x 2048 (the document
+    cell), sixteen of 6144 x 2048 (the long sessions). The packed walk over
+    the touched experts (PR 51) is ONE Mosaic call whose expert axis ends
+    with the count, at the tile the shape gives (256 columns; 512 under the
+    long sessions' decode rows), and the TPU compiler takes it for a v5e."""
     from paddle_tpu.fusion import moe
-    text = _tpu_text(
-        lambda x, w, n, g, u, d: moe.experts(x, w, n, g, u, d,
-                                             backend="pallas"),
-        S((rows, 7168), BF16), S((12, rows, 1), F32), S((12,), I32),
-        S((12, 7168, 2048), BF16), S((12, 7168, 2048), BF16),
-        S((12, 2048, 7168), BF16))
-    assert _n_calls(text) == 1
+    assert moe.experts_tile(rows, width, 2048, 2) == (
+        512 if (rows, width) == (32, 6144) else 256)
+    f = lambda x, w, n, g, u, d: moe.experts(x, w, n, g, u, d,   # noqa: E731
+                                             backend="pallas")
+    args = [S((rows, width), BF16), S((held, rows, 1), F32), S((held,), I32),
+            S((held, width, 2048), BF16), S((held, width, 2048), BF16),
+            S((held, 2048, width), BF16)]
+    assert _n_calls(_tpu_text(f, *args)) == 1
+    args = [S(a.shape, a.dtype, sharding=one_chip) for a in args]
+    text = jax.jit(f).lower(*args).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert f"f32[{rows},{width}]" in text
 
 
 # -- grouped queries over bfloat16 pools, and all 32 experts held -----------
@@ -531,7 +543,8 @@ def test_grouped_paged_kernel_compiles_for_v5e(one_chip, slots, positions):
 @pytest.mark.parametrize("rows", [64, 320])
 def test_all_held_expert_product_compiles_for_v5e(one_chip, rows):
     """The grouped expert product of that cell's decode tick (64 rows) and
-    mixed tick (64 + 2 * 128): all 32 experts of 2048 x 1792 held."""
+    mixed tick (64 + 2 * 128): all 32 experts of 2048 x 1792 held, seven
+    steps of 256 columns a touched expert (PR 51: the tile is the shape's)."""
     from paddle_tpu.fusion import moe
     args = [S((rows, 2048), BF16), S((32, rows, 1), F32), S((32,), I32),
             S((32, 2048, 1792), BF16), S((32, 2048, 1792), BF16),
@@ -640,9 +653,9 @@ def test_ssm_decode_update_compiles_for_v5e_in_place(one_chip):
 
 @pytest.mark.parametrize("rows", [64, 320])
 def test_latent_expert_product_compiles_for_v5e(one_chip, rows):
-    """128 held experts of 1024 x 2688 and 2688 x 1024, a whole expert a grid
-    step (2,688 is no multiple of the gated kernel's tile of 256), under the
-    decode tick's 64 rows and the mixed tick's 64 + 2 * 128."""
+    """128 held experts of 1024 x 2688 and 2688 x 1024, seven steps of 384
+    columns a touched expert (PR 51; a whole expert a step before it), under
+    the decode tick's 64 rows and the mixed tick's 64 + 2 * 128."""
     from paddle_tpu.fusion import moe
     args = [S((rows, 1024), BF16), S((128, rows, 1), F32), S((128,), I32),
             S((128, 1024, 2688), BF16), S((128, 2688, 1024), BF16)]

@@ -301,18 +301,52 @@ def test_two_matrix_kernel_equals_composite_and_skips_the_unselected(held):
 
 
 def test_the_two_matrix_kernels_tile_comes_from_the_shape():
-    # a whole expert a step where two tiles of it, double-buffered, fit
-    assert moe.relu2_tile(1024, 2688, 2) == 2688
-    assert moe.relu2_tile(128, 384, 4) == 384
-    # a wider row: the largest divisor in whole 128-lane rows that fits
-    assert moe.relu2_tile(8192, 2688, 2) == 384
+    def tile(rows, d_model, d_expert, itemsize):
+        return moe.experts_tile(rows, d_model, d_expert, itemsize, matrices=2)
+    # the largest divisor in whole 128-lane rows of at most 512 columns
+    # (PR 51: a whole expert of 2,688 a step cost the call's first fetch)
+    assert tile(64, 1024, 2688, 2) == 384
+    assert tile(64, 128, 384, 4) == 384
+    # a wider row: the largest whose two tiles fit a step's bytes
+    assert tile(64, 8192, 2688, 2) == 384
     # a width that is no multiple of 128 has none: the composite
-    assert moe.relu2_tile(32, 48, 4) == 0
+    assert tile(64, 32, 48, 4) == 0
     assert moe.experts_lowering(64, 1024, 2688, "pallas",
-                                moe.relu2_tile(1024, 2688, 2)) == moe.KERNEL
+                                tile(64, 1024, 2688, 2)) == moe.KERNEL
     assert moe.experts_lowering(64, 32, 48, "pallas", 0) == moe.COMPOSITE
-    # 2,688 is no multiple of the gated kernel's 256
-    assert moe.experts_lowering(64, 1024, 2688, "pallas") == moe.COMPOSITE
+    # the gated kernel takes its tile from the shape too (PR 51)
+    assert moe.experts_lowering(64, 1024, 2688, "pallas") == moe.KERNEL
+
+
+# the four routed serving cells: (held, d_model, d_expert, matrices, decode
+# rows, mixed rows) -> the tile of a decode tick, of a mixed tick
+CELL_SHAPES = {
+    "assistant": ((32, 2048, 1792, 3, 64, 320), (256, 256)),
+    "bursts": ((128, 1024, 2688, 2, 64, 320), (384, 384)),
+    "long_sessions": ((16, 6144, 2048, 3, 32, 288), (512, 256)),
+    "document": ((12, 7168, 2048, 3, 32, 288), (256, 256)),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_SHAPES))
+def test_the_tile_is_a_function_of_the_shape_inside_the_vmem_budget(cell):
+    (_, d_model, d_expert, matrices, decode, mixed), want = CELL_SHAPES[cell]
+    got = tuple(moe.experts_tile(n, d_model, d_expert, 2, matrices)
+                for n in (decode, mixed))
+    assert got == want          # what Step 0 and the cells timed as best
+    for n, tile in zip((decode, mixed), got):
+        assert d_expert % tile == 0 and tile % 128 == 0
+        assert moe.experts_lowering(n, d_model, d_expert, "pallas",
+                                    tile) == moe.KERNEL
+    # under 320 rows (the widest mixed tick) either tile leaves the call's
+    # VMEM limit a quarter of its room
+    for tile in got:
+        assert moe.experts_vmem_bytes(320, d_model, tile, 2, matrices) \
+            <= 0.75 * moe._VMEM_LIMIT
+    # the rule sees the shape and nothing else: float32 stacks of the same
+    # widths take half the columns or fewer
+    assert moe.experts_tile(decode, d_model, d_expert, 4, matrices) \
+        <= got[0]
 
 
 def test_the_gated_product_keeps_its_kernel_and_its_name():
@@ -329,6 +363,78 @@ def test_the_gated_product_keeps_its_kernel_and_its_name():
         *args[:3], *args[4:]).lower(
         lowering_platforms=("tpu",)).as_text(debug_info=True)
     assert "latent_experts" in two and two.count("tpu_custom_call") == 1
+
+
+# -- the packed walk over the touched experts (PR 51) -------------------------
+
+H16 = 16                                    # held experts of the walk's tests
+
+
+def _touched_set(name):
+    on = np.zeros(H16, bool)
+    if name == "all":
+        on[:] = True
+    elif name == "first":
+        on[0] = True
+    elif name == "last":
+        on[-1] = True
+    elif name == "alternating":
+        on[::2] = True
+    elif name == "middle_run":
+        on[5:11] = True
+    elif name.startswith("random"):
+        share = int(name[len("random"):]) / 100
+        on[np.random.default_rng(int(share * 100)).choice(
+            H16, max(1, round(share * H16)), replace=False)] = True
+    else:
+        assert name == "none"
+    return on
+
+
+SETS = ("none", "all", "first", "last", "alternating", "middle_run",
+        "random15", "random75")
+
+
+@pytest.mark.parametrize("touched", SETS)
+def test_the_walks_tables(touched):
+    on = _touched_set(touched)
+    order, count = (np.asarray(a) for a in moe.packed_walk(
+        jnp.asarray(on * 3, jnp.int32)))
+    k = int(on.sum())
+    assert count.tolist() == [k] and order.dtype == np.int32
+    # the touched ids ascending and packed to the front ...
+    assert order[:k].tolist() == np.flatnonzero(on).tolist()
+    # ... and every step past them holds the LAST touched expert (expert 0
+    # where none is touched): its block is the one the step before used
+    assert (order[k:] == (np.flatnonzero(on)[-1] if k else 0)).all()
+
+
+@pytest.mark.parametrize("rows", [16, 48])          # a decode, a mixed tick
+@pytest.mark.parametrize("touched", SETS)
+@pytest.mark.parametrize("gated", [True, False], ids=["gated", "two_matrix"])
+def test_the_walk_equals_the_composite(gated, touched, rows):
+    """Both kernels, interpreted, two tiles an expert, against the composite
+    over every held expert: what no row selected is never read (its weights
+    are NaN here), what a row selected is never skipped."""
+    on = _touched_set(touched)
+    rng = np.random.default_rng(rows + len(touched))
+    w = jnp.asarray(on[:, None, None] * rng.uniform(0.1, 1.0, (H16, rows, 1))
+                    * (rng.uniform(size=(H16, rows, 1)) < 0.5), jnp.float32)
+    # an expert is touched where the router's count says so, even if the
+    # weights it hands over round to nothing
+    counts = jnp.asarray(on * 2, jnp.int32)
+    x = X[:rows]
+    clean = (GATE[:H16], UP[:H16], DOWN[:H16]) if gated else \
+        (UP[:H16], DOWN[:H16])
+    want = np.asarray(
+        moe._experts_composite(x, w, *clean) if gated
+        else moe._relu2_composite(x, w, *clean))
+    poisoned = tuple(jnp.where(jnp.asarray(on)[:, None, None], m, jnp.nan)
+                     for m in clean)
+    got = np.asarray(moe._walk_pallas(x, w, counts, poisoned, tile=128,
+                                      interpret=True))
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    assert got.any() == bool(on.any())
 
 
 def test_a_latent_spec_names_its_activation_and_widths():

@@ -1012,6 +1012,69 @@ def _check_recurrent(kind, batch, steps, hidden, backend, timing):
     return max(errs)
 
 
+def _check_train_experts(rows, top_k, d_model, d_expert, n_held, n_routed,
+                         backend, timing):
+    """The routed TRAINING layer (fusion/moe.py `train_experts`: the row
+    gather, megablox's products, the elementwise step between them and the
+    sum back, bfloat16 operands) against its composite (`jax.lax.ragged_dot`,
+    `x[index]`), the layer and all five gradients, with a quarter of the
+    pairs held (an even routing's share), 72% (what the training cell's lone
+    rank comes to hold) and all of them. On the chip the rows behind the held
+    pairs are whatever memory held. Returns the largest error."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.fusion import moe
+
+    key = jax.random.PRNGKey(11)
+    x, probe = (jax.random.normal(jax.random.fold_in(key, i),
+                                  (rows, d_model), jnp.float32)
+                for i in (0, 1))
+    gate, up = (jax.random.normal(jax.random.fold_in(key, i),
+                                  (n_held, d_model, d_expert), jnp.float32)
+                * d_model ** -0.5 for i in (2, 3))
+    down = jax.random.normal(jax.random.fold_in(key, 4),
+                             (n_held, d_expert, d_model),
+                             jnp.float32) * d_expert ** -0.5
+    w = jax.random.uniform(jax.random.fold_in(key, 5), (rows, top_k),
+                           jnp.float32, 0.05, 0.5)
+    _check(moe.rows_lowering(x, rows * top_k, jnp.bfloat16, backend)
+           == moe.KERNEL, f"no row gather serves {rows} rows of {d_model}")
+    rng = np.random.RandomState(11)
+
+    def run(be):
+        def layer(idx, x, w, gate, up, down):
+            out, sizes = moe.train_experts(x, idx, w, tuple(range(n_held)),
+                                           n_routed, gate, up, down,
+                                           backend=be)
+            return jnp.sum(out * probe), (out, sizes)
+        return jax.jit(jax.value_and_grad(layer, argnums=(1, 2, 3, 4, 5),
+                                          has_aux=True))
+    kernel, composite = run(backend), run("xla")
+    worst = 0.0
+    for share in (0.25, 0.72, 1.0):
+        # a row selects its held experts first, then the others
+        mine = rng.binomial(min(top_k, n_held), share, rows) if share < 1 \
+            else np.full(rows, min(top_k, n_held))
+        idx = np.stack([np.concatenate([
+            rng.permutation(n_held)[:n],
+            n_held + rng.permutation(n_routed - n_held)[:top_k - n]])
+            for n in mine]).astype(np.int32)
+        args = (jnp.asarray(idx), x, w, gate, up, down)
+        ((_, (got, sizes)), got_grads), c = _timed_first(kernel, *args)
+        timing["compile_s"] += c
+        t0 = time.time()
+        (_, (want, _)), want_grads = composite(*args)
+        _check(int(sizes.sum()) == int(mine.sum()), "a held pair was dropped")
+        err = max([_rel_err(got, want)] + [
+            _rel_err(a, b) for a, b in zip(got_grads, want_grads)])
+        timing["run_s"] += time.time() - t0
+        _check(err <= TOL_BF16,
+               f"routed training layer, {share:.0%} of the pairs held: "
+               f"max rel err {err:.3e} > {TOL_BF16:.1e}")
+        worst = max(worst, err)
+    return worst
+
+
 def phase_kernels(backend="pallas",
                   flash_shapes=(((8, 16, 1024, 64), True),
                                 ((64, 16, 128, 64), True),
@@ -1021,7 +1084,8 @@ def phase_kernels(backend="pallas",
                   paged=(16, 1024, 16, 16, 64, 64), chunk=(2, 128),
                   latent=(32, 5, 2048, 64, 64, 640, 512, 272),
                   experts=((True, 32, 2048, 1792, 64),
-                           (False, 128, 1024, 2688, 64))):
+                           (False, 128, 1024, 2688, 64)),
+                  train_experts=(8192, 8, 2304, 896, 16, 64)):
     """Every Pallas kernel the package selects by default on a TPU, called
     directly, compiled by Mosaic, run, and compared with its own composite.
     flash_shapes = ([B, H, T, D], causal): the training cells' shapes (the
@@ -1039,7 +1103,10 @@ def phase_kernels(backend="pallas",
     widths, five slots live at 17k positions; experts = (gated, held experts,
     d_model, d_expert, rows) a case: the assistant cell's gated product and
     the bursts cell's two-matrix one at their decode shapes, each with a
-    scattered touched set, with every expert touched and with none."""
+    scattered touched set, with every expert touched and with none;
+    train_experts = (rows, top-k, d_model, d_expert, held experts, routed
+    experts): the routed layer of the 8k training cell, forward and
+    backward, with a quarter, 72% and all of its pairs held."""
     import jax
     import jax.numpy as jnp
     from paddle_tpu.ops.pallas_kernels import _plan_for
@@ -1067,6 +1134,8 @@ def phase_kernels(backend="pallas",
     for case in experts:
         errs["experts_gated" if case[0] else "experts_two_matrix"] = \
             _check_experts(*case, backend, timing)
+    errs["train_experts"] = _check_train_experts(*train_experts, backend,
+                                                 timing)
     for kind in ("lstm", "gru"):
         errs["fused_" + kind] = _check_recurrent(kind, *recurrent, backend,
                                                  timing)

@@ -42,12 +42,15 @@ in the rows, the router and the stacks: softmax scores (`train_route`), a
 balance term (`balance_term`), and the held experts' part of the sum as a
 grouped product over the (row, expert) pairs sorted by expert
 (`train_experts`: megablox's Mosaic kernels on a TPU, `jax.lax.ragged_dot`
-elsewhere; over a pair buffer with room for an even routing's held pairs
-and a quarter more, or by `lax.cond` over the buffer of all the pairs, so no
-row is ever dropped and no count changes a shape), with counters the
-step keeps on the device. docs/fusion.md has the section. The two ops above
-stay what the serving ticks use: their kernel is the decode shape, and they
-carry no gradient.
+elsewhere; over ONE pair buffer with room for all the pairs, so no row is
+ever dropped and no count changes a shape; since PR 53 the buffer is touched
+by kernels alone, each over its live tiles: a row gather writes it, the
+products and one fused elementwise step read and write it, a sum through
+the same indices reads it back, and the rows past the held pairs are left
+as they lie), with counters the step keeps on
+the device. docs/fusion.md has the section. The two ops above stay what the
+serving ticks use: their kernel is the decode shape, and they carry no
+gradient.
 
 With `gate` None an expert is `down_e(relu(up_e x)^2)`: two matrices, no gate
 (the latent experts, whose x is a latent row between projections the layer
@@ -66,6 +69,7 @@ import jax
 import jax.numpy as jnp
 
 from ..framework.registry import register_op
+from ..ops.pallas_kernels import _traced_once
 from .decode_attention import _auto_backend
 
 KERNEL, COMPOSITE = "kernel", "composite"
@@ -357,78 +361,268 @@ def _megablox():
         "jax.experimental.pallas.ops.tpu.megablox.gmm")
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _grouped(lhs, rhs, sizes, role, transpose_rhs, interpret):
-    """lhs[rows of group e] @ rhs[e] for the groups laid one after another
-    from row 0 (`sizes` rows each; the kernel leaves the rows past their sum
-    as they lie in memory: they come back 0), by megablox's kernel under
-    `_TILINGS[role]`; lhs's dtype out, float32 sums."""
-    with jax.named_scope("moe_train_" + role):
-        out = _megablox().gmm(lhs, rhs, sizes, lhs.dtype, _TILINGS[role],
-                              transpose_rhs=transpose_rhs,
-                              interpret=interpret)
-    live = jnp.arange(lhs.shape[0])[:, None] < jnp.sum(sizes)
-    return jnp.where(live, out, jnp.zeros((), out.dtype))
+def _count(name, scope):
+    """A set-up counter (a compiled step records nothing): `moe_train/call`,
+    a call of one of the layer's kernels at a call site, and
+    `moe_train/body_traced`, a trace of its body; `scope` the kernel's name
+    in the trace's scopes."""
+    from ..observability import tracing
+    tracing.record_counter(name, 1, scope=scope)
 
 
-def _grouped_fwd(lhs, rhs, sizes, role, transpose_rhs, interpret):
-    return (_grouped(lhs, rhs, sizes, role, transpose_rhs, interpret),
-            (lhs, rhs, sizes))
+def _one_context():
+    """JAX traces a custom derivative's backward under an empty abstract
+    mesh that it SETS and its forward under none set; jit's cache takes the
+    two for different contexts and would trace (and lower to Mosaic) a
+    kernel that both call twice. Setting the mesh that is there makes them
+    one, and changes nothing under a mesh of the caller's."""
+    return jax.sharding.use_abstract_mesh(jax.sharding.get_abstract_mesh())
 
 
-def _grouped_bwd(role, transpose_rhs, interpret, res, g):
-    lhs, rhs, sizes = res
-    assert not transpose_rhs
-    d_lhs = _grouped(g, rhs, sizes, role + "_t", True, interpret)
-    with jax.named_scope("moe_train_" + role + "_w"):
-        d_rhs = _megablox().tgmm(lhs.swapaxes(0, 1), g, sizes, rhs.dtype,
-                                 _TILINGS[role + "_w"], interpret=interpret)
-    return d_lhs, d_rhs, None
+def _kernel_call(kernel, scope, backend, *operands, **static):
+    """One call site of one of the layer's jitted kernels: counted, and
+    traced in the one context."""
+    _count("moe_train/call", scope)
+    with _one_context():
+        return kernel(*operands, interpret=backend == "pallas_interpret",
+                      **static)
 
 
-_grouped.defvjp(_grouped_fwd, _grouped_bwd)
+@_traced_once
+def _product(lhs, rhs, sizes, *, role, interpret):
+    """One of the layer's six grouped products by megablox's kernel under
+    `_TILINGS[role]`, its body traced once a step: `in` / `out` are lhs[rows
+    of group e] @ rhs[e], `in_t` / `out_t` the same against rhs[e]
+    transposed (lhs's dtype out), `in_w` / `out_w` the weight gradients
+    lhs[rows of e]^T @ rhs[rows of e] (float32 sums, `rhs`'s dtype out). The
+    kernels visit the tiles that hold a group's rows and no other: the rows
+    past `sum(sizes)` are left as they lie in memory, in the result, and are
+    read by nobody (a weight gradient selects its rows by the groups)."""
+    scope = "moe_train_" + role
+    _count("moe_train/body_traced", scope)
+    with jax.named_scope(scope):
+        if role.endswith("_w"):
+            return _megablox().tgmm(lhs.swapaxes(0, 1), rhs, sizes, rhs.dtype,
+                                    _TILINGS[role], interpret=interpret)
+        return _megablox().gmm(lhs, rhs, sizes, lhs.dtype, _TILINGS[role],
+                               transpose_rhs=role.endswith("_t"),
+                               interpret=interpret)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _expand(x, perm, inv, k):
-    """x [N, D] -> the rows of the sorted pairs `perm` holds, [m, D]: pair
-    `perm[i]` is row `perm[i] // k`. Its transpose is `_combine` (a gather
-    too: the derivative XLA would write is a scatter-add over the rows)."""
-    return x[jnp.minimum(perm // k, x.shape[0] - 1)]
+def _products(sizes, backend):
+    """The layer's products over the pair buffer, by role (`_product`): (a
+    row product `a[rows of e] @ b[e]`, or against b[e] transposed under a
+    role that ends in `_t`; a weight gradient `a[rows of e]^T @ g[rows of
+    e]`). megablox's kernels, or under backend "xla" (off a TPU)
+    `jax.lax.ragged_dot` and its own derivative: XLA's lowering of it
+    reached 14% of the chip's peak at the training cell's shape where the
+    kernels reach 60% (PERF.md section 6, PR 50). The rows past the groups
+    are DEAD out of a kernel: whatever memory held, NaN or Inf among it.
+    Every product and every elementwise step keeps a row's garbage in that
+    row, and what sums over rows selects the live ones first (`tgmm` by its
+    groups, `_pair_total` by the count)."""
+    if backend == "xla":
+        def ragged(a, b):
+            return jax.lax.ragged_dot(a, b, sizes,
+                                      preferred_element_type=jnp.float32)
+
+        def rows(a, b, role):
+            return ragged(a, b.swapaxes(1, 2) if role.endswith("_t") else b
+                          ).astype(a.dtype)
+
+        def stack(a, g, role):
+            like = jax.ShapeDtypeStruct(
+                (sizes.shape[0], a.shape[1], g.shape[1]), g.dtype)
+            return jax.linear_transpose(lambda b: ragged(a, b), like)(
+                g.astype(jnp.float32))[0]
+        return rows, stack
+
+    def kernel(a, b, role):
+        return _kernel_call(_product, "moe_train_" + role, backend, a, b,
+                            sizes, role=role)
+    return kernel, kernel
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _combine(y, perm, inv, k):
+# VMEM the row gather may take: the source whole, a tile of rows staged in
+# the source's dtype and the result's tile twice (the pipeline's); and the
+# elementwise step's: its tiles twice and a handful of float32 forms of one
+_ROWS_VMEM_LIMIT = 100 * 1024 * 1024
+_GATED_VMEM_LIMIT = 64 * 1024 * 1024
+
+
+def _live_tiles(m, count, interpret):
+    """The grid of a kernel of this file over the pair buffer: it ends with
+    the live rows' last tile of `_PAIR_TILE` (the interpreter wants a static
+    grid and walks them all): a tile past it is never read or written, as
+    megablox leaves it."""
+    tiles = m // _PAIR_TILE
+    return tiles if interpret else jnp.clip(-(-count[0] // _PAIR_TILE), 1,
+                                            tiles)
+
+
+def _rows_kernel(index_ref, count_ref, x_ref, o_ref, stage_ref):
+    """One tile of the row gather: `o[i] = x[index[i]]` for the tile's rows,
+    x whole in VMEM, a row a dynamic-sublane load (a row of a tiled array in
+    HBM is no contiguous span: Mosaic refuses the slice a row DMA would need),
+    eight rows stored as one aligned tile, then the tile cast at once."""
+    from jax.experimental import pallas as pl
+
+    tile = o_ref.shape[0]
+    base = pl.program_id(0) * tile
+
+    def eight(i, carry):
+        at = pl.multiple_of(i * 8, 8)
+        stage_ref[pl.ds(at, 8), :] = jnp.concatenate(
+            [x_ref[pl.ds(index_ref[base + at + u], 1), :] for u in range(8)],
+            axis=0)
+        return carry
+
+    jax.lax.fori_loop(0, tile // 8, eight, 0)
+    o_ref[...] = stage_ref[...].astype(o_ref.dtype)
+
+
+@_traced_once
+def _rows_pallas(x, index, count, *, dtype, interpret):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    _count("moe_train/body_traced", "moe_train_rows")
+    m, (_, d) = index.shape[0], x.shape
+    with jax.named_scope("moe_train_rows"):
+        return pl.pallas_call(
+            _rows_kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2,
+                grid=(_live_tiles(m, count, interpret),),
+                in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
+                out_specs=pl.BlockSpec((_PAIR_TILE, d), lambda t, *_: (t, 0)),
+                scratch_shapes=[pltpu.VMEM((_PAIR_TILE, d), x.dtype)]),
+            out_shape=jax.ShapeDtypeStruct((m, d), dtype),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",),
+                vmem_limit_bytes=_ROWS_VMEM_LIMIT),
+            interpret=interpret,
+        )(index, count, x)
+
+
+def rows_lowering(x, m, dtype, backend):
+    """KERNEL where the row gather's kernel serves the shape: rows of 32-bit
+    values in whole 128-lane tiles, the source and the kernel's tiles within
+    `_ROWS_VMEM_LIMIT`, whole tiles of `_PAIR_TILE` to write."""
+    n, d = x.shape
+    held = (n * d * 4 + _PAIR_TILE * d * (4 + 2 * jnp.dtype(dtype).itemsize)
+            + m * 4)
+    served = (x.dtype.itemsize == 4 and d % 128 == 0 and m % _PAIR_TILE == 0
+              and _PAIR_TILE % 8 == 0 and held <= _ROWS_VMEM_LIMIT)
+    return KERNEL if served and backend != "xla" else COMPOSITE
+
+
+def pair_rows(x, index, count, dtype, backend):
+    """x [N, D] -> x[index] as `dtype`, [m, D], for the first `count[0]` of
+    the m sorted pairs, whole tiles of `_PAIR_TILE`: the rows past them are
+    DEAD (`_products`). The kernel where it serves the shape, else
+    `x[index]`, every row of it."""
+    if rows_lowering(x, index.shape[0], dtype, backend) == KERNEL:
+        return _kernel_call(_rows_pallas, "moe_train_rows", backend, x,
+                            index, count, dtype=jnp.dtype(dtype))
+    return x[index].astype(dtype)
+
+
+def _pair_total(y, inv, count, k):
     """The sorted pairs' rows y [m, D] -> [N, D] float32: a row's k pairs
-    summed (`inv[p]`: where pair p lies in the sorted order; a pair past the
-    m rows y has adds nothing)."""
-    m = y.shape[0]
-    rows = jnp.where((inv < m)[:, None], y[jnp.minimum(inv, m - 1)],
+    summed (`inv[p]`: where pair p lies in the sorted order). A pair at or
+    past `count[0]` is dead and is SELECTED away, never multiplied: its row
+    may hold NaN."""
+    rows = jnp.where((inv < count[0])[:, None],
+                     y[jnp.minimum(inv, y.shape[0] - 1)],
                      jnp.zeros((), y.dtype)).astype(jnp.float32)
     return jnp.sum(rows.reshape(-1, k, y.shape[-1]), axis=1)
 
 
-def _expand_fwd(x, perm, inv, k):
-    return _expand(x, perm, inv, k), (perm, inv)
+def _total_kernel(source_ref, count_ref, y_ref, o_ref):
+    """One tile of the sum back: `o[source[i]] += y[i]` for the tile's LIVE
+    rows, o [N, D] float32 whole in VMEM for the call, zeroed by the first
+    step. y's 16-bit rows lie two to a 32-bit sublane, rows 2q and 2q + 1 in
+    the low and high halves of word row q, and a bfloat16 value is the high
+    half of its float32: a word row is loaded once and shifted or masked
+    into both rows' float32, no row of y is unpacked. A dead row is never
+    read: the loop ends with the live rows."""
+    from jax.experimental import pallas as pl
+
+    tile = y_ref.shape[0]
+    base = pl.program_id(0) * tile
+
+    @pl.when(pl.program_id(0) == 0)
+    def _():
+        o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
+
+    words = y_ref.bitcast(jnp.uint32)
+    live = jnp.clip(count_ref[0] - base, 0, tile)
+
+    def add(row, bits):
+        at = pl.ds(source_ref[base + row], 1)
+        o_ref[at, :] += jax.lax.bitcast_convert_type(bits, jnp.float32)
+
+    def two(q, carry):
+        word = words[pl.ds(q, 1), :]
+        add(2 * q, word << 16)
+        add(2 * q + 1, word & jnp.uint32(0xFFFF0000))
+        return carry
+
+    jax.lax.fori_loop(0, live // 2, two, 0)
+
+    @pl.when(live % 2 == 1)
+    def _():
+        add(live - 1, words[pl.ds(live // 2, 1), :] << 16)
 
 
-def _expand_bwd(k, res, g):
-    perm, inv = res
-    return _combine(g, perm, inv, k).astype(g.dtype), None, None
+@_traced_once
+def _total_pallas(y, source, count, *, n, interpret):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    _count("moe_train/body_traced", "moe_train_total")
+    m, d = y.shape
+    with jax.named_scope("moe_train_total"):
+        return pl.pallas_call(
+            _total_kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2,
+                grid=(_live_tiles(m, count, interpret),),
+                in_specs=[pl.BlockSpec((_PAIR_TILE, d),
+                                       lambda t, *_: (t, 0))],
+                out_specs=pl.BlockSpec(memory_space=pltpu.VMEM)),
+            out_shape=jax.ShapeDtypeStruct((n, d), jnp.float32),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",),
+                vmem_limit_bytes=_ROWS_VMEM_LIMIT),
+            interpret=interpret,
+        )(source, count, y)
 
 
-def _combine_fwd(y, perm, inv, k):
-    # an empty array carries y's dtype: a dtype is no residual JAX takes
-    return _combine(y, perm, inv, k), (perm, inv, jnp.zeros((0,), y.dtype))
+def total_lowering(y, n, backend):
+    """KERNEL where the sum back's kernel serves the shape: bfloat16 rows in
+    whole 128-lane tiles, whole tiles of `_PAIR_TILE` to read, the float32
+    result and the kernel's tiles within `_ROWS_VMEM_LIMIT`."""
+    m, d = y.shape
+    held = n * d * 4 + 2 * _PAIR_TILE * d * 2 + m * 4
+    served = (y.dtype == jnp.bfloat16 and d % 128 == 0
+              and m % _PAIR_TILE == 0 and _PAIR_TILE % 16 == 0
+              and held <= _ROWS_VMEM_LIMIT)
+    return KERNEL if served and backend != "xla" else COMPOSITE
 
 
-def _combine_bwd(k, res, g):
-    perm, inv, like = res
-    return _expand(g.astype(like.dtype), perm, inv, k), None, None
-
-
-_expand.defvjp(_expand_fwd, _expand_bwd)
-_combine.defvjp(_combine_fwd, _combine_bwd)
+def pair_total(y, source, inv, count, k, backend):
+    """The sorted pairs' rows y [m, D] summed back to their rows, [N, D]
+    float32 (N = inv.size // k): `out[source[i]] += y[i]` over the first
+    `count[0]` sorted pairs by the kernel where it serves the shape (in the
+    sorted order, one expert's pairs after another's), else `_pair_total`
+    through `inv` (in a row's own order of its k pairs)."""
+    n = inv.shape[0] // k
+    if total_lowering(y, n, backend) == KERNEL:
+        return _kernel_call(_total_pallas, "moe_train_total", backend, y,
+                            source, count, n=n)
+    return _pair_total(y, inv, count, k)
 
 
 def sort_pairs(idx, held, n_routed):
@@ -450,88 +644,141 @@ def sort_pairs(idx, held, n_routed):
     return perm, inv, sizes
 
 
-def _pair_rows(n_pairs, n_held, n_routed):
-    """The rows of the two pair buffers, whole tiles of `_PAIR_TILE`: the
-    held pairs an even routing makes and a quarter more (seeded routers are
-    not even: PERF.md section 6, PR 50), and ALL the pairs, which no routing
-    overflows. Both are in the program: nothing is dropped and no count
-    changes a shape."""
-    even = n_pairs * n_held // n_routed
-    first, whole = (-(-n // _PAIR_TILE) * _PAIR_TILE
-                    for n in (even + even // 4, n_pairs))
-    return (first, whole) if first < whole else (whole,)
+def _gated(gu, ws, d_hidden=None):
+    """The one elementwise step between the products: [gate | up] rows gu
+    [m, 2 F] and the pairs' weights ws [m, 1] -> hidden = silu(gate) * up *
+    ws in gu's dtype; with the hidden rows' cotangent also (d_gu, d_ws), the
+    derivative. float32 inside."""
+    width = gu.shape[-1] // 2
+    gate, up = (gu[:, :width].astype(jnp.float32),
+                gu[:, width:].astype(jnp.float32))
+    sig = jax.nn.sigmoid(gate)
+    act = gate * sig * up
+    hidden = (act * ws).astype(gu.dtype)
+    if d_hidden is None:
+        return hidden
+    d_hidden = d_hidden.astype(jnp.float32)
+    by_w = d_hidden * ws
+    d_gu = jnp.concatenate(
+        [by_w * up * (sig * (1.0 + gate * (1.0 - sig))), by_w * gate * sig],
+        axis=-1).astype(gu.dtype)
+    return hidden, d_gu, jnp.sum(d_hidden * act, axis=-1, keepdims=True)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9, 10, 11))
-def _held_sum(x, w, gate, up, down, perm, inv, sizes, k, rows, backend,
+def _gated_kernel(count_ref, gu_ref, ws_ref, *refs):
+    """`_gated` over one tile of the pair buffer, forward (hidden out) or
+    backward (the cotangent in; hidden, d_gu, d_ws out)."""
+    if len(refs) == 1:
+        refs[0][...] = _gated(gu_ref[...], ws_ref[...])
+    else:
+        d_hidden_ref, *outs = refs
+        for ref, value in zip(outs, _gated(gu_ref[...], ws_ref[...],
+                                           d_hidden_ref[...])):
+            ref[...] = value
+
+
+@_traced_once
+def _gated_pallas(gu, ws, d_hidden, count, *, interpret):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    scope = "moe_train_gate" + ("" if d_hidden is None else "_bwd")
+    _count("moe_train/body_traced", scope)
+    m, wide = gu.shape
+
+    def rows_of(width):
+        return pl.BlockSpec((_PAIR_TILE, width), lambda t, *_: (t, 0))
+
+    hidden = jax.ShapeDtypeStruct((m, wide // 2), gu.dtype)
+    ins, in_specs = [gu, ws], [rows_of(wide), rows_of(1)]
+    out_shape, out_specs = hidden, rows_of(wide // 2)
+    if d_hidden is not None:
+        ins, in_specs = ins + [d_hidden], in_specs + [rows_of(wide // 2)]
+        out_shape = (hidden, jax.ShapeDtypeStruct(gu.shape, gu.dtype),
+                     jax.ShapeDtypeStruct((m, 1), jnp.float32))
+        out_specs = (rows_of(wide // 2), rows_of(wide), rows_of(1))
+    with jax.named_scope(scope):
+        return pl.pallas_call(
+            _gated_kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1, grid=(_live_tiles(m, count, interpret),),
+                in_specs=in_specs, out_specs=out_specs),
+            out_shape=out_shape,
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",),
+                vmem_limit_bytes=_GATED_VMEM_LIMIT),
+            interpret=interpret,
+        )(count, *ins)
+
+
+def _gated_rows(gu, ws, count, backend, d_hidden=None):
+    """`_gated` over the live tiles of the pair buffer by its kernel (the
+    rows past the held pairs are left as they lie, dead: `_products`), or
+    where the kernel does not serve (backend "xla", a width of no whole
+    128-lane tiles) over every row by XLA."""
+    m, wide = gu.shape
+    if backend == "xla" or wide % 256 or m % _PAIR_TILE or _PAIR_TILE % 16:
+        return _gated(gu, ws, d_hidden)
+    return _kernel_call(
+        _gated_pallas, "moe_train_gate" + ("" if d_hidden is None else "_bwd"),
+        backend, gu, ws, d_hidden, count)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9, 10))
+def _held_sum(x, w, gate, up, down, perm, inv, sizes, k, backend,
               compute_dtype):
-    """`train_experts` on sorted pairs, over the first of the buffers `rows`
-    that holds the held pairs. Its backward keeps none of the buffer's rows:
-    it gathers and multiplies them again from x (one more [gate | up]
-    product a layer, for the gathered rows, the hidden rows and their
-    products' outputs of every layer not held from the forward to the
-    backward)."""
-    return _by_buffer(sizes, rows, lambda m: _pair_sum(
-        x, w, gate, up, down, perm, inv, sizes, k, m, backend,
-        compute_dtype))
+    """`train_experts` on sorted pairs. What touches the pair buffer [m, D]:
+    the row gather writes it (`pair_rows`), the products and the one fused
+    elementwise step between them (`_gated_rows`) read and write its LIVE
+    tiles, and the sum back reads the live rows (`pair_total`, through the
+    gather's own indices). No pass zeroes a dead row and, under the
+    kernels, none runs over one. The stacks are cast (and [gate | up]
+    joined) once, here, and the backward takes the forward's copies and the
+    forward's [gate | up] product (235 MB a layer at the training cell's
+    shape); the pairs' rows it gathers again (302 MB a layer not held, for
+    0.7 ms of the gather's kernel: PERF.md section 6, PR 53). Six products
+    and their elementwise steps by hand: every kernel's body is then traced
+    in one context, once a step."""
+    return _held_sum_fwd(x, w, gate, up, down, perm, inv, sizes, k, backend,
+                         compute_dtype)[0]
 
 
-def _by_buffer(sizes, rows, run):
-    if len(rows) == 1:
-        return run(rows[0])
-    return jax.lax.cond(jnp.sum(sizes) > rows[0],
-                        functools.partial(run, rows[1]),
-                        functools.partial(run, rows[0]))
-
-
-def _pair_sum(x, w, gate, up, down, perm, inv, sizes, k, m, backend,
-              compute_dtype):
-    """The layer over the first m sorted pairs (every held one is among
-    them): their rows gathered, [gate | up] as ONE grouped product, the
-    weighted hidden rows through down, a row's pairs summed back. The
-    products are megablox's kernels, or under backend "xla" (off a TPU)
-    `jax.lax.ragged_dot`: XLA's own lowering of it reached 14% of the chip's
-    peak at the training cell's shape where the kernels reach 60% (PERF.md
-    section 6, PR 50)."""
-    perm = perm[:m]
-    xs = _expand(x.astype(compute_dtype), perm, inv, k)
-    ws = _expand(w.reshape(-1, 1), perm, inv, 1)
-    width = gate.shape[-1]
+def _held_sum_fwd(x, w, gate, up, down, perm, inv, sizes, k, backend,
+                  compute_dtype):
+    rows, _ = _products(sizes, backend)
+    count = jnp.sum(sizes).reshape(1)
     wide = jnp.concatenate([gate, up], axis=-1).astype(compute_dtype)
     narrow = down.astype(compute_dtype)
-    if backend == "xla":
-        def grouped(a, b, role):
-            return jax.lax.ragged_dot(
-                a, b, sizes, preferred_element_type=jnp.float32
-            ).astype(compute_dtype)
-    else:
-        def grouped(a, b, role):
-            return _grouped(a, b, sizes, role, False,
-                            backend == "pallas_interpret")
-    # the rows past the held pairs are 0 out of either product
-    gu = grouped(xs, wide, "in").astype(jnp.float32)
-    hidden = jax.nn.silu(gu[:, :width]) * gu[:, width:] * ws
-    y = grouped(hidden.astype(compute_dtype), narrow, "out")
-    return _combine(y, perm, inv, k)
+    source = jnp.minimum(perm // k, x.shape[0] - 1)
+    ws = w.reshape(-1, 1)[jnp.minimum(perm, w.size - 1)]
+    gu = rows(pair_rows(x, source, count, compute_dtype, backend), wide, "in")
+    y = rows(_gated_rows(gu, ws, count, backend), narrow, "out")
+    # empty arrays carry the operands' dtypes: a dtype is no residual
+    like = tuple(jnp.zeros((0,), a.dtype) for a in (w, gate, up, down))
+    return (pair_total(y, source, inv, count, k, backend),
+            (x, source, gu, ws, wide, narrow, inv, sizes, count, like))
 
 
-def _held_sum_fwd(x, w, gate, up, down, perm, inv, sizes, k, rows, backend,
-                  compute_dtype):
-    out = _held_sum(x, w, gate, up, down, perm, inv, sizes, k, rows, backend,
-                    compute_dtype)
-    return out, (x, w, gate, up, down, perm, inv, sizes)
-
-
-def _held_sum_bwd(k, rows, backend, compute_dtype, res, g):
-    x, w, gate, up, down, perm, inv, sizes = res
-
-    def back(m):
-        _, vjp = jax.vjp(
-            lambda *a: _pair_sum(*a, perm, inv, sizes, k, m, backend,
-                                 compute_dtype), x, w, gate, up, down)
-        return vjp(g)
-
-    return _by_buffer(sizes, rows, back) + (None, None, None)
+def _held_sum_bwd(k, backend, compute_dtype, res, g):
+    # behind a barrier, so that XLA shares no subexpression of the backward
+    # with the forward: the gathered rows and the float32 forms of gu would
+    # else stay alive from one to the other (1.2 GB and more a step)
+    x, source, gu, ws, wide, narrow, inv, sizes, count, like = \
+        jax.lax.optimization_barrier(res)
+    rows, stack = _products(sizes, backend)
+    xs = pair_rows(x, source, count, compute_dtype, backend)
+    d_y = pair_rows(g, source, count, compute_dtype, backend)
+    hidden, d_gu, d_ws = _gated_rows(gu, ws, count, backend,
+                                     rows(d_y, narrow, "out_t"))
+    d_narrow = stack(hidden, d_y, "out_w")
+    d_wide = stack(xs, d_gu, "in_w")
+    width = narrow.shape[1]
+    grads = (_pair_total(d_ws, inv, count, 1).reshape(-1, k),
+             d_wide[..., :width], d_wide[..., width:], d_narrow)
+    return (pair_total(rows(d_gu, wide, "in_t"), source, inv, count, k,
+                       backend).astype(x.dtype),
+            *(a.astype(b.dtype) for a, b in zip(grads, like)),
+            None, None, None)
 
 
 _held_sum.defvjp(_held_sum_fwd, _held_sum_bwd)
@@ -543,17 +790,14 @@ def train_experts(x, idx, w, held, n_routed, gate, up, down, backend=None,
     w * down_e(silu(gate_e x) * up_e x): x [N, D], idx, w [N, k]
     (`train_route`), stacks [n_held, D, F] / [n_held, F, D] -> ([N, D]
     float32, sizes [n_held] int32). The pairs sorted by held expert, the
-    held ones' rows gathered into a buffer, grouped products over it, a
-    row's pairs summed back (`_pair_sum`); differentiable in x, w and the
-    stacks. The buffer is the first of `_pair_rows`' two that holds the held
-    pairs: room for an even routing's and a quarter more, else all N x k
-    pairs, so no routing drops a row. Static shapes: the routing changes
-    `sizes` and which buffer a step takes, not the program."""
+    held ones' rows gathered into ONE buffer with room for all N x k pairs,
+    grouped products over its live tiles, a row's pairs summed back
+    (`_held_sum`); differentiable in x, w and the stacks. No routing drops a
+    row and none changes a shape: the routing changes `sizes`, and with them
+    how many tiles the kernels visit."""
     perm, inv, sizes = sort_pairs(idx, held, n_routed)
-    rows = _pair_rows(idx.size, len(held), n_routed)
     out = _held_sum(x, w, gate, up, down, perm, inv, sizes, idx.shape[1],
-                    rows, backend or _auto_backend(),
-                    jnp.dtype(compute_dtype))
+                    backend or _auto_backend(), jnp.dtype(compute_dtype))
     return out, sizes
 
 

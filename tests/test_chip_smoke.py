@@ -112,12 +112,14 @@ def test_kernels_phase_through_the_interpreter():
         paged=(5, 40, 8, 4, 16, 6),     # 8 rows of 16: one 128-lane row
         chunk=(2, 16),
         latent=(4, 2, 24, 16, 8, 256, 128, 6),
-        experts=((True, 8, 128, 256, 16), (False, 8, 128, 384, 16)))
+        experts=((True, 8, 128, 256, 16), (False, 8, 128, 384, 16)),
+        train_experts=(256, 2, 128, 128, 4, 8))
     flash = {"flash_1x2x256x64", "flash_1x2x256x64_seg",
              "flash_2x2x128x64_full", "flash_2x2x128x64_full_seg"}
     assert set(out["max_rel_err"]) == flash | {f + "_tm" for f in flash} | {
         "decode_T1408", "paged_decode", "paged_chunk", "latent_decode",
-        "experts_gated", "experts_two_matrix", "fused_lstm", "fused_gru"}
+        "experts_gated", "experts_two_matrix", "train_experts", "fused_lstm",
+        "fused_gru"}
     assert out["flash_plans"]["flash_1x2x256x64"] == [
         "flash_fwd_resident_q256_k256_rows2",
         "flash_bwd_resident_q256_k256_rows2"]

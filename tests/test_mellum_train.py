@@ -29,6 +29,7 @@ from paddle_tpu.ops import pallas_kernels as pk
 from benchmark.models import mellum
 
 T, B = 32, 2
+S = jax.ShapeDtypeStruct
 YARN = {"rope_type": "yarn", "rope_theta": 500000, "factor": 16,
         "original_max_position_embeddings": 8, "beta_fast": 32,
         "beta_slow": 1, "attention_factor": 1.2772588722239782}
@@ -205,16 +206,16 @@ def test_the_counters_read_through_the_registry(step):
 N, D, F, E, K = 48, 32, 16, 8, 2
 
 
-def _layer_operands(seed=0):
+def _layer_operands(seed=0, d=D, f=F):
     key = jax.random.PRNGKey(seed)
     ks = jax.random.split(key, 6)
     return dict(
-        x=jax.random.normal(ks[0], (N, D)),
-        router=jax.random.normal(ks[1], (D, E)) * 0.5,
-        gate=jax.random.normal(ks[2], (E, D, F)) * D ** -0.5,
-        up=jax.random.normal(ks[3], (E, D, F)) * D ** -0.5,
-        down=jax.random.normal(ks[4], (E, F, D)) * F ** -0.5,
-        probe=jax.random.normal(ks[5], (N, D)))
+        x=jax.random.normal(ks[0], (N, d)),
+        router=jax.random.normal(ks[1], (d, E)) * 0.5,
+        gate=jax.random.normal(ks[2], (E, d, f)) * d ** -0.5,
+        up=jax.random.normal(ks[3], (E, d, f)) * d ** -0.5,
+        down=jax.random.normal(ks[4], (E, f, d)) * f ** -0.5,
+        probe=jax.random.normal(ks[5], (N, d)))
 
 
 def _routed_part(ops, held, backend="xla"):
@@ -238,10 +239,9 @@ def _routed_part(ops, held, backend="xla"):
 
 @pytest.fixture(params=[512, 8])
 def pair_tile(request, monkeypatch):
-    """The pair buffer's tile: at 512 the tiny layer has ONE buffer (all 96
-    pairs pad to a tile); at 8 the first has 64 rows (48 an even routing
-    holds, 12 more, whole tiles), the other all 96, and a routing that holds
-    more than 64 pairs takes the second."""
+    """The pair buffer's tile: at 512 the tiny layer's 96 pairs pad to one
+    tile of 512 rows, most of them behind the pairs; at 8 the buffer is the
+    96 pairs and a routing that holds them all leaves no dead row."""
     monkeypatch.setattr(moe, "_PAIR_TILE", request.param)
     return request.param
 
@@ -251,8 +251,8 @@ def test_the_ranks_shares_sum_to_the_uncut_layer(pair_tile):
     all eight experts, and so do their gradients: of the rows, of the ROUTER
     (each rank's weights are normalised over all the selected, held or not)
     and of the stacks (a rank's stack gets the uncut layer's slice)."""
-    assert moe._pair_rows(N * K, 4, E) == ((512,) if pair_tile == 512
-                                           else (64, 96))
+    assert moe.sort_pairs(jnp.zeros((N, K), jnp.int32), range(4), E)[0].shape \
+        == ((512,) if pair_tile == 512 else (N * K,))
     ops = _layer_operands()
     whole, sizes, g_whole = _routed_part(ops, range(8))
     parts = [_routed_part(ops, r) for r in (range(0, 4), range(4, 8))]
@@ -268,9 +268,9 @@ def test_the_ranks_shares_sum_to_the_uncut_layer(pair_tile):
 
 def test_no_row_is_dropped_where_one_expert_takes_every_row(pair_tile):
     """A router that sends every row to expert 0 first and expert 1 second:
-    held here, they get all 2 N pairs, more than the usual buffer's rows
-    (the step then takes the buffer of all the pairs), and the layer and its
-    gradients are the two experts' dense products'."""
+    held here, they get all 2 N pairs (at a tile of 8 the buffer then has
+    no dead row at all), and the layer and its gradients are the two
+    experts' dense products'."""
     ops = _layer_operands(1)
     # scores that grow with the expert's index reversed: expert 0, then 1
     router = jnp.zeros((D, E)).at[0].set(jnp.arange(E, 0, -1.0))
@@ -297,24 +297,214 @@ def test_no_row_is_dropped_where_one_expert_takes_every_row(pair_tile):
     (_, (want, _)), g_want = jax.value_and_grad(
         dense, argnums=(0, 1, 2, 3, 4), has_aux=True)(*args)
     assert np.asarray(sizes).tolist() == [N, N, 0, 0]
-    assert 2 * N > moe._pair_rows(N * K, 4, E)[0] or pair_tile == 512
+    assert 2 * N == moe.sort_pairs(idx, held, E)[0].shape[0] or \
+        pair_tile == 512
     assert _rel(got, want) < 1e-5
     for a, b in zip(g_got, g_want):
         assert _rel(a, b) < 1e-5
 
 
-def test_the_kernel_product_is_the_composites(monkeypatch):
-    """megablox's kernels (interpreted) under fusion/moe.py's custom
-    derivative against `jax.lax.ragged_dot`'s own: the layer and all five
-    gradients, with dead rows behind the held pairs."""
-    monkeypatch.setattr(moe, "_TILINGS",
-                        {k: (128, 128, 128) for k in moe._TILINGS})
-    ops = _layer_operands(2)
-    want = _routed_part(ops, (1, 2, 4, 6))
-    got = _routed_part(ops, (1, 2, 4, 6), backend="pallas_interpret")
-    assert _rel(got[0], want[0]) < 1e-5
+def _routing_that_holds(pairs, n=N, k=K, held=4, routed=E):
+    """idx [n, k] of distinct experts a row with exactly `pairs` selections
+    among the first `held` ids."""
+    rng = np.random.RandomState(pairs)
+    base, extra = divmod(pairs, n)
+    idx = np.empty((n, k), np.int32)
+    for row in range(n):
+        mine = base + (row < extra)
+        idx[row, :mine] = rng.choice(held, mine, replace=False)
+        idx[row, mine:] = held + rng.choice(routed - held, k - mine,
+                                            replace=False)
+    return jnp.asarray(idx[rng.permutation(n)])
+
+
+def _layer_at(ops, idx, backend, compute_dtype=jnp.float32):
+    """(the layer, sizes, gradients wrt x, w and the three held stacks) for
+    a routing given as indices, the first four experts held."""
+    w = jax.nn.softmax(ops["x"][:, :K] * 3.0, axis=-1)
+
+    def f(x, w, gate, up, down):
+        out, sizes = moe.train_experts(x, idx, w, (0, 1, 2, 3), E, gate, up,
+                                       down, backend=backend,
+                                       compute_dtype=compute_dtype)
+        return jnp.sum(out * ops["probe"]), (out, sizes)
+
+    (_, (out, sizes)), grads = jax.value_and_grad(
+        f, argnums=(0, 1, 2, 3, 4), has_aux=True)(
+            ops["x"], w, ops["gate"][:4], ops["up"][:4], ops["down"][:4])
+    return out, sizes, grads
+
+
+def _poison_dead_rows(monkeypatch):
+    """The chip's dead rows on a CPU: every row past the held pairs comes
+    back NaN from the row gather, from the four row products and from the
+    elementwise step's kernel (the interpreter would hand back zeros, and a
+    grid it cannot cut short writes every tile)."""
+    def dead_as_nan(out, count):
+        live = jnp.arange(out.shape[0])[:, None] < count
+        return jnp.where(live, out, jnp.nan)
+
+    product, rows, gated = moe._product, moe._rows_pallas, moe._gated_pallas
+    # (the sum back's kernel reads the poisoned rows: it writes none)
+
+    def poisoned_product(lhs, rhs, sizes, *, role, interpret):
+        out = product(lhs, rhs, sizes, role=role, interpret=interpret)
+        return out if role.endswith("_w") else dead_as_nan(out, sizes.sum())
+
+    def poisoned_rows(x, index, count, *, dtype, interpret):
+        return dead_as_nan(rows(x, index, count, dtype=dtype,
+                                interpret=interpret), count[0])
+
+    def poisoned_gated(gu, ws, d_hidden, count, *, interpret):
+        out = gated(gu, ws, d_hidden, count, interpret=interpret)
+        if d_hidden is None:
+            return dead_as_nan(out, count[0])
+        return tuple(dead_as_nan(o, count[0]) for o in out)
+
+    monkeypatch.setattr(moe, "_product", poisoned_product)
+    monkeypatch.setattr(moe, "_gated_pallas", poisoned_gated)
+    monkeypatch.setattr(moe, "_rows_pallas", poisoned_rows)
+
+
+@pytest.mark.parametrize("case", [
+    "routed", "held-0", "held-25", "held-72", "held-100",
+    "poisoned-0", "poisoned-25", "poisoned-72", "poisoned-100",
+    "poisoned-bf16-0", "poisoned-bf16-72", "poisoned-bf16-100"])
+def test_the_kernel_product_is_the_composites(monkeypatch, case):
+    """The layer's kernels (interpreted: megablox's six products and the row
+    gather) under fusion/moe.py's custom derivative against
+    `jax.lax.ragged_dot`'s own and `x[index]`: the layer and all five
+    gradients. `routed`: the tiny layer as its router draws it (rows too
+    narrow for the row gather's kernel). `held-<share>`: rows of 128 and a
+    buffer of three tiles of 32, that share of the 96 pairs on the four held
+    experts, so none, a partial last tile, or every row of the buffer is
+    live. `poisoned-<share>`: the same with every dead row NaN, as the chip
+    may leave it: output and gradients are still finite and the same.
+    `poisoned-bf16-<share>`: bfloat16 operands as a step has them, where
+    the sum back is its kernel too; both sides round their products to
+    bfloat16, so they agree to a few of its steps (2 ** -8 each)."""
+    if case == "routed":
+        monkeypatch.setattr(moe, "_TILINGS",
+                            {k: (128, 128, 128) for k in moe._TILINGS})
+        ops = _layer_operands(2)
+        want = _routed_part(ops, (1, 2, 4, 6))
+        got = _routed_part(ops, (1, 2, 4, 6), backend="pallas_interpret")
+    else:
+        monkeypatch.setattr(moe, "_PAIR_TILE", 32)
+        monkeypatch.setattr(moe, "_TILINGS",
+                            {k: (32, 128, 128) for k in moe._TILINGS})
+        kind, *bf16, share = case.split("-")
+        dtype, tol = (jnp.bfloat16, 2e-2) if bf16 else (jnp.float32, 1e-5)
+        pairs = N * K * int(share) // 100
+        ops = _layer_operands(3, d=128, f=128)
+        idx = _routing_that_holds(pairs)
+        assert moe.rows_lowering(ops["x"], N * K, jnp.float32,
+                                 "pallas_interpret") == moe.KERNEL
+        assert (moe.total_lowering(S((N * K, 128), dtype), N,
+                                   "pallas_interpret") == moe.KERNEL) \
+            == bool(bf16)
+        want = _layer_at(ops, idx, "xla", dtype)
+        if kind == "poisoned":
+            _poison_dead_rows(monkeypatch)
+        got = _layer_at(ops, idx, "pallas_interpret", dtype)
+        assert int(got[1].sum()) == int(want[1].sum()) == pairs
+    tol = 1e-5 if case == "routed" else tol
+    assert np.isfinite(np.asarray(got[0])).all()
+    assert _rel(got[0], want[0]) < tol
     for a, b in zip(got[2], want[2]):
-        assert np.isfinite(np.asarray(a)).all() and _rel(a, b) < 1e-5
+        assert np.isfinite(np.asarray(a)).all() and _rel(a, b) < tol
+
+
+@pytest.mark.parametrize("count", [0, 1, 70, 96])
+def test_the_row_gather_is_x_at_the_index(monkeypatch, count):
+    """The row gather's kernel (interpreted) against `x[index]` over three
+    tiles of 32 rows: every live row, with none live, one, a partial last
+    tile and the whole buffer; float32 rows out as bfloat16 and as they
+    are."""
+    monkeypatch.setattr(moe, "_PAIR_TILE", 32)
+    rng = np.random.RandomState(count)
+    x = jnp.asarray(rng.randn(40, 256).astype(np.float32))
+    index = jnp.asarray(rng.randint(0, 40, 96).astype(np.int32))
+    for dtype in (jnp.bfloat16, jnp.float32):
+        assert moe.rows_lowering(x, 96, dtype, "pallas_interpret") == \
+            moe.KERNEL
+        got = moe.pair_rows(x, index, jnp.asarray([count], jnp.int32), dtype,
+                            "pallas_interpret")
+        assert got.dtype == dtype and got.shape == (96, 256)
+        want = x[index].astype(dtype)
+        assert (np.asarray(got[:count], np.float32)
+                == np.asarray(want[:count], np.float32)).all()
+    # what the kernel does not serve goes the plain way: narrow rows, a
+    # 16-bit source, a buffer of no whole tiles, backend "xla"
+    for src, m, backend in ((x[:, :32], 96, "pallas_interpret"),
+                            (x.astype(jnp.bfloat16), 96, "pallas_interpret"),
+                            (x, 80, "pallas_interpret"), (x, 96, "xla")):
+        assert moe.rows_lowering(src, m, jnp.bfloat16, backend) == \
+            moe.COMPOSITE
+
+
+@pytest.mark.parametrize("count", [0, 1, 33, 70, 96])
+def test_the_sum_back_is_the_rows_totals(monkeypatch, count):
+    """The sum back's kernel (interpreted) against `_pair_total` over three
+    tiles of 32 bfloat16 rows with every dead row NaN: none live, one, an
+    odd count (a word row of which one half is live), a partial last tile,
+    the whole buffer. Both sum in float32; a row's pairs in another order."""
+    monkeypatch.setattr(moe, "_PAIR_TILE", 32)
+    rng = np.random.RandomState(count)
+    n, k, d = 24, 4, 256
+    perm = jnp.asarray(rng.permutation(n * k).astype(np.int32))
+    inv = jnp.zeros_like(perm).at[perm].set(jnp.arange(n * k, dtype=jnp.int32))
+    y = jnp.asarray(rng.randn(n * k, d), jnp.bfloat16)
+    y = jnp.where(jnp.arange(n * k)[:, None] < count, y, jnp.nan)
+    at = jnp.asarray([count], jnp.int32)
+    assert moe.total_lowering(y, n, "pallas_interpret") == moe.KERNEL
+    got = moe.pair_total(y, perm // k, inv, at, k, "pallas_interpret")
+    want = moe._pair_total(y, inv, at, k)
+    assert got.dtype == jnp.float32 and np.isfinite(np.asarray(got)).all()
+    assert _rel(got, want) < 1e-6 if count else not np.asarray(got).any()
+    for other, backend in ((y.astype(jnp.float32), "pallas_interpret"),
+                           (y[:, :64], "pallas_interpret"), (y, "xla")):
+        assert moe.total_lowering(other, n, backend) == moe.COMPOSITE
+
+
+def test_four_layers_trace_each_kernel_body_once():
+    """`moe_train/body_traced` counts one body a kernel (the row gather, the
+    sum back, the elementwise step and its derivative, megablox's six
+    products) for four layers' forward and backward, where `moe_train/call`
+    counts every call site: thirteen a layer (the forward's gather,
+    elementwise step, two products and sum back; the backward's two
+    gathers, the step's derivative, two transposed products, two weight
+    gradients and the sum back of the rows' cotangent)."""
+    from paddle_tpu.observability import tracing
+    ops = _layer_operands(4, d=128, f=128)
+    idx = _routing_that_holds(40)
+
+    def four_layers(x, w, gate, up, down):
+        for _ in range(4):
+            x = x + moe.train_experts(x, idx, w, (0, 1, 2, 3), E, gate, up,
+                                      down, backend="pallas_interpret")[0]
+        return jnp.sum(x)
+
+    jax.clear_caches()
+    tracing.force_enable(True)
+    try:
+        mark = tracing.mark()
+        jax.make_jaxpr(jax.grad(four_layers, argnums=(0, 1, 2, 3, 4)))(
+            ops["x"], jnp.full((N, K), 0.5), ops["gate"][:4], ops["up"][:4],
+            ops["down"][:4])
+        spans = tracing.spans_since(mark)
+    finally:
+        tracing.force_enable(False)
+    bodies = [s.attrs["scope"] for s in spans
+              if s.name == "moe_train/body_traced"]
+    calls = [s.attrs["scope"] for s in spans if s.name == "moe_train/call"]
+    kernels = {"moe_train_" + role for role in
+               ("rows", "total", "gate", "gate_bwd", "in", "out", "in_t",
+                "out_t", "in_w", "out_w")}
+    assert sorted(bodies) == sorted(kernels)
+    assert len(calls) == 4 * 13 and set(calls) == kernels
+    assert calls.count("moe_train_rows") == 4 * 3
+    assert calls.count("moe_train_total") == 4 * 2
 
 
 def test_balance_term_is_one_under_an_even_spread():

@@ -711,14 +711,18 @@ def test_grouped_window_flash_compiles_for_v5e(one_chip, window, scope):
 
 
 def test_training_expert_product_compiles_for_v5e(one_chip):
-    """The routed layer's training product at the 8k training cell's shape
-    (8,192 rows of 2,304, top-8 of 64, 16 held experts of width 896),
-    forward and backward: megablox's kernels under fusion/moe.py's tilings
-    fit VMEM, for both pair buffers a step chooses from (20,480 rows under
-    an even routing, or all 65,536 pairs), both in the program."""
+    """The routed layer's training kernels at the 8k training cell's shape
+    (8,192 rows of 2,304, top-8 of 64, 16 held experts of width 896, ONE
+    pair buffer of all m = 65,536 pairs), forward and backward: megablox's
+    six products under fusion/moe.py's tilings fit the scoped VMEM PR 50's
+    other tilings were refused at (17 MiB); the row gather and the sum
+    back, which hold their float32 side whole in VMEM, and the elementwise
+    step and its derivative compile beside them under limits of their
+    own."""
     from paddle_tpu.fusion import moe
     N, D, F, E, H, K = 8192, 2304, 896, 64, 16, 8
-    assert moe._pair_rows(N * K, H, E) == (20480, 65536)
+    assert moe.rows_lowering(S((N, D), jnp.float32), N * K, jnp.bfloat16,
+                             "pallas") == moe.KERNEL
 
     def fwd_bwd(x, idx, w, gate, up, down):
         def layer(x, w, gate, up, down):
@@ -732,6 +736,12 @@ def test_training_expert_product_compiles_for_v5e(one_chip):
             S((H, D, F), jnp.float32), S((H, F, D), jnp.float32)]
     args = [S(a.shape, a.dtype, sharding=one_chip) for a in args]
     text = jax.jit(fwd_bwd).lower(*args).compile().as_text()
-    # forward 2 products, backward 1 again + 2 transposed + 2 weight
-    # gradients, in each of the two buffers' branches
-    assert text.count("tpu_custom_call") == 2 * (2 + 5)
+    assert moe.total_lowering(S((N * K, D), jnp.bfloat16), N,
+                              "pallas") == moe.KERNEL
+    # forward: the gather, 2 products, the step between them, the sum back;
+    # backward: the rows' and the cotangent's gathers, the step's
+    # derivative, 2 transposed products, 2 weight gradients, the sum back
+    assert text.count("tpu_custom_call") == 5 + 8
+    for scope in ("rows", "total", "gate", "gate_bwd", "in", "out", "in_t",
+                  "out_t", "in_w", "out_w"):
+        assert f"moe_train_{scope}/" in text, scope

@@ -544,6 +544,153 @@ def phase_serve_ssm(vocab=32768, d_model=4096, num_heads=32, num_kv_heads=2,
             "tpu_custom_calls": n_calls, "mixed_tpu_custom_calls": n_mixed}
 
 
+def phase_serve_parallel(vocab=261120, d_model=5120, d_inner=21504,
+                         num_heads=20, num_kv_heads=4, d_head=128,
+                         ssm=(32, 128, 2, 256), num_layers=4, n_slots=4,
+                         block_size=64, n_blocks=96, n_snapshots=4,
+                         max_len=1024, preamble=256, turns=(40, 150),
+                         max_new=24, expect_lowering="kernel"):
+    """A model of TWO mixers a layer through the same PagedKVEngine: in every
+    layer a Mamba-2 mixer (heads of 128 x 256 over 2 groups, a float32 state
+    a decode row updates in place and a prefix hit restores from the snapshot
+    pool) and rotary grouped-query attention with FIVE query heads a
+    key/value head, summed, under the family's multipliers, over the whole
+    261,120-row vocabulary: the published widths of
+    benchmark/configs/falcon-h1-34b-pp12.json, four layers. Every matrix
+    is seeded N(0, 1 / (fan-in x multiplier^2)), larger by the inverse of the
+    multiplier that follows it (as the configuration's seeded weights are),
+    or both mixers would vanish beside the residual and the comparison below
+    would see neither. Requests
+    that start from a shared preamble (K/V blocks AND the state after them)
+    must emit the tokens an engine without prefix sharing emits; with the
+    pool's entries swapped they must not."""
+    from paddle_tpu.models.decoder_spec import (DecoderSpec, Multipliers,
+                                                RopeSpec, SsmSpec)
+    from paddle_tpu.serving import PagedKVEngine
+    import jax
+    import jax.numpy as jnp
+    import paddle_tpu as pt
+
+    by = Multipliers(
+        embedding=5.656854249492381, attention_out=0.0375,
+        key=0.011048543456039804, ssm_in=0.25, ssm_out=0.08838834764831845,
+        ssm=(0.3535533905932738, 0.25, 0.1767766952966369, 0.5,
+             0.3535533905932738),
+        mlp=(0.1767766952966369, 0.011160714285714284), lm_head=0.0078125)
+    spec = DecoderSpec.parallel_ssm_gqa(
+        vocab, d_model, d_inner, num_heads, num_kv_heads, d_head, num_layers,
+        SsmSpec(*ssm), RopeSpec(dim=d_head, theta=1e11), by)
+    t0 = time.time()
+    scope = pt.Scope()
+    sizes = dict(n_slots=n_slots, max_len=max_len, block_size=block_size,
+                 n_blocks=n_blocks, n_snapshots=n_snapshots, scope=scope,
+                 model=spec)
+    # a bound step pins the weights it was built over, so they are in the
+    # scope BEFORE the engines are built (an engine's start-up makes only
+    # what it does not find): the tick program, built and not run, says
+    # which parameters there are. The state-space output is four times its
+    # unit scale and has no skip term: the twins' comparison reads tokens,
+    # and has to see the state in them
+    from paddle_tpu.core import unique_name
+    from paddle_tpu.framework.program import Program, program_guard
+    from paddle_tpu.models import transformer
+    declared = Program()
+    with program_guard(declared, Program()), unique_name.guard():
+        transformer.transformer_lm_paged_decode_tick(
+            n_slots, n_blocks, block_size, max_len // block_size, model=spec,
+            n_snapshots=n_snapshots, cache_prefix="declared")
+    gains = {"tok_emb": 1 / by.embedding, "_ssm_out.w_0": 4 / by.ssm_out,
+             "_ssm_taps": 1.0, "_ssm_in.w_0": 1 / (by.ssm_in * by.ssm[1]),
+             "_attn_k.w_0": 1.6 / by.key, "_attn_q.w_0": 1.6,
+             "_attn_o.w_0": 1 / by.attention_out,
+             "_ffn_gate.w_0": 1 / by.mlp[0], "_ffn_down.w_0": 1 / by.mlp[1],
+             "lm_head.w_0": 1 / by.lm_head}
+    key = jax.random.key(5)
+    seeded = jax.jit(lambda k, shape, dtype, std: jax.random.normal(
+        k, shape, dtype) * std, static_argnums=(1, 2))
+    params = sorted((v.name, tuple(v.shape), jnp.dtype(str(v.dtype)))
+                    for v in declared.global_block().vars.values()
+                    if v.persistable and not v.name.startswith("declared"))
+    for k, (name, shape, dtype) in enumerate(params):
+        if len(shape) == 2:
+            # N(0, gain^2 / fan-in), a unit row for the embedding (a conv
+            # channel's fan-in is its taps)
+            gain = next((g for end, g in gains.items() if name.endswith(end)),
+                        1.0)
+            fan_in = shape[1] if name.endswith("_ssm_taps") else shape[0]
+            std = gain * (1.0 if name == "tok_emb" else fan_in ** -0.5)
+            value = seeded(jax.random.fold_in(key, k), shape, dtype,
+                           jnp.asarray(std, dtype))
+        elif name.endswith(".scale"):
+            value = jnp.ones(shape, dtype)
+        elif name.endswith("_dt_bias"):
+            # with A = -1 (A_log 0): a state that neither explodes nor
+            # forgets at once
+            value = jnp.full(shape, -3.0, dtype)
+        else:                       # A_log, the conv bias, the skip term D
+            value = jnp.zeros(shape, dtype)
+        scope.set_var(name, value)
+    engines = [PagedKVEngine(prefix_sharing=share, **sizes)
+               for share in (True, False)]
+    rng = np.random.RandomState(5)
+    head = rng.randint(0, vocab, (preamble,)).tolist()
+    other = rng.randint(0, vocab, (preamble,)).tolist()
+    prompts = [head + rng.randint(0, vocab, (n,)).tolist() for n in turns]
+    tokens = []
+    for eng in engines:
+        warm = [eng.submit(p, 2) for p in (head, other)]
+        eng.run_until_idle()        # both preambles' blocks and snapshots
+        rest = [eng.submit(p, max_new) for p in prompts]
+        eng.run_until_idle()
+        _check(all(r.done and r.error is None for r in warm + rest),
+               "a request of the two-mixer engine did not finish")
+        tokens.append([r.tokens for r in rest])
+    run_s = time.time() - t0
+    shared, alone = engines
+    st = shared.stats()
+    _check(tokens[0] == tokens[1],
+           "a request that resumed from the prefix cache (K/V blocks and the "
+           "state-space snapshot of the same layers) emitted other tokens "
+           "than its self-prefilled twin")
+    pool = st["ssm_state"]
+    _check(pool["restores"] == len(turns) and pool["layers"] == num_layers
+           == pool["layers_with_kv"],
+           f"state-space restores {pool}: every prefix hit resumes from an "
+           f"entry of the snapshot pool, in every layer")
+    n_calls = _n_custom_calls(shared.tick_hlo())
+    n_mixed = _n_custom_calls(shared.mixed_tick_hlo())
+    want = 2 * num_layers if expect_lowering == "kernel" else 0
+    _check((st["paged_attention_lowering"], n_calls, n_mixed)
+           == (expect_lowering, want, want + (num_layers if want else 0)),
+           f"the two-mixer engine reports its cache read as "
+           f"{st['paged_attention_lowering']!r}, its ticks hold {n_calls} "
+           f"and {n_mixed} tpu_custom_calls; expected {expect_lowering!r} "
+           f"with {want} (a state update and a read a layer) and one more a "
+           f"layer for the lanes' read")
+    for j in range(num_layers):
+        for part in ("h", "conv"):
+            name = f"{shared._cache_prefix}_ssm_snap_{part}{j}"
+            snap = scope.get(name)
+            scope.set_var(name, snap.at[0].set(snap[1]).at[1].set(snap[0]))
+    probe = head + rng.randint(0, vocab, (2,)).tolist()
+    pair = [eng.submit(probe, max_new) for eng in engines]
+    for eng in engines:
+        eng.run_until_idle()
+    _check(pair[0].shared_len == preamble and pair[1].shared_len == 0
+           and pair[0].tokens != pair[1].tokens,
+           "a request that resumed from ANOTHER prompt's snapshot emitted its "
+           "self-prefilled twin's tokens: the twins' comparison does not see "
+           "the state")
+    return {"compile_s": 0.0, "run_s": round(run_s, 2),
+            "tokens_out": sum(len(t) for t in tokens[0]),
+            "swapped_state_differs_at": next(
+                i for i, (a, b) in enumerate(zip(*(r.tokens for r in pair)))
+                if a != b),
+            "ssm_state": pool, "block_bytes": st["block_bytes"],
+            "paged_attention_lowering": st["paged_attention_lowering"],
+            "tpu_custom_calls": n_calls, "mixed_tpu_custom_calls": n_mixed}
+
+
 def phase_window_read(n_slots=32, n_blocks=512, block_size=64, num_heads=64,
                       num_kv_heads=8, d_head=128, blocks_per_req=16,
                       window=128, n_lanes=2, chunk=128, backend=None):
@@ -1305,6 +1452,8 @@ def _run():
     _free_device_memory()
     phase("serve_hybrid", phase_serve_hybrid)
     phase("serve_ssm", phase_serve_ssm)
+    _free_device_memory()
+    phase("serve_parallel", phase_serve_parallel)
     _free_device_memory()
     phase("window_read", phase_window_read)
     phase("kernels", phase_kernels)

@@ -70,6 +70,17 @@ def decode_lowering(heads, head_dim, state, backend=None):
     return COMPOSITE
 
 
+def _count(name, scope):
+    """A set-up counter (a compiled tick records nothing), in the registry
+    the flash kernels count in: `ssm/call`, a call of `scope`
+    ("ssm_decode_update" | "ssd_chunk") at a call site, a layer of a tick
+    program each, and `ssm/body_traced`, a trace of its body (the decode
+    kernel is a jitted function: layers of one shape share one trace; the
+    chunked form is plain XLA, traced where it is called)."""
+    from ..observability import tracing
+    tracing.record_counter(name, 1, scope=scope)
+
+
 def _decode_composite(h, live, x, b, c, dt, decay):
     rep = h.shape[1] // b.shape[1]
     bh = jnp.repeat(b.astype(jnp.float32), rep, axis=1)          # [S,H,N]
@@ -139,6 +150,7 @@ def _decode_pallas(h, live, x, b, c, dt, decay, interpret):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    _count("ssm/body_traced", "ssm_decode_update")
     S, H, P, N = h.shape
     G = b.shape[1]
     exact = x.dtype == jnp.bfloat16
@@ -194,6 +206,7 @@ def ssm_decode_update(h, live, x, b, c, dt, decay, backend=None):
     live = live.reshape(-1) > 0
     if decode_lowering(h.shape[1], h.shape[2], h.shape[3],
                        backend) == KERNEL:
+        _count("ssm/call", "ssm_decode_update")
         return _decode_pallas(h, live, x, b, c, dt, decay,
                               interpret=backend == "pallas_interpret")
     return _decode_composite(h, live, x, b, c, dt, decay)
@@ -205,6 +218,8 @@ def ssd_chunk(h_in, x, b, c, dt, a, snap_rows=None):
     [H] float32 -> (y [L, Q, H, P] float32 without the D x term, h_out, and
     with `snap_rows` [L] the state after each lane's first `snap_rows`
     rows)."""
+    _count("ssm/call", "ssd_chunk")
+    _count("ssm/body_traced", "ssd_chunk")
     with jax.named_scope("ssd_chunk"):
         L, Q, H, _ = x.shape
         rep = H // b.shape[2]

@@ -12,7 +12,10 @@ attention), `DecoderSpec.ssm_gqa_moe` the fourth (ONE sublayer a layer: a
 Mamba-2 state-space mixer, grouped-query attention without positions, or
 latent routed experts), `DecoderSpec.window_gqa_moe` the fifth (a kind of
 ATTENTION a layer: a sliding window or every position, each kind with its
-own rotary positions or none). A spec comes from one of the constructors;
+own rotary positions or none), `DecoderSpec.parallel_ssm_gqa` the sixth (TWO
+mixers a layer: a Mamba-2 state-space mixer and rotary grouped-query
+attention on one normed input, summed into one residual, under the family's
+scalar multipliers). A spec comes from one of the constructors;
 the fields are what `_decoder_block` reads, not a product to pick from: any
 other combination raises where a graph would have to build it.
 `serving.PagedKVEngine(model=spec)` takes any of them;
@@ -40,6 +43,10 @@ Kinds (each a string, checked by name; nothing is guessed):
               None: attention everywhere. With `one_sublayer` a layer is its
               kind ALONE under one pre-norm residual, out of "ssm"
               (`SsmSpec`) | "attention" | "moe"
+  mixer       "kind" (a layer's token mixer is its kind's, one) |
+              "ssm+attention" (EVERY layer runs the state-space mixer and
+              full-head attention on one normed input and adds both to the
+              residual: the layer holds a slot state, snapshots AND K/V rows)
   ffn         "relu" | "gated_silu"; layers from `moe.first_dense` on are
               routed experts + shared expert (`MoESpec`: `scoring`
               "sigmoid" is what the serving ticks route by, "softmax" with
@@ -177,6 +184,30 @@ class SsmSpec:
         return self.heads * self.head_dim * self.state * 4
 
 
+@dataclasses.dataclass(frozen=True)
+class Multipliers:
+    """The scalar multipliers a muP-parametrised family publishes (Falcon-H1),
+    each applied to an ACTIVATION where the equations put it, none folded
+    into a weight; 1 everywhere builds no op. `ssm` scales the five column
+    ranges of the state-space input projection, in the order z, x, B, C, dt;
+    `mlp` the feed-forward's gate (before the SiLU) and its output; `key` the
+    keys before the rotation; `lm_head` the logits."""
+    embedding: float = 1.0
+    attention_in: float = 1.0
+    attention_out: float = 1.0
+    key: float = 1.0
+    ssm_in: float = 1.0
+    ssm_out: float = 1.0
+    ssm: Tuple[float, float, float, float, float] = (1.0,) * 5
+    mlp: Tuple[float, float] = (1.0, 1.0)
+    lm_head: float = 1.0
+
+    def __post_init__(self):
+        if len(self.ssm) != 5 or len(self.mlp) != 2:
+            raise ValueError("Multipliers.ssm has five values (z, x, B, C, "
+                             "dt) and Multipliers.mlp two (gate, output)")
+
+
 TOPK_METHODS = ("none", "bias")
 ACTIVATIONS = ("gated_silu", "relu2")
 SCORING = ("sigmoid", "softmax")
@@ -271,6 +302,8 @@ class DecoderSpec:
     attention_kinds: Optional[Tuple[str, ...]] = None   # "window" | "full"
     window: int = 0                         # positions a window layer sees
     rope_full: Optional[RopeSpec] = None    # the "full" kind's own rotation
+    mixer: str = "kind"                     # | "ssm+attention": both, summed
+    multipliers: Multipliers = Multipliers()
 
     def __post_init__(self):
         for field, kinds in (("norm", ("layer_norm", "rms_norm")),
@@ -278,6 +311,7 @@ class DecoderSpec:
                              ("positions", ("sinusoid", "rotary", "none")),
                              ("attention", ("full", "latent")),
                              ("ffn", ("relu", "gated_silu")),
+                             ("mixer", ("kind", "ssm+attention")),
                              ("dtype", ("float32", "bfloat16"))):
             if getattr(self, field) not in kinds:
                 raise ValueError(f"DecoderSpec.{field} = "
@@ -303,6 +337,22 @@ class DecoderSpec:
             raise ValueError(f"layer_kinds {kinds!r}: one of "
                              f"{sorted(known)} for each of "
                              f"{self.num_layers} layers")
+        if self.mixer == "ssm+attention" and (
+                self.ssm is None or self.attention != "full"
+                or self.rope is None or self.residual != "pre"
+                or self.norm != "rms_norm" or self.ffn != "gated_silu"
+                or kinds is not None or self.attention_kinds is not None
+                or self.moe is not None or self.conv is not None
+                or self.qk_norm or self.tied_head):
+            raise ValueError(
+                "mixer='ssm+attention' is the pre-norm RMSNorm block of an "
+                "SsmSpec beside rotary full-head attention (`rope`) and a "
+                "gated SiLU pair, the same in every layer: no layer_kinds, "
+                "attention_kinds, latent, MoESpec, ConvSpec, qk_norm or tied "
+                "head builds beside it")
+        if self.multipliers != Multipliers() and self.mixer != "ssm+attention":
+            raise ValueError("`multipliers` scale the 'ssm+attention' block: "
+                             "no other block applies them")
         if (self.ssm is not None) != bool(self.ssm_layers):
             raise ValueError("an 'ssm' layer comes with an SsmSpec, and "
                              "only it")
@@ -408,6 +458,27 @@ class DecoderSpec:
                    attention_kinds=tuple(attention_kinds),
                    window=int(window), rope_full=rope_full)
 
+    @classmethod
+    def parallel_ssm_gqa(cls, vocab, d_model, d_inner, num_heads,
+                         num_kv_heads, d_head, num_layers, ssm: SsmSpec,
+                         rope: RopeSpec,
+                         multipliers: Multipliers = Multipliers(),
+                         norm_eps=1e-5, dtype="bfloat16"):
+        """The Falcon-H1 family's block: a pre-norm RMSNorm residual whose
+        token mixer is the SUM of a Mamba-2 mixer (`ssm`, an inner width of
+        its own: heads x head_dim whatever `d_model`) and grouped-query
+        attention (heads of `d_head`, q and k rotated by `rope` over the
+        whole head, no bias, no QK-norm) on ONE normed input, then a gated
+        SiLU pair under a second norm; `multipliers` on the embedding, both
+        mixers' inputs and outputs, the keys, the state-space projection's
+        column ranges, the feed-forward and the logits; a final norm and an
+        untied head."""
+        return cls(vocab, d_model, d_inner, num_heads, num_layers,
+                   norm="rms_norm", norm_eps=norm_eps, residual="pre",
+                   positions="rotary", ffn="gated_silu", dtype=dtype,
+                   num_kv_heads=num_kv_heads, rope=rope, head_dim=d_head,
+                   ssm=ssm, mixer="ssm+attention", multipliers=multipliers)
+
     @property
     def is_classic(self) -> bool:
         return self == DecoderSpec.classic(**self.dims())
@@ -482,6 +553,11 @@ class DecoderSpec:
 
     @property
     def ssm_layers(self) -> Tuple[int, ...]:
+        """The layers with a state-space mixer: by kind, or every layer
+        where the mixer is the sum of both (such a layer is among the
+        `attention_layers` too)."""
+        if self.mixer == "ssm+attention":
+            return tuple(range(self.num_layers))
         return tuple(i for i in range(self.num_layers)
                      if self.layer_kind(i) == "ssm")
 
@@ -509,8 +585,9 @@ class DecoderSpec:
     def state_bytes(self) -> int:
         """Bytes of ONE copy of a request's per-layer state beside its
         per-token rows (a slot's, or a snapshot): the conv layers' last
-        rows, or the state-space layers' `h` (float32) and last conv rows;
-        0 where every layer is attention."""
+        rows, or the state-space layers' `h` (float32) and last conv rows
+        (a layer whose mixer is both holds them BESIDE its rows of
+        `cache_row_bytes`); 0 where every layer is attention alone."""
         if self.ssm is not None:
             return len(self.ssm_layers) * (
                 self.ssm.h_bytes()

@@ -273,11 +273,18 @@ def _pre_norm(x, spec, name):
                              bias_attr=ParamAttr(name=name + ".bias"))
 
 
-def _gated_ffn(x, d_model, d_inner, name):
-    """down(silu(gate x) * up x), no biases."""
+def _scaled(x, by):
+    """x times a spec's scalar multiplier, on the activation; no op at 1."""
+    return x if by == 1.0 else layers.scale(x, scale=float(by))
+
+
+def _gated_ffn(x, d_model, d_inner, name, by=(1.0, 1.0)):
+    """down(silu(gate x) * up x), no biases; `by` (`Multipliers.mlp`) scales
+    the gate before the SiLU and the output."""
     gate, up = (_proj(x, d_inner, f"{name}_{n}") for n in ("gate", "up"))
-    return _proj(layers.elementwise_mul(layers.silu(gate), up), d_model,
-                 name + "_down")
+    return _scaled(
+        _proj(layers.elementwise_mul(layers.silu(_scaled(gate, by[0])), up),
+              d_model, name + "_down"), by[1])
 
 
 def _moe_ffn(x, spec, name, rows):
@@ -403,9 +410,12 @@ def _grouped_attention(x, spec, name, attend, rows, table=None):
     query heads (query head i reads key/value head i // group), an RMSNorm
     a head on q and k where the spec asks (`qk_norm`), rotary positions
     over the whole head by `table` (the layer's: `_TickRows.table_of`; None:
-    not rotated), around `attend(q, k_new, v_new)`. x is a tick's rows
-    [n, 1, d] or a training graph's [B, T, d]."""
+    not rotated), around `attend(q, k_new, v_new)`; `spec.multipliers`
+    scales the input, the keys before the rotation and the output. x is a
+    tick's rows [n, 1, d] or a training graph's [B, T, d]."""
     n, nh, nkv, dh = x.shape[0], spec.num_heads, spec.kv_heads, spec.d_head
+    by = spec.multipliers
+    x = _scaled(x, by.attention_in)
 
     def heads(t, count, which):
         if spec.qk_norm:
@@ -420,9 +430,10 @@ def _grouped_attention(x, spec, name, attend, rows, table=None):
         return layers.reshape(t, shape=[n, x.shape[1], count * dh])
 
     q = heads(_proj(x, nh * dh, name + "_q"), nh, "q")
-    k = heads(_proj(x, nkv * dh, name + "_k"), nkv, "k")
+    k = heads(_scaled(_proj(x, nkv * dh, name + "_k"), by.key), nkv, "k")
     v = _proj(x, nkv * dh, name + "_v")
-    return _proj(attend(q, k, v), spec.d_model, name + "_o")
+    return _scaled(_proj(attend(q, k, v), spec.d_model, name + "_o"),
+                   by.attention_out)
 
 
 def _short_conv(x, spec, name, rows):
@@ -441,9 +452,18 @@ def _short_conv(x, spec, name, rows):
 def _ssm_mixer(x, spec, name, rows):
     """The Mamba-2 mixer: `[z, xBC, dt] = x W_in`; the convolution and the
     scan from the request's state (`rows.ssm`, fusion/ssm.py); the gated
-    group RMSNorm; `W_out`; no bias on either projection."""
-    ssm = spec.ssm
-    zxd = _proj(x, ssm.in_dim, name + "_in")
+    group RMSNorm; `W_out`; no bias on either projection. Under
+    `spec.multipliers`: the input and the output by a scalar each, the
+    projection's columns by a vector that is constant on each of the ranges
+    z, x, B, C, dt (in float32, rounded once to the activations' dtype)."""
+    ssm, by = spec.ssm, spec.multipliers
+    zxd = _proj(_scaled(x, by.ssm_in), ssm.in_dim, name + "_in")
+    if by.ssm != (1.0,) * 5:
+        gn = ssm.groups * ssm.state
+        ranges = np.repeat(np.asarray(by.ssm, "float32"),
+                           [ssm.d_inner, ssm.d_inner, gn, gn, ssm.heads])
+        zxd = layers.cast(layers.elementwise_mul(
+            layers.cast(zxd, "float32"), layers.assign(ranges)), spec.dtype)
     z, xbc, dt = (layers.slice(zxd, axes=[2], starts=[a], ends=[b])
                   for a, b in ((0, ssm.d_inner),
                                (ssm.d_inner, ssm.d_inner + ssm.conv_dim),
@@ -457,7 +477,7 @@ def _ssm_mixer(x, spec, name, rows):
     y = rows.ssm.layer(xbc, dt, params, rows.live)
     y = layers.gated_rms_norm(y, z, ssm.groups, epsilon=spec.norm_eps,
                               param_attr=ParamAttr(name=name + "_norm.scale"))
-    return _proj(y, spec.d_model, name + "_out")
+    return _scaled(_proj(y, spec.d_model, name + "_out"), by.ssm_out)
 
 
 def _decoder_block(x, i, attend, d_model, d_inner, dropout, is_test=True,
@@ -480,7 +500,10 @@ def _decoder_block(x, i, attend, d_model, d_inner, dropout, is_test=True,
       `{prefix}{i}_ln{1,2[,3]}`) or pre (`x + f(norm(x))`, same names);
     - with `spec.one_sublayer` ONE of the Mamba-2 mixer (`_ssm_mixer`, its
       state in `rows.ssm`), grouped attention or the routed experts, alone
-      under one pre-norm residual.
+      under one pre-norm residual;
+    - with `spec.mixer` "ssm+attention" the Mamba-2 mixer AND grouped
+      attention on one normed input, both added to the residual, then the
+      feed-forward under its own norm.
 
     `rows` (`_TickRows`) carries what the rotary and routed kinds need of
     the tick. The parameter names are the contract: a graph built from this
@@ -499,7 +522,13 @@ def _decoder_block(x, i, attend, d_model, d_inner, dropout, is_test=True,
             "moe": lambda x: _moe_ffn(x, spec, f"{name}_moe", rows)}[kind]
         return layers.elementwise_add(
             x, sublayer(_pre_norm(x, spec, f"{name}_ln1")))
-    if spec.layer_kind(i) == "conv":
+    if spec.mixer == "ssm+attention":
+        sublayers = [lambda x: layers.elementwise_add(
+            _ssm_mixer(x, spec, f"{name}_ssm", rows),
+            _grouped_attention(x, spec, f"{name}_{attn}",
+                               functools.partial(attend, i), rows,
+                               rows.table_of(spec, i)))]
+    elif spec.layer_kind(i) == "conv":
         sublayers = [lambda x: _short_conv(x, spec, f"{name}_conv", rows)]
     elif spec.attention == "latent":
         sublayers = [lambda x: _latent_attention(
@@ -519,7 +548,8 @@ def _decoder_block(x, i, attend, d_model, d_inner, dropout, is_test=True,
         sublayers.append(lambda x: _moe_ffn(x, spec, f"{name}_moe", rows))
     elif kind == "gated_silu":
         sublayers.append(lambda x: _gated_ffn(x, d_model, d_inner,
-                                              f"{name}_ffn"))
+                                              f"{name}_ffn",
+                                              spec.multipliers.mlp))
     else:
         sublayers.append(lambda x: ffn(x, d_model, d_inner, dropout, is_test,
                                        name=f"{name}_ffn"))
@@ -1568,8 +1598,10 @@ def _kinds_paged_tick(model, n_slots, n_blocks, block_size, blocks_per_req,
     rows = _TickRows(model, positions,
                      _live_rows(wblock, lrows, lanes[1] if lanes else 0),
                      NLB * block_size, conv, ssm)
-    x = _lm_decoder(_embed_rows(toks, model), cache.attend, model.num_layers,
-                    model.d_model, model.d_inner, 0.0, spec=model, rows=rows)
+    x = _lm_decoder(
+        _scaled(_embed_rows(toks, model), model.multipliers.embedding),
+        cache.attend, model.num_layers, model.d_model, model.d_inner, 0.0,
+        spec=model, rows=rows)
     if conv is not None:
         conv.commit()
     if lanes is not None:
@@ -1586,8 +1618,10 @@ def _kinds_paged_tick(model, n_slots, n_blocks, block_size, blocks_per_req,
             layers.matmul(x, table, transpose_y=True, out_dtype="float32"),
             axis=2)
     else:
-        _, next_ids, _ = _lm_head(x, model.vocab, bias=False,
-                                  out_dtype="float32")
+        logits, next_ids, _ = _lm_head(x, model.vocab, bias=False,
+                                       out_dtype="float32", ids=False)
+        next_ids = layers.argmax(
+            _scaled(logits, model.multipliers.lm_head), axis=2)
     last.keep(next_ids)
     return rows.with_counts(next_ids), cache.names
 
@@ -1703,6 +1737,11 @@ def _spec_lm(tokens, label, spec, max_len):
     `moe.aux_coef` times the routed layers' balance terms. Parameters are
     float32 under the names the serving ticks read; matmul operands
     bfloat16 (`use_bf16`)."""
+    if spec.mixer != "kind":
+        raise NotImplementedError(
+            f"transformer_lm(model=spec): mixer {spec.mixer!r} has no "
+            "training graph (the state-space scan carries no gradient); "
+            "serve it through PagedKVEngine(model=spec)")
     if spec.attention != "full" or spec.layer_kinds is not None \
             or spec.residual != "pre" or spec.positions != "rotary" \
             or spec.tied_head or spec.dropout or spec.packed:

@@ -1142,6 +1142,7 @@ class PagedKVEngine(ContinuousBatchingEngine):
                            if model.window else "")
                         + "; serve this model without it",
                         exc=InvalidArgumentError)
+        self._described = not model.is_classic    # built from a spec's kinds
         #: (layer, held expert) -> rows routed to it since construction
         self.expert_rows = np.zeros(
             (len(model.moe_layers), len(model.moe.held) if model.moe else 0),
@@ -1330,7 +1331,7 @@ class PagedKVEngine(ContinuousBatchingEngine):
         # rule the op itself applies when the tick compiles; on a TPU it
         # raises rather than serve float32 pools from the composite
         d = self._builder_dims
-        dh = d["d_model"] // d["num_heads"]
+        dh = self.model.d_head
         if self.model.attention == "latent":
             from ..fusion.latent_attention import latent_attention_lowering
             lat = self.model.latent
@@ -1417,6 +1418,10 @@ class PagedKVEngine(ContinuousBatchingEngine):
                 win_rows += min(req.fed + 1, window)
         self._tick_attrs["kv_blocks"] = kv_blocks
         self._tick_attrs["decode_rows"] = kv_rows
+        if self.n_snapshots:
+            # the live decode rows, whose state-space state the tick reads
+            # and writes (a slot in prefill moves its state in a lane)
+            self._tick_attrs["state_rows"] = len(active) - len(prefilling)
         if window:
             # `kv_blocks`: the blocks the reads span in BOTH pools
             self._tick_attrs["kv_blocks"] += win_blocks
@@ -1436,7 +1441,7 @@ class PagedKVEngine(ContinuousBatchingEngine):
         `prefill_tokens`; `kv_blocks` adds the blocks their reads span)."""
         attrs = self._tick_attrs
         lanes = []
-        tokens = snapshots = 0
+        tokens = snapshots = lane_blocks = 0
         for i in range(self.n_lanes, len(prefilling)):
             prefilling[i].lane_wait_ticks += 1
         attrs["lane_waiting"] = max(len(prefilling) - self.n_lanes, 0)
@@ -1480,10 +1485,14 @@ class PagedKVEngine(ContinuousBatchingEngine):
                     snapshots += (k0 + n) // bs - b0
                 lanes.append((req, n))
                 tokens += n
-                attrs["kv_blocks"] += b0 + nb
+                lane_blocks += b0 + nb
         self._lanes = lanes
+        attrs["kv_blocks"] += lane_blocks
         attrs["prefill"] = len(lanes)
         attrs["prefill_tokens"] = tokens
+        # the lanes' part of `kv_blocks` (the first pool's), apart from the
+        # decode rows': a lane reads its request's blocks up to its chunk
+        attrs["lane_kv_blocks"] = lane_blocks
         if self.state_bytes:
             attrs["state_snapshots"] = snapshots
 
@@ -1533,6 +1542,10 @@ class PagedKVEngine(ContinuousBatchingEngine):
                 + np.count_nonzero(on[:, 1:] & ~on[:, :-1]))
             tick.attrs["routed_rows"] = int(counts.sum())
             tick.attrs["expert_rows"] = counts.ravel().tolist()
+        elif self._described:
+            # a described block without a routed layer streams no expert:
+            # the tick says so, as the routed models' ticks say how many
+            tick.attrs["experts_touched"] = 0
 
     def _commits_every_tick(self) -> bool:
         # the host tier's `_pre_tick` moves blocks between ticks, and
@@ -2041,6 +2054,11 @@ class PagedKVEngine(ContinuousBatchingEngine):
             # copy a slot, and the pool's entries
             s["ssm_state"] = dict(
                 s["pager"]["snapshot_pool"],
+                # the layers that hold it, and those of them that hold K/V
+                # rows too (a layer whose mixer is both)
+                layers=len(self.model.ssm_layers),
+                layers_with_kv=len(set(self.model.ssm_layers)
+                                   & set(self.model.attention_layers)),
                 bytes_per_copy=self.state_bytes,
                 slot_bytes=self.state_bytes * self.n_slots,
                 pool_bytes=self.state_bytes * self.n_snapshots)

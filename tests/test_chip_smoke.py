@@ -98,6 +98,23 @@ def test_serve_ssm_phase():
     assert out["block_bytes"] == 1 * 2 * 2 * 8 * 2 * 8    # one attention layer
 
 
+def test_serve_parallel_phase():
+    """The smoke's two-mixer engine (ISSUE 54: a Mamba-2 mixer and rotary
+    grouped-query attention with five query heads a key/value head in every
+    layer, under the published multipliers) at a tiny size: prefix-hit
+    requests equal their self-prefilled twins, and do not once the pool's
+    entries are swapped."""
+    out = chip_smoke.phase_serve_parallel(
+        vocab=97, d_model=64, d_inner=96, num_heads=10, num_kv_heads=2,
+        d_head=8, ssm=(8, 8, 2, 16), num_layers=3, n_slots=4, block_size=8,
+        n_blocks=40, n_snapshots=4, max_len=64, preamble=24, turns=(5, 11),
+        max_new=24, expect_lowering="composite")
+    assert out["ssm_state"]["restores"] == 2 and out["tokens_out"] == 48
+    assert out["ssm_state"]["layers_with_kv"] == 3
+    assert out["swapped_state_differs_at"] < 24  # the planted fault is refused
+    assert out["block_bytes"] == 3 * 2 * 2 * 8 * 2 * 8   # K/V in every layer
+
+
 def test_train_resnet_phase():
     out = chip_smoke.phase_train_resnet50(batch=2, steps=2, depth=18,
                                           image=32)

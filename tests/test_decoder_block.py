@@ -372,3 +372,153 @@ def test_kinds_are_checked_by_name():
     with pytest.raises(NotImplementedError, match="no cache seam"):
         _program(lambda: T.transformer_lm_paged_decode_tick(4, 20, 4, 6,
                                                            model=odd))
+
+
+# -- two mixers in one layer, under multipliers (ISSUE 54) --------------------
+
+from paddle_tpu.models.decoder_spec import (ConvSpec, Multipliers,  # noqa: E402
+                                            SsmSpec)
+
+PARALLEL = DecoderSpec.parallel_ssm_gqa(
+    vocab=61, d_model=32, d_inner=48, num_heads=10, num_kv_heads=2, d_head=8,
+    num_layers=2, ssm=SsmSpec(heads=4, head_dim=8, groups=2, state=16),
+    rope=RopeSpec(dim=8, theta=1e11),
+    multipliers=Multipliers(embedding=5.66, attention_out=0.0375, key=0.011,
+                            ssm_in=0.25, ssm_out=0.088,
+                            ssm=(0.354, 0.25, 0.177, 0.5, 0.354),
+                            mlp=(0.177, 0.0112), lm_head=2 ** -7))
+
+OLDER = {
+    "classic": DecoderSpec.classic(**DIMS),
+    "latent_moe": KINDS,
+    "conv_gqa_moe": DecoderSpec.conv_gqa_moe(
+        61, 32, 64, 4, 2, ("conv", "attention"), RopeSpec(dim=8)),
+    "ssm_gqa_moe": DecoderSpec.ssm_gqa_moe(
+        61, 32, 4, 2, 8, ("ssm", "attention", "moe"),
+        SsmSpec(heads=4, head_dim=8, groups=2, state=16),
+        MoESpec(n_routed=8, top_k=2, d_expert=16, held=(0, 1), first_dense=0,
+                topk_method="bias", activation="relu2", latent=16,
+                d_shared=32)),
+    "window_gqa_moe": DecoderSpec.window_gqa_moe(
+        61, 32, 64, 4, 2, 8, ("window", "full"), 8, RopeSpec(dim=8)),
+}
+
+
+def _kinds_build(graph, spec):
+    return lambda: PAGED_BUILDERS[graph](model=spec, n_snapshots=2,
+                                         n_window_blocks=9)
+
+
+@pytest.mark.parametrize("graph", sorted(PAGED_BUILDERS))
+@pytest.mark.parametrize("kind", sorted(OLDER))
+def test_the_five_older_specs_build_no_multipliers_op(graph, kind):
+    """Multipliers of 1 build no op: a spec of one of the five older
+    constructors builds the program it built before a block had them, op for
+    op (`_scaled` returns its argument; the head's argmax moved out of
+    `_lm_head` and is the same op on the same variable)."""
+    spec = OLDER[kind]
+    assert spec.multipliers == Multipliers() and spec.mixer == "kind"
+    ops = _op_types(_kinds_build(graph, spec))
+    assert "scale" not in ops or kind == "classic"
+    # with the helper taken out the program is the same list of ops
+    with pytest.MonkeyPatch.context() as m:
+        m.setattr(T, "_scaled", lambda x, by: x)
+        assert _op_types(_kinds_build(graph, spec)) == ops
+    assert ops.count("arg_max") == 1
+
+
+@pytest.mark.parametrize("graph", sorted(PAGED_BUILDERS))
+def test_two_mixers_a_layer_go_through_the_one_block(graph, monkeypatch):
+    """Every layer of the parallel spec is built by `_decoder_block`: two
+    norms a layer (one for BOTH mixers, one for the feed-forward), a state
+    scan AND a K/V write pair a layer, the multipliers as `scale` ops on
+    activations and one vector product a mixer."""
+    build = _kinds_build(graph, PARALLEL)
+    before = _op_types(build)
+    block, pre_norm = T._decoder_block, T._pre_norm
+
+    def marked_block(*a, **kw):
+        with monkeypatch.context() as m:
+            m.setattr(T, "_pre_norm", lambda *a, **kw: layers.gelu(
+                pre_norm(*a, **kw)))
+            return block(*a, **kw)
+
+    monkeypatch.setattr(T, "_decoder_block", marked_block)
+    after = _op_types(build)
+    n = PARALLEL.num_layers
+    assert after.count("gelu") == 2 * n and "gelu" not in before
+    monkeypatch.undo()
+    assert before.count("rms_norm") == 2 * n + 1
+    assert before.count("ssm_scan") == before.count("gated_rms_norm") == n
+    assert before.count("paged_cache_write") == 2 * n
+    reads = 2 if graph == "paged_mixed_tick" else 1
+    assert before.count("paged_decode_attention") == reads * n
+    assert before.count("rotary") == 2 * n
+    # embedding, logits; a layer: ssm in, ssm out, key, attention out, gate,
+    # feed-forward out (attention_in is 1: no op)
+    assert before.count("scale") == 2 + 6 * n
+    program = _program(build)
+    names = {v.name: tuple(v.shape)
+             for v in program.global_block().vars.values() if v.persistable}
+    for i in range(n):
+        assert names[f"l{i}_ssm_in.w_0"] == (32, 32 + 96 + 4)
+        assert names[f"l{i}_ssm_out.w_0"] == (32, 32)
+        assert names[f"l{i}_attn_q.w_0"] == (32, 80)
+        assert names[f"l{i}_attn_k.w_0"] == (32, 16)
+        assert names[f"l{i}_ffn_gate.w_0"] == (32, 48)
+        assert f"l{i}_ln2.scale" in names and f"l{i}_ln3.scale" not in names
+        for var in (f"pgd_ssm_h{i}", f"pgd_ssm_snap_h{i}", f"pgd_k{i}",
+                    f"pgd_v{i}"):
+            assert var in names, var
+    assert names["lm_head.w_0"] == (32, 61) and "lm_head.w_1" not in names
+
+
+def test_a_layer_that_is_both_answers_for_both():
+    assert PARALLEL.ssm_layers == PARALLEL.attention_layers == (0, 1)
+    assert PARALLEL.full_layers == (0, 1) and PARALLEL.conv_layers == ()
+    assert PARALLEL.moe_layers == () and not PARALLEL.is_classic
+    assert PARALLEL.ssm.d_inner == 32 and PARALLEL.ssm.in_dim == 132
+    assert PARALLEL.cache_row_bytes() == 2 * 2 * 2 * 8 * 2
+    assert PARALLEL.state_bytes() == 2 * (4 * 8 * 16 * 4 + 3 * 96 * 2)
+    assert PARALLEL.window_row_bytes() == 0
+    assert PARALLEL.rotates(0) and PARALLEL.rope_of(1) == PARALLEL.rope
+    # the kinds partition the layers everywhere else
+    nemo = OLDER["ssm_gqa_moe"]
+    assert not set(nemo.ssm_layers) & set(nemo.attention_layers)
+
+
+@pytest.mark.parametrize("change, match", [
+    (dict(mixer="parallel"), "mixer"),
+    (dict(ssm=None), "ssm\\+attention"),
+    (dict(rope=None, positions="none"), "ssm\\+attention"),
+    (dict(attention="latent", latent=KINDS.latent), "ssm\\+attention"),
+    (dict(moe=KINDS.moe), "ssm\\+attention"),
+    (dict(conv=ConvSpec()), "ssm\\+attention"),
+    (dict(layer_kinds=("attention", "attention")), "ssm\\+attention"),
+    (dict(attention_kinds=("full", "full")), "ssm\\+attention"),
+    (dict(one_sublayer=True, layer_kinds=("ssm", "attention")),
+     "ssm\\+attention"),
+    (dict(qk_norm=True), "ssm\\+attention"),
+    (dict(tied_head=True), "ssm\\+attention"),
+    (dict(norm="layer_norm"), "ssm\\+attention"),
+    (dict(residual="post"), "ssm\\+attention"),
+    (dict(ffn="relu"), "ssm\\+attention"),
+])
+def test_every_combination_no_graph_builds_raises_by_name(change, match):
+    import dataclasses
+    with pytest.raises(ValueError, match=match):
+        dataclasses.replace(PARALLEL, **change)
+
+
+@pytest.mark.parametrize("kind", sorted(set(OLDER) - {"classic"}))
+def test_multipliers_belong_to_the_parallel_block_alone(kind):
+    import dataclasses
+    with pytest.raises(ValueError, match="multipliers"):
+        dataclasses.replace(OLDER[kind], multipliers=Multipliers(lm_head=0.5))
+
+
+def test_the_training_graph_refuses_two_mixers_by_name():
+    pt.reset_default_programs()
+    with pytest.raises(NotImplementedError, match="ssm\\+attention"):
+        T.transformer_lm(max_len=16, model=PARALLEL)
+    pt.reset_default_programs()

@@ -651,6 +651,48 @@ def test_ssm_decode_update_compiles_for_v5e_in_place(one_chip):
     assert "f32[64,128,1,64]" in compiled.as_text()
 
 
+def test_ssm_decode_update_compiles_for_v5e_at_heads_of_128_by_256(one_chip):
+    """The decode update at Falcon-H1's widths (ISSUE 54: 16 slots of 32
+    heads x 128 x 256 float32 over 2 groups, 4 MB a slot like the heads of
+    64 x 128 above) through the TPU compiler for a v5e, in place."""
+    from paddle_tpu.fusion import ssm
+    slots, h, p, g, n = 16, 32, 128, 2, 256
+    args = [S((slots, h, p, n), F32), S((slots,), F32), S((slots, h, p), BF16),
+            S((slots, g, n), BF16), S((slots, g, n), BF16), S((slots, h), F32),
+            S((slots, h), F32)]
+    f = lambda st, live, x, b, c, dt, dec: ssm.ssm_decode_update(  # noqa: E731
+        st, live, x, b, c, dt, dec, backend="pallas")
+    assert _n_calls(_tpu_text(f, *args)) == 1
+    args = [S(a.shape, a.dtype, sharding=one_chip) for a in args]
+    compiled = jax.jit(f, donate_argnums=0).lower(*args).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    assert "f32[16,32,1,128]" in compiled.as_text()
+
+
+@pytest.mark.parametrize("slots, positions", [(16, 1), (2, 128)])
+def test_grouped_paged_kernel_compiles_at_five_queries_a_kv_head(
+        one_chip, slots, positions):
+    """The paged read of falcon-h1-34b-pp12_serve_long_prompts at its
+    published widths (ISSUE 54): 16 decode rows, and two prefill lanes of 128
+    positions, 20 query heads over 4 key/value heads of 128 (FIVE a group,
+    not a power of two: padded to a sublane tile in the decode read, 640 rows
+    a lane in the chunk read) in bfloat16 blocks of 64, a table of 200
+    blocks: ONE Mosaic call, which the TPU compiler takes for a v5e."""
+    from paddle_tpu.fusion import paged_decode_attention
+    nh, nkv, dh = 20, 4, 128
+    pool = S((3264, nkv, 64 * dh // 128, 128), BF16)
+    f = lambda q, k, v, t, p, r: paged_decode_attention(  # noqa: E731
+        q, k, v, t, p, nh, scale=dh ** -0.5, rows=r, backend="pallas")
+    args = [S((slots, positions, nh * dh), BF16), pool, pool,
+            S((slots, 200), I32), S((slots, 1, 1), F32), S((slots,), I32)]
+    assert _n_calls(_tpu_text(f, *args)) == 1
+    args = [S(a.shape, a.dtype, sharding=one_chip) for a in args]
+    text = jax.jit(f).lower(*args).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    if positions == 1:          # the shape gqa_decode_roofline keys
+        assert "f32[16,4,8,128]" in text
+
+
 @pytest.mark.parametrize("rows", [64, 320])
 def test_latent_expert_product_compiles_for_v5e(one_chip, rows):
     """128 held experts of 1024 x 2688 and 2688 x 1024, seven steps of 384

@@ -181,3 +181,89 @@ def test_gated_rms_norm_gates_first_and_norms_a_group():
     want = (g / np.sqrt((g * g).mean(-1, keepdims=True) + 1e-5)) \
         .reshape(5, 32) * np.asarray(scale, np.float64)
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+
+
+# -- heads of 128 x 256 over 2 groups (ISSUE 54: the Falcon-H1 mixer) ---------
+
+def _wide_rows(rng, t, heads=4, groups=2, p=128, n=256, dtype=jnp.float32):
+    x = jnp.asarray(rng.normal(size=(t, heads, p)), dtype)
+    b = jnp.asarray(rng.normal(size=(t, groups, n)), dtype) * 0.3
+    c = jnp.asarray(rng.normal(size=(t, groups, n)), dtype) * 0.3
+    dt = jnp.asarray(rng.uniform(0.01, 0.2, size=(t, heads)), jnp.float32)
+    a = -jnp.asarray(rng.uniform(0.5, 4.0, size=(heads,)), jnp.float32)
+    return x, b, c, dt, a
+
+
+def _wide_recurrence(h, x, b, c, dt, a, groups=2):
+    h = np.asarray(h, np.float64)
+    x, b, c, dt, a = (np.asarray(t, np.float64) for t in (x, b, c, dt, a))
+    rep, ys = h.shape[0] // groups, []
+    for t in range(x.shape[0]):
+        bh, ch = np.repeat(b[t], rep, 0), np.repeat(c[t], rep, 0)
+        h = np.exp(dt[t] * a)[:, None, None] * h \
+            + (dt[t][:, None] * x[t])[:, :, None] * bh[:, None, :]
+        ys.append(np.einsum("hpn,hn->hp", h, ch))
+    return np.stack(ys), h
+
+
+@pytest.mark.parametrize("backend", ["xla", "pallas_interpret"])
+@pytest.mark.parametrize("live", [[1, 0, 1], [0, 0, 0]])
+def test_decode_update_at_heads_of_128_by_256_and_two_groups(backend, live):
+    """The Falcon-H1 mixer's head (128 x 256, sixteen heads a group there,
+    two here): a live row is one step of the plain recurrence, head h
+    reading group h // (heads / 2); an idle slot's state stays as it is."""
+    rng = np.random.default_rng(11)
+    S, heads = 3, 4
+    assert ssm.decode_lowering(heads, 128, 256, "pallas") == ssm.KERNEL
+    h0 = jnp.asarray(rng.normal(size=(S, heads, 128, 256)), jnp.float32)
+    x, b, c, dt, a = _wide_rows(rng, S, heads)
+    y, h1 = ssm.ssm_decode_update(h0, jnp.asarray(live, jnp.float32), x, b,
+                                  c, dt, jnp.exp(dt * a), backend=backend)
+    for s in range(S):
+        want_y, want_h = _wide_recurrence(h0[s], x[s:s + 1], b[s:s + 1],
+                                          c[s:s + 1], dt[s:s + 1], a)
+        if live[s]:
+            np.testing.assert_allclose(h1[s], want_h, rtol=1e-5, atol=1e-5)
+            np.testing.assert_allclose(y[s], want_y[0], rtol=1e-4, atol=2e-4)
+        else:
+            np.testing.assert_array_equal(h1[s], h0[s])
+
+
+@pytest.mark.parametrize("rows, cut", [(32, 32), (21, 8), (1, 1)])
+def test_chunked_form_at_heads_of_128_by_256_and_two_groups(rows, cut):
+    """`ssd_chunk` over a chunk of 32 of which `rows` are real, against the
+    recurrence token by token; the snapshot inside the chunk is the state
+    after its first `cut` rows."""
+    rng = np.random.default_rng(12)
+    Q, heads = 32, 4
+    h0 = jnp.asarray(rng.normal(size=(heads, 128, 256)), jnp.float32)
+    x, b, c, dt, a = _wide_rows(rng, Q, heads)
+    dt = dt * (jnp.arange(Q) < rows)[:, None]
+    y, h1, snap = ssm.ssd_chunk(h0[None], x[None], b[None], c[None],
+                                dt[None], a, snap_rows=jnp.asarray([cut]))
+    want_y, want_h = _wide_recurrence(h0, x[:rows], b[:rows], c[:rows],
+                                      dt[:rows], a)
+    np.testing.assert_allclose(y[0, :rows], want_y, rtol=2e-4, atol=2e-4)
+    np.testing.assert_allclose(h1[0], want_h, rtol=1e-4, atol=1e-4)
+    _, want_snap = _wide_recurrence(h0, x[:cut], b[:cut], c[:cut], dt[:cut],
+                                    a)
+    np.testing.assert_allclose(snap[0], want_snap, rtol=1e-4, atol=1e-4)
+
+
+def test_the_set_up_counters_count_calls_and_traces():
+    """`ssm/call` a call site, `ssm/body_traced` a trace of the decode
+    kernel's jitted body: a second call of one shape pays no second trace."""
+    from paddle_tpu.observability import tracing
+    rng = np.random.default_rng(13)
+    S, heads = 2, 2
+    h0 = jnp.asarray(rng.normal(size=(S, heads, 8, 128)), jnp.float32)
+    x, b, c, dt, a = _wide_rows(rng, S, heads, 1, 8, 128)
+    ssm._decode_pallas.clear_cache()
+    mark = tracing.mark()
+    for _ in range(2):
+        ssm.ssm_decode_update(h0, jnp.ones((S,)), x, b, c, dt,
+                              jnp.exp(dt * a), backend="pallas_interpret")
+    seen = [(s.name, s.attrs["scope"]) for s in tracing.spans_since(mark)
+            if s.name.startswith("ssm/")]
+    assert seen.count(("ssm/call", "ssm_decode_update")) == 2
+    assert seen.count(("ssm/body_traced", "ssm_decode_update")) == 1

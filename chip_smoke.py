@@ -302,6 +302,7 @@ def phase_serve_lm(scope, vocab=32000, max_len=1024, d_model=1024,
     dispatch = dict(stats["dispatch"])
     late_reads = dispatch.pop("late_reads")
     run_ahead = dispatch.pop("run_ahead")
+    copies_found = dispatch.pop("copies_found")
     host_args = {n: d["host_args"] for n, d in dispatch.items()}
     _check(host_args == {"main": 1, "mixed": 1},
            f"a launch hands over {host_args} host arrays; expected one for "
@@ -324,7 +325,7 @@ def phase_serve_lm(scope, vocab=32000, max_len=1024, d_model=1024,
     return {"compile_s": round(compile_s, 2),
             "run_s": round(result["run_s"], 2),
             "host_args": host_args, "late_reads": late_reads,
-            "run_ahead": run_ahead,
+            "run_ahead": run_ahead, "copies_found": copies_found,
             "requests": n_requests + 1, "max_new": max_new,
             "prompt_lens": [len(p) for p in prompts],
             "ticks": stats["ticks"], "tokens_out": stats["tokens_out"],

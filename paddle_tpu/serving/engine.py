@@ -48,7 +48,7 @@ import os
 import threading
 import time
 from collections import deque
-from typing import Callable, Dict, List, Optional, Sequence
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -275,6 +275,29 @@ class _Running:
             self.mean = mean + (x - mean) / 8
 
 
+def seen_done_lag_s(reads: int = 5) -> float:
+    """How much later than the device the host sees a tick done, taken on
+    the default device: the median of `reads` synchronous reads of a small
+    array that is complete (`np.asarray`, no copy ahead of it). 0.4-0.5 ms
+    on a TPU's host, microseconds on the CPU backend. It is what the pacer
+    took off before the ids' copy started with the launch (the running mean
+    of the tick's own read, 0.41-0.46 ms): the runtime's own way from the
+    device's last op to the host (~0.25 ms on a TPU: PERF.md section 6, PR
+    48) and a copy's enqueue and completion on top, so a launch timed by it
+    comes 0.15-0.2 ms early, on purpose: late costs the chip as much, early
+    costs an arrival a tick only if it falls into that stretch."""
+    import jax
+    ids = np.zeros((8, 1), np.int32)
+    lags = []
+    for _ in range(reads):
+        on_device = jax.device_put(ids)
+        on_device.block_until_ready()
+        t = time.perf_counter()
+        np.asarray(on_device)
+        lags.append(time.perf_counter() - t)
+    return float(np.median(lags))
+
+
 class _Launched:
     """A tick on the device whose ids the host has not read: its
     `engine/tick` span, the fetch, the (request, row) pairs whose row is a
@@ -298,9 +321,12 @@ class _TickPacer:
     arrivals between two steps still gets them into the very next tick.
     Everything is estimated from what the engine sees, on `clock`:
 
-    - `copy_back`: the running mean of the ids' way back (`np.asarray` of
-      a tick that is done). The host sees a tick done that much after the
-      device was: `done` takes it off;
+    - `way_back_s`: how much later than the device the host sees a tick
+      done (`seen_done_lag_s`, taken once when the engine is built: what a
+      synchronous read of a small array that is complete takes). `done`
+      takes it off. It does not come from the tick's own read: the ids'
+      copy is enqueued with the launch (`_plain_tick`), so that read takes
+      what is left of the copy's way, down to nothing;
     - `device_s[program]`: the device times last seen for a tick program,
       each from the later of (the tick before done, this tick's launch
       returned) to this tick done; the hold goes by the LEAST of the last
@@ -317,15 +343,21 @@ class _TickPacer:
       (`nap_floor_s`, taken once, here) and the running mean of what the
       hold's own sleeps overran (`oversleep`)."""
 
-    __slots__ = ("clock", "sleep", "device_s", "own", "gap", "copy_back",
+    #: a read of a tick's ids that returns within this found them on the
+    #: host (`found` on `engine/copy_back`): such a read is 0.02-0.04 ms on
+    #: a TPU's host, one that waits for the copy 0.15 ms and more
+    FOUND_WITHIN_S = 1e-4
+
+    __slots__ = ("clock", "sleep", "device_s", "own", "gap", "way_back_s",
                  "nap_floor_s", "oversleep", "entered_at", "left_at",
                  "free_at")
 
-    def __init__(self, clock=time.perf_counter, sleep=time.sleep):
-        self.clock, self.sleep = clock, sleep
+    def __init__(self, clock=time.perf_counter, sleep=time.sleep,
+                 way_back_s: float = 0.0):
+        self.clock, self.sleep, self.way_back_s = clock, sleep, way_back_s
         self.device_s: Dict[str, deque] = {}
         self.free_at = float("-inf")    # the device was never seen busy
-        self.own, self.gap, self.copy_back = _Running(), _Running(), _Running()
+        self.own, self.gap = _Running(), _Running()
         naps = []
         for _ in range(3):
             t = clock()
@@ -365,7 +397,7 @@ class _TickPacer:
         """`run` was seen done at `seen_at` (a wait on it returned, or ONE
         `np.asarray` of it): when the device was done with it and free for
         the next, and its program's device time."""
-        run.done_at = seen_at - (self.copy_back.mean or 0.0)
+        run.done_at = seen_at - self.way_back_s
         self.device_s.setdefault(run.program, deque(maxlen=3)).append(
             run.done_at - self._started(run))
         self.free_at = run.done_at
@@ -402,12 +434,13 @@ class _TickPacer:
             run.fetch.block_until_ready()
             self.done(run, self.clock())
 
-    def read(self, fetch) -> np.ndarray:
-        """The ids of a tick that is done, timed: their way back."""
+    def read(self, fetch) -> Tuple[np.ndarray, bool]:
+        """The ids of a tick that is done, and whether the read found them
+        on the host (it returned within `FOUND_WITHIN_S`) or had to wait
+        for their copy."""
         t = self.clock()
         ids = np.asarray(fetch)
-        self.copy_back.add(self.clock() - t)
-        return ids
+        return ids, self.clock() - t < self.FOUND_WITHIN_S
 
 
 class ContinuousBatchingEngine:
@@ -547,11 +580,13 @@ class ContinuousBatchingEngine:
         #: and commits it after the next launch
         self._uncommitted: Optional[_Launched] = None
         #: when a late tick's `step()` returns (`_plain_tick`, the hold)
-        self._pacer = _TickPacer()
-        #: ticks whose ids were read a launch late, and ticks launched while
-        #: the tick before was still on the device (`stats()["dispatch"]`)
+        self._pacer = _TickPacer(way_back_s=seen_done_lag_s())
+        #: ticks whose ids were read a launch late, ticks launched while
+        #: the tick before was still on the device, and reads behind a wait
+        #: that found the ids on the host (`stats()["dispatch"]`)
         self.late_reads = 0
         self.run_ahead = 0
+        self.copies_found = 0
         # census counters (`stats()`: occupancy)
         self.n_ticks = 0
         self.busy_slot_ticks = 0
@@ -1040,7 +1075,8 @@ class ContinuousBatchingEngine:
 
     def _plain_tick(self, active: Dict[int, "GenRequest"]
                     ) -> List[GenRequest]:
-        """Fill, launch, read the tick before, commit, hold: one tick. What
+        """Fill, launch (the ids' copy to the host enqueued right behind
+        it), read the tick before, commit, hold: one tick. What
         the host does with a tick's results is in two halves. The POSITIONS
         (`_advance_positions`: `fed`, the blocks filled, who ends by count)
         need no ids and are applied after the launch, beside the device. The
@@ -1071,6 +1107,13 @@ class ContinuousBatchingEngine:
                     self._note_tick_writes(active)
                 with span("dispatch", "engine/launch") as launch:
                     fetches = self._launch_tick()
+                    # the ids' copy to the host is enqueued WITH the tick,
+                    # so no host thread stands in its way: the read (behind
+                    # the next launch on a late tick, at once on an eager
+                    # one) finds the bytes on the host or waits for a copy
+                    # that is on its way (a TPU's runtime starts it once ITS
+                    # host side has seen the tick done: docs/serving.md)
+                    fetches[0].copy_to_host_async()
                     self.target_forwards += 1
                     launch.attrs["host_args"] = self._bound_steps[
                         self._target_state_owner].host_args
@@ -1103,8 +1146,7 @@ class ContinuousBatchingEngine:
                 with span("tick", "engine/wait"):
                     with span("tick", "engine/device_wait"):
                         pacer.wait_for(before)
-                    with span("tick", "engine/copy_back"):
-                        ids = pacer.read(before.fetch)
+                    ids = self._read_ids(before.fetch)
             with span("tick", "engine/commit"):
                 delivered: List[GenRequest] = []
                 if before is not None:
@@ -1126,11 +1168,9 @@ class ContinuousBatchingEngine:
                 # its end (at once where it is found done; to its end, today
                 # as before, while its program's device time was never
                 # seen). An eager one needs its ids now: ONE `np.asarray`,
-                # and on a sampled tick the same in its two parts (the copy
-                # back enqueued first thing, as `np.asarray` alone does,
-                # 0.05-0.1 ms of the host's own work on a TPU, the device
-                # busy through it; the rest of the ids' way back after the
-                # wait)
+                # and on a sampled tick the same in its two parts (the wait
+                # for the tick, then what is left of the ids' way back: their
+                # copy was enqueued at the launch, like every tick's)
                 if late:
                     target = pacer.hold_until(run)
                     if target is None:
@@ -1146,11 +1186,8 @@ class ContinuousBatchingEngine:
                 elif (self.n_ticks % self.WAIT_SPLIT_EVERY == 0
                         and _tracing.enabled()):
                     with span("tick", "engine/device_wait"):
-                        fetches[0].copy_to_host_async()
-                        fetches[0].block_until_ready()
-                        pacer.done(run, pacer.clock())
-                    with span("tick", "engine/copy_back"):
-                        ids = pacer.read(fetches[0])
+                        pacer.wait_for(run)
+                    ids = self._read_ids(fetches[0])
                 else:
                     ids = np.asarray(fetches[0])
                     pacer.done(run, pacer.clock())
@@ -1172,6 +1209,16 @@ class ContinuousBatchingEngine:
                 finished = self._commit_ids(run.emits, ids)
         self._finish(finished)
         return delivered + finished
+
+    def _read_ids(self, fetch) -> np.ndarray:
+        """The ids of a tick that is done, under `engine/copy_back`: `found`
+        1 where the copy started at the launch had brought them to the host
+        already, 0 where the read had to wait for it."""
+        with _tracing.span("tick", "engine/copy_back") as back:
+            ids, found = self._pacer.read(fetch)
+            back.attrs["found"] = int(found)
+        self.copies_found += found
+        return ids
 
     def _note_tick_counts(self, tick, ids: np.ndarray):
         """What the tick brought back behind its ids, onto ITS `engine/tick`
@@ -1346,12 +1393,14 @@ class ContinuousBatchingEngine:
             "speculative": (self.spec.stats()
                             if self.spec is not None else None),
             # per bound step, the host arrays one launch hands over; the
-            # ticks whose ids were read a launch late; and those launched
-            # while the tick before was still on the device
+            # ticks whose ids were read a launch late; those launched while
+            # the tick before was still on the device; and the reads of a
+            # tick seen done that found its ids on the host
             "dispatch": {**{name: {"host_args": step.host_args}
                             for name, step in self._bound_steps.items()},
                          "late_reads": self.late_reads,
-                         "run_ahead": self.run_ahead},
+                         "run_ahead": self.run_ahead,
+                         "copies_found": self.copies_found},
         }
 
 

@@ -231,7 +231,8 @@ def test_mixed_tick_runs_on_the_decode_ticks_arrays():
     assert eng._pos is eng._feeds["tick_pos"]
     assert eng.stats()["dispatch"] == {"main": {"host_args": 1},
                                        "mixed": {"host_args": 1},
-                                       "late_reads": 0, "run_ahead": 0}
+                                       "late_reads": 0, "run_ahead": 0,
+                                       "copies_found": 0}
 
 
 def test_a_feed_on_one_side_only_cannot_happen():

@@ -387,10 +387,10 @@ class _Clock:
         self.now += s + self.overrun
 
 
-def _pacer(**kw):
+def _pacer(way_back_s=0.0, **kw):
     from paddle_tpu.serving.engine import _TickPacer
     clock = _Clock(**kw)
-    pacer = _TickPacer(clock=clock, sleep=clock.sleep)
+    pacer = _TickPacer(clock=clock, sleep=clock.sleep, way_back_s=way_back_s)
     clock.naps.clear()                  # the three that took the floor
     return pacer, clock
 
@@ -516,16 +516,71 @@ def test_a_compile_inside_a_launch_does_not_stay_in_the_lead():
 
 
 def test_seen_done_is_taken_back_by_the_ids_way_back():
-    """The host sees a tick done later than the device was: by the copy back
-    where ONE `np.asarray` read it, timed on the ticks where wait and copy
-    back are apart."""
-    pacer, clock = _pacer()
-    for _ in range(3):
-        pacer.copy_back.add(0.4)
+    """The host sees a tick done later than the device was: by the way back
+    the engine measured when it was built (`seen_done_lag_s`)."""
+    pacer, clock = _pacer(way_back_s=0.4)
     a = _tick(pacer, clock, "mixed", 0.0, 1.0, 4.0)
     pacer.done(a, 5.4)                  # the `np.asarray` returned at 5.4
     assert a.done_at == pytest.approx(5.0) == pacer.free_at
     assert list(pacer.device_s["mixed"]) == pytest.approx([4.0])
+
+
+class _Ids(_Fetch):
+    """A fetch whose ids reach the host `way` after the tick's end: a read
+    before that waits for them, one after it costs `found` on the clock."""
+
+    def __init__(self, clock, at, way, found=1e-5):
+        super().__init__(clock, at)
+        self.way, self.found = way, found
+
+    def __array__(self, *a, **kw):
+        self.clock.now = max(self.clock.now, self.at + self.way) + self.found
+        return np.zeros((2, 1), np.int32)
+
+
+def test_a_read_that_costs_nothing_leaves_the_targets_where_they_were():
+    """The ids' copy starts with the launch, so the read of a tick that is
+    done finds them on the host and takes microseconds. What `done` takes
+    off "seen done" is the way back the engine measured, not the read's
+    time: `free_at` and the hold's target are the parent's, whose reads took
+    the way back's length (a pacer that went by the read's own time would
+    see every tick done 0.4 later, and launch as late)."""
+    way = 0.4
+    pacer, clock = _pacer(way_back_s=way)
+    t = 0.0
+    for _ in range(4):                  # each waited out, then read: instant
+        run = _tick(pacer, clock, "main", t, 1.0, 8.0)
+        run.fetch = _Ids(clock, run.fetch.at, way)
+        clock.now = run.fetch.at + way  # the block returns the way back late
+        pacer.done(run, clock.now)
+        ids, found = pacer.read(run.fetch)
+        assert found and ids.shape == (2, 1)
+        assert clock.now == pytest.approx(run.fetch.at + way + 1e-5)
+        assert pacer.free_at == pytest.approx(run.fetch.at) == run.done_at
+        pacer.left()
+        t = clock.now + 0.25
+    assert list(pacer.device_s["main"]) == pytest.approx([8.0] * 3)
+    nxt = _tick(pacer, clock, "main", t, 1.0, 8.0)
+    assert pacer.hold_until(nxt) == pytest.approx(
+        t + 1.0 + 8.0 - pacer.lead_s())
+    # behind a tick that is still running: from THAT tick's end, as it was
+    pacer.done(nxt, nxt.fetch.at + way)
+    behind = _tick(pacer, clock, "main", nxt.fetch.at - 2.0, 1.0, 8.0,
+                   free=nxt.fetch.at)
+    assert pacer.hold_until(behind) == pytest.approx(
+        nxt.fetch.at + 8.0 - pacer.lead_s())
+
+
+def test_found_is_the_read_that_did_not_wait():
+    pacer, clock = _pacer(way_back_s=4e-4)
+    limit = pacer.FOUND_WITHIN_S
+    done_long_ago = _Ids(clock, clock.now - 1.0, 3e-4)
+    _, found = pacer.read(done_long_ago)
+    assert found is True
+    just_done = _Ids(clock, clock.now, 3e-4)        # the copy is on its way
+    t = clock.now
+    _, found = pacer.read(just_done)
+    assert found is False and clock.now - t >= 3e-4 > limit
 
 
 def test_a_sleep_is_cut_by_twice_what_sleeps_overran():
@@ -722,3 +777,139 @@ def test_fail_all_with_a_tick_on_the_device(kind):
     assert all(r.done and r.error is boom for r in reqs)
     with pytest.raises(RuntimeError):
         eng.submit([1, 2], 2)
+
+
+# -- a tick's ids start their way back when the tick is launched (ISSUE 55) --
+
+
+class _Recorded:
+    """A tick's fetch that writes down what is done to it, in `log`."""
+
+    def __init__(self, fetch, log):
+        self.fetch, self.log = fetch, log
+
+    def copy_to_host_async(self):
+        self.log.append(("copy", self))
+        self.fetch.copy_to_host_async()
+
+    def is_ready(self):
+        return self.fetch.is_ready()
+
+    def block_until_ready(self):
+        self.log.append(("wait", self))
+        self.fetch.block_until_ready()
+
+    def __array__(self, *a, **kw):
+        self.log.append(("read", self))
+        return np.asarray(self.fetch)
+
+
+def _record_launches(eng):
+    """-> the log of every launch of `eng` from here on and of what was done
+    to its ids."""
+    log, launch = [], eng._launch_tick
+
+    def recorded():
+        fetches = list(launch())
+        fetches[0] = _Recorded(fetches[0], log)
+        log.append(("launch", fetches[0]))
+        return fetches
+    eng._launch_tick = recorded
+    return log
+
+
+@pytest.mark.parametrize("kind", ["slot", "classic", "classic_one_token",
+                                  "ssm"])
+def test_the_copy_starts_at_the_launch_and_tokens_are_the_eager_orders(kind):
+    """Every launch enqueues its ids' copy to the host ONCE, before anything
+    waits for the tick or reads it, late ticks and eager ones alike, with
+    every eager tick realized in its two parts (the sampled split tick); the
+    order of launches, reads and commits is the parent's, so the tokens are
+    those of the order that commits every tick."""
+    make = KINDS[kind]()
+    eng, ref = make(), make()
+    ref._late_ok = False
+    eng.WAIT_SPLIT_EVERY = 1
+    load = _load(eng._builder_dims["vocab"])
+    log = _record_launches(eng)
+    want, _ = _serve(ref, load)
+    mark = tracing.mark()
+    reqs, _ = _serve(eng, load)
+    assert [r.tokens for r in reqs] == [r.tokens for r in want]
+    launched = [f for what, f in log if what == "launch"]
+    assert len(launched) == eng.n_ticks and eng.late_reads > 0
+    spans = tracing.spans_since(mark)
+    lates = [s.attrs["late"] for s in spans if s.name == "engine/tick"]
+    for k, fetch in enumerate(launched):
+        mine = [what for what, f in log if f is fetch]
+        assert mine[:2] == ["launch", "copy"] and mine.count("copy") == 1
+        assert mine.count("read") == 1
+        # a late tick is read behind the NEXT launch, an eager one at once
+        read_at = log.index(("read", fetch))
+        nxt = (log.index(("launch", launched[k + 1]))
+               if k + 1 < len(launched) else len(log))
+        assert (nxt < read_at) == bool(lates[k])
+    backs = [s for s in spans if s.name == "engine/copy_back"]
+    assert len(backs) == len(launched)      # every read is one: split or late
+    assert all(s.attrs["found"] in (0, 1) for s in backs)
+    assert eng.stats()["dispatch"]["copies_found"] == \
+        sum(s.attrs["found"] for s in backs) == eng.copies_found
+
+
+def test_found_on_the_span_is_the_read_that_did_not_wait():
+    """On an injected clock: the ids reach the host 0.3 ms after their tick's
+    end. A tick read long after its end (the host's period is the longer
+    one) is `found`; one read the instant it is seen done is not."""
+    make = KINDS["classic"]()
+    eng = make()
+    clock = _Clock(look=1e-6)
+    eng._pacer = type(eng._pacer)(clock=clock, sleep=clock.sleep,
+                                  way_back_s=3e-4)
+    host_s = [0.0]
+    free = [clock.now]
+
+    class OnItsWay(_Ids):
+        def __init__(self, fetch):
+            free[0] = max(free[0], clock.now) + 0.002
+            super().__init__(clock, free[0], 3e-4)
+            self.fetch = fetch
+
+        def copy_to_host_async(self):
+            pass
+
+        def __array__(self, *a, **kw):
+            super().__array__()
+            return np.asarray(self.fetch)
+
+    launch = eng._launch_tick
+
+    def timed_launch():
+        clock.now += host_s[0]
+        return [OnItsWay(f) for f in launch()]
+    eng._launch_tick = timed_launch
+    load = _load(50)[:3]
+    # no eager tick is realized in two parts (the first would be): such a
+    # read comes the instant its tick is seen done, by construction
+    eng.n_ticks, eng.WAIT_SPLIT_EVERY = 1, 1 << 30
+
+    def founds():
+        mark = tracing.mark()
+        for _, p, n in load:
+            eng.submit(p, n)
+        eng.run_until_idle()
+        spans = tracing.spans_since(mark)
+        late = {s.attrs.get("late") for s in spans if s.name == "engine/tick"}
+        assert late == {0, 1}
+        return [s.attrs["found"] for s in spans
+                if s.name == "engine/copy_back"]
+    # a launch that costs the host 5 ms behind a 2 ms tick: every late read
+    # comes long after its tick's end
+    host_s[0] = 0.005
+    found = founds()
+    assert found and all(found)
+    # a launch that costs nothing: the read comes the instant the wait for
+    # the tick returns, its copy still on its way
+    host_s[0] = 0.0
+    found = founds()
+    assert found and not any(found)
+    assert eng.copies_found == eng.stats()["dispatch"]["copies_found"] > 0

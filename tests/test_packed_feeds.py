@@ -319,10 +319,14 @@ def test_paged_engine_emits_the_unpacked_paths_tokens(kind, monkeypatch):
     # blocks between ticks and reads each at once
     late = packed.stats()["dispatch"]["late_reads"]
     ahead = packed.stats()["dispatch"]["run_ahead"]
+    found = packed.stats()["dispatch"]["copies_found"]
     assert (late > 0) == (kind == "chunked") and 0 <= ahead <= late
+    # a read that finds the ids on the host is one behind a wait for the tick
+    assert found == sum(s.attrs["found"] for s in spans
+                        if s.name == "engine/copy_back")
     assert packed.stats()["dispatch"] == {
         **{n: {"host_args": 1} for n in names}, "late_reads": late,
-        "run_ahead": ahead}
+        "run_ahead": ahead, "copies_found": found}
     launches = [s for s in spans if s.name == "engine/launch"]
     ticks = [s for s in spans if s.name == "engine/tick"]
     assert len(launches) == len(ticks) == len(launched)
@@ -335,7 +339,8 @@ def test_slot_engine_and_its_hlo():
     eng = ContinuousBatchingEngine(n_slots=3, max_len=_MAX_LEN,
                                    scope=_trained_scope(), **_DIMS)
     assert eng.stats()["dispatch"] == {"main": {"host_args": 1},
-                                       "late_reads": 0, "run_ahead": 0}
+                                       "late_reads": 0, "run_ahead": 0,
+                                       "copies_found": 0}
     hlo = eng.tick_hlo()
     # seed + tick_tok [3,1] + tick_pos [3,1,1] + tick_from_last [3,1]: ONE
     # small host argument
@@ -358,7 +363,8 @@ def test_speculative_engine_binds_four_steps_of_one_host_array(paged):
     assert spec.spec.stats()["rounds"] > 0
     assert spec.stats()["dispatch"] == {
         **{n: {"host_args": 1} for n in ("main", "draft", "verify")},
-        "late_reads": 0, "run_ahead": 0}
+        "late_reads": 0, "run_ahead": 0,
+        "copies_found": spec.copies_found}
 
 
 def test_topk_engine_and_beam_search_through_run():
@@ -370,7 +376,8 @@ def test_topk_engine_and_beam_search_through_run():
     greedy, topk = PagedKVEngine(**kw), PagedKVEngine(topk_k=3, **kw)
     assert topk.prefill == "one_token"
     assert topk.stats()["dispatch"] == {"main": {"host_args": 1},
-                                        "late_reads": 0, "run_ahead": 0}
+                                        "late_reads": 0, "run_ahead": 0,
+                                        "copies_found": 0}
     prompt = _requests()[2]
     want = _gen(greedy, [prompt], max_new=6)[0]
     (tokens, _), = paged_beam_search(topk, prompt, max_new=6, beam_size=1)

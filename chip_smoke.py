@@ -93,18 +93,12 @@ def _n_custom_calls(hlo: str) -> int:
     return hlo.count(_MOSAIC_CALL)
 
 
-def _count_compiles():
-    """The running list of XLA compilations (a load of a cached executable
-    counts), one entry each; the listener is registered on first use."""
-    if not hasattr(_count_compiles, "log"):
-        import jax.monitoring
-        log = _count_compiles.log = []
-
-        def on(event, duration, **kw):
-            if event == "/jax/core/compile/backend_compile_duration":
-                log.append(duration)
-        jax.monitoring.register_event_duration_secs_listener(on)
-    return _count_compiles.log
+def _executables():
+    """How many executables this process has compiled or loaded so far, by
+    the program's own record (`tracing.compile_spans()`: each first run's
+    span and the `jax/unscoped` records; docs/observability.md)."""
+    from paddle_tpu.observability import tracing
+    return sum(s.attrs.get("executables", 0) for s in tracing.compile_spans())
 
 
 def _free_device_memory():
@@ -195,6 +189,15 @@ def phase_train_lm(vocab=32000, seq_len=1024, d_model=1024, d_inner=4096,
     feed = _lm_feed(batch, seq_len, vocab)
     losses, compile_s, run_s = _run_steps(
         lambda: exe.run(feed=feed, fetch_list=[loss])[0], steps)
+    # the step's first run is on record as what it was: a compile or a load
+    from paddle_tpu.observability import tracing
+    first = [s.attrs for s in tracing.compile_spans()
+             if s.name == "executor/compile_or_load"
+             and s.attrs["program"] == "train_step"]
+    _check(len(first) == 1 and first[0]["executables"] >= 1,
+           f"the training step's first run left {first} as its "
+           f"executor/compile_or_load spans; expected one, with an "
+           f"executable compiled or loaded")
     t0 = time.time()
     n_calls = _n_custom_calls(exe.compiled_hlo(feed=feed, fetch_list=[loss]))
     hlo_s = time.time() - t0
@@ -206,6 +209,8 @@ def phase_train_lm(vocab=32000, seq_len=1024, d_model=1024, d_inner=4096,
     return {"compile_s": round(compile_s, 2), "run_s": round(run_s, 2),
             "hlo_s": round(hlo_s, 2), "steps": steps,
             "losses": [round(x, 4) for x in losses],
+            "first_run": {k: round(first[0][k], 3) for k in (
+                "trace_s", "lower_s", "compile_s", "cache_load_s")},
             "tpu_custom_calls": n_calls, "batch_tokens": batch * seq_len}
 
 
@@ -233,8 +238,7 @@ def phase_serve_lm(scope, vocab=32000, max_len=1024, d_model=1024,
     _check(eng.stats()["prefill"] == "chunked",
            f"the engine consumes prompts {eng.stats()['prefill']!r}, not "
            f"in chunks through the mixed tick's lanes")
-    compiles = _count_compiles()
-    built = len(compiles)
+    built = _executables()
 
     rng = np.random.RandomState(1)
     prompts = [rng.randint(0, vocab, (int(n),)).tolist()
@@ -281,7 +285,7 @@ def phase_serve_lm(scope, vocab=32000, max_len=1024, d_model=1024,
     # serving compiled the engine's two tick programs (the mixed tick on the
     # first prompt, the decode tick on the first tick without one) and
     # nothing else: every later shape of traffic runs one of the two
-    n_programs = len(compiles) - built
+    n_programs = _executables() - built
     _check(n_programs == 2,
            f"serving compiled {n_programs} programs; the engine has two "
            f"(the decode tick and the mixed tick)")

@@ -7,7 +7,11 @@ metrics, io — implemented TPU-first: programs trace to jax functions compiled
 by XLA; parallelism is SPMD over a jax.sharding.Mesh with compiled collectives.
 """
 
-from .core import compile_cache as _compile_cache
+import time as _time
+
+_import_began = _time.perf_counter()    # the `paddle_tpu/import` span's start
+
+from .core import compile_cache as _compile_cache  # noqa: E402
 
 _compile_cache.configure()
 
@@ -48,3 +52,9 @@ from .io import (load_inference_model, load_params,  # noqa: F401,E402
                  save_params, save_persistables, save_vars)
 
 __version__ = "0.1.0"
+
+# what a process pays before its first statement of work: a `compile` span
+# like the other once-only costs (JAX's own import is in it where nothing
+# imported JAX before)
+observability.tracing.record_span("compile", "paddle_tpu/import",
+                                  _import_began, _time.perf_counter())

@@ -28,6 +28,7 @@ import numpy as np
 from ..core import flags
 from ..core.enforce import InvalidArgumentError, NotFoundError, enforce
 from ..core.places import Place, default_place
+from ..observability import tracing as _tracing
 from .lowering import LowerCtx, build_plan, run_plan
 from .program import (BATCH_ROW_MASK_NAME, Program, Variable,
                       default_main_program)
@@ -65,13 +66,42 @@ def as_numpy(x):
 
 
 class _CompiledStep:
-    def __init__(self, fn, ro_names, rw_names, feed_names, fetch_names):
+    def __init__(self, fn, ro_names, rw_names, feed_names, fetch_names,
+                 program):
         self.fn = fn
         self.ro_names = ro_names
         self.rw_names = rw_names
         self.feed_names = feed_names
         self.fetch_names = fetch_names
         self._packed_fns = {}
+        #: what the step's `compile` spans call it
+        self.program = program
+        #: its jitted functions that have not run yet (`first_run`)
+        self.unrun = {fn}
+
+    def first_run(self, fn):
+        """The `executor/compile_or_load` span around `fn`'s FIRST call:
+        jax.jit is lazy, so a jitted function's trace, lowering and XLA
+        compile (or its load from the persistent cache) all happen inside
+        that call, and it is where the span is (`tracing.compile_span`). A
+        launch path opens it in its OWN frame, while `unrun` holds its
+        function (the steady path pays that one test; a step served through
+        a PreparedStep never runs `self.fn`, which stays in the set and costs
+        the packed launches nothing):
+
+            if fn in compiled.unrun:
+                with compiled.first_run(fn):
+                    out = fn(*args)
+            else:
+                out = fn(*args)
+
+        and NOT through a wrapper that calls `fn`: a frame between the
+        launcher and the jitted function is in every traced op's location,
+        so in every Mosaic kernel's serialized body and the compile cache's
+        key, and it cost the routed training cell 3 s of set-up (PERF.md
+        section 6, PR 57). `fn` has run once the block is left without an
+        exception."""
+        return _FirstRun(self, fn)
 
     def packed_fn(self, spans):
         """The step as a PreparedStep launches it: `fn(pack, rest_vals,
@@ -116,7 +146,21 @@ class _CompiledStep:
             return inner(feed_vals, ro_vals, rw_vals, seed)
 
         fn = self._packed_fns[spans] = jax.jit(step, **kwargs)
+        self.unrun.add(fn)
         return fn
+
+
+class _FirstRun(_tracing.compile_span):
+    __slots__ = ("_step", "_fn")
+
+    def __init__(self, step, fn):
+        super().__init__("executor/compile_or_load", step.program)
+        self._step, self._fn = step, fn
+
+    def __exit__(self, *exc):
+        if exc[0] is None:
+            self._step.unrun.discard(self._fn)
+        return super().__exit__(*exc)
 
 
 def _packed_dtype(v):
@@ -225,7 +269,13 @@ class PreparedStep:
         self._owner._run_counter += 1
         self._buf[0] = (self._random_seed * 1000003
                         + self._owner._run_counter) % (2 ** 31)
-        fetches, new_state = self._fn(self._buf, rest_vals, ro_vals, rw_vals)
+        fn = self._fn
+        if fn in compiled.unrun:
+            with compiled.first_run(fn):
+                fetches, new_state = fn(self._buf, rest_vals, ro_vals,
+                                        rw_vals)
+        else:
+            fetches, new_state = fn(self._buf, rest_vals, ro_vals, rw_vals)
         for name, val in zip(compiled.state_out_names, new_state):
             scope.set_var(name, val)
         if self._b_rw_vals is not None:
@@ -340,8 +390,14 @@ class PreparedStep:
         staged, other = self._b_staged
         self._b_staged = (other, staged)
         np.copyto(staged, buf)
-        fetches, new_state = self._fn(staged, self._b_rest_vals,
-                                      self._b_ro_vals, self._b_rw_vals)
+        fn = self._fn
+        if fn in self._compiled.unrun:
+            with self._compiled.first_run(fn):
+                fetches, new_state = fn(staged, self._b_rest_vals,
+                                        self._b_ro_vals, self._b_rw_vals)
+        else:
+            fetches, new_state = fn(staged, self._b_rest_vals,
+                                    self._b_ro_vals, self._b_rw_vals)
         self._b_rw_vals = self._b_rw_pick(new_state)
         sv = self._b_scope_vars
         for name, val in zip(self._b_state_names, new_state):
@@ -522,7 +578,8 @@ class Executor:
                 compiled._mfu_warm = True
 
     def _compile(self, program: Program, scope: Scope, feed_names, fetch_names,
-                 in_shardings=None, out_shardings=None, analysis=None):
+                 in_shardings=None, out_shardings=None, analysis=None,
+                 name=None):
         program = self._prepare_program(program, scope)
         ro, rw, out_only = analysis or self._analyze_state(
             program, scope, feed_names, fetch_names)
@@ -541,7 +598,10 @@ class Executor:
         if out_shardings is not None:
             jit_kwargs["out_shardings"] = out_shardings
         fn = jax.jit(step, **jit_kwargs)
-        compiled = _CompiledStep(fn, ro, rw, feed_names, fetch_names)
+        if name is None:
+            name = ("startup" if not feed_names and not fetch_names
+                    else "train_step" if state_out_names else "infer_step")
+        compiled = _CompiledStep(fn, ro, rw, feed_names, fetch_names, name)
         compiled.state_out_names = state_out_names
         # what a PreparedStep builds its one-host-array launch from
         # (_CompiledStep.packed_fn)
@@ -619,12 +679,14 @@ class Executor:
         return feed
 
     def _lookup_or_compile(self, program: Program, feed: Dict[str, Any],
-                           fetch_names, scope: Scope) -> _CompiledStep:
+                           fetch_names, scope: Scope,
+                           name=None) -> _CompiledStep:
         """Validate fetch targets and return the cached compiled step for
         (program, feed signature, fetches, scope contents), compiling on
         miss. The cache key includes which persistable vars currently exist
         in the scope: compiling before the startup program ran must not
-        poison the cache for post-initialization runs."""
+        poison the cache for post-initialization runs. `name`: what the
+        step's `compile` spans call a step built here (`prepare`)."""
         self._validate_fetches(program, feed, fetch_names)
         avail_key = self._scope_avail_key(program, scope)
         key = (id(program), program._version, _feed_signature(feed),
@@ -636,12 +698,13 @@ class Executor:
             # (and ParallelExecutor's feed shardings, which stash the
             # same dict in run()); keep them current for this compile
             self._feed_shapes = {n: np.shape(v) for n, v in feed.items()}
-            from ..observability import tracing as _tracing
-            with _tracing.span("compile", "executor/trace_and_compile",
+            # the step FUNCTION is built here and nothing is compiled:
+            # jax.jit is lazy (`_CompiledStep.first_run` holds the compile)
+            with _tracing.span("compile", "executor/build_step",
                                program_version=program._version,
                                n_fetches=len(fetch_names)):
                 compiled = self._compile(program, scope, list(feed.keys()),
-                                         fetch_names)
+                                         fetch_names, name=name)
             self._cache[key] = compiled
         return compiled
 
@@ -655,7 +718,6 @@ class Executor:
         """≙ Executor.run (reference executor.py:374-473). Missing fetch vars
         raise; feed arrays are validated against declared var dtypes."""
         from .. import profiler as _prof
-        from ..observability import tracing as _tracing
         program = program or default_main_program()
         scope = scope or global_scope()
         with _tracing.span("step", "executor/lookup"):
@@ -685,7 +747,13 @@ class Executor:
         t0 = time.time()
         with _tracing.span("step", "executor/run",
                            program_version=program._version):
-            fetches, new_state = compiled.fn(feed_vals, ro_vals, rw_vals, seed)
+            fn = compiled.fn
+            if fn in compiled.unrun:
+                with compiled.first_run(fn):
+                    fetches, new_state = fn(feed_vals, ro_vals, rw_vals,
+                                            seed)
+            else:
+                fetches, new_state = fn(feed_vals, ro_vals, rw_vals, seed)
             if _prof.profiler_enabled():
                 jax.block_until_ready(fetches)
         if flags.get_flag("check_nan_inf") and jax.default_backend() != "cpu":
@@ -805,8 +873,8 @@ class Executor:
                 jit_kwargs["in_shardings"] = scan_sh[0]
                 jit_kwargs["out_shardings"] = scan_sh[1]
             fn = jax.jit(loop, **jit_kwargs)
-            compiled = _CompiledStep(fn, ro, rw,
-                                     list(feed_list[0].keys()), fetch_names)
+            compiled = _CompiledStep(fn, ro, rw, list(feed_list[0].keys()),
+                                     fetch_names, "run_steps")
             compiled.state_out_names = state_out_names
             self._stash_flops_estimate(compiled, program,
                                        feed=feed_list[0])
@@ -826,11 +894,16 @@ class Executor:
             from ..observability.memory import per_device_bytes
             compiled.census_state_bytes = sum(
                 per_device_bytes(v) for v in ro_vals + rw_vals)
-        from ..observability import tracing as _tracing
         t0 = time.time()
         with _tracing.span("step", "executor/run_steps", steps=k):
-            fetches, final_state = compiled.fn(feed_stacks, ro_vals, rw_vals,
-                                               seed)
+            fn = compiled.fn
+            if fn in compiled.unrun:
+                with compiled.first_run(fn):
+                    fetches, final_state = fn(feed_stacks, ro_vals, rw_vals,
+                                              seed)
+            else:
+                fetches, final_state = fn(feed_stacks, ro_vals, rw_vals,
+                                          seed)
         if flags.get_flag("check_nan_inf") and jax.default_backend() != "cpu":
             # same contract as run(): sweep BEFORE the scope write-back so
             # the last-good parameters stay checkpointable when a step in
@@ -851,20 +924,24 @@ class Executor:
                 program: Optional[Program] = None,
                 feed: Optional[Dict[str, Any]] = None,
                 fetch_list: Optional[Sequence[Union[str, Variable]]] = None,
-                scope: Optional[Scope] = None) -> "PreparedStep":
+                scope: Optional[Scope] = None,
+                name: Optional[str] = None) -> "PreparedStep":
         """Compile (or fetch from cache) the step for this exact
         (program, feed signature, fetch list, scope) and return a
         PreparedStep whose run() skips every per-call setup cost.
 
         `feed` is an EXAMPLE feed carrying the signature (names, shapes,
-        dtypes) every later PreparedStep.run call must match."""
+        dtypes) every later PreparedStep.run call must match. `name` is the
+        `program` of the step's `executor/compile_or_load` spans where this
+        call builds it (an engine names its tick programs)."""
         program = program or default_main_program()
         user_names = set(feed or {})
         feed = self._synthesize_batch_mask(program, dict(feed or {}))
         fetch_names = [f.name if isinstance(f, Variable) else f
                        for f in (fetch_list or [])]
         scope = scope or global_scope()
-        compiled = self._lookup_or_compile(program, feed, fetch_names, scope)
+        compiled = self._lookup_or_compile(program, feed, fetch_names, scope,
+                                           name=name)
         # keys synthesize added beyond the caller's example feed (the
         # reserved @batch_row_mask) become per-call constants: the batch
         # size is pinned by the prepared signature, so the all-ones mask

@@ -17,7 +17,15 @@ only while a profiler context was open. This module is the ONE recorder:
 - `export_chrome_trace()` / `aggregate()` turn the ring into the Chrome
   (catapult) timeline and the per-span summary tables;
   `paddle_tpu/profiler.py` keeps its fluid-compatible surface as a thin
-  window over this ring (`RecordEvent` == a "user" span).
+  window over this ring (`RecordEvent` == a "user" span);
+- `compile_span(name, program)` is the span around a jitted function's FIRST
+  call: JAX's own seconds of tracing, lowering, compiling and loading from
+  the persistent cache (one `jax.monitoring` listener, registered here) land
+  on the innermost one open on the compiling thread, and otherwise in the
+  open `jax/unscoped` record, which `mark()` closes into a span. Spans of
+  kind `compile` are rare and told once, so they are ALSO kept beside the
+  ring (`compile_spans()`): a ring that wrapped or was resized still says
+  what a process compiled, and when.
 
 Span kinds are CLOSED (SPAN_KINDS): a typo'd kind raises instead of
 minting a new category that no aggregation ever finds.
@@ -25,6 +33,7 @@ minting a new category that no aggregation ever finds.
 
 from __future__ import annotations
 
+import collections
 import itertools
 import json
 import os
@@ -32,12 +41,17 @@ import threading
 import time
 from typing import Any, Callable, Dict, List, Optional
 
+import jax.monitoring
+
 from ..core import flags
 from ..core.enforce import (InvalidArgumentError, OutOfRangeError,
                             enforce)
 
 SPAN_KINDS = frozenset({
-    "compile",     # executor trace+XLA-compile of a program
+    "compile",     # what a process pays once: the package's import, a step
+                   # function built, a jitted function's first call (its
+                   # trace, lowering, XLA compile or cache load); kept
+                   # beside the ring too (compile_spans)
     "step",        # one executor.run / run_steps dispatch
     "tick",        # one serving-engine decode tick
     "collective",  # host-side collective setup (placement, reconcile)
@@ -215,6 +229,8 @@ def mark() -> int:
     # would skip a slot. Track via a sacrificial draw is wrong; instead
     # the mark is the NEXT sequence number, derived from a draw we then
     # hand to no span — acceptable: one empty slot per mark.
+    if _unscoped is not None:
+        _close_unscoped()
     return next(_seq)
 
 
@@ -225,6 +241,8 @@ def _record(span: Span):
     # instrumented hot path
     ring = _ensure_ring()
     ring[span.seq % len(ring)] = span
+    if span.kind == "compile":
+        _keep(span)
 
 
 class span:
@@ -350,12 +368,197 @@ def record_counter(name: str, value: float, **attrs) -> Optional[Span]:
     return s
 
 
+# -- compiles ----------------------------------------------------------------
+# jax.jit is lazy: a function's trace, its lowering and the XLA compile (or
+# the load from the persistent cache) all happen inside its FIRST call. JAX
+# times each itself and tells whoever listens; `compile_span` is where those
+# seconds get a place and a program's name.
+
+#: the duration events JAX reports, by what this module files them under
+_JAX_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "load",
+}
+_KEPT_CAP = 4096
+_kept: "collections.deque[Span]" = collections.deque(maxlen=_KEPT_CAP)
+_kept_dropped = 0
+_unscoped: Optional["_JaxSeconds"] = None    # the open jax/unscoped record
+_unscoped_lock = threading.Lock()
+
+
+class _JaxSeconds:
+    """JAX's own seconds of one first call (or of everything outside one),
+    summed by kind as the listener hears them."""
+
+    __slots__ = ("first", "last", "traces", "trace", "longest", "lower",
+                 "backend", "executables", "load", "loads")
+
+    def __init__(self):
+        self.first = self.last = None    # where the events lie, perf_counter
+        self.traces = self.executables = self.loads = 0
+        self.trace = self.longest = self.lower = 0.0
+        self.backend = self.load = 0.0
+
+    def add(self, what, seconds):
+        now = time.perf_counter()
+        if self.first is None:
+            self.first = now - seconds
+        self.last = now
+        if what == "trace":
+            self.traces += 1
+            self.trace += seconds
+            self.longest = max(self.longest, seconds)
+        elif what == "lower":
+            self.lower += seconds
+        elif what == "backend":
+            self.executables += 1
+            self.backend += seconds
+        else:
+            self.loads += 1
+            self.load += seconds
+
+    def attrs(self, nested):
+        """The span's attrs. Traces NEST inside a first call (a jitted
+        helper's trace is an event of its own inside the step's): the longest
+        is the outermost, the others an upper bound of what nested in it.
+        Outside one every trace is counted whole. JAX's backend event holds
+        the cache's retrieval when there is one: `compile_s` is it LESS the
+        retrieval, ~0 where every executable came from the cache."""
+        outer = self.longest if nested else self.trace
+        inner = max(self.traces - 1, 0) if nested else self.traces
+        return {"trace_s": outer, "nested_trace_s": self.trace - outer,
+                "jits": inner, "lower_s": self.lower,
+                "compile_s": max(self.backend - self.load, 0.0),
+                "cache_load_s": self.load, "executables": self.executables,
+                "cache_hit": int(0 < self.executables <= self.loads)}
+
+
+class compile_span(span):
+    """The live span of kind `compile` around the first call of a jitted
+    function: `with compile_span("executor/compile_or_load", "train_step")`.
+    While it is open JAX's duration events on THIS thread add into it (the
+    innermost one, where they nest), and at exit it carries `program`,
+    `trace_s`, `nested_trace_s`, `jits`, `lower_s`, `compile_s`,
+    `cache_load_s`, `executables`, `cache_hit` (`_JaxSeconds.attrs`) and
+    `kernel_calls` / `kernel_bodies_traced`: the `*/call` and `*/body_traced`
+    counter samples this thread recorded meanwhile, read in the ring."""
+
+    __slots__ = ("_jax", "_seq0")
+
+    def __init__(self, name: str, program: str):
+        super().__init__("compile", name, program=program)
+
+    def __enter__(self):
+        super().__enter__()
+        if self._stack is not None:
+            self._jax = _JaxSeconds()
+            self._seq0 = next(_seq)      # a mark of its own: one empty slot
+        return self
+
+    def __exit__(self, *exc):
+        if self._stack is not None:
+            calls, bodies = _kernel_samples(self._seq0, self._start)
+            self.attrs.update(self._jax.attrs(nested=True),
+                              kernel_calls=calls, kernel_bodies_traced=bodies)
+        return super().__exit__(*exc)
+
+
+def _kernel_samples(seq0, start):
+    """(calls, bodies traced): the kernels' set-up counter samples this
+    thread recorded after slot `seq0`, found in the ring where they are."""
+    ring, me = _ring, threading.get_ident()
+    n, upto = len(ring), next(_seq)
+    found = (ring[i % n] for i in range(seq0 + 1, upto)) \
+        if upto - seq0 <= n else iter(ring)
+    calls = bodies = 0
+    for s in found:
+        if (s is not None and s.kind == "memory" and s.thread_id == me
+                and s.start >= start):
+            calls += s.name.endswith("/call")
+            bodies += s.name.endswith("/body_traced")
+    return calls, bodies
+
+
+def _on_jax_duration(event, seconds, **_):
+    """THE `jax.monitoring` duration listener of the tree."""
+    global _unscoped
+    if not (_TRACE_FLAG.value or _force_count):
+        return
+    what = _JAX_EVENTS.get(event)
+    if what is None:
+        return
+    for s in reversed(getattr(_tls, "stack", ())):
+        if isinstance(s, compile_span):
+            s._jax.add(what, seconds)
+            return
+    with _unscoped_lock:
+        if _unscoped is None:
+            _unscoped = _JaxSeconds()
+        _unscoped.add(what, seconds)
+
+
+def _unscoped_span(rec):
+    """The `jax/unscoped` record as a span: what JAX traced, lowered,
+    compiled or loaded with NO compile_span open on the thread (a weight
+    builder's jitted generator, an eager `jnp` op). SUMS over whatever
+    threads compiled between its first event and its last, and no interval
+    the process spent compiling: nobody stood around these events."""
+    return Span("compile", "jax/unscoped", rec.first, rec.last,
+                threading.get_ident(), "", 0,
+                dict(rec.attrs(nested=False), program="unscoped"), -1)
+
+
+def _close_unscoped():
+    global _unscoped
+    with _unscoped_lock:
+        rec, _unscoped = _unscoped, None
+    if rec is not None:
+        s = _unscoped_span(rec)
+        s.seq, s.id = next(_seq), next(_ids)
+        _record(s)
+
+
+def _keep(s: Span):
+    global _kept_dropped
+    if len(_kept) == _KEPT_CAP:
+        _kept_dropped += 1
+    _kept.append(s)
+
+
+def compile_spans() -> List[Span]:
+    """Every span of kind `compile` this process recorded, oldest first, the
+    newest 4,096 of them (`compile_spans_dropped()` counts the rest): kept
+    BESIDE the ring, so they outlive its wrapping and a `trace_ring` resize.
+    `clear()` empties the list; with tracing off nothing is kept. The open
+    `jax/unscoped` record, where it holds anything, comes last, as it
+    stands."""
+    out = list(_kept)
+    rec = _unscoped
+    if rec is not None:
+        out.append(_unscoped_span(rec))
+    return out
+
+
+def compile_spans_dropped() -> int:
+    return _kept_dropped
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
+
+
 def clear():
-    """Drop every recorded span (test isolation; profiler.reset)."""
-    global _ring, _seq
+    """Drop every recorded span, the kept `compile` spans and the open
+    `jax/unscoped` record with them (test isolation; profiler.reset)."""
+    global _ring, _seq, _unscoped, _kept_dropped
     with _resize_lock:
         _ring = [None] * max(_ring_cap, 1)
         _seq = itertools.count()
+    with _unscoped_lock:
+        _unscoped = None
+        _kept.clear()
+        _kept_dropped = 0
 
 
 def spans(since: Optional[int] = None) -> List[Span]:

@@ -198,7 +198,8 @@ class ParallelExecutor(Executor):
                 (fetch_shard, state_out_shard))
 
     def _compile(self, program: Program, scope: Scope, feed_names, fetch_names,
-                 in_shardings=None, out_shardings=None, analysis=None):
+                 in_shardings=None, out_shardings=None, analysis=None,
+                 name=None):
         program = self._prepare_program(program, scope)
         analysis = analysis or self._analyze_state(program, scope, feed_names,
                                                    fetch_names)
@@ -209,7 +210,8 @@ class ParallelExecutor(Executor):
                                              state_out_names)
         return super()._compile(
             program, scope, feed_names, fetch_names,
-            in_shardings=in_sh, out_shardings=out_sh, analysis=analysis)
+            in_shardings=in_sh, out_shardings=out_sh, analysis=analysis,
+            name=name)
 
     # -- explicit gradient-comm pipeline (parallel/grad_comm.py) ----------
     def _gate_manual_mode(self, program: Program, what: str):

@@ -561,7 +561,7 @@ class ContinuousBatchingEngine:
         # (PreparedStep.bind)
         self._step = self._exe.prepare(
             self._program, dict(self._feeds), self._tick_fetches(),
-            self.scope).bind(self._feeds)
+            self.scope, name="decode_tick").bind(self._feeds)
         self._tok = self._feeds["tick_tok"]
         self._pos = self._feeds["tick_pos"]
         self._from_last = self._feeds["tick_from_last"]

@@ -1306,7 +1306,8 @@ class PagedKVEngine(ContinuousBatchingEngine):
                                         share=self._feeds)
         self._mixed_step = self._exe.prepare(
             self._mixed_program, dict(self._mixed_feeds),
-            self._mixed_fetches(), self.scope).bind(self._mixed_feeds)
+            self._mixed_fetches(), self.scope,
+            name="mixed_tick").bind(self._mixed_feeds)
         # ONE host buffer for both ticks: the decode tick's feeds lead the
         # mixed tick's, so the decode step moves onto that leading span
         # (and its views): a fill writes them once, the decode tick

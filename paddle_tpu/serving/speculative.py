@@ -244,11 +244,11 @@ class SpeculativeDecoder:
         self._draft_step = eng._exe.prepare(
             self._draft_program, dict(self._draft_feeds),
             [self._draft_ids, self._draft_logp],
-            eng.scope).bind(self._draft_feeds)
+            eng.scope, name="draft_tick").bind(self._draft_feeds)
         self._verify_step = eng._exe.prepare(
             self._verify_program, dict(self._verify_feeds),
             [self._verify_ids, self._verify_logp],
-            eng.scope).bind(self._verify_feeds)
+            eng.scope, name="verify_tick").bind(self._verify_feeds)
         eng._bound_steps.update(draft=self._draft_step,
                                 verify=self._verify_step)
         self._rng = np.random.RandomState(self.cfg.seed)

@@ -16,6 +16,7 @@
 import json
 import os
 import sys
+import time
 
 import numpy as np
 import pytest
@@ -132,7 +133,7 @@ class TestTracing:
         exe.run(feed={"x": rng.rand(2, 4).astype("float32")},
                 fetch_list=[y])
         kinds = {(s.kind, s.name) for s in tracing.spans_since(m)}
-        assert ("compile", "executor/trace_and_compile") in kinds
+        assert ("compile", "executor/build_step") in kinds
         assert ("step", "executor/run") in kinds
         assert ("feed_fetch", "executor/feed") in kinds
         assert ("feed_fetch", "executor/state_writeback") in kinds
@@ -585,7 +586,7 @@ class TestHostPhaseSpans:
         spans = tracing.spans_since(m)
         lookup = self._one(spans, "executor/lookup")
         assert self._one(spans,
-                         "executor/trace_and_compile").parent_id == lookup.id
+                         "executor/build_step").parent_id == lookup.id
         # no numpy asked for: no fetch span, nothing waited for
         assert not [s for s in spans if s.name == "executor/fetch"]
 
@@ -932,7 +933,9 @@ class TestHostPhaseSpans:
                     if s.kind != "memory"
                     and not s.name.startswith("request/")]
             assert closed == live
-            assert set(closed) - {"caller", "executor/trace_and_compile"} \
+            # (a step built and a first launch are once-only `compile` spans)
+            assert set(closed) - {"caller", "executor/build_step",
+                                  "executor/compile_or_load"} \
                 <= set(names) | {"engine/pre_tick"}
             # a tick read late is held and leaves its wait and its copy
             # back to the next step
@@ -1093,6 +1096,372 @@ class TestHostPhaseSpans:
         # interpolated inside a bucket 10% wide, not one of 25 ms
         assert abs(h.quantile(0.5) - 0.0337) < 0.002
         assert abs(h.quantile(0.99) - 0.0337) < 0.002
+
+
+# ---------------------------------------------------------------------------
+# set-up: a program's first run, JAX's own seconds, the kept `compile` spans
+# ---------------------------------------------------------------------------
+
+JAX_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+JAX_LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+JAX_BACKEND = "/jax/core/compile/backend_compile_duration"
+JAX_LOAD = "/jax/compilation_cache/cache_retrieval_time_sec"
+
+
+def _tell(event, seconds):
+    """What JAX does when it has timed a phase of a compile."""
+    import jax.monitoring
+    jax.monitoring.record_event_duration_secs(event, seconds)
+
+
+def _first_runs(span_list=None):
+    return [s for s in (tracing.compile_spans() if span_list is None
+                        else span_list)
+            if s.name == "executor/compile_or_load"]
+
+
+def _tiny_train():
+    x = layers.data(name="x", shape=[4], dtype="float32")
+    loss = layers.mean(layers.fc(x, size=2))
+    pt.optimizer.SGDOptimizer(0.1).minimize(loss)
+    return loss, {"x": np.ones((2, 4), "float32")}
+
+
+class TestCompileSpans:
+    """ISSUE 57: jax.jit is lazy, so a program's trace, lowering and compile
+    (or cache load) are inside its first run: that run is a `compile` span
+    with JAX's own seconds on it, and `compile` spans are kept beside the
+    ring."""
+
+    def test_first_run_is_one_span_a_program_and_a_second_run_is_none(self):
+        loss, feed = _tiny_train()
+        exe = pt.Executor()
+        exe.run(pt.default_startup_program())
+        exe.run(feed=feed, fetch_list=[loss])
+        first = _first_runs()
+        assert [s.attrs["program"] for s in first] == ["startup",
+                                                       "train_step"]
+        for s in first:
+            assert s.kind == "compile" and s.attrs["executables"] == 1
+            assert s.attrs["trace_s"] > 0 and s.attrs["lower_s"] > 0
+            # the CPU tier keeps no persistent cache: all of it compiled
+            assert s.attrs["compile_s"] > 0 and s.attrs["cache_hit"] == 0
+            assert s.attrs["cache_load_s"] == 0
+            assert (s.attrs["kernel_calls"],
+                    s.attrs["kernel_bodies_traced"]) == (0, 0)
+            parts = sum(s.attrs[k] for k in ("trace_s", "lower_s",
+                                             "compile_s", "cache_load_s"))
+            assert parts <= s.end - s.start
+        step = first[1]
+        run = [s for s in tracing.spans() if s.name == "executor/run"][-1]
+        assert step.parent_id == run.id and step.parent == "executor/run"
+        m = tracing.mark()
+        exe.run(feed=feed, fetch_list=[loss])
+        assert len(_first_runs()) == 2
+        assert not [s for s in tracing.spans_since(m) if s.kind == "compile"]
+        # another batch size is another jitted signature of the SAME
+        # function: it recompiles under no first-run span, and JAX's
+        # seconds of it are on record all the same
+        exe.run(feed={"x": np.ones((3, 4), "float32")}, fetch_list=[loss])
+        assert [s.attrs["program"] for s in _first_runs()] == [
+            "startup", "train_step", "train_step"]
+
+    @pytest.mark.parametrize("program,fetches,want", [
+        ("startup", False, "startup"), ("train", True, "train_step"),
+        ("infer", True, "infer_step"), ("named", True, "decode_tick")])
+    def test_a_program_is_named_by_what_it_is(self, program, fetches, want):
+        x = layers.data(name="x", shape=[4], dtype="float32")
+        y = layers.mean(layers.fc(x, size=2))
+        feed = {"x": np.ones((2, 4), "float32")}
+        exe = pt.Executor()
+        if program == "train":
+            pt.optimizer.SGDOptimizer(0.1).minimize(y)
+        exe.run(pt.default_startup_program())
+        if program == "named":
+            exe.prepare(feed=feed, fetch_list=[y], name=want).run(feed)
+        elif program != "startup":
+            exe.run(feed=feed, fetch_list=[y])
+        assert _first_runs()[-1].attrs["program"] == want
+
+    @pytest.mark.parametrize("path", ["run", "run_bound"])
+    def test_prepared_step_s_first_launch_on_both_paths(self, path):
+        loss, feed = _tiny_train()
+        exe = pt.Executor()
+        exe.run(pt.default_startup_program())
+        tracing.clear()
+        step = exe.prepare(feed=dict(feed), fetch_list=[loss], name="tick")
+        assert not _first_runs()        # prepared, and nothing compiled yet
+        if path == "run":
+            launch = lambda: step.run(feed)
+        else:
+            step.bind(dict(feed))
+            launch = step.run_bound
+        launch()
+        (first,) = _first_runs()
+        assert first.attrs["program"] == "tick"
+        assert first.attrs["executables"] == 1 and first.attrs["lower_s"] > 0
+        launch()
+        launch()
+        assert _first_runs() == [first]
+        # a second handle on the same layout launches what already ran;
+        # Executor.run's own function of the step has not run yet
+        exe.prepare(feed=dict(feed), fetch_list=[loss]).run(feed)
+        assert _first_runs() == [first]
+        exe.run(feed=feed, fetch_list=[loss])
+        assert [s.attrs["program"] for s in _first_runs()] == ["tick",
+                                                               "tick"]
+
+    def test_run_steps_and_the_mesh_executor_open_it_too(self):
+        from paddle_tpu.parallel import DeviceMesh, ParallelExecutor
+        import jax
+        loss, feed = _tiny_train()
+        pt.Executor().run(pt.default_startup_program())
+        tracing.clear()
+        pt.Executor().run_steps([feed, feed], fetch_list=[loss])
+        (first,) = _first_runs()
+        assert first.attrs["program"] == "run_steps"
+        assert first.parent == "executor/run_steps"
+        pexe = ParallelExecutor(loss_name=loss.name,
+                                mesh=DeviceMesh(jax.devices()[:2],
+                                                {"dp": 2}))
+        pexe.run(fetch_list=[loss], feed=feed)
+        pexe.run(fetch_list=[loss], feed=feed)
+        assert [s.attrs["program"] for s in _first_runs()] == [
+            "run_steps", "train_step"]
+
+    def test_the_engines_name_their_tick_programs(self):
+        from paddle_tpu.framework.scope import Scope
+        from paddle_tpu.serving import PagedKVEngine
+        eng = PagedKVEngine(n_slots=2, max_len=24, block_size=4,
+                            scope=Scope(), vocab=50, d_model=16, d_inner=32,
+                            num_heads=2, num_layers=1)
+        tracing.clear()
+        eng.submit([3, 4, 5], max_new=3)
+        eng.run_until_idle()
+        assert {s.attrs["program"] for s in _first_runs()} == {
+            "decode_tick", "mixed_tick"}
+        for s in _first_runs():
+            assert s.parent == "engine/launch"
+
+    def test_events_land_in_the_innermost_span_of_their_thread(self):
+        import threading
+        tracing.clear()
+        other = {}
+
+        def elsewhere():
+            _tell(JAX_TRACE, 0.5)       # no span open on THIS thread
+            with tracing.compile_span("executor/compile_or_load", "b") as sp:
+                _tell(JAX_LOWER, 0.25)
+            other["span"] = sp
+
+        with tracing.compile_span("executor/compile_or_load", "outer") as o:
+            _tell(JAX_TRACE, 2.0)
+            with tracing.span("step", "between"):
+                with tracing.compile_span("executor/compile_or_load",
+                                          "inner") as i:
+                    _tell(JAX_TRACE, 1.0)
+                    _tell(JAX_LOWER, 0.125)
+                _tell(JAX_LOWER, 4.0)       # a plain span takes nothing
+            th = threading.Thread(target=elsewhere)
+            th.start()
+            th.join()
+            _tell("/jax/some/other_duration", 9.0)      # not one of the four
+        assert (i.attrs["trace_s"], i.attrs["lower_s"]) == (1.0, 0.125)
+        assert (o.attrs["trace_s"], o.attrs["lower_s"]) == (2.0, 4.0)
+        assert other["span"].attrs["lower_s"] == 0.25
+        assert other["span"].attrs["trace_s"] == 0.0
+        (unscoped,) = [s for s in tracing.compile_spans()
+                       if s.name == "jax/unscoped"]
+        assert unscoped.attrs["program"] == "unscoped"
+        assert (unscoped.attrs["trace_s"], unscoped.attrs["jits"]) == (0.5, 1)
+        assert unscoped.attrs["lower_s"] == 0.0
+
+    def test_mark_closes_the_unscoped_record_into_a_span(self):
+        tracing.clear()
+        _tell(JAX_TRACE, 0.5)
+        _tell(JAX_TRACE, 0.25)
+        _tell(JAX_BACKEND, 1.0)
+        # open: on the list as it stands, and not in the ring
+        (open_,) = tracing.compile_spans()
+        assert open_.name == "jax/unscoped" and not tracing.spans()
+        m = tracing.mark()
+        (closed,) = tracing.compile_spans()
+        assert tracing.spans() == [closed] and closed.seq < m
+        # outside a first run every trace counts whole: sums, no nesting
+        assert closed.attrs == {
+            "program": "unscoped", "trace_s": 0.75, "nested_trace_s": 0.0,
+            "jits": 2, "lower_s": 0.0, "compile_s": 1.0, "cache_load_s": 0.0,
+            "executables": 1, "cache_hit": 0}
+        assert closed.start <= closed.end <= time.perf_counter()
+        # what compiles after the mark is another record
+        _tell(JAX_LOWER, 2.0)
+        tracing.mark()
+        before, after = tracing.compile_spans()
+        assert before is closed and after.attrs["lower_s"] == 2.0
+        assert after.start >= closed.end - 2.0 and after.seq > m
+        tracing.mark()      # nothing heard since: nothing recorded
+        assert len(tracing.compile_spans()) == 2
+
+    def test_compile_s_is_the_backend_event_less_the_retrieval_in_it(self):
+        with tracing.compile_span("executor/compile_or_load", "hit") as hit:
+            _tell(JAX_TRACE, 3.0)
+            _tell(JAX_TRACE, 0.5)
+            _tell(JAX_TRACE, 0.25)
+            _tell(JAX_LOAD, 2.0)        # reported INSIDE the backend event
+            _tell(JAX_BACKEND, 2.125)
+        # traces nest: the longest is the outermost, the others are inside
+        assert (hit.attrs["trace_s"], hit.attrs["nested_trace_s"],
+                hit.attrs["jits"]) == (3.0, 0.75, 2)
+        assert (hit.attrs["compile_s"], hit.attrs["cache_load_s"],
+                hit.attrs["executables"], hit.attrs["cache_hit"]) == (
+                    0.125, 2.0, 1, 1)
+        with tracing.compile_span("executor/compile_or_load", "half") as half:
+            _tell(JAX_LOAD, 1.0)
+            _tell(JAX_BACKEND, 1.5)
+            _tell(JAX_BACKEND, 4.0)     # this one compiled
+        assert (half.attrs["compile_s"], half.attrs["cache_load_s"],
+                half.attrs["executables"], half.attrs["cache_hit"]) == (
+                    4.5, 1.0, 2, 0)
+        with tracing.compile_span("executor/compile_or_load", "none") as none:
+            pass
+        assert (none.attrs["executables"], none.attrs["cache_hit"],
+                none.attrs["jits"], none.attrs["compile_s"]) == (0, 0, 0, 0.0)
+
+    def test_kernel_counters_are_read_where_they_are(self):
+        import threading
+        tracing.record_counter("flash/call", 1, scope="before")
+        with tracing.compile_span("executor/compile_or_load", "step") as sp:
+            for _ in range(3):
+                tracing.record_counter("flash/call", 1, scope="fwd")
+            tracing.record_counter("flash/body_traced", 1, scope="fwd")
+            tracing.record_counter("moe_train/call", 1, scope="rows")
+            tracing.record_counter("ssm/body_traced", 1, scope="ssd_chunk")
+            tracing.record_counter("device_state_bytes", 7.0)   # no kernel's
+            th = threading.Thread(target=tracing.record_counter,
+                                  args=("flash/call", 1))
+            th.start()
+            th.join()
+        assert (sp.attrs["kernel_calls"],
+                sp.attrs["kernel_bodies_traced"]) == (4, 2)
+
+    def test_kept_spans_outlive_a_wrapped_and_a_resized_ring(self):
+        old = flags.get_flag("trace_ring")
+        flags.set_flag("trace_ring", 8)
+        tracing.clear()
+        try:
+            with tracing.compile_span("executor/compile_or_load", "early"):
+                _tell(JAX_LOWER, 0.5)
+            tracing.record_span("compile", "paddle_tpu/import", 1.0, 2.0)
+            for i in range(40):
+                with tracing.span("user", f"s{i}"):
+                    pass
+            assert not [s for s in tracing.spans() if s.kind == "compile"]
+            kept = tracing.compile_spans()
+            assert [s.name for s in kept] == ["executor/compile_or_load",
+                                              "paddle_tpu/import"]
+            assert kept[0].attrs["lower_s"] == 0.5
+            # the serving loop's resize REPLACES the ring
+            flags.set_flag("trace_ring", 64)
+            with tracing.span("user", "after"):
+                pass
+            assert [s.name for s in tracing.spans()] == ["after"]
+            assert tracing.compile_spans() == kept
+            assert tracing.compile_spans_dropped() == 0
+        finally:
+            flags.set_flag("trace_ring", old)
+            tracing.clear()
+
+    def test_the_list_keeps_the_newest_and_counts_the_rest(self, monkeypatch):
+        import collections
+        monkeypatch.setattr(tracing, "_KEPT_CAP", 3)
+        monkeypatch.setattr(tracing, "_kept", collections.deque(maxlen=3))
+        for i in range(5):
+            with tracing.span("compile", f"c{i}"):
+                pass
+        assert [s.name for s in tracing.compile_spans()] == ["c2", "c3", "c4"]
+        assert tracing.compile_spans_dropped() == 2
+        tracing.clear()
+        assert (tracing.compile_spans(),
+                tracing.compile_spans_dropped()) == ([], 0)
+
+    def test_clear_empties_the_list_and_the_open_record(self):
+        with tracing.compile_span("executor/compile_or_load", "p"):
+            pass
+        _tell(JAX_TRACE, 1.0)
+        assert len(tracing.compile_spans()) == 2
+        tracing.clear()
+        assert tracing.compile_spans() == []
+        tracing.mark()
+        assert tracing.compile_spans() == [] and tracing.spans() == []
+
+    def test_with_tracing_off_nothing_is_recorded_or_kept(self):
+        loss, feed = _tiny_train()
+        flags.set_flag("trace", False)
+        try:
+            exe = pt.Executor()
+            exe.run(pt.default_startup_program())
+            exe.run(feed=feed, fetch_list=[loss])
+            _tell(JAX_TRACE, 1.0)
+            with tracing.compile_span("executor/compile_or_load", "p") as sp:
+                _tell(JAX_LOWER, 1.0)
+            tracing.mark()
+            assert sp.attrs == {"program": "p"}
+            assert tracing.compile_spans() == [] and tracing.spans() == []
+            assert tracing._unscoped is None
+        finally:
+            flags.set_flag("trace", True)
+        # the programs that ran unrecorded have run: nothing to tell now
+        exe.run(feed=feed, fetch_list=[loss])
+        assert not _first_runs()
+
+    def test_a_first_run_that_raises_is_still_a_first_run_next_time(self):
+        loss, feed = _tiny_train()
+        exe = pt.Executor()
+        exe.run(pt.default_startup_program())
+        compiled = exe._lookup_or_compile(pt.default_main_program(),
+                                          dict(feed), [loss.name],
+                                          pt.global_scope())
+        fn = compiled.fn
+        with pytest.raises(TypeError):
+            with compiled.first_run(fn):
+                fn()                            # not the step's arguments
+        assert fn in compiled.unrun
+        exe.run(feed=feed, fetch_list=[loss])
+        assert not compiled.unrun
+        assert [s.attrs["program"] for s in _first_runs()] == [
+            "startup", "train_step", "train_step"]
+
+    def test_one_listener_in_the_tree_and_the_import_is_a_span(self):
+        """In a process of its own: what `import paddle_tpu` registers and
+        records, before any test's `clear()`."""
+        import subprocess
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        code = (
+            "import time; t0 = time.perf_counter()\n"
+            "import jax._src.monitoring as m, paddle_tpu\n"
+            "from paddle_tpu.observability import tracing\n"
+            "(s,) = tracing.compile_spans()\n"
+            "assert s.name == 'paddle_tpu/import' and s.kind == 'compile'\n"
+            "assert t0 <= s.start < s.end <= time.perf_counter()\n"
+            "assert s.end - s.start > 0.05, s.end - s.start\n"
+            "print(len(m.get_event_duration_listeners()))\n")
+        out = subprocess.run(
+            [sys.executable, "-c", code], cwd=root, capture_output=True,
+            text=True, env=dict(os.environ, JAX_PLATFORMS="cpu"))
+        assert out.returncode == 0, out.stderr[-2000:]
+        assert out.stdout.split() == ["1"]
+        # and in the source: one registration outside benchmark/ and tests/
+        found = []
+        for top in ("paddle_tpu", "tools", "examples", "chip_smoke.py"):
+            path = os.path.join(root, top)
+            files = ([path] if path.endswith(".py") else
+                     [os.path.join(d, f) for d, _, fs in os.walk(path)
+                      for f in fs if f.endswith(".py")])
+            for f in files:
+                with open(f) as fh:
+                    if "register_event_duration_secs_listener" in fh.read():
+                        found.append(os.path.relpath(f, root))
+        assert found == ["paddle_tpu/observability/tracing.py"]
 
 
 # ---------------------------------------------------------------------------

@@ -5,11 +5,13 @@
 
 The first form builds the cell's program as `benchmark/loops/train.py` does,
 runs the startup program and ONE step, and prints one JSON line: the loop's
-own `build`, `init` and `compile_or_load`, and inside the last the seconds JAX
-reports for the step's trace (`jaxpr_trace`), its lowering to a module
-(`jaxpr_to_mlir`), the backend's compile (`backend_compile`: 0 in a run that
-loads the executable from the compile cache) and the cache's retrieval, with
-the `flash/call` and `flash/body_traced` counters of that trace.
+own `build`, `init` and `compile_or_load`, and `spans`, every `compile` span
+the program kept (`tracing.compile_spans()`, docs/observability.md): inside
+`compile_or_load` lies the `executor/compile_or_load` span whose `program` is
+`train_step`, with JAX's seconds of the step's trace, its lowering, the
+backend's compile (~0 in a run that loads the executable from the compile
+cache) and the cache's retrieval, and the kernels' calls and traced bodies;
+`by_kind` has the sums over the process.
 `--attention xla` pins every `fused_attention` op to the composite, as
 `chip_smoke.py` does: the difference between the two runs is the flash calls'
 share. The second form times `jax.jit(jax.grad(...)).trace()` and `.lower()`
@@ -19,7 +21,6 @@ A run that finds the cache cold fills it: run twice and read the second.
 Run from the root of the tree to be probed (PR 47, Step 0)."""
 
 import argparse
-import collections
 import json
 import os
 import sys
@@ -28,38 +29,14 @@ import time
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
-EVENTS = {"/jax/core/compile/jaxpr_trace_duration": "jaxpr_trace",
-          "/jax/core/compile/jaxpr_to_mlir_module_duration": "jaxpr_to_mlir",
-          "/jax/core/compile/backend_compile_duration": "backend_compile",
-          "/jax/compilation_cache/cache_retrieval_time_sec": "cache_retrieval",
-          "/jax/compilation_cache/compile_time_saved_sec": "cache_saved"}
+KINDS = ("trace_s", "lower_s", "compile_s", "cache_load_s", "executables")
 
 
-class Durations:
-    def __init__(self):
-        import jax.monitoring
-        self.total = collections.defaultdict(float)
-        self.count = collections.Counter()
-        self.longest = collections.defaultdict(float)
-        jax.monitoring.register_event_duration_secs_listener(self._on)
-
-    def _on(self, event, duration, **kw):
-        name = EVENTS.get(event)
-        if name:
-            self.total[name] += duration
-            self.count[name] += 1
-            self.longest[name] = max(self.longest[name], duration)
-
-    def since(self, before):
-        return {k: round(v - before.get(k, 0.0), 3)
-                for k, v in self.total.items()}
-
-
-def flash_counters(tracing, mark):
-    names = collections.Counter(
-        s.name for s in tracing.spans_since(mark)
-        if s.name.startswith("flash/"))
-    return dict(names)
+def told(span):
+    """A kept `compile` span as the line shows it."""
+    return {"name": span.name, "s": round(span.end - span.start, 3),
+            **{k: round(v, 3) if isinstance(v, float) else v
+               for k, v in span.attrs.items()}}
 
 
 def probe_cell(args):
@@ -69,7 +46,6 @@ def probe_cell(args):
     from benchmark import harness, traffic
     from paddle_tpu.observability import tracing
 
-    durations = Durations()
     cell = harness.Cell(args.workload)
     parts = {"import": time.perf_counter() - t0}
     t = time.perf_counter()
@@ -109,25 +85,20 @@ def probe_cell(args):
     jax.block_until_ready(pt.global_scope().get(adapter.param_names(cfg)[0]))
     parts["init"] = time.perf_counter() - t
 
-    before, mark = dict(durations.total), tracing.mark()
     t = time.perf_counter()
     first = float(step(batches[0]["feed"]))
     parts["compile_or_load"] = time.perf_counter() - t
     t = time.perf_counter()
     second = float(step(batches[1 % len(batches)]["feed"]))
     parts["second_step"] = time.perf_counter() - t
+    kept = tracing.compile_spans()
     print(json.dumps({
         "workload": args.workload, "attention": args.attention or "default",
         "fused_attention_ops": n_attention,
         "parts": {k: round(v, 3) for k, v in parts.items()},
-        "in_compile_or_load": durations.since(before),
-        "whole_process": durations.since({}),
-        # traces nest (a jitted callable inside the step is an event of its
-        # own inside the step's): the longest is the step's whole trace
-        "longest_event": {k: round(v, 3)
-                          for k, v in durations.longest.items()},
-        "events": dict(durations.count),
-        "flash": flash_counters(tracing, mark),
+        "by_kind": {k: round(sum(s.attrs.get(k, 0) for s in kept), 3)
+                    for k in KINDS},
+        "spans": [told(s) for s in kept],
         "loss": [first, second],
         "device": f"{device['platform']} {device['kind']} x{device['count']}",
     }), flush=True)

@@ -425,13 +425,16 @@ class _JaxSeconds:
         is the outermost, the others an upper bound of what nested in it.
         Outside one every trace is counted whole. JAX's backend event holds
         the cache's retrieval when there is one: `compile_s` is it LESS the
-        retrieval, ~0 where every executable came from the cache."""
+        retrieval, ~0 where every executable came from the cache;
+        `cache_loads` counts the retrievals, `executables` less it the
+        executables XLA compiled."""
         outer = self.longest if nested else self.trace
         inner = max(self.traces - 1, 0) if nested else self.traces
         return {"trace_s": outer, "nested_trace_s": self.trace - outer,
                 "jits": inner, "lower_s": self.lower,
                 "compile_s": max(self.backend - self.load, 0.0),
                 "cache_load_s": self.load, "executables": self.executables,
+                "cache_loads": self.loads,
                 "cache_hit": int(0 < self.executables <= self.loads)}
 
 
@@ -441,7 +444,8 @@ class compile_span(span):
     While it is open JAX's duration events on THIS thread add into it (the
     innermost one, where they nest), and at exit it carries `program`,
     `trace_s`, `nested_trace_s`, `jits`, `lower_s`, `compile_s`,
-    `cache_load_s`, `executables`, `cache_hit` (`_JaxSeconds.attrs`) and
+    `cache_load_s`, `executables`, `cache_loads`, `cache_hit`
+    (`_JaxSeconds.attrs`) and
     `kernel_calls` / `kernel_bodies_traced`: the `*/call` and `*/body_traced`
     counter samples this thread recorded meanwhile, read in the ring."""
 

@@ -6,6 +6,7 @@ that its checks hold at a small size — which is also how the script is
 debugged before any chip time is spent.
 """
 
+import json
 import os
 import subprocess
 import sys
@@ -208,24 +209,67 @@ def test_import_initializes_no_backend(tmp_path):
 
 def test_compile_cache_placed_from_outside(tmp_path):
     code = ("import jax, paddle_tpu\n"
-            "print(jax.config.jax_compilation_cache_dir)\n")
+            "print(jax.config.jax_compilation_cache_dir,\n"
+            "      jax.config.jax_persistent_cache_min_compile_time_secs)\n")
     a, b = tmp_path / "a", tmp_path / "b"
     a.mkdir()
     b.mkdir()
     given = str(tmp_path / "given_cache")
     # no backend is initialized by these imports, so leaving the platform
     # unpinned is safe on a machine without a chip
-    procs = [
-        _spawn(code, a, JAX_COMPILATION_CACHE_DIR=None, JAX_PLATFORMS=None),
-        _spawn(code, b, JAX_COMPILATION_CACHE_DIR=None, JAX_PLATFORMS=None),
-        _spawn(code, a, JAX_COMPILATION_CACHE_DIR=given, JAX_PLATFORMS=None),
-        _spawn(code, a, JAX_COMPILATION_CACHE_DIR=None, JAX_PLATFORMS="cpu"),
-    ]
-    from_a, from_b, from_env, cpu_pinned = [_finish(p) for p in procs]
-    # unset: the fixed in-checkout path, whatever the working directory
-    assert from_a == from_b == os.path.join(REPO, ".jax_cache")
-    # set from outside: JAX honours it and the package sets nothing
-    assert from_env == given
-    # the CPU-pinned test tier keeps no cache
-    assert cpu_pinned == "None"
+    def spawn(cwd, directory, platforms):
+        return _spawn(code, cwd, JAX_COMPILATION_CACHE_DIR=directory,
+                      JAX_PLATFORMS=platforms,
+                      JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS=None)
+
+    procs = [spawn(a, None, None), spawn(b, None, None),
+             spawn(a, given, None), spawn(a, None, "cpu")]
+    from_a, from_b, from_env, cpu_pinned = [_finish(p).split() for p in procs]
+    # unset: the fixed in-checkout path, whatever the working directory,
+    # and every executable is admitted to it, however fast it compiled
+    assert from_a == from_b == [os.path.join(REPO, ".jax_cache"), "0.0"]
+    # set from outside: JAX honours it and the package sets no directory;
+    # what is written there is the rule's all the same
+    assert from_env == [given, "0.0"]
+    # the CPU-pinned test tier keeps no cache, and nothing is set for it
+    assert cpu_pinned == ["None", "1.0"]
     assert not os.path.exists(given)    # configuring creates nothing
+
+
+_WARM_PROCESS = """
+import json
+import jax, jax.numpy as jnp
+import paddle_tpu
+from paddle_tpu.observability import tracing
+
+@jax.jit
+def small(x):
+    for i in range(18):     # sub-second to compile, and not next to nothing
+        x = jnp.tanh(x @ x.T @ x) * (1.0 + i) + jnp.cumsum(x, axis=i % 2)
+    return x.sum()
+
+with tracing.compile_span("executor/compile_or_load", "small") as sp:
+    x = jnp.arange(12.0).reshape(3, 4) * 0.5       # two eager jnp ops,
+    jax.block_until_ready(small(x))                # one jitted function
+print(json.dumps(sp.attrs))
+"""
+
+
+def test_a_warm_process_loads_what_the_first_one_compiled(tmp_path):
+    """ISSUE 58: the cache admits executables that compiled in under a second
+    (JAX's default refuses them), so a second process on the same directory
+    loads every one. A CPU-pinned process keeps a cache only where
+    JAX_COMPILATION_CACHE_DIR gives it one: this is that case. Stdout only:
+    XLA's CPU loader prints feature warnings to stderr on such loads."""
+    env = dict(JAX_PLATFORMS="cpu", JAX_COMPILATION_CACHE_DIR=str(tmp_path),
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS=None)
+    cold = json.loads(_finish(_spawn(_WARM_PROCESS, tmp_path, **env)))
+    assert cold["executables"] >= 3 and cold["cache_loads"] == 0
+    assert cold["cache_hit"] == 0 and cold["compile_s"] > 0
+    assert len(os.listdir(tmp_path)) >= cold["executables"]    # written
+    warm = json.loads(_finish(_spawn(_WARM_PROCESS, tmp_path, **env)))
+    assert warm["cache_loads"] == warm["executables"] == cold["executables"]
+    assert warm["cache_hit"] == 1 and warm["cache_load_s"] > 0
+    assert warm["compile_s"] < 0.1 * cold["compile_s"]
+    # tracing and lowering come before the cache is asked: the same programs
+    assert warm["jits"] == cold["jits"]

@@ -313,11 +313,27 @@ def test_get_places_lists_devices():
     assert get_places(device_count=2) == places[:2]
 
 
-def test_compile_cache_rule_in_process(monkeypatch):
+_THRESHOLD = "jax_persistent_cache_min_compile_time_secs"
+_THRESHOLD_ENV = "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"
+_BOUND = "jax_compilation_cache_max_size"
+
+
+@pytest.fixture
+def jax_cache_config():
+    """What `compile_cache.configure()` reads and sets, put back after."""
+    import jax
+    was = {k: getattr(jax.config, k) for k in (
+        "jax_platforms", "jax_compilation_cache_dir", _THRESHOLD, _BOUND)}
+    yield
+    for k, v in was.items():
+        jax.config.update(k, v)
+
+
+def test_compile_cache_rule_in_process(monkeypatch, jax_cache_config):
     """core/compile_cache.py replaced the PTPU_JIT_CACHE flag with one
-    rule: JAX_COMPILATION_CACHE_DIR set -> the package sets nothing; unset
-    -> the fixed in-checkout directory, except in a CPU-pinned process like
-    this one (tests/test_chip_smoke.py checks the TPU-side half from
+    rule: JAX_COMPILATION_CACHE_DIR set -> the package sets no directory;
+    unset -> the fixed in-checkout directory, except in a CPU-pinned process
+    like this one (tests/test_chip_smoke.py checks the TPU-side half from
     fresh interpreters)."""
     import os
     import jax
@@ -332,6 +348,46 @@ def test_compile_cache_rule_in_process(monkeypatch):
     monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
     compile_cache.configure()
     assert jax.config.jax_compilation_cache_dir == before
+
+
+@pytest.mark.parametrize("platforms, directory, named, directory_set, reads", [
+    # a directory in effect -> the cache admits every executable
+    ("", None, None, True, 0.0),             # unpinned: the in-checkout one
+    ("", "/given/cache", None, False, 0.0),  # given from outside, unpinned
+    ("cpu", "/given/cache", None, False, 0.0),   # ... and pinned to the CPU
+    ("tpu,cpu", None, None, True, 0.0),
+    # pinned to the CPU with no directory given: no cache, nothing set
+    ("cpu", None, None, False, 1.0),
+    (" CPU ", None, None, False, 1.0),
+    # a threshold named from outside is left as given, like the directory
+    ("", None, "2.5", True, 2.5),
+    ("cpu", "/given/cache", "0.5", False, 0.5),
+])
+def test_compile_cache_admits_every_executable(monkeypatch, jax_cache_config,
+                                               platforms, directory, named,
+                                               directory_set, reads):
+    """ISSUE 58: wherever a cache directory is in effect after the rule has
+    run, JAX's minimum compile time for an entry goes from 1 s to 0. A bound
+    on the directory's size is JAX's own and stays as it was named."""
+    import jax
+    from paddle_tpu.core import compile_cache
+
+    for env, value in (("JAX_COMPILATION_CACHE_DIR", directory),
+                       (_THRESHOLD_ENV, named)):
+        if value is None:
+            monkeypatch.delenv(env, raising=False)
+        else:
+            monkeypatch.setenv(env, value)
+    jax.config.update("jax_platforms", platforms)
+    jax.config.update("jax_compilation_cache_dir", None)
+    # what JAX itself read from the environment when it was imported
+    jax.config.update(_THRESHOLD, float(named) if named else 1.0)
+    jax.config.update(_BOUND, 201326592)
+    compile_cache.configure()
+    assert getattr(jax.config, _BOUND) == 201326592
+    assert jax.config.jax_compilation_cache_dir == (
+        compile_cache.CACHE_DIR if directory_set else None)
+    assert getattr(jax.config, _THRESHOLD) == reads
 
 
 class TestNanGuard:

@@ -1291,7 +1291,7 @@ class TestCompileSpans:
         assert closed.attrs == {
             "program": "unscoped", "trace_s": 0.75, "nested_trace_s": 0.0,
             "jits": 2, "lower_s": 0.0, "compile_s": 1.0, "cache_load_s": 0.0,
-            "executables": 1, "cache_hit": 0}
+            "executables": 1, "cache_loads": 0, "cache_hit": 0}
         assert closed.start <= closed.end <= time.perf_counter()
         # what compiles after the mark is another record
         _tell(JAX_LOWER, 2.0)
@@ -1326,6 +1326,51 @@ class TestCompileSpans:
             pass
         assert (none.attrs["executables"], none.attrs["cache_hit"],
                 none.attrs["jits"], none.attrs["compile_s"]) == (0, 0, 0, 0.0)
+
+    @pytest.mark.parametrize("executables, loads", [(3, 3), (5, 2), (4, 0),
+                                                    (0, 0)])
+    def test_cache_loads_counts_the_retrievals(self, executables, loads):
+        """ISSUE 58: how many of a span's executables came from the
+        persistent cache, beside the 0/1 `cache_hit`."""
+        with tracing.compile_span("executor/compile_or_load", "p") as sp:
+            for i in range(executables):
+                if i < loads:
+                    _tell(JAX_LOAD, 0.25)   # reported inside the backend event
+                _tell(JAX_BACKEND, 0.5)
+            _tell(JAX_LOWER, 1.0)           # no executable, no load
+        assert (sp.attrs["executables"], sp.attrs["cache_loads"]) == (
+            executables, loads)
+        assert sp.attrs["cache_hit"] == int(0 < executables == loads)
+        assert sp.attrs["cache_load_s"] == 0.25 * loads
+        assert sp.attrs["compile_s"] == 0.5 * executables - 0.25 * loads
+
+    def test_cache_loads_sums_over_threads_in_the_unscoped_record(self):
+        import threading
+        tracing.clear()
+
+        def builder(loads):
+            for _ in range(loads):
+                _tell(JAX_LOAD, 0.125)
+                _tell(JAX_BACKEND, 0.25)
+
+        threads = [threading.Thread(target=builder, args=(n,))
+                   for n in (2, 3)]
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join()
+        _tell(JAX_LOAD, 0.125)
+        _tell(JAX_BACKEND, 0.25)
+        (open_,) = tracing.compile_spans()
+        assert (open_.name, open_.attrs["executables"],
+                open_.attrs["cache_loads"], open_.attrs["cache_hit"]) == (
+                    "jax/unscoped", 6, 6, 1)
+        _tell(JAX_BACKEND, 2.0)             # one more, and it compiled
+        tracing.mark()
+        (closed,) = tracing.compile_spans()
+        assert (closed.attrs["executables"], closed.attrs["cache_loads"],
+                closed.attrs["cache_hit"]) == (7, 6, 0)
+        assert closed.attrs["compile_s"] == 6 * 0.25 + 2.0 - 6 * 0.125
 
     def test_kernel_counters_are_read_where_they_are(self):
         import threading

@@ -549,6 +549,139 @@ def phase_serve_ssm(vocab=32768, d_model=4096, num_heads=32, num_kv_heads=2,
             "tpu_custom_calls": n_calls, "mixed_tpu_custom_calls": n_mixed}
 
 
+def phase_serve_kda(vocab=39296, d_model=2560, d_inner=6144, num_heads=32,
+                    head_dim=128, kv_lora_rank=512, rope_dim=64, d_expert=768,
+                    n_routed=512, n_held=64, top_k=8, n_group=8, topk_group=4,
+                    kinds=("kda", "kda", "attention", "kda"), n_slots=16,
+                    block_size=64, n_blocks=128, n_snapshots=4, max_len=1024,
+                    preamble=256, turns=(40, 150), max_new=24,
+                    expect_lowering="kernel"):
+    """A model whose layers are channel-wise gated delta-rule (kda) mixers or
+    latent attention by kind, through the same PagedKVEngine: a float32
+    MATRIX state a head that a decode row updates in place (the kda kernel)
+    and a prefix hit restores from the snapshot POOL, beside ONE latent pool
+    with a gate a head and no query bottleneck, group-limited routed experts
+    of which one whole group is held, at the published widths of
+    benchmark/configs/ling3-flash-ep4.json and four layers. Requests that
+    start from a shared preamble (latent blocks from the prefix cache and the
+    state after them from the pool) must emit the tokens an engine without
+    prefix sharing emits, which prefills the preamble itself; with the pool's
+    entries swapped they must not, nor with the entries zeroed."""
+    from paddle_tpu.models.decoder_spec import (DecoderSpec, KdaSpec,
+                                                LatentSpec, MoESpec, RopeSpec)
+    from paddle_tpu.serving import PagedKVEngine
+    import jax.numpy as jnp
+    import paddle_tpu as pt
+
+    spec = DecoderSpec.kda_latent_moe(
+        vocab, d_model, d_inner, num_heads, kinds,
+        KdaSpec(heads=num_heads, head_dim=head_dim),
+        LatentSpec(q_lora_rank=None, kv_lora_rank=kv_lora_rank,
+                   qk_nope_head_dim=head_dim, v_head_dim=head_dim,
+                   rope=RopeSpec(dim=rope_dim, theta=6e6), gate="head"),
+        MoESpec(n_routed=n_routed, top_k=top_k, d_expert=d_expert,
+                held=tuple(range(n_held)), n_shared=1, first_dense=1,
+                scaling=2.5, topk_method="group_bias", norm_eps=1e-20,
+                n_group=n_group, topk_group=topk_group))
+    t0 = time.time()
+    scope = pt.Scope()
+    sizes = dict(max_len=max_len, block_size=block_size, scope=scope,
+                 model=spec)
+    # the startup program leaves A_log and dt_bias as it leaves a matrix; a
+    # state that remembers across a turn needs slow channels (rate 1, a bias
+    # of -4: a decay of exp(-5 sigmoid(f - 4)) ~ 0.9 a step). A bound step
+    # pins the weights it was built over, so they are made by an engine that
+    # never runs and set BEFORE the two that do are built
+    PagedKVEngine(n_slots=1, n_blocks=max_len // block_size + 1,
+                  n_snapshots=1, **sizes)
+    for name in list(scope.local_var_names()):
+        if name.endswith("_a_log"):
+            scope.set_var(name, jnp.zeros_like(scope.get(name)))
+        elif name.endswith("_dt_bias"):
+            scope.set_var(name, jnp.full_like(scope.get(name), -4.0))
+    engines = [PagedKVEngine(n_slots=n_slots, n_blocks=n_blocks,
+                             n_snapshots=n_snapshots, prefix_sharing=share,
+                             **sizes)
+               for share in (True, False)]
+    rng = np.random.RandomState(3)
+    head = rng.randint(0, vocab, (preamble,)).tolist()
+    other = rng.randint(0, vocab, (preamble,)).tolist()
+    prompts = [head + rng.randint(0, vocab, (n,)).tolist() for n in turns]
+    tokens = []
+    for eng in engines:
+        warm = [eng.submit(p, 2) for p in (head, other)]
+        eng.run_until_idle()        # both preambles' blocks and snapshots
+        rest = [eng.submit(p, max_new) for p in prompts]
+        eng.run_until_idle()
+        _check(all(r.done and r.error is None for r in warm + rest),
+               "a request of the kda engine did not finish")
+        tokens.append([r.tokens for r in rest])
+    run_s = time.time() - t0
+    shared, alone = engines
+    st = shared.stats()
+    _check(tokens[0] == tokens[1],
+           "a request that resumed from the prefix cache (latent blocks and "
+           "the delta-rule snapshot) emitted other tokens than its "
+           "self-prefilled twin")
+    pool = st["ssm_state"]
+    _check(pool["restores"] == len(turns)
+           and alone.stats()["ssm_state"]["restores"] == 0,
+           f"delta-rule restores {pool}: every prefix hit resumes from an "
+           f"entry of the snapshot pool")
+    n_calls = _n_custom_calls(shared.tick_hlo())
+    n_mixed = _n_custom_calls(shared.mixed_tick_hlo())
+    per = {k: list(kinds).count(k) for k in ("kda", "attention")}
+    n_moe = len(kinds) - 1
+    want = per["kda"] + per["attention"] + n_moe \
+        if expect_lowering == "kernel" else 0
+    _check((st["paged_attention_lowering"], n_calls, n_mixed)
+           == (expect_lowering, want,
+               want + (per["attention"] if want else 0)),
+           f"the kda engine reports its cache read as "
+           f"{st['paged_attention_lowering']!r}, its ticks hold {n_calls} "
+           f"and {n_mixed} tpu_custom_calls; expected {expect_lowering!r} "
+           f"with {want} (a state update a kda layer, a product a routed "
+           f"layer, a read a latent layer) and one more a lanes' read")
+
+    def snapshots(change):
+        for j in range(per["kda"]):
+            for part in ("h", "conv"):
+                name = f"{shared._cache_prefix}_kda_snap_{part}{j}"
+                scope.set_var(name, change(scope.get(name)))
+
+    def differs_at(what):
+        """A request two tokens behind `head` on both engines: where the hit
+        and its self-prefilled twin part ways."""
+        probe = head + rng.randint(0, vocab, (2,)).tolist()
+        pair = [eng.submit(probe, max_new) for eng in engines]
+        for eng in engines:
+            eng.run_until_idle()
+        _check(pair[0].shared_len == preamble and pair[1].shared_len == 0
+               and pair[0].tokens != pair[1].tokens,
+               f"a request that resumed from {what} emitted its "
+               "self-prefilled twin's tokens: the twins' comparison does not "
+               "see the state")
+        return next(i for i, (a, b) in enumerate(zip(*(r.tokens
+                                                       for r in pair)))
+                    if a != b)
+
+    # the comparison above has to refuse a restore that is broken: with the
+    # two preambles' snapshots swapped a request that starts two tokens after
+    # `head` reads the state after `other`; with the entries zeroed, nothing
+    snapshots(lambda s: s.at[0].set(s[1]).at[1].set(s[0]))
+    swapped = differs_at("ANOTHER prompt's snapshot")
+    snapshots(jnp.zeros_like)
+    zeroed = differs_at("a snapshot that holds zeros")
+    return {"compile_s": 0.0, "run_s": round(run_s, 2),
+            "tokens_out": sum(len(t) for t in tokens[0]),
+            "swapped_state_differs_at": swapped,
+            "zeroed_state_differs_at": zeroed,
+            "ssm_state": pool, "block_bytes": st["block_bytes"],
+            "experts_touched": int(np.count_nonzero(shared.expert_rows)),
+            "paged_attention_lowering": st["paged_attention_lowering"],
+            "tpu_custom_calls": n_calls, "mixed_tpu_custom_calls": n_mixed}
+
+
 def phase_serve_parallel(vocab=261120, d_model=5120, d_inner=21504,
                          num_heads=20, num_kv_heads=4, d_head=128,
                          ssm=(32, 128, 2, 256), num_layers=4, n_slots=4,
@@ -1459,6 +1592,8 @@ def _run():
     phase("serve_ssm", phase_serve_ssm)
     _free_device_memory()
     phase("serve_parallel", phase_serve_parallel)
+    _free_device_memory()
+    phase("serve_kda", phase_serve_kda)
     _free_device_memory()
     phase("window_read", phase_window_read)
     phase("kernels", phase_kernels)

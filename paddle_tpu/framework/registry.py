@@ -194,7 +194,7 @@ def _ensure_builtin_ops():
                        beam_search_ops, detection_ops, pallas_kernels)
     from ..fusion import (decode_attention, paged_attention,  # noqa: F401
                           recurrent, latent_attention, moe,
-                          short_conv, ssm)
+                          short_conv, ssm, kda)
 
 
 @dataclass

@@ -52,3 +52,4 @@ from .latent_attention import (latent_attention_lowering,  # noqa: F401
 from . import moe  # noqa: F401  (registers moe_route / moe_experts)
 from . import short_conv  # noqa: F401  (registers short_conv / conv_state_commit)
 from . import ssm  # noqa: F401  (registers ssm_scan / gated_rms_norm)
+from . import kda  # noqa: F401  (registers kda_scan / kda_gate_norm / head_gate)

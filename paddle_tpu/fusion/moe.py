@@ -5,7 +5,11 @@
 runs over every selected expert, held here or not. With a `bias` (one value
 an expert) the selection is the top-k of score + bias and the weights stay
 the UNBIASED scores of the selected; `norm_eps` joins the sum the weights
-are divided by. What leaves the op is the
+are divided by. With `groups` (n_group, topk_group) the experts stand in
+`n_group` groups of equal size, a group scores the sum of its 2 largest
+score + bias, and only the `topk_group` best groups' experts are eligible
+(`group_limited`: the family deploys whole groups a chip, so this step
+decides which chips a row visits). What leaves the op is the
 weights' HELD part, dense: `[n_held, N, 1]`, zero where a row did not select
 the expert (or the row is dead: an idle slot, the tail of a short chunk),
 and `rows[e]`, how many rows expert e got.
@@ -75,16 +79,38 @@ from .decode_attention import _auto_backend
 KERNEL, COMPOSITE = "kernel", "composite"
 
 
+def group_limited(keys, n_group, topk_group):
+    """keys [N, E] with the experts outside each row's `topk_group` best of
+    `n_group` groups at -inf: a group scores the sum of its 2 largest keys
+    (DeepSeek-V3's `noaux_tc`)."""
+    n = keys.shape[0]
+    grouped = keys.reshape(n, n_group, -1)
+    # the 2 largest of a group by two maxima (the first's ONE position
+    # masked): `top_k` over a group's members is a sort on a TPU
+    first = jnp.argmax(grouped, axis=-1, keepdims=True)
+    member = jax.lax.broadcasted_iota(jnp.int32, grouped.shape, 2)
+    best2 = jnp.max(grouped, axis=-1) + jnp.max(
+        jnp.where(member == first, -jnp.inf, grouped), axis=-1)
+    _, kept = jax.lax.top_k(best2, topk_group)                     # [N, g]
+    on = jnp.any(kept[:, :, None] == jnp.arange(n_group)[None, None, :],
+                 axis=1)                                          # [N, G]
+    return jnp.where(on[:, :, None], grouped, -jnp.inf).reshape(keys.shape)
+
+
 def route(x, w_router, held, top_k, scaling, norm_topk_prob=True, live=None,
-          bias=None, norm_eps=0.0):
-    """x [N, D], w_router [D, E], bias [E] or None -> (weights
-    [n_held, N, 1] float32, rows [n_held] int32)."""
+          bias=None, norm_eps=0.0, groups=None):
+    """x [N, D], w_router [D, E], bias [E] or None, groups (n_group,
+    topk_group) or None -> (weights [n_held, N, 1] float32, rows [n_held]
+    int32)."""
     logits = jnp.dot(x, w_router, preferred_element_type=jnp.float32)
     scores = jax.nn.sigmoid(logits.astype(jnp.float32))
-    if bias is None:
+    if bias is None and groups is None:
         top, idx = jax.lax.top_k(scores, top_k)               # [N, k]
     else:
-        _, idx = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+        keys = scores if bias is None else scores + bias.astype(jnp.float32)
+        if groups is not None:
+            keys = group_limited(keys, *groups)
+        _, idx = jax.lax.top_k(keys, top_k)
         top = jnp.take_along_axis(scores, idx, axis=-1)
     total = jnp.sum(top, axis=-1, keepdims=True)
     if norm_eps:
@@ -110,7 +136,9 @@ def _moe_route_op(ctx, ins, attrs):
                     attrs.get("norm_topk_prob", True),
                     ins["Live"][0] if ins.get("Live") else None,
                     ins["Bias"][0] if ins.get("Bias") else None,
-                    attrs.get("norm_eps", 0.0))
+                    attrs.get("norm_eps", 0.0),
+                    (attrs["n_group"], attrs["topk_group"])
+                    if "n_group" in attrs else None)
     return {"Weights": [w], "Rows": [rows]}
 
 

@@ -13,7 +13,7 @@ h // (H/G)); `dt = softplus(dt + dt_bias)`, `A = -exp(A_log)`, a value a head;
 then `RMSNorm_groups(y * silu(z)) W_out` (`gated_rms_norm`). Everything but
 the convolution and `h` is row-wise. `h` [H, P, N] in float32 (4 MB a layer
 at 128 x 64 x 128) and the conv rows are a request's STATE, whatever its
-length. Per state-space layer four persistable arrays hold it (`_SsmState`
+length. Per state-space layer four persistable arrays hold it (`_RecurrentState`
 declares them):
 
 - `slot_h` [n_slots, H, P, N] float32 and `slot_conv` [n_slots, K-1, CD]: the
@@ -265,6 +265,48 @@ def _put(arr, idx, new, on):
         arr, jnp.where(on, new.astype(arr.dtype), old), idx, 0)
 
 
+def _lane_ints(lanes):
+    """A mixed tick's lane feeds as flat int32 vectors: lpos, lrows, lslot,
+    snap_src, snap_dst, snap_rows."""
+    return tuple(t.reshape(-1).astype(jnp.int32) for t in lanes[2:8])
+
+
+def lanes_start(lanes, slot_h, slot_conv):
+    """The state each lane's chunk starts from, for any layer that keeps a
+    slot state and a snapshot pool (`ssm_scan`, `kda.kda_scan`): a snapshot
+    (`snap_src` >= 0), the slot's own (an earlier chunk of the request left
+    it), zeros at position 0 -> ((lrows, snap_rows), h_in, conv_in)."""
+    snap_h, snap_conv = lanes[:2]
+    lpos, lrows, lslot, src, _, snap_rows = _lane_ints(lanes)
+    from_snap, resumed = src >= 0, lpos > 0
+    pick = lambda snap, slot: jnp.stack([jnp.where(  # noqa: E731
+        from_snap[i], _take(snap, jnp.maximum(src[i], 0)),
+        jnp.where(resumed[i], _take(slot, lslot[i]), 0).astype(snap.dtype))
+        for i in range(lrows.shape[0])])
+    return ((lrows, snap_rows), pick(snap_h, slot_h),
+            pick(snap_conv, slot_conv))
+
+
+def lanes_commit(lanes, slot_h, slot_conv, ext, r, h_out, h_snap):
+    """What the lanes leave, in place: in each lane's slot the state after
+    its last real row (`h_out`, and the `r` rows of `ext`, the convolution's
+    input behind its state rows, that end there), in the pool entry
+    `snap_dst` (>= 0) the state after its first `snap_rows` rows (`h_snap`)
+    -> (slot_h, slot_conv, snap_h, snap_conv)."""
+    snap_h, snap_conv = lanes[:2]
+    _, lrows, lslot, _, dst, snap_rows = _lane_ints(lanes)
+    rows_at = lambda n: jax.vmap(  # noqa: E731
+        lambda e, k: jax.lax.dynamic_slice_in_dim(e, k, r, 0))(ext, n)
+    conv_out, conv_snap = rows_at(lrows), rows_at(snap_rows)
+    for i in range(lrows.shape[0]):
+        fed, at = lrows[i] > 0, jnp.maximum(dst[i], 0)
+        slot_h = _put(slot_h, lslot[i], h_out[i], fed)
+        slot_conv = _put(slot_conv, lslot[i], conv_out[i], fed)
+        snap_h = _put(snap_h, at, h_snap[i], dst[i] >= 0)
+        snap_conv = _put(snap_conv, at, conv_snap[i], dst[i] >= 0)
+    return slot_h, slot_conv, snap_h, snap_conv
+
+
 def ssm_scan(xbc, dt_raw, taps, conv_bias, a_log, dt_bias, d_skip, slot_h,
              slot_conv, live, spec, lanes=None, backend=None):
     """One state-space layer's convolution and scan over a tick's rows.
@@ -303,19 +345,9 @@ def ssm_scan(xbc, dt_raw, taps, conv_bias, a_log, dt_bias, d_skip, slot_h,
     slot_conv = jnp.where(alive[:, None, None], ext[:, 1:], slot_conv)
     if lanes is None:
         return y.astype(dtype), slot_h, slot_conv, None, None
-    snap_h, snap_conv, lpos, lrows, lslot, src, dst, snap_rows, chunk = lanes
-    ints = lambda t: t.reshape(-1).astype(jnp.int32)  # noqa: E731
-    lpos, lrows, lslot, src, dst, snap_rows = map(
-        ints, (lpos, lrows, lslot, src, dst, snap_rows))
+    chunk = lanes[-1]
+    (lrows, snap_rows), h_in, conv_in = lanes_start(lanes, slot_h, slot_conv)
     L = lrows.shape[0]
-    # the state each chunk starts from: a snapshot, the slot's own (an
-    # earlier chunk of the request left it), zeros at position 0
-    from_snap, resumed = src >= 0, lpos > 0
-    pick = lambda snap, slot: jnp.stack([jnp.where(  # noqa: E731
-        from_snap[i], _take(snap, jnp.maximum(src[i], 0)),
-        jnp.where(resumed[i], _take(slot, lslot[i]), 0).astype(snap.dtype))
-        for i in range(L)])
-    h_in, conv_in = pick(snap_h, slot_h), pick(snap_conv, slot_conv)
     ul = xbc[S:].reshape(L, chunk, -1)
     ext_l = jnp.concatenate([conv_in.astype(dtype), ul], axis=1)
     xl, bl, cl = split(conv_rows(ext_l, taps, chunk))
@@ -324,17 +356,9 @@ def ssm_scan(xbc, dt_raw, taps, conv_bias, a_log, dt_bias, d_skip, slot_h,
                      step_size(dt_raw[S:]).reshape(L, chunk, H), 0.0)
     y_l, h_out, h_snap = ssd_chunk(h_in, xl, bl, cl, dt_l, a, snap_rows)
     y_l = y_l + d_skip.astype(f32)[:, None] * xl.astype(f32)
-    rows_at = lambda n: jax.vmap(  # noqa: E731
-        lambda e, k: jax.lax.dynamic_slice_in_dim(e, k, r, 0))(ext_l, n)
-    conv_out, conv_snap = rows_at(lrows), rows_at(snap_rows)
-    for i in range(L):
-        fed, at = lrows[i] > 0, jnp.maximum(dst[i], 0)
-        slot_h = _put(slot_h, lslot[i], h_out[i], fed)
-        slot_conv = _put(slot_conv, lslot[i], conv_out[i], fed)
-        snap_h = _put(snap_h, at, h_snap[i], dst[i] >= 0)
-        snap_conv = _put(snap_conv, at, conv_snap[i], dst[i] >= 0)
     y = jnp.concatenate([y, y_l.reshape(L * chunk, H * P)], axis=0)
-    return y.astype(dtype), slot_h, slot_conv, snap_h, snap_conv
+    return (y.astype(dtype),) + lanes_commit(lanes, slot_h, slot_conv, ext_l,
+                                             r, h_out, h_snap)
 
 
 def gated_rms_norm(y, z, scale, groups, eps):

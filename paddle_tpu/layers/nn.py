@@ -1111,13 +1111,14 @@ def latent_paged_attention(q, pool, block_table, pos, num_heads, v_width,
 
 
 def moe_route(x, w_router, held, top_k, scaling, norm_topk_prob=True,
-              live=None, name=None, bias=None, norm_eps=0.0):
+              live=None, name=None, bias=None, norm_eps=0.0, groups=None):
     """Sigmoid top-k routing over every column of `w_router`; returns the
     dense weights of the `held` experts [n_held, N, 1] (float32) and the
     rows each got [n_held] (int32) (fusion/moe.py). `live` [N]: rows that
     are 0 there select nothing. `bias` [E]: the selection is the top-k of
     score + bias, the weights the unbiased scores; `norm_eps` joins the sum
-    they are divided by."""
+    they are divided by. `groups` (n_group, topk_group): only the experts of
+    the `topk_group` best of `n_group` groups are eligible."""
     helper = LayerHelper("moe_route", name=name)
     n = _prod(x.shape[:-1])
     weights = helper.create_tmp_variable(dtype="float32",
@@ -1135,6 +1136,8 @@ def moe_route(x, w_router, held, top_k, scaling, norm_topk_prob=True,
         inputs["Bias"] = [bias]
     if norm_eps:
         attrs["norm_eps"] = float(norm_eps)
+    if groups is not None:
+        attrs["n_group"], attrs["topk_group"] = map(int, groups)
     helper.append_op(type="moe_route", inputs=inputs,
                      outputs={"Weights": [weights], "Rows": [rows]},
                      attrs=attrs)
@@ -1298,6 +1301,66 @@ def gated_rms_norm(x, z, groups, epsilon=1e-5, param_attr=None, name=None):
                      inputs={"X": [x], "Z": [z], "Scale": [scale]},
                      outputs={"Out": [out]},
                      attrs={"groups": int(groups), "epsilon": float(epsilon)})
+    return out
+
+
+def kda_scan(qkv, f, b, params, state, live, kda, lanes=None, name=None):
+    """One kda layer's convolution and delta-rule scan over a tick's rows
+    (fusion/kda.py): `qkv` [N, 1, conv_dim], the gate's `f` [N, 1, d_inner]
+    and `b` [N, 1, heads] from the input projections, `params` (dict: taps,
+    a_log, dt_bias), `state` and `lanes` as `ssm_scan` takes them, `kda` the
+    `KdaSpec`. Returns o [N, 1, d_inner]."""
+    helper = LayerHelper("kda_scan", name=name)
+    out = helper.create_tmp_variable(
+        dtype=dtype_name(qkv.dtype),
+        shape=list(qkv.shape[:-1]) + [kda.d_inner], stop_gradient=True)
+    inputs = {"QKV": [qkv], "F": [f], "B": [b], "Taps": [params["taps"]],
+              "ALog": [params["a_log"]], "DtBias": [params["dt_bias"]],
+              "SlotH": [state["slot_h"]], "SlotConv": [state["slot_conv"]],
+              "Live": [live]}
+    outputs = {"Out": [out], "SlotHOut": [state["slot_h"]],
+               "SlotConvOut": [state["slot_conv"]]}
+    attrs = {"heads": kda.heads, "head_dim": kda.head_dim,
+             "gate_lower_bound": float(kda.gate_lower_bound)}
+    if lanes is not None:
+        inputs.update(SnapH=[state["snap_h"]], SnapConv=[state["snap_conv"]],
+                      LanePos=[lanes["lpos"]], LaneRows=[lanes["lrows"]],
+                      LaneSlot=[lanes["lslot"]], SnapSrc=[lanes["snap_src"]],
+                      SnapDst=[lanes["snap_dst"]],
+                      SnapRows=[lanes["snap_rows"]])
+        outputs.update(SnapHOut=[state["snap_h"]],
+                       SnapConvOut=[state["snap_conv"]])
+        attrs["chunk"] = int(lanes["chunk"])
+    helper.append_op(type="kda_scan", inputs=inputs, outputs=outputs,
+                     attrs=attrs)
+    return out
+
+
+def kda_gate_norm(x, gate, heads, epsilon=1e-6, param_attr=None, name=None):
+    """RMSNorm over each of `heads` heads of the last dimension of `x` with
+    ONE learned scale a head value (shared by the heads), times
+    sigmoid(`gate`) (fusion/kda.py)."""
+    helper = LayerHelper("kda_gate_norm", name=name)
+    scale = helper.create_parameter(
+        param_attr, shape=[x.shape[-1] // heads], dtype=dtype_name(x.dtype),
+        default_initializer=ConstantInitializer(1.0))
+    out = helper.create_tmp_variable(dtype=dtype_name(x.dtype), shape=x.shape,
+                                     stop_gradient=True)
+    helper.append_op(type="kda_gate_norm",
+                     inputs={"X": [x], "Gate": [gate], "Scale": [scale]},
+                     outputs={"Out": [out]},
+                     attrs={"heads": int(heads), "epsilon": float(epsilon)})
+    return out
+
+
+def head_gate(x, gate, heads, name=None):
+    """`x` [.., heads * d] times sigmoid(`gate`) [.., heads], a value a head
+    (fusion/kda.py)."""
+    helper = LayerHelper("head_gate", name=name)
+    out = helper.create_tmp_variable(dtype=dtype_name(x.dtype), shape=x.shape,
+                                     stop_gradient=True)
+    helper.append_op(type="head_gate", inputs={"X": [x], "Gate": [gate]},
+                     outputs={"Out": [out]}, attrs={"heads": int(heads)})
     return out
 
 

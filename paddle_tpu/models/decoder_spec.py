@@ -15,7 +15,10 @@ ATTENTION a layer: a sliding window or every position, each kind with its
 own rotary positions or none), `DecoderSpec.parallel_ssm_gqa` the sixth (TWO
 mixers a layer: a Mamba-2 state-space mixer and rotary grouped-query
 attention on one normed input, summed into one residual, under the family's
-scalar multipliers). A spec comes from one of the constructors;
+scalar multipliers), `DecoderSpec.kda_latent_moe` the seventh (a kind PER
+LAYER under latent attention: a channel-wise gated delta-rule mixer with a
+MATRIX state a head, or latent attention with no query bottleneck and a gate
+a head; group-limited routing). A spec comes from one of the constructors;
 the fields are what `_decoder_block` reads, not a product to pick from: any
 other combination raises where a graph would have to build it.
 `serving.PagedKVEngine(model=spec)` takes any of them;
@@ -39,7 +42,8 @@ Kinds (each a string, checked by name; nothing is guessed):
               `rope_full` where the spec has one, else NOT rotated);
               None: full everywhere, rotated where the spec has a `rope`
   layer_kinds a kind a layer, "attention" | "conv" (`ConvSpec`: a gated
-              short convolution whose state is the last rows of its input);
+              short convolution whose state is the last rows of its input),
+              or under attention "latent": "attention" | "kda" (`KdaSpec`);
               None: attention everywhere. With `one_sublayer` a layer is its
               kind ALONE under one pre-norm residual, out of "ssm"
               (`SsmSpec`) | "attention" | "moe"
@@ -50,7 +54,9 @@ Kinds (each a string, checked by name; nothing is guessed):
   ffn         "relu" | "gated_silu"; layers from `moe.first_dense` on are
               routed experts + shared expert (`MoESpec`: `scoring`
               "sigmoid" is what the serving ticks route by, "softmax" with
-              a balance term `aux_coef` what a training graph does)
+              a balance term `aux_coef` what a training graph does;
+              `topk_method` "group_bias" keeps the `topk_group` best of
+              `n_group` groups of experts before the top-k)
   tied_head   the vocabulary head is the embedding, transposed
 """
 
@@ -98,14 +104,23 @@ class RopeSpec:
 @dataclasses.dataclass(frozen=True)
 class LatentSpec:
     """Latent attention (MLA): queries through a rank-`q_lora_rank`
-    bottleneck; keys and values from ONE cached row a token,
-    `kv_lora_rank` normalised values + `rope.dim` rotated ones, shared by all
-    heads."""
-    q_lora_rank: int
+    bottleneck (None: ONE query matrix, no bottleneck and no norm); keys and
+    values from ONE cached row a token, `kv_lora_rank` normalised values +
+    `rope.dim` rotated ones, shared by all heads. `gate` "head": a head's
+    output times sigmoid of one value a head, `u W_gate` [d_model -> heads],
+    before the output projection; "none": no gate."""
+    q_lora_rank: Optional[int]
     kv_lora_rank: int
     qk_nope_head_dim: int
     v_head_dim: int
     rope: RopeSpec
+    gate: str = "none"
+
+    def __post_init__(self):
+        if self.gate not in ("none", "head"):
+            raise NotImplementedError(
+                f"LatentSpec.gate {self.gate!r}: 'none' or 'head' (one "
+                "sigmoid gate a head; no element-wise gate is built)")
 
     @property
     def row_values(self) -> int:
@@ -180,8 +195,53 @@ class SsmSpec:
     def state_rows(self) -> int:
         return self.taps - 1
 
+    @property
+    def state_shape(self) -> Tuple[int, int, int]:
+        return (self.heads, self.head_dim, self.state)
+
     def h_bytes(self) -> int:
         return self.heads * self.head_dim * self.state * 4
+
+
+@dataclasses.dataclass(frozen=True)
+class KdaSpec:
+    """The channel-wise gated delta-rule mixer (Kimi Delta Attention;
+    fusion/kda.py has the equations): `heads` heads whose state is a MATRIX
+    `S` [head_dim (keys), head_dim (values)] in float32, decayed by a gate a
+    KEY CHANNEL in (`gate_lower_bound`, 0) and corrected by what it already
+    holds; a causal depthwise convolution of `taps` taps (no bias, SiLU) over
+    q, k and v. What a request carries from token to token is `S` and the
+    last `taps - 1` rows of the convolution's input."""
+    heads: int
+    head_dim: int
+    taps: int = 4
+    gate_lower_bound: float = -5.0
+
+    def __post_init__(self):
+        if not self.gate_lower_bound < 0:
+            raise ValueError("KdaSpec.gate_lower_bound is the log of the "
+                             f"smallest decay: below 0, not "
+                             f"{self.gate_lower_bound}")
+
+    @property
+    def d_inner(self) -> int:
+        return self.heads * self.head_dim
+
+    @property
+    def conv_dim(self) -> int:
+        """Channels the convolution runs over: q, then k, then v."""
+        return 3 * self.d_inner
+
+    @property
+    def state_rows(self) -> int:
+        return self.taps - 1
+
+    @property
+    def state_shape(self) -> Tuple[int, int, int]:
+        return (self.heads, self.head_dim, self.head_dim)
+
+    def h_bytes(self) -> int:
+        return self.heads * self.head_dim * self.head_dim * 4
 
 
 @dataclasses.dataclass(frozen=True)
@@ -208,7 +268,7 @@ class Multipliers:
                              "dt) and Multipliers.mlp two (gate, output)")
 
 
-TOPK_METHODS = ("none", "bias")
+TOPK_METHODS = ("none", "bias", "group_bias")
 ACTIVATIONS = ("gated_silu", "relu2")
 SCORING = ("sigmoid", "softmax")
 
@@ -222,7 +282,12 @@ class MoESpec:
     left out, and no code stands in for the chips that hold them.
     `topk_method` "bias": the selection is the top-k of score + a learned
     per-expert bias, the weights are the UNBIASED scores of the selected;
-    `norm_eps` is added to the sum the weights are divided by.
+    `norm_eps` is added to the sum the weights are divided by. "group_bias":
+    before that top-k the experts stand in `n_group` groups of equal size, a
+    group scores the sum of its 2 largest score + bias, and only the
+    `topk_group` best groups' experts are eligible (a deployment places whole
+    groups on a chip, so the group step decides which CHIPS a token visits:
+    `held` is whole groups).
     `activation` "relu2": an expert is `W2 relu(W1 z)^2`, two matrices and no
     gate; `latent` > 0: the routed experts run on a row of that width between
     a down- and an up-projection all of them share; `d_shared`: the shared
@@ -247,6 +312,8 @@ class MoESpec:
     latent: int = 0
     d_shared: Optional[int] = None
     aux_coef: float = 0.0
+    n_group: int = 1
+    topk_group: int = 1
 
     @property
     def shared_width(self) -> int:
@@ -261,7 +328,7 @@ class MoESpec:
             raise NotImplementedError(
                 f"topk_method {self.topk_method!r}: the router implements "
                 f"{TOPK_METHODS} (top-k over every expert, plain or of "
-                "score + bias; no group limit)")
+                "score + bias, or of score + bias inside the best groups)")
         if self.scoring not in SCORING:
             raise NotImplementedError(
                 f"scoring_func {self.scoring!r}: the router implements "
@@ -270,6 +337,28 @@ class MoESpec:
                 not 0 <= self.held[0] <= self.held[-1] < self.n_routed:
             raise ValueError(f"held experts {self.held!r} must be distinct, "
                              f"ascending, inside 0..{self.n_routed - 1}")
+        if self.topk_method != "group_bias":
+            if (self.n_group, self.topk_group) != (1, 1):
+                raise ValueError(
+                    f"n_group {self.n_group} / topk_group {self.topk_group} "
+                    "limit the selection under topk_method='group_bias', "
+                    "and only it")
+            return
+        if self.n_group < 1 or self.n_routed % self.n_group:
+            raise ValueError(f"group_bias: {self.n_routed} experts do not "
+                             f"stand in {self.n_group} groups of equal size")
+        size = self.n_routed // self.n_group
+        if not 1 <= self.topk_group <= self.n_group or size < 2 \
+                or self.topk_group * size < self.top_k:
+            raise ValueError(
+                f"group_bias: topk_group {self.topk_group} of {self.n_group} "
+                f"groups of {size} (a group scores its 2 largest) cannot "
+                f"give a top-{self.top_k}")
+        groups = {e // size for e in self.held}
+        if len(self.held) != len(groups) * size:
+            raise ValueError(
+                f"group_bias: held experts {self.held[0]}..{self.held[-1]} "
+                f"cut a group of {size} in two: a chip holds whole groups")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -304,6 +393,7 @@ class DecoderSpec:
     rope_full: Optional[RopeSpec] = None    # the "full" kind's own rotation
     mixer: str = "kind"                     # | "ssm+attention": both, summed
     multipliers: Multipliers = Multipliers()
+    kda: Optional[KdaSpec] = None
 
     def __post_init__(self):
         for field, kinds in (("norm", ("layer_norm", "rms_norm")),
@@ -331,6 +421,7 @@ class DecoderSpec:
                              f"{self.kv_heads} key/value heads")
         kinds = self.layer_kinds
         known = ({"ssm", "attention", "moe"} if self.one_sublayer
+                 else {"attention", "kda"} if self.attention == "latent"
                  else {"attention", "conv"})
         if (kinds is None and self.one_sublayer) or (kinds is not None and (
                 len(kinds) != self.num_layers or set(kinds) - known)):
@@ -362,6 +453,18 @@ class DecoderSpec:
         if (self.conv is not None) != bool(self.conv_layers):
             raise ValueError("a 'conv' layer comes with a ConvSpec, and "
                              "only it")
+        if (self.kda is not None) != bool(self.kda_layers):
+            raise ValueError("a 'kda' layer comes with a KdaSpec, and only "
+                             "it")
+        if self.kda is not None and (
+                self.kv_heads != self.num_heads or self.qk_norm
+                or self.residual != "pre" or self.norm != "rms_norm"
+                or self.ffn != "gated_silu" or self.tied_head):
+            raise ValueError(
+                "a 'kda' layer stands beside latent attention (no grouped "
+                "heads: num_kv_heads, no QK-norm of the full-head kind) in "
+                "the pre-norm RMSNorm block with a gated SiLU pair and an "
+                "untied head")
         akinds = self.attention_kinds
         if akinds is not None and (
                 len(akinds) != self.num_layers
@@ -479,6 +582,26 @@ class DecoderSpec:
                    num_kv_heads=num_kv_heads, rope=rope, head_dim=d_head,
                    ssm=ssm, mixer="ssm+attention", multipliers=multipliers)
 
+    @classmethod
+    def kda_latent_moe(cls, vocab, d_model, d_inner, num_heads, layer_kinds,
+                       kda: KdaSpec, latent: LatentSpec,
+                       moe: Optional[MoESpec] = None, norm_eps=1e-6,
+                       dtype="bfloat16"):
+        """The Ling-3 / Kimi-Linear family's block: pre-norm RMSNorm
+        residuals; a layer's mixer by `layer_kinds`, the channel-wise gated
+        delta-rule mixer ("kda": a matrix state a head in float32, q, k and
+        v through a short convolution, not rotated) or latent attention
+        ("attention": `latent`, here with `q_lora_rank` None and a gate a
+        head; ONE cached row a position in those layers alone); a gated SiLU
+        pair, routed experts beside the shared one from `moe.first_dense`
+        on (group-limited: `topk_method` "group_bias"); a final norm and an
+        untied head."""
+        return cls(vocab, d_model, d_inner, num_heads, len(layer_kinds),
+                   norm="rms_norm", norm_eps=norm_eps, residual="pre",
+                   positions="rotary", attention="latent", ffn="gated_silu",
+                   dtype=dtype, latent=latent, moe=moe,
+                   layer_kinds=tuple(layer_kinds), kda=kda)
+
     @property
     def is_classic(self) -> bool:
         return self == DecoderSpec.classic(**self.dims())
@@ -561,6 +684,22 @@ class DecoderSpec:
         return tuple(i for i in range(self.num_layers)
                      if self.layer_kind(i) == "ssm")
 
+    @property
+    def kda_layers(self) -> Tuple[int, ...]:
+        return tuple(i for i in range(self.num_layers)
+                     if self.layer_kind(i) == "kda")
+
+    @property
+    def recurrent(self):
+        """What describes the layers that keep a slot state and a snapshot
+        pool (`SsmSpec` or `KdaSpec`: `state_shape`, `state_rows`,
+        `conv_dim`, `h_bytes`), or None."""
+        return self.ssm if self.ssm is not None else self.kda
+
+    @property
+    def recurrent_layers(self) -> Tuple[int, ...]:
+        return self.ssm_layers if self.ssm is not None else self.kda_layers
+
     # -- bytes ----------------------------------------------------------------
     @property
     def itemsize(self) -> int:
@@ -572,7 +711,8 @@ class DecoderSpec:
         heads (a window layer's rows are the window pool's:
         `window_row_bytes`)."""
         if self.attention == "latent":
-            return self.num_layers * self.latent.row_lanes * self.itemsize
+            return (len(self.attention_layers) * self.latent.row_lanes
+                    * self.itemsize)
         return (len(self.full_layers) * 2 * self.kv_heads * self.d_head
                 * self.itemsize)
 
@@ -585,13 +725,15 @@ class DecoderSpec:
     def state_bytes(self) -> int:
         """Bytes of ONE copy of a request's per-layer state beside its
         per-token rows (a slot's, or a snapshot): the conv layers' last
-        rows, or the state-space layers' `h` (float32) and last conv rows
-        (a layer whose mixer is both holds them BESIDE its rows of
-        `cache_row_bytes`); 0 where every layer is attention alone."""
-        if self.ssm is not None:
-            return len(self.ssm_layers) * (
-                self.ssm.h_bytes()
-                + self.ssm.state_rows * self.ssm.conv_dim * self.itemsize)
+        rows, or the state-space layers' `h` / the kda layers' `S` (float32)
+        and last conv rows (a layer whose mixer is both holds them BESIDE
+        its rows of `cache_row_bytes`); 0 where every layer is attention
+        alone."""
+        rec = self.recurrent
+        if rec is not None:
+            return len(self.recurrent_layers) * (
+                rec.h_bytes()
+                + rec.state_rows * rec.conv_dim * self.itemsize)
         if self.conv is None:
             return 0
         return (len(self.conv_layers) * self.conv.state_rows * self.d_model

@@ -218,7 +218,8 @@ class _TickRows:
     idle slot, the tail of a short chunk — selects no expert), and where
     the routed layers leave their counts (`expert_rows`: per layer, the
     rows each held expert got); `conv`, where the spec has conv layers, is
-    their state (`_ConvState`), `ssm` the state-space layers' (`_SsmState`).
+    their state (`_ConvState`), `ssm` the state-space or kda layers'
+    (`_RecurrentState`).
     `training`: the rows are a training graph's, [B, T] under one row of
     positions: the rotation carries a gradient, the routed layers are
     `layers.moe_train` and leave their balance terms in `aux`."""
@@ -308,11 +309,13 @@ def _moe_ffn(x, spec, name, rows):
                        else [len(moe.held), dz, moe.d_expert], spec.dtype)
              for n in (("gate", "up", "down") if gated else ("up", "down"))}
     bias = None
-    if moe.topk_method == "bias":
+    if moe.topk_method != "none":
         bias = _param(name + "_router_bias", [moe.n_routed], "float32")
     weights, n_rows = layers.moe_route(
         x, router, moe.held, moe.top_k, moe.scaling, moe.norm_topk_prob,
-        live=rows.live, bias=bias, norm_eps=moe.norm_eps)
+        live=rows.live, bias=bias, norm_eps=moe.norm_eps,
+        groups=(moe.n_group, moe.topk_group)
+        if moe.topk_method == "group_bias" else None)
     rows.expert_rows.append(n_rows)
     z = _proj(x, dz, name + "_latent_down") if moe.latent else x
     routed = layers.moe_experts(z, weights, n_rows, stack.get("gate"),
@@ -359,7 +362,9 @@ def _moe_train_ffn(x, spec, name, rows):
 
 def _latent_attention(x, spec, name, attend, rows):
     """Latent attention (MLA) around `attend(q_rows, cache_row)`: queries
-    through the `q_a` bottleneck and its norm; ONE row a token from `kv_a`
+    through the `q_a` bottleneck and its norm (`q_lora_rank` None: ONE matrix
+    `_q`); a gate a head on the output where the spec has one (`_gate`);
+    ONE row a token from `kv_a`
     (normalised `c_kv`, rotated `k_pe`); the key half of `kv_b` absorbed
     into the query, so that the cache is read as it is stored, and the
     value half applied to what the read returns (fusion/latent_attention.py).
@@ -368,11 +373,14 @@ def _latent_attention(x, spec, name, attend, rows):
     n = x.shape[0]
     dn, dr, c = lat.qk_nope_head_dim, lat.rope.dim, lat.kv_lora_rank
     pad = lat.row_lanes - lat.row_values
-    c_q = layers.rms_norm(_proj(x, lat.q_lora_rank, name + "_qa"),
-                          epsilon=spec.norm_eps,
-                          param_attr=ParamAttr(name=name + "_qa_norm.scale"))
-    q = layers.reshape(_proj(c_q, nh * (dn + dr), name + "_qb"),
-                       shape=[n, nh, dn + dr])
+    if lat.q_lora_rank is None:
+        q = _proj(x, nh * (dn + dr), name + "_q")
+    else:
+        c_q = layers.rms_norm(
+            _proj(x, lat.q_lora_rank, name + "_qa"), epsilon=spec.norm_eps,
+            param_attr=ParamAttr(name=name + "_qa_norm.scale"))
+        q = _proj(c_q, nh * (dn + dr), name + "_qb")
+    q = layers.reshape(q, shape=[n, nh, dn + dr])
     q_nope = layers.reshape(
         layers.slice(q, axes=[2], starts=[0], ends=[dn]), shape=[n, nh * dn])
     q_pe = layers.rotary(
@@ -402,6 +410,8 @@ def _latent_attention(x, spec, name, attend, rows):
                        shape=[n, 1, lat.row_lanes]))          # [n,1,nh*c]
     out = layers.latent_head_proj(ctx, kv_b, "expand_v", nh, dn,
                                   lat.v_head_dim)
+    if lat.gate == "head":
+        out = layers.head_gate(out, _proj(x, nh, name + "_gate"), nh)
     return _proj(out, spec.d_model, name + "_o")
 
 
@@ -474,10 +484,31 @@ def _ssm_mixer(x, spec, name, rows):
         a_log=_param(name + "_a_log", [ssm.heads], "float32"),
         dt_bias=_param(name + "_dt_bias", [ssm.heads], "float32"),
         d=_param(name + "_d", [ssm.heads], "float32"))
-    y = rows.ssm.layer(xbc, dt, params, rows.live)
+    y = rows.ssm.layer(xbc, (dt,), params, rows.live)
     y = layers.gated_rms_norm(y, z, ssm.groups, epsilon=spec.norm_eps,
                               param_attr=ParamAttr(name=name + "_norm.scale"))
     return _scaled(_proj(y, spec.d_model, name + "_out"), by.ssm_out)
+
+
+def _kda_mixer(x, spec, name, rows):
+    """The channel-wise gated delta-rule mixer: `[q | k | v] = x W_qkv`, the
+    gate's `f = x W_f` (a value a key channel, ONE full matrix) and `b = x
+    W_b` (a value a head); the convolution and the delta-rule scan from the
+    request's state (`rows.ssm`, fusion/kda.py); an RMSNorm a head times
+    sigmoid(x W_g); `W_o`; no bias anywhere, no rotation."""
+    kda = spec.kda
+    qkv = _proj(x, kda.conv_dim, name + "_qkv")
+    f = _proj(x, kda.d_inner, name + "_f")
+    b = _proj(x, kda.heads, name + "_b")
+    params = dict(
+        taps=_param(name + "_taps", [kda.conv_dim, kda.taps], spec.dtype),
+        a_log=_param(name + "_a_log", [kda.heads], "float32"),
+        dt_bias=_param(name + "_dt_bias", [kda.d_inner], "float32"))
+    o = rows.ssm.layer(qkv, (f, b), params, rows.live)
+    o = layers.kda_gate_norm(o, _proj(x, kda.d_inner, name + "_g"), kda.heads,
+                             epsilon=spec.norm_eps,
+                             param_attr=ParamAttr(name=name + "_norm.scale"))
+    return _proj(o, spec.d_model, name + "_o")
 
 
 def _decoder_block(x, i, attend, d_model, d_inner, dropout, is_test=True,
@@ -492,7 +523,8 @@ def _decoder_block(x, i, attend, d_model, d_inner, dropout, is_test=True,
       graph chooses: see the module docstring; `_grouped_attention` where
       the heads are rotated, normalised or grouped), latent attention
       (`_latent_attention` around `attend(i, q_rows, cache_row)`), or the
-      gated short convolution (`_short_conv`, its state in `rows.conv`);
+      gated short convolution (`_short_conv`, its state in `rows.conv`), or
+      the gated delta-rule mixer (`_kda_mixer`, its state in `rows.ssm`);
     - `cross(i, x)` when given;
     - the feed-forward: the ReLU pair, the gated SiLU pair, or from
       `spec.moe.first_dense` on routed experts beside the shared one;
@@ -530,6 +562,8 @@ def _decoder_block(x, i, attend, d_model, d_inner, dropout, is_test=True,
                                rows.table_of(spec, i)))]
     elif spec.layer_kind(i) == "conv":
         sublayers = [lambda x: _short_conv(x, spec, f"{name}_conv", rows)]
+    elif spec.layer_kind(i) == "kda":
+        sublayers = [lambda x: _kda_mixer(x, spec, f"{name}_kda", rows)]
     elif spec.attention == "latent":
         sublayers = [lambda x: _latent_attention(
             x, spec, f"{name}_{attn}", functools.partial(attend, i), rows)]
@@ -1394,10 +1428,11 @@ class _LatentPagedCache:
         self.num_heads, self.lat = spec.num_heads, lat
         self.btab, self.pos, self.wblock, self.woff = btab, pos, wblock, woff
         self.lanes = lanes
-        self.pools = [_slot_cache_var(
+        # a pool a LATENT layer (every layer, unless the spec gives kinds)
+        self.pools = {i: _slot_cache_var(
             f"{cache_prefix}_c{i}", [n_blocks, 1, block_size, lat.row_lanes],
-            dtype=spec.dtype) for i in range(spec.num_layers)]
-        self.names = [v.name for v in self.pools]
+            dtype=spec.dtype) for i in spec.attention_layers}
+        self.names = [v.name for v in self.pools.values()]
 
     def _read(self, q, pool, btab, pos, n_rows=None):
         return layers.latent_paged_attention(
@@ -1464,41 +1499,49 @@ class _ConvState:
                               last=self.last))
 
 
-class _SsmState:
-    """The state-space layers' state beside the paged cache (fusion/ssm.py
-    has the scheme): per layer j `{cache_prefix}_ssm_h{j}` [n_slots, H, P, N]
-    float32 and `{cache_prefix}_ssm_conv{j}` [n_slots, K-1, conv_dim] in the
-    spec's dtype, a slot's state, and the snapshot POOL
-    `{cache_prefix}_ssm_snap_h{j}` / `_ssm_snap_conv{j}` [n_snapshots, ..],
-    all persistable, zero at start-up, each written in place by its layer's
-    one `ssm_scan`. `lanes`: the mixed tick's lpos and lrows plus the
-    lanes' own feeds (`lane_slot`, `lane_snap_src`, `lane_snap_dst`,
-    `lane_snap_rows`: serving/kv_pager.py fills them)."""
+class _RecurrentState:
+    """The state-space or kda layers' state beside the paged cache
+    (fusion/ssm.py has the scheme; the shapes are the spec's:
+    `DecoderSpec.recurrent`): per layer j `{cache_prefix}_{kind}_h{j}`
+    [n_slots, H, P, N] float32 (kind "ssm": Mamba-2's h; "kda": the delta
+    rule's S [n_slots, H, D, D]) and `{cache_prefix}_{kind}_conv{j}`
+    [n_slots, K-1, conv_dim] in the spec's dtype, a slot's state, and the
+    snapshot POOL `{cache_prefix}_{kind}_snap_h{j}` / `_snap_conv{j}`
+    [n_snapshots, ..], all persistable, zero at start-up, each written in
+    place by its layer's one scan op (`ssm_scan` | `kda_scan`). `lanes`: the
+    mixed tick's lpos and lrows plus the lanes' own feeds (`lane_slot`,
+    `lane_snap_src`, `lane_snap_dst`, `lane_snap_rows`: serving/kv_pager.py
+    fills them)."""
 
     def __init__(self, cache_prefix, spec, n_slots, n_snapshots, lanes=None):
-        ssm = spec.ssm
-        h = [ssm.heads, ssm.head_dim, ssm.state]
-        conv = [ssm.state_rows, ssm.conv_dim]
-        self.ssm, self.lanes = ssm, lanes
+        rec = spec.recurrent
+        kind = "ssm" if spec.ssm is not None else "kda"
+        h = list(rec.state_shape)
+        conv = [rec.state_rows, rec.conv_dim]
+        self.rec, self.lanes = rec, lanes
+        self.scan = layers.ssm_scan if kind == "ssm" else layers.kda_scan
         self.states = [dict(
-            slot_h=_slot_cache_var(f"{cache_prefix}_ssm_h{j}", [n_slots] + h),
-            slot_conv=_slot_cache_var(f"{cache_prefix}_ssm_conv{j}",
+            slot_h=_slot_cache_var(f"{cache_prefix}_{kind}_h{j}",
+                                   [n_slots] + h),
+            slot_conv=_slot_cache_var(f"{cache_prefix}_{kind}_conv{j}",
                                       [n_slots] + conv, dtype=spec.dtype),
-            snap_h=_slot_cache_var(f"{cache_prefix}_ssm_snap_h{j}",
+            snap_h=_slot_cache_var(f"{cache_prefix}_{kind}_snap_h{j}",
                                    [n_snapshots] + h),
-            snap_conv=_slot_cache_var(f"{cache_prefix}_ssm_snap_conv{j}",
+            snap_conv=_slot_cache_var(f"{cache_prefix}_{kind}_snap_conv{j}",
                                       [n_snapshots] + conv, dtype=spec.dtype))
-            for j in range(len(spec.ssm_layers))]
+            for j in range(len(spec.recurrent_layers))]
         self.n_slots, self.built, self.live = n_slots, 0, None
 
-    def layer(self, xbc, dt, params, live):
+    def layer(self, u, gates, params, live):
+        """The next layer's scan: `u` the convolution's input, `gates` what
+        else the scan reads of the row (ssm: (dt,); kda: (f, b))."""
         state = self.states[self.built]
         self.built += 1
         if self.live is None:           # the decode rows' part, cut once
             self.live = layers.slice(live, axes=[0], starts=[0],
                                      ends=[self.n_slots])
-        return layers.ssm_scan(xbc, dt, params, state, self.live, self.ssm,
-                               lanes=self.lanes)
+        return self.scan(u, *gates, params, state, self.live, self.rec,
+                         lanes=self.lanes)
 
 
 def _embed_rows(tok, spec, name="tok_emb"):
@@ -1535,8 +1578,9 @@ def _kinds_paged_tick(model, n_slots, n_blocks, block_size, blocks_per_req,
     `_lane_feeds`, and `lane_slot` where a lane leaves a state in its
     slot); rows embedded without positions; `_LatentPagedCache`, or
     `_PagedCache` / `_PagedLaneCache` over the attention layers' key/value
-    heads beside `_ConvState` or `_SsmState` (`n_snapshots` entries in its
-    snapshot pool); the blocks through `_lm_decoder`; a float32 head without
+    heads, beside `_ConvState` or `_RecurrentState` (`n_snapshots` entries
+    in its snapshot pool; kda layers' stands beside `_LatentPagedCache`);
+    the blocks through `_lm_decoder`; a float32 head without
     bias, the embedding itself where the spec ties it.
     Returns (next_ids followed by the routed layers' counts, the K/V
     pools' names)."""
@@ -1587,14 +1631,14 @@ def _kinds_paged_tick(model, n_slots, n_blocks, block_size, blocks_per_req,
                 cache_prefix, model, S, n_blocks, wblock,
                 lanes and dict(lane_feeds, block_size=block_size,
                                lslot=_feed("lane_slot", [lanes[0]])))
-        if model.ssm is not None:
-            ssm = _SsmState(
-                cache_prefix, model, S, n_snapshots,
-                lanes and dict(
-                    lpos=lpos, lrows=lrows, chunk=lanes[1],
-                    lslot=_feed("lane_slot", [lanes[0]]),
-                    **{n: _feed("lane_" + n, [lanes[0]])
-                       for n in ("snap_src", "snap_dst", "snap_rows")}))
+    if model.recurrent is not None:
+        ssm = _RecurrentState(
+            cache_prefix, model, S, n_snapshots,
+            lanes and dict(
+                lpos=lpos, lrows=lrows, chunk=lanes[1],
+                lslot=_feed("lane_slot", [lanes[0]]),
+                **{n: _feed("lane_" + n, [lanes[0]])
+                   for n in ("snap_src", "snap_dst", "snap_rows")}))
     rows = _TickRows(model, positions,
                      _live_rows(wblock, lrows, lanes[1] if lanes else 0),
                      NLB * block_size, conv, ssm)
@@ -1742,6 +1786,11 @@ def _spec_lm(tokens, label, spec, max_len):
             f"transformer_lm(model=spec): mixer {spec.mixer!r} has no "
             "training graph (the state-space scan carries no gradient); "
             "serve it through PagedKVEngine(model=spec)")
+    if spec.kda is not None:
+        raise NotImplementedError(
+            "transformer_lm(model=spec): a 'kda' layer has no training graph "
+            "(the delta-rule scan carries no gradient); serve it through "
+            "PagedKVEngine(model=spec)")
     if spec.attention != "full" or spec.layer_kinds is not None \
             or spec.residual != "pre" or spec.positions != "rotary" \
             or spec.tied_head or spec.dropout or spec.packed:

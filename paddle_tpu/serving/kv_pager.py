@@ -1122,6 +1122,8 @@ class PagedKVEngine(ContinuousBatchingEngine):
                         + (" beside short-convolution layers"
                            if model.conv else "")
                         + (" beside state-space layers" if model.ssm else "")
+                        + (" beside gated delta-rule (kda) layers"
+                           if model.kda else "")
                         + (" beside sliding-window layers"
                            if model.window else "")
                         + (" and routed experts" if model.moe else "")
@@ -1135,6 +1137,10 @@ class PagedKVEngine(ContinuousBatchingEngine):
                            "rows, the snapshot pool), which it would "
                            "neither roll back, spill, fork nor quantize"
                            if model.ssm else "")
+                        + (", not the delta-rule state (a slot's matrix state "
+                           "S and conv rows, the snapshot pool), which it "
+                           "would neither roll back, spill, fork nor quantize"
+                           if model.kda else "")
                         + (", not the window table (a request's second block "
                            "table over the window pool, whose blocks are "
                            "released behind the window), which it would "
@@ -1182,13 +1188,14 @@ class PagedKVEngine(ContinuousBatchingEngine):
         #: ... and in the window pool (0 without sliding-window layers)
         self.window_block_bytes = model.window_row_bytes() * self.block_size
         self.n_window_blocks = int(n_window_blocks) if model.window else 0
-        #: entries of the snapshot pool (state-space layers only: their
-        #: state is too large for a snapshot a block)
-        self.n_snapshots = int(n_snapshots) if model.ssm is not None else 0
-        enforce(model.ssm is None or self.n_snapshots >= 1,
-                "a model with state-space layers needs n_snapshots >= 1: "
-                "without a snapshot every request prefills its whole prompt",
-                exc=InvalidArgumentError)
+        #: entries of the snapshot pool (state-space and kda layers only:
+        #: their state is too large for a snapshot a block)
+        self.n_snapshots = int(n_snapshots) \
+            if model.recurrent is not None else 0
+        enforce(model.recurrent is None or self.n_snapshots >= 1,
+                "a model with state-space or kda layers needs n_snapshots "
+                ">= 1: without a snapshot every request prefills its whole "
+                "prompt", exc=InvalidArgumentError)
         per_blk_f32 = 2 * num_layers * num_heads * self.block_size * dh * 4
         per_blk_i8 = 2 * num_layers * num_heads * self.block_size * (dh + 4)
         self.kv_quant_freed_bytes = 0
@@ -2052,13 +2059,14 @@ class PagedKVEngine(ContinuousBatchingEngine):
                 request_bound=self.pager.window_bound(self.chunk_tokens))
         if self.n_snapshots:
             # the second kind of state, too large for a snapshot a block: a
-            # copy a slot, and the pool's entries
+            # copy a slot, and the pool's entries (Mamba-2's `h` or the delta
+            # rule's `S`: one seam, one key, which keeps its first name)
             s["ssm_state"] = dict(
                 s["pager"]["snapshot_pool"],
                 # the layers that hold it, and those of them that hold K/V
                 # rows too (a layer whose mixer is both)
-                layers=len(self.model.ssm_layers),
-                layers_with_kv=len(set(self.model.ssm_layers)
+                layers=len(self.model.recurrent_layers),
+                layers_with_kv=len(set(self.model.recurrent_layers)
                                    & set(self.model.attention_layers)),
                 bytes_per_copy=self.state_bytes,
                 slot_bytes=self.state_bytes * self.n_slots,

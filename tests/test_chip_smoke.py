@@ -116,6 +116,25 @@ def test_serve_parallel_phase():
     assert out["block_bytes"] == 3 * 2 * 2 * 8 * 2 * 8   # K/V in every layer
 
 
+def test_serve_kda_phase():
+    """The smoke's kda engine (ISSUE 59: delta-rule mixers with a float32
+    matrix state beside one latent-attention layer with a gate a head,
+    group-limited routing over one held group) at a tiny size: prefix-hit
+    requests equal their self-prefilled twins, and do not once the pool's
+    entries are swapped or zeroed."""
+    out = chip_smoke.phase_serve_kda(
+        vocab=97, d_model=64, d_inner=96, num_heads=4, head_dim=16,
+        kv_lora_rank=32, rope_dim=8, d_expert=32, n_routed=16, n_held=4,
+        top_k=3, n_group=4, topk_group=2, n_slots=4, block_size=8,
+        n_blocks=40, n_snapshots=4, max_len=64, preamble=24, turns=(5, 11),
+        max_new=12, expect_lowering="composite")
+    assert out["ssm_state"]["restores"] == 2 and out["tokens_out"] == 24
+    assert out["ssm_state"]["layers"] == 3 and out["ssm_state"]["written"] >= 2
+    assert out["swapped_state_differs_at"] < 12  # the planted faults are
+    assert out["zeroed_state_differs_at"] < 12   # refused
+    assert out["block_bytes"] == 1 * 128 * 2 * 8    # ONE latent layer's rows
+
+
 def test_train_resnet_phase():
     out = chip_smoke.phase_train_resnet50(batch=2, steps=2, depth=18,
                                           image=32)

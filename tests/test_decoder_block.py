@@ -523,3 +523,118 @@ def test_the_training_graph_refuses_two_mixers_by_name():
     with pytest.raises(NotImplementedError, match="ssm\\+attention"):
         T.transformer_lm(max_len=16, model=PARALLEL)
     pt.reset_default_programs()
+
+
+# -- a kda mixer or latent attention by layer, group-limited routing (ISSUE 59)
+
+from paddle_tpu.models.decoder_spec import KdaSpec  # noqa: E402
+
+KDA = DecoderSpec.kda_latent_moe(
+    vocab=61, d_model=32, d_inner=48, num_heads=4,
+    layer_kinds=("kda", "attention", "kda"), kda=KdaSpec(heads=4, head_dim=8),
+    latent=LatentSpec(q_lora_rank=None, kv_lora_rank=16, qk_nope_head_dim=8,
+                      v_head_dim=8, rope=RopeSpec(dim=4, theta=6e6),
+                      gate="head"),
+    moe=MoESpec(n_routed=8, top_k=2, d_expert=16, held=(0, 1, 2, 3),
+                first_dense=1, scaling=2.5, topk_method="group_bias",
+                n_group=4, topk_group=2, norm_eps=1e-20))
+NEW_OPS = {"kda_scan", "kda_gate_norm", "head_gate"}
+
+
+@pytest.mark.parametrize("graph", sorted(PAGED_BUILDERS))
+def test_kda_and_latent_layers_go_through_the_one_block(graph):
+    """The seventh constructor: a layer's mixer by kind, the delta-rule scan
+    with its slot state and snapshot pool beside ONE latent pool (the latent
+    layer's), a dense first layer and group-limited routed layers after."""
+    build = _kinds_build(graph, KDA)
+    ops = _op_types(build)
+    assert ops.count("kda_scan") == ops.count("kda_gate_norm") == 2
+    assert ops.count("head_gate") == 1
+    reads = 2 if graph == "paged_mixed_tick" else 1
+    assert ops.count("latent_paged_attention") == reads
+    assert ops.count("paged_cache_write") == 1
+    assert ops.count("moe_route") == ops.count("moe_experts") == 2
+    assert ops.count("rms_norm") == 2 * 3 + 1 + 1      # + kva_norm + final
+    assert "ssm_scan" not in ops and "short_conv" not in ops
+    program = _program(build)
+    routes = [op for op in program.global_block().ops
+              if op.type == "moe_route"]
+    assert all((op.attrs["n_group"], op.attrs["topk_group"]) == (4, 2)
+               for op in routes)
+    names = {v.name: tuple(v.shape)
+             for v in program.global_block().vars.values() if v.persistable}
+    assert names["l0_kda_qkv.w_0"] == (32, 96)
+    assert names["l0_kda_f.w_0"] == names["l0_kda_g.w_0"] == (32, 32)
+    assert names["l0_kda_b.w_0"] == (32, 4) == names["l1_attn_gate.w_0"]
+    assert names["l0_kda_taps"] == (96, 4) and names["l0_kda_dt_bias"] == (32,)
+    assert names["l0_kda_norm.scale"] == (8,)
+    assert names["l1_attn_q.w_0"] == (32, 4 * 12)
+    assert "l1_attn_qa.w_0" not in names and "l1_attn_qb.w_0" not in names
+    assert names["l0_ffn_gate.w_0"] == (32, 48)
+    assert names["l1_moe_router_bias"] == (8,)
+    slots = names["pgd_kda_h0"][0]
+    assert names["pgd_kda_h0"] == names["pgd_kda_h1"] == (slots, 4, 8, 8)
+    assert names["pgd_kda_snap_h1"] == (2, 4, 8, 8)
+    assert names["pgd_kda_conv0"] == (slots, 3, 96)
+    assert [n for n in names if n.startswith("pgd_c")] == ["pgd_c1"]
+
+
+def test_a_spec_of_kda_and_latent_layers_counts_its_bytes():
+    assert KDA.kda_layers == (0, 2) and KDA.attention_layers == (1,)
+    assert KDA.recurrent is KDA.kda and KDA.recurrent_layers == (0, 2)
+    assert KDA.ssm_layers == () and KDA.moe_layers == (1, 2)
+    assert KDA.kda.conv_dim == 96 and KDA.kda.state_shape == (4, 8, 8)
+    assert KDA.cache_row_bytes() == 128 * 2          # ONE latent layer's row
+    assert KDA.state_bytes() == 2 * (4 * 8 * 8 * 4 + 3 * 96 * 2)
+    assert KINDS.cache_row_bytes() == (
+        KINDS.num_layers * KINDS.latent.row_lanes * 2)
+    assert OLDER["ssm_gqa_moe"].recurrent is OLDER["ssm_gqa_moe"].ssm
+
+
+@pytest.mark.parametrize("graph", sorted(PAGED_BUILDERS))
+@pytest.mark.parametrize("kind", sorted(OLDER) + ["parallel_ssm_gqa"])
+def test_the_six_older_specs_build_none_of_the_new_ops(graph, kind):
+    """The six constructors that were build the programs they built: no op
+    of the kda mixer, no gate a head, no group step on a router."""
+    spec = OLDER.get(kind, PARALLEL)
+    program = _program(_kinds_build(graph, spec))
+    ops = program.global_block().ops
+    assert not NEW_OPS & {op.type for op in ops}
+    assert all("n_group" not in op.attrs for op in ops
+               if op.type == "moe_route")
+
+
+@pytest.mark.parametrize("change, match", [
+    (dict(kda=None), "KdaSpec"),
+    (dict(layer_kinds=("kda", "conv", "kda")), "layer_kinds"),
+    (dict(layer_kinds=("attention",) * 3), "KdaSpec"),
+    (dict(num_kv_heads=2), "grouped"),
+    (dict(qk_norm=True), "'kda' layer"),
+    (dict(tied_head=True), "'kda' layer"),
+    (dict(ffn="relu"), "'kda' layer"),
+    (dict(positions="none"), "rotary"),
+    (dict(attention="full", latent=None), "RopeSpec"),
+])
+def test_every_kda_combination_no_graph_builds_raises_by_name(change, match):
+    import dataclasses
+    with pytest.raises(ValueError, match=match):
+        dataclasses.replace(KDA, **change)
+
+
+def test_latent_and_kda_specs_refuse_what_they_do_not_build():
+    import dataclasses
+    with pytest.raises(NotImplementedError, match="gate"):
+        dataclasses.replace(KDA.latent, gate="element")
+    with pytest.raises(ValueError, match="gate_lower_bound"):
+        KdaSpec(heads=4, head_dim=8, gate_lower_bound=0.5)
+    # a kda kind under full-head attention is no kind at all
+    with pytest.raises(ValueError, match="layer_kinds"):
+        dataclasses.replace(OLDER["conv_gqa_moe"],
+                            layer_kinds=("kda", "attention"))
+
+
+def test_the_training_graph_refuses_a_kda_layer_by_name():
+    pt.reset_default_programs()
+    with pytest.raises(NotImplementedError, match="'kda' layer"):
+        T.transformer_lm(max_len=16, model=KDA)
+    pt.reset_default_programs()

@@ -1681,6 +1681,9 @@ COVERED_ELSEWHERE = {
     "conv_state_commit": "tests/test_short_conv.py",
     "ssm_scan": "tests/test_ssm.py",
     "gated_rms_norm": "tests/test_ssm.py",
+    "kda_scan": "tests/test_ling_engine.py",
+    "kda_gate_norm": "tests/test_kda.py",
+    "head_gate": "tests/test_kda.py",
     "moe_experts": "tests/test_routed_experts.py",
     # the routed layer of a training graph: values and every gradient
     # against the plain reference, the ranks' shares, no dropped row

@@ -787,3 +787,55 @@ def test_training_expert_product_compiles_for_v5e(one_chip):
     for scope in ("rows", "total", "gate", "gate_bwd", "in", "out", "in_t",
                   "out_t", "in_w", "out_w"):
         assert f"moe_train_{scope}/" in text, scope
+
+
+def _kda_update(slots=96, h=32, d=128):
+    from paddle_tpu.fusion import kda
+    args = [S((slots, h, d, d), F32), S((slots,), F32), S((slots, h, d), F32),
+            S((slots, h, d), F32), S((slots, h, d), BF16),
+            S((slots, h, d), F32), S((slots, h), F32)]
+    return (lambda st, live, q, k, v, g, beta: kda.kda_decode_update(
+        st, live, q, k, v, g, beta, backend="pallas")), args
+
+
+def test_kda_decode_update_compiles_for_v5e_in_place(one_chip):
+    """The delta-rule decode update at the published widths (ISSUE 59: 96
+    slots of 32 heads x 128 x 128 float32, 2 MB a slot: a whole slot a grid
+    step) through the TPU compiler for a v5e, the state aliased onto its
+    input; the name `kda_decode_roofline` keys is the result's shape."""
+    f, args = _kda_update()
+    text = _tpu_text(f, *args)
+    assert _n_calls(text) == 1 and "kda_decode" in jax.jit(f).trace(
+        *args).lower(lowering_platforms=("tpu",)).as_text(debug_info=True)
+    assert "output_operand_aliases" in text or "operand_index" in text
+    args = [S(a.shape, a.dtype, sharding=one_chip) for a in args]
+    compiled = jax.jit(f, donate_argnums=0).lower(*args).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    assert "f32[96,32,128]" in compiled.as_text()
+
+
+def test_kda_scan_compiles_for_v5e_at_the_lanes_shape(one_chip):
+    """One kda layer of the MIXED tick at the published widths (96 decode
+    rows and two lanes of 128 rows: the decode kernel and the chunked form,
+    its sub-blocks of 16 rows, the snapshot pool of 32) through the TPU
+    compiler for a v5e: one Mosaic call, the rest XLA."""
+    from paddle_tpu.fusion import kda
+    slots, lanes, chunk, h, d, snaps = 96, 2, 128, 32, 128, 32
+    n = slots + lanes * chunk
+    args = [S((n, 3 * h * d), BF16), S((n, h * d), BF16), S((n, h), BF16),
+            S((3 * h * d, 4), BF16), S((h,), F32), S((h * d,), F32),
+            S((slots, h, d, d), F32), S((slots, 3, 3 * h * d), BF16),
+            S((slots,), F32), S((snaps, h, d, d), F32),
+            S((snaps, 3, 3 * h * d), BF16)]
+    args += [S((lanes,), I32)] * 6
+
+    def f(qkv, fr, br, taps, a_log, dt_bias, slot_s, slot_conv, live, snap_s,
+          snap_conv, lpos, lrows, lslot, src, dst, snap_rows):
+        return kda.kda_scan(
+            qkv, fr, br, taps, a_log, dt_bias, slot_s, slot_conv, live,
+            (h, d, -5.0), (snap_s, snap_conv, lpos, lrows, lslot, src, dst,
+                           snap_rows, chunk), backend="pallas")
+    assert _n_calls(_tpu_text(f, *args)) == 1
+    args = [S(a.shape, a.dtype, sharding=one_chip) for a in args]
+    compiled = jax.jit(f, donate_argnums=(6, 7, 9, 10)).lower(*args).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1

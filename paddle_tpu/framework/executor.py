@@ -16,8 +16,12 @@ state are donated to XLA so parameter updates are in-place on device.
 
 from __future__ import annotations
 
+import hashlib
+import json
 import math
 import operator
+import os
+import sys
 import time
 from typing import Any, Dict, List, Optional, Sequence, Union
 
@@ -25,13 +29,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..core import compile_cache as _store
 from ..core import flags
 from ..core.enforce import InvalidArgumentError, NotFoundError, enforce
 from ..core.places import Place, default_place
 from ..observability import tracing as _tracing
 from .lowering import LowerCtx, build_plan, run_plan
-from .program import (BATCH_ROW_MASK_NAME, Program, Variable,
-                      default_main_program)
+from .program import (BATCH_ROW_MASK_NAME, Parameter, Program, Variable,
+                      default_main_program, dtype_name)
 from .scope import Scope, global_scope
 
 
@@ -65,9 +70,118 @@ def as_numpy(x):
     return np.asarray(x)
 
 
+def _digest(obj) -> str:
+    """sha256 of `obj` as canonical JSON. Sets go in sorted and tuples as
+    lists; whatever else JSON has no form for raises TypeError: a step
+    that holds one has no key in the store."""
+    def plain(v):
+        if isinstance(v, (set, frozenset)):
+            return sorted(v)
+        if isinstance(v, np.dtype):
+            return str(v)
+        raise TypeError(f"{type(v).__name__} has no JSON form")
+    return hashlib.sha256(json.dumps(
+        obj, sort_keys=True, default=plain).encode()).hexdigest()
+
+
+def _program_digest(program: Program) -> str:
+    """sha256 over everything `Program.to_json()` holds (every block's vars
+    with shape, dtype, flags and sharding spec, every op's type, slots and
+    attrs) and each var's staging, LESS `random_seed`: every launch passes
+    the seed as an argument and no trace reads it. A constant table among
+    the attrs (the LM's position encoding is a list of a million floats, 21
+    MB as JSON and 0.6 s to write) goes in as its bytes. Raises TypeError on
+    an attr JSON has no form for, like `to_json()`."""
+    def attr(v):
+        if isinstance(v, np.ndarray) or (isinstance(v, (list, tuple))
+                                         and len(v) > 256):
+            a = np.asarray(v)
+            if a.dtype != object:
+                return ["array", str(a.dtype), a.shape,
+                        hashlib.sha256(a.tobytes()).hexdigest()]
+        if isinstance(v, np.generic):
+            return v.item()
+        if isinstance(v, (list, tuple)):
+            return [attr(x) for x in v]
+        if isinstance(v, dict):
+            return {k: attr(x) for k, x in v.items()}
+        return v
+
+    h = hashlib.sha256()
+    for b in program.blocks:
+        h.update(json.dumps([
+            b.idx, b.parent_idx,
+            [[v.name, v.shape, dtype_name(v.dtype), v.persistable,
+              v.stop_gradient, v.lod_level, v.is_data,
+              isinstance(v, Parameter), v.trainable,
+              getattr(v, "sharding_spec", None),
+              getattr(v, "is_optimizer_state", False), repr(v.staging)]
+             for v in b.vars.values()],
+            [[op.type, op.inputs, op.outputs,
+              {k: attr(v) for k, v in op.attrs.items()}] for op in b.ops],
+        ], sort_keys=True).encode())
+    return h.hexdigest()
+
+
+def _sharding_key(sh):
+    """A sharding as the store's key holds it: its text (the mesh's axes
+    and the spec; a single device's id) and the devices in their order."""
+    mesh = getattr(sh, "mesh", None)
+    ids = ([d.id for d in mesh.devices.flat] if mesh is not None
+           else sorted(d.id for d in sh.device_set))
+    return str(sh), ids
+
+
+def _arg_key(v):
+    """One argument of a launch as the key holds it: shape, dtype, and
+    for a device array where it lies and whether it is committed there
+    (an executable built for committed arguments commits its results)."""
+    if isinstance(v, jax.Array):
+        return (v.shape, str(v.dtype), bool(v.weak_type),
+                _sharding_key(v.sharding), bool(v.committed))
+    if isinstance(v, (np.ndarray, np.generic)):
+        return np.shape(v), str(v.dtype), "host"
+    return type(v).__name__, "host"     # a Python scalar: weakly typed
+
+
+def _store_world():
+    """What the process adds to every key: the versions of jax, jaxlib and
+    the device runtime, the devices, EVERY flag of `core/flags.py`, every
+    `jax.config` value as this thread sees it (a `with
+    jax.default_matmul_precision(...)` around a launch is another program),
+    the package's knobs in the environment that are not flags, JAX's, XLA's
+    and the TPU runtime's own, and the digest of the package's source.
+
+    The `jax.config` options are those JAX had defined when the package
+    was imported (`compile_cache.configure`), each with the value it has
+    NOW: JAX defines some of its options where a module is first imported
+    (Pallas's three switches, with the first kernel a process TRACES), and
+    a process that loads every program never imports it. With every name
+    of the moment in, the process that filled the store made the decode
+    tick's key after the mixed tick's trace had imported Pallas, and no
+    later process found that entry (PERF.md section 6, PR 60). An option
+    defined later is in the key through its `JAX_*` environment variable
+    alone."""
+    import jaxlib
+    devices = jax.devices()
+    return {
+        "jax": jax.__version__, "jaxlib": jaxlib.__version__,
+        "python": list(sys.version_info[:3]),
+        "runtime": devices[0].client.platform_version,
+        "devices": [(d.platform, d.device_kind, d.id) for d in devices],
+        "flags": {k: repr(v) for k, v in flags.all_flags().items()},
+        "config": {k: repr(jax.config._value_holders[k].value)
+                   for k in _store.CONFIG_NAMES},
+        "env": {k: v for k, v in os.environ.items()
+                if k.startswith(("PTPU_", "JAX_"))
+                or k in ("XLA_FLAGS", "LIBTPU_INIT_ARGS")},
+        "source": _store.source_digest(),
+    }
+
+
 class _CompiledStep:
     def __init__(self, fn, ro_names, rw_names, feed_names, fetch_names,
-                 program):
+                 program, layout):
         self.fn = fn
         self.ro_names = ro_names
         self.rw_names = rw_names
@@ -76,32 +190,54 @@ class _CompiledStep:
         self._packed_fns = {}
         #: what the step's `compile` spans call it
         self.program = program
-        #: its jitted functions that have not run yet (`first_run`)
-        self.unrun = {fn}
+        #: what launches each of its jitted functions that has RUN: the
+        #: function itself, or its executable out of the store (`_Stored`).
+        #: A function that is not in here has its first run before it
+        self.launch = {}
+        #: how each jitted function takes its arguments, (kind, ..., the
+        #: keywords it was jitted with): part of its executable's key
+        self.layouts = {fn: layout}
+        #: set where the step is built (`Executor._compile`, `run_steps`):
+        #: the executor and the prepared program its key is made from
+        self.owner = self.prepared = self.jit_kwargs = None
+        self.key_base = None
 
-    def first_run(self, fn):
-        """The `executor/compile_or_load` span around `fn`'s FIRST call:
-        jax.jit is lazy, so a jitted function's trace, lowering and XLA
-        compile (or its load from the persistent cache) all happen inside
-        that call, and it is where the span is (`tracing.compile_span`). A
-        launch path opens it in its OWN frame, while `unrun` holds its
-        function (the steady path pays that one test; a step served through
-        a PreparedStep never runs `self.fn`, which stays in the set and costs
-        the packed launches nothing):
+    def first_run(self, fn, args):
+        """The `executor/compile_or_load` span around `fn`'s FIRST call
+        with `args`, and the store's part in it. jax.jit is lazy, so a
+        jitted function's trace, lowering and XLA compile (or its load from
+        JAX's persistent cache) all happen inside that call, and it is where
+        the span is (`tracing.compile_span`). Entered, it has looked in the
+        executor's store (`core/compile_cache.py`) under a key made WITHOUT
+        tracing (`Executor._store_key`), and `launch` says what it found:
 
-            if fn in compiled.unrun:
-                with compiled.first_run(fn):
-                    out = fn(*args)
+        - the executable, loaded and ready (`_Stored`): no jaxpr, no MLIR;
+        - `fn` itself where the step has no key (no cache directory in
+          effect, attrs that do not serialise, arrays this process cannot
+          address whole): today's lazy path;
+        - None on a miss: the launcher lowers and compiles with the very
+          arguments it has (JAX's cache serves or stores that compile as
+          before) and hands the executable to `keep`, which writes the entry.
+
+        A launch path does all of it in its OWN frame, while `launch` lacks
+        its function (the steady path pays that one probe):
+
+            launch = compiled.launch.get(fn)
+            if launch is None:
+                with compiled.first_run(fn, args) as first:
+                    launch = first.launch or first.keep(
+                        fn.lower(*args).compile())
+                    out = launch(*args)
             else:
-                out = fn(*args)
+                out = launch(*args)
 
-        and NOT through a wrapper that calls `fn`: a frame between the
-        launcher and the jitted function is in every traced op's location,
-        so in every Mosaic kernel's serialized body and the compile cache's
-        key, and it cost the routed training cell 3 s of set-up (PERF.md
-        section 6, PR 57). `fn` has run once the block is left without an
-        exception."""
-        return _FirstRun(self, fn)
+        and NOT through a wrapper that calls `fn` or lowers it: a frame
+        between the launcher and the jitted function is in every traced
+        op's location, so in every Mosaic kernel's serialized body and the
+        compile cache's key, and it cost the routed training cell 3 s of
+        set-up (PERF.md section 6, PR 57). `fn` has run once the block is
+        left without an exception, and `launch` holds what launches it."""
+        return _FirstRun(self, fn, args)
 
     def packed_fn(self, spans):
         """The step as a PreparedStep launches it: `fn(pack, rest_vals,
@@ -146,20 +282,82 @@ class _CompiledStep:
             return inner(feed_vals, ro_vals, rw_vals, seed)
 
         fn = self._packed_fns[spans] = jax.jit(step, **kwargs)
-        self.unrun.add(fn)
+        self.layouts[fn] = ("packed", spans, kwargs)
         return fn
 
 
-class _FirstRun(_tracing.compile_span):
-    __slots__ = ("_step", "_fn")
+class _Stored:
+    """What launches a jitted function whose executable the store holds (a
+    hit's, loaded; a miss's, compiled here and written): the
+    `jax.stages.Compiled`, called through the same C++ path as the
+    `jax.jit`. An executable refuses arguments it was not built for where
+    the `jax.jit` would trace again (a state value of another dtype, a
+    committed array of another sharding): from the first such refusal on,
+    the `jax.jit` launches, as before the store."""
 
-    def __init__(self, step, fn):
+    __slots__ = ("call", "jit", "executable", "loaded")
+
+    def __init__(self, executable, jit, loaded):
+        self.call = self.executable = executable
+        self.jit, self.loaded = jit, loaded
+
+    def __call__(self, *args):
+        try:
+            return self.call(*args)
+        except (TypeError, ValueError):
+            # raised by the executable's check of its arguments, before
+            # anything ran or was donated
+            if self.call is self.jit:
+                raise
+            self.call = self.jit
+            return self.jit(*args)
+
+
+class _FirstRun(_tracing.compile_span):
+    __slots__ = ("_step", "_fn", "_args", "_entry", "launch")
+
+    def __init__(self, step, fn, args):
         super().__init__("executor/compile_or_load", step.program)
-        self._step, self._fn = step, fn
+        self._step, self._fn, self._args, self.launch = step, fn, args, fn
+
+    def __enter__(self):
+        super().__enter__()
+        owner = self._step.owner
+        self._entry = (owner._store_key(self._step, self._fn, self._args)
+                       if owner is not None else None)
+        self._args = None           # the launcher holds them, not the span
+        if self._entry is not None:
+            t = time.perf_counter()
+            executable = _store.load_executable(*self._entry)
+            if executable is None:
+                self.launch = None
+            else:
+                if self._stack is not None:
+                    # no event of JAX's tells this load: told as JAX tells
+                    # a retrieval from its own cache (one executable, one
+                    # load, nothing compiled), so `cache_load_s` goes on
+                    # meaning "seconds spent loading"
+                    seconds = time.perf_counter() - t
+                    self._jax.add("backend", seconds)
+                    self._jax.add("load", seconds)
+                self.launch = _Stored(executable, self._fn, True)
+            self.attrs["stored"] = int(executable is not None)
+        return self
+
+    def keep(self, executable):
+        """A miss's executable, just compiled by the launcher: written to
+        the store (where it serializes and the directory takes it), and
+        what launches the function from here on."""
+        t = time.perf_counter()
+        if _store.store_executable(*self._entry, self._step.program,
+                                   executable):
+            self.attrs["store_write_s"] = time.perf_counter() - t
+        self.launch = _Stored(executable, self._fn, False)
+        return self.launch
 
     def __exit__(self, *exc):
         if exc[0] is None:
-            self._step.unrun.discard(self._fn)
+            self._step.launch[self._fn] = self.launch
         return super().__exit__(*exc)
 
 
@@ -254,8 +452,10 @@ class PreparedStep:
     def run(self, feed, return_numpy=False):
         """feed: dict with EXACTLY the prepared names/shapes/dtypes (not
         re-validated — a drifted signature recompiles via jit's own shape
-        check or fails inside XLA). Packs them on the way in: the launch
-        is run_bound()'s. Returns the fetch list (jax arrays unless
+        check or fails inside XLA; where the launch goes through a stored
+        executable, which refuses what it was not built for, `_Stored`
+        hands a drifted call to the jit). Packs them on the way in: the
+        launch is run_bound()'s. Returns the fetch list (jax arrays unless
         return_numpy)."""
         compiled = self._compiled
         scope = self._scope
@@ -270,12 +470,15 @@ class PreparedStep:
         self._buf[0] = (self._random_seed * 1000003
                         + self._owner._run_counter) % (2 ** 31)
         fn = self._fn
-        if fn in compiled.unrun:
-            with compiled.first_run(fn):
-                fetches, new_state = fn(self._buf, rest_vals, ro_vals,
-                                        rw_vals)
+        launch = compiled.launch.get(fn)
+        if launch is None:
+            args = (self._buf, rest_vals, ro_vals, rw_vals)
+            with compiled.first_run(fn, args) as first:
+                launch = first.launch or first.keep(fn.lower(*args).compile())
+                fetches, new_state = launch(*args)
         else:
-            fetches, new_state = fn(self._buf, rest_vals, ro_vals, rw_vals)
+            fetches, new_state = launch(self._buf, rest_vals, ro_vals,
+                                        rw_vals)
         for name, val in zip(compiled.state_out_names, new_state):
             scope.set_var(name, val)
         if self._b_rw_vals is not None:
@@ -391,13 +594,16 @@ class PreparedStep:
         self._b_staged = (other, staged)
         np.copyto(staged, buf)
         fn = self._fn
-        if fn in self._compiled.unrun:
-            with self._compiled.first_run(fn):
-                fetches, new_state = fn(staged, self._b_rest_vals,
-                                        self._b_ro_vals, self._b_rw_vals)
+        launch = self._compiled.launch.get(fn)
+        if launch is None:
+            args = (staged, self._b_rest_vals, self._b_ro_vals,
+                    self._b_rw_vals)
+            with self._compiled.first_run(fn, args) as first:
+                launch = first.launch or first.keep(fn.lower(*args).compile())
+                fetches, new_state = launch(*args)
         else:
-            fetches, new_state = fn(staged, self._b_rest_vals,
-                                    self._b_ro_vals, self._b_rw_vals)
+            fetches, new_state = launch(staged, self._b_rest_vals,
+                                        self._b_ro_vals, self._b_rw_vals)
         self._b_rw_vals = self._b_rw_pick(new_state)
         sv = self._b_scope_vars
         for name, val in zip(self._b_state_names, new_state):
@@ -601,13 +807,90 @@ class Executor:
         if name is None:
             name = ("startup" if not feed_names and not fetch_names
                     else "train_step" if state_out_names else "infer_step")
-        compiled = _CompiledStep(fn, ro, rw, feed_names, fetch_names, name)
+        compiled = _CompiledStep(fn, ro, rw, feed_names, fetch_names, name,
+                                 ("plain", jit_kwargs))
         compiled.state_out_names = state_out_names
         # what a PreparedStep builds its one-host-array launch from
         # (_CompiledStep.packed_fn)
         compiled.pure_step, compiled.jit_kwargs = step, jit_kwargs
+        compiled.owner, compiled.prepared = self, program
         self._stash_flops_estimate(compiled, program)
         return compiled
+
+    # -- the store of loaded-and-ready executables -------------------------
+    def _store_marks(self):
+        """Hook: what this EXECUTOR adds to the key of its executables
+        beside the program it prepared (ParallelExecutor: its strategies
+        and its mesh)."""
+        return [type(self).__name__]
+
+    def _store_key_base(self, compiled: _CompiledStep):
+        """The part of a step's key that all its launch functions share,
+        made from the PREPARED program: `_program_digest` (what its JSON
+        holds: every op's attrs, every var's shape, dtype and sharding
+        spec); the marks the rewrites left on the program object; the
+        names the step is built around; and the file of every op lowering
+        the program uses that lies outside the package (those inside are
+        in the source digest). Raises where any of it has no JSON form."""
+        program = compiled.prepared
+        from .registry import lookup_op
+        outside = {}
+        for op_type in sorted({op.type for b in program.blocks
+                               for op in b.ops}):
+            path = lookup_op(op_type).lower.__code__.co_filename
+            if not _store.in_package(path):
+                outside[op_type] = _store.file_digest(path)
+        return {
+            "program": _program_digest(program),
+            "marks": {k: v for k, v in vars(program).items()
+                      if k not in ("blocks", "random_seed", "_version",
+                                   "_current_block_idx")},
+            "names": [compiled.program, compiled.feed_names,
+                      compiled.fetch_names, compiled.ro_names,
+                      compiled.rw_names, compiled.state_out_names],
+            "lowerings": outside, "executor": self._store_marks()}
+
+    def _store_key(self, compiled: _CompiledStep, fn, args):
+        """(entry's path, key) of the executable of `fn`, one of
+        `compiled`'s jitted functions, for a launch with `args`; None where
+        it has none and takes the lazy path: no cache directory in effect,
+        a world of several processes or an array this one cannot address
+        whole, a program or an argument with no JSON form. Nothing here
+        traces: the key is made from what the executor holds before it
+        does. The path names the program and the key LESS the source
+        digest, so an entry of an older source is replaced."""
+        root = _store.store_dir()
+        if root is None or jax.process_count() > 1:
+            return None
+        try:
+            if compiled.key_base is None:
+                compiled.key_base = self._store_key_base(compiled)
+            kind, *layout, kwargs = compiled.layouts[fn]
+            leaves, tree = jax.tree_util.tree_flatten(args)
+            if not all(getattr(v, "is_fully_addressable", True)
+                       for v in leaves):
+                return None
+            key = dict(
+                compiled.key_base, world=_store_world(),
+                launch=[kind, layout, str(tree),
+                        [_arg_key(v) for v in leaves],
+                        {k: jax.tree_util.tree_map(_sharding_key, v)
+                         if k.endswith("_shardings") else v
+                         for k, v in kwargs.items()}])
+            whole = _digest(key)
+            if flags.get_flag("vlog") >= 2:     # "why did it miss?"
+                flags.vlog(2, "store key of %s: %s %s", compiled.program,
+                           whole, json.dumps(key, sort_keys=True,
+                                             default=str))
+            del key["world"]["source"]
+            name = "".join(c if c.isalnum() else "_"
+                           for c in compiled.program)
+            return (os.path.join(root, f"{name}-{_digest(key)[:32]}.exe"),
+                    whole)
+        except Exception as e:   # no key, for whatever reason: the lazy path
+            flags.vlog(1, "no stored executable for %s: %s: %s",
+                       compiled.program, type(e).__name__, e)
+            return None
 
     def _scan_shardings(self, program, feed_names, fetch_names, ro, rw,
                         state_out_names):
@@ -748,12 +1031,16 @@ class Executor:
         with _tracing.span("step", "executor/run",
                            program_version=program._version):
             fn = compiled.fn
-            if fn in compiled.unrun:
-                with compiled.first_run(fn):
-                    fetches, new_state = fn(feed_vals, ro_vals, rw_vals,
-                                            seed)
+            launch = compiled.launch.get(fn)
+            if launch is None:
+                args = (feed_vals, ro_vals, rw_vals, seed)
+                with compiled.first_run(fn, args) as first:
+                    launch = first.launch or first.keep(
+                        fn.lower(*args).compile())
+                    fetches, new_state = launch(*args)
             else:
-                fetches, new_state = fn(feed_vals, ro_vals, rw_vals, seed)
+                fetches, new_state = launch(feed_vals, ro_vals, rw_vals,
+                                            seed)
             if _prof.profiler_enabled():
                 jax.block_until_ready(fetches)
         if flags.get_flag("check_nan_inf") and jax.default_backend() != "cpu":
@@ -874,8 +1161,10 @@ class Executor:
                 jit_kwargs["out_shardings"] = scan_sh[1]
             fn = jax.jit(loop, **jit_kwargs)
             compiled = _CompiledStep(fn, ro, rw, list(feed_list[0].keys()),
-                                     fetch_names, "run_steps")
+                                     fetch_names, "run_steps",
+                                     ("run_steps", k, jit_kwargs))
             compiled.state_out_names = state_out_names
+            compiled.owner, compiled.prepared = self, program
             self._stash_flops_estimate(compiled, program,
                                        feed=feed_list[0])
             self._cache[key] = compiled
@@ -897,13 +1186,16 @@ class Executor:
         t0 = time.time()
         with _tracing.span("step", "executor/run_steps", steps=k):
             fn = compiled.fn
-            if fn in compiled.unrun:
-                with compiled.first_run(fn):
-                    fetches, final_state = fn(feed_stacks, ro_vals, rw_vals,
-                                              seed)
+            launch = compiled.launch.get(fn)
+            if launch is None:
+                args = (feed_stacks, ro_vals, rw_vals, seed)
+                with compiled.first_run(fn, args) as first:
+                    launch = first.launch or first.keep(
+                        fn.lower(*args).compile())
+                    fetches, final_state = launch(*args)
             else:
-                fetches, final_state = fn(feed_stacks, ro_vals, rw_vals,
-                                          seed)
+                fetches, final_state = launch(feed_stacks, ro_vals, rw_vals,
+                                              seed)
         if flags.get_flag("check_nan_inf") and jax.default_backend() != "cpu":
             # same contract as run(): sweep BEFORE the scope write-back so
             # the last-good parameters stay checkpointable when a step in
@@ -960,6 +1252,12 @@ class Executor:
         (the bench tools' convention)."""
         aot = getattr(compiled, "aot_cache", None)
         if aot is None:
+            launch = compiled.launch.get(compiled.fn)
+            if isinstance(launch, _Stored) and not launch.loaded:
+                # a miss of the store compiled this very function ahead of
+                # time, in this process, for the step's own arguments
+                compiled.aot_cache = launch.executable
+                return launch.executable
             feed_vals = tuple(
                 jnp.asarray(feed[n]) if n in feed else scope.get(n)
                 for n in compiled.feed_names)

@@ -447,7 +447,11 @@ class compile_span(span):
     `cache_load_s`, `executables`, `cache_loads`, `cache_hit`
     (`_JaxSeconds.attrs`) and
     `kernel_calls` / `kernel_bodies_traced`: the `*/call` and `*/body_traced`
-    counter samples this thread recorded meanwhile, read in the ring."""
+    counter samples this thread recorded meanwhile, read in the ring. A
+    first call that found its executable in the executor's store traced and
+    lowered nothing (`framework/executor.py` `_FirstRun`): it tells the span
+    the load's seconds as JAX tells a retrieval from its own cache, and
+    every count that comes of a trace reads 0."""
 
     __slots__ = ("_jax", "_seq0")
 

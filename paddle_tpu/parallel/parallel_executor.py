@@ -568,6 +568,15 @@ class ParallelExecutor(Executor):
             sp.attrs["moved"] = moved
         marks[id(scope)] = cfg_key
 
+    def _store_marks(self):
+        # everything of THIS executor that a trace of its step reads and
+        # the prepared program does not carry: the strategies (dataclasses
+        # of enums and scalars: their repr is their value) and the mesh
+        return super()._store_marks() + [
+            repr(self.build_strategy), repr(self.exec_strategy),
+            self.mesh.axes,
+            [d.id for d in self.mesh.jax_mesh.devices.flat]]
+
     def _lowering_mesh(self, program: Program):
         # the manual modes already run the step per shard (shard_map
         # below): a lowering must not map its kernel over the mesh again

@@ -1468,11 +1468,11 @@ class TestCompileSpans:
                                           pt.global_scope())
         fn = compiled.fn
         with pytest.raises(TypeError):
-            with compiled.first_run(fn):
+            with compiled.first_run(fn, ()):
                 fn()                            # not the step's arguments
-        assert fn in compiled.unrun
+        assert fn not in compiled.launch
         exe.run(feed=feed, fetch_list=[loss])
-        assert not compiled.unrun
+        assert compiled.launch[fn] is fn        # no store in effect: the jit
         assert [s.attrs["program"] for s in _first_runs()] == [
             "startup", "train_step", "train_step"]
 

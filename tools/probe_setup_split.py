@@ -11,7 +11,12 @@ the program kept (`tracing.compile_spans()`, docs/observability.md): inside
 `train_step`, with JAX's seconds of the step's trace, its lowering, the
 backend's compile (~0 in a run that loads the executable from the compile
 cache) and the cache's retrieval, and the kernels' calls and traced bodies;
-`by_kind` has the sums over the process.
+`by_kind` has the sums over the process; `stored` says what the executor's
+store of ready executables (`paddle_tpu/core/compile_cache.py`) did for the
+programs: `hit_share`, the percentage of first runs that found their
+executable there and so traced and lowered nothing, and `load_s`, the seconds
+each program's executable took to load (a miss's are the retrieval from
+JAX's cache, and its `write_s` what the entry cost to write).
 `--attention xla` pins every `fused_attention` op to the composite, as
 `chip_smoke.py` does: the difference between the two runs is the flash calls'
 share. The second form times `jax.jit(jax.grad(...)).trace()` and `.lower()`
@@ -37,6 +42,20 @@ def told(span):
     return {"name": span.name, "s": round(span.end - span.start, 3),
             **{k: round(v, 3) if isinstance(v, float) else v
                for k, v in span.attrs.items()}}
+
+
+def stored(kept):
+    """What the store did for the first runs among the kept spans."""
+    runs = [s for s in kept if s.name == "executor/compile_or_load"]
+    keyed = [s for s in runs if "stored" in s.attrs]
+    return {
+        "programs": len(runs), "keyed": len(keyed),
+        "hit_share": round(100.0 * sum(s.attrs["stored"] for s in keyed)
+                           / len(keyed), 1) if keyed else None,
+        "load_s": {s.attrs["program"]: round(s.attrs["cache_load_s"], 3)
+                   for s in runs},
+        "write_s": {s.attrs["program"]: round(s.attrs["store_write_s"], 3)
+                    for s in runs if "store_write_s" in s.attrs}}
 
 
 def probe_cell(args):
@@ -98,6 +117,7 @@ def probe_cell(args):
         "parts": {k: round(v, 3) for k, v in parts.items()},
         "by_kind": {k: round(sum(s.attrs.get(k, 0) for s in kept), 3)
                     for k in KINDS},
+        "stored": stored(kept),
         "spans": [told(s) for s in kept],
         "loss": [first, second],
         "device": f"{device['platform']} {device['kind']} x{device['count']}",

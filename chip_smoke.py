@@ -682,6 +682,132 @@ def phase_serve_kda(vocab=39296, d_model=2560, d_inner=6144, num_heads=32,
             "tpu_custom_calls": n_calls, "mixed_tpu_custom_calls": n_mixed}
 
 
+def phase_serve_dsa(vocab=19360, d_model=4096, d_inner=12288, num_heads=64,
+                    head_dim=128, q_lora_rank=1536, kv_lora_rank=512,
+                    latent_head_dim=256, index=(32, 128, 256, 4, 64),
+                    d_expert=2048, n_routed=288, n_held=8, top_k=8,
+                    kinds=("kda", "attention", "kda"), n_slots=16,
+                    block_size=64, n_blocks=96, n_snapshots=4, max_len=2048,
+                    preamble=1024, turns=(40, 150), max_new=24,
+                    expect_lowering="kernel"):
+    """A model whose residual is FOUR streams mixed through Sinkhorn around
+    every sub-layer, whose layers are kda mixers with low-rank gate pairs or
+    ONE sparse NoPE latent layer (an indexer of `index` = (heads, dim, topk,
+    kpool, rotated) scores pooled keys in a second pool under the same block
+    table and the read attends the best groups and the tail), clamped gated
+    pairs, through the same PagedKVEngine at the published widths of
+    benchmark/configs/glm53-flash-ep8.json and three layers. The preamble
+    holds four times the positions `index_topk` keeps, so every row behind it
+    drops groups. Requests that start from a shared preamble (latent blocks
+    AND pooled keys from the prefix cache, the state from the pool) must emit
+    the tokens an engine without prefix sharing emits; with the shared
+    blocks' pooled keys zeroed they must not."""
+    from paddle_tpu.models.decoder_spec import (DecoderSpec, HyperSpec,
+                                                IndexerSpec, KdaSpec,
+                                                LatentSpec, MoESpec, RopeSpec)
+    from paddle_tpu.serving import PagedKVEngine
+    import jax.numpy as jnp
+    import paddle_tpu as pt
+
+    ih, idim, topk, kpool, rot = index
+    spec = DecoderSpec.kda_latent_moe(
+        vocab, d_model, d_inner, num_heads, kinds,
+        KdaSpec(heads=num_heads, head_dim=head_dim, gate_rank=head_dim),
+        LatentSpec(q_lora_rank=q_lora_rank, kv_lora_rank=kv_lora_rank,
+                   qk_nope_head_dim=latent_head_dim,
+                   v_head_dim=latent_head_dim, rope=None),
+        MoESpec(n_routed=n_routed, top_k=top_k, d_expert=d_expert,
+                held=tuple(range(n_held)), n_shared=1, first_dense=1,
+                scaling=2.5, topk_method="bias", norm_eps=1e-20,
+                swiglu_limit=10.0),
+        norm_eps=1e-5,
+        indexer=IndexerSpec(ih, idim, topk, kpool, RopeSpec(rot, 1e6)),
+        hyper=HyperSpec())
+    t0 = time.time()
+    scope = pt.Scope()
+    sizes = dict(max_len=max_len, block_size=block_size, scope=scope,
+                 model=spec)
+    # slow channels and stream maps of the benchmark's making, set BEFORE the
+    # engines that run are built (a bound step pins the weights it was built
+    # over): see `phase_serve_kda`; the maps' scalars 1, their bias 2 on
+    # H_res's diagonal
+    PagedKVEngine(n_slots=1, n_blocks=max_len // block_size + 1,
+                  n_snapshots=1, **sizes)
+    n = spec.hyper.mult
+    for name in list(scope.local_var_names()):
+        var = scope.get(name)
+        if name.endswith("_a_log"):
+            scope.set_var(name, jnp.zeros_like(var))
+        elif name.endswith("_dt_bias"):
+            scope.set_var(name, jnp.full_like(var, -4.0))
+        elif "_hc" in name and name.endswith("_a"):
+            scope.set_var(name, jnp.ones_like(var))
+        elif "_hc" in name and name.endswith("_b"):
+            scope.set_var(name, jnp.concatenate(
+                [jnp.zeros((2 * n,), var.dtype),
+                 2.0 * jnp.eye(n, dtype=var.dtype).reshape(-1)]))
+    engines = [PagedKVEngine(n_slots=n_slots, n_blocks=n_blocks,
+                             n_snapshots=n_snapshots, prefix_sharing=share,
+                             **sizes)
+               for share in (True, False)]
+    rng = np.random.RandomState(3)
+    head = rng.randint(0, vocab, (preamble,)).tolist()
+    prompts = [head + rng.randint(0, vocab, (n,)).tolist() for n in turns]
+    tokens = []
+    for eng in engines:
+        warm = eng.submit(head, 2)
+        eng.run_until_idle()
+        rest = [eng.submit(p, max_new) for p in prompts]
+        eng.run_until_idle()
+        _check(all(r.done and r.error is None for r in [warm] + rest),
+               "a request of the sparse engine did not finish")
+        tokens.append([r.tokens for r in rest])
+    run_s = time.time() - t0
+    shared, alone = engines
+    st = shared.stats()
+    _check(tokens[0] == tokens[1],
+           "a request that resumed from the prefix cache (latent blocks, "
+           "pooled index keys and the delta-rule snapshot) emitted other "
+           "tokens than its self-prefilled twin")
+    n_calls = _n_custom_calls(shared.tick_hlo())
+    n_mixed = _n_custom_calls(shared.mixed_tick_hlo())
+    per = {k: list(kinds).count(k) for k in ("kda", "attention")}
+    want = per["kda"] + per["attention"] + len(kinds) - 1 \
+        if expect_lowering == "kernel" else 0
+    _check((st["paged_attention_lowering"], n_calls, n_mixed)
+           == (expect_lowering, want, want),
+           f"the sparse engine reports its cache read as "
+           f"{st['paged_attention_lowering']!r}, its ticks hold {n_calls} "
+           f"and {n_mixed} tpu_custom_calls; expected {expect_lowering!r} "
+           f"with {want} in both (a state update a kda layer, a product a "
+           "routed layer, ONE read a sparse layer: lanes and decode rows are "
+           "one batch of rows)")
+    # the comparison has to refuse a broken second pool: with every pooled
+    # key zeroed a hit selects by index scores of zero (the first groups)
+    index_pools = [c for c in shared.cache_names if "_ci" in c]
+    _check(len(index_pools) == per["attention"],
+           f"index pools {index_pools}: one a sparse layer")
+    for name in index_pools:
+        scope.set_var(name, jnp.zeros_like(scope.get(name)))
+    probe = head + rng.randint(0, vocab, (2,)).tolist()
+    pair = [eng.submit(probe, max_new) for eng in engines]
+    for eng in engines:
+        eng.run_until_idle()
+    _check(pair[0].shared_len == preamble and pair[1].shared_len == 0
+           and pair[0].tokens != pair[1].tokens,
+           "a request that resumed from zeroed pooled keys emitted its "
+           "self-prefilled twin's tokens: the comparison does not see the "
+           "index pool")
+    zeroed = next(i for i, (a, b) in enumerate(zip(*(r.tokens for r in pair)))
+                  if a != b)
+    return {"compile_s": 0.0, "run_s": round(run_s, 2),
+            "tokens_out": sum(len(t) for t in tokens[0]),
+            "zeroed_index_differs_at": zeroed,
+            "ssm_state": st["ssm_state"], "block_bytes": st["block_bytes"],
+            "paged_attention_lowering": st["paged_attention_lowering"],
+            "tpu_custom_calls": n_calls, "mixed_tpu_custom_calls": n_mixed}
+
+
 def phase_serve_parallel(vocab=261120, d_model=5120, d_inner=21504,
                          num_heads=20, num_kv_heads=4, d_head=128,
                          ssm=(32, 128, 2, 256), num_layers=4, n_slots=4,
@@ -1594,6 +1720,7 @@ def _run():
     phase("serve_parallel", phase_serve_parallel)
     _free_device_memory()
     phase("serve_kda", phase_serve_kda)
+    phase("serve_dsa", phase_serve_dsa)
     _free_device_memory()
     phase("window_read", phase_window_read)
     phase("kernels", phase_kernels)

@@ -53,3 +53,5 @@ from . import moe  # noqa: F401  (registers moe_route / moe_experts)
 from . import short_conv  # noqa: F401  (registers short_conv / conv_state_commit)
 from . import ssm  # noqa: F401  (registers ssm_scan / gated_rms_norm)
 from . import kda  # noqa: F401  (registers kda_scan / kda_gate_norm / head_gate)
+from . import hyper_connection  # noqa: F401  (registers hyper_connection_pre / _post / _exit)
+from . import sparse_latent_attention  # noqa: F401  (registers sparse_latent_attention)

@@ -198,10 +198,19 @@ def experts_lowering(n_rows, d_model, d_expert, backend=None, tile=None):
     return COMPOSITE
 
 
-def _experts_composite(x, w, gate, up, down):
+def clamp_pair(g, u, limit):
+    """`swiglu_limit`: gate <- min(gate, limit), up <- clip(up, -limit,
+    limit); 0 leaves both."""
+    if not limit:
+        return g, u
+    return jnp.minimum(g, limit), jnp.clip(u, -limit, limit)
+
+
+def _experts_composite(x, w, gate, up, down, limit=0.0):
     xf = x.astype(gate.dtype)
     g = jnp.einsum("nd,edf->enf", xf, gate, preferred_element_type=jnp.float32)
     u = jnp.einsum("nd,edf->enf", xf, up, preferred_element_type=jnp.float32)
+    g, u = clamp_pair(g, u, limit)
     h = (jax.nn.silu(g) * u * w).astype(down.dtype)
     return jnp.einsum("enf,efd->nd", h, down,
                       preferred_element_type=jnp.float32)
@@ -235,10 +244,11 @@ def packed_walk(touched):
 
 
 def _experts_kernel(order_ref, count_ref, x_ref, w_ref, g_ref, u_ref, d_ref,
-                    o_ref):
+                    o_ref, *, limit=0.0):
     def hidden(x):
         g = jnp.dot(x, g_ref[0], preferred_element_type=jnp.float32)
         u = jnp.dot(x, u_ref[0], preferred_element_type=jnp.float32)
+        g, u = clamp_pair(g, u, limit)
         return g * jax.nn.sigmoid(g) * u
 
     _walk_step(count_ref, x_ref, w_ref, d_ref, o_ref, hidden)
@@ -271,8 +281,8 @@ def _walk_step(count_ref, x_ref, w_ref, d_ref, o_ref, hidden):
                               d_ref[0], preferred_element_type=jnp.float32)
 
 
-@functools.partial(jax.jit, static_argnames=("tile", "interpret"))
-def _walk_pallas(x, w, touched, stacks, tile, interpret):
+@functools.partial(jax.jit, static_argnames=("tile", "interpret", "limit"))
+def _walk_pallas(x, w, touched, stacks, tile, interpret, limit=0.0):
     """The grouped product over the touched experts only: `stacks` is (gate,
     up, down) or (up, down), `tile` columns of the expert width a step."""
     from jax.experimental import pallas as pl
@@ -300,7 +310,8 @@ def _walk_pallas(x, w, touched, stacks, tile, interpret):
     steps = n_held if interpret else jnp.maximum(count[0], 1)
     with jax.named_scope("moe_experts" if gated else "latent_experts"):
         return pl.pallas_call(
-            _experts_kernel if gated else _relu2_kernel,
+            (functools.partial(_experts_kernel, limit=limit) if limit
+             else _experts_kernel) if gated else _relu2_kernel,
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=2,
                 grid=(steps, nf),
@@ -320,19 +331,21 @@ def _walk_pallas(x, w, touched, stacks, tile, interpret):
         )(order, count, x.astype(down.dtype), w, *ins, down)
 
 
-def experts(x, w, rows, gate, up, down, backend=None):
+def experts(x, w, rows, gate, up, down, backend=None, limit=0.0):
     """x [N, D]; w [n_held, N, 1] float32 (`route`); rows [n_held]; gate,
     up [n_held, D, F]; down [n_held, F, D] -> [N, D] float32. `gate` None:
-    the two-matrix form, `down_e(relu(up_e x)^2)`."""
+    the two-matrix form, `down_e(relu(up_e x)^2)`. `limit`: the gated
+    pair's clamp (`clamp_pair`)."""
     stacks = (up, down) if gate is None else (gate, up, down)
     tile = experts_tile(*x.shape, up.shape[-1], up.dtype.itemsize,
                         len(stacks))
     if experts_lowering(*x.shape, up.shape[-1], backend, tile) == KERNEL:
         return _walk_pallas(x, w, rows, stacks, tile=tile,
-                            interpret=backend == "pallas_interpret")
+                            interpret=backend == "pallas_interpret",
+                            limit=float(limit))
     if gate is None:
         return _relu2_composite(x, w, up, down)
-    return _experts_composite(x, w, gate, up, down)
+    return _experts_composite(x, w, gate, up, down, limit)
 
 
 # ---------------------------------------------------------------------------
@@ -869,5 +882,6 @@ def _moe_experts_op(ctx, ins, attrs):
     out = experts(x.reshape(-1, x.shape[-1]), ins["Weights"][0],
                   ins["Rows"][0],
                   ins["Gate"][0] if ins.get("Gate") else None, ins["Up"][0],
-                  ins["Down"][0], backend=attrs.get("backend"))
+                  ins["Down"][0], backend=attrs.get("backend"),
+                  limit=attrs.get("limit", 0.0))
     return {"Out": [out.reshape(x.shape).astype(x.dtype)]}

@@ -1110,6 +1110,88 @@ def latent_paged_attention(q, pool, block_table, pos, num_heads, v_width,
     return out
 
 
+def sparse_latent_attention(q, pool, index_pool, qi, ki, wi, positions,
+                            table, block_table, wblock, woff, num_heads,
+                            v_width, scale, indexer, lanes=None, name=None):
+    """The latent read over the rows an indexer SELECTS
+    (fusion/sparse_latent_attention.py): `q` [N, 1, nh*W] the tick's padded
+    query rows, `pool` the written latent pool, `index_pool` the pooled index
+    keys' pool (written here, in place), `qi` / `ki` / `wi` the rows' index
+    queries [N, 1, heads*dim], key [N, 1, dim] and head weights [N, 1,
+    heads], `positions` [N, 1, 1], `table` the indexer's rotary table, the
+    decode rows' `block_table`, `wblock`, `woff`; `lanes` (dict: lbtab,
+    lwblocks, lrows, chunk) where the tick has them; `indexer` the
+    `IndexerSpec`. Returns [N, 1, nh*v_width]."""
+    helper = LayerHelper("sparse_latent_attention", name=name)
+    out = helper.create_tmp_variable(
+        dtype=dtype_name(q.dtype),
+        shape=list(q.shape[:-1]) + [num_heads * v_width], stop_gradient=True)
+    inputs = {"Q": [q], "Pool": [pool], "IndexPool": [index_pool],
+              "QI": [qi], "KI": [ki], "WI": [wi], "Positions": [positions],
+              "Table": [table], "BlockTable": [block_table],
+              "WBlock": [wblock], "WOff": [woff]}
+    attrs = {"num_heads": num_heads, "v_width": v_width,
+             "scale": float(scale), "index_heads": indexer.heads,
+             "top_groups": indexer.top_groups, "kpool": indexer.kpool}
+    if lanes is not None:
+        inputs.update(LaneBlockTable=[lanes["lbtab"]],
+                      LaneWBlocks=[lanes["lwblocks"]],
+                      LaneRows=[lanes["lrows"]])
+        attrs["chunk"] = int(lanes["chunk"])
+    helper.append_op(type="sparse_latent_attention", inputs=inputs,
+                     outputs={"Out": [out], "IndexPoolOut": [index_pool]},
+                     attrs=attrs)
+    return out
+
+
+def hyper_connection_pre(x, p, a, b, hyper, norm_eps, name=None):
+    """The streams `x` [N, 1, n*d] mixed into ONE row for a sub-layer
+    (fusion/hyper_connection.py): returns (the row [N, 1, d], H_post [N, n],
+    H_res [N, n*n]), the maps float32."""
+    helper = LayerHelper("hyper_connection_pre", name=name)
+    n, rows = hyper.mult, _prod(x.shape[:-1])
+    out = helper.create_tmp_variable(
+        dtype=dtype_name(x.dtype),
+        shape=list(x.shape[:-1]) + [x.shape[-1] // n], stop_gradient=True)
+    h_post = helper.create_tmp_variable(dtype="float32", shape=[rows, n],
+                                        stop_gradient=True)
+    h_res = helper.create_tmp_variable(dtype="float32", shape=[rows, n * n],
+                                       stop_gradient=True)
+    helper.append_op(type="hyper_connection_pre",
+                     inputs={"X": [x], "P": [p], "A": [a], "B": [b]},
+                     outputs={"Out": [out], "HPost": [h_post],
+                              "HRes": [h_res]},
+                     attrs={"mult": n,
+                            "sinkhorn_iters": int(hyper.sinkhorn_iters),
+                            "eps": float(hyper.eps),
+                            "norm_eps": float(norm_eps)})
+    return out, h_post, h_res
+
+
+def hyper_connection_post(x, y, h_post, h_res, hyper, name=None):
+    """The streams after a sub-layer: `H_res x + H_post^T y`, as `x`."""
+    helper = LayerHelper("hyper_connection_post", name=name)
+    out = helper.create_tmp_variable(dtype=dtype_name(x.dtype), shape=x.shape,
+                                     stop_gradient=True)
+    helper.append_op(type="hyper_connection_post",
+                     inputs={"X": [x], "Y": [y], "HPost": [h_post],
+                             "HRes": [h_res]},
+                     outputs={"Out": [out]}, attrs={"mult": hyper.mult})
+    return out
+
+
+def hyper_connection_exit(x, hyper, name=None):
+    """The streams' sum: [N, 1, n*d] -> [N, 1, d]."""
+    helper = LayerHelper("hyper_connection_exit", name=name)
+    out = helper.create_tmp_variable(
+        dtype=dtype_name(x.dtype),
+        shape=list(x.shape[:-1]) + [x.shape[-1] // hyper.mult],
+        stop_gradient=True)
+    helper.append_op(type="hyper_connection_exit", inputs={"X": [x]},
+                     outputs={"Out": [out]}, attrs={"mult": hyper.mult})
+    return out
+
+
 def moe_route(x, w_router, held, top_k, scaling, norm_topk_prob=True,
               live=None, name=None, bias=None, norm_eps=0.0, groups=None):
     """Sigmoid top-k routing over every column of `w_router`; returns the
@@ -1180,11 +1262,12 @@ def moe_train(x, w_router, held, top_k, gate, up, down, counters,
     return out, aux
 
 
-def moe_experts(x, weights, rows, gate, up, down, name=None):
+def moe_experts(x, weights, rows, gate, up, down, name=None, limit=0.0):
     """The held experts' part of a routed layer: sum over them of
     `weights[e] * down_e(silu(gate_e x) * up_e x)`, skipping every expert
     whose `rows[e]` is 0 (fusion/moe.py). `gate` None: an expert is
-    `down_e(relu(up_e x)^2)`."""
+    `down_e(relu(up_e x)^2)`. `limit` > 0: the pair is clamped,
+    `silu(min(gate, limit)) * clip(up, -limit, limit)`."""
     helper = LayerHelper("moe_experts", name=name)
     out = helper.create_tmp_variable(dtype=dtype_name(x.dtype), shape=x.shape,
                                      stop_gradient=True)
@@ -1193,7 +1276,8 @@ def moe_experts(x, weights, rows, gate, up, down, name=None):
     if gate is not None:
         inputs["Gate"] = [gate]
     helper.append_op(type="moe_experts", inputs=inputs,
-                     outputs={"Out": [out]})
+                     outputs={"Out": [out]},
+                     attrs={"limit": float(limit)} if limit else {})
     return out
 
 

@@ -18,7 +18,11 @@ attention on one normed input, summed into one residual, under the family's
 scalar multipliers), `DecoderSpec.kda_latent_moe` the seventh (a kind PER
 LAYER under latent attention: a channel-wise gated delta-rule mixer with a
 MATRIX state a head, or latent attention with no query bottleneck and a gate
-a head; group-limited routing). A spec comes from one of the constructors;
+a head; group-limited routing; with its optional fields the GLM-5-Next
+family's: `hyper` residual streams mixed around every sub-layer, an `indexer`
+that makes the latent read sparse over a second pool of pooled keys, an
+unrotated latent row, low-rank kda gates, clamped gated pairs). A spec comes
+from one of the constructors;
 the fields are what `_decoder_block` reads, not a product to pick from: any
 other combination raises where a graph would have to build it.
 `serving.PagedKVEngine(model=spec)` takes any of them;
@@ -30,12 +34,17 @@ everything else in the package takes the classic one.
 Kinds (each a string, checked by name; nothing is guessed):
 
   norm        "layer_norm" | "rms_norm"
-  residual    "post" (x = norm(x + f(x))) | "pre" (x = x + f(norm(x)))
+  residual    "post" (x = norm(x + f(x))) | "pre" (x = x + f(norm(x))) |
+              "mhc" (`HyperSpec`: `mult` streams X, X = H_res X + H_post^T
+              f(norm(H_pre X)), the three maps made from X, H_res through
+              Sinkhorn; the embedding enters every stream, their sum leaves)
   positions   "sinusoid" (added at the embedding) | "rotary" (inside attention)
               | "none" (the state-space layers carry the order)
   attention   "full" (q/k/v heads over K and V pools; `num_kv_heads` fewer
               key/value heads than query heads, `qk_norm` an RMSNorm a head
-              on q and k) | "latent" (`LatentSpec`)
+              on q and k) | "latent" (`LatentSpec`; with `indexer`
+              (`IndexerSpec`) a row attends the best pooled groups and its
+              tail alone)
   attention_kinds  a kind an (attention) layer, "window" (a query sees the
               last `window` positions, itself among them; rotated by `rope`
               where the spec has one) | "full" (every position; rotated by
@@ -106,14 +115,16 @@ class LatentSpec:
     """Latent attention (MLA): queries through a rank-`q_lora_rank`
     bottleneck (None: ONE query matrix, no bottleneck and no norm); keys and
     values from ONE cached row a token, `kv_lora_rank` normalised values +
-    `rope.dim` rotated ones, shared by all heads. `gate` "head": a head's
-    output times sigmoid of one value a head, `u W_gate` [d_model -> heads],
-    before the output projection; "none": no gate."""
+    `rope.dim` rotated ones, shared by all heads (`rope` None: NO rotation
+    anywhere in the attention, the row is the `kv_lora_rank` values alone).
+    `gate` "head": a head's output times sigmoid of one value a head,
+    `u W_gate` [d_model -> heads], before the output projection; "none": no
+    gate."""
     q_lora_rank: Optional[int]
     kv_lora_rank: int
     qk_nope_head_dim: int
     v_head_dim: int
-    rope: RopeSpec
+    rope: Optional[RopeSpec]
     gate: str = "none"
 
     def __post_init__(self):
@@ -123,8 +134,12 @@ class LatentSpec:
                 "sigmoid gate a head; no element-wise gate is built)")
 
     @property
+    def rope_dim(self) -> int:
+        return 0 if self.rope is None else self.rope.dim
+
+    @property
     def row_values(self) -> int:
-        return self.kv_lora_rank + self.rope.dim
+        return self.kv_lora_rank + self.rope_dim
 
     @property
     def row_lanes(self) -> int:
@@ -138,11 +153,71 @@ class LatentSpec:
 
     @property
     def qk_head_dim(self) -> int:
-        return self.qk_nope_head_dim + self.rope.dim
+        return self.qk_nope_head_dim + self.rope_dim
 
     @property
     def softmax_scale(self) -> float:
-        return self.qk_head_dim ** -0.5 * self.rope.softmax_mscale ** 2
+        mscale = 1.0 if self.rope is None else self.rope.softmax_mscale
+        return self.qk_head_dim ** -0.5 * mscale ** 2
+
+
+@dataclasses.dataclass(frozen=True)
+class IndexerSpec:
+    """The selector of a sparse latent read (DeepSeek-V3.2's lightning
+    indexer over POOLED keys; fusion/sparse_latent_attention.py has the
+    equations): `heads` index heads of `head_dim` values whose first
+    `rope.dim` are rotated at the token's own position; a key a position,
+    mean-pooled over groups of `kpool` consecutive positions, ONE pooled row
+    a group in a second pool beside the latent rows; a query attends the
+    `topk // kpool` best whole groups and the tail (the positions of its own
+    unfinished group). `share` "full": every sparse layer computes its own
+    index (an index shared from another layer is not built)."""
+    heads: int
+    head_dim: int
+    topk: int
+    kpool: int
+    rope: RopeSpec
+    share: str = "full"
+
+    def __post_init__(self):
+        if self.share != "full":
+            raise NotImplementedError(
+                f"IndexerSpec.share {self.share!r}: an indexer that shares "
+                "another layer's index is not built; every sparse layer "
+                "computes its own ('full')")
+        if self.kpool < 1 or self.topk % self.kpool:
+            raise ValueError(f"index_topk {self.topk} counts positions: a "
+                             f"whole number of groups of {self.kpool}")
+        if self.rope.dim > self.head_dim or self.rope.factor != 1.0:
+            raise ValueError("the indexer rotates the first `rope.dim` of "
+                             "its `head_dim` values, unstretched")
+
+    @property
+    def top_groups(self) -> int:
+        return self.topk // self.kpool
+
+
+@dataclasses.dataclass(frozen=True)
+class HyperSpec:
+    """Manifold-constrained hyper-connections (mHC, arXiv:2512.24880): the
+    residual is `mult` streams of `d_model` values, mixed around every
+    sub-layer by three maps computed from the streams themselves
+    (fusion/hyper_connection.py has the equations); the stream-to-stream map
+    is made doubly stochastic by `sinkhorn_iters` rounds of row and column
+    division with `eps` inside both."""
+    mult: int = 4
+    sinkhorn_iters: int = 20
+    eps: float = 1e-6
+
+    def __post_init__(self):
+        if self.mult < 2 or self.sinkhorn_iters < 1:
+            raise ValueError("HyperSpec: at least two streams and one "
+                             "Sinkhorn round (one stream is residual='pre')")
+
+    @property
+    def maps(self) -> int:
+        """Values the three maps hold: H_pre, H_post [n], H_res [n, n]."""
+        return 2 * self.mult + self.mult ** 2
 
 
 @dataclasses.dataclass(frozen=True)
@@ -211,11 +286,15 @@ class KdaSpec:
     KEY CHANNEL in (`gate_lower_bound`, 0) and corrected by what it already
     holds; a causal depthwise convolution of `taps` taps (no bias, SiLU) over
     q, k and v. What a request carries from token to token is `S` and the
-    last `taps - 1` rows of the convolution's input."""
+    last `taps - 1` rows of the convolution's input. `gate_rank` > 0: the
+    decay gate's `f` and the output gate are low-rank PAIRS
+    [d_model -> gate_rank -> d_inner] (Kimi Linear's own layer); 0: one full
+    matrix each."""
     heads: int
     head_dim: int
     taps: int = 4
     gate_lower_bound: float = -5.0
+    gate_rank: int = 0
 
     def __post_init__(self):
         if not self.gate_lower_bound < 0:
@@ -296,7 +375,9 @@ class MoESpec:
     (a training graph; the serving ticks route by "sigmoid" alone);
     `aux_coef`: what a training loss adds of each routed layer's balance
     term `n_routed * sum_e f_e P_e` (f_e the share of the assignments that
-    chose e, P_e the mean score of e, over ALL experts)."""
+    chose e, P_e the mean score of e, over ALL experts). `swiglu_limit` > 0
+    clamps every gated pair of the block (routed, shared, and the dense
+    layers'): `silu(min(gate, limit)) * clip(up, -limit, limit)`."""
     n_routed: int
     top_k: int
     d_expert: int
@@ -314,6 +395,7 @@ class MoESpec:
     aux_coef: float = 0.0
     n_group: int = 1
     topk_group: int = 1
+    swiglu_limit: float = 0.0
 
     @property
     def shared_width(self) -> int:
@@ -333,6 +415,11 @@ class MoESpec:
             raise NotImplementedError(
                 f"scoring_func {self.scoring!r}: the router implements "
                 f"{SCORING}")
+        if self.swiglu_limit < 0 or (self.swiglu_limit and (
+                self.activation != "gated_silu" or self.scoring != "sigmoid")):
+            raise NotImplementedError(
+                f"swiglu_limit {self.swiglu_limit}: a clamp of the gated "
+                "SiLU pair in a serving tick (>= 0; 0 = off)")
         if not self.held or sorted(set(self.held)) != list(self.held) or \
                 not 0 <= self.held[0] <= self.held[-1] < self.n_routed:
             raise ValueError(f"held experts {self.held!r} must be distinct, "
@@ -394,10 +481,12 @@ class DecoderSpec:
     mixer: str = "kind"                     # | "ssm+attention": both, summed
     multipliers: Multipliers = Multipliers()
     kda: Optional[KdaSpec] = None
+    indexer: Optional[IndexerSpec] = None   # the latent layers read sparsely
+    hyper: Optional[HyperSpec] = None       # residual="mhc": its streams
 
     def __post_init__(self):
         for field, kinds in (("norm", ("layer_norm", "rms_norm")),
-                             ("residual", ("post", "pre")),
+                             ("residual", ("post", "pre", "mhc")),
                              ("positions", ("sinusoid", "rotary", "none")),
                              ("attention", ("full", "latent")),
                              ("ffn", ("relu", "gated_silu")),
@@ -409,9 +498,31 @@ class DecoderSpec:
         if (self.attention == "latent") != (self.latent is not None):
             raise ValueError("attention='latent' comes with a LatentSpec, "
                              "and only it")
-        if self.attention == "latent" and self.positions != "rotary":
-            raise ValueError("latent attention rotates part of its row: "
-                             "positions='rotary'")
+        if self.attention == "latent" and (
+                (self.latent.rope is None and self.indexer is None)
+                != (self.positions == "none")
+                or self.positions == "sinusoid"):
+            raise ValueError(
+                "latent attention rotates part of its row, or its indexer's "
+                "keys: positions='rotary'; with neither (`LatentSpec.rope` "
+                "None and no IndexerSpec), positions='none'")
+        if self.indexer is not None and (
+                self.latent is None or self.latent.q_lora_rank is None):
+            raise ValueError(
+                "an IndexerSpec selects the rows a LatentSpec's read attends "
+                "and takes its queries from the latent query bottleneck "
+                "(`q_lora_rank`): it stands beside both, and only them")
+        if (self.residual == "mhc") != (self.hyper is not None):
+            raise ValueError("residual='mhc' comes with a HyperSpec "
+                             "(`hyper`), and only it")
+        if self.hyper is not None and (
+                self.norm != "rms_norm" or self.one_sublayer
+                or self.mixer != "kind" or self.dropout):
+            raise NotImplementedError(
+                "residual='mhc' (hc_mult streams) mixes around the sub-layers "
+                "of the pre-norm RMSNorm block: a post-norm block, a "
+                "one-sublayer layer or the 'ssm+attention' mixer carries one "
+                "stream")
         if self.attention == "full" and \
                 (self.positions == "rotary") != (self.rope is not None):
             raise ValueError("rotary positions with full heads come with a "
@@ -458,7 +569,7 @@ class DecoderSpec:
                              "it")
         if self.kda is not None and (
                 self.kv_heads != self.num_heads or self.qk_norm
-                or self.residual != "pre" or self.norm != "rms_norm"
+                or self.residual == "post" or self.norm != "rms_norm"
                 or self.ffn != "gated_silu" or self.tied_head):
             raise ValueError(
                 "a 'kda' layer stands beside latent attention (no grouped "
@@ -586,7 +697,9 @@ class DecoderSpec:
     def kda_latent_moe(cls, vocab, d_model, d_inner, num_heads, layer_kinds,
                        kda: KdaSpec, latent: LatentSpec,
                        moe: Optional[MoESpec] = None, norm_eps=1e-6,
-                       dtype="bfloat16"):
+                       dtype="bfloat16",
+                       indexer: Optional[IndexerSpec] = None,
+                       hyper: Optional[HyperSpec] = None):
         """The Ling-3 / Kimi-Linear family's block: pre-norm RMSNorm
         residuals; a layer's mixer by `layer_kinds`, the channel-wise gated
         delta-rule mixer ("kda": a matrix state a head in float32, q, k and
@@ -595,12 +708,22 @@ class DecoderSpec:
         head; ONE cached row a position in those layers alone); a gated SiLU
         pair, routed experts beside the shared one from `moe.first_dense`
         on (group-limited: `topk_method` "group_bias"); a final norm and an
-        untied head."""
+        untied head. With the optional fields, the GLM-5-Next family's: an
+        `indexer` makes the latent layers' read SPARSE (the best pooled
+        groups and the tail; `latent.rope` None: nothing in the attention is
+        rotated but the indexer's keys), `hyper` carries `hyper.mult`
+        residual streams between the sub-layers (residual "mhc"),
+        `kda.gate_rank` the gates' low-rank pairs, `moe.swiglu_limit` the
+        clamp on every gated pair."""
+        rotary = latent.rope is not None or indexer is not None
         return cls(vocab, d_model, d_inner, num_heads, len(layer_kinds),
-                   norm="rms_norm", norm_eps=norm_eps, residual="pre",
-                   positions="rotary", attention="latent", ffn="gated_silu",
+                   norm="rms_norm", norm_eps=norm_eps,
+                   residual="pre" if hyper is None else "mhc",
+                   positions="rotary" if rotary else "none",
+                   attention="latent", ffn="gated_silu",
                    dtype=dtype, latent=latent, moe=moe,
-                   layer_kinds=tuple(layer_kinds), kda=kda)
+                   layer_kinds=tuple(layer_kinds), kda=kda, indexer=indexer,
+                   hyper=hyper)
 
     @property
     def is_classic(self) -> bool:
@@ -711,10 +834,18 @@ class DecoderSpec:
         heads (a window layer's rows are the window pool's:
         `window_row_bytes`)."""
         if self.attention == "latent":
-            return (len(self.attention_layers) * self.latent.row_lanes
-                    * self.itemsize)
+            row = self.latent.row_lanes * self.itemsize
+            if self.indexer is not None:    # a pooled key a group, beside it
+                row += self.index_row_bytes()
+            return len(self.attention_layers) * row
         return (len(self.full_layers) * 2 * self.kv_heads * self.d_head
                 * self.itemsize)
+
+    def index_row_bytes(self) -> int:
+        """Bytes ONE position holds of ONE sparse layer's pooled index keys
+        (a row of `head_dim` values a group of `kpool` positions)."""
+        ix = self.indexer
+        return ix.head_dim * self.itemsize // ix.kpool
 
     def window_row_bytes(self) -> int:
         """Bytes ONE position holds in the window pool, over the window

@@ -229,9 +229,12 @@ class _TickRows:
         self.positions, self.live, self.conv = positions, live, conv
         self.ssm, self.training = ssm, training
         self.expert_rows, self.aux = [], []
-        self.table, self.tables = None, {}
-        if spec.positions == "rotary":
-            rope = spec.latent.rope if spec.latent else spec.rope
+        self.table, self.tables, self.index_table = None, {}, None
+        if spec.indexer is not None:    # the indexer's own rotation
+            self.index_table = layers.assign(
+                rotary_table(spec.indexer.rope, max_len))
+        rope = spec.latent.rope if spec.latent else spec.rope
+        if spec.positions == "rotary" and rope is not None:
             self.table = layers.assign(rotary_table(rope, max_len))
             self.tables[rope] = self.table
             if spec.rope_full is not None and spec.rope_full not in self.tables:
@@ -279,10 +282,14 @@ def _scaled(x, by):
     return x if by == 1.0 else layers.scale(x, scale=float(by))
 
 
-def _gated_ffn(x, d_model, d_inner, name, by=(1.0, 1.0)):
+def _gated_ffn(x, d_model, d_inner, name, by=(1.0, 1.0), limit=0.0):
     """down(silu(gate x) * up x), no biases; `by` (`Multipliers.mlp`) scales
-    the gate before the SiLU and the output."""
+    the gate before the SiLU and the output; `limit` > 0 (`swiglu_limit`)
+    clamps the pair: gate <- min(gate, limit), up <- clip(up, +-limit)."""
     gate, up = (_proj(x, d_inner, f"{name}_{n}") for n in ("gate", "up"))
+    if limit:
+        gate = layers.clip(gate, -3e38, float(limit))
+        up = layers.clip(up, -float(limit), float(limit))
     return _scaled(
         _proj(layers.elementwise_mul(layers.silu(_scaled(gate, by[0])), up),
               d_model, name + "_down"), by[1])
@@ -319,13 +326,15 @@ def _moe_ffn(x, spec, name, rows):
     rows.expert_rows.append(n_rows)
     z = _proj(x, dz, name + "_latent_down") if moe.latent else x
     routed = layers.moe_experts(z, weights, n_rows, stack.get("gate"),
-                                stack["up"], stack["down"])
+                                stack["up"], stack["down"],
+                                limit=moe.swiglu_limit)
     if moe.latent:
         routed = _proj(routed, d, name + "_latent_up")
     if not moe.n_shared:
         return routed
     if gated:
-        shared = _gated_ffn(x, d, moe.shared_width, name + "_shared")
+        shared = _gated_ffn(x, d, moe.shared_width, name + "_shared",
+                            limit=moe.swiglu_limit)
     else:
         shared = _proj(layers.square(layers.relu(
             _proj(x, moe.shared_width, name + "_shared_up"))), d,
@@ -368,11 +377,15 @@ def _latent_attention(x, spec, name, attend, rows):
     (normalised `c_kv`, rotated `k_pe`); the key half of `kv_b` absorbed
     into the query, so that the cache is read as it is stored, and the
     value half applied to what the read returns (fusion/latent_attention.py).
-    A query row and a cache row are padded alike to `row_lanes`."""
+    A query row and a cache row are padded alike to `row_lanes`. Without a
+    `lat.rope` nothing is rotated and the row is `c_kv` alone. With
+    `spec.indexer` the read is SPARSE and `attend` also takes the row's index
+    inputs (`_index_inputs`)."""
     lat, nh = spec.latent, spec.num_heads
     n = x.shape[0]
-    dn, dr, c = lat.qk_nope_head_dim, lat.rope.dim, lat.kv_lora_rank
+    dn, dr, c = lat.qk_nope_head_dim, lat.rope_dim, lat.kv_lora_rank
     pad = lat.row_lanes - lat.row_values
+    c_q = None
     if lat.q_lora_rank is None:
         q = _proj(x, nh * (dn + dr), name + "_q")
     else:
@@ -381,38 +394,72 @@ def _latent_attention(x, spec, name, attend, rows):
             param_attr=ParamAttr(name=name + "_qa_norm.scale"))
         q = _proj(c_q, nh * (dn + dr), name + "_qb")
     q = layers.reshape(q, shape=[n, nh, dn + dr])
-    q_nope = layers.reshape(
-        layers.slice(q, axes=[2], starts=[0], ends=[dn]), shape=[n, nh * dn])
-    q_pe = layers.rotary(
-        layers.reshape(layers.slice(q, axes=[2], starts=[dn], ends=[dn + dr]),
-                       shape=[n, nh * dr]), rows.positions, rows.table)
+    if dr:
+        q_nope = layers.reshape(
+            layers.slice(q, axes=[2], starts=[0], ends=[dn]),
+            shape=[n, nh * dn])
+        q_pe = layers.rotary(
+            layers.reshape(
+                layers.slice(q, axes=[2], starts=[dn], ends=[dn + dr]),
+                shape=[n, nh * dr]), rows.positions, rows.table)
+    else:
+        q_nope = layers.reshape(q, shape=[n, nh * dn])
     kv = layers.reshape(_proj(x, c + dr, name + "_kva"), shape=[n, c + dr])
     c_kv = layers.rms_norm(
-        layers.slice(kv, axes=[1], starts=[0], ends=[c]),
+        layers.slice(kv, axes=[1], starts=[0], ends=[c]) if dr else kv,
         epsilon=spec.norm_eps,
         param_attr=ParamAttr(name=name + "_kva_norm.scale"))
-    k_pe = layers.rotary(layers.slice(kv, axes=[1], starts=[c], ends=[c + dr]),
-                         rows.positions, rows.table)
+    if dr:
+        k_pe = layers.rotary(
+            layers.slice(kv, axes=[1], starts=[c], ends=[c + dr]),
+            rows.positions, rows.table)
     kv_b = _param(name + "_kvb.w_0", [c, nh * (dn + lat.v_head_dim)],
                   spec.dtype)
     q_lat = layers.latent_head_proj(q_nope, kv_b, "absorb_q", nh, dn,
                                     lat.v_head_dim)
-    q_parts = [layers.reshape(q_lat, shape=[n, nh, c]),
-               layers.reshape(q_pe, shape=[n, nh, dr])]
-    row_parts = [c_kv, k_pe]
+    q_parts, row_parts = [layers.reshape(q_lat, shape=[n, nh, c])], [c_kv]
+    if dr:
+        q_parts.append(layers.reshape(q_pe, shape=[n, nh, dr]))
+        row_parts.append(k_pe)
     if pad:
         q_parts.append(layers.fill_constant([n, nh, pad], spec.dtype, 0.0))
         row_parts.append(layers.fill_constant([n, pad], spec.dtype, 0.0))
+
+    def joined(parts, axis):
+        return parts[0] if len(parts) == 1 else layers.concat(parts,
+                                                              axis=axis)
+    index = () if spec.indexer is None else (
+        _index_inputs(x, c_q, spec, name, rows),)
     ctx = attend(
-        layers.reshape(layers.concat(q_parts, axis=2),
-                       shape=[n, 1, nh * lat.row_lanes]),
-        layers.reshape(layers.concat(row_parts, axis=1),
-                       shape=[n, 1, lat.row_lanes]))          # [n,1,nh*c]
+        layers.reshape(joined(q_parts, 2), shape=[n, 1, nh * lat.row_lanes]),
+        layers.reshape(joined(row_parts, 1), shape=[n, 1, lat.row_lanes]),
+        *index)                                               # [n,1,nh*c]
     out = layers.latent_head_proj(ctx, kv_b, "expand_v", nh, dn,
                                   lat.v_head_dim)
     if lat.gate == "head":
         out = layers.head_gate(out, _proj(x, nh, name + "_gate"), nh)
     return _proj(out, spec.d_model, name + "_o")
+
+
+def _index_inputs(x, c_q, spec, name, rows):
+    """What a sparse latent read needs of the rows beside their queries
+    (`IndexerSpec`; fusion/sparse_latent_attention.py): the index queries
+    from the query bottleneck `c_q` (`_iq`), the index key (`_ik`, then a
+    LayerNorm with scale and bias), the heads' weights (`_iw`, scaled by
+    heads^-1/2 dim^-1/2), the rows' positions and the indexer's rotary
+    table. The rotation, the pooling and the selection are the read's."""
+    ix = spec.indexer
+    ki = layers.layer_norm(
+        _proj(x, ix.head_dim, name + "_ik"), begin_norm_axis=2,
+        epsilon=spec.norm_eps,
+        param_attr=ParamAttr(name=name + "_ik_norm.scale"),
+        bias_attr=ParamAttr(name=name + "_ik_norm.bias"))
+    wi = layers.scale(
+        layers.fc(x, size=ix.heads, num_flatten_dims=2, bias_attr=False,
+                  use_bf16=True, name=name + "_iw", out_dtype="float32"),
+        scale=float(ix.heads) ** -0.5 * float(ix.head_dim) ** -0.5)
+    return dict(qi=_proj(c_q, ix.heads * ix.head_dim, name + "_iq"), ki=ki,
+                wi=wi, positions=rows.positions, table=rows.index_table)
 
 
 def _grouped_attention(x, spec, name, attend, rows, table=None):
@@ -497,15 +544,22 @@ def _kda_mixer(x, spec, name, rows):
     request's state (`rows.ssm`, fusion/kda.py); an RMSNorm a head times
     sigmoid(x W_g); `W_o`; no bias anywhere, no rotation."""
     kda = spec.kda
+
+    def gate_proj(which):
+        """One full matrix, or the low-rank pair `_{which}a`, `_{which}b`."""
+        if not kda.gate_rank:
+            return _proj(x, kda.d_inner, f"{name}_{which}")
+        return _proj(_proj(x, kda.gate_rank, f"{name}_{which}a"),
+                     kda.d_inner, f"{name}_{which}b")
     qkv = _proj(x, kda.conv_dim, name + "_qkv")
-    f = _proj(x, kda.d_inner, name + "_f")
+    f = gate_proj("f")
     b = _proj(x, kda.heads, name + "_b")
     params = dict(
         taps=_param(name + "_taps", [kda.conv_dim, kda.taps], spec.dtype),
         a_log=_param(name + "_a_log", [kda.heads], "float32"),
         dt_bias=_param(name + "_dt_bias", [kda.d_inner], "float32"))
     o = rows.ssm.layer(qkv, (f, b), params, rows.live)
-    o = layers.kda_gate_norm(o, _proj(x, kda.d_inner, name + "_g"), kda.heads,
+    o = layers.kda_gate_norm(o, gate_proj("g"), kda.heads,
                              epsilon=spec.norm_eps,
                              param_attr=ParamAttr(name=name + "_norm.scale"))
     return _proj(o, spec.d_model, name + "_o")
@@ -581,9 +635,9 @@ def _decoder_block(x, i, attend, d_model, d_inner, dropout, is_test=True,
     if kind == "moe":
         sublayers.append(lambda x: _moe_ffn(x, spec, f"{name}_moe", rows))
     elif kind == "gated_silu":
-        sublayers.append(lambda x: _gated_ffn(x, d_model, d_inner,
-                                              f"{name}_ffn",
-                                              spec.multipliers.mlp))
+        sublayers.append(lambda x: _gated_ffn(
+            x, d_model, d_inner, f"{name}_ffn", spec.multipliers.mlp,
+            limit=spec.moe.swiglu_limit if spec.moe else 0.0))
     else:
         sublayers.append(lambda x: ffn(x, d_model, d_inner, dropout, is_test,
                                        name=f"{name}_ffn"))
@@ -591,6 +645,21 @@ def _decoder_block(x, i, attend, d_model, d_inner, dropout, is_test=True,
         for n, sublayer in enumerate(sublayers, 1):
             x = layers.elementwise_add(
                 x, sublayer(_pre_norm(x, spec, f"{name}_ln{n}")))
+        return x
+    if spec.residual == "mhc":
+        # x holds `hyper.mult` streams side by side; a sub-layer sees their
+        # mix under its own pre-norm, and its output goes back to all of
+        # them (fusion/hyper_connection.py)
+        hc, width = spec.hyper, spec.hyper.mult * d_model
+        for n, sublayer in enumerate(sublayers, 1):
+            u, h_post, h_res = layers.hyper_connection_pre(
+                x, _param(f"{name}_hc{n}_p", [width, hc.maps], spec.dtype),
+                _param(f"{name}_hc{n}_a", [3], "float32"),
+                _param(f"{name}_hc{n}_b", [hc.maps], "float32"), hc,
+                spec.norm_eps)
+            x = layers.hyper_connection_post(
+                x, sublayer(_pre_norm(u, spec, f"{name}_ln{n}")), h_post,
+                h_res, hc)
         return x
     if spec.norm != "layer_norm":
         raise NotImplementedError(
@@ -604,10 +673,15 @@ def _lm_decoder(x, attend, num_layers, d_model, d_inner, dropout,
                 is_test=True, param_prefix="", spec=None, rows=None):
     """The LM's stack of `_decoder_block`s, weights `{param_prefix}l{i}_*`;
     a pre-norm stack ends in its final norm (`{param_prefix}final_norm`)."""
+    hyper = spec.hyper if spec is not None else None
+    if hyper is not None:       # the embedding in every stream
+        x = layers.concat([x] * hyper.mult, axis=2)
     for i in range(num_layers):
         x = _decoder_block(x, i, attend, d_model, d_inner, dropout, is_test,
                            prefix=f"{param_prefix}l", spec=spec, rows=rows)
-    if spec is not None and spec.residual == "pre":
+    if hyper is not None:       # ... and their sum out
+        x = layers.hyper_connection_exit(x, hyper)
+    if spec is not None and spec.residual != "post":
         x = _pre_norm(x, spec, f"{param_prefix}final_norm")
     return x
 
@@ -1432,19 +1506,44 @@ class _LatentPagedCache:
         self.pools = {i: _slot_cache_var(
             f"{cache_prefix}_c{i}", [n_blocks, 1, block_size, lat.row_lanes],
             dtype=spec.dtype) for i in spec.attention_layers}
-        self.names = [v.name for v in self.pools.values()]
+        # ... and with an indexer a SECOND pool beside it, a pooled index
+        # key a group of `kpool` positions, under the same table
+        self.indexer, self.index_pools = spec.indexer, {}
+        if spec.indexer is not None:
+            ix = spec.indexer
+            if block_size % ix.kpool or (lanes and lanes["chunk"] % ix.kpool):
+                raise ValueError(
+                    f"a block of {block_size} positions (and a lane's chunk) "
+                    f"holds whole groups of index_kpool {ix.kpool}")
+            self.index_pools = {i: _slot_cache_var(
+                f"{cache_prefix}_ci{i}",
+                [n_blocks, 1, block_size // ix.kpool, ix.head_dim],
+                dtype=spec.dtype) for i in spec.attention_layers}
+        self.names = [v.name for v in (*self.pools.values(),
+                                       *self.index_pools.values())]
 
     def _read(self, q, pool, btab, pos, n_rows=None):
         return layers.latent_paged_attention(
             q, pool, btab, pos, self.num_heads, self.lat.kv_lora_rank,
             self.lat.softmax_scale, n_rows=n_rows)
 
-    def attend(self, i, q, row):
+    def _sparse_read(self, i, q, pool, index):
+        """The read over the rows the indexer selects, decode rows and lane
+        rows as ONE batch (the selection is a row's own)."""
+        return layers.sparse_latent_attention(
+            q, pool, self.index_pools[i], index["qi"], index["ki"],
+            index["wi"], index["positions"], index["table"], self.btab,
+            self.wblock, self.woff, self.num_heads, self.lat.kv_lora_rank,
+            self.lat.softmax_scale, self.indexer, lanes=self.lanes)
+
+    def attend(self, i, q, row, index=None):
         pool, w = self.pools[i], self.lat.row_lanes
         if self.lanes is None:
             pool = layers.paged_cache_write(
                 pool, layers.reshape(row, shape=[-1, 1, w]), self.wblock,
                 self.woff, out=pool)
+            if index is not None:
+                return self._sparse_read(i, q, pool, index)
             return self._read(q, pool, self.btab, self.pos)
         ln = self.lanes
         split = functools.partial(_split_rows, S=ln["n_slots"],
@@ -1453,6 +1552,8 @@ class _LatentPagedCache:
         pool = layers.paged_cache_write(
             pool, layers.reshape(rd, shape=[-1, 1, w]), self.wblock,
             self.woff, out=pool, chunk=rl, chunk_block_ids=ln["lwblocks"])
+        if index is not None:
+            return self._sparse_read(i, q, pool, index)
         ctx_d = self._read(qd, pool, self.btab, self.pos)
         ctx_l = self._read(ql, pool, ln["lbtab"], ln["lpos"], ln["lrows"])
         return layers.concat(
@@ -1584,7 +1685,7 @@ def _kinds_paged_tick(model, n_slots, n_blocks, block_size, blocks_per_req,
     bias, the embedding itself where the spec ties it.
     Returns (next_ids followed by the routed layers' counts, the K/V
     pools' names)."""
-    if model.residual != "pre" or model.positions == "sinusoid":
+    if model.residual == "post" or model.positions == "sinusoid":
         raise NotImplementedError(
             "the paged ticks build the classic spec, latent attention or "
             "rotary grouped attention beside short convolutions, each in a "

@@ -418,6 +418,11 @@ class KVPager:
         self._snap_used = [0] * self.n_snapshots     # LRU clock an entry
         self._snap_pins = [0] * self.n_snapshots     # admitted, not yet read
         self._snap_clock = 0
+        # what the eviction order reads beside the clock (`_snapshot_rank`):
+        # the clock of an entry's last RESTORE (0: none yet) and the logical
+        # block its state stands behind
+        self._snap_restored = [0] * self.n_snapshots
+        self._snap_depth = [0] * self.n_snapshots
         self.snapshot_evictions = 0     # valid entries taken for another
         self.hits_truncated = 0         # hits cut to a shallower snapshot
         #: ... or, where some layers attend a sliding WINDOW of `window`
@@ -566,6 +571,7 @@ class KVPager:
             table.snapshot = entry
             if self._snap_node[entry] is not None:   # else: evicted just now
                 self._touch_snapshot(entry)
+                self._snap_restored[entry] = self._snap_clock
             self.state_restores += 1
         return table
 
@@ -606,7 +612,7 @@ class KVPager:
         free = [e for e in range(self.n_snapshots) if not self._snap_pins[e]]
         if not free:
             return None
-        entry = min(free, key=self._snap_used.__getitem__)
+        entry = min(free, key=self._snapshot_rank)
         node = self._snap_node[entry]
         if node is not None:
             node.snap = None
@@ -615,6 +621,31 @@ class KVPager:
         self._snap_pins[entry] += 1
         table.snapshot_write = (entry, logical_block)
         return entry
+
+    #: an entry counts as PROVEN while its last restore lies within this many
+    #: pool-fuls of the snapshot clock (a tick of it a write or a restore)
+    PROVEN_FOR = 16
+
+    def _snapshot_rank(self, entry: int):
+        """What `take_snapshot_entry` evicts first (the least): an entry
+        that holds nothing; then an entry no request has restored from
+        lately (the DEEPEST first: a state behind a request's own turn serves
+        that one prompt, the state behind a shorter prefix of it every prompt
+        that starts so; the least recently used among equals); a PROVEN entry
+        (restored from within `PROVEN_FOR` pool-fuls of writes and restores)
+        only when nothing else is left, the least recently used first. Plain
+        LRU let the one-off snapshot every request writes at its own prompt's
+        end push out a shared context's that was not asked for for a pool-ful
+        of requests, and a context whose snapshot is gone is prefilled WHOLE
+        by every later request that starts from it (the hit is cut to the
+        deepest node that holds a snapshot, and a request writes one at its
+        own end alone): PERF.md section 6, PR 61."""
+        if self._snap_node[entry] is None:
+            return (0, 0, self._snap_used[entry])
+        last = self._snap_restored[entry]
+        if last and self._snap_clock - last <= self.PROVEN_FOR * self.n_snapshots:
+            return (2, 0, self._snap_used[entry])
+        return (1, -self._snap_depth[entry], self._snap_used[entry])
 
     def snapshot_written(self, table: BlockTable, prompt: Sequence[int]):
         """The tick that wrote `table.snapshot_write` is done: hand the
@@ -632,6 +663,8 @@ class KVPager:
             return
         node.snap = entry
         self._snap_node[entry] = node
+        self._snap_restored[entry] = 0
+        self._snap_depth[entry] = logical_block
         self._touch_snapshot(entry)
 
     # -- the window pool --------------------------------------------------
@@ -1124,6 +1157,9 @@ class PagedKVEngine(ContinuousBatchingEngine):
                         + (" beside state-space layers" if model.ssm else "")
                         + (" beside gated delta-rule (kda) layers"
                            if model.kda else "")
+                        + (" read sparsely through an indexer's pool (a "
+                           "draft would need the target's index)"
+                           if model.indexer else "")
                         + (" beside sliding-window layers"
                            if model.window else "")
                         + (" and routed experts" if model.moe else "")
@@ -1426,6 +1462,13 @@ class PagedKVEngine(ContinuousBatchingEngine):
                 win_rows += min(req.fed + 1, window)
         self._tick_attrs["kv_blocks"] = kv_blocks
         self._tick_attrs["decode_rows"] = kv_rows
+        if self.model.indexer is not None:
+            # the sparse read's rows (the lanes add theirs): what they hold
+            # and what the selection lets them attend, by arithmetic on the
+            # positions (fusion/sparse_latent_attention.py `select`)
+            at = np.asarray([r.fed for r in active.values()
+                             if not self._prefilling(r)], np.int64)
+            self._tick_attrs.update(self._sparse_counts(at))
         if self.n_snapshots:
             # the live decode rows, whose state-space state the tick reads
             # and writes (a slot in prefill moves its state in a lane)
@@ -1437,6 +1480,23 @@ class PagedKVEngine(ContinuousBatchingEngine):
             self._tick_attrs["window_rows"] = win_rows
         if self._mixed_step is not None:
             self._fill_lanes(prefilling)
+
+    def _sparse_counts(self, positions: np.ndarray) -> Dict[str, int]:
+        """`engine/tick`'s counts of the sparse latent read over rows at
+        `positions`: `dsa_rows`, the positions they hold
+        (`dsa_live_positions`), the positions the selection attends (the
+        best `top_groups` whole groups and the tail:
+        `dsa_selected_positions`) and the pooled rows the tick writes into
+        the index pool (`index_pool_rows`: a row a decode row, a row a group
+        a lane touches), each over the spec's sparse layers."""
+        ix, n = self.model.indexer, len(self.model.attention_layers)
+        held = positions + 1
+        picked = np.minimum(held // ix.kpool, ix.top_groups) * ix.kpool \
+            + held % ix.kpool
+        return {"dsa_rows": n * len(positions),
+                "dsa_live_positions": n * int(held.sum()),
+                "dsa_selected_positions": n * int(picked.sum()),
+                "index_pool_rows": n * len(positions)}
 
     def _fill_lanes(self, prefilling: List[GenRequest]):
         """Give the tick's lanes to the slots in prefill, in admission
@@ -1495,6 +1555,15 @@ class PagedKVEngine(ContinuousBatchingEngine):
                 tokens += n
                 lane_blocks += b0 + nb
         self._lanes = lanes
+        if self.model.indexer is not None and lanes:
+            kpool = self.model.indexer.kpool
+            more = self._sparse_counts(np.concatenate(
+                [np.arange(req.fed, req.fed + n) for req, n in lanes]))
+            # a lane writes a pooled row a group it touches, not a row a row
+            more["index_pool_rows"] = len(self.model.attention_layers) * sum(
+                -(-n // kpool) for _, n in lanes)
+            for key, value in more.items():
+                attrs[key] += value
         attrs["kv_blocks"] += lane_blocks
         attrs["prefill"] = len(lanes)
         attrs["prefill_tokens"] = tokens

@@ -135,6 +135,24 @@ def test_serve_kda_phase():
     assert out["block_bytes"] == 1 * 128 * 2 * 8    # ONE latent layer's rows
 
 
+def test_serve_dsa_phase():
+    """The smoke's sparse engine (ISSUE 61: four mixed residual streams, kda
+    layers with low-rank gate pairs, one sparse NoPE latent layer with its
+    index pool, clamped pairs) at a tiny size: prefix-hit requests equal
+    their self-prefilled twins, and do not once the pooled keys are zeroed."""
+    out = chip_smoke.phase_serve_dsa(
+        vocab=97, d_model=64, d_inner=96, num_heads=4, head_dim=16,
+        q_lora_rank=24, kv_lora_rank=32, latent_head_dim=16,
+        index=(4, 16, 8, 4, 8), d_expert=32, n_routed=16, n_held=4, top_k=3,
+        n_slots=4, block_size=8, n_blocks=40, n_snapshots=4, max_len=64,
+        preamble=24, turns=(5, 11), max_new=12, expect_lowering="composite")
+    assert out["ssm_state"]["restores"] == 2 and out["tokens_out"] == 24
+    assert out["ssm_state"]["layers"] == 2
+    assert out["zeroed_index_differs_at"] < 12   # the planted fault is refused
+    # ONE sparse layer's rows: c alone and a pooled key a group of 4
+    assert out["block_bytes"] == (128 * 2 + 16 * 2 // 4) * 8
+
+
 def test_train_resnet_phase():
     out = chip_smoke.phase_train_resnet50(batch=2, steps=2, depth=18,
                                           image=32)

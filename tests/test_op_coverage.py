@@ -1684,6 +1684,10 @@ COVERED_ELSEWHERE = {
     "kda_scan": "tests/test_ling_engine.py",
     "kda_gate_norm": "tests/test_kda.py",
     "head_gate": "tests/test_kda.py",
+    "sparse_latent_attention": "tests/test_glm_engine.py",
+    "hyper_connection_pre": "tests/test_glm_engine.py",
+    "hyper_connection_post": "tests/test_glm_engine.py",
+    "hyper_connection_exit": "tests/test_glm_engine.py",
     "moe_experts": "tests/test_routed_experts.py",
     # the routed layer of a training graph: values and every gradient
     # against the plain reference, the ranks' shares, no dropped row

@@ -839,3 +839,71 @@ def test_kda_scan_compiles_for_v5e_at_the_lanes_shape(one_chip):
     args = [S(a.shape, a.dtype, sharding=one_chip) for a in args]
     compiled = jax.jit(f, donate_argnums=(6, 7, 9, 10)).lower(*args).compile()
     assert compiled.as_text().count("tpu_custom_call") == 1
+
+
+def test_kda_decode_update_compiles_for_v5e_at_64_heads(one_chip):
+    """ISSUE 61: 64 slots of 64 heads x 128 x 128 float32, 4.19 MB a slot
+    and grid step, twice the reasoning cell's."""
+    f, args = _kda_update(slots=64, h=64)
+    args = [S(a.shape, a.dtype, sharding=one_chip) for a in args]
+    compiled = jax.jit(f, donate_argnums=0).lower(*args).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    assert "f32[64,64,128]" in compiled.as_text()
+
+
+def _sparse_read(slots, lanes, chunk=128, n_blocks=1024, nlb=552, bs=64,
+                 nh=64, c=512, ni=32, di=128, top=512, kpool=4):
+    from paddle_tpu.fusion import sparse_latent_attention as sla
+    n = slots + lanes * chunk
+    args = [S((n, 1, nh * c), BF16), S((n_blocks, 1, bs, c), BF16),
+            S((n_blocks, 1, bs // kpool, di), BF16), S((n, ni * di), BF16),
+            S((n, di), BF16), S((n, ni), F32), S((n, 1, 1), F32),
+            S((nlb * bs, 64), F32), S((slots, nlb), I32), S((slots,), I32),
+            S((slots,), I32)]
+    if lanes:
+        args += [S((lanes, nlb), I32), S((lanes * chunk // bs,), I32),
+                 S((lanes,), I32)]
+
+    def f(q, pool, ipool, qi, ki, wi, pos, table, btab, wblock, woff,
+          *lane):
+        return sla.sparse_latent_attention(
+            q, pool, ipool, qi, ki, wi, pos, table, btab, wblock, woff,
+            (*lane, chunk) if lane else None, num_heads=nh, v_width=c,
+            scale=256 ** -0.5, index_heads=ni, top_groups=top, kpool=kpool,
+            backend="pallas")
+    return f, args
+
+
+@pytest.mark.parametrize("slots, lanes", [(64, 0), (64, 2)])
+def test_sparse_latent_read_compiles_for_v5e(one_chip, slots, lanes):
+    """The sparse latent read at the repository cell's widths (ISSUE 61: 64
+    decode rows, and with them two lanes of 128; 64 heads over rows of 512
+    values, 32 index heads of 128 over 8,832 pooled keys a request, the best
+    512 groups of 4 and the tail gathered into 33 blocks of 64 a row) through
+    the TPU compiler for a v5e: the index pool written in place, ONE Mosaic
+    call (the latent read's decode body over the scratch), the rest XLA."""
+    f, args = _sparse_read(slots, lanes)
+    assert _n_calls(_tpu_text(f, *args)) == 1
+    args = [S(a.shape, a.dtype, sharding=one_chip) for a in args]
+    compiled = jax.jit(f, donate_argnums=2).lower(*args).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert f"bf16[{slots + lanes * 128},64,512]" in text
+
+
+def test_clamped_expert_walk_compiles_for_v5e(one_chip):
+    """The walk's gated body with `swiglu_limit` 10 at the repository cell's
+    widths: 36 held experts of 4096 x 2048, the mixed tick's 320 rows."""
+    from paddle_tpu.fusion import moe
+    held, d, f_, rows = 36, 4096, 2048, 320
+    args = [S((rows, d), BF16), S((held, rows, 1), F32), S((held,), I32),
+            S((held, d, f_), BF16), S((held, d, f_), BF16),
+            S((held, f_, d), BF16)]
+
+    def f(x, w, n, gate, up, down):
+        return moe.experts(x, w, n, gate, up, down, backend="pallas",
+                           limit=10.0)
+    assert _n_calls(_tpu_text(f, *args)) == 1
+    args = [S(a.shape, a.dtype, sharding=one_chip) for a in args]
+    assert jax.jit(f).lower(*args).compile().as_text().count(
+        "tpu_custom_call") == 1

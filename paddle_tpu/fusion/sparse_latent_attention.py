@@ -29,26 +29,48 @@ pool itself between its ticks, as a running sum that nothing reads before
 the group is whole: no state beside the pools.
 
 `sparse_latent_attention` is ONE op with two lowerings that share the
-selection (index write, scores, `top_k`, and the gather of the picked groups'
-rows of `c` into a dense scratch [N, top_groups * kpool + a block, W], the
-valid rows first, `count` of them): the composite attends the scratch in
-`jax.numpy`; the kernel (a TPU) is the latent read's DECODE body
-(`latent_attention._latent_decode_pallas`) over the scratch as a pool of its
-own, a row of the tick a "slot" of one query position. Scopes: `dsa_index`
-(write, scores, top-k) and `sparse_latent_attention` (gather, attend).
+selection (index write, scores, the sort: `ids` [N, G] the picked PHYSICAL
+groups, the valid ones first, and `count` the selected positions). Neither
+copies a pool: the latent pool is read as the engine keeps it, [NB, 1, BS, W],
+or seen as [NB * BS, W], which is a bitcast (a block is whole tiles).
+
+- the composite gathers the picked groups' SINGLE rows of that view into a
+  dense [N, G * kpool, W] and attends it in `jax.numpy`;
+- the kernel (a TPU, ISSUE 62) is a body of its own, `_sparse_fetch_kernel`,
+  with the latent decode body's structure (`latent_attention.
+  _latent_decode_kernel`: double-buffered DMAs from the pool in HBM, online
+  softmax, idle rows skipped, the next live row's first step in flight during
+  a row's last) and the row's PICKED groups as its table: one DMA a picked
+  group, of the 8 rows of the pool the group lies in (a DMA may slice a
+  bfloat16 pool by whole (8, 128) tiles and no finer: Mosaic refuses 4 rows),
+  the 4 rows beside it masked by a bias the XLA side builds from `ids % 2`;
+  two picked groups of one chunk are fetched twice and each masked to its
+  own. No scratch in HBM, no fetch for an idle row or for a slot past a row's
+  selection. One form for decode rows and lanes: timed alone at the
+  repository cell's shapes it beat XLA's gather (by groups of a regrouped
+  pool, PR 61's, and by single rows) at 11 and 25 live decode rows and at the
+  mixed tick's 320 (PERF.md section 6, PR 62). Where a group is no whole
+  number of such chunks (`fetch_chunk`) the read is the composite's.
+
+Scopes: `dsa_index` (write, scores, sort) and `sparse_latent_attention` (the
+composite's gather and attend; the Mosaic call `sparse_fetch` inside it).
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
 
 from ..framework.registry import register_op
 from ..ops.tensor_ops import _write_pool_blocks, _write_pool_rows
-from .latent_attention import (KERNEL, _MASKED, _latent_decode_pallas,
+from .latent_attention import (KERNEL, _M_INIT, _MASKED,
                                latent_attention_lowering)
 
 _HEAD_BLOCK = 8         # index heads scored at a time (bounds the scratch)
+_FETCH_ROWS = 8         # rows of the pool a DMA may slice (its HBM tiling)
+_FETCH_STEP_KEYS = 512  # fetched rows a step of the fetch kernel scores
 
 
 def rotate_first(x, pos, table):
@@ -144,10 +166,207 @@ def _attend_composite(q, scratch, count, num_heads, v_width, scale):
 
 
 def scratch_rows(top_groups, kpool, block_size, n_logical):
-    """Rows of a tick row's scratch: the selection and the tail's group,
+    """Rows of a tick row's selection: the picked groups and the tail's,
     rounded up to whole blocks."""
     k = min(top_groups, n_logical * block_size // kpool)
     return -(-(k + 1) * kpool // block_size) * block_size
+
+
+def picked_rows(ids, kpool):
+    """ids [..., G] physical groups -> [..., G * kpool] the rows of the pool
+    seen as [NB * BS, W] they hold."""
+    rows = ids[..., None] * kpool + jnp.arange(kpool, dtype=ids.dtype)
+    return rows.reshape(ids.shape[:-1] + (-1,))
+
+
+def fetch_chunk(kpool, block_size):
+    """Rows of ONE DMA of the fetch kernel: the `_FETCH_ROWS` rows of the pool
+    a picked group lies in (a DMA slices whole tiles of the pool as it lies in
+    HBM, (8, 128) of them), or the group itself where it is whole tiles.
+    None where neither holds: the read is then the composite's."""
+    chunk = max(kpool, _FETCH_ROWS)
+    if chunk % kpool or chunk % _FETCH_ROWS or block_size % chunk:
+        return None
+    return chunk
+
+
+def _sparse_fetch_kernel(fetch_ref, src_ref, nxt_ref, q_ref, bias_ref,
+                         pool_hbm, o_ref, kbuf, sem, parity_ref, *,
+                         step_chunks, chunk, v_width, scale):
+    """One grid step = one row of the tick: `num_heads` rows [R, W] against
+    the row's PICKED latent rows, fetched from the pool as it lies.
+
+    `src_ref` [1, 1, G] (SMEM, this row's; `nxt_ref` the next row's) the pool
+    row each picked group's chunk starts on, `fetch_ref[r]` how many of them
+    hold a selected position (0: an idle row, which fetches nothing and
+    returns zeros). They come `step_chunks` DMAs of `chunk` rows a step into
+    one of two buffers, the next step in flight while this one is scored, and
+    during a row's last step the first step of the row after it, where that
+    row is live (`parity_ref` says which buffer it lands in). `bias_ref`
+    [1, steps, keys] masks what a chunk holds beside its group, the tail's
+    rows past the row's position, and the slots not fetched (the buffers are
+    zeroed at the first step: finite). Two picked groups of one chunk are two
+    fetches, each masked to its own group.
+
+    Every step but a row's last is whole, and its body is ONE block of code
+    as the latent decode body's is: the next step's starts written out, each
+    under its own condition (the next step may be the short last one), and
+    this step's waits, so that the compiler lays their scalar work beside the
+    products; 528 DMAs a row are what bounds the read (PERF.md section 6, PR
+    62). The last step's copies run in loops. Same arithmetic as the latent
+    decode body."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    r, n_tick = pl.program_id(0), pl.num_programs(0)
+    n_fetch = fetch_ref[r]
+    n_rows = q_ref.shape[1]
+
+    def copy(ref, step, buf, g):
+        """The DMA of slot g of step `step` of the row `ref` lists."""
+        src = pl.multiple_of(ref[0, 0, step * step_chunks + g], chunk)
+        dst = g * chunk
+        if not isinstance(g, int):
+            dst = pl.multiple_of(dst, chunk)
+        return pltpu.make_async_copy(
+            pool_hbm.at[pl.ds(src, chunk), :],
+            kbuf.at[buf, pl.ds(dst, chunk), :], sem.at[buf])
+
+    def fetch(ref, step, buf, count, wait):
+        """Start (or wait for) the first `count` DMAs of a step, in a loop."""
+        def one(g, carry):
+            cp = copy(ref, step, buf, g)
+            cp.wait() if wait else cp.start()
+            return carry
+        jax.lax.fori_loop(0, jnp.minimum(count, step_chunks), one, 0)
+
+    @pl.when(r == 0)
+    def _():
+        kbuf[...] = jnp.zeros(kbuf.shape, kbuf.dtype)
+        parity_ref[0] = 0
+
+    @pl.when(n_fetch == 0)
+    def _():
+        o_ref[0] = jnp.zeros(o_ref.shape[1:], o_ref.dtype)
+
+    @pl.when(n_fetch > 0)
+    def _():
+        base = parity_ref[0]      # the buffer this row's first step is in
+        n_steps = jax.lax.div(n_fetch + step_chunks - 1, step_chunks)
+        q = q_ref[0]                                          # [R, W]
+
+        # the live row before this one started this row's first step
+        @pl.when((r == 0) | (fetch_ref[jnp.maximum(r - 1, 0)] == 0))
+        def _():
+            fetch(src_ref, 0, base, n_fetch, wait=False)
+
+        def scored(carry, j, buf):
+            m, l, acc = carry
+            k = kbuf[buf]                                     # [keys, W]
+            sc = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale \
+                + bias_ref[0, pl.ds(j, 1), :]                 # [R, keys]
+            m_new = jnp.maximum(m, jnp.max(sc, axis=1, keepdims=True))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(sc - m_new)
+            l = alpha * l + jnp.sum(p, axis=1, keepdims=True)
+            acc = alpha * acc + jax.lax.dot_general(
+                p.astype(k.dtype), k[:, :v_width],
+                (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+            return m_new, l, acc
+
+        def whole_step(j, carry):
+            buf = jax.lax.rem(base + j, 2)
+            left = n_fetch - (j + 1) * step_chunks
+            for g in range(step_chunks):
+                @pl.when(g < left)
+                def _():
+                    copy(src_ref, j + 1, 1 - buf, g).start()
+            for g in range(step_chunks):
+                copy(src_ref, j, buf, g).wait()
+            return scored(carry, j, buf)
+
+        carry = jax.lax.fori_loop(
+            0, n_steps - 1, whole_step,
+            (jnp.full((n_rows, 1), _M_INIT, jnp.float32),
+             jnp.zeros((n_rows, 1), jnp.float32),
+             jnp.zeros((n_rows, v_width), jnp.float32)))
+        last = n_steps - 1
+        buf = jax.lax.rem(base + last, 2)
+        fetch(nxt_ref, 0, 1 - buf,
+              jnp.where(r + 1 < n_tick,
+                        fetch_ref[jnp.minimum(r + 1, n_tick - 1)], 0),
+              wait=False)
+        fetch(src_ref, last, buf, n_fetch - last * step_chunks, wait=True)
+        _, l, acc = scored(carry, last, buf)
+        parity_ref[0] = jax.lax.rem(base + n_steps, 2)
+        o_ref[0] = (acc / l).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("num_heads", "v_width", "scale",
+                                             "kpool", "chunk", "interpret"))
+def _sparse_fetch_pallas(q, pool, ids, count, live, num_heads, v_width, scale,
+                         kpool, chunk, interpret):
+    """q [N, 1, nh*W]; pool [NB, 1, BS, W]; ids [N, G] the picked physical
+    groups, the valid ones first; count [N] the selected positions; live [N]
+    -> [N, 1, nh*v_width]. ONE Mosaic call, `sparse_fetch`, made inside the
+    scope `sparse_latent_attention`."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    n, g = ids.shape
+    w = pool.shape[-1]
+    i32 = jnp.int32
+    per = chunk // kpool                    # groups a chunk holds
+    step_chunks = min(max(1, _FETCH_STEP_KEYS // chunk), g)
+    n_steps = -(-g // step_chunks)
+    keys = step_chunks * chunk
+    ids = jnp.pad(ids, ((0, 0), (0, n_steps * step_chunks - g)))
+    c = jnp.arange(chunk, dtype=i32)
+    slot = jnp.arange(ids.shape[1], dtype=i32)
+    valid = ((c // kpool == (ids % per)[:, :, None])
+             & (slot[None, :, None] * kpool + c % kpool
+                < count[:, None, None]))
+    bias = jnp.where(valid, 0.0, _MASKED).astype(jnp.float32) \
+        .reshape(n, n_steps, keys)
+    n_fetch = jnp.where(live > 0, -(-count // kpool), 0).astype(i32)
+    src = ((ids // per) * chunk)[:, None, :]
+    with jax.named_scope("sparse_fetch"):
+        out = pl.pallas_call(
+            functools.partial(
+                _sparse_fetch_kernel, step_chunks=step_chunks, chunk=chunk,
+                v_width=v_width, scale=scale),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=(n,),
+                in_specs=[pl.BlockSpec((1, 1, ids.shape[1]),
+                                       lambda i, *_: (i, 0, 0),
+                                       memory_space=pltpu.SMEM),
+                          pl.BlockSpec((1, 1, ids.shape[1]),
+                                       lambda i, *_: (
+                                           jnp.minimum(i + 1, n - 1), 0, 0),
+                                       memory_space=pltpu.SMEM),
+                          pl.BlockSpec((1, num_heads, w),
+                                       lambda i, *_: (i, 0, 0)),
+                          pl.BlockSpec((1, n_steps, keys),
+                                       lambda i, *_: (i, 0, 0)),
+                          pl.BlockSpec(memory_space=pl.ANY)],
+                out_specs=pl.BlockSpec((1, num_heads, v_width),
+                                       lambda i, *_: (i, 0, 0)),
+                scratch_shapes=[pltpu.VMEM((2, keys, w), pool.dtype),
+                                pltpu.SemaphoreType.DMA((2,)),
+                                pltpu.SMEM((1,), jnp.int32)]),
+            out_shape=jax.ShapeDtypeStruct((n, num_heads, v_width), q.dtype),
+            # rows run in order: a row's first step is fetched while the
+            # live row before it scores its last
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",),
+                vmem_limit_bytes=64 * 1024 * 1024),
+            interpret=interpret,
+        )(n_fetch, src, src, q.reshape(n, num_heads, w).astype(pool.dtype),
+          bias, pool.reshape(-1, w))
+    return out.reshape(n, 1, num_heads * v_width)
 
 
 def sparse_latent_attention(q, pool, ipool, qi, ki, wi, positions, table,
@@ -206,24 +425,19 @@ def sparse_latent_attention(q, pool, ipool, qi, ki, wi, positions, table,
                             t_rows // kpool)
         count = jnp.maximum(count, 1)
     with jax.named_scope("sparse_latent_attention"):
-        groups = pool.reshape(-1, kpool, w)
         lowering = latent_attention_lowering(w, v_width, num_heads, 1, backend)
-        if lowering != KERNEL:
-            out = _attend_composite(q, groups[ids].reshape(n, t_rows, w),
-                                    count, num_heads, v_width, float(scale))
-            return out, ipool
-        # the scratch as a pool of its own behind a null block (the decode
-        # body reads a slot at position 0 on block 0 as idle), a row of the
-        # tick a slot whose table is its own blocks in order
-        nb = t_rows // block_size
-        ids = jnp.concatenate([jnp.zeros((gpb,), i32), ids.reshape(-1)])
-        scratch = groups[ids].reshape(1 + n * nb, 1, block_size, w)
-    # the decode body opens the scope `latent_paged_attention` itself; the
-    # reader of this read sums the three scopes
-    out = _latent_decode_pallas(
-        q, scratch, 1 + jnp.arange(n * nb, dtype=i32).reshape(n, nb),
-        count - 1, live.astype(i32), num_heads, v_width, float(scale),
-        interpret=backend == "pallas_interpret")
+        fetched = fetch_chunk(kpool, block_size)
+        if lowering == KERNEL and fetched is not None:
+            out = _sparse_fetch_pallas(
+                q, pool, ids, count, live.astype(i32), num_heads, v_width,
+                float(scale), kpool, fetched,
+                interpret=backend == "pallas_interpret")
+        else:
+            # single rows of the pool seen as [NB * BS, W]: a bitcast, blocks
+            # are whole tiles
+            rows = pool.reshape(-1, w)[picked_rows(ids, kpool)]
+            out = _attend_composite(q, rows, count, num_heads, v_width,
+                                    float(scale))
     return out, ipool
 
 

@@ -879,16 +879,25 @@ def test_sparse_latent_read_compiles_for_v5e(one_chip, slots, lanes):
     """The sparse latent read at the repository cell's widths (ISSUE 61: 64
     decode rows, and with them two lanes of 128; 64 heads over rows of 512
     values, 32 index heads of 128 over 8,832 pooled keys a request, the best
-    512 groups of 4 and the tail gathered into 33 blocks of 64 a row) through
-    the TPU compiler for a v5e: the index pool written in place, ONE Mosaic
-    call (the latent read's decode body over the scratch), the rest XLA."""
+    512 groups of 4 and the tail) through the TPU compiler for a v5e: the
+    index pool written in place, ONE Mosaic call (ISSUE 62: the fetch kernel,
+    `sparse_fetch`, a DMA a picked group's 8-row chunk from the pool as it
+    lies, decode rows and lanes alike), the rest XLA. And NO instruction
+    regroups the pool (1,024 blocks here: `bf16[16384,4,512]`) or a scratch
+    of picked groups (`bf16[<any>,4,512]`), or gathers one by blocks: PR 61
+    copied 537 MB on every tick and 692 MB more on a mixed one that way."""
     f, args = _sparse_read(slots, lanes)
     assert _n_calls(_tpu_text(f, *args)) == 1
     args = [S(a.shape, a.dtype, sharding=one_chip) for a in args]
     compiled = jax.jit(f, donate_argnums=2).lower(*args).compile()
     text = compiled.as_text()
     assert text.count("tpu_custom_call") == 1
-    assert f"bf16[{slots + lanes * 128},64,512]" in text
+    n = slots + lanes * 128
+    assert f"bf16[{n},64,512]" in text
+    assert "sparse_fetch" in text and "latent_paged_attention" not in text
+    assert not re.search(r"bf16\[\d+,4,512\]", text)
+    assert f"bf16[{1 + n * 33},1,64,512]" not in text
+    assert "bf16[65536,512]{1,0:T(8,128)(2,1)} bitcast(" in text
 
 
 def test_clamped_expert_walk_compiles_for_v5e(one_chip):

@@ -1,28 +1,49 @@
 """The sparse latent read of ONE layer, timed ALONE on the chip at the shapes
-of `glm53-flash-ep8_serve_repo_sessions` (PR 61):
+of `glm53-flash-ep8_serve_repo_sessions` (PR 61, PR 62):
 
     chiprun --chips 1 -- python3 tools/probe_sparse_read.py [--slots 64]
-        [--live 25] [--lanes 2] [--position 33000] [--reps 10]
+        [--live 11,25] [--lanes 2] [--position 33000] [--reps 6]
+        [--parts whole,read,selection]
 
 64 decode rows of which `--live` sit at ~`--position` positions (the others
 idle on the null block), 64 heads over rows of 512 values, 32 index heads of
 128 over the ~8,250 pooled keys of a row's table, the best 512 groups of 4 and
-the tail. Timed, each as `INNER` calls chained inside ONE launch:
+the tail. Every part is timed as the TICK runs it: `INNER` calls chained
+inside ONE launch whose loop CARRIES the pools and writes a row of them
+before each call, as the tick's own write does, so that nothing that reads a
+pool can be hoisted out of the loop. Timed, for each count of `--live`:
 
 - `sparse_decode`: the whole op as the decode tick runs it (the pooled row's
-  write, index scores, `top_k`, the gather into the scratch, the latent
-  read's decode body over the scratch);
+  write, index scores, the sort, the picked rows' fetch and attend);
 - `sparse_mixed`: the same with `--lanes` lanes of 128 rows beside them (the
   mixed tick's 320 rows);
-- its parts at the decode shape alone: `index_scores`, `top_k`, `gather`,
-  `attend` (the decode body over a ready scratch);
+- the picked rows' way from the pool to the context, three forms, at the
+  decode shape (`read_*_decode`) and at the mixed tick's 320 rows
+  (`read_*_mixed`), each from the SAME `ids` and `count`:
+  - `groups` (A, PR 61's): the pool regrouped `[NB*16, 4, 512]` (a copy of
+    the pool), groups gathered, the scratch regrouped back to blocks of 64 (a
+    copy of the scratch), the latent read's decode body over it;
+  - `rows` (C / F): single rows gathered from the pool seen as `[NB*64, 512]`
+    (a bitcast) straight into blocks of 64, the same decode body;
+  - `fetch` (K): `_sparse_fetch_pallas`, one DMA a picked group's 8-row chunk
+    from the pool in HBM, live rows only, no scratch;
+  and `gather_groups` / `gather_rows`: A's and C's gathers alone, their
+  scratch read once AS BLOCKS OF 64 (what the attend kernel takes);
+- `index_scores`, `sort_payload`: the selection's parts (not PR 62's);
 - `dense_decode`: `latent_paged_attention`'s decode body over the WHOLE table
-  of the same rows (what the read would cost with the selection ignored:
-  the candidate the selection has to beat at this length).
+  of the same rows (the selection ignored).
 
-One JSON line of median milliseconds a call. What the numbers decided is in
-PERF.md section 6, PR 61 (the plain gather route against a kernel of 512
-four-row DMAs a row, which is not built)."""
+`fetch_vs_rows_max_abs` holds K's context to C's on the chip. A form this
+checkout does not have reports its error and the others still run, so the
+file copied into a parent's checkout times the parent in the same call.
+
+One JSON line of median milliseconds a call. PR 61 took A on a probe whose
+loop HOISTED A's copy of the pool (its pool was a constant of the loop) and
+whose consumer never paid the scratch's way back to blocks: in the tick A
+cost 1.86 ms more on every tick and 2.4 more on a mixed one (ledger, PR 61).
+What PR 62 read here and which regime took which form is in PERF.md section
+6, PR 62. (B, `(4, 512)` windows of the 2-D pool, read 44.9 ms in PR 61 and
+D, a pool stored by groups, was not built: neither is timed any more.)"""
 
 from __future__ import annotations
 
@@ -38,39 +59,53 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 INNER = 20
 
 
-def _median_ms(fn, args, reps):
-    """Median milliseconds of ONE call of `fn(carry, *args) -> carry` (a
-    scalar the next call's inputs are nudged by, so that no call is elided),
-    `INNER` calls inside one jitted loop; a part that fails says why and the
-    others still report."""
+def _median_ms(fn, state, args, reps):
+    """(median milliseconds of ONE call of `fn(carry, state, *args) -> carry`
+    (a scalar the next call's inputs are nudged by, so that no call is
+    elided), the pools as the launches left them); a part that fails says why
+    in place of the time, hands back None, and the others still report."""
     try:
-        return _timed(fn, args, reps)
+        return _timed(fn, state, args, reps)
     except Exception as e:      # noqa: BLE001  (a probe: report and go on)
-        return f"{type(e).__name__}: {str(e)[:300]}"
+        return f"{type(e).__name__}: {str(e)[:300]}", None
 
 
-def _timed(fn, args, reps):
+def _timed(fn, state, args, reps):
+    """`state`: the pools, carried through the loop and written (one row of
+    block 1 + i) before call i, in place: the launch donates them and the
+    caller goes on with the ones that come back."""
     import jax
     import jax.numpy as jnp
-    loop = jax.jit(lambda *a: jax.lax.fori_loop(
-        0, INNER, lambda _, c: fn(c, *a), jnp.zeros((), jnp.float32)))
+
+    def body(i, c):
+        carry, state = c
+        state = tuple(p.at[1 + i, 0, 3].set(carry.astype(p.dtype))
+                      for p in state)
+        out = fn(carry, state, *args)
+        return (out[0], out[1]) if isinstance(out, tuple) else (out, state)
+
+    loop = jax.jit(lambda state, *a: jax.lax.fori_loop(
+        0, INNER, body, (jnp.zeros((), jnp.float32), state)),
+        donate_argnums=0)
     times = []
     for k in range(reps + 2):
         t = time.perf_counter()
-        jax.block_until_ready(loop(*args))
+        _, state = jax.block_until_ready(loop(state, *args))
         if k >= 2:
             times.append(1e3 * (time.perf_counter() - t) / INNER)
-    return float(np.median(times))
+    return float(np.median(times)), state
 
 
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--slots", type=int, default=64)
-    ap.add_argument("--live", type=int, default=25)
+    ap.add_argument("--live", default="11,25")
     ap.add_argument("--lanes", type=int, default=2)
     ap.add_argument("--position", type=int, default=33000)
-    ap.add_argument("--reps", type=int, default=10)
+    ap.add_argument("--reps", type=int, default=6)
+    ap.add_argument("--parts", default="whole,read,selection")
     args = ap.parse_args(argv)
+    parts = args.parts.split(",")
     sys.path.insert(0, ROOT)
     import jax
     import jax.numpy as jnp
@@ -83,18 +118,33 @@ def main(argv=None):
     bf16, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
     rng = np.random.default_rng(0)
     key = jax.random.key(0)
-    pool = jax.random.normal(key, (nb, 1, bs, c), bf16)
-    ipool = jax.random.normal(jax.random.fold_in(key, 1),
-                              (nb, 1, bs // kp, di), bf16)
+    pools = {"pool": jax.random.normal(key, (nb, 1, bs, c), bf16),
+             "ipool": jax.random.normal(jax.random.fold_in(key, 1),
+                                        (nb, 1, bs // kp, di), bf16)}
     table = jnp.asarray(rng.standard_normal((nlb * bs, 64)), f32)
+    scale = 256 ** -0.5
 
-    def rows(n_rows, n_live, lanes):
+    def timed(fn, names, fn_args):
+        """Time `fn` with the pools `names` carried; keep the pools that come
+        back (the launch donated the ones that went in)."""
+        ms, back = _median_ms(fn, tuple(pools[k] for k in names), fn_args,
+                              args.reps)
+        if back is not None:
+            pools.update(zip(names, back))
+        elif any(pools[k].is_deleted() for k in names):
+            for k, shape in (("pool", (nb, 1, bs, c)),
+                             ("ipool", (nb, 1, bs // kp, di))):
+                pools[k] = jax.random.normal(key, shape, bf16)
+        return ms
+
+    def rows(n_live, lanes):
+        n_rows = S + lanes * C
         pos = np.zeros((n_rows,), np.int64)
         btab = np.zeros((S, nlb), np.int64)
         wblock, woff = np.zeros((S,), np.int64), np.zeros((S,), np.int64)
         for s in range(n_live):
             p = args.position + 17 * s
-            blocks = rng.choice(np.arange(1, nb), p // bs + 1, replace=False)
+            blocks = rng.choice(np.arange(32, nb), p // bs + 1, replace=False)
             btab[s, :len(blocks)] = blocks
             pos[s], wblock[s], woff[s] = p, blocks[-1], p % bs
         lane = ()
@@ -103,10 +153,10 @@ def main(argv=None):
             lwb = np.zeros((lanes * C // bs,), np.int64)
             for j in range(lanes):
                 p0 = (args.position // C) * C
-                blocks = rng.choice(np.arange(1, nb), p0 // bs + C // bs,
+                blocks = rng.choice(np.arange(32, nb), p0 // bs + C // bs,
                                     replace=False)
                 lbtab[j, :len(blocks)] = blocks
-                lwb[j * 2:j * 2 + 2] = blocks[-2:]
+                lwb[j * (C // bs):(j + 1) * (C // bs)] = blocks[-(C // bs):]
                 pos[S + j * C:S + (j + 1) * C] = p0 + np.arange(C)
             lane = (jnp.asarray(lbtab, i32), jnp.asarray(lwb, i32),
                     jnp.full((lanes,), C, i32))
@@ -121,118 +171,144 @@ def main(argv=None):
             btab=jnp.asarray(btab, i32), wblock=jnp.asarray(wblock, i32),
             woff=jnp.asarray(woff, i32), lane=lane)
 
-    kw = dict(num_heads=nh, v_width=c, scale=256 ** -0.5, index_heads=ni,
+    kw = dict(num_heads=nh, v_width=c, scale=scale, index_heads=ni,
               top_groups=top, kpool=kp)
 
-    def whole(r):
-        def fn(carry, pool, ipool, table):
-            out, _ = sla.sparse_latent_attention(
-                r["q"] + carry.astype(bf16), pool, ipool,
-                r["qi"] + carry.astype(bf16), r["ki"] + carry.astype(bf16),
-                r["wi"], r["pos"], table, r["btab"], r["wblock"], r["woff"],
-                (*r["lane"], C) if r["lane"] else None, **kw)
-            return jnp.sum(out[0, 0, :4].astype(f32)) * 1e-9
-        return fn
+    def whole(carry, state, r):
+        pool, ipool = state
+        out, ipool = sla.sparse_latent_attention(
+            r["q"] + carry.astype(bf16), pool, ipool,
+            r["qi"] + carry.astype(bf16), r["ki"] + carry.astype(bf16),
+            r["wi"], r["pos"], table, r["btab"], r["wblock"], r["woff"],
+            (*r["lane"], C) if r["lane"] else None, **kw)
+        return jnp.sum(out[0, 0, :4].astype(f32)) * 1e-9, (pool, ipool)
 
-    dec, mix = rows(S, args.live, 0), rows(S + L * C, args.live, L)
-    out = {"slots": S, "live": args.live, "lanes": L,
-           "position": args.position,
-           "device": jax.devices()[0].device_kind}
-    out["sparse_decode_ms"] = _median_ms(whole(dec), (pool, ipool, table),
-                                         args.reps)
-    out["sparse_mixed_ms"] = _median_ms(whole(mix), (pool, ipool, table),
-                                        args.reps)
-    # the parts, at the decode shape
     gpb = bs // kp
+    t_rows = sla.scratch_rows(top, kp, bs, nlb)
+    n_blk = t_rows // bs
+
+    def selection(r):
+        """A row's `ids`, `count`, `live` from seeded scores, as the op makes
+        them, and the scratch's table."""
+        n = r["q"].shape[0]
+        pos = r["pos"].reshape(-1).astype(i32)
+        tab, live = r["btab"], r["wblock"] > 0
+        if r["lane"]:
+            tab = jnp.concatenate([tab, jnp.repeat(r["lane"][0], C, axis=0)])
+            live = jnp.concatenate([live, jnp.ones((n - S,), bool)])
+        sc = jax.random.normal(jax.random.fold_in(key, 7 + n),
+                               (n, nlb * gpb), f32)
+        ids, count = sla.select(sc, pos, tab, kp, top, gpb, t_rows // kp)
+        return dict(q=r["q"], ids=ids, count=jnp.maximum(count, 1),
+                    live=live.astype(i32),
+                    tab=1 + jnp.arange(n * n_blk, dtype=i32)
+                    .reshape(n, n_blk))
+
+    def attend(q, scratch, sel):
+        return la._latent_decode_pallas(
+            q, scratch, sel["tab"], sel["count"] - 1, sel["live"], nh, c,
+            scale, interpret=False)
+
+    def scratch_groups(pool, sel):      # A: groups of a regrouped pool
+        n = sel["ids"].shape[0]
+        flat = jnp.concatenate([jnp.zeros((gpb,), i32),
+                                sel["ids"].reshape(-1)])
+        return pool.reshape(-1, kp, c)[flat].reshape(1 + n * n_blk, 1, bs, c)
+
+    def scratch_rows_(pool, sel):       # C: single rows of the 2-D pool
+        n = sel["ids"].shape[0]
+        at = jnp.concatenate([jnp.zeros((bs,), i32),
+                              sla.picked_rows(sel["ids"].reshape(-1), kp)])
+        return pool.reshape(-1, c)[at].reshape(1 + n * n_blk, 1, bs, c)
+
+    def fetch(q, pool, sel):            # K: the fetch kernel, no scratch
+        return sla._sparse_fetch_pallas(
+            q, pool, sel["ids"], sel["count"], sel["live"], nh, c, scale, kp,
+            sla.fetch_chunk(kp, bs), interpret=False)
+
+    def first(o):
+        return jnp.sum(o[0, 0, :4].astype(f32)) * 1e-9
+
+    def read_groups(carry, state, sel):
+        return first(attend(sel["q"] + carry.astype(bf16),
+                            scratch_groups(state[0], sel), sel))
+
+    def read_rows(carry, state, sel):
+        return first(attend(sel["q"] + carry.astype(bf16),
+                            scratch_rows_(state[0], sel), sel))
+
+    def read_fetch(carry, state, sel):
+        return first(fetch(sel["q"] + carry.astype(bf16), state[0], sel))
+
+    def consumed(scratch):
+        """The gathered rows as the attend kernel takes them (a pool of
+        blocks of 64), read once."""
+        return jnp.sum(scratch[:, 0, ::16, :8].astype(f32)) * 1e-9
+
+    def gather_groups(carry, state, sel):
+        return consumed(scratch_groups(state[0], sel)) + carry * 1e-9
+
+    def gather_rows(carry, state, sel):
+        return consumed(scratch_rows_(state[0], sel)) + carry * 1e-9
+
+    out = {"slots": S, "lanes": L, "position": args.position,
+           "device": jax.devices()[0].device_kind}
+    for n_live in (int(v) for v in args.live.split(",")):
+        dec, mix = rows(n_live, 0), rows(n_live, L)
+        tag = f"_live{n_live}"
+        if "whole" in parts:
+            out["sparse_decode_ms" + tag] = timed(whole, ("pool", "ipool"),
+                                                  (dec,))
+            out["sparse_mixed_ms" + tag] = timed(whole, ("pool", "ipool"),
+                                                 (mix,))
+        for shape, r in (("decode", dec), ("mixed", mix)):
+            if "read" not in parts:
+                break
+            sel = selection(r)
+            for name, fn in (("read_groups", read_groups),
+                             ("read_rows", read_rows),
+                             ("read_fetch", read_fetch),
+                             ("gather_groups", gather_groups),
+                             ("gather_rows", gather_rows)):
+                out[f"{name}_{shape}_ms{tag}"] = timed(fn, ("pool",), (sel,))
+            try:
+                a = fetch(sel["q"], pools["pool"], sel)
+                b = attend(sel["q"], scratch_rows_(pools["pool"], sel), sel)
+                out[f"fetch_vs_rows_max_abs_{shape}{tag}"] = float(
+                    jnp.max(jnp.abs(a.astype(f32) - b.astype(f32))))
+            except Exception as e:      # noqa: BLE001
+                out[f"fetch_vs_rows_max_abs_{shape}{tag}"] = \
+                    f"{type(e).__name__}: {str(e)[:200]}"
+        print(json.dumps(out), flush=True)
+    if "selection" not in parts:
+        return 0
+    # the selection's parts and the dense read, at the decode shape
     pos = dec["pos"].reshape(-1).astype(i32)
     qi = dec["qi"].reshape(S, 1, ni, di)
     wi = dec["wi"].reshape(S, 1, ni)
 
-    def scores(carry, ipool):
+    def scores(carry, state):
         sc = sla.index_scores(qi + carry.astype(bf16), wi,
-                              ipool[dec["btab"]].reshape(S, nlb * gpb, di))
+                              state[0][dec["btab"]].reshape(S, nlb * gpb, di),
+                              head_block=ni)
         return jnp.sum(sc[0, 0, :4]) * 1e-9
-    out["index_scores_ms"] = _median_ms(scores, (ipool,), args.reps)
+    out["index_scores_ms"] = timed(scores, ("ipool",), ())
     sc = jax.random.normal(key, (S, nlb * gpb), f32)
-
-    def topk(carry, sc):
-        _, idx = jax.lax.top_k(sc + carry, top)
-        return jnp.sum(idx[0, :4]).astype(f32) * 1e-9
-    out["top_k_ms"] = _median_ms(topk, (sc,), args.reps)
-    t_rows = sla.scratch_rows(top, kp, bs, nlb)
-    ids, count = sla.select(sc, pos, dec["btab"], kp, top, gpb, t_rows // kp)
-    n_blk = t_rows // bs
-    flat = jnp.concatenate([jnp.zeros((gpb,), i32), ids.reshape(-1)])
-
-    def consumer(scratch):
-        """The gathered rows as the attend kernel takes them (a pool of
-        blocks of 64), read once."""
-        blocks = scratch.reshape(1 + S * n_blk, 1, bs, c)
-        return jnp.sum(blocks[:, 0, ::16, :8].astype(f32)) * 1e-9
-
-    def gather(carry, pool):            # A: groups of a reshaped pool
-        return consumer(
-            pool.reshape(-1, kp, c)[flat + (carry > 1).astype(i32)])
-    out["gather_ms"] = _median_ms(gather, (pool,), args.reps)
-
-    def gather_windows(carry, pool):    # B: (4, 512) windows of the 2-D pool
-        dn = jax.lax.GatherDimensionNumbers(
-            offset_dims=(1, 2), collapsed_slice_dims=(),
-            start_index_map=(0,))
-        start = (flat * kp + (carry > 1).astype(i32))[:, None]
-        return consumer(jax.lax.gather(
-            pool.reshape(-1, c), start, dn, (kp, c), mode="clip"))
-    out["gather_windows_ms"] = _median_ms(gather_windows, (pool,), args.reps)
-
-    def gather_rows(carry, pool):       # C: single rows of the 2-D pool
-        rows_ = (flat[:, None] * kp + jnp.arange(kp, dtype=i32)).reshape(-1)
-        return consumer(pool.reshape(-1, c)[rows_ + (carry > 1).astype(i32)])
-    out["gather_rows_ms"] = _median_ms(gather_rows, (pool,), args.reps)
-
-    # D: a pool stored by groups, [NB * 16, 4, 512]: no copy a call
-    def in_use():
-        return (jax.devices()[0].memory_stats() or {}).get("bytes_in_use", 0)
-    before = in_use()
-    native = jax.block_until_ready(
-        jax.random.normal(key, (nb * gpb, kp, c), bf16))
-    out["native_pool_gb"] = (in_use() - before) / 1e9
-
-    def gather_native(carry, native):
-        return consumer(native[flat + (carry > 1).astype(i32)])
-    out["gather_native_ms"] = _median_ms(gather_native, (native,), args.reps)
-    del native
-
     phys = (jnp.repeat(dec["btab"], gpb, axis=1) * gpb
             + jnp.tile(jnp.arange(gpb, dtype=i32), nlb)[None, :])
 
-    def sort_payload(carry, sc):        # the ids ride through the sort
+    def sort_payload(carry, state, sc):     # the ids ride through the sort
         _, ids_ = jax.lax.sort((-(sc + carry), phys), num_keys=1)
         return jnp.sum(ids_[0, :4]).astype(f32) * 1e-9
-    out["sort_payload_ms"] = _median_ms(sort_payload, (sc,), args.reps)
-
-    def map_ids(carry, sc):             # top_k, then the ids looked up
-        _, idx = jax.lax.top_k(sc + carry, top)
-        ids_ = jnp.take_along_axis(phys, idx, axis=1)
-        return jnp.sum(ids_[0, :4]).astype(f32) * 1e-9
-    out["top_k_and_lookup_ms"] = _median_ms(map_ids, (sc,), args.reps)
-    scratch = pool.reshape(-1, kp, c)[flat].reshape(1 + S * n_blk, 1, bs, c)
-    tab = 1 + jnp.arange(S * n_blk, dtype=i32).reshape(S, n_blk)
+    out["sort_payload_ms"] = timed(sort_payload, (), (sc,))
     live = (dec["wblock"] > 0).astype(i32)
 
-    def attend(carry, scratch):
-        o = la._latent_decode_pallas(
-            dec["q"] + carry.astype(bf16), scratch, tab,
-            jnp.maximum(count, 1) - 1, live, nh, c, 256 ** -0.5,
-            interpret=False)
-        return jnp.sum(o[0, 0, :4].astype(f32)) * 1e-9
-    out["attend_ms"] = _median_ms(attend, (scratch,), args.reps)
-
-    def dense(carry, pool):
-        o = la.latent_paged_attention(dec["q"] + carry.astype(bf16), pool,
-                                      dec["btab"], pos, nh, c, 256 ** -0.5,
+    def dense(carry, state):
+        o = la.latent_paged_attention(dec["q"] + carry.astype(bf16), state[0],
+                                      dec["btab"], pos, nh, c, scale,
                                       rows=live)
         return jnp.sum(o[0, 0, :4].astype(f32)) * 1e-9
-    out["dense_decode_ms"] = _median_ms(dense, (pool,), args.reps)
+    out["dense_decode_ms"] = timed(dense, ("pool",), ())
     print(json.dumps(out), flush=True)
     return 0
 

@@ -26,9 +26,20 @@ and the bare `jax.jit`s under parallel/):
   bound by the entry (PERF.md section 6, PR 58, has the seconds).
 
 A process pinned to the CPU platform (`JAX_PLATFORMS=cpu` — the test tier)
-with no directory given keeps no cache, and nothing is set for it: its
-compiles are small, and the suite's time window is better spent running
-tests than serializing executables.
+with no directory given keeps no cache, and nothing is set for it. Not for
+the time: GIVEN one directory a run, tier-1's whole suite took 563 s for 786
+and 3,116 s of case time for 4,091 (PR 63: six workers from an empty
+directory; the eager ops' compiles, made once a run, are most of it). For
+the store below. On the CPU backend (jax 0.9.0) an executable that JAX
+LOADED from its own cache serializes again without its kernels: the copy
+deserializes, loads, and fails at its first launch (`NOT_FOUND: Function
+<kernel> not found`). A step that misses the store and hits JAX's cache
+writes such an entry, and eleven tests of that run died on one. With the
+threshold named out of reach, so that JAX's cache holds nothing and the store
+works alone, the run was steady and 771 s: no gain worth a mechanism. A TPU's
+executable serializes again whole. Until the store declines what came out of
+JAX's cache on a CPU (ROADMAP.md D20 (7)), a CPU process is given no
+directory, or a threshold with it.
 
 **The executor's store** lives in the subdirectory `STORE_SUBDIR` of whatever
 directory is in effect (none in effect, no store). JAX's cache finds an
@@ -105,7 +116,7 @@ except ImportError:
 
 def store_dir() -> Optional[str]:
     """Where the executor's executables are kept, or None where no
-    compile-cache directory is in effect (the CPU test tier)."""
+    compile-cache directory is in effect (a CPU-pinned process given none)."""
     root = jax.config.jax_compilation_cache_dir
     if not root or not jax.config.jax_enable_compilation_cache:
         return None

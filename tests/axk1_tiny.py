@@ -3,11 +3,8 @@ benchmark/configs/axk1-ep16.json (latent attention with a padded row, YaRN
 past its original length, a leading dense layer, routed experts of which a
 share is held, a shared expert), none of its widths."""
 
-import numpy as np
-
 import tiny_engines
-from benchmark.models import axk1, axk1_reference as ref  # noqa: F401
-from tiny_engines import emitted_logits, scored_engine  # noqa: F401
+from benchmark.models import axk1, axk1_reference as ref
 
 CFG = dict(
     model="axk1", hidden_size=64, intermediate_size=96,
@@ -25,31 +22,4 @@ CFG = dict(
     cache_dtype="bfloat16", max_len=64)
 ENGINE = {"class": "PagedKVEngine", "n_slots": 4, "max_len": 64,
           "block_size": 8, "n_blocks": 40}
-
-
-def cfg(**over):
-    return dict(CFG, **over)
-
-
-def engine(config, seed=7, **spec):
-    return tiny_engines.engine(axk1, ENGINE, config, seed, **spec)
-
-
-def gaps(config, params, req, pad_to=64):
-    """Per emitted token of a finished request: how far its reference logit
-    lies below the position's largest, in standard deviations of that
-    position's logits (benchmark/loops/serve.py `_check`)."""
-    seq = np.asarray(req.prompt + req.tokens[:-1], np.int32)
-    ref = axk1.reference_logits(config, params, seq, pad_to)
-    ref = ref[len(req.prompt) - 1:]
-    toks = req.tokens
-    return (ref.max(-1) - ref[np.arange(len(toks)), toks]) / ref.std(-1)
-
-
-def logit_error(config, params, req, got, pad_to=64):
-    """max |program - reference| over the emitted positions' logits, in
-    standard deviations of the reference's logits."""
-    seq = np.asarray(req.prompt + req.tokens[:-1], np.int32)
-    ref = axk1.reference_logits(config, params, seq, pad_to)
-    ref = ref[len(req.prompt) - 1:]
-    return float(np.abs(got - ref).max() / ref.std())
+TINY = tiny_engines.Tiny(axk1, ref, CFG, ENGINE)

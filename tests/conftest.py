@@ -30,6 +30,10 @@ _ensure_virtual_cpu_devices(8)
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 
+# tiny_engines holds test bodies that the served models' files share: its
+# asserts explain themselves as a test module's do
+pytest.register_assert_rewrite("tiny_engines")
+
 
 @pytest.fixture(autouse=True)
 def fresh_state():

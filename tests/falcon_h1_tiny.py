@@ -5,12 +5,9 @@ beside rotary grouped-query attention with five query heads a key/value head;
 all of the family's multipliers at their published values; a gated SiLU pair;
 an untied head), none of its widths."""
 
-import numpy as np
-
 import tiny_engines
-from benchmark.models import falcon_h1 as falcon  # noqa: F401
-from benchmark.models import falcon_h1_reference as ref  # noqa: F401
-from tiny_engines import emitted_logits, scored_engine  # noqa: F401
+from benchmark.models import falcon_h1 as falcon
+from benchmark.models import falcon_h1_reference as ref
 
 CFG = dict(
     model="falcon_h1", hidden_size=64, intermediate_size=96,
@@ -36,26 +33,4 @@ CFG = dict(
     max_len=64)
 ENGINE = {"class": "PagedKVEngine", "n_slots": 4, "max_len": 64,
           "block_size": 8, "n_blocks": 40, "n_snapshots": 4}
-F32 = dict(weights_dtype="float32", cache_dtype="float32")
-
-
-def cfg(**over):
-    return dict(CFG, **over)
-
-
-def engine(config, seed=7, scored=False, **spec):
-    return tiny_engines.engine(falcon, ENGINE, config, seed, scored, **spec)
-
-
-def reference(config, params, req, pad_to=64):
-    """The reference's logits for the positions `req` emitted from."""
-    seq = np.asarray(req.prompt + req.tokens[:-1], np.int32)
-    return falcon.reference_logits(config, params, seq, pad_to)[
-        len(req.prompt) - 1:]
-
-
-def logit_error(config, params, req, got, pad_to=64):
-    """max |program - reference| over the emitted positions' logits, in
-    standard deviations of the reference's logits."""
-    r = reference(config, params, req, pad_to)
-    return float(np.abs(got - r).max() / r.std())
+TINY = tiny_engines.Tiny(falcon, ref, CFG, ENGINE)

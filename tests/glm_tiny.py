@@ -7,12 +7,9 @@ dense first layer, then 16 experts of which 8 are held beside a shared one,
 every gated pair clamped at `swiglu_limit`; an untied head), none of its
 widths."""
 
-import numpy as np
-
 import tiny_engines
-from benchmark.models import glm  # noqa: F401
-from benchmark.models import glm_reference as ref  # noqa: F401
-from tiny_engines import emitted_logits, scored_engine  # noqa: F401
+from benchmark.models import glm
+from benchmark.models import glm_reference as ref
 
 CFG = dict(
     model="glm", hidden_size=64, intermediate_size=96,
@@ -40,26 +37,4 @@ CFG = dict(
     weights_dtype="bfloat16", cache_dtype="bfloat16", max_len=64)
 ENGINE = {"class": "PagedKVEngine", "n_slots": 4, "max_len": 64,
           "block_size": 8, "n_blocks": 40, "n_snapshots": 4}
-F32 = dict(weights_dtype="float32", cache_dtype="float32")
-
-
-def cfg(**over):
-    return dict(CFG, **over)
-
-
-def engine(config, seed=7, scored=False, **spec):
-    return tiny_engines.engine(glm, ENGINE, config, seed, scored, **spec)
-
-
-def reference(config, params, req, pad_to=64):
-    """The reference's logits for the positions `req` emitted from."""
-    seq = np.asarray(req.prompt + req.tokens[:-1], np.int32)
-    return glm.reference_logits(config, params, seq, pad_to)[
-        len(req.prompt) - 1:]
-
-
-def logit_error(config, params, req, got, pad_to=64):
-    """max |program - reference| over the emitted positions' logits, in
-    standard deviations of the reference's logits."""
-    r = reference(config, params, req, pad_to)
-    return float(np.abs(got - r).max() / r.std())
+TINY = tiny_engines.Tiny(glm, ref, CFG, ENGINE)

@@ -6,12 +6,9 @@ experts of which a rank's share is held, a shared expert, an untied head),
 none of its widths: a window of 8 positions, blocks of 4, 16 experts of which
 a rank holds 2."""
 
-import numpy as np
-
 import tiny_engines
-from benchmark.models import kexaone as kex  # noqa: F401
-from benchmark.models import kexaone_reference as ref  # noqa: F401
-from tiny_engines import emitted_logits, scored_engine  # noqa: F401
+from benchmark.models import kexaone as kex
+from benchmark.models import kexaone_reference as ref
 
 LAYERS = ["sliding_attention", "sliding_attention", "sliding_attention",
           "full_attention", "sliding_attention"]
@@ -30,26 +27,4 @@ CFG = dict(
     cache_dtype="bfloat16", max_len=64)
 ENGINE = {"class": "PagedKVEngine", "n_slots": 4, "max_len": 64,
           "block_size": 4, "n_blocks": 80, "n_window_blocks": 40}
-F32 = dict(weights_dtype="float32", cache_dtype="float32")
-
-
-def cfg(**over):
-    return dict(CFG, **over)
-
-
-def engine(config, seed=7, scored=False, **spec):
-    return tiny_engines.engine(kex, ENGINE, config, seed, scored, **spec)
-
-
-def reference(config, params, req, pad_to=64):
-    """The reference's logits for the positions `req` emitted from."""
-    seq = np.asarray(req.prompt + req.tokens[:-1], np.int32)
-    return kex.reference_logits(config, params, seq, pad_to)[
-        len(req.prompt) - 1:]
-
-
-def logit_error(config, params, req, got, pad_to=64):
-    """max |program - reference| over the emitted positions' logits, in
-    standard deviations of the reference's logits."""
-    r = reference(config, params, req, pad_to)
-    return float(np.abs(got - r).max() / r.std())
+TINY = tiny_engines.Tiny(kex, ref, CFG, ENGINE)

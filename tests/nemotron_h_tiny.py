@@ -6,12 +6,9 @@ key/value heads without positions; latent routed experts of which a share is
 held, two matrices an expert of a width that is no multiple of 256, a shared
 expert of its own width; an untied head), none of its widths."""
 
-import numpy as np
-
 import tiny_engines
-from benchmark.models import nemotron_h as nemo  # noqa: F401
-from benchmark.models import nemotron_h_reference as ref  # noqa: F401
-from tiny_engines import emitted_logits, scored_engine  # noqa: F401
+from benchmark.models import nemotron_h as nemo
+from benchmark.models import nemotron_h_reference as ref
 
 CFG = dict(
     model="nemotron_h", hidden_size=64, num_attention_heads=8,
@@ -28,26 +25,4 @@ CFG = dict(
     cache_dtype="bfloat16", max_len=64)
 ENGINE = {"class": "PagedKVEngine", "n_slots": 4, "max_len": 64,
           "block_size": 8, "n_blocks": 40, "n_snapshots": 4}
-F32 = dict(weights_dtype="float32", cache_dtype="float32")
-
-
-def cfg(**over):
-    return dict(CFG, **over)
-
-
-def engine(config, seed=7, scored=False, **spec):
-    return tiny_engines.engine(nemo, ENGINE, config, seed, scored, **spec)
-
-
-def reference(config, params, req, pad_to=64):
-    """The reference's logits for the positions `req` emitted from."""
-    seq = np.asarray(req.prompt + req.tokens[:-1], np.int32)
-    return nemo.reference_logits(config, params, seq, pad_to)[
-        len(req.prompt) - 1:]
-
-
-def logit_error(config, params, req, got, pad_to=64):
-    """max |program - reference| over the emitted positions' logits, in
-    standard deviations of the reference's logits."""
-    r = reference(config, params, req, pad_to)
-    return float(np.abs(got - r).max() / r.std())
+TINY = tiny_engines.Tiny(nemo, ref, CFG, ENGINE)

@@ -10,18 +10,14 @@ rounding, so the tolerance that accepts the program refuses every planted
 fault."""
 
 import dataclasses
-import json
-import os
 
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import falcon_h1_tiny as T
-from falcon_h1_tiny import falcon, ref
+import tiny_engines as E
+from falcon_h1_tiny import TINY as T, falcon, ref
 from paddle_tpu import serving
-from paddle_tpu.core import flags
-from paddle_tpu.core.enforce import InvalidArgumentError
 from paddle_tpu.models.decoder_spec import DecoderSpec, Multipliers
 from paddle_tpu.observability import tracing
 
@@ -30,19 +26,11 @@ TURNS = (5, 11, 3, 17)
 HEAD = 24           # a shared head: three blocks of 8
 
 
-def _prompts(seed=1, head=HEAD):
-    rng = np.random.default_rng(seed)
-    sys_prompt = rng.integers(0, 97, head).tolist()
-    return [sys_prompt] + [sys_prompt + rng.integers(0, 97, n).tolist()
-                           for n in TURNS]
+def _prompts(seed=1):
+    return E.prompts(TURNS, HEAD, seed)
 
 
-@pytest.fixture(scope="module")
-def exact_matmuls():
-    old = flags.get_flag("use_bf16_matmul")
-    flags.set_flag("use_bf16_matmul", False)
-    yield T.cfg(**T.F32)
-    flags.set_flag("use_bf16_matmul", old)
+exact_matmuls = E.exact_matmuls_fixture(T)
 
 
 @pytest.fixture(scope="module")
@@ -54,28 +42,19 @@ def exact(exact_matmuls):
     cfg = exact_matmuls
     eng, params = T.engine(cfg, 7, scored=True)
     prompts = _prompts()
-    runs = [T.emitted_logits(eng, prompts[0], 2)]
-    runs += [T.emitted_logits(eng, p, 10) for p in prompts[1:]]
+    runs = [E.emitted_logits(eng, prompts[0], 2)]
+    runs += [E.emitted_logits(eng, p, 10) for p in prompts[1:]]
     rng = np.random.default_rng(8)
-    runs += [T.emitted_logits(eng, rng.integers(0, 97, n).tolist(), 6)
+    runs += [E.emitted_logits(eng, rng.integers(0, 97, n).tolist(), 6)
              for n in (37, 32, 1)]
     return cfg, params, eng, runs
 
 
-def _worst(cfg, params, runs):
-    return max(T.logit_error(cfg, params, r, got) for r, got in runs)
-
-
 def test_lanes_then_decode_agree_with_the_full_forward(exact):
-    cfg, params, eng, runs = exact
-    assert eng.prefill == "chunked" and eng.chunk_tokens == 16
-    assert [r.shared_len for r, _ in runs] == [0, 24, 24, 24, 24, 0, 0, 0]
-    assert _worst(cfg, params, runs) < TOL
-    st = eng.stats()["ssm_state"]
-    assert st["restores"] == 4 == eng.pager.prefix_hits
+    E.lanes_then_decode_agree(T, exact, TOL, [0, 24, 24, 24, 24, 0, 0, 0])
     # every layer holds the state AND K/V rows
-    assert st["layers"] == st["layers_with_kv"] == 3
-    assert st["bytes_per_copy"] == falcon.spec_of(cfg).state_bytes()
+    E.state_counts(T, exact, "ssm_state", restores=4, layers=3,
+                   layers_with_kv=3)
 
 
 def test_a_prompt_ending_inside_a_chunk(exact):
@@ -88,32 +67,11 @@ def test_a_prompt_ending_inside_a_chunk(exact):
 
 
 def test_a_prefix_hit_equals_its_self_prefilled_twin(exact):
-    cfg, params, eng, runs = exact
-    alone, _ = T.engine(cfg, 7, scored=True)
-    alone.pager.prefix_sharing = False
-    for (req, got), prompt in zip(runs[1:5], _prompts()[1:]):
-        twin, twin_got = T.emitted_logits(alone, prompt, 10)
-        assert twin.shared_len == 0 and req.shared_len == 24
-        assert twin.tokens == req.tokens
-        np.testing.assert_allclose(twin_got, got, atol=2e-5)
+    E.a_prefix_hit_equals_its_twin(T, exact, _prompts())
 
 
 def test_a_hit_needs_blocks_and_a_snapshot(exact_matmuls):
-    """A prompt of 37 tokens leaves blocks 0-3 in the index and ONE snapshot,
-    at the end of block 3 (32). A second that shares 29 tokens matches three
-    blocks, none of which holds a snapshot: it prefills from position 0."""
-    cfg = exact_matmuls
-    eng, params = T.engine(cfg, 7, scored=True)
-    rng = np.random.default_rng(5)
-    first = rng.integers(0, 97, 37).tolist()
-    runs = [T.emitted_logits(eng, first, 4)]
-    shallow = first[:29] + rng.integers(0, 97, 6).tolist()
-    runs.append(T.emitted_logits(eng, shallow, 4))
-    assert runs[-1][0].shared_len == 0 and eng.pager.hits_truncated == 1
-    deep = first[:36] + rng.integers(0, 97, 6).tolist()
-    runs.append(T.emitted_logits(eng, deep, 4))
-    assert runs[-1][0].shared_len == 32
-    assert _worst(cfg, params, runs) < TOL
+    E.a_hit_is_truncated_to_the_deepest_snapshot(T, exact_matmuls, TOL)
 
 
 @pytest.mark.parametrize("fault", falcon.FAULTS)
@@ -122,35 +80,16 @@ def test_the_tolerance_catches_a_fault_planted_in_the_reference(exact, fault):
     each mechanism of the block: a mixer's output dropped, the multipliers on
     the wrong column ranges, the key multiplier left out, no rotation, a
     restore from a snapshot one chunk stale."""
-    cfg, params, _, runs = exact
-    cfg = dict(cfg, check_stale_at=HEAD, mamba_chunk_size=8)
-    with falcon.planted(fault, cfg, None) as c:
-        assert _worst(c, params, runs[1:6]) > 100 * TOL
-    assert ref.FAULT is None
-    assert _worst(cfg, params, runs) < TOL
+    E.a_planted_fault_is_caught(
+        T, exact, fault, TOL, dict(check_stale_at=HEAD, mamba_chunk_size=8),
+        factor=100, faulty=slice(1, 6))
 
 
 def test_the_tolerance_catches_a_stale_snapshot_in_the_program(exact_matmuls):
-    """The program's own restore, from an entry that holds another prompt's
-    state: the twin of the reference's `snapshot_stale`."""
     cfg = exact_matmuls
-    eng, params = T.engine(cfg, 7, scored=True)
-    prompts = _prompts()
-    T.emitted_logits(eng, prompts[0], 2)
-    T.emitted_logits(eng, _prompts(seed=9)[0], 2)
-    for j in range(len(falcon.spec_of(cfg).ssm_layers)):
-        name = f"{eng._cache_prefix}_ssm_snap_h{j}"
-        snap = eng.scope.get(name)
-        eng.scope.set_var(name, snap.at[0].set(snap[1]))
-    hit = T.emitted_logits(eng, prompts[1], 6)
-    assert hit[0].shared_len == 24
-    assert _worst(cfg, params, [hit]) > 100 * TOL
-
-
-def _committed(kind, name):
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "benchmark", kind, name + ".json")) as f:
-        return json.load(f)
+    E.a_stale_snapshot_is_caught(
+        T, cfg, TURNS, [f"_ssm_snap_h{j}" for j in range(
+            len(falcon.spec_of(cfg).ssm_layers))], 100 * TOL)
 
 
 def test_a_layer_declares_slot_state_snapshot_pool_and_kv_pools():
@@ -197,7 +136,7 @@ def test_bytes_count_both_states_in_every_layer():
     spec = falcon.spec_of(T.cfg())
     assert spec.ssm_layers == spec.attention_layers == (0, 1, 2)
     assert spec.full_layers == (0, 1, 2) and not spec.window_layers
-    big_cfg = _committed("configs", "falcon-h1-34b-pp12")
+    big_cfg = E.committed("configs", "falcon-h1-34b-pp12")
     big = falcon.spec_of(big_cfg)
     assert big.cache_row_bytes() == 12288            # a position, six layers
     assert big.state_bytes() == 6 * (32 * 128 * 256 * 4 + 3 * 5120 * 2)
@@ -259,22 +198,13 @@ def test_setup_counters_say_how_many_bodies_the_layers_traced():
         assert counts["ssm/body_traced", "ssm_decode_update"] == 1
 
 
-@pytest.mark.parametrize("option, value", [
-    ("speculative", serving.SpecConfig(gamma=2)),
-    ("host_tier", serving.HostTierConfig()),
-    ("kv_quant", True), ("quant", "int8"), ("topk_k", 4)])
+@pytest.mark.parametrize("option, value", E.REFUSED)
 def test_what_is_not_built_for_the_model_is_refused_by_name(option, value):
-    with pytest.raises(InvalidArgumentError,
-                       match=option + "=.*state-space state"):
-        serving.PagedKVEngine(n_slots=2, max_len=32, block_size=8,
-                              n_snapshots=2, model=falcon.spec_of(T.cfg()),
-                              **{option: value})
+    E.refused_by_name(T, option, value, "state-space state", n_snapshots=2)
 
 
 def test_an_engine_without_a_snapshot_pool_is_refused():
-    with pytest.raises(InvalidArgumentError, match="n_snapshots"):
-        serving.PagedKVEngine(n_slots=2, max_len=32, block_size=8,
-                              model=falcon.spec_of(T.cfg()))
+    E.without_a_snapshot_pool_is_refused(T)
 
 
 def test_a_spec_is_two_mixers_a_layer_and_nothing_else_beside_them():
@@ -315,7 +245,7 @@ def test_every_branch_has_unit_scale_after_its_multiplier(exact_matmuls):
     unit variance, and the state-space output projection is centred."""
     import jax
     cfg = exact_matmuls
-    scope = T.tiny_engines.weights(falcon, cfg, 7)
+    scope = E.weights(falcon, cfg, 7)
     params = {n: scope.get(n) for n in falcon.param_names(cfg)}
     tokens = np.random.default_rng(0).integers(0, 97, 64)
     c = dict(cfg)

@@ -17,11 +17,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import glm_tiny as T
-from glm_tiny import glm, ref
-from paddle_tpu import serving
-from paddle_tpu.core import flags
-from paddle_tpu.core.enforce import InvalidArgumentError
+import tiny_engines as E
+from glm_tiny import TINY as T, glm, ref
 from paddle_tpu.fusion import hyper_connection, moe
 from paddle_tpu.models.decoder_spec import (HyperSpec, IndexerSpec,
                                             RopeSpec)
@@ -32,63 +29,30 @@ TURNS = (5, 11, 3, 17)
 HEAD = 24           # the shared context: three blocks of 8, six groups of 4
 
 
-def _prompts(seed=1, head=HEAD):
-    rng = np.random.default_rng(seed)
-    sys_prompt = rng.integers(0, 97, head).tolist()
-    return [sys_prompt] + [sys_prompt + rng.integers(0, 97, n).tolist()
-                           for n in TURNS]
+def _prompts(seed=1):
+    return E.prompts(TURNS, HEAD, seed)
 
 
-@pytest.fixture(scope="module")
-def exact_matmuls():
-    old = flags.get_flag("use_bf16_matmul")
-    flags.set_flag("use_bf16_matmul", False)
-    yield T.cfg(**T.F32)
-    flags.set_flag("use_bf16_matmul", old)
-
-
-@pytest.fixture(scope="module")
-def exact(exact_matmuls):
-    """float32 weights, pools, state and matmuls. The shared context alone
-    first (as the benchmark's warm-up sends it), then four turns behind it:
-    prompts of 29, 35, 27 and 41 tokens cross a chunk of 16, end inside a
-    group of 4 (29 = 7 groups + 1, 35 = 8 + 3, 27 = 6 + 3, 41 = 10 + 1) and
-    decode ten tokens across two more group boundaries; `index_topk` 8 keeps
-    2 of up to 12 whole groups, so every row past position 11 drops some."""
-    cfg = exact_matmuls
-    eng, params = T.engine(cfg, 7, scored=True)
-    prompts = _prompts()
-    runs = [T.emitted_logits(eng, prompts[0], 2)]
-    runs += [T.emitted_logits(eng, p, 10) for p in prompts[1:]]
-    return cfg, params, eng, runs
-
-
-def _worst(cfg, params, runs):
-    return max(T.logit_error(cfg, params, r, got) for r, got in runs)
+exact_matmuls = E.exact_matmuls_fixture(T)
+# The shared context alone first (as the benchmark's warm-up sends it), then
+# four turns behind it: prompts of 29, 35, 27 and 41 tokens cross a chunk of
+# 16, end inside a group of 4 (29 = 7 groups + 1, 35 = 8 + 3, 27 = 6 + 3,
+# 41 = 10 + 1) and decode ten tokens across two more group boundaries;
+# `index_topk` 8 keeps 2 of up to 12 whole groups, so every row past position
+# 11 drops some.
+exact = E.exact_fixture(T, TURNS, HEAD)
 
 
 def test_lanes_then_decode_agree_with_the_full_forward(exact):
-    cfg, params, eng, runs = exact
-    assert eng.prefill == "chunked" and eng.chunk_tokens == 16
-    assert [r.shared_len for r, _ in runs] == [0, 24, 24, 24, 24]
-    assert _worst(cfg, params, runs) < TOL
-    st = eng.stats()["ssm_state"]
-    assert st["restores"] == 4 == eng.pager.prefix_hits
-    assert st["layers"] == 4 and st["layers_with_kv"] == 0
-    assert st["bytes_per_copy"] == glm.spec_of(cfg).state_bytes()
+    E.lanes_then_decode_agree(T, exact, TOL, [0, 24, 24, 24, 24])
+    E.state_counts(T, exact, "ssm_state", restores=4, layers=4,
+                   layers_with_kv=0)
 
 
 def test_a_prefix_hit_equals_its_self_prefilled_twin(exact):
     """The hit brings the latent blocks, the POOLED index keys of the same
     blocks and the kda state; the twin writes all three itself."""
-    cfg, params, eng, runs = exact
-    alone, _ = T.engine(cfg, 7, scored=True)
-    alone.pager.prefix_sharing = False
-    for (req, got), prompt in zip(runs[1:], _prompts()[1:]):
-        twin, twin_got = T.emitted_logits(alone, prompt, 10)
-        assert twin.shared_len == 0 and req.shared_len == 24
-        assert twin.tokens == req.tokens
-        np.testing.assert_allclose(twin_got, got, atol=2e-5)
+    E.a_prefix_hit_equals_its_twin(T, exact, _prompts())
 
 
 def test_the_sparse_layer_is_dense_while_the_groups_fit_the_top_k(
@@ -98,29 +62,14 @@ def test_the_sparse_layer_is_dense_while_the_groups_fit_the_top_k(
     (the reference with the selection ignored)."""
     cfg = dict(exact_matmuls, index_topk=64)
     eng, params = T.engine(cfg, 7, scored=True)
-    runs = [T.emitted_logits(eng, p, 10) for p in _prompts()[3:]]
+    runs = [E.emitted_logits(eng, p, 10) for p in _prompts()[3:]]
     with glm.planted("selection_ignored", cfg, None) as c:
-        assert _worst(c, params, runs) < TOL
-    assert _worst(cfg, params, runs) < TOL
+        assert T.worst(c, params, runs) < TOL
+    assert T.worst(cfg, params, runs) < TOL
 
 
 def test_a_request_preempted_and_resumed_reads_the_same(exact_matmuls):
-    cfg = exact_matmuls
-    eng, params = T.engine(cfg, 7, scored=True, n_blocks=9, n_slots=2)
-    prompts = _prompts()
-    a = eng.submit(prompts[2], 12)
-    b = eng.submit(prompts[4], 12)
-    waited = 0
-    while not (a.done and b.done):
-        eng.step()
-        waited += eng.n_pending
-    assert waited > 0                   # b was held back for blocks
-    assert a.error is None and b.error is None
-    fresh, _ = T.engine(cfg, 7, scored=True)
-    for req, prompt in ((a, prompts[2]), (b, prompts[4])):
-        twin, _ = T.emitted_logits(fresh, prompt, 12)
-        assert twin.tokens == req.tokens
-    eng.pager.pool.check()
+    E.a_preempted_request_reads_the_same(T, exact_matmuls, TURNS)
 
 
 @pytest.mark.parametrize("fault", glm.FAULTS)
@@ -130,12 +79,9 @@ def test_the_tolerance_catches_a_fault_planted_in_the_reference(exact, fault):
     dynamic part dropped, the streams collapsed to one, the tail not
     selected, the selection ignored, a group's key its first position's, the
     indexer unrotated, the clamp dropped, a restore one chunk stale."""
-    cfg, params, _, runs = exact
-    cfg = dict(cfg, system_prompt_tokens=HEAD, chunk_size=8)
-    with glm.planted(fault, cfg, None) as c:
-        assert _worst(c, params, runs[1:]) > 10 * TOL
-    assert ref.FAULT is None
-    assert _worst(cfg, params, runs[:2]) < TOL
+    E.a_planted_fault_is_caught(
+        T, exact, fault, TOL, dict(system_prompt_tokens=HEAD, chunk_size=8),
+        clean=slice(2))
 
 
 def test_the_tolerance_catches_a_stale_index_pool_in_the_program(
@@ -145,13 +91,13 @@ def test_the_tolerance_catches_a_stale_index_pool_in_the_program(
     cfg = exact_matmuls
     eng, params = T.engine(cfg, 7, scored=True)
     prompts = _prompts()
-    T.emitted_logits(eng, prompts[0], 2)
+    E.emitted_logits(eng, prompts[0], 2)
     name = f"{eng._cache_prefix}_ci3"
     assert name in eng.cache_names
     eng.scope.set_var(name, jnp.zeros_like(eng.scope.get(name)))
-    hit = T.emitted_logits(eng, prompts[4], 6)
+    hit = E.emitted_logits(eng, prompts[4], 6)
     assert hit[0].shared_len == 24
-    assert _worst(cfg, params, [hit]) > 10 * TOL
+    assert T.worst(cfg, params, [hit]) > 10 * TOL
 
 
 def test_a_shared_contexts_snapshot_outlives_the_one_off_ones(exact_matmuls):
@@ -166,19 +112,19 @@ def test_a_shared_contexts_snapshot_outlives_the_one_off_ones(exact_matmuls):
     rng = np.random.default_rng(3)
     a, b = (rng.integers(0, 97, HEAD).tolist() for _ in range(2))
     for context in (a, b):
-        T.emitted_logits(eng, context, 2)
+        E.emitted_logits(eng, context, 2)
     for _ in range(5):
-        req, _ = T.emitted_logits(eng, a + rng.integers(0, 97, 11).tolist(), 2)
+        req, _ = E.emitted_logits(eng, a + rng.integers(0, 97, 11).tolist(), 2)
         assert req.shared_len == HEAD
     assert eng.pager.snapshot_evictions >= 3
-    run = T.emitted_logits(eng, b + rng.integers(0, 97, 5).tolist(), 6)
+    run = E.emitted_logits(eng, b + rng.integers(0, 97, 5).tolist(), 6)
     assert run[0].shared_len == HEAD
-    assert _worst(cfg, params, [run]) < TOL
+    assert T.worst(cfg, params, [run]) < TOL
     # ... and once restored from, it is proven: the next pool-fuls of one-off
     # entries, shallower ones among them, do not move it
     for n in (1, 9, 1, 9, 1, 9):
-        T.emitted_logits(eng, rng.integers(0, 97, 16 + n).tolist(), 2)
-    assert T.emitted_logits(eng, b + [5, 6], 2)[0].shared_len == HEAD
+        E.emitted_logits(eng, rng.integers(0, 97, 16 + n).tolist(), 2)
+    assert E.emitted_logits(eng, b + [5, 6], 2)[0].shared_len == HEAD
 
 
 def test_bfloat16_engine_keeps_its_pools_and_state_as_stated():
@@ -306,16 +252,9 @@ def test_sinkhorn_leaves_the_stream_map_doubly_stochastic():
     assert float(h_post.min()) > 0 and float(h_post.max()) < 2
 
 
-@pytest.mark.parametrize("option, value", [
-    ("speculative", serving.SpecConfig(gamma=2)),
-    ("host_tier", serving.HostTierConfig()),
-    ("kv_quant", True), ("quant", "int8"), ("topk_k", 4)])
+@pytest.mark.parametrize("option, value", E.REFUSED)
 def test_what_is_not_built_for_the_model_is_refused_by_name(option, value):
-    with pytest.raises(InvalidArgumentError,
-                       match=option + "=.*indexer's pool"):
-        serving.PagedKVEngine(n_slots=2, max_len=32, block_size=8,
-                              n_snapshots=2, model=glm.spec_of(T.cfg()),
-                              **{option: value})
+    E.refused_by_name(T, option, value, "indexer's pool", n_snapshots=2)
 
 
 def test_the_spec_raises_for_what_no_graph_builds():
@@ -347,7 +286,7 @@ def test_an_unrotated_latent_row_without_an_indexer_needs_no_positions():
 
 def test_the_routers_bias_sends_this_rank_its_share():
     cfg = T.cfg(**T.F32, max_len=512)
-    scope = glm.build_weights(cfg, 11)
+    scope = E.weights(glm, cfg, 11)
     params = {n: scope.get(n) for n in glm.param_names(cfg)}
     tokens = np.random.default_rng(0).integers(0, 97, 512)
     x = jnp.asarray(params["tok_emb"])[tokens].astype(jnp.float32)

@@ -15,8 +15,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import kexaone_tiny as T
-from kexaone_tiny import kex, ref
+import tiny_engines as E
+from kexaone_tiny import TINY as T, kex, ref
 from paddle_tpu import serving
 from paddle_tpu.core import flags
 from paddle_tpu.core.enforce import InvalidArgumentError
@@ -31,35 +31,13 @@ NEW = 26            # an answer runs past three windows more
 
 
 def _prompts(seed=1, head=HEAD):
-    rng = np.random.default_rng(seed)
-    sys_prompt = rng.integers(0, 97, head).tolist()
-    return [sys_prompt] + [sys_prompt + rng.integers(0, 97, n).tolist()
-                           for n in TURNS]
+    return E.prompts(TURNS, head, seed)
 
 
-@pytest.fixture(scope="module")
-def exact_matmuls():
-    old = flags.get_flag("use_bf16_matmul")
-    flags.set_flag("use_bf16_matmul", False)
-    yield T.cfg(**T.F32)
-    flags.set_flag("use_bf16_matmul", old)
-
-
-@pytest.fixture(scope="module")
-def exact(exact_matmuls):
-    """float32 weights, pools and matmuls: the program against the reference
-    with nothing but float32 rounding between them. The context alone first
-    (as the benchmark's warm-up sends it), then four turns behind it."""
-    cfg = exact_matmuls
-    eng, params = T.engine(cfg, 7, scored=True)
-    prompts = _prompts()
-    runs = [T.emitted_logits(eng, prompts[0], 2)]
-    runs += [T.emitted_logits(eng, p, NEW) for p in prompts[1:]]
-    return cfg, params, eng, runs
-
-
-def _worst(cfg, params, runs):
-    return max(T.logit_error(cfg, params, r, got) for r, got in runs)
+exact_matmuls = E.exact_matmuls_fixture(T)
+# the context alone first (as the benchmark's warm-up sends it), then four
+# turns behind it
+exact = E.exact_fixture(T, TURNS, HEAD, new=NEW)
 
 
 def test_chunks_then_decode_past_three_windows_agree_with_the_full_forward(
@@ -72,7 +50,7 @@ def test_chunks_then_decode_past_three_windows_agree_with_the_full_forward(
     assert [spec.rotates(i) for i in range(5)] == [True] * 3 + [False, True]
     # the warm-up prefilled the context; every turn started from all of it
     assert [r.shared_len for r, _ in runs] == [0, 24, 24, 24, 24]
-    assert _worst(cfg, params, runs) < TOL
+    assert T.worst(cfg, params, runs) < TOL
     w = eng.stats()["window_pool"]
     assert w["tail_lookups"] == 4 == w["tail_hits"] and not w["hits_truncated"]
     assert w["blocks_released"] > 4 * (HEAD + NEW - 8) // 4 - 8
@@ -85,14 +63,7 @@ def test_chunks_then_decode_past_three_windows_agree_with_the_full_forward(
 
 
 def test_a_prefix_hit_equals_its_self_prefilled_twin(exact):
-    cfg, params, eng, runs = exact
-    alone, _ = T.engine(cfg, 7, scored=True)
-    alone.pager.prefix_sharing = False
-    for (req, got), prompt in zip(runs[1:], _prompts()[1:]):
-        twin, twin_got = T.emitted_logits(alone, prompt, NEW)
-        assert twin.shared_len == 0 and req.shared_len == 24
-        assert twin.tokens == req.tokens
-        np.testing.assert_allclose(twin_got, got, atol=2e-5)
+    E.a_prefix_hit_equals_its_twin(T, exact, _prompts(), new=NEW)
 
 
 def _holders(eng, reqs):
@@ -147,14 +118,14 @@ def test_a_hit_is_cut_where_the_spans_window_tail_is_gone(exact_matmuls):
     cfg = exact_matmuls
     eng, params = T.engine(cfg, 7, scored=True)
     prompts = _prompts(seed=3)
-    runs = [T.emitted_logits(eng, prompts[0], 2)]
+    runs = [E.emitted_logits(eng, prompts[0], 2)]
     pager = eng.pager
     assert sorted(pager.stats()["window"].items())
     assert len(pager._tails) == 3
     node = pager.index.node_of(prompts[0], 4)
     assert node.wblock is not None
     pager._drop_tail(node)
-    runs.append(T.emitted_logits(eng, prompts[1], 12))
+    runs.append(E.emitted_logits(eng, prompts[1], 12))
     req = runs[-1][0]
     # spans of 6 and 5 blocks read block 4; a span of 4 reads blocks 2 and 3,
     # and block 2 was never a tail: nothing is handed out, never a wrong span
@@ -162,9 +133,9 @@ def test_a_hit_is_cut_where_the_spans_window_tail_is_gone(exact_matmuls):
     assert pager.window_tail_lookups == 1 and pager.window_tail_hits == 0
     # the request prefilled the context again and gave the node its tail back
     assert pager.index.node_of(prompts[0], 4).wblock is not None
-    runs.append(T.emitted_logits(eng, prompts[2], 12))
+    runs.append(E.emitted_logits(eng, prompts[2], 12))
     assert runs[-1][0].shared_len == 24 and pager.window_tail_hits == 1
-    assert _worst(cfg, params, runs) < TOL
+    assert T.worst(cfg, params, runs) < TOL
     pager.pool.check()
     pager.check_window()
 
@@ -177,18 +148,18 @@ def test_tails_are_evicted_under_window_pool_pressure_and_with_their_nodes(
                            n_window_blocks=2 * 7 + 1)
     rng = np.random.default_rng(6)
     heads = [rng.integers(0, 97, HEAD).tolist() for _ in range(6)]
-    runs = [T.emitted_logits(eng, h, 2) for h in heads]
+    runs = [E.emitted_logits(eng, h, 2) for h in heads]
     pager = eng.pager
     # six spans' tails are 18 blocks, the pool has 14: the oldest went alone
     assert pager.window_tail_evictions >= 4 and len(pager._tails) <= 14
     assert pager.index.n_cached == 36           # ... and every node stayed
     turn = rng.integers(0, 97, 5).tolist()
-    late, _ = runs.append(T.emitted_logits(eng, heads[-1] + turn, 10)) \
+    late, _ = runs.append(E.emitted_logits(eng, heads[-1] + turn, 10)) \
         or runs[-1]
-    early, _ = runs.append(T.emitted_logits(eng, heads[0] + turn, 10)) \
+    early, _ = runs.append(E.emitted_logits(eng, heads[0] + turn, 10)) \
         or runs[-1]
     assert late.shared_len == 24 and early.shared_len == 0
-    assert _worst(cfg, params, runs) < TOL
+    assert T.worst(cfg, params, runs) < TOL
     # under FULL-pool pressure a node goes leaf first and takes its tail
     # along: with every node gone no tail is left, and neither pool holds one
     assert pager._tails and pager.index.evict_all(pager.pool) == 38
@@ -351,11 +322,11 @@ def test_the_q_and_k_norms_scales_are_seeded_where_the_configuration_says():
     """`qk_norm_init` (the committed configuration's `assumed.init`: at 1 a
     softmax over 16k keys is flat and nothing the full layer does reaches
     the logits): the q and k norms' scales alone, every other norm's 1."""
-    scope = kex.build_weights(T.cfg(qk_norm_init=1.6, **T.F32), 3)
+    scope = E.weights(kex, T.cfg(qk_norm_init=1.6, **T.F32), 3)
     for name in kex.param_names(T.cfg()):
         if name.endswith(".scale"):
             want = 1.6 if name.endswith(("_q_norm.scale", "_k_norm.scale")) \
                 else 1.0
             assert np.allclose(np.asarray(scope.get(name)), want), name
-    plain = kex.build_weights(T.cfg(**T.F32), 3)
+    plain = E.weights(kex, T.cfg(**T.F32), 3)
     assert np.allclose(np.asarray(plain.get("l3_attn_q_norm.scale")), 1.0)

@@ -20,9 +20,9 @@ import time
 import numpy as np
 import pytest
 
-import axk1_tiny
-import lfm2_tiny
-import nemotron_h_tiny
+from axk1_tiny import TINY as AXK1
+from lfm2_tiny import TINY as LFM2
+from nemotron_h_tiny import TINY as NEMOTRON_H
 import paddle_tpu as pt
 from paddle_tpu import serving
 from paddle_tpu.core import flags, unique_name
@@ -30,7 +30,6 @@ from paddle_tpu.observability import tracing
 from paddle_tpu.serving import EngineClient, EngineServer
 from paddle_tpu.serving.kv_pager import HostTierConfig
 
-_F32 = dict(weights_dtype="float32", cache_dtype="float32")
 _DIMS = dict(vocab=50, d_model=32, d_inner=64, num_heads=4, num_layers=2)
 
 
@@ -48,18 +47,18 @@ def _classic(cls=serving.PagedKVEngine, **kw):
     return make
 
 
-def _tiny(mod):
-    config = mod.cfg(**_F32)
-    return lambda: mod.engine(config, 7, n_slots=6, n_blocks=56)[0]
+def _tiny(tiny):
+    config = tiny.cfg(**tiny.F32)
+    return lambda: tiny.engine(config, 7, n_slots=6, n_blocks=56)[0]
 
 
 KINDS = {
     "classic": lambda: _classic(),
     "classic_one_token": lambda: _classic(kv_quant=True),
     "slot": lambda: _classic(serving.ContinuousBatchingEngine),
-    "latent_moe": lambda: _tiny(axk1_tiny),
-    "conv_gqa": lambda: _tiny(lfm2_tiny),
-    "ssm": lambda: _tiny(nemotron_h_tiny),
+    "latent_moe": lambda: _tiny(AXK1),
+    "conv_gqa": lambda: _tiny(LFM2),
+    "ssm": lambda: _tiny(NEMOTRON_H),
 }
 ROUTED = ("latent_moe", "conv_gqa", "ssm")
 

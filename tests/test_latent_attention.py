@@ -10,8 +10,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import axk1_tiny as T
-from axk1_tiny import axk1, ref  # noqa: F401
+import tiny_engines as E
+from axk1_tiny import TINY as T, axk1, ref
 from test_grouped_paged_attention import _pallas_calls
 from paddle_tpu.fusion import latent_attention as la
 from paddle_tpu.models import transformer
@@ -252,20 +252,20 @@ def test_a_prefix_hit_and_a_miss_give_the_same_logits():
     old = flags.get_flag("use_bf16_matmul")
     flags.set_flag("use_bf16_matmul", False)
     try:
-        cfg = T.cfg(weights_dtype="float32", cache_dtype="float32")
-        scope = axk1.build_weights(cfg, 11)
+        cfg = T.cfg(**T.F32)
+        scope = E.weights(axk1, cfg, 11)
 
         def engine(share):
-            return T.scored_engine(
+            return E.scored_engine(
                 n_slots=4, max_len=64, block_size=8, n_blocks=40, scope=scope,
                 model=axk1.spec_of(cfg), prefix_sharing=share)
         rng = np.random.default_rng(2)
         doc = rng.integers(0, 97, 32).tolist()
         ask = doc + rng.integers(0, 97, 9).tolist()
         hit_eng = engine(True)
-        T.emitted_logits(hit_eng, doc, 2)             # the document resident
-        hit, hit_logits = T.emitted_logits(hit_eng, ask, 8)
-        miss, miss_logits = T.emitted_logits(engine(False), ask, 8)
+        E.emitted_logits(hit_eng, doc, 2)             # the document resident
+        hit, hit_logits = E.emitted_logits(hit_eng, ask, 8)
+        miss, miss_logits = E.emitted_logits(engine(False), ask, 8)
         assert (hit.shared_len, miss.shared_len) == (32, 0)
         assert hit.tokens == miss.tokens
         np.testing.assert_allclose(hit_logits, miss_logits, atol=1e-5)

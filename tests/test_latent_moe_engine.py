@@ -8,49 +8,25 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import axk1_tiny as T
-import tiny_engines
-from axk1_tiny import axk1
+import tiny_engines as E
+from axk1_tiny import TINY as T, axk1
 from paddle_tpu import serving
-from paddle_tpu.core import flags
-from paddle_tpu.core.enforce import InvalidArgumentError
 from paddle_tpu.observability import tracing
 
 TOL = 1e-4          # in standard deviations of the reference's logits
-F32 = dict(weights_dtype="float32", cache_dtype="float32")
 
 
-@pytest.fixture(scope="module")
-def exact():
-    """float32 weights, cache and matmuls: the program against the
-    reference with nothing but float32 rounding between them."""
-    old = flags.get_flag("use_bf16_matmul")
-    flags.set_flag("use_bf16_matmul", False)
-    cfg = T.cfg(**F32)
-    scope = tiny_engines.weights(axk1, cfg, 7)
-    eng = T.scored_engine(
-        n_slots=4, max_len=64, block_size=8, n_blocks=40, scope=scope,
-        model=axk1.spec_of(cfg))
-    params = {n: scope.get(n) for n in axk1.param_names(cfg)}
-    rng = np.random.default_rng(1)
-    doc = rng.integers(0, 97, 24).tolist()
-    runs = [T.emitted_logits(eng, doc + rng.integers(0, 97, n).tolist(), 10)
-            for n in (5, 11, 3, 17)]
-    yield cfg, params, eng, runs
-    flags.set_flag("use_bf16_matmul", old)
-
-
-def _worst(cfg, params, runs):
-    return max(T.logit_error(cfg, params, r, got) for r, got in runs)
+exact_matmuls = E.exact_matmuls_fixture(T)
+# a document of 24 tokens and four questions behind it: the first request
+# prefills the document itself
+exact = E.exact_fixture(T, (5, 11, 3, 17), alone=False)
 
 
 def test_lanes_then_decode_agree_with_the_full_forward(exact):
-    cfg, params, eng, runs = exact
-    assert eng.prefill == "chunked" and eng.chunk_tokens == 16
     # the first request prefilled its document itself, the others hit it
-    assert [r.shared_len for r, _ in runs] == [0, 24, 24, 24]
+    E.lanes_then_decode_agree(T, exact, TOL, [0, 24, 24, 24])
+    runs = exact[3]
     assert all(len(r.tokens) == 10 for r, _ in runs)
-    assert _worst(cfg, params, runs) < TOL
     # positions run past YaRN's original length (16) and past one chunk
     assert max(len(r.prompt) + len(r.tokens) for r, _ in runs) > 48
 
@@ -81,15 +57,7 @@ def _fp16_latent(cfg, params):
                                    _fp16_latent])
 def test_the_tolerance_catches_a_planted_fault(exact, fault):
     cfg, params, _, runs = exact
-    assert _worst(*fault(cfg, params), runs) > 10 * TOL
-
-
-def _committed(kind, name):
-    import json
-    import os
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "benchmark", kind, name + ".json")) as f:
-        return json.load(f)
+    assert T.worst(*fault(cfg, params), runs) > 10 * TOL
 
 
 @pytest.mark.parametrize("seed", [2, 4, 7])
@@ -103,8 +71,8 @@ def test_bfloat16_engine_passes_the_cells_comparison_and_the_control_fails(
     and 4 have one, 7 has none); the envelope over the selections the scores
     leave open explains it, and explains nothing of a reference computed
     one precision below."""
-    tol = _committed("cells", "axk1-ep16_serve_docqa")["logit_gap_tol"]
-    margin = _committed("configs", "axk1-ep16")["router_tie_margin"]
+    tol = E.committed("cells", "axk1-ep16_serve_docqa")["logit_gap_tol"]
+    margin = E.committed("configs", "axk1-ep16")["router_tie_margin"]
     cfg = T.cfg()
     eng, params = T.engine(cfg, seed)
     rng = np.random.default_rng(1)
@@ -186,15 +154,9 @@ def test_a_tick_counts_the_rows_its_experts_got():
     assert 0 < rows.sum() <= 2 * 4 * (len(req.prompt) + 4)
 
 
-@pytest.mark.parametrize("option, value", [
-    ("speculative", serving.SpecConfig(gamma=2)),
-    ("host_tier", serving.HostTierConfig()),
-    ("kv_quant", True), ("quant", "int8"), ("topk_k", 4)])
+@pytest.mark.parametrize("option, value", E.REFUSED)
 def test_what_is_not_built_for_the_model_is_refused_by_name(option, value):
-    cfg = T.cfg()
-    with pytest.raises(InvalidArgumentError, match=option + "="):
-        serving.PagedKVEngine(n_slots=2, max_len=32, block_size=8,
-                              model=axk1.spec_of(cfg), **{option: value})
+    E.refused_by_name(T, option, value)
 
 
 def test_the_six_dims_are_the_classic_spec():

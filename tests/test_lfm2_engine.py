@@ -8,77 +8,41 @@ rounding, so the tolerance that accepts the program refuses every planted
 fault."""
 
 import dataclasses
-import json
-import os
 import types
 
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import lfm2_tiny as T
-import tiny_engines
-from lfm2_tiny import lfm2, ref
+import tiny_engines as E
+from lfm2_tiny import DEEP, TINY as T, lfm2, ref
 from paddle_tpu import serving
-from paddle_tpu.core import flags
-from paddle_tpu.core.enforce import InvalidArgumentError
 from paddle_tpu.observability import tracing
 
 TOL = 1e-4          # in standard deviations of the reference's logits
 TURNS = (5, 11, 3, 17)
 
 
-def _prompts(seed=1):
-    rng = np.random.default_rng(seed)
-    head = rng.integers(0, 97, 24).tolist()
-    return [head + rng.integers(0, 97, n).tolist() for n in TURNS]
+def _prompts():
+    return E.prompts(TURNS, alone=False)
 
 
-@pytest.fixture(scope="module")
-def exact_matmuls():
-    old = flags.get_flag("use_bf16_matmul")
-    flags.set_flag("use_bf16_matmul", False)
-    yield T.cfg(**T.F32)
-    flags.set_flag("use_bf16_matmul", old)
-
-
-@pytest.fixture(scope="module")
-def exact(exact_matmuls):
-    """float32 weights, pools, state and matmuls: the program against the
-    reference with nothing but float32 rounding between them."""
-    cfg = exact_matmuls
-    eng, params = T.engine(cfg, 7, scored=True)
-    runs = [T.emitted_logits(eng, p, 10) for p in _prompts()]
-    return cfg, params, eng, runs
-
-
-def _worst(cfg, params, runs):
-    return max(T.logit_error(cfg, params, r, got) for r, got in runs)
+exact_matmuls = E.exact_matmuls_fixture(T)
+exact = E.exact_fixture(T, TURNS, alone=False)
 
 
 def test_lanes_then_decode_agree_with_the_full_forward(exact):
-    cfg, params, eng, runs = exact
-    assert eng.prefill == "chunked" and eng.chunk_tokens == 16
     # the first request prefilled the preamble itself, the others resumed
     # from its third block's K/V and state snapshot
-    assert [r.shared_len for r, _ in runs] == [0, 24, 24, 24]
-    assert all(len(r.tokens) == 10 for r, _ in runs)
-    assert _worst(cfg, params, runs) < TOL
-    st = eng.stats()["conv_state"]
-    assert st["restores"] == 3 == eng.pager.prefix_hits
+    E.lanes_then_decode_agree(T, exact, TOL, [0, 24, 24, 24])
+    assert all(len(r.tokens) == 10 for r, _ in exact[3])
     # prompts of 29, 35, 27, 41 tokens: 3 + 1 + 0 + 2 blocks filled by lanes
-    assert st["snapshots"] == 6 == st["blocks_with_snapshot"]
+    E.state_counts(T, exact, "conv_state", restores=3, snapshots=6,
+                   blocks_with_snapshot=6)
 
 
 def test_a_prefix_hit_equals_its_self_prefilled_twin(exact):
-    cfg, params, eng, runs = exact
-    alone, _ = T.engine(cfg, 7, scored=True)
-    alone.pager.prefix_sharing = False
-    for (req, got), prompt in zip(runs[1:], _prompts()[1:]):
-        twin, twin_got = T.emitted_logits(alone, prompt, 10)
-        assert twin.shared_len == 0 and req.shared_len == 24
-        assert twin.tokens == req.tokens
-        np.testing.assert_allclose(twin_got, got, atol=1e-5)
+    E.a_prefix_hit_equals_its_twin(T, exact, _prompts(), atol=1e-5)
 
 
 @pytest.mark.parametrize("fault", ["bias_dropped", "bias_in_the_weights",
@@ -91,9 +55,9 @@ def test_the_tolerance_catches_a_fault_planted_in_the_reference(exact, fault):
     held = dict(params)
     scope = types.SimpleNamespace(get=held.get, set_var=held.__setitem__)
     with lfm2.planted(fault, cfg, scope) as c:
-        assert _worst(c, held, runs) > 10 * TOL
+        assert T.worst(c, held, runs) > 10 * TOL
     assert all(held[n] is params[n] for n in params)       # and put back
-    assert _worst(cfg, held, runs) < TOL
+    assert T.worst(cfg, held, runs) < TOL
 
 
 @pytest.mark.parametrize("fault", ["qk_norm", "tied_head"])
@@ -102,25 +66,25 @@ def test_the_tolerance_catches_a_fault_planted_in_the_program(
     """The program built without the RMSNorm on q and k, or with a head of
     its own in place of the embedding."""
     cfg = exact_matmuls
-    scope = tiny_engines.weights(lfm2, cfg, 7)
+    scope = E.weights(lfm2, cfg, 7)
     spec = dataclasses.replace(lfm2.spec_of(cfg), **{fault: False})
-    eng = T.scored_engine(n_slots=4, max_len=64, block_size=8, n_blocks=40,
+    eng = E.scored_engine(n_slots=4, max_len=64, block_size=8, n_blocks=40,
                           scope=scope, model=spec)
     params = {n: scope.get(n) for n in lfm2.param_names(cfg)}
-    run = T.emitted_logits(eng, _prompts()[0], 6)
-    assert _worst(cfg, params, [run]) > 10 * TOL
+    run = E.emitted_logits(eng, _prompts()[0], 6)
+    assert T.worst(cfg, params, [run]) > 10 * TOL
 
 
 def test_the_tolerance_catches_a_state_zeroed_on_a_hit(exact_matmuls):
     cfg = exact_matmuls
     eng, params = T.engine(cfg, 7, scored=True)
-    first = T.emitted_logits(eng, _prompts()[0], 4)
-    assert _worst(cfg, params, [first]) < TOL
+    first = E.emitted_logits(eng, _prompts()[0], 4)
+    assert T.worst(cfg, params, [first]) < TOL
     name = eng._cache_prefix + "_conv_block"
     eng.scope.set_var(name, jnp.zeros_like(eng.scope.get(name)))
-    hit = T.emitted_logits(eng, _prompts()[1], 6)
+    hit = E.emitted_logits(eng, _prompts()[1], 6)
     assert hit[0].shared_len == 24
-    assert _worst(cfg, params, [hit]) > 10 * TOL
+    assert T.worst(cfg, params, [hit]) > 10 * TOL
 
 
 def test_a_snapshots_block_is_evicted_and_refilled_under_pool_pressure(
@@ -133,12 +97,12 @@ def test_a_snapshots_block_is_evicted_and_refilled_under_pool_pressure(
     rng = np.random.default_rng(3)
     heads = [rng.integers(0, 97, 24).tolist() for _ in range(3)]
     turn = rng.integers(0, 97, 5).tolist()
-    runs = [T.emitted_logits(eng, h + turn, 8) for h in heads]
+    runs = [E.emitted_logits(eng, h + turn, 8) for h in heads]
     assert eng.pager.evictions > 0
-    again = T.emitted_logits(eng, heads[0] + turn, 8)
+    again = E.emitted_logits(eng, heads[0] + turn, 8)
     assert again[0].shared_len < 24            # its blocks were evicted
     assert again[0].tokens == runs[0][0].tokens
-    assert _worst(cfg, params, runs + [again]) < TOL
+    assert T.worst(cfg, params, runs + [again]) < TOL
     pager = eng.pager
     pager.pool.check()
     # a snapshot is valid only on a block somebody holds
@@ -147,20 +111,14 @@ def test_a_snapshots_block_is_evicted_and_refilled_under_pool_pressure(
     assert pager.sanitizer is None or pager.sanitizer.full_checks > 0
 
 
-def _committed(kind, name):
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "benchmark", kind, name + ".json")) as f:
-        return json.load(f)
-
-
 def _cell_cfg():
     """The tiny configuration at twelve layers with what the committed one
     gives the cell's comparison. ONE dict for every test that serves it: the
     weights are kept per configuration (tests/tiny_engines.py), and a key
     more makes another."""
-    config = _committed("configs", "lfm2-8b-a1b")
+    config = E.committed("configs", "lfm2-8b-a1b")
     return T.cfg(**{k: config[k] for k in (
-        "router_tie_margin", "check_rows_held", "check_echo")}, **T.DEEP)
+        "router_tie_margin", "check_rows_held", "check_echo")}, **DEEP)
 
 
 @pytest.mark.parametrize("seed", [4, 7])
@@ -170,7 +128,7 @@ def test_bfloat16_engine_passes_the_cells_comparison_and_the_control_fails(
     at twelve layers, read by the cell's own comparison under the cell's own
     limit and the configuration's own `router_tie_margin`; the reference
     computed one precision below is refused by the same limit."""
-    tol = _committed("cells", "lfm2-8b-a1b_serve_assistant")["logit_gap_tol"]
+    tol = E.committed("cells", "lfm2-8b-a1b_serve_assistant")["logit_gap_tol"]
     cfg = _cell_cfg()
     eng, params = T.engine(cfg, seed)
     rng = np.random.default_rng(seed)
@@ -223,7 +181,7 @@ def test_the_witness_at_the_stated_precision_reads_like_the_program():
     (`at_stated_precision`), teacher-forced on the program's tokens: its
     choices lie as far from the float32 rows as the program's do, under the
     cell's limit, and the control does not."""
-    tol = _committed("cells", "lfm2-8b-a1b_serve_assistant")["logit_gap_tol"]
+    tol = E.committed("cells", "lfm2-8b-a1b_serve_assistant")["logit_gap_tol"]
     cfg = _cell_cfg()        # `envelope_logits` reads neither check key
     held, echo = cfg["check_rows_held"], cfg["check_echo"]
     eng, params = T.engine(cfg, 4)
@@ -269,9 +227,9 @@ def test_bytes_count_attention_layers_and_key_value_heads_only():
     # the K/V watermark is the pools': the state is reported beside it
     assert eng._kv_cache_bytes() == 40 * st["block_bytes"]
     # the published widths: 524 KB of K/V a block, 98 KB of state beside it
-    big = lfm2.spec_of(_committed("configs", "lfm2-8b-a1b"))
+    big = lfm2.spec_of(E.committed("configs", "lfm2-8b-a1b"))
     assert big.cache_row_bytes() * 64 == 524288 and big.state_bytes() == 98304
-    assert lfm2.kv_row_bytes(_committed("configs", "lfm2-8b-a1b")) * 4 \
+    assert lfm2.kv_row_bytes(E.committed("configs", "lfm2-8b-a1b")) * 4 \
         == big.cache_row_bytes()
 
 
@@ -295,15 +253,9 @@ def test_admit_and_tick_spans_carry_the_states_counts():
     assert all("experts_touched" in s.attrs for s in ticks)
 
 
-@pytest.mark.parametrize("option, value", [
-    ("speculative", serving.SpecConfig(gamma=2)),
-    ("host_tier", serving.HostTierConfig()),
-    ("kv_quant", True), ("quant", "int8"), ("topk_k", 4)])
+@pytest.mark.parametrize("option, value", E.REFUSED)
 def test_what_is_not_built_for_the_model_is_refused_by_name(option, value):
-    with pytest.raises(InvalidArgumentError,
-                       match=option + "=.*conv state"):
-        serving.PagedKVEngine(n_slots=2, max_len=32, block_size=8,
-                              model=lfm2.spec_of(T.cfg()), **{option: value})
+    E.refused_by_name(T, option, value, "conv state")
 
 
 def test_the_classic_programs_are_unchanged_op_for_op():

@@ -10,18 +10,13 @@ looped). In float32 with exact matmuls the two agree to rounding, so the
 tolerance that accepts the program refuses every planted fault."""
 
 import dataclasses
-import json
-import os
 
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import ling_tiny as T
-from ling_tiny import ling, ref
-from paddle_tpu import serving
-from paddle_tpu.core import flags
-from paddle_tpu.core.enforce import InvalidArgumentError
+import tiny_engines as E
+from ling_tiny import TINY as T, ling, ref
 from paddle_tpu.observability import tracing
 
 TOL = 1e-4          # in standard deviations of the reference's logits
@@ -29,105 +24,39 @@ TURNS = (5, 11, 3, 17)
 HEAD = 24           # the shared system prompt: three blocks of 8
 
 
-def _prompts(seed=1, head=HEAD):
-    rng = np.random.default_rng(seed)
-    sys_prompt = rng.integers(0, 97, head).tolist()
-    return [sys_prompt] + [sys_prompt + rng.integers(0, 97, n).tolist()
-                           for n in TURNS]
+def _prompts(seed=1):
+    return E.prompts(TURNS, HEAD, seed)
 
 
-@pytest.fixture(scope="module")
-def exact_matmuls():
-    old = flags.get_flag("use_bf16_matmul")
-    flags.set_flag("use_bf16_matmul", False)
-    yield T.cfg(**T.F32)
-    flags.set_flag("use_bf16_matmul", old)
-
-
-@pytest.fixture(scope="module")
-def exact(exact_matmuls):
-    """float32 weights, pool, state and matmuls: the program against the
-    reference with nothing but float32 rounding between them. The system
-    prompt alone first (as the benchmark's warm-up sends it), then four
-    turns behind it."""
-    cfg = exact_matmuls
-    eng, params = T.engine(cfg, 7, scored=True)
-    prompts = _prompts()
-    runs = [T.emitted_logits(eng, prompts[0], 2)]
-    runs += [T.emitted_logits(eng, p, 10) for p in prompts[1:]]
-    return cfg, params, eng, runs
-
-
-def _worst(cfg, params, runs):
-    return max(T.logit_error(cfg, params, r, got) for r, got in runs)
+exact_matmuls = E.exact_matmuls_fixture(T)
+# the system prompt alone first (as the benchmark's warm-up sends it), then
+# four turns behind it
+exact = E.exact_fixture(T, TURNS, HEAD)
 
 
 def test_lanes_then_decode_agree_with_the_full_forward(exact):
-    cfg, params, eng, runs = exact
-    assert eng.prefill == "chunked" and eng.chunk_tokens == 16
     # the warm-up prefilled the system prompt and left its snapshot at the
     # end of its third block; every turn resumed from it AND from the latent
     # layer's three shared blocks
-    assert [r.shared_len for r, _ in runs] == [0, 24, 24, 24, 24]
-    assert _worst(cfg, params, runs) < TOL
-    st = eng.stats()["ssm_state"]
-    assert st["restores"] == 4 == eng.pager.prefix_hits
+    eng = E.lanes_then_decode_agree(T, exact, TOL, [0, 24, 24, 24, 24])
+    E.state_counts(T, exact, "ssm_state", restores=4, written=3, valid=3,
+                   pinned=0, layers=6, layers_with_kv=0)
     assert eng.pager.shared_blocks_total == 4 * 3
-    assert st["written"] == 3 == st["valid"] and st["pinned"] == 0
-    assert st["layers"] == 6 and st["layers_with_kv"] == 0
-    assert st["bytes_per_copy"] == ling.spec_of(cfg).state_bytes()
 
 
 def test_a_prefix_hit_equals_its_self_prefilled_twin(exact):
-    cfg, params, eng, runs = exact
-    alone, _ = T.engine(cfg, 7, scored=True)
-    alone.pager.prefix_sharing = False
-    for (req, got), prompt in zip(runs[1:], _prompts()[1:]):
-        twin, twin_got = T.emitted_logits(alone, prompt, 10)
-        assert twin.shared_len == 0 and req.shared_len == 24
-        assert twin.tokens == req.tokens
-        np.testing.assert_allclose(twin_got, got, atol=2e-5)
+    E.a_prefix_hit_equals_its_twin(T, exact, _prompts())
 
 
 def test_a_hit_is_truncated_to_the_deepest_snapshot(exact_matmuls):
     """`kv-span-past-snapshot` stands beside latent blocks: a span of the
     latent layer's blocks is handed out only up to a node that holds a state
     snapshot."""
-    cfg = exact_matmuls
-    eng, params = T.engine(cfg, 7, scored=True)
-    rng = np.random.default_rng(5)
-    first = rng.integers(0, 97, 37).tolist()
-    runs = [T.emitted_logits(eng, first, 4)]
-    shallow = first[:29] + rng.integers(0, 97, 6).tolist()
-    runs.append(T.emitted_logits(eng, shallow, 4))
-    assert runs[-1][0].shared_len == 0 and eng.pager.hits_truncated == 1
-    deep = first[:36] + rng.integers(0, 97, 6).tolist()
-    runs.append(T.emitted_logits(eng, deep, 4))
-    assert runs[-1][0].shared_len == 32
-    assert _worst(cfg, params, runs) < TOL
+    E.a_hit_is_truncated_to_the_deepest_snapshot(T, exact_matmuls, TOL)
 
 
 def test_a_request_preempted_and_resumed_reads_the_same(exact_matmuls):
-    """A pool too small for two requests: the second waits at the head of
-    the queue until the first has released its blocks, then runs from the
-    first's snapshot; both read as the reference does, and the slot the
-    first left is the second's, its state overwritten from the snapshot."""
-    cfg = exact_matmuls
-    eng, params = T.engine(cfg, 7, scored=True, n_blocks=9, n_slots=2)
-    prompts = _prompts()
-    a = eng.submit(prompts[2], 12)
-    b = eng.submit(prompts[4], 12)
-    waited = 0
-    while not (a.done and b.done):
-        eng.step()
-        waited += eng.n_pending
-    assert waited > 0                   # b was held back for blocks
-    assert a.error is None and b.error is None
-    fresh, _ = T.engine(cfg, 7, scored=True)
-    for req, prompt in ((a, prompts[2]), (b, prompts[4])):
-        twin, _ = T.emitted_logits(fresh, prompt, 12)
-        assert twin.tokens == req.tokens
-    eng.pager.pool.check()
+    E.a_preempted_request_reads_the_same(T, exact_matmuls, TURNS)
 
 
 @pytest.mark.parametrize("fault", ling.FAULTS)
@@ -135,35 +64,15 @@ def test_the_tolerance_catches_a_fault_planted_in_the_reference(exact, fault):
     """`ling.planted` (what benchmark/witness.py plants on the chip), one in
     each new mechanism: the decay, the delta correction, beta, the group
     step, the bias's role, the gate a head, a restore one chunk stale."""
-    cfg, params, _, runs = exact
-    cfg = dict(cfg, system_prompt_tokens=HEAD, chunk_size=8)
-    with ling.planted(fault, cfg, None) as c:
-        assert _worst(c, params, runs[1:]) > 10 * TOL
-    assert ref.FAULT is None
-    assert _worst(cfg, params, runs) < TOL
+    E.a_planted_fault_is_caught(
+        T, exact, fault, TOL, dict(system_prompt_tokens=HEAD, chunk_size=8))
 
 
 def test_the_tolerance_catches_a_stale_snapshot_in_the_program(exact_matmuls):
-    """The program's own restore, from an entry that holds another prompt's
-    state: the twin of the reference's `snapshot_stale`."""
     cfg = exact_matmuls
-    eng, params = T.engine(cfg, 7, scored=True)
-    prompts = _prompts()
-    T.emitted_logits(eng, prompts[0], 2)
-    T.emitted_logits(eng, _prompts(seed=9)[0], 2)
-    for j in range(len(ling.spec_of(cfg).kda_layers)):
-        name = f"{eng._cache_prefix}_kda_snap_h{j}"
-        snap = eng.scope.get(name)
-        eng.scope.set_var(name, snap.at[0].set(snap[1]))
-    hit = T.emitted_logits(eng, prompts[1], 6)
-    assert hit[0].shared_len == 24
-    assert _worst(cfg, params, [hit]) > 10 * TOL
-
-
-def _committed(kind, name):
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "benchmark", kind, name + ".json")) as f:
-        return json.load(f)
+    E.a_stale_snapshot_is_caught(
+        T, cfg, TURNS, [f"_kda_snap_h{j}" for j in range(
+            len(ling.spec_of(cfg).kda_layers))], 10 * TOL)
 
 
 def test_bfloat16_engine_keeps_its_state_in_float32():
@@ -187,7 +96,7 @@ def test_bytes_count_the_state_and_the_one_latent_layer():
                                                                  5, 6)
     assert spec.cache_row_bytes() == 128 * 2
     assert spec.state_bytes() == 6 * (4 * 16 * 16 * 4 + 3 * 192 * 2)
-    big_cfg = _committed("configs", "ling3-flash-ep4")
+    big_cfg = E.committed("configs", "ling3-flash-ep4")
     big = ling.spec_of(big_cfg)
     assert big.layer_kinds == ("kda",) * 5 + ("attention", "kda")
     assert big.cache_row_bytes() == 1280
@@ -225,22 +134,13 @@ def test_tick_spans_carry_the_state_rows_the_blocks_and_the_picks():
         s.attrs["state_rows"] + s.attrs["prefill_tokens"]) for s in mixed)
 
 
-@pytest.mark.parametrize("option, value", [
-    ("speculative", serving.SpecConfig(gamma=2)),
-    ("host_tier", serving.HostTierConfig()),
-    ("kv_quant", True), ("quant", "int8"), ("topk_k", 4)])
+@pytest.mark.parametrize("option, value", E.REFUSED)
 def test_what_is_not_built_for_the_model_is_refused_by_name(option, value):
-    with pytest.raises(InvalidArgumentError,
-                       match=option + "=.*delta-rule state"):
-        serving.PagedKVEngine(n_slots=2, max_len=32, block_size=8,
-                              n_snapshots=2, model=ling.spec_of(T.cfg()),
-                              **{option: value})
+    E.refused_by_name(T, option, value, "delta-rule state", n_snapshots=2)
 
 
 def test_an_engine_without_a_snapshot_pool_is_refused():
-    with pytest.raises(InvalidArgumentError, match="n_snapshots"):
-        serving.PagedKVEngine(n_slots=2, max_len=32, block_size=8,
-                              model=ling.spec_of(T.cfg()))
+    E.without_a_snapshot_pool_is_refused(T)
 
 
 def test_a_clamped_expert_is_refused_by_name():
@@ -264,7 +164,7 @@ def test_the_routers_bias_sends_this_rank_its_share():
     held groups of four get about half of the picks (a rank of the
     deployment: two groups of eight, a quarter)."""
     cfg = T.cfg(**T.F32, max_len=512)
-    scope = ling.build_weights(cfg, 11)
+    scope = E.weights(ling, cfg, 11)
     params = {n: scope.get(n) for n in ling.param_names(cfg)}
     c = dict(cfg, num_hidden_layers=7)
     tokens = np.random.default_rng(0).integers(0, 97, 512)

@@ -9,18 +9,14 @@ two agree to rounding, so the tolerance that accepts the program refuses
 every planted fault."""
 
 import dataclasses
-import json
-import os
 
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import nemotron_h_tiny as T
-from nemotron_h_tiny import nemo, ref
+import tiny_engines as E
+from nemotron_h_tiny import TINY as T, nemo, ref
 from paddle_tpu import serving
-from paddle_tpu.core import flags
-from paddle_tpu.core.enforce import InvalidArgumentError
 from paddle_tpu.observability import tracing
 
 TOL = 1e-4          # in standard deviations of the reference's logits
@@ -28,84 +24,32 @@ TURNS = (5, 11, 3, 17)
 HEAD = 24           # the shared system prompt: three blocks of 8
 
 
-def _prompts(seed=1, head=HEAD):
-    rng = np.random.default_rng(seed)
-    sys_prompt = rng.integers(0, 97, head).tolist()
-    return [sys_prompt] + [sys_prompt + rng.integers(0, 97, n).tolist()
-                           for n in TURNS]
+def _prompts(seed=1):
+    return E.prompts(TURNS, HEAD, seed)
 
 
-@pytest.fixture(scope="module")
-def exact_matmuls():
-    old = flags.get_flag("use_bf16_matmul")
-    flags.set_flag("use_bf16_matmul", False)
-    yield T.cfg(**T.F32)
-    flags.set_flag("use_bf16_matmul", old)
-
-
-@pytest.fixture(scope="module")
-def exact(exact_matmuls):
-    """float32 weights, pools, state and matmuls: the program against the
-    reference with nothing but float32 rounding between them. The system
-    prompt alone first (as the benchmark's warm-up sends it), then four
-    turns behind it."""
-    cfg = exact_matmuls
-    eng, params = T.engine(cfg, 7, scored=True)
-    prompts = _prompts()
-    runs = [T.emitted_logits(eng, prompts[0], 2)]
-    runs += [T.emitted_logits(eng, p, 10) for p in prompts[1:]]
-    return cfg, params, eng, runs
-
-
-def _worst(cfg, params, runs):
-    return max(T.logit_error(cfg, params, r, got) for r, got in runs)
+exact_matmuls = E.exact_matmuls_fixture(T)
+# the system prompt alone first (as the benchmark's warm-up sends it), then
+# four turns behind it
+exact = E.exact_fixture(T, TURNS, HEAD)
 
 
 def test_lanes_then_decode_agree_with_the_full_forward(exact):
-    cfg, params, eng, runs = exact
-    assert eng.prefill == "chunked" and eng.chunk_tokens == 16
     # the warm-up prefilled the system prompt and left its snapshot at the
     # end of its third block; every turn resumed from it
-    assert [r.shared_len for r, _ in runs] == [0, 24, 24, 24, 24]
-    assert _worst(cfg, params, runs) < TOL
-    st = eng.stats()["ssm_state"]
-    assert st["restores"] == 4 == eng.pager.prefix_hits
+    E.lanes_then_decode_agree(T, exact, TOL, [0, 24, 24, 24, 24])
     # prompts of 24, 29, 35, 27, 41 tokens: a snapshot where the last whole
     # block ends beyond the shared span (24; none; 32; none; 40)
-    assert st["written"] == 3 == st["valid"] and st["pinned"] == 0
-    assert st["bytes_per_copy"] == nemo.spec_of(cfg).state_bytes()
+    E.state_counts(T, exact, "ssm_state", restores=4, written=3, valid=3,
+                   pinned=0)
 
 
 def test_a_prefix_hit_equals_its_self_prefilled_twin(exact):
-    cfg, params, eng, runs = exact
-    alone, _ = T.engine(cfg, 7, scored=True)
-    alone.pager.prefix_sharing = False
-    for (req, got), prompt in zip(runs[1:], _prompts()[1:]):
-        twin, twin_got = T.emitted_logits(alone, prompt, 10)
-        assert twin.shared_len == 0 and req.shared_len == 24
-        assert twin.tokens == req.tokens
-        np.testing.assert_allclose(twin_got, got, atol=2e-5)
+    E.a_prefix_hit_equals_its_twin(T, exact, _prompts())
 
 
 def test_a_hit_is_truncated_to_the_deepest_snapshot(exact_matmuls):
-    """A prompt of 37 tokens leaves blocks 0-3 in the index and ONE snapshot,
-    at the end of block 3 (32). A second prompt that shares its first 29
-    tokens matches three blocks, of which none holds a snapshot: it is
-    handed nothing and prefills from position 0. A third that shares 36
-    matches four and is handed all four."""
-    cfg = exact_matmuls
-    eng, params = T.engine(cfg, 7, scored=True)
-    rng = np.random.default_rng(5)
-    first = rng.integers(0, 97, 37).tolist()
-    runs = [T.emitted_logits(eng, first, 4)]
-    shallow = first[:29] + rng.integers(0, 97, 6).tolist()
-    runs.append(T.emitted_logits(eng, shallow, 4))
-    assert runs[-1][0].shared_len == 0
-    assert eng.pager.hits_truncated == 1
-    deep = first[:36] + rng.integers(0, 97, 6).tolist()
-    runs.append(T.emitted_logits(eng, deep, 4))
-    assert runs[-1][0].shared_len == 32
-    assert _worst(cfg, params, runs) < TOL
+    eng = E.a_hit_is_truncated_to_the_deepest_snapshot(T, exact_matmuls, TOL)
     st = eng.stats()["ssm_state"]
     assert st["hits_truncated"] == 1 and st["restores"] == 1
 
@@ -118,15 +62,15 @@ def test_snapshots_are_evicted_least_recently_used_under_a_pool_of_two(
     heads = [rng.integers(0, 97, 24).tolist() for _ in range(3)]
     turn = rng.integers(0, 97, 5).tolist()
     for h in heads:                      # three snapshots through two entries
-        T.emitted_logits(eng, h, 2)
+        E.emitted_logits(eng, h, 2)
     st = eng.stats()["ssm_state"]
     assert st["written"] == 3 and st["evictions"] == 1 and st["valid"] == 2
-    runs = [T.emitted_logits(eng, h + turn, 6) for h in reversed(heads)]
+    runs = [E.emitted_logits(eng, h + turn, 6) for h in reversed(heads)]
     # the first head's snapshot went: its K/V blocks are still indexed, but
     # the span is not handed out past a snapshot, so it prefills again (and
     # takes the least recently used entry for the state it leaves at 24)
     assert [r.shared_len for r, _ in runs] == [24, 24, 0]
-    assert _worst(cfg, params, runs) < TOL
+    assert T.worst(cfg, params, runs) < TOL
     assert eng.pager.hits_truncated == 1
     eng.pager.pool.check()
 
@@ -137,13 +81,13 @@ def test_a_snapshot_is_void_once_its_nodes_block_is_evicted(exact_matmuls):
     rng = np.random.default_rng(4)
     heads = [rng.integers(0, 97, 24).tolist() for _ in range(3)]
     turn = rng.integers(0, 97, 5).tolist()
-    runs = [T.emitted_logits(eng, h + turn, 8) for h in heads]
+    runs = [E.emitted_logits(eng, h + turn, 8) for h in heads]
     assert eng.pager.evictions > 0
     pool = eng.pager.stats()["snapshot_pool"]
     assert pool["valid"] < pool["written"]
-    again = T.emitted_logits(eng, heads[0] + turn, 8)
+    again = E.emitted_logits(eng, heads[0] + turn, 8)
     assert again[0].tokens == runs[0][0].tokens
-    assert _worst(cfg, params, runs + [again]) < TOL
+    assert T.worst(cfg, params, runs + [again]) < TOL
     # every valid entry belongs to a node that is still in the index
     for entry, node in enumerate(eng.pager._snap_node):
         assert node is None or (node.snap == entry
@@ -155,35 +99,15 @@ def test_the_tolerance_catches_a_fault_planted_in_the_reference(exact, fault):
     """`nemo.planted` (what benchmark/witness.py plants on the chip), one in
     each new mechanism: the state decayed by a wrong dt, a restore from a
     snapshot one chunk stale, the routed sum's scaling dropped."""
-    cfg, params, _, runs = exact
-    cfg = dict(cfg, system_prompt_tokens=HEAD, chunk_size=8)
-    with nemo.planted(fault, cfg, None) as c:
-        assert _worst(c, params, runs[1:]) > 10 * TOL
-    assert ref.FAULT is None
-    assert _worst(cfg, params, runs) < TOL
+    E.a_planted_fault_is_caught(
+        T, exact, fault, TOL, dict(system_prompt_tokens=HEAD, chunk_size=8))
 
 
 def test_the_tolerance_catches_a_stale_snapshot_in_the_program(exact_matmuls):
-    """The program's own restore, from an entry that holds another prompt's
-    state: the twin of the reference's `snapshot_stale`."""
     cfg = exact_matmuls
-    eng, params = T.engine(cfg, 7, scored=True)
-    prompts = _prompts()
-    T.emitted_logits(eng, prompts[0], 2)
-    T.emitted_logits(eng, _prompts(seed=9)[0], 2)
-    for j in range(len(nemo.spec_of(cfg).ssm_layers)):
-        name = f"{eng._cache_prefix}_ssm_snap_h{j}"
-        snap = eng.scope.get(name)
-        eng.scope.set_var(name, snap.at[0].set(snap[1]))
-    hit = T.emitted_logits(eng, prompts[1], 6)
-    assert hit[0].shared_len == 24
-    assert _worst(cfg, params, [hit]) > 10 * TOL
-
-
-def _committed(kind, name):
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "benchmark", kind, name + ".json")) as f:
-        return json.load(f)
+    E.a_stale_snapshot_is_caught(
+        T, cfg, TURNS, [f"_ssm_snap_h{j}" for j in range(
+            len(nemo.spec_of(cfg).ssm_layers))], 10 * TOL)
 
 
 def test_bfloat16_engine_keeps_its_state_in_float32():
@@ -205,7 +129,7 @@ def test_bytes_count_the_state_and_the_one_attention_layer():
     assert spec.moe_layers == (1, 3, 5, 8, 10)
     assert spec.cache_row_bytes() == 2 * 2 * 8 * 2
     assert spec.state_bytes() == 5 * (16 * 8 * 16 * 4 + 3 * 256 * 2)
-    big_cfg = _committed("configs", "nemotron3-super-ep4")
+    big_cfg = E.committed("configs", "nemotron3-super-ep4")
     big = nemo.spec_of(big_cfg)
     assert big.cache_row_bytes() == 1024
     assert big.state_bytes() == 5 * (128 * 64 * 128 * 4 + 3 * 10240 * 2)
@@ -248,22 +172,13 @@ def test_admit_and_tick_spans_carry_the_pools_counts():
     assert eng.stats()["ssm_state"]["evictions"] == 1
 
 
-@pytest.mark.parametrize("option, value", [
-    ("speculative", serving.SpecConfig(gamma=2)),
-    ("host_tier", serving.HostTierConfig()),
-    ("kv_quant", True), ("quant", "int8"), ("topk_k", 4)])
+@pytest.mark.parametrize("option, value", E.REFUSED)
 def test_what_is_not_built_for_the_model_is_refused_by_name(option, value):
-    with pytest.raises(InvalidArgumentError,
-                       match=option + "=.*state-space state"):
-        serving.PagedKVEngine(n_slots=2, max_len=32, block_size=8,
-                              n_snapshots=2, model=nemo.spec_of(T.cfg()),
-                              **{option: value})
+    E.refused_by_name(T, option, value, "state-space state", n_snapshots=2)
 
 
 def test_an_engine_without_a_snapshot_pool_is_refused():
-    with pytest.raises(InvalidArgumentError, match="n_snapshots"):
-        serving.PagedKVEngine(n_slots=2, max_len=32, block_size=8,
-                              model=nemo.spec_of(T.cfg()))
+    E.without_a_snapshot_pool_is_refused(T)
 
 
 def test_the_other_programs_are_unchanged_op_for_op():
@@ -302,7 +217,7 @@ def test_the_routers_bias_is_made_so_that_the_load_is_even():
     import jax
     cfg = T.cfg(**T.F32, router_width=32, num_experts_per_tok=4,
                 n_routed_experts=32, max_len=512)    # 512 rows to balance on
-    scope = nemo.build_weights(cfg, 11)
+    scope = E.weights(nemo, cfg, 11)
     params = {n: scope.get(n) for n in nemo.param_names(cfg)}
     c = dict(cfg, num_hidden_layers=cfg["num_layers"])
     tokens = np.random.default_rng(0).integers(0, 97, 512)     # fresh rows

@@ -29,10 +29,33 @@ pool itself between its ticks, as a running sum that nothing reads before
 the group is whole: no state beside the pools.
 
 `sparse_latent_attention` is ONE op with two lowerings that share the
-selection (index write, scores, the sort: `ids` [N, G] the picked PHYSICAL
-groups, the valid ones first, and `count` the selected positions). Neither
-copies a pool: the latent pool is read as the engine keeps it, [NB, 1, BS, W],
-or seen as [NB * BS, W], which is a bitcast (a block is whole tiles).
+selection (`tick_selection`: index write, scores, the sort: `ids` [N, G] the
+picked PHYSICAL groups, the valid ones first, and `count` the selected
+positions).
+
+The selection runs for the rows that HOLD A TOKEN (ISSUE 64). A tick's shapes
+are static (64 slots, two lanes of 128), its live rows are few (7 decode rows
+at the repository cell's median tick, one busy lane of two, a short last
+chunk), and the sort costs the same for a row whatever the row holds. So what
+grows with the rows (the index pool gathered through the decode rows' tables,
+their scores, a lane's scores, the sort over all N rows) runs
+`over_live_rows`: `_STEP` rows at a time over the order that puts the live
+rows first, in a loop whose trip count the device takes from the live count
+(`rung`: the rows it reaches), the results put back to the rows' own places.
+A sort of 8 rows costs what 8 rows of the parent's one sort cost, so the loop
+is ONE small sort program where a ladder of row counts under a switch (built
+first and timed: PERF.md section 6, PR 64) was a program a rung. A live row
+gets the scores, the stable sort, the ids in the order and the count it got
+when every row was sorted; an idle row reads ids 0 and count 1, which the
+kernel never fetches (`n_fetch` 0) and the composite attends as the null
+block. A tick with no live row of a kind (a mixed tick with no decode row:
+the contexts' prefill) runs no step of it. One algorithm at the row count it
+observes: no option chooses. The pager counts the rows it reaches
+(`engine/tick` `dsa_scored_rows`, by `rung` itself).
+
+Neither lowering copies a pool: the latent pool is read as the engine keeps
+it, [NB, 1, BS, W], or seen as [NB * BS, W], which is a bitcast (a block is
+whole tiles).
 
 - the composite gathers the picked groups' SINGLE rows of that view into a
   dense [N, G * kpool, W] and attends it in `jax.numpy`;
@@ -52,8 +75,9 @@ or seen as [NB * BS, W], which is a bitcast (a block is whole tiles).
   mixed tick's 320 (PERF.md section 6, PR 62). Where a group is no whole
   number of such chunks (`fetch_chunk`) the read is the composite's.
 
-Scopes: `dsa_index` (write, scores, sort) and `sparse_latent_attention` (the
-composite's gather and attend; the Mosaic call `sparse_fetch` inside it).
+Scopes: `dsa_index` (write, scores, sort: `tick_selection`, the bodies of
+its loops within) and `sparse_latent_attention` (the composite's gather
+and attend; the Mosaic call `sparse_fetch` inside it).
 """
 
 from __future__ import annotations
@@ -71,6 +95,9 @@ from .latent_attention import (KERNEL, _M_INIT, _MASKED,
 _HEAD_BLOCK = 8         # index heads scored at a time (bounds the scratch)
 _FETCH_ROWS = 8         # rows of the pool a DMA may slice (its HBM tiling)
 _FETCH_STEP_KEYS = 512  # fetched rows a step of the fetch kernel scores
+# rows the selection takes at a time: a sort of 8 rows of 8,832 scores costs
+# what 8 rows of a larger sort cost (~0.1 ms; PERF.md section 6, PR 64)
+_STEP = 8
 
 
 def rotate_first(x, pos, table):
@@ -150,6 +177,50 @@ def select(scores, pos, tab, kpool, top_groups, groups_per_block, n_scratch):
                     jnp.pad(picked[:, :k], ((0, 0), (0, n_scratch - k))),
                     jnp.where(j == n_valid[:, None], tail, 0))
     return ids, n_valid * kpool + (pos + 1) % kpool
+
+
+def rung(count, n_rows, step=_STEP):
+    """The rows the selection runs over where `count` of `n_rows` rows hold
+    a token: whole steps of `step`. Plain integers: the pager counts
+    `dsa_scored_rows` by it."""
+    return min(-(-count // step) * step, n_rows)
+
+
+def over_live_rows(fn, live, rows, fill, step=_STEP):
+    """`fn(*rows)` (arrays [n, ...] in, a tree of arrays [n, ...] out, row by
+    row) run `step` rows at a time over the order that puts the rows `live`
+    [n] flags first, each kind in its own order, for as many steps as hold
+    the live rows: a loop whose trip count the DEVICE takes from the flags
+    (no live row: no step). The results go back to the rows' own places; a
+    row no step reached reads `fill` (a tree like `fn`'s result). A live row
+    gets what `fn` over all rows gave it."""
+    n = live.shape[0]
+    i32 = jnp.int32
+    n_live = jnp.sum(live, dtype=i32)
+    # where a row stands in that order, and the order itself: the inverse
+    # permutation by a compare of n x n (no sort, no scatter), padded to
+    # whole steps with rows nothing reads back
+    place = jnp.where(live, jnp.cumsum(live, dtype=i32) - 1,
+                      n_live + jnp.cumsum(~live, dtype=i32) - 1)
+    at = jnp.arange(n, dtype=i32)
+    order = jnp.pad(jnp.sum(jnp.where(place[:, None] == at[None, :],
+                                      at[:, None], 0), axis=0), (0, -n % step))
+    shapes = jax.eval_shape(fn, *(jax.ShapeDtypeStruct(
+        (step,) + a.shape[1:], a.dtype) for a in rows))
+
+    def one_step(i, outs):
+        taken = jax.lax.dynamic_slice(order, (i * step,), (step,))
+        new = fn(*(a[taken] for a in rows))
+        return jax.tree.map(
+            lambda out, part: jax.lax.dynamic_update_slice(
+                out, part, (i * step,) + (0,) * (part.ndim - 1)), outs, new)
+
+    outs = jax.lax.fori_loop(
+        0, (n_live + step - 1) // step, one_step,
+        jax.tree.map(lambda shape, value: jnp.full(
+            (order.shape[0],) + shape.shape[1:], value, shape.dtype),
+            shapes, fill))
+    return jax.tree.map(lambda out: out[place], outs)
 
 
 def _attend_composite(q, scratch, count, num_heads, v_width, scale):
@@ -369,6 +440,59 @@ def _sparse_fetch_pallas(q, pool, ids, count, live, num_heads, v_width, scale,
     return out.reshape(n, 1, num_heads * v_width)
 
 
+def tick_selection(ipool, qi, ki, wi, pos, table, btab, wblock, woff, lanes,
+                   *, dtype, index_heads, top_groups, kpool):
+    """The selection of a tick's N rows (the arguments of
+    `sparse_latent_attention`, the integers int32, `dtype` the activations'):
+    the rows' index keys written into `ipool`, then ids [N, G], count [N]
+    (`select`'s) of the rows that hold a token, `live` [N]. What grows with
+    the rows (the index pool's gather through the table, the scores, the
+    sort) runs `over_live_rows`, 8 rows at a time; an idle row reads ids 0 and count 1, which
+    nothing attends."""
+    n, s, n_logical = qi.shape[0], btab.shape[0], btab.shape[1]
+    d, gpb = ki.shape[-1], ipool.shape[2]
+    f32 = jnp.float32
+    live = wblock > 0
+    # the rotated index rows, rounded as the activations are
+    qi = rotate_first(qi.reshape(n, index_heads, d), pos, table).astype(dtype)
+    ki = rotate_first(ki.reshape(n, 1, d), pos, table)[:, 0] \
+        .astype(dtype).astype(f32)
+    wi = wi.reshape(n, index_heads).astype(f32)
+    ipool = write_index(ipool, ki, wblock, woff, kpool, lanes and lanes[1:])
+
+    def pooled(tab):    # the index pool's rows of each row of `tab`
+        return ipool[tab].reshape(tab.shape[0], n_logical * gpb, d)
+
+    scores = over_live_rows(
+        lambda qi, wi, tab: index_scores(
+            qi[:, None], wi[:, None], pooled(tab),
+            head_block=index_heads)[:, 0],
+        live, (qi[:s], wi[:s], btab), 0.0)
+    tab = btab
+    if lanes is not None:
+        lbtab, _, lrows, chunk = lanes
+        n_lanes = lbtab.shape[0]
+        # a lane's queries share its pooled rows: a lane a step, an idle
+        # LANE none
+        sl = over_live_rows(
+            lambda qi, wi, tab: index_scores(qi, wi, pooled(tab)),
+            lrows > 0,
+            (qi[s:].reshape(n_lanes, chunk, index_heads, d),
+             wi[s:].reshape(n_lanes, chunk, index_heads), lbtab), 0.0,
+            step=1)
+        scores = jnp.concatenate([scores, sl.reshape(n - s, -1)], axis=0)
+        tab = jnp.concatenate([btab, jnp.repeat(lbtab, chunk, axis=0)])
+        live = jnp.concatenate(
+            [live, (jnp.arange(chunk)[None, :] < lrows[:, None]).reshape(-1)])
+    n_scratch = scratch_rows(top_groups, kpool, gpb * kpool, n_logical) \
+        // kpool
+    ids, count = over_live_rows(
+        lambda scores, pos, tab: select(scores, pos, tab, kpool, top_groups,
+                                        gpb, n_scratch),
+        live, (scores, pos, tab), (0, 1))
+    return ids, jnp.maximum(count, 1), live, ipool
+
+
 def sparse_latent_attention(q, pool, ipool, qi, ki, wi, positions, table,
                             btab, wblock, woff, lanes=None, *, num_heads,
                             v_width, scale, index_heads, top_groups, kpool,
@@ -381,49 +505,17 @@ def sparse_latent_attention(q, pool, ipool, qi, ki, wi, positions, table,
     [S, NLB], wblock, woff [S] the decode rows' table and write target;
     `lanes` (lbtab [L, NLB], lwblocks [L*C/BS], lrows [L], chunk).
     Returns (out [N, 1, nh*v_width], ipool written)."""
-    n, s = q.shape[0], btab.shape[0]
     _, _, block_size, w = pool.shape
-    n_logical = btab.shape[1]
-    gpb = block_size // kpool
-    f32, i32 = jnp.float32, jnp.int32
-    pos = positions.reshape(-1).astype(i32)
-    btab, wblock, woff = (t.astype(i32) for t in (btab, wblock.reshape(-1),
-                                                  woff.reshape(-1)))
-    live = wblock > 0
+    i32 = jnp.int32
+    flat = lambda t: t.reshape(-1).astype(i32)  # noqa: E731
+    if lanes is not None:
+        lbtab, lwblocks, lrows, chunk = lanes
+        lanes = (lbtab.astype(i32), flat(lwblocks), flat(lrows), chunk)
     with jax.named_scope("dsa_index"):
-        d = ki.shape[-1]
-        # the rotated index rows, rounded as the activations are
-        qi = rotate_first(qi.reshape(n, index_heads, d), pos, table) \
-            .astype(q.dtype)
-        ki = rotate_first(ki.reshape(n, 1, d), pos, table)[:, 0] \
-            .astype(q.dtype).astype(f32)
-        wi = wi.reshape(n, index_heads).astype(f32)
-        chunk_lanes = None
-        if lanes is not None:
-            lbtab, lwblocks, lrows, chunk = lanes
-            lbtab, lrows = lbtab.astype(i32), lrows.reshape(-1).astype(i32)
-            chunk_lanes = (lwblocks.reshape(-1).astype(i32), lrows, chunk)
-        ipool = write_index(ipool, ki, wblock, woff, kpool, chunk_lanes)
-        scores = index_scores(
-            qi[:s, None], wi[:s, None],
-            ipool[btab].reshape(s, n_logical * gpb, d),
-            head_block=index_heads)[:, 0]
-        tab = btab
-        if lanes is not None:
-            n_lanes = lbtab.shape[0]
-            sl = index_scores(
-                qi[s:].reshape(n_lanes, chunk, index_heads, d),
-                wi[s:].reshape(n_lanes, chunk, index_heads),
-                ipool[lbtab].reshape(n_lanes, n_logical * gpb, d))
-            scores = jnp.concatenate([scores, sl.reshape(n - s, -1)], axis=0)
-            tab = jnp.concatenate([btab, jnp.repeat(lbtab, chunk, axis=0)])
-            live = jnp.concatenate(
-                [live, (jnp.arange(chunk)[None, :] < lrows[:, None])
-                 .reshape(-1)])
-        t_rows = scratch_rows(top_groups, kpool, block_size, n_logical)
-        ids, count = select(scores, pos, tab, kpool, top_groups, gpb,
-                            t_rows // kpool)
-        count = jnp.maximum(count, 1)
+        ids, count, live, ipool = tick_selection(
+            ipool, qi, ki, wi, flat(positions), table, btab.astype(i32),
+            flat(wblock), flat(woff), lanes, dtype=q.dtype,
+            index_heads=index_heads, top_groups=top_groups, kpool=kpool)
     with jax.named_scope("sparse_latent_attention"):
         lowering = latent_attention_lowering(w, v_width, num_heads, 1, backend)
         fetched = fetch_chunk(kpool, block_size)

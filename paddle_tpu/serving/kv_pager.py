@@ -1468,7 +1468,7 @@ class PagedKVEngine(ContinuousBatchingEngine):
             # positions (fusion/sparse_latent_attention.py `select`)
             at = np.asarray([r.fed for r in active.values()
                              if not self._prefilling(r)], np.int64)
-            self._tick_attrs.update(self._sparse_counts(at))
+            self._tick_attrs.update(self._sparse_counts(at, self.n_slots))
         if self.n_snapshots:
             # the live decode rows, whose state-space state the tick reads
             # and writes (a slot in prefill moves its state in a lane)
@@ -1481,14 +1481,19 @@ class PagedKVEngine(ContinuousBatchingEngine):
         if self._mixed_step is not None:
             self._fill_lanes(prefilling)
 
-    def _sparse_counts(self, positions: np.ndarray) -> Dict[str, int]:
+    def _sparse_counts(self, positions: np.ndarray, tick_rows: int,
+                       before: int = 0) -> Dict[str, int]:
         """`engine/tick`'s counts of the sparse latent read over rows at
         `positions`: `dsa_rows`, the positions they hold
         (`dsa_live_positions`), the positions the selection attends (the
         best `top_groups` whole groups and the tail:
-        `dsa_selected_positions`) and the pooled rows the tick writes into
+        `dsa_selected_positions`), the pooled rows the tick writes into
         the index pool (`index_pool_rows`: a row a decode row, a row a group
-        a lane touches), each over the spec's sparse layers."""
+        a lane touches) and the rows the selection SORTED
+        (`dsa_scored_rows`: the op's own `rung`, whole steps of 8, for these
+        rows and the `before` live ones counted already, of a tick of
+        `tick_rows`), each over the spec's sparse layers."""
+        from ..fusion.sparse_latent_attention import rung
         ix, n = self.model.indexer, len(self.model.attention_layers)
         held = positions + 1
         picked = np.minimum(held // ix.kpool, ix.top_groups) * ix.kpool \
@@ -1496,7 +1501,9 @@ class PagedKVEngine(ContinuousBatchingEngine):
         return {"dsa_rows": n * len(positions),
                 "dsa_live_positions": n * int(held.sum()),
                 "dsa_selected_positions": n * int(picked.sum()),
-                "index_pool_rows": n * len(positions)}
+                "index_pool_rows": n * len(positions),
+                "dsa_scored_rows": n * rung(before + len(positions),
+                                            tick_rows)}
 
     def _fill_lanes(self, prefilling: List[GenRequest]):
         """Give the tick's lanes to the slots in prefill, in admission
@@ -1557,11 +1564,18 @@ class PagedKVEngine(ContinuousBatchingEngine):
         self._lanes = lanes
         if self.model.indexer is not None and lanes:
             kpool = self.model.indexer.kpool
-            more = self._sparse_counts(np.concatenate(
-                [np.arange(req.fed, req.fed + n) for req, n in lanes]))
+            n_layers = len(self.model.attention_layers)
+            more = self._sparse_counts(
+                np.concatenate([np.arange(req.fed, req.fed + n)
+                                for req, n in lanes]),
+                self.n_slots + self.n_lanes * self.chunk_tokens,
+                before=attrs["dsa_rows"] // n_layers)
             # a lane writes a pooled row a group it touches, not a row a row
-            more["index_pool_rows"] = len(self.model.attention_layers) * sum(
+            more["index_pool_rows"] = n_layers * sum(
                 -(-n // kpool) for _, n in lanes)
+            # the mixed tick's rows are sorted together, the decode rows among
+            # them: ONE count
+            attrs["dsa_scored_rows"] = more.pop("dsa_scored_rows")
             for key, value in more.items():
                 attrs[key] += value
         attrs["kv_blocks"] += lane_blocks

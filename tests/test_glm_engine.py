@@ -20,6 +20,7 @@ import pytest
 import tiny_engines as E
 from glm_tiny import TINY as T, glm, ref
 from paddle_tpu.fusion import hyper_connection, moe
+from paddle_tpu.fusion import sparse_latent_attention as sla
 from paddle_tpu.models.decoder_spec import (HyperSpec, IndexerSpec,
                                             RopeSpec)
 from paddle_tpu.observability import tracing
@@ -160,10 +161,20 @@ def test_tick_spans_carry_the_sparse_reads_counts():
     eng.run_until_idle()
     ticks = [s for s in tracing.spans_since(mark) if s.name == "engine/tick"]
     keys = {"dsa_rows", "dsa_live_positions", "dsa_selected_positions",
-            "index_pool_rows", "state_rows", "kv_blocks"}
+            "dsa_scored_rows", "index_pool_rows", "state_rows", "kv_blocks"}
     assert all(keys <= set(s.attrs) for s in ticks)
+    # the rows the selection sorted: the op's own `rung` (whole steps of 8)
+    # for the tick's live rows (ONE sparse layer), of the decode tick's slots
+    # or of the mixed tick's slots and lanes; never fewer than the rows that
+    # hold a token
+    for s in ticks:
+        rows = eng.n_slots + (eng.n_lanes * eng.chunk_tokens
+                              if s.attrs.get("prefill") else 0)
+        assert s.attrs["dsa_scored_rows"] \
+            == sla.rung(s.attrs["dsa_rows"], rows) >= s.attrs["dsa_rows"]
     mixed = [s for s in ticks if s.attrs.get("prefill")]
     assert [s.attrs["dsa_rows"] for s in mixed] == [16, 1]
+    assert [s.attrs["dsa_scored_rows"] for s in mixed] == [16, 8]
     # rows at positions 24..39 hold 25..40 positions and attend 2 groups of
     # 4 and the tail of (t + 1) % 4
     assert mixed[0].attrs["dsa_live_positions"] == sum(range(25, 41))
@@ -173,6 +184,8 @@ def test_tick_spans_carry_the_sparse_reads_counts():
     assert [s.attrs["dsa_live_positions"] for s in decode] == [42, 43, 44]
     assert [s.attrs["dsa_selected_positions"] for s in decode] == [10, 11, 8]
     assert all(s.attrs["index_pool_rows"] == 1 for s in decode)
+    assert all(s.attrs["dsa_scored_rows"] == min(8, eng.n_slots)
+               for s in decode)
 
 
 def test_eight_ranks_shares_and_the_shared_expert_once_add_up():

@@ -885,7 +885,11 @@ def test_sparse_latent_read_compiles_for_v5e(one_chip, slots, lanes):
     lies, decode rows and lanes alike), the rest XLA. And NO instruction
     regroups the pool (1,024 blocks here: `bf16[16384,4,512]`) or a scratch
     of picked groups (`bf16[<any>,4,512]`), or gathers one by blocks: PR 61
-    copied 537 MB on every tick and 692 MB more on a mixed one that way."""
+    copied 537 MB on every tick and 692 MB more on a mixed one that way.
+    ISSUE 64: the selection's ONE sort is of 8 rows and stands in the body
+    of a `while` under `dsa_index` whose trip count the device takes from the
+    live rows (no sort over all 64 or 320 rows is left), as do the index
+    pool's gather and the scores; the fetch kernel stands outside, once."""
     f, args = _sparse_read(slots, lanes)
     assert _n_calls(_tpu_text(f, *args)) == 1
     args = [S(a.shape, a.dtype, sharding=one_chip) for a in args]
@@ -898,6 +902,13 @@ def test_sparse_latent_read_compiles_for_v5e(one_chip, slots, lanes):
     assert not re.search(r"bf16\[\d+,4,512\]", text)
     assert f"bf16[{1 + n * 33},1,64,512]" not in text
     assert "bf16[65536,512]{1,0:T(8,128)(2,1)} bitcast(" in text
+    loops = re.findall(r" while\(.*op_name=\"[^\"]*dsa_index/", text)
+    assert len(loops) == (3 if lanes else 2)
+    sorts = re.findall(
+        r"= \(f32\[(\d+),8832\].* sort\(.*op_name=\"([^\"]*)\"", text)
+    assert [int(rows) for rows, _ in sorts] == [8]
+    assert "dsa_index/while/body" in sorts[0][1]
+    assert not re.search(r"bf16\[(64|320),8832,128\]", text)
 
 
 def test_clamped_expert_walk_compiles_for_v5e(one_chip):

@@ -283,3 +283,175 @@ def _selection(seed):
 @pytest.mark.parametrize("seed", sorted(SELECTED))
 def test_selection_is_the_parents(seed):
     assert _selection(seed) == SELECTED[seed]
+
+
+# ISSUE 64: the selection runs for the rows that hold a token
+
+SLOTS = 64
+LIVE = [0, 1, 7, 8, 9, 33, 64]
+LANES = {"alone": (), "lane_idle_lane": ((32, C), (0, 0)),
+         "one_row_idle_lane": ((48, 1), (0, 0)),
+         "lane_short_lane": ((32, C), (96, 5)),
+         "two_lanes": ((64, C), (16, C))}
+
+
+def _scattered(n_live, lanes, seed):
+    """`n_live` of 64 decode rows live, scattered over the slots."""
+    rng = np.random.default_rng(seed)
+    decode = [None] * SLOTS
+    for slot in rng.choice(SLOTS, n_live, replace=False):
+        decode[slot] = int(rng.integers(0, NLB * BS))
+    return _Tick(seed, decode, list(lanes), nb=1024)
+
+
+def _op_args(tick):
+    i32 = jnp.int32
+    lanes = None
+    if len(tick.lrows):
+        lanes = (jnp.asarray(tick.lbtab, i32), jnp.asarray(tick.lwblocks, i32),
+                 jnp.asarray(tick.lrows, i32), C)
+    return (jnp.asarray(tick.ipool), jnp.asarray(tick.qi),
+            jnp.asarray(tick.ki), jnp.asarray(tick.wi),
+            jnp.asarray(tick.pos, i32), jnp.asarray(tick.table),
+            jnp.asarray(tick.btab, i32), jnp.asarray(tick.wblock, i32),
+            jnp.asarray(tick.woff, i32), lanes)
+
+
+def _parents_selection(ipool, qi, ki, wi, pos, table, btab, wblock, woff,
+                       lanes):
+    """ids, count and the written index pool as the parent (5105f9a) made
+    them: EVERY row of the tick gathered, scored and sorted."""
+    f32 = jnp.float32
+    n, s = qi.shape[0], btab.shape[0]
+    gpb = BS // KP
+    qi = sla.rotate_first(qi.reshape(n, NI, DI), pos, table)
+    ki = sla.rotate_first(ki.reshape(n, 1, DI), pos, table)[:, 0]
+    ipool = sla.write_index(ipool, ki, wblock, woff, KP,
+                            lanes and lanes[1:])
+    scores = sla.index_scores(
+        qi[:s, None], wi[:s, None], ipool[btab].reshape(s, NLB * gpb, DI),
+        head_block=NI)[:, 0]
+    tab = btab
+    if lanes:
+        lbtab = lanes[0]
+        sl = sla.index_scores(
+            qi[s:].reshape(-1, C, NI, DI), wi[s:].reshape(-1, C, NI),
+            ipool[lbtab].reshape(lbtab.shape[0], NLB * gpb, DI))
+        scores = jnp.concatenate([scores, sl.reshape(n - s, -1)])
+        tab = jnp.concatenate([btab, jnp.repeat(lbtab, C, axis=0)])
+    ids, count = sla.select(scores, pos, tab, KP, TOP, gpb,
+                            sla.scratch_rows(TOP, KP, BS, NLB) // KP)
+    return (np.asarray(ids), np.asarray(jnp.maximum(count, 1)),
+            np.asarray(ipool.astype(f32)))
+
+
+def _read(tick, ids, count, backend):
+    """The op's second half over a given selection."""
+    q, pool = jnp.asarray(tick.q), jnp.asarray(tick.pool)
+    ids, count = jnp.asarray(ids), jnp.asarray(count)
+    if backend == "xla":
+        rows = pool.reshape(-1, W)[sla.picked_rows(ids, KP)]
+        out = sla._attend_composite(q, rows, count, NH, W, SCALE)
+    else:
+        out = sla._sparse_fetch_pallas(
+            q, pool, ids, count, jnp.asarray(tick.live, jnp.int32), NH, W,
+            SCALE, KP, sla.fetch_chunk(KP, BS), interpret=True)
+    return np.asarray(out).reshape(-1, NH, W)
+
+
+@pytest.mark.parametrize("backend", BACKENDS)
+@pytest.mark.parametrize("lanes", sorted(LANES))
+@pytest.mark.parametrize("n_live", LIVE)
+def test_live_rows_get_the_unbucketed_selection(n_live, lanes, backend):
+    """However many steps the live count takes, a live row's ids, count and
+    context are the ones a selection over EVERY row gives it, bit for bit,
+    and the index pool is written as before."""
+    tick = _scattered(n_live, LANES[lanes],
+                      seed=100 + 10 * LIVE.index(n_live)
+                      + sorted(LANES).index(lanes))
+    live = tick.live
+    assert live[:SLOTS].sum() == n_live
+    args = _op_args(tick)
+    ids, count, flags, ipool = sla.tick_selection(
+        *args, dtype=jnp.float32, index_heads=NI, top_groups=TOP, kpool=KP)
+    want_ids, want_count, want_ipool = _parents_selection(*args)
+    assert np.array_equal(np.asarray(flags), live)
+    assert np.array_equal(np.asarray(ids)[live], want_ids[live])
+    assert np.array_equal(np.asarray(count)[live], want_count[live])
+    assert np.array_equal(np.asarray(ipool), want_ipool)
+    # an idle row's selection is nothing the read has to tolerate anew
+    assert np.asarray(ids).min() >= 0 and np.asarray(count).min() >= 1
+    out, written = tick.run(backend)
+    assert np.array_equal(written, want_ipool)
+    assert np.array_equal(out[live], _read(tick, want_ids, want_count,
+                                           backend)[live])
+    np.testing.assert_allclose(out[live], tick.expected[live], rtol=2e-4,
+                               atol=2e-5)
+    assert np.all(out[:SLOTS][~live[:SLOTS]] == 0.0)
+
+
+def _eqns_outside_loops(jaxpr):
+    """Every equation of `jaxpr` and of what it calls, the bodies of a
+    `while` left out."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name == "while":
+            continue
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns_outside_loops(sub)
+
+
+@pytest.mark.parametrize("n_lanes", [0, 2])
+def test_row_proportional_work_stands_inside_the_loops(n_lanes):
+    """Outside the loops whose trip count the device takes from the live
+    rows, the op holds no sort, no gather of the index pool through the
+    decode rows' tables and no product that scores decode rows; the loops'
+    bodies hold them, 8 rows at a time."""
+    s, n = SLOTS, SLOTS + n_lanes * C
+    g = NLB * BS // KP
+    f32, i32 = jnp.float32, jnp.int32
+    shapes = [((n, 1, NH * W), f32), ((NB, 1, BS, W), f32),
+              ((NB, 1, BS // KP, DI), f32), ((n, NI * DI), f32),
+              ((n, DI), f32), ((n, NI), f32), ((n, 1, 1), f32),
+              ((NLB * BS, R), f32), ((s, NLB), i32), ((s,), i32), ((s,), i32)]
+    if n_lanes:
+        shapes += [((n_lanes, NLB), i32), ((n_lanes * C // BS,), i32),
+                   ((n_lanes,), i32)]
+
+    def f(*a):
+        return sla.sparse_latent_attention(
+            *a[:11], (*a[11:], C) if n_lanes else None, **KW, backend="xla")
+    jaxpr = jax.make_jaxpr(f)(*(jax.ShapeDtypeStruct(*x) for x in shapes))
+    outside = list(_eqns_outside_loops(jaxpr.jaxpr))
+    loops = [e for e in outside if e.primitive.name == "while"]
+    assert len(loops) == (3 if n_lanes else 2)
+
+    def heavy(e, rows):
+        """The index pool through `rows` tables, or a score of `rows` decode
+        rows over every group."""
+        shape = e.outvars[0].aval.shape
+        return (e.primitive.name == "gather"
+                and shape == (rows, NLB, 1, BS // KP, DI)
+                or e.primitive.name == "dot_general" and shape[0] == rows
+                and shape[-1] == g)
+    assert not [e for e in outside if e.primitive.name == "sort"]
+    assert not [e for e in outside if heavy(e, s) or heavy(e, sla._STEP)]
+    inside = [e for w in loops
+              for e in _eqns_outside_loops(w.params["body_jaxpr"].jaxpr)]
+    sorts = [e for e in inside if e.primitive.name == "sort"]
+    assert [e.outvars[0].aval.shape for e in sorts] == [(sla._STEP, g)]
+    assert sum(heavy(e, sla._STEP) for e in inside) == 2
+
+
+@pytest.mark.parametrize("count, n_rows, rows", [
+    (0, 64, 0), (1, 64, 8), (7, 64, 8), (8, 64, 8), (9, 64, 16), (16, 64, 16),
+    (17, 64, 24), (33, 64, 40), (57, 64, 64), (64, 64, 64), (0, 320, 0),
+    (64, 320, 64), (65, 320, 72), (128, 320, 128), (135, 320, 136),
+    (263, 320, 264), (313, 320, 320), (320, 320, 320), (5, 6, 6),
+    (9, 12, 12), (1, 2, 2), (0, 2, 0)])
+def test_rung_holds_the_live_rows(count, n_rows, rows):
+    assert sla.rung(count, n_rows) == rows
+    assert rows == n_rows or rows % sla._STEP == 0 and rows - count < sla._STEP
